@@ -85,6 +85,15 @@ _FLOAT_DTYPES = ("f16", "bf16", "f32", "f64", "f8e4m3fn", "f8e5m2")
 # identical programs built from different checkouts must fingerprint
 # identically, so metadata is stripped before hashing
 _HLO_METADATA_RE = re.compile(r",?\s*metadata=\{[^}]*\}")
+# the module header's stack-frame tables (file names with the checkout
+# path, function names, line/column locations, the frames the
+# per-instruction `stack_frame_id`s index): each is a title line followed
+# by numbered rows — they describe the call stack, not the program
+_HLO_STACK_TABLES_RE = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+    r"(?:\d+ .*\n)*\n?",
+    re.MULTILINE,
+)
 # StableHLO location info (same role as HLO metadata)
 _MLIR_LOC_RE = re.compile(r"\s*loc\([^)]*\)")
 _MLIR_LOCDEF_RE = re.compile(r"^#loc.*$", re.MULTILINE)
@@ -92,8 +101,10 @@ _MLIR_LOCDEF_RE = re.compile(r"^#loc.*$", re.MULTILINE)
 
 def canonicalize_hlo(hlo_text: str) -> str:
     """The optimized HLO module with per-instruction metadata (source
-    paths/lines, op_name) stripped — what the `hlo_fingerprint` hashes."""
-    return _HLO_METADATA_RE.sub("", hlo_text)
+    paths/lines, op_name) and the header's stack-frame tables stripped —
+    what the `hlo_fingerprint` hashes. The fingerprint must not depend on
+    the call stack or on where the checkout lives."""
+    return _HLO_METADATA_RE.sub("", _HLO_STACK_TABLES_RE.sub("", hlo_text))
 
 
 def canonicalize_stablehlo(mlir_text: str) -> str:

@@ -218,15 +218,22 @@ def lower_step_program(
 def step_example_args_cg(instance, loss_attrs, label_dtype=None):
     """Zero-filled (batch, label, rng) for a ComputationGraph-backed
     instance (ModelTrainingInstance / DataParallelTrainingInstance) —
-    the trace-only fingerprint path's example arguments. Placement is
-    irrelevant here: the DP jit carries explicit in_shardings, and a
-    trace never touches device buffers."""
+    the trace-only fingerprint path's example arguments. They are placed
+    as the dataloader places real batches (the DP instance's input/label
+    shardings): jit keys its trace on argument placement, so arguments
+    placed any other way trace the step a second time, under other
+    private function names, and the executable compiled from this
+    lowering is then not the one fit() reuses."""
     import jax
     import jax.numpy as jnp
 
     from flexflow_tpu.op_attrs.ops import InputAttrs
     from flexflow_tpu.parallel.executor import param_key
 
+    def place(arr, sharding):
+        return arr if sharding is None else jax.device_put(arr, sharding)
+
+    sharded = hasattr(instance, "input_sharding")
     cg = instance.cg
     batch: Dict[str, object] = {}
     for n in cg.topological_ordering():
@@ -235,11 +242,16 @@ def step_example_args_cg(instance, loss_attrs, label_dtype=None):
             continue
         (out,) = cg.outputs_of(n)
         ts = cg.tensor_shape(out)
-        batch[la.name or param_key(n)] = jnp.zeros(
-            tuple(ts.dims), ts.dtype.to_jnp()
+        key = la.name or param_key(n)
+        batch[key] = place(
+            jnp.zeros(tuple(ts.dims), ts.dtype.to_jnp()),
+            instance.input_sharding(key) if sharded else None,
         )
     logit_ts = cg.tensor_shape(instance.logit_tensor)
-    label = _example_label(logit_ts.dims, loss_attrs, label_dtype)
+    label = place(
+        _example_label(logit_ts.dims, loss_attrs, label_dtype),
+        instance.label_sharding() if sharded else None,
+    )
     return batch, label, jax.random.PRNGKey(0)
 
 

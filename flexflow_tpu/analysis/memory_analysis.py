@@ -456,19 +456,11 @@ def detect_device_hbm_bytes() -> Optional[int]:
     (`memory_stats()["bytes_limit"]`), or None when the backend does not
     expose one (the CPU test mesh): capacity-relative rules then cannot
     trip, but peak timelines are still computed and recorded."""
-    try:
-        import jax
+    import jax
 
-        stats = jax.local_devices()[0].memory_stats()
-        if stats:
-            limit = stats.get("bytes_limit")
-            if limit:
-                return int(limit)
-    except Exception:
-        return None
-    return None
-
-
+    stats = jax.local_devices()[0].memory_stats()
+    limit = (stats or {}).get("bytes_limit")
+    return int(limit) if limit else None
 
 
 def verify_memory(

@@ -30,6 +30,36 @@ _CACHE: Dict[Tuple[str, int], "MachineCalibration"] = {}
 
 
 @dataclass(frozen=True)
+class _ProbeSizes:
+    matmul_n: int  # square matmul edge
+    compute_dtype: str
+    hbm_bytes: int  # elementwise pass, f32
+    payloads: Tuple[int, int]  # all-reduce bytes per device: small, large
+    collective_chain: int  # dependent all-reduces per probe call
+    measure_iters: int
+
+
+# A probe measures the device only if one call's device time is several
+# times what the call costs the host to dispatch; under that the two-point
+# slope returns the dispatch rate. On the TPU v5e host a jitted call costs
+# ~0.19 ms, which is what a 2048^3 bf16 matmul (0.09 ms of device time) and a
+# 64 MiB elementwise pass read as, while 8192^3 reads 187 TFLOP/s and 1 GiB
+# reads 658 GB/s (my chip run, PR 21). The all-reduce probe chains dependent
+# collectives inside one program for the same reason. On the CPU mesh the
+# probes stay small: every op there takes milliseconds and tier-1 runs them.
+_PROBE_SIZES = {
+    "cpu": _ProbeSizes(512, "float32", 8 << 20, (1 << 20, 8 << 20), 1, 4),
+    "tpu": _ProbeSizes(8192, "bfloat16", 1 << 30, (4 << 20, 32 << 20), 8, 8),
+}
+
+
+def _probe_sizes() -> _ProbeSizes:
+    import jax
+
+    return _PROBE_SIZES[jax.default_backend()]
+
+
+@dataclass(frozen=True)
 class CollectiveConstants:
     """Fitted all-reduce constants for one participant count."""
 
@@ -141,11 +171,10 @@ def _measure_compute(settings) -> float:
 
     from flexflow_tpu.kernels.profiling import profile_fn
 
-    on_cpu = jax.default_backend() == "cpu"
-    n = 512 if on_cpu else 2048
-    dtype = jnp.float32 if on_cpu else jnp.bfloat16
-    a = jnp.ones((n, n), dtype)
-    b = jnp.ones((n, n), dtype)
+    sizes = _probe_sizes()
+    n = sizes.matmul_n
+    a = jnp.ones((n, n), sizes.compute_dtype)
+    b = jnp.ones((n, n), sizes.compute_dtype)
     f = jax.jit(lambda a, b: a @ b)
     ms = profile_fn(f, settings, a, b)
     return 2 * n**3 / (ms / 1000.0)
@@ -158,8 +187,7 @@ def _measure_hbm(settings) -> float:
 
     from flexflow_tpu.kernels.profiling import profile_fn
 
-    on_cpu = jax.default_backend() == "cpu"
-    n = (8 if on_cpu else 64) * 1024 * 1024 // 4  # 8MB / 64MB f32
+    n = _probe_sizes().hbm_bytes // 4
     x = jnp.ones((n,), jnp.float32)
     f = jax.jit(lambda x: x * 1.0001 + 1.0)
     ms = profile_fn(f, settings, x)
@@ -180,12 +208,19 @@ def _measure_allreduce(devs, k, payload_bytes, settings) -> float:
     m = max(1, payload_bytes // 4)
     x = jnp.ones((k, m), jnp.float32)
     x = jax.device_put(x, NamedSharding(mesh, P("a")))
-    f = jax.jit(
-        shard_map_compat(lambda v: jax.lax.psum(v, "a"), mesh, P("a"), P("a"))
-    )
+    chain = _probe_sizes().collective_chain
+
+    def allreduce_chain(v):
+        # each all-reduce consumes the last one's result, so none can be
+        # hoisted or merged (ones grow to k**chain, far inside f32)
+        return jax.lax.fori_loop(
+            0, chain, lambda _, v: jax.lax.psum(v, "a"), v
+        )
+
+    f = jax.jit(shard_map_compat(allreduce_chain, mesh, P("a"), P("a")))
     # min-of-repeats: host contention (the emulated mesh shares the host
     # with everything else) only ever ADDS time
-    return min(profile_fn(f, settings, x) for _ in range(3))
+    return min(profile_fn(f, settings, x) for _ in range(3)) / chain
 
 
 def _measure_overlap(devs, payload_bytes, settings) -> Optional[float]:
@@ -210,8 +245,7 @@ def _measure_overlap(devs, payload_bytes, settings) -> Optional[float]:
     k = len(devs)
     if k <= 1:
         return None
-    on_cpu = jax.default_backend() == "cpu"
-    dtype = jnp.float32 if on_cpu else jnp.bfloat16
+    dtype = _probe_sizes().compute_dtype
     mesh = Mesh(np.asarray(devs), ("a",))
     m_el = max(1, payload_bytes // 4)
     w = jax.device_put(
@@ -267,9 +301,8 @@ def _measure_shard_speedup(devs, settings) -> Optional[float]:
     k = len(devs)
     if k <= 1:
         return None
-    on_cpu = jax.default_backend() == "cpu"
-    n = 512 if on_cpu else 2048
-    dtype = jnp.float32 if on_cpu else jnp.bfloat16
+    sizes = _probe_sizes()
+    n, dtype = sizes.matmul_n, sizes.compute_dtype
     a = jnp.ones((k, n, n), dtype)
     w = jnp.ones((n, n), dtype)
     f = jax.jit(lambda a, w: a @ w)
@@ -283,14 +316,18 @@ def _measure_shard_speedup(devs, settings) -> Optional[float]:
     return max(1.0, min(float(k), t_serial / t_sharded))
 
 
-def calibrate(devices=None, payloads=(1 << 20, 8 << 20)) -> MachineCalibration:
+def calibrate(devices=None) -> MachineCalibration:
     """Measure the attached backend. ~2-5s on the 8-device CPU mesh."""
     import jax
 
     from flexflow_tpu.kernels.profiling import ProfilingSettings
 
     devs = list(devices if devices is not None else jax.devices())
-    settings = ProfilingSettings(warmup_iters=1, measure_iters=4)
+    sizes = _probe_sizes()
+    payloads = sizes.payloads
+    settings = ProfilingSettings(
+        warmup_iters=1, measure_iters=sizes.measure_iters
+    )
     peak_flops = _measure_compute(settings)
     hbm_gbps = _measure_hbm(settings)
 

@@ -82,14 +82,12 @@ def device_kind_signature() -> str:
     global _DEVICE_KIND_CACHE
     if _DEVICE_KIND_CACHE is not None:
         return _DEVICE_KIND_CACHE
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        kind = str(getattr(dev, "device_kind", "") or "").strip()
-        _DEVICE_KIND_CACHE = f"{jax.default_backend()}:{kind or 'unknown'}"
-    except Exception:
-        return "unknown:unknown"  # uncached: the backend may appear later
+    # a backend that fails to initialise raises here: keying measurements
+    # under an "unknown" device would file them where no session looks
+    dev = jax.devices()[0]
+    _DEVICE_KIND_CACHE = f"{dev.platform}:{dev.device_kind}"
     return _DEVICE_KIND_CACHE
 
 

@@ -9,6 +9,7 @@ GPU-isms reinterpreted (workers_per_node = TPU chips per host).
 from __future__ import annotations
 
 import argparse
+import os
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -63,10 +64,6 @@ class FFConfig:
     # tests). Epoch ends (and recompile triggers) end a window early: the
     # tail runs as a smaller window.
     steps_per_dispatch: int = 1
-    # persistent XLA compilation cache (jax_compilation_cache_dir): repeat
-    # runs of the same program skip recompiles — the searched flagship
-    # compiles in seconds instead of minutes on a warm cache. Empty = off.
-    compile_cache_dir: str = ""
     # elastic runtime (runtime/checkpoint.py): checkpoint_dir enables
     # fit-loop checkpointing — full-resume snapshots (params, opt state,
     # RNG stream position, dataloader epoch + cursor) every
@@ -288,13 +285,6 @@ class FFConfig:
             "(lax.scan over a stacked batch window; 1 = per-step loop)",
         )
         p.add_argument(
-            "--compile-cache-dir",
-            type=str,
-            default="",
-            help="persistent XLA compilation cache directory "
-            "(jax_compilation_cache_dir): repeat runs skip recompiles",
-        )
-        p.add_argument(
             "--checkpoint-dir",
             type=str,
             default="",
@@ -514,7 +504,6 @@ class FFConfig:
             health_policy=getattr(args, "health_policy", "off"),
             plan_audit=getattr(args, "plan_audit", False),
             steps_per_dispatch=getattr(args, "steps_per_dispatch", 1),
-            compile_cache_dir=getattr(args, "compile_cache_dir", ""),
             checkpoint_dir=getattr(args, "checkpoint_dir", ""),
             checkpoint_every_n_steps=getattr(
                 args, "checkpoint_every_n_steps", 0
@@ -557,18 +546,32 @@ class FFConfig:
         )
 
 
-def configure_compilation_cache(cache_dir: str) -> None:
-    """Point jax's persistent compilation cache at `cache_dir`
-    (`--compile-cache-dir`): a second process compiling the identical step
-    program loads the cached executable instead of re-running XLA. The
-    min-entry/min-compile-time floors are dropped so even small test
-    programs cache (the default floors skip everything under 1 s of
-    compile time, which on CPU meshes is most of the suite). Idempotent."""
+#: where the persistent XLA compilation cache lives when the environment
+#: does not place it: one fixed path inside the checkout (the path is part
+#: of the cache key, so a directory that moves never hits)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+
+def configure_compilation_cache() -> str:
+    """Turn on jax's persistent compilation cache and return its
+    directory: a second process compiling the identical step program
+    loads the cached executable instead of re-running XLA. Where
+    `JAX_COMPILATION_CACHE_DIR` is set jax already reads it and no
+    directory is set in code; otherwise the cache goes to
+    `DEFAULT_COMPILE_CACHE_DIR`. Call before the first compile — jax
+    decides once per process whether the cache is used. Idempotent."""
     import jax
 
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update(
+            "jax_compilation_cache_dir", DEFAULT_COMPILE_CACHE_DIR
+        )
+    return jax.config.jax_compilation_cache_dir
 
 
 @dataclass

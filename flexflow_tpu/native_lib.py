@@ -3,16 +3,21 @@
 The reference implements its graph machinery and pattern matcher natively in
 C++17 (lib/utils, lib/substitutions); this build does the same, exposed over a
 flat C ABI since pybind11 is not available. The library is compiled lazily
-with g++ on first use and cached under native/build/; every algorithm has a
-pure-Python fallback so the framework works without a toolchain
-(FF_TPU_NO_NATIVE=1 disables the native path entirely).
+with g++ on first use and cached under native/build/ in a file named by the
+hash of its sources, so a copied or checked-out tree never loads a library
+built from other sources. Every algorithm has a pure-Python fallback so the
+framework works without a toolchain (FF_TPU_NO_NATIVE=1 disables the native
+path entirely); a build that fails says why once on stderr and in
+`load_error()`.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import sys
 import threading
 from typing import List, Optional, Sequence, Tuple
 
@@ -20,32 +25,40 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_REPO_ROOT, "native", "src", "ffcore.cc")
 _HDR_DIR = os.path.join(_REPO_ROOT, "native", "include")
 _BUILD_DIR = os.path.join(_REPO_ROOT, "native", "build")
-_SO = os.path.join(_BUILD_DIR, "_ffcore.so")
 
 _ABI_VERSION = 10
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_lib_failed = False
+_lib_error: Optional[str] = None
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_SO):
-        return True
-    src_mtime = max(
-        os.path.getmtime(_SRC),
-        os.path.getmtime(os.path.join(_HDR_DIR, "ffcore.h")),
-    )
-    return os.path.getmtime(_SO) < src_mtime
+def _so_path() -> str:
+    """The library path for the sources as they are now: staleness is a
+    different file name, never an mtime comparison (a copy or a checkout
+    resets mtimes)."""
+    h = hashlib.sha256()
+    for path in (_SRC, os.path.join(_HDR_DIR, "ffcore.h")):
+        with open(path, "rb") as f:
+            h.update(f.read())
+    return os.path.join(_BUILD_DIR, f"_ffcore_{h.hexdigest()[:16]}.so")
 
 
-def _build() -> None:
+def _build(so: str) -> None:
     os.makedirs(_BUILD_DIR, exist_ok=True)
+    # build beside the target and rename: a concurrent process never
+    # dlopens a half-written file
+    tmp = f"{so}.{os.getpid()}.tmp"
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC",
-        "-I", _HDR_DIR, "-o", _SO, _SRC,
+        "-I", _HDR_DIR, "-o", tmp, _SRC,
     ]
-    subprocess.run(cmd, check=True, capture_output=True)
+    try:
+        subprocess.run(cmd, check=True, capture_output=True, text=True)
+        os.replace(tmp, so)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def _configure(lib: ctypes.CDLL) -> None:
@@ -94,36 +107,49 @@ def _configure(lib: ctypes.CDLL) -> None:
 def get_lib() -> Optional[ctypes.CDLL]:
     """Returns the loaded native library, building it if necessary.
 
-    Returns None (and remembers the failure) if disabled or the build fails.
+    Returns None if disabled (FF_TPU_NO_NATIVE) or if the build or load
+    failed; the failure is remembered, reported once on stderr, and kept
+    for `load_error()`.
     """
-    global _lib, _lib_failed
+    global _lib, _lib_error
     if _lib is not None:
         return _lib
-    if _lib_failed or os.environ.get("FF_TPU_NO_NATIVE"):
+    if _lib_error is not None or os.environ.get("FF_TPU_NO_NATIVE"):
         return None
     with _lock:
         if _lib is not None:
             return _lib
         try:
-            if _needs_build():
-                _build()
-            lib = ctypes.CDLL(_SO)
+            so = _so_path()
+            if not os.path.exists(so):
+                _build(so)
+            lib = ctypes.CDLL(so)
             _configure(lib)
             if lib.ffc_abi_version() != _ABI_VERSION:
-                # stale binary: unlink first so the relink gets a fresh inode
-                # (dlopen would otherwise hand back the cached stale handle)
-                os.unlink(_SO)
-                _build()
-                lib = ctypes.CDLL(_SO)
-                _configure(lib)
-                if lib.ffc_abi_version() != _ABI_VERSION:
-                    _lib_failed = True
-                    return None
+                raise RuntimeError(
+                    f"ffcore.cc reports ABI {lib.ffc_abi_version()}, "
+                    f"native_lib.py expects {_ABI_VERSION}"
+                )
             _lib = lib
-        except Exception:
-            _lib_failed = True
-            return None
+        except subprocess.CalledProcessError as e:
+            _lib_error = f"g++ exited {e.returncode}: {e.stderr[-2000:]}"
+        except (OSError, RuntimeError, AttributeError) as e:
+            # no g++ on PATH / unreadable sources / dlopen failure / ABI
+            # mismatch / a symbol _configure expects is missing
+            _lib_error = f"{type(e).__name__}: {e}"
+        if _lib_error is not None:
+            print(
+                "[flexflow_tpu] native core unavailable, using the Python "
+                f"fallbacks: {_lib_error}",
+                file=sys.stderr,
+            )
     return _lib
+
+
+def load_error() -> Optional[str]:
+    """Why `get_lib()` returned None after a failed build or load (None
+    when the library loaded, was never asked for, or is disabled)."""
+    return _lib_error
 
 
 def native_available() -> bool:

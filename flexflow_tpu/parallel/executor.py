@@ -727,6 +727,9 @@ class DistributedTrainingInstance:
             if overlap_lowering_active(overlap)
             else {}
         )
+        # (params, opt_state) shardings, recorded by initialize(): the
+        # step programs hand the new state back under exactly these
+        self._state_shardings = None
         self._jit_step = None
         self._jit_multi_step = None
         self._jit_fwd = None
@@ -737,6 +740,19 @@ class DistributedTrainingInstance:
         return cast_for_compute(tree, self.compute_dtype)
 
     # -- placement helpers -------------------------------------------------
+
+    def _state_out_shardings(self, n_outputs: int):
+        """jit `out_shardings` pinning a step program's first two outputs
+        (new params, new optimizer state) to the shardings the state
+        arrives with. Left to XLA, a state leaf comes back under an equal
+        layout spelled differently (`PartitionSpec()` for the
+        `PartitionSpec(None,)` it was placed with), jit keys its cache on
+        the spelling, and the second step of every fit recompiles the
+        whole program — 31 s on the 12-layer flagship (my chip run,
+        PR 21)."""
+        if self._state_shardings is None:
+            return None
+        return (*self._state_shardings, *([None] * (n_outputs - 2)))
 
     def _weight_sharding(self, n: Node):
         (out,) = self.pcg.outputs_of(n)
@@ -772,6 +788,8 @@ class DistributedTrainingInstance:
     def initialize(self, seed: int = 0):
         """Global init + placement onto the mesh (sharded weight, replicated
         optimizer moments sharded like their weight)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         params = init_pcg_params(self.pcg, jax.random.PRNGKey(seed))
         from flexflow_tpu.runtime.distributed import device_put_global
 
@@ -787,8 +805,26 @@ class DistributedTrainingInstance:
                     if s is not None
                     else params[k]
                 )
-        opt_state = make_optimizer_state(self.optimizer_attrs, placed)
-        return placed, opt_state
+        mesh = self.machine_mesh.mesh
+        replicated = NamedSharding(mesh, P())
+
+        def on_mesh(x):
+            # unconstrained weights and the optimizer's scalar slots are
+            # born on the default device; the step returns them replicated
+            s = x.sharding
+            return (
+                s if isinstance(s, NamedSharding) and s.mesh == mesh
+                else replicated
+            )
+
+        state = (placed, make_optimizer_state(self.optimizer_attrs, placed))
+        self._state_shardings = jax.tree_util.tree_map(on_mesh, state)
+        return jax.tree_util.tree_map(
+            # a leaf already there is left alone: across processes it is a
+            # global array no single host can read back to re-place
+            lambda x, s: x if x.sharding == s else device_put_global(x, s),
+            state, self._state_shardings,
+        )
 
     # -- step --------------------------------------------------------------
 
@@ -831,7 +867,12 @@ class DistributedTrainingInstance:
 
     def compiled_step(self):
         if self._jit_step is None:
-            self._jit_step = jax.jit(self._step, donate_argnums=(0, 1))
+            self._jit_step = jax.jit(
+                self._step, donate_argnums=(0, 1),
+                out_shardings=self._state_out_shardings(
+                    5 if self.collect_step_stats else 4
+                ),
+            )
         return self._jit_step
 
     def _multi_step(self, params, opt_state, batch_stack, label_stack, rng):
@@ -851,7 +892,8 @@ class DistributedTrainingInstance:
         scan body unchanged."""
         if self._jit_multi_step is None:
             self._jit_multi_step = jax.jit(
-                self._multi_step, donate_argnums=(0, 1)
+                self._multi_step, donate_argnums=(0, 1),
+                out_shardings=self._state_out_shardings(6),
             )
         return self._jit_multi_step
 

@@ -122,15 +122,14 @@ def serving_search_context(
         MachineMappingContext,
     )
 
-    # same backend-keyed machine constants as FFModel._compile_distributed:
+    # same device-kind-keyed machine constants as FFModel._compile_searched:
     # a serving search priced with TPU numbers but executed on the CPU
     # test mesh would pick plans the emulation cannot afford
-    if jax.default_backend() == "cpu":
-        peak_flops, hbm_gbps = 5e10, 10.0
-        ici_lat_ms, dcn_lat_ms = 0.1, 0.2
-    else:
-        peak_flops, hbm_gbps = 197e12, 820.0
-        ici_lat_ms, dcn_lat_ms = 0.001, 0.01
+    from flexflow_tpu.compiler.machine_constants import machine_constants
+
+    mc = machine_constants()
+    peak_flops, hbm_gbps = mc.peak_flops, mc.hbm_gbps
+    ici_lat_ms, dcn_lat_ms = mc.ici_latency_ms, mc.dcn_latency_ms
     cost_store = None
     if cost_store_dir:
         import os

@@ -129,6 +129,9 @@ class ServingProgram:
         params_seed: int = 0,
         params: Optional[Dict[str, jnp.ndarray]] = None,
     ) -> None:
+        from flexflow_tpu.local_execution.config import (
+            configure_compilation_cache,
+        )
         from flexflow_tpu.op_attrs.ops import InputAttrs
         from flexflow_tpu.parallel.executor import (
             collect_overlap_sites,
@@ -136,6 +139,8 @@ class ServingProgram:
         )
         from flexflow_tpu.parallel.sharding import pcg_shardings
 
+        # before this program's first compile (param init below)
+        configure_compilation_cache()
         self.pcg = _as_pcg(graph)
         self.serving = serving
         self.machine_mesh = machine_mesh

@@ -1,27 +1,17 @@
-"""jax.shard_map version-compatibility shim, shared by every shard_map call
-site (executor lowering, flash attention SPMD entry, calibration probes).
+"""The one `jax.shard_map` spelling every call site shares (executor
+lowering, flash attention SPMD entry, calibration probes).
 
-Newer jax exposes `jax.shard_map` with `check_vma`; older versions spell it
-`jax.experimental.shard_map.shard_map` with `check_rep`. Replication checking
-is disabled in all cases: it cannot see through a pallas_call's out_shape,
-and our call sites declare exact specs.
+Replication (vma) checking is disabled: it cannot see through a
+pallas_call's out_shape, and our call sites declare exact specs.
 """
 
 from __future__ import annotations
 
+import jax
+
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    try:
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    except TypeError:  # older jax spells it check_rep
-        return shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )

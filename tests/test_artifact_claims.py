@@ -1,5 +1,5 @@
 """Claims hygiene in the tier-1 suite: every numeric claim README.md makes
-must match the driver-captured artifact it is anchored to
+must match the committed artifact it is anchored to
 (tools/check_artifact_claims.py)."""
 
 import os
@@ -29,14 +29,14 @@ def test_every_claim_is_anchored():
 
 
 def test_mismatch_is_detected(tmp_path):
-    # a README claiming a wrong headline MFU must fail the checker
+    # a README claiming a wrong A/B speedup must fail the checker
     with open(os.path.join(REPO, "README.md")) as f:
         text = f.read()
     import re
 
     bad = re.sub(
-        r"tree measures \*\*[\d.]+% MFU\*\*",
-        "tree measures **99.9% MFU**",
+        r"beating measured DP by [\d.]+x",
+        "beating measured DP by 9.99x",
         text,
         count=1,
     )
@@ -44,7 +44,7 @@ def test_mismatch_is_detected(tmp_path):
     p = tmp_path / "README.md"
     p.write_text(bad)
     failures = check_artifact_claims.check(str(p))
-    assert any("headline MFU" in f for f in failures)
+    assert any("A/B transformer searched win" in f for f in failures)
 
 
 def test_serving_family_mismatch_is_detected(tmp_path):

@@ -536,6 +536,33 @@ class TestCompileProvenance:
         assert check["match"] is True
         assert check["fingerprint_field"] == "hlo_fingerprint"
 
+    def test_fit_compiles_the_searched_step_once(self):
+        """The step hands its state back under the shardings it arrived
+        with: left to XLA, an equal layout spelled differently
+        (`PartitionSpec()` for `PartitionSpec(None,)`) re-keys jit's cache
+        and the second step of every fit recompiles the whole program."""
+        from flexflow_tpu.core import FFConfig
+
+        m = _small_model(FFConfig(batch_size=16, search_budget=2))
+        X, Y = _xy()
+        m.fit(X, Y, epochs=1, batch_size=16, verbose=False)  # 4 steps
+        assert m.instance.compiled_step()._cache_size() == 1
+
+    def test_fingerprint_independent_of_call_site(self):
+        """XLA prints the Python call stack (file names with the checkout
+        path, function names) in the HLO module header: the same searched
+        program compiled from two call sites must keep one fingerprint."""
+        from flexflow_tpu.core import FFConfig
+
+        def another_call_site():
+            return _small_model(FFConfig(batch_size=16, search_budget=2))
+
+        a = _small_model(FFConfig(batch_size=16, search_budget=2))
+        b = another_call_site()
+        rec_a, rec_b = (m.search_provenance["exec"] for m in (a, b))
+        assert rec_a["hlo_fingerprint"] == rec_b["hlo_fingerprint"]
+        assert rec_a["program_fingerprint"] == rec_b["program_fingerprint"]
+
 
 @pytest.mark.filterwarnings("ignore")
 class TestResumeContract:

@@ -340,12 +340,9 @@ class TestFusedConfig:
 
         p = argparse.ArgumentParser()
         FFConfig.add_args(p)
-        args = p.parse_args(
-            ["--steps-per-dispatch", "8", "--compile-cache-dir", "/tmp/c"]
-        )
+        args = p.parse_args(["--steps-per-dispatch", "8"])
         cfg = FFConfig.from_args(args)
         assert cfg.steps_per_dispatch == 8
-        assert cfg.compile_cache_dir == "/tmp/c"
 
 
 @pytest.mark.slow

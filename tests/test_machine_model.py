@@ -374,3 +374,16 @@ class TestPerAxisLinkPricing:
             ),
         ))
         assert model.movement_cost_ms(m_feat) > model.movement_cost_ms(m_batch)
+
+
+def test_machine_constants_are_keyed_by_device_kind_and_unknown_raises():
+    """One sourced table; a device that is not in it is an error, never
+    another chip's peaks."""
+    from flexflow_tpu.compiler.machine_constants import machine_constants
+
+    v5e = machine_constants("TPU v5 lite")
+    assert (v5e.peak_flops, v5e.hbm_gbps) == (197e12, 819.0)
+    assert "TPU v5e" in v5e.source
+    assert machine_constants() is machine_constants("cpu")  # the test mesh
+    with pytest.raises(ValueError, match="TPU v9"):
+        machine_constants("TPU v9")

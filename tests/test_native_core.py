@@ -139,3 +139,23 @@ class TestNativeTTSPDecompose:
         sp = _ttsp_decomposition(g)
         assert isinstance(sp, SeriesSplit)
         assert sp == self._python_ttsp(monkeypatch, g)
+
+
+def test_library_is_named_by_source_hash_and_build_failure_is_reported(
+    monkeypatch, tmp_path, capsys
+):
+    """Staleness is a file name, not an mtime (a copied tree resets
+    mtimes), and a failed build says why instead of a bare None."""
+    import os
+
+    good = os.path.basename(native_lib._so_path())
+    bad_src = tmp_path / "ffcore.cc"
+    bad_src.write_text("this is not C++\n")
+    monkeypatch.setattr(native_lib, "_SRC", str(bad_src))
+    monkeypatch.setattr(native_lib, "_BUILD_DIR", str(tmp_path / "build"))
+    monkeypatch.setattr(native_lib, "_lib", None)
+    monkeypatch.setattr(native_lib, "_lib_error", None)
+    assert os.path.basename(native_lib._so_path()) != good
+    assert native_lib.get_lib() is None
+    assert "g++ exited" in native_lib.load_error()
+    assert "native core unavailable" in capsys.readouterr().err

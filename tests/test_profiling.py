@@ -1,10 +1,11 @@
 """kernels/profiling.py coverage: the two-point slope measurement, the
 noisy fallback (per_iter <= 0), and force_sync on array-free pytrees.
 
-The two-point discipline exists because tunneled backends add a large FIXED
-dispatch/round-trip latency to every run: per-iter time must come from the
-slope between a short and a long run, not a single average. The slope tests
-substitute a synthetic _timed_run so the arithmetic is pinned exactly.
+The two-point discipline exists because every timed window pays a FIXED
+cost (the first dispatch's ramp and the final sync): per-iter time must come
+from the slope between a short and a long run, not a single average. The
+slope tests substitute a synthetic _timed_run so the arithmetic is pinned
+exactly.
 """
 
 import jax.numpy as jnp
@@ -84,7 +85,7 @@ class TestTwoPointSlope:
 
 class TestForceSync:
     def test_empty_pytrees_are_noops(self):
-        # no leaf with a dtype -> nothing to read back, no error
+        # no array leaf -> nothing to wait on, no error
         force_sync(None)
         force_sync({})
         force_sync([])
@@ -96,11 +97,10 @@ class TestForceSync:
 
     def test_array_pytree_syncs(self):
         out = {"loss": jnp.ones((3,)), "metrics": (jnp.zeros(()), None)}
-        force_sync(out)  # completes the host readback without error
+        force_sync(out)  # completes the wait without error
 
     def test_zero_size_array_leaf(self):
-        # jnp.ravel(x)[0] on an empty array is an out-of-bounds read —
-        # zero-size leaves carry no device work to wait on and are skipped
+        # zero-size leaves carry no device work to wait on
         force_sync(jnp.zeros((0,)))
         force_sync({"empty": jnp.zeros((0, 4)), "real": jnp.ones((2,))})
 

@@ -1,8 +1,8 @@
 """Claims hygiene: cross-check README.md's numeric claims against the
-driver-captured benchmark artifacts (BENCH_r*.json / AB_r*.json).
+committed artifacts (AB_r*.json, AUDIT_r*.json, ...).
 
 Every checked claim is anchored to the ROUND NUMBER the README text itself
-names ("round-5 tree", "BENCH_r04.json", "Round-5 highlights"), so the
+names ("AB_r05.json", "Round-5 highlights"), so the
 checker stays valid when later rounds land: a round-5 claim is forever
 checked against the round-5 artifact. A claim whose anchor text disappears
 from the README fails too — silently dropping a checked claim is how stale
@@ -26,15 +26,6 @@ if REPO not in sys.path:  # live claims import flexflow_tpu.analysis
     sys.path.insert(0, REPO)
 
 
-def load_bench(round_no: int) -> Optional[dict]:
-    path = os.path.join(REPO, f"BENCH_r{round_no:02d}.json")
-    if not os.path.exists(path):
-        return None
-    with open(path) as f:
-        d = json.load(f)
-    return d.get("parsed", d)
-
-
 def load_ab(round_no: int) -> Optional[list]:
     path = os.path.join(REPO, f"AB_r{round_no:02d}.json")
     if not os.path.exists(path):
@@ -45,9 +36,7 @@ def load_ab(round_no: int) -> Optional[list]:
 
 def load_fused_bench(round_no: int) -> Optional[dict]:
     """Fused-dispatch artifact (`bench.py --fused` output, committed as
-    BENCH_FUSED_r*.json — a separate family from the driver-captured
-    headline BENCH_r*.json so the two captures never overwrite each
-    other)."""
+    BENCH_FUSED_r*.json)."""
     path = os.path.join(REPO, f"BENCH_FUSED_r{round_no:02d}.json")
     if not os.path.exists(path):
         return None
@@ -295,16 +284,6 @@ class Claim:
     artifact_value: Callable[[int], Optional[float]]
 
 
-def _bench_field(field: str, scale: float = 1.0):
-    def get(round_no: int) -> Optional[float]:
-        d = load_bench(round_no)
-        if d is None or d.get(field) is None:
-            return None
-        return float(d[field]) * scale
-
-    return get
-
-
 def _ab_speedup(model: str):
     def get(round_no: int) -> Optional[float]:
         ab = load_ab(round_no)
@@ -322,29 +301,6 @@ def _ab_inversions(round_no: int) -> Optional[float]:
 
 
 CLAIMS = [
-    Claim(
-        "driver-captured headline MFU",
-        r"last driver capture: `BENCH_r0?(?P<round>\d+)\.json` —\s*"
-        r"\*\*(?P<val>[\d.]+)% MFU\*\*",
-        _bench_field("value", 100.0),
-    ),
-    Claim(
-        "current-tree headline MFU",
-        r"round-(?P<round>\d+) tree measures \*\*(?P<val>[\d.]+)% MFU\*\*",
-        _bench_field("value", 100.0),
-    ),
-    Claim(
-        "headline step-time spread",
-        r"round-(?P<round>\d+) tree measures.{0,80}?"
-        r"with a (?P<val>[\d.]+) ms step-time spread",
-        _bench_field("step_time_spread_ms"),
-    ),
-    Claim(
-        "long-context MFU",
-        r"`longctx_seq2048_mfu`, (?P<val>[\d.]+)% on the "
-        r"round-(?P<round>\d+) tree",
-        _bench_field("longctx_seq2048_mfu", 100.0),
-    ),
     Claim(
         "A/B transformer searched win",
         r"Round-(?P<round>\d+) highlights.{0,400}?"
@@ -368,27 +324,6 @@ CLAIMS = [
         r"(?P<val>\d+) decisive rank-inversion.{0,200}?"
         r"`AB_r0?(?P<round>\d+)\.json`",
         _ab_inversions,
-    ),
-    # search-time performance claims (round-6 overhaul): wall-clock at the
-    # two bench budgets and the shared-cache hit rate, each anchored to the
-    # BENCH round the README text names
-    Claim(
-        "search seconds budget-30",
-        r"`search_seconds_12l_budget30` at \*\*(?P<val>[\d.]+) s\*\* "
-        r"\(`BENCH_r0?(?P<round>\d+)\.json`\)",
-        _bench_field("search_seconds_12l_budget30"),
-    ),
-    Claim(
-        "search seconds budget-8",
-        r"`search_seconds_12l_budget8` at \*\*(?P<val>[\d.]+) s\*\* "
-        r"\(`BENCH_r0?(?P<round>\d+)\.json`\)",
-        _bench_field("search_seconds_12l_budget8"),
-    ),
-    Claim(
-        "budget-30 mm_cache hit rate",
-        r"mm_cache hit rate is\s+\*\*(?P<val>[\d.]+)%\*\*\s+"
-        r"\(`BENCH_r0?(?P<round>\d+)\.json`",
-        _bench_field("search_mm_cache_hit_rate_b30", 100.0),
     ),
     # plan-audit / run-health claims (ISSUE 3): the audit numbers the
     # README quotes must match the committed AUDIT_r*.json they name

@@ -49,18 +49,7 @@ def build_instance(seq=512, batch=64, vocab=32000, layers=12, embed=1024, heads=
 
 def print_top_ops(outdir: str, steps: int, top: int = 25) -> None:
     """Parse the captured xplane with xprof and print per-op self time."""
-    try:
-        try:
-            from xprof.convert import raw_to_tool_data as rtd
-        except ImportError:
-            from tensorboard_plugin_profile.convert import raw_to_tool_data as rtd
-    except ImportError:
-        print(
-            "per-op breakdown skipped: install xprof or "
-            "tensorboard-plugin-profile to parse the trace "
-            f"(raw trace kept under {outdir})"
-        )
-        return
+    from xprof.convert import raw_to_tool_data as rtd
 
     xplanes = glob.glob(os.path.join(outdir, "plugins/profile/*/*.xplane.pb"))
     if not xplanes:
