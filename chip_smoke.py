@@ -24,9 +24,12 @@ Without a TPU the script exits non-zero before compiling anything.
 virtual CPU mesh with interpret-mode kernels; it prints `"platform": "cpu"`
 and is a check of the script, never of the chip.
 
-The last line of standard output is one JSON object:
-`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}, ...}`.
-Step times and MFU in it are information for the reader, not gates.
+The last line of standard output is one JSON object with exactly these keys,
+the device as JAX reports it:
+`{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}`.
+The line before it, `[chip_smoke] report: {...}`, is the full record (jax and
+libtpu versions, the compile cache and its hits, every phase). Step times and
+MFU in it are information for the reader, not gates.
 """
 
 import argparse
@@ -376,23 +379,23 @@ def main():
         + "  ".join(f"{k}={v['loss_first']:.6f}" for k, v in phases.items()),
         flush=True,
     )
-    print(
-        json.dumps(
-            {
-                "ok": True,
-                "device": {
-                    "platform": dev.platform,
-                    "kind": dev.device_kind,
-                    "count": ndev,
-                },
-                "jax": jax.__version__,
-                "libtpu": importlib.metadata.version("libtpu"),
-                "model": shapes,
-                "compile_cache": {"dir": cache_dir, **cache.snapshot()},
-                "phases": phases,
-            }
-        )
-    )
+    device = {
+        "platform": dev.platform,
+        "kind": dev.device_kind,
+        "count": ndev,
+    }
+    report = {
+        "device": device,
+        "jax": jax.__version__,
+        "libtpu": importlib.metadata.version("libtpu"),
+        "model": shapes,
+        "compile_cache": {"dir": cache_dir, **cache.snapshot()},
+        "phases": phases,
+    }
+    print(f"[chip_smoke] report: {json.dumps(report)}", flush=True)
+    # the result line: these keys and no others; every phase above raised
+    # on a failed check, so reaching it means all of them passed
+    print(json.dumps({"ok": True, "device": device}), flush=True)
     return 0
 
 

@@ -35,15 +35,21 @@ def test_cpu_rehearsal_passes_and_names_the_cpu(tmp_path):
         JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
     )
     assert out.returncode == 0, out.stderr[-3000:]
-    result = json.loads(out.stdout.splitlines()[-1])
-    assert result["ok"] is True
-    assert result["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
-    assert {k: v["backend"] for k, v in result["phases"].items()} == {
+    lines = out.stdout.splitlines()
+    # the result line carries these keys and no others
+    assert json.loads(lines[-1]) == {
+        "ok": True,
+        "device": {"platform": "cpu", "kind": "cpu", "count": 8},
+    }
+    tag = "[chip_smoke] report: "
+    assert lines[-2].startswith(tag)
+    report = json.loads(lines[-2][len(tag):])
+    assert {k: v["backend"] for k, v in report["phases"].items()} == {
         "single": "ModelTrainingInstance",
         "searched": "DistributedTrainingInstance",
         "dp": "DataParallelTrainingInstance",
     }
-    assert result["phases"]["searched"]["search"]["native_dp"] is True
+    assert report["phases"]["searched"]["search"]["native_dp"] is True
     # the environment placed the cache and the program used it there
-    assert result["compile_cache"]["dir"] == cache_dir
+    assert report["compile_cache"]["dir"] == cache_dir
     assert os.listdir(cache_dir)
