@@ -268,6 +268,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret=False):
         )
         o, lse = pl.pallas_call(
             kernel,
+            name="flash_fwd_rows_folded",
             interpret=interpret,
             compiler_params=None if interpret else pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")
@@ -293,6 +294,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret=False):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd_rows",
         interpret=interpret,
         grid=(bh, nq),
         in_specs=[
@@ -433,6 +435,7 @@ def _delta_rows(do, o, interpret=False):
     bb = _delta_fold_cap(bh, s, d, do.dtype.itemsize)
     return pl.pallas_call(
         _delta_kernel,
+        name="flash_delta_rows",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel",)
@@ -458,6 +461,7 @@ def _bwd_rows_fused(q, k, v, o, lse, do, causal, interpret=False):
     bb = _batch_block(bh, s, s, s, d, q.dtype.itemsize, fused_bwd=True)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel_b, causal=causal, scale=scale),
+        name="flash_bwd_fused_rows",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel",)
@@ -500,6 +504,7 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret=False):
         functools.partial(
             _bwd_dq_kernel, causal=causal, block_k=block_k, scale=scale
         ),
+        name="flash_bwd_dq_rows",
         interpret=interpret,
         grid=(bh, nq),
         in_specs=[
@@ -518,6 +523,7 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret=False):
         functools.partial(
             _bwd_dkv_kernel, causal=causal, block_q=block_q, scale=scale
         ),
+        name="flash_bwd_dkv_rows",
         interpret=interpret,
         grid=(bh, nk),
         in_specs=[
@@ -743,12 +749,13 @@ def _fwd_kernel_b(
 
 
 def _fwd_pair_call(
-    operands, b, s, f, h, causal, block_q, block_k, interpret, dtype,
+    name, operands, b, s, f, h, causal, block_q, block_k, interpret, dtype,
     qkv_index_maps,
 ):
     """Shared pallas_call of the head-pair forwards: `operands` are the q/k/v
-    arrays (three distinct, or the same fused-QKV array thrice) and
-    qkv_index_maps their minor-block index maps."""
+    arrays (three distinct, or the same fused-QKV array thrice),
+    qkv_index_maps their minor-block index maps and `name` the kernel's
+    name in the device trace."""
     d = f // h
     assert 2 * d == 128 and h % 2 == 0, (d, h)
     nq = s // block_q
@@ -761,6 +768,7 @@ def _fwd_pair_call(
     q_map, k_map, v_map = qkv_index_maps
     o, lse = pl.pallas_call(
         kernel,
+        name=name,
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
@@ -790,7 +798,8 @@ def _fwd_bshf_pair(q, k, v, h, causal, block_q, block_k, interpret=False):
     _fwd_kernel_pair."""
     b, s, f = q.shape
     return _fwd_pair_call(
-        (q, k, v), b, s, f, h, causal, block_q, block_k, interpret, q.dtype,
+        "flash_fwd_pair", (q, k, v), b, s, f, h, causal, block_q, block_k,
+        interpret, q.dtype,
         (
             lambda bi, hp, i: (bi, i, hp),
             lambda bi, hp, i: (bi, 0, hp),
@@ -810,6 +819,7 @@ def _bwd_bshf_pair_fused(q, k, v, o, lse, do, h, causal, interpret=False):
         functools.partial(
             _bwd_fused_kernel_pair, causal=causal, scale=scale, d=d
         ),
+        name="flash_bwd_fused_pair",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
@@ -846,8 +856,8 @@ def _fwd_bshf_pair_qkv(qkv, h, causal, block_q, block_k, interpret=False):
     no slicing copy."""
     b, s, f3 = qkv.shape
     return _fwd_pair_call(
-        (qkv, qkv, qkv), b, s, f3 // 3, h, causal, block_q, block_k,
-        interpret, qkv.dtype,
+        "flash_fwd_pair_qkv", (qkv, qkv, qkv), b, s, f3 // 3, h, causal,
+        block_q, block_k, interpret, qkv.dtype,
         (
             lambda bi, hp, i: (bi, i, 3 * hp),
             lambda bi, hp, i: (bi, 0, 3 * hp + 1),
@@ -868,6 +878,7 @@ def _bwd_bshf_pair_fused_qkv(qkv, o, lse, do, h, causal, interpret=False):
         functools.partial(
             _bwd_fused_kernel_pair_qkv, causal=causal, scale=scale, d=d
         ),
+        name="flash_bwd_fused_pair_qkv",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
@@ -945,6 +956,7 @@ def _fwd_bshf(q, k, v, h, causal, block_q, block_k, interpret=False):
     )
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd_bshf",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
@@ -1229,6 +1241,7 @@ def _delta_bshf(do, o, b, s, h, d, interpret=False):
     bb = _delta_fold_cap(b, s, d, do.dtype.itemsize)
     return pl.pallas_call(
         _delta_kernel,
+        name="flash_delta_bshf",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
@@ -1252,6 +1265,7 @@ def _bwd_bshf_fused(q, k, v, o, lse, do, h, causal, interpret=False):
     bb = _batch_block(b, s, s, s, d, q.dtype.itemsize, fused_bwd=True)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_bwd_fused_kernel_b, causal=causal, scale=scale),
+        name="flash_bwd_fused_bshf",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
@@ -1349,6 +1363,7 @@ def _bwd_bshf_onepass(q, k, v, o, lse, do, h, causal, block_q, block_k,
     delta4 = _delta_bshf(do, o, b, s, h, d, interpret)
     dq, dkp, dvp = pl.pallas_call(
         functools.partial(_bwd_onepass_kernel, scale=scale, nk=nk),
+        name="flash_bwd_onepass_bshf",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -1401,6 +1416,7 @@ def _bwd_bshf(q, k, v, o, lse, do, h, causal, block_q, block_k, interpret=False)
             _bwd_dq_kernel, causal=causal, block_k=block_k, scale=scale,
             pid_axis=2,
         ),
+        name="flash_bwd_dq_bshf",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")
@@ -1423,6 +1439,7 @@ def _bwd_bshf(q, k, v, o, lse, do, h, causal, block_q, block_k, interpret=False)
             _bwd_dkv_kernel, causal=causal, block_q=block_q, scale=scale,
             pid_axis=2,
         ),
+        name="flash_bwd_dkv_bshf",
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")

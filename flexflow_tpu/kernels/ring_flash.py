@@ -259,6 +259,7 @@ def _ring_fwd_step(
     )
     return pl.pallas_call(
         kernel,
+        name="ring_flash_fwd_step",
         interpret=interpret,
         grid=(bh, s_blk // block_q),
         in_specs=[
@@ -297,6 +298,7 @@ def _ring_dq_step(
     )
     return pl.pallas_call(
         kernel,
+        name="ring_flash_bwd_dq_step",
         interpret=interpret,
         grid=(bh, s_blk // block_q),
         in_specs=[
@@ -326,6 +328,7 @@ def _ring_dkv_step(
     )
     return pl.pallas_call(
         kernel,
+        name="ring_flash_bwd_dkv_step",
         interpret=interpret,
         grid=(bh, t_blk // block_k),
         in_specs=[

@@ -32,6 +32,7 @@ from flexflow_tpu.kernels import (
     loss_forward,
     make_optimizer_state,
 )
+from flexflow_tpu.observability import trace
 from flexflow_tpu.op_attrs.core import (
     IncomingTensorRole,
     OpAttrs,
@@ -150,14 +151,17 @@ def forward_interpreter(
         else:
             slot_vals = [env[v] for v in cg.inputs_of(n)]
             data_vals, weight_vals = split_slot_values(attrs, slot_vals)
-            if n in barrier_nodes:
-                data_vals = [optimization_barrier(x) for x in data_vals]
-            op_rng = (
-                jax.random.fold_in(rng, n.idx) if rng is not None else None
-            )
-            results = kernel_forward(
-                attrs, data_vals, weight_vals, train=train, rng=op_rng
-            )
+            # everything this node lowers to carries its name in the device
+            # trace (observability/trace.py)
+            with trace.node_scope(cg, n):
+                if n in barrier_nodes:
+                    data_vals = [optimization_barrier(x) for x in data_vals]
+                op_rng = (
+                    jax.random.fold_in(rng, n.idx) if rng is not None else None
+                )
+                results = kernel_forward(
+                    attrs, data_vals, weight_vals, train=train, rng=op_rng
+                )
             for o, r in zip(outs, results):
                 env[o] = r
     return env
@@ -312,38 +316,45 @@ class ModelTrainingInstance:
     # -- step -------------------------------------------------------------
 
     def loss_fn(self, params, batch_inputs, label, rng=None):
+        with trace.step_scope("cast"):
+            params = self._cast_for_compute(params)
+            batch_inputs = self._cast_for_compute(batch_inputs)
         env = forward_interpreter(
             self.cg,
-            self._cast_for_compute(params),
-            self._cast_for_compute(batch_inputs),
+            params,
+            batch_inputs,
             train=True,
             rng=rng,
             barrier_nodes=self._barrier_nodes,
         )
         logit = env[self.logit_tensor]
-        loss = loss_forward(self.loss_attrs, logit, label)
-        for t in self.aux_loss_tensors:
-            loss = loss + jnp.sum(env[t].astype(loss.dtype))
+        with trace.step_scope("loss"):
+            loss = loss_forward(self.loss_attrs, logit, label)
+            for t in self.aux_loss_tensors:
+                loss = loss + jnp.sum(env[t].astype(loss.dtype))
         return loss, logit
 
     def _step(self, params, opt_state, batch_inputs, label, rng):
         (loss, logit), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
             params, batch_inputs, label, rng
         )
-        new_params, new_opt_state = apply_optimizer(
-            self.optimizer_attrs, params, grads, opt_state
-        )
-        metric_vals = compute_metrics(self.metrics, logit, label)
+        with trace.step_scope("optimizer"):
+            new_params, new_opt_state = apply_optimizer(
+                self.optimizer_attrs, params, grads, opt_state
+            )
+        with trace.step_scope("metrics"):
+            metric_vals = compute_metrics(self.metrics, logit, label)
         # run-health scalars, fused into this same XLA program: each global
         # norm is one reduction over the pytree, not a host trip per leaf;
         # under skip_step/raise a non-finite update never reaches the
         # parameters or optimizer state
         from flexflow_tpu.observability.metrics import finalize_step
 
-        new_params, new_opt_state, stats = finalize_step(
-            self.collect_step_stats, self.guard_nonfinite_updates,
-            params, new_params, grads, loss, opt_state, new_opt_state,
-        )
+        with trace.step_scope("health"):
+            new_params, new_opt_state, stats = finalize_step(
+                self.collect_step_stats, self.guard_nonfinite_updates,
+                params, new_params, grads, loss, opt_state, new_opt_state,
+            )
         if stats is None:
             return new_params, new_opt_state, loss, metric_vals
         return new_params, new_opt_state, loss, metric_vals, stats

@@ -1,28 +1,41 @@
-"""Structured step tracing: a lightweight span/event recorder.
+"""Two kinds of instrumentation, on two clocks.
 
-The jitted train step is ONE XLA program, so the interesting host-side
-phases are dispatch (enqueue of the donated step) and device_sync (the wait
-for results). Dispatch is asynchronous, so every sync boundary here waits
-on the result pytree (`kernels/profiling.force_sync`) before the span's end
-timestamp is taken.
+**Device-trace scopes** (`node_scope`, `step_scope`, `parse_scope`): every
+operation the step programs lower carries the name of the PCG node and the
+part of the step it came from, as a `jax.named_scope` of the form
+`ff.<kind>.<name>` (a node) or `ff.<part>` (`cast`, `loss`, `optimizer`,
+`metrics`, `health`). A named scope is HLO metadata and nothing else: the
+program is the same with and without it, there is no switch, and its
+"spans" are the device events of a `jax.profiler` trace, on the device's
+own clock. JAX wraps the scope in `jvp(...)` / `transpose(...)` as it
+differentiates, which is where the phase comes from. This is what answers
+"where does the step's time go" since PR 22 put the benchmark on the device
+trace: `benchmark/step_anatomy.py` reads these scopes back with
+`parse_scope`, which lives here so that format and parser cannot drift.
+
+**Host spans** (`TraceRecorder`, `record_span`, `trace_session`): a
+span/event recorder on the host's `perf_counter` clock. The jitted train
+step is ONE XLA program, so all it can see of a step is dispatch (enqueue of
+the donated step) and device_sync (the wait for results); every sync
+boundary waits on the result pytree (`kernels/profiling.force_sync`) before
+the span's end timestamp is taken, which serializes host and device (16 ms
+of a 262 ms step; my chip run, PR 22), and it cannot look inside the step.
+It is not how step time is measured or attributed. What it is still for:
+
+- the watchdog's hang forensics: `open_span_names(tid)` says what a hung
+  thread was doing (`step / dispatch / device_sync`, `checkpoint/...`);
+- `--profile-trace-dir`'s host timeline: `trace_session` writes the spans
+  as Chrome-trace JSON (`chrome://tracing` / Perfetto "traceEvents")
+  beside the XLA trace, with the search's phases (`search/<name>`), the
+  checkpoint writes and the input pipeline's `host_to_device` transfers.
 
 Under fused multi-step dispatch (steps_per_dispatch=K) the `step` span
-covers the whole K-step window and carries a `fused_steps` arg, and the
-double-buffered input pipeline's producer thread records a
-`host_to_device` span around each window transfer — spans nest PER
-THREAD, so the transfer lands beside (not inside) the consumer's step
-spans and the prefetch overlap is directly visible on the timeline.
+covers the whole K-step window and carries a `fused_steps` arg. Spans nest
+PER THREAD, so the producer thread's transfers land beside (not inside) the
+consumer's step spans.
 
-Spans nest per thread; the recorder serializes them as Chrome-trace JSON
-(`chrome://tracing` / Perfetto "traceEvents" format) so the DP and
-searched-PCG step programs can be compared phase-by-phase on one timeline —
-this is the tool that measures the searched-executor tax directly instead of
-inferring it from whole-step ratios.
-
-A module-level active recorder keeps the instrumentation in
-`local_execution/training_backing.py` and `parallel/executor.py` zero-cost
-when tracing is off: `record_span(...)` is a no-op null context unless a
-recorder is installed (via `set_recorder` or `trace_session`).
+`record_span(...)` is a null context unless a recorder is installed (via
+`set_recorder` or `trace_session`).
 """
 
 from __future__ import annotations
@@ -30,10 +43,15 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import re
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
+
+import jax
+
+from flexflow_tpu.op_attrs.core import PARALLEL_OP_TYPES, op_type_of
 
 
 @dataclass
@@ -220,6 +238,80 @@ def record_span(name: str, sync=None, **args):
         return
     with rec.span(name, sync=sync, **args) as r:
         yield r
+
+
+# -- device-trace scopes ---------------------------------------------------
+
+# scopes of the step that belong to no node, and the phase each is booked to
+STEP_SCOPES = {
+    "cast": "other",
+    "loss": None,  # forward or backward, as JAX's transforms say
+    "optimizer": "opt",
+    "metrics": "other",
+    "health": "other",
+}
+PHASES = ("fwd", "bwd", "opt", "other", "unattributed")
+# kinds whose OperatorType value is not the name the tables use
+_KIND_NAMES = {"linear": "dense", "multihead_attention": "mha"}
+_NOT_IN_NAME = re.compile(r"[^A-Za-z0-9_.\-]")
+# the first `ff.` token of a name stack: a kind holds no dot, so the first
+# two dots split the scope; a name ends at the first `/` or `)`
+_SCOPE = re.compile(
+    r"(?<![A-Za-z0-9_.\-])ff\.([a-z0-9_]+)(?:\.([A-Za-z0-9_.\-]+))?"
+)
+
+
+def scope_kind(op_type) -> str:
+    """The `<kind>` of a node's scope: its `OperatorType` in lower case,
+    `dense` and `mha` for the two the tables abbreviate, `parallel_<op>`
+    for the four parallel ops."""
+    if op_type in PARALLEL_OP_TYPES:
+        return "parallel_" + op_type.value
+    return _KIND_NAMES.get(op_type.value, op_type.value)
+
+
+def scope_name(graph, n) -> str:
+    """`ff.<kind>.<name>` for node `n` of a computation graph or PCG:
+    `<name>` is the layer's name, or `n<idx>` where it has none, with every
+    character the name stack could not carry (it splits on `/` and wraps in
+    `jvp(...)`, `transpose(...)`) replaced by `_`."""
+    la = graph.layer_attrs(n)
+    name = la.name if la.name else f"n{n.idx}"
+    kind = scope_kind(op_type_of(la.attrs))
+    return f"ff.{kind}.{_NOT_IN_NAME.sub('_', name)}"
+
+
+def node_scope(graph, n):
+    """The `jax.named_scope` everything lowered for node `n` goes under."""
+    return jax.named_scope(scope_name(graph, n))
+
+
+def step_scope(part: str):
+    """`jax.named_scope("ff.<part>")` for a part of the step that is no
+    node: one of `STEP_SCOPES`."""
+    assert part in STEP_SCOPES, part
+    return jax.named_scope("ff." + part)
+
+
+def parse_scope(op_name: str) -> Tuple[str, str, str]:
+    """The inverse of `node_scope` / `step_scope` on an HLO `op_name`
+    (`jit(step)/transpose(jvp(ff.dense.l0))/mul`): `(phase, kind, name)`.
+    The phase is what JAX itself wrote around the scope: inside
+    `transpose(` or a rematerialized computation it is `bwd`, under
+    `ff.optimizer` `opt`, under `ff.cast` / `ff.metrics` / `ff.health`
+    `other`, under any other `ff.` scope `fwd`; with no `ff.` scope it is
+    `("unattributed", "", "")`."""
+    m = _SCOPE.search(op_name)
+    if m is None:
+        return "unattributed", "", ""
+    kind, name = m.group(1), m.group(2)
+    if name is None and STEP_SCOPES.get(kind) is not None:
+        return STEP_SCOPES[kind], kind, ""
+    backward = (
+        "transpose(" in op_name[: m.start()]
+        or "rematted_computation" in op_name
+    )
+    return ("bwd" if backward else "fwd"), kind, name or ""
 
 
 @contextlib.contextmanager

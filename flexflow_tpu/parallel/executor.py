@@ -34,6 +34,7 @@ from flexflow_tpu.kernels import (
     make_optimizer_state,
 )
 from flexflow_tpu.local_execution.training_backing import split_slot_values
+from flexflow_tpu.observability import trace
 from flexflow_tpu.op_attrs.core import is_parallel_op
 from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
 from flexflow_tpu.op_attrs.ops.loss_functions import LossAttrs
@@ -169,98 +170,104 @@ def _interpret(
         la = pcg.layer_attrs(n)
         attrs = la.attrs
         outs = pcg.outputs_of(n)
-        if isinstance(attrs, InputAttrs):
-            key = la.name if la.name is not None and la.name in inputs else param_key(n)
-            assert key in inputs, f"missing input binding for {la.name or key}"
-            env[outs[0]] = constrain(inputs[key], outs[0])
-        elif isinstance(attrs, WeightAttrs):
-            env[outs[0]] = constrain(params[param_key(n)], outs[0])
-        elif is_parallel_op(attrs):
-            (src,) = pcg.inputs_of(n)
-            env[outs[0]] = constrain(env[src], outs[0])
-        elif isinstance(attrs, RingAttentionAttrs) and mesh is not None:
-            # explicit sequence-parallel schedule via shard_map (a sharding
-            # constraint alone would make XLA all-gather K/V): ppermute ring
-            # for RingAttentionAttrs, heads-for-sequence all-to-all for the
-            # Ulysses subclass. Both compose with head parallelism
-            # (head-sharded weight) and with qkv/output biases
-            from flexflow_tpu.kernels.ulysses_attention import (
-                UlyssesAttentionAttrs,
-                ulysses_mha_forward,
-            )
-
-            in_tensors = pcg.inputs_of(n)
-            slot_vals = [env[v] for v in in_tensors]
-            data_vals, weight_vals = split_slot_values(attrs, slot_vals)
-            q_sharding = shardings.get(in_tensors[0])
-            q_spec = None if q_sharding is None else q_sharding.spec
-            w_sharding = shardings.get(in_tensors[3])
-            w_spec = None if w_sharding is None else w_sharding.spec
-            fwd = (
-                ulysses_mha_forward
-                if isinstance(attrs, UlyssesAttentionAttrs)
-                else ring_mha_forward
-            )
-            out = fwd(
-                attrs, *data_vals, weight_vals[0], mesh, q_spec,
-                w_spec=w_spec,
-                input_bias=weight_vals[1] if attrs.bias else None,
-                output_bias=weight_vals[2] if attrs.bias else None,
-            )
-            env[outs[0]] = constrain(out, outs[0])
-        else:
-            in_tensors = pcg.inputs_of(n)
-            slot_vals = [env[v] for v in in_tensors]
-            if n in barrier_nodes:
-                # barrier the DATA slots in place so both the kernel path
-                # (via split_slot_values below) and the pinned-reduction
-                # path (which consumes raw slot_vals) see the fusion split
-                from flexflow_tpu.op_attrs.core import IncomingTensorRole
-                from flexflow_tpu.local_execution.training_backing import (
-                    optimization_barrier,
-                    slot_roles,
+        # everything this node lowers to carries its name in the device
+        # trace (observability/trace.py): the kernel, the barrier, a
+        # parallel op's or a parameter's sharding constraint, and the
+        # shard_map entries (ring / all-to-all attention, sharded flash,
+        # pinned reduction, collective matmul) alike
+        with trace.node_scope(pcg, n):
+            if isinstance(attrs, InputAttrs):
+                key = la.name if la.name is not None and la.name in inputs else param_key(n)
+                assert key in inputs, f"missing input binding for {la.name or key}"
+                env[outs[0]] = constrain(inputs[key], outs[0])
+            elif isinstance(attrs, WeightAttrs):
+                env[outs[0]] = constrain(params[param_key(n)], outs[0])
+            elif is_parallel_op(attrs):
+                (src,) = pcg.inputs_of(n)
+                env[outs[0]] = constrain(env[src], outs[0])
+            elif isinstance(attrs, RingAttentionAttrs) and mesh is not None:
+                # explicit sequence-parallel schedule via shard_map (a sharding
+                # constraint alone would make XLA all-gather K/V): ppermute ring
+                # for RingAttentionAttrs, heads-for-sequence all-to-all for the
+                # Ulysses subclass. Both compose with head parallelism
+                # (head-sharded weight) and with qkv/output biases
+                from flexflow_tpu.kernels.ulysses_attention import (
+                    UlyssesAttentionAttrs,
+                    ulysses_mha_forward,
                 )
 
-                roles = slot_roles(attrs, len(slot_vals))
-                slot_vals = [
-                    optimization_barrier(v)
-                    if r == IncomingTensorRole.INPUT
-                    else v
-                    for v, r in zip(slot_vals, roles)
-                ]
-            data_vals, weight_vals = split_slot_values(attrs, slot_vals)
-            fused_kind = overlap_sites.get(n)
-            if fused_kind == "ag_matmul":
-                fused = _try_overlap_ag_matmul(
-                    pcg, n, attrs, in_tensors, shardings, mesh, env
+                in_tensors = pcg.inputs_of(n)
+                slot_vals = [env[v] for v in in_tensors]
+                data_vals, weight_vals = split_slot_values(attrs, slot_vals)
+                q_sharding = shardings.get(in_tensors[0])
+                q_spec = None if q_sharding is None else q_sharding.spec
+                w_sharding = shardings.get(in_tensors[3])
+                w_spec = None if w_sharding is None else w_sharding.spec
+                fwd = (
+                    ulysses_mha_forward
+                    if isinstance(attrs, UlyssesAttentionAttrs)
+                    else ring_mha_forward
                 )
-                if fused is not None:
-                    env[outs[0]] = fused
+                out = fwd(
+                    attrs, *data_vals, weight_vals[0], mesh, q_spec,
+                    w_spec=w_spec,
+                    input_bias=weight_vals[1] if attrs.bias else None,
+                    output_bias=weight_vals[2] if attrs.bias else None,
+                )
+                env[outs[0]] = constrain(out, outs[0])
+            else:
+                in_tensors = pcg.inputs_of(n)
+                slot_vals = [env[v] for v in in_tensors]
+                if n in barrier_nodes:
+                    # barrier the DATA slots in place so both the kernel path
+                    # (via split_slot_values below) and the pinned-reduction
+                    # path (which consumes raw slot_vals) see the fusion split
+                    from flexflow_tpu.op_attrs.core import IncomingTensorRole
+                    from flexflow_tpu.local_execution.training_backing import (
+                        optimization_barrier,
+                        slot_roles,
+                    )
+
+                    roles = slot_roles(attrs, len(slot_vals))
+                    slot_vals = [
+                        optimization_barrier(v)
+                        if r == IncomingTensorRole.INPUT
+                        else v
+                        for v, r in zip(slot_vals, roles)
+                    ]
+                data_vals, weight_vals = split_slot_values(attrs, slot_vals)
+                fused_kind = overlap_sites.get(n)
+                if fused_kind == "ag_matmul":
+                    fused = _try_overlap_ag_matmul(
+                        pcg, n, attrs, in_tensors, shardings, mesh, env
+                    )
+                    if fused is not None:
+                        env[outs[0]] = fused
+                        continue
+                sharded = _try_sharded_flash_mha(
+                    attrs, data_vals, weight_vals, in_tensors, shardings, mesh
+                )
+                if sharded is not None:
+                    env[outs[0]] = sharded
                     continue
-            sharded = _try_sharded_flash_mha(
-                attrs, data_vals, weight_vals, in_tensors, shardings, mesh
-            )
-            if sharded is not None:
-                env[outs[0]] = sharded
-                continue
-            pinned = _try_pinned_reduction(
-                pcg, n, attrs, slot_vals, in_tensors, shardings, mesh,
-                ring_overlap=(fused_kind == "matmul_rs"),
-            )
-            if pinned is not None:
-                env[outs[0]] = pinned
-                continue
-            op_rng = jax.random.fold_in(rng, n.idx) if rng is not None else None
-            results = kernel_forward(
-                attrs, data_vals, weight_vals, train=train, rng=op_rng
-            )
-            # compute ops get NO explicit constraint: the PCG's sharding
-            # intent is pinned at inputs/weights/parallel-op boundaries and
-            # XLA propagates it through the op; constraining every tensor
-            # multiplies partitioner work and blocks fusion for no
-            # additional information
-            for o, r in zip(outs, results):
-                env[o] = r
+                pinned = _try_pinned_reduction(
+                    pcg, n, attrs, slot_vals, in_tensors, shardings, mesh,
+                    ring_overlap=(fused_kind == "matmul_rs"),
+                )
+                if pinned is not None:
+                    env[outs[0]] = pinned
+                    continue
+                op_rng = jax.random.fold_in(rng, n.idx) if rng is not None else None
+                results = kernel_forward(
+                    attrs, data_vals, weight_vals, train=train, rng=op_rng
+                )
+                # compute ops get NO explicit constraint: the PCG's sharding
+                # intent is pinned at inputs/weights/parallel-op boundaries and
+                # XLA propagates it through the op; constraining every tensor
+                # multiplies partitioner work and blocks fusion for no
+                # additional information
+                for o, r in zip(outs, results):
+                    env[o] = r
     return env
 
 
@@ -829,10 +836,13 @@ class DistributedTrainingInstance:
     # -- step --------------------------------------------------------------
 
     def loss_fn(self, params, batch_inputs, label, rng=None):
+        with trace.step_scope("cast"):
+            params = self._cast_for_compute(params)
+            batch_inputs = self._cast_for_compute(batch_inputs)
         env = pcg_forward_interpreter(
             self.pcg,
-            self._cast_for_compute(params),
-            self._cast_for_compute(batch_inputs),
+            params,
+            batch_inputs,
             self.shardings,
             train=True,
             rng=rng,
@@ -841,26 +851,30 @@ class DistributedTrainingInstance:
             overlap_sites=self.overlap_sites,
         )
         logit = env[self.loss_logit_tensor]
-        loss = loss_forward(self.loss_attrs, logit, label)
-        for t in self.aux_loss_tensors:
-            loss = loss + jnp.sum(env[t].astype(loss.dtype))
+        with trace.step_scope("loss"):
+            loss = loss_forward(self.loss_attrs, logit, label)
+            for t in self.aux_loss_tensors:
+                loss = loss + jnp.sum(env[t].astype(loss.dtype))
         return loss, logit
 
     def _step(self, params, opt_state, batch_inputs, label, rng):
         (loss, logit), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
             params, batch_inputs, label, rng
         )
-        new_params, new_opt_state = apply_optimizer(
-            self.optimizer_attrs, params, grads, opt_state
-        )
-        metric_vals = compute_metrics(self.metrics, logit, label)
+        with trace.step_scope("optimizer"):
+            new_params, new_opt_state = apply_optimizer(
+                self.optimizer_attrs, params, grads, opt_state
+            )
+        with trace.step_scope("metrics"):
+            metric_vals = compute_metrics(self.metrics, logit, label)
         # same shared run-health tail as ModelTrainingInstance._step
         from flexflow_tpu.observability.metrics import finalize_step
 
-        new_params, new_opt_state, stats = finalize_step(
-            self.collect_step_stats, self.guard_nonfinite_updates,
-            params, new_params, grads, loss, opt_state, new_opt_state,
-        )
+        with trace.step_scope("health"):
+            new_params, new_opt_state, stats = finalize_step(
+                self.collect_step_stats, self.guard_nonfinite_updates,
+                params, new_params, grads, loss, opt_state, new_opt_state,
+            )
         if stats is None:
             return new_params, new_opt_state, loss, metric_vals
         return new_params, new_opt_state, loss, metric_vals, stats
