@@ -236,10 +236,6 @@ def data_parallel_plan(k: int) -> PlanFn:
         t = op_type_of(attrs)
         if t not in _DP_TYPES:
             return None
-        if t == OperatorType.MULTIHEAD_ATTENTION and getattr(
-            attrs, "bias", False
-        ):
-            return None  # data_parallel_attention_rule matches bias=False
         data_vals, weight_vals = _data_weight_values(pcg, n)
         for v in data_vals:
             sizes = _sizes(pcg, v)

@@ -101,6 +101,33 @@ def parallel_degree_summary(pcg: ParallelComputationGraph) -> Dict[str, int]:
     return out
 
 
+def serial_compute_nodes(pcg: ParallelComputationGraph) -> List[str]:
+    """Names of the compute nodes (not Input/Weight/parallel ops) whose every
+    input and output tensor has total degree 1. A template or rule that
+    cannot wrap an op leaves it like this without saying so (build_wrapped
+    falls back to the serial op); on a machine with more than one device
+    each such node runs whole on every device."""
+    from flexflow_tpu.op_attrs.core import is_parallel_op, op_type_of
+    from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
+    from flexflow_tpu.op_attrs.parallel_tensor_shape import (
+        total_parallel_degree,
+    )
+
+    names = []
+    for n in pcg.topological_ordering():
+        at = pcg.op_attrs(n)
+        if isinstance(at, (InputAttrs, WeightAttrs)) or is_parallel_op(at):
+            continue
+        if all(
+            total_parallel_degree(pcg.tensor_shape(v)) == 1
+            for v in (*pcg.inputs_of(n), *pcg.outputs_of(n))
+        ):
+            names.append(
+                pcg.layer_attrs(n).name or f"{op_type_of(at).value}_{n.idx}"
+            )
+    return names
+
+
 def _rule_slot_wrappers(sub: Substitution):
     """The parallel-op attrs the rule's RHS inserts on each input slot of the
     rewritten op (None for slots fed directly by a graph input). Used to
@@ -170,10 +197,12 @@ def _already_applied_at(
 class OptimizerConfig:
     """reference: unity_algorithm.h OptimizerConfig{alpha, budget, threshold,
     max_num_ops} + config.h:82-84 flag defaults. threshold > 0 additionally
-    drops candidates whose absolute runtime exceeds it. seed_frontier pushes
-    the dp/tp/sp strategy-template rewrites into the frontier as first-class
-    candidates (the best-first walk then spends its budget improving on
-    them instead of climbing the whole rule lattice from serial)."""
+    drops candidates whose absolute runtime exceeds it. max_num_ops caps the
+    candidates the walk's rewrites produce, not the templates. seed_frontier
+    pushes the dp/tp/sp strategy-template rewrites into the frontier as
+    first-class candidates (the best-first walk then spends its budget
+    improving on them instead of climbing the whole rule lattice from
+    serial)."""
 
     alpha: float = 1.2
     budget: int = 10
@@ -894,8 +923,12 @@ def _graph_optimize(
                     )
                 )
         for label, seed_pcg in seed_candidates:
-            if len(seed_pcg) > config.max_num_ops:
-                continue
+            # no max_num_ops test here: that cap bounds what the walk's
+            # rewrites may grow a candidate to, and a template is one
+            # bounded rewrite of the input. Every template of a 24-block
+            # encoder (576-927 nodes) is over it; dropped unpriced, they
+            # leave the result at what `budget` single rewrites reach from
+            # the serial graph instead of flooring it at the templates.
             key = _canonical_key(seed_pcg)
             if key in seen:
                 key_hits += 1

@@ -1849,6 +1849,7 @@ class FFModel:
 
                 from flexflow_tpu.compiler.unity_algorithm import (
                     parallel_degree_summary,
+                    serial_compute_nodes,
                 )
 
                 t0 = _time.perf_counter()
@@ -1892,6 +1893,10 @@ class FFModel:
                     "search_seconds": _time.perf_counter() - t0,
                     "seed_runtimes": dict(result.seed_runtimes or {}),
                     "parallel_degrees": parallel_degree_summary(result.pcg),
+                    # ops the winner leaves whole on every device (this
+                    # path has ndev > 1): a template or rule that could
+                    # not wrap an op says so nowhere else
+                    "serial_compute_nodes": serial_compute_nodes(result.pcg),
                     "cost_model": cfg.cost_model,
                     # how the plan was found (observability: evaluation/
                     # dedup counters + the active dedup flags, so A/B

@@ -377,18 +377,9 @@ def get_parallel_weight_shapes(
         q, k, v = inputs
         ws = [attrs.parallel_weights_shape(q, k, v)]
         if attrs.bias:
-            from flexflow_tpu.op_attrs.parallel_tensor_shape import (
-                lift_to_parallel,
-                get_reduced_shape,
-            )
-
             ws += [
-                lift_to_parallel(
-                    attrs.input_bias_shape(*map(get_reduced_shape, inputs))
-                ),
-                lift_to_parallel(
-                    attrs.output_bias_shape(*map(get_reduced_shape, inputs))
-                ),
+                attrs.parallel_input_bias_shape(q, k, v),
+                attrs.parallel_output_bias_shape(q, k, v),
             ]
         return ws
     if isinstance(attrs, Conv2DAttrs):

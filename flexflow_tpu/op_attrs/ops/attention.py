@@ -118,3 +118,34 @@ class MultiHeadAttentionAttrs:
         return lift_to_parallel_with_degrees(
             unpar, 1, batch_degree, (1, head_degree)
         )
+
+    def _parallel_bias_shape(
+        self,
+        unpar_shape,
+        q: ParallelTensorShape,
+        k: ParallelTensorShape,
+        v: ParallelTensorShape,
+    ) -> ParallelTensorShape:
+        """A bias is replicated over the batch shards like the weight
+        (discard_copy_degree = batch degree, degree 1 keeps the serial
+        shape)."""
+        batch_degree, head_degree = self._parse_parallel(q, k, v)
+        assert head_degree == 1, (
+            "biased attention cannot be head-parallel: each head shard's "
+            "output is a partial sum, so the output bias must be added once "
+            "after the Reduction"
+        )
+        unpar = unpar_shape(
+            get_reduced_shape(q), get_reduced_shape(k), get_reduced_shape(v)
+        )
+        return lift_to_parallel_with_degrees(unpar, 1, batch_degree, (1,))
+
+    def parallel_input_bias_shape(
+        self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
+    ) -> ParallelTensorShape:
+        return self._parallel_bias_shape(self.input_bias_shape, q, k, v)
+
+    def parallel_output_bias_shape(
+        self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
+    ) -> ParallelTensorShape:
+        return self._parallel_bias_shape(self.output_bias_shape, q, k, v)

@@ -81,3 +81,12 @@ class RingAttentionAttrs(MultiHeadAttentionAttrs):
         return lift_to_parallel_with_degrees(
             unpar, 1, batch_degree * seq_degree, (1, head_degree)
         )
+
+    def _parse_parallel(
+        self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
+    ):
+        # (batch, head) for the inherited bias shapes, which follow the
+        # batch degree alone: no template shards a biased attention over the
+        # sequence yet (sequence_parallel_plan)
+        batch_degree, _, head_degree = self._parse_parallel_ring(q, k, v)
+        return batch_degree, head_degree

@@ -622,36 +622,42 @@ def branch_reduce_sum_rule(degree: int) -> Substitution:
     )
 
 
-def data_parallel_attention_rule(degree: int) -> Substitution:
-    """MHA(q,k,v,w) -> Combine_0(MHA(Repartition_0(q,k,v), Replicate(w))):
-    sample parallelism for attention (reference attention.cc sample-dim
-    rule). Without this the transformer's searched DP plan left every MHA
-    serial, forcing a full reshard at each attention boundary."""
+def data_parallel_attention_rule(degree: int, bias: bool = False) -> Substitution:
+    """MHA(q,k,v,w[,bi,bo]) -> Combine_0(MHA(Repartition_0(q,k,v),
+    Replicate(w)[, Replicate(bi), Replicate(bo)])): sample parallelism for
+    attention (reference attention.cc sample-dim rule). Without this the
+    transformer's searched DP plan left every MHA serial, forcing a full
+    reshard at each attention boundary. `bias=True` matches the biased op
+    (input and output bias as two more weights, replicated like w)."""
     p = PCGPattern()
     q = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     k = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     v = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
-    w = p.add_input()
+    weights = [p.add_input() for _ in range(3 if bias else 1)]
     pnode, (py,) = p.add_operator(
         OperatorAttributePattern.for_op_type(
-            OperatorType.MULTIHEAD_ATTENTION, bias=False
+            OperatorType.MULTIHEAD_ATTENTION, bias=bias
         ),
-        [q, k, v, w],
+        [q, k, v, *weights],
     )
     og = OutputGraphExpr()
-    oq, ok, ov, ow = (og.add_input() for _ in range(4))
+    oq, ok, ov = (og.add_input() for _ in range(3))
+    o_weights = [og.add_input() for _ in weights]
     parts = []
     for oi in (oq, ok, ov):
         _, (xp,) = og.add_operator(AttrConstant(RepartitionAttrs(0, degree)), [oi])
         parts.append(xp)
-    _, (wr,) = og.add_operator(AttrConstant(ReplicateAttrs(degree)), [ow])
-    _, (y,) = og.add_operator(CopyAttrsFromMatched(pnode), [*parts, wr])
+    reps = []
+    for ow in o_weights:
+        _, (wr,) = og.add_operator(AttrConstant(ReplicateAttrs(degree)), [ow])
+        reps.append(wr)
+    _, (y,) = og.add_operator(CopyAttrsFromMatched(pnode), [*parts, *reps])
     _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [y])
     return Substitution(
-        f"data_parallel_attention_{degree}",
+        f"data_parallel_attention_{'b_' if bias else ''}{degree}",
         p,
         og,
-        ((q, oq), (k, ok), (v, ov), (w, ow)),
+        ((q, oq), (k, ok), (v, ov), *zip(weights, o_weights)),
         ((py, out),),
     )
 
@@ -926,7 +932,8 @@ def generate_parallelization_rules(
             rules.append(data_parallel_conv2d_rule(k, use_bias))
         rules.append(data_parallel_embedding_rule(k))
         rules.append(data_parallel_batch_norm_rule(k))
-        rules.append(data_parallel_attention_rule(k))
+        for bias in (False, True):
+            rules.append(data_parallel_attention_rule(k, bias))
         rules.append(data_parallel_layer_norm_rule(k))
         rules.append(sequence_parallel_attention_rule(k))
         rules.append(sequence_parallel_attention_a2a_rule(k))

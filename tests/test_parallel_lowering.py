@@ -329,3 +329,86 @@ def test_weight_repartition_chain_rests_fully_sharded():
     assert final is not None
     for cv in chain_vals:
         assert sh[cv] is final, (cv, sh[cv])
+
+
+def test_biased_attention_data_parallel_step_on_four_devices(monkeypatch):
+    """The batch-parallel template carries [b/4, s, e] into BERT's biased
+    attention, so the executor's flash path shards it over the batch: the
+    compiled step gathers nothing and all-reduces each gradient once (and
+    the loss), never an activation; one step equals a single device's."""
+    import re
+
+    from flexflow_tpu.analysis.lowering import lower_step_trace
+    from flexflow_tpu.core import FFConfig, FFModel, SGDOptimizer
+    from flexflow_tpu.local_execution.training_backing import (
+        ModelTrainingInstance,
+    )
+    from flexflow_tpu.op_attrs.ops import WeightAttrs
+
+    from test_seed_templates import bert_like_graph
+
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_MIN_SEQ", "128")
+
+    def compiled(**config):
+        graph, logits = bert_like_graph(batch=8, seq=128)
+        model = FFModel.from_computation_graph(
+            graph, logits,
+            FFConfig(batch_size=8, seed=0, print_freq=0, **config),
+        )
+        model.compile(SGDOptimizer(lr=0.1), "sparse_categorical_crossentropy")
+        graph = getattr(model.instance, "pcg", None) or model.instance.cg
+        keys = {
+            graph.layer_attrs(n).name: f"n{n.idx}"
+            for n in graph.topological_ordering()
+            if isinstance(graph.op_attrs(n), WeightAttrs)
+        }
+        return model, keys
+
+    four, keys4 = compiled(
+        max_devices=4, search_budget=2, force_strategy_seed="dp4xtp1xsp1"
+    )
+    assert isinstance(four.instance, DistributedTrainingInstance)
+    assert four.search_provenance["serial_compute_nodes"] == []
+
+    text = lower_step_trace(
+        four.instance, four.loss_attrs,
+        params=four.params, opt_state=four.opt_state,
+    ).compile().as_text()
+    assert "flash_fwd" in text  # the sharded kernel path, not dense XLA
+    collectives = [
+        line for line in text.split("\n")
+        if re.search(
+            r" (all-reduce|all-gather|reduce-scatter|all-to-all|"
+            r"collective-permute)(-start)?\(", line,
+        )
+    ]
+    assert collectives and all(" all-reduce(" in c for c in collectives)
+    assert not any("/psum" in c for c in collectives)
+    reduced = sum(
+        int(np.prod([int(d) for d in dims.split(",") if d]))
+        for c in collectives
+        for dims in re.findall(r"\w+\[([0-9,]*)\]", c.split(" all-reduce(")[0])
+    )
+    n_params = sum(int(np.prod(v.shape)) for v in four.params.values())
+    assert reduced == n_params + 1  # every gradient once, and the loss
+
+    one, keys1 = compiled(max_devices=1)
+    assert isinstance(one.instance, ModelTrainingInstance)
+    assert one.search_provenance is None
+    assert set(keys1) == set(keys4)
+    one.params = {
+        keys1[name]: jnp.asarray(np.asarray(four.params[keys4[name]]))
+        for name in keys1
+    }
+    rs = np.random.RandomState(0)
+    x = rs.randn(8, 128, 32).astype(np.float32)
+    y = rs.randint(0, 8, (8, 128)).astype(np.int32)
+    four.fit(x, y, epochs=1, verbose=False)
+    one.fit(x, y, epochs=1, verbose=False)
+    for name in keys1:
+        np.testing.assert_allclose(
+            np.asarray(four.params[keys4[name]]),
+            np.asarray(one.params[keys1[name]]),
+            atol=1e-5, rtol=0, err_msg=name,
+        )

@@ -31,6 +31,7 @@ from flexflow_tpu.substitutions import (
     tensor_parallel_linear_rule,
     combine_reduction_cancel_rules,
 )
+from flexflow_tpu.substitutions.rules import data_parallel_attention_rule
 
 
 def pts(dims, sum_degree=1, discard=1):
@@ -177,6 +178,51 @@ class TestApplySubstitution:
         ops = [op_type_of(new_pcg.op_attrs(n)) for n in new_pcg.topological_ordering()]
         assert ops.count(OperatorType.REPLICATE) == 3
         assert OperatorType.REDUCTION in ops
+
+    @pytest.mark.parametrize("bias", [False, True])
+    def test_data_parallel_attention(self, bias):
+        """Each variant matches its own op only, and the biased one
+        replicates the two biases beside the weight."""
+        b = ComputationGraphBuilder()
+        x = b.create_input([8, 16, 32], name="x")
+        b.multihead_attention(x, x, x, 32, 4, bias=bias, name="attn")
+        pcg = pcg_from_computation_graph(b.graph)
+        other = data_parallel_attention_rule(4, bias=not bias)
+        assert find_pattern_matches(other.pattern, pcg) == []
+        rule = data_parallel_attention_rule(4, bias=bias)
+        assert rule.name == (
+            "data_parallel_attention_b_4" if bias else "data_parallel_attention_4"
+        )
+        (m,) = find_pattern_matches(rule.pattern, pcg)
+        assert is_valid_match_for_substitution(pcg, rule, m)
+        new_pcg = apply_substitution(pcg, rule, m)
+        (mha,) = [
+            n for n in new_pcg.topological_ordering()
+            if op_type_of(new_pcg.op_attrs(n)) == OperatorType.MULTIHEAD_ATTENTION
+        ]
+        ins = new_pcg.inputs_of(mha)
+        assert len(ins) == (6 if bias else 4)
+        for v in ins[:3]:
+            assert new_pcg.tensor_shape(v).shard_degrees() == (4, 1, 1)
+        for v in ins[3:]:
+            assert op_type_of(new_pcg.op_attrs(v.node)) == OperatorType.REPLICATE
+            assert new_pcg.tensor_shape(v).discard_copy_degree == 4
+        (out,) = new_pcg.outputs_of(mha)
+        assert new_pcg.tensor_shape(out).shard_degrees() == (4, 1, 1)
+
+    def test_biased_attention_has_no_head_parallel_move(self):
+        b = ComputationGraphBuilder()
+        x = b.create_input([8, 16, 32], name="x")
+        b.multihead_attention(x, x, x, 32, 4, bias=True, name="attn")
+        pcg = pcg_from_computation_graph(b.graph)
+        rule = head_parallel_attention_rule(2)
+        assert find_pattern_matches(rule.pattern, pcg) == []
+
+    def test_generated_rule_set_has_both_attention_variants(self):
+        names = [r.name for r in generate_parallelization_rules([2, 4])]
+        for k in (2, 4):
+            assert names.count(f"data_parallel_attention_{k}") == 1
+            assert names.count(f"data_parallel_attention_b_{k}") == 1
 
     def test_generated_rule_set_nonempty_and_applicable(self):
         pcg = mlp_pcg()
