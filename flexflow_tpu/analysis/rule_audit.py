@@ -59,6 +59,7 @@ from flexflow_tpu.op_attrs.ops import (
     EmbeddingAttrs,
     InputAttrs,
     LayerNormAttrs,
+    RMSNormAttrs,
     LinearAttrs,
     MultiHeadAttentionAttrs,
     NoopAttrs,
@@ -109,6 +110,8 @@ RULE_AUDIT_CATALOG: Dict[str, str] = {
 }
 
 _AUDIT_SINK_PREFIX = "__audit_out__"
+# stands for "any value but None" among a pattern's equalities
+_SET = object()
 
 
 # ---------------------------------------------------------------------------
@@ -129,7 +132,13 @@ def _pattern_fields(op_pattern: OperatorAttributePattern):
             eq[c.field_name] = c.value
         elif c.constraint_type == ConstraintType.DIVISIBLE_BY:
             div[c.field_name] = math.lcm(div.get(c.field_name, 1), c.value)
-        # NOT_EQUAL / NOT_CONTAINS are validated against the defaults later
+        elif (
+            c.constraint_type == ConstraintType.NOT_EQUAL and c.value is None
+        ):
+            # "this optional field is set": the defaults pick a value for
+            # it. Other NOT_EQUAL / NOT_CONTAINS constraints are validated
+            # against the defaults later
+            eq.setdefault(c.field_name, _SET)
     return op_type, eq, div
 
 
@@ -245,13 +254,25 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
             num_entries=64,
             out_channels=up(size, div.get("out_channels", 1)),
         )
-    if op_type == OperatorType.MULTIHEAD_ATTENTION:
+    if op_type in (
+        OperatorType.MULTIHEAD_ATTENTION, OperatorType.RING_ATTENTION
+    ):
+        from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
+
         heads = up(size, div.get("num_heads", 1))
-        return MultiHeadAttentionAttrs(
+        cls = (
+            RingAttentionAttrs
+            if op_type == OperatorType.RING_ATTENTION
+            else MultiHeadAttentionAttrs
+        )
+        return cls(
             embed_dim=heads * 4,
             num_heads=heads,
             bias=eq.get("bias", False),
+            qk_norm_eps=1e-5 if eq.get("qk_norm_eps") is _SET else None,
         )
+    if op_type == OperatorType.RMS_NORM:
+        return RMSNormAttrs()
     if op_type == OperatorType.BATCH_NORM:
         return BatchNormAttrs(affine=eq.get("affine", True))
     if op_type == OperatorType.LAYER_NORM:
@@ -296,6 +317,7 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
             out_channels=size,
             use_bias=eq.get("use_bias", False),
             lambda_bal=lambda_bal,
+            gated=eq.get("gated", False),
         )
     if op_type == OperatorType.REPARTITION:
         return RepartitionAttrs(
@@ -323,6 +345,8 @@ def _data_shape_table(op_type: OperatorType, size: int, arity: int):
         OperatorType.CONV2D: ((S, S, 8, 8),),
         OperatorType.EMBEDDING: ((S, S),),
         OperatorType.MULTIHEAD_ATTENTION: ((8, S, S), (8, S, S), (8, S, S)),
+        OperatorType.RING_ATTENTION: ((8, S, S), (8, S, S), (8, S, S)),
+        OperatorType.RMS_NORM: ((S, S, S),),
         OperatorType.BATCH_NORM: ((S, S, 8, 8),),
         OperatorType.LAYER_NORM: ((S, S, S),),
         OperatorType.SOFTMAX: ((S, S),),
@@ -450,6 +474,7 @@ def _synthesize_host(
         # equality): unify to the elementwise lcm across slots
         if op_type in (
             OperatorType.MULTIHEAD_ATTENTION,
+            OperatorType.RING_ATTENTION,
             OperatorType.ELEMENT_BINARY,
         ):
             ranks = {len(d) for d in slot_dims.values()}

@@ -756,7 +756,7 @@ class AnalyticTPUCostEstimator(CostEstimator):
             machine_spec, ici_latency_ms, dcn_latency_ms)
 
     def estimate_op_cost(self, key: OpCostEstimateKey) -> float:
-        from flexflow_tpu.kernels.ops import op_forward_flops
+        from flexflow_tpu.kernels.ops import op_forward_flops, op_internal_bytes
         from flexflow_tpu.op_attrs.core import (
             get_output_shapes,
             get_weight_shapes,
@@ -835,6 +835,9 @@ class AnalyticTPUCostEstimator(CostEstimator):
             sum(s.size_bytes for s in piece_inputs)
             + sum(s.size_bytes for s in weight_shapes)
             + sum(s.size_bytes for s in (piece_outs or out_shapes))
+            + op_internal_bytes(
+                key.op_attrs, piece_inputs, piece_weights or None
+            )
         )
         # fwd + bwd ~= 3x fwd flops; grads roughly double the traffic.
         # Forward-only (serving): the deployed program IS the forward pass

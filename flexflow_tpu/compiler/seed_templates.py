@@ -210,6 +210,7 @@ _DP_TYPES = frozenset(
         OperatorType.EMBEDDING,
         OperatorType.BATCH_NORM,
         OperatorType.LAYER_NORM,
+        OperatorType.RMS_NORM,
         OperatorType.ELEMENT_UNARY,
         OperatorType.ELEMENT_BINARY,
         OperatorType.SOFTMAX,
@@ -218,6 +219,10 @@ _DP_TYPES = frozenset(
         OperatorType.DROPOUT,
         OperatorType.CONCAT,
         OperatorType.MULTIHEAD_ATTENTION,
+        # the program's causal attention, its sequence dim left whole
+        OperatorType.RING_ATTENTION,
+        # each batch shard routes its own tokens (data_parallel_experts_rule)
+        OperatorType.EXPERTS,
     }
 )
 
@@ -275,7 +280,12 @@ def megatron_plan(pcg: ParallelComputationGraph, k: int) -> PlanFn:
             ):
                 decision[n] = "row"
         elif t == OperatorType.MULTIHEAD_ATTENTION:
-            if not getattr(attrs, "bias", False) and attrs.num_heads % k == 0:
+            # QK-norm's statistic spans every head (attention.py)
+            if (
+                not getattr(attrs, "bias", False)
+                and not attrs.qk_norm
+                and attrs.num_heads % k == 0
+            ):
                 decision[n] = "head"
         elif t == OperatorType.EMBEDDING:
             if attrs.out_channels % k == 0:
@@ -364,6 +374,8 @@ def sequence_parallel_plan(k: int, flavor: str = "ring") -> PlanFn:
         if t == OperatorType.MULTIHEAD_ATTENTION:
             if getattr(attrs, "bias", False):
                 return None
+            if attrs.qk_norm or attrs.rope_theta is not None:
+                return None  # RingAttentionAttrs' shape rule (ROADMAP R7)
             if flavor == "a2a" and attrs.num_heads % k:
                 return None
             if any(
@@ -388,6 +400,7 @@ def sequence_parallel_plan(k: int, flavor: str = "ring") -> PlanFn:
         if t not in (
             OperatorType.LINEAR,
             OperatorType.LAYER_NORM,
+            OperatorType.RMS_NORM,
             OperatorType.ELEMENT_UNARY,
             OperatorType.ELEMENT_BINARY,
             OperatorType.DROPOUT,

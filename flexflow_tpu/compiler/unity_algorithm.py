@@ -635,13 +635,14 @@ def expert_parallel_seed(
 ) -> ParallelComputationGraph:
     """Expert-parallel template: every Experts op sharded over its expert
     dim (each device owns num_experts/degree experts and contributes a
-    partial sum), both the plain and aux-loss (lambda_bal>0) forms."""
+    partial sum), every form of the op (legacy with and without biases,
+    gated; plain and with the auxiliary scalar)."""
     from flexflow_tpu.substitutions.rules import expert_parallel_experts_rule
 
     k = degree
     rules = [
-        expert_parallel_experts_rule(k, ub, with_aux=wa)
-        for ub in (True, False)
+        expert_parallel_experts_rule(k, ub, with_aux=wa, gated=g)
+        for ub, g in ((True, False), (False, False), (False, True))
         for wa in (False, True)
     ]
     cur = greedy_apply(pcg, rules, degree_cap=degree_cap)

@@ -243,12 +243,14 @@ class FFModel:
         self, query, key, value, embed_dim, num_heads,
         kdim=0, vdim=0, dropout=0.0, bias=False,
         add_bias_kv=False, add_zero_attn=False, initializer=None, name=None,
+        causal=False, rope_theta=None, qk_norm_eps=None,
     ) -> Tensor:
         return self._wrap(self._builder.multihead_attention(
             self._unwrap(query), self._unwrap(key), self._unwrap(value),
             embed_dim, num_heads, kdim=kdim, vdim=vdim, dropout=dropout,
             bias=bias, add_bias_kv=add_bias_kv, add_zero_attn=add_zero_attn,
-            initializer=initializer, name=name,
+            initializer=initializer, name=name, causal=causal,
+            rope_theta=rope_theta, qk_norm_eps=qk_norm_eps,
         ))
 
     def conv2d(
@@ -290,6 +292,11 @@ class FFModel:
             self._unwrap(input), axes=list(axes),
             elementwise_affine=elementwise_affine, eps=eps, name=name,
         ))
+
+    def rms_norm(self, input, eps=1e-5, name=None) -> Tensor:
+        return self._wrap(
+            self._builder.rms_norm(self._unwrap(input), eps=eps, name=name)
+        )
 
     def flat(self, input, name=None) -> Tensor:
         return self._wrap(self._builder.flat(self._unwrap(input), name=name))
@@ -404,6 +411,9 @@ class FFModel:
     def gelu(self, x, name=None):
         return self._wrap(self._builder.gelu(self._unwrap(x), name=name))
 
+    def silu(self, x, name=None):
+        return self._wrap(self._builder.silu(self._unwrap(x), name=name))
+
     def elu(self, x, name=None):
         return self._wrap(self._builder.elu(self._unwrap(x), name=name))
 
@@ -467,6 +477,22 @@ class FFModel:
             name=name,
         )
         if len(outs) > 1:  # load-balance aux loss joins the training loss
+            self._aux_loss_tensors.append(outs[1])
+        return self._wrap(outs[0])
+
+    def experts(
+        self, input, num_experts: int, num_select: int, hidden_size: int,
+        name=None, **attrs,
+    ) -> Tensor:
+        """The fused MoE FFN with every `ExpertsAttrs` setting
+        (`ComputationGraphBuilder.experts`): `gated`, `capacity_factor=None`
+        for dropless, `renormalize`, `lambda_bal`, `lambda_z`, ... The
+        auxiliary scalar, where there is one, joins the training loss."""
+        outs = self._builder.experts(
+            self._unwrap(input), num_experts, num_select, hidden_size,
+            name=name, **attrs,
+        )
+        if len(outs) > 1:
             self._aux_loss_tensors.append(outs[1])
         return self._wrap(outs[0])
 
@@ -623,6 +649,12 @@ class FFModel:
         # substitutions, so such graphs keep the DP backend rather than
         # silently training a different objective.
         structural_aux = set(_find_aux_outputs(self.cg))
+        # an Experts op with an auxiliary coefficient contributes its scalar
+        # however the graph reached this model (a bare graph handed to
+        # from_computation_graph carries no builder's list)
+        for t in _find_aux_outputs(self.cg):
+            if t not in self._aux_loss_tensors:
+                self._aux_loss_tensors.append(t)
         custom_aux = [
             t for t in self._aux_loss_tensors if t not in structural_aux
         ]
@@ -3393,13 +3425,13 @@ class FFModel:
 def _find_aux_outputs(graph) -> List[DataflowOutput]:
     """Aux-loss outputs, found structurally (so they survive substitutions
     that rebuild node identity): any secondary output of an Experts op with
-    lambda_bal > 0 is its load-balance scalar."""
+    an auxiliary coefficient (lambda_bal, lambda_z) is that scalar."""
     from flexflow_tpu.op_attrs.ops import ExpertsAttrs
 
     aux = []
     for n in graph.topological_ordering():
         attrs = graph.op_attrs(n)
-        if isinstance(attrs, ExpertsAttrs) and attrs.lambda_bal > 0:
+        if isinstance(attrs, ExpertsAttrs) and attrs.has_aux:
             aux.extend(graph.outputs_of(n)[1:])
     return aux
 

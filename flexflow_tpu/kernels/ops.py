@@ -42,6 +42,7 @@ from flexflow_tpu.op_attrs.ops import (
     InputAttrs,
     LayerNormAttrs,
     LinearAttrs,
+    RMSNormAttrs,
     MultiHeadAttentionAttrs,
     NoopAttrs,
     Pool2DAttrs,
@@ -81,6 +82,7 @@ _UNARY_FNS = {
     ElementUnaryOpType.SIGMOID: jax.nn.sigmoid,
     ElementUnaryOpType.TANH: jnp.tanh,
     ElementUnaryOpType.GELU: jax.nn.gelu,
+    ElementUnaryOpType.SILU: jax.nn.silu,
     ElementUnaryOpType.ELU: jax.nn.elu,
     ElementUnaryOpType.RSQRT: lax.rsqrt,
     ElementUnaryOpType.SQRT: jnp.sqrt,
@@ -206,12 +208,62 @@ def mha_project_qkv_bshf_fused(
     return qkv, wo2
 
 
+def rms_norm(x, gain, eps):
+    """x * rsqrt(mean(x^2, last) + eps) * gain, the mean of squares in
+    float32; the result in x's dtype."""
+    x32 = x.astype(jnp.float32)
+    scale = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    return (x32 * scale * gain.astype(jnp.float32)).astype(x.dtype)
+
+
+def rope_bshf(x, num_heads: int, theta: float):
+    """Rotary position embedding on x [b, s, h*d], positions 0..s-1, each
+    d-lane head block rotated by itself with the rotate-half pairing
+    (i, i + d/2) and angle pos * theta^(-2j/d). Written on the fused row so
+    that the projections' layout is kept: within a block, rotate_half(x) is
+    x rolled down by d/2 lanes in the lower half and up in the upper half,
+    and a roll of the whole row agrees with the roll of a block wherever
+    that half reads from its own block."""
+    b, s, f = x.shape
+    d = f // num_heads
+    half = d // 2
+    assert d % 2 == 0, f"rotary embedding needs an even head size, got {d}"
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos = jnp.tile(jnp.cos(angle), (1, 2 * num_heads))  # [s, f]
+    sin = jnp.sin(angle)
+    sin = jnp.tile(jnp.concatenate([-sin, sin], axis=-1), (1, num_heads))
+    lower = (jnp.arange(f) % d) < half
+    x32 = x.astype(jnp.float32)
+    rotated = jnp.where(
+        lower, jnp.roll(x32, -half, axis=-1), jnp.roll(x32, half, axis=-1)
+    )
+    return (x32 * cos + rotated * sin).astype(x.dtype)
+
+
+def mha_qk_norm_rope(attrs: MultiHeadAttentionAttrs, qp, kp, qk_gains):
+    """What the attrs ask for between projection and attention core, on the
+    fused [b, s, h*d] projections: QK-norm over the whole row, then RoPE on
+    each head block."""
+    if attrs.qk_norm:
+        qp = rms_norm(qp, qk_gains[0], attrs.qk_norm_eps)
+        kp = rms_norm(kp, qk_gains[1], attrs.qk_norm_eps)
+    if attrs.rope_theta is not None:
+        qp = rope_bshf(qp, attrs.num_heads, attrs.rope_theta)
+        kp = rope_bshf(kp, attrs.num_heads, attrs.rope_theta)
+    return qp, kp
+
+
 def _mha_forward(
-    attrs: MultiHeadAttentionAttrs, q, k, v, weight, input_bias=None, causal=False
+    attrs: MultiHeadAttentionAttrs, q, k, v, weight, input_bias=None,
+    causal=False, qk_gains=None,
 ):
     import os
 
     kd = attrs.q_proj_size
+    # QK-norm and RoPE act on the fused-row projections; a node without
+    # them takes the paths it always took
+    post = attrs.qk_norm or attrs.rope_theta is not None
     use_flash = os.environ.get("FLEXFLOW_TPU_FLASH", "1") != "0"
     if use_flash:
         from flexflow_tpu.kernels.flash_attention import (
@@ -249,7 +301,7 @@ def _mha_forward(
                 and bshf_ok
                 and flash_attention_supported(proj_q, proj_kv, proj_kv)
             ):
-                if kd % 128 != 0 and q is k and k is v:
+                if kd % 128 != 0 and q is k and k is v and not post:
                     # self-attention on the head-pair path: ONE fused
                     # projection matmul into the interleaved
                     # [q_pair|k_pair|v_pair] layout; flash reads the three
@@ -268,10 +320,27 @@ def _mha_forward(
                 qp, kp, vp, wo2 = mha_project_qkv_bshf(
                     attrs, q, k, v, weight, input_bias
                 )
+                if post:
+                    qp, kp = mha_qk_norm_rope(attrs, qp, kp, qk_gains)
                 ctx = flash_attention_bshf(qp, kp, vp, H, causal=causal)
                 return ctx @ wo2
 
-    qp, kp, vp, wo = mha_project_qkv(attrs, q, k, v, weight, input_bias)
+    if post:
+        # the same fused-row projections, then split into heads for the
+        # [b, h, s, d] paths below
+        qp, kp, vp, wo2 = mha_project_qkv_bshf(
+            attrs, q, k, v, weight, input_bias
+        )
+        qp, kp = mha_qk_norm_rope(attrs, qp, kp, qk_gains)
+        H, vd = attrs.num_heads, attrs.v_proj_size
+
+        def heads(x, d):
+            return jnp.swapaxes(x.reshape(*x.shape[:2], H, d), 1, 2)
+
+        qp, kp, vp = heads(qp, kd), heads(kp, kd), heads(vp, vd)
+        wo = jnp.transpose(wo2.reshape(H, vd, attrs.embed_dim), (1, 2, 0))
+    else:
+        qp, kp, vp, wo = mha_project_qkv(attrs, q, k, v, weight, input_bias)
     if use_flash:
         mesh_ctx = current_flash_mesh()
         if mesh_ctx is not None:
@@ -433,6 +502,9 @@ def forward(
             out = out * gamma.reshape(bshape) + beta.reshape(bshape)
         return [out]
 
+    if isinstance(attrs, RMSNormAttrs):
+        return [rms_norm(inputs[0], weights[0], attrs.eps)]
+
     if isinstance(attrs, SoftmaxAttrs):
         return [jax.nn.softmax(inputs[0], axis=attrs.dim)]
 
@@ -455,7 +527,10 @@ def forward(
         q, k, v = inputs
         input_bias = weights[1] if attrs.bias else None
         causal = isinstance(attrs, RingAttentionAttrs) and attrs.causal
-        out = _mha_forward(attrs, q, k, v, weights[0], input_bias, causal=causal)
+        out = _mha_forward(
+            attrs, q, k, v, weights[0], input_bias, causal=causal,
+            qk_gains=weights[-2:] if attrs.qk_norm else None,
+        )
         if attrs.bias:
             out = out + weights[2]
         return [out]
@@ -548,6 +623,51 @@ def forward(
     raise TypeError(f"no kernel for {type(attrs).__name__}")
 
 
+def _experts_rows(attrs, tokens: int, weight_shapes=None) -> int:
+    """Rows the Experts op's grouped matmuls run: the active experts only,
+    N*k dispatched decisions (fewer where a finite capacity drops some), not
+    E x capacity buffers. With `weight_shapes` (the cost model's per-device
+    pieces: the gate table, then [e_local, ...] expert tensors) the piece
+    owns e_local / e of the rows, and each of its experts pads its rows to
+    whole 128-row MXU tiles, half a tile on average: at a few rows an expert
+    that is what the grouped matmul costs, and what expert parallelism
+    divides."""
+    from flexflow_tpu.op_attrs.ops.moe import expert_capacity
+
+    e = attrs.num_experts
+    rows = tokens * attrs.num_select
+    if attrs.capacity_factor is not None:
+        cap = expert_capacity(
+            tokens, e, attrs.num_select, attrs.capacity_factor
+        )
+        rows = min(rows, e * cap)
+    if weight_shapes and len(weight_shapes) > 1:
+        e_local = weight_shapes[1].dims[0]
+        rows = rows * e_local // e + 64 * e_local
+    return rows
+
+
+def op_internal_bytes(attrs: OpAttrs, input_shapes, weight_shapes=None) -> int:
+    """Bytes an op writes and reads again INSIDE its node, beyond its
+    inputs, weights and outputs (which the cost model counts itself). Only
+    the Experts op has any worth counting: its dispatched rows, gathered
+    [rows, D], the hidden [rows, H] (twice in the gated form) and the
+    experts' output [rows, out], each written once and read once. At the
+    published sizes this is more traffic than the expert weights."""
+    from flexflow_tpu.op_attrs.ops.moe import ExpertsAttrs
+
+    if not isinstance(attrs, ExpertsAttrs):
+        return 0
+    x = input_shapes[0]
+    d = x.dims[-1]
+    tokens = int(x.num_elements) // d
+    rows = _experts_rows(attrs, tokens, weight_shapes)
+    width = d + (2 if attrs.gated else 1) * attrs.hidden_size + (
+        attrs.out_channels or d
+    )
+    return 2 * rows * width * x.dtype.size_bytes
+
+
 def op_forward_flops(
     attrs: OpAttrs,
     input_shapes,
@@ -617,24 +737,18 @@ def op_forward_flops(
     if isinstance(attrs, EmbeddingAttrs):
         return 0
 
-    from flexflow_tpu.op_attrs.ops.moe import ExpertsAttrs, expert_capacity
+    from flexflow_tpu.op_attrs.ops.moe import ExpertsAttrs
 
     if isinstance(attrs, ExpertsAttrs):
         x = input_shapes[0]
         d = x.dims[-1]
         n = nelem(x) // d
-        e, h = attrs.num_experts, attrs.hidden_size
+        h = attrs.hidden_size
         o = attrs.out_channels or d
-        # capacity is per GLOBAL expert; local compute covers e_local experts
-        cap = expert_capacity(n, e, attrs.num_select, attrs.capacity_factor)
-        e_local = e
-        if weight_shapes and len(weight_shapes) > 1:
-            # slots: gate table (replicated), then [e/k, ...] expert tensors
-            e_local = weight_shapes[1].dims[0]
-        gate = 2 * n * d * e  # every device gates all its tokens
-        dispatch = 2 * n * e_local * cap * (d + o)
-        mlp = 2 * e_local * cap * (d * h + h * o)
-        return gate + dispatch + mlp
+        rows = _experts_rows(attrs, n, weight_shapes)
+        gate = 2 * n * d * attrs.num_experts  # every device gates its tokens
+        mlp = 2 * rows * ((2 if attrs.gated else 1) * d * h + h * o)
+        return gate + mlp
 
     total = sum(nelem(s) for s in output_shapes)
     return total

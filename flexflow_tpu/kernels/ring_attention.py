@@ -149,6 +149,7 @@ def seq_parallel_mha_forward(
     w_spec=None,
     input_bias=None,
     output_bias=None,
+    qk_gains=None,
 ):
     """Shared global-view plumbing for the sequence-parallel attention
     schedules (ring ppermute, Ulysses all-to-all).
@@ -173,7 +174,8 @@ def seq_parallel_mha_forward(
 
     def dense_fallback():
         out = _mha_forward(
-            attrs, q, k, v, weight, input_bias, causal=attrs.causal
+            attrs, q, k, v, weight, input_bias, causal=attrs.causal,
+            qk_gains=qk_gains,
         )
         return out if output_bias is None else out + output_bias
 
@@ -186,6 +188,8 @@ def seq_parallel_mha_forward(
         sp *= mesh.shape[a]
     if sp == 1:
         return dense_fallback()
+    # RingAttentionAttrs' shape rule refuses such a plan
+    assert not attrs.qk_norm and attrs.rope_theta is None, attrs
 
     head_entry = w_spec[1] if w_spec is not None and len(w_spec) > 1 else None
     head_axes = (
@@ -218,9 +222,10 @@ def seq_parallel_mha_forward(
 
 
 def ring_mha_forward(attrs, q, k, v, weight, mesh, q_spec, w_spec=None,
-                     input_bias=None, output_bias=None):
+                     input_bias=None, output_bias=None, qk_gains=None):
     """Global-view entry for the ppermute ring schedule."""
     return seq_parallel_mha_forward(
         ring_mha_shard_fn, attrs, q, k, v, weight, mesh, q_spec,
         w_spec=w_spec, input_bias=input_bias, output_bias=output_bias,
+        qk_gains=qk_gains,
     )

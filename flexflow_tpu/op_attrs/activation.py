@@ -13,6 +13,7 @@ class Activation(enum.Enum):
     SIGMOID = "sigmoid"
     TANH = "tanh"
     GELU = "gelu"
+    SILU = "silu"
 
     def apply(self, x):
         import jax
@@ -22,6 +23,7 @@ class Activation(enum.Enum):
             Activation.SIGMOID: jax.nn.sigmoid,
             Activation.TANH: jax.numpy.tanh,
             Activation.GELU: jax.nn.gelu,
+            Activation.SILU: jax.nn.silu,
         }[self](x)
 
 

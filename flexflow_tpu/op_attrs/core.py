@@ -42,6 +42,7 @@ from flexflow_tpu.op_attrs.ops.conv_ops import (
 )
 from flexflow_tpu.op_attrs.ops.norm_ops import (
     LayerNormAttrs,
+    RMSNormAttrs,
     SoftmaxAttrs,
     DropoutAttrs,
 )
@@ -90,6 +91,7 @@ class OperatorType(enum.Enum):
     FLAT = "flat"
     BATCH_NORM = "batch_norm"
     LAYER_NORM = "layer_norm"
+    RMS_NORM = "rms_norm"
     SOFTMAX = "softmax"
     DROPOUT = "dropout"
     MULTIHEAD_ATTENTION = "multihead_attention"
@@ -128,7 +130,7 @@ OpAttrs = Union[
     ElementUnaryAttrs, ElementBinaryAttrs, CastAttrs, BroadcastAttrs,
     LinearAttrs, BatchMatmulAttrs, EmbeddingAttrs,
     Conv2DAttrs, Pool2DAttrs, FlatAttrs, BatchNormAttrs,
-    LayerNormAttrs, SoftmaxAttrs, DropoutAttrs,
+    LayerNormAttrs, RMSNormAttrs, SoftmaxAttrs, DropoutAttrs,
     MultiHeadAttentionAttrs, RingAttentionAttrs, UlyssesAttentionAttrs,
     ConcatAttrs, StackAttrs, SplitAttrs, ReshapeAttrs, TransposeAttrs,
     ReverseAttrs, GatherAttrs, TopKAttrs, ReduceAttrs,
@@ -153,6 +155,7 @@ _OP_TYPE_BY_ATTRS = {
     FlatAttrs: OperatorType.FLAT,
     BatchNormAttrs: OperatorType.BATCH_NORM,
     LayerNormAttrs: OperatorType.LAYER_NORM,
+    RMSNormAttrs: OperatorType.RMS_NORM,
     SoftmaxAttrs: OperatorType.SOFTMAX,
     DropoutAttrs: OperatorType.DROPOUT,
     MultiHeadAttentionAttrs: OperatorType.MULTIHEAD_ATTENTION,
@@ -225,13 +228,17 @@ def get_incoming_tensor_roles(attrs: OpAttrs) -> List[IncomingTensorRole]:
         roles = [I, I, I, W]
         if attrs.bias:
             roles += [W, W]
+        if attrs.qk_norm:
+            roles += [W, W]
         return roles
     if isinstance(attrs, BatchNormAttrs):
         return [I, W, W] if attrs.affine else [I]
     if isinstance(attrs, LayerNormAttrs):
         return [I, W, W] if attrs.elementwise_affine else [I]
+    if isinstance(attrs, RMSNormAttrs):
+        return [I, W]
     if isinstance(attrs, ExpertsAttrs):
-        return [I, W, W, W, W, W] if attrs.use_bias else [I, W, W, W]
+        return [I] + [W] * attrs.num_weights
     n = num_data_inputs(attrs)
     return [I] * n
 
@@ -260,7 +267,7 @@ def num_outputs(attrs: OpAttrs, inputs: Sequence[TensorShape] = ()) -> int:
     if isinstance(attrs, GroupByAttrs):
         return attrs.n_experts
     if isinstance(attrs, ExpertsAttrs):
-        return 2 if attrs.lambda_bal > 0 else 1
+        return 2 if attrs.has_aux else 1
     return 1
 
 
@@ -314,11 +321,15 @@ def get_weight_shapes(
         ws = [attrs.weights_shape(q, k, v)]
         if attrs.bias:
             ws += [attrs.input_bias_shape(q, k, v), attrs.output_bias_shape(q, k, v)]
+        if attrs.qk_norm:
+            ws += [attrs.qk_gain_shape(q, k, v)] * 2
         return ws
     if isinstance(attrs, BatchNormAttrs) and attrs.affine:
         return [attrs.gamma_shape(inputs[0]), attrs.beta_shape(inputs[0])]
     if isinstance(attrs, LayerNormAttrs) and attrs.elementwise_affine:
         return [attrs.gamma_shape(inputs[0]), attrs.beta_shape(inputs[0])]
+    if isinstance(attrs, RMSNormAttrs):
+        return [attrs.gamma_shape(inputs[0])]
     if isinstance(attrs, ExpertsAttrs):
         return list(attrs.weight_shapes(inputs[0]))
     return []
@@ -338,6 +349,11 @@ def get_default_weight_initializers(attrs: OpAttrs, num_weights: int):
         return [ConstantInitializerAttrs(1.0), ZeroInitializerAttrs()][
             :num_weights
         ]
+    if isinstance(attrs, RMSNormAttrs):
+        return [ConstantInitializerAttrs(1.0)][:num_weights]
+    if isinstance(attrs, MultiHeadAttentionAttrs) and attrs.qk_norm:
+        # the two QK-norm gains are the last two slots
+        return [None] * (num_weights - 2) + [ConstantInitializerAttrs(1.0)] * 2
     return [None] * num_weights
 
 
@@ -381,6 +397,8 @@ def get_parallel_weight_shapes(
                 attrs.parallel_input_bias_shape(q, k, v),
                 attrs.parallel_output_bias_shape(q, k, v),
             ]
+        if attrs.qk_norm:
+            ws += [attrs.parallel_qk_gain_shape(q, k, v)] * 2
         return ws
     if isinstance(attrs, Conv2DAttrs):
         ws = [attrs.parallel_kernel_shape(inputs[0])]
@@ -395,6 +413,8 @@ def get_parallel_weight_shapes(
     if isinstance(attrs, LayerNormAttrs) and attrs.elementwise_affine:
         g = attrs.parallel_gamma_shape(inputs[0])
         return [g, g]
+    if isinstance(attrs, RMSNormAttrs):
+        return [attrs.parallel_gamma_shape(inputs[0])]
     if isinstance(attrs, ExpertsAttrs):
         return list(attrs.parallel_weight_shapes(inputs[0]))
     return []

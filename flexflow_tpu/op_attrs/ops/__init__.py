@@ -31,6 +31,7 @@ from flexflow_tpu.op_attrs.ops.conv_ops import (
 )
 from flexflow_tpu.op_attrs.ops.norm_ops import (
     LayerNormAttrs,
+    RMSNormAttrs,
     SoftmaxAttrs,
     DropoutAttrs,
 )

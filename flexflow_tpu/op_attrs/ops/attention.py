@@ -15,6 +15,7 @@ the MHA op and adds sequence parallelism as a separate RingAttention op
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import Optional
 
 from flexflow_tpu.op_attrs.tensor_shape import TensorShape
 from flexflow_tpu.op_attrs.parallel_tensor_shape import (
@@ -34,6 +35,23 @@ class MultiHeadAttentionAttrs:
     bias: bool = False
     add_bias_kv: bool = False
     add_zero_attn: bool = False
+    # What happens between the projections and the attention core is the
+    # op's, because the op owns its projections (ROADMAP D10). Both default
+    # to None: a graph without them has the weight slots, rule matches and
+    # lowered program it always had.
+    # rope_theta: rotary position embedding on q and k, positions 0..s-1,
+    # rotate-half pairing (i, i + d/2) within each head, angle
+    # pos * theta^(-2j/d).
+    rope_theta: Optional[float] = None
+    # qk_norm_eps: RMS norm of the projected q and of the projected k over
+    # ALL heads' features (the whole [h*d] row, before the split into heads
+    # and before RoPE), each with a gain [h*d]: two more weight slots after
+    # the biases, q's then k's.
+    qk_norm_eps: Optional[float] = None
+
+    @property
+    def qk_norm(self) -> bool:
+        return self.qk_norm_eps is not None
 
     @property
     def q_proj_size(self) -> int:
@@ -76,6 +94,10 @@ class MultiHeadAttentionAttrs:
     def output_bias_shape(self, q: TensorShape, k: TensorShape, v: TensorShape) -> TensorShape:
         return TensorShape((self.embed_dim,), q.dtype)
 
+    def qk_gain_shape(self, q: TensorShape, k: TensorShape, v: TensorShape) -> TensorShape:
+        """One QK-norm gain (q's and k's have the same shape)."""
+        return TensorShape((self.num_heads * self.q_proj_size,), q.dtype)
+
     # -- parallel ---------------------------------------------------------
 
     def _parse_parallel(
@@ -101,11 +123,19 @@ class MultiHeadAttentionAttrs:
         self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
     ) -> ParallelTensorShape:
         batch_degree, head_degree = self._parse_parallel(q, k, v)
+        self._check_qk_norm_heads(head_degree)
         unpar = self.output_shape(
             get_reduced_shape(q), get_reduced_shape(k), get_reduced_shape(v)
         )
         return lift_to_parallel_with_degrees(
             unpar, head_degree, 1, (batch_degree, 1, 1)
+        )
+
+    def _check_qk_norm_heads(self, head_degree: int) -> None:
+        assert not (self.qk_norm and head_degree > 1), (
+            "QK-norm takes its mean of squares over every head's features: "
+            "a head shard would need the other shards' sums, so attention "
+            "with qk_norm_eps cannot be head-parallel"
         )
 
     def parallel_weights_shape(
@@ -149,3 +179,14 @@ class MultiHeadAttentionAttrs:
         self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
     ) -> ParallelTensorShape:
         return self._parallel_bias_shape(self.output_bias_shape, q, k, v)
+
+    def parallel_qk_gain_shape(
+        self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
+    ) -> ParallelTensorShape:
+        """A QK-norm gain is replicated wherever the flat weight is (batch
+        shards, and sequence shards for the ring subclass)."""
+        unpar = self.qk_gain_shape(
+            get_reduced_shape(q), get_reduced_shape(k), get_reduced_shape(v)
+        )
+        copies = self.parallel_weights_shape(q, k, v).discard_copy_degree
+        return lift_to_parallel_with_degrees(unpar, 1, copies, (1,))

@@ -36,6 +36,7 @@ class ElementUnaryOpType(enum.Enum):
     SIGMOID = "sigmoid"
     TANH = "tanh"
     GELU = "gelu"
+    SILU = "silu"
     ELU = "elu"
     RSQRT = "rsqrt"
     POW = "pow"

@@ -5,21 +5,29 @@ composition gating-dense -> softmax -> TopK -> GroupBy -> expert towers ->
 Aggregate (ff.moe(input, num_exp, num_select, hidden_size, alpha, lambda);
 legacy Group_by/Aggregate ops, SURVEY.md §2.12 expert-parallelism row).
 
-TPU-native design: GroupBy/Aggregate are kept for composition parity but the
-centerpiece is the fused `ExpertsAttrs` op — a GShard-style dense-dispatch MoE
-FFN (one-hot dispatch/combine einsums, static capacity) whose expert dimension
-shards over a mesh axis. Dense dispatch keeps every shape static (XLA
-requirement) and lets the SPMD partitioner place the token<->expert exchange
-as all-to-all over ICI; the capacity factor bounds per-expert work exactly like
-the reference's `alpha` argument to GroupBy (moe.cc `moeConfig.alpha`).
+TPU-native design: GroupBy/Aggregate are kept for composition parity (one-hot
+dispatch masks, frozen) but the centerpiece is the fused `ExpertsAttrs` op:
+router -> top-k -> dispatch -> expert MLP -> combine in one node, whose expert
+dimension shards over a mesh axis. Dispatch is by sorted index
+(`kernels/moe.py`): the N*k routing decisions are sorted by expert, the token
+rows gathered in that order and multiplied by the experts' matrices as grouped
+(ragged) matmuls in the compute dtype. Shapes stay static because the row
+count is always N*k; only the group sizes are data. A finite
+`capacity_factor` bounds per-expert work exactly like the reference's `alpha`
+argument to GroupBy (moe.cc `moeConfig.alpha`) by zeroing the decisions whose
+rank within their expert is past the capacity; `capacity_factor=None` is
+dropless. The earlier one-hot `[N*k, E, capacity]` formulation could not run a
+published model: at 16,384 tokens, 8 of 64 experts and capacity factor 1 the
+dispatch tensor alone is 131,072 x 64 x 2,048 x 4 B = 68.7 GB on a 16 GB chip.
 
 Expert parallelism in PCG terms (mirrors the Linear reduction-parallel rule,
 linear_ops.py): the input is REPLICATED over the expert axes
 (discard_copy_degree = ep) while expert weights are SHARDED on their leading
-expert dim; each expert group contributes partial combined outputs (tokens
-routed to remote experts contribute zero locally), so the op's output carries
-sum_degree = ep — a pending partial sum the lowering resolves with psum, the
-exact Unity "attribute parallelism" pattern.
+expert dim; each expert group routes at the full router width and contributes
+the combined output of its own experts (tokens routed to remote experts
+contribute zero locally), so the op's output carries sum_degree = ep, a
+pending partial sum the lowering resolves with psum: the exact Unity
+"attribute parallelism" pattern.
 """
 
 from __future__ import annotations
@@ -125,13 +133,36 @@ class AggregateAttrs:
 
 @dataclass(frozen=True)
 class ExpertsAttrs:
-    """Fused GShard-style MoE FFN: gate -> top-k -> dispatch -> two-layer
-    expert MLP -> combine (+ optional Switch-style load-balance aux loss).
+    """Fused MoE FFN: router -> top-k -> dispatch -> expert MLP -> combine
+    (+ optional auxiliary router losses).
 
-    weights (slot order): gate [D, E]; w1 [E, D, H]; b1 [E, H];
-    w2 [E, H, out]; b2 [E, out]  (biases present iff use_bias).
-    outputs: [.., out] and, when lambda_bal > 0, an aux-loss scalar [1] to be
-    added to the training loss (reference: MoE lambda argument, moe.cc).
+    weights (slot order), legacy two-matrix form: gate [D, E]; w1 [E, D, H];
+    b1 [E, H]; w2 [E, H, out]; b2 [E, out]  (biases present iff use_bias).
+    `gated=True` is the three-matrix form of the published sparse decoders,
+    `(act(x w1) * (x w3)) w2`, without biases: gate [D, E]; w1 [E, D, H];
+    w3 [E, D, H]; w2 [E, H, out].
+
+    capacity_factor: a float bounds every expert at
+    ceil(factor * k * N / E) decisions, earlier tokens first, the rest
+    dropped; None is dropless (every decision is computed).
+    renormalize: divide the k selected router probabilities by their sum
+    (True, the GShard/Switch habit and this op's behaviour before the flag
+    existed) or use them as they are (False: `norm_topk_prob` false).
+    The router and its softmax are computed in float32 in either form.
+
+    outputs: [.., out] and, when lambda_bal > 0 or lambda_z > 0, one
+    float32 scalar [1] to be added to the training loss (reference: MoE
+    lambda argument, moe.cc), whatever the compute dtype:
+        lambda_bal * LB + lambda_z * Z
+        LB = E * sum_e f_e P_e,  P_e = mean_n p[n, e],  f_e without gradient
+        Z  = mean_n (logsumexp_e logits[n, e])^2     (ST-MoE's z-loss)
+    In the gated form f_e = (tokens that chose e) / N, so sum_e f_e = k: the
+    published load-balancing loss of the Switch / Mixtral / OLMoE line. The
+    legacy form keeps what it always computed, f_e = (decisions routed to e)
+    / (N k), k times smaller, so that models trained with it keep their
+    lambda. N is the tokens this op sees: under a batch-sharded plan each
+    shard takes f_e, P_e and Z over its own tokens and the shards' scalars
+    are averaged, which is what data-parallel MoE training does.
     """
 
     num_experts: int
@@ -139,14 +170,33 @@ class ExpertsAttrs:
     hidden_size: int
     out_channels: Optional[int] = None
     activation: Optional[Activation] = Activation.RELU
-    capacity_factor: float = 2.0
+    capacity_factor: Optional[float] = 2.0
     use_bias: bool = True
     lambda_bal: float = 0.0
+    gated: bool = False
+    renormalize: bool = True
+    lambda_z: float = 0.0
+
+    def __post_init__(self):
+        assert not (self.gated and self.use_bias), (
+            "the gated expert form has no biases: pass use_bias=False"
+        )
+
+    @property
+    def has_aux(self) -> bool:
+        return self.lambda_bal > 0 or self.lambda_z > 0
+
+    @property
+    def num_weights(self) -> int:
+        return 4 if self.gated else (5 if self.use_bias else 3)
 
     def _out_dim(self, input: TensorShape) -> int:
         return self.out_channels or input.dims[-1]
 
-    def capacity(self, input: TensorShape) -> int:
+    def capacity(self, input: TensorShape) -> Optional[int]:
+        """Decisions one expert keeps, or None where nothing is dropped."""
+        if self.capacity_factor is None:
+            return None
         tokens = _prod(input.dims[:-1])
         return expert_capacity(
             tokens, self.num_experts, self.num_select, self.capacity_factor
@@ -156,7 +206,7 @@ class ExpertsAttrs:
         out = TensorShape(
             input.dims[:-1] + (self._out_dim(input),), input.dtype
         )
-        if self.lambda_bal > 0:
+        if self.has_aux:
             return [out, TensorShape((1,), input.dtype)]
         return [out]
 
@@ -167,6 +217,8 @@ class ExpertsAttrs:
             TensorShape((d, e), input.dtype),
             TensorShape((e, d, h), input.dtype),
         ]
+        if self.gated:
+            ws.append(TensorShape((e, d, h), input.dtype))
         if self.use_bias:
             ws.append(TensorShape((e, h), input.dtype))
         ws.append(TensorShape((e, h, o), input.dtype))
@@ -187,10 +239,10 @@ class ExpertsAttrs:
         unpars = self.output_shapes(get_reduced_shape(input))
         in_degrees = input.shard_degrees()
         out = lift_to_parallel_with_degrees(unpars[0], ep, 1, in_degrees)
-        if self.lambda_bal > 0:
+        if self.has_aux:
             # each batch shard gates a different token slice, so its local
-            # balance loss is a partial value (summed/averaged by the training
-            # loss); across ep the gating is replicated
+            # scalar is a partial value (averaged by the lowering, see the
+            # class docstring); across ep the gating is replicated
             batch = _prod(in_degrees)
             aux = lift_to_parallel_with_degrees(unpars[1], batch, ep, (1,))
             return [out, aux]

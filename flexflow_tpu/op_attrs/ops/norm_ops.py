@@ -1,6 +1,8 @@
-"""LayerNorm, Softmax, Dropout.
+"""LayerNorm, RMSNorm, Softmax, Dropout.
 
-Reference: op-attrs/ops/{layer_norm,softmax,dropout}.h.
+Reference: op-attrs/ops/{layer_norm,softmax,dropout}.h. RMSNorm has no
+reference counterpart: it is the norm of the decoders after 2023 (no mean, no
+shift; Zhang & Sennrich, arXiv:1910.07467).
 """
 
 from __future__ import annotations
@@ -56,6 +58,39 @@ class LayerNormAttrs:
             1,
             non_norm_degrees * input.discard_copy_degree,
             (1,) * len(self.axes),
+        )
+
+
+@dataclass(frozen=True)
+class RMSNormAttrs:
+    """x * rsqrt(mean(x^2, last dim) + eps) * gain, the mean of squares
+    accumulated in float32 whatever the compute dtype. One weight, the gain
+    [channels], which starts at one. Always over the last dim: that is the
+    only form the published decoders use, and it keeps every leading dim
+    free to shard."""
+
+    eps: float = 1e-5
+
+    def output_shape(self, input: TensorShape) -> TensorShape:
+        return input
+
+    def gamma_shape(self, input: TensorShape) -> TensorShape:
+        return TensorShape((input.dims[-1],), input.dtype)
+
+    def parallel_output_shape(self, input: ParallelTensorShape) -> ParallelTensorShape:
+        assert input.sum_degree == 1, "rms norm over partial sums is invalid"
+        assert input.shard_dim_at(-1).degree == 1, (
+            "the normalized (last) dim must be unsharded"
+        )
+        return input
+
+    def parallel_gamma_shape(self, input: ParallelTensorShape) -> ParallelTensorShape:
+        unpar = self.gamma_shape(get_reduced_shape(input))
+        return lift_to_parallel_with_degrees(
+            unpar,
+            1,
+            _prod(input.shard_degrees()) * input.discard_copy_degree,
+            (1,),
         )
 
 

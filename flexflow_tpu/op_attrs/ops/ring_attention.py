@@ -63,6 +63,13 @@ class RingAttentionAttrs(MultiHeadAttentionAttrs):
         self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
     ) -> ParallelTensorShape:
         batch_degree, seq_degree, head_degree = self._parse_parallel_ring(q, k, v)
+        self._check_qk_norm_heads(head_degree)
+        # a sequence shard would have to rotate by its GLOBAL positions, and
+        # the ring and all-to-all schedules project per shard to [b, h, s, d]
+        # without the fused-row step QK-norm and RoPE act on (ROADMAP R7)
+        assert seq_degree == 1 or not (
+            self.qk_norm or self.rope_theta is not None
+        ), "attention with rope_theta or qk_norm_eps cannot be sequence-parallel yet"
         unpar = self.output_shape(
             get_reduced_shape(q), get_reduced_shape(k), get_reduced_shape(v)
         )

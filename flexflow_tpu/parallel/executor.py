@@ -213,6 +213,7 @@ def _interpret(
                     w_spec=w_spec,
                     input_bias=weight_vals[1] if attrs.bias else None,
                     output_bias=weight_vals[2] if attrs.bias else None,
+                    qk_gains=weight_vals[-2:] if attrs.qk_norm else None,
                 )
                 env[outs[0]] = constrain(out, outs[0])
             else:
@@ -249,6 +250,13 @@ def _interpret(
                 )
                 if sharded is not None:
                     env[outs[0]] = sharded
+                    continue
+                sharded = _try_sharded_experts(
+                    attrs, slot_vals, in_tensors, shardings, mesh
+                )
+                if sharded is not None:
+                    for o, r in zip(outs, sharded):
+                        env[o] = r
                     continue
                 pinned = _try_pinned_reduction(
                     pcg, n, attrs, slot_vals, in_tensors, shardings, mesh,
@@ -620,6 +628,9 @@ def _try_sharded_flash_mha(attrs, data_vals, weight_vals, in_tensors,
         or mesh.size <= 1
         or not isinstance(attrs, MultiHeadAttentionAttrs)
         or isinstance(attrs, RingAttentionAttrs)
+        # QK-norm and RoPE act on the fused-row projections: _mha_forward
+        or attrs.qk_norm
+        or attrs.rope_theta is not None
     ):
         return None
     if os.environ.get("FLEXFLOW_TPU_FLASH", "1") == "0":
@@ -667,6 +678,72 @@ def _try_sharded_flash_mha(attrs, data_vals, weight_vals, in_tensors,
     if attrs.bias:
         out = out + weight_vals[2]
     return out
+
+
+def _try_sharded_experts(attrs, slot_vals, in_tensors, shardings, mesh):
+    """The Experts op under a batch- and/or expert-sharded plan, per shard
+    (a global-view lowering would sort and gather the GLOBAL decisions, which
+    XLA can only do by replicating them). Each shard routes ITS tokens at the
+    full router width and runs the experts it holds on them:
+
+    - batch shards (the data-parallel rule and template): the capacity, and
+      f_e, P_e and Z of the auxiliary scalar, are over the shard's own
+      tokens; the shards' scalars are averaged. This is what data-parallel
+      MoE training does, and it is not the one-device value.
+    - expert shards (Replicate -> Experts -> Reduction): the combine of the
+      local experts' rows is a partial sum, summed here; the Reduction node
+      that follows is then an identity on the global value.
+
+    Returns the node's outputs, or None to fall back to the global view."""
+    from jax.sharding import PartitionSpec as P
+
+    from flexflow_tpu.kernels.moe import experts_forward
+    from flexflow_tpu.op_attrs.ops import ExpertsAttrs
+
+    if mesh is None or mesh.size <= 1 or not isinstance(attrs, ExpertsAttrs):
+        return None
+    x = slot_vals[0]
+    x_sh = shardings.get(in_tensors[0])
+    w_shs = [shardings.get(t) for t in in_tensors[2:]]
+    x_spec = (None,) * x.ndim if x_sh is None else _padded_spec(x_sh, x.ndim)
+    ep_entry = _spec_entry(w_shs[0], 0)
+    batch_axes = tuple(a for e in x_spec[:-1] for a in _entry_names(e))
+    ep_axes = _entry_names(ep_entry)
+    if x_spec[-1] is not None or not (batch_axes or ep_axes):
+        return None
+    if set(batch_axes) & set(ep_axes):
+        return None
+    for w, sh in zip(slot_vals[2:], w_shs):
+        spec = (None,) * w.ndim if sh is None else _padded_spec(sh, w.ndim)
+        if spec[0] != ep_entry or any(e is not None for e in spec[1:]):
+            return None
+    gate_sh = shardings.get(in_tensors[1])
+    if gate_sh is not None and any(e is not None for e in gate_sh.spec):
+        return None
+    ep = _mesh_axes_size(mesh, ep_axes)
+    if attrs.num_experts % ep:
+        return None
+    here = attrs.num_experts // ep
+
+    def local(x, gate, *ws):
+        shard = None
+        if ep > 1:
+            shard = (jax.lax.axis_index(ep_axes) * here, here)
+        res = experts_forward(
+            attrs, x, [gate, *ws], expert_shard=shard, per_shard=True
+        )
+        if ep > 1:
+            res[0] = jax.lax.psum(res[0], ep_axes)
+        if attrs.has_aux and batch_axes:
+            res[1] = jax.lax.pmean(res[1], batch_axes)
+        return tuple(res)
+
+    in_specs = (
+        P(*x_spec), P(),
+        *[P(ep_entry, *[None] * (w.ndim - 1)) for w in slot_vals[2:]],
+    )
+    out_specs = (P(*x_spec),) + ((P(),) if attrs.has_aux else ())
+    return list(_shard_map(local, mesh, in_specs, out_specs)(*slot_vals))
 
 
 class DistributedTrainingInstance:

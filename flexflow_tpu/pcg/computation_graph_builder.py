@@ -39,6 +39,7 @@ from flexflow_tpu.op_attrs.ops import (
     GatherAttrs,
     InputAttrs,
     LayerNormAttrs,
+    RMSNormAttrs,
     LinearAttrs,
     MultiHeadAttentionAttrs,
     NoopAttrs,
@@ -240,10 +241,24 @@ class ComputationGraphBuilder:
         add_zero_attn: bool = False,
         initializer: Optional[InitializerAttrs] = None,
         name: Optional[str] = None,
+        causal: bool = False,
+        rope_theta: Optional[float] = None,
+        qk_norm_eps: Optional[float] = None,
     ) -> Tensor:
-        attrs = MultiHeadAttentionAttrs(
-            embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv, add_zero_attn
+        """`causal`, `rope_theta` (rotary positions 0..s-1 on q and k) and
+        `qk_norm_eps` (RMS norm of the projected q and k over all heads'
+        features, two gain weights) are what a decoder adds; a causal node
+        is a `RingAttentionAttrs`, the program's causal attention."""
+        fields = (
+            embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
+            add_zero_attn, rope_theta, qk_norm_eps,
         )
+        if causal:
+            from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
+
+            attrs = RingAttentionAttrs(*fields, causal=True)
+        else:
+            attrs = MultiHeadAttentionAttrs(*fields)
         (out,) = self.add_layer(attrs, [query, key, value], [initializer], name)
         return out
 
@@ -317,6 +332,13 @@ class ComputationGraphBuilder:
         (out,) = self.add_layer(attrs, [input], [], name)
         return out
 
+    def rms_norm(
+        self, input: Tensor, eps: float = 1e-5, name: Optional[str] = None
+    ) -> Tensor:
+        """RMS norm over the last dim with a gain (no mean, no shift)."""
+        (out,) = self.add_layer(RMSNormAttrs(eps), [input], [], name)
+        return out
+
     def softmax(self, input: Tensor, dim: int = -1, name: Optional[str] = None) -> Tensor:
         (out,) = self.add_layer(SoftmaxAttrs(dim), [input], [], name)
         return out
@@ -354,6 +376,9 @@ class ComputationGraphBuilder:
 
     def gelu(self, x, name=None):
         return self._unary(ElementUnaryOpType.GELU, x, name=name)
+
+    def silu(self, x, name=None):
+        return self._unary(ElementUnaryOpType.SILU, x, name=name)
 
     def elu(self, x, name=None):
         return self._unary(ElementUnaryOpType.ELU, x, name=name)
@@ -520,12 +545,19 @@ class ComputationGraphBuilder:
         hidden_size: int,
         out_channels: Optional[int] = None,
         activation: Optional[Activation] = Activation.RELU,
-        capacity_factor: float = 2.0,
+        capacity_factor: Optional[float] = 2.0,
         use_bias: bool = True,
         lambda_bal: float = 0.0,
         name=None,
+        gated: bool = False,
+        renormalize: bool = True,
+        lambda_z: float = 0.0,
+        initializer: Optional[InitializerAttrs] = None,
     ) -> List[Tensor]:
-        """Fused GShard-style MoE FFN; returns [out] or [out, aux_loss]."""
+        """Fused MoE FFN (`ExpertsAttrs`); returns [out] or, with an
+        auxiliary loss coefficient, [out, aux_loss], the scalar recorded in
+        `self.aux_loss_tensors` for the training loss. `initializer`, if
+        given, initializes every weight slot."""
         from flexflow_tpu.op_attrs.ops.moe import ExpertsAttrs
 
         attrs = ExpertsAttrs(
@@ -537,8 +569,16 @@ class ComputationGraphBuilder:
             capacity_factor,
             use_bias,
             lambda_bal,
+            gated,
+            renormalize,
+            lambda_z,
         )
-        return self.add_layer(attrs, [input], [], name)
+        outs = self.add_layer(
+            attrs, [input], [initializer] * attrs.num_weights, name
+        )
+        if len(outs) > 1 and outs[1] not in self.aux_loss_tensors:
+            self.aux_loss_tensors.append(outs[1])
+        return outs
 
     def moe(
         self,
@@ -563,6 +603,4 @@ class ComputationGraphBuilder:
             lambda_bal=lambda_bal,
             name=name,
         )
-        if len(outs) > 1:
-            self.aux_loss_tensors.append(outs[1])
         return outs[0]

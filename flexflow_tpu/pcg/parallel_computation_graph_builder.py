@@ -224,10 +224,13 @@ class ParallelComputationGraphBuilder:
         hidden_size: int,
         out_channels: Optional[int] = None,
         activation: Optional[Activation] = Activation.RELU,
-        capacity_factor: float = 2.0,
+        capacity_factor: Optional[float] = 2.0,
         use_bias: bool = True,
         lambda_bal: float = 0.0,
         name: Optional[str] = None,
+        gated: bool = False,
+        renormalize: bool = True,
+        lambda_z: float = 0.0,
     ) -> List[Tensor]:
         """Fused MoE FFN. Expert parallelism = parallel_replicate the input
         to degree ep first (the op shards expert weights over the replica
@@ -244,6 +247,9 @@ class ParallelComputationGraphBuilder:
             capacity_factor,
             use_bias,
             lambda_bal,
+            gated,
+            renormalize,
+            lambda_z,
         )
         return self.add_layer(attrs, [input], [], name)
 
@@ -320,6 +326,14 @@ class ParallelComputationGraphBuilder:
             tuple(a % nd for a in axes), elementwise_affine, eps
         )
         (out,) = self.add_layer(attrs, [x], [], name)
+        return out
+
+    def rms_norm(
+        self, x: Tensor, eps: float = 1e-5, name: Optional[str] = None
+    ) -> Tensor:
+        from flexflow_tpu.op_attrs.ops import RMSNormAttrs
+
+        (out,) = self.add_layer(RMSNormAttrs(eps), [x], [], name)
         return out
 
     def add(self, a: Tensor, b: Tensor, name: Optional[str] = None) -> Tensor:

@@ -298,6 +298,10 @@ class ServingProgram:
         scores, -1e30 mask, softmax, wo einsum."""
         from flexflow_tpu.kernels.ops import mha_project_qkv
 
+        assert not attrs.qk_norm and attrs.rope_theta is None, (
+            "the serving programs do not rotate by cache position or apply "
+            "QK-norm yet (ROADMAP R5): training only"
+        )
         q, k, v = data_vals
         input_bias = weight_vals[1] if attrs.bias else None
         qp, kp, vp, wo = mha_project_qkv(

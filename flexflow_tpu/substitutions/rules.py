@@ -179,6 +179,7 @@ def head_parallel_attention_rule(degree: int) -> Substitution:
     v = p.add_input()
     w = p.add_input()
     pnode, (py,) = p.add_operator(
+        # (a node with QK-norm has two more weight slots and cannot match)
         OperatorAttributePattern.for_op_type(
             OperatorType.MULTIHEAD_ATTENTION, bias=False
         ),
@@ -221,7 +222,9 @@ def _seq_parallel_attention_rule(
     pnode, (py,) = p.add_operator(
         _attr_pattern(
             OperatorType.MULTIHEAD_ATTENTION,
-            eq=dict(bias=False),
+            # RoPE under a sequence shard needs the shard's global
+            # positions (RingAttentionAttrs' shape rule, ROADMAP R7)
+            eq=dict(bias=False, rope_theta=None),
             div=extra_div,
         ),
         [q, k, v, w],
@@ -471,8 +474,70 @@ def column_parallel_embedding_rule(degree: int) -> Substitution:
     )
 
 
+def _experts_pattern(use_bias, gated, with_aux, div=None):
+    """(attribute pattern, weight slots, outputs) of one form of the Experts
+    op. The forms differ in their number of weight slots (legacy with and
+    without biases, gated) and outputs (an auxiliary scalar or none), and a
+    pattern has a fixed number of both. `with_aux` matches lambda_bal != 0
+    (with or without a z-loss); a z-loss alone has no rule."""
+    eq = dict(use_bias=use_bias, gated=gated)
+    if not with_aux:
+        eq.update(lambda_bal=0.0, lambda_z=0.0)
+    pattern = _attr_pattern(
+        OperatorType.EXPERTS,
+        eq=eq,
+        div=div,
+        ne=dict(lambda_bal=0.0) if with_aux else None,
+    )
+    num_w = 4 if gated else (5 if use_bias else 3)
+    return pattern, num_w, 2 if with_aux else 1
+
+
+def _experts_tag(use_bias, gated, with_aux):
+    form = "g" if gated else ("b" if use_bias else "nb")
+    return f"{form}{'_aux' if with_aux else ''}"
+
+
+def data_parallel_experts_rule(
+    degree: int, use_bias: bool, gated: bool = False, with_aux: bool = False
+) -> Substitution:
+    """Experts(x, gate, w...) -> Combine_0(Experts(Repartition_0(x),
+    Replicate(gate), Replicate(w)...)): sample parallelism for the MoE FFN.
+    Each batch shard routes its own tokens to every expert: a finite
+    capacity, and f_e, P_e and Z of the auxiliary scalar, are over the
+    shard's tokens (the shards' scalars are averaged), which is what
+    data-parallel MoE training does and not the one-device value. As in the
+    expert-parallel rule the auxiliary output is found structurally, not
+    interface-mapped."""
+    attr_pattern, num_w, num_out = _experts_pattern(use_bias, gated, with_aux)
+    p = PCGPattern()
+    a = p.add_input(_shard_pattern(0, degree))
+    ws = [p.add_input() for _ in range(num_w)]
+    pnode, pouts = p.add_operator(attr_pattern, [a, *ws], num_outputs=num_out)
+    og = OutputGraphExpr()
+    oa = og.add_input()
+    ows = [og.add_input() for _ in ws]
+    _, (ap,) = og.add_operator(AttrConstant(RepartitionAttrs(0, degree)), [oa])
+    reps = []
+    for ow in ows:
+        _, (wr,) = og.add_operator(AttrConstant(ReplicateAttrs(degree)), [ow])
+        reps.append(wr)
+    _, youts = og.add_operator(
+        CopyAttrsFromMatched(pnode), [ap, *reps], num_outputs=num_out
+    )
+    _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [youts[0]])
+    return Substitution(
+        f"data_parallel_experts_{_experts_tag(use_bias, gated, with_aux)}"
+        f"_{degree}",
+        p,
+        og,
+        ((a, oa), *zip(ws, ows)),
+        ((pouts[0], out),),
+    )
+
+
 def expert_parallel_experts_rule(
-    degree: int, use_bias: bool, with_aux: bool = False
+    degree: int, use_bias: bool, with_aux: bool = False, gated: bool = False
 ) -> Substitution:
     """Experts(x, gate, w1[, b1], w2[, b2]) -> Reduction(Experts(Replicate(x),
     Replicate(gate), Repartition_0(w1)[, ...])): expert parallelism — each
@@ -484,25 +549,14 @@ def expert_parallel_experts_rule(
     load-balance aux scalar is unconsumed inside the graph (training adds it
     to the loss), so only the main output is interface-mapped; the RHS op
     emits its own replicated aux, found structurally by the training
-    instance."""
-    num_w = 5 if use_bias else 3
-    num_out = 2 if with_aux else 1
+    instance. `gated=True` matches the three-matrix form (four weights)."""
+    attr_pattern, num_w, num_out = _experts_pattern(
+        use_bias, gated, with_aux, div=dict(num_experts=degree)
+    )
     p = PCGPattern()
     a = p.add_input()
     ws = [p.add_input() for _ in range(num_w)]
-    eq = dict(use_bias=use_bias)
-    if not with_aux:
-        eq["lambda_bal"] = 0.0
-    pnode, pouts = p.add_operator(
-        _attr_pattern(
-            OperatorType.EXPERTS,
-            eq=eq,
-            div=dict(num_experts=degree),
-            ne=dict(lambda_bal=0.0) if with_aux else None,
-        ),
-        [a, *ws],
-        num_outputs=num_out,
-    )
+    pnode, pouts = p.add_operator(attr_pattern, [a, *ws], num_outputs=num_out)
     py = pouts[0]
     og = OutputGraphExpr()
     oa = og.add_input()
@@ -522,8 +576,8 @@ def expert_parallel_experts_rule(
     )
     _, (out,) = og.add_operator(AttrConstant(ReductionAttrs(degree)), [youts[0]])
     return Substitution(
-        f"expert_parallel_experts_{'b' if use_bias else 'nb'}"
-        f"{'_aux' if with_aux else ''}_{degree}",
+        f"expert_parallel_experts_{_experts_tag(use_bias, gated, with_aux)}"
+        f"_{degree}",
         p,
         og,
         ((a, oa), *zip(ws, ows)),
@@ -622,21 +676,32 @@ def branch_reduce_sum_rule(degree: int) -> Substitution:
     )
 
 
-def data_parallel_attention_rule(degree: int, bias: bool = False) -> Substitution:
-    """MHA(q,k,v,w[,bi,bo]) -> Combine_0(MHA(Repartition_0(q,k,v),
-    Replicate(w)[, Replicate(bi), Replicate(bo)])): sample parallelism for
-    attention (reference attention.cc sample-dim rule). Without this the
-    transformer's searched DP plan left every MHA serial, forcing a full
-    reshard at each attention boundary. `bias=True` matches the biased op
-    (input and output bias as two more weights, replicated like w)."""
+def data_parallel_attention_rule(
+    degree: int, bias: bool = False, qk_norm: bool = False,
+    op_type: OperatorType = OperatorType.MULTIHEAD_ATTENTION,
+) -> Substitution:
+    """MHA(q,k,v,w[,bi,bo][,gq,gk]) -> Combine_0(MHA(Repartition_0(q,k,v),
+    Replicate(w)[, Replicate(bi), Replicate(bo)][, Replicate(gq),
+    Replicate(gk)])): sample parallelism for attention (reference
+    attention.cc sample-dim rule). Without this the transformer's searched
+    DP plan left every MHA serial, forcing a full reshard at each attention
+    boundary. `bias=True` matches the biased op (input and output bias as
+    two more weights, replicated like w), `qk_norm=True` the op with
+    QK-norm (its two gains likewise); RoPE adds no slot and needs no rule
+    of its own. `op_type=RING_ATTENTION` is the same rewrite for the
+    program's causal attention, whose sequence dim stays whole here."""
     p = PCGPattern()
     q = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     k = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     v = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
-    weights = [p.add_input() for _ in range(3 if bias else 1)]
+    weights = [
+        p.add_input() for _ in range(1 + 2 * bool(bias) + 2 * bool(qk_norm))
+    ]
     pnode, (py,) = p.add_operator(
-        OperatorAttributePattern.for_op_type(
-            OperatorType.MULTIHEAD_ATTENTION, bias=bias
+        _attr_pattern(
+            op_type,
+            eq=dict(bias=bias),
+            ne=dict(qk_norm_eps=None) if qk_norm else None,
         ),
         [q, k, v, *weights],
     )
@@ -654,7 +719,9 @@ def data_parallel_attention_rule(degree: int, bias: bool = False) -> Substitutio
     _, (y,) = og.add_operator(CopyAttrsFromMatched(pnode), [*parts, *reps])
     _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [y])
     return Substitution(
-        f"data_parallel_attention_{'b_' if bias else ''}{degree}",
+        f"data_parallel_"
+        f"{'ring_' if op_type == OperatorType.RING_ATTENTION else ''}"
+        f"attention_{'b_' if bias else ''}{'qkn_' if qk_norm else ''}{degree}",
         p,
         og,
         ((q, oq), (k, ok), (v, ov), *zip(weights, o_weights)),
@@ -695,6 +762,31 @@ def data_parallel_layer_norm_rule(degree: int, dim: int = 0) -> Substitution:
         p,
         og,
         ((a, oa), (g, og_), (b, ob)),
+        ((py, out),),
+    )
+
+
+def data_parallel_rms_norm_rule(degree: int, dim: int = 0) -> Substitution:
+    """RMSNorm(x, g) -> Combine_d(RMSNorm(Repartition_d(x), Replicate(g))):
+    the statistic is per position over the last dim, so any other dim
+    shards (dim=0 batch, dim=1 sequence)."""
+    p = PCGPattern()
+    a = p.add_input(_shard_pattern(dim, degree))
+    g = p.add_input()
+    pnode, (py,) = p.add_operator(
+        OperatorAttributePattern.for_op_type(OperatorType.RMS_NORM), [a, g]
+    )
+    og = OutputGraphExpr()
+    oa, og_ = og.add_input(), og.add_input()
+    _, (ap,) = og.add_operator(AttrConstant(RepartitionAttrs(dim, degree)), [oa])
+    _, (gr,) = og.add_operator(AttrConstant(ReplicateAttrs(degree)), [og_])
+    _, (y,) = og.add_operator(CopyAttrsFromMatched(pnode), [ap, gr])
+    _, (out,) = og.add_operator(AttrConstant(CombineAttrs(dim, degree)), [y])
+    return Substitution(
+        f"data_parallel_rms_norm{_dim_tag(dim)}_{degree}",
+        p,
+        og,
+        ((a, oa), (g, og_)),
         ((py, out),),
     )
 
@@ -934,7 +1026,22 @@ def generate_parallelization_rules(
         rules.append(data_parallel_batch_norm_rule(k))
         for bias in (False, True):
             rules.append(data_parallel_attention_rule(k, bias))
+        for bias in (False, True):
+            rules.append(
+                data_parallel_attention_rule(
+                    k, bias, op_type=OperatorType.RING_ATTENTION
+                )
+            )
+        for op_type in (
+            OperatorType.MULTIHEAD_ATTENTION, OperatorType.RING_ATTENTION,
+        ):
+            rules.append(
+                data_parallel_attention_rule(
+                    k, False, qk_norm=True, op_type=op_type
+                )
+            )
         rules.append(data_parallel_layer_norm_rule(k))
+        rules.append(data_parallel_rms_norm_rule(k))
         rules.append(sequence_parallel_attention_rule(k))
         rules.append(sequence_parallel_attention_a2a_rule(k))
         # sequence-axis (dim=1) variants: the seq-parallel residual stream's
@@ -943,6 +1050,7 @@ def generate_parallelization_rules(
         for use_bias in (True, False):
             rules.append(data_parallel_linear_rule(k, use_bias, dim=1))
         rules.append(data_parallel_layer_norm_rule(k, dim=1))
+        rules.append(data_parallel_rms_norm_rule(k, dim=1))
         rules.append(data_parallel_op_rule(OperatorType.ELEMENT_UNARY, k, dim=1))
         rules.append(
             data_parallel_op_rule(
@@ -961,6 +1069,14 @@ def generate_parallelization_rules(
         for use_bias in (True, False):
             rules.append(expert_parallel_experts_rule(k, use_bias))
             rules.append(expert_parallel_experts_rule(k, use_bias, with_aux=True))
+        for with_aux in (False, True):
+            rules.append(
+                expert_parallel_experts_rule(k, False, with_aux, gated=True)
+            )
+            for use_bias, gated in ((True, False), (False, False), (False, True)):
+                rules.append(
+                    data_parallel_experts_rule(k, use_bias, gated, with_aux)
+                )
         # branch parallelism over stacked isomorphic branches
         # (compiler/branch_stacking.py): shard the stacked leading axis,
         # merge via local sum + Reduction
