@@ -35,9 +35,12 @@ _tls = threading.local()
 @contextlib.contextmanager
 def flash_mesh(mesh, batch_axes, head_axes, interpret: bool = False):
     """Declare the SPMD context for attention kernels traced within: the
-    mesh plus the PartitionSpec entries of the per-head tensors' batch and
-    head dims. _mha_forward consults this to route through
-    sharded_flash_attention instead of a bare (unpartitionable) pallas_call."""
+    mesh plus the PartitionSpec entries of the attention node's batch and
+    head dims. _mha_forward consults this to map its kernels over the shards
+    (shard_map) instead of emitting a bare (unpartitionable) pallas_call:
+    the one-chip fused-row dispatch per batch shard when heads are whole
+    (`head_axes is None`), sharded_flash_attention on [b, h, s, d] when they
+    are split."""
     prev = getattr(_tls, "mesh_ctx", None)
     _tls.mesh_ctx = (mesh, batch_axes, head_axes, interpret)
     try:
@@ -62,10 +65,11 @@ def interpret_default() -> bool:
 
 @contextlib.contextmanager
 def no_flash():
-    """Disable the pallas path within this trace (used by the distributed
-    executor: a pallas_call has no SPMD partitioning rule, so sharded
-    global-view programs must keep XLA's dense attention or go through
-    shard_map)."""
+    """Refuse a bare pallas_call within this trace (used by the distributed
+    executor: a pallas_call has no SPMD partitioning rule). What admits a
+    kernel to a sharded global-view program is a declared `flash_mesh`,
+    under which the kernel is mapped over the shards; a node lowered with
+    none declared keeps XLA's dense attention."""
     prev = getattr(_tls, "disabled", False)
     _tls.disabled = True
     try:
@@ -1696,6 +1700,41 @@ def sharded_flash_supported(
     if min_seq is None:
         min_seq = _min_seq_default()
     return _flash_shape_ok((b // db, h // dh, s, d), min_seq)
+
+
+def flash_core_supported(q_shape, k_shape, v_shape) -> bool:
+    """The static gate of the trace a kernel would be emitted into: with no
+    mesh declared, flash_attention_supported on the [b, h, s, d] shapes;
+    under a declared `flash_mesh`, sharded_flash_supported on the block
+    each device sees."""
+    ctx = current_flash_mesh()
+    if ctx is None:
+        return flash_attention_supported(q_shape, k_shape, v_shape)
+    mesh, batch_axes, head_axes, interpret = ctx
+    return k_shape == q_shape == v_shape and sharded_flash_supported(
+        q_shape, mesh, batch_axes, head_axes, interpret=interpret
+    )
+
+
+def per_batch_shard(entry, *rows, **kwargs):
+    """entry(*rows, **kwargs) on [b, s, f] fused-row operands, the way the
+    current trace admits it: called as it is with no mesh declared; under a
+    `flash_mesh` whose heads are whole, mapped over the batch shards with
+    the mesh's interpret flag, so that each device runs the one-chip kernel
+    on its own sequences. Attention is independent over the batch: the body
+    needs no collective."""
+    ctx = current_flash_mesh()
+    if ctx is None:
+        return entry(*rows, **kwargs)
+    from jax.sharding import PartitionSpec as P
+
+    from flexflow_tpu.utils.shard_map_compat import shard_map_compat
+
+    mesh, batch_axes, head_axes, interpret = ctx
+    assert head_axes is None, "a fused row has no head dimension to shard"
+    spec = P(batch_axes, None, None)
+    f = functools.partial(entry, interpret=interpret, **kwargs)
+    return shard_map_compat(f, mesh, (spec,) * len(rows), spec)(*rows)
 
 
 def sharded_flash_attention(

@@ -254,76 +254,109 @@ def mha_qk_norm_rope(attrs: MultiHeadAttentionAttrs, qp, kp, qk_gains):
     return qp, kp
 
 
+def mha_core_route(
+    attrs: MultiHeadAttentionAttrs, q_shape, k_shape, v_shape, fused_qkv: bool
+) -> str:
+    """The attention core `_mha_forward` lowers these [b, s, e] operands to
+    in the current trace, from static facts alone (the shapes, the declared
+    `flash_mesh`, the `no_flash` guard, the backend). THE layout rule, for
+    one chip and for a mesh alike:
+
+    - "fused_row_qkv": one projection matmul into the head-pair interleaved
+      row [b, s, 3*h*d] and `flash_attention_bshf_qkv` on it (d=64
+      self-attention, `fused_qkv`: q is k is v);
+    - "fused_row": three plain matmuls into [b, s, h*d] rows and
+      `flash_attention_bshf` (d % 128 == 0, or d=64 with distinct operands
+      or QK-norm / RoPE between projection and core);
+    - "rows": the per-head [b, h, s, d] projections and `flash_attention`
+      (other head sizes; every head-sharded plan, through
+      `sharded_flash_attention`);
+    - "dense": XLA's attention.
+
+    Under a declared mesh the gates read the block each device sees and the
+    fused-row kernels are mapped over the batch shards (`per_batch_shard`);
+    a fused row sharded over heads would need a pair-aligned split, so a
+    head-sharded plan takes "rows"."""
+    import os
+
+    if os.environ.get("FLEXFLOW_TPU_FLASH", "1") == "0":
+        return "dense"
+    from flexflow_tpu.kernels.flash_attention import (
+        bshf_pair_supported,
+        current_flash_mesh,
+        flash_core_supported,
+    )
+
+    H, kd, vd = attrs.num_heads, attrs.q_proj_size, attrs.v_proj_size
+    b, s, t = q_shape[0], q_shape[1], k_shape[1]
+    proj_q = (b, H, s, kd)
+    proj_kv = (b, H, t, kd)
+    mesh_ctx = current_flash_mesh()
+    heads_whole = mesh_ctx is None or mesh_ctx[2] is None
+    # kd % 128: blocks carved from the fused h*d minor dim must be
+    # lane-aligned (Pallas requires block minor dims divisible by 128 unless
+    # equal to the array dim). d=64 (the reference heads=16 config) rides
+    # the HEAD-PAIR bshf kernels — two heads per 128-lane block — so its
+    # projections stay plain matmuls too (the per-head [b,h,s,d] entry pays
+    # ~27 ms/step of transpose copies on the headline shapes). Other head
+    # dims use the batch-folded per-head entry.
+    if (
+        heads_whole
+        and kd == vd
+        and (kd % 128 == 0 or bshf_pair_supported(H, kd, s))
+        and flash_core_supported(proj_q, proj_kv, proj_kv)
+    ):
+        post = attrs.qk_norm or attrs.rope_theta is not None
+        if kd % 128 != 0 and fused_qkv and not post:
+            return "fused_row_qkv"
+        return "fused_row"
+    if flash_core_supported(proj_q, proj_kv, (b, H, v_shape[1], vd)):
+        return "rows"
+    return "dense"
+
+
 def _mha_forward(
     attrs: MultiHeadAttentionAttrs, q, k, v, weight, input_bias=None,
     causal=False, qk_gains=None,
 ):
-    import os
+    from flexflow_tpu.kernels.flash_attention import (
+        current_flash_mesh,
+        flash_attention,
+        flash_attention_bshf,
+        flash_attention_bshf_qkv,
+        per_batch_shard,
+        sharded_flash_attention,
+    )
 
     kd = attrs.q_proj_size
+    H = attrs.num_heads
     # QK-norm and RoPE act on the fused-row projections; a node without
     # them takes the paths it always took
     post = attrs.qk_norm or attrs.rope_theta is not None
-    use_flash = os.environ.get("FLEXFLOW_TPU_FLASH", "1") != "0"
-    if use_flash:
-        from flexflow_tpu.kernels.flash_attention import (
-            current_flash_mesh,
-            flash_attention,
-            flash_attention_bshf,
-            flash_attention_supported,
-            sharded_flash_attention,
-            sharded_flash_supported,
+    route = mha_core_route(attrs, q.shape, k.shape, v.shape, q is k and k is v)
+    if route == "fused_row_qkv":
+        # self-attention on the head-pair path: ONE fused projection matmul
+        # into the interleaved [q_pair|k_pair|v_pair] layout; flash reads
+        # the three operands as views of it and the backward returns one
+        # fused dqkv (saves two projection launches + two input reads + the
+        # gradient combine per layer). The projections stay outside the
+        # shard_map of a mesh: XLA partitions a plain matmul over the batch
+        # by itself and the weight gradients stay ordinary HLO
+        qkv, wo2 = mha_project_qkv_bshf_fused(attrs, q, weight, input_bias)
+        ctx = per_batch_shard(
+            flash_attention_bshf_qkv, qkv, num_heads=H, causal=causal
         )
-
-        if current_flash_mesh() is None:
-            # single-device path: gate on the would-be projected shapes so
-            # the projections can be emitted in the copy-free bshf layout
-            H, vd = attrs.num_heads, attrs.v_proj_size
-            b, s = q.shape[0], q.shape[1]
-            t = k.shape[1]
-            proj_q = (b, H, s, kd)
-            proj_kv = (b, H, t, kd)
-            # kd % 128: blocks carved from the fused h*d minor dim must be
-            # lane-aligned (Pallas requires block minor dims divisible by
-            # 128 unless equal to the array dim). d=64 (the reference
-            # heads=16 config) rides the HEAD-PAIR bshf kernels — two
-            # heads per 128-lane block — so its projections stay plain
-            # matmuls too (the per-head [b,h,s,d] entry pays ~27 ms/step
-            # of transpose copies on the headline shapes). Other head
-            # dims use the batch-folded per-head entry below.
-            from flexflow_tpu.kernels.flash_attention import (
-                bshf_pair_supported,
-            )
-
-            bshf_ok = kd % 128 == 0 or bshf_pair_supported(H, kd, s)
-            if (
-                kd == vd
-                and bshf_ok
-                and flash_attention_supported(proj_q, proj_kv, proj_kv)
-            ):
-                if kd % 128 != 0 and q is k and k is v and not post:
-                    # self-attention on the head-pair path: ONE fused
-                    # projection matmul into the interleaved
-                    # [q_pair|k_pair|v_pair] layout; flash reads the three
-                    # operands as views of it and the backward returns one
-                    # fused dqkv (saves two projection launches + two
-                    # input reads + the gradient combine per layer)
-                    from flexflow_tpu.kernels.flash_attention import (
-                        flash_attention_bshf_qkv,
-                    )
-
-                    qkv, wo2 = mha_project_qkv_bshf_fused(
-                        attrs, q, weight, input_bias
-                    )
-                    ctx = flash_attention_bshf_qkv(qkv, H, causal=causal)
-                    return ctx @ wo2
-                qp, kp, vp, wo2 = mha_project_qkv_bshf(
-                    attrs, q, k, v, weight, input_bias
-                )
-                if post:
-                    qp, kp = mha_qk_norm_rope(attrs, qp, kp, qk_gains)
-                ctx = flash_attention_bshf(qp, kp, vp, H, causal=causal)
-                return ctx @ wo2
+        return ctx @ wo2
+    if route == "fused_row":
+        qp, kp, vp, wo2 = mha_project_qkv_bshf(
+            attrs, q, k, v, weight, input_bias
+        )
+        if post:
+            qp, kp = mha_qk_norm_rope(attrs, qp, kp, qk_gains)
+        ctx = per_batch_shard(
+            flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal
+        )
+        return ctx @ wo2
 
     if post:
         # the same fused-row projections, then split into heads for the
@@ -332,7 +365,7 @@ def _mha_forward(
             attrs, q, k, v, weight, input_bias
         )
         qp, kp = mha_qk_norm_rope(attrs, qp, kp, qk_gains)
-        H, vd = attrs.num_heads, attrs.v_proj_size
+        vd = attrs.v_proj_size
 
         def heads(x, d):
             return jnp.swapaxes(x.reshape(*x.shape[:2], H, d), 1, 2)
@@ -341,23 +374,19 @@ def _mha_forward(
         wo = jnp.transpose(wo2.reshape(H, vd, attrs.embed_dim), (1, 2, 0))
     else:
         qp, kp, vp, wo = mha_project_qkv(attrs, q, k, v, weight, input_bias)
-    if use_flash:
+    if route == "rows":
         mesh_ctx = current_flash_mesh()
-        if mesh_ctx is not None:
-            # SPMD trace (e.g. the data-parallel jit): a bare pallas_call has
-            # no partitioning rule, so flash must go through shard_map
-            mesh, batch_axes, head_axes, interpret = mesh_ctx
-            if kp.shape == qp.shape == vp.shape and sharded_flash_supported(
-                qp.shape, mesh, batch_axes, head_axes, interpret=interpret
-            ):
-                ctx = sharded_flash_attention(
-                    qp, kp, vp, mesh, batch_axes, head_axes,
-                    causal=causal, interpret=interpret,
-                )
-                return jnp.einsum("bhsv,veh->bse", ctx, wo)
-        elif flash_attention_supported(qp.shape, kp.shape, vp.shape):
+        if mesh_ctx is None:
             ctx = flash_attention(qp, kp, vp, causal=causal)
-            return jnp.einsum("bhsv,veh->bse", ctx, wo)
+        else:
+            # SPMD trace: a bare pallas_call has no partitioning rule, so
+            # flash must go through shard_map
+            mesh, batch_axes, head_axes, interpret = mesh_ctx
+            ctx = sharded_flash_attention(
+                qp, kp, vp, mesh, batch_axes, head_axes,
+                causal=causal, interpret=interpret,
+            )
+        return jnp.einsum("bhsv,veh->bse", ctx, wo)
     scores = jnp.einsum("bhsk,bhtk->bhst", qp, kp) / jnp.sqrt(
         jnp.asarray(kd, qp.dtype)
     )

@@ -149,7 +149,6 @@ def seq_parallel_mha_forward(
     w_spec=None,
     input_bias=None,
     output_bias=None,
-    qk_gains=None,
 ):
     """Shared global-view plumbing for the sequence-parallel attention
     schedules (ring ppermute, Ulysses all-to-all).
@@ -159,35 +158,23 @@ def seq_parallel_mha_forward(
     PartitionSpec ([None, head_axes]) — a sharded head dim composes sequence
     parallelism with head (tensor) parallelism: each (seq, head) shard
     attends its local heads and the output projection psums over the head
-    axes. Falls back to the dense kernel when the sequence is not sharded.
+    axes. A node whose sequence is whole is plain (causal) attention: the
+    executor lowers it through the op's own dispatch, not through here.
 
     `shard_fn_factory(attrs, axis_names, sp, head_axes, tp)` returns the
     per-shard body (ring_mha_shard_fn / ulysses_mha_shard_fn).
     """
     from jax.sharding import PartitionSpec as P
 
-    from flexflow_tpu.kernels.ops import _mha_forward
-
     assert (input_bias is None) == (output_bias is None), (
         "MHA bias weights come in (input, output) pairs"
     )
-
-    def dense_fallback():
-        out = _mha_forward(
-            attrs, q, k, v, weight, input_bias, causal=attrs.causal,
-            qk_gains=qk_gains,
-        )
-        return out if output_bias is None else out + output_bias
-
     seq_entry = q_spec[1] if q_spec is not None and len(q_spec) > 1 else None
-    if seq_entry is None:
-        return dense_fallback()
     axis_names = seq_entry if isinstance(seq_entry, tuple) else (seq_entry,)
     sp = 1
     for a in axis_names:
-        sp *= mesh.shape[a]
-    if sp == 1:
-        return dense_fallback()
+        sp *= 1 if a is None else mesh.shape[a]
+    assert sp > 1, f"sequence-parallel schedule over a whole sequence: {q_spec}"
     # RingAttentionAttrs' shape rule refuses such a plan
     assert not attrs.qk_norm and attrs.rope_theta is None, attrs
 
@@ -222,10 +209,9 @@ def seq_parallel_mha_forward(
 
 
 def ring_mha_forward(attrs, q, k, v, weight, mesh, q_spec, w_spec=None,
-                     input_bias=None, output_bias=None, qk_gains=None):
+                     input_bias=None, output_bias=None):
     """Global-view entry for the ppermute ring schedule."""
     return seq_parallel_mha_forward(
         ring_mha_shard_fn, attrs, q, k, v, weight, mesh, q_spec,
         w_spec=w_spec, input_bias=input_bias, output_bias=output_bias,
-        qk_gains=qk_gains,
     )

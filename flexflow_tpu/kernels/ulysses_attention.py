@@ -108,7 +108,6 @@ def ulysses_mha_forward(
     w_spec=None,
     input_bias=None,
     output_bias=None,
-    qk_gains=None,
 ):
     """Global-view entry for the all-to-all schedule (contract identical to
     ring_mha_forward; plumbing shared via seq_parallel_mha_forward)."""
@@ -129,5 +128,4 @@ def ulysses_mha_forward(
     return seq_parallel_mha_forward(
         factory, attrs, q, k, v, weight, mesh, q_spec,
         w_spec=w_spec, input_bias=input_bias, output_bias=output_bias,
-        qk_gains=qk_gains,
     )

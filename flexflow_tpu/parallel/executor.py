@@ -141,28 +141,28 @@ def pcg_forward_interpreter(
 
     from flexflow_tpu.kernels.flash_attention import no_flash
     from flexflow_tpu.kernels.ring_attention import ring_mha_forward
-    from flexflow_tpu.op_attrs.ops.ring_attention import RingAttentionAttrs
 
     def constrain(v, o):
         s = shardings.get(o)
         return v if s is None else jax.lax.with_sharding_constraint(v, s)
 
-    # a pallas_call cannot be SPMD-partitioned: on a multi-device mesh the
-    # dense-attention kernels must stay pure XLA (sharded via constraints)
+    # a pallas_call cannot be SPMD-partitioned: on a multi-device mesh no
+    # op may emit a bare one. An attention node whose plan shards only batch
+    # and/or heads declares its mesh context instead (_try_sharded_flash_mha)
+    # and has its kernels mapped over the shards; any other stays pure XLA
+    # (sharded via constraints)
     multi_device = mesh is not None and mesh.size > 1
     guard = no_flash() if multi_device else contextlib.nullcontext()
     with guard:
         return _interpret(
             pcg, params, inputs, shardings, constrain, train, rng, mesh,
-            ring_mha_forward, RingAttentionAttrs, barrier_nodes,
-            overlap_sites or {},
+            ring_mha_forward, barrier_nodes, overlap_sites or {},
         )
 
 
 def _interpret(
     pcg, params, inputs, shardings, constrain, train, rng, mesh,
-    ring_mha_forward, RingAttentionAttrs, barrier_nodes=frozenset(),
-    overlap_sites=None,
+    ring_mha_forward, barrier_nodes=frozenset(), overlap_sites=None,
 ):
     overlap_sites = overlap_sites or {}
     env: Dict[DataflowOutput, jnp.ndarray] = {}
@@ -185,12 +185,14 @@ def _interpret(
             elif is_parallel_op(attrs):
                 (src,) = pcg.inputs_of(n)
                 env[outs[0]] = constrain(env[src], outs[0])
-            elif isinstance(attrs, RingAttentionAttrs) and mesh is not None:
+            elif _seq_parallel(attrs, pcg.inputs_of(n), shardings, mesh):
                 # explicit sequence-parallel schedule via shard_map (a sharding
                 # constraint alone would make XLA all-gather K/V): ppermute ring
                 # for RingAttentionAttrs, heads-for-sequence all-to-all for the
                 # Ulysses subclass. Both compose with head parallelism
-                # (head-sharded weight) and with qkv/output biases
+                # (head-sharded weight) and with qkv/output biases. With the
+                # sequence whole the node is plain (causal) attention and
+                # takes the lowering below
                 from flexflow_tpu.kernels.ulysses_attention import (
                     UlyssesAttentionAttrs,
                     ulysses_mha_forward,
@@ -213,7 +215,6 @@ def _interpret(
                     w_spec=w_spec,
                     input_bias=weight_vals[1] if attrs.bias else None,
                     output_bias=weight_vals[2] if attrs.bias else None,
-                    qk_gains=weight_vals[-2:] if attrs.qk_norm else None,
                 )
                 env[outs[0]] = constrain(out, outs[0])
             else:
@@ -277,6 +278,17 @@ def _interpret(
                 for o, r in zip(outs, results):
                     env[o] = r
     return env
+
+
+def _seq_parallel(attrs, in_tensors, shardings, mesh) -> bool:
+    """Does this node's plan shard the sequence of a RingAttentionAttrs (or
+    Ulysses) node over more than one device?"""
+    from flexflow_tpu.op_attrs.ops.ring_attention import RingAttentionAttrs
+
+    if mesh is None or not isinstance(attrs, RingAttentionAttrs):
+        return False
+    seq_axes = _entry_names(_spec_entry(shardings.get(in_tensors[0]), 1))
+    return _mesh_axes_size(mesh, seq_axes) > 1
 
 
 def _spec_entry(sharding, i):
@@ -610,41 +622,19 @@ def _try_pinned_reduction(
     return _shard_map(local_fn, mesh, in_specs, out_spec)(*slot_vals)
 
 
-def _try_sharded_flash_mha(attrs, data_vals, weight_vals, in_tensors,
-                           shardings, mesh):
-    """Flash attention under SPMD (SURVEY.md §7 hard-part 4): when the MHA's
-    batch/head sharding is expressible as shard_map specs and the per-device
-    block is flash-eligible, run the Pallas kernel per-shard. Projections and
-    the output matmul stay in GSPMD-land (XLA partitions einsums natively);
-    only the attention core is shard_mapped. Returns the [b, s, e] output or
-    None to fall back to the dense XLA path."""
-    import os
-
+def _attention_shard_axes(attrs, in_tensors, shardings, mesh):
+    """(batch_axes, head_axes), the PartitionSpec entries of an attention
+    node's batch and head dims, when its plan shards nothing else; None when
+    the node is not this lowering's (no multi-device mesh, a sharded
+    sequence or embedding dim, operands that disagree on the batch)."""
     from flexflow_tpu.op_attrs.ops import MultiHeadAttentionAttrs
-    from flexflow_tpu.op_attrs.ops.ring_attention import RingAttentionAttrs
 
     if (
         mesh is None
         or mesh.size <= 1
         or not isinstance(attrs, MultiHeadAttentionAttrs)
-        or isinstance(attrs, RingAttentionAttrs)
-        # QK-norm and RoPE act on the fused-row projections: _mha_forward
-        or attrs.qk_norm
-        or attrs.rope_theta is not None
     ):
         return None
-    if os.environ.get("FLEXFLOW_TPU_FLASH", "1") == "0":
-        return None
-
-    from flexflow_tpu.kernels.flash_attention import (
-        sharded_flash_attention,
-        sharded_flash_supported,
-    )
-    from flexflow_tpu.kernels.ops import mha_project_qkv
-
-    q, k, v = data_vals
-    if not (q.shape == k.shape == v.shape):
-        return None  # flash core is self-attention-shaped only
     # q/k/v [b, s, e]: batch may be dp-sharded; a sharded seq dim is ring
     # attention's job and a sharded embed dim would make projections partial
     q_sh = shardings.get(in_tensors[0])
@@ -654,30 +644,86 @@ def _try_sharded_flash_mha(attrs, data_vals, weight_vals, in_tensors,
             return None
         if _spec_entry(s, 0) != _spec_entry(q_sh, 0):
             return None
-    batch_axes = _spec_entry(q_sh, 0)
     # weight [per_head_params, H]: head-parallel shards dim 1
     head_axes = _spec_entry(shardings.get(in_tensors[3]), 1)
-    from flexflow_tpu.kernels.flash_attention import interpret_default
-
-    interpret = interpret_default()
-    if attrs.v_proj_size != attrs.q_proj_size:
-        return None  # flash core requires uniform head dims
-    b, s_len, _ = q.shape
-    h = attrs.num_heads
-    d = attrs.q_proj_size
-    if not sharded_flash_supported(
-        (b, h, s_len, d), mesh, batch_axes, head_axes, interpret=interpret
+    # QK-norm and RoPE act on the fused row, which has no head dim to shard
+    if head_axes is not None and (
+        attrs.qk_norm or attrs.rope_theta is not None
     ):
         return None
-    input_bias = weight_vals[1] if attrs.bias else None
-    qp, kp, vp, wo = mha_project_qkv(attrs, q, k, v, weight_vals[0], input_bias)
-    ctx = sharded_flash_attention(
-        qp, kp, vp, mesh, batch_axes, head_axes, interpret=interpret
+    return _spec_entry(q_sh, 0), head_axes
+
+
+def _try_sharded_flash_mha(attrs, data_vals, weight_vals, in_tensors,
+                           shardings, mesh):
+    """Attention under SPMD (SURVEY.md §7 hard-part 4): when the node's plan
+    shards only batch and/or heads, declare that to the op's own kernel
+    (`flash_mesh`) and run it: `kernels/ops._mha_forward` then makes the
+    layout choice it makes on one chip, on the block each device sees. A
+    batch-only plan takes the one-chip fused-row dispatch with each Pallas
+    call mapped over the batch shards, a head-sharded plan the [b, h, s, d]
+    rows kernels; a block no kernel serves stays XLA's dense attention. The
+    projections, biases and the output matmul are traced in the global view
+    either way (XLA partitions a plain matmul natively and the weight
+    gradients stay ordinary HLO for the all-reduce combiner); only the
+    attention core is shard_mapped. Returns the [b, s, e] output, or None
+    for a node that is not this lowering's."""
+    axes = _attention_shard_axes(attrs, in_tensors, shardings, mesh)
+    if axes is None:
+        return None
+    from flexflow_tpu.kernels.flash_attention import (
+        flash_mesh,
+        interpret_default,
     )
-    out = jnp.einsum("bhsv,veh->bse", ctx, wo)
-    if attrs.bias:
-        out = out + weight_vals[2]
+
+    with flash_mesh(mesh, *axes, interpret_default()):
+        (out,) = kernel_forward(attrs, data_vals, weight_vals)
     return out
+
+
+def attention_routes(pcg, shardings, mesh) -> Dict[str, str]:
+    """The route `_interpret` lowers each attention node of the plan by,
+    under the node's scope name in the device trace (`ff.mha.<name>`), from
+    the same static facts the lowering reads (so a plan that falls back to
+    dense or to [b, h, s, d] can be read without a trace):
+
+    - "seq_parallel": the ring / all-to-all schedule over a sharded sequence;
+    - "fused_row_sharded": the one-chip fused-row kernels per batch shard;
+    - "rows_sharded": the [b, h, s, d] kernels per batch and head shard;
+    - "dense": XLA's attention, in the global view;
+    - on a single device, `mha_core_route`'s own names."""
+    from flexflow_tpu.kernels.flash_attention import (
+        flash_mesh,
+        interpret_default,
+    )
+    from flexflow_tpu.kernels.ops import mha_core_route
+    from flexflow_tpu.op_attrs.ops import MultiHeadAttentionAttrs
+
+    routes: Dict[str, str] = {}
+    for n in pcg.topological_ordering():
+        attrs = pcg.op_attrs(n)
+        if not isinstance(attrs, MultiHeadAttentionAttrs):
+            continue
+        in_tensors = pcg.inputs_of(n)
+        q, k, v = in_tensors[:3]
+        core_args = (
+            attrs, *(pcg.tensor_shape(t).sizes() for t in (q, k, v)),
+            q == k == v,
+        )
+        if _seq_parallel(attrs, in_tensors, shardings, mesh):
+            route = "seq_parallel"
+        elif mesh is None or mesh.size <= 1:
+            route = mha_core_route(*core_args)
+        else:
+            route = "dense"
+            axes = _attention_shard_axes(attrs, in_tensors, shardings, mesh)
+            if axes is not None:
+                with flash_mesh(mesh, *axes, interpret_default()):
+                    route = mha_core_route(*core_args)
+            if route != "dense":
+                route = route.replace("_qkv", "") + "_sharded"
+        routes[trace.scope_name(pcg, n)] = route
+    return routes
 
 
 def _try_sharded_experts(attrs, slot_vals, in_tensors, shardings, mesh):
@@ -810,6 +856,10 @@ class DistributedTrainingInstance:
             collect_overlap_sites(pcg, self.shardings, machine_mesh.mesh)
             if overlap_lowering_active(overlap)
             else {}
+        )
+        # how each attention node will be lowered (attention_routes)
+        self.attention_routes = attention_routes(
+            pcg, self.shardings, machine_mesh.mesh
         )
         # (params, opt_state) shardings, recorded by initialize(): the
         # step programs hand the new state back under exactly these

@@ -139,10 +139,200 @@ class TestShardedFlash:
             (4, 2, 256, 64), mesh2, "dp", "tp", min_seq=128, interpret=True
         )
 
+    # one attention node under the distributed executor, per plan: the
+    # kernel entry it must reach (None: XLA's dense attention) and the route
+    # the instance's counter names. heads x d = 128 = embed_dim throughout.
+    # id: (attention class kwargs, heads, bias, distinct operands, seq,
+    #      batch degree, head degree, entry, route)
+    EXECUTOR_CASES = {
+        # (a) BERT's node: biased d=64 self-attention, batch-sharded
+        "biased_d64_self": (
+            {}, 2, True, False, 128, 2, 1,
+            "flash_attention_bshf_qkv", "fused_row_sharded"),
+        # (b) distinct operands and d=128 take the three-matmul fused row
+        "d64_three_operands": (
+            {}, 2, True, True, 128, 2, 1,
+            "flash_attention_bshf", "fused_row_sharded"),
+        "d128_self": (
+            {}, 1, False, False, 128, 2, 1,
+            "flash_attention_bshf", "fused_row_sharded"),
+        # (c) a head-sharded plan keeps the [b, h, s, d] rows kernels
+        "head_sharded": (
+            {}, 2, False, False, 128, 1, 2,
+            "sharded_flash_attention", "rows_sharded"),
+        "batch_and_head_sharded": (
+            {}, 2, False, False, 128, 2, 2,
+            "sharded_flash_attention", "rows_sharded"),
+        # (d) causal RingAttentionAttrs, sequence whole, batch-only mesh
+        "causal_ring_seq_whole": (
+            {"causal": True}, 2, False, False, 128, 2, 1,
+            "flash_attention_bshf_qkv", "fused_row_sharded"),
+        # (f) a local sequence under the threshold stays dense
+        "short_seq": (
+            {}, 2, True, False, 64, 2, 1, None, "dense"),
+    }
+
+    @pytest.mark.parametrize("case", sorted(EXECUTOR_CASES))
+    def test_executor_attention_lowering(self, case, monkeypatch):
+        """The searched executor lowers an attention node through the op's
+        own dispatch (`_mha_forward`) under the node's declared mesh: the
+        entry one chip would take, per shard, with the output and every
+        gradient equal to the single-device op's."""
+        import flexflow_tpu.kernels.flash_attention as fa
+        from flexflow_tpu.kernels.ops import _mha_forward
+        from flexflow_tpu.op_attrs.ops import (
+            MultiHeadAttentionAttrs,
+            RingAttentionAttrs,
+        )
+        from flexflow_tpu.parallel import MachineMesh, pcg_shardings
+        from flexflow_tpu.parallel.executor import (
+            attention_routes,
+            param_key,
+            pcg_forward_interpreter,
+        )
+        from flexflow_tpu.pcg.parallel_computation_graph_builder import (
+            ParallelComputationGraphBuilder,
+        )
+        from test_parallel_lowering import pts
+
+        (kw, heads, bias, distinct, seq, dp, tp, entry, route) = (
+            self.EXECUTOR_CASES[case]
+        )
+        if len(jax.devices()) < dp * tp:
+            pytest.skip("needs multi-device")
+        monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+        monkeypatch.setenv("FLEXFLOW_TPU_FLASH_MIN_SEQ", "128")
+        calls = []
+        for name in (
+            "flash_attention_bshf", "flash_attention_bshf_qkv",
+            "sharded_flash_attention",
+        ):
+            def spy(*a, _name=name, _orig=getattr(fa, name), **k):
+                calls.append(_name)
+                return _orig(*a, **k)
+
+            monkeypatch.setattr(fa, name, spy)
+
+        e, batch = 128, 4
+        cls = RingAttentionAttrs if kw else MultiHeadAttentionAttrs
+        attrs = cls(e, heads, kdim=e // heads, vdim=e // heads, bias=bias, **kw)
+        b = ParallelComputationGraphBuilder()
+        names = ["q", "k", "v"] if distinct else ["x"]
+        ins = [
+            b.create_input_tensor(pts([batch, seq, e], [dp, 1, 1]), name=n)
+            for n in names
+        ]
+        ops = [b.parallel_replicate(t, tp) if tp > 1 else t for t in ins]
+        (out,) = b.add_layer(attrs, ops if distinct else ops * 3, [], "attn")
+        if tp > 1:
+            out = b.parallel_reduce(out, tp)
+        pcg = b.graph
+        mm = MachineMesh.for_devices(dp * tp)
+        shardings = pcg_shardings(pcg, mm)
+        scope = "ff.ring_attention.attn" if kw else "ff.mha.attn"
+        assert attention_routes(pcg, shardings, mm.mesh) == {scope: route}
+
+        rs = np.random.RandomState(5)
+        attn = next(
+            n for n in pcg.topological_ordering()
+            if pcg.layer_attrs(n).name == "attn"
+        )
+        weights = [t.node for t in pcg.inputs_of(attn)[3:]]
+        params = {
+            param_key(n): jnp.asarray(
+                0.1 * rs.randn(*pcg.tensor_shape(pcg.outputs_of(n)[0]).sizes()),
+                jnp.float32,
+            )
+            for n in weights
+        }
+        inputs = {
+            n: jnp.asarray(rs.randn(batch, seq, e), jnp.float32) for n in names
+        }
+
+        def lowered(params, inputs):
+            env = pcg_forward_interpreter(
+                pcg, params, inputs, shardings, mesh=mm.mesh
+            )
+            return env[out]
+
+        def single(params, inputs):
+            q, k, v = (inputs[n] for n in (names if distinct else names * 3))
+            w = [params[param_key(n)] for n in weights]
+            res = _mha_forward(
+                attrs, q, k, v, w[0], w[1] if bias else None,
+                causal=kw.get("causal", False),
+            )
+            return res + w[2] if bias else res
+
+        def with_grads(f):
+            loss = lambda p, i: jnp.sum(f(p, i) ** 2)
+            return jax.jit(
+                lambda p, i: (f(p, i), jax.grad(loss, argnums=(0, 1))(p, i))
+            )
+
+        got = with_grads(lowered)(params, inputs)
+        assert sorted(set(calls)) == ([entry] if entry else [])
+        calls.clear()
+        want = with_grads(single)(params, inputs)
+        assert calls == []  # the reference is XLA's dense attention
+        np.testing.assert_allclose(
+            np.asarray(got[0]), np.asarray(want[0]), atol=1e-5
+        )
+        for a, w in zip(
+            jax.tree_util.tree_leaves(got[1]),
+            jax.tree_util.tree_leaves(want[1]),
+        ):
+            # the bias gradients sum over every position: up to ~1e3 here
+            np.testing.assert_allclose(
+                np.asarray(a), np.asarray(w), atol=2e-4, rtol=1e-5
+            )
+
+    def test_data_parallel_backend_takes_the_fused_row(self, monkeypatch):
+        """(e) DataParallelTrainingInstance declares a batch-only mesh, so
+        its attention takes the same per-shard fused-row dispatch."""
+        import flexflow_tpu.kernels.flash_attention as fa
+        from flexflow_tpu.core import FFConfig, FFModel, SGDOptimizer
+        from flexflow_tpu.parallel.data_parallel import (
+            DataParallelTrainingInstance,
+        )
+
+        if len(jax.devices()) < 2:
+            pytest.skip("needs multi-device")
+        monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+        monkeypatch.setenv("FLEXFLOW_TPU_FLASH_MIN_SEQ", "128")
+        calls = []
+        orig = fa.flash_attention_bshf_qkv
+
+        def spy(*a, **kw):
+            calls.append(kw.get("interpret"))
+            return orig(*a, **kw)
+
+        monkeypatch.setattr(fa, "flash_attention_bshf_qkv", spy)
+        monkeypatch.setattr(
+            fa, "sharded_flash_attention",
+            lambda *a, **kw: pytest.fail("took the [b, h, s, d] layout"),
+        )
+        cfg = FFConfig(
+            batch_size=4, epochs=1, seed=0, max_devices=2,
+            only_data_parallel=True,
+        )
+        m = FFModel(cfg)
+        x = m.create_tensor([4, 128, 128], name="x")
+        t = m.multihead_attention(x, x, x, 128, 2)
+        t = m.dense(t, 8, use_bias=False)
+        m.compile(SGDOptimizer(lr=0.1), "sparse_categorical_crossentropy")
+        assert isinstance(m.instance, DataParallelTrainingInstance)
+        rs = np.random.RandomState(0)
+        xs = rs.randn(4, 128, 128).astype(np.float32)
+        ys = rs.randint(0, 8, (4, 128))
+        m.fit(xs, ys, epochs=1, verbose=False)
+        assert calls and all(calls), calls
+
     def test_distributed_executor_uses_sharded_flash(self, monkeypatch):
         """End-to-end: a DP-sharded transformer train step through the
         distributed executor hits the shard_mapped Pallas kernel (the
-        round-1 no_flash guard disabled it everywhere multi-device)."""
+        round-1 no_flash guard disabled it everywhere multi-device). Heads
+        of 8 lanes are no fused row: the plan takes the [b, h, s, d] entry."""
         import flexflow_tpu.kernels.flash_attention as fa
         from flexflow_tpu.core import FFConfig, FFModel, SGDOptimizer
 

@@ -5,6 +5,8 @@ The TPU-native analogue of the reference's (absent) fake-cluster tests
 equivalence of the distributed executor against an unconstrained run.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -412,3 +414,49 @@ def test_biased_attention_data_parallel_step_on_four_devices(monkeypatch):
             np.asarray(one.params[keys1[name]]),
             atol=1e-5, rtol=0, err_msg=name,
         )
+
+
+@pytest.mark.parametrize(
+    "seed,seq,route,kernel",
+    [
+        ("dp2xtp1xsp1", 128, "fused_row_sharded", "flash_fwd_pair_qkv"),
+        ("dp1xtp2xsp1", 128, "rows_sharded", "flash_fwd_rows_folded"),
+        ("dp2xtp1xsp1", 64, "dense", None),
+        ("dp1xtp1xsp2-ring", 128, "seq_parallel", None),
+    ],
+)
+def test_attention_routes_after_compile(monkeypatch, seed, seq, route, kernel):
+    """The instance names, per attention node, the route the executor will
+    lower it by, readable after compile() with nothing traced; the compiled
+    step holds that route's kernel and no other."""
+    from flexflow_tpu.analysis.lowering import lower_step_trace
+    from flexflow_tpu.core import FFConfig, FFModel, SGDOptimizer
+
+    from test_seed_templates import bert_like_graph
+
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_MIN_SEQ", "128")
+    # two heads of 64: one head pair, so a batch shard can take the fused row
+    graph, logits = bert_like_graph(
+        batch=4, seq=seq, hidden=128, heads=2, blocks=2, bias=False
+    )
+    model = FFModel.from_computation_graph(
+        graph, logits,
+        FFConfig(
+            batch_size=4, seed=0, print_freq=0, max_devices=2,
+            search_budget=2, force_strategy_seed=seed,
+        ),
+    )
+    model.compile(SGDOptimizer(lr=0.1), "sparse_categorical_crossentropy")
+    assert isinstance(model.instance, DistributedTrainingInstance)
+    # the sequence-parallel template rewrites the op; scopes carry the kind
+    kind = "ring_attention" if route == "seq_parallel" else "mha"
+    assert model.instance.attention_routes == {
+        f"ff.{kind}.attn{i}": route for i in range(2)
+    }
+    text = lower_step_trace(
+        model.instance, model.loss_attrs,
+        params=model.params, opt_state=model.opt_state,
+    ).compile().as_text()
+    found = set(re.findall(r"flash_fwd_\w+", text))
+    assert found == ({kernel} if kernel else set()), found

@@ -63,12 +63,14 @@ def test_ring_gradients_match_dense():
     np.testing.assert_allclose(np.asarray(gr_w), np.asarray(gd_w), atol=5e-4)
 
 
-def test_ring_unsharded_seq_falls_back():
+def test_ring_refuses_an_unsharded_seq():
+    """The schedule is for a sharded sequence; a whole one is plain
+    attention, which the executor lowers through the op's own dispatch
+    (tests/test_flash_attention.py: causal_ring_seq_whole)."""
     attrs, q, w = make_inputs()
     mm = MachineMesh.for_devices(8)
-    out = ring_mha_forward(attrs, q, q, q, w, mm.mesh, None)
-    dense = _mha_forward(attrs, q, q, q, w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=1e-6)
+    with pytest.raises(AssertionError, match="whole sequence"):
+        ring_mha_forward(attrs, q, q, q, w, mm.mesh, None)
 
 
 def test_parallel_shape_inference_seq_sharded():

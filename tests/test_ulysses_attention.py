@@ -81,12 +81,11 @@ def test_ulysses_with_head_parallel_and_bias():
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=2e-5)
 
 
-def test_ulysses_unsharded_seq_falls_back():
+def test_ulysses_refuses_an_unsharded_seq():
     attrs, q, w = make_inputs()
     mm = MachineMesh.for_devices(8)
-    out = ulysses_mha_forward(attrs, q, q, q, w, mm.mesh, None)
-    dense = _mha_forward(attrs, q, q, q, w)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(dense), atol=1e-5)
+    with pytest.raises(AssertionError, match="whole sequence"):
+        ulysses_mha_forward(attrs, q, q, q, w, mm.mesh, None)
 
 
 def test_a2a_rule_applies_and_head_divisibility_gates():

@@ -10,8 +10,11 @@ XLA attention the same op lowers to under FLEXFLOW_TPU_FLASH=0:
 - `pair_qkv_d64`       16 heads of 64, self-attention: head-pair kernels
                        fed by the fused QKV projection
 - `pair_d64`           16 heads of 64, distinct q/k/v operands
-- `per_head_shard_map` the [b, h, s, d] entry under shard_map that the
-                       data-parallel and searched backends use
+- `pair_qkv_shard_map` the head-pair kernels mapped over batch shards: what
+                       the data-parallel backend and a batch-only searched
+                       plan lower to
+- `per_head_shard_map` the [b, h, s, d] entry under shard_map that a
+                       head-sharded searched plan uses
 
 With four or more devices it then trains the seq-2048 flagship (2 layers,
 batch 16) through `FFModel` under the two sequence-parallel templates whose
@@ -42,14 +45,17 @@ CASES = {
     "bshf_d128_s2048": dict(batch=2, seq=2048, heads=8, same_qkv=True),
     "pair_qkv_d64": dict(batch=8, seq=512, heads=16, same_qkv=True),
     "pair_d64": dict(batch=8, seq=512, heads=16, same_qkv=False),
+    "pair_qkv_shard_map": dict(
+        batch=8, seq=512, heads=16, same_qkv=True, mesh="batch"
+    ),
     "per_head_shard_map": dict(
-        batch=8, seq=512, heads=8, same_qkv=True, mesh=True
+        batch=8, seq=512, heads=8, same_qkv=True, mesh="heads"
     ),
 }
 SP_SHAPES = dict(batch=16, seq=2048, embed=EMBED, heads=8, layers=2, vocab=32000)
 
 
-def run_case(name, batch, seq, heads, same_qkv, mesh=False):
+def run_case(name, batch, seq, heads, same_qkv, mesh=None):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -70,19 +76,21 @@ def run_case(name, batch, seq, heads, same_qkv, mesh=False):
     cot = jnp.asarray(rs.randn(batch, seq, EMBED), jnp.float32)
     devices = jax.devices()
     if mesh:
-        m = Mesh(np.array(devices), ("data",))
-        if batch % len(devices):
-            raise ValueError(f"{name}: batch {batch} over {len(devices)}")
+        # every device on the one sharded dim: the batch or the heads
+        shape = (-1, 1) if mesh == "batch" else (1, -1)
+        m = Mesh(np.array(devices).reshape(shape), ("data", "heads"))
+        if (batch if mesh == "batch" else heads) % len(devices):
+            raise ValueError(f"{name}: {mesh} over {len(devices)} devices")
         x = jax.device_put(x, NamedSharding(m, P("data")))
         cot = jax.device_put(cot, NamedSharding(m, P("data")))
-        w = jax.device_put(w, NamedSharding(m, P()))
+        w = jax.device_put(w, NamedSharding(m, P(None, "heads")))
 
     def loss(x, w):
         # `x + 0` gives distinct operands: _mha_forward takes the fused-QKV
         # path only when q, k and v are one array
         q, k, v = (x, x, x) if same_qkv else (x, x + 0, x + 0)
         if mesh:
-            with flash_mesh(m, "data", None):
+            with flash_mesh(m, "data", "heads" if mesh == "heads" else None):
                 (out,) = forward(attrs, [q, k, v], [w])
         else:
             (out,) = forward(attrs, [q, k, v], [w])
