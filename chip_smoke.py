@@ -1,8 +1,9 @@
 """Proof that the training main path starts on the chip.
 
-One process drives the 12-layer d=1024 flagship (`bench.build_flagship_cg`:
-8 heads of 128, seq 512, batch 64, vocab 32,000, bf16 compute, Adam, random
-weights and data from seed 0) through the entry points a user calls —
+One process drives the 12-layer d=1024 flagship
+(`models.flagship.build_flagship_cg`: 8 heads of 128, seq 512, batch 64,
+vocab 32,000, bf16 compute, Adam, random weights and data from seed 0)
+through the entry points a user calls —
 `FFModel.from_computation_graph` -> `compile` -> `fit` — once per backend the
 attached host can run:
 
@@ -120,7 +121,10 @@ def run_phase(name, backend, ndev, cfg_kwargs, shapes, x, y, cache, on_tpu):
     import jax
     import jax.numpy as jnp
 
-    from bench import _model_step_flops, build_flagship_cg
+    from flexflow_tpu.models.flagship import (
+        build_flagship_cg,
+        flagship_step_flops as _model_step_flops,
+    )
     from flexflow_tpu.analysis.comm_analysis import extract_collectives
     from flexflow_tpu.analysis.lowering import lower_step_trace
     from flexflow_tpu.compiler.machine_constants import machine_constants

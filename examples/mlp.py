@@ -164,36 +164,6 @@ def main():
     if monitor is not None and monitor.nonfinite_steps:
         print(f"run-health summary: {monitor.summary()}")
 
-    # --roofline: per-op cost attribution of the measured step against the
-    # machine's calibrated constants (observability/roofline.py)
-    if cfg.roofline:
-        import json
-
-        from flexflow_tpu.compiler.calibration import calibrate
-        from flexflow_tpu.observability import (
-            attribute_costs,
-            measure_per_op_ms,
-            roofline_report,
-        )
-
-        per_op = measure_per_op_ms(cg, {"x": x}, logits, seed=cfg.seed)
-        att = attribute_costs(
-            cg, elapsed / args.steps * 1000.0, per_op_ms=per_op
-        )
-        cal = calibrate(devices=jax.devices()[:1])
-        extra = {"subject": "mlp", "backend": jax.default_backend()}
-        if cfg.profile_trace_dir:
-            # the measured loop ran under tracing (per-step device_sync
-            # readbacks serialize dispatch): mark the block so its step_ms
-            # reads as phase-comparison, not a headline number
-            extra["trace_file"] = os.path.join(
-                cfg.profile_trace_dir, "flexflow_trace.json"
-            )
-        block = roofline_report(
-            att, cal.peak_flops, cal.hbm_gbps, extra=extra
-        )
-        print(json.dumps({"roofline": block}))
-
 
 if __name__ == "__main__":
     main()

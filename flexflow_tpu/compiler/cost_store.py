@@ -199,8 +199,8 @@ class CostStore:
         # the persisted entries. Either a float (uniform) or a dict of
         # op_class -> factor with "*" as the default class. Set/cleared by
         # the drift repricer around one graph_optimize call; FF_TPU_COST_SCALE
-        # seeds it at construction (the bench's cold-search-under-perturbed-
-        # costs hook).
+        # seeds it at construction (a cold search under perturbed
+        # costs).
         self.live_scale: Optional[object] = None
         env_scale = os.environ.get("FF_TPU_COST_SCALE", "")
         if env_scale:

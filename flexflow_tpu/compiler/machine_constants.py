@@ -2,7 +2,7 @@
 
 The one table the analytic search (`FFModel._compile_searched`), the
 serving planner (`serving/plan.py`) and the benchmark's utilization
-figures (`bench.py`, `chip_smoke.py`) read. A device kind that is not in
+figures (`chip_smoke.py`) read. A device kind that is not in
 the table raises: pricing an unknown chip with another chip's peaks gives
 plans and utilizations that look plausible and mean nothing.
 """

@@ -27,7 +27,6 @@ import os
 from typing import Dict, List, Tuple
 
 from flexflow_tpu.compiler.machine_mapping.problem_tree import (
-    BASELINE_MODE,
     MMProblemTreeParallelSplit,
     MMProblemTreeSeriesSplit,
     UnmappedOpCostEstimateKey,
@@ -182,9 +181,8 @@ def try_native_dp(cache, context, tree, resources):
     """Solve the root-level DP natively; returns a MachineMappingResult
     (possibly INFEASIBLE, i.e. None) or NATIVE_MISS when the native path is
     unavailable/ineligible and the Python DP must run instead."""
-    # FF_TPU_NO_NATIVE is read per call (tests toggle it in-process);
-    # BASELINE_MODE is import-time everywhere by design (see problem_tree)
-    if os.environ.get("FF_TPU_NO_NATIVE") or BASELINE_MODE:
+    # FF_TPU_NO_NATIVE is read per call (tests toggle it in-process)
+    if os.environ.get("FF_TPU_NO_NATIVE"):
         return NATIVE_MISS
     from flexflow_tpu import native_lib
 

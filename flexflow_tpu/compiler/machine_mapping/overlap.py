@@ -22,7 +22,7 @@ runtime will perform serially: a Combine over a non-contraction dim whose
 sole boundary consumer is a dense leaf taking the moved tensor as its
 FIRST data input ("ag_matmul"), or a bias-free activation-free Linear's
 partial-sum output consumed by its matching Reduction ("matmul_rs"). The
-adjacent dense op is roofline-classified (observability/roofline.py)
+adjacent dense op is roofline-classified (`classify_op`)
 against the estimator's machine constants — a "dispatch"-class op has no
 roofline time to hide a collective behind, so its edges stay serial;
 "mxu"/"bandwidth" ops seed an overlapped entry and the DP arithmetic
@@ -57,6 +57,49 @@ _DENSE_OP_NAMES = (
     "BatchMatmulAttrs",
     "MultiHeadAttentionAttrs",
 )
+
+# fwd+bwd+update over forward-only analytic counts (same 3x the analytic
+# cost model uses)
+TRAIN_FLOPS_FACTOR = 3.0
+# fwd reads+writes, bwd roughly doubles the traffic
+TRAIN_BYTES_FACTOR = 2.0
+
+
+def classify_op(
+    flops: float,
+    nbytes: float,
+    measured_ms: float,
+    peak_flops: float,
+    hbm_gbps: float,
+    *,
+    train_flops_factor: float = TRAIN_FLOPS_FACTOR,
+    train_bytes_factor: float = TRAIN_BYTES_FACTOR,
+    efficiency_floor: float = 0.2,
+    latency_floor_ms: float = 1e-4,
+) -> str:
+    """"mxu" | "bandwidth" | "dispatch" for one op, given its flops, bytes
+    and milliseconds and the machine's peak_flops (FLOP/s) and hbm_gbps:
+
+    - compute_ms = train_factor * flops / peak_flops      (the MXU roofline)
+    - memory_ms  = traffic_factor * bytes / hbm bandwidth (the HBM roofline)
+    - "mxu"       when the compute roofline dominates and the op runs within
+      `efficiency_floor` of it;
+    - "bandwidth" when the memory roofline dominates likewise;
+    - "dispatch"  when the time is more than 1/efficiency_floor above BOTH
+      rooflines (or below the latency floor): the op's milliseconds are
+      overhead (kernel launch, layout change, fusion boundary), not an
+      arithmetic or bandwidth ceiling.
+    """
+    compute_ms = train_flops_factor * flops / max(peak_flops, 1e-9) * 1e3
+    memory_ms = train_bytes_factor * nbytes / max(hbm_gbps * 1e6, 1e-9)
+    ceiling_ms = max(compute_ms, memory_ms)
+    if measured_ms <= latency_floor_ms or ceiling_ms <= 0:
+        return "dispatch"
+    if measured_ms > ceiling_ms / efficiency_floor:
+        # even the binding roofline explains < efficiency_floor of the time
+        return "dispatch"
+    return "mxu" if compute_ms >= memory_ms else "bandwidth"
+
 
 
 @dataclass(frozen=True)
@@ -101,11 +144,6 @@ def leaf_roofline_class(
     from flexflow_tpu.kernels.ops import op_forward_flops
     from flexflow_tpu.local_execution.training_backing import (
         split_slot_values,
-    )
-    from flexflow_tpu.observability.roofline import (
-        TRAIN_BYTES_FACTOR,
-        TRAIN_FLOPS_FACTOR,
-        classify_op,
     )
     from flexflow_tpu.op_attrs.core import get_output_shapes
     from flexflow_tpu.op_attrs.parallel_tensor_shape import get_piece_shape

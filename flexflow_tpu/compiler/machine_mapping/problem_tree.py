@@ -12,7 +12,6 @@ Conventions (equivalent to the reference's BinaryTreePath plumbing):
 
 from __future__ import annotations
 
-import os
 from dataclasses import dataclass
 from typing import Callable, Dict, FrozenSet, List, Optional, Tuple, Union
 
@@ -161,13 +160,6 @@ MachineMappingProblemTree = Union[
 
 _INTERN: Dict[object, object] = {}
 _LEAF_COUNTS: Dict[object, int] = {}
-
-# FF_TPU_SEARCH_BASELINE (the perf-regression test's pre-overhaul mode) is
-# read ONCE at import across every module that honors it — set it before
-# the process starts (the slow test uses subprocesses). A per-call read
-# here with import-time reads in the match-layer memos would let an
-# in-process toggle produce a silently partial baseline.
-BASELINE_MODE = "FF_TPU_SEARCH_BASELINE" in os.environ
 
 
 def intern_problem_tree_node(node):
@@ -531,13 +523,8 @@ def get_machine_mapping_problem_tree(
                 entry[3].add((dst_path[i + 1:], d_shape))
 
     # hash-consing: interned nodes make cross-candidate cache keys O(1) to
-    # hash and compare (see intern_problem_tree_node); BASELINE_MODE exists
-    # so the perf regression test can measure the pre-overhaul behavior
-    if BASELINE_MODE:
-        def intern(node):
-            return node
-    else:
-        intern = intern_problem_tree_node
+    # hash and compare (see intern_problem_tree_node)
+    intern = intern_problem_tree_node
 
     def movement_at(prefix: BinaryTreePath) -> AbstractedTensorSetMovement:
         by_value = by_split.get(prefix)

@@ -764,9 +764,9 @@ class FFModel:
             # movement measurements. Each check runs whenever ITS record
             # exists (an imported strategy carries comm predictions but
             # no memory verification), and a failure lands on the record
-            # it belongs to — never silently absent. The ratios are the
-            # calibration claims the README quotes (cross-checked by
-            # tools/check_artifact_claims.py).
+            # it belongs to — never silently absent. The ratios are what
+            # tools/memory_audit.py and tools/comm_audit.py record
+            # (MEM_r11.json, COMM_r12.json).
             lowered = None
             try:
                 lowered = self._lower_step_program()
@@ -1175,8 +1175,8 @@ class FFModel:
 
     def _forced_seed_result(self, pcg0, ctx, spec, seed_name: str):
         """Lower the named strategy template verbatim (force_strategy_seed):
-        the bench_ab calibration harness measures each template's REAL step
-        time against the cost model's ranking."""
+        lets a caller measure each template's REAL step time against the
+        cost model's ranking."""
         from flexflow_tpu.compiler.machine_mapping.get_optimal_machine_mapping import (
             MachineMappingCache,
         )

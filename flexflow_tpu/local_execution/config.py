@@ -33,11 +33,6 @@ class FFConfig:
     # xprof/tensorboard (the Legion Prof `-lg:prof` analogue, SURVEY §5)
     profiling: bool = False
     profile_trace_dir: str = ""
-    # roofline=True asks bench/example entrypoints (bench.py --roofline,
-    # examples/mlp.py) to emit the observability roofline block: per-op
-    # {flops, bytes, measured_ms, bound} + whole-step MFU
-    # (observability/roofline.py)
-    roofline: bool = False
     # run-health telemetry (observability/metrics.py): when set, fit()
     # appends one JSON event per step (loss, wallclock ms, tokens/s,
     # grad/param global norms, update-to-param ratio, skipped/nonfinite
@@ -69,8 +64,7 @@ class FFConfig:
     # RNG stream position, dataloader epoch + cursor) every
     # checkpoint_every_n_steps, written by a background thread overlapped
     # with the next dispatch window (checkpoint_sync=True forces the
-    # blocking save path — the A/B baseline bench.py --chaos measures
-    # against). fit(resume=True) restores the latest snapshot for a
+    # blocking save path). fit(resume=True) restores the latest snapshot for a
     # bitwise-identical continuation (chaos-tested via FF_TPU_FAULT_STEP).
     checkpoint_dir: str = ""
     checkpoint_every_n_steps: int = 0
@@ -236,9 +230,8 @@ class FFConfig:
     # feeds its per-op measured ms into the same store. Empty = off.
     cost_store: str = ""
     # benchmarking/calibration: skip the search and lower the named strategy
-    # template verbatim ("dp8xtp1xsp1", "dp1xtp1xsp8-a2a", "dp2xep4", ...);
-    # bench_ab uses this to measure every seed's REAL step time against the
-    # cost model's ranking
+    # template verbatim ("dp8xtp1xsp1", "dp1xtp1xsp8-a2a", "dp2xep4", ...),
+    # to measure a seed's REAL step time against the cost model's ranking
     force_strategy_seed: str = ""
     # seed
     seed: int = 0
@@ -255,12 +248,6 @@ class FFConfig:
         p.add_argument("--nodes", type=int, default=1)
         p.add_argument("--profiling", action="store_true")
         p.add_argument("--profile-trace-dir", type=str, default="")
-        p.add_argument(
-            "--roofline",
-            action="store_true",
-            help="emit the per-op roofline attribution block "
-            "(observability/roofline.py)",
-        )
         p.add_argument(
             "--metrics-dir",
             type=str,
@@ -499,7 +486,6 @@ class FFConfig:
             num_nodes=args.nodes,
             profiling=args.profiling,
             profile_trace_dir=args.profile_trace_dir,
-            roofline=getattr(args, "roofline", False),
             metrics_dir=getattr(args, "metrics_dir", ""),
             health_policy=getattr(args, "health_policy", "off"),
             plan_audit=getattr(args, "plan_audit", False),

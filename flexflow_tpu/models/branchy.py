@@ -5,8 +5,8 @@ examples/cpp/split_test/split_test.cc topology family).
 Uniform dp/tp/sp strategy templates cannot shard the branch-stacked
 subgraph at all — only the best-first rule walk's branch_parallel_* rules
 can — so this is the regime where the SEARCH must beat every seed. One
-builder, three consumers: the driver dryrun (__graft_entry__), the A/B
-bench (bench_ab.py) and the CPU pin (tests/test_branch_stacking.py).
+builder, two consumers: the driver dryrun (__graft_entry__) and the CPU
+pin (tests/test_branch_stacking.py).
 """
 
 from __future__ import annotations

@@ -1,20 +1,11 @@
-"""Observability: structured step tracing, per-op cost attribution, and
-roofline reports.
-
-The evidence layer under every performance claim in this repo. Three parts:
+"""Observability: what the program records about itself while it compiles
+and runs. Speeds are the benchmark's (`benchmark/`), read from the device
+trace through the scopes `trace` puts on every operation.
 
 - `trace`       -- span/event recorder with host-readback sync boundaries
                    (kernels/profiling.force_sync discipline), emitting
                    Chrome-trace JSON next to the XLA trace in
                    `--profile-trace-dir`.
-- `cost_attribution` -- per-op flops/bytes (XLA `cost_analysis()` program
-                   totals distributed over the graph's analytic op costs,
-                   with a pure-analytic fallback when the backend exposes no
-                   cost analysis) joined with measured per-op milliseconds.
-- `roofline`    -- classify each op MXU-bound / bandwidth-bound /
-                   dispatch-bound against measured machine constants
-                   (compiler/calibration.py) and report per-op and
-                   whole-step MFU.
 - `search_phases` -- compile-time twin of `trace`: per-phase wall-clock
                    attribution of the Unity search (tree_build / dp /
                    leaf_cost / match), reported as `phase_ms` in search
@@ -38,18 +29,6 @@ from flexflow_tpu.observability.trace import (
     record_span,
     set_recorder,
     trace_session,
-)
-from flexflow_tpu.observability.cost_attribution import (
-    OpCost,
-    StepAttribution,
-    analytic_op_costs,
-    attribute_costs,
-    measure_per_op_ms,
-    step_cost_analysis,
-)
-from flexflow_tpu.observability.roofline import (
-    classify_op,
-    roofline_report,
 )
 from flexflow_tpu.observability.search_phases import (
     collect_search_phases,
@@ -85,14 +64,6 @@ __all__ = [
     "record_span",
     "set_recorder",
     "trace_session",
-    "OpCost",
-    "StepAttribution",
-    "analytic_op_costs",
-    "attribute_costs",
-    "measure_per_op_ms",
-    "step_cost_analysis",
-    "classify_op",
-    "roofline_report",
     "collect_search_phases",
     "search_phase",
     "EVENT_SCHEMA_VERSION",

@@ -22,11 +22,11 @@ Output: per-entry misprediction ratios (measured / predicted) plus a
 summary (geometric-mean ratio per class and combined, worst-N ops by
 log-distance from 1.0). A geomean of 1.0 means the model is calibrated in
 aggregate; a worst-op ratio of 6x names the specific kernel or edge whose
-model term is wrong — which turns the single scalar calibration drift the
-round-5 artifacts carry (0.91) into an attributable work list.
+model term is wrong — which turns a single scalar calibration drift into
+an attributable work list.
 
 Recorded in `FFModel.search_provenance["plan_audit"]` (opt-in:
-`--plan-audit`) and emitted by `bench.py --plan-audit`.
+`--plan-audit`).
 """
 
 from __future__ import annotations

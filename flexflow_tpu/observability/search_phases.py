@@ -8,7 +8,7 @@ Each phase both emits a `search/<name>` span against the active
 TraceRecorder (so --profile-trace-dir timelines include the search) and
 accumulates milliseconds into the collector, which the search telemetry
 reports as `phase_ms` (graph_optimize/mcmc_optimize telemetry ->
-FFModel.search_provenance -> the bench.py search block).
+FFModel.search_provenance; `tools/profile_search.py` prints it).
 
 Phases NEST (leaf_cost runs inside dp, both inside an evaluation): each
 name accumulates independently, so phase_ms is per-phase attribution, not

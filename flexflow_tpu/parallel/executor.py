@@ -349,8 +349,8 @@ def collect_overlap_sites(pcg, shardings, mesh) -> Dict[Node, str]:
     (machine_mapping/overlap.py derive_overlap_plan) affects pricing and
     provenance only. Vetoing fusion from that flag would inherit the
     serial model's whole-stage overlap_fraction haircut — which claims
-    free hiding for most sub-ms edges that the measured flagship subject
-    shows the fused lowering actually winning (BENCH_OVERLAP_r07). Both
+    free hiding for most sub-ms edges on which the fused lowering can
+    win (chip: not measured; no benchmark cell runs a fused edge). Both
     sides are recorded (provenance `edges[].chosen` vs
     `executor_fused_edges`), so the divergence is observable, not
     silent."""

@@ -1,5 +1,5 @@
-"""Seeded chaos-schedule soak harness (shared by tests/test_chaos_soak.py
-and `bench.py --chaos-soak`).
+"""Seeded chaos-schedule soak harness (run by tests/test_chaos_soak.py;
+`soak_schedule` reports one schedule's run as a dict).
 
 The contract being soaked: for EVERY seeded `FaultSchedule` — a ckpt-write
 I/O fault, a producer-thread death, an injected NaN, a simulated hang, a

@@ -16,8 +16,8 @@ Two generations of trigger, both active:
 
    Each (site, step) decision is a pure hash of (seed, site, step): the
    same spec fires at the same steps in every process, every run — which
-   is what lets the chaos soak (tests/test_chaos_soak.py, `bench.py
-   --chaos-soak`) assert that a faulted-then-recovered run ends with
+   is what lets the chaos soak (tests/test_chaos_soak.py,
+   runtime/chaos.py) assert that a faulted-then-recovered run ends with
    BITWISE-identical final params versus the fault-free run. Sites:
 
    - `ckpt_write`  one transient `InjectedFault` (an OSError) on the

@@ -9,7 +9,6 @@ triples evaluated against op attrs; OP_TYPE is the usual anchor.
 from __future__ import annotations
 
 import enum
-import os
 from dataclasses import dataclass
 
 from flexflow_tpu.utils.hashing import memoized_hash
@@ -95,18 +94,10 @@ class OperatorAttributePattern:
 # attrs| of a process.
 _OP_SATISFY_MEMO: dict = {}
 
-# captured at import: this predicate runs O(|patterns| x |hosts|) per match
-# call and a per-call environ probe would cost as much as the memo lookup it
-# guards. The flag's consumer (the perf regression test) sets it before the
-# subprocess starts.
-_BASELINE_MODE = "FF_TPU_SEARCH_BASELINE" in os.environ
-
 
 def op_attrs_satisfy_pattern(attrs: OpAttrs, pattern: OperatorAttributePattern) -> bool:
     if not pattern.constraints:
         return True
-    if _BASELINE_MODE:  # pre-overhaul behavior
-        return all(c.satisfied_by(attrs) for c in pattern.constraints)
     try:
         key = (pattern, attrs)
         hit = _OP_SATISFY_MEMO.get(key)

@@ -16,7 +16,6 @@ from typing import Dict, List, Optional, Tuple
 
 from flexflow_tpu.pcg.parallel_computation_graph import ParallelComputationGraph
 from flexflow_tpu.substitutions.operator_pattern import (
-    _BASELINE_MODE,
     OperatorAttributePattern,
     op_attrs_satisfy_pattern,
 )
@@ -96,10 +95,7 @@ def _find_pattern_matches_native(
     # O(1) counts, not the nodes property / all_values() (frozenset alloc +
     # sort per call would reintroduce the cost this cache removes)
     stamp = (len(pcg._g._nodes), len(pcg._value_label))
-    if _BASELINE_MODE:
-        cached = None  # pre-overhaul behavior: rebuild per call
-    else:
-        cached = getattr(pcg, "_match_host_arrays", None)
+    cached = getattr(pcg, "_match_host_arrays", None)
     if cached is not None and cached[0] == stamp:
         _, host_nodes, host_values, v_id, h_slots = cached
     else:

@@ -8,7 +8,6 @@ tensor's dims/degrees (PARALLEL_DIM, PARALLEL_DEGREE exprs in the reference).
 from __future__ import annotations
 
 import enum
-import os
 from dataclasses import dataclass
 
 from flexflow_tpu.utils.hashing import memoized_hash
@@ -94,17 +93,12 @@ class TensorAttributePattern:
 # (pattern, shape) -> bool; same memo rationale as op_attrs_satisfy_pattern
 _TENSOR_SATISFY_MEMO: dict = {}
 
-# captured at import for the same hot-path reason as operator_pattern.py
-_BASELINE_MODE = "FF_TPU_SEARCH_BASELINE" in os.environ
-
 
 def tensor_attrs_satisfy_pattern(
     shape: ParallelTensorShape, pattern: TensorAttributePattern
 ) -> bool:
     if not pattern.constraints:
         return True
-    if _BASELINE_MODE:  # pre-overhaul behavior
-        return all(c.satisfied_by(shape) for c in pattern.constraints)
     try:
         key = (pattern, shape)
         hit = _TENSOR_SATISFY_MEMO.get(key)

@@ -6,8 +6,7 @@ must force the XLA host-platform device count BEFORE the first jax
 import — and must strip any stale count already in XLA_FLAGS, or the
 duplicate flag aborts backend init. This module is deliberately
 import-free (no jax, nothing heavy), so calling it never defeats its
-own purpose. bench.py keeps inline copies on its real-chip paths where
-the CPU forcing is conditional per sub-benchmark.
+own purpose.
 """
 
 from __future__ import annotations

@@ -3,7 +3,7 @@
 This is the TPU-native analogue of the reference's missing fake-cluster
 (SURVEY.md §4): multi-device sharding tests run on a virtual CPU mesh via
 --xla_force_host_platform_device_count, so the full tp/pp/dp/sp lowering is
-exercised without TPU hardware. Bench runs (bench.py) use the real chip and do
+exercised without TPU hardware. Chip runs (chip_smoke.py, benchmark/run.py) do
 NOT import this.
 """
 
@@ -12,7 +12,7 @@ import sys
 
 # Pin the platform before any backend initializes so tests really run on
 # the virtual 8-device CPU mesh (the chip is reached only through
-# chip_smoke.py / bench.py).
+# chip_smoke.py / benchmark/run.py).
 import re
 
 os.environ["JAX_PLATFORMS"] = "cpu"

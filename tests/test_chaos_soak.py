@@ -2,8 +2,7 @@
 FaultSchedules — ckpt-write IO fault, producer death, injected NaN,
 simulated hang, kill+resume — each must end with BITWISE-identical final
 params and Adam moments versus the fault-free run, on both the DP and
-searched-PCG backends (runtime/chaos.py is the shared harness;
-`bench.py --chaos-soak` commits the same matrix as a CHAOS_r* artifact)."""
+searched-PCG backends (runtime/chaos.py is the harness)."""
 
 import numpy as np
 import pytest

@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from flexflow_tpu.compiler.machine_mapping.overlap import classify_op
 from flexflow_tpu.kernels.collective_matmul import (
     all_gather_matmul,
     matmul_reduce_scatter,
@@ -253,6 +254,31 @@ class TestExecutorOverlapLowering:
 # ---------------------------------------------------------------------------
 
 
+class TestClassifyOp:
+    """The roofline class that seeds an overlapped entry
+    (`leaf_roofline_class`'s one helper)."""
+
+    PEAK = 1e12  # FLOP/s
+    HBM = 100.0  # GB/s
+
+    def test_classify_mxu_bound(self):
+        # compute roofline 3 ms, memory roofline ~0; measured at roofline
+        assert classify_op(1e9, 1e3, 3.0, self.PEAK, self.HBM) == "mxu"
+
+    def test_classify_bandwidth_bound(self):
+        # memory roofline 2 ms dominates; measured at roofline
+        assert (
+            classify_op(1e3, 1e8, 2.0, self.PEAK, self.HBM) == "bandwidth"
+        )
+
+    def test_classify_dispatch_bound(self):
+        # both rooflines are microseconds; a 1 ms measurement is overhead
+        assert classify_op(1e3, 1e3, 1.0, self.PEAK, self.HBM) == "dispatch"
+
+    def test_classify_zero_time_is_dispatch(self):
+        assert classify_op(1e9, 1e3, 0.0, self.PEAK, self.HBM) == "dispatch"
+
+
 class TestOverlapPricing:
     def test_series_combine_takes_cheaper_exposure(self):
         from flexflow_tpu.compiler.machine_mapping.result import (
@@ -297,7 +323,7 @@ class TestOverlapPricing:
         )
 
     def _flagship_pcg(self):
-        from bench import build_flagship_pcg
+        from flexflow_tpu.models.flagship import build_flagship_pcg
 
         return build_flagship_pcg(
             batch=64, seq=512, embed=1024, heads=8, layers=2, vocab=32000

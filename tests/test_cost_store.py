@@ -589,7 +589,7 @@ pcg = pcg_from_computation_graph(b.graph)
 """
 
 _PROXY_PCG = """
-from bench import build_flagship_pcg
+from flexflow_tpu.models.flagship import build_flagship_pcg
 # the 12-layer proxy at CPU-measurable dims: same topology as the
 # flagship, every layer's leaf family measured for real
 pcg = build_flagship_pcg(batch=8, seq=32, embed=64, heads=2, layers=12,

@@ -7,8 +7,7 @@ exit contract.
 Everything here runs on synthetic event streams — no model compile, no
 search — so the whole module stays cheap inside the tier-1 budget. The
 end-to-end searched-fit path (advisory fires under an injected slowdown,
-candidate matches a cold re-search) is exercised by `bench.py --drift`
-and pinned by the DRIFT_r18 artifact claims.
+candidate matches a cold re-search) is what DRIFT_r18.json records.
 """
 
 import json

@@ -351,8 +351,7 @@ def test_fused_k8_speedup_over_per_step():
     per-step loop on a dispatch-bound proxy (tiny MLP whose per-step XLA
     program is far cheaper than its dispatch) on the same host.
     FF_TPU_FUSED_BASELINE=1 is the revert switch — the same FFModel/config
-    runs both ways in-process, mirroring test_search_perf.py's
-    FF_TPU_SEARCH_BASELINE discipline."""
+    runs both ways in-process."""
     batch, steps = 32, 384
     rs = np.random.RandomState(0)
     xv = rs.randn(batch * steps, 64).astype(np.float32)

@@ -152,3 +152,31 @@ def test_split_test_forward():
     x = jnp.asarray(np.random.RandomState(0).randn(4, 256), jnp.float32)
     env = forward_interpreter(cg, params, {"input": x})
     assert env[out].shape == (4, 32)
+
+
+def test_flagship_step_flops_match_graph_count():
+    """The flagship's closed-form training FLOPs (the figure chip_smoke.py
+    divides by step time) agree with three times the forward FLOPs counted
+    op by op from the built graph, at the flagship's own shapes."""
+    from flexflow_tpu.kernels.ops import op_forward_flops
+    from flexflow_tpu.local_execution.training_backing import (
+        split_slot_values,
+    )
+    from flexflow_tpu.models.flagship import (
+        build_flagship_cg,
+        flagship_step_flops,
+    )
+
+    shapes = dict(batch=64, seq=512, embed=1024, heads=8, layers=12, vocab=32000)
+    cg, _ = build_flagship_cg(**shapes)
+    forward = 0
+    for n in cg.topological_ordering():
+        attrs = cg.op_attrs(n)
+        slots = [cg.tensor_shape(t) for t in cg.inputs_of(n)]
+        outs = [cg.tensor_shape(t) for t in cg.outputs_of(n)]
+        data, weights = split_slot_values(attrs, slots)
+        if data:  # inputs and weights do no arithmetic
+            forward += op_forward_flops(
+                attrs, data, outs, weight_shapes=weights or None
+            )
+    assert flagship_step_flops(**shapes) == pytest.approx(3 * forward, rel=0.02)

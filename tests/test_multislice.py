@@ -5,13 +5,10 @@ templates on the 2-slice topology, the v2->v3 movement-store migration
 (foreign link-class entries are never served), and — slow-marked — the
 acceptance gate: on the 4+4 topology the hierarchical search beats the
 flat search's truthfully-re-priced winner by >= 1.2x when DCN is 10x
-slower than ICI (the same A/B recipe bench.py --multislice commits as
-SLICE_r17.json).
+slower than ICI.
 """
 
 import json
-import os
-import sys
 
 import pytest
 
@@ -32,8 +29,6 @@ from flexflow_tpu.pcg.parallel_computation_graph import (
     pcg_from_computation_graph,
 )
 from flexflow_tpu.substitutions import generate_parallelization_rules
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # the emulated 2-slice 4+4 machine: slices are the node axis, DCN is the
 # inter-node link (tools/audit_env.multislice_machine_spec)
@@ -141,26 +136,86 @@ class TestStoreMigrationV3:
         assert r.get(self.V2_KEY + "|dcn") is None
 
 
+def _multislice_proxy_pcg(L=4, d=1024, B=512):
+    """The multi-slice proxy: a uniform weight-heavy dense chain whose
+    dp-hybrid plan replicates d x d weight blocks across the slice (DCN)
+    boundary every step. The shapes sit in the disagreement band the A/B
+    needs: under FLAT (uniform-constant) pricing the full-machine
+    dp-over-the-boundary hybrid wins (the 2x compute advantage beats
+    uniformly-priced weight replication), while under the TRUE 10x
+    ICI/DCN gap those same replicate edges dominate and the optimum
+    stays inside the slice."""
+    from flexflow_tpu.op_attrs.activation import Activation
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.parallel_tensor_shape import lift_to_parallel
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+    from flexflow_tpu.pcg.parallel_computation_graph_builder import (
+        ParallelComputationGraphBuilder,
+    )
+
+    b = ParallelComputationGraphBuilder()
+    x = b.create_input_tensor(
+        lift_to_parallel(TensorShape((B, d), DataType.FLOAT)), name="x"
+    )
+    h = x
+    for i in range(L):
+        h = b.dense(h, d, activation=Activation.RELU, name=f"l{i}")
+    return b.graph
+
+
+def _multislice_spec(gap=10.0, ici_gbps=2.0):
+    """The 2-slice 4+4 virtual machine: slices are the node axis (INTER =
+    DCN at ici/gap GB/s, INTRA = ICI). gap=1.0 is the uniform-bandwidth
+    machine of the counter-example — identical constants on every link,
+    i.e. exactly what the flat (slice-blind) cost model assumes the
+    machine always looks like."""
+    from flexflow_tpu.pcg.machine_view import MachineSpecification
+
+    return MachineSpecification(2, 1, 4, ici_gbps / gap, ici_gbps)
+
+
+def _multislice_ctx(spec, slice_aware=False, hierarchy=False, flat=False):
+    """Estimator + mapping context on `spec`. `flat=True` builds the
+    slice-BLIND arm: the same machine geometry priced with one constant
+    per link class pair (dcn latency = ici latency; the spec passed in
+    should carry uniform bandwidths) — the pre-slice-aware worldview the
+    tentpole replaces."""
+    from flexflow_tpu.compiler.machine_mapping.cost_estimator import (
+        AnalyticTPUCostEstimator,
+        make_default_allowed_machine_views,
+    )
+    from flexflow_tpu.compiler.machine_mapping.get_optimal_machine_mapping import (
+        MachineMappingContext,
+    )
+
+    est = AnalyticTPUCostEstimator(
+        spec, peak_flops=5e10, hbm_gbps=10.0,
+        ici_latency_ms=0.1,
+        dcn_latency_ms=0.1 if flat else 0.2,
+        emulated_mesh=True,
+    )
+    ctx = MachineMappingContext(
+        est, make_default_allowed_machine_views(),
+        overlap_fraction=0.5,
+        slice_aware=slice_aware, slice_hierarchy=hierarchy,
+    )
+    return est, ctx
+
+
 @pytest.mark.slow
 def test_hierarchical_beats_flat_by_1p2x_under_10x_gap():
     """Acceptance gate (ISSUE 17): on the 4+4 topology the hierarchical
     search's winner is >= 1.2x cheaper than the flat (slice-blind)
-    search's winner re-priced under the true 10x ICI/DCN gap — the exact
-    A/B bench.py --multislice commits as SLICE_r17.json."""
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
+    search's winner re-priced under the true 10x ICI/DCN gap."""
     from flexflow_tpu.compiler.unity_algorithm import price_mapped_plan
 
-    pcg = bench._multislice_proxy_pcg()
+    pcg = _multislice_proxy_pcg()
     rules = generate_parallelization_rules([2, 4, 8])
-    spec_true = bench._multislice_spec(10.0)
-    spec_uni = bench._multislice_spec(1.0)
-    _, ctx_true = bench._multislice_ctx(spec_true)
-    _, ctx_flat = bench._multislice_ctx(spec_uni, flat=True)
-    _, ctx_hier = bench._multislice_ctx(
+    spec_true = _multislice_spec(10.0)
+    spec_uni = _multislice_spec(1.0)
+    _, ctx_true = _multislice_ctx(spec_true)
+    _, ctx_flat = _multislice_ctx(spec_uni, flat=True)
+    _, ctx_hier = _multislice_ctx(
         spec_true, slice_aware=True, hierarchy=True
     )
 

@@ -1,11 +1,8 @@
-"""Observability subsystem tests: structured span tracing, per-op cost
-attribution (HLO totals + analytic fallback), roofline classification, and
+"""Observability subsystem tests: structured span tracing and
 search-provenance telemetry.
 
-These pin the ISSUE's acceptance bars: trace-span nesting, attribution
-totals within 20% of the measured step, the analytic-fallback path, and the
-{evaluations, infeasible, dedup_hits, symmetry_dedup, cost_model} record in
-a dry-run search provenance.
+These pin trace-span nesting and the {evaluations, infeasible, dedup_hits,
+symmetry_dedup, cost_model} record in a dry-run search provenance.
 """
 
 import json
@@ -19,17 +16,10 @@ import pytest
 from flexflow_tpu.observability import (
     TraceRecorder,
     active_recorder,
-    analytic_op_costs,
-    attribute_costs,
-    classify_op,
-    measure_per_op_ms,
     record_span,
-    roofline_report,
     set_recorder,
-    step_cost_analysis,
     trace_session,
 )
-from flexflow_tpu.observability.cost_attribution import OpCost, StepAttribution
 from flexflow_tpu.pcg import ComputationGraphBuilder
 
 
@@ -197,201 +187,6 @@ class TestStepInstrumentation:
 
 
 # ---------------------------------------------------------------------------
-# cost attribution
-# ---------------------------------------------------------------------------
-
-
-class TestCostAttribution:
-    def test_analytic_op_costs_cover_compute_ops(self):
-        cg, _ = small_mlp()
-        ops = analytic_op_costs(cg)
-        # input and weight nodes are excluded; dense+relu+dense remain
-        assert sorted(o.op_type for o in ops) == sorted(
-            ["linear", "linear", "element_unary"]
-        )
-        dense = [o for o in ops if o.op_type == "linear"]
-        assert all(o.flops > 0 and o.bytes > 0 for o in dense)
-        # fc1 is [8,16]x[16,16]: 2*8*16*16 fwd flops
-        fc1 = next(o for o in ops if o.name == "fc1")
-        assert fc1.flops == 2 * 8 * 16 * 16
-
-    def test_analytic_fallback_distributes_full_step(self):
-        cg, _ = small_mlp()
-        att = attribute_costs(cg, step_ms=10.0)
-        assert att.source == "analytic"
-        assert att.ms_source == "analytic"
-        assert att.attributed_ms == pytest.approx(10.0, rel=1e-6)
-        assert all(o.measured_ms >= 0 for o in att.ops)
-        assert all(o.raw_ms is None for o in att.ops)
-
-    def test_program_totals_rescale_to_hlo(self):
-        cg, _ = small_mlp()
-        program = {"flops": 9999.0, "bytes_accessed": 5555.0}
-        att = attribute_costs(cg, step_ms=1.0, program=program)
-        assert att.source == "hlo"
-        assert att.flops_source == "hlo" and att.bytes_source == "hlo"
-        assert att.total_flops() == pytest.approx(9999.0)
-        assert att.total_bytes() == pytest.approx(5555.0)
-        assert att.program == program
-
-    def test_partial_program_tags_per_quantity(self):
-        # only flops exposed: bytes keep their analytic counts AND their
-        # analytic source tag (the roofline resolves factors per quantity)
-        cg, _ = small_mlp()
-        analytic_bytes = attribute_costs(cg, step_ms=1.0).total_bytes()
-        att = attribute_costs(cg, step_ms=1.0, program={"flops": 1234.0})
-        assert att.source == "hlo"
-        assert att.flops_source == "hlo"
-        assert att.bytes_source == "analytic"
-        assert att.total_flops() == pytest.approx(1234.0)
-        assert att.total_bytes() == pytest.approx(analytic_bytes)
-
-    def test_measured_per_op_ms_attribution_within_20pct(self):
-        cg, logits, inst, xv, yv = training_instance()
-        params, opt_state = inst.initialize(seed=0)
-        from flexflow_tpu.kernels.profiling import force_sync
-
-        # compile, then a two-point measurement of the fused step
-        params, opt_state, loss, _ = inst.train_step(
-            params, opt_state, {"x": xv}, yv
-        )
-        force_sync(loss)
-
-        def run(iters, params, opt_state):
-            start = time.perf_counter()
-            loss = None
-            for _ in range(iters):
-                params, opt_state, loss, _ = inst.train_step(
-                    params, opt_state, {"x": xv}, yv
-                )
-            force_sync(loss)
-            return time.perf_counter() - start, params, opt_state
-
-        t1, params, opt_state = run(2, params, opt_state)
-        t2, params, opt_state = run(6, params, opt_state)
-        step_ms = max((t2 - t1) / 4, t2 / 6) * 1000.0
-
-        per_op = measure_per_op_ms(cg, {"x": xv}, logits)
-        assert per_op and all(ms >= 0 for ms in per_op.values())
-        att = attribute_costs(cg, step_ms, per_op_ms=per_op)
-        assert att.ms_source == "measured"
-        # the acceptance bar: attributed ms totals the measured step
-        assert abs(att.attributed_ms - step_ms) <= 0.2 * step_ms
-        assert att.scale > 0
-        assert all(o.raw_ms is not None for o in att.ops)
-
-    def test_step_cost_analysis_shape(self):
-        # CPU XLA may or may not expose cost analysis; either a
-        # {flops[, bytes_accessed]} dict or None (analytic fallback) is a
-        # valid contract
-        def f(a, b):
-            return a @ b
-
-        a = jnp.ones((8, 8))
-        program = step_cost_analysis(f, a, a)
-        assert program is None or (
-            isinstance(program, dict) and program.get("flops", 1) > 0
-        )
-
-
-# ---------------------------------------------------------------------------
-# roofline
-# ---------------------------------------------------------------------------
-
-PEAK = 1e12  # FLOP/s
-HBM = 100.0  # GB/s
-
-
-class TestRoofline:
-    def test_classify_mxu_bound(self):
-        # compute roofline 3 ms, memory roofline ~0; measured at roofline
-        assert classify_op(1e9, 1e3, 3.0, PEAK, HBM) == "mxu"
-
-    def test_classify_bandwidth_bound(self):
-        # memory roofline 2 ms dominates; measured at roofline
-        assert classify_op(1e3, 1e8, 2.0, PEAK, HBM) == "bandwidth"
-
-    def test_classify_dispatch_bound(self):
-        # both rooflines are microseconds; a 1 ms measurement is overhead
-        assert classify_op(1e3, 1e3, 1.0, PEAK, HBM) == "dispatch"
-
-    def test_classify_zero_time_is_dispatch(self):
-        assert classify_op(1e9, 1e3, 0.0, PEAK, HBM) == "dispatch"
-
-    def _attribution(self):
-        ops = [
-            OpCost("n1", "matmul", "LINEAR", flops=1e9, bytes=1e3,
-                   measured_ms=3.0),
-            OpCost("n2", "embed", "EMBEDDING", flops=1e3, bytes=1e8,
-                   measured_ms=2.0),
-            OpCost("n3", "reshape", "RESHAPE", flops=1e3, bytes=1e3,
-                   measured_ms=1.0),
-        ]
-        return StepAttribution(
-            ops=ops,
-            step_ms=6.0,
-            attributed_ms=6.0,
-            raw_total_ms=12.0,
-            scale=0.5,
-            source="analytic",
-        )
-
-    def test_report_block(self):
-        block = roofline_report(
-            self._attribution(), PEAK, HBM, extra={"subject": "unit"}
-        )
-        assert block["subject"] == "unit"
-        assert block["num_ops"] == 3
-        by_name = {o["name"]: o for o in block["ops"]}
-        assert by_name["matmul"]["bound"] == "mxu"
-        assert by_name["embed"]["bound"] == "bandwidth"
-        assert by_name["reshape"]["bound"] == "dispatch"
-        # per-op list is sorted most-expensive first
-        assert [o["name"] for o in block["ops"]] == [
-            "matmul", "embed", "reshape",
-        ]
-        # bound_ms partitions the attributed time
-        assert sum(block["bound_ms"].values()) == pytest.approx(6.0)
-        # whole-step MFU: 3x flops factor over the 6 ms step at PEAK
-        assert block["mfu"] == pytest.approx(
-            3.0 * (1e9 + 2e3) / 6e-3 / PEAK, rel=1e-3
-        )
-        for o in block["ops"]:
-            assert set(o) >= {"flops", "bytes", "measured_ms", "bound", "mfu"}
-
-    def test_report_top_n_trims_op_list_only(self):
-        block = roofline_report(self._attribution(), PEAK, HBM, top_n=1)
-        assert len(block["ops"]) == 1
-        assert block["num_ops"] == 3
-        assert sum(block["bound_ms"].values()) == pytest.approx(6.0)
-
-    def test_hlo_source_drops_train_factor(self):
-        # "hlo" flops were rescaled to the FULL fwd+bwd+update program
-        # totals; applying the 3x analytic training multiplier again would
-        # inflate MFU 3x and misclassify dispatch ops as MXU-bound
-        att = self._attribution()
-        att.source = att.flops_source = att.bytes_source = "hlo"
-        block = roofline_report(att, PEAK, HBM)
-        assert block["train_flops_factor"] == 1.0
-        assert block["train_bytes_factor"] == 1.0
-        analytic = roofline_report(self._attribution(), PEAK, HBM)
-        assert analytic["train_flops_factor"] == 3.0
-        # block values are rounded to 4 decimals
-        assert block["mfu"] == pytest.approx(analytic["mfu"] / 3.0, abs=1e-3)
-
-    def test_partial_hlo_factors_resolve_per_quantity(self):
-        # backend exposed only flops: bytes stay forward-only analytic and
-        # must keep their 2x training multiplier
-        att = self._attribution()
-        att.source = att.flops_source = "hlo"
-        block = roofline_report(att, PEAK, HBM)
-        assert block["train_flops_factor"] == 1.0
-        assert block["train_bytes_factor"] == 2.0
-        assert block["flops_source"] == "hlo"
-        assert block["bytes_source"] == "analytic"
-
-
-# ---------------------------------------------------------------------------
 # search telemetry / provenance
 # ---------------------------------------------------------------------------
 
@@ -508,8 +303,8 @@ class TestSearchTelemetry:
             default=str,
         )
 
-    # The provenance key set downstream consumers
-    # (tools/check_artifact_claims.py, bench, merge_ab) may rely on.
+    # The provenance key set downstream consumers (benchmark/layer_metrics,
+    # chip_smoke.py, the *_audit tools) may rely on.
     # FFModel.search_provenance is Dict[str, object]: several values are
     # NESTED dicts / strings / bools, not floats (ISSUE 3 satellite — the
     # old Dict[str, float] annotation lied).

@@ -821,8 +821,7 @@ def test_pipeline_gate_budgeted_search_trains():
     memory budget the flat SPMD mapping is INFEASIBLE, the search selects
     a pipelined plan, and that plan compiles and trains (loss decreases)
     through the 1F1B executor — the same pattern as the overlap/fused
-    gates. The step-time ratio vs the unbudgeted flat winner is recorded
-    via bench.py --pipeline (PIPE_r14.json)."""
+    gates."""
     pcg = _chain_pcg(L=8, d=128, B=32)
     peaks = _seed_peaks(pcg)
     pipe_best = min(

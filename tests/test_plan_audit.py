@@ -1,8 +1,7 @@
 """Plan-audit tests (ISSUE 3 tentpole): the predicted-vs-measured replay of
 the searched plan — per-op ratios against the pricing estimator, movement
 edges measured as real reshards, geomean/worst-op summary, and the
-provenance + artifact plumbing (`FFModel.search_provenance["plan_audit"]`,
-`bench.py --plan-audit`, AUDIT_r*.json claims)."""
+provenance plumbing (`FFModel.search_provenance["plan_audit"]`)."""
 
 import json
 import math
@@ -185,54 +184,6 @@ class TestAuditPlanDirect:
         for e in audit["movement_edges"]:
             assert e["measured_ms"] is None and e["ratio"] is None
         assert all(o["measured_ms"] is not None for o in audit["ops"])
-
-
-class TestBenchAndArtifact:
-    def test_health_demo_block(self):
-        # the bench --plan-audit health_demo block: forced NaN detected,
-        # blamed, skipped, params finite (the committed-artifact source)
-        import bench
-
-        demo = bench._health_demo()
-        assert demo["steps"] == 4
-        assert demo["nonfinite_steps"] == 1
-        assert demo["skipped_steps"] == 1
-        assert demo["events_skipped"] == 1
-        assert demo["first_bad_op"] == "fc1"
-        assert demo["params_finite"] is True
-
-    def test_malformed_audit_artifact_fails_not_skips(self, monkeypatch):
-        # an artifact that EXISTS but lacks the claimed field (bench wrote
-        # dp_seed_error instead of dp_seed) must FAIL the claim, not skip
-        import math
-
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        import check_artifact_claims as cac
-
-        field = cac._audit_field(
-            lambda d: d["dp_seed"]["plan_audit"]["summary"]["x"]
-        )
-        monkeypatch.setattr(
-            cac, "load_audit", lambda r: {"dp_seed_error": "boom"}
-        )
-        assert math.isnan(field(6))  # NaN != claim -> reported as mismatch
-        monkeypatch.setattr(cac, "load_audit", lambda r: None)
-        assert field(6) is None  # genuinely absent artifact -> skip
-
-    def test_committed_audit_artifact_matches_claims_loader(self):
-        # AUDIT_r06.json must keep the shape the claims checker reads
-        sys.path.insert(0, os.path.join(REPO, "tools"))
-        import check_artifact_claims as cac
-
-        d = cac.load_audit(6)
-        assert d is not None, "AUDIT_r06.json missing"
-        assert d["searched"]["plan_audit"]["summary"]["op_geomean_ratio"] > 0
-        assert (
-            d["dp_seed"]["plan_audit"]["summary"]["movement_geomean_ratio"]
-            > 0
-        )
-        assert d["dp_seed"]["plan_audit"]["summary"]["worst_ops"]
-        assert d["health_demo"]["skipped_steps"] >= 1
 
 
 if __name__ == "__main__":

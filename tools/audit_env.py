@@ -79,8 +79,8 @@ def multislice_machine_spec(
     are the node axis (INTER = DCN, INTRA = ICI). The defaults mirror
     the CPU-emulated search constants (ffmodel._compile_searched) with a
     10x ICI/DCN bandwidth gap — the regime where slice-aware search
-    separates from flat (bench.py --multislice commits the A/B; pass
-    dcn_gbps == ici_gbps for the uniform counter-example)."""
+    separates from flat (pass dcn_gbps == ici_gbps for the uniform
+    counter-example)."""
     bootstrap_repo_path()
     from flexflow_tpu.pcg.machine_view import MachineSpecification
 

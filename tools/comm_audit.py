@@ -16,9 +16,6 @@ engine behind `ffcheck --comm`) over three subjects on the virtual
    must DEMONSTRABLY trip COMM001 with a structured diagnostic naming
    the collective and its bytes.
 
-`tools/check_artifact_claims.py` cross-checks the README numbers against
-this artifact (its own COMM_r* family).
-
 Usage:
     python tools/comm_audit.py            # writes COMM_r12.json
     python tools/comm_audit.py --round 13 --out COMM_r13.json

@@ -10,8 +10,7 @@ results as DET_r*.json:
    with 100% donation-alias coverage,
 2. the flagship transformer proxy's SEARCHED winner (the same subject
    MEM_r*/COMM_r* audit — one shape family by construction),
-3. a pp8m2 pipelined plan (8 stages x 2 microbatches, the PIPE_r14
-   shape class) lowered through the 1F1B executor,
+3. a pp8m2 pipelined plan (8 stages x 2 microbatches) lowered through the 1F1B executor,
 4. the serving prefill + decode programs (`ServingProgram
    .exec_contract()`), with the KV cache as the expected-in-place state,
 5. seeded fixtures that DEMONSTRABLY trip each rule id: DET001 (three
@@ -25,9 +24,6 @@ results as DET_r*.json:
    lower + compile the same plan and must produce identical
    canonicalized HLO fingerprints (what makes DET002 a checkable
    invariant across preemption resume).
-
-`tools/check_artifact_claims.py` cross-checks the README numbers against
-this artifact (its own DET_r* family).
 
 Usage:
     python tools/exec_audit.py            # writes DET_r15.json
@@ -141,7 +137,7 @@ def audit_flagship(search_budget: int) -> dict:
 
 
 def build_pp8m2_pcg():
-    """The PIPE_r14 shape class: a deep dense trunk stage-partitioned
+    """A deep dense trunk stage-partitioned
     pp8m2 (8 stages x 2 microbatches on the 8-device mesh)."""
     from flexflow_tpu.pcg import ComputationGraphBuilder
     from flexflow_tpu.pcg.parallel_computation_graph import (

@@ -3,8 +3,7 @@
 compile of the flagship transformer proxy on the virtual 8-device CPU
 mesh with `--plan-audit` + `--hbm-gb`, and commit the static memory
 analysis's predicted per-device peaks beside XLA's own compiled
-`memory_analysis()` bytes — the predicted/measured geomean ratio the
-README quotes and `tools/check_artifact_claims.py` cross-checks.
+`memory_analysis()` bytes, with their predicted/measured geomean ratio.
 
 Usage:
     python tools/memory_audit.py            # writes MEM_r11.json

@@ -104,7 +104,7 @@ def main():
     )
     from flexflow_tpu.pcg.machine_view import MachineSpecification
     from flexflow_tpu.substitutions.rules import generate_parallelization_rules
-    from bench import build_flagship_pcg
+    from flexflow_tpu.models.flagship import build_flagship_pcg
 
     pcg = build_flagship_pcg(layers=args.layers)
     spec = MachineSpecification(1, 1, 8, 1.0, 2.0)
