@@ -1306,9 +1306,9 @@ def _bwd_onepass_kernel(
     pays 7 (both recompute scores and dp). dq accumulates in an f32 VMEM
     scratch across the innermost k grid dim; dk/dv are written as
     per-q-block partials reduced by the caller (nq is small — the fused
-    single-tile kernel owns the s <= block case). Non-causal only: the
-    two-kernel path's per-tile loop bounds skip masked tiles, which wins
-    under causal."""
+    single-tile kernel owns the s <= block case). Non-causal only: every
+    tile of its grid is live. Causal calls take _bwd_causal_kernel, which
+    also visits a tile once and skips the dead ones (PR 29)."""
     ki = pl.program_id(3)
     block_q, d = q_ref.shape
     scale2 = scale * LOG2E
@@ -1516,14 +1516,14 @@ _flash_bshf.defvjp(_flash_bshf_fwd, _flash_bshf_bwd)
 
 
 def _bwd_blocks(
-    block_q: int, block_k: int, s: int, explicit: bool
+    block_q: int, block_k: int, s: int, explicit: bool, causal: bool = False
 ) -> Tuple[int, int]:
     """Backward-pass block sizes: explicit caller blocks verbatim, else
-    FLEXFLOW_TPU_FLASH_BWD_BLOCK_Q/K, else the measured defaults.
-
-    Default (2048, 512): measured on the bench chip at seq 2048 (one-pass
-    backward), a full-seq q tile with streamed 512-wide k tiles beats
-    1024x1024 by ~5% whole-model (76.8% vs 73.4% MFU); the scores tile
+    FLEXFLOW_TPU_FLASH_BWD_BLOCK_Q/K, else _CAUSAL_BLOCK squared for a causal
+    call (measured there) and (2048, 512) otherwise: the non-causal one-pass
+    backward at batch 4, seq 2048, 16 heads of 128 takes 2.20 ms a call at
+    (2048, 512) against 2.61 at (1024, 1024) (my chip run, PR 29; the MFU
+    pair this note used to quote is in no record). Its scores tile
     (bq*bk*4B) stays within scoped VMEM for any s at this shape."""
     import os
 
@@ -1531,8 +1531,8 @@ def _bwd_blocks(
         return _clamp_block(block_q, s), _clamp_block(block_k, s)
     bq = int(os.environ.get("FLEXFLOW_TPU_FLASH_BWD_BLOCK_Q", "0"))
     bk = int(os.environ.get("FLEXFLOW_TPU_FLASH_BWD_BLOCK_K", "0"))
-    bq = bq if bq > 0 else 2048
-    bk = bk if bk > 0 else 512
+    bq = bq if bq > 0 else (_CAUSAL_BLOCK if causal else 2048)
+    bk = bk if bk > 0 else (_CAUSAL_BLOCK if causal else 512)
     return _clamp_block(bq, s), _clamp_block(bk, s)
 
 
@@ -1575,16 +1575,14 @@ def flash_attention_bshf(
     explicit = block_q is not None or block_k is not None
     import os as _os
 
-    env_blocks = (
-        "FLEXFLOW_TPU_FLASH_BLOCK_Q" in _os.environ
-        or "FLEXFLOW_TPU_FLASH_BLOCK_K" in _os.environ
-    )
-    if not explicit and not env_blocks and d % 128 == 0 and s <= 2048:
-        # forward rides the single-k-block fast path whenever the whole
-        # sequence fits one K tile (measured at seq 2048 on the bench chip:
-        # 1.83 vs 2.37 ms, ~23% over the online-softmax loop); explicit
-        # caller blocks and the env sweep knobs opt out. The backward keeps
-        # its own smaller tiles via _bwd_blocks.
+    # explicit caller blocks and the env sweep knobs opt out of both rules
+    swept = explicit or "FLEXFLOW_TPU_FLASH_BLOCK_Q" in _os.environ or (
+        "FLEXFLOW_TPU_FLASH_BLOCK_K" in _os.environ)
+    if not swept and d % 128 == 0 and causal:
+        bq = bk = _clamp_block(_CAUSAL_BLOCK, s)  # the causal tile schedule
+    elif not swept and d % 128 == 0 and s <= 2048:
+        # non-causal: one K tile as long as the sequence, no online softmax:
+        # 1.37 against 1.48 ms at 1024 x 1024 (b 4, s 2048; my chip run, PR 29)
         bk = s
         if s == 2048:
             bq = min(bq, 256)  # scores tile bq*s*4B within scoped VMEM
@@ -1592,14 +1590,16 @@ def flash_attention_bshf(
         f"seq {s} must divide into blocks ({bq}, {bk}); "
         "gate callers on flash_attention_supported"
     )
+    flash = _flash_bshf
     if d % 128 != 0:
         # head-pair mode (d=64): fused-backward only — callers gate on
         # bshf_pair_supported
         assert 2 * d == 128 and num_heads % 2 == 0 and s <= bq and s <= bk, (
             d, num_heads, s, bq, bk,
         )
-    return _flash_bshf(q, k, v, num_heads, causal, bq, bk, interpret,
-                       explicit)
+    elif causal and s > min(bq, bk):
+        flash = _flash_bshf_causal  # more than one tile: skip the dead ones
+    return flash(q, k, v, num_heads, causal, bq, bk, interpret, explicit)
 
 
 def bshf_pair_supported(num_heads: int, d: int, s: int) -> bool:
@@ -1758,3 +1758,301 @@ def sharded_flash_attention(
         f, mesh, (spec, spec, spec), spec
     )
     return wrapped(q, k, v)
+
+
+# ---------------------------------------------------------------------------
+# the causal tile schedule (d % 128 == 0 bshf calls of more than one tile)
+# ---------------------------------------------------------------------------
+#
+# Half of a causal call's (q block, k block) tiles hold no pair the mask
+# keeps. These kernels visit the live ones only, mask only those on the
+# diagonal, and the backward visits each once. They live at the end of the
+# file, behind flash_attention_bshf's rule, so that nothing the other entry
+# points lower moves (the serialized kernel bodies carry line numbers).
+
+# Block edge, forward and backward, when neither the caller nor the env
+# names one. Measured at batch 4, 16 heads of 128, bf16, a call (forward /
+# backward with its delta kernel; my chip runs, PR 29): seq 2048, 512 x 512
+# 1.27 / 1.78 ms, 1024 x 1024 1.40 / 1.94, 256 x 256 1.43 / 2.58, against
+# 1.45 / 3.20 for the kernels this replaces (single k block; dq + dkv at
+# (2048, 512)); seq 4096, 3.45 / 5.41, 3.71 / 5.53, 4.15 / 8.74 against
+# 3.84 / 8.90. Unequal edges (256 or 1024 by 512) are no better.
+_CAUSAL_BLOCK = 512
+# flash_bwd_causal_bshf keeps q, do, dq and the f32 dq accumulator resident
+# as whole [s, d] rows (2 KB a position at d = 128, bf16) next to its
+# [block_k, block_q] f32 tiles; v5e has 128 MiB of VMEM, 16 of it scoped to
+# a kernel by default.
+_CAUSAL_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _causal_k_range(qi, block_q, block_k):
+    """(full, live) for q block `qi` under the causal mask: k blocks
+    [0, full) lie wholly on or under the diagonal and take no mask,
+    [full, live) straddle it, and those from `live` on are dead (every
+    column after the block's last row). Plain integer arithmetic: `qi` is a
+    Python int (causal_tile_schedule) or a kernel's program id."""
+    return (qi * block_q + 1) // block_k, pl.cdiv((qi + 1) * block_q, block_k)
+
+
+def _causal_q_range(ki, block_q, block_k):
+    """The transpose of _causal_k_range, (start, full) for k block `ki`:
+    q blocks before `start` are dead, [start, full) straddle the diagonal,
+    and those from `full` on take no mask."""
+    return (ki * block_k) // block_q, pl.cdiv((ki + 1) * block_k - 1, block_q)
+
+
+def causal_tile_schedule(s: int, block_q: int, block_k: int):
+    """(live, diagonal, total) (q block, k block) tiles of a causal call:
+    the tiles the kernels visit, those of them that are masked, and the
+    grid a non-causal call visits. (2048, 512, 512) -> (10, 4, 16). The
+    kernels take their loop bounds from the same two range functions."""
+    ranges = [
+        _causal_k_range(qi, block_q, block_k) for qi in range(s // block_q)
+    ]
+    live = sum(hi for _, hi in ranges)
+    diagonal = live - sum(full for full, _ in ranges)
+    return live, diagonal, (s // block_q) * (s // block_k)
+
+
+def _causal_mask(scores, q0, k0, q_axis):
+    """NEG_INF where a key comes after its query: `scores` is a [..., n, m]
+    tile whose `q_axis` (-2 or -1) runs over queries from position q0 and
+    whose other minor axis runs over keys from k0."""
+    n, m = scores.shape[-2:]
+    q_ids = q0 + jax.lax.broadcasted_iota(jnp.int32, (n, m), 2 + q_axis)
+    k_ids = k0 + jax.lax.broadcasted_iota(jnp.int32, (n, m), -1 - q_axis)
+    keep = (q_ids >= k_ids)[(None,) * (scores.ndim - 2)]
+    return jnp.where(keep, scores, NEG_INF)
+
+
+def _lane_sums(p):
+    """[..., n] -> [..., 128] f32: the 128-lane slices of the minor dim
+    added elementwise on the VPU, the cross-lane fold left to the caller.
+    (A rowsum on the MXU, p @ ones, costs the MXU as much as p @ v does at
+    d = 128: 1.35 against 1.27 ms a forward call at seq 2048, my chip runs,
+    PR 29.)"""
+    n = p.shape[-1]
+    p = p.astype(jnp.float32)
+    if n % 128:
+        return p.sum(axis=-1, keepdims=True)
+    out = p[..., 0:128]
+    for j in range(1, n // 128):
+        out = out + p[..., j * 128:(j + 1) * 128]
+    return out
+
+
+def _fwd_causal_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, scale,
+):
+    """_fwd_kernel_b's online softmax on the causal tile schedule: k blocks
+    wholly under the diagonal run the unmasked body, the (at most
+    cdiv(block_q, block_k)) blocks on it the masked one, and dead blocks
+    are outside both loops (_causal_k_range). The row sums are carried as
+    128 lane partials and folded once a q block."""
+    qi = pl.program_id(2)
+    bb, block_q, d = q_ref.shape
+    scale2 = scale * LOG2E
+    # scale folded into the [bb, block_q, d] operand (see _fwd_kernel)
+    q = q_ref[:] * jnp.asarray(scale2, q_ref.dtype)
+
+    def body(j, carry, masked=False):
+        acc, m, l = carry
+        kb = k_ref[:, pl.ds(j * block_k, block_k), :]
+        vb = v_ref[:, pl.ds(j * block_k, block_k), :]
+        scores = jax.lax.dot_general(
+            q, kb, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        if masked:
+            scores = _causal_mask(scores, qi * block_q, j * block_k, -2)
+        m_new = jnp.maximum(m, _row_max(scores))
+        p = _exp2_probs(scores - m_new[..., None], q_ref.dtype)
+        alpha = jnp.exp2(m - m_new)
+        l = l * alpha[..., None] + _lane_sums(p)
+        acc = acc * alpha[..., None] + jax.lax.dot_general(
+            p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        return acc, m_new, l
+
+    carry = (
+        jnp.zeros((bb, block_q, d), jnp.float32),
+        jnp.full((bb, block_q), NEG_INF, jnp.float32),
+        jnp.zeros((bb, block_q, 1 if block_k % 128 else 128), jnp.float32),
+    )
+    full, live = _causal_k_range(qi, block_q, block_k)
+    carry = jax.lax.fori_loop(0, full, body, carry)
+    acc, m, l = jax.lax.fori_loop(
+        full, live, functools.partial(body, masked=True), carry
+    )
+    l = l.sum(axis=-1)
+    o_ref[:] = (acc / l[..., None]).astype(o_ref.dtype)
+    lse_ref[:, 0, :] = m + jnp.log2(l)  # base-2 lse
+
+
+def _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret=False):
+    """_fwd_bshf's grid and blocks (k and v resident as whole rows, batch
+    rows folded), on the kernel that skips."""
+    b, s, f = q.shape
+    d = f // h
+    bb = _batch_block(b, block_q, block_k, s, d, q.dtype.itemsize)
+    tile = pl.BlockSpec((bb, block_q, d), lambda bi, hi, i: (bi, i, hi))
+    row = pl.BlockSpec((bb, s, d), lambda bi, hi, i: (bi, 0, hi))
+    return pl.pallas_call(
+        functools.partial(
+            _fwd_causal_kernel, block_k=block_k, scale=1.0 / (d**0.5)
+        ),
+        name="flash_fwd_causal_bshf",
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")
+        ),
+        grid=(b // bb, h, s // block_q),
+        in_specs=[tile, row, row],
+        out_specs=[
+            tile,
+            pl.BlockSpec(
+                (bb, None, 1, block_q), lambda bi, hi, i: (bi, hi, 0, i)
+            ),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, f), q.dtype),
+            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
+        ],
+    )(q, k, v)
+
+
+def _bwd_causal_kernel(
+    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+    dq_ref, dk_ref, dv_ref, dq_acc, *, block_q, scale,
+):
+    """Causal backward on the tile schedule: one program per (batch, head,
+    k block), k innermost, visiting each live (q block, k block) tile ONCE.
+    Scores, exp2 and dp are computed once a tile and dv, dk, dq all come
+    from them: 5 matmuls where the dq/dkv kernel pair pays 7. q blocks
+    before the k block are never visited, the blocks on the diagonal are
+    masked and the rest are not (_causal_q_range). dk/dv accumulate in f32
+    across the q blocks of this k block; dq accumulates in f32 in `dq_acc`,
+    the whole [s, d] row of this (batch, head), across the k grid dim and is
+    written once with the last k block, so nothing gradient-sized goes
+    through HBM. q, do, lse and delta stay resident as whole rows (their
+    block index does not change along k). Tiles are held transposed,
+    [block_k, block_q]: lse and delta then broadcast as rows and only dq
+    contracts over a tile's major dim (1.78 against 1.93 ms a call at seq
+    2048 for the [block_q, block_k] form; my chip run, PR 29).
+    Probabilities and ds are f32 as in the kernel pair; only the MXU
+    operands are cast."""
+    ki = pl.program_id(2)
+    block_k, d = k_ref.shape
+    s = q_ref.shape[0]
+    scale2 = scale * LOG2E
+    kb = k_ref[:]
+    vb = v_ref[:]
+    # scale folds into the [block_k, d] operand once a program
+    k_scaled = kb * jnp.asarray(scale, kb.dtype)
+
+    @pl.when(ki == 0)
+    def _init():
+        dq_acc[:] = jnp.zeros_like(dq_acc)
+
+    def body(i, carry, masked=False):
+        dk, dv = carry
+        rows = pl.ds(i * block_q, block_q)
+        qb = q_ref[rows, :]
+        dob = do_ref[rows, :]
+        scores = jax.lax.dot_general(
+            kb, qb * jnp.asarray(scale2, qb.dtype),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        if masked:
+            scores = _causal_mask(scores, i * block_q, ki * block_k, -1)
+        p = jnp.exp2(scores - lse_ref[:, rows])  # base-2 lse, [1, block_q]
+        dv = dv + jax.lax.dot_general(
+            p.astype(dob.dtype), dob, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dp = jax.lax.dot_general(
+            vb, dob, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        ds = (p * (dp - delta_ref[:, rows])).astype(qb.dtype)
+        dk = dk + jax.lax.dot_general(
+            ds, qb * jnp.asarray(scale, qb.dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dq_acc[rows, :] += jax.lax.dot_general(
+            ds, k_scaled, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return dk, dv
+
+    start, full = _causal_q_range(ki, block_q, block_k)
+    zero = jnp.zeros((block_k, d), jnp.float32)
+    carry = jax.lax.fori_loop(
+        start, full, functools.partial(body, masked=True), (zero, zero)
+    )
+    dk, dv = jax.lax.fori_loop(full, s // block_q, body, carry)
+    dk_ref[:] = dk.astype(dk_ref.dtype)
+    dv_ref[:] = dv.astype(dv_ref.dtype)
+
+    @pl.when(ki == pl.num_programs(2) - 1)
+    def _fin():
+        dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
+
+
+def _bwd_bshf_causal(q, k, v, o, lse, do, h, block_q, block_k,
+                     interpret=False):
+    b, s, f = q.shape
+    d = f // h
+    delta4 = _delta_bshf(do, o, b, s, h, d, interpret)
+    row = pl.BlockSpec((None, s, d), lambda bi, hi, j: (bi, 0, hi))
+    col = pl.BlockSpec((None, block_k, d), lambda bi, hi, j: (bi, j, hi))
+    stat = pl.BlockSpec((None, None, 1, s), lambda bi, hi, j: (bi, hi, 0, 0))
+    return pl.pallas_call(
+        functools.partial(
+            _bwd_causal_kernel, block_q=block_q, scale=1.0 / (d**0.5)
+        ),
+        name="flash_bwd_causal_bshf",
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
+        ),
+        grid=(b, h, s // block_k),
+        in_specs=[row, col, col, row, stat, stat],
+        out_specs=[row, col, col],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, f), q.dtype),
+            jax.ShapeDtypeStruct((b, s, f), k.dtype),
+            jax.ShapeDtypeStruct((b, s, f), v.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
+    )(q, k, v, do, lse, delta4)
+
+
+# _flash_bshf's signature, so that flash_attention_bshf picks one of the two
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_bshf_causal(q, k, v, h, causal, block_q, block_k, interpret,
+                       explicit=False):
+    assert causal
+    o, _ = _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret)
+    return o
+
+
+def _flash_bshf_causal_fwd(q, k, v, h, causal, block_q, block_k, interpret,
+                           explicit=False):
+    o, lse = _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bshf_causal_bwd(h, causal, block_q, block_k, interpret, explicit,
+                           res, do):
+    q, k, v, o, lse = res
+    bwd_bq, bwd_bk = _bwd_blocks(block_q, block_k, q.shape[1], explicit, True)
+    return _bwd_bshf_causal(
+        q, k, v, o, lse, do, h, bwd_bq, bwd_bk, interpret
+    )
+
+
+_flash_bshf_causal.defvjp(_flash_bshf_causal_fwd, _flash_bshf_causal_bwd)

@@ -686,3 +686,207 @@ def test_bshf_pair_gate():
     assert not bshf_pair_supported(15, 64, 512)  # odd heads
     assert not bshf_pair_supported(16, 32, 512)  # d != 64
     assert not bshf_pair_supported(16, 64, 2048)  # exceeds fused-bwd tile
+
+
+# -- the causal tile schedule of the d % 128 == 0 bshf kernels ---------------
+
+
+def _brute_force_schedule(s, block_q, block_k):
+    """(live, diagonal, total) by looking at every (row, column) of every
+    tile: live holds a pair the mask keeps, diagonal also one it drops."""
+    rows = np.arange(s)[:, None]
+    cols = np.arange(s)[None, :]
+    keep = rows >= cols
+    live = diagonal = 0
+    for i in range(s // block_q):
+        for j in range(s // block_k):
+            tile = keep[
+                i * block_q:(i + 1) * block_q, j * block_k:(j + 1) * block_k
+            ]
+            live += bool(tile.any())
+            diagonal += bool(tile.any() and not tile.all())
+    return live, diagonal, (s // block_q) * (s // block_k)
+
+
+@pytest.mark.parametrize(
+    "s,block_q,block_k,want",
+    [
+        (2048, 512, 512, (10, 4, 16)),
+        (4096, 512, 512, (36, 8, 64)),
+        (512, 128, 128, (10, 4, 16)),
+        (512, 256, 128, (6, 4, 8)),
+        (512, 128, 256, (6, 4, 8)),
+        (1024, 512, 512, (3, 2, 4)),
+        (256, 256, 128, (2, 2, 2)),
+        (4096, 1024, 256, (40, 16, 64)),
+    ],
+)
+def test_causal_tile_schedule_counts_what_the_mask_keeps(
+    s, block_q, block_k, want
+):
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    assert fa.causal_tile_schedule(s, block_q, block_k) == want
+    assert _brute_force_schedule(s, block_q, block_k) == want
+    # the backward walks the same tiles by k block: its ranges are the
+    # transpose of the forward's
+    by_q = {
+        (i, j, j >= full)
+        for i in range(s // block_q)
+        for full, live in [fa._causal_k_range(i, block_q, block_k)]
+        for j in range(live)
+    }
+    by_k = {
+        (i, j, i < full)
+        for j in range(s // block_k)
+        for start, full in [fa._causal_q_range(j, block_q, block_k)]
+        for i in range(start, s // block_q)
+    }
+    assert by_q == by_k and len(by_q) == want[0]
+
+
+# id: (batch, seq, block_q, block_k): explicit blocks, or None for the rule
+# the dispatch reads from (causal, s, d). Two heads of 128 throughout.
+CAUSAL_SCHEDULE_CASES = {
+    # s = 4 x block: the first k block is full (unmasked) for every q block
+    # but the first, and the last q block walks three full tiles
+    "square_blocks": (1, 512, 128, 128),
+    "block_q_twice_block_k": (3, 512, 256, 128),
+    "block_k_twice_block_q": (1, 512, 128, 256),
+    # every live tile straddles the diagonal: the masked loop alone
+    "only_diagonal_tiles": (1, 256, 256, 128),
+    # the default blocks above the single-tile limit: 512 x 512, 3 of 4 live
+    "default_blocks": (1, 1024, None, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CAUSAL_SCHEDULE_CASES))
+def test_flash_bshf_causal_schedule_matches_dense(case):
+    """Forward and the three gradients of the causal d=128 bshf entry
+    against dense attention, over every branch of the tile schedule (dead
+    tiles skipped, diagonal tiles masked, full tiles unmasked, one visit a
+    tile in the backward)."""
+    from flexflow_tpu.kernels.flash_attention import flash_attention_bshf
+
+    b, s, block_q, block_k = CAUSAL_SCHEDULE_CASES[case]
+    h, d = 2, 128
+    rs = np.random.RandomState(17)
+    q4, k4, v4 = (
+        jnp.asarray(rs.randn(b, h, s, d), jnp.float32) for _ in range(3)
+    )
+    to_bshf = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, h * d)
+    blocks = (
+        {} if block_q is None else {"block_q": block_q, "block_k": block_k}
+    )
+
+    def flash(q, k, v):
+        return flash_attention_bshf(
+            to_bshf(q), to_bshf(k), to_bshf(v), h, causal=True,
+            interpret=True, **blocks,
+        )
+
+    np.testing.assert_allclose(
+        np.asarray(flash(q4, k4, v4)),
+        np.asarray(to_bshf(dense_attention(q4, k4, v4, True))),
+        atol=1e-5,
+    )
+    gf = jax.grad(
+        lambda q, k, v: jnp.sum(flash(q, k, v) ** 2), argnums=(0, 1, 2)
+    )(q4, k4, v4)
+    gd = jax.grad(
+        lambda q, k, v: jnp.sum(dense_attention(q, k, v, True) ** 2),
+        argnums=(0, 1, 2),
+    )(q4, k4, v4)
+    for a, b_ in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)
+
+
+def _pallas_calls(jaxpr):
+    """(name, grid, block shapes) of every pallas_call in a jaxpr; a
+    squeezed block dim reads None."""
+    from test_step_scopes import pallas_eqns
+
+    return [
+        (
+            eqn.params["name"],
+            tuple(eqn.params["grid_mapping"].grid),
+            tuple(
+                tuple(
+                    dim if isinstance(dim, int)
+                    else getattr(dim, "block_size", None)
+                    for dim in block.block_shape
+                )
+                for block in eqn.params["grid_mapping"].block_mappings
+            ),
+        )
+        for eqn in pallas_eqns(jaxpr)
+    ]
+
+
+_ROW, _COL = (None, 2048, 128), (None, 512, 128)
+_STAT = (None, None, 1, 2048)
+# id: (entry, operand shape, heads, causal, the Pallas calls of forward +
+# backward). The first two are literals read off the parent of PR 29 (commit
+# 2fb166c) and pin what the causal tile schedule must not change.
+DISPATCH_CASES = {
+    "noncausal_d128_s2048": (
+        "flash_attention_bshf", (4, 2048, 2048), 16, False,
+        [
+            ("flash_fwd_bshf", (2, 16, 8),  # single k block: bk = s
+             ((2, 256, 128), (2, 2048, 128), (2, 2048, 128), (2, 256, 128),
+              (2, None, 1, 256))),
+            ("flash_delta_bshf", (2, 16),
+             ((2, 2048, 128), (2, 2048, 128), (2, None, 1, 2048))),
+            ("flash_bwd_onepass_bshf", (4, 16, 1, 4),
+             (_ROW, _COL, _COL, _ROW, _STAT, _STAT, _ROW,
+              (None, None, 512, 128), (None, None, 512, 128))),
+        ],
+    ),
+    "pair_d64_s512": (
+        "flash_attention_bshf_qkv", (16, 512, 3072), 16, False,
+        [
+            ("flash_fwd_pair_qkv", (4, 8, 1),
+             ((4, 512, 128),) * 4 + ((4, 2, 1, 512),)),
+            ("flash_bwd_fused_pair_qkv", (4, 8),
+             ((4, 512, 128),) * 5 + ((4, 2, 1, 512), (4, 512, 384))),
+        ],
+    ),
+    # 512 x 512 blocks: 10 live tiles of 16; dq's row and q / do resident
+    "causal_d128_s2048": (
+        "flash_attention_bshf", (4, 2048, 2048), 16, True,
+        [
+            ("flash_fwd_causal_bshf", (2, 16, 4),
+             ((2, 512, 128), (2, 2048, 128), (2, 2048, 128), (2, 512, 128),
+              (2, None, 1, 512))),
+            ("flash_delta_bshf", (2, 16),
+             ((2, 2048, 128), (2, 2048, 128), (2, None, 1, 2048))),
+            ("flash_bwd_causal_bshf", (4, 16, 4),
+             (_ROW, _COL, _COL, _ROW, _STAT, _STAT, _ROW, _COL, _COL)),
+        ],
+    ),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCH_CASES))
+def test_flash_bshf_dispatch_is_pinned(case):
+    """Which kernels a call lowers to, on which grid and blocks, at the
+    benchmark's shapes (bf16, traced only): the non-causal d=128 call and
+    the d=64 pair call as on the parent; the causal d=128 call on the tile
+    schedule, through neither half of the dq/dkv kernel pair."""
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    entry, shape, heads, causal, want = DISPATCH_CASES[case]
+    operand = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    args = (operand,) if entry.endswith("_qkv") else (operand,) * 3
+
+    def loss(*xs):
+        out = getattr(fa, entry)(*xs, heads, causal=causal, interpret=True)
+        return jnp.sum(out.astype(jnp.float32))
+
+    grad = jax.grad(loss, argnums=tuple(range(len(args))))
+    found = _pallas_calls(jax.make_jaxpr(grad)(*args).jaxpr)
+    assert found == want
+    if causal:
+        assert not {"flash_bwd_dq_bshf", "flash_bwd_dkv_bshf"} & {
+            name for name, _, _ in found
+        }

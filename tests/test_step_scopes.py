@@ -284,22 +284,27 @@ def test_scopes_change_nothing_but_metadata(make, monkeypatch, request):
 # -- (v) kernel names ----------------------------------------------------------
 
 
-def pallas_names(jaxpr, found=None):
-    """`name` of every pallas_call in a jaxpr, with the tail of the name
-    stack it was bound under."""
-    found = [] if found is None else found
+def pallas_eqns(jaxpr):
+    """Every pallas_call equation of a jaxpr, those of nested jaxprs
+    included, in order."""
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            found.append(
-                (eqn.params["name"], str(eqn.source_info.name_stack))
-            )
+            yield eqn
             continue
         for value in eqn.params.values():
             for sub in value if isinstance(value, (tuple, list)) else (value,):
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    pallas_names(inner, found)
-    return found
+                    yield from pallas_eqns(inner)
+
+
+def pallas_names(jaxpr):
+    """`name` of every pallas_call in a jaxpr, with the tail of the name
+    stack it was bound under."""
+    return [
+        (eqn.params["name"], str(eqn.source_info.name_stack))
+        for eqn in pallas_eqns(jaxpr)
+    ]
 
 
 def _rows(s, d=64):
@@ -364,13 +369,21 @@ KERNEL_CASES = {
         _bshf(256, 2, 128), {},
         {"flash_fwd_bshf", "flash_delta_bshf", "flash_bwd_onepass_bshf"},
     ),
+    # non-causal with more than two q blocks: the constant-memory pair
     "bshf_two_pass_backward": (
+        lambda q, k, v: fa.flash_attention_bshf(
+            q, k, v, 2, block_q=128, block_k=128, interpret=True),
+        _bshf(512, 2, 128), {},
+        {"flash_fwd_bshf", "flash_delta_bshf", "flash_bwd_dq_bshf",
+         "flash_bwd_dkv_bshf"},
+    ),
+    # causal: the tile schedule's one-visit backward
+    "bshf_causal_backward": (
         lambda q, k, v: fa.flash_attention_bshf(
             q, k, v, 2, causal=True, block_q=128, block_k=128,
             interpret=True),
         _bshf(256, 2, 128), {},
-        {"flash_fwd_bshf", "flash_delta_bshf", "flash_bwd_dq_bshf",
-         "flash_bwd_dkv_bshf"},
+        {"flash_fwd_causal_bshf", "flash_delta_bshf", "flash_bwd_causal_bshf"},
     ),
     "ring": (
         _ring, _rows(256, 16), {},
@@ -397,7 +410,7 @@ def test_flash_kernels_lower_under_their_names(case, monkeypatch):
 def test_every_pallas_call_is_named():
     import inspect
 
-    for module, sites in ((fa, 15), (ring_flash, 3)):
+    for module, sites in ((fa, 17), (ring_flash, 3)):
         source = inspect.getsource(module)
         calls = source.count("pl.pallas_call(")
         assert calls == sites
