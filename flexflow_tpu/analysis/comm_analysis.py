@@ -77,6 +77,13 @@ DEFAULT_BYTES_FLOOR = 4096
 DEFAULT_SLACK = 2.5
 DEFAULT_BAND = 4.0
 
+# An all-gather XLA names after a weight node's sharding constraint, or
+# after the cast to the compute dtype, gathers the copy of a parameter the
+# step computes with: the executor stores a weight's master cut over every
+# axis the plan replicates it on (parallel/executor.py `initialize`), and
+# the interpreter's constraint on the (cast) weight is what gathers it.
+_WEIGHT_GATHER_RE = re.compile(r'op_name="[^"]*[/(]ff\.(?:weight\.|cast/)')
+
 COLLECTIVE_KINDS = (
     "all-gather",
     "all-reduce",
@@ -133,6 +140,8 @@ class HloCollective:
     op_name: str = ""  # jax op_name metadata tail, when present
     source: str = ""  # source_file:line metadata, when present
     target: str = ""  # custom-call target (host transfers)
+    # an all-gather of a parameter's compute copy (`_WEIGHT_GATHER_RE`)
+    weight_gather: bool = False
 
     def to_json(self) -> dict:
         d = {
@@ -267,6 +276,10 @@ def extract_collectives(hlo_text: str) -> List[HloCollective]:
                 group_size=group,
                 op_name=op_name,
                 source=src,
+                weight_gather=(
+                    kind == "all-gather"
+                    and _WEIGHT_GATHER_RE.search(line) is not None
+                ),
             )
         )
     return out
@@ -333,6 +346,12 @@ class CommAnalysis:
     # geomean of matched/predicted bytes over edges with both sides > 0
     bytes_geomean: Optional[float] = None
     extra: Dict[str, object] = field(default_factory=dict)
+    # the gathers of the parameters' compute copies the update rule
+    # predicts (HloCollective.weight_gather), kept out of the movement
+    # edges' pools, and the float32 bytes the rule predicts a device
+    # gathers once (None: no prediction was given, nothing set aside)
+    weight_gathers: List[HloCollective] = field(default_factory=list)
+    weight_gather_bytes: Optional[int] = None
 
 
 def _compatible(collective_kind: str, template_classes: frozenset) -> bool:
@@ -407,10 +426,21 @@ def cross_check_comm(
     bytes_floor: int = DEFAULT_BYTES_FLOOR,
     slack: float = DEFAULT_SLACK,
     band: float = DEFAULT_BAND,
+    weight_gather_bytes: Optional[int] = None,
 ) -> CommAnalysis:
     """Assign each HLO collective to a priced movement edge (budgeted
     best-fit pools — see module docstring) and compute the per-edge and
     aggregate accounting.
+
+    The all-gathers of the parameters' compute copies (the second half of
+    the sharded weight update; the first, the gradient's reduction into a
+    shard, is a Replicate edge's reduce-class template) belong to no
+    movement edge. With `weight_gather_bytes` (the update rule's own
+    prediction, `DistributedTrainingInstance.update_record`: the piece
+    bytes of every leaf stored cut finer than the PCG places it) they are
+    set aside and held to it: XLA may gather a leaf again in the backward
+    rather than keep the copy, so up to `slack` times the prediction; more
+    than that and they are matched, or unpredicted, like any other.
 
     Two passes: priced edges first claim ONE size-appropriate collective
     each (largest-need first), so a spurious COMM002 can never be caused
@@ -470,7 +500,16 @@ def cross_check_comm(
             e.pool_bytes = 0
 
     host = [c for c in collectives if c.kind == "host-transfer"]
-    real = [c for c in collectives if c.kind != "host-transfer"]
+    weight_gathers = [c for c in collectives if c.weight_gather]
+    if weight_gather_bytes is None or (
+        sum(c.bytes for c in weight_gathers) > slack * weight_gather_bytes
+    ):
+        weight_gathers = []
+    aside = {id(c) for c in weight_gathers}
+    real = [
+        c for c in collectives
+        if c.kind != "host-transfer" and id(c) not in aside
+    ]
     remaining = {id(e): e.pool_bytes for e in edges}
     assigned: set = set()
 
@@ -565,6 +604,8 @@ def cross_check_comm(
         slack=float(slack),
         band=float(band),
         bytes_geomean=None if geomean is None else round(geomean, 4),
+        weight_gathers=weight_gathers,
+        weight_gather_bytes=weight_gather_bytes,
     )
 
 
@@ -697,6 +738,15 @@ def comm_diagnostics(analysis: CommAnalysis) -> List[Diagnostic]:
     return diags
 
 
+def predicted_weight_gather_bytes(instance) -> Optional[int]:
+    """The float32 bytes a device receives gathering, once, every parameter
+    the executor stores cut finer than the PCG places it, as the update
+    rule records them for `instance`; None for a backend without the rule
+    (or no instance at hand)."""
+    record = getattr(instance, "update_record", None)
+    return None if record is None else int(record["gather_bytes_per_device"])
+
+
 def verify_comm(
     pcg,
     mapping: Optional[dict] = None,
@@ -733,6 +783,9 @@ def verify_comm(
         bytes_floor=bytes_floor,
         slack=slack,
         band=band,
+        weight_gather_bytes=predicted_weight_gather_bytes(
+            getattr(lowered, "instance", None)
+        ),
     )
     return analysis, comm_diagnostics(analysis)
 
@@ -813,4 +866,9 @@ def comm_summary_json(analysis: CommAnalysis) -> dict:
         "unmatched": [c.to_json() for c in over_floor[:20]],
         "host_transfers": len(analysis.host_transfers),
         "bytes_geomean": analysis.bytes_geomean,
+        "weight_gathers": {
+            "count": len(analysis.weight_gathers),
+            "bytes": int(sum(c.bytes for c in analysis.weight_gathers)),
+            "predicted_bytes": analysis.weight_gather_bytes,
+        },
     }

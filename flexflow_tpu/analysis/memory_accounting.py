@@ -114,6 +114,31 @@ def kv_cache_piece_bytes(attrs, q_parallel_shape, w_parallel_shape,
     )
 
 
+def update_shard_ways(parallel_shape, num_devices: Optional[int] = None) -> int:
+    """How many ways finer than the PCG places it the executor stores this
+    weight (its float32 master and each optimizer slot) and computes its
+    update (`parallel/sharding.update_partition_spec`, in the PCG's
+    terms): the replicas of the weight, if some dimension's piece takes
+    them all, else 1. With the mesh's size known the replicas are its
+    devices over the weight's shard degrees (under GSPMD every op runs on
+    the whole mesh, so an axis the weight does not use holds a copy);
+    without it, the view-independent `discard_copy_degree`, which is never
+    more."""
+    shard = 1
+    for d in parallel_shape.shard_degrees():
+        shard *= max(int(d), 1)
+    if num_devices:
+        ways = int(num_devices) // shard
+    else:
+        ways = int(parallel_shape.discard_copy_degree)
+    if ways <= 1:
+        return 1
+    for size, d in zip(parallel_shape.sizes(), parallel_shape.shard_degrees()):
+        if size % (max(int(d), 1) * ways) == 0:
+            return ways
+    return 1
+
+
 @dataclass(frozen=True)
 class OpStepMemory:
     """Per-category step residency of one op, in bytes (one device's
@@ -153,6 +178,7 @@ def estimate_memory(
     steps_per_dispatch: int = 1,
     serving: Optional[ServingMemorySpec] = None,
     kv_cache_bytes: int = 0,
+    slot_shard_ways: Optional[Sequence[int]] = None,
 ) -> OpStepMemory:
     """Step residency of one op from its (piece) TensorShapes.
 
@@ -166,7 +192,13 @@ def estimate_memory(
     one decode window over a persistent cache, not K training batches),
     plus `kv_cache_bytes` — the caller's per-device cache share from
     `kv_cache_piece_bytes` (this function sees piece TensorShapes only,
-    which carry no degrees)."""
+    which carry no degrees). `slot_shard_ways[i]` is `update_shard_ways`
+    of weight slot i, for the same reason the caller's to give: each
+    optimizer slot is resident at 1/ways of its weight's piece. The
+    weight term stays the whole piece: the float32 master is stored at
+    1/ways too, but the step gathers the copy it computes with, and the
+    `weights` term stands for that copy (in the compute dtype it is
+    smaller: the term errs high)."""
     from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
 
     k = 1 if serving is not None else max(int(steps_per_dispatch), 1)
@@ -197,7 +229,13 @@ def estimate_memory(
         activation_grads=in_bytes,
         weights=w_bytes,
         weight_grads=w_bytes,
-        optimizer_state=max(int(optimizer_state_slots), 0) * w_bytes,
+        optimizer_state=max(int(optimizer_state_slots), 0) * sum(
+            -(-s.size_bytes // max(int(ways), 1))
+            for s, ways in zip(
+                weight_shapes or (),
+                slot_shard_ways or [1] * len(weight_shapes or ()),
+            )
+        ),
         outputs=out_bytes,
         output_grads=out_bytes,
     )
@@ -276,7 +314,14 @@ def leaf_step_memory_bytes(
     from flexflow_tpu.local_execution.training_backing import split_slot_values
 
     data, weights = split_slot_values(attrs, in_pieces)
+    # the slots at their update shard: view-independent like the pieces
+    # (the weight's own replica degree, not the mesh's size)
+    slot_ways = [
+        update_shard_ways(s)
+        for s in split_slot_values(attrs, list(leaf.input_shapes))[1]
+    ]
     if not weights:
+        slot_ways = None
         try:
             weights = get_weight_shapes(attrs, list(data))
         except (AssertionError, IndexError, ValueError, TypeError):
@@ -302,6 +347,7 @@ def leaf_step_memory_bytes(
         steps_per_dispatch=k,
         serving=serving,
         kv_cache_bytes=cache_bytes,
+        slot_shard_ways=slot_ways,
     )
     if ctx is not None and serving is None:
         # 1F1B activation stashing (ISSUE 13): inside a pipeline region an

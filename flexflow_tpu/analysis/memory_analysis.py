@@ -13,7 +13,9 @@ analysis/memory_accounting.leaf_step_memory_bytes).
 The liveness model (forward ticks 0..N-1 over the topological order,
 backward ticks N..2N-1 in reverse):
 
-- parameters: weight + grad + optimizer slots resident the WHOLE step,
+- parameters: weight + grad resident the WHOLE step and each optimizer
+  slot at its update shard (the weight's piece over the replicas the
+  executor cuts it by, `memory_accounting.update_shard_ways`),
   charged at each CONSUMING op's weight slots in the sharded form that op
   reads (the executor places weights under their post-reshard sharding
   from init, so the unsharded Weight layer and its reshard chain hold no
@@ -81,6 +83,7 @@ from flexflow_tpu.analysis.memory_accounting import (
     ServingMemorySpec,
     kv_cache_piece_bytes,
     leaf_step_memory_bytes,
+    update_shard_ways,
 )
 
 MEMORY_RULE_IDS = ("MEM001", "MEM002", "MEM003", "MEM004", "MEM005")
@@ -198,6 +201,9 @@ def analyze_memory(
     slots = 0 if serving is not None else max(int(optimizer_state_slots), 0)
 
     ndev = machine_spec.num_devices if machine_spec is not None else 1
+    # the mesh the executor replicates a weight over (None: not known,
+    # and a slot is cut by its weight's own replica degree alone)
+    machine_devices = ndev if machine_spec is not None else None
     devices = list(range(max(ndev, 1)))
     # per device: resident bytes by category + interval events
     resident: Dict[int, Dict[str, int]] = {
@@ -264,16 +270,23 @@ def analyze_memory(
             )
 
             _, weight_vals = split_slot_values(attrs, list(ins))
-            w_bytes = sum(
-                get_piece_shape(pcg.tensor_shape(v)).size_bytes
-                for v in weight_vals
+            w_shapes = [
+                pcg.tensor_shape(v) for v in weight_vals
                 if _from_weight(pcg, v)
-            )
+            ]
+            w_bytes = sum(get_piece_shape(s).size_bytes for s in w_shapes)
             if w_bytes:
                 charge_resident(devs, "params", w_bytes)
                 if serving is None:
                     charge_resident(devs, "grads", w_bytes)
-                    charge_resident(devs, "opt_state", slots * w_bytes)
+                    # a slot lives at its weight's update shard (the
+                    # executor cuts it over every axis the weight is
+                    # replicated on: update_shard_ways)
+                    charge_resident(devs, "opt_state", slots * sum(
+                        -(-get_piece_shape(s).size_bytes
+                          // update_shard_ways(s, machine_devices))
+                        for s in w_shapes
+                    ))
         if serving is not None and isinstance(attrs, MultiHeadAttentionAttrs):
             # the persistent KV cache: resident across the whole serving
             # dispatch on this op's devices, sharded with the op's own

@@ -320,7 +320,10 @@ def _weight_state_by_device(
     charges (value + grad are NOT double-counted here: at a swap
     boundary the step is quiesced, so the co-resident state is the
     checkpoint-carried set — params + moments)."""
-    from flexflow_tpu.analysis.memory_accounting import estimate_memory
+    from flexflow_tpu.analysis.memory_accounting import (
+        estimate_memory,
+        update_shard_ways,
+    )
     from flexflow_tpu.op_attrs.parallel_tensor_shape import get_piece_shape
 
     per_mult = 1 + max(int(optimizer_state_slots), 0)
@@ -329,18 +332,22 @@ def _weight_state_by_device(
     by_leaf: Dict[str, Dict[int, int]] = {}
     for path, (n, v, pts) in weight_leaves(pcg).items():
         piece = get_piece_shape(pts).size_bytes
-        # estimate_memory's weight term at slots=per_mult-1 yields
-        # weights + optimizer_state = piece * per_mult; spelled directly
-        # on the shared primitive so the accounting cannot drift
+        # at rest the executor stores the float32 master like a slot: cut
+        # over every axis the plan replicates the weight on
+        # (update_shard_ways). estimate_memory's optimizer term at
+        # slots=per_mult is that set; spelled on the shared primitive so
+        # the accounting cannot drift
+        ways = update_shard_ways(pts, machine_spec and ndev)
         mem = estimate_memory(
             pcg.op_attrs(n),
             [],
             [get_piece_shape(pts)],
             [],
-            optimizer_state_slots=per_mult - 1,
+            optimizer_state_slots=per_mult,
+            slot_shard_ways=[ways],
         )
-        state = mem.weights + mem.optimizer_state
-        assert state == piece * per_mult
+        state = mem.optimizer_state
+        assert state == per_mult * -(-piece // ways)
         devs = _leaf_devices(pcg, n, machine_spec, mapping)
         by_leaf[path] = {d: state for d in devs}
         for d in devs:

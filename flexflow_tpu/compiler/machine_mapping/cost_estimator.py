@@ -465,7 +465,10 @@ def parallel_op_cost_ms(
                 # weight-sharded plans on the CPU test mesh
                 return 2 * latency_ms + k * total_bytes / per_ms
             # replicated parameters are resident (no per-step broadcast);
-            # the recurring cost is the bwd gradient all-reduce
+            # the recurring cost is the bwd gradient all-reduce, which the
+            # executor runs as its two halves around the sharded update
+            # (reduce-scatter of the gradient, all-gather of the weight's
+            # compute copy next step): the same bytes over the wire
             return 2 * latency_ms + 2 * total_bytes / per_ms
         # fwd broadcast + bwd grad all-reduce (~2x over the wire)
         return 3 * latency_ms + 3 * total_bytes / per_ms

@@ -151,7 +151,11 @@ def _templates_for(
     if kind == "ReplicateAttrs":
         if weight_resident:
             # resident replicas; the recurring collective is the bwd
-            # gradient all-reduce (the per-step DP weight sync)
+            # gradient all-reduce (the per-step DP weight sync), lowered
+            # as a reduce-scatter into the shard the update runs on; the
+            # all-gather of the weight's compute copy that completes it
+            # the census holds to the update rule, not to this pool
+            # (comm_analysis.cross_check_comm, `weight_gather_bytes`)
             return ((REDUCE, t), (GATHER, t)), t
         # fwd broadcast (often elided when the value is already
         # replicated) + bwd gradient all-reduce

@@ -733,6 +733,10 @@ class FFModel:
             if isinstance(self.search_provenance, dict)
             else None
         )
+        if prov is not None and getattr(self.instance, "update_record", None):
+            # which leaves the executor stores and updates cut over the
+            # axes the plan replicates them on, and which not
+            prov["update_sharding"] = dict(self.instance.update_record)
         has_mem = prov is not None and "memory" in prov
         has_comm = prov is not None and isinstance(prov.get("comm"), dict)
         can_lower = (
@@ -1369,6 +1373,7 @@ class FFModel:
             comm_summary_json,
             cross_check_comm,
             extract_collectives,
+            predicted_weight_gather_bytes,
         )
         from flexflow_tpu.analysis.diagnostics import (
             summarize as _verify_summarize,
@@ -1386,6 +1391,7 @@ class FFModel:
             ctx["predictions"],
             extract_collectives(lowered.hlo_text()),
             bypassed_nodes=ctx["bypassed"],
+            weight_gather_bytes=predicted_weight_gather_bytes(self.instance),
         )
         diags = comm_diagnostics(analysis)
         summary = comm_summary_json(analysis)

@@ -3,10 +3,21 @@ optimizer_kernels.h — sgd/adam_{ps,nccl}_update_task_gpu,
 src/cuda/optimizer_kernel.cu).
 
 The reference splits updates into PS (sum replica grads on shard 0) vs NCCL
-(allreduce in place, update everywhere). On TPU, gradient sync is a psum baked
-into the jitted train step by the distributed lowering, so the update kernels
-here are the pure per-parameter math, applied identically on every device —
-exactly the NCCL variant's post-allreduce behavior.
+(allreduce in place, update everywhere). On TPU, gradient sync is a
+collective baked into the jitted train step by the distributed lowering, so
+the update kernels here are the pure per-parameter math, on whatever part of
+a parameter the caller hands them:
+
+- the single-device and data-parallel backends hand every device the whole
+  leaf after the all-reduce: the NCCL variant's post-allreduce behaviour;
+- the searched executor (`parallel/executor.py`) hands each device the
+  shard of the leaf that it stores: the float32 master and the optimizer
+  slots of a weight live cut over every mesh axis the plan replicates the
+  weight on, `apply_optimizer`'s `grads_at` has the gradient reduced into
+  that shard (reduce-scatter), the update runs on 1/replicas of the leaf,
+  and the next step all-gathers the copy it computes with (in the compute
+  dtype). That is the variant XLA's TPU partitioner was built for (Xu et
+  al., arXiv:2004.13336), and the third the reference does not have.
 """
 
 from __future__ import annotations
@@ -85,12 +96,20 @@ def barrier_grads(grads):
     return jax.lax.optimization_barrier(grads)
 
 
-def apply_optimizer(attrs: OptimizerAttrs, params: Dict, grads: Dict, state: Dict):
+def apply_optimizer(
+    attrs: OptimizerAttrs, params: Dict, grads: Dict, state: Dict,
+    grads_at=None,
+):
     """Apply one update across a parameter pytree. Returns (params, state).
 
     Applies barrier_grads so every training backend gets the anti-fusion
-    barrier (jitted callers; a no-op cost for eager execute_update)."""
+    barrier (jitted callers; a no-op cost for eager execute_update).
+    `grads_at(grads)` places the gradients where the update is computed:
+    the searched executor constrains each to the sharding its parameter
+    and slots are stored at."""
     grads = barrier_grads(grads)
+    if grads_at is not None:
+        grads = grads_at(grads)
     step = state["step"] + 1
     if isinstance(attrs, SGDOptimizerAttrs):
         if attrs.momentum > 0.0:

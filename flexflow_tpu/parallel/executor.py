@@ -44,7 +44,7 @@ from flexflow_tpu.pcg.machine_view import MachineView
 from flexflow_tpu.pcg.optimizer import OptimizerAttrs
 from flexflow_tpu.pcg.parallel_computation_graph import ParallelComputationGraph
 from flexflow_tpu.parallel.mesh import MachineMesh
-from flexflow_tpu.parallel.sharding import pcg_shardings
+from flexflow_tpu.parallel.sharding import pcg_shardings, update_partition_spec
 from flexflow_tpu.utils.graph import DataflowOutput, Node
 
 
@@ -864,6 +864,11 @@ class DistributedTrainingInstance:
         # (params, opt_state) shardings, recorded by initialize(): the
         # step programs hand the new state back under exactly these
         self._state_shardings = None
+        # param key -> NamedSharding of the leaves whose master, slots and
+        # update are cut finer than the PCG places the weight, and what
+        # the rule did in numbers; both set with _state_shardings
+        self.update_shardings: Dict[str, object] = {}
+        self.update_record: Optional[dict] = None
         self._jit_step = None
         self._jit_multi_step = None
         self._jit_fwd = None
@@ -920,47 +925,115 @@ class DistributedTrainingInstance:
         return NamedSharding(self.machine_mesh.mesh, P(*spec))
 
     def initialize(self, seed: int = 0):
-        """Global init + placement onto the mesh (sharded weight, replicated
-        optimizer moments sharded like their weight)."""
+        """Global init + placement onto the mesh. The float32 master of each
+        weight and its optimizer slots live at the weight's update sharding
+        (`update_partition_spec`: the PCG's sharding of the weight plus every
+        mesh axis the PCG replicates it over); the scalar step is
+        replicated. What the PCG places is the copy the step computes with:
+        the interpreter's constraint on the cast weight gathers it."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         params = init_pcg_params(self.pcg, jax.random.PRNGKey(seed))
         from flexflow_tpu.runtime.distributed import device_put_global
 
-        placed: Dict[str, jnp.ndarray] = {}
-        for n in self.pcg.topological_ordering():
-            if isinstance(self.pcg.op_attrs(n), WeightAttrs):
-                k = param_key(n)
-                s = self._weight_sharding(n)
-                # every process computes the identical init (same PRNGKey);
-                # device_put_global places only the shards this host owns
-                placed[k] = (
-                    device_put_global(params[k], s)
-                    if s is not None
-                    else params[k]
-                )
+        replicated = NamedSharding(self.machine_mesh.mesh, P())
+        weight_shardings = {
+            # an unconstrained weight counts as replicated
+            param_key(n): self._weight_sharding(n) or replicated
+            for n in self.pcg.topological_ordering()
+            if isinstance(self.pcg.op_attrs(n), WeightAttrs)
+        }
+        self._set_state_shardings(weight_shardings, params)
+        param_at, opt_at = self._state_shardings
+        # every process computes the identical init (same PRNGKey);
+        # device_put_global places only the shards this host owns
+        placed = {
+            k: device_put_global(params[k], param_at[k]) for k in param_at
+        }
+        # the slots are born at their shardings: a whole zero moment per
+        # chip, placed and then cut, would be the start-up's memory peak
+        opt_state = jax.jit(
+            lambda p: make_optimizer_state(self.optimizer_attrs, p),
+            out_shardings=opt_at,
+        )(placed)
+        return placed, opt_state
+
+    def _set_state_shardings(self, weight_shardings, params) -> None:
+        """Record the (params, opt_state) shardings of `params` (arrays or
+        shapes) whose PCG placements are `weight_shardings`: each leaf and
+        its slots at the leaf's update sharding. `update_shardings` keeps
+        the leaves that cuts finer than the PCG does; `update_record` says
+        what the rule did, for `search_provenance["update_sharding"]` and
+        the static verifiers: how many leaves are cut finer and over which
+        axes, which stayed as placed, the bytes a device of one copy of the
+        leaves as the PCG places them and as they are stored (the weight,
+        and each slot), and the float32 bytes a device gathers for the
+        copies the step computes with (what the COMM census holds the
+        weights' all-gathers to)."""
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
         mesh = self.machine_mesh.mesh
-        replicated = NamedSharding(mesh, P())
-
-        def on_mesh(x):
-            # unconstrained weights and the optimizer's scalar slots are
-            # born on the default device; the step returns them replicated
-            s = x.sharding
-            return (
-                s if isinstance(s, NamedSharding) and s.mesh == mesh
-                else replicated
+        names = {
+            param_key(n): self.pcg.layer_attrs(n).name or param_key(n)
+            for n in self.pcg.topological_ordering()
+            if isinstance(self.pcg.op_attrs(n), WeightAttrs)
+        }
+        state_at = dict(weight_shardings)
+        self.update_shardings = {}
+        axes_count: Dict[str, int] = {}
+        whole, placed_bytes, stored_bytes, gathered = [], 0, 0, 0
+        for k, s in weight_shardings.items():
+            piece = int(
+                np.prod(s.shard_shape(params[k].shape), dtype=np.int64)
+            ) * params[k].dtype.itemsize
+            placed_bytes += piece
+            spec, axes = update_partition_spec(
+                params[k].shape, s.spec, dict(mesh.shape)
             )
-
-        state = (placed, make_optimizer_state(self.optimizer_attrs, placed))
-        self._state_shardings = jax.tree_util.tree_map(on_mesh, state)
-        return jax.tree_util.tree_map(
-            # a leaf already there is left alone: across processes it is a
-            # global array no single host can read back to re-place
-            lambda x, s: x if x.sharding == s else device_put_global(x, s),
-            state, self._state_shardings,
+            if not axes:
+                whole.append(names.get(k, k))
+                stored_bytes += piece
+                continue
+            state_at[k] = NamedSharding(mesh, P(*spec))
+            self.update_shardings[k] = state_at[k]
+            axes_count[",".join(axes)] = axes_count.get(",".join(axes), 0) + 1
+            stored_bytes += piece // _mesh_axes_size(mesh, axes)
+            gathered += piece
+        self.update_record = {
+            "mesh": dict(mesh.shape),
+            "leaves_sharded": len(self.update_shardings),
+            "axes": axes_count,
+            "leaves_whole": sorted(whole),
+            "bytes_per_device_as_placed": placed_bytes,
+            "bytes_per_device": stored_bytes,
+            "gather_bytes_per_device": gathered,
+        }
+        opt_shapes = jax.eval_shape(
+            lambda p: make_optimizer_state(self.optimizer_attrs, p), params
+        )
+        replicated = NamedSharding(mesh, P())
+        self._state_shardings = (
+            state_at,
+            {
+                name: state_at if isinstance(slot, dict) else replicated
+                for name, slot in opt_shapes.items()
+            },
         )
 
     # -- step --------------------------------------------------------------
+
+    def _at_update_shardings(self, grads):
+        """Each gradient constrained to where its weight's update is
+        computed (where the weight's master and slots live), so that XLA
+        reduces it into a shard (a reduce-scatter) and not into a copy a
+        chip. No leaf has one on a single chip, or under a plan that shards
+        every weight over every axis: the lowered step is then what it was
+        without the rule."""
+        at = self.update_shardings
+        return {
+            k: jax.lax.with_sharding_constraint(g, at[k]) if k in at else g
+            for k, g in grads.items()
+        }
 
     def loss_fn(self, params, batch_inputs, label, rng=None):
         with trace.step_scope("cast"):
@@ -990,7 +1063,8 @@ class DistributedTrainingInstance:
         )
         with trace.step_scope("optimizer"):
             new_params, new_opt_state = apply_optimizer(
-                self.optimizer_attrs, params, grads, opt_state
+                self.optimizer_attrs, params, grads, opt_state,
+                grads_at=self._at_update_shardings,
             )
         with trace.step_scope("metrics"):
             metric_vals = compute_metrics(self.metrics, logit, label)

@@ -160,3 +160,41 @@ def pcg_shardings(
             for t in chain:
                 out[t] = out[v]
     return out
+
+
+def update_partition_spec(shape, spec, mesh_shape):
+    """Where a weight's update is computed, and its float32 master and
+    optimizer slots live: the weight's own partition `spec` plus every mesh
+    axis the weight is replicated over, all on the weight's first dimension
+    whose local extent those axes divide. Returns (spec entries padded to
+    the rank, the axes added); a leaf with no free axis, or one that no
+    dimension takes (a vector of odd length), keeps the weight's own spec
+    and adds ().
+
+    The weight-update sharding of Xu et al. (arXiv:2004.13336): a gradient
+    all-reduce is a reduce-scatter and an all-gather, and Adam between the
+    two runs on 1/replicas of the leaf with 1/replicas of its moments."""
+    entries = [
+        tuple(e) if isinstance(e, (tuple, list)) else ((e,) if e else ())
+        for e in tuple(spec)
+    ]
+    entries += [()] * (len(shape) - len(entries))
+    used = {a for e in entries for a in e}
+    free = tuple(a for a, n in mesh_shape.items() if n > 1 and a not in used)
+    ways = 1
+    for a in free:
+        ways *= mesh_shape[a]
+    added = ()
+    if free:
+        for d, size in enumerate(shape):
+            held = 1
+            for a in entries[d]:
+                held *= mesh_shape[a]
+            if size % (held * ways) == 0:
+                entries[d] = entries[d] + free
+                added = free
+                break
+    return (
+        tuple(None if not e else (e[0] if len(e) == 1 else e) for e in entries),
+        added,
+    )

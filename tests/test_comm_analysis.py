@@ -270,7 +270,12 @@ class TestCommRules:
         comm002 = [d for d in diags if d.rule_id == "COMM002"]
         assert comm002, [str(d) for d in diags]
         assert "over_replicate" in comm002[0].message
-        assert not analysis.collectives  # truly nothing lowered
+        # truly nothing lowered but the gather of the weight's compute copy
+        # (its master is stored cut over the 8 devices that replicate it),
+        # which the census holds to the update rule and not to this edge
+        assert analysis.weight_gathers == analysis.collectives
+        assert [c.bytes for c in analysis.collectives] == [64 * 256 * 4]
+        assert analysis.weight_gather_bytes == 64 * 256 * 4
 
     def test_comm003_bytes_band(self):
         """A synthetic census whose only realization is far smaller than
@@ -452,6 +457,7 @@ COMM_SUMMARY_FIELDS = (
     "unmatched",
     "unmatched_bytes",
     "unmatched_collectives",
+    "weight_gathers",
 )
 
 COMM_EDGE_FIELDS = (

@@ -226,16 +226,24 @@ class TestLivenessAnalysis:
             assert ana.tick_labels[d.peak_tick].startswith("bwd")
 
     def test_resident_matches_param_accounting(self):
-        # 2 weights of 256x256 f32: params+grads+2 slots = 4x, plus the
-        # batch window (K=1) — nothing else is whole-step resident
+        # 2 weights of 256x256 f32: params + grads whole, the 2 slots at
+        # their update shard (the executor cuts a slot over every axis its
+        # weight is replicated on: all 8 devices here), plus the batch
+        # window (K=1) — nothing else is whole-step resident
         pcg = _mlp_pcg(width=256, batch=64)
         ana = analyze_memory(pcg, SPEC8, optimizer_state_slots=2)
         w = 2 * 256 * 256 * 4
         batch = 64 * 256 * 4
         assert all(
-            d.resident_bytes == 4 * w + batch
+            d.resident_bytes == 2 * w + 2 * w // 8 + batch
             for d in ana.per_device.values()
         )
+        # without a machine the mesh is not known: a slot is cut by its
+        # weight's own replica degree alone (1 here), the old accounting
+        (alone,) = analyze_memory(
+            pcg, None, optimizer_state_slots=2
+        ).per_device.values()
+        assert alone.resident_bytes == 4 * w + batch
 
     def test_window_buffer_scales_with_k(self):
         pcg = _mlp_pcg(width=256, batch=64)

@@ -336,8 +336,8 @@ def test_weight_repartition_chain_rests_fully_sharded():
 def test_biased_attention_data_parallel_step_on_four_devices(monkeypatch):
     """The batch-parallel template carries [b/4, s, e] into BERT's biased
     attention, so the executor's flash path shards it over the batch: the
-    compiled step gathers nothing and all-reduces each gradient once (and
-    the loss), never an activation; one step equals a single device's."""
+    compiled step gathers no activation and reduces each gradient once (and
+    the loss); one step equals a single device's."""
     import re
 
     from flexflow_tpu.analysis.lowering import lower_step_trace
@@ -385,6 +385,15 @@ def test_biased_attention_data_parallel_step_on_four_devices(monkeypatch):
             r"collective-permute)(-start)?\(", line,
         )
     ]
+    # since PR 30 a replicated weight is stored, and updated, a quarter a
+    # chip: the only gathers are those of the weights' compute copies
+    gathers = [c for c in collectives if " all-gather(" in c]
+    collectives = [c for c in collectives if c not in gathers]
+    assert len(gathers) >= len(four.instance.update_shardings) > 0
+    weight_shapes = {tuple(v.shape) for v in four.params.values()}
+    for c in gathers:
+        (dims,) = re.findall(r"= f32\[([0-9,]*)\]", c)
+        assert tuple(int(d) for d in dims.split(",")) in weight_shapes, c
     assert collectives and all(" all-reduce(" in c for c in collectives)
     assert not any("/psum" in c for c in collectives)
     reduced = sum(

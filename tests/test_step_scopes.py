@@ -231,8 +231,10 @@ def test_searched_step_scopes_parallel_ops_and_collectives(searched_text):
     names = lowered_op_names(searched_text)
     parsed = {trace.parse_scope(n) for _, n in names}
     assert any(kind.startswith("parallel_") for _, kind, _ in parsed), parsed
-    # a parameter's reshard is booked to the parameter
-    assert any(kind == "weight" for _, kind, _ in parsed), parsed
+    # a parameter's reshard is booked to the parameter or, where the step
+    # computes in another dtype, to the cast whose result is resharded:
+    # since PR 30 that is the all-gather of the weight's compute copy
+    assert any(kind in ("weight", "cast") for _, kind, _ in parsed), parsed
     assert [n for _, n in names if trace.parse_scope(n)[0] == "unattributed"] == []
     collectives = [
         line for line in searched_text.split("\n") if COLLECTIVE.search(line)
@@ -241,6 +243,12 @@ def test_searched_step_scopes_parallel_ops_and_collectives(searched_text):
     for line in collectives:
         m = OP_NAME.search(line)
         assert m and trace.parse_scope(m.group(1))[0] != "unattributed", line
+    gathers = [line for line in collectives if " all-gather(" in line]
+    assert gathers and all(
+        trace.parse_scope(OP_NAME.search(line).group(1))[1]
+        in ("weight", "cast")
+        for line in gathers
+    )
     # the pinned reduction's psum is the row-parallel dense's, both ways
     owners = {
         trace.parse_scope(OP_NAME.search(line).group(1))

@@ -167,15 +167,19 @@ class TestRuleNegatives:
 
 class TestMigrationPeak:
     def test_dp8_to_tp4_linear_co_residency(self):
-        """One Linear [32x64] f32 leaf, SGD-with-momentum-free default
-        (2 optimizer slots -> x3 state multiplier):
+        """One Linear [32x64] f32 leaf, 2 optimizer slots: at rest the
+        executor stores the weight's master and both slots cut over every
+        axis the plan replicates the weight on (`update_shard_ways`):
 
-        dp8 src: weight replicated, piece = 32*64*4       = 8192 B/device
-        tp4 dst: out-dim sharded 4-way, piece = 32*16*4   = 2048 B/device
-        bulk peak     = 3*(8192 + 2048)                   = 30720 B
-        streamed peak = 3*8192 + 3*(8192 + 2048)          = 55296 B
+        dp8 src: weight replicated, piece = 32*64*4 = 8192 B, cut 8 ways:
+                 3 * 1024                                 = 3072 B/device
+        tp4 dst: out-dim sharded 4-way, piece = 32*16*4 = 2048 B, the 2
+                 replicas left cut it again: 3 * 1024     = 3072 B/device
+        bulk peak     = 3072 + 3072                       = 6144 B
+        streamed peak = 3072 + (3072 + 3072)              = 9216 B
         (single leaf: the streamed bound's rest-of-state term and the
-        in-flight leaf are the same leaf, so streamed > bulk)
+        in-flight leaf are the same leaf, so streamed > bulk; what MOVES is
+        still value + both moments whole: 3*8192)
         """
         spec = _flat_spec()
         old_pcg, old_map = _mapped_seed(_linear(), "dp8xtp1xsp1", spec)
@@ -191,20 +195,20 @@ class TestMigrationPeak:
         assert leaf.dst_piece_bytes == 2048
         assert leaf.moved and leaf.moved_bytes == 3 * 8192
         assert leaf.link_class == "ici"
-        assert a.bulk_peak_bytes == 30720
-        assert a.streamed_peak_bytes == 55296
+        assert a.bulk_peak_bytes == 6144
+        assert a.streamed_peak_bytes == 9216
         assert a.migration_verdict == "bulk"
         assert a.verdict == "swappable"
 
     def test_tight_hbm_flips_to_over(self):
-        # 30000 B sits below the 30720 B bulk peak AND below the 55296 B
+        # 6000 B sits below the 6144 B bulk peak AND below the 9216 B
         # streamed bound: the migration is infeasible, not just streamed
         spec = _flat_spec()
         old_pcg, old_map = _mapped_seed(_linear(), "dp8xtp1xsp1", spec)
         new_pcg, new_map = _mapped_seed(_linear(), "dp2xtp4xsp1", spec)
         a, _ = verify_transition(
             old_pcg, old_map, new_pcg, new_map,
-            machine_spec=spec, hbm_bytes=30000.0,
+            machine_spec=spec, hbm_bytes=6000.0,
         )
         assert a.migration_verdict == "over"
         assert a.rules_tripped == ["TRN002"]
