@@ -644,13 +644,13 @@ def _batch_block(
     """Batch rows folded into ONE kernel program (bshf path).
 
     At [512, 64]-shaped per-head tiles a program's compute is sub-µs while
-    its fixed launch cost is ~2.5µs — the headline step spent ~62 ms on
-    ~25k program launches. Folding BB batch rows per program divides the
-    launch count by BB; the cap keeps the whole per-program VMEM residency
-    within budget — not just the f32 score tile but also the K/V blocks
-    (full local sequence, 2*s*d per row) plus the q/out/acc tiles, all of
-    which scale with BB. Override via FLEXFLOW_TPU_FLASH_BATCH_BLOCK
-    (1 = the old one-row-per-program grid).
+    its fixed launch cost is ~2.5µs: folding BB batch rows per program
+    divides the launch count by BB. The budget keeps the per-program VMEM
+    residency in bounds where the [s, s] tiles dominate it (K/V blocks of
+    the full local sequence, q/out/acc tiles: all scale with BB), and
+    _MAX_FOLD bounds BB where they do not (short sequences; see there).
+    Override via FLEXFLOW_TPU_FLASH_BATCH_BLOCK (1 = the old
+    one-row-per-program grid).
     """
     import os
 
@@ -664,13 +664,13 @@ def _batch_block(
         budget = 16 * 1024 * 1024
         score = 3 * block_q * block_k * 4
         resident = bwd_blocks * s * d * itemsize
-        bb = max(1, budget // max(1, score + resident))
+        bb = min(_MAX_FOLD, max(1, budget // max(1, score + resident)))
     else:
         budget = 12 * 1024 * 1024  # VMEM bytes per program
         score = 2 * block_q * block_k * 4  # f32 scores + exp tile
         resident = (2 * s + 2 * block_q) * d * itemsize  # k+v, q+out
         acc = block_q * d * 4
-        bb = max(1, budget // max(1, score + resident + acc))
+        bb = min(_MAX_FOLD, max(1, budget // max(1, score + resident + acc)))
     bb = min(bb, b)
     while b % bb != 0:
         bb -= 1
@@ -766,8 +766,8 @@ def _fwd_pair_call(
     scale = 1.0 / (d**0.5)
     bb = _batch_block(b, block_q, block_k, s, 128, dtype.itemsize)
     kernel = functools.partial(
-        _fwd_kernel_pair, causal=causal, block_k=block_k, scale=scale, d=d,
-        pid_axis=2,
+        _pair_fwd_kernel(s, block_q, block_k), causal=causal,
+        block_k=block_k, scale=scale, d=d, pid_axis=2,
     )
     q_map, k_map, v_map = qkv_index_maps
     o, lse = pl.pallas_call(
@@ -1615,13 +1615,13 @@ def bshf_pair_supported(num_heads: int, d: int, s: int) -> bool:
 
 
 def _min_seq_default() -> int:
-    """Crossover sequence length below which XLA's fused dense attention
-    wins (overridable for benchmarking/tests via FLEXFLOW_TPU_FLASH_MIN_SEQ).
-    Measured on the bench chip with 1024-blocks: flash beats dense at every
-    length from 512 up (66.6% vs 60.6% whole-model MFU at seq 512)."""
-    import os
-
-    return int(os.environ.get("FLEXFLOW_TPU_FLASH_MIN_SEQ", "512"))
+    """Least (local) sequence length of a kernel route that no measurement
+    has placed: MIN_SEQ_UNMEASURED, 512 (overridable for benchmarking and
+    tests via FLEXFLOW_TPU_FLASH_MIN_SEQ). The ring and all-to-all sequence-
+    parallel kernels read it as their least local block; the routes of
+    `ops.mha_core_route` read `min_seq_for`, whose table (at the end of this
+    file) holds what was measured, route by route."""
+    return min_seq_for()
 
 
 def _flash_shape_ok(shape: Tuple[int, ...], min_seq: int) -> bool:
@@ -1640,10 +1640,10 @@ def flash_attention_supported(
     q_shape: Tuple[int, ...], k_shape, v_shape, min_seq: int = None
 ) -> bool:
     """Static gate: TPU backend, self-attention-shaped, block-aligned, and
-    long enough that blockwise beats XLA's fused dense attention (with
-    1024-blocks the measured crossover on the bench chip is at seq 512 —
-    see _min_seq_default; flash additionally avoids materializing the
-    [s, s] scores)."""
+    at least `min_seq` long: the length from which the caller's route beats
+    XLA's dense attention, which keeps the [b, h, s, s] probabilities in
+    HBM for the backward (`min_seq_for`; _min_seq_default for a caller that
+    names no route)."""
     if getattr(_tls, "disabled", False):
         return False
     if not _backend_ok():
@@ -1702,17 +1702,17 @@ def sharded_flash_supported(
     return _flash_shape_ok((b // db, h // dh, s, d), min_seq)
 
 
-def flash_core_supported(q_shape, k_shape, v_shape) -> bool:
-    """The static gate of the trace a kernel would be emitted into: with no
-    mesh declared, flash_attention_supported on the [b, h, s, d] shapes;
-    under a declared `flash_mesh`, sharded_flash_supported on the block
-    each device sees."""
+def flash_core_supported(q_shape, k_shape, v_shape, family=None) -> bool:
+    """The static gate of the trace a kernel of `family` (`min_seq_for`)
+    would be emitted into: flash_attention_supported on the [b, h, s, d]
+    shapes, or under a `flash_mesh` on the block each device sees."""
+    least = min_seq_for(family)
     ctx = current_flash_mesh()
     if ctx is None:
-        return flash_attention_supported(q_shape, k_shape, v_shape)
+        return flash_attention_supported(q_shape, k_shape, v_shape, least)
     mesh, batch_axes, head_axes, interpret = ctx
     return k_shape == q_shape == v_shape and sharded_flash_supported(
-        q_shape, mesh, batch_axes, head_axes, interpret=interpret
+        q_shape, mesh, batch_axes, head_axes, least, interpret=interpret
     )
 
 
@@ -2056,3 +2056,112 @@ def _flash_bshf_causal_bwd(h, causal, block_q, block_k, interpret, explicit,
 
 
 _flash_bshf_causal.defvjp(_flash_bshf_causal_fwd, _flash_bshf_causal_bwd)
+
+
+# ---------------------------------------------------------------------------
+# where the kernels start: the least length per route, the fold, and the
+# head-pair forward of a short sequence
+# ---------------------------------------------------------------------------
+#
+# At the end of the file for the reason the causal schedule is: the seq-512
+# cells' lowered text holds the line of every frame above.
+
+# Most batch rows one program of the bshf kernels folds. _batch_block's
+# budgets were fitted on [512, 64] tiles, where the f32 [s, s] tiles are the
+# residency, and there they allow 4. At s = 128 the double-buffered blocks
+# and the lane-padded 64-wide halves outweigh the tiles: the compiler's
+# stack is 710 KB a row against 327,680 B budgeted, and the 32 rows the
+# budget allows ask for 22.72 MB of the 16 MB scoped VMEM (19.36 MB for 16
+# rows at s = 256). Measured, head-pair forward / fused backward, ms a call
+# at 8,192 positions, 16 heads of 64, bf16 (my chip runs, PR 31): s = 128,
+# 2 rows 0.572 / 0.395, 4 rows 0.391 / 0.292, 8 rows 0.335 / 0.327, 16 rows
+# 0.578 / 0.305; s = 256, 2 rows 0.582 / 0.389, 4 rows 0.491 / 0.355, 8
+# rows 0.442 / 0.324.
+_MAX_FOLD = 8
+
+# The least (local) sequence length at which each kernel family of
+# `ops.mha_core_route` takes over from XLA's dense attention, which writes
+# the [b, h, s, s] probabilities to HBM and reads them back in the backward.
+# Whole cells at 8,192 tokens a step on one v5e, dense -> kernels (my chip
+# runs, PR 31; `tokens_per_s`, `step_hbm_gb`):
+#   "pair" (d = 64 head-pair kernels; bert-large-uncased, 24 layers):
+#     seq 128: 57,630 -> 62,333 (+8.2%), 10.484 -> 9.442 GB, before the
+#     transposed forward below; seq 256: step 150.4 -> 134.9 ms (-10.3%),
+#     busy MFU 57.63 -> 64.31%, 11.276 -> 9.442 GB;
+#   "lane" (d % 128 == 0 fused rows; cerebras-gpt-1.3b, 8 layers, causal):
+#     seq 256: 44,318 -> 44,664 and 44,333 -> 44,646 (+0.8%, +0.7%), 12.200
+#     -> 11.651 GB; seq 128: 44,919 -> 44,931 (even), so 256;
+#   "rows" (per-head [b, h, s, d] entry; 16 heads of 96, hidden 1536, 12
+#     layers): seq 256: 53,941 -> 53,010 (-1.7%); seq 128: 55,883 -> 53,695
+#     (-3.9%): it loses, and keeps MIN_SEQ_UNMEASURED.
+# The ring and all-to-all sequence-parallel kernels (ring_flash.py,
+# ulysses_attention.py) read MIN_SEQ_UNMEASURED too: no cell runs them.
+MIN_SEQ_UNMEASURED = 512
+_MIN_SEQ = {"pair": 128, "lane": 256, "rows": MIN_SEQ_UNMEASURED}
+
+
+def min_seq_for(family=None) -> int:
+    """The least sequence length of a kernel family: FLEXFLOW_TPU_FLASH_MIN_SEQ
+    where set (benchmarking and tests: one length for every family), else
+    what was measured for it (_MIN_SEQ), else MIN_SEQ_UNMEASURED."""
+    import os
+
+    env = os.environ.get("FLEXFLOW_TPU_FLASH_MIN_SEQ")
+    if env is not None:
+        return int(env)
+    return _MIN_SEQ.get(family, MIN_SEQ_UNMEASURED)
+
+
+# Below this length a head-pair forward of one tile holds its scores
+# TRANSPOSED. From it on _fwd_kernel_pair stands: nothing was measured
+# there, and the seq-512 cells' lowered text is what PR 31 had to leave.
+_PAIR_TRANSPOSED_BELOW = 512
+
+
+def _fwd_kernel_pair_t(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k, scale, d,
+    pid_axis=2,
+):
+    """_fwd_kernel_pair's single-tile case (and its signature: block_k and
+    pid_axis have nothing to say to one tile) with the scores held as [t, s]:
+    keys on the sublanes, queries on the lanes. The row maximum and the row
+    sum of the softmax then run down the sublanes, elementwise over vregs
+    on the VPU, where _one_block_attn_3d reduces across the lanes of every
+    vreg of the tile; lse comes out along the lanes, as lse_ref stores it;
+    and the sum that divides o rides the p @ v matmul as a column of ones
+    beside v's 64 lanes (the MXU is 128 wide either way). Same arithmetic:
+    f32 scores, _exp2_probs, f32 sums. Forward, ms a call at 8,192 positions,
+    16 heads of 64, 8 rows a program (my chip runs, PR 31): s = 128, 0.318
+    -> 0.188; s = 256, 0.422 -> 0.193."""
+    bb, s, _ = q_ref.shape
+    scale2 = scale * LOG2E
+    for h2 in range(2):
+        sl = pl.ds(h2 * d, d)
+        q = q_ref[:, :, sl] * jnp.asarray(scale2, q_ref.dtype)
+        vb = v_ref[:, :, sl]
+        scores = jax.lax.dot_general(
+            k_ref[:, :, sl], q, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [bb, t, s]
+        if causal:
+            keys = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
+            queries = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
+            scores = jnp.where((queries >= keys)[None], scores, NEG_INF)
+        m = scores.max(axis=1)
+        p = _exp2_probs(scores - m[:, None, :], q_ref.dtype)
+        acc = jax.lax.dot_general(
+            p.astype(vb.dtype), jnp.concatenate([vb, jnp.ones_like(vb)], -1),
+            (((1,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # [bb, s, 2d]: p^T v beside the row sums
+        o_ref[:, :, sl] = (acc[:, :, :d] / acc[:, :, d:d + 1]).astype(
+            o_ref.dtype
+        )
+        lse_ref[:, h2, 0, :] = m + jnp.log2(p.astype(jnp.float32).sum(axis=1))
+
+
+def _pair_fwd_kernel(s: int, block_q: int, block_k: int):
+    """The head-pair forward body for a call's shape."""
+    if s < _PAIR_TRANSPOSED_BELOW and block_q == s == block_k:
+        return _fwd_kernel_pair_t
+    return _fwd_kernel_pair

@@ -271,12 +271,12 @@ def mha_core_route(
     - "rows": the per-head [b, h, s, d] projections and `flash_attention`
       (other head sizes; every head-sharded plan, through
       `sharded_flash_attention`);
-    - "dense": XLA's attention.
+    - "dense": XLA's attention, below the route's least length
+      (`flash_attention.min_seq_for`: measured per kernel family).
 
     Under a declared mesh the gates read the block each device sees and the
     fused-row kernels are mapped over the batch shards (`per_batch_shard`);
-    a fused row sharded over heads would need a pair-aligned split, so a
-    head-sharded plan takes "rows"."""
+    a head-sharded plan cannot split a fused row by pairs and takes "rows"."""
     import os
 
     if os.environ.get("FLEXFLOW_TPU_FLASH", "1") == "0":
@@ -296,21 +296,21 @@ def mha_core_route(
     # kd % 128: blocks carved from the fused h*d minor dim must be
     # lane-aligned (Pallas requires block minor dims divisible by 128 unless
     # equal to the array dim). d=64 (the reference heads=16 config) rides
-    # the HEAD-PAIR bshf kernels — two heads per 128-lane block — so its
+    # the HEAD-PAIR bshf kernels (two heads per 128-lane block), so its
     # projections stay plain matmuls too (the per-head [b,h,s,d] entry pays
-    # ~27 ms/step of transpose copies on the headline shapes). Other head
-    # dims use the batch-folded per-head entry.
+    # ~27 ms/step of transpose copies); other head dims use the per-head entry
+    family = "lane" if kd % 128 == 0 else "pair"  # min_seq_for's families
     if (
         heads_whole
         and kd == vd
         and (kd % 128 == 0 or bshf_pair_supported(H, kd, s))
-        and flash_core_supported(proj_q, proj_kv, proj_kv)
+        and flash_core_supported(proj_q, proj_kv, proj_kv, family)
     ):
         post = attrs.qk_norm or attrs.rope_theta is not None
         if kd % 128 != 0 and fused_qkv and not post:
             return "fused_row_qkv"
         return "fused_row"
-    if flash_core_supported(proj_q, proj_kv, (b, H, v_shape[1], vd)):
+    if flash_core_supported(proj_q, proj_kv, (b, H, v_shape[1], vd), "rows"):
         return "rows"
     return "dense"
 
