@@ -104,7 +104,8 @@ def kv_cache_piece_bytes(attrs, q_parallel_shape, w_parallel_shape,
         head_degree = max(w_parallel_shape.shard_dim_at(1).degree, 1)
     seqs = math.ceil(serving.max_concurrent_seqs / batch_degree)
     positions = math.ceil(serving.max_seq_len / seq_degree)
-    heads = math.ceil(attrs.num_heads / head_degree)
+    # grouped-query attention caches its key/value heads, not the queries'
+    heads = math.ceil(attrs.kv_heads / head_degree)
     return (
         seqs
         * positions

@@ -318,6 +318,17 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
             use_bias=eq.get("use_bias", False),
             lambda_bal=lambda_bal,
             gated=eq.get("gated", False),
+            scoring="sigmoid" if eq.get("selection_bias") else "softmax",
+            selection_bias=bool(eq.get("selection_bias", False)),
+            # the pattern of the shared form pins the selection bias and
+            # asks for a shared width that is not zero
+            shared_hidden_size=size if eq.get("selection_bias") else 0,
+        )
+    if op_type == OperatorType.STATE_SPACE:
+        from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
+
+        return StateSpaceAttrs(
+            num_heads=2, head_dim=4, state_size=4, num_groups=1, chunk_size=4
         )
     if op_type == OperatorType.REPARTITION:
         return RepartitionAttrs(
@@ -359,6 +370,7 @@ def _data_shape_table(op_type: OperatorType, size: int, arity: int):
         OperatorType.REDUCE: ((S, S, S),),
         OperatorType.BROADCAST: ((S, S, S),),
         OperatorType.EXPERTS: ((S, S),),
+        OperatorType.STATE_SPACE: ((S, S, S),),
         OperatorType.REPARTITION: ((S, S, S),),
         OperatorType.COMBINE: ((S, S, S),),
         OperatorType.REPLICATE: ((S, S, S),),

@@ -223,6 +223,8 @@ _DP_TYPES = frozenset(
         OperatorType.RING_ATTENTION,
         # each batch shard routes its own tokens (data_parallel_experts_rule)
         OperatorType.EXPERTS,
+        # the scan runs along each sample's own sequence
+        OperatorType.STATE_SPACE,
     }
 )
 

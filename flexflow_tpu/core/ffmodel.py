@@ -2943,6 +2943,7 @@ class FFModel:
         # conversion after the final block_until_ready. The run-health hook
         # below syncs per step, but only when telemetry is installed.
         macc: Optional[Dict[str, jnp.ndarray]] = None
+        throttle = _host_collective_throttle()
         epoch = start_epoch
         while epoch < epochs:
             batch_in_epoch = skip_batches if epoch == start_epoch else 0
@@ -2965,6 +2966,8 @@ class FFModel:
                     )
                     prev_step = self._step_count
                     self._step_count += 1
+                    if throttle is not None:
+                        throttle(loss)
                     if sup is not None:
                         # seeded "slow" soft-site (ISSUE 18): the sleep
                         # lands INSIDE the timed region (before the
@@ -3044,6 +3047,7 @@ class FFModel:
             jax.block_until_ready(loss)
         elapsed = time.perf_counter() - start
         perf = _perf_from_metric_values(macc) if macc is not None else PerfMetrics()
+        _publish_routing(self.instance, macc)
         if verbose:
             print(
                 f"ELAPSED TIME = {elapsed:.4f}s, "
@@ -3172,6 +3176,7 @@ class FFModel:
         perf = (
             _perf_from_metric_values(macc) if macc is not None else PerfMetrics()
         )
+        _publish_routing(self.instance, macc)
         if verbose:
             print(
                 f"ELAPSED TIME = {elapsed:.4f}s, "
@@ -3468,6 +3473,47 @@ def _read_losses_host(losses) -> np.ndarray:
     sanctioned readbacks happen in named helpers like this one, where a
     reviewer can see each sync point at a glance."""
     return np.asarray(jax.device_get(losses))
+
+
+def _host_collective_throttle():
+    """None, or (on the CPU backend with more than one device) a function
+    to call with each step's loss that waits for the step before last.
+
+    XLA's CPU backend runs every device of a multi-device program on
+    threads of one pool and ABORTS THE PROCESS when a collective's
+    participants have not all arrived within 40 s (`rendezvous.cc`:
+    "Termination timeout ... exceeded"). The fit loop dispatches steps
+    asynchronously and never looks at a result, so hundreds of steps queue;
+    on a loaded host (the test suite: six workers of eight virtual devices
+    each) the pool's threads sit in the rendezvous of later steps while an
+    earlier step's last participant waits for a thread, and two runs in ten
+    died that way (PR 32: ten concurrent copies of
+    `test_ffmodel_api.py::test_fit_reduces_loss`, none of ten with this
+    wait). Two steps in flight keep the devices busy and the queue short.
+    On an accelerator the queue is the device's own, nothing can starve,
+    and the loop is left as it was."""
+    if jax.default_backend() != "cpu" or jax.device_count() < 2:
+        return None
+    pending = []
+
+    def wait_for_step_before_last(loss):
+        pending.append(loss)
+        if len(pending) > 2:
+            jax.block_until_ready(pending.pop(0))
+
+    return wait_for_step_before_last
+
+
+def _publish_routing(instance, mvals) -> None:
+    """Hand a fit call's summed routing counters (observability/routing.py)
+    to where a reader finds them; a graph without a held expert share has
+    none."""
+    from flexflow_tpu.observability import routing
+
+    if mvals is None or routing.ROUTING_KEY not in mvals:
+        return
+    graph = getattr(instance, "pcg", None) or instance.cg
+    routing.publish(mvals[routing.ROUTING_KEY], routing.held_nodes(graph))
 
 
 def _perf_from_metric_values(mvals: Dict[str, jnp.ndarray]) -> PerfMetrics:

@@ -23,6 +23,13 @@ state a capacity, in which case the decisions ranked past it get weight zero.
 Neither the gather nor the combine needs a scatter in either direction: both
 are row permutations, and the transpose of a permutation is the gather by its
 inverse (`_take_rows`).
+
+A call that has a SHARE of the experts' matrices (an op that holds one,
+`ExpertsAttrs.held_experts`: one chip's share under expert parallelism,
+without the exchange; or one expert-parallel shard inside a `shard_map`,
+`expert_shard`) routes over all of them and takes `_held_rows_forward`
+instead: it touches only the rows routed to its experts, a window of them at
+a time, so its cost follows the rows that do work and not N*k.
 """
 
 from __future__ import annotations
@@ -136,31 +143,68 @@ def _pallas_allowed(per_shard: bool) -> bool:
     )
 
 
-def _grouped_matmul(rows, w, group_sizes, pallas: bool):
+def _gmm_tile(m: int, k: int, n: int, groups: int):
+    """The megablox tile for rows [m, k] x [groups', k, n] with `groups`
+    group sizes, or None where the kernels do not take the shape. The row
+    tile follows the rows a group has on average: every group costs whole
+    row tiles, and at 192 rows a group (a held share of a wide router) a
+    512-row tile computes two to five times the rows. A contraction or
+    column size that is no multiple of its tile ends in a partial block,
+    which the kernels mask (k) or drop on the write (n); it must still fill
+    whole 128-lane vregs but for that last block."""
+    tm, tk, tn = _GMM_TILE
+    if m // groups < tm:
+        tm = 128
+    if m % tm or min(k, n) < 128 or k % 64 or n % 64:
+        return None
+    return tm, min(tk, k - k % 128), min(tn, n - n % 128)
+
+
+def _grouped_matmul(rows, w, group_sizes, pallas: bool, group_offset=None):
     """rows [M, K] (sorted by group) x w [G, K, N] -> [M, N] in rows' dtype,
-    float32 accumulation; `pallas`: `_pallas_allowed`."""
+    float32 accumulation; `pallas`: `_pallas_allowed`. `group_offset` (Pallas
+    only, see `_held_rows_forward`): `w` holds the G groups from that one on
+    of the `group_sizes`' many; the rows of the others come back zero."""
     w = w.astype(rows.dtype)
     (m, k), n = rows.shape, w.shape[-1]
-    tm, tk, tn = _GMM_TILE
-    if pallas and m % tm == 0 and k % 128 == 0 and n % 128 == 0:
+    tile = _gmm_tile(m, k, n, group_sizes.shape[0]) if pallas else None
+    if tile is not None:
         from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
 
         return megablox.gmm(
-            rows, w, group_sizes, rows.dtype, (tm, min(tk, k), min(tn, n))
+            rows, w, group_sizes, rows.dtype, tile, group_offset
         )
+    assert group_offset is None, "an offset into the groups needs the kernels"
     return lax.ragged_dot(
         rows, w, group_sizes, preferred_element_type=rows.dtype
     )
 
 
-def route(attrs: ExpertsAttrs, x2: jnp.ndarray, gate_w: jnp.ndarray):
+def route(attrs: ExpertsAttrs, x2: jnp.ndarray, gate_w: jnp.ndarray,
+          select_bias=None):
     """The router, in float32 whatever x2's dtype: (logits [N, E],
-    probabilities [N, E], selected experts [N, k], their weights [N, k])."""
+    probabilities or sigmoid scores [N, E], selected experts [N, k], their
+    weights [N, k]). `select_bias` [E] (sigmoid scoring) moves the choice
+    and nothing else, and takes no gradient."""
     logits = x2.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+    if attrs.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores
+        if select_bias is not None:
+            choice = scores + lax.stop_gradient(
+                select_bias.astype(jnp.float32)
+            )
+        _, topi = lax.top_k(choice, attrs.num_select)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+        if attrs.renormalize:
+            topv = topv / (topv.sum(axis=-1, keepdims=True) + 1e-20)
+        return logits, scores, topi, topv * attrs.routed_scale
     probs = jax.nn.softmax(logits, axis=-1)
     topv, topi = lax.top_k(probs, attrs.num_select)
     if attrs.renormalize:
         topv = topv / topv.sum(axis=-1, keepdims=True)
+    if attrs.routed_scale != 1.0:
+        topv = topv * attrs.routed_scale
     return logits, probs, topi, topv
 
 
@@ -176,24 +220,44 @@ def experts_forward(
     expert_shard: None, or (first expert, experts here) when the expert
     tensors in `weights` are one expert-parallel shard's slice: routing is
     at the full router width and the output is this shard's experts' part
-    of the combine (the caller sums the parts).
+    of the combine (the caller sums the parts), as for an op that holds a
+    share (`attrs.held_experts`).
     per_shard: called from the body of a shard_map, a per-device program
     whatever the enclosing trace."""
     gate_w, rest = weights[0], list(weights[1:])
+    select_bias = rest.pop(0) if attrs.selection_bias else None
     w1 = rest.pop(0)
     w3 = rest.pop(0) if attrs.gated else None
     b1 = rest.pop(0) if attrs.use_bias else None
     w2 = rest.pop(0)
     b2 = rest.pop(0) if attrs.use_bias else None
+    shared = rest  # ws1[, ws3], ws2 of the shared expert, or nothing
 
-    lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     n = x2.shape[0]
     e, k = attrs.num_experts, attrs.num_select
-    logits, probs, topi, topv = route(attrs, x2, gate_w)
+    logits, probs, topi, topv = route(attrs, x2, gate_w, select_bias)
+    pallas = _pallas_allowed(per_shard)
+    flat_e = topi.reshape(-1).astype(jnp.int32)  # [N*k]
+    if attrs.held_experts is not None or expert_shard is not None:
+        assert None in (attrs.held_experts, expert_shard), (
+            "a held share is not sharded again"
+        )
+        experts = {"w1": w1, "w3": w3, "b1": b1, "w2": w2, "b2": b2}
+        out, here = _held_rows_forward(
+            attrs, attrs.held_experts or expert_shard, x2, flat_e, topv,
+            {name: w for name, w in experts.items() if w is not None}, pallas,
+        )
+        if attrs.held_experts is not None:
+            from flexflow_tpu.observability import routing
+
+            routing.record(here, n * k)
+        counts = None
+        if attrs.lambda_bal > 0:
+            counts = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+        return _finish(attrs, out, x, shared, counts, probs, logits)
 
     # -- dispatch: decisions in (token, select) order, sorted by expert ----
-    flat_e = topi.reshape(-1).astype(jnp.int32)  # [N*k]
     order = jnp.argsort(flat_e, stable=True)
     inverse = jnp.argsort(order)  # decision -> its row after the sort
     counts = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
@@ -204,33 +268,8 @@ def experts_forward(
         kept = (rank < cap)[inverse].reshape(n, k)
         topv = jnp.where(kept, topv, 0.0)
 
-    if expert_shard is None:
-        group_sizes = counts
-    else:
-        # rows of the experts before and after this shard's form one group
-        # each around its own; their two "experts" are zero matrices, so the
-        # rows cost their FLOPs and contribute nothing
-        lo, here = expert_shard
-        before = jnp.sum(jnp.where(jnp.arange(e) < lo, counts, 0))
-        mine = lax.dynamic_slice(counts, (lo,), (here,))
-        after = n * k - before - jnp.sum(mine)
-        group_sizes = jnp.concatenate(
-            [before[None], mine, after[None]]
-        ).astype(jnp.int32)
-
-        def pad(w):
-            zero = jnp.zeros((1,) + w.shape[1:], w.dtype)
-            return jnp.concatenate([zero, w, zero])
-
-        w1, w2 = pad(w1), pad(w2)
-        w3 = None if w3 is None else pad(w3)
-        b1 = None if b1 is None else pad(b1)
-        b2 = None if b2 is None else pad(b2)
-
-    pallas = _pallas_allowed(per_shard)
-
     def grouped(rows, w):
-        return _grouped_matmul(rows, w, group_sizes, pallas)
+        return _grouped_matmul(rows, w, counts, pallas)
 
     def bias_rows(b, dtype):
         # each row's expert's bias, as a grouped matmul of a column of ones:
@@ -255,7 +294,17 @@ def experts_forward(
     y = _take_rows(y, inverse, order, 1)
     y = y.reshape(n, k, y.shape[-1]).astype(jnp.float32)
     out = jnp.einsum("nk,nko->no", topv, y)
-    out = out.reshape(*lead, out.shape[-1]).astype(x.dtype)
+    return _finish(attrs, out, x, shared, counts, probs, logits)
+
+
+def _finish(attrs, out, x, shared, counts, probs, logits):
+    """The node's outputs from its routed part `out` [N, out] float32: the
+    shared expert added, x's leading dims and dtype back, and the auxiliary
+    scalar where the attrs ask for one (`counts` [E]: decisions per expert
+    of the whole router)."""
+    n, e = probs.shape
+    out = _add_shared_expert(attrs, out, x, shared)
+    out = out.reshape(*x.shape[:-1], out.shape[-1]).astype(x.dtype)
 
     if not attrs.has_aux:
         return [out]
@@ -263,7 +312,9 @@ def experts_forward(
     if attrs.lambda_bal > 0:
         # gated: f_e = tokens that chose e / N; legacy: decisions / (N k)
         # (ExpertsAttrs docstring). Counts carry no gradient.
-        frac = counts.astype(jnp.float32) / (n if attrs.gated else n * k)
+        frac = counts.astype(jnp.float32) / (
+            n if attrs.gated else n * attrs.num_select
+        )
         aux += attrs.lambda_bal * e * jnp.sum(
             lax.stop_gradient(frac) * probs.mean(axis=0)
         )
@@ -274,3 +325,154 @@ def experts_forward(
     # float32 out of the node whatever the compute dtype: 0.01 x LB is about
     # 0.08, whose bf16 rounding (3e-4) is the size of a loss tolerance
     return [out, aux.reshape(1)]
+
+
+def _add_shared_expert(attrs: ExpertsAttrs, out, x, shared):
+    """out [N, out] float32 plus the shared expert's dense path on every
+    token of x [.., D] (`shared`: ws1[, ws3], ws2; nothing where the op has
+    none)."""
+    if not shared:
+        return out
+    with jax.named_scope("shared_expert"):
+        x2 = x.reshape(-1, x.shape[-1])
+        hs = x2 @ shared[0].astype(x2.dtype)
+        if attrs.activation is not None:
+            hs = attrs.activation.apply(hs)
+        if attrs.gated:
+            hs = hs * (x2 @ shared[1].astype(x2.dtype))
+        return out + (hs @ shared[-1].astype(x2.dtype)).astype(jnp.float32)
+
+
+def held_window_rows(decisions: int, held: int, experts: int) -> int:
+    """Rows one pass of `_held_rows_forward` takes: a quarter more than a
+    uniform router sends the held experts, in whole 128-row tiles, and never
+    more than there are decisions."""
+    expected = -(-decisions * held // experts)
+    return min(decisions, max(128, -(-(expected + expected // 4) // 128) * 128))
+
+
+def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
+    """The routed part of a call that has the matrices of experts `first ..
+    first + held - 1` (`share`) of the E its router chose among: sum over a
+    token's decisions that landed on one of them of weight * expert(x),
+    [N, out] float32; the other decisions add nothing. Also the decisions
+    per expert of the share, [held]. `flat_e` [N*k]: the chosen experts in
+    (token, select) order; `ws`: the share's `w1`, `w2` and, where the attrs
+    have them, `w3`, `b1`, `b2`.
+
+    Only the rows that do work are touched. The decisions are sorted with
+    the share's first, by local expert (one stable sort of N k small keys),
+    and taken in windows of `held_window_rows` rows: gather the window's
+    token rows, run the grouped matmuls over the share's groups (`gmm` /
+    `tgmm` visit those groups' row tiles and zero the rest), scatter-add
+    the weighted results to their tokens. A uniform router fills less than
+    one window; a router that sends this share more takes as many windows
+    as it needs (a loop whose trip count is the data's), so nothing is
+    dropped that the attrs' capacity keeps and no window without a row of
+    the share is run. The backward pass is the same loop: each window
+    recomputed from x and the routing and differentiated by itself, its
+    gradients accumulated, so what the forward keeps is its inputs."""
+    first, held = share
+    n, k = topv.shape
+    decisions = n * k
+    window = held_window_rows(decisions, held, attrs.num_experts)
+    local = flat_e - first
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    order = jnp.argsort(key, stable=True)  # the share's decisions first
+    counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+    flat_w = topv.reshape(-1)
+    if attrs.capacity_factor is not None:
+        # a decision's rank within its expert is "earlier tokens first"
+        cap = expert_capacity(n, attrs.num_experts, k, attrs.capacity_factor)
+        first_row = jnp.cumsum(counts) - counts
+        rank = jnp.arange(decisions, dtype=jnp.int32) - first_row[key[order]]
+        kept = jnp.zeros((decisions,), bool).at[order].set(rank < cap)
+        flat_w = jnp.where(kept, flat_w, 0.0)
+    counts = counts[:held]
+
+    def grouped(rows, w, sizes):
+        if pallas and _gmm_tile(window, w.shape[1], w.shape[2], held):
+            # `held` matrices for held + 1 sizes: the rows of the last,
+            # the window's rest, are not visited and come back zero
+            return _grouped_matmul(
+                rows, w, sizes, True, jnp.zeros((), jnp.int32)
+            )
+        zero = jnp.zeros((1,) + w.shape[1:], w.dtype)
+        return _grouped_matmul(rows, jnp.concatenate([w, zero]), sizes, False)
+
+    def one_window(t, order, counts, x2, flat_w, ws):
+        """(tokens [window], their weighted expert outputs [window, out]
+        float32) of the t-th window of the share's rows."""
+        total = jnp.sum(counts)
+        ends = jnp.cumsum(counts)
+        lo = t * window
+        at = lo + jnp.arange(window, dtype=jnp.int32)
+        valid = at < total
+        decision = order[jnp.minimum(at, decisions - 1)]
+        token = decision // k
+        rows = jnp.where(valid[:, None], x2[token], 0)
+        sizes = jnp.clip(
+            jnp.minimum(ends, lo + window) - jnp.maximum(ends - counts, lo),
+            0, window,
+        )
+        sizes = jnp.concatenate(
+            [sizes, (window - jnp.sum(sizes))[None]]
+        ).astype(jnp.int32)
+
+        def bias_rows(b):
+            # each row's expert's bias, as a grouped matmul of a column of
+            # ones (see `experts_forward`)
+            return grouped(jnp.ones((window, 1), b.dtype), b[:, None, :], sizes)
+
+        with jax.named_scope("grouped_matmul"):
+            h = grouped(rows, ws["w1"], sizes)
+            if "b1" in ws:
+                h = h + bias_rows(ws["b1"])
+            if attrs.activation is not None:
+                h = attrs.activation.apply(h)
+            if "w3" in ws:
+                h = h * grouped(rows, ws["w3"], sizes)
+            y = grouped(h, ws["w2"], sizes)
+            if "b2" in ws:
+                y = y + bias_rows(ws["b2"])
+        weight = jnp.where(valid, flat_w[decision], 0.0)
+        return token, weight[:, None] * y.astype(jnp.float32)
+
+    def windows(counts):
+        return (jnp.sum(counts) + window - 1) // window
+
+    def forward(order, counts, x2, flat_w, ws):
+        def body(t, out):
+            token, rows_out = one_window(t, order, counts, x2, flat_w, ws)
+            return out.at[token].add(rows_out)
+
+        zero = jnp.zeros((n, ws["w2"].shape[-1]), jnp.float32)
+        return lax.fori_loop(0, windows(counts), body, zero)
+
+    @jax.custom_vjp
+    def routed(order, counts, x2, flat_w, ws):
+        return forward(order, counts, x2, flat_w, ws)
+
+    def routed_fwd(order, counts, x2, flat_w, ws):
+        return forward(order, counts, x2, flat_w, ws), (
+            order, counts, x2, flat_w, ws
+        )
+
+    def routed_bwd(kept, g_out):
+        order, counts, x2, flat_w, ws = kept
+
+        def window_dot(x2, flat_w, ws, t):
+            token, rows_out = one_window(t, order, counts, x2, flat_w, ws)
+            return jnp.sum(rows_out * g_out[token])
+
+        def body(t, grads):
+            g = jax.grad(window_dot, argnums=(0, 1, 2))(x2, flat_w, ws, t)
+            return jax.tree_util.tree_map(jnp.add, grads, g)
+
+        zero = jax.tree_util.tree_map(jnp.zeros_like, (x2, flat_w, ws))
+        g_x2, g_w, g_ws = lax.fori_loop(0, windows(counts), body, zero)
+        return None, None, g_x2, g_w, g_ws
+
+    routed.defvjp(routed_fwd, routed_bwd)
+    ws = {name: w.astype(x2.dtype) for name, w in ws.items()}
+    return routed(order, counts, x2, flat_w, ws), counts

@@ -153,13 +153,15 @@ def mha_project_qkv_bshf(
     attrs: MultiHeadAttentionAttrs, q, k, v, weight, input_bias=None
 ):
     """q/k/v projections -> seq-major fused-head tensors [b, s, h*d] plus wo
-    pre-arranged as [h*v, e].
+    pre-arranged as [h*v, e]. Grouped-query heads: k and v come out as their
+    `kv_heads` published heads, [b, s, kv*d] (`mha_between` repeats them).
 
     With heads fused into the minor dim every projection is a PLAIN MATMUL
     ([b,s,e] @ [e, h*d]), whose natural output layout matches
     flash_attention_bshf's operand layout — no physical transpose between
     the projection fusion and the custom call."""
-    wq2, wk2, wv2, wo2 = _bshf_weights(
+    unpack = unpack_gqa_weights if attrs.grouped_query else _bshf_weights
+    wq2, wk2, wv2, wo2 = unpack(
         attrs, q.shape[-1], k.shape[-1], v.shape[-1], weight
     )
     H = attrs.num_heads
@@ -168,6 +170,7 @@ def mha_project_qkv_bshf(
     kp = k @ wk2
     vp = v @ wv2
     if input_bias is not None:
+        assert not attrs.grouped_query, "grouped-query attention has no bias yet"
         qp = qp + jnp.tile(input_bias[:kd], H)[None, None, :]
         kp = kp + jnp.tile(input_bias[kd : 2 * kd], H)[None, None, :]
         vp = vp + jnp.tile(input_bias[2 * kd :], H)[None, None, :]
@@ -241,17 +244,39 @@ def rope_bshf(x, num_heads: int, theta: float):
     return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
-def mha_qk_norm_rope(attrs: MultiHeadAttentionAttrs, qp, kp, qk_gains):
+def mha_row_projections(attrs: MultiHeadAttentionAttrs) -> bool:
+    """Whether something acts on the fused-row projections [b, s, h*d]
+    between projection and attention core (`mha_between`): QK-norm, RoPE,
+    or the repeat of grouped-query key/value heads."""
+    return attrs.qk_norm or attrs.rope_theta is not None or attrs.grouped_query
+
+
+def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains):
     """What the attrs ask for between projection and attention core, on the
     fused [b, s, h*d] projections: QK-norm over the whole row, then RoPE on
-    each head block."""
+    each head block, then each grouped-query key/value head repeated for
+    the query heads that read it (head h reads h // group), so that the core
+    is the equal-head one: same kernels, same route, and the repeat's
+    transpose sums dK and dV over the group. A kernel that indexes the
+    key/value block by `h // group` instead would save the two repeated
+    copies (ROADMAP, Reach (3))."""
     if attrs.qk_norm:
         qp = rms_norm(qp, qk_gains[0], attrs.qk_norm_eps)
         kp = rms_norm(kp, qk_gains[1], attrs.qk_norm_eps)
     if attrs.rope_theta is not None:
         qp = rope_bshf(qp, attrs.num_heads, attrs.rope_theta)
-        kp = rope_bshf(kp, attrs.num_heads, attrs.rope_theta)
-    return qp, kp
+        kp = rope_bshf(kp, attrs.kv_heads, attrs.rope_theta)
+    if attrs.grouped_query:
+        H, KV = attrs.num_heads, attrs.num_kv_heads
+
+        def repeated(x):
+            b, t, f = x.shape
+            x = x.reshape(b, t, KV, 1, f // KV)
+            x = jnp.broadcast_to(x, (b, t, KV, H // KV, f // KV))
+            return x.reshape(b, t, H * (f // KV))
+
+        kp, vp = repeated(kp), repeated(vp)
+    return qp, kp, vp
 
 
 def mha_core_route(
@@ -306,13 +331,28 @@ def mha_core_route(
         and (kd % 128 == 0 or bshf_pair_supported(H, kd, s))
         and flash_core_supported(proj_q, proj_kv, proj_kv, family)
     ):
-        post = attrs.qk_norm or attrs.rope_theta is not None
-        if kd % 128 != 0 and fused_qkv and not post:
+        if kd % 128 != 0 and fused_qkv and not mha_row_projections(attrs):
             return "fused_row_qkv"
         return "fused_row"
     if flash_core_supported(proj_q, proj_kv, (b, H, v_shape[1], vd), "rows"):
         return "rows"
     return "dense"
+
+
+def unpack_gqa_weights(
+    attrs: MultiHeadAttentionAttrs, qsize: int, ksize: int, vsize: int, weight
+):
+    """The grouped-query layout: one flat column holding wq [qsize, h*d],
+    wk [ksize, kv*d], wv [vsize, kv*v] and wo [h*v, e], each row-major with
+    head-major columns (`MultiHeadAttentionAttrs.num_kv_heads`)."""
+    H, KV = attrs.num_heads, attrs.num_kv_heads
+    kd, vd, e = attrs.q_proj_size, attrs.v_proj_size, attrs.embed_dim
+    shapes = [(qsize, H * kd), (ksize, KV * kd), (vsize, KV * vd), (H * vd, e)]
+    flat, out, at = weight.reshape(-1), [], 0
+    for rows, cols in shapes:
+        out.append(flat[at:at + rows * cols].reshape(rows, cols))
+        at += rows * cols
+    return out
 
 
 def _mha_forward(
@@ -330,9 +370,9 @@ def _mha_forward(
 
     kd = attrs.q_proj_size
     H = attrs.num_heads
-    # QK-norm and RoPE act on the fused-row projections; a node without
-    # them takes the paths it always took
-    post = attrs.qk_norm or attrs.rope_theta is not None
+    # QK-norm, RoPE and the grouped-query repeat act on the fused-row
+    # projections; a node without them takes the paths it always took
+    post = mha_row_projections(attrs)
     route = mha_core_route(attrs, q.shape, k.shape, v.shape, q is k and k is v)
     if route == "fused_row_qkv":
         # self-attention on the head-pair path: ONE fused projection matmul
@@ -352,7 +392,7 @@ def _mha_forward(
             attrs, q, k, v, weight, input_bias
         )
         if post:
-            qp, kp = mha_qk_norm_rope(attrs, qp, kp, qk_gains)
+            qp, kp, vp = mha_between(attrs, qp, kp, vp, qk_gains)
         ctx = per_batch_shard(
             flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal
         )
@@ -364,7 +404,7 @@ def _mha_forward(
         qp, kp, vp, wo2 = mha_project_qkv_bshf(
             attrs, q, k, v, weight, input_bias
         )
-        qp, kp = mha_qk_norm_rope(attrs, qp, kp, qk_gains)
+        qp, kp, vp = mha_between(attrs, qp, kp, vp, qk_gains)
         vd = attrs.v_proj_size
 
         def heads(x, d):
@@ -618,6 +658,13 @@ def forward(
             out = out.reshape(1)
         return [out]
 
+    from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
+
+    if isinstance(attrs, StateSpaceAttrs):
+        from flexflow_tpu.kernels.ssm import state_space_forward
+
+        return [state_space_forward(attrs, inputs[0], weights)]
+
     from flexflow_tpu.op_attrs.ops.moe import (
         AggregateAttrs,
         ExpertsAttrs,
@@ -671,8 +718,10 @@ def _experts_rows(attrs, tokens: int, weight_shapes=None) -> int:
         )
         rows = min(rows, e * cap)
     if weight_shapes and len(weight_shapes) > 1:
-        e_local = weight_shapes[1].dims[0]
+        e_local = weight_shapes[attrs.weight_roles().index("expert")].dims[0]
         rows = rows * e_local // e + 64 * e_local
+    elif attrs.held_experts is not None:
+        rows = rows * attrs.num_local_experts // e
     return rows
 
 
@@ -684,17 +733,25 @@ def op_internal_bytes(attrs: OpAttrs, input_shapes, weight_shapes=None) -> int:
     experts' output [rows, out], each written once and read once. At the
     published sizes this is more traffic than the expert weights."""
     from flexflow_tpu.op_attrs.ops.moe import ExpertsAttrs
+    from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 
+    if isinstance(attrs, StateSpaceAttrs):
+        # the state-space node's own tensors: the input projection's row,
+        # the convolved x | B | C, the scan's y and the gated norm's output
+        x = input_shapes[0]
+        tokens = int(x.num_elements) // x.dims[-1]
+        width = attrs.in_proj_width + attrs.conv_width + 2 * attrs.inner
+        return 2 * tokens * width * x.dtype.size_bytes
     if not isinstance(attrs, ExpertsAttrs):
         return 0
     x = input_shapes[0]
     d = x.dims[-1]
     tokens = int(x.num_elements) // d
     rows = _experts_rows(attrs, tokens, weight_shapes)
-    width = d + (2 if attrs.gated else 1) * attrs.hidden_size + (
-        attrs.out_channels or d
-    )
-    return 2 * rows * width * x.dtype.size_bytes
+    forms = 2 if attrs.gated else 1
+    width = d + forms * attrs.hidden_size + (attrs.out_channels or d)
+    shared = tokens * forms * attrs.shared_hidden_size
+    return 2 * (rows * width + shared) * x.dtype.size_bytes
 
 
 def op_forward_flops(
@@ -752,9 +809,14 @@ def op_forward_flops(
         q = input_shapes[0]
         b, s, e = q.dims
         kd, vd, H = attrs.q_proj_size, attrs.v_proj_size, attrs.num_heads
-        if weight_shapes:  # [per-head params, H/k] head-parallel piece
-            H = weight_shapes[0].dims[1]
-        proj = 2 * b * s * e * (kd + kd + vd) * H + 2 * b * s * vd * attrs.embed_dim * H
+        if weight_shapes and not attrs.grouped_query:
+            H = weight_shapes[0].dims[1]  # [per-head params, H/k] piece
+        # grouped-query heads project kv_heads keys and values, not H
+        KV = attrs.kv_heads * H // attrs.num_heads
+        proj = (
+            2 * b * s * e * (kd * H + (kd + vd) * KV)
+            + 2 * b * s * vd * attrs.embed_dim * H
+        )
         scores = 2 * b * H * s * s * kd + 2 * b * H * s * s * vd
         if isinstance(attrs, RingAttentionAttrs) and seq_parallel_degree > 1:
             # the piece sees s/k queries but attends ALL k K/V blocks (ring
@@ -776,8 +838,26 @@ def op_forward_flops(
         o = attrs.out_channels or d
         rows = _experts_rows(attrs, n, weight_shapes)
         gate = 2 * n * d * attrs.num_experts  # every device gates its tokens
-        mlp = 2 * rows * ((2 if attrs.gated else 1) * d * h + h * o)
-        return gate + mlp
+        forms = 2 if attrs.gated else 1
+        mlp = 2 * rows * (forms * d * h + h * o)
+        # the shared expert sees every token
+        hs = attrs.shared_hidden_size
+        return gate + mlp + 2 * n * (forms * d * hs + hs * o)
+
+    from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
+
+    if isinstance(attrs, StateSpaceAttrs):
+        b, s, d = input_shapes[0].dims
+        q, p, n = attrs.chunk_size, attrs.head_dim, attrs.state_size
+        proj = 2 * b * s * d * (attrs.in_proj_width + attrs.inner)
+        # a position's share of the chunked scan: C.B over the chunk once a
+        # group, the masked [q, q] x [q, p] product and the state's two
+        # [p, n] products a head
+        scan = b * s * (
+            attrs.num_groups * 2 * q * n
+            + attrs.num_heads * (2 * q * p + 4 * p * n)
+        )
+        return proj + scan + 2 * b * s * attrs.conv_kernel * attrs.conv_width
 
     total = sum(nelem(s) for s in output_shapes)
     return total

@@ -316,34 +316,49 @@ class ModelTrainingInstance:
     # -- step -------------------------------------------------------------
 
     def loss_fn(self, params, batch_inputs, label, rng=None):
+        loss, (logit, _) = self._loss_and_routing(
+            params, batch_inputs, label, rng
+        )
+        return loss, logit
+
+    def _loss_and_routing(self, params, batch_inputs, label, rng=None):
+        """(loss, (logits, the held expert nodes' routing counts stacked
+        [nodes, held], or None in a graph without such a node))."""
+        from flexflow_tpu.observability import routing
+
         with trace.step_scope("cast"):
             params = self._cast_for_compute(params)
             batch_inputs = self._cast_for_compute(batch_inputs)
-        env = forward_interpreter(
-            self.cg,
-            params,
-            batch_inputs,
-            train=True,
-            rng=rng,
-            barrier_nodes=self._barrier_nodes,
-        )
+        with routing.collecting() as held_rows:
+            env = forward_interpreter(
+                self.cg,
+                params,
+                batch_inputs,
+                train=True,
+                rng=rng,
+                barrier_nodes=self._barrier_nodes,
+            )
         logit = env[self.logit_tensor]
         with trace.step_scope("loss"):
             loss = loss_forward(self.loss_attrs, logit, label)
             for t in self.aux_loss_tensors:
                 loss = loss + jnp.sum(env[t].astype(loss.dtype))
-        return loss, logit
+        return loss, (logit, jnp.stack(held_rows) if held_rows else None)
 
     def _step(self, params, opt_state, batch_inputs, label, rng):
-        (loss, logit), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
-            params, batch_inputs, label, rng
-        )
+        (loss, (logit, held_rows)), grads = jax.value_and_grad(
+            self._loss_and_routing, has_aux=True
+        )(params, batch_inputs, label, rng)
         with trace.step_scope("optimizer"):
             new_params, new_opt_state = apply_optimizer(
                 self.optimizer_attrs, params, grads, opt_state
             )
         with trace.step_scope("metrics"):
             metric_vals = compute_metrics(self.metrics, logit, label)
+            if held_rows is not None:
+                from flexflow_tpu.observability.routing import ROUTING_KEY
+
+                metric_vals[ROUTING_KEY] = held_rows
         # run-health scalars, fused into this same XLA program: each global
         # norm is one reduction over the pytree, not a host trip per leaf;
         # under skip_step/raise a non-finite update never reaches the

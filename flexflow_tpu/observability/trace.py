@@ -252,19 +252,27 @@ STEP_SCOPES = {
 }
 PHASES = ("fwd", "bwd", "opt", "other", "unattributed")
 # kinds whose OperatorType value is not the name the tables use
-_KIND_NAMES = {"linear": "dense", "multihead_attention": "mha"}
+_KIND_NAMES = {
+    "linear": "dense", "multihead_attention": "mha", "state_space": "ssm",
+}
 _NOT_IN_NAME = re.compile(r"[^A-Za-z0-9_.\-]")
 # the first `ff.` token of a name stack: a kind holds no dot, so the first
 # two dots split the scope; a name ends at the first `/` or `)`
 _SCOPE = re.compile(
     r"(?<![A-Za-z0-9_.\-])ff\.([a-z0-9_]+)(?:\.([A-Za-z0-9_.\-]+))?"
 )
+# a state-space node puts its scan under a further scope inside its own
+# (`ff.ssm.<name>/scan`, `kernels/ssm.py`): `parse_scope` keeps that in the
+# name, `<name>/scan`, so that one table tells the scan from the node's
+# projections. The node's scope may be closed by JAX's `jvp(...)` /
+# `transpose(...)` before the scan's begins.
+_SCAN_PART = re.compile(r"\)*/scan(?:[/)]|$)")
 
 
 def scope_kind(op_type) -> str:
     """The `<kind>` of a node's scope: its `OperatorType` in lower case,
-    `dense` and `mha` for the two the tables abbreviate, `parallel_<op>`
-    for the four parallel ops."""
+    `dense`, `mha` and `ssm` for the three the tables abbreviate,
+    `parallel_<op>` for the four parallel ops."""
     if op_type in PARALLEL_OP_TYPES:
         return "parallel_" + op_type.value
     return _KIND_NAMES.get(op_type.value, op_type.value)
@@ -300,7 +308,8 @@ def parse_scope(op_name: str) -> Tuple[str, str, str]:
     `transpose(` or a rematerialized computation it is `bwd`, under
     `ff.optimizer` `opt`, under `ff.cast` / `ff.metrics` / `ff.health`
     `other`, under any other `ff.` scope `fwd`; with no `ff.` scope it is
-    `("unattributed", "", "")`."""
+    `("unattributed", "", "")`. An operation of a state-space node's scan
+    has the name `<name>/scan`."""
     m = _SCOPE.search(op_name)
     if m is None:
         return "unattributed", "", ""
@@ -311,6 +320,8 @@ def parse_scope(op_name: str) -> Tuple[str, str, str]:
         "transpose(" in op_name[: m.start()]
         or "rematted_computation" in op_name
     )
+    if kind == "ssm" and _SCAN_PART.match(op_name, m.end()):
+        name = f"{name}/scan"
     return ("bwd" if backward else "fwd"), kind, name or ""
 
 

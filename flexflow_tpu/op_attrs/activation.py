@@ -14,6 +14,7 @@ class Activation(enum.Enum):
     TANH = "tanh"
     GELU = "gelu"
     SILU = "silu"
+    RELU2 = "relu2"  # relu(x)^2, the squared ReLU of the Primer / Nemotron line
 
     def apply(self, x):
         import jax
@@ -24,6 +25,7 @@ class Activation(enum.Enum):
             Activation.TANH: jax.numpy.tanh,
             Activation.GELU: jax.nn.gelu,
             Activation.SILU: jax.nn.silu,
+            Activation.RELU2: lambda v: jax.numpy.square(jax.nn.relu(v)),
         }[self](x)
 
 

@@ -73,6 +73,7 @@ from flexflow_tpu.op_attrs.ops.moe import (
     AggregateAttrs,
     ExpertsAttrs,
 )
+from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 
 
 class OperatorType(enum.Enum):
@@ -109,6 +110,7 @@ class OperatorType(enum.Enum):
     GROUP_BY = "group_by"
     AGGREGATE = "aggregate"
     EXPERTS = "experts"  # fused tpu-native MoE FFN (expert parallelism)
+    STATE_SPACE = "state_space"  # selective state-space mixer (chunked scan)
     REPARTITION = "repartition"
     COMBINE = "combine"
     REPLICATE = "replicate"
@@ -134,7 +136,7 @@ OpAttrs = Union[
     MultiHeadAttentionAttrs, RingAttentionAttrs, UlyssesAttentionAttrs,
     ConcatAttrs, StackAttrs, SplitAttrs, ReshapeAttrs, TransposeAttrs,
     ReverseAttrs, GatherAttrs, TopKAttrs, ReduceAttrs,
-    GroupByAttrs, AggregateAttrs, ExpertsAttrs,
+    GroupByAttrs, AggregateAttrs, ExpertsAttrs, StateSpaceAttrs,
     RepartitionAttrs, CombineAttrs, ReplicateAttrs, ReductionAttrs,
     StagePartitionAttrs, StageMergeAttrs,
 ]
@@ -173,6 +175,7 @@ _OP_TYPE_BY_ATTRS = {
     GroupByAttrs: OperatorType.GROUP_BY,
     AggregateAttrs: OperatorType.AGGREGATE,
     ExpertsAttrs: OperatorType.EXPERTS,
+    StateSpaceAttrs: OperatorType.STATE_SPACE,
     RepartitionAttrs: OperatorType.REPARTITION,
     CombineAttrs: OperatorType.COMBINE,
     ReplicateAttrs: OperatorType.REPLICATE,
@@ -237,7 +240,7 @@ def get_incoming_tensor_roles(attrs: OpAttrs) -> List[IncomingTensorRole]:
         return [I, W, W] if attrs.elementwise_affine else [I]
     if isinstance(attrs, RMSNormAttrs):
         return [I, W]
-    if isinstance(attrs, ExpertsAttrs):
+    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs)):
         return [I] + [W] * attrs.num_weights
     n = num_data_inputs(attrs)
     return [I] * n
@@ -330,7 +333,7 @@ def get_weight_shapes(
         return [attrs.gamma_shape(inputs[0]), attrs.beta_shape(inputs[0])]
     if isinstance(attrs, RMSNormAttrs):
         return [attrs.gamma_shape(inputs[0])]
-    if isinstance(attrs, ExpertsAttrs):
+    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs)):
         return list(attrs.weight_shapes(inputs[0]))
     return []
 
@@ -354,6 +357,25 @@ def get_default_weight_initializers(attrs: OpAttrs, num_weights: int):
     if isinstance(attrs, MultiHeadAttentionAttrs) and attrs.qk_norm:
         # the two QK-norm gains are the last two slots
         return [None] * (num_weights - 2) + [ConstantInitializerAttrs(1.0)] * 2
+    if isinstance(attrs, StateSpaceAttrs):
+        from flexflow_tpu.pcg.initializer import (
+            InverseSoftplusLogUniformInitializerAttrs,
+            LogOfUniformInitializerAttrs,
+            UniformInitializerAttrs,
+        )
+
+        # the published Mamba-2 defaults: the convolution as torch's conv1d
+        # (uniform in +-1/sqrt(taps)), dt log-uniform in [1e-3, 1e-1] held
+        # over 1e-4 and stored through the inverse of softplus, A uniform
+        # in [1, 16] stored as its log, D and the norm's gain one
+        bound = float(attrs.conv_kernel) ** -0.5
+        conv = UniformInitializerAttrs(min_val=-bound, max_val=bound)
+        one = ConstantInitializerAttrs(1.0)
+        return [
+            None, conv, conv,
+            InverseSoftplusLogUniformInitializerAttrs(1e-3, 1e-1, 1e-4),
+            LogOfUniformInitializerAttrs(1.0, 16.0), one, one, None,
+        ][:num_weights]
     return [None] * num_weights
 
 
@@ -415,6 +437,6 @@ def get_parallel_weight_shapes(
         return [g, g]
     if isinstance(attrs, RMSNormAttrs):
         return [attrs.parallel_gamma_shape(inputs[0])]
-    if isinstance(attrs, ExpertsAttrs):
+    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs)):
         return list(attrs.parallel_weight_shapes(inputs[0]))
     return []

@@ -69,3 +69,4 @@ from flexflow_tpu.op_attrs.ops.moe import (
     ExpertsAttrs,
     expert_capacity,
 )
+from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs

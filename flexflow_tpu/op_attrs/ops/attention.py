@@ -48,6 +48,35 @@ class MultiHeadAttentionAttrs:
     # and before RoPE), each with a gain [h*d]: two more weight slots after
     # the biases, q's then k's.
     qk_norm_eps: Optional[float] = None
+    # num_kv_heads: grouped-query attention. None is one key/value head a
+    # query head, the op as it always was. With fewer, query head h reads
+    # key/value head h // (num_heads / num_kv_heads), and the weight is one
+    # flat column [wq | wk | wv | wo, 1] holding wq [e, h*d], wk and wv
+    # [e, kv*d] (the published, smaller projections) and wo [h*d, e]: a
+    # per-head column as in the equal-head layout has no place for a
+    # key/value head that several query heads share.
+    num_kv_heads: Optional[int] = None
+
+    def __post_init__(self):
+        if self.num_kv_heads is not None:
+            assert self.num_heads % self.num_kv_heads == 0, (
+                f"{self.num_heads} query heads do not divide over "
+                f"{self.num_kv_heads} key/value heads"
+            )
+            assert not self.qk_norm, (
+                "QK-norm with grouped-query heads is not expressed yet"
+            )
+
+    @property
+    def grouped_query(self) -> bool:
+        return (
+            self.num_kv_heads is not None
+            and self.num_kv_heads != self.num_heads
+        )
+
+    @property
+    def kv_heads(self) -> int:
+        return self.num_kv_heads if self.grouped_query else self.num_heads
 
     @property
     def qk_norm(self) -> bool:
@@ -78,6 +107,15 @@ class MultiHeadAttentionAttrs:
         """Flat per-head weight [wq+wk+wv+wo, num_heads]
         (reference attention.cc:136-170)."""
         self._check_inputs(q, k, v)
+        if self.grouped_query:
+            h, kv = self.num_heads, self.num_kv_heads
+            flat = (
+                q.dims[-1] * h * self.q_proj_size
+                + k.dims[-1] * kv * self.k_proj_size
+                + v.dims[-1] * kv * self.v_proj_size
+                + h * self.v_proj_size * self.embed_dim
+            )
+            return TensorShape((flat, 1), q.dtype)
         per_head = (
             q.dims[-1] * self.q_proj_size
             + k.dims[-1] * self.k_proj_size
@@ -132,6 +170,10 @@ class MultiHeadAttentionAttrs:
         )
 
     def _check_qk_norm_heads(self, head_degree: int) -> None:
+        assert not (self.grouped_query and head_degree > 1), (
+            "grouped-query attention keeps its projections in one flat "
+            "column: it cannot be head-parallel yet"
+        )
         assert not (self.qk_norm and head_degree > 1), (
             "QK-norm takes its mean of squares over every head's features: "
             "a head shard would need the other shards' sums, so attention "

@@ -150,6 +150,25 @@ class ExpertsAttrs:
     existed) or use them as they are (False: `norm_topk_prob` false).
     The router and its softmax are computed in float32 in either form.
 
+    scoring: "softmax" (the above) or "sigmoid", the router of the
+    bias-balanced sparse decoders: s = sigmoid(logits); the k experts of
+    largest s + b are chosen, b the selection bias [E] (`selection_bias`: one
+    more weight slot after the gate, a buffer: no gradient reaches it and it
+    enters nothing but the choice); their weights are s, divided by their
+    sum + 1e-20 when `renormalize`, times `routed_scale`. No auxiliary loss
+    is defined for it here.
+    shared_hidden_size: a dense expert of that width beside the routed ones,
+    applied to every token and added to their sum; it has the routed
+    experts' form (activation, gated or not) and no bias: ws1 [D, Hs]
+    (gated: ws3 [D, Hs]) and ws2 [Hs, out], the last weight slots.
+    held_experts: None, or (first, count): this op HOLDS that range of the
+    `num_experts` the router chooses among, and its expert tensors have
+    `count` as their leading dim. The router keeps its width and its k; the
+    op computes its own experts' part of the result for the rows routed to
+    them, and what the absent experts would add is left out (the share of a
+    layer that expert parallelism gives one chip, without the exchange).
+    The shared expert is whole in every share.
+
     outputs: [.., out] and, when lambda_bal > 0 or lambda_z > 0, one
     float32 scalar [1] to be added to the training loss (reference: MoE
     lambda argument, moe.cc), whatever the compute dtype:
@@ -176,19 +195,56 @@ class ExpertsAttrs:
     gated: bool = False
     renormalize: bool = True
     lambda_z: float = 0.0
+    scoring: str = "softmax"
+    selection_bias: bool = False
+    routed_scale: float = 1.0
+    shared_hidden_size: int = 0
+    held_experts: Optional[Tuple[int, int]] = None
 
     def __post_init__(self):
         assert not (self.gated and self.use_bias), (
             "the gated expert form has no biases: pass use_bias=False"
         )
+        assert self.scoring in ("softmax", "sigmoid"), self.scoring
+        assert not (self.scoring == "sigmoid" and self.has_aux), (
+            "no auxiliary router loss is defined for sigmoid scoring"
+        )
+        assert not (self.selection_bias and self.scoring != "sigmoid"), (
+            "the selection bias belongs to sigmoid scoring"
+        )
+        if self.held_experts is not None:
+            first, count = self.held_experts
+            assert 0 <= first and count > 0 and first + count <= self.num_experts, (
+                f"held experts {self.held_experts} outside 0..{self.num_experts}"
+            )
+            assert self.capacity_factor is None and not self.use_bias, (
+                "a held share of the experts is dropless and has no biases"
+            )
 
     @property
     def has_aux(self) -> bool:
         return self.lambda_bal > 0 or self.lambda_z > 0
 
     @property
+    def num_local_experts(self) -> int:
+        """Experts whose matrices this op has."""
+        return self.held_experts[1] if self.held_experts else self.num_experts
+
+    def weight_roles(self) -> List[str]:
+        """What each weight slot is, in slot order: "router" (the gate and
+        the selection bias: whole wherever the op runs), "expert" (leading
+        dim the local experts) or "shared" (the shared expert's matrices)."""
+        roles = ["router"] * (2 if self.selection_bias else 1)
+        roles += ["expert"] * (
+            3 if self.gated else (4 if self.use_bias else 2)
+        )
+        if self.shared_hidden_size:
+            roles += ["shared"] * (3 if self.gated else 2)
+        return roles
+
+    @property
     def num_weights(self) -> int:
-        return 4 if self.gated else (5 if self.use_bias else 3)
+        return len(self.weight_roles())
 
     def _out_dim(self, input: TensorShape) -> int:
         return self.out_channels or input.dims[-1]
@@ -212,11 +268,11 @@ class ExpertsAttrs:
 
     def weight_shapes(self, input: TensorShape) -> List[TensorShape]:
         d = input.dims[-1]
-        e, h, o = self.num_experts, self.hidden_size, self._out_dim(input)
-        ws = [
-            TensorShape((d, e), input.dtype),
-            TensorShape((e, d, h), input.dtype),
-        ]
+        e, h, o = self.num_local_experts, self.hidden_size, self._out_dim(input)
+        ws = [TensorShape((d, self.num_experts), input.dtype)]
+        if self.selection_bias:
+            ws.append(TensorShape((self.num_experts,), input.dtype))
+        ws.append(TensorShape((e, d, h), input.dtype))
         if self.gated:
             ws.append(TensorShape((e, d, h), input.dtype))
         if self.use_bias:
@@ -224,6 +280,10 @@ class ExpertsAttrs:
         ws.append(TensorShape((e, h, o), input.dtype))
         if self.use_bias:
             ws.append(TensorShape((e, o), input.dtype))
+        if self.shared_hidden_size:
+            hs = self.shared_hidden_size
+            ws += [TensorShape((d, hs), input.dtype)] * (2 if self.gated else 1)
+            ws.append(TensorShape((hs, o), input.dtype))
         return ws
 
     # -- parallel (expert parallelism; see module docstring) ---------------
@@ -236,6 +296,12 @@ class ExpertsAttrs:
         # the input must be fully reduced before expert dispatch
         assert input.sum_degree == 1, "experts input must not be a partial sum"
         ep = input.discard_copy_degree
+        assert ep == 1 or (
+            self.held_experts is None and not self.shared_hidden_size
+        ), (
+            "a held share is already one expert-parallel shard, and a shared "
+            "expert would be summed once per shard"
+        )
         unpars = self.output_shapes(get_reduced_shape(input))
         in_degrees = input.shard_degrees()
         out = lift_to_parallel_with_degrees(unpars[0], ep, 1, in_degrees)
@@ -255,8 +321,8 @@ class ExpertsAttrs:
         batch = _prod(input.shard_degrees())
         unpars = self.weight_shapes(get_reduced_shape(input))
         out: List[ParallelTensorShape] = []
-        for i, w in enumerate(unpars):
-            if i == 0:  # gate: replicated everywhere (every shard gates)
+        for role, w in zip(self.weight_roles(), unpars):
+            if role != "expert":  # whole everywhere (every shard gates)
                 out.append(
                     lift_to_parallel_with_degrees(
                         w, 1, ep * batch, (1,) * w.num_dims

@@ -750,33 +750,37 @@ def _try_sharded_experts(attrs, slot_vals, in_tensors, shardings, mesh):
         return None
     x = slot_vals[0]
     x_sh = shardings.get(in_tensors[0])
-    w_shs = [shardings.get(t) for t in in_tensors[2:]]
+    # the router's slots and the shared expert's are whole on every shard;
+    # the expert tensors shard their leading dim over the expert axes
+    roles = attrs.weight_roles()
+    first_expert = 1 + roles.index("expert")
     x_spec = (None,) * x.ndim if x_sh is None else _padded_spec(x_sh, x.ndim)
-    ep_entry = _spec_entry(w_shs[0], 0)
+    ep_entry = _spec_entry(shardings.get(in_tensors[first_expert]), 0)
     batch_axes = tuple(a for e in x_spec[:-1] for a in _entry_names(e))
     ep_axes = _entry_names(ep_entry)
     if x_spec[-1] is not None or not (batch_axes or ep_axes):
         return None
     if set(batch_axes) & set(ep_axes):
         return None
-    for w, sh in zip(slot_vals[2:], w_shs):
+    for i, role in enumerate(roles, start=1):
+        w, sh = slot_vals[i], shardings.get(in_tensors[i])
         spec = (None,) * w.ndim if sh is None else _padded_spec(sh, w.ndim)
-        if spec[0] != ep_entry or any(e is not None for e in spec[1:]):
+        lead = ep_entry if role == "expert" else None
+        if spec[0] != lead or any(e is not None for e in spec[1:]):
             return None
-    gate_sh = shardings.get(in_tensors[1])
-    if gate_sh is not None and any(e is not None for e in gate_sh.spec):
-        return None
     ep = _mesh_axes_size(mesh, ep_axes)
     if attrs.num_experts % ep:
         return None
+    if ep > 1 and (attrs.held_experts or attrs.shared_hidden_size):
+        return None  # a held share is one expert shard already
     here = attrs.num_experts // ep
 
-    def local(x, gate, *ws):
+    def local(x, *ws):
         shard = None
         if ep > 1:
             shard = (jax.lax.axis_index(ep_axes) * here, here)
         res = experts_forward(
-            attrs, x, [gate, *ws], expert_shard=shard, per_shard=True
+            attrs, x, list(ws), expert_shard=shard, per_shard=True
         )
         if ep > 1:
             res[0] = jax.lax.psum(res[0], ep_axes)
@@ -785,8 +789,11 @@ def _try_sharded_experts(attrs, slot_vals, in_tensors, shardings, mesh):
         return tuple(res)
 
     in_specs = (
-        P(*x_spec), P(),
-        *[P(ep_entry, *[None] * (w.ndim - 1)) for w in slot_vals[2:]],
+        P(*x_spec),
+        *[
+            P(ep_entry, *[None] * (w.ndim - 1)) if role == "expert" else P()
+            for role, w in zip(roles, slot_vals[1:])
+        ],
     )
     out_specs = (P(*x_spec),) + ((P(),) if attrs.has_aux else ())
     return list(_shard_map(local, mesh, in_specs, out_specs)(*slot_vals))

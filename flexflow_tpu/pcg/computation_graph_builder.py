@@ -244,14 +244,16 @@ class ComputationGraphBuilder:
         causal: bool = False,
         rope_theta: Optional[float] = None,
         qk_norm_eps: Optional[float] = None,
+        num_kv_heads: Optional[int] = None,
     ) -> Tensor:
         """`causal`, `rope_theta` (rotary positions 0..s-1 on q and k) and
         `qk_norm_eps` (RMS norm of the projected q and k over all heads'
         features, two gain weights) are what a decoder adds; a causal node
-        is a `RingAttentionAttrs`, the program's causal attention."""
+        is a `RingAttentionAttrs`, the program's causal attention.
+        `num_kv_heads` fewer than `num_heads` is grouped-query attention."""
         fields = (
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
-            add_zero_attn, rope_theta, qk_norm_eps,
+            add_zero_attn, rope_theta, qk_norm_eps, num_kv_heads,
         )
         if causal:
             from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
@@ -553,11 +555,17 @@ class ComputationGraphBuilder:
         renormalize: bool = True,
         lambda_z: float = 0.0,
         initializer: Optional[InitializerAttrs] = None,
+        scoring: str = "softmax",
+        selection_bias: bool = False,
+        routed_scale: float = 1.0,
+        shared_hidden_size: int = 0,
+        held_experts: Optional[Tuple[int, int]] = None,
     ) -> List[Tensor]:
         """Fused MoE FFN (`ExpertsAttrs`); returns [out] or, with an
         auxiliary loss coefficient, [out, aux_loss], the scalar recorded in
         `self.aux_loss_tensors` for the training loss. `initializer`, if
-        given, initializes every weight slot."""
+        given, initializes every weight slot but the selection bias, a
+        buffer that starts at zero and takes no gradient."""
         from flexflow_tpu.op_attrs.ops.moe import ExpertsAttrs
 
         attrs = ExpertsAttrs(
@@ -572,13 +580,47 @@ class ComputationGraphBuilder:
             gated,
             renormalize,
             lambda_z,
+            scoring,
+            selection_bias,
+            routed_scale,
+            shared_hidden_size,
+            held_experts,
         )
-        outs = self.add_layer(
-            attrs, [input], [initializer] * attrs.num_weights, name
-        )
+        inits = [initializer] * attrs.num_weights
+        if selection_bias:
+            inits[1] = None  # a vector's own default: zero
+        outs = self.add_layer(attrs, [input], inits, name)
         if len(outs) > 1 and outs[1] not in self.aux_loss_tensors:
             self.aux_loss_tensors.append(outs[1])
         return outs
+
+    def state_space(
+        self,
+        input: Tensor,
+        num_heads: int,
+        head_dim: int,
+        state_size: int,
+        num_groups: int = 1,
+        conv_kernel: int = 4,
+        chunk_size: int = 128,
+        norm_eps: float = 1e-5,
+        initializer: Optional[InitializerAttrs] = None,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """The selective state-space mixer (`StateSpaceAttrs`) on
+        [batch, seq, channel]. `initializer`, if given, initializes the two
+        projections; the convolution, `dt_bias`, `A_log`, `D` and the norm's
+        gain take the op's own defaults."""
+        from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
+
+        attrs = StateSpaceAttrs(
+            num_heads, head_dim, state_size, num_groups, conv_kernel,
+            chunk_size, norm_eps,
+        )
+        inits = [None] * attrs.num_weights
+        inits[0] = inits[-1] = initializer
+        (out,) = self.add_layer(attrs, [input], inits, name)
+        return out
 
     def moe(
         self,

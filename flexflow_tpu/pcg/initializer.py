@@ -61,6 +61,26 @@ class ConstantInitializerAttrs:
 
 
 @dataclass(frozen=True)
+class LogOfUniformInitializerAttrs:
+    """log(u), u uniform in [min_val, max_val]: a rate stored as its log
+    (the state-space op's `A_log`)."""
+
+    min_val: float = 1.0
+    max_val: float = 16.0
+
+
+@dataclass(frozen=True)
+class InverseSoftplusLogUniformInitializerAttrs:
+    """softplus^-1(t), t log-uniform in [min_val, max_val] and at least
+    `floor`: a step size stored so that softplus gives it back (the
+    state-space op's `dt_bias`)."""
+
+    min_val: float = 1e-3
+    max_val: float = 1e-1
+    floor: float = 1e-4
+
+
+@dataclass(frozen=True)
 class StackedInitializerAttrs:
     """Initializer of a branch-stacked weight [k, *inner] (see
     compiler/branch_stacking.py): slice i is initialized with `inner` under
@@ -79,6 +99,8 @@ InitializerAttrs = Union[
     NormInitializerAttrs,
     TruncatedNormalInitializerAttrs,
     ConstantInitializerAttrs,
+    LogOfUniformInitializerAttrs,
+    InverseSoftplusLogUniformInitializerAttrs,
     StackedInitializerAttrs,
 ]
 
@@ -125,6 +147,16 @@ def initialize(attrs: InitializerAttrs, key, shape, dtype):
         return std * jax.random.normal(key, shape, dtype)
     if isinstance(attrs, UniformInitializerAttrs):
         return jax.random.uniform(key, shape, dtype, attrs.min_val, attrs.max_val)
+    if isinstance(attrs, LogOfUniformInitializerAttrs):
+        u = jax.random.uniform(
+            key, shape, jnp.float32, attrs.min_val, attrs.max_val
+        )
+        return jnp.log(u).astype(dtype)
+    if isinstance(attrs, InverseSoftplusLogUniformInitializerAttrs):
+        lo, hi = np.log(attrs.min_val), np.log(attrs.max_val)
+        t = jnp.exp(jax.random.uniform(key, shape, jnp.float32, lo, hi))
+        t = jnp.maximum(t, attrs.floor)
+        return (t + jnp.log(-jnp.expm1(-t))).astype(dtype)
     if isinstance(attrs, NormInitializerAttrs):
         return attrs.mean + attrs.stddev * jax.random.normal(key, shape, dtype)
     if isinstance(attrs, TruncatedNormalInitializerAttrs):

@@ -474,32 +474,72 @@ def column_parallel_embedding_rule(degree: int) -> Substitution:
     )
 
 
-def _experts_pattern(use_bias, gated, with_aux, div=None):
+def _experts_pattern(use_bias, gated, with_aux, div=None, shared=False):
     """(attribute pattern, weight slots, outputs) of one form of the Experts
     op. The forms differ in their number of weight slots (legacy with and
-    without biases, gated) and outputs (an auxiliary scalar or none), and a
-    pattern has a fixed number of both. `with_aux` matches lambda_bal != 0
-    (with or without a z-loss); a z-loss alone has no rule."""
-    eq = dict(use_bias=use_bias, gated=gated)
+    without biases, gated; `shared`: the bias-free form with a selection
+    bias and a shared expert, two or three more slots) and outputs (an
+    auxiliary scalar or none), and a pattern has a fixed number of both.
+    `with_aux` matches lambda_bal != 0 (with or without a z-loss); a z-loss
+    alone has no rule."""
+    eq = dict(use_bias=use_bias, gated=gated, selection_bias=shared)
+    ne = {}
     if not with_aux:
         eq.update(lambda_bal=0.0, lambda_z=0.0)
+    else:
+        ne.update(lambda_bal=0.0)
+    if shared:
+        ne.update(shared_hidden_size=0)
+    else:
+        eq.update(shared_hidden_size=0)
     pattern = _attr_pattern(
-        OperatorType.EXPERTS,
-        eq=eq,
-        div=div,
-        ne=dict(lambda_bal=0.0) if with_aux else None,
+        OperatorType.EXPERTS, eq=eq, div=div, ne=ne or None
     )
     num_w = 4 if gated else (5 if use_bias else 3)
+    if shared:
+        num_w += 1 + (3 if gated else 2)
     return pattern, num_w, 2 if with_aux else 1
 
 
-def _experts_tag(use_bias, gated, with_aux):
+def _experts_tag(use_bias, gated, with_aux, shared=False):
     form = "g" if gated else ("b" if use_bias else "nb")
-    return f"{form}{'_aux' if with_aux else ''}"
+    return f"{form}{'_sh' if shared else ''}{'_aux' if with_aux else ''}"
+
+
+def data_parallel_state_space_rule(degree: int) -> Substitution:
+    """StateSpace(x, w...) -> Combine_0(StateSpace(Repartition_0(x),
+    Replicate(w)...)): the scan runs along the sequence of each sample by
+    itself, so the batch dim shards and nothing else does."""
+    from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
+
+    p = PCGPattern()
+    a = p.add_input(_shard_pattern(0, degree))
+    ws = [p.add_input() for _ in range(StateSpaceAttrs.num_weights)]
+    pnode, (py,) = p.add_operator(
+        OperatorAttributePattern.for_op_type(OperatorType.STATE_SPACE), [a, *ws]
+    )
+    og = OutputGraphExpr()
+    oa = og.add_input()
+    ows = [og.add_input() for _ in ws]
+    _, (ap,) = og.add_operator(AttrConstant(RepartitionAttrs(0, degree)), [oa])
+    reps = []
+    for ow in ows:
+        _, (wr,) = og.add_operator(AttrConstant(ReplicateAttrs(degree)), [ow])
+        reps.append(wr)
+    _, (y,) = og.add_operator(CopyAttrsFromMatched(pnode), [ap, *reps])
+    _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [y])
+    return Substitution(
+        f"data_parallel_state_space_{degree}",
+        p,
+        og,
+        ((a, oa), *zip(ws, ows)),
+        ((py, out),),
+    )
 
 
 def data_parallel_experts_rule(
-    degree: int, use_bias: bool, gated: bool = False, with_aux: bool = False
+    degree: int, use_bias: bool, gated: bool = False, with_aux: bool = False,
+    shared: bool = False,
 ) -> Substitution:
     """Experts(x, gate, w...) -> Combine_0(Experts(Repartition_0(x),
     Replicate(gate), Replicate(w)...)): sample parallelism for the MoE FFN.
@@ -509,7 +549,9 @@ def data_parallel_experts_rule(
     data-parallel MoE training does and not the one-device value. As in the
     expert-parallel rule the auxiliary output is found structurally, not
     interface-mapped."""
-    attr_pattern, num_w, num_out = _experts_pattern(use_bias, gated, with_aux)
+    attr_pattern, num_w, num_out = _experts_pattern(
+        use_bias, gated, with_aux, shared=shared
+    )
     p = PCGPattern()
     a = p.add_input(_shard_pattern(0, degree))
     ws = [p.add_input() for _ in range(num_w)]
@@ -527,8 +569,8 @@ def data_parallel_experts_rule(
     )
     _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [youts[0]])
     return Substitution(
-        f"data_parallel_experts_{_experts_tag(use_bias, gated, with_aux)}"
-        f"_{degree}",
+        f"data_parallel_experts_"
+        f"{_experts_tag(use_bias, gated, with_aux, shared)}_{degree}",
         p,
         og,
         ((a, oa), *zip(ws, ows)),
@@ -1042,6 +1084,7 @@ def generate_parallelization_rules(
             )
         rules.append(data_parallel_layer_norm_rule(k))
         rules.append(data_parallel_rms_norm_rule(k))
+        rules.append(data_parallel_state_space_rule(k))
         rules.append(sequence_parallel_attention_rule(k))
         rules.append(sequence_parallel_attention_a2a_rule(k))
         # sequence-axis (dim=1) variants: the seq-parallel residual stream's
@@ -1077,6 +1120,8 @@ def generate_parallelization_rules(
                 rules.append(
                     data_parallel_experts_rule(k, use_bias, gated, with_aux)
                 )
+        # the bias-balanced form: a selection bias and a shared expert
+        rules.append(data_parallel_experts_rule(k, False, shared=True))
         # branch parallelism over stacked isomorphic branches
         # (compiler/branch_stacking.py): shard the stacked leading axis,
         # merge via local sum + Reduction

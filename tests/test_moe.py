@@ -154,6 +154,41 @@ def test_experts_gradients_flow():
     assert float(jnp.abs(gw[1]).sum()) > 0  # expert weights get gradient
 
 
+@pytest.mark.parametrize("alpha", [4.0, 0.5], ids=["no_drops", "drops"])
+@pytest.mark.parametrize("ep", [2, 4])
+def test_expert_shards_parts_add_up_to_the_whole(ep, alpha):
+    """What `experts_forward` gives for each expert-parallel shard's slice
+    of the expert tensors (`expert_shard`, the form `shard_map` calls) adds
+    up to the unsharded call's output and gradients: biases, a capacity
+    that drops decisions and the auxiliary scalar (the same on every shard)
+    included."""
+    attrs, x, weights = make_experts(B=24, alpha=alpha, lambda_bal=0.01)
+    here = attrs.num_experts // ep
+    cot = jnp.asarray(np.random.RandomState(1).randn(*x.shape), jnp.float32)
+
+    def whole(x, weights):
+        out, aux = experts_forward(attrs, x, weights)
+        return jnp.sum(out * cot) + aux[0]
+
+    def parts(x, weights):
+        gate, rest = weights[0], weights[1:]
+        total = 0.0
+        for i in range(ep):
+            mine = [w[i * here:(i + 1) * here] for w in rest]
+            out, aux = experts_forward(
+                attrs, x, [gate, *mine], expert_shard=(i * here, here)
+            )
+            total = total + jnp.sum(out * cot)
+        return total + aux[0]
+
+    tol = dict(rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(parts(x, weights), whole(x, weights), **tol)
+    got = jax.grad(parts, argnums=(0, 1))(x, weights)
+    want = jax.grad(whole, argnums=(0, 1))(x, weights)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(g, w, **tol)
+
+
 def test_experts_parallel_shapes_expert_parallelism():
     """Replicated input (discard_copy=ep) -> expert weights sharded on the
     expert dim, output carries sum_degree=ep (the Unity reduction pattern)."""

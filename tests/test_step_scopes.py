@@ -117,6 +117,12 @@ def test_scope_name_survives_the_name_stack(layer_name, want):
          ("fwd", "mha", "a0")),
         ("jit(_step)/while/body/transpose(jvp(ff.dense.l0))/dot_general",
          ("bwd", "dense", "l0")),
+        # a state-space node's scan keeps its own scope in the name
+        ("jit(_step)/jvp(ff.ssm.m0)/scan/dot_general", ("fwd", "ssm", "m0/scan")),
+        ("jit(_step)/transpose(jvp(ff.ssm.m0))/scan/checkpoint/mul",
+         ("bwd", "ssm", "m0/scan")),
+        ("jit(_step)/jvp(ff.ssm.m0)/dot_general", ("fwd", "ssm", "m0")),
+        ("jit(_step)/jvp(ff.dense.d)/scan/mul", ("fwd", "dense", "d")),
         ("params['n3']", ("unattributed", "", "")),
         ("jit(_step)/jvp(diff.dense.x)/mul", ("unattributed", "", "")),
         ("", ("unattributed", "", "")),
