@@ -17,32 +17,58 @@ chunk, for positions i, j of one chunk:
 
 Everything inside a chunk is a matrix product ([Q, N] x [N, Q] once a GROUP,
 since the heads of a group share B and C; [Q, Q] x [Q, P] and [P, Q] x [Q, N]
-a head), and only the [heads, P, N] states go from chunk to chunk, in a
-sequential `lax.scan` over the s / Q chunks. Nothing is approximated: the
+a head), and only the [heads, P, N] states go from chunk to chunk, one
+after the other over the s / Q chunks. Nothing is approximated: the
 decays are exponentials of float32 differences of float32 running sums,
 masked BEFORE the exponential (La_i - La_j is positive above the diagonal
 and may overflow), and the states are float32. The matrix products take
 operands in the input's dtype (bf16 in a bf16 step) and accumulate in
 float32.
 
-The backward pass is JAX's own transpose of this form under `jax.checkpoint`:
-what is kept from the forward is the scan's INPUTS (x, dt, B, C: a few bytes a
-feature and position); the chunk-boundary states and everything inside the
-chunks are recomputed from them when the gradient is taken. No state per
-position ever exists: the largest tensors are the [chunks, heads, Q, Q] decay
-masks, alive in one layer's backward at a time.
+Two forms of these chunks exist, and `scan_route` picks one from what the
+trace can observe (shapes, backend, `no_flash`, `flash_mesh`); nothing but
+the order of the floating-point sums differs between them:
+
+- **The Pallas kernels** (`ssd_fwd_chunk`, `ssd_states_chunk`,
+  `ssd_bwd_chunk`; "ssd" / "ssd_sharded"): on a TPU, at chunk and state
+  sizes that are multiples of 128 lanes and heads of 64 or 128 whose group
+  fills whole 128-lane tiles. One program is one (batch row, group, chunk);
+  C.B, the running-sum differences, the masked exponentials, the products
+  and the state update of a chunk live in VMEM and only x, dt, B, C and y
+  (and their gradients) cross HBM; the [r * P, N] states ride a VMEM scratch
+  from chunk to chunk. The D x skip is added in the kernel. The backward is
+  a `jax.custom_vjp`: it keeps the scan's INPUTS (x, dt, B, C, D and the
+  [b, s, heads] float32 running sums, a 64th of x), recomputes the state
+  every chunk starts from in a first pass (`ssd_states_chunk`, a transient
+  of one layer) and walks the chunks last to first in a second, carrying the
+  state's gradient in VMEM. The running sums, and their transpose in the
+  backward, are XLA's `cumsum` outside the kernels, in float32.
+- **The XLA form** (`_scan_core`; "xla"): everything else: the CPU, toy
+  widths, a trace that admits no bare Pallas call. Plain batched matmuls and
+  a sequential `lax.scan` over the chunks' states, with the D x skip added
+  after it. The backward is JAX's own transpose of it under
+  `jax.checkpoint`: kept are the scan's inputs, and the chunk-boundary
+  states and everything inside the chunks are recomputed; the largest
+  tensors are the [chunks, heads, Q, Q] decay masks in HBM, alive in one
+  layer's backward at a time.
+
+In neither form does a state per position ever exist.
 
 The scan's device operations go under a `scan` scope inside the node's
-(`ff.ssm.<name>/scan`), so a trace reader can tell it from the projections.
+(`ff.ssm.<name>/scan`), the backward's as the transpose of it, so a trace
+reader can tell the scan from the projections.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 
@@ -64,12 +90,22 @@ def causal_depthwise_conv(x, weight, bias):
 
 def gated_group_norm(y, z, gain, groups: int, eps: float):
     """rms_norm(y * silu(z)) with the mean of squares over each of `groups`
-    equal runs of the last dim, in float32; the result in y's dtype."""
+    equal runs of the last dim, in float32; the result in y's dtype. A run
+    is a slice of the row, not a dimension of its own: a reshape to
+    [.., groups, width] re-tiles the row on a TPU, which XLA pays with
+    copies of the whole float32 tensor in both passes (0.67 ms a layer at
+    [4096, 8 x 512] beside the scan's kernels; my chip run, PR 33)."""
     g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    shape = g.shape
-    g = g.reshape(*shape[:-1], groups, shape[-1] // groups)
-    g = g * lax.rsqrt(jnp.mean(jnp.square(g), axis=-1, keepdims=True) + eps)
-    return (g.reshape(shape) * gain.astype(jnp.float32)).astype(y.dtype)
+    width = g.shape[-1] // groups
+    runs = [g[..., k * width:(k + 1) * width] for k in range(groups)]
+    g = jnp.concatenate(
+        [
+            run * lax.rsqrt(jnp.mean(jnp.square(run), axis=-1, keepdims=True) + eps)
+            for run in runs
+        ],
+        axis=-1,
+    )
+    return (g * gain.astype(jnp.float32)).astype(y.dtype)
 
 
 def _scan_core(x, dt, a_log, b_mat, c_mat, chunk: int):
@@ -137,13 +173,465 @@ def _scan_core(x, dt, a_log, b_mat, c_mat, chunk: int):
     return jnp.moveaxis(y, 4, 2).reshape(b, s, h, p)
 
 
+# ---------------------------------------------------------------------------
+# the same chunks as Pallas kernels: everything of a chunk stays in VMEM
+# ---------------------------------------------------------------------------
+#
+# One program is one (batch row, group, chunk). It reads the chunk's x rows of
+# the group's r = heads / groups heads straight from the [b, s, heads * P] row
+# layout (a head is a P-lane slice of the [Q, r * P] tile), B and C [Q, N],
+# and dt and the running sum La as [Q, r] columns (La as [r, Q] rows too, so
+# that La_i - La_j needs no transpose). The chunk axis is the last grid axis
+# and sequential: the group's [r * P, N] float32 states ride a VMEM scratch
+# from chunk to chunk (from the last chunk to the first in the backward).
+# Products with a [Q, Q] mask run per head; the products with the state run
+# once a group over all r * P rows or columns. The D x skip is added here.
+
+_NN = (((1,), (0,)), ((), ()))  # [m, k] x [k, n]
+_NT = (((1,), (1,)), ((), ()))  # [m, k] x [n, k]
+_TN = (((0,), (0,)), ((), ()))  # [k, m] x [k, n]
+
+# r * P columns a program holds at most: the [r * P, N] state and a few
+# [Q, r * P] float32 tiles have to fit the scoped VMEM. 512 is what the
+# published widths need (8 heads of 64 a group) and what was compiled.
+_MAX_GROUP_COLUMNS = 512
+_LANES = 128
+
+
+def _mm(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _end_decay_columns(la_end, p: int):
+    """exp(La_Q) of every head as a [P, r] block of equal rows: a head's
+    column scales its [P, N] state. Mosaic broadcasts a value along sublanes
+    or along lanes, not both at once, so a [1, 1] decay cannot meet a state;
+    the select is what lays the [1, r] row out over P real rows (a plain
+    `broadcast_to` stays a replicated layout, which the column slice then
+    aborts on; jax 0.9.0)."""
+    shape = (p, la_end.shape[1])
+    rows = lax.broadcasted_iota(jnp.int32, shape, 0)
+    return jnp.exp(jnp.where(rows >= 0, la_end, 0.0))
+
+
+def _causal(q: int):
+    rows = lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    cols = lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    return rows >= cols
+
+
+def _decay_mask(lac, lar_ref, hh: int, causal):
+    """exp(La_i - La_j) for j <= i of head `hh`, float32 [Q, Q]: masked
+    before the exponential."""
+    diff = lac[:, hh:hh + 1] - lar_ref[hh:hh + 1, :]
+    return jnp.exp(jnp.where(causal, diff, -jnp.inf))
+
+
+def _heads_of_tile(p: int):
+    """(k, lane_head): the k = 128 / P heads a 128-lane tile of the
+    [Q, r * P] row holds, and each lane's head within the tile, [1, 128]."""
+    return _LANES // p, lax.broadcasted_iota(jnp.int32, (1, _LANES), 1) // p
+
+
+def _over_lanes(cols, first: int, k: int, lane_head):
+    """Columns `first .. first + k` of cols [Q, r], each laid over its head's
+    P lanes of a tile: [Q, 128]."""
+    out = cols[:, first + k - 1:first + k]
+    for i in reversed(range(k - 1)):
+        out = jnp.where(lane_head == i, cols[:, first + i:first + i + 1], out)
+    return out
+
+
+def _ssd_fwd_kernel(
+    x_ref, b_ref, c_ref, dtc_ref, lac_ref, lar_ref, d_ref, out_ref,
+    state, xe_ref, *inter, heads: int, p: int, states_only: bool,
+):
+    """The forward of one chunk. `states_only` is the backward's first pass:
+    it writes the state the chunk STARTS from and skips what only y needs.
+    The row is walked a 128-lane tile (128 / P heads) at a time, so that
+    every elementwise pass fills its vregs; a head's masked product is taken
+    over the whole tile (the MXU is 128 columns wide either way) and kept on
+    the head's own lanes."""
+    f32 = jnp.float32
+    q = x_ref.shape[0]
+    dtype = x_ref.dtype
+    k, lane_head = _heads_of_tile(p)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_chunk():
+        state[:] = jnp.zeros_like(state)
+
+    lac, dtc = lac_ref[:], dtc_ref[:]
+    la_end = lac[q - 1:q, :]  # [1, r]
+    to_end = jnp.exp(la_end - lac)  # [Q, r], exponent <= 0
+    bm = b_ref[:]
+    h_in = state[:]
+    if states_only:
+        out_ref[:] = h_in
+    else:
+        (inter_ref,) = inter
+        cm = c_ref[:]
+        cb = _mm(cm, bm, _NT)  # C_i . B_j, once a group
+        inter_ref[:] = _mm(cm, h_in.astype(dtype), _NT)  # [Q, r * P]
+        e_la = jnp.exp(lac)
+        causal = _causal(q)
+    for t in range(heads // k):
+        tile = pl.ds(t * _LANES, _LANES)
+        x = x_ref[:, tile].astype(f32)
+        xdt = (x * _over_lanes(dtc, t * k, k, lane_head)).astype(dtype)
+        xe_ref[:, tile] = (
+            xdt.astype(f32) * _over_lanes(to_end, t * k, k, lane_head)
+        ).astype(dtype)
+        if states_only:
+            continue
+        y = None
+        for i in range(k):
+            decay = _decay_mask(lac, lar_ref, t * k + i, causal)
+            y_i = _mm((cb * decay).astype(dtype), xdt, _NN)
+            y = y_i if y is None else jnp.where(lane_head == i, y_i, y)
+        y = y + inter_ref[:, tile] * _over_lanes(e_la, t * k, k, lane_head)
+        out_ref[:, tile] = (y + d_ref[:, tile] * x).astype(out_ref.dtype)
+    s_chunk = _mm(xe_ref[:], bm, _TN)  # [r * P, N]
+    decay_end = _end_decay_columns(la_end, p)
+    for hh in range(heads):
+        rows = slice(hh * p, (hh + 1) * p)
+        state[pl.ds(hh * p, p), :] = (
+            h_in[rows] * decay_end[:, hh:hh + 1] + s_chunk[rows]
+        )
+
+
+def _ssd_bwd_kernel(
+    x_ref, dy_ref, b_ref, c_ref, dtc_ref, lac_ref, lar_ref, d_ref, hin_ref,
+    dx_ref, db_ref, dc_ref, ddtc_ref, dlac_ref, dlar_ref, dd_ref,
+    dstate, xe_ref, dye_ref, dxe_ref, inter_ref, *, heads: int, p: int,
+):
+    """The backward of one chunk, the chunks visited last to first: `dstate`
+    carries the gradient of the state the chunk ENDS in. Writes dx, dB and
+    dC (summed over the group's heads here), per head and position the
+    gradient of dt through x dt and the gradient of La (a column, and a row
+    for the part that sums over a mask's rows); d_skip's gradient adds up
+    over the chunks in its resident output block. Tiles as in the forward."""
+    f32 = jnp.float32
+    q = x_ref.shape[0]
+    dtype = x_ref.dtype
+    k, lane_head = _heads_of_tile(p)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _last_chunk():
+        dstate[:] = jnp.zeros_like(dstate)
+        dd_ref[:] = jnp.zeros_like(dd_ref)
+
+    lac, dtc = lac_ref[:], dtc_ref[:]
+    la_end = lac[q - 1:q, :]
+    to_end = jnp.exp(la_end - lac)
+    e_la = jnp.exp(lac)
+    bm, cm = b_ref[:], c_ref[:]
+    h_in, dh = hin_ref[:], dstate[:]
+    cb = _mm(cm, bm, _NT)
+    dxe_ref[:] = _mm(bm, dh.astype(dtype), _NT)  # d(x dt to_end), [Q, r * P]
+    inter_ref[:] = _mm(cm, h_in.astype(dtype), _NT)
+    causal = _causal(q)
+    decay_end = _end_decay_columns(la_end, p)
+    last = lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    lane = lax.broadcasted_iota(jnp.int32, lac.shape, 1)
+    dg = jnp.zeros((q, q), f32)
+    ddt_cols = jnp.zeros(lac.shape, f32)
+    dla_cols = jnp.zeros(lac.shape, f32)
+
+    def of_head(i, tile_value):  # a head's lanes of a tile, zero elsewhere
+        if k == 1:
+            return tile_value
+        return jnp.where(
+            lane_head == i, tile_value, jnp.zeros_like(tile_value)
+        )
+
+    for t in range(heads // k):
+        tile = pl.ds(t * _LANES, _LANES)
+        x = x_ref[:, tile].astype(f32)
+        dy = dy_ref[:, tile]
+        dyf = dy.astype(f32)
+        dt_t, to_end_t, e_la_t = (
+            _over_lanes(cols, t * k, k, lane_head)
+            for cols in (dtc, to_end, e_la)
+        )
+        xdt = (x * dt_t).astype(dtype)
+        xe = xdt.astype(f32) * to_end_t
+        xe_ref[:, tile] = xe.astype(dtype)
+        dxe = dxe_ref[:, tile]
+        dye = dyf * e_la_t
+        dye_ref[:, tile] = dye.astype(dtype)
+        e_end = dxe * xe
+        through_la = dye * inter_ref[:, tile] - e_end
+        dxdt = None
+        masks = []
+        for i in range(k):
+            decay = _decay_mask(lac, lar_ref, t * k + i, causal)
+            m = cb * decay
+            dm = _mm(of_head(i, dy), xdt, _NT)  # [Q, Q]
+            dg = dg + dm * decay
+            masks.append(dm * m)  # d La_i - d La_j of every pair
+            dxdt_i = _mm(m.astype(dtype), dy, _TN)
+            dxdt = dxdt_i if dxdt is None else jnp.where(
+                lane_head == i, dxdt_i, dxdt
+            )
+        dxdt = dxdt + dxe * to_end_t
+        through_dt = dxdt * x
+        for i, w in enumerate(masks):
+            hh = t * k + i
+            rows = slice(hh * p, (hh + 1) * p)
+            to_last = jnp.sum(of_head(i, e_end), keepdims=True) + jnp.sum(
+                dh[rows] * h_in[rows] * decay_end[:, hh:hh + 1], keepdims=True
+            )
+            dla = (
+                jnp.sum(w, axis=1, keepdims=True)
+                + jnp.sum(of_head(i, through_la), axis=1, keepdims=True)
+                + jnp.where(last, to_last, 0.0)  # [Q, 1] against [1, 1]
+            )
+            dla_cols = jnp.where(lane == hh, dla, dla_cols)
+            dlar_ref[hh:hh + 1, :] = -jnp.sum(w, axis=0, keepdims=True)
+            ddt_cols = jnp.where(
+                lane == hh,
+                jnp.sum(of_head(i, through_dt), axis=1, keepdims=True),
+                ddt_cols,
+            )
+            dstate[pl.ds(hh * p, p), :] = dh[rows] * decay_end[:, hh:hh + 1]
+        dx_ref[:, tile] = (
+            dxdt * dt_t + d_ref[:, tile] * dyf
+        ).astype(dx_ref.dtype)
+        dd_ref[:, tile] += jnp.sum(dyf * x, axis=0, keepdims=True)
+    dlac_ref[:] = dla_cols
+    ddtc_ref[:] = ddt_cols
+    dgc = dg.astype(dtype)
+    dye_all = dye_ref[:]
+    dc_ref[:] = (
+        _mm(dgc, bm, _NN) + _mm(dye_all, h_in.astype(dtype), _NN)
+    ).astype(dc_ref.dtype)
+    db_ref[:] = (
+        _mm(dgc, cm, _TN) + _mm(xe_ref[:], dh.astype(dtype), _NN)
+    ).astype(db_ref.dtype)
+    dstate[:] += _mm(dye_all, cm, _TN)
+
+
+def _by_group(t, groups: int):
+    """[b, s, heads] -> [b, groups, s, heads / groups]."""
+    b, s, h = t.shape
+    return jnp.moveaxis(t.reshape(b, s, groups, h // groups), 2, 1)
+
+
+def _per_head_operands(dt, la, groups: int):
+    """(dt and La as [b, groups, s, r] columns, La as [b, groups, r, s]
+    rows) of the [b, s, heads] float32 arrays."""
+    lac = _by_group(la, groups)
+    return _by_group(dt, groups), lac, jnp.swapaxes(lac, 2, 3)
+
+
+def _from_group(t):
+    """[b, groups, s, r] -> [b, s, groups * r]."""
+    b, g, s, r = t.shape
+    return jnp.moveaxis(t, 1, 2).reshape(b, s, g * r)
+
+
+class _Blocks:
+    """The BlockSpecs of the kernels' operands over the grid (batch, group,
+    chunk); `reverse` visits the chunks last to first."""
+
+    def __init__(self, x, b_mat, dt, groups: int, chunk: int, reverse: bool):
+        b, s, hp = x.shape
+        self.grid = (b, groups, s // chunk)
+        self.r = dt.shape[2] // groups
+        self.rp, self.n = hp // groups, b_mat.shape[2] // groups
+        self.p = self.rp // self.r
+        last = s // chunk - 1
+        at = (lambda ci: last - ci) if reverse else (lambda ci: ci)
+        rp, n, r = self.rp, self.n, self.r
+        # [b, s, groups * width]: the chunk's rows, the group's columns
+        self.x = pl.BlockSpec(
+            (None, chunk, rp), lambda bi, gi, ci: (bi, at(ci), gi)
+        )
+        self.bc = pl.BlockSpec(
+            (None, chunk, n), lambda bi, gi, ci: (bi, at(ci), gi)
+        )
+        # [b, groups, s, r] and [b, groups, r, s]
+        self.col = pl.BlockSpec(
+            (None, None, chunk, r), lambda bi, gi, ci: (bi, gi, at(ci), 0)
+        )
+        self.row = pl.BlockSpec(
+            (None, None, r, chunk), lambda bi, gi, ci: (bi, gi, 0, at(ci))
+        )
+        # [1, groups * rp], and [b, 1, groups * rp] resident over the chunks
+        self.skip = pl.BlockSpec((1, rp), lambda bi, gi, ci: (0, gi))
+        self.sums = pl.BlockSpec(
+            (None, 1, rp), lambda bi, gi, ci: (bi, 0, gi)
+        )
+        # [b, groups, chunks, rp, n]
+        self.state = pl.BlockSpec(
+            (None, None, None, rp, n),
+            lambda bi, gi, ci: (bi, gi, at(ci), 0, 0),
+        )
+
+
+_SEQUENTIAL_CHUNKS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary")
+)
+
+
+# jitted, so that the layers of a model that call a kernel at one shape share
+# ONE trace and ONE Mosaic lowering of its body: a `pallas_call` is traced
+# anew at every call, three kernels a layer, and a process lowers its step
+# more than once (warm `setup_s` of the four-layer cell 38.3-38.9 s without
+# this, 34.0-36.9 with, the XLA form 32.0-34.9; my chip runs, PR 33). The
+# operations keep the scope of the call site they are inlined into.
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9))
+def _ssd_forward(x, dt, la, b_mat, c_mat, d_row, groups, chunk, interpret,
+                 states_only=False):
+    """y [b, s, h * P] in x's dtype, or with `states_only` the float32 state
+    every chunk starts from, [b, groups, chunks, r * P, N]."""
+    f32 = jnp.float32
+    (b, s, _), q = x.shape, chunk
+    at = _Blocks(x, b_mat, dt, groups, chunk, reverse=False)
+    scratch = [pltpu.VMEM((at.rp, at.n), f32), pltpu.VMEM((q, at.rp), x.dtype)]
+    if states_only:
+        out_shape = jax.ShapeDtypeStruct((b, groups, s // q, at.rp, at.n), f32)
+        out_spec = at.state
+    else:
+        out_shape, out_spec = jax.ShapeDtypeStruct(x.shape, x.dtype), at.x
+        scratch.append(pltpu.VMEM((q, at.rp), f32))
+    return pl.pallas_call(
+        functools.partial(
+            _ssd_fwd_kernel, heads=at.r, p=at.p, states_only=states_only
+        ),
+        grid=at.grid,
+        in_specs=[at.x, at.bc, at.bc, at.col, at.col, at.row, at.skip],
+        out_specs=out_spec,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
+        compiler_params=_SEQUENTIAL_CHUNKS,
+        interpret=interpret,
+        name="ssd_states_chunk" if states_only else "ssd_fwd_chunk",
+    )(x, b_mat, c_mat, *_per_head_operands(dt, la, groups), d_row)
+
+
+@functools.partial(jax.jit, static_argnums=(8, 9, 10))
+def _ssd_backward(x, dy, dt, la, b_mat, c_mat, d_row, h_in, groups, chunk,
+                  interpret):
+    """(dx, d dt through x dt, d La, dB, dC, d d_row) of `_ssd_forward`."""
+    f32 = jnp.float32
+    (b, s, hp), q = x.shape, chunk
+    at = _Blocks(x, b_mat, dt, groups, chunk, reverse=True)
+    dtc, lac, lar = _per_head_operands(dt, la, groups)
+    small = jax.ShapeDtypeStruct(lac.shape, f32)
+    dx, db, dc, ddtc, dlac, dlar, dd = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, heads=at.r, p=at.p),
+        grid=at.grid,
+        in_specs=[at.x, at.x, at.bc, at.bc, at.col, at.col, at.row, at.skip,
+                  at.state],
+        out_specs=[at.x, at.bc, at.bc, at.col, at.col, at.row, at.sums],
+        out_shape=[
+            jax.ShapeDtypeStruct(x.shape, x.dtype),
+            jax.ShapeDtypeStruct(b_mat.shape, b_mat.dtype),
+            jax.ShapeDtypeStruct(c_mat.shape, c_mat.dtype),
+            small, small,
+            jax.ShapeDtypeStruct(lar.shape, f32),
+            jax.ShapeDtypeStruct((b, 1, hp), f32),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((at.rp, at.n), f32),
+            pltpu.VMEM((q, at.rp), x.dtype),
+            pltpu.VMEM((q, at.rp), x.dtype),
+            pltpu.VMEM((q, at.rp), f32),
+            pltpu.VMEM((q, at.rp), f32),
+        ],
+        compiler_params=_SEQUENTIAL_CHUNKS,
+        interpret=interpret,
+        name="ssd_bwd_chunk",
+    )(x, dy, b_mat, c_mat, dtc, lac, lar, d_row, h_in)
+    dla = _from_group(dlac) + _from_group(jnp.swapaxes(dlar, 2, 3))
+    return dx, _from_group(ddtc), dla, db, dc, jnp.sum(dd, axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _ssd_scan(groups, chunk, interpret, x, dt, la, b_mat, c_mat, d_row):
+    """The chunked recurrence with the D x skip on row layouts: x
+    [b, s, h * P], dt and la [b, s, h] float32 (la the running sum of dt A
+    within each chunk), b_mat and c_mat [b, s, g * N], d_row [1, h * P]
+    float32 (D repeated over a head's columns) -> y like x. s is a multiple
+    of `chunk`. What the backward keeps is these operands."""
+    return _ssd_forward(x, dt, la, b_mat, c_mat, d_row, groups, chunk, interpret)
+
+
+def _ssd_scan_fwd(groups, chunk, interpret, *operands):
+    return _ssd_scan(groups, chunk, interpret, *operands), operands
+
+
+def _ssd_scan_bwd(groups, chunk, interpret, operands, dy):
+    x, dt, la, b_mat, c_mat, d_row = operands
+    h_in = _ssd_forward(*operands, groups, chunk, interpret, True)
+    return _ssd_backward(
+        x, dy, dt, la, b_mat, c_mat, d_row, h_in, groups, chunk, interpret
+    )
+
+
+_ssd_scan.defvjp(_ssd_scan_fwd, _ssd_scan_bwd)
+
+
+def scan_route(batch, heads, head_dim, groups, state, chunk) -> str:
+    """Which form `selective_scan` takes, from what the trace can observe:
+
+    - "ssd": the Pallas kernels, where the backend is a TPU (or the CPU with
+      interpret mode opted in, `interpret_default`), the tiles fill whole
+      vregs (chunk and state multiples of 128 lanes, a group's r * P columns
+      whole 128-lane tiles of heads of 64 or 128) and fit VMEM
+      (`_MAX_GROUP_COLUMNS`), and the trace admits a bare Pallas call (not
+      under `no_flash()`);
+    - "ssd_sharded": the same under a declared `flash_mesh` with whole heads
+      whose batch axes divide the batch: the kernels mapped over the batch
+      shards, as the attention kernels are;
+    - "xla": everything else, `_scan_core`."""
+    from flexflow_tpu.kernels import flash_attention as flash
+
+    columns = heads // groups * head_dim
+    if (
+        chunk % 128 or state % 128 or columns % 128
+        or head_dim not in (64, 128) or columns > _MAX_GROUP_COLUMNS
+    ):
+        return "xla"
+    ctx = flash.current_flash_mesh()
+    if ctx is None:
+        bare_call_ok = not getattr(flash._tls, "disabled", False)
+        on_chip = flash._backend_ok(flash.interpret_default())
+        return "ssd" if bare_call_ok and on_chip else "xla"
+    mesh, batch_axes, head_axes, interpret = ctx
+    if head_axes is not None or batch % flash._axes_size(mesh, batch_axes):
+        return "xla"
+    return "ssd_sharded" if flash._backend_ok(interpret) else "xla"
+
+
+def _ssd_scan_routed(route, groups, chunk, *operands):
+    from flexflow_tpu.kernels import flash_attention as flash
+
+    if route == "ssd":
+        return _ssd_scan(groups, chunk, flash.interpret_default(), *operands)
+    from jax.sharding import PartitionSpec as P
+
+    from flexflow_tpu.utils.shard_map_compat import shard_map_compat
+
+    mesh, batch_axes, _, interpret = flash.current_flash_mesh()
+    rows = P(batch_axes, None, None)
+    return shard_map_compat(
+        functools.partial(_ssd_scan, groups, chunk, interpret),
+        mesh, (rows,) * 5 + (P(None, None),), rows,
+    )(*operands)
+
+
 def selective_scan(x, dt, a_log, b_mat, c_mat, d_skip, chunk: int):
     """The recurrence of the module docstring: x [b, s, h, p], dt [b, s, h]
     (float32, after softplus), a_log and d_skip [h], b_mat and c_mat
-    [b, s, g, n] -> y [b, s, h, p] in x's dtype. A sequence that is no
-    multiple of `chunk` is padded at its end with dt = 0 and x = 0, which
-    changes no earlier position."""
-    s = x.shape[1]
+    [b, s, g, n] -> y [b, s, h, p] in x's dtype, by the form `scan_route`
+    names. A sequence that is no multiple of `chunk` is padded at its end
+    with dt = 0 and x = 0, which changes no earlier position."""
+    b, s, h, p = x.shape
+    g, n = b_mat.shape[2:]
+    f32 = jnp.float32
     pad = -s % chunk
     if pad:
         def padded(t):
@@ -152,11 +640,23 @@ def selective_scan(x, dt, a_log, b_mat, c_mat, d_skip, chunk: int):
         x_, dt_, b_, c_ = padded(x), padded(dt), padded(b_mat), padded(c_mat)
     else:
         x_, dt_, b_, c_ = x, dt, b_mat, c_mat
+    route = scan_route(b, h, p, g, n, chunk)
     with jax.named_scope("scan"):
-        core = jax.checkpoint(_scan_core, static_argnums=(5,))
-        y = core(x_, dt_, a_log, b_, c_, chunk)[:, :s]
-        y = y + d_skip.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
-        return y.astype(x.dtype)
+        if route == "xla":
+            core = jax.checkpoint(_scan_core, static_argnums=(5,))
+            y = core(x_, dt_, a_log, b_, c_, chunk)[:, :s]
+            y = y + d_skip.astype(f32)[:, None] * x.astype(f32)
+            return y.astype(x.dtype)
+        full = s + pad
+        a = dt_ * (-jnp.exp(a_log.astype(f32)))
+        la = jnp.cumsum(a.reshape(b, full // chunk, chunk, h), axis=2)
+        y = _ssd_scan_routed(
+            route, g, chunk,
+            x_.reshape(b, full, h * p), dt_, la.reshape(b, full, h),
+            b_.reshape(b, full, g * n), c_.reshape(b, full, g * n),
+            jnp.repeat(d_skip.astype(f32), p)[None],
+        )
+        return y[:, :s].reshape(b, s, h, p)
 
 
 def state_space_forward(

@@ -12,8 +12,14 @@ node from the residual stream's normed input to its mixer output.
     y = rms_norm_grouped(y * silu(z); gain, groups) W_out   # inner / groups features a statistic
 
 inner = heads * head_dim. The recurrence is evaluated in chunks of
-`chunk_size` positions (`kernels/ssm.py`): the chunking changes the order of
-the floating-point sums and nothing else.
+`chunk_size` positions (`kernels/ssm.py`), in one of two forms that
+`kernels/ssm.scan_route` picks from the shapes, the backend and the trace:
+Pallas kernels that keep a chunk in VMEM (a TPU, `chunk_size` and
+`state_size` multiples of 128, heads of 64 or 128 whose group fills whole
+128-lane tiles), or XLA matmuls with a scan over the chunks' states
+(everything else). Both keep only the scan's inputs for the backward and
+recompute the chunk-boundary states there; the chunking and the choice of
+form change the order of the floating-point sums and nothing else.
 
 weights (slot order): in_proj [D, 2*inner + 2*groups*state + heads];
 conv weight [conv_kernel, inner + 2*groups*state]; conv bias [that width];

@@ -60,6 +60,12 @@ ADAM = TOY["training"]
 # against per-head einsums), nothing else. Measured 1e-7 to 3e-6 here.
 F32 = dict(rtol=2e-5, atol=2e-5)
 F32_LOSS = 1e-5
+# the state-space op at the widths the Pallas kernels take (inner 256, state
+# 128): its projections and norm sum eight times the products, and BOTH forms
+# of the scan read 9.8e-5 against the recurrence there, on the same element
+F32_WIDE = dict(rtol=2e-5, atol=2e-4)
+# and their gradients 5.1e-4 (the XLA form) and 6.0e-4 (the kernels) over rtol
+F32_WIDE_GRADS = dict(rtol=1e-4, atol=1e-3)
 
 
 def rand(rs, *shape, scale=1.0):
@@ -77,14 +83,30 @@ def assert_trees_close(got, want, **tol):
 # -- the state-space op ------------------------------------------------------
 
 
-def state_space_case(seq, seed=0):
+# the least widths `kernels/ssm.scan_route` gives the Pallas kernels: chunks
+# and a state of 128, 2 heads of 64 a group (128 columns a program)
+KERNEL_TOY = dict(
+    TOY, mamba_num_heads=4, mamba_head_dim=64, ssm_state_size=128, n_groups=2,
+    chunk_size=128,
+)
+SIZES = {"toy": TOY, "kernels": KERNEL_TOY}
+
+
+@pytest.fixture
+def interpreted_kernels(monkeypatch):
+    """The opt-in under which the CPU backend runs the Pallas kernels in
+    interpret mode (`flash_attention.interpret_default`)."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+
+
+def state_space_case(seq, seed=0, sizes=TOY):
     attrs = StateSpaceAttrs(
-        TOY["mamba_num_heads"], TOY["mamba_head_dim"], TOY["ssm_state_size"],
-        TOY["n_groups"], TOY["conv_kernel"], TOY["chunk_size"],
-        TOY["layer_norm_epsilon"],
+        sizes["mamba_num_heads"], sizes["mamba_head_dim"],
+        sizes["ssm_state_size"], sizes["n_groups"], sizes["conv_kernel"],
+        sizes["chunk_size"], sizes["layer_norm_epsilon"],
     )
     rs = np.random.RandomState(seed)
-    d, heads = TOY["hidden_size"], attrs.num_heads
+    d, heads = sizes["hidden_size"], attrs.num_heads
     from flexflow_tpu.op_attrs.datatype import DataType
     from flexflow_tpu.op_attrs.tensor_shape import TensorShape
 
@@ -97,40 +119,206 @@ def state_space_case(seq, seed=0):
     return attrs, rand(rs, BATCH, seq, d), weights
 
 
-def reference_state_space(u, weights):
+def reference_state_space(u, weights, sizes=TOY):
     named = {f"m.weight{i}": w for i, w in enumerate(weights)}
     with jax.default_matmul_precision("highest"):
-        return jax.vmap(lambda row: ref.mamba(named, "m", row, TOY))(u)
+        return jax.vmap(lambda row: ref.mamba(named, "m", row, sizes))(u)
 
 
-@pytest.mark.parametrize("seq", [32, 40, 13])
-def test_state_space_forward_matches_the_step_by_step_recurrence(seq):
-    """32 and 40 positions are whole chunks of 8; 13 is not, and is padded
-    inside the op."""
-    attrs, u, weights = state_space_case(seq)
+def scan_route_of(attrs, batch=BATCH):
+    from flexflow_tpu.kernels.ssm import scan_route
+
+    return scan_route(
+        batch, attrs.num_heads, attrs.head_dim, attrs.num_groups,
+        attrs.state_size, attrs.chunk_size,
+    )
+
+
+# (sizes, positions, the form `scan_route` names): the toy widths take the
+# XLA form; the kernel widths the Pallas kernels, interpreted here
+SCAN_CASES = [
+    ("toy", 32, "xla"), ("toy", 40, "xla"), ("toy", 13, "xla"),
+    ("kernels", 256, "ssd"), ("kernels", 200, "ssd"),
+]
+
+
+@pytest.mark.parametrize("sizes,seq,route", SCAN_CASES)
+def test_state_space_forward_matches_the_step_by_step_recurrence(
+    sizes, seq, route, interpreted_kernels
+):
+    """32 and 40 positions are whole chunks of 8, 256 two of 128; 13 and
+    200 are not, and are padded inside the op."""
+    attrs, u, weights = state_space_case(seq, sizes=SIZES[sizes])
+    assert scan_route_of(attrs) == route
     (got,) = kernel_forward(attrs, [u], weights)
-    np.testing.assert_allclose(got, reference_state_space(u, weights), **F32)
+    want = reference_state_space(u, weights, SIZES[sizes])
+    np.testing.assert_allclose(got, want, **(F32 if sizes == "toy" else F32_WIDE))
 
 
-@pytest.mark.parametrize("seq", [32, 40])
-def test_state_space_gradients_match_the_step_by_step_recurrence(seq):
+@pytest.mark.parametrize(
+    "sizes,seq,route", [case for case in SCAN_CASES if case[1] != 13]
+)
+def test_state_space_gradients_match_the_step_by_step_recurrence(
+    sizes, seq, route, interpreted_kernels
+):
     """The input's gradient and all eight weights', under a random
     cotangent. 1e-4: the gradients of `A_log` and `dt_bias` sum thousands of
     products of decays, in another order on each side."""
-    attrs, u, weights = state_space_case(seq, seed=1)
+    attrs, u, weights = state_space_case(seq, seed=1, sizes=SIZES[sizes])
+    assert scan_route_of(attrs) == route
     cot = rand(np.random.RandomState(2), *u.shape)
 
     def system(u, weights):
         return jnp.sum(kernel_forward(attrs, [u], weights)[0] * cot)
 
     def reference(u, weights):
-        return jnp.sum(reference_state_space(u, weights) * cot)
+        return jnp.sum(reference_state_space(u, weights, SIZES[sizes]) * cot)
 
     got = jax.grad(system, argnums=(0, 1))(u, weights)
     want = jax.grad(reference, argnums=(0, 1))(u, weights)
     for g in jax.tree_util.tree_leaves(want):
         assert float(jnp.max(jnp.abs(g))) > 1e-3  # every slot is reached
-    assert_trees_close(got, want, rtol=1e-4, atol=1e-4)
+    tol = dict(rtol=1e-4, atol=1e-4) if sizes == "toy" else F32_WIDE_GRADS
+    assert_trees_close(got, want, **tol)
+
+
+def scan_operands(seq, dtype, seed=3, heads=4, p=64):
+    """(x, dt, a_log, B, C, D) of `selective_scan` at the kernel widths."""
+    g, n = 2, 128
+    rs = np.random.RandomState(seed)
+    return (
+        rand(rs, BATCH, seq, heads, p).astype(dtype),
+        jnp.asarray(rs.uniform(1e-3, 0.3, (BATCH, seq, heads)), jnp.float32),
+        jnp.asarray(np.log(rs.uniform(1.0, 16.0, heads)), jnp.float32),
+        rand(rs, BATCH, seq, g, n, scale=0.3).astype(dtype),
+        rand(rs, BATCH, seq, g, n, scale=0.3).astype(dtype),
+        rand(rs, heads),
+    )
+
+
+def scan_value_and_gradients(operands, cot, chunk=128):
+    from flexflow_tpu.kernels.ssm import selective_scan
+
+    def loss(*operands):
+        y = selective_scan(*operands, chunk)
+        return jnp.sum(y.astype(jnp.float32) * cot), y
+
+    (_, y), grads = jax.value_and_grad(
+        loss, argnums=tuple(range(6)), has_aux=True
+    )(*operands)
+    return [y, *grads]
+
+
+@pytest.mark.parametrize(
+    "seq,heads,p", [(256, 4, 64), (200, 4, 64), (128, 2, 128)]
+)
+def test_bf16_scan_kernels_agree_with_the_xla_form(seq, heads, p, monkeypatch):
+    """bf16 operands: y and the six gradients of the kernels against the XLA
+    form's, each within 2e-2 of the XLA form's largest entry (the bound this
+    file gives bf16 compute; measured at most 7e-3: both forms cast the same
+    operands and differ in the order of their float32 sums, which moves a
+    bf16 result by an ulp). Heads of 64 share a 128-lane tile, heads of 128
+    have one each."""
+    operands = scan_operands(seq, jnp.bfloat16, heads=heads, p=p)
+    cot = rand(np.random.RandomState(4), *operands[0].shape)
+    want = scan_value_and_gradients(operands, cot)
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    got = scan_value_and_gradients(operands, cot)
+    for g, w in zip(got, want):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.max(np.abs(g - w)) <= 2e-2 * np.max(np.abs(w))
+    assert not np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+
+
+def test_scan_kernels_map_over_the_batch_shards_of_a_declared_mesh(
+    interpreted_kernels,
+):
+    """Under a `flash_mesh` with whole heads (the data-parallel backend's
+    step) the kernels run per batch shard: values and gradients are the
+    one-device kernels'."""
+    from jax.sharding import Mesh
+
+    from flexflow_tpu.kernels.flash_attention import flash_mesh
+
+    operands = scan_operands(128, jnp.float32)
+    cot = rand(np.random.RandomState(4), *operands[0].shape)
+    want = scan_value_and_gradients(operands, cot)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    with mesh, flash_mesh(mesh, "data", None, True):
+        got = jax.jit(scan_value_and_gradients)(operands, cot)
+    assert_trees_close(got, want, rtol=1e-5, atol=1e-5)
+
+
+def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
+    """The published widths take the kernels on a TPU, one row or many; toy
+    widths, a chunk of 8, a group wider than a program holds, a CPU without
+    the interpret opt-in and a trace under `no_flash()` take `_scan_core`;
+    a declared mesh maps the kernels over its batch shards if the heads are
+    whole and the batch divides."""
+    from jax.sharding import Mesh
+
+    from flexflow_tpu.kernels import flash_attention as fa
+    from flexflow_tpu.kernels.ssm import scan_route
+
+    cell = dict(batch=1, heads=64, head_dim=64, groups=8, state=128, chunk=128)
+    assert scan_route(**cell) == "xla"  # the CPU, no opt-in
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    assert scan_route(**cell) == "ssd"
+    monkeypatch.delenv("FLEXFLOW_TPU_FLASH_INTERPRET")
+    monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
+    assert scan_route(**cell) == "ssd"
+    assert scan_route(**dict(cell, batch=8)) == "ssd"
+    for other in (
+        dict(heads=4, head_dim=8, groups=2, state=16, chunk=8),  # the toy
+        dict(chunk=8), dict(chunk=192), dict(state=64), dict(head_dim=32),
+        dict(groups=4),  # 16 heads of 64 a group: 1024 columns a program
+        dict(heads=8),  # one head a group: 64 columns
+    ):
+        assert scan_route(**dict(cell, **other)) == "xla", other
+    with fa.no_flash():
+        assert scan_route(**cell) == "xla"
+    mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
+    with fa.flash_mesh(mesh, "data", None, False), fa.no_flash():
+        assert scan_route(**dict(cell, batch=4)) == "ssd_sharded"
+        assert scan_route(**dict(cell, batch=3)) == "xla"
+    with fa.flash_mesh(mesh, None, "data", False):
+        assert scan_route(**dict(cell, batch=4)) == "xla"
+
+
+def test_lowered_step_holds_the_scan_kernels_and_no_mask_tensor(monkeypatch):
+    """The step of the five-layer tower at the kernel widths, lowered for
+    the TPU platform as `tools/lowered_step_text.py` lowers a cell's: each
+    of its two state-space layers calls the forward kernel, the state pass
+    and the backward kernel once (the jitted callers `_ssd_forward` and
+    `_ssd_backward`; their bodies, `ssd_fwd_chunk`, `ssd_states_chunk` and
+    `ssd_bwd_chunk`, are in the text once for both layers), and no float32
+    `[.., 128, 128]` tensor (C.B, a decay mask, their product) is left in the
+    program, which the XLA form has. Four heads a group, so that a group's
+    `[256, 128]` state is none."""
+    import re
+
+    from flexflow_tpu.analysis import lowering
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    def lowered(sizes):
+        model = compiled_model(256, jnp.bfloat16, sizes=sizes, max_devices=1)
+        example = lowering.step_example_args_cg(model.instance, model.loss_attrs)
+        return model.instance.compiled_step().trace(
+            model.params, model.opt_state, *example
+        ).lower(lowering_platforms=("tpu",)).as_text()
+
+    sizes = dict(KERNEL_TOY, mamba_num_heads=8)
+    mask = re.compile(r"tensor<(?:\d+x)+128x128xf32>")
+    assert mask.findall(lowered(sizes))  # the XLA form
+    monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
+    text = lowered(sizes)
+    assert sorted(
+        name for name in re.findall(r'kernel_name = "(\w+)"', text)
+        if name.startswith("ssd_")
+    ) == ["ssd_bwd_chunk", "ssd_fwd_chunk", "ssd_states_chunk"]
+    calls = re.findall(r"call @(_ssd_\w+?)(?:_\d+)?\(", text)
+    assert sorted(calls) == ["_ssd_backward"] * 2 + ["_ssd_forward"] * 4
+    assert mask.findall(text) == []
 
 
 def test_scan_keeps_no_state_per_position():
@@ -348,8 +536,8 @@ def data(seq, seed=0):
     return ref.make_data(np.random.RandomState(seed), TOY, BATCH, seq)
 
 
-def compiled_model(seq, compute_dtype=None, **config):
-    builder, logits = ref.build(TOY, BATCH, seq)
+def compiled_model(seq, compute_dtype=None, sizes=TOY, **config):
+    builder, logits = ref.build(sizes, BATCH, seq)
     model = FFModel.from_computation_graph(
         builder, logits,
         FFConfig(batch_size=BATCH, seed=7, print_freq=0, **config),
