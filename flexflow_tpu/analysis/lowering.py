@@ -202,14 +202,17 @@ def lower_step_program(
     label_dtype=None,
 ) -> LoweredStepProgram:
     """Lower + compile the instance's donated step ONCE (never execute)."""
-    batch, label, rng = step_example_args(
-        instance, loss_attrs, label_dtype=label_dtype
-    )
-    with instance.machine_mesh.mesh:
-        lowered = instance.compiled_step().lower(
-            params, opt_state, batch, label, rng
+    from flexflow_tpu.observability.trace import record_span
+
+    with record_span("compile/lower_step", compiled=True):
+        batch, label, rng = step_example_args(
+            instance, loss_attrs, label_dtype=label_dtype
         )
-        compiled = lowered.compile()
+        with instance.machine_mesh.mesh:
+            lowered = instance.compiled_step().lower(
+                params, opt_state, batch, label, rng
+            )
+            compiled = lowered.compile()
     return LoweredStepProgram(
         instance=instance, compiled=compiled, lowered=lowered
     )
@@ -263,21 +266,25 @@ def lower_step_trace(
     exec-contract `program_fingerprint` on backends whose compile never
     lowers statically (DP / single-device). Returns the
     `jax.stages.Lowered`."""
+    import contextlib
+
+    from flexflow_tpu.observability.trace import record_span
+
     if params is None:
         params, opt_state = instance.initialize(seed=0)
-    if hasattr(instance, "pcg"):
-        batch, label, rng = step_example_args(
+    with record_span("compile/lower_step", compiled=False):
+        example_args = (
+            step_example_args if hasattr(instance, "pcg")
+            else step_example_args_cg
+        )
+        batch, label, rng = example_args(
             instance, loss_attrs, label_dtype=label_dtype
         )
-    else:
-        batch, label, rng = step_example_args_cg(
-            instance, loss_attrs, label_dtype=label_dtype
-        )
-    step = instance.compiled_step()
-    if hasattr(instance, "machine_mesh"):
-        with instance.machine_mesh.mesh:
-            return step.lower(params, opt_state, batch, label, rng)
-    return step.lower(params, opt_state, batch, label, rng)
+        mesh = getattr(instance, "machine_mesh", None)
+        with mesh.mesh if mesh is not None else contextlib.nullcontext():
+            return instance.compiled_step().lower(
+                params, opt_state, batch, label, rng
+            )
 
 
 def lower_plan(

@@ -222,9 +222,11 @@ class WindowedBatchIterator:
     sharding), and — when `prefetch` is on — builds + transfers window
     n+1 on a background producer thread while the consumer executes
     window n, so the host-side slice/stack/transfer leaves the step
-    loop's critical path. Each transfer records a `host_to_device` span
-    on the active trace recorder, making the overlap visible on the same
-    Chrome-trace timeline as the step's dispatch/device_sync phases.
+    loop's critical path. Each transfer is a `host_to_device` span
+    (observability/trace.py) on the producer thread's line of the profiler
+    trace, beside the consumer's `fit/next_batch` (its wait for a window)
+    and `step` spans and over the device's operations: whether the
+    transfer hid behind the step is read off that one timeline.
 
     An epoch's tail (num_batches % window) comes out as one smaller
     window — epoch ends end windows early rather than mixing epochs (a

@@ -14,6 +14,7 @@ layer methods, `compile` (:2018), `fit` (:2058), `eval`, the stepped
 
 from __future__ import annotations
 
+import contextlib
 import enum
 import os
 import time
@@ -32,6 +33,7 @@ from flexflow_tpu.local_execution.training_backing import (
     ModelTrainingInstance,
     param_key,
 )
+from flexflow_tpu.observability.trace import record_span
 from flexflow_tpu.op_attrs.core import OpAttrs
 from flexflow_tpu.op_attrs.datatype import DataType
 from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
@@ -576,7 +578,21 @@ class FFModel:
 
         Reference: FFModel::compile (model.h:85; flexflow_cffi.py:2018) — CG
         -> PCG lift, strategy search, backing init, optimizer state alloc.
+
+        Where its seconds go is named by host spans (observability/trace.py):
+        `compile` > `compile/search`, `compile/verify`, `compile/lower_step`,
+        `compile/build_instance`, `compile/init_state`.
         """
+        with record_span("compile"):
+            self._compile(
+                optimizer, loss_type, metrics, comp_mode, logit_tensor,
+                compute_dtype,
+            )
+
+    def _compile(
+        self, optimizer, loss_type, metrics, comp_mode, logit_tensor,
+        compute_dtype,
+    ) -> None:
         if isinstance(loss_type, str):
             loss_type = LossFunction(loss_type)
         # remembered for recompile() (runtime/recompile.py); the batch
@@ -681,11 +697,12 @@ class FFModel:
                     "submesh_branches=True but the graph has no Split-fork "
                     "branch partition"
                 )
-            self.instance = SubmeshBranchInstance(
-                self.cg, logit, self.loss_attrs, self.optimizer_attrs,
-                devices=jax.devices()[:ndev], partition=part,
-                metrics=self.metrics,
-            )
+            with record_span("compile/build_instance"):
+                self.instance = SubmeshBranchInstance(
+                    self.cg, logit, self.loss_attrs, self.optimizer_attrs,
+                    devices=jax.devices()[:ndev], partition=part,
+                    metrics=self.metrics,
+                )
             # the machine-mapping DP's disjoint-resource pricing is legal
             # at runtime for this shape now: price the same graph with
             # resource splits enabled and record the provenance
@@ -706,27 +723,32 @@ class FFModel:
             )
 
             collect, guard = self._step_stats_flags()
-            self.instance = DataParallelTrainingInstance(
-                self.cg, logit, self.loss_attrs, self.optimizer_attrs,
-                metrics=self.metrics, compute_dtype=compute_dtype,
-                devices=jax.devices()[:ndev],
-                aux_loss_tensors=self._aux_loss_tensors,
-                collect_step_stats=collect, guard_nonfinite_updates=guard,
-            )
+            with record_span("compile/build_instance"):
+                self.instance = DataParallelTrainingInstance(
+                    self.cg, logit, self.loss_attrs, self.optimizer_attrs,
+                    metrics=self.metrics, compute_dtype=compute_dtype,
+                    devices=jax.devices()[:ndev],
+                    aux_loss_tensors=self._aux_loss_tensors,
+                    collect_step_stats=collect, guard_nonfinite_updates=guard,
+                )
         else:
             collect, guard = self._step_stats_flags()
-            self.instance = ModelTrainingInstance(
-                self.cg, logit, self.loss_attrs, self.optimizer_attrs,
-                metrics=self.metrics, compute_dtype=compute_dtype,
-                aux_loss_tensors=self._aux_loss_tensors,
-                collect_step_stats=collect, guard_nonfinite_updates=guard,
-            )
+            with record_span("compile/build_instance"):
+                self.instance = ModelTrainingInstance(
+                    self.cg, logit, self.loss_attrs, self.optimizer_attrs,
+                    metrics=self.metrics, compute_dtype=compute_dtype,
+                    aux_loss_tensors=self._aux_loss_tensors,
+                    collect_step_stats=collect, guard_nonfinite_updates=guard,
+                )
         if hasattr(self.instance, "halt_on_nonfinite"):
             # fused windows under the `raise` policy freeze after the first
             # tripped step so the post-window state is the pre-trip state
             # the per-step loop would have stopped with (fused_multi_step)
             self.instance.halt_on_nonfinite = cfg.health_policy == "raise"
-        self.params, self.opt_state = self.instance.initialize(seed=cfg.seed)
+        with record_span("compile/init_state"):
+            self.params, self.opt_state = self.instance.initialize(
+                seed=cfg.seed
+            )
         self._step_count = 0
         prov = (
             self.search_provenance
@@ -784,23 +806,26 @@ class FFModel:
                     prov["comm"]["error"] = msg
             if lowered is not None and run_exec:
                 try:
-                    self._exec_contract_check(lowered)
+                    with record_span("compile/verify", check="exec"):
+                        self._exec_contract_check(lowered)
                 except Exception as e:
                     prov["exec"] = {
                         "error": f"{type(e).__name__}: {e}"[:200]
                     }
             if lowered is not None and cfg.plan_audit and has_mem:
                 try:
-                    prov["memory"].update(
-                        self._xla_memory_cross_check(lowered)
-                    )
+                    with record_span("compile/verify", check="memory_xla"):
+                        prov["memory"].update(
+                            self._xla_memory_cross_check(lowered)
+                        )
                 except Exception as e:
                     prov["memory"]["xla_error"] = (
                         f"{type(e).__name__}: {e}"[:200]
                     )
             if lowered is not None and cfg.plan_audit and has_comm:
                 try:
-                    self._comm_cross_check(lowered)
+                    with record_span("compile/verify", check="comm_census"):
+                        self._comm_cross_check(lowered)
                 except Exception as e:
                     prov["comm"]["error"] = (
                         f"{type(e).__name__}: {e}"[:200]
@@ -1682,7 +1707,8 @@ class FFModel:
             )
             from flexflow_tpu.analysis.pcg_verify import verify_pcg
 
-            verify_diags = verify_pcg(pcg, machine_spec=spec, mapping=mapping)
+            with record_span("compile/verify", check="imported_pcg"):
+                verify_diags = verify_pcg(pcg, machine_spec=spec, mapping=mapping)
             self.search_provenance = {
                 "search_algorithm": "imported_strategy",
                 "verify": _verify_summarize(verify_diags),
@@ -1891,38 +1917,39 @@ class FFModel:
                 )
 
                 t0 = _time.perf_counter()
-                if cfg.force_strategy_seed:
-                    result = self._forced_seed_result(
-                        pcg0, ctx, spec, cfg.force_strategy_seed
-                    )
-                elif cfg.search_algorithm == "mcmc":
-                    # legacy search mode: simulated annealing over the same
-                    # rewrite lattice (reference simulator.h:671
-                    # strategy_search_task)
-                    from flexflow_tpu.compiler.mcmc_search import (
-                        MCMCConfig,
-                        mcmc_optimize,
-                    )
+                with record_span("compile/search"):
+                    if cfg.force_strategy_seed:
+                        result = self._forced_seed_result(
+                            pcg0, ctx, spec, cfg.force_strategy_seed
+                        )
+                    elif cfg.search_algorithm == "mcmc":
+                        # legacy search mode: simulated annealing over the same
+                        # rewrite lattice (reference simulator.h:671
+                        # strategy_search_task)
+                        from flexflow_tpu.compiler.mcmc_search import (
+                            MCMCConfig,
+                            mcmc_optimize,
+                        )
 
-                    result = mcmc_optimize(
-                        pcg0, ctx, spec, rules,
-                        # budget<=0 disables the walk, matching the unity
-                        # path's sentinel semantics
-                        MCMCConfig(
-                            budget=max(cfg.search_budget, 0) * 10,
-                            rng_seed=cfg.seed,
-                        ),
-                    )
-                else:
-                    result = graph_optimize(
-                        pcg0, ctx, spec, rules,
-                        OptimizerConfig(
-                            alpha=cfg.search_alpha,
-                            budget=cfg.search_budget,
-                            pipeline_seeds=pipeline_on,
-                            pipeline_microbatches=cfg.pipeline_microbatches,
-                        ),
-                    )
+                        result = mcmc_optimize(
+                            pcg0, ctx, spec, rules,
+                            # budget<=0 disables the walk, matching the unity
+                            # path's sentinel semantics
+                            MCMCConfig(
+                                budget=max(cfg.search_budget, 0) * 10,
+                                rng_seed=cfg.seed,
+                            ),
+                        )
+                    else:
+                        result = graph_optimize(
+                            pcg0, ctx, spec, rules,
+                            OptimizerConfig(
+                                alpha=cfg.search_alpha,
+                                budget=cfg.search_budget,
+                                pipeline_seeds=pipeline_on,
+                                pipeline_microbatches=cfg.pipeline_microbatches,
+                            ),
+                        )
                 telem = result.telemetry or {}
                 self.search_provenance = {
                     "explored": result.explored,
@@ -2021,81 +2048,82 @@ class FFModel:
                             else len(effective_movement_store)
                         ) if effective_movement_store is not None else None,
                     }
-                # static verification of the WINNER is always on (ISSUE 4):
-                # the plan about to be lowered must satisfy every PCG
-                # invariant and its machine views must fit the search grid.
-                # Candidate-level verification stays behind FF_TPU_VERIFY=1
-                # (apply_substitution); the winner check is cheap (once per
-                # compile) and is the last line before GSPMD lowering.
-                from flexflow_tpu.analysis.diagnostics import (
-                    summarize as _verify_summarize,
-                )
-                from flexflow_tpu.analysis.pcg_verify import verify_pcg
+                with record_span("compile/verify", check="pcg_memory"):
+                    # static verification of the WINNER is always on (ISSUE 4):
+                    # the plan about to be lowered must satisfy every PCG
+                    # invariant and its machine views must fit the search grid.
+                    # Candidate-level verification stays behind FF_TPU_VERIFY=1
+                    # (apply_substitution); the winner check is cheap (once per
+                    # compile) and is the last line before GSPMD lowering.
+                    from flexflow_tpu.analysis.diagnostics import (
+                        summarize as _verify_summarize,
+                    )
+                    from flexflow_tpu.analysis.pcg_verify import verify_pcg
 
-                verify_diags = verify_pcg(
-                    result.pcg,
-                    machine_spec=spec,
-                    mapping=result.machine_mapping,
-                )
-                # static memory verification of the winner (ISSUE 10):
-                # the same liveness analysis `ffcheck --memory` runs, at
-                # the capacity the search was constrained to (--hbm-gb)
-                # or, unconstrained, the backend's reported HBM limit.
-                # MEM diagnostics ride the same verify summary; the
-                # per-device peak timeline lands in
-                # search_provenance["memory"] (the plan audit later adds
-                # XLA's compiled per-device bytes beside it).
-                from flexflow_tpu.analysis.memory_analysis import (
-                    detect_device_hbm_bytes,
-                    verify_memory,
-                )
+                    verify_diags = verify_pcg(
+                        result.pcg,
+                        machine_spec=spec,
+                        mapping=result.machine_mapping,
+                    )
+                    # static memory verification of the winner (ISSUE 10):
+                    # the same liveness analysis `ffcheck --memory` runs, at
+                    # the capacity the search was constrained to (--hbm-gb)
+                    # or, unconstrained, the backend's reported HBM limit.
+                    # MEM diagnostics ride the same verify summary; the
+                    # per-device peak timeline lands in
+                    # search_provenance["memory"] (the plan audit later adds
+                    # XLA's compiled per-device bytes beside it).
+                    from flexflow_tpu.analysis.memory_analysis import (
+                        detect_device_hbm_bytes,
+                        verify_memory,
+                    )
 
-                mem_capacity = mem_budget_bytes or detect_device_hbm_bytes()
-                mem_analysis, mem_diags = verify_memory(
-                    result.pcg,
-                    machine_spec=spec,
-                    mapping=result.machine_mapping,
-                    hbm_bytes=mem_capacity or None,
-                    optimizer_state_slots=mem_slots,
-                    steps_per_dispatch=mem_window_k,
-                )
-                verify_diags = list(verify_diags) + list(mem_diags)
-                self.search_provenance["verify"] = _verify_summarize(
-                    verify_diags
-                )
-                from flexflow_tpu.analysis.memory_analysis import (
-                    analyze_memory as _analyze_memory,
-                )
+                    mem_capacity = mem_budget_bytes or detect_device_hbm_bytes()
+                    mem_analysis, mem_diags = verify_memory(
+                        result.pcg,
+                        machine_spec=spec,
+                        mapping=result.machine_mapping,
+                        hbm_bytes=mem_capacity or None,
+                        optimizer_state_slots=mem_slots,
+                        steps_per_dispatch=mem_window_k,
+                    )
+                    verify_diags = list(verify_diags) + list(mem_diags)
+                    self.search_provenance["verify"] = _verify_summarize(
+                        verify_diags
+                    )
+                    from flexflow_tpu.analysis.memory_analysis import (
+                        analyze_memory as _analyze_memory,
+                    )
 
-                # the executor-semantics prediction: the GSPMD lowering
-                # runs every op on the FULL mesh (pieces replicated to
-                # devices outside the searched view), which is what the
-                # compiled program's memory actually looks like — the
-                # mapped analysis above is the Unity-semantics view the
-                # MEM rules verify
-                full_mesh = _analyze_memory(
-                    result.pcg,
-                    spec,
-                    None,
-                    optimizer_state_slots=mem_slots,
-                    steps_per_dispatch=mem_window_k,
-                )
-                self.search_provenance["memory"] = {
-                    "predicted_peak_bytes_per_device": {
-                        str(d): int(v)
-                        for d, v in mem_analysis.peak_by_device().items()
-                    },
-                    "predicted_peak_bytes_full_mesh": {
-                        str(d): int(v)
-                        for d, v in full_mesh.peak_by_device().items()
-                    },
-                    "capacity_bytes": (
-                        int(mem_capacity) if mem_capacity else None
-                    ),
-                    "hbm_gb": cfg.hbm_gb or None,
-                    "optimizer_state_slots": mem_slots,
-                    "steps_per_dispatch": mem_window_k,
-                }
+                    # the executor-semantics prediction: the GSPMD lowering
+                    # runs every op on the FULL mesh (pieces replicated to
+                    # devices outside the searched view), which is what the
+                    # compiled program's memory actually looks like — the
+                    # mapped analysis above is the Unity-semantics view the
+                    # MEM rules verify
+                    full_mesh = _analyze_memory(
+                        result.pcg,
+                        spec,
+                        None,
+                        optimizer_state_slots=mem_slots,
+                        steps_per_dispatch=mem_window_k,
+                    )
+                    self.search_provenance["memory"] = {
+                        "predicted_peak_bytes_per_device": {
+                            str(d): int(v)
+                            for d, v in mem_analysis.peak_by_device().items()
+                        },
+                        "predicted_peak_bytes_full_mesh": {
+                            str(d): int(v)
+                            for d, v in full_mesh.peak_by_device().items()
+                        },
+                        "capacity_bytes": (
+                            int(mem_capacity) if mem_capacity else None
+                        ),
+                        "hbm_gb": cfg.hbm_gb or None,
+                        "optimizer_state_slots": mem_slots,
+                        "steps_per_dispatch": mem_window_k,
+                    }
                 return result.pcg, result.machine_mapping, result.runtime
 
             # multi-host determinism (SURVEY §7 hard-part 6): host 0 searches,
@@ -2236,15 +2264,16 @@ class FFModel:
 
             if analyze_pipeline(pcg) is not None:
                 try:
-                    instance = PipelinedTrainingInstance(
-                        pcg, searched_logit, self.loss_attrs,
-                        self.optimizer_attrs,
-                        devices=jax.devices()[:ndev],
-                        metrics=self.metrics,
-                        compute_dtype=compute_dtype,
-                        collect_step_stats=collect,
-                        guard_nonfinite_updates=guard,
-                    )
+                    with record_span("compile/build_instance"):
+                        instance = PipelinedTrainingInstance(
+                            pcg, searched_logit, self.loss_attrs,
+                            self.optimizer_attrs,
+                            devices=jax.devices()[:ndev],
+                            metrics=self.metrics,
+                            compute_dtype=compute_dtype,
+                            collect_step_stats=collect,
+                            guard_nonfinite_updates=guard,
+                        )
                 except PipelineUnsupported as e:
                     print(
                         "[flexflow_tpu] pipelined winner falls back to the "
@@ -2278,14 +2307,15 @@ class FFModel:
                         "executor": "1f1b",
                     }
         if instance is None:
-            instance = DistributedTrainingInstance(
-                pcg, searched_logit, self.loss_attrs, self.optimizer_attrs,
-                mm, mapping=mapping, metrics=self.metrics,
-                compute_dtype=compute_dtype,
-                aux_loss_tensors=_find_aux_outputs(pcg),
-                collect_step_stats=collect, guard_nonfinite_updates=guard,
-                overlap=cfg.overlap,
-            )
+            with record_span("compile/build_instance"):
+                instance = DistributedTrainingInstance(
+                    pcg, searched_logit, self.loss_attrs, self.optimizer_attrs,
+                    mm, mapping=mapping, metrics=self.metrics,
+                    compute_dtype=compute_dtype,
+                    aux_loss_tensors=_find_aux_outputs(pcg),
+                    collect_step_stats=collect, guard_nonfinite_updates=guard,
+                    overlap=cfg.overlap,
+                )
         # the fused-lowering annotation: movement-edge node -> fused kind
         # (the Combine feeding each ag_matmul site, the Reduction draining
         # each matmul_rs site). Verified against the PCG adjacency rule
@@ -2306,7 +2336,8 @@ class FFModel:
             )
             from flexflow_tpu.analysis.pcg_verify import verify_overlap_plan
 
-            bad = errors_of(verify_overlap_plan(pcg, fused_edge_map))
+            with record_span("compile/verify", check="overlap_plan"):
+                bad = errors_of(verify_overlap_plan(pcg, fused_edge_map))
             if bad:
                 raise ValueError(
                     "fused-overlap annotation failed verification:\n"
@@ -2333,28 +2364,29 @@ class FFModel:
                 export_movement_predictions,
             )
 
-            comm_predictions = export_movement_predictions(
-                pcg, mapping, estimator=audit_estimator,
-                machine_spec=spec, fused_edges=fused_edge_map,
-            )
-            self._comm_ctx = {
-                "predictions": comm_predictions,
-                # the executor consumes the NAME-RESOLVED logit (it may
-                # differ from the topological sink in multi-output
-                # graphs), so the bypassed-chain computation must walk
-                # from the same tensor the instance will use
-                "bypassed": trailing_reshard_nodes(
-                    pcg, logits=[searched_logit]
-                ),
-            }
-            # predicted_bytes_total is NOT recorded here: its canonical
-            # definition (exempt edges excluded) needs the bypassed/
-            # host-feed classification and lands with the census summary
-            # under --plan-audit, one definition only
-            self.search_provenance["comm"] = {
-                "num_edges": len(comm_predictions),
-                "edges": [p.to_json() for p in comm_predictions],
-            }
+            with record_span("compile/verify", check="comm_predictions"):
+                comm_predictions = export_movement_predictions(
+                    pcg, mapping, estimator=audit_estimator,
+                    machine_spec=spec, fused_edges=fused_edge_map,
+                )
+                self._comm_ctx = {
+                    "predictions": comm_predictions,
+                    # the executor consumes the NAME-RESOLVED logit (it may
+                    # differ from the topological sink in multi-output
+                    # graphs), so the bypassed-chain computation must walk
+                    # from the same tensor the instance will use
+                    "bypassed": trailing_reshard_nodes(
+                        pcg, logits=[searched_logit]
+                    ),
+                }
+                # predicted_bytes_total is NOT recorded here: its canonical
+                # definition (exempt edges excluded) needs the bypassed/
+                # host-feed classification and lands with the census summary
+                # under --plan-audit, one definition only
+                self.search_provenance["comm"] = {
+                    "num_edges": len(comm_predictions),
+                    "edges": [p.to_json() for p in comm_predictions],
+                }
         except Exception as e:  # prediction export must not kill compile
             self._comm_ctx = None
             self.search_provenance["comm"] = {
@@ -2534,15 +2566,13 @@ class FFModel:
         resume after an in-run recompile replays a fresh shuffle stream
         (recorded, not bitwise)."""
         assert self.instance is not None, "call compile() first"
-        import contextlib
-
         # XLA trace of the whole fit for xprof/tensorboard (the Legion Prof
         # -lg:prof analogue); per-layer ms timing is the separate
-        # --profiling flag. The structured span trace
-        # (observability/trace.py) lands in the same directory as
-        # flexflow_trace.json: per-step dispatch/device_sync phases in
-        # Chrome-trace format, comparable across the DP and searched
-        # backends.
+        # --profiling flag. The program's host spans (`fit` and what is
+        # under it, observability/trace.py) are events of that trace's host
+        # plane, on the device planes' clock, and no span waits for the
+        # device: the traced fit is the fit. The recorder's copy lands in
+        # the same directory as flexflow_trace.json.
         if self.config.profile_trace_dir:
             from flexflow_tpu.observability.trace import trace_session
 
@@ -2551,7 +2581,7 @@ class FFModel:
         else:
             trace_ctx = contextlib.nullcontext()
             span_ctx = contextlib.nullcontext()
-        with trace_ctx, span_ctx:
+        with trace_ctx, span_ctx, record_span("fit"):
             return self._fit_loop(x, y, epochs, batch_size, shuffle, verbose,
                                   recompile_state, epoch_offset,
                                   checkpoint_dir=checkpoint_dir,
@@ -2683,20 +2713,23 @@ class FFModel:
     ) -> PerfMetrics:
         epochs = epochs or self.config.epochs
         batch_size = batch_size or self.config.batch_size
-        it = self._make_iterator(
-            x, y, batch_size, shuffle=shuffle, seed_offset=epoch_offset
-        )
-        rng = jax.random.fold_in(
-            jax.random.PRNGKey(self.config.seed), epoch_offset
-        )
-        sup = self._setup_supervision()
-        # everything below sup creation runs under ONE finally: a failure
-        # anywhere in the remaining setup (resume restore, metrics dir,
-        # health monitor) must still retire the watchdog monitor and the
-        # checkpoint writer it may already have spawned — a leaked daemon
-        # thread per retried fit call adds up on a preemptible job
-        ckpt = event_log = drift = None
+        # `fit/begin`: from here to the loop's first pull
+        begin = contextlib.ExitStack()
+        # everything below runs under ONE finally: a failure anywhere in
+        # the setup (resume restore, metrics dir, health monitor) must
+        # still retire the watchdog monitor and the checkpoint writer it
+        # may already have spawned — a leaked daemon thread per retried
+        # fit call adds up on a preemptible job
+        sup = ckpt = event_log = drift = None
         try:
+            begin.enter_context(record_span("fit/begin"))
+            it = self._make_iterator(
+                x, y, batch_size, shuffle=shuffle, seed_offset=epoch_offset
+            )
+            rng = jax.random.fold_in(
+                jax.random.PRNGKey(self.config.seed), epoch_offset
+            )
+            sup = self._setup_supervision()
             ckpt, start_epoch, skip_batches, rng = self._setup_checkpointing(
                 checkpoint_dir, checkpoint_every_n_steps, resume, it, rng,
                 epoch_offset, fault_channel=sup.channel,
@@ -2714,6 +2747,7 @@ class FFModel:
                     self.config.metrics_dir, self.search_provenance
                 )
             k = self._effective_steps_per_dispatch()
+            begin.close()
             if k > 1:
                 return self._fit_epochs_fused(
                     x, y, epochs, batch_size, shuffle, verbose,
@@ -2727,9 +2761,11 @@ class FFModel:
                 start_epoch=start_epoch, skip_batches=skip_batches, sup=sup,
             )
         finally:
+            begin.close()
             # retire the watchdog FIRST: its deadline must not fire into
             # the (potentially slow) writer drain below
-            sup.close()
+            if sup is not None:
+                sup.close()
             if drift is not None:
                 # stop the poller and drain the tail on this thread (step
                 # events flush per line, so the final drain sees every
@@ -2947,7 +2983,7 @@ class FFModel:
         epoch = start_epoch
         while epoch < epochs:
             batch_in_epoch = skip_batches if epoch == start_epoch else 0
-            for batch, label in it:
+            for batch, label in _pulls(it):
                 if watchdog is not None:
                     watchdog.begin_window(self._step_count + 1, 1)
                 try:
@@ -3043,11 +3079,16 @@ class FFModel:
             # the next epoch under the new step, so batches are never
             # replayed and a persistent trigger cannot livelock fit()
             epoch += 1
-        if loss is not None:
-            jax.block_until_ready(loss)
-        elapsed = time.perf_counter() - start
-        perf = _perf_from_metric_values(macc) if macc is not None else PerfMetrics()
-        _publish_routing(self.instance, macc)
+        with record_span("fit/end"):
+            if loss is not None:
+                jax.block_until_ready(loss)
+            elapsed = time.perf_counter() - start
+            perf = (
+                _perf_from_metric_values(macc)
+                if macc is not None
+                else PerfMetrics()
+            )
+            _publish_routing(self.instance, macc)
         if verbose:
             print(
                 f"ELAPSED TIME = {elapsed:.4f}s, "
@@ -3096,7 +3137,7 @@ class FFModel:
                 step_base=self._step_count,
             )
             try:
-                for inputs_stack, label_stack, host_win, kk in win_it:
+                for inputs_stack, label_stack, host_win, kk in _pulls(win_it):
                     if watchdog is not None:
                         watchdog.begin_window(self._step_count + 1, kk)
                     try:
@@ -3170,13 +3211,16 @@ class FFModel:
                     monitor, ckpt=ckpt, epoch_base=epoch, sup=sup,
                 ))
                 return perf
-        if loss is not None:
-            jax.block_until_ready(loss)
-        elapsed = time.perf_counter() - start
-        perf = (
-            _perf_from_metric_values(macc) if macc is not None else PerfMetrics()
-        )
-        _publish_routing(self.instance, macc)
+        with record_span("fit/end"):
+            if loss is not None:
+                jax.block_until_ready(loss)
+            elapsed = time.perf_counter() - start
+            perf = (
+                _perf_from_metric_values(macc)
+                if macc is not None
+                else PerfMetrics()
+            )
+            _publish_routing(self.instance, macc)
         if verbose:
             print(
                 f"ELAPSED TIME = {elapsed:.4f}s, "
@@ -3464,6 +3508,20 @@ def _find_sink_output(graph) -> DataflowOutput:
     ]
     assert len(sinks) == 1, f"expected one model output, found {len(sinks)}"
     return sinks[0]
+
+
+def _pulls(batches):
+    """`for item in batches`, each pull under a `fit/next_batch` span: the
+    fit loop's wait for its input (an epoch's shuffle is drawn by its first
+    pull; the pull that finds the epoch over is a span too)."""
+    batches = iter(batches)
+    done = object()
+    while True:
+        with record_span("fit/next_batch"):
+            item = next(batches, done)
+        if item is done:
+            return
+        yield item
 
 
 def _read_losses_host(losses) -> np.ndarray:

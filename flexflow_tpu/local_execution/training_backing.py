@@ -346,6 +346,7 @@ class ModelTrainingInstance:
         return loss, (logit, jnp.stack(held_rows) if held_rows else None)
 
     def _step(self, params, opt_state, batch_inputs, label, rng):
+        trace.count(trace.STEP_TRACE)  # this body runs when JAX traces it
         (loss, (logit, held_rows)), grads = jax.value_and_grad(
             self._loss_and_routing, has_aux=True
         )(params, batch_inputs, label, rng)
@@ -400,22 +401,14 @@ class ModelTrainingInstance:
         """K fused steps in one dispatch. The carry `rng` advances exactly
         as K `train_step` calls advance the fit loop's key (split inside
         the scan), so fused and per-step runs consume one RNG stream."""
-        from flexflow_tpu.observability.trace import active_recorder
-
-        rec = active_recorder()
-        if rec is None:
-            return self.compiled_multi_step()(
-                params, opt_state, batch_stack, label_stack, rng
-            )
         k = jax.tree_util.tree_leaves(batch_stack)[0].shape[0]
-        with rec.span("step", backend=type(self).__name__, fused_steps=k):
-            with rec.span("dispatch"):
-                out = self.compiled_multi_step()(
+        with trace.record_span(
+            "step", backend=type(self).__name__, fused_steps=k
+        ):
+            with trace.record_span("dispatch"):
+                return self.compiled_multi_step()(
                     params, opt_state, batch_stack, label_stack, rng
                 )
-            with rec.span("device_sync", sync=out[3]):
-                pass
-        return out
 
     def _record_stats(self, out):
         """Split the optional stats tail off the step result, keeping the
@@ -428,27 +421,14 @@ class ModelTrainingInstance:
     def train_step(self, params, opt_state, batch_inputs, label, rng=None):
         if rng is None:
             rng = jax.random.PRNGKey(0)
-        from flexflow_tpu.observability.trace import active_recorder
-
-        rec = active_recorder()
-        if rec is None:
-            return self._record_stats(
-                self.compiled_step()(
-                    params, opt_state, batch_inputs, label, rng
-                )
-            )
-        # per-phase timeline comparable with the searched-PCG executor
-        # (parallel/executor.py records the same span names): dispatch is
-        # the host-side enqueue of the one fused XLA program, device_sync
-        # the wait for it (force_sync)
-        backend = type(self).__name__
-        with rec.span("step", backend=backend):
-            with rec.span("dispatch"):
+        # the host sees one thing of a step, the enqueue of its one XLA
+        # program; when the step ended is read off the device plane of the
+        # same trace (observability/trace.py), never waited for here
+        with trace.record_span("step", backend=type(self).__name__):
+            with trace.record_span("dispatch"):
                 out = self.compiled_step()(
                     params, opt_state, batch_inputs, label, rng
                 )
-            with rec.span("device_sync", sync=out[2]):
-                pass
         return self._record_stats(out)
 
     def forward(self, params, batch_inputs):
@@ -487,14 +467,12 @@ class LocalTrainingBacking:
     def _timed(self, node: Node, table: PerLayerElapsedTime, fn, *args):
         if not self.profiling:
             return fn(*args)
-        from flexflow_tpu.observability.trace import record_span
-
         phase = "bwd" if table is self.bwd_elapsed else "fwd"
         name = self.cg.layer_attrs(node).name or param_key(node)
         out = fn(*args)
         jax.block_until_ready(out)
         start = time.perf_counter()
-        with record_span(f"{phase}/{name}", sync=None):
+        with trace.record_span(f"{phase}/{name}"):
             out = fn(*args)
             jax.block_until_ready(out)
         table[node] = (time.perf_counter() - start) * 1000.0

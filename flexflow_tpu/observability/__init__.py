@@ -2,10 +2,11 @@
 and runs. Speeds are the benchmark's (`benchmark/`), read from the device
 trace through the scopes `trace` puts on every operation.
 
-- `trace`       -- span/event recorder with host-readback sync boundaries
-                   (kernels/profiling.force_sync discipline), emitting
-                   Chrome-trace JSON next to the XLA trace in
-                   `--profile-trace-dir`.
+- `trace`       -- the device-trace scopes of every operation, and
+                   `record_span`: the program's host spans as profiler
+                   annotations on the device trace's clock, totalled in
+                   `span_totals()`; the recorder behind the watchdog's
+                   hang forensics and `flexflow_trace.json`.
 - `search_phases` -- compile-time twin of `trace`: per-phase wall-clock
                    attribution of the Unity search (tree_build / dp /
                    leaf_cost / match), reported as `phase_ms` in search
@@ -24,10 +25,13 @@ trace through the scopes `trace` puts on every operation.
 """
 
 from flexflow_tpu.observability.trace import (
+    HOST_SPANS,
     TraceRecorder,
     active_recorder,
+    count,
     record_span,
     set_recorder,
+    span_totals,
     trace_session,
 )
 from flexflow_tpu.observability.search_phases import (
@@ -59,10 +63,13 @@ from flexflow_tpu.observability.plan_audit import (
 )
 
 __all__ = [
+    "HOST_SPANS",
     "TraceRecorder",
     "active_recorder",
+    "count",
     "record_span",
     "set_recorder",
+    "span_totals",
     "trace_session",
     "collect_search_phases",
     "search_phase",

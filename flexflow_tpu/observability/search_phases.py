@@ -4,8 +4,8 @@ Extends the step-trace span recorder (observability/trace.py) into the
 Unity search: the search loops install a per-search accumulator
 (collect_search_phases), and the hot call sites mark their work with
 search_phase("tree_build" | "dp" | "leaf_cost" | "match" | "seed_build").
-Each phase both emits a `search/<name>` span against the active
-TraceRecorder (so --profile-trace-dir timelines include the search) and
+Each phase both emits a `search/<name>` span (`record_span`: the profiler's
+host plane, `span_totals()`, and the active TraceRecorder's timeline) and
 accumulates milliseconds into the collector, which the search telemetry
 reports as `phase_ms` (graph_optimize/mcmc_optimize telemetry ->
 FFModel.search_provenance; `tools/profile_search.py` prints it).
@@ -46,7 +46,7 @@ def collect_search_phases() -> Iterator[Dict[str, float]]:
 @contextlib.contextmanager
 def search_phase(name: str, **args):
     """Attribute the body to `name`: accumulate into the active collector
-    (if any) and emit a `search/<name>` span (no-op without a recorder)."""
+    (if any) and emit a `search/<name>` span."""
     acc = _ACTIVE
     if acc is None:
         with record_span(f"search/{name}", **args):

@@ -1,4 +1,4 @@
-"""Two kinds of instrumentation, on two clocks.
+"""Two kinds of instrumentation, on one clock: the profiler's.
 
 **Device-trace scopes** (`node_scope`, `step_scope`, `parse_scope`): every
 operation the step programs lower carries the name of the PCG node and the
@@ -6,36 +6,53 @@ part of the step it came from, as a `jax.named_scope` of the form
 `ff.<kind>.<name>` (a node) or `ff.<part>` (`cast`, `loss`, `optimizer`,
 `metrics`, `health`). A named scope is HLO metadata and nothing else: the
 program is the same with and without it, there is no switch, and its
-"spans" are the device events of a `jax.profiler` trace, on the device's
-own clock. JAX wraps the scope in `jvp(...)` / `transpose(...)` as it
-differentiates, which is where the phase comes from. This is what answers
-"where does the step's time go" since PR 22 put the benchmark on the device
-trace: `benchmark/step_anatomy.py` reads these scopes back with
-`parse_scope`, which lives here so that format and parser cannot drift.
+"spans" are the device events of a `jax.profiler` trace. JAX wraps the
+scope in `jvp(...)` / `transpose(...)` as it differentiates, which is where
+the phase comes from. `benchmark/step_anatomy.py` reads these scopes back
+with `parse_scope`, which lives here so that format and parser cannot drift.
 
-**Host spans** (`TraceRecorder`, `record_span`, `trace_session`): a
-span/event recorder on the host's `perf_counter` clock. The jitted train
-step is ONE XLA program, so all it can see of a step is dispatch (enqueue of
-the donated step) and device_sync (the wait for results); every sync
-boundary waits on the result pytree (`kernels/profiling.force_sync`) before
-the span's end timestamp is taken, which serializes host and device (16 ms
-of a 262 ms step; my chip run, PR 22), and it cannot look inside the step.
-It is not how step time is measured or attributed. What it is still for:
+**Host spans** (`record_span`, `count`, `span_totals`): `record_span` is the
+one call the program makes around a piece of host work, and every call does
+three things.
 
-- the watchdog's hang forensics: `open_span_names(tid)` says what a hung
-  thread was doing (`step / dispatch / device_sync`, `checkpoint/...`);
-- `--profile-trace-dir`'s host timeline: `trace_session` writes the spans
-  as Chrome-trace JSON (`chrome://tracing` / Perfetto "traceEvents")
-  beside the XLA trace, with the search's phases (`search/<name>`), the
-  checkpoint writes and the input pipeline's `host_to_device` transfers.
+- It enters a `jax.profiler.TraceAnnotation` (the `step` span a
+  `StepTraceAnnotation` whose `step_num` counts the process's dispatches):
+  an event on the `/host:CPU` plane of the same `.xplane.pb` the device
+  planes are written to, on their clock. So what the host was doing while a
+  chip sat idle, and when a step ended on the device, are read off ONE
+  timeline (`benchmark/host_spans.py` does); nothing is forced on the host
+  to learn it. With no profiler session running the annotation is a flag
+  test.
+- It adds the span to a process-wide table, `span_totals()`: name -> count,
+  total seconds, longest single span, on `perf_counter`. Kept in memory and
+  read when a run ends: where set-up went (`compile/...`), what a stalled
+  chunk stalled in (the longest `fit/next_batch` or `dispatch`).
+- Where a `TraceRecorder` is installed (`set_recorder`, `trace_session`) it
+  records the span there too, nested per thread. The recorder is what the
+  watchdog's hang forensics read (`open_span_names(tid)`: what a hung
+  thread was doing, `fit / step / dispatch`, `checkpoint/...`) and what
+  tests assert nesting on. `trace_session` still writes it as Chrome-trace
+  JSON (`flexflow_trace.json`) beside the XLA trace under
+  `--profile-trace-dir`, but **the xplane is the timeline to read**: it
+  holds the same spans laid over the device's operations.
 
-Under fused multi-step dispatch (steps_per_dispatch=K) the `step` span
-covers the whole K-step window and carries a `fused_steps` arg. Spans nest
-PER THREAD, so the producer thread's transfers land beside (not inside) the
-consumer's step spans.
+`HOST_SPANS` lists the names `compile` and `fit` emit, for the readers;
+`search/<name>` (the search's phases), `checkpoint...` and `<phase>/<layer>`
+(`--profiling`) come through the same call. `count(name)` adds an event with
+no duration to the same table: `step_trace`, bumped in the step functions'
+bodies, says how many times JAX traced the step in this process. The table
+also holds what `jax.monitoring` reports under `LOWERING_EVENTS`: the
+seconds JAX spent tracing Python to jaxprs and lowering jaxprs to MLIR, the
+part of set-up that is this program's own Python (the interpreter over the
+graph, the Pallas bodies).
 
-`record_span(...)` is a null context unless a recorder is installed (via
-`set_recorder` or `trace_session`).
+The jitted train step is ONE XLA program, so of a step the host sees only
+`dispatch` (the enqueue of the donated program). No span waits for the
+device: a traced job is the job, not a serialized copy of it. Under fused
+multi-step dispatch (steps_per_dispatch=K) the `step` span covers the whole
+K-step window and carries a `fused_steps` arg. Spans nest PER THREAD, so the
+producer thread's `host_to_device` transfers land beside (not inside) the
+consumer's `fit/next_batch` and `step` spans.
 """
 
 from __future__ import annotations
@@ -93,11 +110,8 @@ class TraceRecorder:
         return self._tls.stack
 
     @contextlib.contextmanager
-    def span(self, name: str, sync=None, **args):
-        """Record `name` around the body. `sync` is a pytree waited on
-        (force_sync) BEFORE the end timestamp, so device work launched
-        inside the span is charged to it, not to whoever reads the result
-        later."""
+    def span(self, name: str, **args):
+        """Record `name` around the body."""
         stack = self._stack()
         start = self._now_ms()
         tid = threading.get_ident()
@@ -120,8 +134,6 @@ class TraceRecorder:
         try:
             yield self
         finally:
-            if sync is not None:
-                _force_sync(sync)
             end = self._now_ms()
             stack.pop()
             with self._lock:
@@ -203,12 +215,6 @@ class TraceRecorder:
         return path
 
 
-def _force_sync(out) -> None:
-    from flexflow_tpu.kernels.profiling import force_sync
-
-    force_sync(out)
-
-
 # -- module-level active recorder ----------------------------------------
 
 _ACTIVE: Optional[TraceRecorder] = None
@@ -227,17 +233,116 @@ def set_recorder(recorder: Optional[TraceRecorder]) -> Optional[TraceRecorder]:
     return prev
 
 
-@contextlib.contextmanager
-def record_span(name: str, sync=None, **args):
-    """Span against the active recorder; a no-op null context when tracing
-    is off (the hot-path guard — instrumented step functions call this
-    unconditionally)."""
-    rec = _ACTIVE
-    if rec is None:
-        yield None
-        return
-    with rec.span(name, sync=sync, **args) as r:
-        yield r
+# -- host spans ---------------------------------------------------------------
+
+# the spans `FFModel.compile` and `FFModel.fit` emit, outermost first; the
+# readers (`benchmark/host_spans.py`) take the names from here
+HOST_SPANS = (
+    "compile",
+    "compile/search",  # the body `search_provenance["search_seconds"]` times
+    "compile/verify",  # the winner's static verifiers, not the lowering they read
+    "compile/lower_step",  # every static lowering of the step (analysis/lowering.py)
+    "compile/build_instance",  # the backend's constructor
+    "compile/init_state",  # `instance.initialize`: parameters, masters, moments
+    "fit",
+    "fit/begin",  # entry to the first pull: iterator, supervision, checkpointing
+    "fit/next_batch",  # each pull from the iterator, the one that ends an epoch too
+    "step",
+    "dispatch",  # the enqueue of the step program, inside `step`
+    "fit/end",  # the last wait, the metric conversion, the routing counters
+    "host_to_device",  # a window's transfer, on the producer thread
+)
+# events with no duration, counted by `count`
+STEP_TRACE = "step_trace"
+# `jax.monitoring` durations kept in the same table: what tracing the
+# program's Python and lowering its jaxprs cost this process
+LOWERING_EVENTS = (
+    "/jax/core/compile/jaxpr_trace_duration",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration",
+)
+
+_TOTALS: Dict[str, List[float]] = {}  # name -> [count, total s, longest s]
+_TOTALS_LOCK = threading.Lock()
+
+
+def _add(name: str, seconds: float) -> None:
+    with _TOTALS_LOCK:
+        row = _TOTALS.get(name)
+        if row is None:
+            _TOTALS[name] = [1, seconds, seconds]
+        else:
+            row[0] += 1
+            row[1] += seconds
+            if seconds > row[2]:
+                row[2] = seconds
+
+
+def count(name: str) -> None:
+    """One more of an event with no duration (`STEP_TRACE`)."""
+    _add(name, 0.0)
+
+
+def span_totals() -> Dict[str, Dict[str, float]]:
+    """`{name: {"count", "total_s", "longest_s"}}` of every span, counter
+    and `LOWERING_EVENTS` duration of this process so far."""
+    with _TOTALS_LOCK:
+        return {
+            name: {"count": row[0], "total_s": row[1], "longest_s": row[2]}
+            for name, row in _TOTALS.items()
+        }
+
+
+def reset_span_totals() -> None:
+    with _TOTALS_LOCK:
+        _TOTALS.clear()
+
+
+def _on_duration(event: str, duration: float, **_) -> None:
+    if event in LOWERING_EVENTS:
+        _add(event, duration)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_duration)
+
+
+class record_span:
+    """`with record_span(name, **args):` is how the program marks a piece of
+    host work (module docstring): a profiler annotation, a row of
+    `span_totals()`, and a span of the active recorder where there is one
+    (which the `with` then binds, else None)."""
+
+    __slots__ = ("name", "args", "_annotation", "_recorded", "_t0")
+
+    def __init__(self, name: str, **args) -> None:
+        self.name = name
+        self.args = args
+
+    def __enter__(self):
+        if self.name == "step":
+            row = _TOTALS.get("step")
+            self._annotation = jax.profiler.StepTraceAnnotation(
+                "step", step_num=row[0] if row else 0, **self.args
+            )
+        else:
+            self._annotation = jax.profiler.TraceAnnotation(
+                self.name, **self.args
+            )
+        self._annotation.__enter__()
+        rec = _ACTIVE
+        self._recorded = None
+        if rec is not None:
+            self._recorded = rec.span(self.name, **self.args)
+            self._recorded.__enter__()
+        self._t0 = time.perf_counter()
+        return rec
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
+        if self._recorded is not None:
+            self._recorded.__exit__(*exc)
+        self._annotation.__exit__(*exc)
+        _add(self.name, seconds)
+        return False
 
 
 # -- device-trace scopes ---------------------------------------------------
@@ -329,8 +434,9 @@ def parse_scope(op_name: str) -> Tuple[str, str, str]:
 def trace_session(trace_dir: str, label: str = "flexflow_trace"):
     """Install a fresh recorder for the body and write
     `<trace_dir>/<label>.json` (Chrome-trace format) on exit. Used by
-    FFModel.fit when `--profile-trace-dir` is set, alongside the XLA/xprof
-    trace jax.profiler writes into the same directory."""
+    FFModel.fit when `--profile-trace-dir` is set, beside the xplane
+    jax.profiler writes into the same directory, which holds the same spans
+    over the device's operations."""
     rec = TraceRecorder()
     prev = set_recorder(rec)
     try:

@@ -879,6 +879,12 @@ class DistributedTrainingInstance:
         self._jit_step = None
         self._jit_multi_step = None
         self._jit_fwd = None
+        # what the `step` span says of this backend, spelled once
+        self._step_span_args = {
+            "backend": type(self).__name__,
+            "mesh": str(dict(machine_mesh.mesh.shape)),
+            "fused_edges": len(self.overlap_sites),
+        }
 
     def _cast_for_compute(self, tree):
         from flexflow_tpu.kernels.precision import cast_for_compute
@@ -1065,6 +1071,7 @@ class DistributedTrainingInstance:
         return loss, logit
 
     def _step(self, params, opt_state, batch_inputs, label, rng):
+        trace.count(trace.STEP_TRACE)  # this body runs when JAX traces it
         (loss, logit), grads = jax.value_and_grad(self.loss_fn, has_aux=True)(
             params, batch_inputs, label, rng
         )
@@ -1120,30 +1127,12 @@ class DistributedTrainingInstance:
         return self._jit_multi_step
 
     def multi_train_step(self, params, opt_state, batch_stack, label_stack, rng):
-        from flexflow_tpu.observability.trace import active_recorder
-
-        rec = active_recorder()
-        if rec is None:
-            with self.machine_mesh.mesh:
+        k = jax.tree_util.tree_leaves(batch_stack)[0].shape[0]
+        with trace.record_span("step", fused_steps=k, **self._step_span_args):
+            with self.machine_mesh.mesh, trace.record_span("dispatch"):
                 return self.compiled_multi_step()(
                     params, opt_state, batch_stack, label_stack, rng
                 )
-        k = jax.tree_util.tree_leaves(batch_stack)[0].shape[0]
-        with rec.span(
-            "step",
-            backend=type(self).__name__,
-            mesh=str(dict(self.machine_mesh.mesh.shape)),
-            fused_steps=k,
-            fused_edges=len(self.overlap_sites),
-        ):
-            with self.machine_mesh.mesh:
-                with rec.span("dispatch"):
-                    out = self.compiled_multi_step()(
-                        params, opt_state, batch_stack, label_stack, rng
-                    )
-                with rec.span("device_sync", sync=out[3]):
-                    pass
-        return out
 
     def _record_stats(self, out):
         if self.collect_step_stats:
@@ -1154,34 +1143,13 @@ class DistributedTrainingInstance:
     def train_step(self, params, opt_state, batch_inputs, label, rng=None):
         if rng is None:
             rng = jax.random.PRNGKey(0)
-        from flexflow_tpu.observability.trace import active_recorder
-
-        rec = active_recorder()
-        if rec is None:
-            with self.machine_mesh.mesh:
-                return self._record_stats(
-                    self.compiled_step()(
-                        params, opt_state, batch_inputs, label, rng
-                    )
+        # the same span names as ModelTrainingInstance.train_step, so the
+        # DP and searched-PCG step programs land on one comparable timeline
+        with trace.record_span("step", **self._step_span_args):
+            with self.machine_mesh.mesh, trace.record_span("dispatch"):
+                out = self.compiled_step()(
+                    params, opt_state, batch_inputs, label, rng
                 )
-        # same per-phase span names as ModelTrainingInstance.train_step so
-        # the DP and searched-PCG step programs land on one comparable
-        # timeline (the executor-tax diagnosis: a searched plan whose
-        # device_sync dwarfs the DP backend's at equal dispatch is losing
-        # on the device, not in the host loop)
-        with rec.span(
-            "step",
-            backend=type(self).__name__,
-            mesh=str(dict(self.machine_mesh.mesh.shape)),
-            fused_edges=len(self.overlap_sites),
-        ):
-            with self.machine_mesh.mesh:
-                with rec.span("dispatch"):
-                    out = self.compiled_step()(
-                        params, opt_state, batch_inputs, label, rng
-                    )
-                with rec.span("device_sync", sync=out[2]):
-                    pass
         return self._record_stats(out)
 
     def forward(self, params, batch_inputs):

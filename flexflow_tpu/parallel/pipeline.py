@@ -59,6 +59,7 @@ from flexflow_tpu.kernels import (
     make_optimizer_state,
 )
 from flexflow_tpu.local_execution.training_backing import split_slot_values
+from flexflow_tpu.observability import trace
 from flexflow_tpu.op_attrs.core import is_parallel_op, is_stage_op
 from flexflow_tpu.op_attrs.ops import (
     CombineAttrs,
@@ -438,6 +439,13 @@ class PipelinedTrainingInstance:
         self._jit_step = None
         self._jit_multi_step = None
         self._jit_fwd = None
+        # what the `step` span says of this backend, spelled once
+        self._step_span_args = {
+            "backend": type(self).__name__,
+            "mesh": str(dict(mesh.shape)),
+            "pipeline_stages": S,
+            "pipeline_microbatches": self.structure.num_microbatches,
+        }
 
     # -- setup -------------------------------------------------------------
 
@@ -660,6 +668,7 @@ class PipelinedTrainingInstance:
     # -- step --------------------------------------------------------------
 
     def _step(self, params, opt_state, batch_inputs, label, rng):
+        trace.count(trace.STEP_TRACE)  # this body runs when JAX traces it
         batch = self._batch_value(self._cast_for_compute(batch_inputs))
         grads, loss, logits = self._pipeline_grads(
             self._cast_for_compute(params), batch, label, rng
@@ -711,58 +720,20 @@ class PipelinedTrainingInstance:
     def train_step(self, params, opt_state, batch_inputs, label, rng=None):
         if rng is None:
             rng = jax.random.PRNGKey(0)
-        from flexflow_tpu.observability.trace import active_recorder
-
-        rec = active_recorder()
-        if rec is None:
-            with self.mesh:
-                return self._record_stats(
-                    self.compiled_step()(
-                        params, opt_state, batch_inputs, label, rng
-                    )
+        with trace.record_span("step", **self._step_span_args):
+            with self.mesh, trace.record_span("dispatch"):
+                out = self.compiled_step()(
+                    params, opt_state, batch_inputs, label, rng
                 )
-        with rec.span(
-            "step",
-            backend=type(self).__name__,
-            mesh=str(dict(self.mesh.shape)),
-            pipeline_stages=self.structure.num_stages,
-            pipeline_microbatches=self.structure.num_microbatches,
-        ):
-            with self.mesh:
-                with rec.span("dispatch"):
-                    out = self.compiled_step()(
-                        params, opt_state, batch_inputs, label, rng
-                    )
-                with rec.span("device_sync", sync=out[2]):
-                    pass
         return self._record_stats(out)
 
     def multi_train_step(self, params, opt_state, batch_stack, label_stack, rng):
-        from flexflow_tpu.observability.trace import active_recorder
-
-        rec = active_recorder()
-        if rec is None:
-            with self.mesh:
+        k = jax.tree_util.tree_leaves(batch_stack)[0].shape[0]
+        with trace.record_span("step", fused_steps=k, **self._step_span_args):
+            with self.mesh, trace.record_span("dispatch"):
                 return self.compiled_multi_step()(
                     params, opt_state, batch_stack, label_stack, rng
                 )
-        k = jax.tree_util.tree_leaves(batch_stack)[0].shape[0]
-        with rec.span(
-            "step",
-            backend=type(self).__name__,
-            mesh=str(dict(self.mesh.shape)),
-            fused_steps=k,
-            pipeline_stages=self.structure.num_stages,
-            pipeline_microbatches=self.structure.num_microbatches,
-        ):
-            with self.mesh:
-                with rec.span("dispatch"):
-                    out = self.compiled_multi_step()(
-                        params, opt_state, batch_stack, label_stack, rng
-                    )
-                with rec.span("device_sync", sync=out[3]):
-                    pass
-        return out
 
     def forward(self, params, batch_inputs):
         """Inference: the sequential microbatch forward (no schedule)."""

@@ -261,7 +261,15 @@ class TestInputPipeline:
         assert all(s.args.get("steps") == 4 for s in h2d)
         assert len(steps) == 2
         assert all(s.args.get("fused_steps") == 4 for s in steps)
-        assert rec.spans_named("dispatch") and rec.spans_named("device_sync")
+        # one enqueue a window and no wait for the device under `step`
+        dispatches = rec.spans_named("dispatch")
+        assert len(dispatches) == 2
+        assert [
+            [c.name for c in rec.children_of(s)] for s in steps
+        ] == [["dispatch"], ["dispatch"]]
+        # the consumer's waits for its windows: one a window, one that
+        # finds the epoch over
+        assert len(rec.spans_named("fit/next_batch")) == 3
 
     def test_windowed_iterator_matches_batch_iterator_order(self):
         """The window stacks are exactly the per-step batches in order

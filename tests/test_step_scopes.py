@@ -282,8 +282,28 @@ def strip_metadata(text):
     )
 
 
+@pytest.fixture
+def no_compile_cache():
+    """jax's persistent compile cache leaves metadata out of its key: with
+    it on (any `FFModel` built earlier in the process turns it on) a program
+    that differs from a cached one only in its scopes loads the other's
+    executable, names and all."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("make", [single_instance, searched_instance])
-def test_scopes_change_nothing_but_metadata(make, monkeypatch, request):
+def test_scopes_change_nothing_but_metadata(
+    make, monkeypatch, request, no_compile_cache
+):
     scoped = request.getfixturevalue(
         "single_text" if make is single_instance else "searched_text"
     )
