@@ -127,8 +127,10 @@ LOG2E = 1.4426950408889634  # log2(e): scores are scaled into the base-2
 
 
 def _one_block_attn_3d(q, kb, vb, causal, row_offset, in_dtype):
-    """Single-k-block attention body shared by the batched ([bb, bq, d])
-    forward kernels: scores -> mask -> row max -> exp2 -> MXU rowsum ->
+    """Single-k-block attention body of the batched ([bb, bq, d]) forward
+    kernel of heads of 128 (_fwd_kernel_b; the d=64 head pairs left it for
+    _fwd_kernel_pair, at the end of this file, which runs at half its
+    time): scores -> mask -> row max -> exp2 -> MXU rowsum ->
     o = (p@v)/l, plus the base-2 lse row. `q` arrives pre-scaled by
     scale*LOG2E (the scale folds into the [bb, bq, d] operand — a
     post-matmul scalar multiply is a full [bq, s] f32 VPU pass). The
@@ -193,7 +195,7 @@ def _fwd_kernel(
             scores = jnp.where(rows >= cols, scores, NEG_INF)
         m = _row_max(scores)
         p = _exp2_probs(scores - m[:, None], q_ref.dtype)
-        # rowsum as p @ ones[s, 1] (see _fwd_kernel_pair)
+        # rowsum as p @ ones[s, 1] (see _fwd_kernel_b)
         l = jax.lax.dot_general(
             p, jnp.ones((s, 1), p.dtype),
             (((1,), (0,)), ((), ())),
@@ -692,9 +694,9 @@ def _fwd_kernel_b(
     q = q_ref[:] * jnp.asarray(scale2, q_ref.dtype)
 
     if nk == 1:
-        # single k block (s <= block_k, the s=512 bench regime): no online
-        # carry — the alpha rescale and running max/sum are pure VPU
-        # overhead when there is nothing to carry across
+        # single k block (s <= block_k): no online carry — the alpha
+        # rescale and running max/sum are pure VPU overhead when there is
+        # nothing to carry across
         o, lse = _one_block_attn_3d(
             q, k_ref[:], v_ref[:], causal, qi * block_q, q_ref.dtype
         )
@@ -766,8 +768,8 @@ def _fwd_pair_call(
     scale = 1.0 / (d**0.5)
     bb = _batch_block(b, block_q, block_k, s, 128, dtype.itemsize)
     kernel = functools.partial(
-        _pair_fwd_kernel(s, block_q, block_k), causal=causal,
-        block_k=block_k, scale=scale, d=d, pid_axis=2,
+        _pair_fwd_kernel(s, block_q, block_k), causal=causal, scale=scale,
+        d=d,
     )
     q_map, k_map, v_map = qkv_index_maps
     o, lse = pl.pallas_call(
@@ -1039,79 +1041,6 @@ def _bwd_fused_kernel_b(
         (((1,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32,
     ).astype(dk_ref.dtype)
-
-
-def _fwd_kernel_pair(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k, scale, d,
-    pid_axis=2,
-):
-    """Head-PAIR variant of _fwd_kernel_b for d=64: the refs carry TWO
-    heads side by side in a 128-lane block (Pallas cannot carve 64-wide
-    blocks out of a fused h*d dim, but a 128-wide block holding a pair is
-    legal), and the online softmax runs per 64-lane half. Keeps the
-    projections plain matmuls at the reference heads=16 / d=64 config —
-    the per-head [b,h,s,d] layout pays ~27 ms/step of transpose copies."""
-    qi = pl.program_id(pid_axis)
-    bb, block_q, _ = q_ref.shape
-    s = k_ref.shape[1]
-    nk = s // block_k
-    scale2 = scale * LOG2E
-    bound = (
-        jnp.minimum(pl.cdiv((qi + 1) * block_q, block_k), nk) if causal else nk
-    )
-    for h2 in range(2):
-        sl = pl.ds(h2 * d, d)
-        # scale folded into the [bb, block_q, d] half (see _fwd_kernel)
-        q = q_ref[:, :, sl] * jnp.asarray(scale2, q_ref.dtype)
-        if nk == 1:
-            # single k block (see _one_block_attn_3d): no online carry
-            o, lse = _one_block_attn_3d(
-                q, k_ref[:, :, sl], v_ref[:, :, sl], causal,
-                qi * block_q, q_ref.dtype,
-            )
-            o_ref[:, :, sl] = o.astype(o_ref.dtype)
-            lse_ref[:, h2, 0, :] = lse
-            continue
-        acc = jnp.zeros((bb, block_q, d), jnp.float32)
-        m = jnp.full((bb, block_q), NEG_INF, jnp.float32)
-        l = jnp.zeros((bb, block_q), jnp.float32)
-
-        def body(j, carry, q=q, sl=sl):
-            acc, m, l = carry
-            kb = k_ref[:, pl.ds(j * block_k, block_k), sl]
-            vb = v_ref[:, pl.ds(j * block_k, block_k), sl]
-            scores = (
-                jax.lax.dot_general(
-                    q, kb, (((2,), (2,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32,
-                )
-            )
-            if causal:
-                rows = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0
-                )
-                cols = j * block_k + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 1
-                )
-                scores = jnp.where((rows >= cols)[None], scores, NEG_INF)
-            m_new = jnp.maximum(m, _row_max(scores))
-            p = _exp2_probs(scores - m_new[..., None], q_ref.dtype)
-            alpha = jnp.exp2(m - m_new)
-            psum = jax.lax.dot_general(
-                jnp.ones((1, p.shape[-1]), p.dtype), p,
-                (((1,), (2,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )[0]
-            l = l * alpha + psum
-            acc = acc * alpha[..., None] + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )
-            return acc, m_new, l
-
-        acc, m, l = jax.lax.fori_loop(0, bound, body, (acc, m, l))
-        o_ref[:, :, sl] = (acc / l[..., None]).astype(o_ref.dtype)
-        lse_ref[:, h2, 0, :] = m + jnp.log2(l)
 
 
 def _bwd_pair_core(
@@ -2060,11 +1989,12 @@ _flash_bshf_causal.defvjp(_flash_bshf_causal_fwd, _flash_bshf_causal_bwd)
 
 # ---------------------------------------------------------------------------
 # where the kernels start: the least length per route, the fold, and the
-# head-pair forward of a short sequence
+# head-pair forward of one key tile
 # ---------------------------------------------------------------------------
 #
-# At the end of the file for the reason the causal schedule is: the seq-512
-# cells' lowered text holds the line of every frame above.
+# At the end of the file for the reason the causal schedule is: a cell's
+# lowered text holds the line of every frame above (what is compared since
+# PR 32, `sha256_without_locations`, does not).
 
 # Most batch rows one program of the bshf kernels folds. _batch_block's
 # budgets were fitted on [512, 64] tiles, where the f32 [s, s] tiles are the
@@ -2076,7 +2006,12 @@ _flash_bshf_causal.defvjp(_flash_bshf_causal_fwd, _flash_bshf_causal_bwd)
 # at 8,192 positions, 16 heads of 64, bf16 (my chip runs, PR 31): s = 128,
 # 2 rows 0.572 / 0.395, 4 rows 0.391 / 0.292, 8 rows 0.335 / 0.327, 16 rows
 # 0.578 / 0.305; s = 256, 2 rows 0.582 / 0.389, 4 rows 0.491 / 0.355, 8
-# rows 0.442 / 0.324.
+# rows 0.442 / 0.324. From 512 on the budget binds and its answer is what
+# the chip chose for the head-pair forward that holds p stationary
+# (_fwd_kernel_pair; ms a call, device time, my chip runs, PR 35): on
+# [24, 512, 3072] 2 rows 0.396, 4 rows (the budget's) 0.395, 8 rows ask
+# for more than the 16 MB of scoped VMEM; on [8, 1024, 3072] 1 row (the
+# budget's) 0.482, 2 rows with the queries in two chunks 0.466.
 _MAX_FOLD = 8
 
 # The least (local) sequence length at which each kernel family of
@@ -2112,56 +2047,105 @@ def min_seq_for(family=None) -> int:
     return _MIN_SEQ.get(family, MIN_SEQ_UNMEASURED)
 
 
-# Below this length a head-pair forward of one tile holds its scores
-# TRANSPOSED. From it on _fwd_kernel_pair stands: nothing was measured
-# there, and the seq-512 cells' lowered text is what PR 31 had to leave.
-_PAIR_TRANSPOSED_BELOW = 512
+# The head-pair forward has ONE body, for one key tile (every call the
+# entries make: the pair path's gate is s <= block, the fused backward's
+# limit). It holds its scores TRANSPOSED, [keys, queries]: the softmax's
+# maximum runs down the sublanes and lse comes out along the lanes, as
+# lse_ref stores it; and p is the STATIONARY operand of the second matmul,
+# so what the compiler turns is the [128, queries] result and not every
+# [keys, queries] tile of p. Forward, ms a call on bf16 [rows, s, 3072], 16
+# heads of 64, device time (8 calls a program; my chip runs, PR 35; the fold
+# is _batch_block's: 8 / 8 / 4 / 4 / 1 rows a program):
+#                          queries x keys    p streams    p stationary
+#   [64, 128]   non-causal   0.324             0.172        0.168
+#   [32, 256]   non-causal   0.430             0.186        0.172
+#   [24, 512]   non-causal   0.750             0.445        0.395
+#   [16, 512]   non-causal   0.509             0.303        0.269
+#   [8, 1024]   non-causal   0.761             0.519        0.482
+#   [24, 512]   causal       0.746             0.396        0.295
+#   [8, 1024]   causal       0.760             0.491        0.409
+# "queries x keys" was the single-tile branch of an online-softmax body:
+# three score-sized matmuls (the row sum was p @ ones[s, 1]) and a
+# cross-lane maximum; the seq-512 cells ran it at 17.7 ms a step. "p
+# streams" was PR 31's transposed body (p^T @ [v | 1]), which ran under
+# 512. Under ~0.2 ms a call this probe reads the host, not the chip: in the
+# device trace of bertlarge_s128_1chip the 24 calls on [64, 128] take 3.64
+# ms a step with p streaming and 2.43 with p stationary (+0.9% tokens/s).
+# There is no body for more than one tile: the pair backward is one tile.
 
 
-def _fwd_kernel_pair_t(
-    q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k, scale, d,
-    pid_axis=2,
+def _pair_causal_chunk(s: int) -> int:
+    """Queries a causal program of _fwd_kernel_pair takes at a time: the
+    keys past a chunk's last query are never read. The largest of 256 and
+    128 that divides s (the pair path's gate is s % 128 == 0). [24, 512]
+    causal, 4 rows a program: one chunk of 512 0.363 ms, chunks of 256
+    0.295, of 128 0.313; [8, 1024] causal, 2 rows: 512 0.358, 256 0.368.
+    In chunks of 128: [24, 384] 0.174, [12, 640] 0.240, [8, 896] 0.498
+    (0.219 / 0.301 / 0.377 without a mask: at one row a program seven
+    chunks cost more than they skip; no cell has such a length). Without a
+    mask there is nothing to skip and one chunk is best (a phase of rows x
+    queries under 1,024 columns leaves its latency exposed: [24, 512], 2
+    rows, 512 queries 0.396, 256 queries 0.456). My chip runs, PR 35."""
+    assert s % 128 == 0, s
+    return 256 if s % 256 == 0 else 128
+
+
+def _fwd_kernel_pair(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale, d,
 ):
-    """_fwd_kernel_pair's single-tile case (and its signature: block_k and
-    pid_axis have nothing to say to one tile) with the scores held as [t, s]:
-    keys on the sublanes, queries on the lanes. The row maximum and the row
-    sum of the softmax then run down the sublanes, elementwise over vregs
-    on the VPU, where _one_block_attn_3d reduces across the lanes of every
-    vreg of the tile; lse comes out along the lanes, as lse_ref stores it;
-    and the sum that divides o rides the p @ v matmul as a column of ones
-    beside v's 64 lanes (the MXU is 128 wide either way). Same arithmetic:
-    f32 scores, _exp2_probs, f32 sums. Forward, ms a call at 8,192 positions,
-    16 heads of 64, 8 rows a program (my chip runs, PR 31): s = 128, 0.318
-    -> 0.188; s = 256, 0.422 -> 0.193."""
+    """Head-PAIR forward for d=64: the refs carry TWO heads side by side in
+    a 128-lane block (Pallas cannot carve 64-wide blocks out of a fused h*d
+    dim, but a 128-wide block holding a pair is legal), and the softmax runs
+    per 64-lane half. Keeps the projections plain matmuls at the reference
+    heads=16 / d=64 config — the per-head [b,h,s,d] layout pays ~27 ms/step
+    of transpose copies. One key tile, scores held [keys, queries]:
+    [v | 1]^T @ p gives o transposed, [2d, queries], with the row sums in
+    the rows the column of ones makes (the MXU is 128 wide either way), so
+    the sum that divides o and lse both lie along the lanes, where the
+    maximum already is, and nothing is summed on the VPU. What is turned is
+    the [2d, queries] quotient of BOTH heads, once, into the pair's
+    128-lane block of o. f32 scores, _exp2_probs, f32 sums on the MXU,
+    bf16 p."""
     bb, s, _ = q_ref.shape
     scale2 = scale * LOG2E
-    for h2 in range(2):
-        sl = pl.ds(h2 * d, d)
-        q = q_ref[:, :, sl] * jnp.asarray(scale2, q_ref.dtype)
-        vb = v_ref[:, :, sl]
-        scores = jax.lax.dot_general(
-            k_ref[:, :, sl], q, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [bb, t, s]
-        if causal:
-            keys = jax.lax.broadcasted_iota(jnp.int32, (s, s), 0)
-            queries = jax.lax.broadcasted_iota(jnp.int32, (s, s), 1)
-            scores = jnp.where((queries >= keys)[None], scores, NEG_INF)
-        m = scores.max(axis=1)
-        p = _exp2_probs(scores - m[:, None, :], q_ref.dtype)
-        acc = jax.lax.dot_general(
-            p.astype(vb.dtype), jnp.concatenate([vb, jnp.ones_like(vb)], -1),
-            (((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # [bb, s, 2d]: p^T v beside the row sums
-        o_ref[:, :, sl] = (acc[:, :, :d] / acc[:, :, d:d + 1]).astype(
-            o_ref.dtype
-        )
-        lse_ref[:, h2, 0, :] = m + jnp.log2(p.astype(jnp.float32).sum(axis=1))
+    cq = _pair_causal_chunk(s) if causal else s
+    for c in range(s // cq):
+        qs = pl.ds(c * cq, cq)
+        t = (c + 1) * cq  # the keys this chunk reads: all s without a mask
+        halves = []
+        for h2 in range(2):
+            sl = pl.ds(h2 * d, d)
+            # scale folded into the [bb, cq, d] half (see _fwd_kernel)
+            q = q_ref[:, qs, sl] * jnp.asarray(scale2, q_ref.dtype)
+            vb = v_ref[:, :t, sl]
+            scores = jax.lax.dot_general(
+                k_ref[:, :t, sl], q, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )  # [bb, t, cq]
+            if causal:
+                keys = jax.lax.broadcasted_iota(jnp.int32, (t, cq), 0)
+                queries = c * cq + jax.lax.broadcasted_iota(
+                    jnp.int32, (t, cq), 1
+                )
+                scores = jnp.where((queries >= keys)[None], scores, NEG_INF)
+            m = scores.max(axis=1)
+            p = _exp2_probs(scores - m[:, None, :], q_ref.dtype)
+            acc = jax.lax.dot_general(
+                jnp.concatenate([vb, jnp.ones_like(vb)], -1),
+                p.astype(vb.dtype),
+                (((1,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )  # [bb, 2d, cq]: (p^T v)^T over the row sums
+            l = acc[:, d:d + 1, :]
+            lse_ref[:, h2, 0, qs] = m + jnp.log2(l[:, 0, :])
+            halves.append(acc[:, :d, :] / l)
+        o_ref[:, qs, :] = jnp.swapaxes(
+            jnp.concatenate(halves, axis=1), 1, 2
+        ).astype(o_ref.dtype)
 
 
 def _pair_fwd_kernel(s: int, block_q: int, block_k: int):
-    """The head-pair forward body for a call's shape."""
-    if s < _PAIR_TRANSPOSED_BELOW and block_q == s == block_k:
-        return _fwd_kernel_pair_t
+    """The head-pair forward body for a call's shape: there is one, and it
+    takes one key tile."""
+    assert block_q == s == block_k, (s, block_q, block_k)
     return _fwd_kernel_pair

@@ -547,18 +547,22 @@ def test_flash_bshf_bf16_backward_error_bounded():
         assert rel < 0.02, rel  # bf16 probs + bf16 (dp - delta) roundoff
 
 
+@pytest.mark.parametrize("s", [256, 384, 512])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_bshf_head_pair_matches_dense(causal):
+def test_flash_bshf_head_pair_matches_dense(causal, s):
     """d=64 head-PAIR path (two heads per 128-lane block): forward and
     backward must match dense attention — the reference TransformerConfig
-    default (num_heads=16, d=64) rides these kernels."""
+    default (num_heads=16, d=64) rides these kernels. 512 is the seq-512
+    cells' length: the backward reads the lse of the forward that holds p
+    stationary (_fwd_kernel_pair), in chunks of queries when causal (of
+    128 at 384, which 256 does not divide)."""
     from flexflow_tpu.kernels.flash_attention import (
         bshf_pair_supported,
         flash_attention_bshf,
     )
 
     rs = np.random.RandomState(7)
-    b, h, s, d = 2, 4, 256, 64
+    b, h, d = 2, 4, 64
     assert bshf_pair_supported(h, d, s)
     q4, k4, v4 = (
         jnp.asarray(rs.randn(b, h, s, d), jnp.float32) for _ in range(3)
@@ -591,18 +595,20 @@ def test_flash_bshf_head_pair_matches_dense(causal):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)
 
 
+@pytest.mark.parametrize("s", [256, 384, 512])
 @pytest.mark.parametrize("causal", [False, True])
-def test_flash_bshf_qkv_fused_matches_pair(causal):
+def test_flash_bshf_qkv_fused_matches_pair(causal, s):
     """Fused-QKV pair entry (one interleaved [b, s, 3f] operand, one fused
     dqkv gradient) must match the three-operand pair path bit-for-bit in
-    forward and, after de-interleaving, in gradient."""
+    forward and, after de-interleaving, in gradient (through
+    _flash_bshf_qkv, whose backward consumes its forward's lse)."""
     from flexflow_tpu.kernels.flash_attention import (
         flash_attention_bshf,
         flash_attention_bshf_qkv,
     )
 
     rs = np.random.RandomState(11)
-    b, h, s, d = 2, 4, 256, 64
+    b, h, d = 2, 4, 64
     f = h * d
     q, k, v = (
         jnp.asarray(rs.randn(b, s, f), jnp.float32) for _ in range(3)

@@ -1,7 +1,8 @@
 """Where the attention kernels start: the least sequence length per kernel
 family (`flash_attention.min_seq_for`), the route `ops.mha_core_route` reads
-from it, and the head-pair kernels at the short lengths it admits (interpret
-mode on the CPU; the chip's numbers are in PERF.md, section 6, PR 31)."""
+from it, the head-pair kernels at the short lengths it admits and the
+forward body `_pair_fwd_kernel` chooses from the shape (interpret mode on
+the CPU; the chip's numbers are in PERF.md, section 6, PR 31 and PR 35)."""
 
 import jax
 import jax.numpy as jnp
@@ -27,40 +28,62 @@ def tpu_shaped_gate(monkeypatch):
         )
 
 
-def dense_core(qkv, h, causal):
-    """XLA's attention on the interleaved [b, s, 3f] row: per pair group
-    [q_pair | k_pair | v_pair] of 128 lanes each."""
+def heads_of(qkv, h):
+    """q, k, v as [b, h, s, d] from the interleaved [b, s, 3f] row: per pair
+    group [q_pair | k_pair | v_pair] of 128 lanes each."""
     b, s, f3 = qkv.shape
     f = f3 // 3
-    d = f // h
-    q, k, v = (
+    return (
         jnp.swapaxes(
-            qkv.reshape(b, s, f // 128, 3, 128)[:, :, :, i].reshape(b, s, h, d),
+            qkv.reshape(b, s, f // 128, 3, 128)[:, :, :, i]
+            .reshape(b, s, h, f // h),
             1, 2,
         )
         for i in range(3)
     )
-    scores = jnp.einsum("bhsd,bhtd->bhst", q, k) / np.sqrt(d)
+
+
+def plain_softmax(qkv, h, causal, precision=None):
+    """XLA's attention on the interleaved row: o [b, s, f] and the base-2
+    lse [b, h, 1, s] the kernels store."""
+    b, s, f3 = qkv.shape
+    q, k, v = heads_of(qkv, h)
+    scores = jnp.einsum(
+        "bhsd,bhtd->bhst", q, k, precision=precision
+    ) / np.sqrt(q.shape[-1])
     if causal:
         mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
         scores = jnp.where(mask, scores, -1e30)
-    ctx = jnp.einsum("bhst,bhtd->bhsd", jax.nn.softmax(scores, -1), v)
-    return jnp.swapaxes(ctx, 1, 2).reshape(b, s, f)
+    ctx = jnp.einsum(
+        "bhst,bhtd->bhsd", jax.nn.softmax(scores, -1), v, precision=precision
+    )
+    lse = jax.nn.logsumexp(scores, -1) * fa.LOG2E
+    return jnp.swapaxes(ctx, 1, 2).reshape(b, s, f3 // 3), lse[:, :, None, :]
+
+
+def dense_core(qkv, h, causal):
+    return plain_softmax(qkv, h, causal)[0]
 
 
 @pytest.mark.parametrize(
     "s,b,causal",
-    [(128, 16, False), (128, 16, True), (256, 8, False), (256, 8, True)],
+    [
+        (128, 16, False), (128, 16, True), (256, 8, False), (256, 8, True),
+        (512, 4, False), (512, 4, True),
+        (384, 4, True), (640, 2, True), (896, 2, True),
+    ],
 )
 def test_pair_qkv_kernels_at_short_sequences_match_dense(s, b, causal):
     """The fused-QKV head-pair kernels at the lengths the gate now admits,
-    with a batch that folds several rows into a program: the forward and
-    the gradients of q, k and v (the three lane groups of dqkv) against the
-    dense core."""
+    with a batch that folds several rows into a program (896 holds one):
+    the forward and the gradients of q, k and v (the three lane groups of
+    dqkv) against the dense core. The gradient comes through the lse of
+    the forward that holds p stationary; where 256 does not divide the
+    length (384, 640, 896) a causal forward takes 128 queries at a time."""
     h, d = 2, 64
     f = h * d
     for fused_bwd in (False, True):
-        assert 1 < fa._batch_block(
+        assert (s < 896) < fa._batch_block(
             b, s, s, s, 128, 4, fused_bwd=fused_bwd, bwd_blocks=8
         ) <= fa._MAX_FOLD
     rs = np.random.RandomState(s + causal)
@@ -90,32 +113,96 @@ def test_pair_qkv_kernels_at_short_sequences_match_dense(s, b, causal):
 
 
 def test_fold_is_capped_only_where_the_budget_allows_more():
-    """_MAX_FOLD binds at short sequences; at [512, 64] tiles the budget's
-    own answer (4 rows a program, forward and fused backward) stands, so the
-    seq-512 cells lower the programs they lowered."""
+    """_MAX_FOLD binds at short sequences (8 rows a program at s = 128 and
+    256, forward and fused backward, as PR 31 measured); at [512, 64] tiles
+    the budget's own answer stands, 4 rows, which is also what the chip
+    chose for the forward that holds p stationary (PR 35: 2 rows 0.396 ms a
+    call on [24, 512, 3072], 4 rows 0.395, and 8 do not fit); at 1,024 one
+    row fits."""
     for fused_bwd in (False, True):
         assert fa._batch_block(
             64, 128, 128, 128, 128, 2, fused_bwd=fused_bwd, bwd_blocks=8
         ) == fa._MAX_FOLD
         assert fa._batch_block(
-            24, 512, 512, 512, 128, 2, fused_bwd=fused_bwd, bwd_blocks=8
-        ) == 4
+            32, 256, 256, 256, 128, 2, fused_bwd=fused_bwd, bwd_blocks=8
+        ) == fa._MAX_FOLD
+        for batch in (24, 16):  # the seq-512 cells' rows a chip
+            assert fa._batch_block(
+                batch, 512, 512, 512, 128, 2, fused_bwd=fused_bwd,
+                bwd_blocks=8,
+            ) == 4
+        assert fa._batch_block(
+            8, 1024, 1024, 1024, 128, 2, fused_bwd=fused_bwd, bwd_blocks=8
+        ) == 1
 
 
 @pytest.mark.parametrize(
-    "s,block_q,block_k,transposed",
+    "s,block_q,block_k,one_tile",
     [
         (128, 128, 128, True),
         (256, 256, 256, True),
-        (512, 512, 512, False),  # the seq-512 cells keep their kernel
-        (256, 128, 128, False),  # more than one tile: the online softmax
+        (384, 384, 384, True),
+        (512, 512, 512, True),  # the seq-512 cells
+        (1024, 1024, 1024, True),
+        (256, 128, 128, False),  # more than one tile: no pair forward (and
+        (512, 256, 512, False),  # no pair backward) is written for it, and
+        (1024, 1024, 512, False),  # the entries' gate keeps it away
     ],
 )
 def test_pair_forward_body_is_chosen_from_the_shape(
-    s, block_q, block_k, transposed
+    s, block_q, block_k, one_tile
 ):
-    want = fa._fwd_kernel_pair_t if transposed else fa._fwd_kernel_pair
-    assert fa._pair_fwd_kernel(s, block_q, block_k) is want
+    if one_tile:
+        assert fa._pair_fwd_kernel(s, block_q, block_k) is fa._fwd_kernel_pair
+    else:
+        with pytest.raises(AssertionError):
+            fa._pair_fwd_kernel(s, block_q, block_k)
+
+
+@pytest.mark.parametrize(
+    "s,chunk",
+    [(128, 128), (256, 256), (384, 128), (512, 256), (640, 128), (768, 256),
+     (896, 128), (1024, 256)],
+)
+def test_causal_chunk_divides_every_length_the_pair_gate_admits(s, chunk):
+    """The gate admits every multiple of 128 up to the block; a chunk that
+    does not divide s leaves the rows after the last whole chunk unwritten."""
+    assert fa.bshf_pair_supported(16, 64, s)
+    assert fa._pair_causal_chunk(s) == chunk and s % chunk == 0
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("entry", ["fused_qkv", "separate"])
+@pytest.mark.parametrize(
+    "s,b",
+    [(128, 8), (256, 4), (384, 2), (512, 2), (640, 1), (896, 1), (1024, 1)],
+)
+def test_pair_forward_of_one_tile_matches_the_plain_softmax(
+    s, b, entry, causal
+):
+    """The single-tile body at every kind of length the gate admits, through
+    the forward of both entries: o AND the base-2 lse the backward will
+    read. A causal program takes its queries 256 at a time where 256 divides
+    s and 128 at a time where it does not (384, 640, 896), and reads no key
+    past a chunk's last query; every row has to be written."""
+    h = 2
+    f = h * 64
+    rs = np.random.RandomState(s + causal)
+    qkv = jnp.asarray(rs.randn(b, s, 3 * f), jnp.float32)
+    if entry == "fused_qkv":
+        o, lse = fa._fwd_bshf_pair_qkv(qkv, h, causal, s, s, interpret=True)
+    else:
+        q, k, v = (
+            jnp.swapaxes(x, 1, 2).reshape(b, s, f) for x in heads_of(qkv, h)
+        )
+        o, lse = fa._fwd_bshf_pair(q, k, v, h, causal, s, s, interpret=True)
+    want_o, want_lse = plain_softmax(qkv, h, causal, precision="highest")
+    for got in (o, lse):
+        assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-5)
+    np.testing.assert_allclose(
+        np.asarray(lse), np.asarray(want_lse), atol=2e-5
+    )
 
 
 BERT_LARGE = MultiHeadAttentionAttrs(embed_dim=1024, num_heads=16, bias=True)
