@@ -3571,7 +3571,9 @@ def _publish_routing(instance, mvals) -> None:
     if mvals is None or routing.ROUTING_KEY not in mvals:
         return
     graph = getattr(instance, "pcg", None) or instance.cg
-    routing.publish(mvals[routing.ROUTING_KEY], routing.held_nodes(graph))
+    routing.publish_recorded(
+        mvals[routing.ROUTING_KEY], routing.held_nodes(graph)
+    )
 
 
 def _perf_from_metric_values(mvals: Dict[str, jnp.ndarray]) -> PerfMetrics:

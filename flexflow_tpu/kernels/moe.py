@@ -39,6 +39,7 @@ from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from flexflow_tpu.op_attrs.ops.moe import (
@@ -244,14 +245,14 @@ def experts_forward(
             "a held share is not sharded again"
         )
         experts = {"w1": w1, "w3": w3, "b1": b1, "w2": w2, "b2": b2}
-        out, here = _held_rows_forward(
+        out, here, windows = _held_rows_forward(
             attrs, attrs.held_experts or expert_shard, x2, flat_e, topv,
             {name: w for name, w in experts.items() if w is not None}, pallas,
         )
         if attrs.held_experts is not None:
             from flexflow_tpu.observability import routing
 
-            routing.record(here, n * k)
+            routing.record(here, n * k, windows)
         counts = None
         if attrs.lambda_bal > 0:
             counts = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
@@ -351,14 +352,100 @@ def held_window_rows(decisions: int, held: int, experts: int) -> int:
     return min(decisions, max(128, -(-(expected + expected // 4) // 128) * 128))
 
 
+def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool):
+    """(tokens [window], their weighted expert outputs [window, out] float32)
+    of the t-th window of a share's rows (`_held_rows_forward`: `order` the
+    decisions with the share's first, `counts` [held] its decisions per
+    expert). Every row is masked by whether the share has a `t * window +
+    i`-th row at all, so a window past the share's last row gives zeros."""
+    held, (n, decisions) = counts.shape[0], (x2.shape[0], order.shape[0])
+    k = decisions // n
+    window = held_window_rows(decisions, held, attrs.num_experts)
+
+    def grouped(rows, w, sizes):
+        if pallas and _gmm_tile(window, w.shape[1], w.shape[2], held):
+            # `held` matrices for held + 1 sizes: the rows of the last,
+            # the window's rest, are not visited and come back zero
+            return _grouped_matmul(
+                rows, w, sizes, True, jnp.zeros((), jnp.int32)
+            )
+        zero = jnp.zeros((1,) + w.shape[1:], w.dtype)
+        return _grouped_matmul(rows, jnp.concatenate([w, zero]), sizes, False)
+
+    total = jnp.sum(counts)
+    ends = jnp.cumsum(counts)
+    lo = t * window
+    at = lo + jnp.arange(window, dtype=jnp.int32)
+    valid = at < total
+    decision = order[jnp.minimum(at, decisions - 1)]
+    token = decision // k
+    rows = jnp.where(valid[:, None], x2[token], 0)
+    sizes = jnp.clip(
+        jnp.minimum(ends, lo + window) - jnp.maximum(ends - counts, lo),
+        0, window,
+    )
+    sizes = jnp.concatenate(
+        [sizes, (window - jnp.sum(sizes))[None]]
+    ).astype(jnp.int32)
+
+    def bias_rows(b):
+        # each row's expert's bias, as a grouped matmul of a column of
+        # ones (see `experts_forward`)
+        return grouped(jnp.ones((window, 1), b.dtype), b[:, None, :], sizes)
+
+    with jax.named_scope("grouped_matmul"):
+        h = grouped(rows, ws["w1"], sizes)
+        if "b1" in ws:
+            h = h + bias_rows(ws["b1"])
+        if attrs.activation is not None:
+            h = attrs.activation.apply(h)
+        if "w3" in ws:
+            h = h * grouped(rows, ws["w3"], sizes)
+        y = grouped(h, ws["w2"], sizes)
+        if "b2" in ws:
+            y = y + bias_rows(ws["b2"])
+    weight = jnp.where(valid, flat_w[decision], 0.0)
+    return token, weight[:, None] * y.astype(jnp.float32)
+
+
+# The two functions of the window index `t` that `_held_rows_forward` calls,
+# each from two sites: straight-line for window 0 and from the body of the
+# loop over the windows after it. Jitted, so that both sites, and every layer
+# that calls them at one shape, share ONE trace and ONE Mosaic lowering of
+# `gmm` / `tgmm`: a `pallas_call` is traced anew at every call site unless
+# its caller is jitted (PERF.md, PR 33). The operations keep the scope of
+# the call site they are inlined into.
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _held_window_add(out, t, order, counts, x2, flat_w, ws, attrs, pallas):
+    """`out` [N, out] float32 with window t's rows added to their tokens."""
+    token, rows_out = _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas)
+    return out.at[token].add(rows_out)
+
+
+@functools.partial(jax.jit, static_argnums=(7, 8))
+def _held_window_grads(g_out, t, order, counts, x2, flat_w, ws, attrs, pallas):
+    """Window t's part of the gradients of (x2, flat_w, ws) under the
+    cotangent `g_out` [N, out] float32 of the share's output: the window
+    recomputed from its inputs and differentiated by itself."""
+
+    def window_dot(x2, flat_w, ws):
+        token, rows_out = _held_window(
+            t, order, counts, x2, flat_w, ws, attrs, pallas
+        )
+        return jnp.sum(rows_out * g_out[token])
+
+    return jax.grad(window_dot, argnums=(0, 1, 2))(x2, flat_w, ws)
+
+
 def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     """The routed part of a call that has the matrices of experts `first ..
     first + held - 1` (`share`) of the E its router chose among: sum over a
     token's decisions that landed on one of them of weight * expert(x),
     [N, out] float32; the other decisions add nothing. Also the decisions
-    per expert of the share, [held]. `flat_e` [N*k]: the chosen experts in
-    (token, select) order; `ws`: the share's `w1`, `w2` and, where the attrs
-    have them, `w3`, `b1`, `b2`.
+    per expert of the share, [held], and the windows the call ran (int32
+    scalar). `flat_e` [N*k]: the chosen experts in (token, select) order;
+    `ws`: the share's `w1`, `w2` and, where the attrs have them, `w3`, `b1`,
+    `b2`.
 
     Only the rows that do work are touched. The decisions are sorted with
     the share's first, by local expert (one stable sort of N k small keys),
@@ -366,12 +453,17 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     token rows, run the grouped matmuls over the share's groups (`gmm` /
     `tgmm` visit those groups' row tiles and zero the rest), scatter-add
     the weighted results to their tokens. A uniform router fills less than
-    one window; a router that sends this share more takes as many windows
-    as it needs (a loop whose trip count is the data's), so nothing is
-    dropped that the attrs' capacity keeps and no window without a row of
-    the share is run. The backward pass is the same loop: each window
-    recomputed from x and the routing and differentiated by itself, its
-    gradients accumulated, so what the forward keeps is its inputs."""
+    one window, so the first window is straight-line code and its results
+    ARE the accumulators: nothing is zero-filled and nothing added to the
+    fill. A router that sends this share more takes the windows after the
+    first in a loop whose trip count is the data's, each added to what the
+    first gave, so nothing is dropped that the attrs' capacity keeps and no
+    later window without a row of the share is run. (A share that no
+    decision reached still runs the first, whose every row is masked.) The
+    backward pass is the same: each window recomputed from x and the
+    routing and differentiated by itself (`_held_window_grads`), the first
+    window's gradients the accumulator of the later ones', so what the
+    forward keeps is its inputs."""
     first, held = share
     n, k = topv.shape
     decisions = n * k
@@ -390,64 +482,23 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
         flat_w = jnp.where(kept, flat_w, 0.0)
     counts = counts[:held]
 
-    def grouped(rows, w, sizes):
-        if pallas and _gmm_tile(window, w.shape[1], w.shape[2], held):
-            # `held` matrices for held + 1 sizes: the rows of the last,
-            # the window's rest, are not visited and come back zero
-            return _grouped_matmul(
-                rows, w, sizes, True, jnp.zeros((), jnp.int32)
-            )
-        zero = jnp.zeros((1,) + w.shape[1:], w.dtype)
-        return _grouped_matmul(rows, jnp.concatenate([w, zero]), sizes, False)
-
-    def one_window(t, order, counts, x2, flat_w, ws):
-        """(tokens [window], their weighted expert outputs [window, out]
-        float32) of the t-th window of the share's rows."""
-        total = jnp.sum(counts)
-        ends = jnp.cumsum(counts)
-        lo = t * window
-        at = lo + jnp.arange(window, dtype=jnp.int32)
-        valid = at < total
-        decision = order[jnp.minimum(at, decisions - 1)]
-        token = decision // k
-        rows = jnp.where(valid[:, None], x2[token], 0)
-        sizes = jnp.clip(
-            jnp.minimum(ends, lo + window) - jnp.maximum(ends - counts, lo),
-            0, window,
-        )
-        sizes = jnp.concatenate(
-            [sizes, (window - jnp.sum(sizes))[None]]
-        ).astype(jnp.int32)
-
-        def bias_rows(b):
-            # each row's expert's bias, as a grouped matmul of a column of
-            # ones (see `experts_forward`)
-            return grouped(jnp.ones((window, 1), b.dtype), b[:, None, :], sizes)
-
-        with jax.named_scope("grouped_matmul"):
-            h = grouped(rows, ws["w1"], sizes)
-            if "b1" in ws:
-                h = h + bias_rows(ws["b1"])
-            if attrs.activation is not None:
-                h = attrs.activation.apply(h)
-            if "w3" in ws:
-                h = h * grouped(rows, ws["w3"], sizes)
-            y = grouped(h, ws["w2"], sizes)
-            if "b2" in ws:
-                y = y + bias_rows(ws["b2"])
-        weight = jnp.where(valid, flat_w[decision], 0.0)
-        return token, weight[:, None] * y.astype(jnp.float32)
-
     def windows(counts):
         return (jnp.sum(counts) + window - 1) // window
 
+    # the first window's index and the loop's first, typed as the loop gives
+    # its own (int32, not weakly), so that the two call sites of a window
+    # function share its one trace; numpy's, because a device array made
+    # while tracing is a constant the step's executable takes as an argument
+    zeroth, after = np.int32(0), np.int32(1)
+
     def forward(order, counts, x2, flat_w, ws):
-        def body(t, out):
-            token, rows_out = one_window(t, order, counts, x2, flat_w, ws)
-            return out.at[token].add(rows_out)
+        def add(t, out):
+            return _held_window_add(
+                out, t, order, counts, x2, flat_w, ws, attrs, pallas
+            )
 
         zero = jnp.zeros((n, ws["w2"].shape[-1]), jnp.float32)
-        return lax.fori_loop(0, windows(counts), body, zero)
+        return lax.fori_loop(after, windows(counts), add, add(zeroth, zero))
 
     @jax.custom_vjp
     def routed(order, counts, x2, flat_w, ws):
@@ -461,18 +512,20 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     def routed_bwd(kept, g_out):
         order, counts, x2, flat_w, ws = kept
 
-        def window_dot(x2, flat_w, ws, t):
-            token, rows_out = one_window(t, order, counts, x2, flat_w, ws)
-            return jnp.sum(rows_out * g_out[token])
+        def grads(t):
+            return _held_window_grads(
+                g_out, t, order, counts, x2, flat_w, ws, attrs, pallas
+            )
 
-        def body(t, grads):
-            g = jax.grad(window_dot, argnums=(0, 1, 2))(x2, flat_w, ws, t)
-            return jax.tree_util.tree_map(jnp.add, grads, g)
+        def add(t, so_far):
+            return jax.tree_util.tree_map(jnp.add, so_far, grads(t))
 
-        zero = jax.tree_util.tree_map(jnp.zeros_like, (x2, flat_w, ws))
-        g_x2, g_w, g_ws = lax.fori_loop(0, windows(counts), body, zero)
+        g_x2, g_w, g_ws = lax.fori_loop(
+            after, windows(counts), add, grads(zeroth)
+        )
         return None, None, g_x2, g_w, g_ws
 
     routed.defvjp(routed_fwd, routed_bwd)
     ws = {name: w.astype(x2.dtype) for name, w in ws.items()}
-    return routed(order, counts, x2, flat_w, ws), counts
+    ran = jnp.maximum(windows(counts), 1)  # the first runs whatever the counts
+    return routed(order, counts, x2, flat_w, ws), counts, ran

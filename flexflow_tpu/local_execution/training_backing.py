@@ -323,7 +323,8 @@ class ModelTrainingInstance:
 
     def _loss_and_routing(self, params, batch_inputs, label, rng=None):
         """(loss, (logits, the held expert nodes' routing counts stacked
-        [nodes, held], or None in a graph without such a node))."""
+        [nodes, held + 3] as `routing.record` lays a row out, or None in a
+        graph without such a node))."""
         from flexflow_tpu.observability import routing
 
         with trace.step_scope("cast"):

@@ -5,6 +5,7 @@ that lives with the configuration, at toy size on the CPU with seeded
 weights. The reference's recurrence runs position by position; the program's
 in chunks. Every tolerance states its reason."""
 
+import functools
 import json
 import os
 import subprocess
@@ -285,33 +286,38 @@ def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
         assert scan_route(**dict(cell, batch=4)) == "xla"
 
 
-def test_lowered_step_holds_the_scan_kernels_and_no_mask_tensor(monkeypatch):
-    """The step of the five-layer tower at the kernel widths, lowered for
-    the TPU platform as `tools/lowered_step_text.py` lowers a cell's: each
-    of its two state-space layers calls the forward kernel, the state pass
-    and the backward kernel once (the jitted callers `_ssd_forward` and
-    `_ssd_backward`; their bodies, `ssd_fwd_chunk`, `ssd_states_chunk` and
-    `ssd_bwd_chunk`, are in the text once for both layers), and no float32
-    `[.., 128, 128]` tensor (C.B, a decay mask, their product) is left in the
-    program, which the XLA form has. Four heads a group, so that a group's
-    `[256, 128]` state is none."""
-    import re
-
+@functools.cache
+def lowered_tower_step(kernels: bool) -> str:
+    """The StableHLO of the step of the five-layer tower at the kernel
+    widths (four heads a group, so that a group's `[256, 128]` state is no
+    `[128, 128]` tensor), lowered for the TPU platform as
+    `tools/lowered_step_text.py` lowers a cell's. `kernels`: the caller has
+    told the kernel gates that a TPU is there."""
     from flexflow_tpu.analysis import lowering
-    from flexflow_tpu.kernels import flash_attention as fa
-
-    def lowered(sizes):
-        model = compiled_model(256, jnp.bfloat16, sizes=sizes, max_devices=1)
-        example = lowering.step_example_args_cg(model.instance, model.loss_attrs)
-        return model.instance.compiled_step().trace(
-            model.params, model.opt_state, *example
-        ).lower(lowering_platforms=("tpu",)).as_text()
 
     sizes = dict(KERNEL_TOY, mamba_num_heads=8)
+    model = compiled_model(256, jnp.bfloat16, sizes=sizes, max_devices=1)
+    example = lowering.step_example_args_cg(model.instance, model.loss_attrs)
+    return model.instance.compiled_step().trace(
+        model.params, model.opt_state, *example
+    ).lower(lowering_platforms=("tpu",)).as_text()
+
+
+def test_lowered_step_holds_the_scan_kernels_and_no_mask_tensor(monkeypatch):
+    """Each of the tower's two state-space layers calls the forward kernel,
+    the state pass and the backward kernel once (the jitted callers
+    `_ssd_forward` and `_ssd_backward`; their bodies, `ssd_fwd_chunk`,
+    `ssd_states_chunk` and `ssd_bwd_chunk`, are in the text once for both
+    layers), and no float32 `[.., 128, 128]` tensor (C.B, a decay mask,
+    their product) is left in the program, which the XLA form has."""
+    import re
+
+    from flexflow_tpu.kernels import flash_attention as fa
+
     mask = re.compile(r"tensor<(?:\d+x)+128x128xf32>")
-    assert mask.findall(lowered(sizes))  # the XLA form
+    assert mask.findall(lowered_tower_step(False))  # the XLA form
     monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
-    text = lowered(sizes)
+    text = lowered_tower_step(True)
     assert sorted(
         name for name in re.findall(r'kernel_name = "(\w+)"', text)
         if name.startswith("ssd_")
@@ -319,6 +325,41 @@ def test_lowered_step_holds_the_scan_kernels_and_no_mask_tensor(monkeypatch):
     calls = re.findall(r"call @(_ssd_\w+?)(?:_\d+)?\(", text)
     assert sorted(calls) == ["_ssd_backward"] * 2 + ["_ssd_forward"] * 4
     assert mask.findall(text) == []
+
+
+def test_lowered_step_starts_no_loop_of_the_held_share_from_zeros():
+    """The held share's loops take the windows after the first, so what
+    they accumulate into is what the first window gave: each of the tower's
+    two expert layers has a loop that carries the experts' `[4, 32, 24]` /
+    `[4, 24, 32]` stacks twice (the matrices and their gradients), and no
+    operand of any `while` is a constant or a broadcast of that shape, as
+    the zero-filled accumulators were. Both call sites of a window function
+    (straight-line and loop body) call the one function of the index."""
+    import re
+
+    text = lowered_tower_step(False)
+    stack = re.compile(r"tensor<4x(?:32x24|24x32)xbf16>")
+    carried, filled = [], []
+    for function in text.split("func.func")[1:]:  # a value's name is local
+        made_by = dict(re.findall(r"(%\w+)(?::\d+)? = ([\w.]+)", function))
+        for line in re.findall(r"stablehlo\.while\((.*)", function):
+            operands, types = line.split(") : ")
+            values = re.findall(r"= (%\w+)", operands)
+            stacks = [
+                made_by.get(value) for value, kind in zip(values, types.split(", "))
+                if stack.fullmatch(kind)
+            ]
+            carried.append(len(stacks))
+            filled += [
+                made for made in stacks
+                if made in ("stablehlo.broadcast_in_dim", "stablehlo.constant")
+            ]
+    assert sorted(carried)[-2:] == [4, 4], carried
+    assert filled == []
+    calls = re.findall(r"call @(_held_window_\w+?)(?:_\d+)?\(", text)
+    assert sorted(calls) == (
+        ["_held_window_add"] * 4 + ["_held_window_grads"] * 4
+    )
 
 
 def test_scan_keeps_no_state_per_position():
@@ -421,7 +462,7 @@ def experts_attrs(held):
     )
 
 
-def experts_case(seed=4):
+def experts_case(seed=4, tokens=48):
     """(tokens [n, D], the UNCUT layer's weights by reference name)."""
     rs = np.random.RandomState(seed)
     d, e = TOY["hidden_size"], TOY["n_routed_experts_total"]
@@ -435,7 +476,7 @@ def experts_case(seed=4):
         "e.weight4": rand(rs, d, shared, scale=0.3),
         "e.weight5": rand(rs, shared, d, scale=0.3),
     }
-    return rand(rs, 48, d), named
+    return rand(rs, tokens, d), named
 
 
 def share_of(named, first, count):
@@ -660,26 +701,79 @@ def test_rehearsal_cell_runs_correct_on_the_cpu_mesh(tmp_path):
     assert "nemotron reference routing" in done.stderr
 
 
-def test_a_router_that_sends_everything_here_drops_nothing():
-    """The held rows are taken in windows sized for a uniform router (a
-    quarter more than 4 / 16 of 144 decisions: one 128-row window). With a
-    selection bias that sends nearly every decision to the held experts the
-    op takes a second window, and still agrees with the reference."""
-    from flexflow_tpu.kernels.moe import held_window_rows
+# selection biases over the 16 experts that send the share (4, 4) of 96
+# tokens' 288 decisions none, a uniform quarter, two of every three, all
+ROUTERS = {
+    "no_decision": (jnp.zeros(16).at[4:8].set(-5.0), 0),
+    "one_window": (None, 1),
+    "two_windows": (jnp.zeros(16).at[4:6].set(5.0), 2),
+    "three_windows": (jnp.zeros(16).at[4:8].set(5.0), 3),
+}
 
-    m, named = experts_case(seed=8)
+
+@pytest.mark.parametrize("router", list(ROUTERS))
+def test_a_router_that_sends_everything_here_drops_nothing(router):
+    """The held rows are taken in windows sized for a uniform router (a
+    quarter more than 4 / 16 of 288 decisions: one 128-row window). The
+    first window is straight-line code and runs whatever the router sent,
+    also where it sent nothing; with a selection bias that sends the held
+    experts more the op takes a second and a third in its loop. Output,
+    the gradients of x, the router and `w1` / `w2`, and the windows the op
+    says it ran, against the reference's dense masked experts."""
+    from flexflow_tpu.kernels.moe import held_window_rows
+    from flexflow_tpu.observability import routing
+
+    bias, windows = ROUTERS[router]
+    m, named = experts_case(seed=8, tokens=96)
     held = (4, 4)
-    named["e.weight1"] = jnp.zeros(16).at[4:8].set(5.0)
-    assert held_window_rows(48 * 3, 4, 16) == 128
-    want, mask = reference_experts(m, named, *held)
-    assert float(jnp.sum(mask[:, 4:8])) > 128  # more than one window's rows
-    got = experts_forward(experts_attrs(held), m, share_of(named, *held))[0]
-    np.testing.assert_allclose(got, want, **F32)
+    if bias is not None:
+        named["e.weight1"] = bias
+    assert held_window_rows(96 * 3, 4, 16) == 128
+    rows = float(jnp.sum(reference_experts(m, named, *held)[1][:, 4:8]))
+    assert -(-rows // 128) == windows, rows
     cot = rand(np.random.RandomState(9), *m.shape)
-    grad = lambda f: jax.grad(lambda m: jnp.sum(f(m) * cot))(m)
-    np.testing.assert_allclose(
-        grad(lambda m: experts_forward(
-            experts_attrs(held), m, share_of(named, *held))[0]),
-        grad(lambda m: reference_experts(m, named, *held)[0]),
-        rtol=1e-4, atol=1e-4,
-    )
+
+    def system(m, named):
+        return experts_forward(experts_attrs(held), m, share_of(named, *held))[0]
+
+    def reference(m, named):
+        return reference_experts(m, named, *held)[0]
+
+    with routing.collecting() as recorded:
+        got = system(m, named)
+    np.testing.assert_allclose(got, reference(m, named), **F32)
+    (row,) = recorded  # the share's counts, the decisions, windows, a step
+    assert [int(v) for v in row[-3:]] == [96 * 3, max(windows, 1), 1]
+    assert float(jnp.sum(row[:4])) == rows
+    got = jax.grad(lambda *a: jnp.sum(system(*a) * cot), (0, 1))(m, named)
+    want = jax.grad(lambda *a: jnp.sum(reference(*a) * cot), (0, 1))(m, named)
+    for name in ("e.weight0", "e.weight2", "e.weight3"):
+        moved = float(jnp.max(jnp.abs(want[1][name])))
+        assert (moved > 1e-3) == (windows > 0), name
+    assert_trees_close(got, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize(
+    "bias,windows", [(None, [1, 1]), (5.0, [2, 1])],
+    ids=["uniform", "overflowing"],
+)
+def test_windows_counter_reads_what_the_loop_ran(bias, windows):
+    """The program's windows counter through `fit` on the CPU mesh, two
+    steps of the toy tower (192 decisions a step, 128-row windows): 1 a
+    step for each held node under the initial router, 2 for the first node
+    once its selection bias sends every decision to the held experts."""
+    from flexflow_tpu.observability import routing
+    from test_olmoe import weight_keys
+
+    seq = 32
+    model = compiled_model(seq, max_devices=1)
+    if bias is not None:
+        key = weight_keys(model.instance)["moe1.weight1"]
+        model.params = dict(
+            model.params, **{key: jnp.zeros(16).at[4:8].set(bias)}
+        )
+    inputs, labels = ref.make_data(np.random.RandomState(0), TOY, 2 * BATCH, seq)
+    model.fit(inputs, labels, epochs=1, shuffle=False, verbose=False)
+    counted = routing.published()
+    assert list(counted["decisions"]) == [BATCH * seq * 3 * 2] * 2
+    assert list(counted["windows_per_step"]) == windows
