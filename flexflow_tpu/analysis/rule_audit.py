@@ -323,6 +323,7 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
             # the pattern of the shared form pins the selection bias and
             # asks for a shared width that is not zero
             shared_hidden_size=size if eq.get("selection_bias") else 0,
+            latent_size=size if eq.get("latent_size") is _SET else None,
         )
     if op_type == OperatorType.STATE_SPACE:
         from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs

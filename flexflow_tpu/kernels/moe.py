@@ -30,6 +30,15 @@ without the exchange; or one expert-parallel shard inside a `shard_map`,
 `expert_shard`) routes over all of them and takes `_held_rows_forward`
 instead: it touches only the rows routed to its experts, a window of them at
 a time, so its cost follows the rows that do work and not N*k.
+
+Where the experts live in a latent space (`ExpertsAttrs.latent_size`) the
+rows that are sorted, gathered, multiplied and combined are the tokens'
+latent images `x w_down`, in every form above; the router and the shared
+expert read x itself, and `w_up` meets the combined float32 rows once.
+
+The node's parts go under scopes of their own inside the node's
+(`ff.experts.<name>/router`, `/latent`, `/routed`, `/shared`;
+`observability/trace.NODE_PARTS`), so a trace reader can tell them apart.
 """
 
 from __future__ import annotations
@@ -227,37 +236,66 @@ def experts_forward(
     whatever the enclosing trace."""
     gate_w, rest = weights[0], list(weights[1:])
     select_bias = rest.pop(0) if attrs.selection_bias else None
+    w_down = rest.pop(0) if attrs.latent_size else None
     w1 = rest.pop(0)
     w3 = rest.pop(0) if attrs.gated else None
     b1 = rest.pop(0) if attrs.use_bias else None
     w2 = rest.pop(0)
     b2 = rest.pop(0) if attrs.use_bias else None
+    w_up = rest.pop(0) if attrs.latent_size else None
     shared = rest  # ws1[, ws3], ws2 of the shared expert, or nothing
 
     x2 = x.reshape(-1, x.shape[-1])
     n = x2.shape[0]
     e, k = attrs.num_experts, attrs.num_select
-    logits, probs, topi, topv = route(attrs, x2, gate_w, select_bias)
+    with jax.named_scope("router"):
+        logits, probs, topi, topv = route(attrs, x2, gate_w, select_bias)
+    # the rows the experts read: x's own, or their latent image
+    z2 = x2
+    if w_down is not None:
+        with jax.named_scope("latent"):
+            z2 = x2 @ w_down.astype(x2.dtype)
     pallas = _pallas_allowed(per_shard)
-    flat_e = topi.reshape(-1).astype(jnp.int32)  # [N*k]
-    if attrs.held_experts is not None or expert_shard is not None:
-        assert None in (attrs.held_experts, expert_shard), (
-            "a held share is not sharded again"
-        )
-        experts = {"w1": w1, "w3": w3, "b1": b1, "w2": w2, "b2": b2}
-        out, here, windows = _held_rows_forward(
-            attrs, attrs.held_experts or expert_shard, x2, flat_e, topv,
-            {name: w for name, w in experts.items() if w is not None}, pallas,
-        )
-        if attrs.held_experts is not None:
-            from flexflow_tpu.observability import routing
+    with jax.named_scope("routed"):
+        flat_e = topi.reshape(-1).astype(jnp.int32)  # [N*k]
+        if attrs.held_experts is not None or expert_shard is not None:
+            assert None in (attrs.held_experts, expert_shard), (
+                "a held share is not sharded again"
+            )
+            experts = {"w1": w1, "w3": w3, "b1": b1, "w2": w2, "b2": b2}
+            out, here, windows = _held_rows_forward(
+                attrs, attrs.held_experts or expert_shard, z2, flat_e, topv,
+                {name: w for name, w in experts.items() if w is not None},
+                pallas,
+            )
+            if attrs.held_experts is not None:
+                from flexflow_tpu.observability import routing
 
-            routing.record(here, n * k, windows)
-        counts = None
-        if attrs.lambda_bal > 0:
-            counts = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
-        return _finish(attrs, out, x, shared, counts, probs, logits)
+                routing.record(here, n * k, windows)
+            counts = None
+            if attrs.lambda_bal > 0:
+                counts = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+        else:
+            out, counts = _all_rows_forward(
+                attrs, z2, flat_e, topv, w1, w3, b1, w2, b2, pallas
+            )
+    if w_up is not None:
+        # once, on the combined rows: float32 in, float32 out, the operands
+        # of the product in the compute dtype like every other's
+        with jax.named_scope("latent"):
+            out = jnp.dot(
+                out.astype(x2.dtype), w_up.astype(x2.dtype),
+                preferred_element_type=jnp.float32,
+            )
+    return _finish(attrs, out, x, shared, counts, probs, logits)
 
+
+def _all_rows_forward(attrs, x2, flat_e, topv, w1, w3, b1, w2, b2, pallas):
+    """The routed part of a call that has every expert's matrices: (sum over
+    a token's k decisions of weight * expert(x) [N, out] float32, decisions
+    per expert [E]). `x2` [N, D]: the rows the experts read; `flat_e` [N*k]:
+    the chosen experts in (token, select) order."""
+    (n, k), e = topv.shape, attrs.num_experts
     # -- dispatch: decisions in (token, select) order, sorted by expert ----
     order = jnp.argsort(flat_e, stable=True)
     inverse = jnp.argsort(order)  # decision -> its row after the sort
@@ -294,8 +332,7 @@ def experts_forward(
     # -- combine: each token's k rows, weighted by the router in float32 ---
     y = _take_rows(y, inverse, order, 1)
     y = y.reshape(n, k, y.shape[-1]).astype(jnp.float32)
-    out = jnp.einsum("nk,nko->no", topv, y)
-    return _finish(attrs, out, x, shared, counts, probs, logits)
+    return jnp.einsum("nk,nko->no", topv, y), counts
 
 
 def _finish(attrs, out, x, shared, counts, probs, logits):
@@ -334,7 +371,7 @@ def _add_shared_expert(attrs: ExpertsAttrs, out, x, shared):
     none)."""
     if not shared:
         return out
-    with jax.named_scope("shared_expert"):
+    with jax.named_scope("shared"), jax.named_scope("shared_expert"):
         x2 = x.reshape(-1, x.shape[-1])
         hs = x2 @ shared[0].astype(x2.dtype)
         if attrs.activation is not None:

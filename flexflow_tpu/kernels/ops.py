@@ -749,9 +749,15 @@ def op_internal_bytes(attrs: OpAttrs, input_shapes, weight_shapes=None) -> int:
     tokens = int(x.num_elements) // d
     rows = _experts_rows(attrs, tokens, weight_shapes)
     forms = 2 if attrs.gated else 1
-    width = d + forms * attrs.hidden_size + (attrs.out_channels or d)
-    shared = tokens * forms * attrs.shared_hidden_size
-    return 2 * (rows * width + shared) * x.dtype.size_bytes
+    latent = attrs.latent_size
+    # a dispatched row is as wide as what the experts read and write; the
+    # latent image of every token and the combined latent rows besides
+    width = (
+        (latent or d) + forms * attrs.hidden_size
+        + (latent or attrs.out_channels or d)
+    )
+    per_token = forms * attrs.shared_hidden_size + 2 * (latent or 0)
+    return 2 * (rows * width + tokens * per_token) * x.dtype.size_bytes
 
 
 def op_forward_flops(
@@ -839,10 +845,12 @@ def op_forward_flops(
         rows = _experts_rows(attrs, n, weight_shapes)
         gate = 2 * n * d * attrs.num_experts  # every device gates its tokens
         forms = 2 if attrs.gated else 1
-        mlp = 2 * rows * (forms * d * h + h * o)
-        # the shared expert sees every token
+        latent = attrs.latent_size
+        mlp = 2 * rows * (forms * (latent or d) * h + h * (latent or o))
+        # the shared expert and the latent projections see every token
         hs = attrs.shared_hidden_size
-        return gate + mlp + 2 * n * (forms * d * hs + hs * o)
+        dense = forms * d * hs + hs * o + (latent or 0) * (d + o)
+        return gate + mlp + 2 * n * dense
 
     from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 

@@ -192,9 +192,13 @@ _NT = (((1,), (1,)), ((), ()))  # [m, k] x [n, k]
 _TN = (((0,), (0,)), ((), ()))  # [k, m] x [k, n]
 
 # r * P columns a program holds at most: the [r * P, N] state and a few
-# [Q, r * P] float32 tiles have to fit the scoped VMEM. 512 is what the
-# published widths need (8 heads of 64 a group) and what was compiled.
-_MAX_GROUP_COLUMNS = 512
+# [Q, r * P] float32 tiles have to fit the scoped VMEM. 1,024 is the widest
+# group of the published widths (16 heads of 64; the other has 8) and what
+# was compiled for the described v5e, forward and backward, at Q = N = 128:
+# the backward's five scratch tiles are 2 MB there and its double-buffered
+# blocks 2.5 MB more, of 16 MB. A wider group would go as column blocks that
+# read the same B and C; nothing published asks for one (PR 39).
+_MAX_GROUP_COLUMNS = 1024
 _LANES = 128
 
 

@@ -366,12 +366,21 @@ _NOT_IN_NAME = re.compile(r"[^A-Za-z0-9_.\-]")
 _SCOPE = re.compile(
     r"(?<![A-Za-z0-9_.\-])ff\.([a-z0-9_]+)(?:\.([A-Za-z0-9_.\-]+))?"
 )
-# a state-space node puts its scan under a further scope inside its own
-# (`ff.ssm.<name>/scan`, `kernels/ssm.py`): `parse_scope` keeps that in the
-# name, `<name>/scan`, so that one table tells the scan from the node's
-# projections. The node's scope may be closed by JAX's `jvp(...)` /
-# `transpose(...)` before the scan's begins.
-_SCAN_PART = re.compile(r"\)*/scan(?:[/)]|$)")
+# a node may put a part of itself under a further scope inside its own
+# (`NODE_PARTS`: the state-space node its scan, `ff.ssm.<name>/scan`,
+# `kernels/ssm.py`; the experts node its router, latent projections, routed
+# experts and shared expert, `kernels/moe.py`): `parse_scope` keeps that in
+# the name, `<name>/<part>`, so that one table tells the scan from the node's
+# projections and the router from the experts. The node's scope may be closed
+# by JAX's `jvp(...)` / `transpose(...)` before the part's begins.
+NODE_PARTS = {
+    "ssm": ("scan",),
+    "experts": ("router", "latent", "routed", "shared"),
+}
+_PART = {
+    kind: re.compile(r"\)*/(" + "|".join(parts) + r")(?:[/)]|$)")
+    for kind, parts in NODE_PARTS.items()
+}
 
 
 def scope_kind(op_type) -> str:
@@ -413,8 +422,8 @@ def parse_scope(op_name: str) -> Tuple[str, str, str]:
     `transpose(` or a rematerialized computation it is `bwd`, under
     `ff.optimizer` `opt`, under `ff.cast` / `ff.metrics` / `ff.health`
     `other`, under any other `ff.` scope `fwd`; with no `ff.` scope it is
-    `("unattributed", "", "")`. An operation of a state-space node's scan
-    has the name `<name>/scan`."""
+    `("unattributed", "", "")`. An operation of a part of a node
+    (`NODE_PARTS`) has the name `<name>/<part>`."""
     m = _SCOPE.search(op_name)
     if m is None:
         return "unattributed", "", ""
@@ -425,8 +434,9 @@ def parse_scope(op_name: str) -> Tuple[str, str, str]:
         "transpose(" in op_name[: m.start()]
         or "rematted_computation" in op_name
     )
-    if kind == "ssm" and _SCAN_PART.match(op_name, m.end()):
-        name = f"{name}/scan"
+    part = kind in _PART and _PART[kind].match(op_name, m.end())
+    if part:
+        name = f"{name}/{part.group(1)}"
     return ("bwd" if backward else "fwd"), kind, name or ""
 
 

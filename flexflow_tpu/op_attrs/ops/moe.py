@@ -168,6 +168,16 @@ class ExpertsAttrs:
     them, and what the absent experts would add is left out (the share of a
     layer that expert parallelism gives one chip, without the exchange).
     The shared expert is whole in every share.
+    latent_size: None, or L: the routed experts live in a latent space of
+    that width. Two more weights, `w_down` [D, L] in front of the dispatch
+    and `w_up` [L, out] after the combine (slot order: gate[, selection
+    bias], w_down, the expert tensors, w_up, the shared expert's), no bias,
+    norm or activation between them; the expert matrices are [E, L, H] and
+    [E, H, L]. The router and the shared expert read the D-wide row:
+        z = x w_down;  u = sum_{e chosen} weight_e expert_e(z)   (float32)
+        out = u w_up + shared(x)
+    `w_up` meets the combined rows once, whatever share of the experts the
+    op has: it is linear, so the shares' parts still add up.
 
     outputs: [.., out] and, when lambda_bal > 0 or lambda_z > 0, one
     float32 scalar [1] to be added to the training loss (reference: MoE
@@ -200,6 +210,7 @@ class ExpertsAttrs:
     routed_scale: float = 1.0
     shared_hidden_size: int = 0
     held_experts: Optional[Tuple[int, int]] = None
+    latent_size: Optional[int] = None
 
     def __post_init__(self):
         assert not (self.gated and self.use_bias), (
@@ -220,6 +231,7 @@ class ExpertsAttrs:
             assert self.capacity_factor is None and not self.use_bias, (
                 "a held share of the experts is dropless and has no biases"
             )
+        assert self.latent_size is None or self.latent_size > 0, self.latent_size
 
     @property
     def has_aux(self) -> bool:
@@ -232,12 +244,17 @@ class ExpertsAttrs:
 
     def weight_roles(self) -> List[str]:
         """What each weight slot is, in slot order: "router" (the gate and
-        the selection bias: whole wherever the op runs), "expert" (leading
-        dim the local experts) or "shared" (the shared expert's matrices)."""
-        roles = ["router"] * (2 if self.selection_bias else 1)
+        the selection bias: whole wherever the op runs), "latent" (the
+        projections into and out of the experts' latent space: whole
+        wherever the op runs, never sharded over the expert axes), "expert"
+        (leading dim the local experts) or "shared" (the shared expert's
+        matrices)."""
+        latent = ["latent"] if self.latent_size else []
+        roles = ["router"] * (2 if self.selection_bias else 1) + latent
         roles += ["expert"] * (
             3 if self.gated else (4 if self.use_bias else 2)
         )
+        roles += latent
         if self.shared_hidden_size:
             roles += ["shared"] * (3 if self.gated else 2)
         return roles
@@ -269,17 +286,24 @@ class ExpertsAttrs:
     def weight_shapes(self, input: TensorShape) -> List[TensorShape]:
         d = input.dims[-1]
         e, h, o = self.num_local_experts, self.hidden_size, self._out_dim(input)
+        # the width the experts read and write: the latent one where there is
+        latent = self.latent_size
+        ed, eo = latent or d, latent or o
         ws = [TensorShape((d, self.num_experts), input.dtype)]
         if self.selection_bias:
             ws.append(TensorShape((self.num_experts,), input.dtype))
-        ws.append(TensorShape((e, d, h), input.dtype))
+        if latent:
+            ws.append(TensorShape((d, latent), input.dtype))
+        ws.append(TensorShape((e, ed, h), input.dtype))
         if self.gated:
-            ws.append(TensorShape((e, d, h), input.dtype))
+            ws.append(TensorShape((e, ed, h), input.dtype))
         if self.use_bias:
             ws.append(TensorShape((e, h), input.dtype))
-        ws.append(TensorShape((e, h, o), input.dtype))
+        ws.append(TensorShape((e, h, eo), input.dtype))
         if self.use_bias:
-            ws.append(TensorShape((e, o), input.dtype))
+            ws.append(TensorShape((e, eo), input.dtype))
+        if latent:
+            ws.append(TensorShape((latent, o), input.dtype))
         if self.shared_hidden_size:
             hs = self.shared_hidden_size
             ws += [TensorShape((d, hs), input.dtype)] * (2 if self.gated else 1)

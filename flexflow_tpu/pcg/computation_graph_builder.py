@@ -560,6 +560,7 @@ class ComputationGraphBuilder:
         routed_scale: float = 1.0,
         shared_hidden_size: int = 0,
         held_experts: Optional[Tuple[int, int]] = None,
+        latent_size: Optional[int] = None,
     ) -> List[Tensor]:
         """Fused MoE FFN (`ExpertsAttrs`); returns [out] or, with an
         auxiliary loss coefficient, [out, aux_loss], the scalar recorded in
@@ -585,6 +586,7 @@ class ComputationGraphBuilder:
             routed_scale,
             shared_hidden_size,
             held_experts,
+            latent_size,
         )
         inits = [initializer] * attrs.num_weights
         if selection_bias:

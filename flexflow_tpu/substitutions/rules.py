@@ -474,11 +474,13 @@ def column_parallel_embedding_rule(degree: int) -> Substitution:
     )
 
 
-def _experts_pattern(use_bias, gated, with_aux, div=None, shared=False):
+def _experts_pattern(use_bias, gated, with_aux, div=None, shared=False,
+                     latent=False):
     """(attribute pattern, weight slots, outputs) of one form of the Experts
     op. The forms differ in their number of weight slots (legacy with and
     without biases, gated; `shared`: the bias-free form with a selection
-    bias and a shared expert, two or three more slots) and outputs (an
+    bias and a shared expert, two or three more slots; `latent`: the
+    projections into and out of a latent space, two more) and outputs (an
     auxiliary scalar or none), and a pattern has a fixed number of both.
     `with_aux` matches lambda_bal != 0 (with or without a z-loss); a z-loss
     alone has no rule."""
@@ -492,18 +494,25 @@ def _experts_pattern(use_bias, gated, with_aux, div=None, shared=False):
         ne.update(shared_hidden_size=0)
     else:
         eq.update(shared_hidden_size=0)
+    if latent:
+        ne.update(latent_size=None)
     pattern = _attr_pattern(
         OperatorType.EXPERTS, eq=eq, div=div, ne=ne or None
     )
     num_w = 4 if gated else (5 if use_bias else 3)
     if shared:
         num_w += 1 + (3 if gated else 2)
+    if latent:
+        num_w += 2
     return pattern, num_w, 2 if with_aux else 1
 
 
-def _experts_tag(use_bias, gated, with_aux, shared=False):
+def _experts_tag(use_bias, gated, with_aux, shared=False, latent=False):
     form = "g" if gated else ("b" if use_bias else "nb")
-    return f"{form}{'_sh' if shared else ''}{'_aux' if with_aux else ''}"
+    return (
+        f"{form}{'_sh' if shared else ''}{'_lat' if latent else ''}"
+        f"{'_aux' if with_aux else ''}"
+    )
 
 
 def data_parallel_state_space_rule(degree: int) -> Substitution:
@@ -539,7 +548,7 @@ def data_parallel_state_space_rule(degree: int) -> Substitution:
 
 def data_parallel_experts_rule(
     degree: int, use_bias: bool, gated: bool = False, with_aux: bool = False,
-    shared: bool = False,
+    shared: bool = False, latent: bool = False,
 ) -> Substitution:
     """Experts(x, gate, w...) -> Combine_0(Experts(Repartition_0(x),
     Replicate(gate), Replicate(w)...)): sample parallelism for the MoE FFN.
@@ -550,7 +559,7 @@ def data_parallel_experts_rule(
     expert-parallel rule the auxiliary output is found structurally, not
     interface-mapped."""
     attr_pattern, num_w, num_out = _experts_pattern(
-        use_bias, gated, with_aux, shared=shared
+        use_bias, gated, with_aux, shared=shared, latent=latent
     )
     p = PCGPattern()
     a = p.add_input(_shard_pattern(0, degree))
@@ -570,7 +579,7 @@ def data_parallel_experts_rule(
     _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [youts[0]])
     return Substitution(
         f"data_parallel_experts_"
-        f"{_experts_tag(use_bias, gated, with_aux, shared)}_{degree}",
+        f"{_experts_tag(use_bias, gated, with_aux, shared, latent)}_{degree}",
         p,
         og,
         ((a, oa), *zip(ws, ows)),
@@ -1122,6 +1131,10 @@ def generate_parallelization_rules(
                 )
         # the bias-balanced form: a selection bias and a shared expert
         rules.append(data_parallel_experts_rule(k, False, shared=True))
+        # and the same with its experts in a latent space
+        rules.append(
+            data_parallel_experts_rule(k, False, shared=True, latent=True)
+        )
         # branch parallelism over stacked isomorphic branches
         # (compiler/branch_stacking.py): shard the stacked leading axis,
         # merge via local sum + Reduction

@@ -272,7 +272,7 @@ def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
     for other in (
         dict(heads=4, head_dim=8, groups=2, state=16, chunk=8),  # the toy
         dict(chunk=8), dict(chunk=192), dict(state=64), dict(head_dim=32),
-        dict(groups=4),  # 16 heads of 64 a group: 1024 columns a program
+        dict(groups=2),  # 32 heads of 64 a group: 2048 columns a program
         dict(heads=8),  # one head a group: 64 columns
     ):
         assert scan_route(**dict(cell, **other)) == "xla", other

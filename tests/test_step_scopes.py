@@ -123,6 +123,19 @@ def test_scope_name_survives_the_name_stack(layer_name, want):
          ("bwd", "ssm", "m0/scan")),
         ("jit(_step)/jvp(ff.ssm.m0)/dot_general", ("fwd", "ssm", "m0")),
         ("jit(_step)/jvp(ff.dense.d)/scan/mul", ("fwd", "dense", "d")),
+        # an experts node's parts (`trace.NODE_PARTS`) keep theirs too
+        ("jit(_step)/jvp(ff.experts.moe1)/router/dot_general",
+         ("fwd", "experts", "moe1/router")),
+        ("jit(_step)/transpose(jvp(ff.experts.moe1))/latent/dot_general",
+         ("bwd", "experts", "moe1/latent")),
+        ("jit(_step)/jvp(ff.experts.moe1)/routed/grouped_matmul/gmm/pallas_call",
+         ("fwd", "experts", "moe1/routed")),
+        ("jit(_step)/transpose(jvp(ff.experts.moe1))/shared/shared_expert/mul",
+         ("bwd", "experts", "moe1/shared")),
+        # a scope that only begins like a part, and a part of another kind
+        ("jit(_step)/jvp(ff.experts.moe1)/shared_expert/mul",
+         ("fwd", "experts", "moe1")),
+        ("jit(_step)/jvp(ff.experts.moe1)/scan/mul", ("fwd", "experts", "moe1")),
         ("params['n3']", ("unattributed", "", "")),
         ("jit(_step)/jvp(diff.dense.x)/mul", ("unattributed", "", "")),
         ("", ("unattributed", "", "")),
