@@ -36,6 +36,11 @@ rows that are sorted, gathered, multiplied and combined are the tokens'
 latent images `x w_down`, in every form above; the router and the shared
 expert read x itself, and `w_up` meets the combined float32 rows once.
 
+On the routing path no value is fetched, counted or sent back one element
+at a time: the chosen scores are picked from the `[N, E]` scores, and the
+decisions counted per expert, by comparison with an iota and a reduction
+(`_pick_columns`, `_count_keys`), and the ranking gives indices only.
+
 The node's parts go under scopes of their own inside the node's
 (`ff.experts.<name>/router`, `/latent`, `/routed`, `/shared`;
 `observability/trace.NODE_PARTS`), so a trace reader can tell them apart.
@@ -190,32 +195,62 @@ def _grouped_matmul(rows, w, group_sizes, pallas: bool, group_offset=None):
     )
 
 
+# XLA's TPU gather and scatter move one element at a time: 8.8-10.2 ns each
+# on a v5e whatever the element (a float of `[4096, 512]`, a one into 9
+# bins; PERF.md section 5). Comparing an index with an iota and reducing is
+# dense VPU work that XLA keeps in one fusion each way, 1.0 ps a compared
+# element at the widest router here (512; the others 9 to 128), and exact at
+# any width (PERF.md section 6, PR 40, has the widths at which the two tie).
+
+
+def _pick_columns(values: jnp.ndarray, index: jnp.ndarray) -> jnp.ndarray:
+    """values[n, index[n, j]]: [N, E], [N, k] -> [N, k], the k columns of a
+    row distinct: `sum_e where(index[n, j] == e, values[n, e], 0)`, one term
+    of which is not zero, so the sum is the picked value to the bit, and
+    autodiff's transpose `sum_j where(index[n, j] == e, g[n, j], 0)` is the
+    gather's scatter-add to the bit because a row's columns are distinct."""
+    e = values.shape[-1]
+    hit = index[:, :, None] == lax.broadcasted_iota(index.dtype, (1, 1, e), 2)
+    return jnp.sum(jnp.where(hit, values[:, None, :], 0), axis=-1)
+
+
+def _count_keys(keys: jnp.ndarray, bins: int) -> jnp.ndarray:
+    """How many of `keys` [M] fall in each of `bins` bins, [bins] int32:
+    `sum_i (keys[i] == b)`, so a key outside the bins is counted in none."""
+    hit = keys[None, :] == lax.broadcasted_iota(keys.dtype, (bins, 1), 0)
+    return jnp.sum(hit, axis=1, dtype=jnp.int32)
+
+
 def route(attrs: ExpertsAttrs, x2: jnp.ndarray, gate_w: jnp.ndarray,
           select_bias=None):
     """The router, in float32 whatever x2's dtype: (logits [N, E],
     probabilities or sigmoid scores [N, E], selected experts [N, k], their
     weights [N, k]). `select_bias` [E] (sigmoid scoring) moves the choice
-    and nothing else, and takes no gradient."""
+    and nothing else, and takes no gradient. The ranking gives indices only
+    (no JVP of `top_k` is built); the weights are picked from the scores by
+    `_pick_columns`."""
     logits = x2.astype(jnp.float32) @ gate_w.astype(jnp.float32)
     if attrs.scoring == "sigmoid":
-        scores = jax.nn.sigmoid(logits)
-        choice = scores
+        scores = choice = jax.nn.sigmoid(logits)
         if select_bias is not None:
-            choice = scores + lax.stop_gradient(
-                select_bias.astype(jnp.float32)
-            )
-        _, topi = lax.top_k(choice, attrs.num_select)
-        topv = jnp.take_along_axis(scores, topi, axis=-1)
+            choice = scores + select_bias.astype(jnp.float32)
+    else:
+        scores = choice = jax.nn.softmax(logits, axis=-1)
+    _, topi = lax.top_k(lax.stop_gradient(choice), attrs.num_select)
+    topv = _pick_columns(scores, topi)
+    if attrs.renormalize:
+        # a buffer of their own, as the gather's were: fused into the pick,
+        # the sum over k adds its terms in another order
+        topv = lax.optimization_barrier(topv)
+    if attrs.scoring == "sigmoid":
         if attrs.renormalize:
             topv = topv / (topv.sum(axis=-1, keepdims=True) + 1e-20)
         return logits, scores, topi, topv * attrs.routed_scale
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = lax.top_k(probs, attrs.num_select)
     if attrs.renormalize:
         topv = topv / topv.sum(axis=-1, keepdims=True)
     if attrs.routed_scale != 1.0:
         topv = topv * attrs.routed_scale
-    return logits, probs, topi, topv
+    return logits, scores, topi, topv
 
 
 def experts_forward(
@@ -274,7 +309,7 @@ def experts_forward(
                 routing.record(here, n * k, windows)
             counts = None
             if attrs.lambda_bal > 0:
-                counts = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+                counts = _count_keys(flat_e, e)
         else:
             out, counts = _all_rows_forward(
                 attrs, z2, flat_e, topv, w1, w3, b1, w2, b2, pallas
@@ -299,7 +334,7 @@ def _all_rows_forward(attrs, x2, flat_e, topv, w1, w3, b1, w2, b2, pallas):
     # -- dispatch: decisions in (token, select) order, sorted by expert ----
     order = jnp.argsort(flat_e, stable=True)
     inverse = jnp.argsort(order)  # decision -> its row after the sort
-    counts = jnp.zeros((e,), jnp.int32).at[flat_e].add(1)
+    counts = _count_keys(flat_e, e)
     if attrs.capacity_factor is not None:
         cap = expert_capacity(n, e, k, attrs.capacity_factor)
         first_row = jnp.cumsum(counts) - counts
@@ -508,7 +543,7 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     local = flat_e - first
     key = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(key, stable=True)  # the share's decisions first
-    counts = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+    counts = _count_keys(key, held + 1)
     flat_w = topv.reshape(-1)
     if attrs.capacity_factor is not None:
         # a decision's rank within its expert is "earlier tokens first"

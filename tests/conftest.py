@@ -26,6 +26,7 @@ os.environ["XLA_FLAGS"] = (
 ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -40,3 +41,16 @@ assert all(d.platform == "cpu" for d in jax.devices()), jax.devices()
 assert len(jax.devices()) == 8, jax.devices()
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+
+@pytest.fixture
+def top_k_jvp_refused(monkeypatch):
+    """Until the test ends, differentiating `top_k`'s values raises: JAX
+    does it by a gather of single elements, whose transpose is a scatter-add
+    (`kernels/moe.route` ranks a `stop_gradient` and uses the indices)."""
+    from jax.interpreters import ad
+
+    def refuse(primals, tangents, **params):
+        raise AssertionError("top_k was differentiated")
+
+    monkeypatch.setitem(ad.primitive_jvps, jax.lax.top_k_p, refuse)

@@ -11,11 +11,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flexflow_tpu.kernels import moe as moe_kernels
 from flexflow_tpu.kernels.moe import (
     aggregate_forward,
     dispatch_mask,
     experts_forward,
     group_by_forward,
+    route,
 )
 from flexflow_tpu.op_attrs.core import (
     get_incoming_tensor_roles,
@@ -118,6 +120,153 @@ def test_experts_matches_per_token_reference():
     (out,) = experts_forward(attrs, x, weights)
     ref = _dense_moe_reference(attrs, x, weights)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-4, atol=1e-5)
+
+
+# -- the router's pick and the histograms, by comparison ------------------------
+
+
+def route_by_gather(attrs, x2, gate_w, select_bias=None):
+    """`route` as it stood before `_pick_columns`: the sigmoid scores
+    fetched by `take_along_axis`, the softmax probabilities by `top_k`'s own
+    values (which JAX differentiates by gather). The same arithmetic around
+    them, so every output and gradient is equal to the bit."""
+    logits = x2.astype(jnp.float32) @ gate_w.astype(jnp.float32)
+    if attrs.scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores
+        if select_bias is not None:
+            choice = scores + jax.lax.stop_gradient(
+                select_bias.astype(jnp.float32)
+            )
+        _, topi = jax.lax.top_k(choice, attrs.num_select)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+        if attrs.renormalize:
+            topv = topv / (topv.sum(axis=-1, keepdims=True) + 1e-20)
+        return logits, scores, topi, topv * attrs.routed_scale
+    probs = jax.nn.softmax(logits, axis=-1)
+    topv, topi = jax.lax.top_k(probs, attrs.num_select)
+    if attrs.renormalize:
+        topv = topv / topv.sum(axis=-1, keepdims=True)
+    if attrs.routed_scale != 1.0:
+        topv = topv * attrs.routed_scale
+    return logits, probs, topi, topv
+
+
+def traces_to(primitive, fn, *args):
+    """Whether `fn`'s jaxpr binds `primitive`, nested jits included."""
+    return f" {primitive}[" in str(jax.make_jaxpr(fn)(*args))
+
+
+@pytest.mark.parametrize(
+    "scoring, experts, select, renormalize",
+    [
+        ("sigmoid", 512, 22, True),  # nemotron-3-super
+        ("sigmoid", 128, 6, True),  # nemotron-twotower
+        ("softmax", 64, 8, True),
+        ("softmax", 64, 8, False),  # olmoe
+        ("sigmoid", 4097, 6, True),  # wider than any router here
+        ("softmax", 4097, 6, False),
+    ],
+)
+def test_router_picks_by_comparison_what_the_gather_fetched(
+    scoring, experts, select, renormalize
+):
+    """The chosen experts, their weights and the gradients of x, of the
+    router's matrix and (by the helper alone) of the scores, equal to the
+    bit, with tied scores in every row: columns 1, 2 and 7 of the router
+    repeat column 0, bias included. At any width, and with no gather."""
+    rs = np.random.RandomState(experts + select)
+    tokens, d = 24, 16
+    x = jnp.asarray(rs.randn(tokens, d), jnp.float32)
+    gate = rs.randn(d, experts).astype(np.float32)
+    bias = (0.2 * rs.randn(experts)).astype(np.float32)
+    # a column that is ranked wherever its logit is positive, or by its bias
+    gate[:, 0] *= 4
+    bias[0] = 1.0
+    for tied in (1, 2, 7):
+        gate[:, tied], bias[tied] = gate[:, 0], bias[0]
+    gate = jnp.asarray(gate)
+    bias = jnp.asarray(bias) if scoring == "sigmoid" else None
+    attrs = ExpertsAttrs(
+        experts, select, 8, scoring=scoring, renormalize=renormalize,
+        selection_bias=bias is not None,
+        routed_scale=2.5 if scoring == "sigmoid" else 1.0,
+    )
+    mix = jnp.asarray(rs.randn(tokens, select), jnp.float32)
+
+    def weighed(router):
+        def loss(x, gate):
+            _, scores, topi, topv = router(attrs, x, gate, bias)
+            return jnp.sum(topv * mix), (scores, topi, topv)
+
+        return jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)(x, gate)
+
+    (_, (scores, topi, topv)), grads = weighed(route)
+    (_, (_, want_i, want_v)), want_grads = weighed(route_by_gather)
+    np.testing.assert_array_equal(np.asarray(topi), np.asarray(want_i))
+    np.testing.assert_array_equal(np.asarray(topv), np.asarray(want_v))
+    for got, want in zip(grads, want_grads):
+        assert float(jnp.max(jnp.abs(want))) > 0
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # a tie was ranked in some row, or the case does not test one
+    assert np.isin(np.asarray(topi), (0, 1, 2, 7)).sum(axis=-1).max() >= 2
+
+    def pick(values):
+        return moe_kernels._pick_columns(values, topi)
+
+    def fetch(values):
+        return jnp.take_along_axis(values, topi, axis=-1)
+
+    got, pulled = jax.vjp(pick, scores)
+    want, want_pulled = jax.vjp(fetch, scores)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(
+        np.asarray(pulled(mix)[0]), np.asarray(want_pulled(mix)[0])
+    )
+    assert not traces_to("gather", pick, scores)
+
+
+def test_ranking_the_experts_builds_no_jvp_of_top_k(top_k_jvp_refused):
+    """Only the indices of `top_k` are used, of a `stop_gradient`: the rule
+    that differentiates its values is never called, whichever the scoring."""
+    rs = np.random.RandomState(3)
+    x = jnp.asarray(rs.randn(8, 16), jnp.float32)
+    gate = jnp.asarray(rs.randn(16, 12), jnp.float32)
+    for scoring in ("sigmoid", "softmax"):
+        attrs = ExpertsAttrs(12, 3, 8, scoring=scoring, renormalize=True)
+        grad = jax.grad(lambda x: jnp.sum(route(attrs, x, gate)[3] ** 2))(x)
+        assert float(jnp.max(jnp.abs(grad))) > 0
+
+
+@pytest.mark.parametrize(
+    "case, bins",
+    [
+        ("spread", 9), ("empty_share", 9), ("one_bin", 9), ("overflow", 9),
+        ("spread", 64), ("spread", 512), ("spread", 4097),
+    ],
+)
+def test_keys_counted_by_comparison_equal_the_scatter_add(case, bins):
+    """`held + 1` bins of a share (the last takes every decision that
+    landed elsewhere), or a router's E: a share nothing reached, one expert
+    taking everything, and a key past the bins, which neither form counts."""
+    rs = np.random.RandomState(bins)
+    keys = {
+        "spread": rs.randint(0, bins, size=1000),
+        "empty_share": np.full(1000, bins - 1),
+        "one_bin": np.full(1000, 3),
+        "overflow": np.where(rs.rand(1000) < 0.1, bins, rs.randint(0, bins, 1000)),
+    }[case].astype(np.int32)
+    keys = jnp.asarray(keys)
+
+    def count(keys):
+        return moe_kernels._count_keys(keys, bins)
+
+    got = count(keys)
+    want = jnp.zeros((bins,), jnp.int32).at[keys].add(1)
+    assert got.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert int(got.sum()) == int(jnp.sum(keys < bins))
+    assert not traces_to("scatter-add", count, keys)
 
 
 def test_experts_shapes_roles_and_aux():

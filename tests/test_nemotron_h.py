@@ -286,21 +286,79 @@ def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
         assert scan_route(**dict(cell, batch=4)) == "xla"
 
 
-@functools.cache
-def lowered_tower_step(kernels: bool) -> str:
-    """The StableHLO of the step of the five-layer tower at the kernel
-    widths (four heads a group, so that a group's `[256, 128]` state is no
-    `[128, 128]` tensor), lowered for the TPU platform as
-    `tools/lowered_step_text.py` lowers a cell's. `kernels`: the caller has
-    told the kernel gates that a TPU is there."""
+def traced_step(model):
+    """The model's train step traced against example arguments, as
+    `tools/lowered_step_text.py` traces a cell's (`jax.stages.Traced`)."""
     from flexflow_tpu.analysis import lowering
 
-    sizes = dict(KERNEL_TOY, mamba_num_heads=8)
-    model = compiled_model(256, jnp.bfloat16, sizes=sizes, max_devices=1)
     example = lowering.step_example_args_cg(model.instance, model.loss_attrs)
     return model.instance.compiled_step().trace(
         model.params, model.opt_state, *example
-    ).lower(lowering_platforms=("tpu",)).as_text()
+    )
+
+
+def kernel_tower():
+    """The five-layer tower at the kernel widths (four heads a group, so
+    that a group's `[256, 128]` state is no `[128, 128]` tensor)."""
+    sizes = dict(KERNEL_TOY, mamba_num_heads=8)
+    return compiled_model(256, jnp.bfloat16, sizes=sizes, max_devices=1)
+
+
+@functools.cache
+def lowered_tower_step(kernels: bool) -> str:
+    """The StableHLO of `kernel_tower`'s step, lowered for the TPU
+    platform. `kernels`: the caller has told the kernel gates that a TPU is
+    there."""
+    return traced_step(kernel_tower()).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+
+
+def element_moves(traced):
+    """{(primitive, operand's shape)} of every gather and scatter of a traced
+    step under an expert node's scope that moves ONE element an index (a gather whose
+    slice, a scatter whose update window, is a single element): what XLA's
+    TPU backend runs an element at a time. Whole rows (`x2[token]`,
+    `out.at[token].add`) are not among them."""
+    from jax.extend import core as jex
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            stack = f"{outer}/{eqn.source_info.name_stack}"
+            yield eqn, stack
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) else [value]:
+                    if isinstance(sub, jex.ClosedJaxpr):
+                        sub = sub.jaxpr
+                    if isinstance(sub, jex.Jaxpr):
+                        yield from walk(sub, stack)
+
+    moves = set()
+    for eqn, stack in walk(traced.jaxpr.jaxpr, ""):
+        name = eqn.primitive.name
+        if "ff.experts" not in stack or not name.startswith(("gather", "scatter")):
+            continue
+        one = (
+            set(eqn.params["slice_sizes"]) == {1} if name == "gather"
+            else not eqn.params["dimension_numbers"].update_window_dims
+        )
+        if one:
+            moves.add((name, eqn.invars[0].aval.shape))
+    return moves
+
+
+def test_step_moves_no_routing_value_an_element_at_a_time(top_k_jvp_refused):
+    """Under the expert nodes' scopes the step picks the chosen scores and
+    counts the share's keys by comparison: no `[N, E]` gather, none
+    transposed to a scatter, no histogram by scatter-add, and no JVP of
+    `top_k` (the step is traced anew here, with the rule refusing). What is
+    left an element at a time is a WINDOW of the 1,536 decisions: the
+    window's decisions and weights read from the sorted order, and the
+    weights' gradient going back."""
+    decisions = (BATCH * 256 * KERNEL_TOY["num_experts_per_tok"],)
+    assert element_moves(traced_step(kernel_tower())) == {
+        ("gather", decisions), ("scatter-add", decisions),
+    }
 
 
 def test_lowered_step_holds_the_scan_kernels_and_no_mask_tensor(monkeypatch):

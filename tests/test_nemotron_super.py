@@ -468,37 +468,49 @@ def test_data_parallel_rule_matches_the_latent_form_only():
     assert op_attrs_satisfy_pattern(tower_tests.experts_attrs((4, 4)), without)
 
 
-# -- without a latent size the programs are the parent's -------------------------
+# -- the toy steps' programs, pinned ----------------------------------------------
 
-# sha256 of the toy steps' StableHLO, lowered for the TPU platform, as the
-# commit before this attribute existed (9399094) lowers them: the text holds
-# no Mosaic kernel at these widths, so no source location either. A PR that
-# means to change these programs writes its own hashes here.
-PARENT_TOY_STEPS = {
-    "olmoe": "6a1ab18a67eafefd86c74808de3836f085f313606183753ba93daa5eccff94a3",
-    "twotower": "a277837425bd7453f3b7599b6778180414adcf60bc17200fc1417e201cd6142e",
+# sha256 of the toy steps' StableHLO, lowered for the TPU platform: the text
+# holds no Mosaic kernel at these widths, so no source location either. PR 39
+# pinned the two older ones to show that `latent_size=None` is the op it was;
+# PR 40 (the router's pick and the histograms by comparison, the picked
+# weights behind a barrier where they are renormalised) changed all three
+# programs and wrote its own. A PR that means to change them does too.
+PINNED_TOY_STEPS = {
+    "olmoe": "ba9e83a700b28d5db0dcecc91e1189c8156340b2c7c11118e46c7dd09e8c7c49",
+    "twotower": "e94ce1683a7fe1828dc15b086a2a7c08217ffb32583c5ab1e117c156d5f51214",
+    "super": "3f461ff501eae871185410eee2384efc5cb81b2d924079d068e738224e0e0d02",
 }
 
 
-def _lowered_toy_step(which):
-    from flexflow_tpu.analysis import lowering
-
+def _toy_model(which):
     if which == "olmoe":
         import test_olmoe
 
-        model = test_olmoe.compiled_model(jnp.bfloat16, max_devices=1)
-    else:
-        model = tower_tests.compiled_model(32, jnp.bfloat16, max_devices=1)
-    example = lowering.step_example_args_cg(model.instance, model.loss_attrs)
-    return model.instance.compiled_step().trace(
-        model.params, model.opt_state, *example
-    ).lower(lowering_platforms=("tpu",)).as_text()
+        return test_olmoe.compiled_model(jnp.bfloat16, max_devices=1)
+    if which == "twotower":
+        return tower_tests.compiled_model(32, jnp.bfloat16, max_devices=1)
+    return compiled_model(32, jnp.bfloat16, max_devices=1)
 
 
-@pytest.mark.parametrize("which", sorted(PARENT_TOY_STEPS))
-def test_no_latent_size_lowers_to_the_parents_text(which):
-    text = _lowered_toy_step(which)
-    assert hashlib.sha256(text.encode()).hexdigest() == PARENT_TOY_STEPS[which]
+@pytest.mark.parametrize("which", sorted(PINNED_TOY_STEPS))
+def test_toy_steps_lower_to_the_pinned_text(which):
+    text = tower_tests.traced_step(_toy_model(which)).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == PINNED_TOY_STEPS[which]
+
+
+def test_step_moves_no_routing_value_an_element_at_a_time(top_k_jvp_refused):
+    """The tower's test (`test_nemotron_h.py`) on the latent-expert step:
+    of the `[N, 16]` scores nothing is fetched or sent back by index, the
+    share's five bins are counted by comparison, `top_k` has no JVP; the
+    element moves left are a window of the decisions."""
+    decisions = (BATCH * 32 * TOY["num_experts_per_tok"],)
+    traced = tower_tests.traced_step(_toy_model("super"))
+    assert tower_tests.element_moves(traced) == {
+        ("gather", decisions), ("scatter-add", decisions),
+    }
 
 
 # -- the benchmark's CPU rehearsal of the cell ---------------------------------
