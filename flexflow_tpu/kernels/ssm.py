@@ -1,6 +1,6 @@
 """The state-space mixer's kernels (`StateSpaceAttrs`): the short causal
-depthwise convolution, the selective scan in its chunked ("SSD") form, and
-the gated grouped RMS norm.
+depthwise convolution with its SiLU (`conv_silu`), the selective scan in its
+chunked ("SSD") form, and the gated grouped RMS norm (`gated_group_norm`).
 
 The recurrence, per head h (group g = h // (heads / groups)) and position t:
 
@@ -54,9 +54,33 @@ the order of the floating-point sums differs between them:
 
 In neither form does a state per position ever exist.
 
-The scan's device operations go under a `scan` scope inside the node's
-(`ff.ssm.<name>/scan`), the backward's as the transpose of it, so a trace
-reader can tell the scan from the projections.
+The two elementwise stages around the scan, `conv_silu` and
+`gated_group_norm`, are plain JAX in one form for every route, each with a
+WRITTEN backward (`jax.custom_vjp`), because what JAX's own transpose keeps
+and writes is the cost (PR 41; a node at [4096, 6144] / [4096, 4096] moved
+3.1 GB through these passes where 0.8 would do). Kept for the backward are
+their operands in the step's dtype (x, the taps and the bias; y, z and the
+gain) and the norm's reciprocal roots, one [rows, 1] column a run: no
+float32 tensor of the row's width, not the pre-activation, not the gate. The
+backwards recompute those in float32 inside the fusion that needs them.
+Float32 accumulation throughout; a rounding to the step's dtype only where
+the forward rounds (after the convolution, after SiLU, after the norm) and,
+in the convolution's backward, once between SiLU's derivative and the
+mirrored convolution, where the transposed casts round too.
+
+The node's parts go under scopes of their own inside the node's
+(`ff.ssm.<name>/scan`, `/conv`, `/norm`; `observability/trace.NODE_PARTS`),
+the backward's as the transpose of each, so a trace reader can tell the
+scan, the convolution and the norm from the projections.
+
+Two `optimization_barrier`s say what XLA's fusion heuristics get wrong here
+(my chip runs, PR 41). The convolution's backward recomputes the
+pre-activation from x behind one, or XLA shares the forward's and has the
+forward WRITE it for the backward. The norm's result passes one, or XLA
+recomputes the normalised rows (a sigmoid and the runs' selects an element)
+inside every tile of the two matmuls that read them, the output projection
+and its weight gradient: 1.9 ms a step of `twotower30b_s4096_1chip` in the
+matmuls' rows for 0.5 saved in the norm's.
 """
 
 from __future__ import annotations
@@ -73,39 +97,178 @@ from jax.experimental.pallas import tpu as pltpu
 from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 
 
-def causal_depthwise_conv(x, weight, bias):
-    """x [b, s, f], weight [taps, f], bias [f]: y_t = bias + sum_k
-    weight[k] * x_{t - (taps - 1) + k}, zeros before the first position (the
-    causal conv1d of the model codes, `groups = channels`, `padding =
-    taps - 1` cut back to s). Four shifted multiply-adds in float32."""
-    taps = weight.shape[0]
+def _shifted(x, taps: int, mirrored: bool = False):
+    """The `taps` shifted copies of x [b, s, f] that a causal convolution
+    reads, in float32: x_{t - (taps - 1) + k} for k = 0 .. taps - 1, zeros
+    before the first position; `mirrored`, x_{t + (taps - 1) - k}, zeros
+    after the last. x is padded in its own dtype and converted slice by
+    slice INSIDE the fusion that reads them, so no padded float32 copy of x
+    ever exists."""
     s = x.shape[1]
-    padded = jnp.pad(x.astype(jnp.float32), ((0, 0), (taps - 1, 0), (0, 0)))
+    padded = jnp.pad(x, ((0, 0), (0, taps - 1) if mirrored else (taps - 1, 0), (0, 0)))
+    return [
+        lax.dynamic_slice_in_dim(
+            padded, taps - 1 - k if mirrored else k, s, axis=1
+        ).astype(jnp.float32)
+        for k in range(taps)
+    ]
+
+
+def _conv_taps(x, weight, bias, mirrored: bool = False):
+    """bias + sum_k weight[k] * x_{t - (taps - 1) + k} in float32: the
+    causal depthwise conv1d of the model codes (`groups = channels`,
+    `padding = taps - 1` cut back to s); `mirrored`, its transpose in x.
+    Four shifted multiply-adds either way."""
     w = weight.astype(jnp.float32)
     y = bias.astype(jnp.float32)
-    for k in range(taps):
-        y = y + w[k] * lax.dynamic_slice_in_dim(padded, k, s, axis=1)
-    return y.astype(x.dtype)
+    for k, x_k in enumerate(_shifted(x, len(weight), mirrored)):
+        y = y + w[k] * x_k
+    return y
 
 
+def _silu_slope(a):
+    """(silu(a), silu'(a)) of a float32 a: s = sigmoid(a), a s and
+    s (1 + a (1 - s))."""
+    sig = jax.nn.sigmoid(a)
+    return a * sig, sig * (1.0 + a * (1.0 - sig))
+
+
+@jax.custom_vjp
+def conv_silu(x, weight, bias):
+    """silu(causal_depthwise_conv(x)): x [b, s, f], weight [taps, f], bias
+    [f] -> [b, s, f] in x's dtype. The convolution accumulates in float32
+    and is rounded to x's dtype, SiLU is taken of that in float32 and
+    rounded again: one pass over x, one output.
+
+    The backward is written because JAX's own keeps the wrong things: the
+    transpose of `w[k] * slice(pad(float32(x)))` keeps the padded float32
+    copy of x, and hands the input's gradient back as one float32
+    [b, s, f] tensor a tap, added up in a further pass (1,240 MB a node at
+    [4096, 6144] for a least of 150; described-chip compile, PR 41). Kept
+    here: x, weight, bias. The backward recomputes the pre-activation
+    beside SiLU's derivative, rounds `dy silu'(a)` to x's dtype ONCE (where
+    the transposed casts rounded it too), and takes the input's gradient as
+    the mirrored convolution of that and the weight's and the bias's as
+    reductions over the same operands."""
+    return _conv_silu_fwd(x, weight, bias)[0]
+
+
+def _conv_silu_fwd(x, weight, bias):
+    a = _conv_taps(x, weight, bias).astype(x.dtype)
+    return jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype), (x, weight, bias)
+
+
+def _conv_silu_bwd(kept, dy):
+    x, weight, bias = kept
+    f32 = jnp.float32
+    # the forward computes the same pre-activation: without the barrier XLA
+    # shares it, which is the forward WRITING it for the backward to read
+    x = lax.optimization_barrier(x)
+    a = _conv_taps(x, weight, bias).astype(x.dtype).astype(f32)
+    ds = (dy.astype(f32) * _silu_slope(a)[1]).astype(x.dtype)
+    dx = _conv_taps(ds, weight, jnp.zeros((), f32), mirrored=True)
+    dsf = ds.astype(f32)
+    dw = jnp.stack(
+        [jnp.sum(dsf * x_k, axis=(0, 1)) for x_k in _shifted(x, len(weight))]
+    )
+    db = jnp.sum(dsf, axis=(0, 1))
+    return dx.astype(x.dtype), dw.astype(weight.dtype), db.astype(bias.dtype)
+
+
+conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def _run_of_column(ndim: int, inner: int, groups: int):
+    """Which of `groups` equal runs each of `inner` columns lies in, shaped
+    to broadcast against a [.., inner] tensor of `ndim` dims."""
+    shape = (1,) * (ndim - 1) + (inner,)
+    return lax.broadcasted_iota(jnp.int32, shape, ndim - 1) // (inner // groups)
+
+
+def _run_sums(t, groups: int):
+    """[.., inner] float32 -> `groups` [.., 1] columns: the sum over each of
+    `groups` equal runs of the last dim, every run a masked sum over the
+    whole row. XLA fuses `t`'s producers into the one reduction that gives
+    all of them, so `t` itself never exists. A run is never a dimension of
+    its own (the reshape re-tiles the row on a TPU: two float32 copies a
+    pass, PR 33) and never a slice either: slices of a float32 product are
+    what makes XLA write the product to HBM first (PR 41)."""
+    if groups == 1:
+        return [jnp.sum(t, axis=-1, keepdims=True)]
+    run_of = _run_of_column(t.ndim, t.shape[-1], groups)
+    return [
+        jnp.sum(jnp.where(run_of == k, t, 0.0), axis=-1, keepdims=True)
+        for k in range(groups)
+    ]
+
+
+def _over_runs(columns, inner: int):
+    """`groups` [.., 1] columns -> [.., inner] (or a column that broadcasts
+    to it): each run's value on its own columns, by selects, inside whatever
+    fusion reads it. The runs are never glued back together: a
+    `concatenate` of [rows, width] pieces is `groups` in-place updates of a
+    float32 [rows, inner] tensor in HBM (PR 41)."""
+    run_of = _run_of_column(columns[0].ndim, inner, len(columns))
+    out = columns[-1]
+    for k in reversed(range(len(columns) - 1)):
+        out = jnp.where(run_of == k, columns[k], out)
+    return out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def gated_group_norm(y, z, gain, groups: int, eps: float):
     """rms_norm(y * silu(z)) with the mean of squares over each of `groups`
-    equal runs of the last dim, in float32; the result in y's dtype. A run
-    is a slice of the row, not a dimension of its own: a reshape to
-    [.., groups, width] re-tiles the row on a TPU, which XLA pays with
-    copies of the whole float32 tensor in both passes (0.67 ms a layer at
-    [4096, 8 x 512] beside the scan's kernels; my chip run, PR 33)."""
-    g = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    width = g.shape[-1] // groups
-    runs = [g[..., k * width:(k + 1) * width] for k in range(groups)]
-    g = jnp.concatenate(
-        [
-            run * lax.rsqrt(jnp.mean(jnp.square(run), axis=-1, keepdims=True) + eps)
-            for run in runs
-        ],
-        axis=-1,
-    )
-    return (g * gain.astype(jnp.float32)).astype(y.dtype)
+    equal runs of the last dim, in float32; the result in y's dtype. Two
+    passes over y and z: the runs' sums of squares (`_run_sums`), then the
+    normalised rows, written once in y's dtype.
+
+    The backward is written because JAX's own keeps float32 [rows, inner]
+    products and re-assembles the runs' gradients with a `concatenate`
+    (490 + 520 MB a node at [4096, 4096] in 8 runs for leasts of 100 and
+    168; described-chip compile, PR 41). Kept here: y, z, gain and the
+    runs' reciprocal roots, [rows, 1] each; the gate is recomputed."""
+    return _gated_group_norm_fwd(y, z, gain, groups, eps)[0]
+
+
+def _gated_group_norm_fwd(y, z, gain, groups, eps):
+    f32 = jnp.float32
+    inner = y.shape[-1]
+    g = y.astype(f32) * jax.nn.silu(z.astype(f32))
+    roots = [
+        lax.rsqrt(total / (inner // groups) + eps)
+        for total in _run_sums(jnp.square(g), groups)
+    ]
+    out = (g * _over_runs(roots, inner) * gain.astype(f32)).astype(y.dtype)
+    # written once: without the barrier XLA recomputes the normalised rows
+    # (the gate's sigmoid, the runs' selects) inside every tile of the two
+    # matmuls that read them, the output projection and its weight gradient
+    return lax.optimization_barrier(out), (y, z, gain, roots)
+
+
+def _gated_group_norm_bwd(groups, eps, kept, d_out):
+    """With g = y silu(z), r a run's reciprocal root, n = g r and
+    d n = d_out gain: d g = r d n - g r^3 mean_run(d n g). One reduction
+    for the runs' sums of d n g, then d y and d z from y, z and d_out."""
+    y, z, gain, roots = kept
+    f32 = jnp.float32
+    inner = y.shape[-1]
+    yf, zf, df = y.astype(f32), z.astype(f32), d_out.astype(f32)
+    gate, slope = _silu_slope(zf)
+    g = yf * gate
+    dn = df * gain.astype(f32)
+    pulls = [
+        total * (r * r * r) / (inner // groups)
+        for total, r in zip(_run_sums(dn * g, groups), roots)
+    ]
+    spread = _over_runs(roots, inner)
+    dg = spread * dn - _over_runs(pulls, inner) * g
+    d_gain = jnp.sum(df * g * spread, axis=tuple(range(y.ndim - 1)))
+    dy = dg * gate
+    dz = dg * yf * slope
+    return dy.astype(y.dtype), dz.astype(z.dtype), d_gain.astype(gain.dtype)
+
+
+gated_group_norm.defvjp(_gated_group_norm_fwd, _gated_group_norm_bwd)
 
 
 def _scan_core(x, dt, a_log, b_mat, c_mat, chunk: int):
@@ -676,14 +839,15 @@ def state_space_forward(
     z = zxbcdt[..., :inner]
     xbc = zxbcdt[..., inner:inner + attrs.conv_width]
     dt = zxbcdt[..., inner + attrs.conv_width:]
-    xbc = causal_depthwise_conv(xbc, w_conv, b_conv)
-    xbc = jax.nn.silu(xbc.astype(jnp.float32)).astype(u.dtype)
+    with jax.named_scope("conv"):
+        xbc = conv_silu(xbc, w_conv, b_conv)
     x = xbc[..., :inner].reshape(b, s, heads, p)
     b_mat = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
     c_mat = xbc[..., inner + g * n:].reshape(b, s, g, n)
     dt = jax.nn.softplus(dt.astype(jnp.float32) + dt_bias.astype(jnp.float32))
     y = selective_scan(x, dt, a_log, b_mat, c_mat, d_skip, attrs.chunk_size)
-    y = gated_group_norm(
-        y.reshape(b, s, inner), z, gain, attrs.num_groups, attrs.norm_eps
-    )
+    with jax.named_scope("norm"):
+        y = gated_group_norm(
+            y.reshape(b, s, inner), z, gain, attrs.num_groups, attrs.norm_eps
+        )
     return y @ w_out

@@ -367,14 +367,15 @@ _SCOPE = re.compile(
     r"(?<![A-Za-z0-9_.\-])ff\.([a-z0-9_]+)(?:\.([A-Za-z0-9_.\-]+))?"
 )
 # a node may put a part of itself under a further scope inside its own
-# (`NODE_PARTS`: the state-space node its scan, `ff.ssm.<name>/scan`,
-# `kernels/ssm.py`; the experts node its router, latent projections, routed
-# experts and shared expert, `kernels/moe.py`): `parse_scope` keeps that in
-# the name, `<name>/<part>`, so that one table tells the scan from the node's
+# (`NODE_PARTS`: the state-space node its scan, its convolution with SiLU and
+# its gated norm, `ff.ssm.<name>/scan|conv|norm`, `kernels/ssm.py`; the
+# experts node its router, latent projections, routed experts and shared
+# expert, `kernels/moe.py`): `parse_scope` keeps that in the name,
+# `<name>/<part>`, so that one table tells the scan from the node's
 # projections and the router from the experts. The node's scope may be closed
 # by JAX's `jvp(...)` / `transpose(...)` before the part's begins.
 NODE_PARTS = {
-    "ssm": ("scan",),
+    "ssm": ("scan", "conv", "norm"),
     "experts": ("router", "latent", "routed", "shared"),
 }
 _PART = {

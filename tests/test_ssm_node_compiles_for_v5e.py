@@ -1,0 +1,227 @@
+"""ONE state-space node (`kernels/ssm.state_space_forward`), forward and
+backward in one program, compiled at the two `nemotron_h` cells' shapes for a
+DESCRIBED TPU v5e (the TPU's compiler is installed here; no chip is attached
+and nothing runs), and what XLA made of its two elementwise stages, read from
+the compiled program's ENTRY computation, where every instruction's result is
+a buffer (what a fusion keeps inside its body is not):
+
+- no float32 tensor of the convolution's width: `conv_silu` converts tap by
+  tap inside its fusions and keeps x in the step's dtype for the backward
+  (before PR 41 a padded float32 copy of x was written, kept, and four more
+  float32 tensors a node came back as the taps' gradients);
+- no `dynamic-update-slice` and no `concatenate`: `gated_group_norm` never
+  glues its runs back together (eight in-place updates of a float32
+  [rows, inner] tensor before);
+- at most one float32 [rows, inner] tensor (none today; the bound leaves the
+  compiler one): the norm's statistics come from masked sums that XLA fuses
+  the gate's product into, and its result is written once in the step's dtype.
+
+A compile that passes is not a chip run and says nothing of speed; the
+chip's numbers are in PERF.md. In the pattern of
+`test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
+pinned to the CPU, skipped only where the TPU's library is not installed.
+
+    python tests/test_ssm_node_compiles_for_v5e.py            # the JSON the tests read
+    python tests/test_ssm_node_compiles_for_v5e.py twotower   # the node's ENTRY
+        instructions of 4 MB or more, in schedule order, with operand and result
+        bytes (7 s; `--root <checkout>` lists another checkout's node)
+"""
+
+import importlib.util
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+ROWS = 4096
+# hidden size and `StateSpaceAttrs` (heads, head size, state, groups, taps,
+# chunk) of a node of each cell, batch 1 x 4,096 positions in bf16
+SHAPES = {
+    "twotower": (2688, (64, 64, 128, 8, 4, 128)),
+    "super": (4096, (16, 64, 128, 1, 4, 128)),
+}
+INVARIANTS = [
+    "compiles_with_the_scan_kernels",
+    "no_float32_of_the_convolutions_width",
+    "no_run_glued_back",
+    "at_most_one_float32_rows_by_inner",
+]
+_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
+_SHAPE = re.compile(r"\b(f32|bf16|s32|u32|pred|s8|u8)\[([0-9,]*)\]")
+_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)")
+_NO_BUFFER = ("parameter", "get-tuple-element", "tuple", "bitcast", "constant")
+
+
+def compiled_node(name):
+    """The compiled HLO text of one node's forward and backward at a cell's
+    shape, for the described chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from flexflow_tpu.kernels import flash_attention as fa
+    from flexflow_tpu.kernels import ssm
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    # `scan_route` asks the backend; nothing runs here, so say a TPU is there
+    fa._backend_ok = lambda allow_interpret=False: True
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    hidden, sizes = SHAPES[name]
+    attrs = StateSpaceAttrs(*sizes, 1e-5)
+
+    def on_chip(dims):
+        return jax.ShapeDtypeStruct(tuple(dims), jnp.bfloat16, sharding=chip)
+
+    u = on_chip((1, ROWS, hidden))
+    weights = [
+        on_chip(w.dims)
+        for w in attrs.weight_shapes(TensorShape((1, ROWS, hidden), DataType.FLOAT))
+    ]
+
+    def node(u, weights, cot):
+        y, vjp = jax.vjp(
+            lambda u, weights: ssm.state_space_forward(attrs, u, weights),
+            u, weights,
+        )
+        return y, vjp(cot)
+
+    return attrs, jax.jit(node).lower(u, weights, u).compile().as_text()
+
+
+def entry_instructions(text):
+    """[(name, result, opcode, operand names, line)] of the ENTRY
+    computation, in schedule order."""
+    entry = text[text.index("ENTRY"):]
+    entry = entry[: entry.index("\n}")]
+    rows = []
+    for line in entry.splitlines()[1:]:
+        m = _INSTRUCTION.match(line)
+        if m:
+            name, result, opcode, rest = m.groups()
+            operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
+            rows.append((name, result, opcode, operands, line))
+    return rows
+
+
+def shapes_of(result):
+    """[(dtype, dims)] of an instruction's result, a tuple's members each."""
+    return [
+        (m.group(1), tuple(int(d) for d in m.group(2).split(",") if d))
+        for m in _SHAPE.finditer(result)
+    ]
+
+
+def _nbytes(result):
+    total = 0
+    for dtype, dims in shapes_of(result):
+        n = _BYTES[dtype]
+        for d in dims:
+            n *= d
+        total += n
+    return total
+
+
+def check(name):
+    """{invariant: "ok" or what was found} for one cell's node."""
+    try:
+        attrs, text = compiled_node(name)
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        return dict.fromkeys(INVARIANTS, f"{type(e).__name__}: {e}"[:2000])
+    rows = [r for r in entry_instructions(text) if r[2] not in _NO_BUFFER]
+    kernels = text.count("tpu_custom_call")
+    wide, inner_f32 = [], []
+    for row_name, result, *_ in rows:
+        for dtype, dims in shapes_of(result):
+            if dtype != "f32" or len(dims) < 2 or dims[-2] < ROWS:
+                continue
+            if dims[-1] == attrs.conv_width:
+                wide.append(f"{row_name} f32{list(dims)}")
+            if dims[-1] == attrs.inner and dims[-2] == ROWS:
+                inner_f32.append(f"{row_name} f32{list(dims)}")
+    glued = [
+        r[0] for r in rows
+        if "dynamic-update-slice" in r[0] or "concatenate" in r[0]
+        or r[2] in ("dynamic-update-slice", "concatenate")
+    ]
+    return {
+        "compiles_with_the_scan_kernels": (
+            "ok" if kernels >= 3 else f"{kernels} kernels, want 3"
+        ),
+        "no_float32_of_the_convolutions_width": "ok" if not wide else ", ".join(wide),
+        "no_run_glued_back": "ok" if not glued else ", ".join(glued),
+        "at_most_one_float32_rows_by_inner": (
+            "ok" if len(inner_f32) <= 1 else ", ".join(inner_f32)
+        ),
+    }
+
+
+def listing(name, least=4e6):
+    """The node's ENTRY instructions that move `least` bytes or more."""
+    _, text = compiled_node(name)
+    rows = entry_instructions(text)
+    result_of = {r[0]: r[1] for r in rows}
+    lines, moved = [], 0
+    for row_name, result, opcode, operands, line in rows:
+        if opcode in _NO_BUFFER or opcode.endswith("-done"):
+            continue  # an asynchronous copy is counted at its start
+        read = sum(_nbytes(result_of.get(o, "")) for o in operands)
+        written = _nbytes(result)
+        moved += read + written
+        if read + written >= least:
+            kind = re.search(r"kind=(k\w+)", line)
+            scope = re.search(r'op_name="([^"]*)"', line)
+            lines.append(
+                f"{row_name:42s} {opcode:12s} {kind.group(1) if kind else '':8s}"
+                f" reads {read / 1e6:7.1f} MB writes {written / 1e6:7.1f} MB  "
+                f"{result[:64]:64s} {scope.group(1)[-56:] if scope else ''}"
+            )
+    lines.append(
+        f"operands and results of every ENTRY instruction: {moved / 1e6:.1f} MB "
+        "(an operand is counted whole, also where a fusion reads a slice of it)"
+    )
+    return "\n".join(lines)
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("the TPU's compiler (libtpu) is not installed here")
+    env = dict(
+        os.environ, JAX_PLATFORMS="cpu", TPU_LOG_DIR="disabled",
+        ALLOW_MULTIPLE_LIBTPU_LOAD="1",
+    )
+    child = subprocess.run(
+        [sys.executable, os.path.abspath(__file__)], env=env, timeout=900,
+        capture_output=True, text=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert child.returncode == 0, child.stderr[-4000:]
+    return json.loads(child.stdout.strip().splitlines()[-1])
+
+
+@pytest.mark.parametrize("invariant", INVARIANTS)
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_node_compiled_for_the_described_chip(compiled, shape, invariant):
+    assert compiled[shape][invariant] == "ok"
+
+
+if __name__ == "__main__":
+    argv = sys.argv[1:]
+    root = os.getcwd()
+    if "--root" in argv:
+        at = argv.index("--root")
+        root = os.path.abspath(argv[at + 1])
+        del argv[at:at + 2]
+    sys.path.insert(0, root)
+    if argv:
+        print(listing(argv[0]))
+    else:
+        print(json.dumps({name: check(name) for name in SHAPES}))

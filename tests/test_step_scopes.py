@@ -122,6 +122,16 @@ def test_scope_name_survives_the_name_stack(layer_name, want):
         ("jit(_step)/transpose(jvp(ff.ssm.m0))/scan/checkpoint/mul",
          ("bwd", "ssm", "m0/scan")),
         ("jit(_step)/jvp(ff.ssm.m0)/dot_general", ("fwd", "ssm", "m0")),
+        # and so do its convolution with SiLU and its gated norm, whose
+        # written backwards close the part's scope with the node's
+        ("jit(_step)/jvp(ff.ssm.m0)/conv/convert_element_type",
+         ("fwd", "ssm", "m0/conv")),
+        ("jit(_step)/transpose(jvp(ff.ssm.m0/conv))/reduce_sum",
+         ("bwd", "ssm", "m0/conv")),
+        ("jit(_step)/jvp(ff.ssm.m0)/norm/reduce_sum", ("fwd", "ssm", "m0/norm")),
+        ("jit(_step)/transpose(jvp(ff.ssm.m0/norm))/convert_element_type",
+         ("bwd", "ssm", "m0/norm")),
+        ("jit(_step)/jvp(ff.ssm.m0)/normal/mul", ("fwd", "ssm", "m0")),
         ("jit(_step)/jvp(ff.dense.d)/scan/mul", ("fwd", "dense", "d")),
         # an experts node's parts (`trace.NODE_PARTS`) keep theirs too
         ("jit(_step)/jvp(ff.experts.moe1)/router/dot_general",
