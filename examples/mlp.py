@@ -60,15 +60,6 @@ def main():
     # the loop below emits one JSONL event per step and enforces the policy
     # (observability/{metrics,health}.py — same wiring FFModel.fit does)
     health_on = cfg.health_policy not in ("", "off")
-    if cfg.steps_per_dispatch > 1:
-        # dead-flag rule: this example demonstrates the INSTANCE-level
-        # per-step loop; fused windows live in FFModel.fit
-        # (examples/alexnet.py exercises them)
-        print(
-            "[mlp.py] --steps-per-dispatch applies to the FFModel.fit "
-            "loop; this instance-level example steps one dispatch at a "
-            "time"
-        )
     inst = ModelTrainingInstance(
         cg,
         logits,

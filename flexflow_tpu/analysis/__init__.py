@@ -1,5 +1,5 @@
 """Static verification layer (ISSUE 4; memory analysis added by ISSUE 10:
-`memory_accounting` + `memory_analysis` — MEM001-MEM004, `ffcheck
+`memory_accounting` + `memory_analysis` — MEM001-MEM003, `ffcheck
 --memory`, and the machine-mapping DPs' feasibility pruner all read one
 shared accounting, and `FFModel.compile` records the winner's per-device
 peaks in `search_provenance["memory"]`; communication analysis added by

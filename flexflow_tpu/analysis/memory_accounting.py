@@ -19,12 +19,7 @@ The model (the round-3/5 accounting, now centralized):
                  (weight + grad + the optimizer's per-weight state tensors
                  — Adam m/v = 2, SGD+momentum = 1, plain SGD = 0),
     outputs:     every output x2 (out + out-grad),
-    input layers (InputAttrs): the fused-dispatch stacked window. Under
-                 `steps_per_dispatch=K` the host->device producer stages K
-                 batches as ONE [K, batch, ...] device buffer, so the
-                 input layer's residency is K x its per-step bytes — the
-                 term the old `_measure` accounting silently dropped
-                 (pinned by the K=1 vs K=8 tests).
+    input layers (InputAttrs): the step's one batch, x1 (no gradient).
 
 Weight layers (and the pure reshard chains hanging off them) account to
 zero here: parameters are STORED in the sharded form the consuming op
@@ -36,9 +31,8 @@ make every parameter-parallel plan look as heavy as the serial one.
 
 Serving mode (ISSUE 12): passing a `ServingMemorySpec` switches the
 accounting to forward-only inference residency — activations / weights /
-outputs at x1 (no gradients, no optimizer slots, no stacked dispatch
-window) — and charges each attention op its per-device share of the
-persistent KV cache: 2 (K+V) x sequences x max_seq_len x heads x head_dim
+outputs at x1 (no gradients, no optimizer slots) — and charges each
+attention op its per-device share of the persistent KV cache: 2 (K+V) x sequences x max_seq_len x heads x head_dim
 x dtype bytes, divided by the op's batch / sequence / head shard degrees
 (the cache is a parallel tensor whose degrees are BOUND to the attention
 op's own sharding — serving/kv_cache.py lowers the same degrees to
@@ -152,7 +146,7 @@ class OpStepMemory:
     optimizer_state: int = 0
     outputs: int = 0
     output_grads: int = 0
-    window_buffer: int = 0  # stacked [K, batch, ...] input staging
+    input_batch: int = 0  # an input layer's batch (no gradient)
     kv_cache: int = 0  # persistent serving KV cache (ServingMemorySpec)
 
     @property
@@ -165,7 +159,7 @@ class OpStepMemory:
             + self.optimizer_state
             + self.outputs
             + self.output_grads
-            + self.window_buffer
+            + self.input_batch
             + self.kv_cache
         )
 
@@ -176,7 +170,6 @@ def estimate_memory(
     weight_shapes: Optional[Sequence] = None,
     output_shapes: Optional[Sequence] = None,
     optimizer_state_slots: int = 2,
-    steps_per_dispatch: int = 1,
     serving: Optional[ServingMemorySpec] = None,
     kv_cache_bytes: int = 0,
     slot_shard_ways: Optional[Sequence[int]] = None,
@@ -189,10 +182,8 @@ def estimate_memory(
     own shape).
 
     With `serving` set the regime is forward-only inference: no gradient
-    or optimizer terms, no stacked window (the serving engine dispatches
-    one decode window over a persistent cache, not K training batches),
-    plus `kv_cache_bytes` — the caller's per-device cache share from
-    `kv_cache_piece_bytes` (this function sees piece TensorShapes only,
+    or optimizer terms, plus `kv_cache_bytes` — the caller's per-device
+    cache share from `kv_cache_piece_bytes` (this function sees piece TensorShapes only,
     which carry no degrees). `slot_shard_ways[i]` is `update_shard_ways`
     of weight slot i, for the same reason the caller's to give: each
     optimizer slot is resident at 1/ways of its weight's piece. The
@@ -202,16 +193,13 @@ def estimate_memory(
     smaller: the term errs high)."""
     from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
 
-    k = 1 if serving is not None else max(int(steps_per_dispatch), 1)
     if isinstance(attrs, InputAttrs):
-        # the stacked dispatch window: K per-step batches resident as one
-        # device buffer (K=1 degenerates to the plain per-step batch)
         out_bytes = (
             sum(s.size_bytes for s in output_shapes)
             if output_shapes
             else attrs.shape.size_bytes
         )
-        return OpStepMemory(window_buffer=k * out_bytes)
+        return OpStepMemory(input_batch=out_bytes)
     if isinstance(attrs, WeightAttrs):
         # charged at the consuming op's weight slots (see module docstring)
         return OpStepMemory()
@@ -249,7 +237,7 @@ def estimate_memory(
 def leaf_step_memory_bytes(
     leaf,
     optimizer_state_slots: int = 2,
-    steps_per_dispatch: int = 1,
+    *,
     serving: Optional[ServingMemorySpec] = None,
 ) -> int:
     """Per-device step residency of ONE machine-mapping leaf
@@ -272,7 +260,7 @@ def leaf_step_memory_bytes(
     consuming op's weight slots (see module docstring).
 
     With `serving` set the residency is forward-only inference (no grad /
-    optimizer / window terms) and attention leaves additionally charge
+    optimizer terms) and attention leaves additionally charge
     their per-device KV-cache share (`kv_cache_piece_bytes`) — this is
     the predicate both machine-mapping DPs prune serving plans on."""
     from flexflow_tpu.op_attrs.core import (
@@ -285,13 +273,12 @@ def leaf_step_memory_bytes(
 
     from flexflow_tpu.op_attrs.core import is_stage_op
 
-    k = 1 if serving is not None else max(int(steps_per_dispatch), 1)
     out_pieces = [get_piece_shape(s) for s in leaf.output_shapes]
     out_bytes = sum(s.size_bytes for s in out_pieces)
     attrs = leaf.op_attrs
     ctx = getattr(leaf, "pipeline", None)  # pcg.pipeline.PipelineLeafContext
     if isinstance(attrs, InputAttrs):
-        return k * out_bytes
+        return out_bytes
     if isinstance(attrs, WeightAttrs):
         return 0
     in_pieces = [get_piece_shape(s) for s in leaf.input_shapes]
@@ -345,7 +332,6 @@ def leaf_step_memory_bytes(
         weights,
         outs,
         optimizer_state_slots=optimizer_state_slots,
-        steps_per_dispatch=k,
         serving=serving,
         kv_cache_bytes=cache_bytes,
         slot_shard_ways=slot_ways,
@@ -366,7 +352,7 @@ def pipeline_scaled_total(mem: OpStepMemory, ctx) -> int:
     """Apply the 1F1B residency scaling to one op's training accounting:
     activations/outputs x min(S-s, M)/M (the in-flight stash bound),
     activation/output grads x 1/M (one microbatch's backward in flight);
-    weights, grads, optimizer state, window buffers unchanged."""
+    weights, grads, optimizer state, input batches unchanged."""
     s_total, m = max(ctx.num_stages, 1), max(ctx.num_microbatches, 1)
     keep = max(min(s_total - ctx.stage, m), 1)
     acts = mem.activations + mem.outputs

@@ -29,8 +29,7 @@ backward ticks N..2N-1 in reverse):
   counts like an activation on its devices — src and dst pieces are
   simultaneously live while the reshard runs, and a Combine back to
   degree 1 materializes the FULL tensor per device,
-- fused-dispatch windows: `steps_per_dispatch=K` stages K batches as one
-  stacked [K, batch, ...] device buffer, resident the whole step.
+- input batches: an input layer's piece, resident the whole step.
 
 Per-device charging uses piece bytes (`get_piece_shape`): under GSPMD
 every device of an op's view holds one piece. Without a mapping the
@@ -48,9 +47,6 @@ MEM003 unsharded-optimizer     optimizer state dominates (> half the
                                capacity) while parameters are unsharded:
                                the classic fix is weight sharding, not a
                                smaller model (warning)
-MEM004 window-over-budget      the stacked dispatch-window buffers alone
-                               exceed half the capacity: lower
-                               --steps-per-dispatch (error)
 MEM005 serving-over-capacity   (serving mode, ISSUE 12) the static
                                max-concurrent-sequences verdict — how many
                                sequences' KV cache fits beside the model's
@@ -59,7 +55,7 @@ MEM005 serving-over-capacity   (serving mode, ISSUE 12) the static
 
 Serving mode (`ffcheck --memory --serving`, `ServingMemorySpec`): the
 liveness runs forward-only (ticks 0..N-1, no gradient intervals, no
-optimizer state, no dispatch window) and each attention op's devices hold
+optimizer state) and each attention op's devices hold
 its persistent KV-cache share (`kv_cache_piece_bytes`) as whole-step
 residency. The per-sequence slope of that cache term against the free
 capacity yields the MEM005 verdict, which the serving engine's admission
@@ -86,7 +82,7 @@ from flexflow_tpu.analysis.memory_accounting import (
     update_shard_ways,
 )
 
-MEMORY_RULE_IDS = ("MEM001", "MEM002", "MEM003", "MEM004", "MEM005")
+MEMORY_RULE_IDS = ("MEM001", "MEM002", "MEM003", "MEM005")
 
 # category keys of the per-device breakdowns (stable: the ffcheck --json
 # schema and the provenance records carry them)
@@ -97,7 +93,7 @@ CATEGORIES = (
     "activations",
     "activation_grads",
     "collective_staging",
-    "window_buffer",
+    "input_batch",
     "kv_cache",
 )
 
@@ -122,7 +118,6 @@ class MemoryAnalysis:
     per_device: Dict[int, DeviceMemoryTimeline]
     num_ticks: int
     optimizer_state_slots: int
-    steps_per_dispatch: int
     # tick -> human label ("fwd ff1" / "bwd attn") for table rendering
     tick_labels: Dict[int, str] = field(default_factory=dict)
     # the serving regime analyzed under (None = training step)
@@ -167,7 +162,6 @@ def analyze_memory(
     machine_spec=None,
     mapping: Optional[dict] = None,
     optimizer_state_slots: int = 2,
-    steps_per_dispatch: int = 1,
     serving: Optional[ServingMemorySpec] = None,
 ) -> MemoryAnalysis:
     """Build the per-device peak-HBM timeline of one training step — or,
@@ -197,7 +191,6 @@ def analyze_memory(
     ticks = n_ops if serving is not None else 2 * n_ops
     fwd_tick = {n: i for i, n in enumerate(order)}
     bwd_tick = {n: ticks - 1 - i for i, n in enumerate(order)}
-    k = 1 if serving is not None else max(int(steps_per_dispatch), 1)
     slots = 0 if serving is not None else max(int(optimizer_state_slots), 0)
 
     ndev = machine_spec.num_devices if machine_spec is not None else 1
@@ -253,7 +246,7 @@ def analyze_memory(
             # CONSUMING op's weight slots (post-reshard sharded form)
             continue
         if isinstance(attrs, InputAttrs):
-            charge_resident(devs, "window_buffer", k * out_piece_bytes)
+            charge_resident(devs, "input_batch", out_piece_bytes)
             continue
         ins = pcg.inputs_of(n)
         if is_parallel_op(attrs) and ins and all(
@@ -386,7 +379,6 @@ def analyze_memory(
         per_device=per_device,
         num_ticks=ticks,
         optimizer_state_slots=slots,
-        steps_per_dispatch=k,
         tick_labels=tick_labels,
         serving=serving,
     )
@@ -482,16 +474,15 @@ def verify_memory(
     mapping: Optional[dict] = None,
     hbm_bytes: Optional[float] = None,
     optimizer_state_slots: int = 2,
-    steps_per_dispatch: int = 1,
     analysis: Optional[MemoryAnalysis] = None,
     serving: Optional[ServingMemorySpec] = None,
 ) -> Tuple[MemoryAnalysis, List[Diagnostic]]:
-    """Run the liveness analysis and derive the MEM001-MEM005 diagnostics
-    against a per-device capacity of `hbm_bytes` (None = no capacity known:
-    the analysis still runs — peaks land in provenance — but no rule can
+    """Run the liveness analysis and derive the MEM001-MEM003 and MEM005
+    diagnostics against a per-device capacity of `hbm_bytes` (None = no
+    capacity known: the analysis still runs — peaks land in provenance — but no rule can
     trip). With `serving` set the analysis is forward-only + KV cache and
     the serving-specific MEM005 admission verdict replaces the
-    training-only MEM003/MEM004 rules. Returns (analysis, diagnostics)."""
+    training-only MEM003 rule. Returns (analysis, diagnostics)."""
     from flexflow_tpu.compiler.machine_mapping.problem_tree import _leaf_key
     from flexflow_tpu.op_attrs.core import is_parallel_op
     from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
@@ -505,7 +496,6 @@ def verify_memory(
             machine_spec,
             mapping,
             optimizer_state_slots=optimizer_state_slots,
-            steps_per_dispatch=steps_per_dispatch,
             serving=serving,
         )
     serving = analysis.serving
@@ -525,8 +515,7 @@ def verify_memory(
             need = leaf_step_memory_bytes(
                 _leaf_key(pcg, n, pipe_ctx),
                 optimizer_state_slots,
-                steps_per_dispatch,
-                serving,
+                serving=serving,
             )
         except (AssertionError, IndexError, KeyError, ValueError, TypeError):
             continue  # PCG001-003 own malformed shapes
@@ -561,8 +550,7 @@ def verify_memory(
                 f"({_gib(hbm_bytes)} capacity) at {at}; top terms: "
                 + ", ".join(f"{c}={_gib(v)}" for c, v in top),
                 hint="shard the dominating term (weights -> parameter "
-                "parallel, activations -> batch/sequence parallel) or "
-                "lower --steps-per-dispatch",
+                "parallel, activations -> batch/sequence parallel)",
             )
         )
     if len(over) > 4:
@@ -577,9 +565,9 @@ def verify_memory(
     if serving is not None:
         # MEM005: the static max-concurrent-sequences verdict is below the
         # workload's requested concurrency — admitting the full batch
-        # would OOM a device on cache residency alone. MEM003/MEM004 are
-        # training-only regimes (optimizer state / dispatch windows) and
-        # cannot apply to a forward-only serving dispatch.
+        # would OOM a device on cache residency alone. MEM003 is a
+        # training-only regime (optimizer state) and cannot apply to a
+        # forward-only serving dispatch.
         verdict = serving_verdict(analysis, hbm_bytes)
         if (
             verdict is not None
@@ -633,25 +621,6 @@ def verify_memory(
                     "optimizer slots shard with them",
                 )
             )
-
-    # MEM004: the stacked dispatch window dominates
-    if analysis.steps_per_dispatch > 1:
-        for d in sorted(analysis.per_device.values(), key=lambda x: x.device):
-            win = d.peak_breakdown.get("window_buffer", 0)
-            if win > 0.5 * hbm_bytes:
-                diags.append(
-                    error(
-                        "MEM004",
-                        f"device {d.device}'s stacked dispatch-window "
-                        f"buffers hold {_gib(win)} "
-                        f"(steps_per_dispatch="
-                        f"{analysis.steps_per_dispatch}) of the "
-                        f"{_gib(hbm_bytes)} capacity",
-                        hint="lower --steps-per-dispatch (the window "
-                        "buffer scales linearly with K)",
-                    )
-                )
-                break  # one structured finding names the knob; one suffices
     return analysis, diags
 
 
@@ -720,7 +689,6 @@ def memory_summary_json(
         "memory": 1,  # schema version
         "hbm_bytes": None if not hbm_bytes else int(hbm_bytes),
         "optimizer_state_slots": analysis.optimizer_state_slots,
-        "steps_per_dispatch": analysis.steps_per_dispatch,
         "serving": serving_block,
         "devices": [
             {

@@ -89,7 +89,6 @@ PCG_RULE_CATALOG: Dict[str, str] = {
     "MEM001": "over-capacity: a device's peak-HBM timeline exceeds the capacity",
     "MEM002": "piece-too-large: one op's piece residency alone exceeds the capacity",
     "MEM003": "unsharded-optimizer: optimizer state dominates while parameters are unsharded",
-    "MEM004": "window-over-budget: stacked dispatch-window buffers exceed the memory budget",
     "MEM005": "serving-over-capacity: the static max-concurrent-sequences verdict is below the serving workload's requested concurrency",
     # static communication rules (analysis/comm_analysis.py — the HLO
     # collective census cross-checked against the plan's priced movement

@@ -50,9 +50,9 @@ LINT005 host-transfer-in-fit-loop
                             critical path. A blocking host transfer there
                             stalls async dispatch of the next donated step
                             every iteration. Nested function definitions
-                            are exempt: background producer/writer thread
-                            bodies (the input pipeline, the async
-                            checkpoint writer) are the sanctioned home for
+                            are exempt: background writer thread bodies
+                            (the async checkpoint writer) are the
+                            sanctioned home for
                             host transfers, as are named helpers outside
                             the drivers (each sync point then has a
                             reviewable name, e.g. `_read_losses_host`).
@@ -79,13 +79,13 @@ LINT007 unsupervised-thread   concurrency discipline for `flexflow_tpu/
                             primitives (`on_hang`, `raise_pending`,
                             `_async_raise`) — so a thread that dies
                             surfaces at a window boundary instead of
-                            silently leaving the run uncheckpointed /
-                            unfed (the PR-8 producer-death class).
+                            silently leaving the run uncheckpointed
+                            (the PR-8 silent-death class).
 
 LINT008 undonated-step-jit  a `jax.jit`/`jit`/`pjit` call whose jitted
                             callable is a training/serving STEP (its
                             snake_case name carries a `step` token, e.g.
-                            `_step`, `_multi_step`, `decode_step`) but
+                            `_step`, `decode_step`) but
                             which passes neither `donate_argnums` nor
                             `donate_argnames`. Step programs rewrite the
                             largest trees in the system (params +
@@ -159,7 +159,7 @@ LINT_CATALOG: Dict[str, str] = {
 }
 
 # training-loop drivers: functions holding the step-dispatch critical path
-# (FFModel._fit_loop/_fit_epochs/_fit_epochs_fused and kin)
+# (FFModel._fit_loop/_fit_epochs and kin)
 _FIT_LOOP_PREFIX = "_fit_"
 
 _SHARD_MAP_NAMES = ("shard_map", "shard_map_compat", "_shard_map")

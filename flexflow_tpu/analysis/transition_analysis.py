@@ -37,8 +37,8 @@ TRN003 resume-contract-break      step/RNG contract: a batch-schedule
        change, a pipeline microbatch-count change (loss accumulation
        re-orders — float addition is not associative), or a malformed
        pipeline region in exactly one plan would break bitwise resume
-       (error). COMPATIBLE changes — steps_per_dispatch restacking,
-       stage-count changes at fixed M, pure view moves — are annotated
+       (error). COMPATIBLE changes — stage-count changes at fixed M,
+       pure view moves — are annotated
        in `carry_remap` with the exact state remap the swap executor
        applies (no diagnostic)
 TRN004 exec-contract-violation    the NEW plan's compiled step must
@@ -359,11 +359,11 @@ def _weight_state_by_device(
 
 
 def _step_contract(
-    pcg, steps_per_dispatch: int, batch_size: Optional[int] = None
+    pcg, batch_size: Optional[int] = None
 ) -> Dict[str, object]:
     """The scalars bitwise resume is defined over: the batch schedule
-    (every input layer's global shape), the fused-dispatch window K, and
-    the pipeline (S, M) when a stage region exists.
+    (every input layer's global shape) and the pipeline (S, M) when a
+    stage region exists.
 
     `batch_size` overrides the leading (batch) dimension of every input
     shape: a live model's computation graph carries the BUILD-time batch,
@@ -396,7 +396,6 @@ def _step_contract(
             microbatches = int(region.num_microbatches)
     return {
         "batch_schedule": batch,
-        "steps_per_dispatch": max(int(steps_per_dispatch), 1),
         "pipeline_stages": stages,
         "pipeline_microbatches": microbatches,
         "pipeline_region_ok": region_ok,
@@ -414,8 +413,6 @@ def analyze_transition(
     machine_spec=None,
     hbm_bytes: Optional[float] = None,
     optimizer_state_slots: int = 2,
-    steps_per_dispatch: int = 1,
-    steps_per_dispatch_new: Optional[int] = None,
     batch_size: Optional[int] = None,
     batch_size_new: Optional[int] = None,
     lowered_new=None,
@@ -436,13 +433,6 @@ def analyze_transition(
     slots = max(int(optimizer_state_slots), 0)
     a = TransitionAnalysis(
         hbm_bytes=hbm_bytes, optimizer_state_slots=slots
-    )
-    k_old = max(int(steps_per_dispatch), 1)
-    k_new = max(
-        int(steps_per_dispatch_new)
-        if steps_per_dispatch_new is not None
-        else k_old,
-        1,
     )
     old_leaves = weight_leaves(old_pcg)
     new_leaves = weight_leaves(new_pcg)
@@ -542,9 +532,9 @@ def analyze_transition(
             a.migration_verdict = "over"
 
     # TRN003: the step/RNG contract
-    a.contract_old = _step_contract(old_pcg, k_old, batch_size=batch_size)
+    a.contract_old = _step_contract(old_pcg, batch_size=batch_size)
     a.contract_new = _step_contract(
-        new_pcg, k_new,
+        new_pcg,
         batch_size=batch_size if batch_size_new is None else batch_size_new,
     )
     if a.contract_old["batch_schedule"] == a.contract_new["batch_schedule"]:
@@ -553,12 +543,6 @@ def analyze_transition(
         )
         a.carry_remap["dataloader"] = (
             "cursor continues at the same global step"
-        )
-    if k_old != k_new:
-        a.carry_remap["steps_per_dispatch"] = (
-            f"dispatch window restacked K={k_old} -> K={k_new}: the "
-            "resume cursor is per-step, so the carry resumes at the "
-            "same global step with the new stacking"
         )
     s_old = a.contract_old["pipeline_stages"]
     s_new = a.contract_new["pipeline_stages"]
@@ -756,8 +740,6 @@ def verify_transition(
     machine_spec=None,
     hbm_bytes: Optional[float] = None,
     optimizer_state_slots: int = 2,
-    steps_per_dispatch: int = 1,
-    steps_per_dispatch_new: Optional[int] = None,
     batch_size: Optional[int] = None,
     batch_size_new: Optional[int] = None,
     lowered_new=None,
@@ -779,8 +761,6 @@ def verify_transition(
             machine_spec=machine_spec,
             hbm_bytes=hbm_bytes,
             optimizer_state_slots=optimizer_state_slots,
-            steps_per_dispatch=steps_per_dispatch,
-            steps_per_dispatch_new=steps_per_dispatch_new,
             batch_size=batch_size,
             batch_size_new=batch_size_new,
             lowered_new=lowered_new,

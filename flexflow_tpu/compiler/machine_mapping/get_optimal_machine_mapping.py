@@ -130,9 +130,8 @@ class MachineMappingContext:
     memory_budget_bytes: float = 0.0
     # memory-model parameters the budget is evaluated under (must match
     # what the run will actually execute: the compiled optimizer's state
-    # slots and the fused-dispatch window K)
+    # slots)
     optimizer_state_slots: int = 2
-    steps_per_dispatch: int = 1
     # Serving regime (ISSUE 12): a ServingMemorySpec switches the memory
     # model to forward-only inference residency plus each attention
     # leaf's per-device KV-cache share, so over-capacity SERVING plans
@@ -504,8 +503,7 @@ def leaf_memory_infeasible(
         need = leaf_step_memory_bytes(
             leaf,
             context.optimizer_state_slots,
-            context.steps_per_dispatch,
-            context.serving,
+            serving=context.serving,
         )
     except (AssertionError, IndexError, KeyError, ValueError, TypeError):
         return False  # malformed shapes are the verifier's finding, not ours

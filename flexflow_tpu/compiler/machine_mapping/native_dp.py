@@ -304,8 +304,7 @@ def _solve(cache, context, tree, resources):
                     leaf_step_memory_bytes(
                         k,
                         context.optimizer_state_slots,
-                        context.steps_per_dispatch,
-                        context.serving,
+                        serving=context.serving,
                     )
                 )
             except (AssertionError, IndexError, KeyError, ValueError, TypeError):

@@ -398,7 +398,6 @@ def evaluate_pcg(
             mapping,
             hbm_bytes=context.memory_budget_bytes,
             optimizer_state_slots=context.optimizer_state_slots,
-            steps_per_dispatch=context.steps_per_dispatch,
             serving=getattr(context, "serving", None),
         )
         if has_errors(mem_diags):

@@ -112,8 +112,8 @@ class FFModel:
         initialize()
         self.config = config or FFConfig()
         # persistent XLA compilation cache: installed before any jit so
-        # every program this model compiles (step, fused window, eval
-        # forward) is reusable by the next process
+        # every program this model compiles (step, eval forward) is
+        # reusable by the next process
         from flexflow_tpu.local_execution.config import (
             configure_compilation_cache,
         )
@@ -740,11 +740,6 @@ class FFModel:
                     aux_loss_tensors=self._aux_loss_tensors,
                     collect_step_stats=collect, guard_nonfinite_updates=guard,
                 )
-        if hasattr(self.instance, "halt_on_nonfinite"):
-            # fused windows under the `raise` policy freeze after the first
-            # tripped step so the post-window state is the pre-trip state
-            # the per-step loop would have stopped with (fused_multi_step)
-            self.instance.halt_on_nonfinite = cfg.health_policy == "raise"
         with record_span("compile/init_state"):
             self.params, self.opt_state = self.instance.initialize(
                 seed=cfg.seed
@@ -910,7 +905,6 @@ class FFModel:
         old_params, old_opt = self.params, self.opt_state
         step_count = self._step_count  # training progress survives recompile
         old_plan = self._transition_plan()
-        old_k = max(int(self.config.steps_per_dispatch), 1)
         # the graph carries the BUILD-time batch; the effective batch is
         # whatever the last compile() ran under — config may ALREADY be
         # altered by the time recompile() runs (recompile_on_condition's
@@ -980,10 +974,6 @@ class FFModel:
                 ),
                 optimizer_state_slots=optimizer_state_slots_of(
                     self.optimizer_attrs
-                ),
-                steps_per_dispatch=old_k,
-                steps_per_dispatch_new=max(
-                    int(cfg.steps_per_dispatch), 1
                 ),
                 batch_size=old_b,
                 batch_size_new=int(cfg.batch_size),
@@ -1155,11 +1145,6 @@ class FFModel:
             raise ValueError(
                 f"health_policy {cfg.health_policy!r} not in "
                 f"{HEALTH_POLICIES}"
-            )
-        if cfg.steps_per_dispatch < 1:
-            raise ValueError(
-                f"steps_per_dispatch must be >= 1, got "
-                f"{cfg.steps_per_dispatch}"
             )
         if cfg.max_devices < 0:
             raise ValueError(f"max_devices must be >= 0, got {cfg.max_devices}")
@@ -1626,11 +1611,10 @@ class FFModel:
         )
 
         # static memory safety (ISSUE 10): the memory model's parameters
-        # for THIS compile — the optimizer actually compiled and the fused
-        # window K — plus the per-device budget the search must respect
+        # for THIS compile — the optimizer actually compiled — plus the
+        # per-device budget the search must respect
         # (--hbm-gb; 0 = no search-side constraint, winner analysis only)
         mem_slots = _opt_slots_of(self.optimizer_attrs)
-        mem_window_k = max(cfg.steps_per_dispatch, 1)
         mem_budget_bytes = (
             cfg.hbm_gb * 2**30 if cfg.hbm_gb and cfg.hbm_gb > 0 else 0.0
         )
@@ -1782,11 +1766,6 @@ class FFModel:
                                 self.optimizer_attrs
                             ),
                             cost_store=cost_store,
-                            # the fused window K is part of the memory
-                            # model: the estimator must price the same
-                            # regime the DP pruner and the verifier check
-                            # (shared module)
-                            steps_per_dispatch=mem_window_k,
                         ),
                         ici_latency_ms=ici_lat_ms,
                         dcn_latency_ms=dcn_lat_ms,
@@ -1855,7 +1834,6 @@ class FFModel:
                     # (ISSUE 10)
                     memory_budget_bytes=mem_budget_bytes,
                     optimizer_state_slots=mem_slots,
-                    steps_per_dispatch=mem_window_k,
                     # --multislice: slice-boundary legality masks every
                     # candidate view (constrained included) and multi-node
                     # specs search through the two-level ICI/DCN DP
@@ -2085,7 +2063,6 @@ class FFModel:
                         mapping=result.machine_mapping,
                         hbm_bytes=mem_capacity or None,
                         optimizer_state_slots=mem_slots,
-                        steps_per_dispatch=mem_window_k,
                     )
                     verify_diags = list(verify_diags) + list(mem_diags)
                     self.search_provenance["verify"] = _verify_summarize(
@@ -2106,7 +2083,6 @@ class FFModel:
                         spec,
                         None,
                         optimizer_state_slots=mem_slots,
-                        steps_per_dispatch=mem_window_k,
                     )
                     self.search_provenance["memory"] = {
                         "predicted_peak_bytes_per_device": {
@@ -2122,7 +2098,6 @@ class FFModel:
                         ),
                         "hbm_gb": cfg.hbm_gb or None,
                         "optimizer_state_slots": mem_slots,
-                        "steps_per_dispatch": mem_window_k,
                     }
                 return result.pcg, result.machine_mapping, result.runtime
 
@@ -2189,7 +2164,6 @@ class FFModel:
                     optimizer_state_slots=optimizer_state_slots_of(
                         self.optimizer_attrs
                     ),
-                    steps_per_dispatch=mem_window_k,
                 )
                 return transition_verdict_record(a)
 
@@ -2424,7 +2398,6 @@ class FFModel:
                     optimizer_state_slots=optimizer_state_slots_of(
                         self.optimizer_attrs
                     ),
-                    steps_per_dispatch=mem_window_k,
                     fused_edges=fused_edge_map,
                     overlap_predictions=overlap_predictions,
                     movement_store=effective_movement_store,
@@ -2556,8 +2529,8 @@ class FFModel:
         config fields) enable the elastic runtime: full-resume snapshots —
         params, optimizer state, RNG stream position, dataloader epoch +
         within-epoch cursor — written by a background thread overlapped
-        with the next dispatch window (`config.checkpoint_sync` forces the
-        blocking path). `resume=True` restores the latest snapshot and
+        with the next step (`config.checkpoint_sync` forces the blocking
+        path). `resume=True` restores the latest snapshot and
         continues BITWISE-identically to the uninterrupted run: same
         shuffle permutations, same RNG stream, same loss trajectory
         (chaos-pinned in tests/test_elastic.py via FF_TPU_FAULT_STEP).
@@ -2632,7 +2605,7 @@ class FFModel:
         a searched plan with a finite positive predicted step cost to
         compare against. The monitor is a daemon thread supervised
         through the fit's FaultChannel — its crashes surface as
-        BackgroundFault at the next window boundary, never as a silent
+        BackgroundFault at the next step boundary, never as a silent
         stall — and it only ever ADVISES; the compiled executable is
         untouched."""
         import math
@@ -2746,15 +2719,7 @@ class FFModel:
                 write_provenance(
                     self.config.metrics_dir, self.search_provenance
                 )
-            k = self._effective_steps_per_dispatch()
             begin.close()
-            if k > 1:
-                return self._fit_epochs_fused(
-                    x, y, epochs, batch_size, shuffle, verbose,
-                    recompile_state, epoch_offset, it, rng, event_log,
-                    monitor, k, ckpt=ckpt, start_epoch=start_epoch,
-                    skip_batches=skip_batches, sup=sup,
-                )
             return self._fit_epochs(
                 x, y, epochs, batch_size, shuffle, verbose, recompile_state,
                 epoch_offset, it, rng, event_log, monitor, ckpt=ckpt,
@@ -2932,40 +2897,15 @@ class FFModel:
                 self.config.metrics_dir, "checkpoint_fallback", **report
             )
 
-    def _effective_steps_per_dispatch(self) -> int:
-        """The fused window length this fit will run. FF_TPU_FUSED_BASELINE=1
-        reverts to the per-step loop in-process (the regression test's
-        revert switch); a backend without a fused program (submesh) falls
-        back loudly rather than silently ignoring the flag."""
-        import os
-
-        k = int(self.config.steps_per_dispatch)
-        if k <= 1:
-            return 1
-        if os.environ.get("FF_TPU_FUSED_BASELINE") == "1":
-            print(
-                "[flexflow_tpu] FF_TPU_FUSED_BASELINE=1: steps_per_dispatch "
-                f"{k} reverted to the per-step loop"
-            )
-            return 1
-        if not hasattr(self.instance, "multi_train_step"):
-            print(
-                "[flexflow_tpu] steps_per_dispatch: backend "
-                f"{type(self.instance).__name__} has no fused multi-step "
-                "program; running per-step"
-            )
-            return 1
-        return k
-
     def _fit_epochs(
         self, x, y, epochs, batch_size, shuffle, verbose, recompile_state,
         epoch_offset, it, rng, event_log, monitor, ckpt=None,
-        start_epoch: int = 0, skip_batches: int = 0, epoch_base: int = 0,
-        sup=None,
+        start_epoch: int = 0, skip_batches: int = 0, sup=None,
     ) -> PerfMetrics:
         from flexflow_tpu.runtime.fault import (
             inject_hang_fault,
             inject_kill_fault,
+            inject_nonfinite_fault,
             inject_slow_fault,
             maybe_inject_fault,
         )
@@ -2984,6 +2924,10 @@ class FFModel:
         while epoch < epochs:
             batch_in_epoch = skip_batches if epoch == start_epoch else 0
             for batch, label in _pulls(it):
+                if sup is not None:
+                    batch = inject_nonfinite_fault(
+                        sup.schedule, self._step_count + 1, batch
+                    )
                 if watchdog is not None:
                     watchdog.begin_window(self._step_count + 1, 1)
                 try:
@@ -3051,8 +2995,7 @@ class FFModel:
                     # bitwise-resume point (runtime/checkpoint.py)
                     ckpt.snapshot(
                         self._step_count, self.params, self.opt_state,
-                        rng, epoch_base + epoch, batch_in_epoch,
-                        epoch_offset,
+                        rng, epoch, batch_in_epoch, epoch_offset,
                     )
                 if sup is not None:
                     inject_kill_fault(
@@ -3096,268 +3039,6 @@ class FFModel:
             )
         return perf
 
-    def _fit_epochs_fused(
-        self, x, y, epochs, batch_size, shuffle, verbose, recompile_state,
-        epoch_offset, it, rng, event_log, monitor, k: int, ckpt=None,
-        start_epoch: int = 0, skip_batches: int = 0, sup=None,
-    ) -> PerfMetrics:
-        """The fused window loop (`steps_per_dispatch=K`): each iteration
-        dispatches ONE donated XLA program covering K training steps
-        (instance.multi_train_step) over a stacked batch window that the
-        double-buffered input pipeline transferred while the previous
-        window executed. Loss/metric/health scalars come back as [k]
-        vectors — one host readback per window instead of one per step —
-        and are re-emitted per step so the JSONL event stream and health
-        policies keep their exact per-step granularity. Checkpoint
-        snapshots land only at window boundaries (the post-window state IS
-        a step boundary), so a resumed run re-chunks the remaining epoch
-        into identical windows."""
-        from flexflow_tpu.core.dataloader import WindowedBatchIterator
-        from flexflow_tpu.runtime.fault import (
-            inject_kill_fault,
-            maybe_inject_fault,
-        )
-
-        watchdog = sup.watchdog if sup is not None else None
-        start = time.perf_counter()
-        num_samples = 0
-        loss = None
-        macc: Optional[Dict[str, jnp.ndarray]] = None
-        telem = event_log is not None or monitor is not None
-        pf = self.config.print_freq if verbose else 0
-        epoch = start_epoch
-        while epoch < epochs:
-            # per-epoch wrapper: iter_host re-shuffles exactly like the
-            # per-step loop's __iter__, and a window never spans the epoch
-            # boundary (the tail comes out as one smaller window)
-            batch_in_epoch = skip_batches if epoch == start_epoch else 0
-            win_it = WindowedBatchIterator(
-                it, k, keep_host=monitor is not None,
-                fault_channel=sup.channel if sup is not None else None,
-                step_base=self._step_count,
-            )
-            try:
-                for inputs_stack, label_stack, host_win, kk in _pulls(win_it):
-                    if watchdog is not None:
-                        watchdog.begin_window(self._step_count + 1, kk)
-                    try:
-                        rng, losses, macc = (
-                            self._run_fused_window(
-                                inputs_stack, label_stack, host_win, kk,
-                                rng, event_log, monitor, batch_size, telem,
-                                macc, pf, epoch, sup, watchdog,
-                            )
-                        )
-                    finally:
-                        # disarm BEFORE the boundary work: a slow-but-
-                        # healthy checkpoint commit (or teardown after a
-                        # raise) must not be indistinguishable from a
-                        # hang; the armed region covers dispatch,
-                        # readback, and the simulated-hang site only
-                        if watchdog is not None:
-                            watchdog.end_window(self._step_count)
-                    loss = losses[kk - 1]
-                    base_step = self._step_count - kk
-                    num_samples += batch_size * kk
-                    batch_in_epoch += kk
-                    if ckpt is not None and ckpt.due(
-                        base_step, self._step_count
-                    ):
-                        # window boundaries are the fused loop's only
-                        # step boundaries: snapshot the post-window
-                        # state with the carry rng + the epoch cursor,
-                        # handed to the background writer overlapped
-                        # with the next window
-                        ckpt.snapshot(
-                            self._step_count, self.params,
-                            self.opt_state, rng, epoch, batch_in_epoch,
-                            epoch_offset,
-                        )
-                    if sup is not None:
-                        inject_kill_fault(
-                            sup.schedule, base_step, self._step_count
-                        )
-                        sup.channel.raise_pending()
-                    maybe_inject_fault(base_step, self._step_count)
-                    if recompile_state is not None:
-                        from flexflow_tpu.runtime.recompile import (
-                            recompile_on_condition,
-                        )
-
-                        if recompile_on_condition(self, recompile_state):
-                            # a recompile ends the window stream early (same
-                            # epoch-boundary semantics as the per-step loop)
-                            batch_size = self.config.batch_size
-                            it = self._make_iterator(
-                                x, y, batch_size, shuffle=shuffle,
-                                seed_offset=epoch_offset,
-                            )
-                            k = self._effective_steps_per_dispatch()
-                            break
-            finally:
-                win_it.close()
-            epoch += 1
-            if k == 1 and epoch < epochs:
-                # the recompiled backend has no fused program: finish the
-                # remaining epochs on the per-step loop, merging metrics
-                perf = (
-                    _perf_from_metric_values(macc)
-                    if macc is not None
-                    else PerfMetrics()
-                )
-                perf.update(self._fit_epochs(
-                    x, y, epochs - epoch, batch_size, shuffle, verbose,
-                    recompile_state, epoch_offset, it, rng, event_log,
-                    monitor, ckpt=ckpt, epoch_base=epoch, sup=sup,
-                ))
-                return perf
-        with record_span("fit/end"):
-            if loss is not None:
-                jax.block_until_ready(loss)
-            elapsed = time.perf_counter() - start
-            perf = (
-                _perf_from_metric_values(macc)
-                if macc is not None
-                else PerfMetrics()
-            )
-            _publish_routing(self.instance, macc)
-        if verbose:
-            print(
-                f"ELAPSED TIME = {elapsed:.4f}s, "
-                f"THROUGHPUT = {num_samples / max(elapsed, 1e-9):.2f} samples/s"
-            )
-        return perf
-
-    def _run_fused_window(
-        self, inputs_stack, label_stack, host_win, kk, rng, event_log,
-        monitor, batch_size, telem, macc, pf, epoch, sup, watchdog,
-    ):
-        """One fused window's in-armed-region work: dispatch, per-step
-        telemetry readback/emission, verbose prints, metric fold, and
-        the simulated-hang fault site — everything a real hang could
-        stall, and nothing the watchdog should not time (the checkpoint
-        snapshot and boundary bookkeeping happen back in the caller,
-        after the deadline is disarmed). Returns (rng, losses, macc)."""
-        win_t0 = time.perf_counter() if telem else None
-        pre_rng = rng
-        (
-            self.params, self.opt_state, rng, losses, mvals,
-            stat_stacks,
-        ) = self.instance.multi_train_step(
-            self.params, self.opt_state, inputs_stack,
-            label_stack, rng,
-        )
-        base_step = self._step_count
-        self._step_count += kk
-        if sup is not None:
-            # seeded "slow" soft-site (ISSUE 18): sleep before the window's
-            # telemetry readback, so the injected slowdown lands inside the
-            # window wall-clock the drift monitor observes
-            from flexflow_tpu.runtime.fault import inject_slow_fault
-
-            inject_slow_fault(sup.schedule, base_step, self._step_count)
-        losses_host = None
-        if telem:
-            # label elements per step, from the window's static
-            # shape (the per-step loop reads label.shape; the
-            # host window is only retained for the monitor)
-            tokens = (
-                int(np.prod(label_stack.shape[1:]))
-                if label_stack is not None
-                else batch_size
-            )
-            losses_host = self._emit_window_health(
-                event_log, monitor, base_step, losses,
-                stat_stacks, host_win, kk, win_t0, tokens,
-                pre_rng,
-            )
-        # the window's metric totals were left-folded inside the
-        # jitted program (same accumulation order and f32 device
-        # adds as the per-step loop); one add per window here
-        macc = (
-            mvals
-            if macc is None
-            else {key: macc[key] + v for key, v in mvals.items()}
-        )
-        if pf and base_step // pf != (base_step + kk) // pf:
-            # a print boundary fell inside this window: report
-            # from the window's already-read loss vector — the
-            # per-step loop's float(loss) would force an extra
-            # device sync against the in-flight pipeline
-            if losses_host is None:
-                losses_host = _read_losses_host(losses)
-            for i in range(kk):
-                if (base_step + i + 1) % pf == 0:
-                    print(
-                        f"epoch {epoch} step {base_step + i + 1}: "
-                        f"loss {float(losses_host[i]):.4f}"
-                    )
-        if sup is not None:
-            # the simulated-hang site lives INSIDE the armed window: a
-            # hung dispatch never reaches the window boundary
-            from flexflow_tpu.runtime.fault import inject_hang_fault
-
-            inject_hang_fault(
-                sup.schedule, base_step, self._step_count,
-                watchdog=watchdog,
-            )
-        return rng, losses, macc
-
-    def _emit_window_health(
-        self, event_log, monitor, base_step, losses, stat_stacks, host_win,
-        kk, win_t0, tokens, pre_rng,
-    ):
-        """Per-step event emission + policy enforcement for one fused
-        window: the loss and stat vectors are read back in ONE transfer
-        (the window's single host sync) and re-emitted as kk per-step
-        events. The window's wall-clock — measured at that first readback,
-        so it includes the device work — is apportioned equally over its
-        steps. Returns the host loss vector (reused by the verbose print).
-
-        Under `raise`, the scan froze the window at the first tripped step
-        (halt_on_nonfinite), so self.params already hold the pre-trip
-        values; the un-fused blame replay runs against them with the
-        offending step's exact batch and rng (re-derived by splitting the
-        window's carry-in key, matching the in-scan split stream)."""
-        import time as _time
-
-        from flexflow_tpu.observability.health import (
-            NonFiniteError,
-            record_step_health,
-        )
-        from flexflow_tpu.observability.metrics import split_window_stats
-
-        losses_host = np.asarray(jax.device_get(losses))
-        stats_host = (
-            jax.device_get(stat_stacks) if stat_stacks is not None else None
-        )
-        per_step_ms = (_time.perf_counter() - win_t0) * 1000.0 / kk
-        step_stats = split_window_stats(stats_host, kk)
-        r = pre_rng
-        for i in range(kk):
-            batch_i = label_i = None
-            if host_win is not None:
-                batch_i = {name: arr[i] for name, arr in host_win[0].items()}
-                label_i = (
-                    host_win[1][i] if host_win[1] is not None else None
-                )
-            if monitor is not None:
-                # the step's rng, for the localizer's train-mode replay
-                r, step_rng = jax.random.split(r)
-                self._last_step_rng = step_rng
-            try:
-                record_step_health(
-                    event_log, monitor, base_step + i + 1, losses_host[i],
-                    step_stats[i], batch=batch_i, label=label_i,
-                    tokens=tokens, wallclock_ms=per_step_ms,
-                )
-            except NonFiniteError:
-                # the per-step loop would have stopped HERE: steps past the
-                # trip were frozen inside the scan and never happened
-                self._step_count = base_step + i + 1
-                raise
-        return losses_host
-
     def set_learning_rate(self, lr: float) -> None:
         """Update the optimizer's learning rate mid-training (reference:
         Optimizer::set_learning_rate, driven by the keras
@@ -3378,7 +3059,6 @@ class FFModel:
             else:
                 self.instance.optimizer_attrs = self.optimizer_attrs
                 self.instance._jit_step = None
-                self.instance._jit_multi_step = None
 
     def eval(self, x=None, y=None, batch_size: Optional[int] = None) -> PerfMetrics:
         """Forward-only metric evaluation (reference FFModel.eval)."""
@@ -3522,15 +3202,6 @@ def _pulls(batches):
         if item is done:
             return
         yield item
-
-
-def _read_losses_host(losses) -> np.ndarray:
-    """Window loss-vector host readback. Lives OUTSIDE the `_fit_*` loop
-    drivers on purpose: LINT005 (analysis/source_lints.py) bans blocking
-    host transfers lexically inside the training-loop critical path —
-    sanctioned readbacks happen in named helpers like this one, where a
-    reviewer can see each sync point at a glance."""
-    return np.asarray(jax.device_get(losses))
 
 
 def _host_collective_throttle():

@@ -50,21 +50,12 @@ class FFConfig:
     # that picked it (observability/plan_audit.py); recorded in
     # FFModel.search_provenance["plan_audit"]
     plan_audit: bool = False
-    # fused multi-step dispatch (the Legion trace capture/replay analogue at
-    # the STEP-LOOP level): pack this many training steps into one donated
-    # XLA program — lax.scan over a stacked batch window, RNG split inside
-    # the scan, per-step loss/health stat vectors read back once per window.
-    # 1 = the classic one-jitted-step-per-Python-iteration loop.
-    # FF_TPU_FUSED_BASELINE=1 reverts to 1 in-process (perf regression
-    # tests). Epoch ends (and recompile triggers) end a window early: the
-    # tail runs as a smaller window.
-    steps_per_dispatch: int = 1
     # elastic runtime (runtime/checkpoint.py): checkpoint_dir enables
     # fit-loop checkpointing — full-resume snapshots (params, opt state,
     # RNG stream position, dataloader epoch + cursor) every
     # checkpoint_every_n_steps, written by a background thread overlapped
-    # with the next dispatch window (checkpoint_sync=True forces the
-    # blocking save path). fit(resume=True) restores the latest snapshot for a
+    # with the next step (checkpoint_sync=True forces the blocking save
+    # path). fit(resume=True) restores the latest snapshot for a
     # bitwise-identical continuation (chaos-tested via FF_TPU_FAULT_STEP).
     checkpoint_dir: str = ""
     checkpoint_every_n_steps: int = 0
@@ -114,7 +105,7 @@ class FFConfig:
     # piece residency exceeds it, candidate plans whose full liveness
     # timeline (analysis/memory_analysis.py) peaks above it are
     # INFEASIBLE, and the searched winner's per-device peaks are verified
-    # (MEM001-MEM004) into search_provenance["verify"]/["memory"].
+    # (MEM001-MEM003) into search_provenance["verify"]/["memory"].
     # 0 (default) = no search-side constraint; the winner's peaks are
     # still analyzed against the attached device's reported HBM limit
     # when the backend exposes one (memory_stats()["bytes_limit"]).
@@ -265,13 +256,6 @@ class FFConfig:
             "stops with the first bad op named (observability/health.py)",
         )
         p.add_argument(
-            "--steps-per-dispatch",
-            type=int,
-            default=1,
-            help="pack K training steps into one fused XLA dispatch "
-            "(lax.scan over a stacked batch window; 1 = per-step loop)",
-        )
-        p.add_argument(
             "--checkpoint-dir",
             type=str,
             default="",
@@ -358,7 +342,7 @@ class FFConfig:
             default=0.0,
             help="per-device HBM capacity in GiB (> 0): OOM mappings "
             "become INFEASIBLE in the machine-mapping search and the "
-            "winner is statically verified against it (MEM001-MEM004; "
+            "winner is statically verified against it (MEM001-MEM003; "
             "analysis/memory_analysis.py)",
         )
         p.add_argument(
@@ -489,7 +473,6 @@ class FFConfig:
             metrics_dir=getattr(args, "metrics_dir", ""),
             health_policy=getattr(args, "health_policy", "off"),
             plan_audit=getattr(args, "plan_audit", False),
-            steps_per_dispatch=getattr(args, "steps_per_dispatch", 1),
             checkpoint_dir=getattr(args, "checkpoint_dir", ""),
             checkpoint_every_n_steps=getattr(
                 args, "checkpoint_every_n_steps", 0

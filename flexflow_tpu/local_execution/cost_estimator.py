@@ -70,7 +70,6 @@ class LocalCostEstimator:
         settings: Optional[ProfilingSettings] = None,
         optimizer_state_slots: int = 2,
         cost_store=None,
-        steps_per_dispatch: int = 1,
         forward_only: bool = False,
         serving=None,
     ) -> None:
@@ -79,11 +78,6 @@ class LocalCostEstimator:
         FFModel optimizer family; SGD-momentum = 1, plain SGD = 0). Part of
         the memory model, so part of the cache key space — one estimator
         instance prices one optimizer regime.
-
-        steps_per_dispatch: the fused-dispatch window K. Input layers are
-        staged as ONE stacked [K, batch, ...] device buffer, so their
-        memory term is K x the per-step batch (analysis/memory_accounting —
-        the shared module this estimator's mem model now reads).
 
         forward_only (ISSUE 12, serving): measure the op's FORWARD kernel
         only — the regime a serving plan's prefill/decode programs run in.
@@ -94,7 +88,6 @@ class LocalCostEstimator:
         the ServingMemorySpec so mem_bytes prices inference residency."""
         self.settings = settings or ProfilingSettings(warmup_iters=2, measure_iters=4)
         self.optimizer_state_slots = optimizer_state_slots
-        self.steps_per_dispatch = max(int(steps_per_dispatch), 1)
         self.forward_only = bool(forward_only)
         self.serving = serving
         if self.forward_only and cost_store is not None:
@@ -119,14 +112,10 @@ class LocalCostEstimator:
         from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
 
         if isinstance(attrs, InputAttrs):
-            # no kernel, but real residency: the fused-dispatch window
-            # stages K batches as one stacked device buffer (the term the
-            # old accounting dropped — ISSUE 10 satellite)
+            # no kernel, but real residency: the step's batch
             from flexflow_tpu.analysis.memory_accounting import estimate_memory
 
-            mem = estimate_memory(
-                attrs, [], steps_per_dispatch=self.steps_per_dispatch
-            )
+            mem = estimate_memory(attrs, [])
             return CostDetails(0.0, mem.total)
         if is_parallel_op(attrs) or isinstance(attrs, WeightAttrs):
             # no kernel: parallel ops lower to sharding constraints, and
@@ -186,7 +175,6 @@ class LocalCostEstimator:
                 output_shapes=[
                     get_piece_shape(s) for s in parallel_output_shapes
                 ],
-                steps_per_dispatch=self.steps_per_dispatch,
             )
             return CostDetails(0.0, mem.total)
         pieces = [get_piece_shape(s) for s in parallel_input_shapes]
@@ -280,7 +268,6 @@ class LocalCostEstimator:
             weight_shapes,
             out_shapes,
             optimizer_state_slots=self.optimizer_state_slots,
-            steps_per_dispatch=self.steps_per_dispatch,
             serving=self.serving,
         )
         return CostDetails(elapsed_ms, mem.total)

@@ -167,81 +167,6 @@ def forward_interpreter(
     return env
 
 
-def fused_multi_step(instance, params, opt_state, batch_stack, label_stack, rng):
-    """K training steps as ONE donated XLA program: `lax.scan` over a
-    stacked `[k, ...]` batch window (the step-loop analogue of Legion trace
-    capture/replay — the reference amortizes per-iteration launch overhead
-    by replaying a captured trace; here K launches collapse into one).
-
-    Shared by ModelTrainingInstance and DistributedTrainingInstance (their
-    `_multi_step`s), so fused semantics can never diverge between the DP
-    and searched-PCG backends:
-
-    - The RNG splits INSIDE the scan exactly as the per-step fit loop
-      splits on the host (`rng, step_rng = jax.random.split(rng)` per
-      step), so a fused run consumes the identical key stream — dropout
-      masks and the returned carry key are bitwise those of K unfused
-      steps.
-    - Per-step loss / metric / run-health stat VECTORS come back stacked
-      `[k]`, one host readback per window; the skip_step guard still
-      applies inside each scan step (finalize_step), so a poisoned step's
-      update never reaches the parameters while later steps in the window
-      keep training.
-    - `instance.halt_on_nonfinite` (the `raise` policy) freezes
-      params/opt_state/key for the REST of the window after the first
-      tripped step: the post-window state is exactly the pre-trip state
-      the per-step loop would have stopped with, which is what the
-      un-fused blame replay needs.
-
-    Returns (params, opt_state, rng, losses[k], metric_stacks, stat_stacks
-    or None)."""
-    from flexflow_tpu.observability.metrics import guard_nonfinite
-
-    collect = instance.collect_step_stats
-    halt = getattr(instance, "halt_on_nonfinite", False)
-
-    def body(carry, xs):
-        params, opt_state, rng, halted = carry
-        batch, label = xs
-        next_rng, step_rng = jax.random.split(rng)
-        out = instance._step(params, opt_state, batch, label, step_rng)
-        if collect:
-            new_params, new_opt_state, loss, mvals, stats = out
-        else:
-            new_params, new_opt_state, loss, mvals = out
-            stats = None
-        if halt and stats is not None:
-            live = jnp.logical_not(halted)
-            new_params = guard_nonfinite(live, new_params, params)
-            new_opt_state = guard_nonfinite(live, new_opt_state, opt_state)
-            next_rng = jnp.where(live, next_rng, rng)
-            halted = jnp.logical_or(halted, jnp.logical_not(stats["ok"]))
-        ys = (loss, mvals, stats) if collect else (loss, mvals)
-        return (new_params, new_opt_state, next_rng, halted), ys
-
-    init = (params, opt_state, rng, jnp.zeros((), jnp.bool_))
-    (new_params, new_opt_state, new_rng, _), ys = jax.lax.scan(
-        body, init, (batch_stack, label_stack)
-    )
-    if collect:
-        losses, mstacks, stat_stacks = ys
-    else:
-        (losses, mstacks), stat_stacks = ys, None
-
-    def window_fold(v):
-        # the window's metric total as the same LEFT fold of f32/int device
-        # adds the per-step fit loop performs — inside this jit, so the
-        # host never indexes the stacked vector (a jnp gather per step per
-        # metric measurably dominated the fused loop on CPU meshes)
-        acc = v[0]
-        for i in range(1, v.shape[0]):
-            acc = acc + v[i]
-        return acc
-
-    mvals = jax.tree_util.tree_map(window_fold, mstacks)
-    return new_params, new_opt_state, new_rng, losses, mvals, stat_stacks
-
-
 class ModelTrainingInstance:
     """CG + loss + optimizer + metrics -> one jitted, donated train step.
 
@@ -283,10 +208,6 @@ class ModelTrainingInstance:
         self.compute_dtype = compute_dtype
         self.collect_step_stats = collect_step_stats or guard_nonfinite_updates
         self.guard_nonfinite_updates = guard_nonfinite_updates
-        # `raise` health policy under fused dispatch: freeze the rest of the
-        # window after the first non-finite step so the post-window state is
-        # the pre-trip state (set by FFModel.compile; see fused_multi_step)
-        self.halt_on_nonfinite = False
         # device-scalar dict from the latest train_step (collect_step_stats)
         self.last_step_stats = None
         # Extra scalar loss terms from the graph (e.g. the Experts op's
@@ -297,7 +218,6 @@ class ModelTrainingInstance:
         # share a fusion with the upstream norm's backward reductions
         self._barrier_nodes = frozenset({logit_tensor.node})
         self._jit_step = None
-        self._jit_multi_step = None
         self._jit_fwd = None
 
     def _cast_for_compute(self, tree):
@@ -381,35 +301,6 @@ class ModelTrainingInstance:
         if self._jit_step is None:
             self._jit_step = jax.jit(self._step, donate_argnums=(0, 1))
         return self._jit_step
-
-    def _multi_step(self, params, opt_state, batch_stack, label_stack, rng):
-        return fused_multi_step(
-            self, params, opt_state, batch_stack, label_stack, rng
-        )
-
-    def compiled_multi_step(self):
-        """The fused K-step window program (steps_per_dispatch > 1): one jit
-        object serves every window length — a different k retraces under
-        the new leading dim and caches alongside (the per-epoch tail
-        window compiles once)."""
-        if self._jit_multi_step is None:
-            self._jit_multi_step = jax.jit(
-                self._multi_step, donate_argnums=(0, 1)
-            )
-        return self._jit_multi_step
-
-    def multi_train_step(self, params, opt_state, batch_stack, label_stack, rng):
-        """K fused steps in one dispatch. The carry `rng` advances exactly
-        as K `train_step` calls advance the fit loop's key (split inside
-        the scan), so fused and per-step runs consume one RNG stream."""
-        k = jax.tree_util.tree_leaves(batch_stack)[0].shape[0]
-        with trace.record_span(
-            "step", backend=type(self).__name__, fused_steps=k
-        ):
-            with trace.record_span("dispatch"):
-                return self.compiled_multi_step()(
-                    params, opt_state, batch_stack, label_stack, rng
-                )
 
     def _record_stats(self, out):
         """Split the optional stats tail off the step result, keeping the

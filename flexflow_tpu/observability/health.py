@@ -313,17 +313,11 @@ def record_step_health(
     label=None,
     tokens: Optional[int] = None,
     step_t0: Optional[float] = None,
-    wallclock_ms: Optional[float] = None,
 ) -> bool:
     """The per-step telemetry wiring shared by FFModel.fit and
     instance-level training loops (examples/mlp.py): read the step's
     statistics, enforce the health policy, emit the JSONL event. Returns
     the step's finiteness.
-
-    `wallclock_ms` is the caller-attributed step time for steps whose
-    wall-clock is not directly observable — a fused window is ONE
-    dispatch, so the fused fit loop apportions the measured window time
-    over its K steps instead of passing `step_t0`.
 
     Ordering matters twice here: the wall-clock is captured at the FIRST
     host sync (reading `ok` materializes the step's device work) and
@@ -339,7 +333,7 @@ def record_step_health(
     wall_ms = (
         (time.perf_counter() - step_t0) * 1000.0
         if step_t0 is not None
-        else wallclock_ms
+        else None
     )
     health_err = None
     skipped = False

@@ -130,20 +130,6 @@ def finalize_step(
     return new_params, new_opt_state, stats
 
 
-def split_window_stats(stat_stacks, k: int) -> List[Optional[Dict[str, object]]]:
-    """Per-step stat dicts from a fused window's stacked stat vectors
-    (fused_multi_step reads the whole window back in ONE host transfer;
-    this reshapes {name: [k]} into k per-step {name: scalar} dicts so the
-    event log and health monitor keep their exact per-step contract).
-    `stat_stacks` may be device arrays or the np result of a device_get;
-    returns [None]*k when the window carried no stats."""
-    if stat_stacks is None:
-        return [None] * k
-    return [
-        {name: vec[i] for name, vec in stat_stacks.items()} for i in range(k)
-    ]
-
-
 def guard_nonfinite(ok, new_tree, old_tree):
     """Keep `old_tree` wherever the step went non-finite (the skip_step /
     raise policies: a NaN update must never reach the parameters). Traced

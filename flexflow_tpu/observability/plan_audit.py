@@ -238,7 +238,6 @@ def audit_plan(
     settings=None,
     top_n: int = 5,
     optimizer_state_slots: int = 2,
-    steps_per_dispatch: int = 1,
     fused_edges: Optional[Dict[int, str]] = None,
     overlap_predictions: Optional[Dict[int, float]] = None,
     movement_store=None,
@@ -290,7 +289,7 @@ def audit_plan(
     settings = settings or ProfilingSettings(warmup_iters=1, measure_iters=3)
     local = LocalCostEstimator(
         settings, optimizer_state_slots=optimizer_state_slots,
-        cost_store=cost_store, steps_per_dispatch=steps_per_dispatch,
+        cost_store=cost_store,
     )
     # pair-recording gate: the audit's predicted side is the pricing
     # estimator's own number; only an ANALYTIC prediction forms a valid

@@ -48,11 +48,9 @@ graph, the Pallas bodies).
 
 The jitted train step is ONE XLA program, so of a step the host sees only
 `dispatch` (the enqueue of the donated program). No span waits for the
-device: a traced job is the job, not a serialized copy of it. Under fused
-multi-step dispatch (steps_per_dispatch=K) the `step` span covers the whole
-K-step window and carries a `fused_steps` arg. Spans nest PER THREAD, so the
-producer thread's `host_to_device` transfers land beside (not inside) the
-consumer's `fit/next_batch` and `step` spans.
+device: a traced job is the job, not a serialized copy of it. Spans nest
+PER THREAD, so the checkpoint writer's `checkpoint` spans land beside (not
+inside) the fit thread's `fit/next_batch` and `step` spans.
 """
 
 from __future__ import annotations
@@ -250,7 +248,6 @@ HOST_SPANS = (
     "step",
     "dispatch",  # the enqueue of the step program, inside `step`
     "fit/end",  # the last wait, the metric conversion, the routing counters
-    "host_to_device",  # a window's transfer, on the producer thread
 )
 # events with no duration, counted by `count`
 STEP_TRACE = "step_trace"

@@ -56,10 +56,6 @@ class DataParallelTrainingInstance(ModelTrainingInstance):
         self.mesh = Mesh(np.array(devices), ("data",))
         self.replicated = NamedSharding(self.mesh, P())
         self.batch_sharded = NamedSharding(self.mesh, P("data"))
-        # stacked [k, batch, ...] windows (fused multi-step dispatch): the
-        # window dim is the scan axis and stays unsharded; batch rides
-        # "data" exactly as in the per-step program
-        self.window_sharded = NamedSharding(self.mesh, P(None, "data"))
 
     # -- dataloader hooks --------------------------------------------------
 
@@ -117,31 +113,3 @@ class DataParallelTrainingInstance(ModelTrainingInstance):
                 out_shardings=rep,
             )
         return self._jit_step
-
-    def compiled_multi_step(self):
-        if self._jit_multi_step is None:
-            from flexflow_tpu.kernels.flash_attention import (
-                flash_mesh,
-                interpret_default,
-            )
-
-            def multi_step_with_mesh_ctx(*args):
-                with flash_mesh(self.mesh, "data", None, interpret_default()):
-                    return self._multi_step(*args)
-
-            rep, win = self.replicated, self.window_sharded
-            self._jit_multi_step = jax.jit(
-                multi_step_with_mesh_ctx,
-                donate_argnums=(0, 1),
-                in_shardings=(
-                    rep,  # params
-                    rep,  # opt_state
-                    win,  # stacked batch window [k, batch, ...]
-                    win,  # stacked label window
-                    rep,  # rng
-                ),
-                # same output pinning as compiled_step (donated feedback
-                # loop must get replicated params back)
-                out_shardings=rep,
-            )
-        return self._jit_multi_step

@@ -837,8 +837,6 @@ class DistributedTrainingInstance:
         # host side, optional nonfinite guard for skip_step/raise policies)
         self.collect_step_stats = collect_step_stats or guard_nonfinite_updates
         self.guard_nonfinite_updates = guard_nonfinite_updates
-        # `raise` policy under fused dispatch (see fused_multi_step)
-        self.halt_on_nonfinite = False
         self.last_step_stats = None
         self.aux_loss_tensors = tuple(aux_loss_tensors)
         self.shardings = pcg_shardings(pcg, machine_mesh, mapping)
@@ -877,7 +875,6 @@ class DistributedTrainingInstance:
         self.update_shardings: Dict[str, object] = {}
         self.update_record: Optional[dict] = None
         self._jit_step = None
-        self._jit_multi_step = None
         self._jit_fwd = None
         # what the `step` span says of this backend, spelled once
         self._step_span_args = {
@@ -1103,36 +1100,6 @@ class DistributedTrainingInstance:
                 ),
             )
         return self._jit_step
-
-    def _multi_step(self, params, opt_state, batch_stack, label_stack, rng):
-        from flexflow_tpu.local_execution.training_backing import (
-            fused_multi_step,
-        )
-
-        return fused_multi_step(
-            self, params, opt_state, batch_stack, label_stack, rng
-        )
-
-    def compiled_multi_step(self):
-        """Fused K-step window over the searched PCG: the scan slices the
-        stacked window (placed by the dataloader under each input's
-        window sharding — leading scan dim unsharded, the PCG's own spec
-        behind it) and the per-step sharding constraints apply inside the
-        scan body unchanged."""
-        if self._jit_multi_step is None:
-            self._jit_multi_step = jax.jit(
-                self._multi_step, donate_argnums=(0, 1),
-                out_shardings=self._state_out_shardings(6),
-            )
-        return self._jit_multi_step
-
-    def multi_train_step(self, params, opt_state, batch_stack, label_stack, rng):
-        k = jax.tree_util.tree_leaves(batch_stack)[0].shape[0]
-        with trace.record_span("step", fused_steps=k, **self._step_span_args):
-            with self.machine_mesh.mesh, trace.record_span("dispatch"):
-                return self.compiled_multi_step()(
-                    params, opt_state, batch_stack, label_stack, rng
-                )
 
     def _record_stats(self, out):
         if self.collect_step_stats:

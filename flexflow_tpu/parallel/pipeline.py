@@ -15,10 +15,7 @@ XLA step program whose core is a `lax.scan` over the static 1F1B schedule
 - in-flight microbatch activations are stashed in a min(S, M)-slot
   modular arrival buffer; backwards REMATERIALIZE the stage forward from
   the stashed stage input (per-stage activation checkpointing), which is
-  what keeps the stash the 1F1B bound the static memory model charges;
-- the whole schedule composes with the PR-5 fused-dispatch machinery
-  unchanged: `_step` is an ordinary traceable step function, so
-  `fused_multi_step` scans K of them into one donated window program.
+  what keeps the stash the 1F1B bound the static memory model charges.
 
 Numerics contract (pinned by tests/test_pipeline.py): the pipelined step
 is BITWISE-identical — loss trajectory and final params — to the
@@ -369,10 +366,8 @@ class PipelinedTrainingInstance:
     """Stage-partitioned PCG + loss + optimizer -> 1F1B jitted train step.
 
     Duck-types the training-instance surface (`initialize` / `_step` /
-    `train_step` / `multi_train_step` / `compiled_step` /
-    `compiled_multi_step` / run-health stats), so the fit loop, the PR-5
-    fused windows, and the PR-7 checkpoint/resume machinery drive it
-    unchanged."""
+    `train_step` / `compiled_step` / run-health stats), so the fit loop
+    and the PR-7 checkpoint/resume machinery drive it unchanged."""
 
     def __init__(
         self,
@@ -396,7 +391,6 @@ class PipelinedTrainingInstance:
         self.compute_dtype = compute_dtype
         self.collect_step_stats = collect_step_stats or guard_nonfinite_updates
         self.guard_nonfinite_updates = guard_nonfinite_updates
-        self.halt_on_nonfinite = False
         self.last_step_stats = None
         self.unroll_schedule = bool(unroll_schedule)
         # lowering-compat surface (plan-audit/census helpers): the loss
@@ -437,7 +431,6 @@ class PipelinedTrainingInstance:
             S, self.structure.num_microbatches
         )
         self._jit_step = None
-        self._jit_multi_step = None
         self._jit_fwd = None
         # what the `step` span says of this backend, spelled once
         self._step_span_args = {
@@ -692,25 +685,6 @@ class PipelinedTrainingInstance:
             self._jit_step = jax.jit(self._step, donate_argnums=(0, 1))
         return self._jit_step
 
-    def _multi_step(self, params, opt_state, batch_stack, label_stack, rng):
-        from flexflow_tpu.local_execution.training_backing import (
-            fused_multi_step,
-        )
-
-        return fused_multi_step(
-            self, params, opt_state, batch_stack, label_stack, rng
-        )
-
-    def compiled_multi_step(self):
-        """The PR-5 fused window pointed at the 1F1B schedule: K whole
-        schedules run in ONE donated program (scan over steps around the
-        scan over ticks)."""
-        if self._jit_multi_step is None:
-            self._jit_multi_step = jax.jit(
-                self._multi_step, donate_argnums=(0, 1)
-            )
-        return self._jit_multi_step
-
     def _record_stats(self, out):
         if self.collect_step_stats:
             self.last_step_stats = out[4]
@@ -726,14 +700,6 @@ class PipelinedTrainingInstance:
                     params, opt_state, batch_inputs, label, rng
                 )
         return self._record_stats(out)
-
-    def multi_train_step(self, params, opt_state, batch_stack, label_stack, rng):
-        k = jax.tree_util.tree_leaves(batch_stack)[0].shape[0]
-        with trace.record_span("step", fused_steps=k, **self._step_span_args):
-            with self.mesh, trace.record_span("dispatch"):
-                return self.compiled_multi_step()(
-                    params, opt_state, batch_stack, label_stack, rng
-                )
 
     def forward(self, params, batch_inputs):
         """Inference: the sequential microbatch forward (no schedule)."""

@@ -2,8 +2,8 @@
 `soak_schedule` reports one schedule's run as a dict).
 
 The contract being soaked: for EVERY seeded `FaultSchedule` — a ckpt-write
-I/O fault, a producer-thread death, an injected NaN, a simulated hang, a
-kill+resume preemption — the run either completes (the fault was absorbed
+I/O fault, an injected NaN, a simulated hang, a kill+resume
+preemption — the run either completes (the fault was absorbed
 transparently) or dies with a structured error and, after
 `fit(resume=True)`, ends with BITWISE-identical final params and Adam
 moments versus the fault-free reference run. That is the strongest
@@ -11,8 +11,8 @@ statement "the supervision layer works" can make: detection fires, the
 diagnosis is structured, and recovery loses nothing.
 
 The harness is deliberately model-agnostic: callers hand it a
-`build(metrics_dir, checkpoint_dir)` factory (DP or searched-PCG backend,
-fused or per-step) and a reference final state; `soak_schedule` installs
+`build(metrics_dir, checkpoint_dir)` factory (DP or searched-PCG backend)
+and a reference final state; `soak_schedule` installs
 the schedule, runs, recovers, and reports. Seeds are found
 deterministically with `fault.find_seed`, so every process derives the
 same schedules without storing magic numbers.

@@ -24,8 +24,8 @@ Three layers:
    cheap device-side copy of the state (donated step buffers cannot
    invalidate it), kicks off the D2H transfer non-blocking, and returns;
    the gather + serialization + atomic rename run on the writer thread,
-   overlapped with the next fused dispatch window and visible as a
-   `checkpoint` span on the Chrome trace.
+   overlapped with the next steps and visible as a `checkpoint` span on
+   the Chrome trace.
 3. `TrainingCheckpointer` — the fit()-loop session: interval policy
    (`checkpoint_every_n_steps`), full-resume snapshots (params, opt state,
    RNG stream position, dataloader epoch + within-epoch cursor), and
@@ -748,8 +748,8 @@ class AsyncCheckpointWriter:
                     from flexflow_tpu.observability.trace import record_span
 
                     # the span lands on the writer thread's timeline row,
-                    # BESIDE the consumer's step spans — the overlap with
-                    # the next fused window is directly visible
+                    # BESIDE the fit thread's step spans — the overlap with
+                    # the next steps is directly visible
                     with record_span(
                         "checkpoint",
                         step=step,
@@ -832,12 +832,10 @@ class TrainingCheckpointer:
         )
 
     def due(self, prev_step: int, step: int) -> bool:
-        """True when [prev_step, step] crossed an interval boundary — under
-        fused dispatch a window advances several steps at once, so the
-        check is a crossing, not a modulo. Also the async writer's
-        surfacing point: a commit that failed (retries exhausted) since
-        the last boundary raises HERE, one window later, instead of
-        hiding until final wait()."""
+        """True when [prev_step, step] crossed an interval boundary. Also
+        the async writer's surfacing point: a commit that failed (retries
+        exhausted) since the last boundary raises HERE, one step later,
+        instead of hiding until final wait()."""
         if self._writer is not None:
             self._writer.check()
         if self.every <= 0:
@@ -854,7 +852,7 @@ class TrainingCheckpointer:
         batch_in_epoch: int,
         epoch_offset: int = 0,
     ) -> None:
-        """Snapshot at a step/window boundary. `rng` is the fit loop's
+        """Snapshot at a step boundary. `rng` is the fit loop's
         POST-step carry key (the exact stream position the next step will
         split from); the dataloader cursor pins the shuffle position. On
         the async path the key is materialized on the WRITER thread — a

@@ -4,15 +4,15 @@ Two generations of trigger, both active:
 
 1. `FF_TPU_FAULT_STEP=N` (PR 7) — the single-kill switch: raise
    `SimulatedFault` as soon as training progress crosses step N, after
-   that step's (or window's) state update has landed, mirroring a
-   preemption that kills the process between dispatches. The trigger is a
+   that step's state update has landed, mirroring a preemption that
+   kills the process between dispatches. The trigger is a
    CROSSING (prev_step < N <= step), not a threshold, so a resumed run
    restarting below N does not re-raise forever.
 
 2. `FF_TPU_FAULT_SPEC` (this PR) — a seeded *schedule* of faults at named
    sites, e.g.::
 
-       FF_TPU_FAULT_SPEC="seed=7;sites=ckpt_write,h2d,nonfinite,hang;rate=0.02"
+       FF_TPU_FAULT_SPEC="seed=7;sites=ckpt_write,nonfinite,hang;rate=0.02"
 
    Each (site, step) decision is a pure hash of (seed, site, step): the
    same spec fires at the same steps in every process, every run — which
@@ -24,14 +24,10 @@ Two generations of trigger, both active:
                    checkpoint commit rename — absorbed by the
                    runtime/retry.py backoff (escalates only if the
                    filesystem really is down).
-   - `h2d`         the input-pipeline producer thread dies with an
-                   InjectedFault while building the window — surfaced to
-                   the training thread through the FaultChannel /
-                   producer-liveness check (runtime/supervisor.py).
-   - `nonfinite`   the step's host batch is poisoned with a NaN before
-                   the device transfer — the run-health policies
+   - `nonfinite`   the batch the step is about to consume is poisoned
+                   with a NaN — the run-health policies
                    (--health-policy raise/skip_step) own the reaction.
-   - `hang`        the window boundary blocks like a hung dispatch until
+   - `hang`        the step boundary blocks like a hung dispatch until
                    the watchdog deadline fires (WindowWatchdog
                    .simulate_hang) — requires an armed watchdog.
    - `kill`        SimulatedFault at the boundary (the FF_TPU_FAULT_STEP
@@ -54,7 +50,7 @@ FAULT_SPEC_ENV = "FF_TPU_FAULT_SPEC"
 
 #: The injectable fault sites, in pipeline order (the README taxonomy
 #: table documents each site's detection + recovery path).
-FAULT_SITES = ("ckpt_write", "h2d", "nonfinite", "hang", "kill")
+FAULT_SITES = ("ckpt_write", "nonfinite", "hang", "kill")
 
 #: Soft perturbation sites (ISSUE 18): schedule-driven degradations that
 #: do NOT fault the run — they bend its telemetry. Kept out of
@@ -83,7 +79,7 @@ class SimulatedFault(RuntimeError):
 
 
 class InjectedFault(OSError):
-    """A schedule-injected I/O-shaped fault (sites `ckpt_write`, `h2d`).
+    """A schedule-injected I/O-shaped fault (site `ckpt_write`).
     Subclasses OSError on purpose: the transient-retry machinery
     (runtime/retry.py) must treat it exactly like the real flaky
     filesystem it simulates."""
@@ -248,7 +244,7 @@ def active_schedule() -> Optional[FaultSchedule]:
     return _ENV_CACHE[1]
 
 
-# -- boundary hooks (the fit loops) -----------------------------------------
+# -- boundary hooks (the fit loop) ------------------------------------------
 
 
 def fault_step() -> Optional[int]:
@@ -258,11 +254,34 @@ def fault_step() -> Optional[int]:
 
 def maybe_inject_fault(prev_step: int, step: int) -> None:
     """Raise SimulatedFault when [prev_step, step] crossed the configured
-    fault step. Called by the fit loops after each completed step/window —
-    i.e. after checkpoint hooks, so a due checkpoint survives the fault."""
+    fault step. Called by the fit loop after each completed step — i.e.
+    after checkpoint hooks, so a due checkpoint survives the fault."""
     n = fault_step()
     if n is not None and prev_step < n <= step:
         raise SimulatedFault(step)
+
+
+def inject_nonfinite_fault(
+    schedule: Optional[FaultSchedule], step: int, batch: dict
+) -> dict:
+    """Schedule site `nonfinite` for the batch step `step` is about to
+    consume: the first element of every floating input becomes NaN, so the
+    run-health policies see a genuinely non-finite step. Returns the batch
+    (the same dict when the site does not fire)."""
+    if schedule is None or not schedule.fire_once("nonfinite", step):
+        return batch
+    import jax.numpy as jnp
+    import numpy as np
+
+    from flexflow_tpu.runtime.distributed import device_put_global
+
+    poisoned = dict(batch)
+    for name, arr in batch.items():
+        if jnp.issubdtype(arr.dtype, jnp.floating):
+            host = np.array(arr)
+            host.reshape(-1)[0] = np.nan
+            poisoned[name] = device_put_global(host, arr.sharding)
+    return poisoned
 
 
 def inject_hang_fault(
@@ -273,7 +292,7 @@ def inject_hang_fault(
 ) -> None:
     """Schedule site `hang` for the window that computed steps
     (prev_step, step]. Fired INSIDE the armed watchdog window (the fit
-    loops call this before disarming): a hung dispatch never reaches the
+    loop calls this before disarming): a hung dispatch never reaches the
     window boundary, so neither does the simulation — the boundary's
     checkpoint snapshot correctly does not happen. Blocks via the
     watchdog's cooperative simulation and raises WindowHangError when
@@ -338,7 +357,7 @@ def inject_boundary_faults(
     watchdog=None,
 ) -> None:
     """Both schedule-driven boundary sites in one call (hang, then
-    kill) — the standalone-harness convenience; the fit loops call the
+    kill) — the standalone-harness convenience; the fit loop calls the
     two halves separately so the hang rides inside the armed window and
     the kill after the checkpoint hook."""
     inject_hang_fault(schedule, prev_step, step, watchdog=watchdog)
@@ -360,6 +379,7 @@ __all__ = [
     "inject_boundary_faults",
     "inject_hang_fault",
     "inject_kill_fault",
+    "inject_nonfinite_fault",
     "inject_slow_fault",
     "install_schedule",
     "maybe_inject_fault",

@@ -3,14 +3,14 @@
 PR 7 gave the elastic runtime *recovery* (async checkpoints, bitwise
 resume, degraded-grid re-search) but almost no *detection*: a hung
 dispatch window blocks the training thread forever, and exceptions on the
-background writer/producer threads could die silently or surface only at
+background writer thread could die silently or surface only at
 teardown. The reference's Legion runtime survives because task failures
 are first-class events routed to the mapper (PAPER.md §0); this module is
 the JAX-native equivalent — a supervision layer that turns hangs and
 thread deaths into structured, recoverable events:
 
 - `FaultChannel` — the shared mailbox background threads (the async
-  checkpoint writer, the H2D producer) post their exceptions into; the
+  checkpoint writer, the drift monitor) post their exceptions into; the
   fit loop drains it at every window boundary, so a background failure
   surfaces within one window as a `BackgroundFault` naming the site
   instead of at final `wait()` (or never).
@@ -44,7 +44,7 @@ from typing import Callable, List, Optional, Tuple
 
 
 class BackgroundFault(RuntimeError):
-    """A background supervision event: the exception a producer/writer
+    """A background supervision event: the exception a background
     thread died with, re-raised on the training thread with the fault
     site named. The original exception rides `original` (and
     `__cause__`)."""

@@ -11,7 +11,7 @@ Layering (each importable without the ones below it):
 - `kv_cache` — the cache as a parallel tensor: degrees bound to the
   plan's sharding, lowered via SNIPPETS-[1]-style regex partition rules.
 - `program` — the lowered runtime: one donated prefill program + a
-  `lax.scan` fused decode window (the PR-5 dispatch-fusion pattern).
+  `lax.scan` fused decode window.
 - `engine` — request queue, continuous batching at decode-window
   boundaries, watchdog/FaultChannel replica shedding, JSONL request
   metrics with an SLO-violation counter.

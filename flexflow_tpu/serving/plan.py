@@ -178,7 +178,6 @@ def serving_search_context(
         overlap_fraction=0.5,
         memory_budget_bytes=(hbm_gb * 2**30 if hbm_gb and hbm_gb > 0 else 0.0),
         optimizer_state_slots=0,
-        steps_per_dispatch=1,
         serving=cache_spec,
     ), cost_store
 

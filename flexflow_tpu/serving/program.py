@@ -9,9 +9,8 @@ causal attention:
   K/V written into the slots being admitted; the last valid position's
   logits seed generation. One donated jit — the cache buffer is reused
   in place.
-- **decode window**: `lax.scan` over W single-token steps — the PR-5
-  fused-dispatch pattern (`training_backing.fused_multi_step`) pointed
-  at decode: W kernel launches collapse into one dispatch, the cache and
+- **decode window**: `lax.scan` over W single-token steps: W kernel
+  launches collapse into one dispatch, the cache and
   the per-slot length/token state ride the scan carry, and greedy
   (argmax) sampling feeds each step's token to the next.
 

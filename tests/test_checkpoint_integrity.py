@@ -297,7 +297,7 @@ class TestLegacyLayouts:
 class TestFallbackInFit:
     def _build(self, mdir, cdir):
         cfg = FFConfig(
-            batch_size=16, seed=0, steps_per_dispatch=4, print_freq=0,
+            batch_size=16, seed=0, print_freq=0,
             metrics_dir=mdir, checkpoint_dir=cdir,
             checkpoint_every_n_steps=4, checkpoint_backend="npz",
         )

@@ -26,14 +26,17 @@ END_TO_END = [m["name"] for m in MANIFEST["end_to_end"]]
 PER_LAYER = [m["name"] for m in MANIFEST["per_layer"]]
 CONFIGS = [c["name"] for c in MANIFEST["configs"]]
 
-# what PR 28 deleted; the README must not send a reader to any of it (the
-# claims checker's name is written in halves: a grep for it finds nothing)
+# what PR 28 and PR 42 deleted; the README must not send a reader to any of
+# it (the claims checker's name is written in halves: a grep for it finds
+# nothing)
 DELETED = (
     "bench.py", "bench_ab.py", "merge_ab.py", "profile_bench.py",
     "bench_flash_pair.py", "check_artifact" "_claims.py", "roofline.py",
     "cost_attribution.py", "AB_r03", "AB_r04", "AB_r05", "AUDIT_r06",
     "BENCH_COSTDB_r10", "BENCH_FUSED_r06", "BENCH_OVERLAP_r07", "CHAOS_r08",
     "CHAOS_r09", "PIPE_r14", "SERVE_r13", "SLICE_r17",
+    "steps_per_dispatch", "steps-per-dispatch", "FF_TPU_FUSED_BASELINE",
+    "WindowedBatchIterator", "fused_multi_step", "MEM004",
 )
 
 
@@ -101,10 +104,10 @@ def test_readme_names_only_files_that_exist():
     assert not missing, f"README.md names files that do not exist: {missing}"
 
 
-def test_readme_names_nothing_pr28_deleted():
+def test_readme_names_nothing_deleted():
     readme = _read("README.md")
     named = [
         name for name in DELETED
         if re.search(rf"(?<![A-Za-z0-9_]){re.escape(name)}", readme)
     ]
-    assert not named, f"README.md still names deleted files: {named}"
+    assert not named, f"README.md still names what was deleted: {named}"

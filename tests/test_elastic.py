@@ -1,16 +1,15 @@
 """Elastic training runtime (ISSUE 7): async checkpointing, deterministic
 preemption recovery, degraded-grid re-search.
 
-The chaos contract: a run killed mid-window via FF_TPU_FAULT_STEP and
+The chaos contract: a run killed mid-epoch via FF_TPU_FAULT_STEP and
 resumed with fit(resume=True) produces a BITWISE-identical loss trajectory
 (and bitwise final params) to an uninterrupted run — on both the DP and
-searched-PCG backends, per-step and under fused steps_per_dispatch>1, with
-dropout in the DP model so the restored RNG stream position is
-load-bearing. The degraded-grid contract: shrinking the device grid after
-a failure re-runs the machine-mapping search, re-shards the restored
-checkpoint onto the new mesh, verifies the new plan, keeps training, and
-records the transition in search_provenance["recovery"] + the JSONL
-metrics stream.
+searched-PCG backends, with dropout in the DP model so the restored RNG
+stream position is load-bearing. The degraded-grid contract: shrinking the
+device grid after a failure re-runs the machine-mapping search, re-shards
+the restored checkpoint onto the new mesh, verifies the new plan, keeps
+training, and records the transition in search_provenance["recovery"] + the
+JSONL metrics stream.
 """
 
 import os
@@ -38,12 +37,12 @@ def _data(seed=0):
     return rs.randn(N, 32).astype(np.float32), rs.randint(0, 10, N)
 
 
-def _build(k=1, budget=-1, metrics_dir="", ckpt_dir="", every=0,
+def _build(budget=-1, metrics_dir="", ckpt_dir="", every=0,
            dropout=None, sync=False):
     if dropout is None:
         dropout = budget <= 0  # stochastic op on the DP backend only
     cfg = FFConfig(
-        batch_size=BATCH, seed=0, steps_per_dispatch=k, print_freq=0,
+        batch_size=BATCH, seed=0, print_freq=0,
         search_budget=budget, metrics_dir=metrics_dir,
         checkpoint_dir=ckpt_dir, checkpoint_every_n_steps=every,
         checkpoint_sync=sync,
@@ -81,28 +80,26 @@ def _assert_params_bitwise(ref, other):
 
 
 class TestChaosResume:
-    """Kill mid-window, resume, compare against uninterrupted: bitwise."""
+    """Kill mid-epoch, resume, compare against uninterrupted: bitwise."""
 
     @pytest.mark.parametrize(
-        "k,budget",
-        [(4, -1), (1, -1), (4, 2)],
-        ids=["dp-fused-k4", "dp-per-step", "searched-fused-k4"],
+        "budget", [-1, 2], ids=["dp-per-step", "searched-per-step"]
     )
-    def test_kill_and_resume_bitwise_trajectory(self, monkeypatch, k, budget):
+    def test_kill_and_resume_bitwise_trajectory(self, monkeypatch, budget):
         xv, yv = _data()
 
         # uninterrupted reference — ALSO checkpointing, so the async writer
         # itself is proven not to perturb the trajectory
         d1, c1 = tempfile.mkdtemp(), tempfile.mkdtemp()
-        m1 = _build(k=k, budget=budget, metrics_dir=d1, ckpt_dir=c1, every=8)
+        m1 = _build(budget=budget, metrics_dir=d1, ckpt_dir=c1, every=8)
         m1.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
         ref = _losses_by_step(d1)
         assert sorted(ref) == list(range(1, 2 * STEPS_PER_EPOCH + 1))
 
-        # chaos run: fault crosses step 10 (mid-epoch-2 window under k=4),
-        # last checkpoint at step 8 -> resume re-runs steps 9..16
+        # chaos run: fault crosses step 10 (mid-epoch-2), last checkpoint
+        # at step 8 -> resume re-runs steps 9..16
         d2, c2 = tempfile.mkdtemp(), tempfile.mkdtemp()
-        m2 = _build(k=k, budget=budget, metrics_dir=d2, ckpt_dir=c2, every=8)
+        m2 = _build(budget=budget, metrics_dir=d2, ckpt_dir=c2, every=8)
         monkeypatch.setenv("FF_TPU_FAULT_STEP", "10")
         with pytest.raises(SimulatedFault):
             m2.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
@@ -114,7 +111,7 @@ class TestChaosResume:
         # the execution contract rides the checkpoint dir (ISSUE 14)
         assert "exec_contract.json" in os.listdir(c2)
 
-        m2b = _build(k=k, budget=budget, metrics_dir=d2, ckpt_dir=c2, every=8)
+        m2b = _build(budget=budget, metrics_dir=d2, ckpt_dir=c2, every=8)
         m2b.fit(xv, yv, epochs=2, shuffle=True, verbose=False, resume=True)
         got = _losses_by_step(d2)
         assert sorted(got) == sorted(ref)
@@ -135,7 +132,7 @@ class TestChaosResume:
         not re-emitted (no double training on the same data)."""
         xv, yv = _data()
         d, c = tempfile.mkdtemp(), tempfile.mkdtemp()
-        m = _build(k=1, metrics_dir=d, ckpt_dir=c, every=8)
+        m = _build(metrics_dir=d, ckpt_dir=c, every=8)
         monkeypatch.setenv("FF_TPU_FAULT_STEP", "10")
         with pytest.raises(SimulatedFault):
             m.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
@@ -143,7 +140,7 @@ class TestChaosResume:
         before = len(
             [e for e in read_events(d) if "step" in e]
         )  # 10 events (steps 1..10)
-        m2 = _build(k=1, metrics_dir=d, ckpt_dir=c, every=8)
+        m2 = _build(metrics_dir=d, ckpt_dir=c, every=8)
         m2.fit(xv, yv, epochs=2, shuffle=True, verbose=False, resume=True)
         resumed = [e["step"] for e in read_events(d) if "step" in e][before:]
         assert resumed == list(range(9, 17))  # 9..16, nothing below 9
@@ -153,15 +150,15 @@ class TestChaosResume:
         same bitwise resume."""
         xv, yv = _data()
         d1 = tempfile.mkdtemp()
-        m1 = _build(k=4, metrics_dir=d1)
+        m1 = _build(metrics_dir=d1)
         m1.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
         d2, c2 = tempfile.mkdtemp(), tempfile.mkdtemp()
-        m2 = _build(k=4, metrics_dir=d2, ckpt_dir=c2, every=8, sync=True)
+        m2 = _build(metrics_dir=d2, ckpt_dir=c2, every=8, sync=True)
         monkeypatch.setenv("FF_TPU_FAULT_STEP", "10")
         with pytest.raises(SimulatedFault):
             m2.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
         monkeypatch.delenv("FF_TPU_FAULT_STEP")
-        m2b = _build(k=4, metrics_dir=d2, ckpt_dir=c2, every=8, sync=True)
+        m2b = _build(metrics_dir=d2, ckpt_dir=c2, every=8, sync=True)
         m2b.fit(xv, yv, epochs=2, shuffle=True, verbose=False, resume=True)
         ref, got = _losses_by_step(d1), _losses_by_step(d2)
         assert ref == got
@@ -250,13 +247,57 @@ class TestResumeSemantics:
         assert CheckpointManager(c, backend="npz").all_steps() == [4, 8]
 
 
+class TestBatchIteratorCursor:
+    """The one batch iterator's resume cursor: what `fit(resume=True)`
+    stands on."""
+
+    def _it(self):
+        from flexflow_tpu.core.dataloader import BatchIterator
+
+        xv, yv = _data()
+        return BatchIterator(
+            {"x": xv}, yv.astype(np.int32), BATCH, shuffle=True, seed=7
+        )
+
+    @staticmethod
+    def _epoch(it):
+        return [(np.asarray(b["x"]), np.asarray(l)) for b, l in it]
+
+    def test_resume_skip_yields_the_tail_of_the_same_permutation(self):
+        whole = self._it()
+        first, second = self._epoch(whole), self._epoch(whole)
+        resumed = self._it()
+        resumed.set_resume_skip(3)
+        tail = self._epoch(resumed)
+        assert len(tail) == STEPS_PER_EPOCH - 3
+        for (xa, ya), (xb, yb) in zip(first[3:], tail):
+            np.testing.assert_array_equal(xa, xb)
+            np.testing.assert_array_equal(ya, yb)
+        # the skip is one shot: the next epoch is whole, and the same
+        for (xa, ya), (xb, yb) in zip(second, self._epoch(resumed)):
+            np.testing.assert_array_equal(xa, xb)
+            np.testing.assert_array_equal(ya, yb)
+
+    def test_advance_epochs_burns_the_permutations_of_finished_epochs(self):
+        whole = self._it()
+        self._epoch(whole)
+        second = self._epoch(whole)
+        resumed = self._it()
+        resumed.advance_epochs(1)
+        got = self._epoch(resumed)
+        assert len(got) == len(second) == STEPS_PER_EPOCH
+        for (xa, ya), (xb, yb) in zip(second, got):
+            np.testing.assert_array_equal(xa, xb)
+            np.testing.assert_array_equal(ya, yb)
+
+
 class TestCheckpointTrace:
     def test_async_checkpoint_span_on_writer_thread(self):
         """The `checkpoint` span lands on the Chrome trace, on a DIFFERENT
         thread row than the consumer's step spans — the serialization is
-        visibly off the critical path, overlapped with the next window."""
+        visibly off the critical path, overlapped with the next steps."""
         c = tempfile.mkdtemp()
-        m = _build(k=4, ckpt_dir=c, every=4)
+        m = _build(ckpt_dir=c, every=4)
         xv, yv = _data()
         rec = TraceRecorder()
         prev = set_recorder(rec)
@@ -275,7 +316,7 @@ class TestCheckpointTrace:
 
     def test_sync_checkpoint_span_on_main_thread(self):
         c = tempfile.mkdtemp()
-        m = _build(k=4, ckpt_dir=c, every=4, sync=True)
+        m = _build(ckpt_dir=c, every=4, sync=True)
         xv, yv = _data()
         rec = TraceRecorder()
         prev = set_recorder(rec)
@@ -291,8 +332,7 @@ class TestCheckpointTrace:
 
 class TestDegradedGridRecovery:
     def _train_one_epoch(self, budget, mdir, cdir):
-        m = _build(
-            k=1, budget=budget, metrics_dir=mdir, ckpt_dir=cdir, every=4,
+        m = _build(budget=budget, metrics_dir=mdir, ckpt_dir=cdir, every=4,
             dropout=False,
         )
         xv, yv = _data()

@@ -383,8 +383,9 @@ class TestPlanContract:
     def test_catalog_covers_exec_rules(self):
         for rid in EXEC_RULE_IDS:
             assert rid in PCG_RULE_CATALOG
-        # ISSUE 19 grows the catalog to 32 verifier rules (TRN001-TRN004)
-        assert len(PCG_RULE_CATALOG) == 32
+        # ISSUE 19 grew the catalog to 32 verifier rules (TRN001-TRN004);
+        # PR 42 took MEM004 out with the dispatch window it judged
+        assert len(PCG_RULE_CATALOG) == 31
 
 
 def test_pipelined_plan_contract():

@@ -51,6 +51,57 @@ class TestBuildCompileFit:
         assert 0.0 <= perf.accuracy <= 1.0
 
 
+ONE_LOOP_BACKENDS = {
+    "ModelTrainingInstance": dict(max_devices=1),
+    "DataParallelTrainingInstance": dict(),
+    "DistributedTrainingInstance": dict(search_budget=2),
+    "PipelinedTrainingInstance": dict(
+        search_budget=1, pipeline=True, force_strategy_seed="pp2m4xdp4"
+    ),
+}
+
+
+@pytest.mark.parametrize("backend", sorted(ONE_LOOP_BACKENDS))
+def test_fit_is_one_train_step_a_batch_on_every_backend(backend, monkeypatch):
+    """The one fit loop asks a backend for `train_step`, once a batch, each
+    time with the next key of the loop's stream, and for nothing else."""
+    import jax
+
+    cfg = FFConfig(
+        batch_size=16, seed=0, print_freq=0, **ONE_LOOP_BACKENDS[backend]
+    )
+    m = FFModel(cfg)
+    h = m.create_tensor([16, 16], name="x")
+    for i in range(4):
+        h = m.relu(m.dense(h, 16, name=f"fc{i}"))
+    m.compile(
+        AdamOptimizer(alpha=1e-2), "sparse_categorical_crossentropy",
+        logit_tensor=h,
+    )
+    assert type(m.instance).__name__ == backend
+    calls = []
+    train_step = m.instance.train_step
+
+    def counted(params, opt_state, batch, label, rng):
+        calls.append(np.asarray(jax.random.key_data(rng)).tolist())
+        return train_step(params, opt_state, batch, label, rng)
+
+    monkeypatch.setattr(m.instance, "train_step", counted)
+    rs = np.random.RandomState(0)
+    xs = rs.randn(64, 16).astype(np.float32)
+    ys = rs.randint(0, 16, 64)
+    m.fit(xs, ys, epochs=2, shuffle=True, verbose=False)
+    assert len(calls) == m._step_count == 2 * 4
+    key = jax.random.fold_in(jax.random.PRNGKey(0), 0)
+    expected = []
+    for _ in range(8):
+        key, step_key = jax.random.split(key)
+        expected.append(np.asarray(jax.random.key_data(step_key)).tolist())
+    assert calls == expected
+    for v in jax.tree_util.tree_leaves(m.params):
+        assert np.isfinite(np.asarray(v)).all()
+
+
 class TestTensorRoundTrip:
     def test_get_set_weights(self):
         m, x, out = build_mlp()

@@ -70,7 +70,7 @@ def emitted():
     return {
         "single": _compile_and_fit(max_devices=1),
         "searched": _compile_and_fit(max_devices=4, search_budget=2),
-        "fused": _compile_and_fit(max_devices=1, steps_per_dispatch=2),
+        "dp": _compile_and_fit(max_devices=4),
     }
 
 
@@ -88,7 +88,6 @@ PARENT = {
     "step": "fit",
     "dispatch": "step",
     "fit/end": "fit",
-    "host_to_device": None,  # the producer thread's own line
 }
 SEARCH_ONLY = {"compile/search", "compile/verify", "compile/lower_step"}
 
@@ -103,14 +102,12 @@ def test_every_host_span_is_emitted_where_the_module_says(emitted, name):
     assert backends == {
         "single": "ModelTrainingInstance",
         "searched": "DistributedTrainingInstance",
-        "fused": "ModelTrainingInstance",
+        "dp": "DataParallelTrainingInstance",
     }
-    if name == "host_to_device":
-        jobs = ["fused"]
-    elif name in SEARCH_ONLY:
+    if name in SEARCH_ONLY:
         jobs = ["searched"]
     else:
-        jobs = ["single", "searched", "fused"]
+        jobs = ["single", "searched", "dp"]
     for job in jobs:
         parents = emitted[job][1]
         assert parents.get(name) == {PARENT[name]}, (job, parents.get(name))
@@ -140,14 +137,36 @@ class TestSpanTotals:
         assert fit["total_s"] == fit["longest_s"] >= step["total_s"] > 0
         assert step["longest_s"] <= step["total_s"]
 
-    def test_fused_window_is_one_step_span(self):
-        m = _model(max_devices=1, steps_per_dispatch=2)
-        trace.reset_span_totals()
+    @pytest.mark.parametrize(
+        "backend,config",
+        [
+            ("ModelTrainingInstance", dict(max_devices=1)),
+            ("DataParallelTrainingInstance", dict(max_devices=4)),
+            ("DistributedTrainingInstance",
+             dict(max_devices=4, search_budget=2)),
+        ],
+        ids=["single", "dp", "searched"],
+    )
+    def test_a_step_span_is_one_step_and_holds_its_dispatch(
+        self, recorder, backend, config
+    ):
+        """The one loop: a `step` span a step, nothing under it but the
+        enqueue, and the batch's transfer inside `fit/next_batch` (no span,
+        and no thread, of its own)."""
+        m = _model(**config)
+        recorder.spans.clear()  # `compile`'s spans are another test's
         m.fit(*_data(), epochs=1, shuffle=False, verbose=False)
-        counts = {k: v["count"] for k, v in trace.span_totals().items()}
-        assert counts["step"] == counts["dispatch"] == STEPS // 2
-        assert counts["host_to_device"] == STEPS // 2
-        assert counts["fit/next_batch"] == STEPS // 2 + 1
+        steps = recorder.spans_named("step")
+        assert len(steps) == STEPS
+        assert all("fused_steps" not in s.args for s in steps)
+        assert all(s.args["backend"] == backend for s in steps)
+        assert [
+            [c.name for c in recorder.children_of(s)] for s in steps
+        ] == [["dispatch"]] * STEPS
+        assert len(recorder.spans_named("fit/next_batch")) == STEPS + 1
+        assert recorder.spans_named("host_to_device") == []
+        assert "host_to_device" not in trace.HOST_SPANS
+        assert {s.tid for s in recorder.spans} == {threading.get_ident()}
 
     def test_span_and_counter_need_no_recorder(self):
         assert trace.active_recorder() is None
@@ -163,16 +182,16 @@ class TestSpanTotals:
         }
 
     def test_spans_of_other_threads_land_beside(self, recorder):
-        def producer():
-            with trace.record_span("host_to_device", steps=2):
+        def writer():
+            with trace.record_span("checkpoint", step=2):
                 pass
 
         with trace.record_span("fit"):
-            t = threading.Thread(target=producer)
+            t = threading.Thread(target=writer)
             t.start()
             t.join()
-        (h2d,) = recorder.spans_named("host_to_device")
-        assert h2d.parent is None and h2d.tid != threading.get_ident()
+        (ckpt,) = recorder.spans_named("checkpoint")
+        assert ckpt.parent is None and ckpt.tid != threading.get_ident()
 
     def test_jax_reports_its_tracing_and_lowering_to_the_table(self):
         trace.reset_span_totals()

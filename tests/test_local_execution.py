@@ -123,6 +123,39 @@ class TestTrainingInstance:
         assert losses[-1] < 0.1, losses[-1]
 
 
+BACKENDS = (
+    "flexflow_tpu.local_execution.training_backing.ModelTrainingInstance",
+    "flexflow_tpu.parallel.data_parallel.DataParallelTrainingInstance",
+    "flexflow_tpu.parallel.executor.DistributedTrainingInstance",
+    "flexflow_tpu.parallel.pipeline.PipelinedTrainingInstance",
+)
+
+
+@pytest.mark.parametrize("path", BACKENDS, ids=lambda p: p.rsplit(".", 1)[1])
+def test_a_backend_is_one_step_program(path):
+    """`train_step` and `compiled_step` are the whole contract of a backend
+    towards `fit`: one step program a backend, no K-step window beside it
+    and no option that would ask for one (PR 42)."""
+    import argparse
+    import dataclasses
+    import importlib
+
+    from flexflow_tpu.core import FFConfig
+
+    module, name = path.rsplit(".", 1)
+    backend = getattr(importlib.import_module(module), name)
+    assert callable(backend.train_step) and callable(backend.compiled_step)
+    assert callable(backend.initialize) and callable(backend.forward)
+    for gone in ("multi_train_step", "compiled_multi_step", "_multi_step"):
+        assert not hasattr(backend, gone), gone
+    assert "steps_per_dispatch" not in {
+        f.name for f in dataclasses.fields(FFConfig)
+    }
+    parser = argparse.ArgumentParser()
+    FFConfig.add_args(parser)
+    assert "--steps-per-dispatch" not in parser._option_string_actions
+
+
 class TestSteppedBacking:
     def test_forward_backward_update_parity(self):
         """Per-op stepped path produces the same gradients as autodiff over

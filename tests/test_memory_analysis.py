@@ -1,7 +1,7 @@
 """Static memory-safety analysis tests (ISSUE 10).
 
 Covers: the shared accounting module (hand-computed Linear / attention /
-fused-window footprints, the K-stacked window fix), the liveness-based
+input-batch footprints), the liveness-based
 per-device timeline, negative paths pinning every MEM00x rule id, the DP
 memory pruner (python + native exact parity, and search/verify agreement:
 a budgeted search never selects a plan `ffcheck --memory` rejects), the
@@ -104,18 +104,13 @@ class TestAccounting:
         )
         assert m.total == 49152 * 2 + 16384 * 4 + 16384 * 2
 
-    def test_fused_window_k8_hand_computed(self):
-        # the K-stacked window (the fix this PR pins): InputAttrs under
-        # steps_per_dispatch=8 stages 8 batches as ONE device buffer
-        attrs = InputAttrs(TensorShape((4, 8)))
-        m1 = estimate_memory(attrs, [], steps_per_dispatch=1)
-        m8 = estimate_memory(attrs, [], steps_per_dispatch=8)
-        assert m1.window_buffer == 4 * 8 * 4
-        assert m8.window_buffer == 8 * m1.window_buffer
-        assert m8.total == 8 * m1.total
+    def test_input_layer_charges_one_batch(self):
+        # an input is its batch once: no gradient, no optimizer slot
+        m = estimate_memory(InputAttrs(TensorShape((4, 8))), [])
+        assert m.input_batch == m.total == 4 * 8 * 4
 
     def test_sharded_input_leaf_charges_piece_bytes(self):
-        """A batch-sharded input's window residency is the per-device
+        """A batch-sharded input's residency is the per-device
         PIECE: the estimator agrees with the DP pruner and the verifier
         (the output's parallel shape carries the degree)."""
         from flexflow_tpu.compiler.machine_mapping.problem_tree import (
@@ -130,20 +125,19 @@ class TestAccounting:
         attrs = InputAttrs(TensorShape((64, 32)))
         sharded_out = pts([64, 32], [8, 1])
         est = LocalCostEstimator(
-            ProfilingSettings(warmup_iters=1, measure_iters=2),
-            steps_per_dispatch=4,
+            ProfilingSettings(warmup_iters=1, measure_iters=2)
         )
         got = est.estimate_operator_cost_parallel(
             attrs, [], [sharded_out]
         ).mem_bytes
         piece = 64 * 32 * 4 // 8
-        assert got == 4 * piece
+        assert got == piece
         leaf = UnmappedOpCostEstimateKey(attrs, (), (sharded_out,), ())
-        assert leaf_step_memory_bytes(leaf, 2, 4) == got
+        assert leaf_step_memory_bytes(leaf, 2) == got
 
     def test_local_cost_estimator_reads_shared_module(self):
         """The estimator's mem model is the shared implementation: the
-        window term shows up in CostDetails.mem_bytes too."""
+        input's batch shows up in CostDetails.mem_bytes too."""
         from flexflow_tpu.kernels.profiling import ProfilingSettings
         from flexflow_tpu.local_execution.cost_estimator import (
             LocalCostEstimator,
@@ -151,10 +145,8 @@ class TestAccounting:
 
         settings = ProfilingSettings(warmup_iters=1, measure_iters=2)
         attrs = InputAttrs(TensorShape((4, 8)))
-        k1 = LocalCostEstimator(settings, steps_per_dispatch=1)
-        k8 = LocalCostEstimator(settings, steps_per_dispatch=8)
-        assert k1.estimate_operator_cost(attrs, []).mem_bytes == 128
-        assert k8.estimate_operator_cost(attrs, []).mem_bytes == 8 * 128
+        est = LocalCostEstimator(settings)
+        assert est.estimate_operator_cost(attrs, []).mem_bytes == 128
 
     def test_leaf_memory_parallel_op_staging(self):
         """A Combine back to degree 1 charges src piece + FULL dst piece:
@@ -169,7 +161,7 @@ class TestAccounting:
         attrs = CombineAttrs(0, 8)
         (out,) = get_parallel_output_shapes(attrs, [sharded])
         leaf = UnmappedOpCostEstimateKey(attrs, (sharded,), (out,), (False,))
-        need = leaf_step_memory_bytes(leaf, 2, 1)
+        need = leaf_step_memory_bytes(leaf, 2)
         piece = 64 * 1024 * 4 // 8
         assert need == piece + 64 * 1024 * 4  # src piece + full gather
 
@@ -187,12 +179,12 @@ class TestAccounting:
         w_leaf = UnmappedOpCostEstimateKey(
             WeightAttrs(TensorShape((1024, 1024))), (), (shape,), ()
         )
-        assert leaf_step_memory_bytes(w_leaf, 2, 1) == 0
+        assert leaf_step_memory_bytes(w_leaf, 2) == 0
         reshard = UnmappedOpCostEstimateKey(
             RepartitionAttrs(0, 8), (shape,),
             (pts([1024, 1024], [8, 1]),), (True,),
         )
-        assert leaf_step_memory_bytes(reshard, 2, 1) == 0
+        assert leaf_step_memory_bytes(reshard, 2) == 0
         # the consumer: x [64,1024] @ W [1024,1024] with the weight slot
         # sharded 8-way — weight piece 512 KiB x 4 (Adam) + activations
         x = pts([64, 1024])
@@ -205,7 +197,7 @@ class TestAccounting:
         w_piece = 1024 * 1024 * 4 // 8
         act = 64 * 1024 * 4
         assert (
-            leaf_step_memory_bytes(linear, 2, 1)
+            leaf_step_memory_bytes(linear, 2)
             == 2 * act + 4 * w_piece + 2 * act
         )
 
@@ -228,8 +220,8 @@ class TestLivenessAnalysis:
     def test_resident_matches_param_accounting(self):
         # 2 weights of 256x256 f32: params + grads whole, the 2 slots at
         # their update shard (the executor cuts a slot over every axis its
-        # weight is replicated on: all 8 devices here), plus the batch
-        # window (K=1) — nothing else is whole-step resident
+        # weight is replicated on: all 8 devices here), plus the one
+        # batch — nothing else is whole-step resident
         pcg = _mlp_pcg(width=256, batch=64)
         ana = analyze_memory(pcg, SPEC8, optimizer_state_slots=2)
         w = 2 * 256 * 256 * 4
@@ -245,15 +237,18 @@ class TestLivenessAnalysis:
         ).per_device.values()
         assert alone.resident_bytes == 4 * w + batch
 
-    def test_window_buffer_scales_with_k(self):
+    def test_an_input_is_resident_as_its_one_batch(self):
+        """Every device's breakdown books the input's piece once under
+        `input_batch`, whole-step resident."""
+        from flexflow_tpu.analysis.memory_analysis import CATEGORIES
+
+        assert "input_batch" in CATEGORIES
         pcg = _mlp_pcg(width=256, batch=64)
-        a1 = analyze_memory(pcg, SPEC8, steps_per_dispatch=1)
-        a8 = analyze_memory(pcg, SPEC8, steps_per_dispatch=8)
-        batch = 64 * 256 * 4
-        for d1, d8 in zip(
-            a1.per_device.values(), a8.per_device.values()
-        ):
-            assert d8.resident_bytes - d1.resident_bytes == 7 * batch
+        ana = analyze_memory(pcg, SPEC8)
+        assert all(
+            d.peak_breakdown["input_batch"] == 64 * 256 * 4
+            for d in ana.per_device.values()
+        )
 
     def test_sharded_plan_cuts_per_device_bytes(self):
         from flexflow_tpu.compiler.unity_algorithm import (
@@ -287,7 +282,7 @@ class TestLivenessAnalysis:
 
 
 # ---------------------------------------------------------------------------
-# MEM001-MEM004 negative paths (each id pinned on a seeded fixture)
+# MEM001-MEM003 negative paths (each id pinned on a seeded fixture)
 # ---------------------------------------------------------------------------
 
 
@@ -296,7 +291,7 @@ class TestMemoryRules:
         pcg = _mlp_pcg(width=512, batch=64)
         ana = analyze_memory(pcg, SPEC8)
         worst_leaf = max(
-            leaf_step_memory_bytes(_leaf, 2, 1)
+            leaf_step_memory_bytes(_leaf, 2)
             for _leaf in _leaves(pcg)
         )
         # capacity above every single leaf but below the aggregate peak:
@@ -326,19 +321,6 @@ class TestMemoryRules:
         assert "MEM003" in rule_ids(diags)  # warning severity
         assert "MEM003" not in rule_ids(errors_of(diags))
 
-    def test_mem004_window_over_budget(self):
-        pcg = _mlp_pcg(width=512, batch=512)
-        window = 8 * 512 * 512 * 4
-        _, diags = verify_memory(
-            pcg, SPEC8, hbm_bytes=window * 1.5, steps_per_dispatch=8
-        )
-        assert "MEM004" in rule_ids(errors_of(diags))
-        # the same capacity without fusing does not trip the window rule
-        _, diags1 = verify_memory(
-            pcg, SPEC8, hbm_bytes=window * 1.5, steps_per_dispatch=1
-        )
-        assert "MEM004" not in rule_ids(diags1)
-
     def test_clean_at_generous_capacity(self):
         _, diags = verify_memory(_mlp_pcg(), SPEC8, hbm_bytes=float(2**40))
         assert diags == []
@@ -348,7 +330,10 @@ class TestMemoryRules:
         assert diags == [] and ana.max_peak_bytes() > 0
 
     def test_catalog_covers_memory_rules(self):
-        for rid in ("MEM001", "MEM002", "MEM003", "MEM004"):
+        from flexflow_tpu.analysis.memory_analysis import MEMORY_RULE_IDS
+
+        assert MEMORY_RULE_IDS == ("MEM001", "MEM002", "MEM003", "MEM005")
+        for rid in MEMORY_RULE_IDS:
             assert rid in PCG_RULE_CATALOG
 
 
@@ -491,41 +476,6 @@ class TestDPMemoryPruner:
         # serial was memory-infeasible: serial_ms records None, never a
         # bare inf that would poison provenance JSON
         assert result.serial_runtime is None
-
-    def test_window_rule_agreement_under_k8(self):
-        """MEM004 parity between search and verifier: a K=8 plan whose
-        aggregate peak FITS but whose stacked window exceeds half the
-        budget is rejected by evaluate_pcg exactly like ffcheck would
-        reject it (the K>1 corner of search/verify agreement)."""
-        from flexflow_tpu.compiler import (
-            AnalyticTPUCostEstimator,
-            MachineMappingContext,
-            make_default_allowed_machine_views,
-        )
-        from flexflow_tpu.compiler.machine_mapping.get_optimal_machine_mapping import (
-            MachineMappingCache,
-        )
-        from flexflow_tpu.compiler.unity_algorithm import evaluate_pcg
-
-        pcg = _mlp_pcg(width=64, batch=512)  # window-dominated shape
-        window = 8 * 512 * 64 * 4
-        ana = analyze_memory(pcg, SPEC8, steps_per_dispatch=8)
-        # peak fits, but the window exceeds half the budget
-        budget = (ana.max_peak_bytes() + 2 * window) / 2
-        assert ana.max_peak_bytes() < budget < 2 * window
-        ctx = MachineMappingContext(
-            AnalyticTPUCostEstimator(SPEC8, peak_flops=5e10, hbm_gbps=10.0),
-            make_default_allowed_machine_views(),
-            memory_budget_bytes=budget,
-            steps_per_dispatch=8,
-        )
-        assert (
-            evaluate_pcg(pcg, ctx, SPEC8, MachineMappingCache()) is None
-        )
-        _, diags = verify_memory(
-            pcg, SPEC8, hbm_bytes=budget, steps_per_dispatch=8
-        )
-        assert "MEM004" in rule_ids(errors_of(diags))
 
     def test_structural_infeasibility_not_blamed_on_budget(self):
         """A non-SP graph under a GENEROUS budget keeps the accurate

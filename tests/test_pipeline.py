@@ -7,9 +7,9 @@ rules, bubble-aware DP pricing with exact python/native parity (ABI v9),
 the 1F1B activation-stash memory model and its agreement with the search
 pruner, budgeted-search-selects-pipelined end to end, the shard_map +
 ppermute 1F1B executor's BITWISE parity against the sequential microbatch
-reference (dropout on, per-step and fused windows), the stage-op
-substitution rule's soundness audit, and the FFModel e2e path including
-kill-mid-window checkpoint resume on a pipelined plan.
+reference (dropout on), the stage-op substitution rule's soundness audit,
+and the FFModel e2e path including kill-and-resume from a checkpoint on a
+pipelined plan.
 """
 
 import os
@@ -82,7 +82,7 @@ def _ctx(spec=SPEC8, budget=0.0):
     return MachineMappingContext(
         _estimator(spec), make_default_allowed_machine_views(),
         overlap_fraction=0.5, memory_budget_bytes=budget,
-        optimizer_state_slots=2, steps_per_dispatch=1,
+        optimizer_state_slots=2,
     )
 
 
@@ -432,8 +432,8 @@ class TestMemory:
 
         lf = linear_leaf(flat, {})
         lp = linear_leaf(p, ctxmap)
-        flat_bytes = leaf_step_memory_bytes(lf, 2, 1)
-        pipe_bytes = leaf_step_memory_bytes(lp, 2, 1)
+        flat_bytes = leaf_step_memory_bytes(lf, 2)
+        pipe_bytes = leaf_step_memory_bytes(lp, 2)
         # hand computation: weights side unchanged; activations+outputs
         # x keep/M (stage 0 of S=2, M=4: keep=min(2,4)=2 -> x 2/4), the
         # activation/output grads x 1/M
@@ -505,28 +505,19 @@ def _pipelined_instance(pcg, **kw):
     )
 
 
-def _train(inst, steps, B, d, k=1, seed=7):
+def _train(inst, steps, B, d, seed=7):
     params, opt = inst.initialize(seed=0)
     rng = jax.random.PRNGKey(seed)
     rs = np.random.RandomState(seed)
     xv = jnp.asarray(rs.randn(B, d), jnp.float32)
     yv = jnp.asarray(rs.randint(0, d, (B,)), jnp.int32)
     losses = []
-    if k == 1:
-        for _ in range(steps):
-            rng, srng = jax.random.split(rng)
-            params, opt, loss, _ = inst.train_step(
-                params, opt, {"x": xv}, yv, srng
-            )
-            losses.append(np.asarray(loss))
-    else:
-        xs = jnp.broadcast_to(xv, (k,) + xv.shape)
-        ys = jnp.broadcast_to(yv, (k,) + yv.shape)
-        for _ in range(steps // k):
-            params, opt, rng, lvec, _, _ = inst.multi_train_step(
-                params, opt, {"x": xs}, ys, rng
-            )
-            losses.extend(np.asarray(lvec))
+    for _ in range(steps):
+        rng, srng = jax.random.split(rng)
+        params, opt, loss, _ = inst.train_step(
+            params, opt, {"x": xv}, yv, srng
+        )
+        losses.append(np.asarray(loss))
     return losses, params, opt
 
 
@@ -555,20 +546,6 @@ class TestExecutor1F1B:
             jax.tree_util.tree_leaves(ref_opt),
         ):
             assert np.array_equal(np.asarray(a), np.asarray(b))
-
-    def test_fused_window_bitwise_vs_per_step(self):
-        """PR-5 window machinery over the 1F1B schedule: K schedules in
-        one donated program, bitwise the per-step loop (dropout on)."""
-        p = insert_pipeline_stages(
-            _chain_pcg(L=4, d=16, B=16, dropout=0.1), 2, 4
-        )
-        per_step = _pipelined_instance(p)
-        l1, p1, _ = _train(per_step, 4, 16, 16, k=1)
-        fused = _pipelined_instance(p)
-        l4, p4, _ = _train(fused, 4, 16, 16, k=4)
-        assert [float(a) for a in l1] == [float(a) for a in l4]
-        for key in p1:
-            assert np.array_equal(np.asarray(p1[key]), np.asarray(p4[key]))
 
     def test_allclose_vs_flat_gspmd_executor(self):
         """Stage ops are value-identity: the flat GSPMD executor on the
@@ -701,7 +678,7 @@ class TestSearchAndRules:
 
 
 # ---------------------------------------------------------------------------
-# FFModel end to end: compile, fit, kill-mid-window resume (PR-7 path)
+# FFModel end to end: compile, fit, kill-and-resume (PR-7 path)
 # ---------------------------------------------------------------------------
 
 BATCH = 16
@@ -718,11 +695,11 @@ def _ffdata(seed=0):
     )
 
 
-def _ffbuild(k=1, metrics_dir="", ckpt_dir="", every=0, dropout=True):
+def _ffbuild(metrics_dir="", ckpt_dir="", every=0, dropout=True):
     from flexflow_tpu.core import FFConfig, FFModel
 
     cfg = FFConfig(
-        batch_size=BATCH, seed=0, steps_per_dispatch=k, print_freq=0,
+        batch_size=BATCH, seed=0, print_freq=0,
         search_budget=1, metrics_dir=metrics_dir,
         checkpoint_dir=ckpt_dir, checkpoint_every_n_steps=every,
         pipeline=True, force_strategy_seed="pp2m4xdp4",
@@ -763,9 +740,39 @@ class TestFFModelPipeline:
         for v in jax.tree_util.tree_leaves(m.params):
             assert bool(jnp.isfinite(v).all())
 
-    def test_kill_mid_window_resume_bitwise(self, monkeypatch):
-        """The PR-7 elastic contract on a PIPELINED plan: kill mid-window
-        (fused k=4), resume from the step-8 snapshot, and the loss
+    def test_fit_trajectory_is_train_step_by_step(self):
+        """`train_step` is the whole contract of a backend towards `fit`:
+        the loop's losses are, to the bit, those of the same instance's
+        `train_step` driven by hand on the same batches with the loop's
+        key stream (dropout on)."""
+        from flexflow_tpu.observability.metrics import read_events
+
+        xv, yv = _ffdata()
+        d1 = tempfile.mkdtemp()
+        m1 = _ffbuild(metrics_dir=d1)
+        m1.fit(xv, yv, epochs=1, shuffle=False, verbose=False)
+        fit_losses = [e["loss"] for e in read_events(d1) if "step" in e]
+        assert len(fit_losses) == STEPS_PER_EPOCH
+
+        m2 = _ffbuild(metrics_dir=tempfile.mkdtemp())
+        rng = jax.random.fold_in(jax.random.PRNGKey(m2.config.seed), 0)
+        params, opt = m2.params, m2.opt_state
+        by_hand = []
+        for batch, label in m2._make_iterator(xv, yv, BATCH, shuffle=False):
+            rng, step_rng = jax.random.split(rng)
+            params, opt, loss, _ = m2.instance.train_step(
+                params, opt, batch, label, step_rng
+            )
+            by_hand.append(float(loss))
+        assert by_hand == fit_losses
+        for key in params:
+            assert np.array_equal(
+                np.asarray(params[key]), np.asarray(m1.params[key])
+            ), key
+
+    def test_kill_and_resume_bitwise(self, monkeypatch):
+        """The PR-7 elastic contract on a PIPELINED plan: kill mid-epoch,
+        resume from the step-8 snapshot, and the loss
         trajectory + final params + Adam moments are bitwise the
         uninterrupted run's (dropout on: the restored RNG position is
         load-bearing through the per-(stage, microbatch) fold chain)."""
@@ -781,19 +788,19 @@ class TestFFModelPipeline:
 
         xv, yv = _ffdata()
         d1, c1 = tempfile.mkdtemp(), tempfile.mkdtemp()
-        m1 = _ffbuild(k=4, metrics_dir=d1, ckpt_dir=c1, every=8)
+        m1 = _ffbuild(metrics_dir=d1, ckpt_dir=c1, every=8)
         m1.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
         ref = losses_by_step(d1)
         assert sorted(ref) == list(range(1, 2 * STEPS_PER_EPOCH + 1))
 
         d2, c2 = tempfile.mkdtemp(), tempfile.mkdtemp()
-        m2 = _ffbuild(k=4, metrics_dir=d2, ckpt_dir=c2, every=8)
+        m2 = _ffbuild(metrics_dir=d2, ckpt_dir=c2, every=8)
         monkeypatch.setenv("FF_TPU_FAULT_STEP", "10")
         with pytest.raises(SimulatedFault):
             m2.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
         monkeypatch.delenv("FF_TPU_FAULT_STEP")
 
-        m2b = _ffbuild(k=4, metrics_dir=d2, ckpt_dir=c2, every=8)
+        m2b = _ffbuild(metrics_dir=d2, ckpt_dir=c2, every=8)
         m2b.fit(xv, yv, epochs=2, shuffle=True, verbose=False, resume=True)
         got = losses_by_step(d2)
         assert sorted(got) == sorted(ref)
@@ -820,7 +827,7 @@ def test_pipeline_gate_budgeted_search_trains():
     """The CI gate (ISSUE 13 satellite): on the deep proxy under a binding
     memory budget the flat SPMD mapping is INFEASIBLE, the search selects
     a pipelined plan, and that plan compiles and trains (loss decreases)
-    through the 1F1B executor — the same pattern as the overlap/fused
+    through the 1F1B executor — the same pattern as the overlap
     gates."""
     pcg = _chain_pcg(L=8, d=128, B=32)
     peaks = _seed_peaks(pcg)

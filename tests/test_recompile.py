@@ -126,12 +126,11 @@ def test_recompile_carry_over_keeps_scalars_uncommitted():
     )
 
 
-def test_fused_fit_with_batch_growth_rebuilds_window_stream():
-    """The recompile trigger under fused dispatch: the window stream ends
-    early, the iterator is rebuilt at the new batch size, and training
-    finishes all epochs (the fused analogue of test_fit_with_batch_growth)."""
-    cfg = FFConfig(batch_size=8, epochs=1, seed=0, print_freq=0,
-                   steps_per_dispatch=2)
+def test_batch_growth_ends_the_epoch_and_metrics_carry_over():
+    """A recompile fired mid-epoch ends that epoch: the iterator is rebuilt
+    at the new batch size, no batch is replayed, and the metric totals of
+    the steps before it are in what `fit` returns."""
+    cfg = FFConfig(batch_size=8, epochs=1, seed=0, print_freq=0)
     m = FFModel(cfg)
     x = m.create_tensor([8, 16], name="x")
     t = m.dense(x, 32, use_bias=False, name="fc1")
@@ -151,7 +150,9 @@ def test_fused_fit_with_batch_growth_rebuilds_window_stream():
                  recompile_state=state)
     assert state.recompilations == 1
     assert m.config.batch_size == 16
-    assert perf.train_all > 0
+    # 2 steps of 8, then the second epoch whole at 16: 4 steps
+    assert m._step_count == 2 + 4
+    assert perf.train_all == 2 * 8 + 4 * 16
 
 
 def test_profile_trace_dir_writes_xla_trace(tmp_path):

@@ -41,10 +41,11 @@ HIDDEN = 32
 CLASSES = 10
 
 
-def build_model(metrics_dir="", health_policy="off", ndev_config=None):
+def build_model(metrics_dir="", health_policy="off", ndev_config=None,
+                **config):
     cfg = FFConfig(
         batch_size=BATCH, seed=0, metrics_dir=metrics_dir,
-        health_policy=health_policy,
+        health_policy=health_policy, **config,
     )
     m = FFModel(cfg)
     x = m.create_tensor([BATCH, HIDDEN], name="x")
@@ -223,6 +224,20 @@ class TestEventSchema:
         assert snap["counters"]["steps_skipped"] == 1
         assert m.health_monitor.nonfinite_steps == 1
 
+    def test_verbose_fit_prints_the_loss_every_print_freq_steps(
+        self, capsys
+    ):
+        m = build_model(print_freq=3)
+        xv, yv = clean_data(steps=7)
+        m.fit(xv, yv, epochs=1, shuffle=False, verbose=True)
+        out = capsys.readouterr().out
+        printed = [
+            int(line.split("step ")[1].split(":")[0])
+            for line in out.splitlines() if ": loss " in line
+        ]
+        assert printed == [3, 6]
+        assert "ELAPSED TIME" in out and "samples/s" in out
+
     def test_no_metrics_dir_means_no_stats_collection(self):
         m = build_model()
         assert m.instance.collect_step_stats is False
@@ -301,6 +316,57 @@ class TestHealthPolicies:
         # raise guards too: params stayed finite for the post-mortem
         for k, v in m.params.items():
             assert np.all(np.isfinite(np.asarray(v))), k
+
+    @pytest.mark.parametrize(
+        "backend,budget",
+        [("DataParallelTrainingInstance", -1),
+         ("DistributedTrainingInstance", 2)],
+        ids=["dp", "searched"],
+    )
+    def test_raise_stops_at_the_trip_with_the_state_before_it(
+        self, backend, budget
+    ):
+        """`raise` on step 3: the loop stops there, `_step_count` says so,
+        and the state is to the bit that of a run of the two steps before
+        it (the guarded update never landed)."""
+        m = build_model(health_policy="raise", search_budget=budget)
+        assert type(m.instance).__name__ == backend
+        xv, yv = poisoned_data(steps=4, bad_step=3)
+        with pytest.raises(NonFiniteError) as ei:
+            m.fit(xv, yv, epochs=1, shuffle=False, verbose=False)
+        assert ei.value.report.op_name == "fc1"
+        assert m._step_count == 3
+        ref = build_model(health_policy="raise", search_budget=budget)
+        ref.fit(xv[: 2 * BATCH], yv[: 2 * BATCH], epochs=1, shuffle=False,
+                verbose=False)
+        for k, v in ref.params.items():
+            np.testing.assert_array_equal(
+                np.asarray(v), np.asarray(m.params[k]), err_msg=k
+            )
+        jax.tree_util.tree_map(
+            lambda a, b: np.testing.assert_array_equal(
+                np.asarray(a), np.asarray(b)
+            ),
+            ref.opt_state, m.opt_state,
+        )
+
+    @pytest.mark.parametrize("budget", [-1, 2], ids=["dp", "searched"])
+    def test_skip_step_run_equals_the_run_without_the_batch(self, budget):
+        """A skipped step is no step: parameters and optimizer state end
+        where a run that never saw the poisoned batch ends."""
+        m = build_model(health_policy="skip_step", search_budget=budget)
+        xv, yv = poisoned_data(steps=4, bad_step=3)
+        m.fit(xv, yv, epochs=1, shuffle=False, verbose=False)
+        assert m._step_count == 4
+        assert m.health_monitor.skipped_steps == 1
+        keep = np.r_[0 : 2 * BATCH, 3 * BATCH : 4 * BATCH]
+        ref = build_model(health_policy="skip_step", search_budget=budget)
+        ref.fit(xv[keep], yv[keep], epochs=1, shuffle=False, verbose=False)
+        assert ref.health_monitor.skipped_steps == 0
+        for k, v in ref.params.items():
+            np.testing.assert_array_equal(
+                np.asarray(v), np.asarray(m.params[k]), err_msg=k
+            )
 
     def test_warn_continues_without_guard(self, capsys):
         m = build_model(health_policy="warn")

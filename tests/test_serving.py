@@ -109,7 +109,7 @@ class TestCacheAccounting:
         layer = attention_layers(pcg)[0]
         spec = ServingMemorySpec(max_concurrent_seqs=8, max_seq_len=16)
         leaf = _leaf_key(pcg, layer.node)
-        got = leaf_step_memory_bytes(leaf, 2, 4, spec)
+        got = leaf_step_memory_bytes(leaf, 2, serving=spec)
         ins = [get_piece_shape(s).size_bytes for s in leaf.input_shapes]
         outs = sum(get_piece_shape(s).size_bytes for s in leaf.output_shapes)
         cache = kv_cache_piece_bytes(
@@ -120,7 +120,7 @@ class TestCacheAccounting:
         assert got == want
         # the training accounting for the same leaf charges grads +
         # optimizer slots and no cache — strictly different regime
-        assert leaf_step_memory_bytes(leaf, 2, 1) != got
+        assert leaf_step_memory_bytes(leaf, 2) != got
 
 
 # ---------------------------------------------------------------------------

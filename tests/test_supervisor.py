@@ -3,9 +3,9 @@ seeded fault schedules, and the supervised background threads.
 
 The detection contract: a hung dispatch window raises a structured
 WindowHangError (with a HangDiagnostic in the metrics JSONL) instead of
-blocking forever; a producer/writer thread death surfaces on the training
-thread at the next window boundary (or `due()` call) instead of silently
-or at final wait(); and every injected fault is deterministic per
+blocking forever; a writer thread death surfaces on the training thread
+at the next step boundary (or `due()` call) instead of silently or at
+final wait(); and every injected fault is deterministic per
 (seed, site, step) so chaos runs are reproducible."""
 
 import os
@@ -21,9 +21,9 @@ from flexflow_tpu.pcg.optimizer import AdamOptimizerAttrs
 from flexflow_tpu.runtime import fault
 from flexflow_tpu.runtime.fault import (
     FaultSchedule,
-    InjectedFault,
     SimulatedFault,
     inject_boundary_faults,
+    inject_nonfinite_fault,
 )
 from flexflow_tpu.runtime.supervisor import (
     BackgroundFault,
@@ -43,10 +43,10 @@ def _data(seed=0):
     return rs.randn(N, 32).astype(np.float32), rs.randint(0, 10, N)
 
 
-def _build(k=4, metrics_dir="", ckpt_dir="", every=0, watchdog_factor=0.0,
-           health_policy="off"):
+def _build(metrics_dir="", ckpt_dir="", every=0, watchdog_factor=0.0,
+           health_policy="off", budget=-1):
     cfg = FFConfig(
-        batch_size=BATCH, seed=0, steps_per_dispatch=k, print_freq=0,
+        batch_size=BATCH, seed=0, print_freq=0, search_budget=budget,
         metrics_dir=metrics_dir, checkpoint_dir=ckpt_dir,
         checkpoint_every_n_steps=every, checkpoint_backend="npz",
         watchdog_factor=watchdog_factor, health_policy=health_policy,
@@ -235,10 +235,10 @@ class TestWindowWatchdog:
 class TestFaultSchedule:
     def test_parse_round_trip(self):
         s = FaultSchedule.parse(
-            "seed=7;sites=ckpt_write,h2d,nonfinite,hang;rate=0.02"
+            "seed=7;sites=ckpt_write,nonfinite,hang;rate=0.02"
         )
         assert s.seed == 7
-        assert s.sites == {"ckpt_write", "h2d", "nonfinite", "hang"}
+        assert s.sites == {"ckpt_write", "nonfinite", "hang"}
         assert s.rate == 0.02
 
     def test_unknown_site_rejected(self):
@@ -262,9 +262,16 @@ class TestFaultSchedule:
         assert s.fire_once("kill", 6)
         assert s.fired_log == [("kill", 5), ("kill", 6)]
 
+    def test_the_sites_are_those_of_the_one_loop(self):
+        """No producer thread, no `h2d` site: a spec that still names it is
+        refused loudly, never run fault-free."""
+        assert fault.FAULT_SITES == ("ckpt_write", "nonfinite", "hang", "kill")
+        with pytest.raises(ValueError, match="unknown fault sites.*h2d"):
+            FaultSchedule.parse("seed=7;sites=ckpt_write,h2d;rate=0.02")
+
     def test_sites_not_listed_never_fire(self):
         s = FaultSchedule(seed=3, sites=frozenset({"kill"}), rate=1.0)
-        assert not s.should_fire("h2d", 5)
+        assert not s.should_fire("hang", 5)
 
     def test_find_seed_pins_first_fire_in_range(self):
         seed = fault.find_seed("kill", 0.05, 6, 14)
@@ -292,7 +299,7 @@ class TestFaultSchedule:
 
     def test_install_overrides_env(self, monkeypatch):
         monkeypatch.setenv(fault.FAULT_SPEC_ENV, "seed=1;sites=kill;rate=1.0")
-        mine = FaultSchedule(seed=9, sites=frozenset({"h2d"}), rate=0.5)
+        mine = FaultSchedule(seed=9, sites=frozenset({"hang"}), rate=0.5)
         fault.install_schedule(mine)
         try:
             assert fault.active_schedule() is mine
@@ -311,91 +318,76 @@ class TestFaultSchedule:
             inject_boundary_faults(s, 0, 1, watchdog=None)
 
 
-class TestProducerDeathRegression:
-    """Satellite: a producer-thread death must never leave the consumer
-    blocked on the queue forever."""
+class TestNonfiniteSite:
+    """The `nonfinite` chaos site in the one fit loop: the batch the
+    firing step is about to consume gets a NaN, and the run-health policy
+    owns the reaction."""
 
-    def _win_iter(self, fault_channel=None):
-        from flexflow_tpu.core.dataloader import (
-            BatchIterator,
-            WindowedBatchIterator,
+    def _schedule(self):
+        return FaultSchedule(
+            seed=fault.find_seed("nonfinite", 0.08, 6, 14),
+            sites=frozenset({"nonfinite"}), rate=0.08,
         )
 
-        rs = np.random.RandomState(0)
-        it = BatchIterator(
-            {"x": rs.randn(64, 4).astype(np.float32)},
-            rs.randint(0, 3, 64),
-            batch_size=8,
-        )
-        return WindowedBatchIterator(
-            it, 2, fault_channel=fault_channel
-        )
+    def test_poisons_floating_inputs_of_the_firing_step_only(self):
+        import jax.numpy as jnp
 
-    def test_producer_exception_propagates_to_consumer(self, monkeypatch):
-        win = self._win_iter()
-        calls = {"n": 0}
-        orig = type(win)._windows
-
-        def dying_windows(self):
-            for item in orig(self):
-                calls["n"] += 1
-                if calls["n"] == 2:
-                    raise OSError("H2D transfer died")
-                yield item
-
-        monkeypatch.setattr(type(win), "_windows", dying_windows)
-        with pytest.raises(OSError, match="H2D transfer died"):
-            list(win)
-
-    def test_silent_producer_death_detected_by_liveness(self, monkeypatch):
-        """The regression: kill the producer HARD (it exits without
-        posting an error item or the DONE sentinel — the 'exception
-        constructing the error' / hard-kill shape). The consumer used to
-        block forever; now it raises BackgroundFault within the liveness
-        poll."""
-        win = self._win_iter()
-
-        def hard_death(self):
-            return  # thread exits: no DONE, no error item
-
-        monkeypatch.setattr(type(win), "_producer", hard_death)
-        t0 = time.time()
-        with pytest.raises(BackgroundFault, match="h2d_producer"):
-            list(win)
-        assert time.time() - t0 < 10.0
-
-    def test_channel_fault_preferred_when_posted(self, monkeypatch):
-        """A producer that died after posting to the FaultChannel (but
-        whose queue item was lost) surfaces the REAL exception."""
-        ch = FaultChannel()
-        win = self._win_iter(fault_channel=ch)
-
-        def post_and_die(self):
-            self.fault_channel.post(
-                "h2d_producer", ValueError("real cause")
-            )
-            return
-
-        monkeypatch.setattr(type(win), "_producer", post_and_die)
-        with pytest.raises(BackgroundFault, match="real cause"):
-            list(win)
-
-    def test_mid_epoch_producer_kill_in_fit(self):
-        """End-to-end: the h2d fault site kills the producer mid-epoch;
-        fit() surfaces the InjectedFault instead of hanging."""
         sched = FaultSchedule(
-            seed=fault.find_seed("h2d", 0.08, 6, 14),
-            sites=frozenset({"h2d"}), rate=0.08,
+            seed=0, sites=frozenset({"nonfinite"}), rate=1.0
         )
+        batch = {
+            "x": jnp.ones((4, 3), jnp.float32),
+            "ids": jnp.ones((4, 3), jnp.int32),
+        }
+        assert inject_nonfinite_fault(None, 1, batch) is batch
+        out = inject_nonfinite_fault(sched, 1, batch)
+        x = np.asarray(out["x"])
+        assert np.isnan(x[0, 0]) and np.isfinite(x.reshape(-1)[1:]).all()
+        assert out["x"].sharding == batch["x"].sharding
+        assert out["ids"] is batch["ids"]
+        assert np.isfinite(np.asarray(batch["x"])).all()  # not in place
+        # one transient a (site, step): a retry of the step runs clean
+        assert inject_nonfinite_fault(sched, 1, batch) is batch
+        assert sched.fired_log == [("nonfinite", 1)]
+
+    @pytest.mark.parametrize("budget", [-1, 2], ids=["dp", "searched"])
+    def test_mid_epoch_nonfinite_raises_at_the_firing_step(self, budget):
+        from flexflow_tpu.observability.health import NonFiniteError
+
+        sched = self._schedule()
         fault.install_schedule(sched)
         try:
-            m = _build(k=4)
+            m = _build(health_policy="raise", budget=budget)
             xv, yv = _data()
-            with pytest.raises(InjectedFault, match="h2d"):
+            with pytest.raises(NonFiniteError):
                 m.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
         finally:
             fault.install_schedule(None)
-        assert sched.fired_log and sched.fired_log[0][0] == "h2d"
+        (site, step), = sched.fired_log
+        assert site == "nonfinite" and 6 <= step <= 14
+        assert m._step_count == step
+        for v in m.params.values():
+            assert np.isfinite(np.asarray(v)).all()
+
+    @pytest.mark.parametrize("budget", [-1, 2], ids=["dp", "searched"])
+    def test_mid_epoch_nonfinite_skipped_and_the_run_completes(self, budget):
+        from flexflow_tpu.observability.metrics import read_events
+
+        sched = self._schedule()
+        fault.install_schedule(sched)
+        mdir = tempfile.mkdtemp()
+        try:
+            m = _build(
+                metrics_dir=mdir, health_policy="skip_step", budget=budget
+            )
+            xv, yv = _data()
+            m.fit(xv, yv, epochs=2, shuffle=True, verbose=False)
+        finally:
+            fault.install_schedule(None)
+        assert m._step_count == 2 * STEPS_PER_EPOCH
+        skipped = [e["step"] for e in read_events(mdir) if e["skipped"]]
+        assert skipped == [step for _, step in sched.fired_log]
+        assert m.health_monitor.skipped_steps == len(skipped) >= 1
 
 
 class TestWriterFailureSurfacing:
@@ -441,7 +433,7 @@ class TestWriterFailureSurfacing:
     ):
         """A persistently failing commit exhausts the backoff on the
         writer thread; the NEXT due() raises it as a BackgroundFault
-        naming the checkpoint_writer site (one window later, not at
+        naming the checkpoint_writer site (one step later, not at
         final wait)."""
         import flexflow_tpu.runtime.checkpoint as ckpt_mod
         from flexflow_tpu.runtime.checkpoint import TrainingCheckpointer
@@ -500,7 +492,7 @@ class TestWatchdogEndToEnd:
         fault.install_schedule(sched)
         mdir = tempfile.mkdtemp()
         try:
-            m = _build(k=4, metrics_dir=mdir, watchdog_factor=3.0)
+            m = _build(metrics_dir=mdir, watchdog_factor=3.0)
             xv, yv = _data()
             t0 = time.time()
             with pytest.raises(WindowHangError) as ei:
@@ -524,7 +516,7 @@ class TestWatchdogEndToEnd:
         """FF_TPU_WATCHDOG supplies the factor when the config field is
         unset (the production knob on an existing launch script)."""
         monkeypatch.setenv("FF_TPU_WATCHDOG", "50.0")
-        m = _build(k=4)
+        m = _build()
         sup = m._setup_supervision()
         try:
             assert sup.watchdog is not None
@@ -533,7 +525,7 @@ class TestWatchdogEndToEnd:
             sup.close()
 
     def test_no_watchdog_thread_by_default(self):
-        m = _build(k=4)
+        m = _build()
         sup = m._setup_supervision()
         try:
             assert sup.watchdog is None
@@ -547,10 +539,10 @@ class TestWatchdogEndToEnd:
 
         xv, yv = _data()
         d1 = tempfile.mkdtemp()
-        m1 = _build(k=4, metrics_dir=d1)
+        m1 = _build(metrics_dir=d1)
         m1.fit(xv, yv, epochs=1, shuffle=True, verbose=False)
         d2 = tempfile.mkdtemp()
-        m2 = _build(k=4, metrics_dir=d2, watchdog_factor=10000.0)
+        m2 = _build(metrics_dir=d2, watchdog_factor=10000.0)
         m2.fit(xv, yv, epochs=1, shuffle=True, verbose=False)
         l1 = {e["step"]: e["loss"] for e in read_events(d1) if "step" in e}
         l2 = {e["step"]: e["loss"] for e in read_events(d2) if "step" in e}

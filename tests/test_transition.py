@@ -132,14 +132,15 @@ class TestRuleNegatives:
         )
 
     def test_trn003_compatible_change_is_carry_remap(self):
-        # a pure steps-per-dispatch change keeps the batch schedule: it
-        # is annotated, not flagged
-        a, _ = verify_transition(
-            _mlp(), None, _mlp(), None,
-            steps_per_dispatch=1, steps_per_dispatch_new=4,
-        )
+        # an unchanged batch schedule is annotated, not flagged: the key
+        # and the dataloader's cursor carry over as they are
+        a, _ = verify_transition(_mlp(), None, _mlp(), None)
         assert a.rules_tripped == []
-        assert "steps_per_dispatch" in a.carry_remap
+        assert {"rng", "dataloader"} <= set(a.carry_remap)
+        assert set(a.contract_new) == {
+            "batch_schedule", "pipeline_stages", "pipeline_microbatches",
+            "pipeline_region_ok",
+        }
 
     def test_trn004_undonated_new_step(self):
         import jax

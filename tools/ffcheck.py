@@ -52,11 +52,10 @@ lines, mirroring --memory's contract.
 
 --memory runs the static liveness-based per-device HBM analysis
 (analysis/memory_analysis.py) over each input file against a per-device
-capacity of --hbm-gb GiB, emitting MEM001-MEM004 diagnostics and a
+capacity of --hbm-gb GiB, emitting MEM001-MEM003 diagnostics and a
 per-device peak timeline table (or, under --json, one summary object per
 file with key "memory" beside the per-diagnostic lines). The memory
-model's knobs mirror the runtime's: --optimizer-slots (Adam m/v = 2) and
---steps-per-dispatch (the fused window K).
+model's knob mirrors the runtime's: --optimizer-slots (Adam m/v = 2).
 
 File inputs are auto-detected: a document with a "kind" key is a
 computation_graph / parallel_computation_graph file (pcg/file_format.py); a
@@ -96,7 +95,7 @@ def _hbm_bytes(args) -> float:
 
 
 def _memory_diags(pcg, mapping, args, path, summaries, lowered_box) -> List:
-    """MEM001-MEM004 diagnostics + the per-device analysis for one file
+    """MEM001-MEM003 diagnostics + the per-device analysis for one file
     (`--memory`). Graph files without a mapping analyze under the
     full-mesh GSPMD lowering (every op on every device of the grid).
     Under --serving the analysis is forward-only + KV cache and MEM005
@@ -118,7 +117,6 @@ def _memory_diags(pcg, mapping, args, path, summaries, lowered_box) -> List:
         mapping=mapping,
         hbm_bytes=_hbm_bytes(args),
         optimizer_state_slots=args.optimizer_slots,
-        steps_per_dispatch=args.steps_per_dispatch,
         serving=serving,
     )
     summaries.setdefault("memory", []).append((path, analysis))
@@ -368,7 +366,6 @@ def check_transition_pair(
             machine_spec=spec,
             hbm_bytes=_hbm_bytes(args),
             optimizer_state_slots=args.optimizer_slots,
-            steps_per_dispatch=args.steps_per_dispatch,
             lowered_new=lowered,
         )
     except Exception as e:
@@ -585,9 +582,6 @@ def main(argv=None) -> int:
     ap.add_argument("--optimizer-slots", type=int, default=2,
                     help="per-weight optimizer-state slots the memory model"
                     " charges (Adam m/v = 2, SGD+momentum = 1, SGD = 0)")
-    ap.add_argument("--steps-per-dispatch", type=int, default=1,
-                    help="fused-dispatch window K: input layers are charged"
-                    " K x their per-step batch (the stacked window buffer)")
     ap.add_argument("--nodes", type=int, default=1)
     ap.add_argument("--devices-per-node", type=int, default=8)
     ap.add_argument("--slices", type=int, default=0,
