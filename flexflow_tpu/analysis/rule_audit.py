@@ -265,11 +265,16 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
             if op_type == OperatorType.RING_ATTENTION
             else MultiHeadAttentionAttrs
         )
+        latent = eq.get("kv_latent_rank") is _SET
         return cls(
             embed_dim=heads * 4,
             num_heads=heads,
+            kdim=6 if latent else 0,
+            vdim=4 if latent else 0,
             bias=eq.get("bias", False),
             qk_norm_eps=1e-5 if eq.get("qk_norm_eps") is _SET else None,
+            kv_latent_rank=4 if latent else None,
+            shared_key_dim=2 if latent else 0,
         )
     if op_type == OperatorType.RMS_NORM:
         return RMSNormAttrs()
@@ -331,6 +336,12 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
         return StateSpaceAttrs(
             num_heads=2, head_dim=4, state_size=4, num_groups=1, chunk_size=4
         )
+    if op_type == OperatorType.GATED_DELTA:
+        from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+
+        return GatedDeltaAttrs(
+            num_heads=2, key_dim=4, value_dim=4, gate_rank=2, chunk_size=4
+        )
     if op_type == OperatorType.REPARTITION:
         return RepartitionAttrs(
             eq.get("repartition_dim", 0), eq.get("repartition_degree", 2)
@@ -372,6 +383,7 @@ def _data_shape_table(op_type: OperatorType, size: int, arity: int):
         OperatorType.BROADCAST: ((S, S, S),),
         OperatorType.EXPERTS: ((S, S),),
         OperatorType.STATE_SPACE: ((S, S, S),),
+        OperatorType.GATED_DELTA: ((S, S, S),),
         OperatorType.REPARTITION: ((S, S, S),),
         OperatorType.COMBINE: ((S, S, S),),
         OperatorType.REPLICATE: ((S, S, S),),

@@ -225,6 +225,7 @@ _DP_TYPES = frozenset(
         OperatorType.EXPERTS,
         # the scan runs along each sample's own sequence
         OperatorType.STATE_SPACE,
+        OperatorType.GATED_DELTA,
     }
 )
 

@@ -1777,9 +1777,12 @@ def _fwd_causal_kernel(
     wholly under the diagonal run the unmasked body, the (at most
     cdiv(block_q, block_k)) blocks on it the masked one, and dead blocks
     are outside both loops (_causal_k_range). The row sums are carried as
-    128 lane partials and folded once a q block."""
+    128 lane partials and folded once a q block. The keys may be wider than
+    the values (`flash_attention_bshf_wide_key`): the accumulator is as wide
+    as a value."""
     qi = pl.program_id(2)
-    bb, block_q, d = q_ref.shape
+    bb, block_q, _ = q_ref.shape
+    d = v_ref.shape[-1]
     scale2 = scale * LOG2E
     # scale folded into the [bb, block_q, d] operand (see _fwd_kernel)
     q = q_ref[:] * jnp.asarray(scale2, q_ref.dtype)
@@ -1819,17 +1822,26 @@ def _fwd_causal_kernel(
     lse_ref[:, 0, :] = m + jnp.log2(l)  # base-2 lse
 
 
-def _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret=False):
+def _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret=False,
+                     scale=None):
     """_fwd_bshf's grid and blocks (k and v resident as whole rows, batch
-    rows folded), on the kernel that skips."""
+    rows folded), on the kernel that skips. q and k [b, s, h * d], v
+    [b, s, h * dv]; `scale` where it is not d ** -0.5 (a key padded with
+    zero columns)."""
     b, s, f = q.shape
-    d = f // h
+    d, dv = f // h, v.shape[-1] // h
     bb = _batch_block(b, block_q, block_k, s, d, q.dtype.itemsize)
-    tile = pl.BlockSpec((bb, block_q, d), lambda bi, hi, i: (bi, i, hi))
-    row = pl.BlockSpec((bb, s, d), lambda bi, hi, i: (bi, 0, hi))
+
+    def tile(width):
+        return pl.BlockSpec((bb, block_q, width), lambda bi, hi, i: (bi, i, hi))
+
+    def row(width):
+        return pl.BlockSpec((bb, s, width), lambda bi, hi, i: (bi, 0, hi))
+
     return pl.pallas_call(
         functools.partial(
-            _fwd_causal_kernel, block_k=block_k, scale=1.0 / (d**0.5)
+            _fwd_causal_kernel, block_k=block_k,
+            scale=1.0 / (d**0.5) if scale is None else scale,
         ),
         name="flash_fwd_causal_bshf",
         interpret=interpret,
@@ -1837,15 +1849,15 @@ def _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret=False):
             dimension_semantics=("parallel", "parallel", "parallel")
         ),
         grid=(b // bb, h, s // block_q),
-        in_specs=[tile, row, row],
+        in_specs=[tile(d), row(d), row(dv)],
         out_specs=[
-            tile,
+            tile(dv),
             pl.BlockSpec(
                 (bb, None, 1, block_q), lambda bi, hi, i: (bi, hi, 0, i)
             ),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, f), q.dtype),
+            jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
         ],
     )(q, k, v)
@@ -1918,8 +1930,10 @@ def _bwd_causal_kernel(
 
     start, full = _causal_q_range(ki, block_q, block_k)
     zero = jnp.zeros((block_k, d), jnp.float32)
+    # a value may be narrower than its key (flash_attention_bshf_wide_key)
+    dv0 = zero if v_ref.shape == zero.shape else jnp.zeros(v_ref.shape, jnp.float32)
     carry = jax.lax.fori_loop(
-        start, full, functools.partial(body, masked=True), (zero, zero)
+        start, full, functools.partial(body, masked=True), (zero, dv0)
     )
     dk, dv = jax.lax.fori_loop(full, s // block_q, body, carry)
     dk_ref[:] = dk.astype(dk_ref.dtype)
@@ -1931,16 +1945,24 @@ def _bwd_causal_kernel(
 
 
 def _bwd_bshf_causal(q, k, v, o, lse, do, h, block_q, block_k,
-                     interpret=False):
+                     interpret=False, scale=None):
     b, s, f = q.shape
-    d = f // h
-    delta4 = _delta_bshf(do, o, b, s, h, d, interpret)
-    row = pl.BlockSpec((None, s, d), lambda bi, hi, j: (bi, 0, hi))
-    col = pl.BlockSpec((None, block_k, d), lambda bi, hi, j: (bi, j, hi))
+    d, dv = f // h, v.shape[-1] // h
+    delta4 = _delta_bshf(do, o, b, s, h, dv, interpret)
+
+    def row(width):
+        return pl.BlockSpec((None, s, width), lambda bi, hi, j: (bi, 0, hi))
+
+    def col(width):
+        return pl.BlockSpec(
+            (None, block_k, width), lambda bi, hi, j: (bi, j, hi)
+        )
+
     stat = pl.BlockSpec((None, None, 1, s), lambda bi, hi, j: (bi, hi, 0, 0))
     return pl.pallas_call(
         functools.partial(
-            _bwd_causal_kernel, block_q=block_q, scale=1.0 / (d**0.5)
+            _bwd_causal_kernel, block_q=block_q,
+            scale=1.0 / (d**0.5) if scale is None else scale,
         ),
         name="flash_bwd_causal_bshf",
         interpret=interpret,
@@ -1949,12 +1971,12 @@ def _bwd_bshf_causal(q, k, v, o, lse, do, h, block_q, block_k,
             vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
         ),
         grid=(b, h, s // block_k),
-        in_specs=[row, col, col, row, stat, stat],
-        out_specs=[row, col, col],
+        in_specs=[row(d), col(d), col(dv), row(dv), stat, stat],
+        out_specs=[row(d), col(d), col(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((b, s, f), q.dtype),
             jax.ShapeDtypeStruct((b, s, f), k.dtype),
-            jax.ShapeDtypeStruct((b, s, f), v.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
     )(q, k, v, do, lse, delta4)
@@ -2038,7 +2060,10 @@ _MIN_SEQ = {"pair": 128, "lane": 256, "rows": MIN_SEQ_UNMEASURED}
 def min_seq_for(family=None) -> int:
     """The least sequence length of a kernel family: FLEXFLOW_TPU_FLASH_MIN_SEQ
     where set (benchmarking and tests: one length for every family), else
-    what was measured for it (_MIN_SEQ), else MIN_SEQ_UNMEASURED."""
+    what was measured for it (_MIN_SEQ), else MIN_SEQ_UNMEASURED. A key wider
+    than its value (kd != vd: latent attention's 192 | 128) reads the "lane"
+    family's length and, besides, needs more than one causal tile
+    (`wide_key_supported`): below that it takes XLA's dense attention."""
     import os
 
     env = os.environ.get("FLEXFLOW_TPU_FLASH_MIN_SEQ")
@@ -2149,3 +2174,67 @@ def _pair_fwd_kernel(s: int, block_q: int, block_k: int):
     takes one key tile."""
     assert block_q == s == block_k, (s, block_q, block_k)
     return _fwd_kernel_pair
+
+
+# ---------------------------------------------------------------------------
+# a key wider than its value (latent attention: 192 | 128)
+# ---------------------------------------------------------------------------
+#
+# The causal tile kernels above take a value narrower than the key (the
+# forward's accumulator and the backward's dv are as wide as v). A 192-wide
+# key is no whole number of 128-lane tiles, so the caller pads q and k with
+# zero columns to `wide_key_padded` (256) and names the scale of the TRUE
+# width: zero columns add nothing to a score. The other form, a second score
+# term over the 64 columns the heads share (two more operands, a dk_s summed
+# over the heads), contracts 128 + 128 MXU columns too once the slice is
+# padded to a tile, and was not built (PERF.md section 6, PR 43).
+
+
+def wide_key_padded(kd: int) -> int:
+    """The least multiple of 128 lanes that holds a `kd`-wide key."""
+    return -(-kd // 128) * 128
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bshf_wide_key(q, k, v, h, block, interpret, scale):
+    o, _ = _fwd_bshf_causal(q, k, v, h, block, block, interpret, scale)
+    return o
+
+
+def _flash_bshf_wide_key_fwd(q, k, v, h, block, interpret, scale):
+    o, lse = _fwd_bshf_causal(q, k, v, h, block, block, interpret, scale)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bshf_wide_key_bwd(h, block, interpret, scale, res, do):
+    q, k, v, o, lse = res
+    return _bwd_bshf_causal(
+        q, k, v, o, lse, do, h, block, block, interpret, scale
+    )
+
+
+_flash_bshf_wide_key.defvjp(_flash_bshf_wide_key_fwd, _flash_bshf_wide_key_bwd)
+
+
+def wide_key_supported(s: int) -> bool:
+    """More than one causal tile: the tile schedule is the only body these
+    shapes have."""
+    return s % _CAUSAL_BLOCK == 0 and s > _CAUSAL_BLOCK
+
+
+def flash_attention_bshf_wide_key(
+    q, k, v, num_heads: int, *, scale: float, interpret: bool = False,
+):
+    """Causal attention on q, k [b, s, num_heads * dk] and v
+    [b, s, num_heads * dv] with dk and dv multiples of 128 and dk != dv
+    allowed; softmax(q k^T * scale) v -> [b, s, num_heads * dv]. The causal
+    tile schedule at its measured block (`_CAUSAL_BLOCK`)."""
+    b, s, f = q.shape
+    assert k.shape == q.shape and v.shape[:2] == q.shape[:2], (
+        q.shape, k.shape, v.shape
+    )
+    assert f % (128 * num_heads) == 0 and v.shape[-1] % (128 * num_heads) == 0
+    assert wide_key_supported(s), s
+    return _flash_bshf_wide_key(
+        q, k, v, num_heads, _CAUSAL_BLOCK, interpret, float(scale)
+    )

@@ -299,6 +299,12 @@ def mha_core_route(
     - "dense": XLA's attention, below the route's least length
       (`flash_attention.min_seq_for`: measured per kernel family).
 
+    A key wider than its value (kd != vd) has one kernel form: latent
+    attention (`attrs.latent`) under a causal mask at more than one causal
+    tile takes "fused_row" on `flash_attention_bshf_wide_key`, its key
+    padded with zero columns to whole 128-lane tiles (192 -> 256); every
+    other kd != vd shape takes "rows" or "dense", as it always did.
+
     Under a declared mesh the gates read the block each device sees and the
     fused-row kernels are mapped over the batch shards (`per_batch_shard`);
     a head-sharded plan cannot split a fused row by pairs and takes "rows"."""
@@ -318,6 +324,19 @@ def mha_core_route(
     proj_kv = (b, H, t, kd)
     mesh_ctx = current_flash_mesh()
     heads_whole = mesh_ctx is None or mesh_ctx[2] is None
+    if attrs.latent:
+        from flexflow_tpu.kernels.flash_attention import (
+            wide_key_padded,
+            wide_key_supported,
+        )
+
+        padded = (b, H, s, wide_key_padded(kd))
+        kernel = (
+            heads_whole and getattr(attrs, "causal", False)
+            and vd % 128 == 0 and wide_key_supported(s)
+            and flash_core_supported(padded, padded, padded, "lane")
+        )
+        return "fused_row" if kernel else "dense"
     # kd % 128: blocks carved from the fused h*d minor dim must be
     # lane-aligned (Pallas requires block minor dims divisible by 128 unless
     # equal to the array dim). d=64 (the reference heads=16 config) rides
@@ -348,11 +367,82 @@ def unpack_gqa_weights(
     H, KV = attrs.num_heads, attrs.num_kv_heads
     kd, vd, e = attrs.q_proj_size, attrs.v_proj_size, attrs.embed_dim
     shapes = [(qsize, H * kd), (ksize, KV * kd), (vsize, KV * vd), (H * vd, e)]
+    return _unpack_flat(weight, shapes)
+
+
+def _unpack_flat(weight, shapes):
+    """The row-major matrices of `shapes`, one after the other in one flat
+    column."""
     flat, out, at = weight.reshape(-1), [], 0
     for rows, cols in shapes:
         out.append(flat[at:at + rows * cols].reshape(rows, cols))
         at += rows * cols
     return out
+
+
+def unpack_latent_weights(attrs: MultiHeadAttentionAttrs, esize: int, weight):
+    """The latent layout (`MultiHeadAttentionAttrs.kv_latent_rank`): wq
+    [e, h*kd], wkv_a [e, rank + shared], wkv_b [rank, h*(own + vd)] (a
+    head's own key columns, then its value's) and wo [h*vd, e]."""
+    H, rank = attrs.num_heads, attrs.kv_latent_rank
+    kd, vd = attrs.q_proj_size, attrs.v_proj_size
+    return _unpack_flat(weight, [
+        (esize, H * kd), (esize, rank + attrs.shared_key_dim),
+        (rank, H * (attrs.own_key_dim + vd)), (H * vd, attrs.embed_dim),
+    ])
+
+
+def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal):
+    """Latent self-attention on x [b, s, e]: keys and values from one normed
+    low-rank row (scope `latent`), head h's key its own columns beside the
+    slice all heads share, then the attention core (scope `core`): the
+    wide-key flash kernels where `mha_core_route` says so, on a key padded
+    with zero columns, else XLA's dense attention."""
+    from flexflow_tpu.kernels.flash_attention import (
+        flash_attention_bshf_wide_key,
+        per_batch_shard,
+        wide_key_padded,
+    )
+
+    H, rank, shared = attrs.num_heads, attrs.kv_latent_rank, attrs.shared_key_dim
+    kd, vd, own = attrs.q_proj_size, attrs.v_proj_size, attrs.own_key_dim
+    b, s, e = x.shape
+    wq, wkv_a, wkv_b, wo = unpack_latent_weights(attrs, e, weight)
+    with jax.named_scope("latent"):
+        low = x @ wkv_a
+        c = rms_norm(low[..., :rank], gain, attrs.kv_latent_norm_eps)
+        kv = (c @ wkv_b).reshape(b, s, H, own + vd)
+        parts = [
+            kv[..., :own],
+            jnp.broadcast_to(low[:, :, None, rank:], (b, s, H, shared)),
+        ]
+        v = kv[..., own:]
+    route = mha_core_route(attrs, x.shape, x.shape, x.shape, True)
+    if route == "fused_row":
+        pad = wide_key_padded(kd) - kd
+        # zero columns of the WEIGHT are the padded query's zero columns
+        wq = jnp.pad(wq.reshape(e, H, kd), ((0, 0), (0, 0), (0, pad)))
+        with jax.named_scope("latent"):
+            k = jnp.concatenate(
+                parts + [jnp.zeros((b, s, H, pad), x.dtype)], axis=-1
+            ).reshape(b, s, H * (kd + pad))
+        with jax.named_scope("core"):
+            ctx = per_batch_shard(
+                flash_attention_bshf_wide_key,
+                x @ wq.reshape(e, H * (kd + pad)), k, v.reshape(b, s, H * vd),
+                num_heads=H, scale=kd ** -0.5,
+            )
+        return ctx @ wo
+    q = (x @ wq).reshape(b, s, H, kd)
+    with jax.named_scope("core"):
+        scores = jnp.einsum(
+            "bshk,bthk->bhst", q, jnp.concatenate(parts, axis=-1)
+        ) / jnp.sqrt(jnp.asarray(kd, q.dtype))
+        if causal:
+            mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+            scores = jnp.where(mask, scores, jnp.asarray(-1e30, scores.dtype))
+        ctx = jnp.einsum("bhst,bthv->bshv", jax.nn.softmax(scores, axis=-1), v)
+    return ctx.reshape(b, s, H * vd) @ wo
 
 
 def _mha_forward(
@@ -596,6 +686,8 @@ def forward(
         q, k, v = inputs
         input_bias = weights[1] if attrs.bias else None
         causal = isinstance(attrs, RingAttentionAttrs) and attrs.causal
+        if attrs.latent:  # self-attention: reads its first input
+            return [_latent_mha_forward(attrs, q, weights[0], weights[1], causal)]
         out = _mha_forward(
             attrs, q, k, v, weights[0], input_bias, causal=causal,
             qk_gains=weights[-2:] if attrs.qk_norm else None,
@@ -664,6 +756,13 @@ def forward(
         from flexflow_tpu.kernels.ssm import state_space_forward
 
         return [state_space_forward(attrs, inputs[0], weights)]
+
+    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+
+    if isinstance(attrs, GatedDeltaAttrs):
+        from flexflow_tpu.kernels.kda import gated_delta_forward
+
+        return [gated_delta_forward(attrs, inputs[0], weights)]
 
     from flexflow_tpu.op_attrs.ops.moe import (
         AggregateAttrs,
@@ -741,6 +840,19 @@ def op_internal_bytes(attrs: OpAttrs, input_shapes, weight_shapes=None) -> int:
         x = input_shapes[0]
         tokens = int(x.num_elements) // x.dims[-1]
         width = attrs.in_proj_width + attrs.conv_width + 2 * attrs.inner
+        return 2 * tokens * width * x.dtype.size_bytes
+    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+
+    if isinstance(attrs, GatedDeltaAttrs):
+        # the input projection's row, the convolved q | k | v, the two
+        # gates' up-projections, the recurrence's o and the gated norm's
+        # output
+        x = input_shapes[0]
+        tokens = int(x.num_elements) // x.dims[-1]
+        width = (
+            attrs.in_proj_width + attrs.conv_width + attrs.key_width
+            + 3 * attrs.value_width
+        )
         return 2 * tokens * width * x.dtype.size_bytes
     if not isinstance(attrs, ExpertsAttrs):
         return 0
@@ -823,6 +935,13 @@ def op_forward_flops(
             2 * b * s * e * (kd * H + (kd + vd) * KV)
             + 2 * b * s * vd * attrs.embed_dim * H
         )
+        if attrs.latent:
+            rank = attrs.kv_latent_rank
+            proj = 2 * b * s * (
+                e * (kd * H + rank + attrs.shared_key_dim)
+                + rank * H * (attrs.own_key_dim + vd)
+                + vd * H * attrs.embed_dim
+            )
         scores = 2 * b * H * s * s * kd + 2 * b * H * s * s * vd
         if isinstance(attrs, RingAttentionAttrs) and seq_parallel_degree > 1:
             # the piece sees s/k queries but attends ALL k K/V blocks (ring
@@ -864,6 +983,23 @@ def op_forward_flops(
         scan = b * s * (
             attrs.num_groups * 2 * q * n
             + attrs.num_heads * (2 * q * p + 4 * p * n)
+        )
+        return proj + scan + 2 * b * s * attrs.conv_kernel * attrs.conv_width
+
+    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+
+    if isinstance(attrs, GatedDeltaAttrs):
+        b, s, d = input_shapes[0].dims
+        q, dk, dv = attrs.chunk_size, attrs.key_dim, attrs.value_dim
+        proj = 2 * b * s * (
+            d * (attrs.in_proj_width + attrs.value_width)
+            + attrs.gate_rank * (attrs.key_width + attrs.value_width)
+        )
+        # a position's share of a chunk, a head: both decayed score
+        # matrices and T's two products over the chunk's rows, and the
+        # state's four [dk, dv] products
+        scan = b * s * attrs.num_heads * (
+            2 * q * (2 * dk + dk + dv) + 2 * q * dv + 4 * 2 * dk * dv
         )
         return proj + scan + 2 * b * s * attrs.conv_kernel * attrs.conv_width
 

@@ -118,11 +118,12 @@ def _conv_taps(x, weight, bias, mirrored: bool = False):
     """bias + sum_k weight[k] * x_{t - (taps - 1) + k} in float32: the
     causal depthwise conv1d of the model codes (`groups = channels`,
     `padding = taps - 1` cut back to s); `mirrored`, its transpose in x.
-    Four shifted multiply-adds either way."""
+    Four shifted multiply-adds either way. `bias` None is a convolution
+    without one: the sum starts at the first tap's product."""
     w = weight.astype(jnp.float32)
-    y = bias.astype(jnp.float32)
+    y = None if bias is None else bias.astype(jnp.float32)
     for k, x_k in enumerate(_shifted(x, len(weight), mirrored)):
-        y = y + w[k] * x_k
+        y = w[k] * x_k if y is None else y + w[k] * x_k
     return y
 
 
@@ -136,7 +137,8 @@ def _silu_slope(a):
 @jax.custom_vjp
 def conv_silu(x, weight, bias):
     """silu(causal_depthwise_conv(x)): x [b, s, f], weight [taps, f], bias
-    [f] -> [b, s, f] in x's dtype. The convolution accumulates in float32
+    [f] or None (the gated delta-rule mixer's convolutions have none, and
+    none is made for them) -> [b, s, f] in x's dtype. The convolution accumulates in float32
     and is rounded to x's dtype, SiLU is taken of that in float32 and
     rounded again: one pass over x, one output.
 
@@ -171,8 +173,9 @@ def _conv_silu_bwd(kept, dy):
     dw = jnp.stack(
         [jnp.sum(dsf * x_k, axis=(0, 1)) for x_k in _shifted(x, len(weight))]
     )
-    db = jnp.sum(dsf, axis=(0, 1))
-    return dx.astype(x.dtype), dw.astype(weight.dtype), db.astype(bias.dtype)
+    db = None if bias is None else jnp.sum(dsf, axis=(0, 1))
+    dx, dw = dx.astype(x.dtype), dw.astype(weight.dtype)
+    return dx, dw, None if bias is None else db.astype(bias.dtype)
 
 
 conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)

@@ -356,6 +356,7 @@ PHASES = ("fwd", "bwd", "opt", "other", "unattributed")
 # kinds whose OperatorType value is not the name the tables use
 _KIND_NAMES = {
     "linear": "dense", "multihead_attention": "mha", "state_space": "ssm",
+    "gated_delta": "kda",
 }
 _NOT_IN_NAME = re.compile(r"[^A-Za-z0-9_.\-]")
 # the first `ff.` token of a name stack: a kind holds no dot, so the first
@@ -374,9 +375,24 @@ _SCOPE = re.compile(
 NODE_PARTS = {
     "ssm": ("scan", "conv", "norm"),
     "experts": ("router", "latent", "routed", "shared"),
+    # the gated delta-rule node (`kernels/kda.py`): the chunk-to-chunk pass,
+    # the chunks' operands (decayed scores, the triangular inverse), the
+    # normalisation and the two gates, the convolution, the gated norm
+    "kda": ("scan", "prep", "gates", "conv", "norm"),
+    # latent attention (`kernels/ops._latent_mha_forward`): the low-rank
+    # key/value projections with their norm, and the attention core
+    "ring_attention": ("latent", "core"),
 }
+# Between the node's scope and the part's, JAX may also write what a
+# `jax.checkpoint` around the parts leaves in the backward's names: the
+# rematerialised forward's own `jvp(ff.<kind>.<name>)`, `checkpoint`,
+# `rematted_computation` (the gated delta-rule node keeps only its inputs and
+# recomputes its parts under one checkpoint, `kernels/kda.py`).
 _PART = {
-    kind: re.compile(r"\)*/(" + "|".join(parts) + r")(?:[/)]|$)")
+    kind: re.compile(
+        r"\)*(?:/(?:jvp\([^()]*\)|checkpoint|rematted_computation))*/("
+        + "|".join(parts) + r")(?:[/)]|$)"
+    )
     for kind, parts in NODE_PARTS.items()
 }
 

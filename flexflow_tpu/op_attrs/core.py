@@ -74,6 +74,7 @@ from flexflow_tpu.op_attrs.ops.moe import (
     ExpertsAttrs,
 )
 from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
+from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 
 
 class OperatorType(enum.Enum):
@@ -111,6 +112,7 @@ class OperatorType(enum.Enum):
     AGGREGATE = "aggregate"
     EXPERTS = "experts"  # fused tpu-native MoE FFN (expert parallelism)
     STATE_SPACE = "state_space"  # selective state-space mixer (chunked scan)
+    GATED_DELTA = "gated_delta"  # gated delta-rule linear attention (chunked)
     REPARTITION = "repartition"
     COMBINE = "combine"
     REPLICATE = "replicate"
@@ -137,6 +139,7 @@ OpAttrs = Union[
     ConcatAttrs, StackAttrs, SplitAttrs, ReshapeAttrs, TransposeAttrs,
     ReverseAttrs, GatherAttrs, TopKAttrs, ReduceAttrs,
     GroupByAttrs, AggregateAttrs, ExpertsAttrs, StateSpaceAttrs,
+    GatedDeltaAttrs,
     RepartitionAttrs, CombineAttrs, ReplicateAttrs, ReductionAttrs,
     StagePartitionAttrs, StageMergeAttrs,
 ]
@@ -176,6 +179,7 @@ _OP_TYPE_BY_ATTRS = {
     AggregateAttrs: OperatorType.AGGREGATE,
     ExpertsAttrs: OperatorType.EXPERTS,
     StateSpaceAttrs: OperatorType.STATE_SPACE,
+    GatedDeltaAttrs: OperatorType.GATED_DELTA,
     RepartitionAttrs: OperatorType.REPARTITION,
     CombineAttrs: OperatorType.COMBINE,
     ReplicateAttrs: OperatorType.REPLICATE,
@@ -233,6 +237,8 @@ def get_incoming_tensor_roles(attrs: OpAttrs) -> List[IncomingTensorRole]:
             roles += [W, W]
         if attrs.qk_norm:
             roles += [W, W]
+        if attrs.latent:
+            roles += [W]
         return roles
     if isinstance(attrs, BatchNormAttrs):
         return [I, W, W] if attrs.affine else [I]
@@ -240,7 +246,7 @@ def get_incoming_tensor_roles(attrs: OpAttrs) -> List[IncomingTensorRole]:
         return [I, W, W] if attrs.elementwise_affine else [I]
     if isinstance(attrs, RMSNormAttrs):
         return [I, W]
-    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs)):
+    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs, GatedDeltaAttrs)):
         return [I] + [W] * attrs.num_weights
     n = num_data_inputs(attrs)
     return [I] * n
@@ -326,6 +332,8 @@ def get_weight_shapes(
             ws += [attrs.input_bias_shape(q, k, v), attrs.output_bias_shape(q, k, v)]
         if attrs.qk_norm:
             ws += [attrs.qk_gain_shape(q, k, v)] * 2
+        if attrs.latent:
+            ws += [attrs.latent_gain_shape(q)]
         return ws
     if isinstance(attrs, BatchNormAttrs) and attrs.affine:
         return [attrs.gamma_shape(inputs[0]), attrs.beta_shape(inputs[0])]
@@ -333,7 +341,7 @@ def get_weight_shapes(
         return [attrs.gamma_shape(inputs[0]), attrs.beta_shape(inputs[0])]
     if isinstance(attrs, RMSNormAttrs):
         return [attrs.gamma_shape(inputs[0])]
-    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs)):
+    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs, GatedDeltaAttrs)):
         return list(attrs.weight_shapes(inputs[0]))
     return []
 
@@ -357,6 +365,9 @@ def get_default_weight_initializers(attrs: OpAttrs, num_weights: int):
     if isinstance(attrs, MultiHeadAttentionAttrs) and attrs.qk_norm:
         # the two QK-norm gains are the last two slots
         return [None] * (num_weights - 2) + [ConstantInitializerAttrs(1.0)] * 2
+    if isinstance(attrs, MultiHeadAttentionAttrs) and attrs.latent:
+        # the latent norm's gain is the last slot
+        return [None] * (num_weights - 1) + [ConstantInitializerAttrs(1.0)]
     if isinstance(attrs, StateSpaceAttrs):
         from flexflow_tpu.pcg.initializer import (
             InverseSoftplusLogUniformInitializerAttrs,
@@ -375,6 +386,25 @@ def get_default_weight_initializers(attrs: OpAttrs, num_weights: int):
             None, conv, conv,
             InverseSoftplusLogUniformInitializerAttrs(1e-3, 1e-1, 1e-4),
             LogOfUniformInitializerAttrs(1.0, 16.0), one, one, None,
+        ][:num_weights]
+    if isinstance(attrs, GatedDeltaAttrs):
+        from flexflow_tpu.pcg.initializer import (
+            InverseSoftplusLogUniformInitializerAttrs,
+            LogOfUniformInitializerAttrs,
+            UniformInitializerAttrs,
+        )
+
+        # as the public KDA layer starts (recalled; `assumed` in the
+        # benchmark's configuration): the convolution as torch's conv1d, the
+        # decay's step log-uniform in [1e-3, 1e-1] a key channel through the
+        # inverse of softplus, A uniform in [1, 16] a head stored as its
+        # log, the gate's bias zero (a vector's own default), the gain one
+        bound = float(attrs.conv_kernel) ** -0.5
+        return [
+            None, UniformInitializerAttrs(min_val=-bound, max_val=bound), None,
+            InverseSoftplusLogUniformInitializerAttrs(1e-3, 1e-1, 1e-4),
+            LogOfUniformInitializerAttrs(1.0, 16.0), None, None,
+            ConstantInitializerAttrs(1.0), None,
         ][:num_weights]
     return [None] * num_weights
 
@@ -421,6 +451,8 @@ def get_parallel_weight_shapes(
             ]
         if attrs.qk_norm:
             ws += [attrs.parallel_qk_gain_shape(q, k, v)] * 2
+        if attrs.latent:
+            ws += [attrs.parallel_latent_gain_shape(q, k, v)]
         return ws
     if isinstance(attrs, Conv2DAttrs):
         ws = [attrs.parallel_kernel_shape(inputs[0])]
@@ -437,6 +469,6 @@ def get_parallel_weight_shapes(
         return [g, g]
     if isinstance(attrs, RMSNormAttrs):
         return [attrs.parallel_gamma_shape(inputs[0])]
-    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs)):
+    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs, GatedDeltaAttrs)):
         return list(attrs.parallel_weight_shapes(inputs[0]))
     return []

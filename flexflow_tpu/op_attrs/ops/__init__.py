@@ -70,3 +70,4 @@ from flexflow_tpu.op_attrs.ops.moe import (
     expert_capacity,
 )
 from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
+from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs

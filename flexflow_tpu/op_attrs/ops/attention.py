@@ -56,8 +56,34 @@ class MultiHeadAttentionAttrs:
     # per-head column as in the equal-head layout has no place for a
     # key/value head that several query heads share.
     num_kv_heads: Optional[int] = None
+    # kv_latent_rank: latent attention (multi-head latent attention with no
+    # position encoding, as `kimi_linear` with `mla_use_nope` writes it). The
+    # keys and values come from ONE low-rank row: [c | k_s] = x W_kv_a
+    # ([e, rank + shared_key_dim]), [k_n | v] = rms_norm(c; gain [rank])
+    # W_kv_b ([rank, h * (kdim - shared_key_dim + vdim)], head-major columns,
+    # a head's k_n then its v), and head h's key is [k_n^h | k_s]: its last
+    # `shared_key_dim` columns are one slice shared by all the heads. `kdim`
+    # is the whole key (and query) width, so kdim != vdim is allowed here.
+    # The weight is one flat column [wq | wkv_a | wkv_b | wo, 1] (wq
+    # [e, h * kdim], wo [h * vdim, e]), and the latent norm's gain is one
+    # more weight slot after it. No bias, rotary, QK-norm or grouped heads.
+    kv_latent_rank: Optional[int] = None
+    shared_key_dim: int = 0
+    kv_latent_norm_eps: float = 1e-5
 
     def __post_init__(self):
+        if self.kv_latent_rank is not None:
+            assert not (
+                self.bias or self.qk_norm or self.rope_theta is not None
+                or self.num_kv_heads is not None
+            ), "latent attention takes no bias, QK-norm, rotary or grouped heads"
+            assert self.kdim > self.shared_key_dim >= 0 and self.vdim > 0, (
+                "latent attention names its key and value widths"
+            )
+        else:
+            assert self.shared_key_dim == 0, (
+                "a shared key slice comes with kv_latent_rank"
+            )
         if self.num_kv_heads is not None:
             assert self.num_heads % self.num_kv_heads == 0, (
                 f"{self.num_heads} query heads do not divide over "
@@ -77,6 +103,16 @@ class MultiHeadAttentionAttrs:
     @property
     def kv_heads(self) -> int:
         return self.num_kv_heads if self.grouped_query else self.num_heads
+
+    @property
+    def latent(self) -> bool:
+        return self.kv_latent_rank is not None
+
+    @property
+    def own_key_dim(self) -> int:
+        """A head's own key columns (latent attention: without the slice
+        the heads share)."""
+        return self.q_proj_size - self.shared_key_dim
 
     @property
     def qk_norm(self) -> bool:
@@ -107,6 +143,15 @@ class MultiHeadAttentionAttrs:
         """Flat per-head weight [wq+wk+wv+wo, num_heads]
         (reference attention.cc:136-170)."""
         self._check_inputs(q, k, v)
+        if self.latent:
+            h, rank = self.num_heads, self.kv_latent_rank
+            flat = (
+                q.dims[-1] * h * self.q_proj_size
+                + k.dims[-1] * (rank + self.shared_key_dim)
+                + rank * h * (self.own_key_dim + self.v_proj_size)
+                + h * self.v_proj_size * self.embed_dim
+            )
+            return TensorShape((flat, 1), q.dtype)
         if self.grouped_query:
             h, kv = self.num_heads, self.num_kv_heads
             flat = (
@@ -135,6 +180,9 @@ class MultiHeadAttentionAttrs:
     def qk_gain_shape(self, q: TensorShape, k: TensorShape, v: TensorShape) -> TensorShape:
         """One QK-norm gain (q's and k's have the same shape)."""
         return TensorShape((self.num_heads * self.q_proj_size,), q.dtype)
+
+    def latent_gain_shape(self, q: TensorShape) -> TensorShape:
+        return TensorShape((self.kv_latent_rank,), q.dtype)
 
     # -- parallel ---------------------------------------------------------
 
@@ -170,9 +218,9 @@ class MultiHeadAttentionAttrs:
         )
 
     def _check_qk_norm_heads(self, head_degree: int) -> None:
-        assert not (self.grouped_query and head_degree > 1), (
-            "grouped-query attention keeps its projections in one flat "
-            "column: it cannot be head-parallel yet"
+        assert not ((self.grouped_query or self.latent) and head_degree > 1), (
+            "grouped-query and latent attention keep their projections in "
+            "one flat column: they cannot be head-parallel yet"
         )
         assert not (self.qk_norm and head_degree > 1), (
             "QK-norm takes its mean of squares over every head's features: "
@@ -230,5 +278,13 @@ class MultiHeadAttentionAttrs:
         unpar = self.qk_gain_shape(
             get_reduced_shape(q), get_reduced_shape(k), get_reduced_shape(v)
         )
+        copies = self.parallel_weights_shape(q, k, v).discard_copy_degree
+        return lift_to_parallel_with_degrees(unpar, 1, copies, (1,))
+
+    def parallel_latent_gain_shape(
+        self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
+    ) -> ParallelTensorShape:
+        """The latent norm's gain, replicated wherever the flat weight is."""
+        unpar = self.latent_gain_shape(get_reduced_shape(q))
         copies = self.parallel_weights_shape(q, k, v).discard_copy_degree
         return lift_to_parallel_with_degrees(unpar, 1, copies, (1,))

@@ -70,8 +70,8 @@ class RingAttentionAttrs(MultiHeadAttentionAttrs):
         assert seq_degree == 1 or not (
             self.qk_norm or self.rope_theta is not None
         ), "attention with rope_theta or qk_norm_eps cannot be sequence-parallel yet"
-        assert seq_degree == 1 or not self.grouped_query, (
-            "grouped-query attention cannot be sequence-parallel yet"
+        assert seq_degree == 1 or not (self.grouped_query or self.latent), (
+            "grouped-query and latent attention cannot be sequence-parallel yet"
         )
         unpar = self.output_shape(
             get_reduced_shape(q), get_reduced_shape(k), get_reduced_shape(v)

@@ -29,7 +29,9 @@ out_proj [inner, D]. No bias on the projections.
 Parallel rule: batch and nothing else. The scan runs along the sequence and
 every head reads the whole input row, so the sequence and feature dims stay
 whole; weights are replicated over the batch shards. Head- or group-sharded
-mixers are not expressed yet (ROADMAP, Reach (5)).
+mixers are not expressed yet (ROADMAP, Reach, "What the system cannot run
+yet" (5), where the gated delta-rule mixer's same gap is listed:
+`op_attrs/ops/kda.py`).
 """
 
 from __future__ import annotations

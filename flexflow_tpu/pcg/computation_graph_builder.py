@@ -245,15 +245,22 @@ class ComputationGraphBuilder:
         rope_theta: Optional[float] = None,
         qk_norm_eps: Optional[float] = None,
         num_kv_heads: Optional[int] = None,
+        kv_latent_rank: Optional[int] = None,
+        shared_key_dim: int = 0,
+        kv_latent_norm_eps: float = 1e-5,
     ) -> Tensor:
         """`causal`, `rope_theta` (rotary positions 0..s-1 on q and k) and
         `qk_norm_eps` (RMS norm of the projected q and k over all heads'
         features, two gain weights) are what a decoder adds; a causal node
         is a `RingAttentionAttrs`, the program's causal attention.
-        `num_kv_heads` fewer than `num_heads` is grouped-query attention."""
+        `num_kv_heads` fewer than `num_heads` is grouped-query attention.
+        `kv_latent_rank` is latent attention: keys and values from one
+        normed low-rank row, the last `shared_key_dim` of a key's `kdim`
+        columns one slice for all heads (`MultiHeadAttentionAttrs`)."""
         fields = (
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, rope_theta, qk_norm_eps, num_kv_heads,
+            kv_latent_rank, shared_key_dim, kv_latent_norm_eps,
         )
         if causal:
             from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
@@ -621,6 +628,36 @@ class ComputationGraphBuilder:
         )
         inits = [None] * attrs.num_weights
         inits[0] = inits[-1] = initializer
+        (out,) = self.add_layer(attrs, [input], inits, name)
+        return out
+
+    def gated_delta(
+        self,
+        input: Tensor,
+        num_heads: int,
+        key_dim: int,
+        value_dim: int,
+        conv_kernel: int = 4,
+        gate_rank: int = 128,
+        chunk_size: int = 64,
+        norm_eps: float = 1e-5,
+        initializer: Optional[InitializerAttrs] = None,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """The gated delta-rule linear-attention mixer (`GatedDeltaAttrs`) on
+        [batch, seq, channel]. `initializer`, if given, initializes the
+        projections (in, out and the two low-rank gates' up-projections);
+        the convolution, `dt_bias`, `A_log`, the gate's bias and the norm's
+        gain take the op's own defaults."""
+        from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+
+        attrs = GatedDeltaAttrs(
+            num_heads, key_dim, value_dim, conv_kernel, gate_rank,
+            chunk_size, norm_eps,
+        )
+        inits = [None] * attrs.num_weights
+        for slot in (0, 2, 5, 8):
+            inits[slot] = initializer
         (out,) = self.add_layer(attrs, [input], inits, name)
         return out
 

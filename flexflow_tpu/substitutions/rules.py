@@ -515,17 +515,26 @@ def _experts_tag(use_bias, gated, with_aux, shared=False, latent=False):
     )
 
 
-def data_parallel_state_space_rule(degree: int) -> Substitution:
+def data_parallel_state_space_rule(
+    degree: int, op_type: OperatorType = OperatorType.STATE_SPACE
+) -> Substitution:
     """StateSpace(x, w...) -> Combine_0(StateSpace(Repartition_0(x),
     Replicate(w)...)): the scan runs along the sequence of each sample by
-    itself, so the batch dim shards and nothing else does."""
+    itself, so the batch dim shards and nothing else does. The same rule for
+    the gated delta-rule mixer (`op_type` GATED_DELTA), whose recurrence
+    runs along the sequence as the scan does."""
+    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
     from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 
+    attrs_cls = (
+        StateSpaceAttrs if op_type == OperatorType.STATE_SPACE
+        else GatedDeltaAttrs
+    )
     p = PCGPattern()
     a = p.add_input(_shard_pattern(0, degree))
-    ws = [p.add_input() for _ in range(StateSpaceAttrs.num_weights)]
+    ws = [p.add_input() for _ in range(attrs_cls.num_weights)]
     pnode, (py,) = p.add_operator(
-        OperatorAttributePattern.for_op_type(OperatorType.STATE_SPACE), [a, *ws]
+        OperatorAttributePattern.for_op_type(op_type), [a, *ws]
     )
     og = OutputGraphExpr()
     oa = og.add_input()
@@ -538,7 +547,7 @@ def data_parallel_state_space_rule(degree: int) -> Substitution:
     _, (y,) = og.add_operator(CopyAttrsFromMatched(pnode), [ap, *reps])
     _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [y])
     return Substitution(
-        f"data_parallel_state_space_{degree}",
+        f"data_parallel_{op_type.value}_{degree}",
         p,
         og,
         ((a, oa), *zip(ws, ows)),
@@ -730,6 +739,7 @@ def branch_reduce_sum_rule(degree: int) -> Substitution:
 def data_parallel_attention_rule(
     degree: int, bias: bool = False, qk_norm: bool = False,
     op_type: OperatorType = OperatorType.MULTIHEAD_ATTENTION,
+    latent: bool = False,
 ) -> Substitution:
     """MHA(q,k,v,w[,bi,bo][,gq,gk]) -> Combine_0(MHA(Repartition_0(q,k,v),
     Replicate(w)[, Replicate(bi), Replicate(bo)][, Replicate(gq),
@@ -740,19 +750,27 @@ def data_parallel_attention_rule(
     two more weights, replicated like w), `qk_norm=True` the op with
     QK-norm (its two gains likewise); RoPE adds no slot and needs no rule
     of its own. `op_type=RING_ATTENTION` is the same rewrite for the
-    program's causal attention, whose sequence dim stays whole here."""
+    program's causal attention, whose sequence dim stays whole here;
+    `latent=True` matches latent attention (the latent norm's gain as one
+    more weight)."""
     p = PCGPattern()
     q = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     k = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     v = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     weights = [
-        p.add_input() for _ in range(1 + 2 * bool(bias) + 2 * bool(qk_norm))
+        p.add_input()
+        for _ in range(1 + 2 * bool(bias) + 2 * bool(qk_norm) + bool(latent))
     ]
+    ne = {}
+    if qk_norm:
+        ne.update(qk_norm_eps=None)
+    if latent:
+        ne.update(kv_latent_rank=None)
     pnode, (py,) = p.add_operator(
         _attr_pattern(
             op_type,
             eq=dict(bias=bias),
-            ne=dict(qk_norm_eps=None) if qk_norm else None,
+            ne=ne or None,
         ),
         [q, k, v, *weights],
     )
@@ -772,7 +790,8 @@ def data_parallel_attention_rule(
     return Substitution(
         f"data_parallel_"
         f"{'ring_' if op_type == OperatorType.RING_ATTENTION else ''}"
-        f"attention_{'b_' if bias else ''}{'qkn_' if qk_norm else ''}{degree}",
+        f"attention_{'b_' if bias else ''}{'qkn_' if qk_norm else ''}"
+        f"{'lat_' if latent else ''}{degree}",
         p,
         og,
         ((q, oq), (k, ok), (v, ov), *zip(weights, o_weights)),
@@ -1091,9 +1110,17 @@ def generate_parallelization_rules(
                     k, False, qk_norm=True, op_type=op_type
                 )
             )
+            rules.append(
+                data_parallel_attention_rule(
+                    k, False, op_type=op_type, latent=True
+                )
+            )
         rules.append(data_parallel_layer_norm_rule(k))
         rules.append(data_parallel_rms_norm_rule(k))
         rules.append(data_parallel_state_space_rule(k))
+        rules.append(
+            data_parallel_state_space_rule(k, OperatorType.GATED_DELTA)
+        )
         rules.append(sequence_parallel_attention_rule(k))
         rules.append(sequence_parallel_attention_a2a_rule(k))
         # sequence-axis (dim=1) variants: the seq-parallel residual stream's

@@ -16,6 +16,14 @@ a buffer (what a fusion keeps inside its body is not):
   compiler one): the norm's statistics come from masked sums that XLA fuses
   the gate's product into, and its result is written once in the step's dtype.
 
+The same child also compiles what the `kimi_linear` cell added (PR 43), at its
+published shape, 32 heads of 128 over 4,096 positions: ONE gated delta-rule
+node (`kernels/kda.gated_delta_forward`) forward and backward, whose
+chunk-to-chunk pass must come out as its three Pallas kernels and which must
+hold no `[heads, positions, 128, 128]` state per position; and the causal
+flash kernels on a 256-wide padded key beside a 128-wide value
+(`flash_attention_bshf_wide_key`), forward and backward.
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -163,6 +171,93 @@ def check(name):
     }
 
 
+KIMI_INVARIANTS = [
+    "kda_node_compiles_with_its_three_kernels",
+    "kda_holds_no_state_per_position",
+    "wide_key_flash_compiles_forward_and_backward",
+]
+
+
+def check_kimi():
+    """{invariant: "ok" or what was found} for the `kimi_linear` cell's two
+    new kernel paths at the published shape."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from flexflow_tpu.kernels import flash_attention as fa
+    from flexflow_tpu.kernels import kda
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    fa._backend_ok = lambda allow_interpret=False: True
+    topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(dims):
+        return jax.ShapeDtypeStruct(tuple(dims), jnp.bfloat16, sharding=chip)
+
+    found = {}
+    hidden, heads = 2304, 32
+    try:
+        attrs = GatedDeltaAttrs(heads, 128, 128, 4, 128, 64, 1e-5)
+        u = on_chip((1, ROWS, hidden))
+        weights = [
+            on_chip(w.dims) for w in attrs.weight_shapes(
+                TensorShape((1, ROWS, hidden), DataType.FLOAT)
+            )
+        ]
+
+        def node(u, weights, cot):
+            y, vjp = jax.vjp(
+                lambda u, weights: kda.gated_delta_forward(attrs, u, weights),
+                u, weights,
+            )
+            return y, vjp(cot)
+
+        text = jax.jit(node).lower(u, weights, u).compile().as_text()
+        kernels = text.count("tpu_custom_call")
+        found["kda_node_compiles_with_its_three_kernels"] = (
+            "ok" if kernels == 3 else f"{kernels} kernels, want 3"
+        )
+        states = [
+            f"{dtype}{list(dims)}" for dtype, dims in shapes_of(text)
+            if len(dims) >= 4 and dims[-2:] == (128, 128) and ROWS in dims
+        ]
+        found["kda_holds_no_state_per_position"] = (
+            "ok" if not states else ", ".join(sorted(set(states)))
+        )
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        complaint = f"{type(e).__name__}: {e}"[:2000]
+        found.setdefault("kda_node_compiles_with_its_three_kernels", complaint)
+        found.setdefault("kda_holds_no_state_per_position", complaint)
+    try:
+        q = on_chip((1, ROWS, heads * 256))
+        v = on_chip((1, ROWS, heads * 128))
+
+        def core(q, k, v, cot):
+            o, vjp = jax.vjp(
+                lambda q, k, v: fa.flash_attention_bshf_wide_key(
+                    q, k, v, heads, scale=192 ** -0.5
+                ), q, k, v,
+            )
+            return o, vjp(cot)
+
+        text = jax.jit(core).lower(q, q, v, v).compile().as_text()
+        kernels = text.count("tpu_custom_call")
+        found["wide_key_flash_compiles_forward_and_backward"] = (
+            "ok" if kernels == 3 else f"{kernels} kernels, want 3"
+        )
+    except Exception as e:  # noqa: BLE001
+        found["wide_key_flash_compiles_forward_and_backward"] = (
+            f"{type(e).__name__}: {e}"[:2000]
+        )
+    return found
+
+
 def listing(name, least=4e6):
     """The node's ENTRY instructions that move `least` bytes or more."""
     _, text = compiled_node(name)
@@ -213,6 +308,11 @@ def test_node_compiled_for_the_described_chip(compiled, shape, invariant):
     assert compiled[shape][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", KIMI_INVARIANTS)
+def test_kimi_kernels_compiled_for_the_described_chip(compiled, invariant):
+    assert compiled["kimi"][invariant] == "ok"
+
+
 if __name__ == "__main__":
     argv = sys.argv[1:]
     root = os.getcwd()
@@ -224,4 +324,6 @@ if __name__ == "__main__":
     if argv:
         print(listing(argv[0]))
     else:
-        print(json.dumps({name: check(name) for name in SHAPES}))
+        print(json.dumps(
+            dict({name: check(name) for name in SHAPES}, kimi=check_kimi())
+        ))

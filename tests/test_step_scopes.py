@@ -142,6 +142,29 @@ def test_scope_name_survives_the_name_stack(layer_name, want):
          ("fwd", "experts", "moe1/routed")),
         ("jit(_step)/transpose(jvp(ff.experts.moe1))/shared/shared_expert/mul",
          ("bwd", "experts", "moe1/shared")),
+        # the gated delta-rule node's parts; its recurrence is recomputed
+        # under one `jax.checkpoint`, which writes the rematerialised
+        # forward's own scope between the node's and the part's
+        ("jit(_step)/jvp(ff.kda.kda2)/prep/dot_general",
+         ("fwd", "kda", "kda2/prep")),
+        ("jit(_step)/jvp(ff.kda.kda2)/scan/kda_fwd_chunk/pallas_call",
+         ("fwd", "kda", "kda2/scan")),
+        ("jit(_step)/transpose(jvp(ff.kda.kda2))/jvp(ff.kda.kda2)/checkpoint"
+         "/rematted_computation/prep/exp", ("bwd", "kda", "kda2/prep")),
+        ("jit(_step)/transpose(jvp(ff.kda.kda2))/jvp(ff.kda.kda2)/checkpoint"
+         "/scan/kda_bwd_chunk/pallas_call", ("bwd", "kda", "kda2/scan")),
+        ("jit(_step)/transpose(jvp(ff.kda.kda2))/gates/dot_general",
+         ("bwd", "kda", "kda2/gates")),
+        ("jit(_step)/transpose(jvp(ff.kda.kda2))/jvp(ff.kda.kda2)/checkpoint"
+         "/mul", ("bwd", "kda", "kda2")),
+        # latent attention's low-rank projections and its core
+        ("jit(_step)/jvp(ff.ring_attention.mla4)/latent/dot_general",
+         ("fwd", "ring_attention", "mla4/latent")),
+        ("jit(_step)/transpose(jvp(ff.ring_attention.mla4))/core"
+         "/flash_bwd_causal_bshf/pallas_call",
+         ("bwd", "ring_attention", "mla4/core")),
+        ("jit(_step)/jvp(ff.ring_attention.a0)/dot_general",
+         ("fwd", "ring_attention", "a0")),
         # a scope that only begins like a part, and a part of another kind
         ("jit(_step)/jvp(ff.experts.moe1)/shared_expert/mul",
          ("fwd", "experts", "moe1")),
