@@ -39,30 +39,43 @@ I + Diag(beta) A goes up the same levels (`unit_lower_inverse`): with X the
 inverse of the diagonal blocks so far and L the level's off-diagonal part,
 X <- X - X L X is exact block elimination, two [Q, Q] products a level.
 
-Two forms of the chunk-to-chunk pass exist, and `scan_route` picks one from
-what the trace can observe (shapes, backend, `no_flash`, `flash_mesh`):
+Two forms of the recurrence exist, and `scan_route` picks one from what the
+trace can observe (shapes, backend, `no_flash`, `flash_mesh`); the ONE route
+decides the chunks' operands and the chunk-to-chunk pass alike:
 
-- **The Pallas kernels** (`kda_fwd_chunk`, `kda_states_chunk`,
-  `kda_bwd_chunk`; "kda"): on a TPU at heads whose key and value sizes are
-  multiples of 128 lanes. One program is one (batch row, head, chunk); the
-  chunk axis is sequential and the head's [dv, dk] float32 state (held
-  transposed, so that the end-of-chunk decay is a row that broadcasts along
-  sublanes) rides a VMEM scratch from chunk to chunk, last to first in the
-  backward.
+- **The Pallas kernels** ("kda"): on a TPU at heads whose key and value sizes
+  are multiples of 128 lanes.
+  *The operands* (`kernel_operands`): `kda_prep_fwd` computes every decay and
+  the decayed scores of eight chunks a program in VMEM, so that nothing a
+  level computes reaches HBM, and `kda_prep_bwd` is its WRITTEN backward
+  (`chunk_scores`, one `jax.custom_vjp` that keeps q, k and g and recomputes
+  the decays). Every exponent is the product of a constant 0/1 matrix with g
+  (the set of positions whose log-decays it sums), so it is <= 0 by
+  construction, float32-exact in three bf16 passes, and its transpose is the
+  way back to g. `kda_prep_inverse` is `unit_lower_inverse`'s levels with two
+  chunks side by side along the lanes; the inverse's two products with
+  K exp(G) and V, and its backward, stay XLA's batched float32 products.
+  *The pass* (`kda_fwd_chunk`, `kda_states_chunk`, `kda_bwd_chunk`): one
+  program is one (batch row, head, chunk); the chunk axis is sequential and
+  the head's [dv, dk] float32 state (held transposed, so that the
+  end-of-chunk decay is a row that broadcasts along sublanes) rides a VMEM
+  scratch from chunk to chunk, last to first in the backward.
 - **The XLA form** ("xla"): everything else (the CPU, toy widths, a trace
-  that admits no bare Pallas call, a declared mesh): the same products as a
-  `lax.scan` over the chunks.
+  that admits no bare Pallas call, a declared mesh): `chunk_operands`, which
+  JAX differentiates but for the triangular inverse, and the same products
+  of the pass as a `lax.scan` over the chunks. It is what the kernels are
+  tested against.
 
-Both are one `jax.custom_vjp` (`chunk_scan`) with a WRITTEN backward: it
-recomputes the state every chunk starts from in a first pass and walks the
-chunks last to first in a second, carrying the state's gradient. The whole
-recurrence (normalisation, gates, `chunk_operands`, `chunk_scan`) runs under
-one `jax.checkpoint`, so what a step keeps of the node for its backward is
-the op's inputs to it (the convolved q | k | v, the two gates' pre-activations
-and the step's logits) and nothing a chunk computes; the chunk operands are
-recomputed there, and differentiated by JAX but for the triangular inverse,
-whose backward is written too (`unit_lower_inverse`). In neither form does a state
-per position ever exist.
+The pass is one `jax.custom_vjp` (`chunk_scan`) with a WRITTEN backward in
+both forms: it recomputes the state every chunk starts from in a first pass
+and walks the chunks last to first in a second, carrying the state's
+gradient. The whole recurrence (normalisation, gates, the operands,
+`chunk_scan`) runs under one `jax.checkpoint`, so what a step keeps of the
+node for its backward is the op's inputs to it (the convolved q | k | v, the
+two gates' pre-activations and the step's logits) and ONE thing a chunk
+computes: the triangular inverse (`_KEPT`, 16 KB a chunk), whose ten products
+a chunk would otherwise run again. Everything else is recomputed there. In
+neither form does a state per position ever exist.
 
 The node's parts go under scopes of their own inside the node's
 (`ff.kda.<name>/scan`, `/prep`, `/gates`, `/conv`, `/norm`;
@@ -78,6 +91,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -88,6 +102,8 @@ from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 # normalised (the public layer's l2norm; `assumed` in the benchmark's file)
 L2_EPS = 1e-6
 _HIGHEST = lax.Precision.HIGHEST
+# the one value of the recurrence that the node's checkpoint keeps
+_KEPT = "kda_triangular_inverse"
 
 
 def _level_blocks(q: int, m: int):
@@ -96,6 +112,13 @@ def _level_blocks(q: int, m: int):
     pos = np.arange(q)
     block = pos // (2 * m)
     return (pos % (2 * m)) >= m, block[:, None] == block[None, :]
+
+
+def _level_mask(q: int, m: int):
+    """[Q, Q]: the pairs (r in the later half, j in the earlier half of the
+    same block of 2m rows), which the level gives."""
+    later, same_block = _level_blocks(q, m)
+    return same_block & later[:, None] & ~later[None, :]
 
 
 def decayed_scores(rows, cols, gc):
@@ -139,12 +162,11 @@ def unit_lower_inverse(n):
     WRITTEN: with X the inverse, dn = -X^T dX X^T below the diagonal, two
     products where differentiating the levels costs four a level."""
     q = n.shape[-1]
-    x = jnp.broadcast_to(jnp.eye(q, dtype=n.dtype), n.shape)
-    m = 1
+    # the first level's X is the identity, and I L I = L: no product
+    x = jnp.eye(q, dtype=n.dtype) - jnp.where(_level_mask(q, 1), n, 0.0)
+    m = 2
     while m < q:
-        later, same_block = _level_blocks(q, m)
-        level = same_block & later[:, None] & ~later[None, :]
-        below = jnp.where(level, n, 0.0)
+        below = jnp.where(_level_mask(q, m), n, 0.0)
         step = jnp.matmul(
             jnp.matmul(x, below, precision=_HIGHEST), x, precision=_HIGHEST
         )
@@ -153,9 +175,15 @@ def unit_lower_inverse(n):
     return x
 
 
-def _unit_lower_inverse_fwd(n):
-    x = unit_lower_inverse(n)
-    return x, x
+def _kept_inverse(inverse):
+    """The forward rule of `inverse`: the node's checkpoint keeps the inverse
+    (`_KEPT`) where it recomputes everything else, 16 KB a chunk for ten
+    products."""
+    def fwd(n):
+        x = checkpoint_name(inverse(n), _KEPT)
+        return x, x
+
+    return fwd
 
 
 def _unit_lower_inverse_bwd(x, dx):
@@ -166,7 +194,19 @@ def _unit_lower_inverse_bwd(x, dx):
     return (jnp.where(np.tri(x.shape[-1], k=-1, dtype=bool), -dn, 0.0),)
 
 
-unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
+unit_lower_inverse.defvjp(
+    _kept_inverse(unit_lower_inverse), _unit_lower_inverse_bwd
+)
+
+
+def _corrected(a, kd, v5, beta5, dtype, inverse=unit_lower_inverse):
+    """T (K exp(G)) and T V in `dtype`, T = (I + Diag(beta) A)^-1 Diag(beta):
+    a [.., Q, Q] and kd [.., Q, dk] float32, v5 [.., Q, dv], beta5 [.., Q];
+    `inverse` is `unit_lower_inverse` or the kernel that computes the same."""
+    t = inverse(beta5[..., :, None] * a) * beta5[..., None, :]
+    w = jnp.matmul(t, kd, precision=_HIGHEST).astype(dtype)
+    uv = jnp.matmul(t, v5.astype(jnp.float32), precision=_HIGHEST).astype(dtype)
+    return w, uv
 
 
 def chunk_operands(q, k, v, g, beta, chunk: int):
@@ -175,7 +215,8 @@ def chunk_operands(q, k, v, g, beta, chunk: int):
     beta [b, h, s] float32; s a multiple of `chunk`. Returns, by chunk
     ([b, h, c, Q, .]): Q exp(G) and T (K exp(G)) [., dk], T V [., dv],
     K exp(G_Q - G) [., dk], the inclusive decayed scores P [., Q] in the
-    inputs' dtype, and exp(G_Q) [b, h, c, 1, dk] float32."""
+    inputs' dtype, and exp(G_Q) [b, h, c, 1, dk] float32. The XLA form, which
+    JAX differentiates but for the triangular inverse."""
     b, h, s, dk = q.shape
     dtype = q.dtype
     f32 = jnp.float32
@@ -185,7 +226,6 @@ def chunk_operands(q, k, v, g, beta, chunk: int):
 
     q5, k5, v5 = by_chunk(q), by_chunk(k), by_chunk(v)
     gc = jnp.cumsum(by_chunk(g), axis=3)
-    beta5 = by_chunk(beta)
     from_start = jnp.exp(gc)
     g_end = gc[..., -1:, :]
     qf, kf = q5.astype(f32), k5.astype(f32)
@@ -197,9 +237,7 @@ def chunk_operands(q, k, v, g, beta, chunk: int):
     # a position reads its own key undecayed
     own = jnp.sum(qf * kf, axis=-1)
     p = p + own[..., None] * jnp.eye(chunk, dtype=f32)
-    t = unit_lower_inverse(beta5[..., :, None] * a) * beta5[..., None, :]
-    w = jnp.matmul(t, kd, precision=_HIGHEST).astype(dtype)
-    uv = jnp.matmul(t, v5.astype(f32), precision=_HIGHEST).astype(dtype)
+    w, uv = _corrected(a, kd, v5, by_chunk(beta), dtype)
     return qd, w, uv, ke, p.astype(dtype), jnp.exp(g_end)
 
 
@@ -484,8 +522,400 @@ def _interpret() -> bool:
     return flash.interpret_default()
 
 
+# ---------------------------------------------------------------------------
+# the chunks' operands, the Pallas form: nothing a level computes reaches HBM
+# ---------------------------------------------------------------------------
+#
+# One program is `n` chunks of one (batch row, head), unrolled: the chunks are
+# independent, so every grid axis is parallel and the scheduler has n product
+# chains to interleave. Every exponent is a SUM of the chunk's log-decays over
+# a set of positions, so it is a product of a constant 0/1 matrix with g, and
+# <= 0 by construction: for the pair (r, j) of a level with reference `ref`,
+# the row's set is (ref, r] and the column's (j, ref] (`_prep_tables`). The
+# matrix is exact in bf16 and g is split into three bf16 parts that add up to
+# it exactly (`_bf16_parts`), so three MXU passes give the float32 sums, and
+# their transpose gives the log-decays' gradient from the exponents'.
+
+# the decays a chunk needs before its levels': from the chunk's start (the
+# inclusive running sum G) and to its end (G_Q - G)
+_FROM_START, _TO_END, _FIRST_LEVEL = 0, 1, 2
+_PARTS = 3  # bf16 parts of a float32 operand
+
+
+@functools.lru_cache(maxsize=None)
+def _prep_tables(q: int):
+    """(sums, sums_t, owner) of a chunk of q positions. A piece is the [q, q]
+    0/1 matrix whose product with g is one set of exponents: from the start,
+    to the end, then a level each, the widest first. `sums`
+    [pieces * q, 3 q] bf16 stacks the pieces down its rows, each written
+    three times side by side (once a bf16 part of g, which are stacked down
+    the contraction: ONE product gives every exponent); `sums_t`
+    [q, pieces * 3 q] lays the pieces' transposes side by side the same way
+    (one product takes every exponent's cotangent back to g). `owner` [q, q]
+    int32 names which level gives the pair (r, j), j < r, with `levels` on
+    the diagonal and `levels + 1` above it."""
+    pos = np.arange(q)
+    r, i = pos[:, None], pos[None, :]
+    pieces = [i <= r, i > r]
+    owner = np.zeros((q, q), np.int32)
+    m, level = q // 2, 0
+    while m >= 1:
+        ref = (pos // (2 * m)) * (2 * m) + m
+        low, high = np.minimum(pos, ref)[:, None], np.maximum(pos, ref)[:, None]
+        pieces.append((low < i) & (i <= high))
+        owner[_level_mask(q, m)] = level
+        m //= 2
+        level += 1
+    owner[r == i] = level
+    owner[r < i] = level + 1
+    sums = np.concatenate([np.tile(p, (1, _PARTS)) for p in pieces], axis=0)
+    sums_t = np.concatenate([np.tile(p.T, (1, _PARTS)) for p in pieces], axis=1)
+    return sums.astype(jnp.bfloat16), sums_t.astype(jnp.bfloat16), owner
+
+
+def _bf16_parts(x):
+    """Three bf16 arrays that add up to the float32 x exactly."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = x.astype(bf16)
+    rest = x - hi.astype(f32)
+    mid = rest.astype(bf16)
+    return hi, mid, (rest - mid.astype(f32)).astype(bf16)
+
+
+def _stacked_parts(x):
+    """`_bf16_parts` of x [q, .] stacked down the rows [3 q, .]: against a
+    0/1 table written three times side by side they give the table's float32
+    product with x, every term `HIGHEST` keeps, in one product."""
+    return jnp.concatenate(_bf16_parts(x), axis=0)
+
+
+def _chunk_decays(sums_ref, g, q: int):
+    """piece -> exp of that piece's sums of the chunk's log-decays g [q, dk],
+    float32 [q, dk]; every exponent is <= 0."""
+    decays = jnp.exp(_mm(sums_ref[:], _stacked_parts(g), _NN))
+    return lambda piece: decays[piece * q:(piece + 1) * q, :]
+
+
+def _kda_prep_fwd_kernel(
+    sums_ref, owner_ref, q_ref, k_ref, g_ref,
+    qd_ref, ke_ref, p_ref, gam_ref, a_ref, kd_ref, *, chunks: int,
+):
+    """Q exp(G), K exp(G_Q - G), P, exp(G_Q), the strictly lower key-against-
+    key scores A and K exp(G) (float32, for the triangular system) of each of
+    the program's chunks."""
+    f32 = jnp.float32
+    dtype = q_ref.dtype
+    q = owner_ref.shape[0]
+    levels = sums_ref.shape[0] // q - _FIRST_LEVEL
+    owner = owner_ref[:]
+
+    def one_chunk(c, _):
+        rows = pl.ds(pl.multiple_of(c * q, q), q)
+        qf, kf = q_ref[rows, :].astype(f32), k_ref[rows, :].astype(f32)
+        decay = _chunk_decays(sums_ref, g_ref[rows, :], q)
+        from_start = decay(_FROM_START)
+        qd_ref[rows, :] = (qf * from_start).astype(dtype)
+        kd_ref[rows, :] = kf * from_start
+        ke_ref[rows, :] = (kf * decay(_TO_END)).astype(dtype)
+        gam_ref[c] = from_start[q - 1:q, :]
+        # a position reads its own key undecayed
+        p = jnp.where(
+            owner == levels, _mm(q_ref[rows, :], k_ref[rows, :], _NT), 0.0
+        )
+        a = jnp.zeros((q, q), f32)
+        for level in range(levels):
+            e = decay(_FIRST_LEVEL + level)
+            kz = (kf * e).astype(dtype)
+            mine = owner == level
+            p = jnp.where(mine, _mm((qf * e).astype(dtype), kz, _NT), p)
+            a = jnp.where(mine, _mm(kz, kz, _NT), a)
+        p_ref[rows, :] = p.astype(dtype)
+        a_ref[rows, :] = a
+
+    lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
+
+
+def _kda_prep_bwd_kernel(
+    sums_ref, sums_t_ref, owner_ref, q_ref, k_ref, g_ref,
+    dqd_ref, dke_ref, dp_ref, dgam_ref, da_ref, dkd_ref,
+    dq_ref, dk_ref, dg_ref, dex_ref, *, chunks: int,
+):
+    """The cotangents of q, k and g from those of `_kda_prep_fwd_kernel`'s six
+    results, every decay recomputed. A level's pairs are (q e) (k e)^T and
+    (k e) (k e)^T under its mask, so its backward is the masked cotangents
+    against k e (the rows' factor) and their transposes against q e | k e
+    (the columns'). What reaches a piece's exponents is kept in bf16 parts in
+    `dex_ref` [pieces * 3 q, dk] and goes back to g through the transposed
+    sums in one product at the chunk's end."""
+    f32 = jnp.float32
+    dtype = q_ref.dtype
+    q = owner_ref.shape[0]
+    levels = sums_ref.shape[0] // q - _FIRST_LEVEL
+    owner = owner_ref[:]
+
+    def one_chunk(c, _):
+        rows = pl.ds(pl.multiple_of(c * q, q), q)
+        qf, kf = q_ref[rows, :].astype(f32), k_ref[rows, :].astype(f32)
+        decay = _chunk_decays(sums_ref, g_ref[rows, :], q)
+
+        def to_g(piece, d_exponent):
+            dex_ref[pl.ds(piece * _PARTS * q, _PARTS * q), :] = _stacked_parts(
+                d_exponent
+            )
+
+        from_start, to_end = decay(_FROM_START), decay(_TO_END)
+        dqd, dkd = dqd_ref[rows, :].astype(f32), dkd_ref[rows, :]
+        dke = dke_ref[rows, :].astype(f32)
+        dq = dqd * from_start
+        dk = dkd * from_start + dke * to_end
+        to_g(_FROM_START, (dqd * qf + dkd * kf) * from_start)
+        to_g(_TO_END, dke * kf * to_end)
+        dp, da = dp_ref[rows, :].astype(f32), da_ref[rows, :]
+        own = jnp.where(owner == levels, dp, 0.0).astype(dtype)
+        dq = dq + _mm(own, k_ref[rows, :], _NN)
+        dk = dk + _mm(own, q_ref[rows, :], _NN)
+        for level in range(levels):
+            e = decay(_FIRST_LEVEL + level)
+            qe, ke = qf * e, kf * e
+            qz, kz = qe.astype(dtype), ke.astype(dtype)
+            mine = owner == level
+            dp_l = jnp.where(mine, dp, 0.0).astype(dtype)
+            da_l = jnp.where(mine, da, 0.0).astype(dtype)
+            dqz = _mm(dp_l, kz, _NN)
+            dkz = _mm(da_l, kz, _NN) + _mm(
+                jnp.concatenate([dp_l, da_l], axis=0),
+                jnp.concatenate([qz, kz], axis=0), _TN,
+            )
+            dq = dq + dqz * e
+            dk = dk + dkz * e
+            to_g(_FIRST_LEVEL + level, dqz * qe + dkz * ke)
+        dq_ref[rows, :] = dq.astype(dq_ref.dtype)
+        dk_ref[rows, :] = dk.astype(dk_ref.dtype)
+        # exp(G_Q) is the decay from the start at the last position, and G_Q
+        # sums EVERY position's g
+        dg_ref[rows, :] = (
+            _mm(sums_t_ref[:], dex_ref[:], _NN)
+            + dgam_ref[c] * from_start[q - 1:q, :]
+        )
+
+    lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
+
+
+_PARALLEL_CHUNKS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel")
+)
+# chunks a program of the two kernels above (the largest that divides the
+# sequence's): independent product chains for the scheduler to interleave and
+# one grid step's fixed cost shared
+_PREP_CHUNKS = (8, 4, 2, 1)
+
+
+def _table(t):
+    """The BlockSpec of a constant table that every program reads whole."""
+    return pl.BlockSpec(t.shape, lambda *_: (0,) * t.ndim)
+
+
+class _PrepBlocks:
+    """The BlockSpecs over the grid (batch, head, group of n chunks) on
+    [b, h, s, .] operands."""
+
+    def __init__(self, b: int, h: int, s: int, dk: int, q: int):
+        c = s // q
+        n = self.chunks = next(n for n in _PREP_CHUNKS if c % n == 0)
+        self.grid = (b, h, c // n)
+
+        def rows(width):
+            return pl.BlockSpec(
+                (None, None, n * q, width), lambda bi, hi, gi: (bi, hi, gi, 0)
+            )
+
+        self.key, self.scores = rows(dk), rows(q)
+        self.gamma = pl.BlockSpec(
+            (None, None, n, 1, dk), lambda bi, hi, gi: (bi, hi, gi, 0, 0)
+        )
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _prep_forward(q, k, g, chunk, interpret):
+    """The kernel on q, k [b, h, s, dk] and g [b, h, s, dk] float32; by chunk
+    out: qd, ke, p, gamma as `chunk_operands` has them, then A [., Q, Q] and
+    K exp(G) [., Q, dk], float32."""
+    f32 = jnp.float32
+    b, h, s, dk = q.shape
+    c = s // chunk
+    at = _PrepBlocks(b, h, s, dk, chunk)
+    sums, _, owner = _prep_tables(chunk)
+
+    def rows(width, dtype):
+        return jax.ShapeDtypeStruct((b, h, s, width), dtype)
+
+    qd, ke, p, gamma, a, kd = pl.pallas_call(
+        functools.partial(_kda_prep_fwd_kernel, chunks=at.chunks),
+        grid=at.grid,
+        in_specs=[_table(sums), _table(owner), at.key, at.key, at.key],
+        out_specs=[at.key, at.key, at.scores, at.gamma, at.scores, at.key],
+        out_shape=[
+            rows(dk, q.dtype), rows(dk, q.dtype), rows(chunk, q.dtype),
+            jax.ShapeDtypeStruct((b, h, c, 1, dk), f32),
+            rows(chunk, f32), rows(dk, f32),
+        ],
+        compiler_params=_PARALLEL_CHUNKS,
+        interpret=interpret,
+        name="kda_prep_fwd",
+    )(jnp.asarray(sums), jnp.asarray(owner), q, k, g)
+
+    def by_chunk(t):
+        return t.reshape(b, h, c, chunk, t.shape[-1])
+
+    return by_chunk(qd), by_chunk(ke), by_chunk(p), gamma, by_chunk(a), by_chunk(kd)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _prep_backward(q, k, g, cotangents, chunk, interpret):
+    b, h, s, dk = q.shape
+    at = _PrepBlocks(b, h, s, dk, chunk)
+    sums, sums_t, owner = _prep_tables(chunk)
+    dqd, dke, dp, dgam, da, dkd = cotangents
+    return pl.pallas_call(
+        functools.partial(_kda_prep_bwd_kernel, chunks=at.chunks),
+        grid=at.grid,
+        in_specs=[
+            _table(sums), _table(sums_t), _table(owner),
+            at.key, at.key, at.key,
+            at.key, at.key, at.scores, at.gamma, at.scores, at.key,
+        ],
+        out_specs=[at.key, at.key, at.key],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(g.shape, g.dtype),
+        ],
+        scratch_shapes=[pltpu.VMEM((sums_t.shape[1], dk), jnp.bfloat16)],
+        compiler_params=_PARALLEL_CHUNKS,
+        interpret=interpret,
+        name="kda_prep_bwd",
+    )(jnp.asarray(sums), jnp.asarray(sums_t), jnp.asarray(owner), q, k, g,
+      _rows(dqd), _rows(dke), _rows(dp), dgam, _rows(da), _rows(dkd))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def chunk_scores(q, k, g, chunk: int):
+    """What the chunks' operands take from q, k and the log-decays alone, as
+    Pallas kernels with a WRITTEN backward (`_prep_forward`'s results). What
+    the backward keeps is q, k and g: it recomputes every decay."""
+    return _prep_forward(q, k, g, chunk, _interpret())
+
+
+def _chunk_scores_fwd(q, k, g, chunk):
+    return chunk_scores(q, k, g, chunk), (q, k, g)
+
+
+def _chunk_scores_bwd(chunk, kept, cotangents):
+    return tuple(_prep_backward(*kept, cotangents, chunk, _interpret()))
+
+
+chunk_scores.defvjp(_chunk_scores_fwd, _chunk_scores_bwd)
+
+
+# The triangular inverse as a kernel (`unit_lower_inverse`'s levels, its
+# products float32 with every term `HIGHEST` keeps): TWO chunks side by side
+# along the lanes ([Q, 2Q]: a float32 [Q, Q] fills half of each vreg), the
+# right-hand operand of a product block-diagonal [2Q, 2Q], so that one MXU
+# pass multiplies both chunks at full depth and width and every vector
+# operation works on whole vregs.
+
+
+def _pair_product(lhs, rhs, first):
+    """[A_a B_a | A_b B_b] of lhs = [A_a | A_b] and rhs = [B_a | B_b], float32
+    [Q, 2Q]; `first` [Q, 2Q] marks the first chunk's lanes. With a_1 + a_2 +
+    a_3 the bf16 parts of an operand, the six terms a_i b_j, i + j <= 4."""
+    q = lhs.shape[0]
+    l1, l2, l3 = _bf16_parts(lhs)
+    r1, r2, r3 = _bf16_parts(jnp.concatenate(
+        [jnp.where(first, rhs, 0.0), jnp.where(first, 0.0, rhs)], axis=0
+    ))
+    by_r1 = _mm(jnp.concatenate([l1, l2, l3], axis=0), r1, _NN)
+    by_r2 = _mm(jnp.concatenate([l1, l2], axis=0), r2, _NN)
+    small = (by_r1[2 * q:] + by_r2[q:]) + _mm(l1, r3, _NN)
+    return by_r1[:q] + ((by_r1[q:2 * q] + by_r2[:q]) + small)
+
+
+def _kda_inverse_kernel(owner_ref, eye_ref, n_ref, x_ref, *, pairs: int):
+    """(I + n)^-1 of each of the program's pairs of chunks, up the levels
+    from the narrowest; `owner_ref` and `eye_ref` [Q, 2Q] hold `_prep_tables`'
+    owner and the identity for both chunks of a pair."""
+    q = owner_ref.shape[0]
+    narrowest = q.bit_length() - 2
+    owner = owner_ref[:]
+    first = lax.broadcasted_iota(jnp.int32, (q, 2 * q), 1) < q
+
+    def one_pair(c, _):
+        one = pl.ds(pl.multiple_of(2 * c * q, 2 * q), q)
+        other = pl.ds(pl.multiple_of(2 * c * q, 2 * q) + q, q)
+        n = jnp.concatenate([n_ref[one, :], n_ref[other, :]], axis=1)
+        # the first level's X is the identity, and I L I = L: no product
+        x = eye_ref[:] - jnp.where(owner == narrowest, n, 0.0)
+        for level in range(narrowest - 1, -1, -1):
+            below = jnp.where(owner == level, n, 0.0)
+            x = x - _pair_product(_pair_product(x, below, first), x, first)
+        x_ref[one, :] = x[:, :q]
+        x_ref[other, :] = x[:, q:]
+
+    lax.fori_loop(0, pairs, one_pair, None, unroll=True)
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def _pallas_inverse(n, interpret):
+    """`unit_lower_inverse` of n [.., Q, Q] float32 over an even number of
+    chunks, four pairs a program where they divide."""
+    q = n.shape[-1]
+    chunks = n.size // (q * q)
+    pairs = next(p for p in (4, 2, 1) if chunks % (2 * p) == 0)
+    owner = np.tile(_prep_tables(q)[2], (1, 2))
+    eye = np.tile(np.eye(q, dtype=np.float32), (1, 2))
+    rows = pl.BlockSpec((2 * pairs * q, q), lambda i: (i, 0))
+
+    x = pl.pallas_call(
+        functools.partial(_kda_inverse_kernel, pairs=pairs),
+        grid=(chunks // (2 * pairs),),
+        in_specs=[_table(owner), _table(eye), rows],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((chunks * q, q), n.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="kda_prep_inverse",
+    )(jnp.asarray(owner), jnp.asarray(eye), n.reshape(chunks * q, q))
+    return x.reshape(n.shape)
+
+
+@jax.custom_vjp
+def kernel_inverse(n):
+    """`unit_lower_inverse` as a kernel, with its written backward."""
+    return _pallas_inverse(n, _interpret())
+
+
+kernel_inverse.defvjp(_kept_inverse(kernel_inverse), _unit_lower_inverse_bwd)
+
+
+def kernel_operands(q, k, v, g, beta, chunk: int):
+    """`chunk_operands` on the "kda" route: the decays and the scores from
+    one kernel, the triangular inverse from another, its two products with
+    K exp(G) and V XLA's batched float32 ones."""
+    b, h, s, _ = q.shape
+    c = s // chunk
+    qd, ke, p, gamma, a, kd = chunk_scores(q, k, g, chunk)
+    w, uv = _corrected(
+        a, kd, v.reshape(b, h, c, chunk, v.shape[-1]),
+        beta.reshape(b, h, c, chunk), q.dtype,
+        # the inverse's kernel takes the chunks two by two
+        kernel_inverse if (b * h * c) % 2 == 0 else unit_lower_inverse,
+    )
+    return qd, w, uv, ke, p, gamma
+
+
 def scan_route(key_dim: int, value_dim: int, chunk: int) -> str:
-    """Which form `chunk_scan` takes, from what the trace can observe:
+    """Which form the recurrence takes, the chunks' operands and
+    `chunk_scan` alike, from what the trace can observe:
 
     - "kda": the Pallas kernels, where the backend is a TPU (or the CPU with
       interpret mode opted in, `interpret_default`), a head's key and value
@@ -546,7 +976,9 @@ def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
         )
         beta = jax.nn.sigmoid(heads_first(b_logit, 1).astype(f32))[..., 0]
     with jax.named_scope("prep"):
-        operands = chunk_operands(q, k, v, g, beta, chunk)
+        operands = (kernel_operands if route == "kda" else chunk_operands)(
+            q, k, v, g, beta, chunk
+        )
     with jax.named_scope("scan"):
         o = chunk_scan(route, *operands)
     o = o.reshape(b, h, s + pad, dv)[:, :, :s]
@@ -579,9 +1011,10 @@ def gated_delta_forward(
         f_up = proj[..., cw:cw + rank] @ w_f
         g_up = proj[..., cw + rank:cw + 2 * rank] @ w_g
     route = scan_route(attrs.key_dim, attrs.value_dim, attrs.chunk_size)
-    o = jax.checkpoint(functools.partial(_recurrence, attrs, route))(
-        qkv, f_up, dt_bias, a_log, proj[..., cw + 2 * rank:]
-    )
+    o = jax.checkpoint(
+        functools.partial(_recurrence, attrs, route),
+        policy=jax.checkpoint_policies.save_only_these_names(_KEPT),
+    )(qkv, f_up, dt_bias, a_log, proj[..., cw + 2 * rank:])
     with jax.named_scope("norm"):
         y = jax.checkpoint(
             functools.partial(

@@ -173,6 +173,34 @@ def test_scan_route_is_read_from_shapes_and_backend(monkeypatch):
     assert kda.scan_route(128, 64, 64) == "xla"
     with flash.no_flash():
         assert kda.scan_route(128, 128, 64) == "xla"
+    # ONE route decides the chunks' operands and the chunk-to-chunk pass
+    # alike: "kda" takes both from the kernels, "xla" the operands from
+    # today's `chunk_operands` and the pass from the scan over the chunks
+    took = []
+    xla_operands, scan = kda.chunk_operands, kda.chunk_scan
+
+    def operands_from(name):
+        def spy(*args):
+            took.append(name)
+            return xla_operands(*args)
+        return spy
+
+    def spy_scan(route, *operands):
+        took.append(route)
+        return scan("xla", *operands)
+
+    monkeypatch.setattr(kda, "kernel_operands", operands_from("kernels"))
+    monkeypatch.setattr(kda, "chunk_operands", operands_from("chunk_operands"))
+    monkeypatch.setattr(kda, "chunk_scan", spy_scan)
+    attrs = GatedDeltaAttrs(1, 128, 128, 4, 8, 64, 1e-5)
+    rs = np.random.RandomState(8)
+    shapes = attrs.weight_shapes(TensorShape((1, 64, 16), DataType.FLOAT))
+    ws = [rand(rs, *s.dims, scale=0.3) for s in shapes]
+    u = rand(rs, 1, 64, 16)
+    kda.gated_delta_forward(attrs, u, ws)
+    with flash.no_flash():
+        kda.gated_delta_forward(attrs, u, ws)
+    assert took == ["kernels", "kda", "chunk_operands", "xla"]
 
 
 def test_chunk_kernels_agree_with_the_scan_over_chunks(monkeypatch):
@@ -203,6 +231,73 @@ def test_chunk_kernels_agree_with_the_scan_over_chunks(monkeypatch):
             )(*operands)
 
     assert_trees_close(run("kda"), run("xla"), rtol=1e-5, atol=1e-5)
+
+
+# Two heads of 128 | 128 in chunks of 64. 256 positions and 100 (padded to
+# 128) are four and two chunks a head, all of a head's in ONE program of the
+# scores' kernels; 192 are three, one a program (`_PREP_CHUNKS`), and on ONE
+# head an odd number of chunks, which XLA's `unit_lower_inverse` inverts
+# where the inverse's kernel takes them two by two. A log-decay below -10 a
+# position makes exp(-G) of the textbook form overflow inside a chunk of 64
+# (e^88.7) ten times over
+@pytest.mark.parametrize(
+    "seq,steeper,h",
+    [(256, 0.0, 2), (192, 0.0, 1), (128, 10.0, 2), (100, 0.0, 2)],
+    ids=["plain_decays", "one_chunk_a_program", "decay_overflows_exp_minus_g",
+         "padded_to_the_chunk"],
+)
+def test_operand_kernels_agree_with_the_xla_operands(monkeypatch, seq, steeper, h):
+    """`kernel_operands` (the Pallas kernels in interpret mode: the scores
+    forward and WRITTEN backward, the triangular inverse) against
+    `chunk_operands` (XLA, differentiated by JAX) at lane-sized heads in
+    float32: the six operands and the cotangents of q, k, v, g and beta. The
+    kernels take every exponent as an exact sum of log-decays where XLA
+    subtracts two running sums, and sum a level's cotangents in another
+    order: measured 4e-6 at values of 4 here."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    rs = np.random.RandomState(11)
+    b, d, chunk = 1, 128, 64
+    pad = -seq % chunk
+
+    def unit(t):
+        return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
+
+    def padded(t):
+        # as `_recurrence` pads: a position that writes nothing (beta 0, k
+        # 0) and decays nothing (g 0)
+        return jnp.pad(t, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 3))
+
+    q, k = unit(rand(rs, b, h, seq, d)), unit(rand(rs, b, h, seq, d))
+    v = rand(rs, b, h, seq, d)
+    g = -jnp.abs(rand(rs, b, h, seq, d, scale=0.3)) - steeper
+    beta = jax.nn.sigmoid(rand(rs, b, h, seq))
+    if steeper:
+        assert float(jnp.max(jnp.cumsum(-g, axis=2)[:, :, chunk - 1])) > 88.7
+    inputs = tuple(padded(t) for t in (q, k, v, g, beta))
+    c = (seq + pad) // chunk
+    assert c >= 2
+    cots = [
+        rand(rs, b, h, c, chunk, width) for width in (d, d, d, d, chunk)
+    ] + [rand(rs, b, h, c, 1, d)]
+
+    def run(operands_of):
+        def loss(*inputs):
+            operands = operands_of(*inputs, chunk)
+            return sum(
+                jnp.sum(o * cot) for o, cot in zip(operands, cots)
+            ), operands
+
+        with jax.default_matmul_precision("highest"):
+            (_, operands), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4), has_aux=True
+            )(*inputs)
+        return operands, grads
+
+    got, want = run(kda.kernel_operands), run(kda.chunk_operands)
+    assert all(
+        bool(jnp.all(jnp.isfinite(t))) for t in jax.tree_util.tree_leaves(got)
+    )
+    assert_trees_close(got, want, rtol=1e-4, atol=1e-5)
 
 
 # -- latent attention ------------------------------------------------------------

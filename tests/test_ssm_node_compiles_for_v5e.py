@@ -19,8 +19,11 @@ a buffer (what a fusion keeps inside its body is not):
 The same child also compiles what the `kimi_linear` cell added (PR 43), at its
 published shape, 32 heads of 128 over 4,096 positions: ONE gated delta-rule
 node (`kernels/kda.gated_delta_forward`) forward and backward, whose
-chunk-to-chunk pass must come out as its three Pallas kernels and which must
-hold no `[heads, positions, 128, 128]` state per position; and the causal
+chunk-to-chunk pass must come out as its three Pallas kernels and whose
+chunks' operands as theirs (PR 44: the scores forward, rematerialised and
+backward, the triangular inverse forward only, each under the node's `prep`
+part by the name the profile will carry), and which
+must hold no `[heads, positions, 128, 128]` state per position; and the causal
 flash kernels on a 256-wide padded key beside a 128-wide value
 (`flash_attention_bshf_wide_key`), forward and backward.
 
@@ -172,7 +175,8 @@ def check(name):
 
 
 KIMI_INVARIANTS = [
-    "kda_node_compiles_with_its_three_kernels",
+    "kda_node_compiles_with_its_seven_kernels",
+    "kda_operand_kernels_are_the_nodes_prep_part",
     "kda_holds_no_state_per_position",
     "wide_key_flash_compiles_forward_and_backward",
 ]
@@ -188,6 +192,7 @@ def check_kimi():
 
     from flexflow_tpu.kernels import flash_attention as fa
     from flexflow_tpu.kernels import kda
+    from flexflow_tpu.observability.trace import parse_scope
     from flexflow_tpu.op_attrs.datatype import DataType
     from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
     from flexflow_tpu.op_attrs.tensor_shape import TensorShape
@@ -211,17 +216,45 @@ def check_kimi():
             )
         ]
 
+        def scoped(u, weights):
+            with jax.named_scope("ff.kda.kda2"):
+                return kda.gated_delta_forward(attrs, u, weights)
+
         def node(u, weights, cot):
-            y, vjp = jax.vjp(
-                lambda u, weights: kda.gated_delta_forward(attrs, u, weights),
-                u, weights,
-            )
+            y, vjp = jax.vjp(scoped, u, weights)
             return y, vjp(cot)
 
         text = jax.jit(node).lower(u, weights, u).compile().as_text()
-        kernels = text.count("tpu_custom_call")
-        found["kda_node_compiles_with_its_three_kernels"] = (
-            "ok" if kernels == 3 else f"{kernels} kernels, want 3"
+        # a kernel's name as the profile has it: the chunk-to-chunk pass
+        # forward, the states and the backward; the scores' kernel forward
+        # and rematerialised, and its backward; the triangular inverse's
+        # kernel forward only, because the node's checkpoint keeps the inverse
+        # (the pass's rematerialised forward is dead code: its backward reads
+        # the operands alone)
+        calls = sorted(
+            parse_scope(op_name) + (op_name.split("/")[-2],)
+            for op_name in re.findall(
+                r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text
+            )
+        )
+        want = sorted(
+            [("fwd", "kda", "kda2/scan", "kda_fwd_chunk"),
+             ("bwd", "kda", "kda2/scan", "kda_states_chunk"),
+             ("bwd", "kda", "kda2/scan", "kda_bwd_chunk"),
+             ("fwd", "kda", "kda2/prep", "kda_prep_fwd"),
+             ("fwd", "kda", "kda2/prep", "kda_prep_inverse"),
+             ("bwd", "kda", "kda2/prep", "kda_prep_fwd"),
+             ("bwd", "kda", "kda2/prep", "kda_prep_bwd")]
+        )
+        kernels = sorted(c[3] for c in calls)
+        found["kda_node_compiles_with_its_seven_kernels"] = (
+            "ok" if kernels == sorted(w[3] for w in want) else f"{kernels}"
+        )
+        # `kda_ms` and `kda_scan_roofline` read the kernels by these names: one
+        # that fell out of the part's scope would leave the roofline reading
+        # the `scan` rows alone
+        found["kda_operand_kernels_are_the_nodes_prep_part"] = (
+            "ok" if calls == want else f"{calls}"
         )
         states = [
             f"{dtype}{list(dims)}" for dtype, dims in shapes_of(text)
@@ -232,8 +265,8 @@ def check_kimi():
         )
     except Exception as e:  # noqa: BLE001 - the complaint is the result
         complaint = f"{type(e).__name__}: {e}"[:2000]
-        found.setdefault("kda_node_compiles_with_its_three_kernels", complaint)
-        found.setdefault("kda_holds_no_state_per_position", complaint)
+        for invariant in KIMI_INVARIANTS[:3]:
+            found.setdefault(invariant, complaint)
     try:
         q = on_chip((1, ROWS, heads * 256))
         v = on_chip((1, ROWS, heads * 128))
