@@ -48,11 +48,21 @@ decides the chunks' operands and the chunk-to-chunk pass alike:
   *The operands* (`kernel_operands`): `kda_prep_fwd` computes every decay and
   the decayed scores of eight chunks a program in VMEM, so that nothing a
   level computes reaches HBM, and `kda_prep_bwd` is its WRITTEN backward
-  (`chunk_scores`, one `jax.custom_vjp` that keeps q, k and g and recomputes
-  the decays). Every exponent is the product of a constant 0/1 matrix with g
-  (the set of positions whose log-decays it sums), so it is <= 0 by
-  construction, float32-exact in three bf16 passes, and its transpose is the
-  way back to g. `kda_prep_inverse` is `unit_lower_inverse`'s levels with two
+  (`chunk_scores`, one `jax.custom_vjp`). Both take the node's inputs RAW
+  and in the MODEL's layout: a head of 128 key channels is one 128-lane
+  column block of the convolved q | k | v [b, s, .] and of the decay's
+  pre-activation, read where the convolution and the projection left it, so
+  no heads-first copy of q, k or the pre-activation exists; the two
+  normalisations (rounded as `_unit` rounds them) and
+  g = -exp(a_log) softplus(f_up + dt_bias) happen in VMEM per chunk
+  (`_raw_gates`), g never reaches HBM, and the backward recomputes them from
+  the same raw inputs (what the node's checkpoint holds anyway), carries its
+  cotangents through the norm and the softplus, writes those of q, k and
+  the pre-activation in the model's layout and a program's part of
+  `dt_bias`'s and `a_log`'s. Every exponent is the product of a constant 0/1
+  matrix with g (the set of positions whose log-decays it sums), so it is
+  <= 0 by construction, float32-exact in three bf16 passes, and its transpose
+  is the way back to g. `kda_prep_inverse` is `unit_lower_inverse`'s levels with two
   chunks side by side along the lanes; the inverse's two products with
   K exp(G) and V, and its backward, stay XLA's batched float32 products.
   *The pass* (`kda_fwd_chunk`, `kda_states_chunk`, `kda_bwd_chunk`): one
@@ -79,7 +89,11 @@ neither form does a state per position ever exist.
 
 The node's parts go under scopes of their own inside the node's
 (`ff.kda.<name>/scan`, `/prep`, `/gates`, `/conv`, `/norm`;
-`observability/trace.NODE_PARTS`).
+`observability/trace.NODE_PARTS`). On the "kda" route `gates` holds what the
+scores' kernels do not take: the two rank-128 gate matmuls (`f_up`, `g_up`),
+beta's sigmoid, v's heads-first copy (and dv's back) and the small
+reductions; on the "xla" route also the norms of q and k, the softplus and
+the heads-first copies of q, k and the pre-activation.
 """
 
 from __future__ import annotations
@@ -596,13 +610,29 @@ def _chunk_decays(sums_ref, g, q: int):
     return lambda piece: decays[piece * q:(piece + 1) * q, :]
 
 
+def _raw_gates(q_ref, k_ref, f_ref, bias_ref, rate_ref, rows, scale: float):
+    """What the "xla" route's `gates` computes, for one chunk's rows of one
+    head read where the model left them: q and k [Q, dk] over their own
+    2-norm (`_unit_root`: float32, ROUNDED to the input's dtype; q times
+    `scale`) and the float32 log-decays g = rate * softplus(f_up + dt_bias);
+    then what the backward takes besides: the two inverse roots [Q, 1] and
+    the log of the softplus's slope."""
+    qn, q_root = _unit_root(q_ref[rows, :], scale)
+    kn, k_root = _unit_root(k_ref[rows, :], 1.0)
+    pre = f_ref[rows, :].astype(jnp.float32) + bias_ref[:]
+    soft = jax.nn.softplus(pre)
+    # the softplus's slope as JAX's own rule takes it: exp(x - softplus(x))
+    return qn, kn, rate_ref[:] * soft, (q_root, k_root, pre - soft)
+
+
 def _kda_prep_fwd_kernel(
-    sums_ref, owner_ref, q_ref, k_ref, g_ref,
-    qd_ref, ke_ref, p_ref, gam_ref, a_ref, kd_ref, *, chunks: int,
+    sums_ref, owner_ref, q_ref, k_ref, f_ref, bias_ref, rate_ref,
+    qd_ref, ke_ref, p_ref, gam_ref, a_ref, kd_ref, *, chunks: int, scale: float,
 ):
     """Q exp(G), K exp(G_Q - G), P, exp(G_Q), the strictly lower key-against-
     key scores A and K exp(G) (float32, for the triangular system) of each of
-    the program's chunks."""
+    the program's chunks, from the RAW q, k and decay pre-activation of the
+    head (`_raw_gates`)."""
     f32 = jnp.float32
     dtype = q_ref.dtype
     q = owner_ref.shape[0]
@@ -611,17 +641,18 @@ def _kda_prep_fwd_kernel(
 
     def one_chunk(c, _):
         rows = pl.ds(pl.multiple_of(c * q, q), q)
-        qf, kf = q_ref[rows, :].astype(f32), k_ref[rows, :].astype(f32)
-        decay = _chunk_decays(sums_ref, g_ref[rows, :], q)
+        qn, kn, g, _ = _raw_gates(
+            q_ref, k_ref, f_ref, bias_ref, rate_ref, rows, scale
+        )
+        qf, kf = qn.astype(f32), kn.astype(f32)
+        decay = _chunk_decays(sums_ref, g, q)
         from_start = decay(_FROM_START)
         qd_ref[rows, :] = (qf * from_start).astype(dtype)
         kd_ref[rows, :] = kf * from_start
         ke_ref[rows, :] = (kf * decay(_TO_END)).astype(dtype)
         gam_ref[c] = from_start[q - 1:q, :]
         # a position reads its own key undecayed
-        p = jnp.where(
-            owner == levels, _mm(q_ref[rows, :], k_ref[rows, :], _NT), 0.0
-        )
+        p = jnp.where(owner == levels, _mm(qn, kn, _NT), 0.0)
         a = jnp.zeros((q, q), f32)
         for level in range(levels):
             e = decay(_FIRST_LEVEL + level)
@@ -635,28 +666,45 @@ def _kda_prep_fwd_kernel(
     lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
 
 
+def _unit_cotangent(x, root, dy, scale: float):
+    """The cotangent of x [Q, d] from dy, that of `_unit(x, scale)` (straight
+    through its rounding, as `astype` is): with r = `root` the inverse root,
+    scale r (dy - r^2 x (x . dy)), float32."""
+    xf = x.astype(jnp.float32)
+    along = jnp.sum(xf * dy, axis=-1, keepdims=True)
+    return (scale * root) * (dy - (root * root * along) * xf)
+
+
 def _kda_prep_bwd_kernel(
-    sums_ref, sums_t_ref, owner_ref, q_ref, k_ref, g_ref,
+    sums_ref, sums_t_ref, owner_ref, q_ref, k_ref, f_ref, bias_ref, rate_ref,
     dqd_ref, dke_ref, dp_ref, dgam_ref, da_ref, dkd_ref,
-    dq_ref, dk_ref, dg_ref, dex_ref, *, chunks: int,
+    dq_ref, dk_ref, df_ref, dbias_ref, dalog_ref, dex_ref,
+    *, chunks: int, scale: float,
 ):
-    """The cotangents of q, k and g from those of `_kda_prep_fwd_kernel`'s six
-    results, every decay recomputed. A level's pairs are (q e) (k e)^T and
-    (k e) (k e)^T under its mask, so its backward is the masked cotangents
-    against k e (the rows' factor) and their transposes against q e | k e
-    (the columns'). What reaches a piece's exponents is kept in bf16 parts in
-    `dex_ref` [pieces * 3 q, dk] and goes back to g through the transposed
-    sums in one product at the chunk's end."""
+    """The cotangents of the RAW q, k and decay pre-activation (in the model's
+    layout, as they were read) from those of `_kda_prep_fwd_kernel`'s six
+    results, the gates and every decay recomputed. A level's pairs are
+    (q e) (k e)^T and (k e) (k e)^T under its mask, so its backward is the
+    masked cotangents against k e (the rows' factor) and their transposes
+    against q e | k e (the columns'). What reaches a piece's exponents is kept
+    in bf16 parts in `dex_ref` [pieces * 3 q, dk] and goes back to g through
+    the transposed sums in one product at the chunk's end; g's cotangent
+    never leaves VMEM: through the softplus it is the pre-activation's
+    (`df_ref`), and the program's sums of that and of dg g are its part of
+    `dt_bias`'s and `a_log`'s ([1, dk] each, summed by the caller)."""
     f32 = jnp.float32
     dtype = q_ref.dtype
     q = owner_ref.shape[0]
     levels = sums_ref.shape[0] // q - _FIRST_LEVEL
     owner = owner_ref[:]
 
-    def one_chunk(c, _):
+    def one_chunk(c, sums):
         rows = pl.ds(pl.multiple_of(c * q, q), q)
-        qf, kf = q_ref[rows, :].astype(f32), k_ref[rows, :].astype(f32)
-        decay = _chunk_decays(sums_ref, g_ref[rows, :], q)
+        qn, kn, g, (q_root, k_root, log_slope) = _raw_gates(
+            q_ref, k_ref, f_ref, bias_ref, rate_ref, rows, scale
+        )
+        qf, kf = qn.astype(f32), kn.astype(f32)
+        decay = _chunk_decays(sums_ref, g, q)
 
         def to_g(piece, d_exponent):
             dex_ref[pl.ds(piece * _PARTS * q, _PARTS * q), :] = _stacked_parts(
@@ -672,8 +720,8 @@ def _kda_prep_bwd_kernel(
         to_g(_TO_END, dke * kf * to_end)
         dp, da = dp_ref[rows, :].astype(f32), da_ref[rows, :]
         own = jnp.where(owner == levels, dp, 0.0).astype(dtype)
-        dq = dq + _mm(own, k_ref[rows, :], _NN)
-        dk = dk + _mm(own, q_ref[rows, :], _NN)
+        dq = dq + _mm(own, kn, _NN)
+        dk = dk + _mm(own, qn, _NN)
         for level in range(levels):
             e = decay(_FIRST_LEVEL + level)
             qe, ke = qf * e, kf * e
@@ -689,16 +737,30 @@ def _kda_prep_bwd_kernel(
             dq = dq + dqz * e
             dk = dk + dkz * e
             to_g(_FIRST_LEVEL + level, dqz * qe + dkz * ke)
-        dq_ref[rows, :] = dq.astype(dq_ref.dtype)
-        dk_ref[rows, :] = dk.astype(dk_ref.dtype)
+        dq_ref[rows, :] = _unit_cotangent(
+            q_ref[rows, :], q_root, dq, scale
+        ).astype(dq_ref.dtype)
+        dk_ref[rows, :] = _unit_cotangent(
+            k_ref[rows, :], k_root, dk, 1.0
+        ).astype(dk_ref.dtype)
         # exp(G_Q) is the decay from the start at the last position, and G_Q
         # sums EVERY position's g
-        dg_ref[rows, :] = (
+        dg = (
             _mm(sums_t_ref[:], dex_ref[:], _NN)
             + dgam_ref[c] * from_start[q - 1:q, :]
         )
+        dpre = dg * rate_ref[:] * jnp.exp(log_slope)
+        df_ref[rows, :] = dpre.astype(df_ref.dtype)
+        dbias, dalog = sums
+        return (
+            dbias + jnp.sum(dpre, axis=0, keepdims=True),
+            dalog + jnp.sum(dg * g, axis=0, keepdims=True),
+        )
 
-    lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
+    zero = jnp.zeros(bias_ref.shape, f32)
+    dbias_ref[:], dalog_ref[:] = lax.fori_loop(
+        0, chunks, one_chunk, (zero, zero), unroll=True
+    )
 
 
 _PARALLEL_CHUNKS = pltpu.CompilerParams(
@@ -716,8 +778,12 @@ def _table(t):
 
 
 class _PrepBlocks:
-    """The BlockSpecs over the grid (batch, head, group of n chunks) on
-    [b, h, s, .] operands."""
+    """The BlockSpecs over the grid (batch, head, group of n chunks). The
+    inputs lie as the model has them, [b, s, heads * dk]: a head of dk = 128
+    key channels is ONE 128-lane column block (`head`; k's lie `heads`
+    blocks after q's), so no heads-first copy of q, k or the decay's
+    pre-activation exists; a row of the per-channel vectors [1, heads * dk]
+    is cut the same way. The results are [b, h, s, .]."""
 
     def __init__(self, b: int, h: int, s: int, dk: int, q: int):
         c = s // q
@@ -729,19 +795,44 @@ class _PrepBlocks:
                 (None, None, n * q, width), lambda bi, hi, gi: (bi, hi, gi, 0)
             )
 
-        self.key, self.scores = rows(dk), rows(q)
+        def head(first):
+            return pl.BlockSpec(
+                (None, n * q, dk), lambda bi, hi, gi: (bi, gi, first + hi)
+            )
+
+        self.key, self.scores, self.head = rows(dk), rows(q), head(0)
         self.gamma = pl.BlockSpec(
             (None, None, n, 1, dk), lambda bi, hi, gi: (bi, hi, gi, 0, 0)
         )
+        channels = pl.BlockSpec((1, dk), lambda bi, hi, gi: (0, hi))
+        # of `_raw_inputs`: q and k out of q | k | v, f_up, dt_bias, the rate
+        self.raw = [head(0), head(h), head(0), channels, channels]
+        # a program's part of a per-channel sum, [b, groups, 1, heads * dk]
+        self.partial = pl.BlockSpec(
+            (None, None, 1, dk), lambda bi, hi, gi: (bi, gi, 0, hi)
+        )
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def _prep_forward(q, k, g, chunk, interpret):
-    """The kernel on q, k [b, h, s, dk] and g [b, h, s, dk] float32; by chunk
-    out: qd, ke, p, gamma as `chunk_operands` has them, then A [., Q, Q] and
-    K exp(G) [., Q, dk], float32."""
+def _raw_inputs(qkv, f_up, dt_bias, a_log):
+    """What the two kernels read of the node's inputs (`_PrepBlocks.raw`):
+    qkv twice (q's and k's column blocks), f_up, and dt_bias and
+    -exp(a_log) a key channel as float32 rows [1, heads * dk]."""
     f32 = jnp.float32
-    b, h, s, dk = q.shape
+    rate = jnp.repeat(-jnp.exp(a_log.astype(f32)), dt_bias.size // a_log.size)
+    return qkv, qkv, f_up, dt_bias.astype(f32)[None, :], rate[None, :]
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _prep_forward(qkv, f_up, dt_bias, a_log, chunk, interpret):
+    """The kernel on the convolved q | k | v [b, s, 2 * h * dk + h * dv] (its
+    q and k columns are read), the decay's pre-activation f_up [b, s, h * dk],
+    dt_bias [h * dk] and a_log [h]; by chunk out ([b, h, c, Q, .]): qd, ke,
+    p, gamma as `chunk_operands` has them, then A [., Q, Q] and K exp(G)
+    [., Q, dk], float32."""
+    f32 = jnp.float32
+    b, s, width = f_up.shape
+    h = a_log.shape[0]
+    dk = width // h
     c = s // chunk
     at = _PrepBlocks(b, h, s, dk, chunk)
     sums, _, owner = _prep_tables(chunk)
@@ -750,19 +841,22 @@ def _prep_forward(q, k, g, chunk, interpret):
         return jax.ShapeDtypeStruct((b, h, s, width), dtype)
 
     qd, ke, p, gamma, a, kd = pl.pallas_call(
-        functools.partial(_kda_prep_fwd_kernel, chunks=at.chunks),
+        functools.partial(
+            _kda_prep_fwd_kernel, chunks=at.chunks, scale=dk ** -0.5
+        ),
         grid=at.grid,
-        in_specs=[_table(sums), _table(owner), at.key, at.key, at.key],
+        in_specs=[_table(sums), _table(owner), *at.raw],
         out_specs=[at.key, at.key, at.scores, at.gamma, at.scores, at.key],
         out_shape=[
-            rows(dk, q.dtype), rows(dk, q.dtype), rows(chunk, q.dtype),
+            rows(dk, qkv.dtype), rows(dk, qkv.dtype), rows(chunk, qkv.dtype),
             jax.ShapeDtypeStruct((b, h, c, 1, dk), f32),
             rows(chunk, f32), rows(dk, f32),
         ],
         compiler_params=_PARALLEL_CHUNKS,
         interpret=interpret,
         name="kda_prep_fwd",
-    )(jnp.asarray(sums), jnp.asarray(owner), q, k, g)
+    )(jnp.asarray(sums), jnp.asarray(owner),
+      *_raw_inputs(qkv, f_up, dt_bias, a_log))
 
     def by_chunk(t):
         return t.reshape(b, h, c, chunk, t.shape[-1])
@@ -770,48 +864,67 @@ def _prep_forward(q, k, g, chunk, interpret):
     return by_chunk(qd), by_chunk(ke), by_chunk(p), gamma, by_chunk(a), by_chunk(kd)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _prep_backward(q, k, g, cotangents, chunk, interpret):
-    b, h, s, dk = q.shape
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _prep_backward(qkv, f_up, dt_bias, a_log, cotangents, chunk, interpret):
+    """The cotangents of `_prep_forward`'s four inputs; v's columns of qkv's
+    are zero."""
+    f32 = jnp.float32
+    b, s, width = f_up.shape
+    h = a_log.shape[0]
+    dk = width // h
     at = _PrepBlocks(b, h, s, dk, chunk)
     sums, sums_t, owner = _prep_tables(chunk)
     dqd, dke, dp, dgam, da, dkd = cotangents
-    return pl.pallas_call(
-        functools.partial(_kda_prep_bwd_kernel, chunks=at.chunks),
+    partial = jax.ShapeDtypeStruct((b, at.grid[2], 1, width), f32)
+    dq, dkey, df, dbias, dalog = pl.pallas_call(
+        functools.partial(
+            _kda_prep_bwd_kernel, chunks=at.chunks, scale=dk ** -0.5
+        ),
         grid=at.grid,
         in_specs=[
-            _table(sums), _table(sums_t), _table(owner),
-            at.key, at.key, at.key,
+            _table(sums), _table(sums_t), _table(owner), *at.raw,
             at.key, at.key, at.scores, at.gamma, at.scores, at.key,
         ],
-        out_specs=[at.key, at.key, at.key],
+        out_specs=[at.head, at.head, at.head, at.partial, at.partial],
         out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(g.shape, g.dtype),
+            jax.ShapeDtypeStruct(f_up.shape, qkv.dtype),
+            jax.ShapeDtypeStruct(f_up.shape, qkv.dtype),
+            jax.ShapeDtypeStruct(f_up.shape, f_up.dtype),
+            partial, partial,
         ],
         scratch_shapes=[pltpu.VMEM((sums_t.shape[1], dk), jnp.bfloat16)],
         compiler_params=_PARALLEL_CHUNKS,
         interpret=interpret,
         name="kda_prep_bwd",
-    )(jnp.asarray(sums), jnp.asarray(sums_t), jnp.asarray(owner), q, k, g,
+    )(jnp.asarray(sums), jnp.asarray(sums_t), jnp.asarray(owner),
+      *_raw_inputs(qkv, f_up, dt_bias, a_log),
       _rows(dqd), _rows(dke), _rows(dp), dgam, _rows(da), _rows(dkd))
+    dv = jnp.zeros((b, s, qkv.shape[-1] - 2 * width), qkv.dtype)
+    return (
+        jnp.concatenate([dq, dkey, dv], axis=-1), df,
+        jnp.sum(dbias, axis=(0, 1, 2)).astype(dt_bias.dtype),
+        jnp.sum(dalog.reshape(-1, h, dk), axis=(0, 2)).astype(a_log.dtype),
+    )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def chunk_scores(q, k, g, chunk: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def chunk_scores(qkv, f_up, dt_bias, a_log, chunk: int):
     """What the chunks' operands take from q, k and the log-decays alone, as
-    Pallas kernels with a WRITTEN backward (`_prep_forward`'s results). What
-    the backward keeps is q, k and g: it recomputes every decay."""
-    return _prep_forward(q, k, g, chunk, _interpret())
+    Pallas kernels with a WRITTEN backward (`_prep_forward`'s inputs and
+    results): the kernels read the node's RAW inputs in the model's layout
+    and normalise q and k and take the softplus in VMEM. What the backward
+    keeps is those inputs: it recomputes the gates and every decay."""
+    return _prep_forward(qkv, f_up, dt_bias, a_log, chunk, _interpret())
 
 
-def _chunk_scores_fwd(q, k, g, chunk):
-    return chunk_scores(q, k, g, chunk), (q, k, g)
+def _chunk_scores_fwd(qkv, f_up, dt_bias, a_log, chunk):
+    return chunk_scores(qkv, f_up, dt_bias, a_log, chunk), (
+        qkv, f_up, dt_bias, a_log
+    )
 
 
 def _chunk_scores_bwd(chunk, kept, cotangents):
-    return tuple(_prep_backward(*kept, cotangents, chunk, _interpret()))
+    return _prep_backward(*kept, cotangents, chunk, _interpret())
 
 
 chunk_scores.defvjp(_chunk_scores_fwd, _chunk_scores_bwd)
@@ -897,16 +1010,32 @@ def kernel_inverse(n):
 kernel_inverse.defvjp(_kept_inverse(kernel_inverse), _unit_lower_inverse_bwd)
 
 
-def kernel_operands(q, k, v, g, beta, chunk: int):
-    """`chunk_operands` on the "kda" route: the decays and the scores from
-    one kernel, the triangular inverse from another, its two products with
-    K exp(G) and V XLA's batched float32 ones."""
-    b, h, s, _ = q.shape
+# what a padded position's decay pre-activation reads: its softplus, and so
+# the position's log-decay, is exactly 0 (exp(-1e30) is) and so is its sigmoid
+_NO_DECAY = -1e30
+
+
+def kernel_operands(qkv, f_up, dt_bias, a_log, v, beta, chunk: int):
+    """`chunk_operands` on the "kda" route, from the node's RAW q, k
+    (qkv [b, s, 2 * h * dk + h * dv], the convolution's result as it lies)
+    and decay pre-activation f_up [b, s, h * dk] beside v [b, h, s', dv] and
+    beta [b, h, s'] as `chunk_operands` takes them, s' = s padded to the
+    chunk: the gates, the decays and the scores from one kernel, the
+    triangular inverse from another, its two products with K exp(G) and V
+    XLA's batched float32 ones."""
+    b, h, s, dv = v.shape
     c = s // chunk
-    qd, ke, p, gamma, a, kd = chunk_scores(q, k, g, chunk)
+    pad = s - f_up.shape[1]
+    if pad:
+        # a padded position has k = 0 (raw zeros stay zeros) and g = 0
+        qkv = jnp.pad(qkv, ((0, 0), (0, pad), (0, 0)))
+        f_up = jnp.pad(
+            f_up, ((0, 0), (0, pad), (0, 0)), constant_values=_NO_DECAY
+        )
+    qd, ke, p, gamma, a, kd = chunk_scores(qkv, f_up, dt_bias, a_log, chunk)
     w, uv = _corrected(
-        a, kd, v.reshape(b, h, c, chunk, v.shape[-1]),
-        beta.reshape(b, h, c, chunk), q.dtype,
+        a, kd, v.reshape(b, h, c, chunk, dv), beta.reshape(b, h, c, chunk),
+        qkv.dtype,
         # the inverse's kernel takes the chunks two by two
         kernel_inverse if (b * h * c) % 2 == 0 else unit_lower_inverse,
     )
@@ -940,11 +1069,17 @@ def scan_route(key_dim: int, value_dim: int, chunk: int) -> str:
 # ---------------------------------------------------------------------------
 
 
-def _unit(t, scale: float):
-    """t [.., d] over its own 2-norm, times `scale`, in float32 and back."""
+def _unit_root(t, scale: float):
+    """(t [.., d] over its own 2-norm, times `scale`, in float32 and back;
+    the inverse root [.., 1] float32)."""
     tf = t.astype(jnp.float32)
     root = lax.rsqrt(jnp.sum(tf * tf, axis=-1, keepdims=True) + L2_EPS)
-    return (tf * (root * scale)).astype(t.dtype)
+    return (tf * (root * scale)).astype(t.dtype), root
+
+
+def _unit(t, scale: float):
+    """t [.., d] over its own 2-norm, times `scale`, in float32 and back."""
+    return _unit_root(t, scale)[0]
 
 
 def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
@@ -966,19 +1101,26 @@ def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
         return jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else t
 
     with jax.named_scope("gates"):
-        q = _unit(heads_first(qkv[..., :kw], dk), dk ** -0.5)
-        k = _unit(heads_first(qkv[..., kw:2 * kw], dk), 1.0)
         v = heads_first(qkv[..., 2 * kw:], dv)
-        rate = -jnp.exp(a_log.astype(f32))[:, None, None]
-        g = rate * jax.nn.softplus(
-            heads_first(f_up, dk).astype(f32)
-            + dt_bias.astype(f32).reshape(h, 1, dk)
-        )
         beta = jax.nn.sigmoid(heads_first(b_logit, 1).astype(f32))[..., 0]
-    with jax.named_scope("prep"):
-        operands = (kernel_operands if route == "kda" else chunk_operands)(
-            q, k, v, g, beta, chunk
-        )
+    if route == "kda":
+        # the scores' kernels read q, k and f_up where they lie and do the
+        # rest of the gates in VMEM
+        with jax.named_scope("prep"):
+            operands = kernel_operands(
+                qkv, f_up, dt_bias, a_log, v, beta, chunk
+            )
+    else:
+        with jax.named_scope("gates"):
+            q = _unit(heads_first(qkv[..., :kw], dk), dk ** -0.5)
+            k = _unit(heads_first(qkv[..., kw:2 * kw], dk), 1.0)
+            rate = -jnp.exp(a_log.astype(f32))[:, None, None]
+            g = rate * jax.nn.softplus(
+                heads_first(f_up, dk).astype(f32)
+                + dt_bias.astype(f32).reshape(h, 1, dk)
+            )
+        with jax.named_scope("prep"):
+            operands = chunk_operands(q, k, v, g, beta, chunk)
     with jax.named_scope("scan"):
         o = chunk_scan(route, *operands)
     o = o.reshape(b, h, s + pad, dv)[:, :, :s]

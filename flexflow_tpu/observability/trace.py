@@ -377,7 +377,13 @@ NODE_PARTS = {
     "experts": ("router", "latent", "routed", "shared"),
     # the gated delta-rule node (`kernels/kda.py`): the chunk-to-chunk pass,
     # the chunks' operands (decayed scores, the triangular inverse), the
-    # normalisation and the two gates, the convolution, the gated norm
+    # gates, the convolution, the gated norm. On the "kda" route the scores'
+    # kernels read q, k and the decay's pre-activation in the model's layout
+    # and normalise and take the softplus in VMEM (PR 45), so that is `prep`
+    # there, and `gates` holds the two rank-128 gate matmuls, beta's sigmoid,
+    # v's heads-first copy (dv's back) and the small reductions; on the "xla"
+    # route `gates` also holds the two norms, the softplus and the
+    # heads-first copies of q, k and the pre-activation
     "kda": ("scan", "prep", "gates", "conv", "norm"),
     # latent attention (`kernels/ops._latent_mha_forward`): the low-rank
     # key/value projections with their norm, and the attention core

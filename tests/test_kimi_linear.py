@@ -177,19 +177,23 @@ def test_scan_route_is_read_from_shapes_and_backend(monkeypatch):
     # alike: "kda" takes both from the kernels, "xla" the operands from
     # today's `chunk_operands` and the pass from the scan over the chunks
     took = []
-    xla_operands, scan = kda.chunk_operands, kda.chunk_scan
+    scan = kda.chunk_scan
 
     def operands_from(name):
+        operands = getattr(kda, name)
+
         def spy(*args):
             took.append(name)
-            return xla_operands(*args)
+            return operands(*args)
         return spy
 
     def spy_scan(route, *operands):
         took.append(route)
         return scan("xla", *operands)
 
-    monkeypatch.setattr(kda, "kernel_operands", operands_from("kernels"))
+    # the scores' kernels take the node's raw inputs: run them (interpreted)
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(kda, "kernel_operands", operands_from("kernel_operands"))
     monkeypatch.setattr(kda, "chunk_operands", operands_from("chunk_operands"))
     monkeypatch.setattr(kda, "chunk_scan", spy_scan)
     attrs = GatedDeltaAttrs(1, 128, 128, 4, 8, 64, 1e-5)
@@ -200,7 +204,7 @@ def test_scan_route_is_read_from_shapes_and_backend(monkeypatch):
     kda.gated_delta_forward(attrs, u, ws)
     with flash.no_flash():
         kda.gated_delta_forward(attrs, u, ws)
-    assert took == ["kernels", "kda", "chunk_operands", "xla"]
+    assert took == ["kernel_operands", "kda", "chunk_operands", "xla"]
 
 
 def test_chunk_kernels_agree_with_the_scan_over_chunks(monkeypatch):
@@ -237,52 +241,82 @@ def test_chunk_kernels_agree_with_the_scan_over_chunks(monkeypatch):
 # 128) are four and two chunks a head, all of a head's in ONE program of the
 # scores' kernels; 192 are three, one a program (`_PREP_CHUNKS`), and on ONE
 # head an odd number of chunks, which XLA's `unit_lower_inverse` inverts
-# where the inverse's kernel takes them two by two. A log-decay below -10 a
-# position makes exp(-G) of the textbook form overflow inside a chunk of 64
-# (e^88.7) ten times over
+# where the inverse's kernel takes them two by two. `dt_bias` 30 lower than
+# drawn with `a_log` + 6 puts every log-decay below -10 a position, so that
+# exp(-G) of the textbook form overflows inside a chunk of 64 (e^88.7) ten
+# times over; `far_gates` draws `dt_bias` around -2 and `a_log` around 1.5, so
+# that the softplus is on its bend and both vectors' gradients are sums of
+# many terms of one sign and no rounding noise
 @pytest.mark.parametrize(
-    "seq,steeper,h",
-    [(256, 0.0, 2), (192, 0.0, 1), (128, 10.0, 2), (100, 0.0, 2)],
+    "seq,dt_shift,a_shift,h",
+    [(256, 0.0, 0.0, 2), (192, 0.0, 0.0, 1), (128, 30.0, 0.0, 2),
+     (100, 0.0, 0.0, 2), (128, -2.0, 1.5, 2)],
     ids=["plain_decays", "one_chunk_a_program", "decay_overflows_exp_minus_g",
-         "padded_to_the_chunk"],
+         "padded_to_the_chunk", "far_gates"],
 )
-def test_operand_kernels_agree_with_the_xla_operands(monkeypatch, seq, steeper, h):
+def test_operand_kernels_agree_with_the_xla_operands(
+    monkeypatch, seq, dt_shift, a_shift, h
+):
     """`kernel_operands` (the Pallas kernels in interpret mode: the scores
-    forward and WRITTEN backward, the triangular inverse) against
-    `chunk_operands` (XLA, differentiated by JAX) at lane-sized heads in
-    float32: the six operands and the cotangents of q, k, v, g and beta. The
-    kernels take every exponent as an exact sum of log-decays where XLA
-    subtracts two running sums, and sum a level's cotangents in another
-    order: measured 4e-6 at values of 4 here."""
+    forward and WRITTEN backward, which read the RAW q | k | v and decay
+    pre-activation in the model's layout and do the gates in VMEM; the
+    triangular inverse) against the XLA form (`heads_first` + `_unit` +
+    softplus + `chunk_operands`, differentiated by JAX) at lane-sized heads
+    in float32: the six operands and the cotangents of qkv, f_up, dt_bias,
+    a_log, v and beta. The kernels take every exponent as an exact sum of
+    log-decays where XLA subtracts two running sums, and sum a level's
+    cotangents in another order: measured 4e-6 at values of 4 here; the
+    two vectors' gradients are sums over every position."""
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     rs = np.random.RandomState(11)
     b, d, chunk = 1, 128, 64
     pad = -seq % chunk
 
-    def unit(t):
-        return t / jnp.linalg.norm(t, axis=-1, keepdims=True)
-
-    def padded(t):
+    def heads_first(t):
         # as `_recurrence` pads: a position that writes nothing (beta 0, k
         # 0) and decays nothing (g 0)
-        return jnp.pad(t, ((0, 0), (0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 3))
+        t = jnp.swapaxes(t.reshape(b, seq, h, -1), 1, 2)
+        return jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
 
-    q, k = unit(rand(rs, b, h, seq, d)), unit(rand(rs, b, h, seq, d))
-    v = rand(rs, b, h, seq, d)
-    g = -jnp.abs(rand(rs, b, h, seq, d, scale=0.3)) - steeper
-    beta = jax.nn.sigmoid(rand(rs, b, h, seq))
-    if steeper:
-        assert float(jnp.max(jnp.cumsum(-g, axis=2)[:, :, chunk - 1])) > 88.7
-    inputs = tuple(padded(t) for t in (q, k, v, g, beta))
+    qkv = rand(rs, b, seq, 3 * h * d)
+    f_up = rand(rs, b, seq, h * d)
+    dt_bias = rand(rs, h * d, scale=0.3) - 1.0 + dt_shift
+    a_log = rand(rs, h, scale=0.3) - 1.0 + a_shift
+    b_logit = rand(rs, b, seq, h)
+    slowest = -jnp.exp(jnp.min(a_log)) * jax.nn.softplus(f_up + dt_bias)
+    if dt_shift > 0:
+        assert float(jnp.min(jnp.cumsum(-slowest, axis=1)[:, chunk - 1])) > 88.7
+    inputs = (qkv, f_up, dt_bias, a_log, b_logit)
     c = (seq + pad) // chunk
     assert c >= 2
     cots = [
         rand(rs, b, h, c, chunk, width) for width in (d, d, d, d, chunk)
     ] + [rand(rs, b, h, c, 1, d)]
 
+    def v_beta(qkv, b_logit):
+        v = heads_first(qkv[..., 2 * h * d:])
+        return v, jax.nn.sigmoid(heads_first(b_logit))[..., 0]
+
+    def kernels(qkv, f_up, dt_bias, a_log, b_logit):
+        return kda.kernel_operands(
+            qkv, f_up, dt_bias, a_log, *v_beta(qkv, b_logit), chunk
+        )
+
+    def xla(qkv, f_up, dt_bias, a_log, b_logit):
+        q = kda._unit(heads_first(qkv[..., :h * d]), d ** -0.5)
+        k = kda._unit(heads_first(qkv[..., h * d:2 * h * d]), 1.0)
+        g = -jnp.exp(a_log)[:, None, None] * jax.nn.softplus(
+            heads_first(f_up) + dt_bias.reshape(h, 1, d)
+        )
+        # a padded position's g is rate * softplus(dt_bias) here; the op
+        # pads AFTER the softplus
+        g = g * (jnp.arange(seq + pad) < seq)[:, None]
+        v, beta = v_beta(qkv, b_logit)
+        return kda.chunk_operands(q, k, v, g, beta, chunk)
+
     def run(operands_of):
         def loss(*inputs):
-            operands = operands_of(*inputs, chunk)
+            operands = operands_of(*inputs)
             return sum(
                 jnp.sum(o * cot) for o, cot in zip(operands, cots)
             ), operands
@@ -293,11 +327,64 @@ def test_operand_kernels_agree_with_the_xla_operands(monkeypatch, seq, steeper, 
             )(*inputs)
         return operands, grads
 
-    got, want = run(kda.kernel_operands), run(kda.chunk_operands)
+    got, want = run(kernels), run(xla)
     assert all(
         bool(jnp.all(jnp.isfinite(t))) for t in jax.tree_util.tree_leaves(got)
     )
-    assert_trees_close(got, want, rtol=1e-4, atol=1e-5)
+    if a_shift:  # dt_bias's and a_log's are more than rounding noise
+        assert float(jnp.min(jnp.abs(want[1][3]))) > 1.0
+        assert float(jnp.median(jnp.abs(want[1][2]))) > 0.1
+    # a_log's gradient is the sum of dg g over a head: it carries dg's error
+    # times |g|, which the overflowing case makes 9 and more
+    (*got_in, got_a, got_b), (*want_in, want_a, want_b) = got[1], want[1]
+    assert_trees_close(
+        (got[0], got_in, got_b), (want[0], want_in, want_b),
+        rtol=1e-4, atol=1e-5,
+    )
+    np.testing.assert_allclose(
+        got_a, want_a, rtol=1e-4,
+        atol=1e-5 * max(1.0, float(jnp.max(-slowest))),
+    )
+
+
+def test_the_whole_node_on_the_kernels_agrees_with_the_xla_route(monkeypatch):
+    """`gated_delta_forward` at two heads of 128 | 128 over two chunks: the
+    "kda" route (every kernel interpreted: the scores' read the convolved
+    q | k and the decay's pre-activation where they lie and do the gates in
+    VMEM) against the "xla" route (`no_flash()`): the output and the
+    gradients of the input and all nine weights, float32. The routes differ
+    where the operands' test says, and the node's projections multiply that
+    by a hidden size of 32."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    attrs = GatedDeltaAttrs(2, 128, 128, 4, 8, 64, 1e-5)
+    rs = np.random.RandomState(21)
+    b, seq, hidden = 1, 128, 32
+    shapes = attrs.weight_shapes(TensorShape((b, seq, hidden), DataType.FLOAT))
+    scales = [0.3, 0.5, 0.5, 1.0, 0.3, 0.5, 0.5, 0.2, 0.1]
+    ws = [rand(rs, *s.dims, scale=k) for s, k in zip(shapes, scales)]
+    ws[7] = 1.0 + ws[7]
+    u, cot = rand(rs, b, seq, hidden), rand(rs, b, seq, hidden)
+    routes = []
+
+    def run():
+        routes.append(kda.scan_route(128, 128, 64))
+        with jax.default_matmul_precision("highest"):
+            def loss(u, ws):
+                y = kda.gated_delta_forward(attrs, u, ws)
+                return jnp.sum(y * cot), y
+
+            (_, y), grads = jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True
+            )(u, ws)
+        return y, grads
+
+    got = run()
+    with flash.no_flash():
+        want = run()
+    assert routes == ["kda", "xla"]
+    for grad in jax.tree_util.tree_leaves(want[1]):
+        assert float(jnp.max(jnp.abs(grad))) > 1e-3  # every weight is reached
+    assert_trees_close(got, want, **F32_GRADS)
 
 
 # -- latent attention ------------------------------------------------------------

@@ -38,8 +38,10 @@ pinned to the CPU, skipped only where the TPU's library is not installed.
         bytes (7 s; `--root <checkout>` lists another checkout's node)
 """
 
+import functools
 import importlib.util
 import json
+import math
 import os
 import re
 import subprocess
@@ -178,53 +180,76 @@ KIMI_INVARIANTS = [
     "kda_node_compiles_with_its_seven_kernels",
     "kda_operand_kernels_are_the_nodes_prep_part",
     "kda_holds_no_state_per_position",
+    "kda_scores_read_the_models_layout",
     "wide_key_flash_compiles_forward_and_backward",
 ]
 
 
-def check_kimi():
-    """{invariant: "ok" or what was found} for the `kimi_linear` cell's two
-    new kernel paths at the published shape."""
+@functools.lru_cache(maxsize=None)
+def _described_chip():
+    """(a bf16 `ShapeDtypeStruct` on the described v5e for `dims`): `scan_route`
+    asks the backend and nothing runs here, so say a TPU is there."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
     from flexflow_tpu.kernels import flash_attention as fa
-    from flexflow_tpu.kernels import kda
-    from flexflow_tpu.observability.trace import parse_scope
-    from flexflow_tpu.op_attrs.datatype import DataType
-    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
-    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
 
     jax.config.update("jax_enable_compilation_cache", False)
     fa._backend_ok = lambda allow_interpret=False: True
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
+    return lambda dims: jax.ShapeDtypeStruct(
+        tuple(dims), jnp.bfloat16, sharding=chip
+    )
 
-    def on_chip(dims):
-        return jax.ShapeDtypeStruct(tuple(dims), jnp.bfloat16, sharding=chip)
+
+KDA_HIDDEN, KDA_HEADS = 2304, 32
+
+
+def compiled_kda_node():
+    """The compiled HLO text of one gated delta-rule node's forward and
+    backward at the `kimi_linear` cell's shape, for the described chip."""
+    import jax
+
+    from flexflow_tpu.kernels import kda
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    on_chip = _described_chip()
+    attrs = GatedDeltaAttrs(KDA_HEADS, 128, 128, 4, 128, 64, 1e-5)
+    u = on_chip((1, ROWS, KDA_HIDDEN))
+    weights = [
+        on_chip(w.dims) for w in attrs.weight_shapes(
+            TensorShape((1, ROWS, KDA_HIDDEN), DataType.FLOAT)
+        )
+    ]
+
+    def scoped(u, weights):
+        with jax.named_scope("ff.kda.kda2"):
+            return kda.gated_delta_forward(attrs, u, weights)
+
+    def node(u, weights, cot):
+        y, vjp = jax.vjp(scoped, u, weights)
+        return y, vjp(cot)
+
+    return jax.jit(node).lower(u, weights, u).compile().as_text()
+
+
+def check_kimi():
+    """{invariant: "ok" or what was found} for the `kimi_linear` cell's two
+    new kernel paths at the published shape."""
+    import jax
+
+    from flexflow_tpu.kernels import flash_attention as fa
+    from flexflow_tpu.observability.trace import parse_scope
 
     found = {}
-    hidden, heads = 2304, 32
+    heads = KDA_HEADS
     try:
-        attrs = GatedDeltaAttrs(heads, 128, 128, 4, 128, 64, 1e-5)
-        u = on_chip((1, ROWS, hidden))
-        weights = [
-            on_chip(w.dims) for w in attrs.weight_shapes(
-                TensorShape((1, ROWS, hidden), DataType.FLOAT)
-            )
-        ]
-
-        def scoped(u, weights):
-            with jax.named_scope("ff.kda.kda2"):
-                return kda.gated_delta_forward(attrs, u, weights)
-
-        def node(u, weights, cot):
-            y, vjp = jax.vjp(scoped, u, weights)
-            return y, vjp(cot)
-
-        text = jax.jit(node).lower(u, weights, u).compile().as_text()
+        text = compiled_kda_node()
         # a kernel's name as the profile has it: the chunk-to-chunk pass
         # forward, the states and the backward; the scores' kernel forward
         # and rematerialised, and its backward; the triangular inverse's
@@ -263,11 +288,34 @@ def check_kimi():
         found["kda_holds_no_state_per_position"] = (
             "ok" if not states else ", ".join(sorted(set(states)))
         )
+        # since PR 45 the scores' kernels read q, k and the decay's
+        # pre-activation where the convolution and the projection left them
+        # and do the gates in VMEM: no float32 tensor a head and position
+        # (the log-decays, q and k on their way to a norm) lies under `gates`,
+        # forward, recomputed or backward
+        rows = entry_instructions(text)
+        by_head = [
+            f"{name} f32{list(dims)}" for name, result, _, _, line in rows
+            if "/gates/" in line
+            for dtype, dims in shapes_of(result)
+            if dtype == "f32" and math.prod(dims) >= heads * ROWS * 128
+        ]
+        model_layout = f"bf16[1,{ROWS},{3 * heads * 128}]{{2,1,0}}"
+        elsewhere = [
+            name for name, _, opcode, _, line in rows
+            if opcode == "custom-call" and "kda_prep_fwd" in line
+            and line.split("operand_layout_constraints=")[1].count(model_layout) != 2
+        ]
+        found["kda_scores_read_the_models_layout"] = (
+            "ok" if not by_head and not elsewhere
+            else ", ".join(by_head + elsewhere)
+        )
     except Exception as e:  # noqa: BLE001 - the complaint is the result
         complaint = f"{type(e).__name__}: {e}"[:2000]
-        for invariant in KIMI_INVARIANTS[:3]:
+        for invariant in KIMI_INVARIANTS[:4]:
             found.setdefault(invariant, complaint)
     try:
+        on_chip = _described_chip()
         q = on_chip((1, ROWS, heads * 256))
         v = on_chip((1, ROWS, heads * 128))
 
@@ -293,7 +341,7 @@ def check_kimi():
 
 def listing(name, least=4e6):
     """The node's ENTRY instructions that move `least` bytes or more."""
-    _, text = compiled_node(name)
+    text = compiled_kda_node() if name == "kimi" else compiled_node(name)[1]
     rows = entry_instructions(text)
     result_of = {r[0]: r[1] for r in rows}
     lines, moved = [], 0
