@@ -342,6 +342,10 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
         return GatedDeltaAttrs(
             num_heads=2, key_dim=4, value_dim=4, gate_rank=2, chunk_size=4
         )
+    if op_type == OperatorType.SHORT_CONV:
+        from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
+
+        return ShortConvAttrs(width=size)
     if op_type == OperatorType.REPARTITION:
         return RepartitionAttrs(
             eq.get("repartition_dim", 0), eq.get("repartition_degree", 2)
@@ -384,6 +388,7 @@ def _data_shape_table(op_type: OperatorType, size: int, arity: int):
         OperatorType.EXPERTS: ((S, S),),
         OperatorType.STATE_SPACE: ((S, S, S),),
         OperatorType.GATED_DELTA: ((S, S, S),),
+        OperatorType.SHORT_CONV: ((S, S, S),),
         OperatorType.REPARTITION: ((S, S, S),),
         OperatorType.COMBINE: ((S, S, S),),
         OperatorType.REPLICATE: ((S, S, S),),

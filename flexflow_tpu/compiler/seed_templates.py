@@ -226,6 +226,8 @@ _DP_TYPES = frozenset(
         # the scan runs along each sample's own sequence
         OperatorType.STATE_SPACE,
         OperatorType.GATED_DELTA,
+        # the short convolution too (no halo is expressed for a shard)
+        OperatorType.SHORT_CONV,
     }
 )
 

@@ -253,14 +253,24 @@ def mha_row_projections(attrs: MultiHeadAttentionAttrs) -> bool:
 
 def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains):
     """What the attrs ask for between projection and attention core, on the
-    fused [b, s, h*d] projections: QK-norm over the whole row, then RoPE on
+    fused [b, s, h*d] projections: QK-norm over the whole row (or, with
+    `qk_norm_per_head`, over each head block by itself), then RoPE on
     each head block, then each grouped-query key/value head repeated for
     the query heads that read it (head h reads h // group), so that the core
     is the equal-head one: same kernels, same route, and the repeat's
     transpose sums dK and dV over the group. A kernel that indexes the
     key/value block by `h // group` instead would save the two repeated
     copies (ROADMAP, Reach (3))."""
-    if attrs.qk_norm:
+    if attrs.qk_norm_per_head:
+        # a head's own features normed by themselves, one gain [d] for all
+        # of q's heads and one for the key heads
+
+        def per_head(x, gain):
+            heads = x.reshape(*x.shape[:2], -1, attrs.q_proj_size)
+            return rms_norm(heads, gain, attrs.qk_norm_eps).reshape(x.shape)
+
+        qp, kp = per_head(qp, qk_gains[0]), per_head(kp, qk_gains[1])
+    elif attrs.qk_norm:
         qp = rms_norm(qp, qk_gains[0], attrs.qk_norm_eps)
         kp = rms_norm(kp, qk_gains[1], attrs.qk_norm_eps)
     if attrs.rope_theta is not None:
@@ -279,6 +289,53 @@ def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains):
     return qp, kp, vp
 
 
+def mha_pads_heads(attrs: MultiHeadAttentionAttrs, s: int) -> bool:
+    """Whether the fused-row core runs these heads of 64 as 128-lane heads,
+    each padded with 64 zero columns: a causal context of more than one tile,
+    which the head-pair kernels refuse (their backward is the single-tile
+    one). The d % 128 causal tile schedule takes them as it takes a padded
+    latent key (`flash_attention_bshf_wide_key`, the true head size's
+    scale): on a 128 x 128 matrix unit a 64-wide contraction or product
+    fills half the array either way, so the zero columns cost bytes and no
+    passes. A causal tile schedule for the pair kernels would save the
+    padded copies (ROADMAP, Reach)."""
+    from flexflow_tpu.kernels.flash_attention import (
+        bshf_pair_supported,
+        wide_key_supported,
+    )
+
+    kd = attrs.q_proj_size
+    return (
+        kd == attrs.v_proj_size == 64
+        and getattr(attrs, "causal", False)
+        and not bshf_pair_supported(attrs.num_heads, kd, s)
+        and wide_key_supported(s)
+    )
+
+
+def _padded_heads_core(attrs: MultiHeadAttentionAttrs, qp, kp, vp):
+    """`mha_pads_heads`' core on the fused [b, s, h * 64] rows: pad, the
+    wide-key entry at 128 | 128, and the context's own 64 columns back."""
+    from flexflow_tpu.kernels.flash_attention import (
+        flash_attention_bshf_wide_key,
+        per_batch_shard,
+    )
+
+    H, kd = attrs.num_heads, attrs.q_proj_size
+    b, s, _ = qp.shape
+
+    def padded(x):
+        x = jnp.pad(x.reshape(b, s, H, kd), ((0, 0),) * 3 + ((0, 128 - kd),))
+        return x.reshape(b, s, H * 128)
+
+    with jax.named_scope("core"):
+        ctx = per_batch_shard(
+            flash_attention_bshf_wide_key, padded(qp), padded(kp), padded(vp),
+            num_heads=H, scale=kd ** -0.5,
+        )
+        return ctx.reshape(b, s, H, 128)[..., :kd].reshape(b, s, H * kd)
+
+
 def mha_core_route(
     attrs: MultiHeadAttentionAttrs, q_shape, k_shape, v_shape, fused_qkv: bool
 ) -> str:
@@ -292,7 +349,9 @@ def mha_core_route(
       self-attention, `fused_qkv`: q is k is v);
     - "fused_row": three plain matmuls into [b, s, h*d] rows and
       `flash_attention_bshf` (d % 128 == 0, or d=64 with distinct operands
-      or QK-norm / RoPE between projection and core);
+      or QK-norm / RoPE between projection and core), or, for d=64 under a
+      causal mask over more than one tile, the d % 128 causal kernels on
+      heads padded to 128 lanes (`mha_pads_heads`);
     - "rows": the per-head [b, h, s, d] projections and `flash_attention`
       (other head sizes; every head-sharded plan, through
       `sharded_flash_attention`);
@@ -353,9 +412,23 @@ def mha_core_route(
         if kd % 128 != 0 and fused_qkv and not mha_row_projections(attrs):
             return "fused_row_qkv"
         return "fused_row"
+    lanes = (b, H, s, 128)
+    if (
+        heads_whole and s == t and mha_pads_heads(attrs, s)
+        and flash_core_supported(lanes, lanes, lanes, "lane")
+    ):
+        return "fused_row"
     if flash_core_supported(proj_q, proj_kv, (b, H, v_shape[1], vd), "rows"):
         return "rows"
     return "dense"
+
+
+def _note_route(route: str) -> None:
+    """Tell the program's counter which core the attention node being
+    lowered took (`observability/trace.attention_routes`)."""
+    from flexflow_tpu.observability import trace
+
+    trace.note_attention_route(route)
 
 
 def unpack_gqa_weights(
@@ -418,6 +491,7 @@ def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal)
         ]
         v = kv[..., own:]
     route = mha_core_route(attrs, x.shape, x.shape, x.shape, True)
+    _note_route(route)
     if route == "fused_row":
         pad = wide_key_padded(kd) - kd
         # zero columns of the WEIGHT are the padded query's zero columns
@@ -464,6 +538,7 @@ def _mha_forward(
     # projections; a node without them takes the paths it always took
     post = mha_row_projections(attrs)
     route = mha_core_route(attrs, q.shape, k.shape, v.shape, q is k and k is v)
+    _note_route(route)
     if route == "fused_row_qkv":
         # self-attention on the head-pair path: ONE fused projection matmul
         # into the interleaved [q_pair|k_pair|v_pair] layout; flash reads
@@ -483,6 +558,8 @@ def _mha_forward(
         )
         if post:
             qp, kp, vp = mha_between(attrs, qp, kp, vp, qk_gains)
+        if mha_pads_heads(attrs, q.shape[1]):
+            return _padded_heads_core(attrs, qp, kp, vp) @ wo2
         ctx = per_batch_shard(
             flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal
         )
@@ -764,6 +841,13 @@ def forward(
 
         return [gated_delta_forward(attrs, inputs[0], weights)]
 
+    from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
+
+    if isinstance(attrs, ShortConvAttrs):
+        from flexflow_tpu.kernels.short_conv import short_conv_forward
+
+        return [short_conv_forward(attrs, inputs[0], weights)]
+
     from flexflow_tpu.op_attrs.ops.moe import (
         AggregateAttrs,
         ExpertsAttrs,
@@ -854,6 +938,14 @@ def op_internal_bytes(attrs: OpAttrs, input_shapes, weight_shapes=None) -> int:
             + 3 * attrs.value_width
         )
         return 2 * tokens * width * x.dtype.size_bytes
+    from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
+
+    if isinstance(attrs, ShortConvAttrs):
+        # the projection's row B | C | z, the gated input, its convolution
+        # and the gated row the output projection reads
+        x = input_shapes[0]
+        tokens = int(x.num_elements) // x.dims[-1]
+        return 2 * tokens * 6 * attrs.width * x.dtype.size_bytes
     if not isinstance(attrs, ExpertsAttrs):
         return 0
     x = input_shapes[0]
@@ -1002,6 +1094,15 @@ def op_forward_flops(
             2 * q * (2 * dk + dk + dv) + 2 * q * dv + 4 * 2 * dk * dv
         )
         return proj + scan + 2 * b * s * attrs.conv_kernel * attrs.conv_width
+
+    from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
+
+    if isinstance(attrs, ShortConvAttrs):
+        b, s, d = input_shapes[0].dims
+        # the two projections, the taps and the two gates
+        return b * s * attrs.width * (
+            2 * d * 4 + 2 * attrs.conv_kernel + 2
+        )
 
     total = sum(nelem(s) for s in output_shapes)
     return total

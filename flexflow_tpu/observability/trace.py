@@ -356,7 +356,7 @@ PHASES = ("fwd", "bwd", "opt", "other", "unattributed")
 # kinds whose OperatorType value is not the name the tables use
 _KIND_NAMES = {
     "linear": "dense", "multihead_attention": "mha", "state_space": "ssm",
-    "gated_delta": "kda",
+    "gated_delta": "kda", "short_conv": "shortconv",
 }
 _NOT_IN_NAME = re.compile(r"[^A-Za-z0-9_.\-]")
 # the first `ff.` token of a name stack: a kind holds no dot, so the first
@@ -388,6 +388,9 @@ NODE_PARTS = {
     # latent attention (`kernels/ops._latent_mha_forward`): the low-rank
     # key/value projections with their norm, and the attention core
     "ring_attention": ("latent", "core"),
+    # the double-gated short-convolution node (`kernels/short_conv.py`): the
+    # input gate, the taps and the output gate between its two projections
+    "shortconv": ("conv",),
 }
 # Between the node's scope and the part's, JAX may also write what a
 # `jax.checkpoint` around the parts leaves in the backward's names: the
@@ -423,9 +426,40 @@ def scope_name(graph, n) -> str:
     return f"ff.{kind}.{_NOT_IN_NAME.sub('_', name)}"
 
 
+# the scope of the node being lowered on this thread, and the attention core
+# each attention node took when it was last lowered in this process
+_lowering = threading.local()
+_ATTENTION_ROUTES: Dict[str, str] = {}
+
+
+@contextlib.contextmanager
 def node_scope(graph, n):
     """The `jax.named_scope` everything lowered for node `n` goes under."""
-    return jax.named_scope(scope_name(graph, n))
+    name = scope_name(graph, n)
+    previous = getattr(_lowering, "scope", None)
+    _lowering.scope = name
+    try:
+        with jax.named_scope(name):
+            yield
+    finally:
+        _lowering.scope = previous
+
+
+def note_attention_route(route: str) -> None:
+    """The attention core (`kernels/ops.mha_core_route`'s names) the node
+    being lowered took; dropped where no node's scope is open (a kernel
+    called by itself)."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _ATTENTION_ROUTES[scope] = route
+
+
+def attention_routes() -> Dict[str, str]:
+    """`{ff.<kind>.<name>: route}` of every attention node this process has
+    lowered on one device or in the global view, as it was lowered last: a
+    program counter a reader (the benchmark's `gqa64_flash_roofline`) prints
+    beside what it measures, so that a change of route says so itself."""
+    return dict(_ATTENTION_ROUTES)
 
 
 def step_scope(part: str):

@@ -75,6 +75,14 @@ from flexflow_tpu.op_attrs.ops.moe import (
 )
 from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
+
+
+# mixers and the experts op: their attrs list their own weight slots
+# (`num_weights`, `weight_shapes`, `parallel_weight_shapes`)
+_OWN_WEIGHT_LIST = (
+    ExpertsAttrs, StateSpaceAttrs, GatedDeltaAttrs, ShortConvAttrs,
+)
 
 
 class OperatorType(enum.Enum):
@@ -113,6 +121,7 @@ class OperatorType(enum.Enum):
     EXPERTS = "experts"  # fused tpu-native MoE FFN (expert parallelism)
     STATE_SPACE = "state_space"  # selective state-space mixer (chunked scan)
     GATED_DELTA = "gated_delta"  # gated delta-rule linear attention (chunked)
+    SHORT_CONV = "short_conv"  # double-gated short-convolution mixer
     REPARTITION = "repartition"
     COMBINE = "combine"
     REPLICATE = "replicate"
@@ -139,7 +148,7 @@ OpAttrs = Union[
     ConcatAttrs, StackAttrs, SplitAttrs, ReshapeAttrs, TransposeAttrs,
     ReverseAttrs, GatherAttrs, TopKAttrs, ReduceAttrs,
     GroupByAttrs, AggregateAttrs, ExpertsAttrs, StateSpaceAttrs,
-    GatedDeltaAttrs,
+    GatedDeltaAttrs, ShortConvAttrs,
     RepartitionAttrs, CombineAttrs, ReplicateAttrs, ReductionAttrs,
     StagePartitionAttrs, StageMergeAttrs,
 ]
@@ -180,6 +189,7 @@ _OP_TYPE_BY_ATTRS = {
     ExpertsAttrs: OperatorType.EXPERTS,
     StateSpaceAttrs: OperatorType.STATE_SPACE,
     GatedDeltaAttrs: OperatorType.GATED_DELTA,
+    ShortConvAttrs: OperatorType.SHORT_CONV,
     RepartitionAttrs: OperatorType.REPARTITION,
     CombineAttrs: OperatorType.COMBINE,
     ReplicateAttrs: OperatorType.REPLICATE,
@@ -246,7 +256,7 @@ def get_incoming_tensor_roles(attrs: OpAttrs) -> List[IncomingTensorRole]:
         return [I, W, W] if attrs.elementwise_affine else [I]
     if isinstance(attrs, RMSNormAttrs):
         return [I, W]
-    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs, GatedDeltaAttrs)):
+    if isinstance(attrs, _OWN_WEIGHT_LIST):
         return [I] + [W] * attrs.num_weights
     n = num_data_inputs(attrs)
     return [I] * n
@@ -341,7 +351,7 @@ def get_weight_shapes(
         return [attrs.gamma_shape(inputs[0]), attrs.beta_shape(inputs[0])]
     if isinstance(attrs, RMSNormAttrs):
         return [attrs.gamma_shape(inputs[0])]
-    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs, GatedDeltaAttrs)):
+    if isinstance(attrs, _OWN_WEIGHT_LIST):
         return list(attrs.weight_shapes(inputs[0]))
     return []
 
@@ -406,6 +416,14 @@ def get_default_weight_initializers(attrs: OpAttrs, num_weights: int):
             LogOfUniformInitializerAttrs(1.0, 16.0), None, None,
             ConstantInitializerAttrs(1.0), None,
         ][:num_weights]
+    if isinstance(attrs, ShortConvAttrs):
+        from flexflow_tpu.pcg.initializer import UniformInitializerAttrs
+
+        # the convolution as torch's conv1d starts a depthwise kernel
+        bound = float(attrs.conv_kernel) ** -0.5
+        return [
+            None, UniformInitializerAttrs(min_val=-bound, max_val=bound), None,
+        ][:num_weights]
     return [None] * num_weights
 
 
@@ -469,6 +487,6 @@ def get_parallel_weight_shapes(
         return [g, g]
     if isinstance(attrs, RMSNormAttrs):
         return [attrs.parallel_gamma_shape(inputs[0])]
-    if isinstance(attrs, (ExpertsAttrs, StateSpaceAttrs, GatedDeltaAttrs)):
+    if isinstance(attrs, _OWN_WEIGHT_LIST):
         return list(attrs.parallel_weight_shapes(inputs[0]))
     return []

@@ -71,3 +71,4 @@ from flexflow_tpu.op_attrs.ops.moe import (
 )
 from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs

@@ -70,6 +70,13 @@ class MultiHeadAttentionAttrs:
     kv_latent_rank: Optional[int] = None
     shared_key_dim: int = 0
     kv_latent_norm_eps: float = 1e-5
+    # qk_norm_per_head: with `qk_norm_eps`, the RMS norm is taken over EACH
+    # head's own `kdim` features (q's heads and the key heads alike, before
+    # RoPE), with one gain [kdim] for q and one for k that every head
+    # shares. The two weight slots are where the whole-row form has them;
+    # this form has a row for a key/value head that several query heads
+    # share, so it is the one grouped-query heads take.
+    qk_norm_per_head: bool = False
 
     def __post_init__(self):
         if self.kv_latent_rank is not None:
@@ -89,9 +96,15 @@ class MultiHeadAttentionAttrs:
                 f"{self.num_heads} query heads do not divide over "
                 f"{self.num_kv_heads} key/value heads"
             )
-            assert not self.qk_norm, (
-                "QK-norm with grouped-query heads is not expressed yet"
+            assert not self.qk_norm or self.qk_norm_per_head, (
+                "QK-norm over the whole row has a gain [num_heads * kdim]: "
+                "it has no row for a key/value head that "
+                f"{self.num_heads // self.num_kv_heads} query heads share; "
+                "grouped-query heads take qk_norm_per_head"
             )
+        assert not self.qk_norm_per_head or self.qk_norm, (
+            "qk_norm_per_head says how qk_norm_eps norms: it needs one"
+        )
 
     @property
     def grouped_query(self) -> bool:
@@ -178,8 +191,10 @@ class MultiHeadAttentionAttrs:
         return TensorShape((self.embed_dim,), q.dtype)
 
     def qk_gain_shape(self, q: TensorShape, k: TensorShape, v: TensorShape) -> TensorShape:
-        """One QK-norm gain (q's and k's have the same shape)."""
-        return TensorShape((self.num_heads * self.q_proj_size,), q.dtype)
+        """One QK-norm gain (q's and k's have the same shape): the whole
+        row's, or with `qk_norm_per_head` one head's."""
+        heads = 1 if self.qk_norm_per_head else self.num_heads
+        return TensorShape((heads * self.q_proj_size,), q.dtype)
 
     def latent_gain_shape(self, q: TensorShape) -> TensorShape:
         return TensorShape((self.kv_latent_rank,), q.dtype)

@@ -248,11 +248,13 @@ class ComputationGraphBuilder:
         kv_latent_rank: Optional[int] = None,
         shared_key_dim: int = 0,
         kv_latent_norm_eps: float = 1e-5,
+        qk_norm_per_head: bool = False,
     ) -> Tensor:
         """`causal`, `rope_theta` (rotary positions 0..s-1 on q and k) and
         `qk_norm_eps` (RMS norm of the projected q and k over all heads'
-        features, two gain weights) are what a decoder adds; a causal node
-        is a `RingAttentionAttrs`, the program's causal attention.
+        features, two gain weights; with `qk_norm_per_head` over each head's
+        own features, the gains one head wide) are what a decoder adds; a
+        causal node is a `RingAttentionAttrs`, the program's causal attention.
         `num_kv_heads` fewer than `num_heads` is grouped-query attention.
         `kv_latent_rank` is latent attention: keys and values from one
         normed low-rank row, the last `shared_key_dim` of a key's `kdim`
@@ -261,6 +263,7 @@ class ComputationGraphBuilder:
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, rope_theta, qk_norm_eps, num_kv_heads,
             kv_latent_rank, shared_key_dim, kv_latent_norm_eps,
+            qk_norm_per_head,
         )
         if causal:
             from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
@@ -659,6 +662,25 @@ class ComputationGraphBuilder:
         for slot in (0, 2, 5, 8):
             inits[slot] = initializer
         (out,) = self.add_layer(attrs, [input], inits, name)
+        return out
+
+    def short_conv(
+        self,
+        input: Tensor,
+        width: int,
+        conv_kernel: int = 3,
+        initializer: Optional[InitializerAttrs] = None,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """The double-gated short-convolution mixer (`ShortConvAttrs`) on
+        [batch, seq, channel]. `initializer`, if given, initializes the two
+        projections; the convolution takes the op's own default."""
+        from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
+
+        attrs = ShortConvAttrs(width, conv_kernel)
+        (out,) = self.add_layer(
+            attrs, [input], [initializer, None, initializer], name
+        )
         return out
 
     def moe(

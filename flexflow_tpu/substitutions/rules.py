@@ -522,14 +522,17 @@ def data_parallel_state_space_rule(
     Replicate(w)...)): the scan runs along the sequence of each sample by
     itself, so the batch dim shards and nothing else does. The same rule for
     the gated delta-rule mixer (`op_type` GATED_DELTA), whose recurrence
-    runs along the sequence as the scan does."""
+    runs along the sequence as the scan does, and for the short-convolution
+    mixer (SHORT_CONV), whose taps read the positions before their own."""
     from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+    from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
     from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 
-    attrs_cls = (
-        StateSpaceAttrs if op_type == OperatorType.STATE_SPACE
-        else GatedDeltaAttrs
-    )
+    attrs_cls = {
+        OperatorType.STATE_SPACE: StateSpaceAttrs,
+        OperatorType.GATED_DELTA: GatedDeltaAttrs,
+        OperatorType.SHORT_CONV: ShortConvAttrs,
+    }[op_type]
     p = PCGPattern()
     a = p.add_input(_shard_pattern(0, degree))
     ws = [p.add_input() for _ in range(attrs_cls.num_weights)]
@@ -1120,6 +1123,9 @@ def generate_parallelization_rules(
         rules.append(data_parallel_state_space_rule(k))
         rules.append(
             data_parallel_state_space_rule(k, OperatorType.GATED_DELTA)
+        )
+        rules.append(
+            data_parallel_state_space_rule(k, OperatorType.SHORT_CONV)
         )
         rules.append(sequence_parallel_attention_rule(k))
         rules.append(sequence_parallel_attention_a2a_rule(k))

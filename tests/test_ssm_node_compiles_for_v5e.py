@@ -27,6 +27,17 @@ must hold no `[heads, positions, 128, 128]` state per position; and the causal
 flash kernels on a 256-wide padded key beside a 128-wide value
 (`flash_attention_bshf_wide_key`), forward and backward.
 
+And what the `lfm2_moe` cell added (PR 47), at its shape, two sequences of
+8,192 positions: ONE double-gated short-convolution node
+(`kernels/short_conv.gated_short_conv`) forward and backward, which must write
+no float32 tensor a position (its chain converts inside its fusions, as
+`conv_silu` does) and the projection's row at most twice (forward, and
+recomputed in the backward: kept nowhere); and the causal core of 32 heads of
+64 over 16 tiles, each head padded to 128 lanes
+(`kernels/ops._padded_heads_core`), forward and backward on the d % 128 causal
+tile kernels (the `[b, h, s, d]` rows kernels are refused at this length: 16 MB
+of scoped VMEM).
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -339,6 +350,74 @@ def check_kimi():
     return found
 
 
+LFM2_INVARIANTS = [
+    "short_conv_node_writes_no_float32_row_and_keeps_no_projection",
+    "padded_heads_core_compiles_on_the_causal_tile_kernels",
+]
+LFM2_SHAPE = (2, 8192, 2048)  # two sequences, hidden 2048; 32 heads of 64
+
+
+def check_lfm2():
+    """{invariant: "ok" or what was found} for the `lfm2_moe` cell's node and
+    its attention core."""
+    import jax
+
+    from flexflow_tpu.kernels.ops import _padded_heads_core
+    from flexflow_tpu.kernels.short_conv import gated_short_conv
+    from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
+
+    found = {}
+    on_chip = _described_chip()
+    x = on_chip(LFM2_SHAPE)
+    hidden = LFM2_SHAPE[-1]
+    try:
+        def node(x, w_in, w, w_out, cot):
+            y, vjp = jax.vjp(gated_short_conv, x, w_in, w, w_out)
+            return y, vjp(cot)
+
+        text = jax.jit(node).lower(
+            x, on_chip((hidden, 3 * hidden)), on_chip((3, hidden)),
+            on_chip((hidden, hidden)), x,
+        ).compile().as_text()
+        rows = entry_instructions(text)
+        per_position = math.prod(LFM2_SHAPE[:2])
+        float32 = [
+            f"{name}: {result[:60]}" for name, result, opcode, _, _ in rows
+            if opcode not in _NO_BUFFER and any(
+                dtype == "f32" and math.prod(dims) >= per_position
+                for dtype, dims in shapes_of(result)
+            )
+        ]
+        projections = [
+            name for name, result, opcode, _, _ in rows
+            if opcode not in _NO_BUFFER
+            and ("bf16", LFM2_SHAPE[:2] + (3 * hidden,)) in shapes_of(result)
+        ]
+        found[LFM2_INVARIANTS[0]] = (
+            "ok" if not float32 and len(projections) <= 2
+            else ", ".join(float32 + projections)
+        )
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        found[LFM2_INVARIANTS[0]] = f"{type(e).__name__}: {e}"[:2000]
+    try:
+        attrs = RingAttentionAttrs(hidden, 32, kdim=64, vdim=64, causal=True)
+
+        def core(q, k, v, cot):
+            o, vjp = jax.vjp(
+                lambda q, k, v: _padded_heads_core(attrs, q, k, v), q, k, v
+            )
+            return o, vjp(cot)
+
+        text = jax.jit(core).lower(x, x, x, x).compile().as_text()
+        kernels = text.count("tpu_custom_call")
+        found[LFM2_INVARIANTS[1]] = (
+            "ok" if kernels == 3 else f"{kernels} kernels, want 3"
+        )
+    except Exception as e:  # noqa: BLE001
+        found[LFM2_INVARIANTS[1]] = f"{type(e).__name__}: {e}"[:2000]
+    return found
+
+
 def listing(name, least=4e6):
     """The node's ENTRY instructions that move `least` bytes or more."""
     text = compiled_kda_node() if name == "kimi" else compiled_node(name)[1]
@@ -394,6 +473,11 @@ def test_kimi_kernels_compiled_for_the_described_chip(compiled, invariant):
     assert compiled["kimi"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", LFM2_INVARIANTS)
+def test_lfm2_node_and_core_compiled_for_the_described_chip(compiled, invariant):
+    assert compiled["lfm2"][invariant] == "ok"
+
+
 if __name__ == "__main__":
     argv = sys.argv[1:]
     root = os.getcwd()
@@ -406,5 +490,6 @@ if __name__ == "__main__":
         print(listing(argv[0]))
     else:
         print(json.dumps(
-            dict({name: check(name) for name in SHAPES}, kimi=check_kimi())
+            dict({name: check(name) for name in SHAPES}, kimi=check_kimi(),
+                 lfm2=check_lfm2())
         ))

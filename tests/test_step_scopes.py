@@ -165,6 +165,17 @@ def test_scope_name_survives_the_name_stack(layer_name, want):
          ("bwd", "ring_attention", "mla4/core")),
         ("jit(_step)/jvp(ff.ring_attention.a0)/dot_general",
          ("fwd", "ring_attention", "a0")),
+        # the short-convolution node: the chain between its two projections
+        # (the input gate, the taps, the output gate), whose written backward
+        # opens the part's scope again under the node's transposed one
+        ("jit(_step)/jvp(ff.shortconv.conv2)/conv/mul",
+         ("fwd", "shortconv", "conv2/conv")),
+        ("jit(_step)/transpose(jvp(ff.shortconv.conv2))/conv/reduce_sum",
+         ("bwd", "shortconv", "conv2/conv")),
+        ("jit(_step)/transpose(jvp(ff.shortconv.conv2))/dot_general",
+         ("bwd", "shortconv", "conv2")),
+        ("jit(_step)/jvp(ff.ring_attention.attn1)/core/flash_fwd_causal_bshf"
+         "/pallas_call", ("fwd", "ring_attention", "attn1/core")),
         # a scope that only begins like a part, and a part of another kind
         ("jit(_step)/jvp(ff.experts.moe1)/shared_expert/mul",
          ("fwd", "experts", "moe1")),
