@@ -16,8 +16,10 @@ token rows in that order, run the experts' matrices as grouped matmuls (rows
 [N*k, D] against [E, D, H] with E group sizes) in the compute dtype with
 float32 accumulation, and combine each token's k rows with the router's
 weights. The grouped matmul is `_grouped_matmul`: on the chip the Pallas
-kernels `gmm` / `tgmm` of `jax.experimental.pallas.ops.tpu.megablox`, elsewhere
-`jax.lax.ragged_dot`. The row count is
+kernels `gmm` / `tgmm` of `jax.experimental.pallas.ops.tpu.megablox`, the
+forward, the input gradient and the weight gradient each with a tile chosen
+from that call's own shape (`_gmm_tile`), elsewhere `jax.lax.ragged_dot`.
+The row count is
 always N*k, so every shape is static and nothing is dropped unless the attrs
 state a capacity, in which case the decisions ranked past it get weight zero.
 Neither the gather nor the combine needs a scatter in either direction: both
@@ -132,14 +134,100 @@ def _take_rows_bwd(fan, inverse, g):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-# (rows, contraction, columns) tile of the megablox kernels. Measured on a v5e
-# at the OLMoE shapes (131,072 rows, 2048 x 1024 and back, 64 groups, forward
-# and backward of the gated expert): 41.8 ms, against 43.1 at 256 rows, 46.9 at
-# a 512 contraction tile, 509 at the library's default (128, 128, 128) and
-# 56.3 for XLA's own `ragged-dot` kernel, which also loses the node's scope in
-# the device trace; 1024 rows or a 2048 contraction tile do not fit VMEM
-# (my chip run, PR 26).
-_GMM_TILE = (512, 1024, 1024)
+# -- the grouped matmul: megablox's `gmm` / `tgmm`, a tile a call ------------
+#
+# A grouped matmul is three kernel calls, named here as megablox names a
+# call's own sides, rows [m, k] x matrices [g, k, n]:
+#   forward          gmm   rows [m, K] x w [g, K, N]        k = K, n = N
+#   input_gradient   gmm   g [m, N] x w^T (`transpose_rhs`)  k = N, n = K
+#   weight_gradient  tgmm  rows^T [K, m] x g [m, N]          [g, K, N] out
+# and a (tm, tk, tn) tile means blocks [tm, tk] x [tk, tn] -> [tm, tn] in the
+# two `gmm` calls and [tm, tk]^T x [tm, tn] -> [tk, tn] in `tgmm`.
+_CALLS = ("forward", "input_gradient", "weight_gradient")
+
+# Bytes of VMEM a tile may ask for, as Pallas lays a call out: every operand
+# and result block twice (the pipeline's two buffers) and the float32
+# accumulator once, operands of two bytes. The v5e's compiler allows a kernel
+# 16 MB with its own temporaries: (512, 2048, 1024), 16 MB by this count,
+# is refused at 16.58 (PR 26; described-chip compile, PR 48), and every tile
+# of up to 14.75 MB tried compiled and ran. A quarter is left to it.
+_TILE_BYTES = 12 * 2**20
+
+
+def _whole_tiles(size: int, tile: int) -> int:
+    """`size` rounded up to whole tiles: what a side costs, since megablox
+    runs a partial block as a whole one."""
+    return -(-size // tile) * tile
+
+
+def _exact_tiles(size: int) -> List[int]:
+    """The multiples of 128 no larger than `size` that end it in the least
+    padding, largest first: 1,536, 768, 512, 384, 256, 128 for 1,536; 640,
+    384, 128 for 1,856 (1,920: it is no multiple of 128)."""
+    tiles = range(size - size % 128, 0, -128)
+    least = min(_whole_tiles(size, t) for t in tiles)
+    return [t for t in tiles if _whole_tiles(size, t) == least]
+
+
+def _tile_bytes(call: str, tm: int, tk: int, tn: int) -> int:
+    if call == "weight_gradient":  # [tm, tk]^T x [tm, tn] -> [tk, tn]
+        return 4 * (tm * tk + tm * tn + tk * tn) + 4 * tk * tn
+    return 4 * (tm * tk + tk * tn + tm * tn) + 4 * tm * tn
+
+
+def _gmm_tile(m: int, k: int, n: int, groups: int, call: str):
+    """The megablox (tm, tk, tn) for one of a grouped matmul's `_CALLS` on
+    rows [m, k] and `groups` matrices [k, n] (the call's own sides), or None
+    where the kernels do not take the shape. Read from the shape alone; what
+    each clause is worth was measured on a v5e, kernels alone, at the five
+    sparse cells' shapes (PERF.md section 6, PR 48; the times here are one
+    `gmm` call of `lfm2moe24b_s8192_1chip`, 10,240 rows x 2048 x 1536):
+
+    - the contraction and the columns in tiles that end them in no padding
+      (`_exact_tiles`): megablox runs a partial block as a whole one, so a
+      1,024-wide tile computes 2,048 of a 1,536-wide side: 553 us at
+      (512, 1024, 1024), 417 at (512, 1024, 768);
+    - the row tile the largest of 512, 256, 128 of which a group holds four
+      (`m // groups` rows; 128 where it holds none): every group that does
+      not start on a tile's boundary visits one tile more, 1.24 of the rows
+      at 2,048 a group and 512 a tile, 1.47 at 1,024 a group, 1.23 at 256;
+    - a `gmm` under 512 rows a tile holds the contraction whole where that
+      fits `_TILE_BYTES`: the group's weight block then stays in VMEM across
+      its row tiles, where a 256-row tile would fetch it again at 256 FLOPs a
+      byte, the chip's balance: 350 us at (256, 2048, 768), 444 at
+      (256, 1024, 768). Kimi's 128-row call on 2304 x 1024: 84 us whole, 114
+      at 768, 134 at the 1,024 that pads 2,304 to 3,072;
+    - otherwise (`tgmm`, whose contraction is the row tile, and 512 rows a
+      tile) the largest block that fits, the squarer of two as large: at
+      OLMoE's 131,072 rows x 2048 x 1024 that is (512, 1024, 1024) in all
+      three calls, the tile measured there against 256 rows, a 512
+      contraction tile, the library's (128, 128, 128), 12 times slower, and
+      XLA's own `ragged-dot` (PR 26)."""
+    assert call in _CALLS, call
+    if m % 128 or min(k, n) < 128 or k % 64 or n % 64:
+        return None
+    tm = next((t for t in (512, 256) if m % t == 0 and m // groups >= 4 * t), 128)
+    whole = call != "weight_gradient" and tm < 512
+    tk, tn = max(
+        (
+            (tk, tn) for tk in _exact_tiles(k) for tn in _exact_tiles(n)
+            if _tile_bytes(call, tm, tk, tn) <= _TILE_BYTES
+        ),
+        key=lambda t: (whole and t[0] == k, t[0] * t[1], min(t)),
+    )
+    return tm, tk, tn
+
+
+def _gmm_tiles(m: int, k: int, n: int, groups: int):
+    """The three tiles of a grouped matmul rows [m, k] x [groups, k, n], in
+    the order of `_CALLS`, or None where the kernels do not take the shape:
+    the input gradient contracts over the forward's columns."""
+    tiles = (
+        _gmm_tile(m, k, n, groups, "forward"),
+        _gmm_tile(m, n, k, groups, "input_gradient"),
+        _gmm_tile(m, k, n, groups, "weight_gradient"),
+    )
+    return None if None in tiles else tiles
 
 
 def _pallas_allowed(per_shard: bool) -> bool:
@@ -158,41 +246,90 @@ def _pallas_allowed(per_shard: bool) -> bool:
     )
 
 
-def _gmm_tile(m: int, k: int, n: int, groups: int):
-    """The megablox tile for rows [m, k] x [groups', k, n] with `groups`
-    group sizes, or None where the kernels do not take the shape. The row
-    tile follows the rows a group has on average: every group costs whole
-    row tiles, and at 192 rows a group (a held share of a wide router) a
-    512-row tile computes two to five times the rows. A contraction or
-    column size that is no multiple of its tile ends in a partial block,
-    which the kernels mask (k) or drop on the write (n); it must still fill
-    whole 128-lane vregs but for that last block."""
-    tm, tk, tn = _GMM_TILE
-    if m // groups < tm:
-        tm = 128
-    if m % tm or min(k, n) < 128 or k % 64 or n % 64:
-        return None
-    return tm, min(tk, k - k % 128), min(tn, n - n % 128)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _gmm(rows, w, group_sizes, group_offset, tiles, interpret=False):
+    """megablox's grouped matmul with a tile a call (`tiles`: `_gmm_tiles`),
+    where `megablox.ops.gmm`'s own `custom_vjp` hands the forward's to all
+    three. Operands in rows' dtype, float32 accumulation, the residuals the
+    library's: rows, matrices, sizes. `group_offset`: see `_grouped_matmul`."""
+    # the backend MODULE's function: the package's `gmm` is `ops.gmm`
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    return gmm(
+        rows, w, group_sizes, rows.dtype, tiles[0], group_offset,
+        interpret=interpret,
+    )
 
 
-def _grouped_matmul(rows, w, group_sizes, pallas: bool, group_offset=None):
+def _gmm_fwd(rows, w, group_sizes, group_offset, tiles, interpret):
+    out = _gmm(rows, w, group_sizes, group_offset, tiles, interpret)
+    return out, (rows, w, group_sizes, group_offset)
+
+
+def _gmm_bwd(tiles, interpret, kept, g):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    rows, w, group_sizes, group_offset = kept
+    g_rows = gmm(
+        g, w, group_sizes, rows.dtype, tiles[1], group_offset,
+        transpose_rhs=True, interpret=interpret,
+    )
+    g_w = tgmm(
+        rows.swapaxes(0, 1), g, group_sizes, w.dtype, tiles[2], group_offset,
+        w.shape[0], interpret=interpret,
+    )
+    return g_rows, g_w, None, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
+
+
+def _grouped_matmul(rows, w, group_sizes, pallas: bool):
     """rows [M, K] (sorted by group) x w [G, K, N] -> [M, N] in rows' dtype,
-    float32 accumulation; `pallas`: `_pallas_allowed`. `group_offset` (Pallas
-    only, see `_held_rows_forward`): `w` holds the G groups from that one on
-    of the `group_sizes`' many; the rows of the others come back zero."""
+    float32 accumulation; `pallas`: `_pallas_allowed`. `group_sizes` has G
+    entries, or G + 1: the last is then the rows' rest (`_held_window`),
+    which meets no matrix and comes back zero. The kernels do not visit it
+    (a group offset of zero into G + 1 sizes); XLA's `ragged_dot` is given a
+    matrix of zeros for it."""
     w = w.astype(rows.dtype)
-    (m, k), n = rows.shape, w.shape[-1]
-    tile = _gmm_tile(m, k, n, group_sizes.shape[0]) if pallas else None
-    if tile is not None:
-        from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
-
-        return megablox.gmm(
-            rows, w, group_sizes, rows.dtype, tile, group_offset
-        )
-    assert group_offset is None, "an offset into the groups needs the kernels"
+    rest = group_sizes.shape[0] - w.shape[0]
+    assert rest in (0, 1), (group_sizes.shape, w.shape)
+    tiles = _gmm_tiles(rows.shape[0], *w.shape[1:], w.shape[0]) if pallas else None
+    if tiles is not None:
+        offset = jnp.zeros((), jnp.int32) if rest else None
+        return _gmm(rows, w, group_sizes, offset, tiles)
+    if rest:
+        w = jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
     return lax.ragged_dot(
         rows, w, group_sizes, preferred_element_type=rows.dtype
     )
+
+
+def _note_tiles(matrices, m: int, pallas: bool) -> None:
+    """Tell the trace which tiles the grouped matmuls of the expert node
+    being lowered take (`observability/trace.grouped_matmul_tiles`): asked
+    of `_gmm_tiles` as `_grouped_matmul` asks, here because the window
+    functions are jitted and traced once for every node of one shape.
+    `matrices`: name -> [G, K, N] (`w1`, `w3`, `w2`), `m`: rows a call."""
+    from flexflow_tpu.observability import trace
+
+    entries = {}
+    for name, w in matrices.items():
+        groups, k, n = w.shape
+        tiles = _gmm_tiles(m, k, n, groups) if pallas else None
+        if tiles is None:
+            continue
+        shapes = ((m, k, n), (m, n, k), (m, k, n))
+        for call, shape, tile in zip(_CALLS, shapes, tiles):
+            entries[f"{name}/{call}"] = {
+                "shape": shape, "tile": tile,
+                "padded_over_true": round(
+                    _whole_tiles(shape[1], tile[1]) * _whole_tiles(shape[2], tile[2])
+                    / (shape[1] * shape[2]), 4
+                ),
+            }
+    if entries:
+        trace.note_grouped_matmul_tiles(entries)
 
 
 # XLA's TPU gather and scatter move one element at a time: 8.8-10.2 ns each
@@ -342,6 +479,11 @@ def _all_rows_forward(attrs, x2, flat_e, topv, w1, w3, b1, w2, b2, pallas):
         kept = (rank < cap)[inverse].reshape(n, k)
         topv = jnp.where(kept, topv, 0.0)
 
+    _note_tiles(
+        {name: w for name, w in (("w1", w1), ("w3", w3), ("w2", w2)) if w is not None},
+        n * k, pallas,
+    )
+
     def grouped(rows, w):
         return _grouped_matmul(rows, w, counts, pallas)
 
@@ -434,15 +576,9 @@ def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool):
     k = decisions // n
     window = held_window_rows(decisions, held, attrs.num_experts)
 
-    def grouped(rows, w, sizes):
-        if pallas and _gmm_tile(window, w.shape[1], w.shape[2], held):
-            # `held` matrices for held + 1 sizes: the rows of the last,
-            # the window's rest, are not visited and come back zero
-            return _grouped_matmul(
-                rows, w, sizes, True, jnp.zeros((), jnp.int32)
-            )
-        zero = jnp.zeros((1,) + w.shape[1:], w.dtype)
-        return _grouped_matmul(rows, jnp.concatenate([w, zero]), sizes, False)
+    def grouped(rows, w):
+        # `held` matrices for held + 1 sizes: the last is the window's rest
+        return _grouped_matmul(rows, w, sizes, pallas)
 
     total = jnp.sum(counts)
     ends = jnp.cumsum(counts)
@@ -463,17 +599,17 @@ def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool):
     def bias_rows(b):
         # each row's expert's bias, as a grouped matmul of a column of
         # ones (see `experts_forward`)
-        return grouped(jnp.ones((window, 1), b.dtype), b[:, None, :], sizes)
+        return grouped(jnp.ones((window, 1), b.dtype), b[:, None, :])
 
     with jax.named_scope("grouped_matmul"):
-        h = grouped(rows, ws["w1"], sizes)
+        h = grouped(rows, ws["w1"])
         if "b1" in ws:
             h = h + bias_rows(ws["b1"])
         if attrs.activation is not None:
             h = attrs.activation.apply(h)
         if "w3" in ws:
-            h = h * grouped(rows, ws["w3"], sizes)
-        y = grouped(h, ws["w2"], sizes)
+            h = h * grouped(rows, ws["w3"])
+        y = grouped(h, ws["w2"])
         if "b2" in ws:
             y = y + bias_rows(ws["b2"])
     weight = jnp.where(valid, flat_w[decision], 0.0)
@@ -599,5 +735,8 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
 
     routed.defvjp(routed_fwd, routed_bwd)
     ws = {name: w.astype(x2.dtype) for name, w in ws.items()}
+    _note_tiles(
+        {name: w for name, w in ws.items() if w.ndim == 3}, window, pallas
+    )
     ran = jnp.maximum(windows(counts), 1)  # the first runs whatever the counts
     return routed(order, counts, x2, flat_w, ws), counts, ran

@@ -427,9 +427,11 @@ def scope_name(graph, n) -> str:
 
 
 # the scope of the node being lowered on this thread, and the attention core
-# each attention node took when it was last lowered in this process
+# each attention node took when it was last lowered in this process; the
+# grouped matmuls' tiles each expert node took likewise
 _lowering = threading.local()
 _ATTENTION_ROUTES: Dict[str, str] = {}
+_GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
 
 
 @contextlib.contextmanager
@@ -460,6 +462,27 @@ def attention_routes() -> Dict[str, str]:
     program counter a reader (the benchmark's `gqa64_flash_roofline`) prints
     beside what it measures, so that a change of route says so itself."""
     return dict(_ATTENTION_ROUTES)
+
+
+def note_grouped_matmul_tiles(entries: Dict[str, dict]) -> None:
+    """What `kernels/moe.py` gave the Pallas grouped matmuls of the expert
+    node being lowered: `{"<matrix>/<call>": {"shape", "tile",
+    "padded_over_true"}}`; dropped where no node's scope is open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _GROUPED_MATMUL_TILES[scope] = dict(entries)
+
+
+def grouped_matmul_tiles() -> Dict[str, Dict[str, dict]]:
+    """`{ff.experts.<name>: {"<matrix>/<call>": entry}}` of every expert node
+    this process has lowered onto the `gmm` / `tgmm` kernels, as it was
+    lowered last: for each of the node's matrices (`w1`, `w3`, `w2`) and each
+    of a grouped matmul's three calls (`forward`, `input_gradient`,
+    `weight_gradient`) the call's `shape` (rows, contraction, columns, in the
+    kernel's own names), the `tile` it was given and `padded_over_true`, the
+    contraction and column sides in whole tiles over their true size (the
+    row side is the data's). A node on XLA's `ragged_dot` has no entry."""
+    return {scope: dict(entries) for scope, entries in _GROUPED_MATMUL_TILES.items()}
 
 
 def step_scope(part: str):

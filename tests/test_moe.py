@@ -524,3 +524,174 @@ def test_expert_parallel_aux_rule_applies():
     assert len(aux) == 1
     # per-shard partial aux: copy degree ep on the rewritten experts op
     assert new_pcg.tensor_shape(aux[0]).dims.discard_copy_degree == 2
+
+
+# -- the grouped matmul's tiles (PR 48) -------------------------------------
+# the five sparse cells: rows a call (OLMoE every decision, the others the
+# held share's window), matrices a call, the expert matrix [K, N] of `w1`
+_CELL_SHAPES = {
+    "olmoe_s4096_1chip": (131072, 64, 2048, 1024),
+    "lfm2moe24b_s8192_1chip": (10240, 8, 2048, 1536),
+    "kimilinear48b_s4096_1chip": (1280, 8, 2304, 1024),
+    "twotower30b_s4096_1chip": (1920, 8, 2688, 1856),
+    "super120b_s4096_1chip": (1792, 8, 1024, 2688),
+}
+
+
+def test_the_windows_are_the_cells():
+    """`_CELL_SHAPES`' rows are what `held_window_rows` gives each held cell
+    (decisions a step, 8 held of the router's width)."""
+    for cell, decisions, experts in [
+        ("lfm2moe24b_s8192_1chip", 65536, 64),
+        ("kimilinear48b_s4096_1chip", 32768, 256),
+        ("twotower30b_s4096_1chip", 24576, 128),
+        ("super120b_s4096_1chip", 90112, 512),
+    ]:
+        assert moe_kernels.held_window_rows(decisions, 8, experts) == (
+            _CELL_SHAPES[cell][0]
+        )
+
+
+@pytest.mark.parametrize("matrix", ["w1", "w2"])
+@pytest.mark.parametrize("call", moe_kernels._CALLS)
+@pytest.mark.parametrize("cell", list(_CELL_SHAPES))
+def test_grouped_matmul_tile_fits_the_calls_own_shape(cell, call, matrix):
+    """The tile rule as a pure function, at each cell's shape and window, for
+    each of the three calls of `w1` [K, N] and of `w2` [N, K]: every side of
+    the tile a multiple of 128, the row tile a divisor of the rows, the
+    contraction and the columns padded by nothing (1,856, no multiple of 128,
+    by 1,920 / 1,856), the bytes inside the bound, and OLMoE's the tile
+    measured at its shape (PR 26)."""
+    m, groups, k, n = _CELL_SHAPES[cell]
+    if matrix == "w2":
+        k, n = n, k
+    tiles = moe_kernels._gmm_tiles(m, k, n, groups)
+    tile = tiles[moe_kernels._CALLS.index(call)]
+    if call == "input_gradient":
+        k, n = n, k  # it contracts over the forward's columns
+    assert tile == moe_kernels._gmm_tile(m, k, n, groups, call)
+    tm, tk, tn = tile
+    assert tm % 128 == tk % 128 == tn % 128 == 0 and m % tm == 0
+    assert tk <= k and tn <= n
+    for size, t in ((k, tk), (n, tn)):
+        padded = moe_kernels._whole_tiles(size, t) / size
+        assert padded == 1.0 or (size == 1856 and padded <= 1.04), (size, t)
+    assert moe_kernels._tile_bytes(call, tm, tk, tn) <= moe_kernels._TILE_BYTES
+    if cell == "olmoe_s4096_1chip":
+        assert tile == (512, 1024, 1024)
+    if m // groups < 512:
+        assert tm == 128  # every group costs whole row tiles
+
+
+@pytest.mark.parametrize(
+    "m, k, n, groups",
+    [(1000, 2048, 1024, 8), (1280, 64, 1024, 8), (1280, 1, 1024, 8),
+     (1280, 1024, 96, 8), (1280, 2080, 1024, 8)],
+    ids=["rows_no_tiles", "contraction_64", "bias_column", "columns_96",
+         "contraction_2080"],
+)
+def test_grouped_matmul_tile_refuses_what_the_kernels_refuse(m, k, n, groups):
+    assert moe_kernels._gmm_tiles(m, k, n, groups) is None
+
+
+@pytest.mark.parametrize(
+    "k, n, tiles",
+    [
+        (256, 384, ((128, 256, 384), (128, 128, 256), (128, 128, 128))),
+        (384, 256, ((128, 128, 256), (128, 256, 128), (128, 384, 128))),
+        (256, 384, ((128, 256, 256), (128, 256, 256), (128, 256, 256))),
+    ],
+    ids=["256x384", "384x256", "256x384_partial_blocks"],
+)
+@pytest.mark.parametrize("rest", [0, 1], ids=["all_groups", "rest_group"])
+def test_grouped_matmul_vjp_with_a_tile_a_call(k, n, tiles, rest):
+    """`_gmm`, the `custom_vjp` over megablox's backend kernels, in interpret
+    mode against `lax.ragged_dot` under `jax.grad`: the value and the
+    cotangents of the rows and the matrices, with tiles that differ between
+    the forward, the input gradient and the weight gradient, over uneven
+    groups of which one is empty and none starts on a tile's boundary; with
+    a rest group (`group_offset` 0 into one size more than matrices) whose
+    rows come back zero and take no gradient. The third case's tiles end the
+    384-wide side in a partial block, which megablox masks or drops."""
+    m, groups = 384, 3
+    sizes = jnp.asarray([100, 0, 284 - 90 * rest] + [90] * rest, jnp.int32)
+    key = jax.random.PRNGKey(k + rest)
+    rows = jax.random.normal(key, (m, k), jnp.float32)
+    w = jax.random.normal(jax.random.fold_in(key, 1), (groups, k, n)) / k**0.5
+    cot = jax.random.normal(jax.random.fold_in(key, 2), (m, n), jnp.float32)
+    offset = jnp.zeros((), jnp.int32) if rest else None
+
+    def kernels(rows, w):
+        out = moe_kernels._gmm(rows, w, sizes, offset, tiles, True)
+        return jnp.sum(out * cot), out
+
+    def reference(rows, w):
+        # `lax.ragged_dot`, a matrix of zeros for the rest group
+        out = moe_kernels._grouped_matmul(rows, w, sizes, False)
+        return jnp.sum(out * cot), out
+
+    (_, got), got_grads = jax.value_and_grad(kernels, (0, 1), has_aux=True)(rows, w)
+    (_, want), want_grads = jax.value_and_grad(reference, (0, 1), has_aux=True)(rows, w)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
+    for g, r in zip(got_grads, want_grads):
+        np.testing.assert_allclose(g, r, rtol=1e-4, atol=1e-4)
+    if rest:
+        assert float(jnp.max(jnp.abs(got[-90:]))) == 0.0
+        assert float(jnp.max(jnp.abs(got_grads[0][-90:]))) == 0.0
+
+
+@pytest.mark.parametrize("held", [(4, 4), None], ids=["held_share", "all_rows"])
+def test_grouped_matmul_tiles_counter_names_what_a_lowered_node_took(
+    monkeypatch, held
+):
+    """`trace.grouped_matmul_tiles()` after an expert node is traced for the
+    kernels (the backend gate forced; nothing runs): under the node's scope,
+    for each of `w1`, `w3`, `w2` and each of the three calls, the call's own
+    shape (the input gradient contracts over the forward's columns), the
+    tile `_gmm_tiles` gives it and 1.0 padded over true. A node on
+    `ragged_dot` notes nothing."""
+    from flexflow_tpu.kernels import flash_attention as flash
+    from flexflow_tpu.observability import trace
+    from flexflow_tpu.op_attrs.activation import Activation
+
+    tokens, hidden, width, experts, select = 1024, 256, 384, 16, 2
+    attrs = ExpertsAttrs(
+        experts, select, width, activation=Activation.SILU,
+        capacity_factor=None, use_bias=False, gated=True, held_experts=held,
+    )
+    here = held[1] if held else experts
+    rows = moe_kernels.held_window_rows(tokens * select, here, experts)
+    x = jnp.zeros((tokens, hidden), jnp.bfloat16)
+    weights = [
+        jnp.zeros((hidden, experts), jnp.bfloat16),
+        jnp.zeros((here, hidden, width), jnp.bfloat16),
+        jnp.zeros((here, hidden, width), jnp.bfloat16),
+        jnp.zeros((here, width, hidden), jnp.bfloat16),
+    ]
+
+    def loss(x, weights):
+        return jnp.sum(experts_forward(attrs, x, weights)[0].astype(jnp.float32))
+
+    monkeypatch.setattr(trace, "_GROUPED_MATMUL_TILES", {})
+    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.on_xla", raising=False)
+    jax.make_jaxpr(loss)(x, weights)
+    assert trace.grouped_matmul_tiles() == {}
+
+    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.e1")
+    text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, weights))
+    noted = trace.grouped_matmul_tiles()
+    assert list(noted) == ["ff.experts.e1"]
+    entries = noted["ff.experts.e1"]
+    assert sorted(entries) == sorted(
+        f"{w}/{call}" for w in ("w1", "w2", "w3") for call in moe_kernels._CALLS
+    )
+    for name, (k, n) in {"w1": (hidden, width), "w3": (hidden, width),
+                         "w2": (width, hidden)}.items():
+        tiles = moe_kernels._gmm_tiles(rows, k, n, here)
+        shapes = [(rows, k, n), (rows, n, k), (rows, k, n)]
+        for call, shape, tile in zip(moe_kernels._CALLS, shapes, tiles):
+            assert entries[f"{name}/{call}"] == {
+                "shape": shape, "tile": tile, "padded_over_true": 1.0,
+            }
+    assert "pallas_call[" in text

@@ -38,6 +38,12 @@ recomputed in the backward: kept nowhere); and the causal core of 32 heads of
 tile kernels (the `[b, h, s, d]` rows kernels are refused at this length: 16 MB
 of scoped VMEM).
 
+And the held experts of both those cells (PR 48): ONE expert node
+(`kernels/moe.experts_forward`, 8 held of the router's width) forward and
+backward, which must compile and whose every `gmm` / `tgmm` block must tile
+its operand's sides whole: no 1,024-wide block on a 1,536 or a 2,304 side,
+which the kernels would run as two or three whole blocks.
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -418,6 +424,107 @@ def check_lfm2():
     return found
 
 
+EXPERTS_INVARIANTS = [
+    "lfm2_held_experts_compile_and_no_block_pads_a_side",
+    "kimi_held_experts_compile_and_no_block_pads_a_side",
+]
+# input, then `ExpertsAttrs`: router width, experts a token, expert width,
+# the shared expert's width; 8 experts held
+EXPERTS_SHAPES = {
+    "lfm2": (LFM2_SHAPE, 64, 4, 1536, 0),
+    "kimi": ((1, ROWS, KDA_HIDDEN), 256, 8, 1024, 1024),
+}
+_KERNEL_CALL = re.compile(
+    r'@tpu_custom_call\(.*?\\22body\\22: \\22([A-Za-z0-9+/=]+).*? : \((.*)\) -> (.*)'
+)
+
+
+def kernel_blocks(lowered_text):
+    """[[(array dims, block dims) of each blocked operand, then of the
+    result]] of every Pallas call of a lowered program: the `window_bounds`
+    its serialized Mosaic body states, beside the shapes the call is given."""
+    import base64
+
+    import jax._src.interpreters.mlir as jax_mlir
+    from jaxlib.mlir import ir
+
+    found = []
+    for body, operands, result in _KERNEL_CALL.findall(lowered_text):
+        ctx = jax_mlir.make_ir_context()
+        ctx.allow_unregistered_dialects = True
+        with ctx:
+            asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm()
+        blocks = [
+            tuple(int(d) for d in bounds.split(", "))
+            for bounds in re.findall(r"window_bounds = array<i64: ([0-9, ]+)>", asm)
+        ]
+        arrays = [
+            tuple(int(d) for d in dims.split("x")[:-1])
+            for dims in re.findall(r"tensor<([0-9x]+x\w+)>", operands + ", " + result)
+        ]
+        # the blocked arrays are the call's last: operands, then the result
+        found.append(list(zip(arrays[-len(blocks):], blocks)))
+    return found
+
+
+def check_experts():
+    """{invariant: "ok" or what was found} for one held-expert node of the
+    `lfm2_moe` and the `kimi_linear` cells, forward and backward."""
+    import jax
+
+    from flexflow_tpu.kernels import moe
+    from flexflow_tpu.op_attrs.activation import Activation
+    from flexflow_tpu.op_attrs.core import get_weight_shapes
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops import ExpertsAttrs
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    found = {}
+    on_chip = _described_chip()
+    for invariant, (shape, experts, select, width, shared) in zip(
+        EXPERTS_INVARIANTS, EXPERTS_SHAPES.values()
+    ):
+        try:
+            attrs = ExpertsAttrs(
+                experts, select, width, activation=Activation.SILU,
+                capacity_factor=None, use_bias=False, gated=True,
+                renormalize=True, scoring="sigmoid", selection_bias=True,
+                shared_hidden_size=shared, held_experts=(0, 8),
+            )
+            x = on_chip(shape)
+            weights = [
+                on_chip(w.dims)
+                for w in get_weight_shapes(attrs, [TensorShape(shape, DataType.FLOAT)])
+            ]
+
+            def node(x, weights, cot):
+                y, vjp = jax.vjp(
+                    lambda x, weights: moe.experts_forward(attrs, x, weights)[0],
+                    x, weights,
+                )
+                return y, vjp(cot)
+
+            lowered = jax.jit(node).lower(x, weights, x)
+            calls = kernel_blocks(lowered.as_text())
+            lowered.compile()
+            padded = [
+                f"{block} of {array}" for call in calls for array, block in call
+                if any(
+                    -(-size // b) * b > -(-size // 128) * 128
+                    for size, b in zip(array, block)
+                )
+            ]
+            # a weight gradient's result is the held matrices themselves
+            tgmm = [call for call in calls if len(call[-1][0]) == 3]
+            found[invariant] = (
+                "ok" if tgmm and len(calls) > len(tgmm) and not padded
+                else f"{len(calls)} kernels, {len(tgmm)} tgmm; " + ", ".join(padded)
+            )
+        except Exception as e:  # noqa: BLE001 - the complaint is the result
+            found[invariant] = f"{type(e).__name__}: {e}"[:2000]
+    return found
+
+
 def listing(name, least=4e6):
     """The node's ENTRY instructions that move `least` bytes or more."""
     text = compiled_kda_node() if name == "kimi" else compiled_node(name)[1]
@@ -478,6 +585,11 @@ def test_lfm2_node_and_core_compiled_for_the_described_chip(compiled, invariant)
     assert compiled["lfm2"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", EXPERTS_INVARIANTS)
+def test_held_experts_compiled_for_the_described_chip(compiled, invariant):
+    assert compiled["experts"][invariant] == "ok"
+
+
 if __name__ == "__main__":
     argv = sys.argv[1:]
     root = os.getcwd()
@@ -491,5 +603,5 @@ if __name__ == "__main__":
     else:
         print(json.dumps(
             dict({name: check(name) for name in SHAPES}, kimi=check_kimi(),
-                 lfm2=check_lfm2())
+                 lfm2=check_lfm2(), experts=check_experts())
         ))
