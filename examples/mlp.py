@@ -102,18 +102,16 @@ def main():
     params, opt_state, loss, _ = inst.train_step(params, opt_state, {"x": x}, y)
     force_sync(loss)
 
-    # --profile-trace-dir: the recorder's copy of the measured loop's host
-    # spans (step > dispatch) in Chrome-trace format; no span waits for the
-    # device, so the loop runs as it does untraced
+    # --profile-trace-dir: an XLA trace of the measured loop, its host spans
+    # (step > dispatch) on the host plane; no span waits for the device, so
+    # the loop runs as it does untraced
     import contextlib
 
-    span_ctx = contextlib.nullcontext()
+    trace_ctx = contextlib.nullcontext()
     if cfg.profile_trace_dir:
-        from flexflow_tpu.observability.trace import trace_session
+        trace_ctx = jax.profiler.trace(cfg.profile_trace_dir)
 
-        span_ctx = trace_session(cfg.profile_trace_dir)
-
-    with span_ctx:
+    with trace_ctx:
         start = time.perf_counter()
         for step in range(args.steps):
             step_t0 = (
@@ -141,8 +139,8 @@ def main():
             if cfg.print_freq and step % cfg.print_freq == 0:
                 print(f"step {step}: loss {float(loss):.4f}")
         force_sync(loss)
-        # timed INSIDE the session: trace_session's exit serializes the
-        # span JSON to disk, which must not count against throughput
+        # timed INSIDE the session: the profiler's exit writes the trace to
+        # disk, which must not count against throughput
         elapsed = time.perf_counter() - start
 
     num_samples = args.steps * cfg.batch_size

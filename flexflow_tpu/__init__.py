@@ -25,4 +25,28 @@ Layer map (mirrors SURVEY.md §1, re-architected for TPU):
   models      -- model zoo (transformer, bert, candle-uno, inception-v3, ...)
 """
 
+import os
+
 __version__ = "0.1.0"
+
+
+def _process_age_s(proc: str = "/proc"):
+    """Seconds since this process started, from its start time in
+    `<proc>/self/stat` (clock ticks after boot, 10 ms) against
+    `<proc>/uptime`; None where they cannot be read."""
+    try:
+        with open(os.path.join(proc, "self", "stat")) as f:
+            # the fields after the command's closing bracket start at the
+            # third; the start time is the 22nd
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open(os.path.join(proc, "uptime")) as f:
+            uptime = float(f.read().split()[0])
+    except (OSError, ValueError, IndexError):
+        return None
+    return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+
+
+# what the process spent before the program's first line: interpreter start,
+# the caller's imports (`import jax`), the backend's start-up where the caller
+# asked for `jax.devices()` first (`observability.trace.pre_program_s`)
+PROCESS_AGE_AT_IMPORT_S = _process_age_s()

@@ -2544,17 +2544,12 @@ class FFModel:
         # --profiling flag. The program's host spans (`fit` and what is
         # under it, observability/trace.py) are events of that trace's host
         # plane, on the device planes' clock, and no span waits for the
-        # device: the traced fit is the fit. The recorder's copy lands in
-        # the same directory as flexflow_trace.json.
+        # device: the traced fit is the fit.
         if self.config.profile_trace_dir:
-            from flexflow_tpu.observability.trace import trace_session
-
             trace_ctx = jax.profiler.trace(self.config.profile_trace_dir)
-            span_ctx = trace_session(self.config.profile_trace_dir)
         else:
             trace_ctx = contextlib.nullcontext()
-            span_ctx = contextlib.nullcontext()
-        with trace_ctx, span_ctx, record_span("fit"):
+        with trace_ctx, record_span("fit"):
             return self._fit_loop(x, y, epochs, batch_size, shuffle, verbose,
                                   recompile_state, epoch_offset,
                                   checkpoint_dir=checkpoint_dir,

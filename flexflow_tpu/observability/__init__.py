@@ -5,8 +5,11 @@ trace through the scopes `trace` puts on every operation.
 - `trace`       -- the device-trace scopes of every operation, and
                    `record_span`: the program's host spans as profiler
                    annotations on the device trace's clock, totalled in
-                   `span_totals()`; the recorder behind the watchdog's
-                   hang forensics and `flexflow_trace.json`.
+                   `span_totals()`, with each thread's open spans for
+                   the watchdog's hang forensics; set-up by owner:
+                   JAX's trace, lowering and compile seconds by
+                   function, the step's trace by node kind, the seconds
+                   before the program (`setup_report()`).
 - `search_phases` -- compile-time twin of `trace`: per-phase wall-clock
                    attribution of the Unity search (tree_build / dp /
                    leaf_cost / match), reported as `phase_ms` in search
@@ -29,10 +32,14 @@ from flexflow_tpu.observability.trace import (
     TraceRecorder,
     active_recorder,
     count,
+    lowering_by_function,
+    node_trace_seconds,
+    open_span_names,
+    pre_program_s,
     record_span,
     set_recorder,
+    setup_report,
     span_totals,
-    trace_session,
 )
 from flexflow_tpu.observability.search_phases import (
     collect_search_phases,
@@ -67,10 +74,14 @@ __all__ = [
     "TraceRecorder",
     "active_recorder",
     "count",
+    "lowering_by_function",
+    "node_trace_seconds",
+    "open_span_names",
+    "pre_program_s",
     "record_span",
     "set_recorder",
+    "setup_report",
     "span_totals",
-    "trace_session",
     "collect_search_phases",
     "search_phase",
     "EVENT_SCHEMA_VERSION",

@@ -13,7 +13,7 @@ with `parse_scope`, which lives here so that format and parser cannot drift.
 
 **Host spans** (`record_span`, `count`, `span_totals`): `record_span` is the
 one call the program makes around a piece of host work, and every call does
-three things.
+four things.
 
 - It enters a `jax.profiler.TraceAnnotation` (the `step` span a
   `StepTraceAnnotation` whose `step_num` counts the process's dispatches):
@@ -27,24 +27,38 @@ three things.
   total seconds, longest single span, on `perf_counter`. Kept in memory and
   read when a run ends: where set-up went (`compile/...`), what a stalled
   chunk stalled in (the longest `fit/next_batch` or `dispatch`).
-- Where a `TraceRecorder` is installed (`set_recorder`, `trace_session`) it
-  records the span there too, nested per thread. The recorder is what the
-  watchdog's hang forensics read (`open_span_names(tid)`: what a hung
-  thread was doing, `fit / step / dispatch`, `checkpoint/...`) and what
-  tests assert nesting on. `trace_session` still writes it as Chrome-trace
-  JSON (`flexflow_trace.json`) beside the XLA trace under
-  `--profile-trace-dir`, but **the xplane is the timeline to read**: it
-  holds the same spans laid over the device's operations.
+- It pushes its name on the thread's list of OPEN spans, which another
+  thread may read (`open_span_names(tid)`): what the watchdog's hang
+  forensics name, `fit / step / dispatch`, `checkpoint/...`, in every job.
+- Where a `TraceRecorder` is installed (`set_recorder`) it records the span
+  there too, nested per thread: the span tree tests assert nesting on. The
+  timeline to read is the xplane, which holds the same spans laid over the
+  device's operations.
 
 `HOST_SPANS` lists the names `compile` and `fit` emit, for the readers;
 `search/<name>` (the search's phases), `checkpoint...` and `<phase>/<layer>`
 (`--profiling`) come through the same call. `count(name)` adds an event with
 no duration to the same table: `step_trace`, bumped in the step functions'
-bodies, says how many times JAX traced the step in this process. The table
-also holds what `jax.monitoring` reports under `LOWERING_EVENTS`: the
-seconds JAX spent tracing Python to jaxprs and lowering jaxprs to MLIR, the
-part of set-up that is this program's own Python (the interpreter over the
-graph, the Pallas bodies).
+bodies, says how many times JAX traced the step in this process.
+
+**Set-up by owner** (`lowering_by_function`, `node_trace_seconds`,
+`pre_program_s`, `setup_report`). `jax.monitoring` reports the start and the
+end of every trace of Python to a jaxpr, every lowering of a jaxpr to MLIR
+and every backend compile (or cache load), with the function's name. A
+nested `jax.jit` (every `jnp` function is one) reports its own trace inside
+its caller's, so the plain sums `span_totals()` keeps under
+`LOWERING_EVENTS` count those seconds twice. The listeners here keep the
+events open on each thread as a stack and file every event, as it ends,
+under its function: inclusive, exclusive (less what was nested in it) and
+top-level seconds (where nothing on its thread was open around it).
+Top-level events of one thread do not overlap, so their sum is wall time.
+`count(STEP_TRACE)` marks the outermost event open on its thread: that trace
+is a trace of the program's step, and the `jit(<name>)` lowering and compile
+that follow it on the thread are the step's; everything else is somebody
+else's (a caller's reference, the eager initialisers), under its own name.
+`node_scope` and `step_scope` time themselves on the host inside such a
+trace: the step's trace seconds by node kind. Memory is a row a function
+name and a row a node kind; nothing here runs unless JAX traces or compiles.
 
 The jitted train step is ONE XLA program, so of a step the host sees only
 `dispatch` (the enqueue of the donated program). No span waits for the
@@ -56,8 +70,6 @@ inside) the fit thread's `fit/next_batch` and `step` spans.
 from __future__ import annotations
 
 import contextlib
-import json
-import os
 import re
 import threading
 import time
@@ -66,6 +78,7 @@ from typing import Dict, List, Optional, Tuple
 
 import jax
 
+import flexflow_tpu
 from flexflow_tpu.op_attrs.core import PARALLEL_OP_TYPES, op_type_of
 
 
@@ -83,7 +96,7 @@ class TraceSpan:
 
 
 class TraceRecorder:
-    """Collects spans/instants; thread-safe; exports Chrome-trace JSON."""
+    """Collects spans as a tree, nested per thread; thread-safe."""
 
     def __init__(self, clock=time.perf_counter) -> None:
         self._clock = clock
@@ -91,11 +104,6 @@ class TraceRecorder:
         self._lock = threading.Lock()
         self._tls = threading.local()
         self.spans: List[TraceSpan] = []
-        self.instants: List[Dict[str, object]] = []
-        # per-thread stacks of OPEN span indices, readable from OTHER
-        # threads (the TLS stack above is not): the watchdog's
-        # HangDiagnostic reads the hung thread's live span stack here
-        self._open: Dict[int, List[int]] = {}
 
     # -- recording ---------------------------------------------------------
 
@@ -127,7 +135,6 @@ class TraceRecorder:
                     args=dict(args),
                 )
             )
-            self._open.setdefault(tid, []).append(idx)
         stack.append(idx)
         try:
             yield self
@@ -136,81 +143,15 @@ class TraceRecorder:
             stack.pop()
             with self._lock:
                 self.spans[idx].dur_ms = end - start
-                open_stack = self._open.get(tid)
-                if open_stack and open_stack[-1] == idx:
-                    open_stack.pop()
-                elif open_stack and idx in open_stack:
-                    open_stack.remove(idx)
-
-    def instant(self, name: str, **args) -> None:
-        with self._lock:
-            self.instants.append(
-                {
-                    "name": name,
-                    "ts_ms": self._now_ms(),
-                    "tid": threading.get_ident(),
-                    "args": dict(args),
-                }
-            )
 
     # -- queries (the test surface) ----------------------------------------
 
     def spans_named(self, name: str) -> List[TraceSpan]:
         return [s for s in self.spans if s.name == name]
 
-    def open_span_names(self, tid: int) -> List[str]:
-        """The names of thread `tid`'s currently-OPEN spans, outermost
-        first — what that thread is doing RIGHT NOW, readable from any
-        thread (the watchdog's hang forensics)."""
-        with self._lock:
-            return [self.spans[i].name for i in self._open.get(tid, [])]
-
     def children_of(self, span: TraceSpan) -> List[TraceSpan]:
         idx = self.spans.index(span)
         return [s for s in self.spans if s.parent == idx]
-
-    # -- export ------------------------------------------------------------
-
-    def to_chrome_trace(self) -> dict:
-        """The `chrome://tracing` JSON object format. Timestamps in µs."""
-        pid = os.getpid()
-        events = []
-        for s in self.spans:
-            events.append(
-                {
-                    "name": s.name,
-                    "ph": "X",
-                    "ts": round(s.start_ms * 1000.0, 3),
-                    "dur": round(s.dur_ms * 1000.0, 3),
-                    "pid": pid,
-                    "tid": s.tid,
-                    "args": s.args,
-                }
-            )
-        for i in self.instants:
-            events.append(
-                {
-                    "name": i["name"],
-                    "ph": "i",
-                    "s": "t",
-                    "ts": round(i["ts_ms"] * 1000.0, 3),
-                    "pid": pid,
-                    "tid": i["tid"],
-                    "args": i["args"],
-                }
-            )
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-    def save(self, path: str) -> str:
-        """Write the Chrome trace to `path` (a directory gets a default
-        file name). Returns the file path written."""
-        if os.path.isdir(path) or not path.endswith(".json"):
-            os.makedirs(path, exist_ok=True)
-            path = os.path.join(path, "flexflow_trace.json")
-        os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
-        with open(path, "w") as f:
-            json.dump(self.to_chrome_trace(), f)
-        return path
 
 
 # -- module-level active recorder ----------------------------------------
@@ -252,14 +193,35 @@ HOST_SPANS = (
 # events with no duration, counted by `count`
 STEP_TRACE = "step_trace"
 # `jax.monitoring` durations kept in the same table: what tracing the
-# program's Python and lowering its jaxprs cost this process
+# program's Python and lowering its jaxprs cost this process, every nested
+# `jax.jit` counted again in its caller (`lowering_by_function` counts once)
 LOWERING_EVENTS = (
     "/jax/core/compile/jaxpr_trace_duration",
     "/jax/core/compile/jaxpr_to_mlir_module_duration",
 )
+# the three stages of a compile as `jax.monitoring` names them, and the names
+# `lowering_by_function()` files them under
+COMPILE_STAGES = {
+    LOWERING_EVENTS[0]: "trace",
+    LOWERING_EVENTS[1]: "to_mlir",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
 
 _TOTALS: Dict[str, List[float]] = {}  # name -> [count, total s, longest s]
 _TOTALS_LOCK = threading.Lock()
+# fun_name -> stage -> [count, inclusive s, exclusive s, top-level s, the
+# step's count, the step's s]
+_LOWERING: Dict[str, Dict[str, List[float]]] = {}
+_LOWERING_KEYS = (
+    "count", "inclusive_s", "exclusive_s", "top_level_s", "step_count", "step_s",
+)
+_NODE_TRACE: Dict[str, List[float]] = {}  # kind or step part -> [calls, s]
+# what is open on each thread: `.stages`, the compile-stage events as
+# [event, start, nested s, is the step's]; `.step_program`, the `jit(<name>)`
+# of the step traced last; `.spans`, the list `_OPEN_SPANS` holds for it
+_thread = threading.local()
+# thread ident -> names of its open `record_span`s, outermost first
+_OPEN_SPANS: Dict[int, List[str]] = {}
 
 
 def _add(name: str, seconds: float) -> None:
@@ -275,8 +237,14 @@ def _add(name: str, seconds: float) -> None:
 
 
 def count(name: str) -> None:
-    """One more of an event with no duration (`STEP_TRACE`)."""
+    """One more of an event with no duration. `STEP_TRACE`, counted in a
+    step function's body, also marks the outermost compile-stage event open
+    on this thread: JAX is tracing the program's step there."""
     _add(name, 0.0)
+    if name == STEP_TRACE:
+        stages = getattr(_thread, "stages", None)
+        if stages:
+            stages[0][3] = True
 
 
 def span_totals() -> Dict[str, Dict[str, float]]:
@@ -290,25 +258,246 @@ def span_totals() -> Dict[str, Dict[str, float]]:
 
 
 def reset_span_totals() -> None:
+    """Empty `span_totals()`, `lowering_by_function()` and
+    `node_trace_seconds()`; what is open stays open."""
     with _TOTALS_LOCK:
         _TOTALS.clear()
+        _LOWERING.clear()
+        _NODE_TRACE.clear()
 
 
-def _on_duration(event: str, duration: float, **_) -> None:
+# -- set-up by owner ------------------------------------------------------------
+
+
+def _on_stage_start(event: str, value: float, **_) -> None:
+    if event in COMPILE_STAGES:
+        stages = getattr(_thread, "stages", None)
+        if stages is None:
+            stages = _thread.stages = []
+        stages.append([event, value, 0.0, False])
+
+
+def _on_stage_end(event: str, start: float, end: float, fun_name="", **_) -> None:
+    stage = COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    seconds = end - start
     if event in LOWERING_EVENTS:
-        _add(event, duration)
+        _add(event, seconds)
+    # children end before their parents, on the same thread: the event's own
+    # entry is the innermost open one (what lies above it was left by an
+    # event that never reported its end, and goes with it)
+    stages = getattr(_thread, "stages", None) or []
+    nested, of_step = 0.0, False
+    for i in range(len(stages) - 1, -1, -1):
+        if stages[i][0] == event and stages[i][1] == start:
+            nested, of_step = stages[i][2:]
+            del stages[i:]
+            break
+    top_level = not stages
+    if stages:
+        stages[-1][2] += seconds
+    elif stage == "trace":
+        # the lowerings and compiles that follow a trace of the step on its
+        # thread under its name are the step's (a later call that finds the
+        # trace in JAX's cache lowers under that name with no stamp)
+        if of_step:
+            _thread.step_program = f"jit({fun_name})"
+    else:
+        of_step = fun_name == getattr(_thread, "step_program", None)
+    with _TOTALS_LOCK:
+        row = _LOWERING.setdefault(fun_name, {}).setdefault(
+            stage, [0, 0.0, 0.0, 0.0, 0, 0.0]
+        )
+        row[0] += 1
+        row[1] += seconds
+        row[2] += seconds - nested
+        if top_level:
+            row[3] += seconds
+            if of_step:
+                row[4] += 1
+                row[5] += seconds
 
 
-jax.monitoring.register_event_duration_secs_listener(_on_duration)
+jax.monitoring.register_scalar_listener(_on_stage_start)
+jax.monitoring.register_event_time_span_listener(_on_stage_end)
+
+
+def lowering_by_function() -> Dict[str, Dict[str, Dict[str, float]]]:
+    """`{fun_name: {stage: {"count", "inclusive_s", "exclusive_s",
+    "top_level_s", "step_count", "step_s"}}}` of every trace (`trace`),
+    lowering to MLIR (`to_mlir`) and backend compile or cache load
+    (`compile`) JAX has finished in this process, under the name JAX gives
+    the function (`_step`, then `jit(_step)` once it is a module).
+    `exclusive_s` is the events' seconds less those of the events nested in
+    them, `top_level_s` their seconds where nothing on their thread was open
+    around them: summed over a thread's rows that is wall time, each second
+    once. `step_count` and `step_s` are the part of the top-level events that
+    belongs to the program's step: a trace in which `count(STEP_TRACE)` ran,
+    and the lowering and compile that followed it on its thread under its
+    name."""
+    with _TOTALS_LOCK:
+        return {
+            name: {
+                stage: dict(zip(_LOWERING_KEYS, row))
+                for stage, row in stages.items()
+            }
+            for name, stages in _LOWERING.items()
+        }
+
+
+def _in_step_trace() -> bool:
+    stages = getattr(_thread, "stages", None)
+    return bool(stages) and stages[0][3]
+
+
+@contextlib.contextmanager
+def _traced_scope(scope: str, kind: str):
+    """`jax.named_scope(scope)`, timed on the host into `node_trace_seconds()`
+    under `kind` where JAX is tracing the step."""
+    t0 = time.perf_counter()
+    try:
+        with jax.named_scope(scope):
+            yield
+    finally:
+        if _in_step_trace():
+            seconds = time.perf_counter() - t0
+            with _TOTALS_LOCK:
+                row = _NODE_TRACE.setdefault(kind, [0, 0.0])
+                row[0] += 1
+                row[1] += seconds
+
+
+def node_trace_seconds() -> Dict[str, Dict[str, float]]:
+    """`{kind: {"calls", "seconds"}}`: the host seconds JAX's traces of the
+    step spent inside `node_scope`, by the node's kind as `scope_kind`
+    writes it (`dense`, `mha`, `experts`, ...: the interpreter's Python for
+    the node, its kernels' bodies, the `jnp` calls under it), and inside
+    `step_scope`, by part (the names of `STEP_SCOPES`). The step's top-level
+    trace seconds less all of these is the backward pass and the glue: JAX
+    runs the `custom_vjp` rules and transposes outside any `with` of ours."""
+    with _TOTALS_LOCK:
+        return {
+            kind: {"calls": row[0], "seconds": row[1]}
+            for kind, row in _NODE_TRACE.items()
+        }
+
+
+def pre_program_s() -> Optional[float]:
+    """The process's age at the first import of `flexflow_tpu`: interpreter
+    start, `import jax`, and the backend's start-up where the caller asked
+    for `jax.devices()` first. None where there is no `/proc`."""
+    return flexflow_tpu.PROCESS_AGE_AT_IMPORT_S
+
+
+def _table(header: Tuple[str, ...], rows: List[tuple]) -> List[str]:
+    """Lines of a table: the first column left, the others right."""
+    cells = [header] + [
+        tuple(f"{c:.3f}" if isinstance(c, float) else str(c) for c in row)
+        for row in rows
+    ]
+    widths = [max(len(row[i]) for row in cells) for i in range(len(header))]
+    return [
+        ("  " + row[0].ljust(widths[0]) + "".join(
+            "  " + c.rjust(w) for c, w in zip(row[1:], widths[1:])
+        )).rstrip()
+        for row in cells
+    ]
+
+
+def setup_report(top: int = 12) -> str:
+    """What an operator prints after `compile()` or the first step to see
+    where set-up went, as text in five parts: the seconds before the
+    program, the `compile/*` spans, JAX's trace, lowering and compile
+    seconds by function (the `top` largest by top-level seconds, the step's
+    marked `*`), the step's trace by node kind, and the double count (what
+    the `LOWERING_EVENTS` rows of `span_totals()` hold over the seconds
+    counted once)."""
+    totals, table, nodes = span_totals(), lowering_by_function(), node_trace_seconds()
+    age = pre_program_s()
+    lines = [
+        "before the program (process start to the first import of "
+        "flexflow_tpu): "
+        + ("no /proc to read it from" if age is None else f"{age:.2f} s"),
+        "compile spans:",
+    ]
+    lines += _table(
+        ("span", "count", "total s", "longest s"),
+        [
+            (name, row["count"], row["total_s"], row["longest_s"])
+            for name, row in totals.items() if name.startswith("compile")
+        ],
+    )
+    rows = sorted(
+        (
+            (name, stage, row) for name, stages in table.items()
+            for stage, row in stages.items()
+        ),
+        key=lambda r: -r[2]["top_level_s"],
+    )
+    once = {stage: 0.0 for stage in COMPILE_STAGES.values()}
+    step = dict(once)
+    for _, stage, row in rows:
+        once[stage] += row["top_level_s"]
+        step[stage] += row["step_s"]
+    lines.append(
+        f"lowering by function, each second once (top-level events; the {top} "
+        f"largest of {len(rows)} rows; * the program's step):"
+    )
+    lines += _table(
+        ("function", "stage", "count", "top-level s", "exclusive s", "inclusive s"),
+        [
+            (("* " if row["step_s"] else "  ") + name, stage, row["count"],
+             row["top_level_s"], row["exclusive_s"], row["inclusive_s"])
+            for name, stage, row in rows[:top]
+        ] + [
+            ("  every function", stage, "", once[stage], "", "")
+            for stage in once
+        ] + [
+            ("* the step's", stage, "", step[stage], "", "") for stage in step
+        ],
+    )
+    step_trace_s = step["trace"]
+    scoped = sum(row["seconds"] for row in nodes.values())
+    lines.append(
+        f"the step's trace by node kind ({step_trace_s:.3f} s in "
+        f"{totals.get(STEP_TRACE, {'count': 0})['count']} trace(s)):"
+    )
+    lines += _table(
+        ("kind", "calls", "seconds"),
+        sorted(
+            ((kind, row["calls"], row["seconds"]) for kind, row in nodes.items()),
+            key=lambda r: -r[2],
+        ) + [("backward+glue", "", step_trace_s - scoped)],
+    )
+    naive = sum(totals.get(e, {"total_s": 0.0})["total_s"] for e in LOWERING_EVENTS)
+    counted_once = once["trace"] + once["to_mlir"]
+    lines.append(
+        f"double count: the LOWERING_EVENTS rows sum {naive:.3f} s, counted "
+        f"once {counted_once:.3f} s: {naive - counted_once:.3f} s of nested jits "
+        "counted again in their callers"
+    )
+    return "\n".join(lines)
+
+
+# -- host spans, the call -------------------------------------------------------
+
+
+def open_span_names(tid: int) -> List[str]:
+    """The names of thread `tid`'s OPEN `record_span`s, outermost first:
+    what that thread is doing right now, readable from any thread (the
+    watchdog's hang forensics), recorder or none."""
+    return list(_OPEN_SPANS.get(tid, ()))
 
 
 class record_span:
     """`with record_span(name, **args):` is how the program marks a piece of
     host work (module docstring): a profiler annotation, a row of
-    `span_totals()`, and a span of the active recorder where there is one
-    (which the `with` then binds, else None)."""
+    `span_totals()`, a name on the thread's open spans, and a span of the
+    active recorder where there is one (which the `with` then binds, else
+    None)."""
 
-    __slots__ = ("name", "args", "_annotation", "_recorded", "_t0")
+    __slots__ = ("name", "args", "_annotation", "_recorded", "_open", "_t0")
 
     def __init__(self, name: str, **args) -> None:
         self.name = name
@@ -330,11 +519,19 @@ class record_span:
         if rec is not None:
             self._recorded = rec.span(self.name, **self.args)
             self._recorded.__enter__()
+        try:
+            self._open = _thread.spans
+        except AttributeError:
+            self._open = _thread.spans = _OPEN_SPANS.setdefault(
+                threading.get_ident(), []
+            )
+        self._open.append(self.name)
         self._t0 = time.perf_counter()
         return rec
 
     def __exit__(self, *exc):
         seconds = time.perf_counter() - self._t0
+        self._open.pop()
         if self._recorded is not None:
             self._recorded.__exit__(*exc)
         self._annotation.__exit__(*exc)
@@ -436,12 +633,13 @@ _GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
 
 @contextlib.contextmanager
 def node_scope(graph, n):
-    """The `jax.named_scope` everything lowered for node `n` goes under."""
+    """The `jax.named_scope` everything lowered for node `n` goes under;
+    inside a trace of the step, a row of `node_trace_seconds()`."""
     name = scope_name(graph, n)
     previous = getattr(_lowering, "scope", None)
     _lowering.scope = name
     try:
-        with jax.named_scope(name):
+        with _traced_scope(name, name.split(".")[1]):
             yield
     finally:
         _lowering.scope = previous
@@ -487,9 +685,9 @@ def grouped_matmul_tiles() -> Dict[str, Dict[str, dict]]:
 
 def step_scope(part: str):
     """`jax.named_scope("ff.<part>")` for a part of the step that is no
-    node: one of `STEP_SCOPES`."""
+    node: one of `STEP_SCOPES`; timed as `node_scope` is."""
     assert part in STEP_SCOPES, part
-    return jax.named_scope("ff." + part)
+    return _traced_scope("ff." + part, part)
 
 
 def parse_scope(op_name: str) -> Tuple[str, str, str]:
@@ -515,20 +713,3 @@ def parse_scope(op_name: str) -> Tuple[str, str, str]:
     if part:
         name = f"{name}/{part.group(1)}"
     return ("bwd" if backward else "fwd"), kind, name or ""
-
-
-@contextlib.contextmanager
-def trace_session(trace_dir: str, label: str = "flexflow_trace"):
-    """Install a fresh recorder for the body and write
-    `<trace_dir>/<label>.json` (Chrome-trace format) on exit. Used by
-    FFModel.fit when `--profile-trace-dir` is set, beside the xplane
-    jax.profiler writes into the same directory, which holds the same spans
-    over the device's operations."""
-    rec = TraceRecorder()
-    prev = set_recorder(rec)
-    try:
-        yield rec
-    finally:
-        set_recorder(prev)
-        os.makedirs(trace_dir, exist_ok=True)
-        rec.save(os.path.join(trace_dir, f"{label}.json"))

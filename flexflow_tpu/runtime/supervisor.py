@@ -308,10 +308,9 @@ class WindowWatchdog:
 
     def _live_spans(self, tid: int) -> List[str]:
         try:
-            from flexflow_tpu.observability.trace import active_recorder
+            from flexflow_tpu.observability.trace import open_span_names
 
-            rec = active_recorder()
-            return [] if rec is None else rec.open_span_names(tid)
+            return open_span_names(tid)
         except Exception:
             return []  # diagnostics must never mask the hang itself
 

@@ -210,7 +210,7 @@ class TestSpanTotals:
         trace.reset_span_totals()
         with pytest.raises(RuntimeError, match="no supervision"):
             m.fit(*_data(), epochs=1, verbose=False)
-        assert recorder.open_span_names(threading.get_ident()) == []
+        assert trace.open_span_names(threading.get_ident()) == []
         counts = {k: v["count"] for k, v in trace.span_totals().items()}
         assert counts["fit"] == counts["fit/begin"] == 1
         assert "fit/end" not in counts

@@ -18,7 +18,6 @@ from flexflow_tpu.observability import (
     active_recorder,
     record_span,
     set_recorder,
-    trace_session,
 )
 from flexflow_tpu.pcg import ComputationGraphBuilder
 
@@ -107,58 +106,6 @@ class TestTraceRecorder:
             set_recorder(prev)
         (x,) = rec.spans_named("x")
         assert x.args == {"tag": 1}
-
-    def test_chrome_trace_export(self, tmp_path):
-        rec = TraceRecorder()
-        with rec.span("step", backend="test"):
-            pass
-        rec.instant("marker", n=3)
-        path = rec.save(str(tmp_path))
-        with open(path) as f:
-            doc = json.load(f)
-        events = doc["traceEvents"]
-        phases = {e["name"]: e["ph"] for e in events}
-        assert phases == {"step": "X", "marker": "i"}
-        step = next(e for e in events if e["name"] == "step")
-        assert step["args"] == {"backend": "test"}
-        assert step["dur"] >= 0  # microseconds
-
-    def test_trace_session_installs_and_writes(self, tmp_path):
-        with trace_session(str(tmp_path), label="t") as rec:
-            assert active_recorder() is rec
-            with record_span("inside"):
-                pass
-        assert active_recorder() is None
-        with open(tmp_path / "t.json") as f:
-            doc = json.load(f)
-        assert any(e["name"] == "inside" for e in doc["traceEvents"])
-
-    def test_trace_session_writes_on_failure(self, tmp_path):
-        # ISSUE 3 satellite: crash traces are the ones that matter — the
-        # `finally` path must still serialize the spans recorded before
-        # the traced block raised, and must restore the previous recorder
-        with pytest.raises(RuntimeError, match="boom"):
-            with trace_session(str(tmp_path), label="crash"):
-                with record_span("before_crash"):
-                    pass
-                raise RuntimeError("boom")
-        assert active_recorder() is None
-        with open(tmp_path / "crash.json") as f:
-            doc = json.load(f)
-        assert any(
-            e["name"] == "before_crash" for e in doc["traceEvents"]
-        )
-
-    def test_trace_session_writes_open_spans_on_failure(self, tmp_path):
-        # raising INSIDE a span: the span is recorded (its slot is reserved
-        # at entry) so the crash trace still shows where execution died
-        with pytest.raises(ValueError):
-            with trace_session(str(tmp_path), label="mid") as rec:
-                with rec.span("dying"):
-                    raise ValueError("x")
-        with open(tmp_path / "mid.json") as f:
-            doc = json.load(f)
-        assert any(e["name"] == "dying" for e in doc["traceEvents"])
 
 
 class TestStepInstrumentation:

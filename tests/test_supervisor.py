@@ -191,14 +191,16 @@ class TestWindowWatchdog:
         finally:
             w.close()
 
-    def test_open_trace_spans_in_diagnostic(self):
+    def test_open_spans_in_diagnostic_without_a_recorder(self):
+        # a normal job installs no recorder: the diagnostic still names what
+        # the hung thread was doing, from `record_span`'s own open list
         from flexflow_tpu.observability.trace import (
-            TraceRecorder,
-            set_recorder,
+            active_recorder,
+            open_span_names,
+            record_span,
         )
 
-        rec = TraceRecorder()
-        prev = set_recorder(rec)
+        assert active_recorder() is None
         fired = []
         w = WindowWatchdog(
             1.0, min_budget_ms=20.0, poll_interval_s=0.005,
@@ -208,28 +210,42 @@ class TestWindowWatchdog:
             w.begin_window(1, 1)
             w.end_window(1)
             with pytest.raises(WindowHangError):
-                with rec.span("step"):
-                    with rec.span("dispatch"):
+                with record_span("fit"), record_span("step"):
+                    with record_span("dispatch"):
                         w.begin_window(2, 1)
                         deadline = time.time() + 5.0
                         while time.time() < deadline:
                             time.sleep(0.01)
-            assert fired and fired[0].trace_spans == ["step", "dispatch"]
+            assert fired and fired[0].trace_spans == ["fit", "step", "dispatch"]
+            # the exception left through three spans: the stack is clean
+            assert open_span_names(threading.get_ident()) == []
         finally:
             w.close()
-            set_recorder(prev)
 
     def test_open_span_names_cross_thread(self):
-        from flexflow_tpu.observability.trace import TraceRecorder
+        from flexflow_tpu.observability.trace import (
+            open_span_names,
+            record_span,
+        )
 
-        rec = TraceRecorder()
-        tid = threading.get_ident()
-        assert rec.open_span_names(tid) == []
-        with rec.span("outer"):
-            with rec.span("inner"):
-                assert rec.open_span_names(tid) == ["outer", "inner"]
-            assert rec.open_span_names(tid) == ["outer"]
-        assert rec.open_span_names(tid) == []
+        inside, release = threading.Event(), threading.Event()
+
+        def work():
+            with record_span("outer"), record_span("inner"):
+                inside.set()
+                release.wait(5.0)
+
+        worker = threading.Thread(target=work)
+        worker.start()
+        try:
+            assert inside.wait(5.0)
+            assert open_span_names(worker.ident) == ["outer", "inner"]
+            assert open_span_names(threading.get_ident()) == []
+        finally:
+            release.set()
+            worker.join(5.0)
+        assert not worker.is_alive()
+        assert open_span_names(worker.ident) == []
 
 
 class TestFaultSchedule:
