@@ -31,7 +31,10 @@ A call that has a SHARE of the experts' matrices (an op that holds one,
 without the exchange; or one expert-parallel shard inside a `shard_map`,
 `expert_shard`) routes over all of them and takes `_held_rows_forward`
 instead: it touches only the rows routed to its experts, a window of them at
-a time, so its cost follows the rows that do work and not N*k.
+a time, so its cost follows the rows that do work and not N*k. A window's
+rows go back to their tokens as a sum over each token's own rows
+(`held_rows_sum`, a Pallas kernel of row copies) where the rows are bf16 on
+the chip, as XLA's scatter-add elsewhere.
 
 Where the experts live in a latent space (`ExpertsAttrs.latent_size`) the
 rows that are sorted, gathered, multiplied and combined are the tokens'
@@ -57,6 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from flexflow_tpu.op_attrs.ops.moe import (
     AggregateAttrs,
@@ -332,6 +337,27 @@ def _note_tiles(matrices, m: int, pallas: bool) -> None:
         trace.note_grouped_matmul_tiles(entries)
 
 
+def _note_held_sums(pallas: bool, n: int, k: int, window: int, dtype, sites):
+    """The forms (`_held_sum_form`) of a held share's two sums of a window's
+    rows over their tokens, the forward's output and the backward's gradient
+    of x2, in that order, told to the trace as well
+    (`observability/trace.held_row_sums`) for the reason `_note_tiles` has.
+    `dtype`: the rows'; `sites`: name -> (row width, the sum's dtype)."""
+    from flexflow_tpu.observability import trace
+
+    entries = {}
+    for name, (width, sum_dtype) in sites.items():
+        form = _held_sum_form(pallas, n, k, width, dtype)
+        entries[name] = {
+            "form": form, "window_rows": window, "width": width,
+            "dtype": jnp.dtype(dtype).name,
+            "sum_dtype": jnp.dtype(sum_dtype).name,
+            "token_tile": _held_sum_tile(n, k, width) if form == "pallas" else None,
+        }
+    trace.note_held_row_sums(entries)
+    return tuple(entry["form"] for entry in entries.values())
+
+
 # XLA's TPU gather and scatter move one element at a time: 8.8-10.2 ns each
 # on a v5e whatever the element (a float of `[4096, 512]`, a one into 9
 # bins; PERF.md section 5). Comparing an index with an iota and reducing is
@@ -558,6 +584,280 @@ def _add_shared_expert(attrs: ExpertsAttrs, out, x, shared):
         return out + (hs @ shared[-1].astype(x2.dtype)).astype(jnp.float32)
 
 
+# -- the held rows back to their tokens: a sum, not a scatter-add ------------
+#
+# XLA's TPU scatter-add takes a window's rows one after another, because two
+# of them may land on one token: 217 ns a row on a v5e against 22 for the
+# gather of as many (PERF.md section 5). `held_rows_sum` turns the movement
+# round: the window's rows are listed in TOKEN order (one sort of `window`
+# decisions with their window row as payload, `_token_order`: the inverse of
+# the window's part of the share's sort), a program takes a tile of tokens,
+# and every row of the tile is one DMA from HBM into a VMEM scratch, all of
+# a tile's copies in flight together. No token has two writers and nothing
+# is read that is not held, so the scalar work follows the held rows and not
+# the N k decisions.
+#
+# Mosaic copies whole (8, 128) tiles, and a row of a `[window, width]` array
+# is an eighth (bf16: a sixteenth) of each of its tiles. `held_rows_lanes`
+# therefore writes the rows once more with a row's 128-lane groups as
+# SUBLANES, so that a row is tiles of its own that lie together in HBM, and
+# packs two bf16 columns into one 32-bit word on the way (a row's first half
+# of the groups in the low halves, the second in the high): half the bytes
+# of the float32 rows XLA's scatter-add was given, in HBM and on the wire.
+# A bf16 is the upper half of its float32, so the sum takes a word apart
+# with a shift and a mask and adds in float32.
+
+# Bytes of VMEM the sum's slots may take: one a decision of a token tile, so
+# that a tile whose every decision is held fits whatever the router does.
+# The accumulator and the out block's two buffers come on top; the limit
+# handed to the compiler is the sum with a quarter to spare.
+_HELD_SUM_SLOT_BYTES = 4 * 2**20
+# Entries a trip of the sum's three loops over a tile's rows (copy, wait, add).
+_HELD_SUM_UNROLL = 8
+
+
+def _word_groups(width: int):
+    """(pairs, sublanes): the 128-lane groups of a row `width` wide come in
+    `pairs` pairs (group p with group p + pairs, the last alone where they
+    are odd), a 128-word sublane each, a row in `sublanes` whole 8-sublane
+    tiles of them."""
+    pairs = -(-width // 256)
+    return pairs, _whole_tiles(pairs, 8)
+
+
+def _held_sum_tile(n: int, k: int, width: int):
+    """Tokens a program of `held_rows_sum` for `n` tokens of `k` decisions
+    and rows `width` wide: the largest power of two from 512 down to 16 that
+    divides n and whose `k` slots a token fit `_HELD_SUM_SLOT_BYTES`; None
+    where there is none, or the width is not whole 128-lane tiles."""
+    if width % 128:
+        return None
+    slot = 4 * 128 * _word_groups(width)[1]
+    return next(
+        (
+            tile for tile in (512, 256, 128, 64, 32, 16)
+            if n % tile == 0 and k * tile * slot <= _HELD_SUM_SLOT_BYTES
+        ),
+        None,
+    )
+
+
+def _held_sum_form(pallas: bool, n: int, k: int, width: int, dtype) -> str:
+    """Which form the sum of a window's rows over their tokens takes:
+    "pallas" (`held_rows_sum`) where `_pallas_allowed`, the rows are bf16
+    and the shape has a tile, "xla" (the scatter-add) elsewhere: the CPU
+    mesh, a global-view SPMD trace, float32 compute, an odd width."""
+    return (
+        "pallas"
+        if pallas and dtype == jnp.bfloat16 and _held_sum_tile(n, k, width)
+        else "xla"
+    )
+
+
+def _token_order(decision, valid, decisions: int):
+    """A window's rows in token order: (`decision` [window] sorted ascending
+    with the masked rows' last, as `decisions`; the window row of each
+    [window]). A decision is `token * k + j`, so a token's rows lie together
+    with j ascending."""
+    window = decision.shape[0]
+    return lax.sort(
+        (
+            jnp.where(valid, decision, decisions),
+            jnp.arange(window, dtype=jnp.int32),
+        ),
+        num_keys=1,
+    )
+
+
+def _held_rows_lanes_kernel(rows_ref, out_ref):
+    """rows_ref [tm, width] bf16 -> out_ref [tm * sublanes, 128] uint32: row
+    r's p-th word sublane at `r * sublanes + p`, group p's bf16 bits in the
+    low halves and group p + pairs' in the high."""
+    tm, width = rows_ref.shape
+    groups = width // 128
+    pairs, sublanes = _word_groups(width)
+
+    def bits(g):  # a bf16 is its float32's upper half
+        part = rows_ref[:, g * 128:(g + 1) * 128].astype(jnp.float32)
+        return pltpu.bitcast(part, jnp.uint32)
+
+    for p in range(pairs):
+        words = bits(p) >> 16
+        if p + pairs < groups:
+            words = words | bits(p + pairs)
+        out_ref[pl.ds(p, tm, stride=sublanes), :] = words
+
+
+@functools.partial(jax.jit, static_argnums=(1,))
+def held_rows_lanes(rows, interpret: bool = False):
+    """rows [window, width] bf16 as uint32 [window * sublanes, 128]
+    (`_word_groups`): a row is `sublanes` sublanes of words that lie
+    together, which one DMA can take. The sublanes past a row's last pair
+    are not written, and nothing reads them."""
+    window, width = rows.shape
+    sublanes = _word_groups(width)[1]
+    tm = 256 if window % 256 == 0 else 128
+    assert window % tm == 0 and width % 128 == 0, rows.shape
+    assert rows.dtype == jnp.bfloat16, rows.dtype
+    return pl.pallas_call(
+        _held_rows_lanes_kernel,
+        out_shape=jax.ShapeDtypeStruct((window * sublanes, 128), jnp.uint32),
+        grid=(window // tm,),
+        in_specs=[pl.BlockSpec((tm, width), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((tm * sublanes, 128), lambda i: (i, 0)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret,
+        name="held_rows_lanes",
+    )(rows)
+
+
+def _held_rows_sum_kernel(tokens_ref, rows_ref, starts_ref, *refs):
+    """One tile of tokens. `tokens_ref` / `rows_ref` [window] (SMEM): the
+    window's rows in token order, token and window row; `starts_ref`: the
+    first entry of each tile, and one past the last's; `refs`: `weight_ref`
+    [window] float32 (SMEM, by window row) where the rows are weighted, then
+    `src_ref` [window * sublanes, 128] uint32 (`held_rows_lanes`), which
+    stays in HBM, `out_ref` [tile, width], the slots `buf_ref` [k * tile *
+    sublanes, 128], one an entry, the float32 accumulator `acc_ref` [tile *
+    2 * sublanes, 128] (a token's low halves, then its high halves) and the
+    copies' semaphore. A 128-lane group of the result is every `2 *
+    sublanes`-th sublane of the accumulator."""
+    *weight_ref, src_ref, out_ref, buf_ref, acc_ref, sem = refs
+    i = pl.program_id(0)
+    tile, width = out_ref.shape
+    pairs, sublanes = _word_groups(width)
+    lo, hi = starts_ref[i], starts_ref[i + 1]
+
+    def row_copy(row, slot):
+        return pltpu.make_async_copy(
+            src_ref.at[pl.ds(pl.multiple_of(row * sublanes, 8), sublanes)],
+            buf_ref.at[pl.ds(pl.multiple_of(slot * sublanes, 8), sublanes)],
+            sem,
+        )
+
+    def begin(at):
+        row_copy(rows_ref[at], at - lo).start()
+
+    def wait(at):
+        row_copy(0, 0).wait()  # every copy is one row: any stands for all
+
+    def add(at):
+        words = buf_ref[pl.ds(pl.multiple_of((at - lo) * sublanes, 8), sublanes), :]
+        parts = (words << 16, words & jnp.uint32(0xFFFF0000))
+        r = (tokens_ref[at] - i * tile) * 2 * sublanes
+        for half, part in enumerate(parts):
+            part = pltpu.bitcast(part, jnp.float32)
+            if weight_ref:
+                part = weight_ref[0][rows_ref[at]] * part
+            here = pl.ds(pl.multiple_of(r + half * sublanes, 8), sublanes)
+            acc_ref[here, :] += part
+
+    def each(entry):
+        # entry(at) for at in [lo, hi): `_HELD_SUM_UNROLL` a trip, so that the
+        # scalar unit overlaps one entry's loads with the next's, then the rest
+        trips = (hi - lo) // _HELD_SUM_UNROLL
+
+        def trip(j, carry):
+            for u in range(_HELD_SUM_UNROLL):
+                entry(lo + j * _HELD_SUM_UNROLL + u)
+            return carry
+
+        def one(at, carry):
+            entry(at)
+            return carry
+
+        lax.fori_loop(0, trips, trip, 0)
+        lax.fori_loop(lo + trips * _HELD_SUM_UNROLL, hi, one, 0)
+
+    acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+    each(begin)
+    each(wait)
+    each(add)
+    for g in range(width // 128):
+        at_sublane = g if g < pairs else sublanes + g - pairs
+        out_ref[:, g * 128:(g + 1) * 128] = acc_ref[
+            pl.ds(at_sublane, tile, stride=2 * sublanes), :
+        ].astype(out_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6, 7, 8))
+def held_rows_sum(src, decisions_sorted, rows_sorted, weight, n: int, k: int,
+                  width: int, dtype, interpret: bool = False):
+    """[n, width] in `dtype`: token t's row the float32 sum, j ascending, of
+    the rows `weight[r] * src[r]`, r = `rows_sorted[i]`, whose
+    `decisions_sorted[i] // k` is t (`_token_order`'s pair; an entry of `n *
+    k` or more is no row). `src`: `held_rows_lanes` of bf16 rows `width`
+    wide; `weight` [window] float32, or None for the rows as they are."""
+    sublanes = _word_groups(width)[1]
+    tile = _held_sum_tile(n, k, width)
+    assert tile and src.dtype == jnp.uint32, (src.shape, src.dtype, n, k)
+    tokens = decisions_sorted // k
+    bounds = jnp.arange(0, n + 1, tile, dtype=jnp.int32)
+    starts = jnp.sum(tokens[None, :] < bounds[:, None], axis=1, dtype=jnp.int32)
+    scalars = (tokens, rows_sorted, starts) + (() if weight is None else (weight,))
+    slots, accumulator = k * tile * sublanes, 2 * tile * sublanes
+    vmem = 4 * 128 * (slots + accumulator) + 2 * tile * width * jnp.dtype(dtype).itemsize
+    return pl.pallas_call(
+        _held_rows_sum_kernel,
+        out_shape=jax.ShapeDtypeStruct((n, width), dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(scalars),
+            grid=(n // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, width), lambda i, *_: (i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((slots, 128), jnp.uint32),
+                pltpu.VMEM((accumulator, 128), jnp.float32),
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=vmem * 5 // 4,
+        ),
+        interpret=interpret,
+        name="held_rows_sum",
+    )(*scalars, src)
+
+
+def _interpret() -> bool:
+    """Pallas interpret mode for `held_rows_sum`: CPU tests that opt in."""
+    from flexflow_tpu.kernels import flash_attention as flash
+
+    return flash.interpret_default()
+
+
+def _masked_rows(x2, token, valid):
+    """A window's token rows, `x2[token]` with the masked rows zero."""
+    return jnp.where(valid[:, None], x2[token], 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _window_rows(x2, token, valid, decisions_sorted, rows_sorted, k):
+    """`_masked_rows` whose transpose is no scatter-add: with the window's
+    rows in token order (`_token_order`) it is `held_rows_sum` of the rows'
+    cotangent, summed in float32 and rounded once to x2's dtype."""
+    del decisions_sorted, rows_sorted, k
+    return _masked_rows(x2, token, valid)
+
+
+def _window_rows_fwd(x2, token, valid, decisions_sorted, rows_sorted, k):
+    return _masked_rows(x2, token, valid), (
+        decisions_sorted, rows_sorted, x2.shape[0]
+    )
+
+
+def _window_rows_bwd(k, kept, g):
+    decisions_sorted, rows_sorted, n = kept
+    g_x2 = held_rows_sum(
+        held_rows_lanes(g, _interpret()), decisions_sorted, rows_sorted, None,
+        n, k, g.shape[1], g.dtype, _interpret(),
+    )
+    return g_x2, None, None, None, None
+
+
+_window_rows.defvjp(_window_rows_fwd, _window_rows_bwd)
+
+
 def held_window_rows(decisions: int, held: int, experts: int) -> int:
     """Rows one pass of `_held_rows_forward` takes: a quarter more than a
     uniform router sends the held experts, in whole 128-row tiles, and never
@@ -566,12 +866,15 @@ def held_window_rows(decisions: int, held: int, experts: int) -> int:
     return min(decisions, max(128, -(-(expected + expected // 4) // 128) * 128))
 
 
-def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool):
-    """(tokens [window], their weighted expert outputs [window, out] float32)
-    of the t-th window of a share's rows (`_held_rows_forward`: `order` the
-    decisions with the share's first, `counts` [held] its decisions per
-    expert). Every row is masked by whether the share has a `t * window +
-    i`-th row at all, so a window past the share's last row gives zeros."""
+def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool, forms):
+    """(tokens [window], the router's weights [window] float32, the experts'
+    outputs [window, out], the window's rows in token order) of the t-th
+    window of a share's rows (`_held_rows_forward`: `order` the decisions
+    with the share's first, `counts` [held] its decisions per expert, `forms`
+    the two sums' forms). A row's weight is zero where the share has no `t *
+    window + i`-th row at all, so a window past the share's last row gives
+    zeros. The last is `_token_order`'s pair where a sum takes the kernel,
+    else None."""
     held, (n, decisions) = counts.shape[0], (x2.shape[0], order.shape[0])
     k = decisions // n
     window = held_window_rows(decisions, held, attrs.num_experts)
@@ -587,7 +890,13 @@ def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool):
     valid = at < total
     decision = order[jnp.minimum(at, decisions - 1)]
     token = decision // k
-    rows = jnp.where(valid[:, None], x2[token], 0)
+    by_token = None
+    if "pallas" in forms:
+        by_token = _token_order(decision, valid, decisions)
+    if forms[1] == "pallas":
+        rows = _window_rows(x2, token, valid, *by_token, k)
+    else:
+        rows = _masked_rows(x2, token, valid)
     sizes = jnp.clip(
         jnp.minimum(ends, lo + window) - jnp.maximum(ends - counts, lo),
         0, window,
@@ -613,7 +922,7 @@ def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool):
         if "b2" in ws:
             y = y + bias_rows(ws["b2"])
     weight = jnp.where(valid, flat_w[decision], 0.0)
-    return token, weight[:, None] * y.astype(jnp.float32)
+    return token, weight, y, by_token
 
 
 # The two functions of the window index `t` that `_held_rows_forward` calls,
@@ -623,24 +932,36 @@ def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool):
 # `gmm` / `tgmm`: a `pallas_call` is traced anew at every call site unless
 # its caller is jitted (PERF.md, PR 33). The operations keep the scope of
 # the call site they are inlined into.
-@functools.partial(jax.jit, static_argnums=(7, 8))
-def _held_window_add(out, t, order, counts, x2, flat_w, ws, attrs, pallas):
-    """`out` [N, out] float32 with window t's rows added to their tokens."""
-    token, rows_out = _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas)
-    return out.at[token].add(rows_out)
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _held_window_add(out, t, order, counts, x2, flat_w, ws, attrs, pallas, forms):
+    """`out` [N, out] float32 with window t's rows added to their tokens:
+    each token's own rows summed (`held_rows_sum`) and the sums added, or
+    XLA's scatter-add (`forms[0]`). The first window's `out` is a constant
+    zero, which XLA folds either way."""
+    token, weight, y, by_token = _held_window(
+        t, order, counts, x2, flat_w, ws, attrs, pallas, forms
+    )
+    if forms[0] == "pallas":
+        return out + held_rows_sum(
+            held_rows_lanes(y, _interpret()), *by_token, weight, x2.shape[0],
+            order.shape[0] // x2.shape[0], out.shape[1], out.dtype,
+            _interpret(),
+        )
+    return out.at[token].add(weight[:, None] * y.astype(jnp.float32))
 
 
-@functools.partial(jax.jit, static_argnums=(7, 8))
-def _held_window_grads(g_out, t, order, counts, x2, flat_w, ws, attrs, pallas):
+@functools.partial(jax.jit, static_argnums=(7, 8, 9))
+def _held_window_grads(g_out, t, order, counts, x2, flat_w, ws, attrs, pallas, forms):
     """Window t's part of the gradients of (x2, flat_w, ws) under the
     cotangent `g_out` [N, out] float32 of the share's output: the window
-    recomputed from its inputs and differentiated by itself."""
+    recomputed from its inputs and differentiated by itself (x2's through
+    `_window_rows`'s written transpose where `forms[1]` is the kernel)."""
 
     def window_dot(x2, flat_w, ws):
-        token, rows_out = _held_window(
-            t, order, counts, x2, flat_w, ws, attrs, pallas
+        token, weight, y, _ = _held_window(
+            t, order, counts, x2, flat_w, ws, attrs, pallas, forms
         )
-        return jnp.sum(rows_out * g_out[token])
+        return jnp.sum(weight[:, None] * y.astype(jnp.float32) * g_out[token])
 
     return jax.grad(window_dot, argnums=(0, 1, 2))(x2, flat_w, ws)
 
@@ -659,8 +980,9 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     the share's first, by local expert (one stable sort of N k small keys),
     and taken in windows of `held_window_rows` rows: gather the window's
     token rows, run the grouped matmuls over the share's groups (`gmm` /
-    `tgmm` visit those groups' row tiles and zero the rest), scatter-add
-    the weighted results to their tokens. A uniform router fills less than
+    `tgmm` visit those groups' row tiles and zero the rest), add the
+    weighted results to their tokens (`held_rows_sum`, or a scatter-add:
+    `_held_sum_form`). A uniform router fills less than
     one window, so the first window is straight-line code and its results
     ARE the accumulators: nothing is zero-filled and nothing added to the
     fill. A router that sends this share more takes the windows after the
@@ -702,7 +1024,7 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     def forward(order, counts, x2, flat_w, ws):
         def add(t, out):
             return _held_window_add(
-                out, t, order, counts, x2, flat_w, ws, attrs, pallas
+                out, t, order, counts, x2, flat_w, ws, attrs, pallas, forms
             )
 
         zero = jnp.zeros((n, ws["w2"].shape[-1]), jnp.float32)
@@ -720,16 +1042,22 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     def routed_bwd(kept, g_out):
         order, counts, x2, flat_w, ws = kept
 
-        def grads(t):
+        def grads(t, forms):
             return _held_window_grads(
-                g_out, t, order, counts, x2, flat_w, ws, attrs, pallas
+                g_out, t, order, counts, x2, flat_w, ws, attrs, pallas, forms
             )
 
+        # a later window's gradient of x2 stays a scatter-add, which XLA does
+        # in place on the running sum; the kernel's result would be a buffer
+        # of its own beside it, and in the loop's body that cost the compiled
+        # step 72 to 274 MB (PERF.md section 6, PR 50)
+        later = (forms[0], "xla")
+
         def add(t, so_far):
-            return jax.tree_util.tree_map(jnp.add, so_far, grads(t))
+            return jax.tree_util.tree_map(jnp.add, so_far, grads(t, later))
 
         g_x2, g_w, g_ws = lax.fori_loop(
-            after, windows(counts), add, grads(zeroth)
+            after, windows(counts), add, grads(zeroth, forms)
         )
         return None, None, g_x2, g_w, g_ws
 
@@ -737,6 +1065,11 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     ws = {name: w.astype(x2.dtype) for name, w in ws.items()}
     _note_tiles(
         {name: w for name, w in ws.items() if w.ndim == 3}, window, pallas
+    )
+    forms = _note_held_sums(
+        pallas, n, k, window, x2.dtype,
+        {"forward": (ws["w2"].shape[-1], jnp.float32),
+         "backward": (x2.shape[-1], x2.dtype)},
     )
     ran = jnp.maximum(windows(counts), 1)  # the first runs whatever the counts
     return routed(order, counts, x2, flat_w, ws), counts, ran

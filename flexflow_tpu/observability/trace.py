@@ -629,6 +629,7 @@ def scope_name(graph, n) -> str:
 _lowering = threading.local()
 _ATTENTION_ROUTES: Dict[str, str] = {}
 _GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
+_HELD_ROW_SUMS: Dict[str, Dict[str, dict]] = {}
 
 
 @contextlib.contextmanager
@@ -681,6 +682,31 @@ def grouped_matmul_tiles() -> Dict[str, Dict[str, dict]]:
     contraction and column sides in whole tiles over their true size (the
     row side is the data's). A node on XLA's `ragged_dot` has no entry."""
     return {scope: dict(entries) for scope, entries in _GROUPED_MATMUL_TILES.items()}
+
+
+def note_held_row_sums(entries: Dict[str, dict]) -> None:
+    """How `kernels/moe.py` sums a held share's window rows over their
+    tokens in the expert node being lowered: `{"forward" | "backward":
+    {"form", "window_rows", "width", "dtype", "sum_dtype", "token_tile"}}`;
+    dropped where no node's scope is open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _HELD_ROW_SUMS[scope] = {site: dict(e) for site, e in entries.items()}
+
+
+def held_row_sums() -> Dict[str, Dict[str, dict]]:
+    """`{ff.experts.<name>: {"forward": entry, "backward": entry}}` of every
+    expert node with a held share this process has lowered, as it was
+    lowered last: for the forward's sum of a window's output rows over their
+    tokens and for the backward's sum of the rows' cotangent (the gradient
+    of the node's input), the `form` (`pallas`: the kernel `held_rows_sum`;
+    `xla`: a scatter-add), the window's rows (`window_rows`), the row's
+    `width` and `dtype`, the sum's (`sum_dtype`), and the tokens a program
+    of the kernel (`token_tile`, None on `xla`)."""
+    return {
+        scope: {site: dict(e) for site, e in entries.items()}
+        for scope, entries in _HELD_ROW_SUMS.items()
+    }
 
 
 def step_scope(part: str):

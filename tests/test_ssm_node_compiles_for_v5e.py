@@ -44,6 +44,16 @@ backward, which must compile and whose every `gmm` / `tgmm` block must tile
 its operand's sides whole: no 1,024-wide block on a 1,536 or a 2,304 side,
 which the kernels would run as two or three whole blocks.
 
+And the held rows' way back to their tokens (PR 50), at all four held cells'
+shapes: ONE expert node forward and backward, which must lower with the
+kernel `held_rows_sum` in it twice, the forward's sum in float32 and the
+gradient of the node's input in bf16, each behind its `held_rows_lanes` (two
+bf16 columns a word), with
+no scatter-add of float32 rows left (a later window's gradient of the input
+keeps its bf16 one, in the loop's body), and compile under the VMEM limit the
+kernel states (k slots a token of a tile, the accumulator and the out block's
+two buffers).
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -467,20 +477,40 @@ def kernel_blocks(lowered_text):
     return found
 
 
-def check_experts():
-    """{invariant: "ok" or what was found} for one held-expert node of the
-    `lfm2_moe` and the `kimi_linear` cells, forward and backward."""
+def _experts_node(attrs, shape):
+    """(the jitted forward-and-backward of one expert node, its arguments on
+    the described chip)."""
     import jax
 
     from flexflow_tpu.kernels import moe
-    from flexflow_tpu.op_attrs.activation import Activation
     from flexflow_tpu.op_attrs.core import get_weight_shapes
     from flexflow_tpu.op_attrs.datatype import DataType
-    from flexflow_tpu.op_attrs.ops import ExpertsAttrs
     from flexflow_tpu.op_attrs.tensor_shape import TensorShape
 
-    found = {}
     on_chip = _described_chip()
+    x = on_chip(shape)
+    weights = [
+        on_chip(w.dims)
+        for w in get_weight_shapes(attrs, [TensorShape(shape, DataType.FLOAT)])
+    ]
+
+    def node(x, weights, cot):
+        y, vjp = jax.vjp(
+            lambda x, weights: moe.experts_forward(attrs, x, weights)[0],
+            x, weights,
+        )
+        return y, vjp(cot)
+
+    return jax.jit(node), (x, weights, x)
+
+
+def check_experts():
+    """{invariant: "ok" or what was found} for one held-expert node of the
+    `lfm2_moe` and the `kimi_linear` cells, forward and backward."""
+    from flexflow_tpu.op_attrs.activation import Activation
+    from flexflow_tpu.op_attrs.ops import ExpertsAttrs
+
+    found = {}
     for invariant, (shape, experts, select, width, shared) in zip(
         EXPERTS_INVARIANTS, EXPERTS_SHAPES.values()
     ):
@@ -491,20 +521,8 @@ def check_experts():
                 renormalize=True, scoring="sigmoid", selection_bias=True,
                 shared_hidden_size=shared, held_experts=(0, 8),
             )
-            x = on_chip(shape)
-            weights = [
-                on_chip(w.dims)
-                for w in get_weight_shapes(attrs, [TensorShape(shape, DataType.FLOAT)])
-            ]
-
-            def node(x, weights, cot):
-                y, vjp = jax.vjp(
-                    lambda x, weights: moe.experts_forward(attrs, x, weights)[0],
-                    x, weights,
-                )
-                return y, vjp(cot)
-
-            lowered = jax.jit(node).lower(x, weights, x)
+            node, args = _experts_node(attrs, shape)
+            lowered = node.lower(*args)
             calls = kernel_blocks(lowered.as_text())
             lowered.compile()
             padded = [
@@ -519,6 +537,65 @@ def check_experts():
             found[invariant] = (
                 "ok" if tgmm and len(calls) > len(tgmm) and not padded
                 else f"{len(calls)} kernels, {len(tgmm)} tgmm; " + ", ".join(padded)
+            )
+        except Exception as e:  # noqa: BLE001 - the complaint is the result
+            found[invariant] = f"{type(e).__name__}: {e}"[:2000]
+    return found
+
+
+HELD_SUM_INVARIANTS = [
+    f"{cell}_rows_reach_their_tokens_through_held_rows_sum_and_compile"
+    for cell in ("lfm2", "kimi", "twotower", "super")
+]
+# input, then `ExpertsAttrs` of a node of each held cell (8 experts held)
+HELD_SUM_NODES = {
+    "lfm2": (LFM2_SHAPE, dict(
+        num_experts=64, num_select=4, hidden_size=1536, gated=True)),
+    "kimi": ((1, ROWS, KDA_HIDDEN), dict(
+        num_experts=256, num_select=8, hidden_size=1024, gated=True,
+        shared_hidden_size=1024)),
+    "twotower": ((1, ROWS, 2688), dict(
+        num_experts=128, num_select=6, hidden_size=1856, gated=False,
+        shared_hidden_size=3712)),
+    "super": ((1, ROWS, 4096), dict(
+        num_experts=512, num_select=22, hidden_size=2688, gated=False,
+        shared_hidden_size=5376, latent_size=1024)),
+}
+
+
+def check_held_sums():
+    """{invariant: "ok" or what was found} for one expert node of each of
+    the four held cells, forward and backward."""
+    from flexflow_tpu.op_attrs.activation import Activation
+    from flexflow_tpu.op_attrs.ops import ExpertsAttrs
+
+    found = {}
+    for invariant, (shape, sizes) in zip(HELD_SUM_INVARIANTS, HELD_SUM_NODES.values()):
+        try:
+            attrs = ExpertsAttrs(
+                activation=Activation.SILU if sizes["gated"] else Activation.RELU2,
+                capacity_factor=None, use_bias=False, renormalize=True,
+                scoring="sigmoid", selection_bias=True, held_experts=(0, 8),
+                **sizes,
+            )
+            node, args = _experts_node(attrs, shape)
+            lowered = node.lower(*args)
+            text = lowered.as_text()
+            sums = text.count('kernel_name = "held_rows_sum"')
+            lanes = text.count('kernel_name = "held_rows_lanes"')
+            # a scatter of rows closes `}) : (tensor<tokens x width>, ...)`;
+            # megablox's own scatters are of integers, and the one of bf16
+            # rows is a later window's gradient of x2, in the loop's body
+            scatters = len(re.findall(
+                r"\}\) : \(tensor<\d+x\d+xf32>, tensor<\d+x1xi32>, ", text
+            ))
+            lowered.compile()
+            # JAX lowers a window function once for the straight-line site
+            # and once for the loop's: two sites, each kernel two or four times
+            found[invariant] = (
+                "ok" if sums == lanes >= 2 and not scatters else
+                f"{sums} held_rows_sum, {lanes} held_rows_lanes, "
+                f"{scatters} scatters of float32 rows"
             )
         except Exception as e:  # noqa: BLE001 - the complaint is the result
             found[invariant] = f"{type(e).__name__}: {e}"[:2000]
@@ -590,6 +667,11 @@ def test_held_experts_compiled_for_the_described_chip(compiled, invariant):
     assert compiled["experts"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", HELD_SUM_INVARIANTS)
+def test_held_rows_sum_compiled_for_the_described_chip(compiled, invariant):
+    assert compiled["held_sums"][invariant] == "ok"
+
+
 if __name__ == "__main__":
     argv = sys.argv[1:]
     root = os.getcwd()
@@ -603,5 +685,6 @@ if __name__ == "__main__":
     else:
         print(json.dumps(
             dict({name: check(name) for name in SHAPES}, kimi=check_kimi(),
-                 lfm2=check_lfm2(), experts=check_experts())
+                 lfm2=check_lfm2(), experts=check_experts(),
+                 held_sums=check_held_sums())
         ))
