@@ -325,10 +325,15 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
             gated=eq.get("gated", False),
             scoring="sigmoid" if eq.get("selection_bias") else "softmax",
             selection_bias=bool(eq.get("selection_bias", False)),
-            # the pattern of the shared form pins the selection bias and
-            # asks for a shared width that is not zero
-            shared_hidden_size=size if eq.get("selection_bias") else 0,
+            # the patterns of the shared forms pin the selection bias or
+            # the shared expert's gate and ask for a shared width that is
+            # not zero
+            shared_hidden_size=(
+                size if eq.get("selection_bias") or eq.get("shared_gate")
+                else 0
+            ),
             latent_size=size if eq.get("latent_size") is _SET else None,
+            shared_gate=bool(eq.get("shared_gate", False)),
         )
     if op_type == OperatorType.STATE_SPACE:
         from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
@@ -339,8 +344,10 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
     if op_type == OperatorType.GATED_DELTA:
         from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 
+        decay = eq.get("decay", "channel")
         return GatedDeltaAttrs(
-            num_heads=2, key_dim=4, value_dim=4, gate_rank=2, chunk_size=4
+            num_heads=2, key_dim=4, value_dim=4, gate_rank=2, chunk_size=4,
+            num_key_heads=1 if decay == "head" else None, decay=decay,
         )
     if op_type == OperatorType.SHORT_CONV:
         from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs

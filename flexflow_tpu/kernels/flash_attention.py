@@ -2238,3 +2238,154 @@ def flash_attention_bshf_wide_key(
     return _flash_bshf_wide_key(
         q, k, v, num_heads, _CAUSAL_BLOCK, interpret, float(scale)
     )
+
+
+# ---------------------------------------------------------------------------
+# grouped key/value heads read in place, and whole rows too long for the
+# scoped default
+# ---------------------------------------------------------------------------
+#
+# The causal kernels above keep a (batch, head)'s keys and values resident as
+# whole [s, d] rows. Double-buffered that is 4 * s * d * itemsize bytes in the
+# forward: 8 MB at 8,192 positions of 128 bf16 columns, and 16 MB, the whole
+# default scope, at heads of 256. `flash_attention_bshf_grouped` is the same
+# two kernel BODIES on blocks of its own: the forward asks for the room the
+# backward always asked for (`_CAUSAL_VMEM_LIMIT`), and query head h reads
+# key/value head h // group WHERE IT LIES in the published [b, s, kv * d]
+# rows, so the group's repeated copies of k and v are never made. The
+# backward writes a query head's own dk and dv ([b, s, h * d]) and the caller
+# sums them over the group. At the end of the file for the reason the causal
+# schedule is.
+
+# what the forward's resident rows may take of the default 16 MB scope before
+# the grouped entry, which names its own limit, takes the shape over
+_SCOPED_ROWS_BUDGET = 12 * 1024 * 1024
+
+
+def causal_rows_exceed_scope(s: int, d: int, itemsize: int) -> bool:
+    """Whether a (batch, head)'s k and v rows [s, d], double-buffered, leave
+    the causal forward no room inside the default scoped VMEM."""
+    return 4 * s * d * itemsize > _SCOPED_ROWS_BUDGET
+
+
+def _fwd_bshf_grouped(q, k, v, h, kv, block, interpret, scale):
+    b, s, f = q.shape
+    d, group = f // h, h // kv
+
+    def tile(width):
+        return pl.BlockSpec((1, block, width), lambda bi, hi, i: (bi, i, hi))
+
+    row = pl.BlockSpec((1, s, d), lambda bi, hi, i: (bi, 0, hi // group))
+    return pl.pallas_call(
+        functools.partial(_fwd_causal_kernel, block_k=block, scale=scale),
+        name="flash_fwd_causal_grouped",
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
+        ),
+        grid=(b, h, s // block),
+        in_specs=[tile(d), row, row],
+        out_specs=[
+            tile(d),
+            pl.BlockSpec((1, None, 1, block), lambda bi, hi, i: (bi, hi, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, f), q.dtype),
+            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
+        ],
+    )(q, k, v)
+
+
+def _delta_bshf_tiled(do, o, h, block, interpret):
+    """`_delta_bshf` a tile of `block` positions a program: the whole-row
+    blocks of that entry ask for 48 MB of VMEM at 8,192 positions of 256
+    columns."""
+    b, s, f = do.shape
+    d = f // h
+    tile = pl.BlockSpec((1, block, d), lambda bi, hi, j: (bi, j, hi))
+    return pl.pallas_call(
+        _delta_kernel,
+        name="flash_delta_grouped",
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel")
+        ),
+        grid=(b, h, s // block),
+        in_specs=[tile, tile],
+        out_specs=pl.BlockSpec(
+            (1, None, 1, block), lambda bi, hi, j: (bi, hi, 0, j)
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
+    )(do, o)
+
+
+def _bwd_bshf_grouped(q, k, v, o, lse, do, h, kv, block, interpret, scale):
+    b, s, f = q.shape
+    d, group = f // h, h // kv
+    delta4 = _delta_bshf_tiled(do, o, h, block, interpret)
+    row = pl.BlockSpec((None, s, d), lambda bi, hi, j: (bi, 0, hi))
+    own = pl.BlockSpec((None, block, d), lambda bi, hi, j: (bi, j, hi))
+    shared = pl.BlockSpec(
+        (None, block, d), lambda bi, hi, j: (bi, j, hi // group)
+    )
+    stat = pl.BlockSpec((None, None, 1, s), lambda bi, hi, j: (bi, hi, 0, 0))
+    like_q = jax.ShapeDtypeStruct((b, s, f), q.dtype)
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_bwd_causal_kernel, block_q=block, scale=scale),
+        name="flash_bwd_causal_grouped",
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
+        ),
+        grid=(b, h, s // block),
+        in_specs=[row, shared, shared, row, stat, stat],
+        out_specs=[row, own, own],
+        out_shape=[like_q, like_q, like_q],
+        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
+    )(q, k, v, do, lse, delta4)
+
+    def over_group(t):
+        t = t.reshape(b, s, kv, group, d).astype(jnp.float32)
+        return jnp.sum(t, axis=3).reshape(b, s, kv * d).astype(k.dtype)
+
+    return dq, over_group(dk), over_group(dv)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bshf_grouped(q, k, v, h, kv, block, interpret, scale):
+    return _fwd_bshf_grouped(q, k, v, h, kv, block, interpret, scale)[0]
+
+
+def _flash_bshf_grouped_fwd(q, k, v, h, kv, block, interpret, scale):
+    o, lse = _fwd_bshf_grouped(q, k, v, h, kv, block, interpret, scale)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bshf_grouped_bwd(h, kv, block, interpret, scale, res, do):
+    q, k, v, o, lse = res
+    return _bwd_bshf_grouped(
+        q, k, v, o, lse, do, h, kv, block, interpret, scale
+    )
+
+
+_flash_bshf_grouped.defvjp(_flash_bshf_grouped_fwd, _flash_bshf_grouped_bwd)
+
+
+def flash_attention_bshf_grouped(
+    q, k, v, num_heads: int, num_kv_heads: int, *, interpret: bool = False,
+):
+    """Causal attention on q [b, s, num_heads * d] and k, v
+    [b, s, num_kv_heads * d], d a multiple of 128, over more than one causal
+    tile; query head h reads key/value head h // (num_heads / num_kv_heads).
+    -> [b, s, num_heads * d]."""
+    b, s, f = q.shape
+    d = f // num_heads
+    assert d % 128 == 0 and num_heads % num_kv_heads == 0, (d, num_heads)
+    assert k.shape == v.shape == (b, s, num_kv_heads * d), (q.shape, k.shape)
+    assert wide_key_supported(s), s
+    return _flash_bshf_grouped(
+        q, k, v, num_heads, num_kv_heads, _CAUSAL_BLOCK, interpret,
+        d ** -0.5,
+    )

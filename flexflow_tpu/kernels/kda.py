@@ -1,6 +1,8 @@
-"""The gated delta-rule mixer's kernels (`GatedDeltaAttrs`; Kimi Delta
-Attention, arXiv:2510.26692): the recurrence, per head, with a [dk, dv] state
-S, a log-decay g_t <= 0 for EVERY key channel and a step beta_t in (0, 1):
+"""The gated delta-rule mixer's kernels (`GatedDeltaAttrs`): the
+recurrence, per value head, with a [dk, dv] state S, a step beta_t in (0, 1)
+and a log-decay g_t <= 0, one for EVERY key channel (Kimi Delta Attention,
+arXiv:2510.26692; written out below) or ONE a head (Gated DeltaNet,
+arXiv:2412.06464; "One decay a head" further down):
 
     S' = Diag(exp(g_t)) S_{t-1}
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T          o_t = S_t^T q_t
@@ -86,6 +88,22 @@ two gates' pre-activations and the step's logits) and ONE thing a chunk
 computes: the triangular inverse (`_KEPT`, 16 KB a chunk), whose ten products
 a chunk would otherwise run again. Everything else is recomputed there. In
 neither form does a state per position ever exist.
+
+**One decay a head** (`GatedDeltaAttrs.decay` "head"). exp(G_r - G_j) is one
+number a pair of positions and leaves the contraction over the key channels:
+
+    A = (K K^T) * M  (j < r),   P = (Q K^T) * M  (j <= r),   M_rj = exp(G_r - G_j)
+
+one [Q, Q] product each and a mask, every exponent still a sum of log-decays
+and so <= 0; none of `decayed_scores`' levels is needed. `num_key_heads` key
+heads may serve more value heads: K K^T and Q K^T are taken once a KEY head
+and meet the value heads' M through a group axis, so a key head is indexed
+(value head h reads key head h // group) and never repeated; Q exp(G),
+K exp(G), K exp(G_Q - G), the inverse and the states are a value head's.
+`head_decay_operands` is that form, XLA's on every route (JAX differentiates
+it but for the triangular inverse, which is `kernel_inverse` on the "kda"
+route); what it returns is what `chunk_operands` returns, exp(G_Q) written
+over the dk lanes, so `chunk_scan` and its kernels take it as they are.
 
 The node's parts go under scopes of their own inside the node's
 (`ff.kda.<name>/scan`, `/prep`, `/gates`, `/conv`, `/norm`;
@@ -253,6 +271,56 @@ def chunk_operands(q, k, v, g, beta, chunk: int):
     p = p + own[..., None] * jnp.eye(chunk, dtype=f32)
     w, uv = _corrected(a, kd, v5, by_chunk(beta), dtype)
     return qd, w, uv, ke, p.astype(dtype), jnp.exp(g_end)
+
+
+def head_decay_operands(q, k, v, g, beta, chunk: int,
+                        inverse=unit_lower_inverse):
+    """`chunk_operands` for ONE log-decay a value head (module docstring,
+    "One decay a head"): q, k [b, hk, s, dk] (normalised), v [b, hv, s, dv],
+    g and beta [b, hv, s] float32, hv a multiple of hk; value head h reads
+    key head h // (hv / hk). Returns what `chunk_operands` returns, by chunk
+    and VALUE head."""
+    b, hk, s, dk = q.shape
+    hv = v.shape[1]
+    group, c = hv // hk, s // chunk
+    dtype, f32 = q.dtype, jnp.float32
+
+    def by_chunk(t):
+        return t.reshape(*t.shape[:2], c, chunk, *t.shape[3:])
+
+    def by_group(t):  # [b, hv, c, ..] -> [b, hk, group, c, ..]
+        return t.reshape(b, hk, group, *t.shape[2:])
+
+    def by_value_head(t):
+        return t.reshape(b, hv, *t.shape[3:])
+
+    q5, k5 = by_chunk(q), by_chunk(k)
+    gc = jnp.cumsum(by_chunk(g), axis=3)  # [b, hv, c, Q]
+    g_end = gc[..., -1:]
+    # exp(G_r - G_j) on and under the diagonal, 0 above it: every exponent
+    # that is taken is a sum of log-decays
+    m = jnp.exp(jnp.where(
+        np.tri(chunk, dtype=bool), gc[..., :, None] - gc[..., None, :],
+        -jnp.inf,
+    ))
+    qk, kk = (
+        jnp.einsum("bhcrk,bhcjk->bhcrj", t, k5, preferred_element_type=f32)
+        for t in (q5, k5)
+    )
+    # one product a KEY head meets its value heads' decays
+    p = by_value_head(by_group(m) * qk[:, :, None])
+    a = by_value_head(by_group(m) * kk[:, :, None])
+    qf, kf = q5.astype(f32)[:, :, None], k5.astype(f32)[:, :, None]
+    from_start = by_group(jnp.exp(gc))[..., None]
+    to_end = by_group(jnp.exp(g_end - gc))[..., None]
+    qd = by_value_head(qf * from_start).astype(dtype)
+    kd = by_value_head(kf * from_start)
+    ke = by_value_head(kf * to_end).astype(dtype)
+    w, uv = _corrected(a, kd, by_chunk(v), by_chunk(beta), dtype, inverse)
+    gamma = jnp.broadcast_to(
+        jnp.exp(g_end)[..., None], (b, hv, c, 1, dk)
+    )
+    return qd, w, uv, ke, p.astype(dtype), gamma
 
 
 # ---------------------------------------------------------------------------
@@ -1127,15 +1195,90 @@ def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
     return jnp.swapaxes(o, 1, 2).reshape(b, s, h * dv)
 
 
-def _head_norm_gate(o, gate_up, gate_bias, gain, heads: int, eps: float):
+def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, qkv, a_pre,
+                           dt_bias, a_log, b_logit):
+    """`_recurrence` for one log-decay a value head: qkv
+    [b, s, 2*hk*dk + hv*dv] after the convolution, a_pre and b_logit
+    [b, s, hv] -> o [b, s, hv*dv]."""
+    f32 = jnp.float32
+    b, s, _ = qkv.shape
+    hv, hk, dk, dv, chunk = (
+        attrs.num_heads, attrs.key_heads, attrs.key_dim, attrs.value_dim,
+        attrs.chunk_size,
+    )
+    kw = attrs.key_width
+    pad = -s % chunk
+
+    def heads_first(t, heads):
+        # [b, s, heads * width] -> [b, heads, s + pad, width]; a padded
+        # position has k = 0 and beta = 0: it writes nothing
+        t = jnp.swapaxes(t.reshape(b, s, heads, -1), 1, 2)
+        return jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else t
+
+    with jax.named_scope("gates"):
+        v = heads_first(qkv[..., 2 * kw:], hv)
+        beta = jax.nn.sigmoid(heads_first(b_logit, hv).astype(f32))[..., 0]
+        q = _unit(heads_first(qkv[..., :kw], hk), dk ** -0.5)
+        k = _unit(heads_first(qkv[..., kw:2 * kw], hk), 1.0)
+        g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
+            heads_first(a_pre, hv)[..., 0].astype(f32)
+            + dt_bias.astype(f32)[:, None]
+        )
+    with jax.named_scope("prep"):
+        pairs = (b * hv * ((s + pad) // chunk)) % 2 == 0
+        operands = head_decay_operands(
+            q, k, v, g, beta, chunk,
+            # the inverse's kernel takes the chunks two by two
+            kernel_inverse if route == "kda" and pairs else unit_lower_inverse,
+        )
+    with jax.named_scope("scan"):
+        o = chunk_scan(route, *operands)
+    o = o.reshape(b, hv, s + pad, dv)[:, :, :s]
+    return jnp.swapaxes(o, 1, 2).reshape(b, s, hv * dv)
+
+
+def _head_norm(o, gain, heads: int, eps: float):
     """rms_norm of each head's dv features of o (gain [dv], shared by the
-    heads) times sigmoid(gate_up + gate_bias), float32 inside, o's dtype
-    out."""
+    heads), float32 [b, s, heads * dv]."""
     f32 = jnp.float32
     b, s, width = o.shape
     of = o.astype(f32).reshape(b, s, heads, width // heads)
     root = lax.rsqrt(jnp.mean(of * of, axis=-1, keepdims=True) + eps)
-    normed = (of * root * gain.astype(f32)).reshape(b, s, width)
+    return (of * root * gain.astype(f32)).reshape(b, s, width)
+
+
+def _head_norm_silu(o, z, gain, heads: int, eps: float):
+    """`_head_norm` of o times silu(z), float32 inside, o's dtype out."""
+    normed = _head_norm(o, gain, heads, eps)
+    return (normed * jax.nn.silu(z.astype(jnp.float32))).astype(o.dtype)
+
+
+def _gated_delta_head_decay(attrs: GatedDeltaAttrs, u, weights):
+    """`gated_delta_forward` for `decay` "head"."""
+    w_in, w_ba, w_conv, dt_bias, a_log, gain, w_out = weights
+    cw, hv = attrs.conv_width, attrs.num_heads
+    proj = u @ w_in
+    with jax.named_scope("conv"):
+        qkv = conv_silu(proj[..., :cw], w_conv, None)
+    with jax.named_scope("gates"):
+        ba = u @ w_ba
+    route = scan_route(attrs.key_dim, attrs.value_dim, attrs.chunk_size)
+    o = jax.checkpoint(
+        functools.partial(_recurrence_head_decay, attrs, route),
+        policy=jax.checkpoint_policies.save_only_these_names(_KEPT),
+    )(qkv, ba[..., hv:], dt_bias, a_log, ba[..., :hv])
+    with jax.named_scope("norm"):
+        y = jax.checkpoint(
+            functools.partial(_head_norm_silu, heads=hv, eps=attrs.norm_eps)
+        )(o, proj[..., cw:], gain)
+    return y @ w_out
+
+
+def _head_norm_gate(o, gate_up, gate_bias, gain, heads: int, eps: float):
+    """`_head_norm` of o times sigmoid(gate_up + gate_bias), float32 inside,
+    o's dtype out."""
+    f32 = jnp.float32
+    normed = _head_norm(o, gain, heads, eps)
     gate = jax.nn.sigmoid(gate_up.astype(f32) + gate_bias.astype(f32))
     return (normed * gate).astype(o.dtype)
 
@@ -1144,6 +1287,8 @@ def gated_delta_forward(
     attrs: GatedDeltaAttrs, u: jnp.ndarray, weights: Sequence[jnp.ndarray]
 ) -> jnp.ndarray:
     """u [b, s, D] -> [b, s, D]; weights in `GatedDeltaAttrs` slot order."""
+    if attrs.per_head_decay:
+        return _gated_delta_head_decay(attrs, u, weights)
     w_in, w_conv, w_f, dt_bias, a_log, w_g, b_g, gain, w_out = weights
     cw, rank = attrs.conv_width, attrs.gate_rank
     proj = u @ w_in

@@ -570,18 +570,29 @@ def _finish(attrs, out, x, shared, counts, probs, logits):
 
 def _add_shared_expert(attrs: ExpertsAttrs, out, x, shared):
     """out [N, out] float32 plus the shared expert's dense path on every
-    token of x [.., D] (`shared`: ws1[, ws3], ws2; nothing where the op has
-    none)."""
+    token of x [.., D] (`shared`: ws1[, ws3], ws2[, w_sg]; nothing where
+    the op has none), times sigmoid(x w_sg) where it has a gate
+    (`ExpertsAttrs.shared_gate`)."""
     if not shared:
         return out
     with jax.named_scope("shared"), jax.named_scope("shared_expert"):
         x2 = x.reshape(-1, x.shape[-1])
+        w_sg = shared[-1] if attrs.shared_gate else None
+        ws2 = shared[-2] if attrs.shared_gate else shared[-1]
         hs = x2 @ shared[0].astype(x2.dtype)
         if attrs.activation is not None:
             hs = attrs.activation.apply(hs)
         if attrs.gated:
             hs = hs * (x2 @ shared[1].astype(x2.dtype))
-        return out + (hs @ shared[-1].astype(x2.dtype)).astype(jnp.float32)
+        y = (hs @ ws2.astype(x2.dtype)).astype(jnp.float32)
+        if w_sg is not None:
+            # one logit a token: a [N, D] x [D, 1] product accumulated in
+            # float32, the sigmoid in float32
+            logit = jnp.matmul(
+                x2, w_sg.astype(x2.dtype), preferred_element_type=jnp.float32
+            )
+            y = y * jax.nn.sigmoid(logit)
+        return out + y
 
 
 # -- the held rows back to their tokens: a sum, not a scatter-add ------------

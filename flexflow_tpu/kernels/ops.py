@@ -15,6 +15,7 @@ op_attrs.get_incoming_tensor_roles).
 
 from __future__ import annotations
 
+import contextlib
 from functools import partial
 from typing import List, Optional, Sequence
 
@@ -211,24 +212,50 @@ def mha_project_qkv_bshf_fused(
     return qkv, wo2
 
 
-def rms_norm(x, gain, eps):
+def rms_norm(x, gain, eps, zero_centered: bool = False):
     """x * rsqrt(mean(x^2, last) + eps) * gain, the mean of squares in
-    float32; the result in x's dtype."""
+    float32; the result in x's dtype. `zero_centered`: `gain` is the weight
+    w of a gain 1 + w (`RMSNormAttrs.zero_centered`)."""
     x32 = x.astype(jnp.float32)
     scale = lax.rsqrt(jnp.mean(jnp.square(x32), axis=-1, keepdims=True) + eps)
+    if zero_centered:
+        return (x32 * scale * (1.0 + gain.astype(jnp.float32))).astype(x.dtype)
     return (x32 * scale * gain.astype(jnp.float32)).astype(x.dtype)
 
 
-def rope_bshf(x, num_heads: int, theta: float):
+def _rope_leading_columns(x, num_heads: int, theta: float, width: int):
+    """`rope_bshf` on the first `width` columns of each head alone (pairs
+    (j, j + width / 2), angle pos * theta^(-2j / width)); the head's other
+    columns pass."""
+    b, s, f = x.shape
+    half = width // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / width)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    heads = x.reshape(b, s, num_heads, f // num_heads)
+    lo = heads[..., :half].astype(jnp.float32)
+    hi = heads[..., half:width].astype(jnp.float32)
+    turned = jnp.concatenate(
+        [lo * cos - hi * sin, hi * cos + lo * sin], axis=-1
+    ).astype(x.dtype)
+    return jnp.concatenate(
+        [turned, heads[..., width:]], axis=-1
+    ).reshape(b, s, f)
+
+
+def rope_bshf(x, num_heads: int, theta: float, rotary_dim=None):
     """Rotary position embedding on x [b, s, h*d], positions 0..s-1, each
     d-lane head block rotated by itself with the rotate-half pairing
     (i, i + d/2) and angle pos * theta^(-2j/d). Written on the fused row so
     that the projections' layout is kept: within a block, rotate_half(x) is
     x rolled down by d/2 lanes in the lower half and up in the upper half,
     and a roll of the whole row agrees with the roll of a block wherever
-    that half reads from its own block."""
+    that half reads from its own block. `rotary_dim` narrower than the head
+    turns the head's first columns only (`_rope_leading_columns`)."""
     b, s, f = x.shape
     d = f // num_heads
+    if rotary_dim is not None and rotary_dim != d:
+        return _rope_leading_columns(x, num_heads, theta, rotary_dim)
     half = d // 2
     assert d % 2 == 0, f"rotary embedding needs an even head size, got {d}"
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
@@ -251,7 +278,8 @@ def mha_row_projections(attrs: MultiHeadAttentionAttrs) -> bool:
     return attrs.qk_norm or attrs.rope_theta is not None or attrs.grouped_query
 
 
-def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains):
+def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains,
+                repeat: bool = True):
     """What the attrs ask for between projection and attention core, on the
     fused [b, s, h*d] projections: QK-norm over the whole row (or, with
     `qk_norm_per_head`, over each head block by itself), then RoPE on
@@ -259,24 +287,28 @@ def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains):
     the query heads that read it (head h reads h // group), so that the core
     is the equal-head one: same kernels, same route, and the repeat's
     transpose sums dK and dV over the group. A kernel that indexes the
-    key/value block by `h // group` instead would save the two repeated
-    copies (ROADMAP, Reach (3))."""
+    key/value block by `h // group` instead saves the two repeated copies:
+    `flash_attention_bshf_grouped` is one, and its caller passes `repeat`
+    false (ROADMAP, Reach (3))."""
     if attrs.qk_norm_per_head:
         # a head's own features normed by themselves, one gain [d] for all
         # of q's heads and one for the key heads
 
         def per_head(x, gain):
             heads = x.reshape(*x.shape[:2], -1, attrs.q_proj_size)
-            return rms_norm(heads, gain, attrs.qk_norm_eps).reshape(x.shape)
+            return rms_norm(
+                heads, gain, attrs.qk_norm_eps, attrs.qk_norm_zero_centered
+            ).reshape(x.shape)
 
         qp, kp = per_head(qp, qk_gains[0]), per_head(kp, qk_gains[1])
     elif attrs.qk_norm:
-        qp = rms_norm(qp, qk_gains[0], attrs.qk_norm_eps)
-        kp = rms_norm(kp, qk_gains[1], attrs.qk_norm_eps)
+        zc = attrs.qk_norm_zero_centered
+        qp = rms_norm(qp, qk_gains[0], attrs.qk_norm_eps, zc)
+        kp = rms_norm(kp, qk_gains[1], attrs.qk_norm_eps, zc)
     if attrs.rope_theta is not None:
-        qp = rope_bshf(qp, attrs.num_heads, attrs.rope_theta)
-        kp = rope_bshf(kp, attrs.kv_heads, attrs.rope_theta)
-    if attrs.grouped_query:
+        qp = rope_bshf(qp, attrs.num_heads, attrs.rope_theta, attrs.rotary_dim)
+        kp = rope_bshf(kp, attrs.kv_heads, attrs.rope_theta, attrs.rotary_dim)
+    if repeat and attrs.num_kv_heads not in (None, attrs.num_heads):
         H, KV = attrs.num_heads, attrs.num_kv_heads
 
         def repeated(x):
@@ -434,12 +466,16 @@ def _note_route(route: str) -> None:
 def unpack_gqa_weights(
     attrs: MultiHeadAttentionAttrs, qsize: int, ksize: int, vsize: int, weight
 ):
-    """The grouped-query layout: one flat column holding wq [qsize, h*d],
+    """The grouped-query layout: one flat column holding wq [qsize, h*d]
+    (with `output_gate` [qsize, h*2*d]: a head's query, then its gate),
     wk [ksize, kv*d], wv [vsize, kv*v] and wo [h*v, e], each row-major with
     head-major columns (`MultiHeadAttentionAttrs.num_kv_heads`)."""
     H, KV = attrs.num_heads, attrs.num_kv_heads
     kd, vd, e = attrs.q_proj_size, attrs.v_proj_size, attrs.embed_dim
-    shapes = [(qsize, H * kd), (ksize, KV * kd), (vsize, KV * vd), (H * vd, e)]
+    shapes = [
+        (qsize, H * attrs.q_columns), (ksize, KV * kd), (vsize, KV * vd),
+        (H * vd, e),
+    ]
     return _unpack_flat(weight, shapes)
 
 
@@ -519,6 +555,64 @@ def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal)
     return ctx.reshape(b, s, H * vd) @ wo
 
 
+def _split_output_gate(attrs: MultiHeadAttentionAttrs, qp):
+    """(q, gate) of the query projection [b, s, h * q_columns]: with
+    `output_gate` a head's columns are its query, then its gate; without,
+    (qp, None)."""
+    if not attrs.output_gate:
+        return qp, None
+    b, s, _ = qp.shape
+    H, kd = attrs.num_heads, attrs.q_proj_size
+    with jax.named_scope("gate"):
+        both = qp.reshape(b, s, H, 2 * kd)
+        return (
+            both[..., :kd].reshape(b, s, H * kd),
+            both[..., kd:].reshape(b, s, H * kd),
+        )
+
+
+def _gated_context(ctx, gate):
+    """ctx * sigmoid(gate), the sigmoid in float32; ctx where no gate is."""
+    if gate is None:
+        return ctx
+    with jax.named_scope("gate"):
+        g = jax.nn.sigmoid(gate.astype(jnp.float32))
+        return (ctx.astype(jnp.float32) * g).astype(ctx.dtype)
+
+
+def _rows_scope(attrs: MultiHeadAttentionAttrs):
+    """The scope of the norm-and-rotary pass of a gated node
+    (`observability/trace.NODE_PARTS`); other nodes keep the names they
+    had."""
+    return (
+        jax.named_scope("rows") if attrs.output_gate
+        else contextlib.nullcontext()
+    )
+
+
+def mha_reads_kv_in_place(
+    attrs: MultiHeadAttentionAttrs, s: int, itemsize: int
+) -> bool:
+    """Whether the "fused_row" core is `flash_attention_bshf_grouped`: a
+    causal context whose whole key and value rows, double-buffered, leave
+    the causal forward no room in the default scoped VMEM (heads of 256 at
+    8,192 positions), so the entry that names its own limit takes it, and
+    reads each key/value head where it lies for the query heads that share
+    it. Every shape that ran before keeps the entry it had."""
+    from flexflow_tpu.kernels.flash_attention import (
+        causal_rows_exceed_scope,
+        wide_key_supported,
+    )
+
+    kd = attrs.q_proj_size
+    return (
+        getattr(attrs, "causal", False)
+        and kd == attrs.v_proj_size and kd % 128 == 0
+        and wide_key_supported(s)
+        and causal_rows_exceed_scope(s, kd, itemsize)
+    )
+
+
 def _mha_forward(
     attrs: MultiHeadAttentionAttrs, q, k, v, weight, input_bias=None,
     causal=False, qk_gains=None,
@@ -527,6 +621,7 @@ def _mha_forward(
         current_flash_mesh,
         flash_attention,
         flash_attention_bshf,
+        flash_attention_bshf_grouped,
         flash_attention_bshf_qkv,
         per_batch_shard,
         sharded_flash_attention,
@@ -556,14 +651,26 @@ def _mha_forward(
         qp, kp, vp, wo2 = mha_project_qkv_bshf(
             attrs, q, k, v, weight, input_bias
         )
+        qp, gate = _split_output_gate(attrs, qp)
+        in_place = mha_reads_kv_in_place(attrs, q.shape[1], q.dtype.itemsize)
         if post:
-            qp, kp, vp = mha_between(attrs, qp, kp, vp, qk_gains)
+            with _rows_scope(attrs):
+                qp, kp, vp = mha_between(
+                    attrs, qp, kp, vp, qk_gains, repeat=not in_place
+                )
         if mha_pads_heads(attrs, q.shape[1]):
             return _padded_heads_core(attrs, qp, kp, vp) @ wo2
-        ctx = per_batch_shard(
-            flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal
-        )
-        return ctx @ wo2
+        if in_place:
+            with jax.named_scope("core"):
+                ctx = per_batch_shard(
+                    flash_attention_bshf_grouped, qp, kp, vp,
+                    num_heads=H, num_kv_heads=attrs.kv_heads,
+                )
+        else:
+            ctx = per_batch_shard(
+                flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal
+            )
+        return _gated_context(ctx, gate) @ wo2
 
     if post:
         # the same fused-row projections, then split into heads for the
@@ -571,7 +678,9 @@ def _mha_forward(
         qp, kp, vp, wo2 = mha_project_qkv_bshf(
             attrs, q, k, v, weight, input_bias
         )
-        qp, kp, vp = mha_between(attrs, qp, kp, vp, qk_gains)
+        qp, gate = _split_output_gate(attrs, qp)
+        with _rows_scope(attrs):
+            qp, kp, vp = mha_between(attrs, qp, kp, vp, qk_gains)
         vd = attrs.v_proj_size
 
         def heads(x, d):
@@ -579,8 +688,11 @@ def _mha_forward(
 
         qp, kp, vp = heads(qp, kd), heads(kp, kd), heads(vp, vd)
         wo = jnp.transpose(wo2.reshape(H, vd, attrs.embed_dim), (1, 2, 0))
+        if gate is not None:
+            gate = heads(gate, vd)
     else:
         qp, kp, vp, wo = mha_project_qkv(attrs, q, k, v, weight, input_bias)
+        gate = None
     if route == "rows":
         mesh_ctx = current_flash_mesh()
         if mesh_ctx is None:
@@ -593,7 +705,7 @@ def _mha_forward(
                 qp, kp, vp, mesh, batch_axes, head_axes,
                 causal=causal, interpret=interpret,
             )
-        return jnp.einsum("bhsv,veh->bse", ctx, wo)
+        return jnp.einsum("bhsv,veh->bse", _gated_context(ctx, gate), wo)
     scores = jnp.einsum("bhsk,bhtk->bhst", qp, kp) / jnp.sqrt(
         jnp.asarray(kd, qp.dtype)
     )
@@ -603,7 +715,7 @@ def _mha_forward(
         scores = jnp.where(mask, scores, jnp.asarray(-1e30, scores.dtype))
     attn = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bhst,bhtv->bhsv", attn, vp)
-    return jnp.einsum("bhsv,veh->bse", ctx, wo)
+    return jnp.einsum("bhsv,veh->bse", _gated_context(ctx, gate), wo)
 
 
 def forward(
@@ -739,7 +851,9 @@ def forward(
         return [out]
 
     if isinstance(attrs, RMSNormAttrs):
-        return [rms_norm(inputs[0], weights[0], attrs.eps)]
+        return [
+            rms_norm(inputs[0], weights[0], attrs.eps, attrs.zero_centered)
+        ]
 
     if isinstance(attrs, SoftmaxAttrs):
         return [jax.nn.softmax(inputs[0], axis=attrs.dim)]
@@ -1023,8 +1137,9 @@ def op_forward_flops(
             H = weight_shapes[0].dims[1]  # [per-head params, H/k] piece
         # grouped-query heads project kv_heads keys and values, not H
         KV = attrs.kv_heads * H // attrs.num_heads
+        # with an output gate a head's query columns come with a gate's
         proj = (
-            2 * b * s * e * (kd * H + (kd + vd) * KV)
+            2 * b * s * e * (attrs.q_columns * H + (kd + vd) * KV)
             + 2 * b * s * vd * attrs.embed_dim * H
         )
         if attrs.latent:
@@ -1085,7 +1200,11 @@ def op_forward_flops(
         q, dk, dv = attrs.chunk_size, attrs.key_dim, attrs.value_dim
         proj = 2 * b * s * (
             d * (attrs.in_proj_width + attrs.value_width)
-            + attrs.gate_rank * (attrs.key_width + attrs.value_width)
+            + (
+                # b | a out of the input, or the two low-rank gates up
+                d * 2 * attrs.num_heads if attrs.per_head_decay
+                else attrs.gate_rank * (attrs.key_width + attrs.value_width)
+            )
         )
         # a position's share of a chunk, a head: both decayed score
         # matrices and T's two products over the chunk's rows, and the

@@ -574,7 +574,9 @@ NODE_PARTS = {
     "experts": ("router", "latent", "routed", "shared"),
     # the gated delta-rule node (`kernels/kda.py`): the chunk-to-chunk pass,
     # the chunks' operands (decayed scores, the triangular inverse), the
-    # gates, the convolution, the gated norm. On the "kda" route the scores'
+    # gates, the convolution, the gated norm (with one decay a head, `prep`
+    # is `head_decay_operands`, XLA's on every route, and `gates` what the
+    # "xla" route's is). On the "kda" route the scores'
     # kernels read q, k and the decay's pre-activation in the model's layout
     # and normalise and take the softplus in VMEM (PR 45), so that is `prep`
     # there, and `gates` holds the two rank-128 gate matmuls, beta's sigmoid,
@@ -584,7 +586,11 @@ NODE_PARTS = {
     "kda": ("scan", "prep", "gates", "conv", "norm"),
     # latent attention (`kernels/ops._latent_mha_forward`): the low-rank
     # key/value projections with their norm, and the attention core
-    "ring_attention": ("latent", "core"),
+    # and of a gated grouped-query node (`kernels/ops._mha_forward` with
+    # `output_gate`): the norm-and-rotary pass over the projected rows and
+    # the gate (its split from the query, its sigmoid on the context),
+    # apart from the flash kernels (`core`)
+    "ring_attention": ("latent", "core", "rows", "gate"),
     # the double-gated short-convolution node (`kernels/short_conv.py`): the
     # input gate, the taps and the output gate between its two projections
     "shortconv": ("conv",),

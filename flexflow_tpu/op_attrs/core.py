@@ -371,10 +371,16 @@ def get_default_weight_initializers(attrs: OpAttrs, num_weights: int):
             :num_weights
         ]
     if isinstance(attrs, RMSNormAttrs):
-        return [ConstantInitializerAttrs(1.0)][:num_weights]
+        # a zero-centred gain's weight starts at zero (a vector's default)
+        one = None if attrs.zero_centered else ConstantInitializerAttrs(1.0)
+        return [one][:num_weights]
     if isinstance(attrs, MultiHeadAttentionAttrs) and attrs.qk_norm:
         # the two QK-norm gains are the last two slots
-        return [None] * (num_weights - 2) + [ConstantInitializerAttrs(1.0)] * 2
+        one = (
+            None if attrs.qk_norm_zero_centered
+            else ConstantInitializerAttrs(1.0)
+        )
+        return [None] * (num_weights - 2) + [one] * 2
     if isinstance(attrs, MultiHeadAttentionAttrs) and attrs.latent:
         # the latent norm's gain is the last slot
         return [None] * (num_weights - 1) + [ConstantInitializerAttrs(1.0)]
@@ -410,6 +416,18 @@ def get_default_weight_initializers(attrs: OpAttrs, num_weights: int):
         # inverse of softplus, A uniform in [1, 16] a head stored as its
         # log, the gate's bias zero (a vector's own default), the gain one
         bound = float(attrs.conv_kernel) ** -0.5
+        if attrs.per_head_decay:
+            # as the public Gated DeltaNet layer starts (recalled;
+            # `assumed`): dt_bias one, A uniform in (0, 16) a value head
+            # stored as its log (the draw held at 1e-3, so that the log is
+            # finite), the gain one
+            return [
+                None, None,
+                UniformInitializerAttrs(min_val=-bound, max_val=bound),
+                ConstantInitializerAttrs(1.0),
+                LogOfUniformInitializerAttrs(1e-3, 16.0),
+                ConstantInitializerAttrs(1.0), None,
+            ][:num_weights]
         return [
             None, UniformInitializerAttrs(min_val=-bound, max_val=bound), None,
             InverseSoftplusLogUniformInitializerAttrs(1e-3, 1e-1, 1e-4),

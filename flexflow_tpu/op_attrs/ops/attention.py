@@ -77,13 +77,46 @@ class MultiHeadAttentionAttrs:
     # this form has a row for a key/value head that several query heads
     # share, so it is the one grouped-query heads take.
     qk_norm_per_head: bool = False
+    # rotary_dim: with `rope_theta`, the rotary turns only the first
+    # `rotary_dim` columns of each head (`partial_rotary_factor` times the
+    # head size): pairs (j, j + rotary_dim / 2), angle
+    # pos * theta^(-2j / rotary_dim); the head's other columns pass. None
+    # turns the whole head, the op as it was.
+    rotary_dim: Optional[int] = None
+    # output_gate: the query projection is [e, h * 2 * kdim]: a head's
+    # 2 * kdim columns are its query, then a gate as wide as its value
+    # (kdim == vdim); the context is multiplied by sigmoid(gate) before wo.
+    # The flat weight (grouped-query layout: a row for a key/value head
+    # that several query heads share) holds the wider wq where wq was.
+    output_gate: bool = False
+    # qk_norm_zero_centered: the two QK-norm weights start at ZERO and the
+    # gains are 1 + w (`RMSNormAttrs.zero_centered`).
+    qk_norm_zero_centered: bool = False
 
     def __post_init__(self):
+        assert self.rotary_dim is None or (
+            self.rope_theta is not None and self.rotary_dim % 2 == 0
+            and 0 < self.rotary_dim <= self.q_proj_size
+        ), f"rotary_dim {self.rotary_dim} needs rope_theta and an even width"
+        assert not self.qk_norm_zero_centered or self.qk_norm, (
+            "qk_norm_zero_centered says how qk_norm_eps norms: it needs one"
+        )
+        if self.output_gate:
+            assert self.num_kv_heads is not None and not self.bias, (
+                "the output gate lives in the grouped-query weight layout "
+                "(num_kv_heads, equal to num_heads where no head is shared) "
+                "and takes no bias"
+            )
+            assert self.q_proj_size == self.v_proj_size, (
+                "a head's gate is as wide as its value, and lies beside its "
+                "query: kdim == vdim"
+            )
         if self.kv_latent_rank is not None:
             assert not (
                 self.bias or self.qk_norm or self.rope_theta is not None
                 or self.num_kv_heads is not None
-            ), "latent attention takes no bias, QK-norm, rotary or grouped heads"
+                or self.output_gate
+            ), "latent attention takes no bias, QK-norm, rotary, gate or grouped heads"
             assert self.kdim > self.shared_key_dim >= 0 and self.vdim > 0, (
                 "latent attention names its key and value widths"
             )
@@ -108,10 +141,17 @@ class MultiHeadAttentionAttrs:
 
     @property
     def grouped_query(self) -> bool:
-        return (
-            self.num_kv_heads is not None
-            and self.num_kv_heads != self.num_heads
+        """Whether the op takes the grouped-query weight layout and path:
+        fewer key/value heads than query heads, or an output gate (whose
+        wider wq has no place in the per-head column)."""
+        return self.num_kv_heads is not None and (
+            self.num_kv_heads != self.num_heads or self.output_gate
         )
+
+    @property
+    def q_columns(self) -> int:
+        """Columns of a head in wq: its query, and its gate where it has."""
+        return (2 if self.output_gate else 1) * self.q_proj_size
 
     @property
     def kv_heads(self) -> int:
@@ -168,7 +208,7 @@ class MultiHeadAttentionAttrs:
         if self.grouped_query:
             h, kv = self.num_heads, self.num_kv_heads
             flat = (
-                q.dims[-1] * h * self.q_proj_size
+                q.dims[-1] * h * self.q_columns
                 + k.dims[-1] * kv * self.k_proj_size
                 + v.dims[-1] * kv * self.v_proj_size
                 + h * self.v_proj_size * self.embed_dim

@@ -1,8 +1,11 @@
-"""GatedDelta: the gated delta-rule linear-attention mixer (Kimi Delta
-Attention, Kimi Linear, arXiv:2510.26692; the delta rule of Yang et al.,
-arXiv:2406.06484 / 2412.06464, with a log-decay for EVERY key channel), one
-node from the residual stream's normed input to its mixer output, as
-`StateSpaceAttrs` is.
+"""GatedDelta: the gated delta-rule linear-attention mixer (the delta rule
+of Yang et al., arXiv:2406.06484 / 2412.06464), one node from the residual
+stream's normed input to its mixer output, as `StateSpaceAttrs` is. Two
+published forms, told apart by `decay`:
+
+**A log-decay for EVERY key channel and a low-rank sigmoid gate** (`decay`
+"channel": Kimi Delta Attention, Kimi Linear, arXiv:2510.26692; as many key
+heads as value heads):
 
     q~ | k~ | v~ | f | z | b = x W_in        # h*dk | h*dk | h*dv | rank | rank | h
     q, k, v = silu(conv1d_causal_depthwise(q~ | k~ | v~; w_conv))   # no bias
@@ -14,6 +17,19 @@ node from the residual stream's normed input to its mixer output, as
     o_t = S_t^T q_t
     y = [ rms_norm_per_head(o; gain [dv]) * sigmoid(z W_g + b_g) ] W_out
 
+**ONE log-decay a value head and a full-width SiLU gate** (`decay` "head":
+Gated DeltaNet, arXiv:2412.06464, as `qwen3_next` writes it), with `num_key_heads` key (and query) heads serving `num_heads` value heads,
+value head h reading key head h // (num_heads / num_key_heads); the state
+is [dk, dv] a VALUE head:
+
+    q~ | k~ | v~ | z = x W_in        # hk*dk | hk*dk | hv*dv | hv*dv
+    b | a = x W_ba                   # hv | hv
+    q, k, v as above (conv, SiLU, the two norms per KEY head)
+    g_t[h] = -exp(A_log[h]) * softplus(a_t[h] + dt_bias[h])   # one a head
+    beta_t[h] = sigmoid(b_t[h])
+    the same recurrence with Diag(exp(g_t)) = exp(g_t[h]) I
+    y = [ rms_norm_per_head(o; gain [dv]) * silu(z) ] W_out
+
 The recurrence is evaluated in chunks of `chunk_size` positions
 (`kernels/kda.py`): inside a chunk the WY / UT form (the inverse of a unit
 lower-triangular [chunk, chunk] matrix a head), from chunk to chunk only the
@@ -22,11 +38,13 @@ pass from the shapes and the backend (Pallas kernels on a TPU at lane-sized
 heads, a `lax.scan` over the chunks everywhere else); the chunking and the
 choice of form change the order of the floating-point sums and nothing else.
 
-weights (slot order): in_proj [D, 2*h*dk + h*dv + 2*rank + h]; conv weight
-[conv_kernel, 2*h*dk + h*dv]; decay up-projection W_f [rank, h*dk]; dt_bias
-[h*dk]; A_log [h]; gate up-projection W_g [rank, h*dv]; gate bias [h*dv];
-norm gain [dv]; out_proj [h*dv, D]. No bias on the projections or the
-convolution.
+weights (slot order), the "channel" form: in_proj [D, 2*h*dk + h*dv + 2*rank
++ h]; conv weight [conv_kernel, 2*h*dk + h*dv]; decay up-projection W_f
+[rank, h*dk]; dt_bias [h*dk]; A_log [h]; gate up-projection W_g
+[rank, h*dv]; gate bias [h*dv]; norm gain [dv]; out_proj [h*dv, D]. The
+"head" form: in_proj [D, 2*hk*dk + 2*hv*dv]; W_ba [D, 2*hv]; conv weight
+[conv_kernel, 2*hk*dk + hv*dv]; dt_bias [hv]; A_log [hv]; norm gain [dv];
+out_proj [hv*dv, D]. No bias on the projections or the convolution.
 
 Parallel rule: batch and nothing else, as the state-space mixer
 (`op_attrs/ops/ssm.py`). Head- or sequence-sharded delta-rule mixers are not
@@ -37,7 +55,7 @@ same item).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List
+from typing import List, Optional
 
 from flexflow_tpu.op_attrs.parallel_tensor_shape import (
     ParallelTensorShape,
@@ -56,6 +74,9 @@ class GatedDeltaAttrs:
     gate_rank: int = 128  # width of the two low-rank gates' down-projections
     chunk_size: int = 64
     norm_eps: float = 1e-5
+    # key (and query) heads; None is one a value head (`num_heads`)
+    num_key_heads: Optional[int] = None
+    decay: str = "channel"  # a log-decay a key "channel", or one a "head"
 
     def __post_init__(self):
         q = self.chunk_size
@@ -63,10 +84,30 @@ class GatedDeltaAttrs:
             f"chunk_size {q}: the decayed scores are built by halving the "
             "chunk (kernels/kda.decayed_scores), so it is a power of two"
         )
+        assert self.decay in ("channel", "head"), (
+            f"decay {self.decay!r}: the weight slots are written for the two "
+            "published forms"
+        )
+        assert self.num_heads % self.key_heads == 0, (
+            f"{self.num_heads} value heads do not divide over "
+            f"{self.key_heads} key heads"
+        )
+        assert self.key_heads == self.num_heads or self.decay == "head", (
+            "the per-channel form's kernels read a value head's own key "
+            "block: fewer key heads come with decay='head'"
+        )
+
+    @property
+    def key_heads(self) -> int:
+        return self.num_key_heads or self.num_heads
+
+    @property
+    def per_head_decay(self) -> bool:
+        return self.decay == "head"
 
     @property
     def key_width(self) -> int:
-        return self.num_heads * self.key_dim
+        return self.key_heads * self.key_dim
 
     @property
     def value_width(self) -> int:
@@ -79,9 +120,13 @@ class GatedDeltaAttrs:
 
     @property
     def in_proj_width(self) -> int:
+        if self.per_head_decay:  # q | k | v | z
+            return self.conv_width + self.value_width
         return self.conv_width + 2 * self.gate_rank + self.num_heads
 
-    num_weights = 9
+    @property
+    def num_weights(self) -> int:
+        return 7 if self.per_head_decay else 9
 
     def _check(self, input: TensorShape) -> None:
         assert input.num_dims == 3, "gated-delta input must be [batch, seq, channel]"
@@ -93,6 +138,16 @@ class GatedDeltaAttrs:
     def weight_shapes(self, input: TensorShape) -> List[TensorShape]:
         self._check(input)
         d, dt = input.dims[-1], input.dtype
+        if self.per_head_decay:
+            return [
+                TensorShape((d, self.in_proj_width), dt),
+                TensorShape((d, 2 * self.num_heads), dt),
+                TensorShape((self.conv_kernel, self.conv_width), dt),
+                TensorShape((self.num_heads,), dt),
+                TensorShape((self.num_heads,), dt),
+                TensorShape((self.value_dim,), dt),
+                TensorShape((self.value_width, d), dt),
+            ]
         return [
             TensorShape((d, self.in_proj_width), dt),
             TensorShape((self.conv_kernel, self.conv_width), dt),

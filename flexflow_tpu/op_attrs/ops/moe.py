@@ -161,6 +161,8 @@ class ExpertsAttrs:
     applied to every token and added to their sum; it has the routed
     experts' form (activation, gated or not) and no bias: ws1 [D, Hs]
     (gated: ws3 [D, Hs]) and ws2 [Hs, out], the last weight slots.
+    shared_gate: the shared expert's output is multiplied, token by token,
+    by sigmoid(x w_sg), w_sg [D, 1]: one more weight slot after ws2.
     held_experts: None, or (first, count): this op HOLDS that range of the
     `num_experts` the router chooses among, and its expert tensors have
     `count` as their leading dim. The router keeps its width and its k; the
@@ -211,8 +213,12 @@ class ExpertsAttrs:
     shared_hidden_size: int = 0
     held_experts: Optional[Tuple[int, int]] = None
     latent_size: Optional[int] = None
+    shared_gate: bool = False
 
     def __post_init__(self):
+        assert not self.shared_gate or self.shared_hidden_size, (
+            "shared_gate gates the shared expert: it needs one"
+        )
         assert not (self.gated and self.use_bias), (
             "the gated expert form has no biases: pass use_bias=False"
         )
@@ -257,6 +263,8 @@ class ExpertsAttrs:
         roles += latent
         if self.shared_hidden_size:
             roles += ["shared"] * (3 if self.gated else 2)
+        if self.shared_gate:
+            roles.append("shared")
         return roles
 
     @property
@@ -308,6 +316,8 @@ class ExpertsAttrs:
             hs = self.shared_hidden_size
             ws += [TensorShape((d, hs), input.dtype)] * (2 if self.gated else 1)
             ws.append(TensorShape((hs, o), input.dtype))
+        if self.shared_gate:
+            ws.append(TensorShape((d, 1), input.dtype))
         return ws
 
     # -- parallel (expert parallelism; see module docstring) ---------------

@@ -67,9 +67,16 @@ class RMSNormAttrs:
     accumulated in float32 whatever the compute dtype. One weight, the gain
     [channels], which starts at one. Always over the last dim: that is the
     only form the published decoders use, and it keeps every leading dim
-    free to shard."""
+    free to shard.
+
+    `zero_centered`: the weight w starts at ZERO and the gain is 1 + w
+    (`x * rsqrt(mean(x^2) + eps) * (1 + w)`). The same function of the
+    gain, another parameter: a weight decay that is an L2 term on every
+    parameter pulls w to zero and so the gain to ONE, where it pulls the
+    plain form's gain to zero."""
 
     eps: float = 1e-5
+    zero_centered: bool = False
 
     def output_shape(self, input: TensorShape) -> TensorShape:
         return input

@@ -249,6 +249,9 @@ class ComputationGraphBuilder:
         shared_key_dim: int = 0,
         kv_latent_norm_eps: float = 1e-5,
         qk_norm_per_head: bool = False,
+        rotary_dim: Optional[int] = None,
+        output_gate: bool = False,
+        qk_norm_zero_centered: bool = False,
     ) -> Tensor:
         """`causal`, `rope_theta` (rotary positions 0..s-1 on q and k) and
         `qk_norm_eps` (RMS norm of the projected q and k over all heads'
@@ -258,12 +261,15 @@ class ComputationGraphBuilder:
         `num_kv_heads` fewer than `num_heads` is grouped-query attention.
         `kv_latent_rank` is latent attention: keys and values from one
         normed low-rank row, the last `shared_key_dim` of a key's `kdim`
-        columns one slice for all heads (`MultiHeadAttentionAttrs`)."""
+        columns one slice for all heads (`MultiHeadAttentionAttrs`).
+        `rotary_dim` turns only a head's first columns, `output_gate` puts a
+        sigmoid gate from the query projection on the context, and
+        `qk_norm_zero_centered` makes the QK-norm gains 1 + w."""
         fields = (
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, rope_theta, qk_norm_eps, num_kv_heads,
             kv_latent_rank, shared_key_dim, kv_latent_norm_eps,
-            qk_norm_per_head,
+            qk_norm_per_head, rotary_dim, output_gate, qk_norm_zero_centered,
         )
         if causal:
             from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
@@ -345,10 +351,14 @@ class ComputationGraphBuilder:
         return out
 
     def rms_norm(
-        self, input: Tensor, eps: float = 1e-5, name: Optional[str] = None
+        self, input: Tensor, eps: float = 1e-5, name: Optional[str] = None,
+        zero_centered: bool = False,
     ) -> Tensor:
-        """RMS norm over the last dim with a gain (no mean, no shift)."""
-        (out,) = self.add_layer(RMSNormAttrs(eps), [input], [], name)
+        """RMS norm over the last dim with a gain (no mean, no shift);
+        `zero_centered`: the gain is 1 + w, w from zero."""
+        (out,) = self.add_layer(
+            RMSNormAttrs(eps, zero_centered), [input], [], name
+        )
         return out
 
     def softmax(self, input: Tensor, dim: int = -1, name: Optional[str] = None) -> Tensor:
@@ -571,6 +581,7 @@ class ComputationGraphBuilder:
         shared_hidden_size: int = 0,
         held_experts: Optional[Tuple[int, int]] = None,
         latent_size: Optional[int] = None,
+        shared_gate: bool = False,
     ) -> List[Tensor]:
         """Fused MoE FFN (`ExpertsAttrs`); returns [out] or, with an
         auxiliary loss coefficient, [out, aux_loss], the scalar recorded in
@@ -597,6 +608,7 @@ class ComputationGraphBuilder:
             shared_hidden_size,
             held_experts,
             latent_size,
+            shared_gate,
         )
         inits = [initializer] * attrs.num_weights
         if selection_bias:
@@ -646,20 +658,25 @@ class ComputationGraphBuilder:
         norm_eps: float = 1e-5,
         initializer: Optional[InitializerAttrs] = None,
         name: Optional[str] = None,
+        num_key_heads: Optional[int] = None,
+        decay: str = "channel",
     ) -> Tensor:
         """The gated delta-rule linear-attention mixer (`GatedDeltaAttrs`) on
-        [batch, seq, channel]. `initializer`, if given, initializes the
-        projections (in, out and the two low-rank gates' up-projections);
-        the convolution, `dt_bias`, `A_log`, the gate's bias and the norm's
-        gain take the op's own defaults."""
+        [batch, seq, channel]: a log-decay a key channel and a low-rank gate,
+        or (`decay` "head") one a value head, `num_key_heads` key heads
+        under `num_heads` value heads and a full-width SiLU gate.
+        `initializer`, if given, initializes the projections (in, out and
+        the two low-rank gates' up-projections, or in, b | a and out); the
+        convolution, `dt_bias`, `A_log`, the gate's bias and the norm's gain
+        take the op's own defaults."""
         from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 
         attrs = GatedDeltaAttrs(
             num_heads, key_dim, value_dim, conv_kernel, gate_rank,
-            chunk_size, norm_eps,
+            chunk_size, norm_eps, num_key_heads, decay,
         )
         inits = [None] * attrs.num_weights
-        for slot in (0, 2, 5, 8):
+        for slot in (0, 1, 6) if attrs.per_head_decay else (0, 2, 5, 8):
             inits[slot] = initializer
         (out,) = self.add_layer(attrs, [input], inits, name)
         return out

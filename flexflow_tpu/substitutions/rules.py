@@ -475,22 +475,27 @@ def column_parallel_embedding_rule(degree: int) -> Substitution:
 
 
 def _experts_pattern(use_bias, gated, with_aux, div=None, shared=False,
-                     latent=False):
+                     latent=False, shared_gate=False):
     """(attribute pattern, weight slots, outputs) of one form of the Experts
     op. The forms differ in their number of weight slots (legacy with and
     without biases, gated; `shared`: the bias-free form with a selection
     bias and a shared expert, two or three more slots; `latent`: the
-    projections into and out of a latent space, two more) and outputs (an
+    projections into and out of a latent space, two more; `shared_gate`: a
+    shared expert with its gate and NO selection bias, the softmax-routed
+    form, three or four more) and outputs (an
     auxiliary scalar or none), and a pattern has a fixed number of both.
     `with_aux` matches lambda_bal != 0 (with or without a z-loss); a z-loss
     alone has no rule."""
-    eq = dict(use_bias=use_bias, gated=gated, selection_bias=shared)
+    eq = dict(
+        use_bias=use_bias, gated=gated, selection_bias=shared,
+        shared_gate=shared_gate,
+    )
     ne = {}
     if not with_aux:
         eq.update(lambda_bal=0.0, lambda_z=0.0)
     else:
         ne.update(lambda_bal=0.0)
-    if shared:
+    if shared or shared_gate:
         ne.update(shared_hidden_size=0)
     else:
         eq.update(shared_hidden_size=0)
@@ -500,45 +505,55 @@ def _experts_pattern(use_bias, gated, with_aux, div=None, shared=False,
         OperatorType.EXPERTS, eq=eq, div=div, ne=ne or None
     )
     num_w = 4 if gated else (5 if use_bias else 3)
-    if shared:
+    if shared or shared_gate:
+        # the shared expert's matrices, and the selection bias or the gate
         num_w += 1 + (3 if gated else 2)
     if latent:
         num_w += 2
     return pattern, num_w, 2 if with_aux else 1
 
 
-def _experts_tag(use_bias, gated, with_aux, shared=False, latent=False):
+def _experts_tag(use_bias, gated, with_aux, shared=False, latent=False,
+                 shared_gate=False):
     form = "g" if gated else ("b" if use_bias else "nb")
     return (
-        f"{form}{'_sh' if shared else ''}{'_lat' if latent else ''}"
+        f"{form}{'_sh' if shared else ''}{'_shg' if shared_gate else ''}"
+        f"{'_lat' if latent else ''}"
         f"{'_aux' if with_aux else ''}"
     )
 
 
 def data_parallel_state_space_rule(
-    degree: int, op_type: OperatorType = OperatorType.STATE_SPACE
+    degree: int, op_type: OperatorType = OperatorType.STATE_SPACE,
+    decay: str = "channel",
 ) -> Substitution:
     """StateSpace(x, w...) -> Combine_0(StateSpace(Repartition_0(x),
     Replicate(w)...)): the scan runs along the sequence of each sample by
     itself, so the batch dim shards and nothing else does. The same rule for
     the gated delta-rule mixer (`op_type` GATED_DELTA), whose recurrence
     runs along the sequence as the scan does, and for the short-convolution
-    mixer (SHORT_CONV), whose taps read the positions before their own."""
+    mixer (SHORT_CONV), whose taps read the positions before their own. The
+    delta-rule mixer has a rule a form (`decay`), because the forms differ
+    in their number of weight slots and a pattern has a fixed number."""
     from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
     from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
     from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 
-    attrs_cls = {
-        OperatorType.STATE_SPACE: StateSpaceAttrs,
-        OperatorType.GATED_DELTA: GatedDeltaAttrs,
-        OperatorType.SHORT_CONV: ShortConvAttrs,
-    }[op_type]
+    tag = op_type.value
+    if op_type == OperatorType.GATED_DELTA:
+        num_weights = GatedDeltaAttrs(2, 4, 4, decay=decay).num_weights
+        attr_pattern = _attr_pattern(op_type, eq=dict(decay=decay))
+        tag += "_head" if decay == "head" else ""
+    else:
+        num_weights = {
+            OperatorType.STATE_SPACE: StateSpaceAttrs,
+            OperatorType.SHORT_CONV: ShortConvAttrs,
+        }[op_type].num_weights
+        attr_pattern = OperatorAttributePattern.for_op_type(op_type)
     p = PCGPattern()
     a = p.add_input(_shard_pattern(0, degree))
-    ws = [p.add_input() for _ in range(attrs_cls.num_weights)]
-    pnode, (py,) = p.add_operator(
-        OperatorAttributePattern.for_op_type(op_type), [a, *ws]
-    )
+    ws = [p.add_input() for _ in range(num_weights)]
+    pnode, (py,) = p.add_operator(attr_pattern, [a, *ws])
     og = OutputGraphExpr()
     oa = og.add_input()
     ows = [og.add_input() for _ in ws]
@@ -550,7 +565,7 @@ def data_parallel_state_space_rule(
     _, (y,) = og.add_operator(CopyAttrsFromMatched(pnode), [ap, *reps])
     _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [y])
     return Substitution(
-        f"data_parallel_{op_type.value}_{degree}",
+        f"data_parallel_{tag}_{degree}",
         p,
         og,
         ((a, oa), *zip(ws, ows)),
@@ -560,7 +575,7 @@ def data_parallel_state_space_rule(
 
 def data_parallel_experts_rule(
     degree: int, use_bias: bool, gated: bool = False, with_aux: bool = False,
-    shared: bool = False, latent: bool = False,
+    shared: bool = False, latent: bool = False, shared_gate: bool = False,
 ) -> Substitution:
     """Experts(x, gate, w...) -> Combine_0(Experts(Repartition_0(x),
     Replicate(gate), Replicate(w)...)): sample parallelism for the MoE FFN.
@@ -571,7 +586,8 @@ def data_parallel_experts_rule(
     expert-parallel rule the auxiliary output is found structurally, not
     interface-mapped."""
     attr_pattern, num_w, num_out = _experts_pattern(
-        use_bias, gated, with_aux, shared=shared, latent=latent
+        use_bias, gated, with_aux, shared=shared, latent=latent,
+        shared_gate=shared_gate,
     )
     p = PCGPattern()
     a = p.add_input(_shard_pattern(0, degree))
@@ -591,7 +607,8 @@ def data_parallel_experts_rule(
     _, (out,) = og.add_operator(AttrConstant(CombineAttrs(0, degree)), [youts[0]])
     return Substitution(
         f"data_parallel_experts_"
-        f"{_experts_tag(use_bias, gated, with_aux, shared, latent)}_{degree}",
+        f"{_experts_tag(use_bias, gated, with_aux, shared, latent, shared_gate)}"
+        f"_{degree}",
         p,
         og,
         ((a, oa), *zip(ws, ows)),
@@ -1121,9 +1138,12 @@ def generate_parallelization_rules(
         rules.append(data_parallel_layer_norm_rule(k))
         rules.append(data_parallel_rms_norm_rule(k))
         rules.append(data_parallel_state_space_rule(k))
-        rules.append(
-            data_parallel_state_space_rule(k, OperatorType.GATED_DELTA)
-        )
+        for decay in ("channel", "head"):
+            rules.append(
+                data_parallel_state_space_rule(
+                    k, OperatorType.GATED_DELTA, decay
+                )
+            )
         rules.append(
             data_parallel_state_space_rule(k, OperatorType.SHORT_CONV)
         )
@@ -1167,6 +1187,10 @@ def generate_parallelization_rules(
         # and the same with its experts in a latent space
         rules.append(
             data_parallel_experts_rule(k, False, shared=True, latent=True)
+        )
+        # the softmax-routed gated form beside a gated shared expert
+        rules.append(
+            data_parallel_experts_rule(k, False, gated=True, shared_gate=True)
         )
         # branch parallelism over stacked isomorphic branches
         # (compiler/branch_stacking.py): shard the stacked leading axis,

@@ -1,0 +1,703 @@
+"""The Gated DeltaNet / output-gated grouped-query / gated-shared-expert tower
+(`benchmark/configs/qwen3-next-80b-a3b.py`) through the public builder and
+`FFModel.compile -> fit`, each part against the plain float32 reference that
+lives with the configuration, at toy size on the CPU with seeded weights.
+Every tolerance states its reason."""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from test_nemotron_h import (
+    BENCH, F32, F32_LOSS, assert_trees_close, bench, rand,
+)
+
+from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import forward as kernel_forward
+from flexflow_tpu.kernels import kda
+from flexflow_tpu.kernels.moe import experts_forward
+from flexflow_tpu.op_attrs.activation import Activation
+from flexflow_tpu.op_attrs.core import (
+    get_default_weight_initializers,
+    get_parallel_output_shapes,
+    get_parallel_weight_shapes,
+    get_weight_shapes,
+)
+from flexflow_tpu.op_attrs.datatype import DataType
+from flexflow_tpu.op_attrs.ops import (
+    ExpertsAttrs,
+    MultiHeadAttentionAttrs,
+    RingAttentionAttrs,
+    RMSNormAttrs,
+)
+from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+from flexflow_tpu.op_attrs.parallel_tensor_shape import (
+    lift_to_parallel_with_degrees,
+)
+from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+CONFIG = os.path.join(BENCH, "configs", "qwen3-next-80b-a3b")
+ref = bench.load_module(CONFIG + ".py")
+
+# 4 value heads over 2 key heads of 8 | 8 in chunks of 8; 8 query heads over
+# 1 key/value head of 16 with a rotary of 4 columns; 4 held of 16 SwiGLU
+# experts of width 24 (top 3) beside a gated shared expert of 24; one whole
+# period G G G A
+TOY = dict(
+    bench.load_json(CONFIG + ".json"),
+    hidden_size=32, head_dim=16, num_attention_heads=8, num_key_value_heads=1,
+    linear_key_head_dim=8, linear_value_head_dim=8, linear_num_key_heads=2,
+    linear_num_value_heads=4, gdn_chunk_size=8, moe_intermediate_size=24,
+    shared_expert_intermediate_size=24, num_experts=4, num_experts_total=16,
+    held_experts_first=4, num_experts_per_tok=3, vocab_rows_held=96,
+    # ten times the published deviation, as in the other towers' tests: at
+    # toy width 0.02 leaves every activation so small that a wrong term
+    # would hide inside a tolerance
+    initializer_range=0.2,
+)
+BATCH = 4
+ADAM = TOY["training"]
+
+# gradients through the chunked recurrence, the triangular inverse and two
+# projections in float32 on the CPU: sums of a few hundred products in
+# another order than the token-by-token recurrence's, on gradients of up to
+# a hundred. Measured 3.9e-4 relative (6e-4 absolute) at worst here.
+F32_GRADS = dict(rtol=1e-3, atol=1e-3)
+
+
+# -- the delta rule with one decay a head ----------------------------------------
+
+
+def gdn_attrs(sizes=TOY):
+    return GatedDeltaAttrs(
+        sizes["linear_num_value_heads"], sizes["linear_key_head_dim"],
+        sizes["linear_value_head_dim"], sizes["linear_conv_kernel_dim"],
+        chunk_size=sizes["gdn_chunk_size"], norm_eps=sizes["rms_norm_eps"],
+        num_key_heads=sizes["linear_num_key_heads"], decay="head",
+    )
+
+
+def gdn_case(seq, key_heads, seed=1, batch=2):
+    """(sizes, u [b, s, D], the op's weights in slot order), with decays
+    strong enough that exp(-G) taken from a chunk's start would overflow:
+    -exp(A_log) softplus(a + dt_bias) is about -25 a position, -200 over a
+    chunk of 8, and float32 ends at e^88."""
+    sizes = dict(TOY, linear_num_key_heads=key_heads)
+    attrs = gdn_attrs(sizes)
+    rs = np.random.RandomState(seed)
+    d = sizes["hidden_size"]
+    shapes = attrs.weight_shapes(TensorShape((batch, seq, d), DataType.FLOAT))
+    ws = [rand(rs, *shape.dims, scale=0.3) for shape in shapes]
+    hv = attrs.num_heads
+    ws[3] = 1.0 + rand(rs, hv, scale=0.1)  # dt_bias
+    ws[4] = jnp.log(jnp.asarray(rs.uniform(15.0, 25.0, hv), jnp.float32))
+    ws[5] = 1.0 + rand(rs, attrs.value_dim, scale=0.2)  # the norm's gain
+    return sizes, rand(rs, batch, seq, d), ws
+
+
+def reference_gdn(sizes, u, ws):
+    w = {f"g.weight{i}": t for i, t in enumerate(ws)}
+    with jax.default_matmul_precision("highest"):
+        return jax.vmap(lambda row: ref.gated_delta_net(w, "g", row, sizes))(u)
+
+
+def program_gdn(sizes, u, ws):
+    with jax.default_matmul_precision("highest"):
+        return kernel_forward(gdn_attrs(sizes), [u], ws)[0]
+
+
+def test_head_decay_slots_and_shapes():
+    attrs = GatedDeltaAttrs(
+        32, 128, 128, num_key_heads=16, decay="head"
+    )
+    x = TensorShape((1, 8192, 2048), DataType.FLOAT)
+    assert [w.dims for w in get_weight_shapes(attrs, [x])] == [
+        (2048, 12288), (2048, 64), (4, 8192), (32,), (32,), (128,),
+        (4096, 2048),
+    ]
+    assert attrs.num_weights == 7 and attrs.key_heads == 16
+    # the per-channel form keeps its nine slots and its in-projection
+    kimi = GatedDeltaAttrs(32, 128, 128)
+    assert kimi.num_weights == 9 and kimi.in_proj_width == 3 * 4096 + 256 + 32
+    batch = lift_to_parallel_with_degrees(x, 1, 1, (1, 1, 1))
+    (out,) = get_parallel_output_shapes(attrs, [batch])
+    assert out.sum_degree == 1
+    assert len(get_parallel_weight_shapes(attrs, [batch])) == 7
+    # dt_bias from one, the gain from one, A_log a log of a positive draw
+    inits = get_default_weight_initializers(attrs, 7)
+    assert inits[3].value == 1.0 and inits[5].value == 1.0
+    assert inits[4].min_val > 0.0
+
+
+def test_unwritten_forms_are_refused_and_say_why():
+    with pytest.raises(AssertionError, match="two published forms"):
+        GatedDeltaAttrs(4, 8, 8, decay="row")
+    with pytest.raises(AssertionError, match="come with decay='head'"):
+        GatedDeltaAttrs(4, 8, 8, num_key_heads=2)
+    with pytest.raises(AssertionError, match="do not divide"):
+        GatedDeltaAttrs(4, 8, 8, num_key_heads=3, decay="head")
+
+
+@pytest.mark.parametrize("key_heads", [4, 2])
+@pytest.mark.parametrize("seq", [32, 27])
+def test_head_decay_node_matches_the_token_by_token_recurrence(seq, key_heads):
+    """Forward, and the gradient of the input and of every weight, at one
+    and at two value heads a key head, at a whole number of chunks and not."""
+    sizes, u, ws = gdn_case(seq, key_heads)
+    cot = rand(np.random.RandomState(2), *u.shape)
+    np.testing.assert_allclose(
+        program_gdn(sizes, u, ws), reference_gdn(sizes, u, ws), **F32
+    )
+
+    def grads(fn):
+        return jax.grad(
+            lambda u, ws: jnp.sum(fn(sizes, u, ws) * cot), (0, 1)
+        )(u, ws)
+
+    assert_trees_close(grads(program_gdn), grads(reference_gdn), **F32_GRADS)
+
+
+def test_scalar_decay_operands_are_the_per_channel_ones_on_a_broadcast_decay():
+    """`head_decay_operands` (one masked product for A, one for P) against
+    `chunk_operands` (the levels) given each head's decay on all its key
+    channels and each key head repeated for its value heads: the same six
+    operands, to float32 rounding of sums taken in another order."""
+    rs = np.random.RandomState(3)
+    b, hk, group, s, d, chunk = 1, 2, 2, 32, 8, 8
+    hv = hk * group
+    q = kda._unit(rand(rs, b, hk, s, d), d ** -0.5)
+    k = kda._unit(rand(rs, b, hk, s, d), 1.0)
+    v = rand(rs, b, hv, s, d)
+    g = -30.0 * jnp.asarray(rs.rand(b, hv, s), jnp.float32)
+    beta = jnp.asarray(rs.rand(b, hv, s), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        scalar = kda.head_decay_operands(q, k, v, g, beta, chunk)
+        channel = kda.chunk_operands(
+            jnp.repeat(q, group, axis=1), jnp.repeat(k, group, axis=1), v,
+            jnp.broadcast_to(g[..., None], (b, hv, s, d)), beta, chunk,
+        )
+    for got, want in zip(scalar, channel):
+        np.testing.assert_allclose(got, want, **F32)
+
+
+# -- the gated grouped-query attention node --------------------------------------
+
+
+def attention_attrs(sizes=TOY, **changes):
+    fields = dict(
+        kdim=sizes["head_dim"], vdim=sizes["head_dim"], causal=True,
+        rope_theta=float(sizes["rope_theta"]),
+        rotary_dim=ref.rotary_dim(sizes), qk_norm_eps=sizes["rms_norm_eps"],
+        qk_norm_per_head=True, qk_norm_zero_centered=True,
+        num_kv_heads=sizes["num_key_value_heads"], output_gate=True,
+    )
+    fields.update(changes)
+    return RingAttentionAttrs(
+        sizes["hidden_size"], sizes["num_attention_heads"], **fields
+    )
+
+
+def attention_case(attrs, seq=24, seed=3, batch=2):
+    """(u, [flat weight, w_q, w_k]) with the zero-centred weights away from
+    zero."""
+    rs = np.random.RandomState(seed)
+    x = TensorShape((batch, seq, attrs.embed_dim), DataType.FLOAT)
+    flat = rand(rs, *attrs.weights_shape(x, x, x).dims, scale=0.3)
+    gains = [rand(rs, attrs.q_proj_size, scale=0.3) for _ in range(2)]
+    return rand(rs, batch, seq, attrs.embed_dim), [flat, *gains]
+
+
+def plain_attention(attrs, u, ws):
+    """The node by hand for any setting of the new attributes, one sequence
+    at a time: a query head's columns in wq are its query, then its gate
+    where it has one."""
+    h, kv, d, e = attrs.num_heads, attrs.kv_heads, attrs.q_proj_size, attrs.embed_dim
+    cols = 2 * d if attrs.output_gate else d
+    cuts = np.cumsum([0, e * h * cols, e * kv * d, e * kv * d, h * d * e])
+    flat = ws[0].reshape(-1)
+    wq = flat[cuts[0]:cuts[1]].reshape(e, h, cols)
+    wk = flat[cuts[1]:cuts[2]].reshape(e, kv, d)
+    wv = flat[cuts[2]:cuts[3]].reshape(e, kv, d)
+    wo = flat[cuts[3]:cuts[4]].reshape(h, d, e)
+    width = attrs.rotary_dim or d
+
+    def one(row):
+        both = jnp.einsum("se,ehd->hsd", row, wq)
+        q = ref.zrms(both[..., :d], ws[1], attrs.qk_norm_eps)
+        k = ref.zrms(jnp.einsum("se,ehd->hsd", row, wk), ws[2], attrs.qk_norm_eps)
+        q, k = (ref.rope(t, attrs.rope_theta, width) for t in (q, k))
+        v = jnp.einsum("se,ehd->hsd", row, wv)
+        k, v = (jnp.repeat(t, h // kv, axis=0) for t in (k, v))
+        ctx = ref.causal_attention(q, k, v)
+        if attrs.output_gate:
+            ctx = ctx * jax.nn.sigmoid(both[..., d:])
+        return jnp.einsum("hsd,hde->se", ctx, wo)
+
+    with jax.default_matmul_precision("highest"):
+        return jax.vmap(one)(u)
+
+
+def program_attention(attrs, u, ws):
+    with jax.default_matmul_precision("highest"):
+        return kernel_forward(attrs, [u, u, u], ws)[0]
+
+
+def test_gated_attention_slots_and_refusals():
+    attrs = attention_attrs(dict(TOY, hidden_size=2048, head_dim=256,
+                                 num_attention_heads=16, num_key_value_heads=2))
+    x = TensorShape((1, 8192, 2048), DataType.FLOAT)
+    shapes = [w.dims for w in get_weight_shapes(attrs, [x, x, x])]
+    # wq with the gates, wk, wv, wo in one column; the two norm weights
+    assert shapes == [
+        (2048 * 16 * 512 + 2 * 2048 * 2 * 256 + 4096 * 2048, 1), (256,), (256,)
+    ]
+    # zero-centred norm weights start at zero (a vector's own default)
+    assert get_default_weight_initializers(attrs, 3)[1:] == [None, None]
+    plain = attention_attrs(qk_norm_zero_centered=False)
+    assert get_default_weight_initializers(plain, 3)[1].value == 1.0
+    with pytest.raises(AssertionError, match="grouped-query weight layout"):
+        MultiHeadAttentionAttrs(32, 4, output_gate=True)
+    with pytest.raises(AssertionError, match="needs rope_theta"):
+        MultiHeadAttentionAttrs(32, 4, rotary_dim=4)
+    with pytest.raises(AssertionError, match="it needs one"):
+        MultiHeadAttentionAttrs(32, 4, qk_norm_zero_centered=True)
+    # the gate is a batch-parallel node like any grouped-query one, and not
+    # head-parallel: its projections lie in one flat column
+    two = TensorShape((2, 8192, 2048), DataType.FLOAT)
+    batch = lift_to_parallel_with_degrees(two, 1, 1, (2, 1, 1))
+    (out,) = get_parallel_output_shapes(attrs, [batch] * 3)
+    assert out.shard_dim_at(0).degree == 2
+    heads = lift_to_parallel_with_degrees(two, 1, 2, (1, 1, 1))
+    with pytest.raises(AssertionError, match="head-parallel"):
+        get_parallel_output_shapes(attrs, [heads] * 3)
+
+
+def test_published_attention_matches_the_reference():
+    """Gate on, a rotary of a quarter of the head, 8 query heads on 1
+    key/value head, zero-centred norm weights away from zero: the
+    configuration's own reference."""
+    attrs = attention_attrs()
+    u, ws = attention_case(attrs)
+    w = {f"a.weight{i}": t for i, t in enumerate(ws)}
+    with jax.default_matmul_precision("highest"):
+        want = jax.vmap(lambda row: ref.attention(w, "a", row, TOY))(u)
+    np.testing.assert_allclose(program_attention(attrs, u, ws), want, **F32)
+    np.testing.assert_allclose(plain_attention(attrs, u, ws), want, **F32)
+
+
+@pytest.mark.parametrize("gate", [True, False])
+@pytest.mark.parametrize("rotary", [4, 16])
+def test_gate_and_rotary_width_each_by_itself(gate, rotary):
+    """The gate on and off, the rotary on a quarter and on the whole head,
+    each against the node written by hand; forward and the gradients."""
+    attrs = attention_attrs(output_gate=gate, rotary_dim=rotary)
+    u, ws = attention_case(attrs, seed=4)
+    np.testing.assert_allclose(
+        program_attention(attrs, u, ws), plain_attention(attrs, u, ws), **F32
+    )
+    cot = rand(np.random.RandomState(5), *u.shape)
+
+    def grads(fn):
+        return jax.grad(
+            lambda u, ws: jnp.sum(fn(attrs, u, ws) * cot), (0, 1)
+        )(u, ws)
+
+    assert_trees_close(
+        grads(program_attention), grads(plain_attention), **F32_GRADS
+    )
+    # the whole-head width is the op as it was before the attribute
+    if rotary == 16:
+        same = attention_attrs(output_gate=gate, rotary_dim=None)
+        np.testing.assert_array_equal(
+            program_attention(same, u, ws), program_attention(attrs, u, ws)
+        )
+
+
+def test_grouped_flash_entry_reads_a_key_head_in_place(monkeypatch):
+    """`flash_attention_bshf_grouped` in interpret mode, 4 query heads on 2
+    key/value heads of 128 over two causal tiles, against dense attention on
+    the repeated heads: forward and the three gradients, dk and dv summed
+    over the group. bf16 kernels against a float32 dense form: 2e-2 is a
+    few bf16 roundings (2^-9) of values of order one through a sum of 1,024
+    terms."""
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    rs = np.random.RandomState(6)
+    b, s, h, kv, d = 1, 1024, 4, 2, 128
+    q = rand(rs, b, s, h * d, scale=0.5).astype(jnp.bfloat16)
+    k = rand(rs, b, s, kv * d, scale=0.5).astype(jnp.bfloat16)
+    v = rand(rs, b, s, kv * d, scale=0.5).astype(jnp.bfloat16)
+    cot = rand(rs, b, s, h * d)
+
+    def kernels(q, k, v):
+        return fa.flash_attention_bshf_grouped(q, k, v, h, kv, interpret=True)
+
+    def dense(q, k, v):
+        qh = q.astype(jnp.float32).reshape(b, s, h, d)
+        kh, vh = (
+            jnp.repeat(t.astype(jnp.float32).reshape(b, s, kv, d), h // kv, 2)
+            for t in (k, v)
+        )
+        scores = jnp.einsum("bshd,bthd->bhst", qh, kh) * d ** -0.5
+        scores = jnp.where(np.tri(s, dtype=bool), scores, -jnp.inf)
+        ctx = jnp.einsum("bhst,bthd->bshd", jax.nn.softmax(scores, -1), vh)
+        return ctx.reshape(b, s, h * d)
+
+    tol = dict(rtol=2e-2, atol=2e-2)
+    np.testing.assert_allclose(
+        kernels(q, k, v).astype(jnp.float32), dense(q, k, v), **tol
+    )
+
+    def grads(fn):
+        out = jax.grad(
+            lambda *ops: jnp.sum(fn(*ops).astype(jnp.float32) * cot), (0, 1, 2)
+        )(q, k, v)
+        return [t.astype(jnp.float32) for t in out]
+
+    # gradients of sums over up to 1,024 positions and 2 heads of a group
+    for got, want in zip(grads(kernels), grads(dense)):
+        np.testing.assert_allclose(got, want, rtol=5e-2, atol=1e-1)
+    # which shapes take it: whole rows that leave the causal forward no room
+    # in the default scope, read from the shapes alone
+    big = attention_attrs(dict(TOY, hidden_size=2048, head_dim=256,
+                               num_attention_heads=16, num_key_value_heads=2))
+    from flexflow_tpu.kernels.ops import mha_reads_kv_in_place
+
+    assert mha_reads_kv_in_place(big, 8192, 2)
+    assert not mha_reads_kv_in_place(big, 4096, 2)  # 8 MB of rows: fits
+    lfm2 = RingAttentionAttrs(2048, 32, kdim=128, vdim=128, causal=True,
+                              num_kv_heads=8)
+    assert not mha_reads_kv_in_place(lfm2, 8192, 2)
+
+
+# -- the zero-centred norm -------------------------------------------------------
+
+
+def test_zero_centred_norm_is_the_plain_norm_at_one_plus_w():
+    rs = np.random.RandomState(7)
+    x, w = rand(rs, 3, 5, 16), rand(rs, 16, scale=0.3)
+    zero_centred = RMSNormAttrs(1e-6, zero_centered=True)
+    got = kernel_forward(zero_centred, [x], [w])[0]
+    np.testing.assert_allclose(got, ref.zrms(x, w, 1e-6), **F32)
+    np.testing.assert_allclose(
+        got, kernel_forward(RMSNormAttrs(1e-6), [x], [1.0 + w])[0], **F32
+    )
+    # w starts at zero, where the plain form's gain starts at one
+    assert get_default_weight_initializers(zero_centred, 1) == [None]
+    assert get_default_weight_initializers(RMSNormAttrs(1e-6), 1)[0].value == 1.0
+    batch = lift_to_parallel_with_degrees(
+        TensorShape((4, 8, 16), DataType.FLOAT), 1, 1, (2, 1, 1)
+    )
+    assert zero_centred.parallel_gamma_shape(batch).discard_copy_degree == 2
+
+
+# -- the gated shared expert and the shares --------------------------------------
+
+
+def experts_attrs(held, sizes=TOY, total=16):
+    return ExpertsAttrs(
+        total, sizes["num_experts_per_tok"], sizes["moe_intermediate_size"],
+        activation=Activation.SILU, capacity_factor=None, use_bias=False,
+        gated=True, renormalize=True, scoring="softmax",
+        shared_hidden_size=sizes["shared_expert_intermediate_size"],
+        shared_gate=True, held_experts=held,
+    )
+
+
+def experts_case(total, seed=8, seq=24):
+    rs = np.random.RandomState(seed)
+    d, width = TOY["hidden_size"], TOY["moe_intermediate_size"]
+    named = {
+        "e.weight0": rand(rs, d, total),
+        "e.weight1": rand(rs, total, d, width, scale=0.3),
+        "e.weight2": rand(rs, total, d, width, scale=0.3),
+        "e.weight3": rand(rs, total, width, d, scale=0.3),
+        "e.weight4": rand(rs, d, width, scale=0.3),
+        "e.weight5": rand(rs, d, width, scale=0.3),
+        "e.weight6": rand(rs, width, d, scale=0.3),
+        "e.weight7": rand(rs, d, 1, scale=0.5),
+    }
+    return named, rand(rs, seq, d)
+
+
+def share_of(named, first, count):
+    ws = [named[f"e.weight{i}"] for i in range(8)]
+    return [ws[0]] + [w[first:first + count] for w in ws[1:4]] + ws[4:]
+
+
+def test_shared_gate_slot_and_its_refusal():
+    attrs = experts_attrs((0, 4))
+    x = TensorShape((2, 24, 32), DataType.FLOAT)
+    shapes = [w.dims for w in get_weight_shapes(attrs, [x])]
+    assert shapes[-1] == (32, 1) and len(shapes) == 8
+    assert attrs.weight_roles()[-4:] == ["shared"] * 4
+    with pytest.raises(AssertionError, match="needs one"):
+        ExpertsAttrs(4, 2, 8, use_bias=False, shared_gate=True)
+
+
+def test_gated_shared_expert_matches_the_reference():
+    named, m = experts_case(16)
+    with jax.default_matmul_precision("highest"):
+        got = experts_forward(
+            experts_attrs((0, 16)), m[None], share_of(named, 0, 16)
+        )[0][0]
+        want, _ = ref.experts(named, "e", m, TOY, held=(0, 16))
+        ungated = want - ref.experts(named, "e", m, TOY, held=(0, 16),
+                                     shared=False)[0]
+    np.testing.assert_allclose(got, want, **F32)
+    assert float(jnp.max(jnp.abs(ungated))) > 1e-3  # the shared part is there
+
+
+def test_sixteen_shares_add_up_to_the_uncut_layer():
+    """The model's own split in miniature, 32 experts in 16 shares of 2 (512
+    in 16 of 32 in the deployment): the shares' routed parts, with the gated
+    shared expert counted ONCE, add up to the uncut reference layer over all
+    32 experts."""
+    named, m = experts_case(32, seed=9)
+    with jax.default_matmul_precision("highest"):
+        parts = [
+            experts_forward(
+                experts_attrs((first, 2), total=32), m[None],
+                share_of(named, first, 2),
+            )[0][0]
+            for first in range(0, 32, 2)
+        ]
+        whole, _ = ref.experts(named, "e", m, TOY, held=(0, 32))
+        shared = whole - ref.experts(
+            named, "e", m, TOY, held=(0, 32), shared=False
+        )[0]
+    routed = [part - shared for part in parts]
+    assert sum(float(jnp.max(jnp.abs(r))) > 1e-3 for r in routed) >= 8
+    np.testing.assert_allclose(sum(routed) + shared, whole, **F32)
+
+
+# -- the rules -------------------------------------------------------------------
+
+
+def test_new_rules_audit_sound():
+    from flexflow_tpu.analysis.rule_audit import audit_substitution
+    from flexflow_tpu.op_attrs.core import OperatorType
+    from flexflow_tpu.substitutions.rules import (
+        data_parallel_experts_rule,
+        data_parallel_state_space_rule,
+    )
+
+    for rule in (
+        data_parallel_state_space_rule(2, OperatorType.GATED_DELTA, "head"),
+        data_parallel_state_space_rule(2, OperatorType.GATED_DELTA),
+        data_parallel_experts_rule(2, False, gated=True, shared_gate=True),
+    ):
+        audit = audit_substitution(rule)
+        assert audit.status == "ok", (rule.name, audit.diagnostics)
+
+
+# -- the whole tiny tower through FFModel ----------------------------------------
+
+
+def data(seq, seed=0):
+    return ref.make_data(np.random.RandomState(seed), TOY, BATCH, seq)
+
+
+def compiled_model(seq, compute_dtype=None, sizes=TOY, **config):
+    builder, logits = ref.build(sizes, BATCH, seq)
+    model = FFModel.from_computation_graph(
+        builder, logits,
+        FFConfig(batch_size=BATCH, seed=7, print_freq=0, **config),
+    )
+    model.compile(
+        AdamOptimizer(
+            alpha=ADAM["alpha"], beta1=ADAM["beta1"], beta2=ADAM["beta2"],
+            epsilon=ADAM["epsilon"], weight_decay=ADAM["weight_decay"],
+        ),
+        ADAM["loss"], compute_dtype=compute_dtype,
+    )
+    return model
+
+
+def system_loss(model, inputs, labels):
+    read = bench.make_loss_reader(model.instance)
+    batch, label = bench.place_batch(model.instance, inputs, labels)
+    return read(model.params, batch, label)
+
+
+def norms_away_from_zero(model):
+    """Every zero-centred weight of the model moved off its zero start, so
+    that the L2 term of the step pulls on it."""
+    from test_olmoe import weight_keys
+
+    rs = np.random.RandomState(11)
+    for name, key in weight_keys(model.instance).items():
+        zero_centred = name.startswith("norm") or name in (
+            "attn3.weight1", "attn3.weight2"
+        )
+        if zero_centred:
+            model.params[key] = rand(rs, *model.params[key].shape, scale=0.3)
+
+
+def test_layers_are_the_published_period():
+    assert ref.layer_names(TOY) == [(0, "G"), (1, "G"), (2, "G"), (3, "A")]
+    assert ref.counts(TOY) == (3, 1, 0, 4)
+
+
+def test_zero_centred_weights_start_at_zero_and_the_gain_at_one():
+    model = compiled_model(24, max_devices=1)
+    named = bench.named_parameters(model.instance, model.params)
+    for name in ("norm0a", "norm3b", "norm_f"):
+        assert float(jnp.max(jnp.abs(named[f"{name}.weight0"]))) == 0.0
+    for j in (1, 2):
+        assert float(jnp.max(jnp.abs(named[f"attn3.weight{j}"]))) == 0.0
+    np.testing.assert_array_equal(named["gdn0.weight5"], 1.0)
+    np.testing.assert_array_equal(named["gdn0.weight3"], 1.0)
+    assert float(jnp.max(named["gdn1.weight4"])) <= np.log(16.0)
+
+
+def test_fit_step_matches_reference_adam_step():
+    """The four-layer tower's loss before and after one `fit` step against
+    the reference's own gradient and Adam step, the zero-centred weights
+    away from zero so that the step's L2 term pulls them (to a gain of one,
+    not of zero): 1e-5 is float32 rounding through two forward passes and
+    the update. The routing counters report the held rows of the model's
+    four expert nodes."""
+    from flexflow_tpu.observability import routing, trace
+
+    seq = 24
+    model = compiled_model(seq, max_devices=1)
+    norms_away_from_zero(model)
+    inputs, labels = data(seq)
+    named = bench.named_parameters(model.instance, model.params)
+    assert float(jnp.max(jnp.abs(named["norm2a.weight0"]))) > 0.1
+    before, after = ref.reference_losses(named, inputs, labels, TOY, ADAM)
+    # the L2 term is felt: the same step on the reference with the decay off
+    # ends elsewhere by more than the bound (`fit` donates the parameters,
+    # so every reading of `named` comes before it)
+    no_decay = dict(ADAM, weight_decay=0.0)
+    _, undecayed = ref.reference_losses(named, inputs, labels, TOY, no_decay)
+    assert abs(undecayed - after) > 10 * F32_LOSS
+    assert abs(system_loss(model, inputs, labels) - before) <= F32_LOSS
+    model.fit(inputs, labels, epochs=1, shuffle=False, verbose=False)
+    assert abs(system_loss(model, inputs, labels) - after) <= F32_LOSS
+    assert before - after > 100 * F32_LOSS  # the step did something
+    counted = routing.published()
+    assert counted["nodes"] == ["moe0", "moe1", "moe2", "moe3"]
+    assert list(counted["decisions"]) == [BATCH * seq * 3] * 4  # one step
+    assert 0.0 < counted["held_rows_pct"] < 100.0
+    assert trace.attention_routes()["ff.ring_attention.attn3"] == "dense"
+
+
+def test_bf16_compute_is_inside_its_tolerance_and_outside_float32s():
+    """The same graph at bf16 compute: inside 2e-2 (a mean over 96 positions
+    averages little rounding away) and outside the float32 bound, so the
+    float32 tests above would catch a bf16 path."""
+    seq = 24
+    model = compiled_model(seq, compute_dtype=jnp.bfloat16, max_devices=1)
+    inputs, labels = data(seq)
+    named = bench.named_parameters(model.instance, model.params)
+    before, _ = ref.reference_losses(named, inputs, labels, TOY, ADAM)
+    off = abs(system_loss(model, inputs, labels) - before)
+    assert 10 * F32_LOSS < off < 2e-2, off
+
+
+def test_data_parallel_plan_shards_the_new_ops_and_trains():
+    """The batch template on four devices through the searched backend: the
+    head-decay delta-rule nodes, the gated attention and the experts beside
+    their gated shared expert are sharded over the batch (no node left
+    serial), the loss is the one-device loss, and a step reduces it."""
+    seq = 24
+    inputs, labels = data(seq)
+    one = compiled_model(seq, max_devices=1)
+    four = compiled_model(
+        seq, max_devices=4, search_budget=2,
+        force_strategy_seed="dp4xtp1xsp1",
+    )
+    from flexflow_tpu.parallel.executor import DistributedTrainingInstance
+    from test_olmoe import weight_keys
+
+    assert isinstance(four.instance, DistributedTrainingInstance)
+    assert four.search_provenance["serial_compute_nodes"] == []
+    keys1, keys4 = weight_keys(one.instance), weight_keys(four.instance)
+    assert set(keys1) == set(keys4)
+    one.params = {
+        keys1[name]: jnp.asarray(np.asarray(four.params[keys4[name]]))
+        for name in keys1
+    }
+    first = system_loss(four, inputs, labels)
+    assert abs(first - system_loss(one, inputs, labels)) <= F32_LOSS
+    four.fit(inputs, labels, epochs=1, shuffle=False, verbose=False)
+    assert system_loss(four, inputs, labels) < first - 0.01
+
+
+def test_arithmetic_of_the_published_cut_by_hand():
+    sizes = bench.load_json(CONFIG + ".json")
+    # the graph at the published widths (shapes only, nothing is allocated):
+    # its weights add up to the configuration's `parameters.as_built`
+    from flexflow_tpu.op_attrs.ops import WeightAttrs
+
+    builder, _ = ref.build(sizes, 1, 8192)
+    graph = builder.graph
+    built = sum(
+        int(np.prod(graph.tensor_attrs(graph.outputs_of(n)[0]).shape.dims))
+        for n in graph.topological_ordering()
+        if isinstance(graph.op_attrs(n), WeightAttrs)
+    )
+    assert built == 625_667_136
+    assert sizes["parameters"]["as_built"].startswith("625,667,136 ")
+    costs = ref.kernel_costs(sizes, 1, 8192)
+    tokens = 8192
+    half = 32.5  # (64 + 1) / 2 positions of a chunk
+    # K K^T and Q K^T at 16 key heads; the scores' product with U, the
+    # triangular solve for 128 + 128 columns and three [128, 128] products a
+    # state at 32 value heads; three passes, three nodes
+    scan = 16 * 2 * 2 * half * 128 + 32 * (
+        2 * half * 128 + 2 * half * 256 + 6 * 128 * 128
+    )
+    assert costs["gdn_scan"]["flops"] == 3 * tokens * 3 * scan
+    # q, k at 16 heads and v, o at 32 in bf16; the decay and beta in float32
+    assert costs["gdn_scan"]["bytes"] == 3 * tokens * 3 * (
+        2 * (2 * 2048 + 2 * 4096) + 8 * 32
+    )
+    pairs = 8192 * 8193 / 2
+    # 2 products forward and 5 backward over the causal half, 16 heads of 256
+    assert costs["flash"]["flops"] == 7 * 2 * pairs * 16 * 256
+    # q, o (16 heads) and k, v (2 heads) once forward; with do and the three
+    # gradients once backward
+    assert costs["flash"]["bytes"] == 6 * 2 * tokens * 256 * (16 + 2)
+    forward = (
+        3 * (2 * 2048 * (12288 + 64 + 4096) + scan)
+        + 2 * 2048 * 256 * (3 * 16 + 2 * 2) + 2 * 2 * pairs * 16 * 256 / 8192
+        + 4 * (2 * 2048 * 513 + 3 * 2 * 2048 * (512 * 10 * 32 / 512 + 512))
+        + 2 * 2048 * 18992
+    )
+    assert ref.flops_per_token(sizes, 8192) == 3.0 * forward
+
+
+# -- the benchmark's CPU rehearsal of the cell -----------------------------------
+
+
+def test_rehearsal_cell_runs_correct_on_the_cpu_mesh(tmp_path):
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    done = subprocess.run(
+        [sys.executable, os.path.join(BENCH, "run.py"), "--manifest",
+         os.path.join(BENCH, "rehearsal-qwen3next.json"), "--workload",
+         "rehearsal_qwen3next_s128_1chip", "--seed", "2147483659", "--seconds",
+         "1", "--trace", "1"],
+        env=env, capture_output=True, text=True, timeout=600,
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    result = json.loads(done.stdout.strip().splitlines()[-1])
+    assert result["correct"], (result["checks"], result["losses"])
+    assert result["device"]["platform"] == "cpu"
+    # no device trace on the CPU mesh: the four trace readers return nothing;
+    # the routing counter is the program's and reads here too
+    for name in ("gdn_ms", "gdn_scan_roofline", "gqa256_flash_roofline",
+                 "qwen3next_moe_held_ms"):
+        assert name not in result["metrics"]
+    assert 0.0 < result["metrics"]["qwen3next_held_rows_pct"]["value"] < 100.0
+    assert "qwen3-next reference routing" in done.stderr
+    assert '"windows_per_step_by_node"' in done.stderr

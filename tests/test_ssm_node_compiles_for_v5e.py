@@ -54,6 +54,17 @@ keeps its bf16 one, in the loop's body), and compile under the VMEM limit the
 kernel states (k slots a token of a tile, the accumulator and the out block's
 two buffers).
 
+And what the `qwen3_next` cell added (PR 51), at its shape, one sequence of
+8,192 positions: ONE gated delta-rule node with one decay a head, 32 value
+heads over 16 key heads of 128 | 128, forward and backward, whose
+chunk-to-chunk pass must come out as the same three Pallas kernels and its
+triangular inverse as the fourth (the chunks' operands are XLA's), and which
+must hold no state per position either; and ONE output-gated grouped-query
+node of 16 query heads over 2 key/value heads of 256, forward and backward,
+whose core must be the three `*_grouped` kernels (forward, delta, backward):
+the entries that keep k and v as whole rows under the default scope do not
+compile at this length and head size.
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -602,6 +613,97 @@ def check_held_sums():
     return found
 
 
+QWEN3NEXT_INVARIANTS = [
+    "head_decay_node_compiles_with_the_pass_and_inverse_kernels",
+    "head_decay_node_keeps_no_state_per_position",
+    "gated_d256_attention_compiles_on_the_grouped_kernels",
+]
+QWEN3NEXT_SHAPE = (1, 8192, 2048)
+
+
+def check_qwen3next():
+    """{invariant: "ok" or what was found} for the `qwen3_next` cell's two
+    new nodes at the published shape."""
+    import jax
+
+    from flexflow_tpu.kernels import kda, ops
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
+    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    found = {}
+    on_chip = _described_chip()
+    x = on_chip(QWEN3NEXT_SHAPE)
+    shape = TensorShape(QWEN3NEXT_SHAPE, DataType.FLOAT)
+    try:
+        attrs = GatedDeltaAttrs(
+            32, 128, 128, 4, chunk_size=64, norm_eps=1e-6, num_key_heads=16,
+            decay="head",
+        )
+        weights = [on_chip(w.dims) for w in attrs.weight_shapes(shape)]
+
+        def scoped(u, weights):
+            with jax.named_scope("ff.kda.gdn0"):
+                return kda.gated_delta_forward(attrs, u, weights)
+
+        def node(u, weights, cot):
+            y, vjp = jax.vjp(scoped, u, weights)
+            return y, vjp(cot)
+
+        text = jax.jit(node).lower(x, weights, x).compile().as_text()
+        names = sorted(set(re.findall(r"/(kda_\w+)/pallas_call", text)))
+        want = ["kda_bwd_chunk", "kda_fwd_chunk", "kda_prep_inverse",
+                "kda_states_chunk"]
+        found[QWEN3NEXT_INVARIANTS[0]] = (
+            "ok" if names == want and text.count("tpu_custom_call") >= 4
+            else f"kernels {names}, want {want}"
+        )
+        per_position = [
+            f"{name}: {result[:60]}"
+            for name, result, opcode, _, _ in entry_instructions(text)
+            if opcode not in _NO_BUFFER and any(
+                dims[-3:] == (QWEN3NEXT_SHAPE[1], 128, 128)
+                for _, dims in shapes_of(result)
+            )
+        ]
+        found[QWEN3NEXT_INVARIANTS[1]] = (
+            "ok" if not per_position else ", ".join(per_position)
+        )
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        for invariant in QWEN3NEXT_INVARIANTS[:2]:
+            found.setdefault(invariant, f"{type(e).__name__}: {e}"[:2000])
+    try:
+        attrs = RingAttentionAttrs(
+            2048, 16, kdim=256, vdim=256, causal=True, rope_theta=1e7,
+            rotary_dim=64, qk_norm_eps=1e-6, qk_norm_per_head=True,
+            qk_norm_zero_centered=True, num_kv_heads=2, output_gate=True,
+        )
+        flat = on_chip(attrs.weights_shape(shape, shape, shape).dims)
+        gain = on_chip((256,))
+
+        def scoped(x, flat, w_q, w_k):
+            with jax.named_scope("ff.ring_attention.attn3"):
+                return ops._mha_forward(
+                    attrs, x, x, x, flat, causal=True, qk_gains=[w_q, w_k]
+                )
+
+        def node(x, flat, w_q, w_k, cot):
+            y, vjp = jax.vjp(scoped, x, flat, w_q, w_k)
+            return y, vjp(cot)
+
+        text = jax.jit(node).lower(x, flat, gain, gain, x).compile().as_text()
+        names = sorted(set(re.findall(r"/(flash_\w+)/pallas_call", text)))
+        want = ["flash_bwd_causal_grouped", "flash_delta_grouped",
+                "flash_fwd_causal_grouped"]
+        found[QWEN3NEXT_INVARIANTS[2]] = (
+            "ok" if names == want else f"kernels {names}, want {want}"
+        )
+    except Exception as e:  # noqa: BLE001
+        found[QWEN3NEXT_INVARIANTS[2]] = f"{type(e).__name__}: {e}"[:2000]
+    return found
+
+
 def listing(name, least=4e6):
     """The node's ENTRY instructions that move `least` bytes or more."""
     text = compiled_kda_node() if name == "kimi" else compiled_node(name)[1]
@@ -672,6 +774,11 @@ def test_held_rows_sum_compiled_for_the_described_chip(compiled, invariant):
     assert compiled["held_sums"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", QWEN3NEXT_INVARIANTS)
+def test_qwen3next_nodes_compiled_for_the_described_chip(compiled, invariant):
+    assert compiled["qwen3next"][invariant] == "ok"
+
+
 if __name__ == "__main__":
     argv = sys.argv[1:]
     root = os.getcwd()
@@ -686,5 +793,5 @@ if __name__ == "__main__":
         print(json.dumps(
             dict({name: check(name) for name in SHAPES}, kimi=check_kimi(),
                  lfm2=check_lfm2(), experts=check_experts(),
-                 held_sums=check_held_sums())
+                 held_sums=check_held_sums(), qwen3next=check_qwen3next())
         ))
