@@ -100,10 +100,24 @@ heads may serve more value heads: K K^T and Q K^T are taken once a KEY head
 and meet the value heads' M through a group axis, so a key head is indexed
 (value head h reads key head h // group) and never repeated; Q exp(G),
 K exp(G), K exp(G_Q - G), the inverse and the states are a value head's.
-`head_decay_operands` is that form, XLA's on every route (JAX differentiates
-it but for the triangular inverse, which is `kernel_inverse` on the "kda"
-route); what it returns is what `chunk_operands` returns, exp(G_Q) written
-over the dk lanes, so `chunk_scan` and its kernels take it as they are.
+`head_decay_operands` is that form in XLA (JAX differentiates it but for the
+triangular inverse): the "xla" route's, and what the kernels are tested
+against. On the "kda" route the operands are `head_kernel_operands`:
+`gdn_prep_fwd` computes the two products once a chunk of a KEY head and, for
+each value head that reads it, the mask and the three decay vectors in VMEM,
+eight chunks a program, and writes Q exp(G), K exp(G_Q - G), P, exp(G_Q), the
+strictly lower A and K exp(G); `gdn_prep_bwd` is its WRITTEN backward
+(`head_chunk_scores`, one `jax.custom_vjp`), which recomputes them from q, k
+and g, sums the value heads' cotangents of q and k in the program and takes
+the exponents' back to g. M, Q K^T, K K^T and the decays never reach HBM. The
+kernels share helpers with the per-channel form's (`_bf16_parts`, `_mm`,
+`kernel_inverse`) and no body: there the exponent is one a key channel and
+needs the levels, here one number a pair of positions and no level. The
+inverse and `_corrected`'s products are as there. Either way what comes back
+is what `chunk_operands` returns, exp(G_Q) written over the dk lanes, so
+`chunk_scan` and its kernels take it as they are. Which form a node took is
+`operand_form`'s answer (the attrs' `decay` and `scan_route`'s route, nothing
+else), counted by node in `observability/trace.delta_rule_operands()`.
 
 The node's parts go under scopes of their own inside the node's
 (`ff.kda.<name>/scan`, `/prep`, `/gates`, `/conv`, `/norm`;
@@ -1110,6 +1124,295 @@ def kernel_operands(qkv, f_up, dt_bias, a_log, v, beta, chunk: int):
     return qd, w, uv, ke, p, gamma
 
 
+# ---------------------------------------------------------------------------
+# the chunks' operands for ONE decay a head, the Pallas form
+# ---------------------------------------------------------------------------
+#
+# One program is `n` chunks of one (batch row, KEY head) with ALL the value
+# heads that read it: q and k are loaded once and Q K^T, K K^T taken once a
+# chunk, then met by each value head's mask M_rj = exp(G_r - G_j). No level is
+# needed and no exponent table: with the chunk's log-decays g a ROW [1, Q]
+# (positions along the lanes, as [b, hv, s] lies), L_ri = g_i for i <= r and
+# 0 elsewhere is a broadcast and a select, and
+#
+#     G_r - G_j = sum_i L_ri [i > j]        one product with a 0/1 matrix
+#     G_r       = sum_i L_ri                a sum along the lanes
+#     G_Q - G_r = sum_i (g_i - L_ri)        likewise
+#
+# each a SUM of log-decays over a set of positions and so <= 0. The product
+# takes L in three bf16 parts (`_bf16_parts`: float32-exact), and its
+# transpose takes the exponents' cotangent back to g the same way. M, Q K^T,
+# K K^T and the decay vectors never leave VMEM.
+
+
+def _head_decays(g_row, tri, after):
+    """Of one chunk of one value head, from its log-decays g_row [1, Q]:
+    (G_r - G_j [Q, Q], valid where j <= r; G_r [Q, 1]; G_Q - G_r [Q, 1]),
+    float32 and <= 0 (the section's comment). `tri` [Q, Q] is i <= r and
+    `after` [Q, Q] bf16 is [i > j]."""
+    q = tri.shape[0]
+
+    def lower(row):  # L_ri = row_i where i <= r, 0 elsewhere
+        return jnp.where(
+            tri, jnp.broadcast_to(row.astype(jnp.float32), (q, q)), 0.0
+        )
+
+    low = lower(g_row)
+    # the bf16 parts are taken of the row, before the broadcast
+    between = sum(
+        _mm(lower(part).astype(jnp.bfloat16), after, _NN)
+        for part in _bf16_parts(g_row)
+    )
+    from_start = jnp.sum(low, axis=1, keepdims=True)
+    to_end = jnp.sum(g_row - low, axis=1, keepdims=True)
+    return between, from_start, to_end
+
+
+def _chunk_masks(q: int):
+    """(i <= r, i < r as [Q, Q] bools over (r, i); [r > i] and [r < i] as
+    bf16 0/1 matrices: the sets (j, r] of a pair's exponent and their
+    transpose)."""
+    r = lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    i = lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    one = jnp.where(r > i, 1.0, 0.0).astype(jnp.bfloat16)
+    one_t = jnp.where(r < i, 1.0, 0.0).astype(jnp.bfloat16)
+    return i <= r, i < r, one, one_t
+
+
+def _gdn_prep_fwd_kernel(
+    q_ref, k_ref, g_ref, qd_ref, ke_ref, p_ref, gam_ref, a_ref, kd_ref,
+    *, chunks: int, q: int,
+):
+    """Q exp(G), K exp(G_Q - G), P, exp(G_Q), the strictly lower A and
+    K exp(G) (float32, for the triangular system) of each of the program's
+    chunks and each value head of its key head, from the NORMALISED q, k
+    [n Q, dk] and the heads' log-decays g [group, n, Q]."""
+    f32 = jnp.float32
+    dtype = q_ref.dtype
+    group = g_ref.shape[0]
+    tri, strict, after, _ = _chunk_masks(q)
+    for c in range(chunks):
+        rows = pl.ds(c * q, q)
+        qn, kn = q_ref[rows, :], k_ref[rows, :]
+        qf, kf = qn.astype(f32), kn.astype(f32)
+        # one product a KEY head meets its value heads' decays
+        scores = _mm(jnp.concatenate([qn, kn], axis=0), kn, _NT)
+        qk, kk = scores[:q], scores[q:]
+        for h in range(group):
+            between, from_start, to_end = _head_decays(
+                g_ref[h, c:c + 1, :], tri, after
+            )
+            m = jnp.exp(between)
+            from_start = jnp.exp(from_start)
+            p_ref[h, rows, :] = jnp.where(tri, m * qk, 0.0).astype(dtype)
+            a_ref[h, rows, :] = jnp.where(strict, m * kk, 0.0)
+            qd_ref[h, rows, :] = (qf * from_start).astype(dtype)
+            kd_ref[h, rows, :] = kf * from_start
+            ke_ref[h, rows, :] = (kf * jnp.exp(to_end)).astype(dtype)
+            gam_ref[h, c] = jnp.broadcast_to(
+                from_start[q - 1:q, :], (1, kf.shape[1])
+            )
+
+
+def _gdn_prep_bwd_kernel(
+    q_ref, k_ref, g_ref, dqd_ref, dke_ref, dp_ref, dgam_ref, da_ref, dkd_ref,
+    dq_ref, dk_ref, dg_ref, *, chunks: int, q: int,
+):
+    """The cotangents of q, k (summed over the key head's value heads,
+    float32) and g from those of `_gdn_prep_fwd_kernel`'s six results, the
+    products and every decay recomputed. With dE the cotangent of the pairs'
+    exponents G_r - G_j, x_r that of G_r and y_r that of G_Q - G_r, g's is
+    the sum down the rows of (dE [i > j]^T + x_r) where i <= r and of y_r
+    elsewhere: the forward's sums transposed."""
+    f32 = jnp.float32
+    dtype = q_ref.dtype
+    group = g_ref.shape[0]
+    tri, strict, after, after_t = _chunk_masks(q)
+    last = lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
+    for c in range(chunks):
+        rows = pl.ds(c * q, q)
+        qn, kn = q_ref[rows, :], k_ref[rows, :]
+        qf, kf = qn.astype(f32), kn.astype(f32)
+        both = jnp.concatenate([qn, kn], axis=0)
+        scores = _mm(both, kn, _NT)
+        qk, kk = scores[:q], scores[q:]
+        dq = jnp.zeros(qf.shape, f32)
+        dk = jnp.zeros(kf.shape, f32)
+        dqk = jnp.zeros((q, q), f32)
+        dkk = jnp.zeros((q, q), f32)
+        for h in range(group):
+            between, from_start, to_end = _head_decays(
+                g_ref[h, c:c + 1, :], tri, after
+            )
+            m = jnp.where(tri, jnp.exp(between), 0.0)
+            from_start, to_end = jnp.exp(from_start), jnp.exp(to_end)
+            dqd, dkd = dqd_ref[h, rows, :].astype(f32), dkd_ref[h, rows, :]
+            dke = dke_ref[h, rows, :].astype(f32)
+            dp = dp_ref[h, rows, :].astype(f32)
+            da = jnp.where(strict, da_ref[h, rows, :], 0.0)
+            dq = dq + dqd * from_start
+            dk = dk + dkd * from_start + dke * to_end
+            dqk = dqk + m * dp
+            dkk = dkk + m * da
+            # exp(G_Q) is the decay from the start at the last position
+            at_start = dqd * qf + dkd * kf + jnp.where(last, dgam_ref[h, c], 0.0)
+            x = jnp.sum(at_start, axis=1, keepdims=True) * from_start
+            y = jnp.sum(dke * kf, axis=1, keepdims=True) * to_end
+            d_between = sum(
+                _mm(part, after_t, _NN)
+                for part in _bf16_parts((dp * qk + da * kk) * m)
+            )
+            dg_ref[h, c:c + 1, :] = jnp.sum(
+                jnp.where(tri, d_between + x, y), axis=0, keepdims=True
+            )
+        dqk, dkk = dqk.astype(dtype), dkk.astype(dtype)
+        dq_ref[rows, :] = (dq + _mm(dqk, kn, _NN)).astype(dq_ref.dtype)
+        dk_ref[rows, :] = (
+            dk + _mm(dkk, kn, _NN)
+            + _mm(jnp.concatenate([dqk, dkk], axis=0), both, _TN)
+        ).astype(dk_ref.dtype)
+
+
+class _HeadPrepBlocks:
+    """The BlockSpecs over the grid (batch, KEY head, group of n chunks):
+    q, k [b, hk, s, dk] a key head's rows; what a value head has,
+    [b, hv, s, .], the rows of the key head's `group` value heads (head
+    hi * group onward: a key head is indexed, never repeated); g
+    [b, hv, c / n, n, Q] and exp(G_Q) [b, hv, c, 1, dk] likewise."""
+
+    def __init__(self, b: int, hk: int, group: int, s: int, dk: int, q: int):
+        c = s // q
+        n = self.chunks = next(n for n in _PREP_CHUNKS if c % n == 0)
+        self.grid = (b, hk, c // n)
+
+        def rows(width, heads):
+            return pl.BlockSpec(
+                (None, heads, n * q, width), lambda bi, hi, gi: (bi, hi, gi, 0)
+            )
+
+        self.key_head = rows(dk, None)
+        self.key, self.scores = rows(dk, group), rows(q, group)
+        self.decay = pl.BlockSpec(
+            (None, group, None, n, q), lambda bi, hi, gi: (bi, hi, gi, 0, 0)
+        )
+        self.gamma = pl.BlockSpec(
+            (None, group, n, 1, dk), lambda bi, hi, gi: (bi, hi, gi, 0, 0)
+        )
+        # a program's blocks in a bf16 step (q, k and the six results or
+        # their cotangents; a [., Q] tile fills 128 lanes), twice for the
+        # pipeline's two buffers, and as much again for what the body holds
+        block = n * q * (
+            2 * 2 * dk + group * (2 * 2 * dk + 4 * dk + 6 * _LANES)
+        )
+        self.params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=max(4 * block, 16 * 1024 * 1024),
+        )
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _head_prep_forward(q, k, g, chunk, interpret):
+    """The kernel on q, k [b, hk, s, dk] (normalised) and g [b, hv, s]
+    float32; by chunk and VALUE head out ([b, hv, c, Q, .]): qd, ke, p, gamma
+    as `head_decay_operands` has them, then A [., Q, Q] and K exp(G)
+    [., Q, dk], float32."""
+    f32 = jnp.float32
+    b, hk, s, dk = q.shape
+    hv = g.shape[1]
+    c = s // chunk
+    at = _HeadPrepBlocks(b, hk, hv // hk, s, dk, chunk)
+
+    def rows(width, dtype):
+        return jax.ShapeDtypeStruct((b, hv, s, width), dtype)
+
+    qd, ke, p, gamma, a, kd = pl.pallas_call(
+        functools.partial(_gdn_prep_fwd_kernel, chunks=at.chunks, q=chunk),
+        grid=at.grid,
+        in_specs=[at.key_head, at.key_head, at.decay],
+        out_specs=[at.key, at.key, at.scores, at.gamma, at.scores, at.key],
+        out_shape=[
+            rows(dk, q.dtype), rows(dk, q.dtype), rows(chunk, q.dtype),
+            jax.ShapeDtypeStruct((b, hv, c, 1, dk), f32),
+            rows(chunk, f32), rows(dk, f32),
+        ],
+        compiler_params=at.params,
+        interpret=interpret,
+        name="gdn_prep_fwd",
+    )(q, k, g.reshape(b, hv, c // at.chunks, at.chunks, chunk))
+
+    def by_chunk(t):
+        return t.reshape(b, hv, c, chunk, t.shape[-1])
+
+    return by_chunk(qd), by_chunk(ke), by_chunk(p), gamma, by_chunk(a), by_chunk(kd)
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _head_prep_backward(q, k, g, cotangents, chunk, interpret):
+    """The cotangents of `_head_prep_forward`'s three inputs."""
+    b, hk, s, dk = q.shape
+    hv = g.shape[1]
+    at = _HeadPrepBlocks(b, hk, hv // hk, s, dk, chunk)
+    dqd, dke, dp, dgam, da, dkd = cotangents
+    by_program = (b, hv, s // chunk // at.chunks, at.chunks, chunk)
+    dq, dkey, dg = pl.pallas_call(
+        functools.partial(_gdn_prep_bwd_kernel, chunks=at.chunks, q=chunk),
+        grid=at.grid,
+        in_specs=[
+            at.key_head, at.key_head, at.decay,
+            at.key, at.key, at.scores, at.gamma, at.scores, at.key,
+        ],
+        out_specs=[at.key_head, at.key_head, at.decay],
+        out_shape=[
+            jax.ShapeDtypeStruct(q.shape, q.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(by_program, g.dtype),
+        ],
+        compiler_params=at.params,
+        interpret=interpret,
+        name="gdn_prep_bwd",
+    )(q, k, g.reshape(by_program),
+      _rows(dqd), _rows(dke), _rows(dp), dgam, _rows(da), _rows(dkd))
+    return dq, dkey, dg.reshape(g.shape)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def head_chunk_scores(q, k, g, chunk: int):
+    """`chunk_scores` for ONE log-decay a value head: what
+    `head_decay_operands` takes from q, k and the log-decays alone, as Pallas
+    kernels with a WRITTEN backward (`_head_prep_forward`'s inputs and
+    results). What the backward keeps is those inputs: it recomputes the
+    products and every decay."""
+    return _head_prep_forward(q, k, g, chunk, _interpret())
+
+
+def _head_chunk_scores_fwd(q, k, g, chunk):
+    return head_chunk_scores(q, k, g, chunk), (q, k, g)
+
+
+def _head_chunk_scores_bwd(chunk, kept, cotangents):
+    return _head_prep_backward(*kept, cotangents, chunk, _interpret())
+
+
+head_chunk_scores.defvjp(_head_chunk_scores_fwd, _head_chunk_scores_bwd)
+
+
+def head_kernel_operands(q, k, v, g, beta, chunk: int):
+    """`head_decay_operands` on the "kda" route, on the same inputs: the
+    decays and the scores from one kernel, the triangular inverse from
+    another, its two products with K exp(G) and V XLA's batched float32
+    ones."""
+    b, hv, s, dv = v.shape
+    c = s // chunk
+    qd, ke, p, gamma, a, kd = head_chunk_scores(q, k, g, chunk)
+    w, uv = _corrected(
+        a, kd, v.reshape(b, hv, c, chunk, dv), beta.reshape(b, hv, c, chunk),
+        q.dtype,
+        # the inverse's kernel takes the chunks two by two
+        kernel_inverse if (b * hv * c) % 2 == 0 else unit_lower_inverse,
+    )
+    return qd, w, uv, ke, p, gamma
+
+
 def scan_route(key_dim: int, value_dim: int, chunk: int) -> str:
     """Which form the recurrence takes, the chunks' operands and
     `chunk_scan` alike, from what the trace can observe:
@@ -1130,6 +1433,21 @@ def scan_route(key_dim: int, value_dim: int, chunk: int) -> str:
     if getattr(flash._tls, "disabled", False):
         return "xla"
     return "kda" if flash._backend_ok(flash.interpret_default()) else "xla"
+
+
+def operand_form(attrs: GatedDeltaAttrs, route: str) -> str:
+    """Which form the chunks' operands of a node take on `route`
+    (`scan_route`'s answer), told to the program's counter as well
+    (`observability/trace.delta_rule_operands`): the attrs' own `decay` names
+    the set of kernels, and nothing else chooses."""
+    from flexflow_tpu.observability import trace
+
+    if attrs.per_head_decay:
+        form = "head_kernels" if route == "kda" else "head_xla"
+    else:
+        form = "channel_kernels" if route == "kda" else "xla"
+    trace.note_delta_rule_operands(form)
+    return form
 
 
 # ---------------------------------------------------------------------------
@@ -1195,11 +1513,11 @@ def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
     return jnp.swapaxes(o, 1, 2).reshape(b, s, h * dv)
 
 
-def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, qkv, a_pre,
-                           dt_bias, a_log, b_logit):
+def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, form: str, qkv,
+                           a_pre, dt_bias, a_log, b_logit):
     """`_recurrence` for one log-decay a value head: qkv
     [b, s, 2*hk*dk + hv*dv] after the convolution, a_pre and b_logit
-    [b, s, hv] -> o [b, s, hv*dv]."""
+    [b, s, hv] -> o [b, s, hv*dv]; `form` is `operand_form`'s answer."""
     f32 = jnp.float32
     b, s, _ = qkv.shape
     hv, hk, dk, dv, chunk = (
@@ -1225,12 +1543,10 @@ def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, qkv, a_pre,
             + dt_bias.astype(f32)[:, None]
         )
     with jax.named_scope("prep"):
-        pairs = (b * hv * ((s + pad) // chunk)) % 2 == 0
-        operands = head_decay_operands(
-            q, k, v, g, beta, chunk,
-            # the inverse's kernel takes the chunks two by two
-            kernel_inverse if route == "kda" and pairs else unit_lower_inverse,
-        )
+        operands = (
+            head_kernel_operands if form == "head_kernels"
+            else head_decay_operands
+        )(q, k, v, g, beta, chunk)
     with jax.named_scope("scan"):
         o = chunk_scan(route, *operands)
     o = o.reshape(b, hv, s + pad, dv)[:, :, :s]
@@ -1263,8 +1579,9 @@ def _gated_delta_head_decay(attrs: GatedDeltaAttrs, u, weights):
     with jax.named_scope("gates"):
         ba = u @ w_ba
     route = scan_route(attrs.key_dim, attrs.value_dim, attrs.chunk_size)
+    form = operand_form(attrs, route)
     o = jax.checkpoint(
-        functools.partial(_recurrence_head_decay, attrs, route),
+        functools.partial(_recurrence_head_decay, attrs, route, form),
         policy=jax.checkpoint_policies.save_only_these_names(_KEPT),
     )(qkv, ba[..., hv:], dt_bias, a_log, ba[..., :hv])
     with jax.named_scope("norm"):
@@ -1298,6 +1615,7 @@ def gated_delta_forward(
         f_up = proj[..., cw:cw + rank] @ w_f
         g_up = proj[..., cw + rank:cw + 2 * rank] @ w_g
     route = scan_route(attrs.key_dim, attrs.value_dim, attrs.chunk_size)
+    operand_form(attrs, route)
     o = jax.checkpoint(
         functools.partial(_recurrence, attrs, route),
         policy=jax.checkpoint_policies.save_only_these_names(_KEPT),

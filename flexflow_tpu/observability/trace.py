@@ -575,8 +575,10 @@ NODE_PARTS = {
     # the gated delta-rule node (`kernels/kda.py`): the chunk-to-chunk pass,
     # the chunks' operands (decayed scores, the triangular inverse), the
     # gates, the convolution, the gated norm (with one decay a head, `prep`
-    # is `head_decay_operands`, XLA's on every route, and `gates` what the
-    # "xla" route's is). On the "kda" route the scores'
+    # is `head_kernel_operands` on the "kda" route, kernels that take q, k
+    # NORMALISED and g, and `head_decay_operands` on the "xla" route; `gates`
+    # is what the "xla" route's is on both). With a decay a key channel, on
+    # the "kda" route the scores'
     # kernels read q, k and the decay's pre-activation in the model's layout
     # and normalise and take the softplus in VMEM (PR 45), so that is `prep`
     # there, and `gates` holds the two rank-128 gate matmuls, beta's sigmoid,
@@ -634,6 +636,7 @@ def scope_name(graph, n) -> str:
 # grouped matmuls' tiles each expert node took likewise
 _lowering = threading.local()
 _ATTENTION_ROUTES: Dict[str, str] = {}
+_DELTA_RULE_OPERANDS: Dict[str, str] = {}
 _GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
 _HELD_ROW_SUMS: Dict[str, Dict[str, dict]] = {}
 
@@ -667,6 +670,25 @@ def attention_routes() -> Dict[str, str]:
     program counter a reader (the benchmark's `gqa64_flash_roofline`) prints
     beside what it measures, so that a change of route says so itself."""
     return dict(_ATTENTION_ROUTES)
+
+
+def note_delta_rule_operands(form: str) -> None:
+    """The form of the chunks' operands (`kernels/kda.operand_form`'s names)
+    the delta-rule node being lowered took; dropped where no node's scope is
+    open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _DELTA_RULE_OPERANDS[scope] = form
+
+
+def delta_rule_operands() -> Dict[str, str]:
+    """`{ff.kda.<name>: form}` of every gated delta-rule node this process
+    has lowered, as it was lowered last: `head_kernels` (one decay a head,
+    the Pallas kernels `gdn_prep_fwd` / `gdn_prep_bwd`), `head_xla` (the same
+    form, `head_decay_operands`), `channel_kernels` (a decay a key channel,
+    `kda_prep_fwd` / `kda_prep_bwd`) or `xla` (`chunk_operands`), so that a
+    run that fell back to XLA's operands says so itself."""
+    return dict(_DELTA_RULE_OPERANDS)
 
 
 def note_grouped_matmul_tiles(entries: Dict[str, dict]) -> None:

@@ -33,9 +33,13 @@ is [dk, dv] a VALUE head:
 The recurrence is evaluated in chunks of `chunk_size` positions
 (`kernels/kda.py`): inside a chunk the WY / UT form (the inverse of a unit
 lower-triangular [chunk, chunk] matrix a head), from chunk to chunk only the
-[dk, dv] states. `kernels/kda.scan_route` picks the form of the chunk-to-chunk
-pass from the shapes and the backend (Pallas kernels on a TPU at lane-sized
-heads, a `lax.scan` over the chunks everywhere else); the chunking and the
+[dk, dv] states. `kernels/kda.scan_route` picks ONE route for the chunks'
+operands and the chunk-to-chunk pass alike, from the shapes and the backend:
+Pallas kernels on a TPU at lane-sized heads (each `decay` has its own pair
+for the operands, `kda_prep_fwd` / `kda_prep_bwd` and `gdn_prep_fwd` /
+`gdn_prep_bwd`; the inverse's and the pass's kernels serve both), XLA's
+products and a `lax.scan` over the chunks everywhere else;
+`kernels/kda.operand_form` names which a node took. The chunking and the
 choice of form change the order of the floating-point sums and nothing else.
 
 weights (slot order), the "channel" form: in_proj [D, 2*h*dk + h*dv + 2*rank

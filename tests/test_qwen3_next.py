@@ -19,6 +19,7 @@ from test_nemotron_h import (
 )
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import flash_attention as flash
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels import kda
 from flexflow_tpu.kernels.moe import experts_forward
@@ -40,6 +41,7 @@ from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 from flexflow_tpu.op_attrs.parallel_tensor_shape import (
     lift_to_parallel_with_degrees,
 )
+from flexflow_tpu.observability import trace
 from flexflow_tpu.op_attrs.tensor_shape import TensorShape
 
 CONFIG = os.path.join(BENCH, "configs", "qwen3-next-80b-a3b")
@@ -184,6 +186,160 @@ def test_scalar_decay_operands_are_the_per_channel_ones_on_a_broadcast_decay():
         )
     for got, want in zip(scalar, channel):
         np.testing.assert_allclose(got, want, **F32)
+
+
+# One key head of 128 | 128 in chunks of 64 with `group` value heads reading
+# it. 256 positions are four chunks, all in ONE program of the kernels; 192
+# are three, one a program (`_PREP_CHUNKS`), and with one value head an odd
+# number of chunk-heads, which XLA's `unit_lower_inverse` inverts where the
+# inverse's kernel takes them two by two; 100 positions pad to 128 as the
+# node pads them (q = k = 0, beta = 0, a decay all the same). A log-decay of
+# -27 to -30 a position puts exp(G_r - G_j) under float32's least (e^-103.3)
+# four positions apart and exp(-G) of the textbook form over its greatest
+# inside three.
+@pytest.mark.parametrize(
+    "group,seq,decay",
+    [(1, 128, 1.0), (2, 256, 1.0), (4, 128, 1.0), (2, 100, 1.0),
+     (1, 192, 1.0), (2, 128, 30.0)],
+    ids=["one_value_head", "two_value_heads", "four_value_heads",
+         "padded_to_the_chunk", "odd_count_of_chunks", "decay_underflows"],
+)
+def test_scalar_decay_kernels_agree_with_the_xla_operands(
+    monkeypatch, group, seq, decay
+):
+    """`head_kernel_operands` (the Pallas kernels in interpret mode:
+    `gdn_prep_fwd` and its WRITTEN backward `gdn_prep_bwd`, the triangular
+    inverse) against `head_decay_operands`, differentiated by JAX, at a
+    lane-sized head in float32: the six operands and the cotangents of q, k,
+    v, g and beta. The kernels take every exponent as one sum of log-decays
+    where XLA subtracts two running sums (of up to -1,900 in the last case:
+    1e-4 of an exponent), and sum the value heads' cotangents in another
+    order: measured 1.5e-5 at values of 5, 9e-5 at values of 8 in the last
+    case."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    rs = np.random.RandomState(13)
+    b, hk, d, chunk = 1, 1, 128, 64
+    hv = hk * group
+    padded = seq + -seq % chunk
+    c = padded // chunk
+    real = (jnp.arange(padded) < seq)[:, None]
+    q = kda._unit(rand(rs, b, hk, padded, d) * real, d ** -0.5)
+    k = kda._unit(rand(rs, b, hk, padded, d) * real, 1.0)
+    v = rand(rs, b, hv, padded, d) * real
+    g = -decay * jnp.asarray(0.9 + 0.1 * rs.rand(b, hv, padded), jnp.float32)
+    beta = jnp.asarray(rs.rand(b, hv, padded), jnp.float32) * real[:, 0]
+    cots = [
+        rand(rs, b, hv, c, chunk, width) for width in (d, d, d, d, chunk)
+    ] + [rand(rs, b, hv, c, 1, d)]
+
+    def run(operands_of):
+        def loss(*inputs):
+            operands = operands_of(*inputs, chunk)
+            return sum(
+                jnp.sum(o * cot) for o, cot in zip(operands, cots)
+            ), operands
+
+        with jax.default_matmul_precision("highest"):
+            (_, operands), grads = jax.value_and_grad(
+                loss, argnums=(0, 1, 2, 3, 4), has_aux=True
+            )(q, k, v, g, beta)
+        return operands, grads
+
+    got, want = run(kda.head_kernel_operands), run(kda.head_decay_operands)
+    assert all(
+        bool(jnp.all(jnp.isfinite(t))) for t in jax.tree_util.tree_leaves(got)
+    )
+    if decay > 20.0:
+        p, gamma = got[0][4], got[0][5]
+        far = np.tri(chunk, k=-4, dtype=bool)
+        assert not np.any(np.asarray(p)[..., far]) and not np.any(gamma)
+        assert float(jnp.max(jnp.abs(p))) > 1e-3  # the diagonal is there
+    tol = dict(rtol=2e-4, atol=2e-4) if decay > 20.0 else dict(rtol=1e-4, atol=2e-5)
+    assert_trees_close(got, want, **tol)
+
+
+def kernel_sized_node(seq):
+    """(attrs, u, weights, a cotangent): four value heads over two key heads
+    of 128 | 128 in chunks of 64, hidden size 32."""
+    attrs = GatedDeltaAttrs(
+        4, 128, 128, 4, chunk_size=64, norm_eps=1e-6, num_key_heads=2,
+        decay="head",
+    )
+    rs = np.random.RandomState(23)
+    b, hidden = 1, 32
+    shapes = attrs.weight_shapes(TensorShape((b, seq, hidden), DataType.FLOAT))
+    scales = [0.3, 0.5, 0.5, 1.0, 0.3, 0.2, 0.1]
+    ws = [rand(rs, *s.dims, scale=k) for s, k in zip(shapes, scales)]
+    ws[5] = 1.0 + ws[5]
+    return attrs, rand(rs, b, seq, hidden), ws, rand(rs, b, seq, hidden)
+
+
+def test_the_whole_head_decay_node_on_the_kernels_agrees_with_the_xla_route(
+    monkeypatch,
+):
+    """`gated_delta_forward` with one decay a head over 100 positions (padded
+    to two chunks): the "kda" route (every kernel interpreted: the operands',
+    the inverse's, the pass's three) against the "xla" route (`no_flash()`):
+    the output and the gradients of the input and all seven weights, float32.
+    The routes differ where the operands' test says, and the node's
+    projections multiply that by a hidden size of 32."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    attrs, u, ws, cot = kernel_sized_node(100)
+    routes = []
+
+    def run():
+        routes.append(kda.scan_route(128, 128, 64))
+        with jax.default_matmul_precision("highest"):
+            def loss(u, ws):
+                y = kda.gated_delta_forward(attrs, u, ws)
+                return jnp.sum(y * cot), y
+
+            (_, y), grads = jax.value_and_grad(
+                loss, argnums=(0, 1), has_aux=True
+            )(u, ws)
+        return y, grads
+
+    got = run()
+    with flash.no_flash():
+        want = run()
+    assert routes == ["kda", "xla"]
+    for grad in jax.tree_util.tree_leaves(want[1]):
+        assert float(jnp.max(jnp.abs(grad))) > 1e-3  # every weight is reached
+    assert_trees_close(got, want, **F32_GRADS)
+
+
+def test_the_operands_form_is_counted_by_node(monkeypatch):
+    """`observability/trace.delta_rule_operands()` names the form each
+    delta-rule node was lowered with: the scalar form's kernels where
+    `scan_route` says "kda", `head_decay_operands` under `no_flash()` and on
+    the plain CPU, and the per-channel form's two names likewise."""
+    attrs, u, ws, _ = kernel_sized_node(64)
+    channel = GatedDeltaAttrs(2, 128, 128, 4, 8, 64, 1e-5)
+    monkeypatch.setattr(trace, "_DELTA_RULE_OPERANDS", {})
+
+    def lowered_as(scope, node=attrs):
+        monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+        if node is attrs:
+            jax.eval_shape(lambda u, ws: kda.gated_delta_forward(attrs, u, ws), u, ws)
+        else:
+            kda.operand_form(node, kda.scan_route(128, 128, 64))
+        return trace.delta_rule_operands()[scope]
+
+    assert lowered_as("ff.kda.on_the_cpu") == "head_xla"
+    assert lowered_as("ff.kda.on_the_cpu", channel) == "xla"
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    assert lowered_as("ff.kda.gdn0") == "head_kernels"
+    assert lowered_as("ff.kda.kda0", channel) == "channel_kernels"
+    with flash.no_flash():
+        assert lowered_as("ff.kda.gdn1") == "head_xla"
+    assert trace.delta_rule_operands() == {
+        "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "head_kernels",
+        "ff.kda.kda0": "channel_kernels", "ff.kda.gdn1": "head_xla",
+    }
+    # a kernel called by itself, under no node's scope, is not counted
+    monkeypatch.setattr(trace._lowering, "scope", None)
+    kda.operand_form(attrs, "kda")
+    assert len(trace.delta_rule_operands()) == 4
 
 
 # -- the gated grouped-query attention node --------------------------------------

@@ -57,9 +57,16 @@ two buffers).
 And what the `qwen3_next` cell added (PR 51), at its shape, one sequence of
 8,192 positions: ONE gated delta-rule node with one decay a head, 32 value
 heads over 16 key heads of 128 | 128, forward and backward, whose
-chunk-to-chunk pass must come out as the same three Pallas kernels and its
-triangular inverse as the fourth (the chunks' operands are XLA's), and which
-must hold no state per position either; and ONE output-gated grouped-query
+chunk-to-chunk pass must come out as the same three Pallas kernels, and which
+must hold no state per position either; since PR 52 its `prep` part must
+hold the scalar-decay operands' kernel `gdn_prep_fwd` twice (forward,
+rematerialised), its written backward `gdn_prep_bwd` once and
+`kda_prep_inverse` once, by the names the profile will carry, with no
+float32 [.., 64, 64] buffer of a mask or of Q K^T / K K^T left between
+ENTRY instructions (A, the inverse and what `_corrected` multiplies are),
+both kernels under the VMEM limit they state (the cell's whole step, compiled
+for the described chip, holds 13,635,138,048 bytes with them; 13,705,657,344
+at PR 51); and ONE output-gated grouped-query
 node of 16 query heads over 2 key/value heads of 256, forward and backward,
 whose core must be the three `*_grouped` kernels (forward, delta, backward):
 the entries that keep k and v as whole rows under the default scope do not
@@ -71,7 +78,7 @@ chip's numbers are in PERF.md. In the pattern of
 pinned to the CPU, skipped only where the TPU's library is not installed.
 
     python tests/test_ssm_node_compiles_for_v5e.py            # the JSON the tests read
-    python tests/test_ssm_node_compiles_for_v5e.py twotower   # the node's ENTRY
+    python tests/test_ssm_node_compiles_for_v5e.py twotower   # (or super, kimi, qwen3next) the node's ENTRY
         instructions of 4 MB or more, in schedule order, with operand and result
         bytes (7 s; `--root <checkout>` lists another checkout's node)
 """
@@ -617,8 +624,47 @@ QWEN3NEXT_INVARIANTS = [
     "head_decay_node_compiles_with_the_pass_and_inverse_kernels",
     "head_decay_node_keeps_no_state_per_position",
     "gated_d256_attention_compiles_on_the_grouped_kernels",
+    "head_decay_operand_kernels_are_the_nodes_prep_part",
+    "head_decay_prep_leaves_no_float32_mask_or_score_tile",
+    "head_decay_operand_kernels_compile_under_the_vmem_limit_they_state",
 ]
+# the chip's default for a kernel's scoped VMEM
+V5E_SCOPED_VMEM = 16 * 1024 * 1024
 QWEN3NEXT_SHAPE = (1, 8192, 2048)
+
+
+def compiled_gdn_node():
+    """(attrs, the compiled HLO text) of one gated delta-rule node with one
+    decay a head, forward and backward, at the `qwen3_next` cell's shape for
+    the described chip."""
+    import jax
+
+    from flexflow_tpu.kernels import kda
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    on_chip = _described_chip()
+    attrs = GatedDeltaAttrs(
+        32, 128, 128, 4, chunk_size=64, norm_eps=1e-6, num_key_heads=16,
+        decay="head",
+    )
+    x = on_chip(QWEN3NEXT_SHAPE)
+    weights = [
+        on_chip(w.dims) for w in attrs.weight_shapes(
+            TensorShape(QWEN3NEXT_SHAPE, DataType.FLOAT)
+        )
+    ]
+
+    def scoped(u, weights):
+        with jax.named_scope("ff.kda.gdn0"):
+            return kda.gated_delta_forward(attrs, u, weights)
+
+    def node(u, weights, cot):
+        y, vjp = jax.vjp(scoped, u, weights)
+        return y, vjp(cot)
+
+    return attrs, jax.jit(node).lower(x, weights, x).compile().as_text()
 
 
 def check_qwen3next():
@@ -627,9 +673,9 @@ def check_qwen3next():
     import jax
 
     from flexflow_tpu.kernels import kda, ops
+    from flexflow_tpu.observability.trace import parse_scope
     from flexflow_tpu.op_attrs.datatype import DataType
     from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
-    from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
     from flexflow_tpu.op_attrs.tensor_shape import TensorShape
 
     found = {}
@@ -637,27 +683,65 @@ def check_qwen3next():
     x = on_chip(QWEN3NEXT_SHAPE)
     shape = TensorShape(QWEN3NEXT_SHAPE, DataType.FLOAT)
     try:
-        attrs = GatedDeltaAttrs(
-            32, 128, 128, 4, chunk_size=64, norm_eps=1e-6, num_key_heads=16,
-            decay="head",
-        )
-        weights = [on_chip(w.dims) for w in attrs.weight_shapes(shape)]
-
-        def scoped(u, weights):
-            with jax.named_scope("ff.kda.gdn0"):
-                return kda.gated_delta_forward(attrs, u, weights)
-
-        def node(u, weights, cot):
-            y, vjp = jax.vjp(scoped, u, weights)
-            return y, vjp(cot)
-
-        text = jax.jit(node).lower(x, weights, x).compile().as_text()
-        names = sorted(set(re.findall(r"/(kda_\w+)/pallas_call", text)))
-        want = ["kda_bwd_chunk", "kda_fwd_chunk", "kda_prep_inverse",
-                "kda_states_chunk"]
+        attrs, text = compiled_gdn_node()
+        names = sorted(set(re.findall(r"/((?:kda|gdn)_\w+)/pallas_call", text)))
+        want = ["gdn_prep_bwd", "gdn_prep_fwd", "kda_bwd_chunk",
+                "kda_fwd_chunk", "kda_prep_inverse", "kda_states_chunk"]
         found[QWEN3NEXT_INVARIANTS[0]] = (
-            "ok" if names == want and text.count("tpu_custom_call") >= 4
+            "ok" if names == want and text.count("tpu_custom_call") >= 7
             else f"kernels {names}, want {want}"
+        )
+        # as `check_kimi` reads them: `gdn_ms` and `gdn_scan_roofline` find
+        # the kernels by these scopes, the operands' forward once more where
+        # the node's checkpoint recomputes it, the inverse kept by it
+        calls = sorted(
+            parse_scope(op_name) + (op_name.split("/")[-2],)
+            for op_name in re.findall(
+                r'custom_call_target="tpu_custom_call".*?op_name="([^"]*)"', text
+            )
+        )
+        want = sorted(
+            [("fwd", "kda", "gdn0/scan", "kda_fwd_chunk"),
+             ("bwd", "kda", "gdn0/scan", "kda_states_chunk"),
+             ("bwd", "kda", "gdn0/scan", "kda_bwd_chunk"),
+             ("fwd", "kda", "gdn0/prep", "gdn_prep_fwd"),
+             ("fwd", "kda", "gdn0/prep", "kda_prep_inverse"),
+             ("bwd", "kda", "gdn0/prep", "gdn_prep_fwd"),
+             ("bwd", "kda", "gdn0/prep", "gdn_prep_bwd")]
+        )
+        found[QWEN3NEXT_INVARIANTS[3]] = "ok" if calls == want else f"{calls}"
+        # a float32 [.., 64, 64] buffer under `prep` is a kernel's (A, the
+        # inverse), the kept inverse's `reduce_precision`, ONE product
+        # Diag(beta) A, or a `dot_general` a VALUE head (the inverse's
+        # backward and `_corrected`'s cotangents); the masks exp(G_r - G_j)
+        # were a `sub` and Q K^T, K K^T products a KEY head ([16, ..] and
+        # [16, 2, ..])
+        tiles = [
+            (name, line.split('op_name="')[1].split('"')[0].split("/")[-1], dims)
+            for name, result, opcode, _, line in entry_instructions(text)
+            if opcode not in _NO_BUFFER and "/prep/" in line
+            for dtype, dims in shapes_of(result)
+            if dtype == "f32" and dims[-1] == 64
+            and (dims[-2] == 64 or len(dims) == 2)
+        ]
+        makers = {"pallas_call", "reduce_precision", "mul", "dot_general"}
+        strays = [
+            f"{name} {made_by} f32{list(dims)}" for name, made_by, dims in tiles
+            if made_by not in makers or attrs.key_heads in dims[:-2]
+        ]
+        products = sum(made_by == "mul" for _, made_by, _ in tiles)
+        found[QWEN3NEXT_INVARIANTS[4]] = (
+            "ok" if tiles and not strays and products <= 1
+            else ", ".join(strays) or f"{len(tiles)} tiles, {products} `mul`"
+        )
+        # the compile above is Mosaic's of both kernels under the limit
+        # their `CompilerParams` carry, which is the chip's default here
+        limit = kda._HeadPrepBlocks(
+            1, attrs.key_heads, attrs.num_heads // attrs.key_heads,
+            QWEN3NEXT_SHAPE[1], attrs.key_dim, attrs.chunk_size,
+        ).params.vmem_limit_bytes
+        found[QWEN3NEXT_INVARIANTS[5]] = (
+            "ok" if limit <= V5E_SCOPED_VMEM else f"{limit} bytes stated"
         )
         per_position = [
             f"{name}: {result[:60]}"
@@ -671,7 +755,7 @@ def check_qwen3next():
             "ok" if not per_position else ", ".join(per_position)
         )
     except Exception as e:  # noqa: BLE001 - the complaint is the result
-        for invariant in QWEN3NEXT_INVARIANTS[:2]:
+        for invariant in QWEN3NEXT_INVARIANTS[:2] + QWEN3NEXT_INVARIANTS[3:]:
             found.setdefault(invariant, f"{type(e).__name__}: {e}"[:2000])
     try:
         attrs = RingAttentionAttrs(
@@ -706,7 +790,12 @@ def check_qwen3next():
 
 def listing(name, least=4e6):
     """The node's ENTRY instructions that move `least` bytes or more."""
-    text = compiled_kda_node() if name == "kimi" else compiled_node(name)[1]
+    if name == "kimi":
+        text = compiled_kda_node()
+    elif name == "qwen3next":
+        text = compiled_gdn_node()[1]
+    else:
+        text = compiled_node(name)[1]
     rows = entry_instructions(text)
     result_of = {r[0]: r[1] for r in rows}
     lines, moved = [], 0
