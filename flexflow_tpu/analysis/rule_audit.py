@@ -275,6 +275,7 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
             qk_norm_eps=1e-5 if eq.get("qk_norm_eps") is _SET else None,
             kv_latent_rank=4 if latent else None,
             shared_key_dim=2 if latent else 0,
+            q_latent_rank=4 if eq.get("q_latent_rank") is _SET else None,
         )
     if op_type == OperatorType.RMS_NORM:
         return RMSNormAttrs()
@@ -353,6 +354,10 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
         from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
 
         return ShortConvAttrs(width=size)
+    if op_type == OperatorType.LABEL_LOSS:
+        from flexflow_tpu.op_attrs.ops import LabelCrossEntropyAttrs
+
+        return LabelCrossEntropyAttrs()
     if op_type == OperatorType.REPARTITION:
         return RepartitionAttrs(
             eq.get("repartition_dim", 0), eq.get("repartition_degree", 2)
@@ -396,6 +401,7 @@ def _data_shape_table(op_type: OperatorType, size: int, arity: int):
         OperatorType.STATE_SPACE: ((S, S, S),),
         OperatorType.GATED_DELTA: ((S, S, S),),
         OperatorType.SHORT_CONV: ((S, S, S),),
+        OperatorType.LABEL_LOSS: ((S, S, S), (S, S)),
         OperatorType.REPARTITION: ((S, S, S),),
         OperatorType.COMBINE: ((S, S, S),),
         OperatorType.REPLICATE: ((S, S, S),),
@@ -490,11 +496,10 @@ def _synthesize_host(
         ]
         if len(data_slots) != len(base):
             return None
-        data_dtype = (
-            DataType.INT32
-            if op_type == OperatorType.EMBEDDING
-            else DataType.FLOAT
-        )
+        # token ids and a loss node's labels are integers
+        int_slots = {
+            OperatorType.EMBEDDING: (0,), OperatorType.LABEL_LOSS: (1,),
+        }.get(op_type, ())
         # required dims per data slot: table defaults scaled by the gi's
         # divisibility constraints; already-produced values keep theirs
         slot_dims: Dict[int, Tuple[int, ...]] = {}
@@ -532,7 +537,11 @@ def _synthesize_host(
         for i in data_slots:
             v = ins[i]
             if isinstance(v, GraphInput):
-                shape = _input_label_for_slot(attrs, slot_dims[i], data_dtype)
+                shape = _input_label_for_slot(
+                    attrs, slot_dims[i],
+                    DataType.INT32 if data_slots.index(i) in int_slots
+                    else DataType.FLOAT,
+                )
                 if materialize_gi(v, shape) is None:
                     return None
             shape = host.tensor_shape(host_val[v])

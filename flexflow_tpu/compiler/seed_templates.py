@@ -228,6 +228,9 @@ _DP_TYPES = frozenset(
         OperatorType.GATED_DELTA,
         # the short convolution too (no halo is expressed for a shard)
         OperatorType.SHORT_CONV,
+        # a loss node's scalar is a partial sum a batch shard, completed by
+        # a Reduction (data_parallel_label_loss_rule)
+        OperatorType.LABEL_LOSS,
     }
 )
 
@@ -254,7 +257,8 @@ def data_parallel_plan(k: int) -> PlanFn:
         return WrapSpec(
             [RepartitionAttrs(0, k)] * len(data_vals),
             [ReplicateAttrs(k)] * len(weight_vals),
-            [CombineAttrs(0, k)],
+            [ReductionAttrs(k) if t == OperatorType.LABEL_LOSS
+             else CombineAttrs(0, k)],
         )
 
     return plan

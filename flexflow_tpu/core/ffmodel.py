@@ -3027,6 +3027,7 @@ class FFModel:
                 else PerfMetrics()
             )
             _publish_routing(self.instance, macc)
+            _publish_loss_terms(self.instance, macc)
         if verbose:
             print(
                 f"ELAPSED TIME = {elapsed:.4f}s, "
@@ -3155,14 +3156,17 @@ class FFModel:
 def _find_aux_outputs(graph) -> List[DataflowOutput]:
     """Aux-loss outputs, found structurally (so they survive substitutions
     that rebuild node identity): any secondary output of an Experts op with
-    an auxiliary coefficient (lambda_bal, lambda_z) is that scalar."""
-    from flexflow_tpu.op_attrs.ops import ExpertsAttrs
+    an auxiliary coefficient (lambda_bal, lambda_z) is that scalar, and so
+    is the output of a loss node (`LabelCrossEntropyAttrs`)."""
+    from flexflow_tpu.op_attrs.ops import ExpertsAttrs, LabelCrossEntropyAttrs
 
     aux = []
     for n in graph.topological_ordering():
         attrs = graph.op_attrs(n)
         if isinstance(attrs, ExpertsAttrs) and attrs.has_aux:
             aux.extend(graph.outputs_of(n)[1:])
+        elif isinstance(attrs, LabelCrossEntropyAttrs):
+            aux.extend(graph.outputs_of(n))
     return aux
 
 
@@ -3240,6 +3244,17 @@ def _publish_routing(instance, mvals) -> None:
     routing.publish_recorded(
         mvals[routing.ROUTING_KEY], routing.held_nodes(graph)
     )
+
+
+def _publish_loss_terms(instance, mvals) -> None:
+    """Hand a fit call's summed loss terms (observability/trace.py) to where
+    a reader finds them; a graph with one loss has none."""
+    from flexflow_tpu.observability import trace
+
+    names = getattr(instance, "loss_term_names", None)
+    if mvals is None or not names or trace.LOSS_TERMS_KEY not in mvals:
+        return
+    trace.publish_loss_terms(names, mvals[trace.LOSS_TERMS_KEY])
 
 
 def _perf_from_metric_values(mvals: Dict[str, jnp.ndarray]) -> PerfMetrics:

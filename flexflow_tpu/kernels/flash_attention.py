@@ -2228,16 +2228,20 @@ def flash_attention_bshf_wide_key(
     """Causal attention on q, k [b, s, num_heads * dk] and v
     [b, s, num_heads * dv] with dk and dv multiples of 128 and dk != dv
     allowed; softmax(q k^T * scale) v -> [b, s, num_heads * dv]. The causal
-    tile schedule at its measured block (`_CAUSAL_BLOCK`)."""
+    tile schedule at its measured block (`_CAUSAL_BLOCK`); rows too long for
+    the default scope take the forward that names its own limit
+    (`wide_key_rows_exceed_scope`)."""
     b, s, f = q.shape
     assert k.shape == q.shape and v.shape[:2] == q.shape[:2], (
         q.shape, k.shape, v.shape
     )
     assert f % (128 * num_heads) == 0 and v.shape[-1] % (128 * num_heads) == 0
     assert wide_key_supported(s), s
-    return _flash_bshf_wide_key(
-        q, k, v, num_heads, _CAUSAL_BLOCK, interpret, float(scale)
+    long_rows = wide_key_rows_exceed_scope(
+        s, f // num_heads, v.shape[-1] // num_heads, q.dtype.itemsize
     )
+    entry = _flash_bshf_wide_key_long if long_rows else _flash_bshf_wide_key
+    return entry(q, k, v, num_heads, _CAUSAL_BLOCK, interpret, float(scale))
 
 
 # ---------------------------------------------------------------------------
@@ -2389,3 +2393,72 @@ def flash_attention_bshf_grouped(
         q, k, v, num_heads, num_kv_heads, _CAUSAL_BLOCK, interpret,
         d ** -0.5,
     )
+
+
+# ---------------------------------------------------------------------------
+# a wide key whose whole rows are too long for the scoped default
+# ---------------------------------------------------------------------------
+#
+# At 8,192 positions a head's padded key row [s, 256] and value row [s, 128]
+# in bf16 are 6 MB, 12 MB double-buffered: the whole of `_SCOPED_ROWS_BUDGET`,
+# and the causal forward does not compile inside the default scope there (a
+# described-chip compile of the latent node at [1, 8192, 2048], 32 heads of
+# 192 | 128: "Scoped allocation with size 48.08M and limit 48.00M exceeded",
+# PR 53). The same kernel body, one batch row a program, under the limit the
+# backward always named; the backward and its delta kernel are the wide-key
+# entry's own (they compile there as they are). Picked from the shapes by
+# `flash_attention_bshf_wide_key`; every shape that ran before keeps its
+# forward.
+
+
+def wide_key_rows_exceed_scope(s: int, dk: int, dv: int, itemsize: int) -> bool:
+    """Whether a (batch, head)'s key row [s, dk] and value row [s, dv],
+    double-buffered, leave the causal forward no room inside the default
+    scoped VMEM."""
+    return 2 * s * (dk + dv) * itemsize >= _SCOPED_ROWS_BUDGET
+
+
+def _fwd_bshf_wide_key_long(q, k, v, h, block, interpret, scale):
+    b, s, f = q.shape
+    d, dv = f // h, v.shape[-1] // h
+
+    def tile(width):
+        return pl.BlockSpec((1, block, width), lambda bi, hi, i: (bi, i, hi))
+
+    def row(width):
+        return pl.BlockSpec((1, s, width), lambda bi, hi, i: (bi, 0, hi))
+
+    return pl.pallas_call(
+        functools.partial(_fwd_causal_kernel, block_k=block, scale=scale),
+        name="flash_fwd_causal_wide_key",
+        interpret=interpret,
+        compiler_params=None if interpret else pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
+        ),
+        grid=(b, h, s // block),
+        in_specs=[tile(d), row(d), row(dv)],
+        out_specs=[
+            tile(dv),
+            pl.BlockSpec((1, None, 1, block), lambda bi, hi, i: (bi, hi, 0, i)),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
+            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
+        ],
+    )(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_bshf_wide_key_long(q, k, v, h, block, interpret, scale):
+    return _fwd_bshf_wide_key_long(q, k, v, h, block, interpret, scale)[0]
+
+
+def _flash_bshf_wide_key_long_fwd(q, k, v, h, block, interpret, scale):
+    o, lse = _fwd_bshf_wide_key_long(q, k, v, h, block, interpret, scale)
+    return o, (q, k, v, o, lse)
+
+
+_flash_bshf_wide_key_long.defvjp(
+    _flash_bshf_wide_key_long_fwd, _flash_bshf_wide_key_bwd
+)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from flexflow_tpu.op_attrs.ops.loss_functions import (
+    LabelCrossEntropyAttrs,
     LossAttrs,
     LossFunction,
     NonconfigurableLossAttrs,
@@ -33,35 +34,34 @@ def _fused_scce(logit: jnp.ndarray, label: jnp.ndarray) -> jnp.ndarray:
     return _scce_fwd_impl(logit, label)[0]
 
 
-def _scce_fwd_impl(logit, label):
+def _row_lse(logit):
+    """logsumexp over the classes in float32, [batch...]."""
     lf = logit.astype(jnp.float32)
     m = jnp.max(lf, axis=-1)
-    lse = m + jnp.log(jnp.sum(jnp.exp(lf - m[..., None]), axis=-1))
-    label = label.astype(jnp.int32)
-    # gather from the ORIGINAL logits: a gather operand cannot fuse, so
-    # gathering from the f32 conversion made XLA materialize the full
-    # [batch..., classes] array in f32 (4.2 GB on the [64,512,32000] LM
-    # head); the picked values are exact in the storage dtype and the
-    # subtraction happens in f32 anyway
-    picked = jnp.take_along_axis(logit, label[..., None], axis=-1)[
+    return m + jnp.log(jnp.sum(jnp.exp(lf - m[..., None]), axis=-1))
+
+
+def _picked(logit, label):
+    """The labelled logit of every row, float32. Gathered from the ORIGINAL
+    logits: a gather operand cannot fuse, so gathering from the f32
+    conversion made XLA materialize the full [batch..., classes] array in
+    f32 (4.2 GB on the [64,512,32000] LM head); the picked values are exact
+    in the storage dtype and the subtraction happens in f32 anyway."""
+    return jnp.take_along_axis(logit, label[..., None], axis=-1)[
         ..., 0
     ].astype(jnp.float32)
-    loss = jnp.mean(lse - picked)
-    return loss, (logit, label, lse)
 
 
-def _scce_bwd(res, g):
-    logit, label, lse = res
-    n = lse.size
-    # the gradient lives in the logit dtype END-TO-END: computing f32
-    # probabilities first made XLA materialize a full-precision
-    # [batch..., classes] fusion output (4.2 GB on the [64,512,32000] LM
-    # head, ~12 ms/step of pure HBM traffic) that the weight-grad matmuls
-    # then re-read. The normalized scores are exact in f32 up to the cast;
-    # p in bf16 has ~0.4% relative error on a value in (0, 1], far below
-    # gradient noise. FLEXFLOW_TPU_FLASH_F32_PROBS=1 (the same knob as the
-    # flash kernels') restores the f32 computation for accuracy-sensitive
-    # runs, paying the HBM traffic back.
+def _probs_minus_onehot(logit, label, lse):
+    """softmax(logit) - onehot(label) in the logit dtype END-TO-END:
+    computing f32 probabilities first made XLA materialize a full-precision
+    [batch..., classes] fusion output (4.2 GB on the [64,512,32000] LM
+    head, ~12 ms/step of pure HBM traffic) that the weight-grad matmuls
+    then re-read. The normalized scores are exact in f32 up to the cast;
+    p in bf16 has ~0.4% relative error on a value in (0, 1], far below
+    gradient noise. FLEXFLOW_TPU_FLASH_F32_PROBS=1 (the same knob as the
+    flash kernels') restores the f32 computation for accuracy-sensitive
+    runs, paying the HBM traffic back. A negative label matches no class."""
     from flexflow_tpu.kernels.flash_attention import _f32_probs
 
     z = logit.astype(jnp.float32) - lse[..., None]
@@ -72,11 +72,66 @@ def _scce_bwd(res, g):
         jax.lax.broadcasted_iota(jnp.int32, logit.shape, logit.ndim - 1)
         == label[..., None]
     )
-    dlogit = (p - onehot.astype(p.dtype)) * jnp.asarray(g / n, p.dtype)
+    return p - onehot.astype(p.dtype)
+
+
+def _scce_fwd_impl(logit, label):
+    lse = _row_lse(logit)
+    label = label.astype(jnp.int32)
+    loss = jnp.mean(lse - _picked(logit, label))
+    return loss, (logit, label, lse)
+
+
+def _scce_bwd(res, g):
+    logit, label, lse = res
+    diff = _probs_minus_onehot(logit, label, lse)
+    dlogit = diff * jnp.asarray(g / lse.size, diff.dtype)
     return dlogit.astype(logit.dtype), None
 
 
 _fused_scce.defvjp(_scce_fwd_impl, _scce_bwd)
+
+
+@jax.custom_vjp
+def _fused_masked_scce(logit: jnp.ndarray, label: jnp.ndarray) -> jnp.ndarray:
+    """`_fused_scce` as a mean over the positions whose label is not
+    negative (a position without a target weighs nothing; with none at all
+    the loss is zero): the same two passes over the logits in their own
+    dtype, no float32 [batch..., classes] array kept."""
+    return _masked_scce_fwd(logit, label)[0]
+
+
+def _masked_scce_fwd(logit, label):
+    lse = _row_lse(logit)
+    label = label.astype(jnp.int32)
+    valid = label >= 0
+    count = jnp.maximum(jnp.sum(valid), 1).astype(jnp.float32)
+    rows = jnp.where(valid, lse - _picked(logit, jnp.maximum(label, 0)), 0.0)
+    return jnp.sum(rows) / count, (logit, label, lse, count)
+
+
+def _masked_scce_bwd(res, g):
+    logit, label, lse, count = res
+    diff = _probs_minus_onehot(logit, label, lse)
+    scale = jnp.where(label >= 0, g / count, 0.0).astype(diff.dtype)
+    return (diff * scale[..., None]).astype(logit.dtype), None
+
+
+_fused_masked_scce.defvjp(_masked_scce_fwd, _masked_scce_bwd)
+
+
+def label_cross_entropy(
+    attrs: LabelCrossEntropyAttrs, logit: jnp.ndarray, label: jnp.ndarray
+) -> jnp.ndarray:
+    """The node's scalar [1]: `attrs.weight` times the mean cross-entropy
+    over the positions with a label, through the fused form. The unweighted
+    mean goes to the step's loss-term counter
+    (`observability/trace.loss_terms`)."""
+    from flexflow_tpu.observability import trace
+
+    mean = _fused_masked_scce(logit, label)
+    trace.record_loss_term(attrs.weight, mean)
+    return (mean * attrs.weight).reshape(1)  # float32, whatever the logits are
 
 
 def loss_forward(attrs: LossAttrs, logit: jnp.ndarray, label: jnp.ndarray) -> jnp.ndarray:

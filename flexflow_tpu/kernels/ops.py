@@ -491,22 +491,100 @@ def _unpack_flat(weight, shapes):
 
 def unpack_latent_weights(attrs: MultiHeadAttentionAttrs, esize: int, weight):
     """The latent layout (`MultiHeadAttentionAttrs.kv_latent_rank`): wq
-    [e, h*kd], wkv_a [e, rank + shared], wkv_b [rank, h*(own + vd)] (a
+    [e, h*kd] (with `q_latent_rank` wq_a [e, q rank] and wq_b [q rank, h*kd]
+    in its place), wkv_a [e, rank + shared], wkv_b [rank, h*(own + vd)] (a
     head's own key columns, then its value's) and wo [h*vd, e]."""
     H, rank = attrs.num_heads, attrs.kv_latent_rank
     kd, vd = attrs.q_proj_size, attrs.v_proj_size
-    return _unpack_flat(weight, [
-        (esize, H * kd), (esize, rank + attrs.shared_key_dim),
+    qr = attrs.q_latent_rank
+    query = [(esize, H * kd)] if qr is None else [(esize, qr), (qr, H * kd)]
+    return _unpack_flat(weight, query + [
+        (esize, rank + attrs.shared_key_dim),
         (rank, H * (attrs.own_key_dim + vd)), (H * vd, attrs.embed_dim),
     ])
 
 
-def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal):
+def rope_tables(s: int, width: int, theta: float):
+    """(cos, sin) [s, width / 2] in float32 of the angles
+    pos * theta^(-2j / width), positions 0..s-1: tables of their own (behind
+    a barrier), so that a pass over rows a head and position wide reads them
+    and does not take a cosine an element."""
+    half = width // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / width)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    return lax.optimization_barrier((jnp.cos(angle), jnp.sin(angle)))
+
+
+def rope_halves(x, cos, sin):
+    """x [b, s, ..., width] with pair j = columns (j, j + width / 2) turned
+    by the tables' angle at its position (axis 1): the products in float32,
+    the result in x's dtype."""
+    half = x.shape[-1] // 2
+    shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
+    cos, sin = cos.reshape(shape), sin.reshape(shape)
+    lo = x[..., :half].astype(jnp.float32)
+    hi = x[..., half:].astype(jnp.float32)
+    return jnp.concatenate(
+        [lo * cos - hi * sin, hi * cos + lo * sin], axis=-1
+    ).astype(x.dtype)
+
+
+def deinterleaved_columns(w, num_heads: int, start: int, width: int):
+    """w [rows, num_heads * d] with columns [start, start + width) of each
+    head block reordered evens first, then odds: what turns the pairing
+    (2j, 2j + 1) of what the matrix produces into (j, j + width / 2). Done
+    to the query's and the key's slice alike it leaves every dot product of
+    the two as it was (the public `deepseek_v3` layer does it to q and k)."""
+    rows = w.shape[0]
+    blocks = w.reshape(rows, num_heads, -1)
+    turned = blocks[..., start:start + width]
+    return jnp.concatenate([
+        blocks[..., :start], turned[..., 0::2], turned[..., 1::2],
+        blocks[..., start + width:],
+    ], axis=-1).reshape(w.shape)
+
+
+def _note_latent_form(attrs: MultiHeadAttentionAttrs, route, s, itemsize):
+    """Tell the program's counter which form the latent node being lowered
+    took (`observability/trace.latent_attention_forms`)."""
+    from flexflow_tpu.kernels.flash_attention import (
+        wide_key_padded,
+        wide_key_rows_exceed_scope,
+    )
+    from flexflow_tpu.observability import trace
+
+    rope = attrs.rope_theta is not None
+    core = "dense"
+    if route == "fused_row":
+        long_rows = wide_key_rows_exceed_scope(
+            s, wide_key_padded(attrs.q_proj_size), attrs.v_proj_size, itemsize
+        )
+        core = (
+            "flash_fwd_causal_wide_key" if long_rows else "flash_fwd_causal_bshf"
+        )
+    trace.note_latent_attention_form({
+        "query_rank": attrs.q_latent_rank,
+        "rotated_columns": attrs.shared_key_dim if rope else 0,
+        "pairing": (
+            None if not rope
+            else "interleaved" if attrs.rope_interleaved else "halves"
+        ),
+        "core": core,
+    })
+
+
+def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal,
+                        q_gain=None):
     """Latent self-attention on x [b, s, e]: keys and values from one normed
     low-rank row (scope `latent`), head h's key its own columns beside the
     slice all heads share, then the attention core (scope `core`): the
     wide-key flash kernels where `mha_core_route` says so, on a key padded
-    with zero columns, else XLA's dense attention."""
+    with zero columns, else XLA's dense attention. With `q_latent_rank` the
+    query comes from a normed low-rank row of its own (scope `latent` too),
+    and with `rope_theta` the shared slice, once a position before the heads
+    share it, and each query head's matching columns are turned (scope
+    `rows`): halves of the slice against each other, on weights whose slice
+    columns `rope_interleaved` puts evens first."""
     from flexflow_tpu.kernels.flash_attention import (
         flash_attention_bshf_wide_key,
         per_batch_shard,
@@ -516,34 +594,80 @@ def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal)
     H, rank, shared = attrs.num_heads, attrs.kv_latent_rank, attrs.shared_key_dim
     kd, vd, own = attrs.q_proj_size, attrs.v_proj_size, attrs.own_key_dim
     b, s, e = x.shape
-    wq, wkv_a, wkv_b, wo = unpack_latent_weights(attrs, e, weight)
+    *wq, wkv_a, wkv_b, wo = unpack_latent_weights(attrs, e, weight)
+    rope = attrs.rope_theta is not None
+    if rope:
+        with jax.named_scope("rows"):
+            cos, sin = rope_tables(s, shared, attrs.rope_theta)
+            if attrs.rope_interleaved:
+                wq[-1] = deinterleaved_columns(wq[-1], H, own, shared)
+                wkv_a = deinterleaved_columns(wkv_a, 1, rank, shared)
     with jax.named_scope("latent"):
         low = x @ wkv_a
         c = rms_norm(low[..., :rank], gain, attrs.kv_latent_norm_eps)
         kv = (c @ wkv_b).reshape(b, s, H, own + vd)
-        parts = [
-            kv[..., :own],
-            jnp.broadcast_to(low[:, :, None, rank:], (b, s, H, shared)),
-        ]
+        parts = [kv[..., :own]]
+        if not rope:
+            parts.append(
+                jnp.broadcast_to(low[:, :, None, rank:], (b, s, H, shared))
+            )
         v = kv[..., own:]
+    if rope:
+        with jax.named_scope("rows"):  # once a position, before the heads share it
+            turned = rope_halves(low[..., rank:], cos, sin)
+        with jax.named_scope("latent"):
+            parts.append(
+                jnp.broadcast_to(turned[:, :, None, :], (b, s, H, shared))
+            )
+
+    def project_q(w_last, pad=0):
+        """The query [b, s, H * (kd + pad)] on `w_last`, the last query
+        matrix: low-rank row and norm first (a full-rank projection is
+        booked to the core); the rotary after, in the pass that writes the
+        `pad` zero columns a head (without a rotary they are `w_last`'s
+        own)."""
+        if attrs.q_latent_rank is None:
+            with jax.named_scope("core"):
+                q = x @ w_last
+        else:
+            with jax.named_scope("latent"):
+                cq = rms_norm(x @ wq[0], q_gain, attrs.q_latent_norm_eps)
+                q = cq @ w_last
+        if rope:
+            with jax.named_scope("rows"):
+                heads = q.reshape(b, s, H, kd)
+                q = jnp.concatenate(
+                    [heads[..., :own], rope_halves(heads[..., own:], cos, sin)]
+                    + ([jnp.zeros((b, s, H, pad), q.dtype)] if pad else []),
+                    axis=-1,
+                ).reshape(b, s, H * (kd + pad))
+        return q
+
     route = mha_core_route(attrs, x.shape, x.shape, x.shape, True)
     _note_route(route)
+    _note_latent_form(attrs, route, s, x.dtype.itemsize)
     if route == "fused_row":
         pad = wide_key_padded(kd) - kd
         # zero columns of the WEIGHT are the padded query's zero columns
-        wq = jnp.pad(wq.reshape(e, H, kd), ((0, 0), (0, 0), (0, pad)))
+        # (with a rotary the pass that turns the query writes them)
+        wq_last = None if rope else jnp.pad(
+            wq[-1].reshape(-1, H, kd), ((0, 0), (0, 0), (0, pad))
+        )
         with jax.named_scope("latent"):
             k = jnp.concatenate(
                 parts + [jnp.zeros((b, s, H, pad), x.dtype)], axis=-1
             ).reshape(b, s, H * (kd + pad))
+        if rope:
+            q = project_q(wq[-1], pad)
+        else:
+            q = project_q(wq_last.reshape(-1, H * (kd + pad)))
         with jax.named_scope("core"):
             ctx = per_batch_shard(
-                flash_attention_bshf_wide_key,
-                x @ wq.reshape(e, H * (kd + pad)), k, v.reshape(b, s, H * vd),
+                flash_attention_bshf_wide_key, q, k, v.reshape(b, s, H * vd),
                 num_heads=H, scale=kd ** -0.5,
             )
         return ctx @ wo
-    q = (x @ wq).reshape(b, s, H, kd)
+    q = project_q(wq[-1]).reshape(b, s, H, kd)
     with jax.named_scope("core"):
         scores = jnp.einsum(
             "bshk,bthk->bhst", q, jnp.concatenate(parts, axis=-1)
@@ -878,7 +1002,10 @@ def forward(
         input_bias = weights[1] if attrs.bias else None
         causal = isinstance(attrs, RingAttentionAttrs) and attrs.causal
         if attrs.latent:  # self-attention: reads its first input
-            return [_latent_mha_forward(attrs, q, weights[0], weights[1], causal)]
+            return [_latent_mha_forward(
+                attrs, q, weights[0], weights[1], causal,
+                q_gain=weights[2] if attrs.q_latent_rank is not None else None,
+            )]
         out = _mha_forward(
             attrs, q, k, v, weights[0], input_bias, causal=causal,
             qk_gains=weights[-2:] if attrs.qk_norm else None,
@@ -886,6 +1013,13 @@ def forward(
         if attrs.bias:
             out = out + weights[2]
         return [out]
+
+    from flexflow_tpu.op_attrs.ops.loss_functions import LabelCrossEntropyAttrs
+
+    if isinstance(attrs, LabelCrossEntropyAttrs):
+        from flexflow_tpu.kernels.loss import label_cross_entropy
+
+        return [label_cross_entropy(attrs, inputs[0], inputs[1])]
 
     if isinstance(attrs, ConcatAttrs):
         return [jnp.concatenate(inputs, axis=attrs.axis)]
@@ -1143,9 +1277,10 @@ def op_forward_flops(
             + 2 * b * s * vd * attrs.embed_dim * H
         )
         if attrs.latent:
-            rank = attrs.kv_latent_rank
+            rank, qr = attrs.kv_latent_rank, attrs.q_latent_rank
             proj = 2 * b * s * (
-                e * (kd * H + rank + attrs.shared_key_dim)
+                (e * kd * H if qr is None else qr * (e + kd * H))
+                + e * (rank + attrs.shared_key_dim)
                 + rank * H * (attrs.own_key_dim + vd)
                 + vd * H * attrs.embed_dim
             )
@@ -1222,6 +1357,13 @@ def op_forward_flops(
         return b * s * attrs.width * (
             2 * d * 4 + 2 * attrs.conv_kernel + 2
         )
+
+    from flexflow_tpu.op_attrs.ops.loss_functions import LabelCrossEntropyAttrs
+
+    if isinstance(attrs, LabelCrossEntropyAttrs):
+        # a maximum, an exponential, two sums and a pick an element of the
+        # logits, whose one scalar says nothing of the passes
+        return 5 * nelem(input_shapes[0])
 
     total = sum(nelem(s) for s in output_shapes)
     return total

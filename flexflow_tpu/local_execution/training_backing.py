@@ -41,7 +41,10 @@ from flexflow_tpu.op_attrs.core import (
     op_type_of,
 )
 from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
-from flexflow_tpu.op_attrs.ops.loss_functions import LossAttrs
+from flexflow_tpu.op_attrs.ops.loss_functions import (
+    LabelCrossEntropyAttrs,
+    LossAttrs,
+)
 from flexflow_tpu.pcg.computation_graph import ComputationGraph
 from flexflow_tpu.pcg.initializer import InitializerAttrs, initialize
 from flexflow_tpu.pcg.optimizer import OptimizerAttrs
@@ -216,7 +219,15 @@ class ModelTrainingInstance:
         # barrier the logit producer's inputs (see forward_interpreter):
         # its dX matmul reads the huge [tokens, vocab] dlogits and must not
         # share a fusion with the upstream norm's backward reductions
-        self._barrier_nodes = frozenset({logit_tensor.node})
+        # (the same for the logits a loss node of the graph reads: a
+        # multi-token-prediction module's second use of the head)
+        self._barrier_nodes = frozenset({logit_tensor.node}) | frozenset(
+            cg.inputs_of(n)[0].node for n in cg.topological_ordering()
+            if isinstance(cg.op_attrs(n), LabelCrossEntropyAttrs)
+        )
+        # [(scope, weight)] of the step's loss terms, as the last trace of
+        # the step recorded them; empty in a graph with one loss
+        self.loss_term_names = []
         self._jit_step = None
         self._jit_fwd = None
 
@@ -242,15 +253,18 @@ class ModelTrainingInstance:
         return loss, logit
 
     def _loss_and_routing(self, params, batch_inputs, label, rng=None):
-        """(loss, (logits, the held expert nodes' routing counts stacked
-        [nodes, held + 3] as `routing.record` lays a row out, or None in a
-        graph without such a node))."""
+        """(loss, (logits, the step's counters by metric key: the held
+        expert nodes' routing counts stacked [nodes, held + 3] as
+        `routing.record` lays a row out, and the loss terms
+        (`trace.LOSS_TERMS_KEY`) of a graph with a loss node; a graph with
+        neither has none))."""
         from flexflow_tpu.observability import routing
 
         with trace.step_scope("cast"):
             params = self._cast_for_compute(params)
             batch_inputs = self._cast_for_compute(batch_inputs)
-        with routing.collecting() as held_rows:
+        with routing.collecting() as held_rows, \
+                trace.collecting_loss_terms() as terms:
             env = forward_interpreter(
                 self.cg,
                 params,
@@ -262,13 +276,24 @@ class ModelTrainingInstance:
         logit = env[self.logit_tensor]
         with trace.step_scope("loss"):
             loss = loss_forward(self.loss_attrs, logit, label)
+            if terms:  # a graph with a loss node: the main loss is the first
+                terms.insert(0, ("ff.loss", 1.0, loss))
             for t in self.aux_loss_tensors:
                 loss = loss + jnp.sum(env[t].astype(loss.dtype))
-        return loss, (logit, jnp.stack(held_rows) if held_rows else None)
+        counters = {}
+        if held_rows:
+            counters[routing.ROUTING_KEY] = jnp.stack(held_rows)
+        if terms:
+            self.loss_term_names = [(name, w) for name, w, _ in terms]
+            counters[trace.LOSS_TERMS_KEY] = jnp.stack(
+                [v.astype(jnp.float32) for _, _, v in terms]
+                + [jnp.ones((), jnp.float32)]
+            )
+        return loss, (logit, counters)
 
     def _step(self, params, opt_state, batch_inputs, label, rng):
         trace.count(trace.STEP_TRACE)  # this body runs when JAX traces it
-        (loss, (logit, held_rows)), grads = jax.value_and_grad(
+        (loss, (logit, counters)), grads = jax.value_and_grad(
             self._loss_and_routing, has_aux=True
         )(params, batch_inputs, label, rng)
         with trace.step_scope("optimizer"):
@@ -277,10 +302,7 @@ class ModelTrainingInstance:
             )
         with trace.step_scope("metrics"):
             metric_vals = compute_metrics(self.metrics, logit, label)
-            if held_rows is not None:
-                from flexflow_tpu.observability.routing import ROUTING_KEY
-
-                metric_vals[ROUTING_KEY] = held_rows
+            metric_vals.update(counters)
         # run-health scalars, fused into this same XLA program: each global
         # norm is one reduction over the pytree, not a host trip per leaf;
         # under skip_step/raise a non-finite update never reaches the

@@ -592,6 +592,9 @@ NODE_PARTS = {
     # `output_gate`): the norm-and-rotary pass over the projected rows and
     # the gate (its split from the query, its sigmoid on the context),
     # apart from the flash kernels (`core`)
+    # (on latent attention with a query rank `latent` holds the query's two
+    # projections and their norm too, and with a rotary `rows` is the rotary
+    # pass over the shared key slice and the queries' matching columns)
     "ring_attention": ("latent", "core", "rows", "gate"),
     # the double-gated short-convolution node (`kernels/short_conv.py`): the
     # input gate, the taps and the output gate between its two projections
@@ -636,6 +639,7 @@ def scope_name(graph, n) -> str:
 # grouped matmuls' tiles each expert node took likewise
 _lowering = threading.local()
 _ATTENTION_ROUTES: Dict[str, str] = {}
+_LATENT_ATTENTION_FORMS: Dict[str, dict] = {}
 _DELTA_RULE_OPERANDS: Dict[str, str] = {}
 _GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
 _HELD_ROW_SUMS: Dict[str, Dict[str, dict]] = {}
@@ -670,6 +674,26 @@ def attention_routes() -> Dict[str, str]:
     program counter a reader (the benchmark's `gqa64_flash_roofline`) prints
     beside what it measures, so that a change of route says so itself."""
     return dict(_ATTENTION_ROUTES)
+
+
+def note_latent_attention_form(form: dict) -> None:
+    """The form the latent-attention node being lowered took
+    (`kernels/ops._latent_mha_forward`); dropped where no node's scope is
+    open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _LATENT_ATTENTION_FORMS[scope] = dict(form)
+
+
+def latent_attention_forms() -> Dict[str, dict]:
+    """`{ff.<kind>.<name>: form}` of every latent-attention node this
+    process has lowered, as it was lowered last: `query_rank` (None: one
+    full-rank query projection), `rotated_columns` (of the shared key slice
+    and of each query head; 0: no position encoding), `pairing`
+    (`interleaved`: columns (2j, 2j + 1); `halves`: (j, j + width / 2); None)
+    and `core` (the forward kernel of the wide-key entry, or `dense`), so
+    that a run says itself which node it measured."""
+    return {scope: dict(f) for scope, f in _LATENT_ATTENTION_FORMS.items()}
 
 
 def note_delta_rule_operands(form: str) -> None:
@@ -734,6 +758,71 @@ def held_row_sums() -> Dict[str, Dict[str, dict]]:
     return {
         scope: {site: dict(e) for site, e in entries.items()}
         for scope, entries in _HELD_ROW_SUMS.items()
+    }
+
+
+# -- the step's loss terms --------------------------------------------------
+#
+# A graph with a loss NODE (`LabelCrossEntropyAttrs`: a multi-token-prediction
+# module's loss) trains on a sum of terms. The terms exist in the step anyway;
+# keeping them apart costs one small vector among the step's metric values.
+# `kernels/loss.label_cross_entropy` hands each node's unweighted mean to
+# `record_loss_term` while the step is traced, the training instance records
+# the main loss first (`ff.loss`, weight 1) under `collecting_loss_terms` and
+# returns the values with a trailing 1 as the metric value `LOSS_TERMS_KEY`,
+# `fit` sums metric values over a call's steps, and `publish_loss_terms` at
+# the call's end is where `loss_terms()` finds the latest. A graph without
+# such a node records nothing and its step is the one it always was.
+
+LOSS_TERMS_KEY = "loss_terms"
+_published_loss_terms: Optional[Dict[str, Dict[str, float]]] = None
+
+
+@contextlib.contextmanager
+def collecting_loss_terms():
+    """While the body traces, `record_loss_term` appends (scope, weight,
+    value) to the list this yields."""
+    previous = getattr(_lowering, "loss_terms", None)
+    sink: list = []
+    _lowering.loss_terms = sink
+    try:
+        yield sink
+    finally:
+        _lowering.loss_terms = previous
+
+
+def record_loss_term(weight: float, value, scope: Optional[str] = None) -> None:
+    """One term of the step's loss: its weight in the sum and its UNWEIGHTED
+    value (a float32 scalar tracer), under `scope` or the scope of the node
+    being lowered. Dropped where nobody collects."""
+    sink = getattr(_lowering, "loss_terms", None)
+    if sink is not None:
+        name = scope or getattr(_lowering, "scope", None) or f"term{len(sink)}"
+        sink.append((name, float(weight), value))
+
+
+def publish_loss_terms(terms, sums) -> None:
+    """`terms` [(scope, weight)] as they were recorded and `sums` [terms + 1]
+    summed over the steps of one `fit` call: each term's values, then the
+    steps."""
+    global _published_loss_terms
+    import numpy as np
+
+    sums = np.asarray(sums, dtype=np.float64)
+    _published_loss_terms = {
+        name: {"weight": weight, "mean": float(total / sums[-1])}
+        for (name, weight), total in zip(terms, sums[:-1])
+    }
+
+
+def loss_terms() -> Optional[Dict[str, Dict[str, float]]]:
+    """`{scope: {"weight", "mean"}}` of the last `fit` call of a graph with a
+    loss node: each term of the training loss by the scope it is computed
+    under (`ff.loss` the main one, `ff.label_loss.<name>` a node's), its
+    weight in the sum and its unweighted mean over the call's steps; None
+    before any, and in a process whose graphs have one loss."""
+    return _published_loss_terms and {
+        name: dict(term) for name, term in _published_loss_terms.items()
     }
 
 

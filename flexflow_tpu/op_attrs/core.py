@@ -76,6 +76,7 @@ from flexflow_tpu.op_attrs.ops.moe import (
 from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
+from flexflow_tpu.op_attrs.ops.loss_functions import LabelCrossEntropyAttrs
 
 
 # mixers and the experts op: their attrs list their own weight slots
@@ -122,6 +123,7 @@ class OperatorType(enum.Enum):
     STATE_SPACE = "state_space"  # selective state-space mixer (chunked scan)
     GATED_DELTA = "gated_delta"  # gated delta-rule linear attention (chunked)
     SHORT_CONV = "short_conv"  # double-gated short-convolution mixer
+    LABEL_LOSS = "label_loss"  # cross-entropy against a label tensor of the graph
     REPARTITION = "repartition"
     COMBINE = "combine"
     REPLICATE = "replicate"
@@ -148,7 +150,7 @@ OpAttrs = Union[
     ConcatAttrs, StackAttrs, SplitAttrs, ReshapeAttrs, TransposeAttrs,
     ReverseAttrs, GatherAttrs, TopKAttrs, ReduceAttrs,
     GroupByAttrs, AggregateAttrs, ExpertsAttrs, StateSpaceAttrs,
-    GatedDeltaAttrs, ShortConvAttrs,
+    GatedDeltaAttrs, ShortConvAttrs, LabelCrossEntropyAttrs,
     RepartitionAttrs, CombineAttrs, ReplicateAttrs, ReductionAttrs,
     StagePartitionAttrs, StageMergeAttrs,
 ]
@@ -190,6 +192,7 @@ _OP_TYPE_BY_ATTRS = {
     StateSpaceAttrs: OperatorType.STATE_SPACE,
     GatedDeltaAttrs: OperatorType.GATED_DELTA,
     ShortConvAttrs: OperatorType.SHORT_CONV,
+    LabelCrossEntropyAttrs: OperatorType.LABEL_LOSS,
     RepartitionAttrs: OperatorType.REPARTITION,
     CombineAttrs: OperatorType.COMBINE,
     ReplicateAttrs: OperatorType.REPLICATE,
@@ -248,7 +251,7 @@ def get_incoming_tensor_roles(attrs: OpAttrs) -> List[IncomingTensorRole]:
         if attrs.qk_norm:
             roles += [W, W]
         if attrs.latent:
-            roles += [W]
+            roles += [W] * (1 + (attrs.q_latent_rank is not None))
         return roles
     if isinstance(attrs, BatchNormAttrs):
         return [I, W, W] if attrs.affine else [I]
@@ -265,7 +268,10 @@ def get_incoming_tensor_roles(attrs: OpAttrs) -> List[IncomingTensorRole]:
 def num_data_inputs(attrs: OpAttrs) -> int:
     if isinstance(attrs, (InputAttrs, WeightAttrs)):
         return 0
-    if isinstance(attrs, (ElementBinaryAttrs, BatchMatmulAttrs, GatherAttrs)):
+    if isinstance(attrs, (
+        ElementBinaryAttrs, BatchMatmulAttrs, GatherAttrs,
+        LabelCrossEntropyAttrs,
+    )):
         return 2
     if isinstance(attrs, GroupByAttrs):
         return 2
@@ -344,6 +350,8 @@ def get_weight_shapes(
             ws += [attrs.qk_gain_shape(q, k, v)] * 2
         if attrs.latent:
             ws += [attrs.latent_gain_shape(q)]
+            if attrs.q_latent_rank is not None:
+                ws += [attrs.q_latent_gain_shape(q)]
         return ws
     if isinstance(attrs, BatchNormAttrs) and attrs.affine:
         return [attrs.gamma_shape(inputs[0]), attrs.beta_shape(inputs[0])]
@@ -382,8 +390,13 @@ def get_default_weight_initializers(attrs: OpAttrs, num_weights: int):
         )
         return [None] * (num_weights - 2) + [one] * 2
     if isinstance(attrs, MultiHeadAttentionAttrs) and attrs.latent:
-        # the latent norm's gain is the last slot
-        return [None] * (num_weights - 1) + [ConstantInitializerAttrs(1.0)]
+        # the latent norm's gain and, with a query rank, the query norm's
+        # are the last slots
+        gains = 1 + (attrs.q_latent_rank is not None)
+        return (
+            [None] * (num_weights - gains)
+            + [ConstantInitializerAttrs(1.0)] * gains
+        )
     if isinstance(attrs, StateSpaceAttrs):
         from flexflow_tpu.pcg.initializer import (
             InverseSoftplusLogUniformInitializerAttrs,
@@ -489,6 +502,8 @@ def get_parallel_weight_shapes(
             ws += [attrs.parallel_qk_gain_shape(q, k, v)] * 2
         if attrs.latent:
             ws += [attrs.parallel_latent_gain_shape(q, k, v)]
+            if attrs.q_latent_rank is not None:
+                ws += [attrs.parallel_q_latent_gain_shape(q, k, v)]
         return ws
     if isinstance(attrs, Conv2DAttrs):
         ws = [attrs.parallel_kernel_shape(inputs[0])]

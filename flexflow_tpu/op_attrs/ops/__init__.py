@@ -62,6 +62,7 @@ from flexflow_tpu.op_attrs.ops.loss_functions import (
     SparseCategoricalCrossEntropyLossAttrs,
     NonconfigurableLossAttrs,
     LossAttrs,
+    LabelCrossEntropyAttrs,
 )
 from flexflow_tpu.op_attrs.ops.moe import (
     GroupByAttrs,

@@ -66,7 +66,11 @@ class MultiHeadAttentionAttrs:
     # is the whole key (and query) width, so kdim != vdim is allowed here.
     # The weight is one flat column [wq | wkv_a | wkv_b | wo, 1] (wq
     # [e, h * kdim], wo [h * vdim, e]), and the latent norm's gain is one
-    # more weight slot after it. No bias, rotary, QK-norm or grouped heads.
+    # more weight slot after it. No bias, QK-norm, output gate or grouped
+    # heads. `rope_theta` beside it turns the SHARED slice, once a position
+    # before it is broadcast over the heads, and the LAST `shared_key_dim`
+    # columns of each head's query (the `deepseek_v3` family's decoupled
+    # rotary; `rotary_dim` stays None: the slice's width is the rotary's).
     kv_latent_rank: Optional[int] = None
     shared_key_dim: int = 0
     kv_latent_norm_eps: float = 1e-5
@@ -92,12 +96,27 @@ class MultiHeadAttentionAttrs:
     # qk_norm_zero_centered: the two QK-norm weights start at ZERO and the
     # gains are 1 + w (`RMSNormAttrs.zero_centered`).
     qk_norm_zero_centered: bool = False
+    # q_latent_rank: with `kv_latent_rank`, the query comes from a low-rank
+    # row of its own: q = rms_norm(x W_q_a; gain [q rank]) W_q_b
+    # (`q_lora_rank`). The flat weight holds wq_a [e, q rank] and wq_b
+    # [q rank, h * kdim] where wq was, and the query norm's gain is one more
+    # weight slot, the LAST (after the latent norm's).
+    q_latent_rank: Optional[int] = None
+    q_latent_norm_eps: float = 1e-5
+    # rope_interleaved: with `rope_theta` on latent attention, the rotary
+    # pairs columns (2j, 2j + 1) of the slice (`rope_interleave`), angle
+    # pos * theta^(-2j / width); False pairs (j, j + width / 2), rotate-half.
+    # The two differ by one fixed permutation of the slice's columns.
+    rope_interleaved: bool = False
 
     def __post_init__(self):
         assert self.rotary_dim is None or (
             self.rope_theta is not None and self.rotary_dim % 2 == 0
             and 0 < self.rotary_dim <= self.q_proj_size
         ), f"rotary_dim {self.rotary_dim} needs rope_theta and an even width"
+        assert self.rotary_dim is None or self.kv_latent_rank is None, (
+            "latent attention's rotary is as wide as the shared key slice"
+        )
         assert not self.qk_norm_zero_centered or self.qk_norm, (
             "qk_norm_zero_centered says how qk_norm_eps norms: it needs one"
         )
@@ -113,17 +132,29 @@ class MultiHeadAttentionAttrs:
             )
         if self.kv_latent_rank is not None:
             assert not (
-                self.bias or self.qk_norm or self.rope_theta is not None
-                or self.num_kv_heads is not None
+                self.bias or self.qk_norm or self.num_kv_heads is not None
                 or self.output_gate
-            ), "latent attention takes no bias, QK-norm, rotary, gate or grouped heads"
+            ), "latent attention takes no bias, QK-norm, gate or grouped heads"
             assert self.kdim > self.shared_key_dim >= 0 and self.vdim > 0, (
                 "latent attention names its key and value widths"
+            )
+            assert self.rope_theta is None or (
+                self.shared_key_dim > 0 and self.shared_key_dim % 2 == 0
+            ), (
+                "latent attention's rotary turns the shared key slice and "
+                "the query's matching columns: it needs an even shared_key_dim"
             )
         else:
             assert self.shared_key_dim == 0, (
                 "a shared key slice comes with kv_latent_rank"
             )
+            assert self.q_latent_rank is None and not self.rope_interleaved, (
+                "q_latent_rank and rope_interleaved are latent attention's "
+                "(kv_latent_rank)"
+            )
+        assert not self.rope_interleaved or self.rope_theta is not None, (
+            "rope_interleaved says how rope_theta pairs columns: it needs one"
+        )
         if self.num_kv_heads is not None:
             assert self.num_heads % self.num_kv_heads == 0, (
                 f"{self.num_heads} query heads do not divide over "
@@ -198,8 +229,12 @@ class MultiHeadAttentionAttrs:
         self._check_inputs(q, k, v)
         if self.latent:
             h, rank = self.num_heads, self.kv_latent_rank
+            qr = self.q_latent_rank
             flat = (
-                q.dims[-1] * h * self.q_proj_size
+                (
+                    q.dims[-1] * h * self.q_proj_size if qr is None
+                    else q.dims[-1] * qr + qr * h * self.q_proj_size
+                )
                 + k.dims[-1] * (rank + self.shared_key_dim)
                 + rank * h * (self.own_key_dim + self.v_proj_size)
                 + h * self.v_proj_size * self.embed_dim
@@ -238,6 +273,9 @@ class MultiHeadAttentionAttrs:
 
     def latent_gain_shape(self, q: TensorShape) -> TensorShape:
         return TensorShape((self.kv_latent_rank,), q.dtype)
+
+    def q_latent_gain_shape(self, q: TensorShape) -> TensorShape:
+        return TensorShape((self.q_latent_rank,), q.dtype)
 
     # -- parallel ---------------------------------------------------------
 
@@ -341,5 +379,13 @@ class MultiHeadAttentionAttrs:
     ) -> ParallelTensorShape:
         """The latent norm's gain, replicated wherever the flat weight is."""
         unpar = self.latent_gain_shape(get_reduced_shape(q))
+        copies = self.parallel_weights_shape(q, k, v).discard_copy_degree
+        return lift_to_parallel_with_degrees(unpar, 1, copies, (1,))
+
+    def parallel_q_latent_gain_shape(
+        self, q: ParallelTensorShape, k: ParallelTensorShape, v: ParallelTensorShape
+    ) -> ParallelTensorShape:
+        """The query norm's gain, replicated wherever the flat weight is."""
+        unpar = self.q_latent_gain_shape(get_reduced_shape(q))
         copies = self.parallel_weights_shape(q, k, v).discard_copy_degree
         return lift_to_parallel_with_degrees(unpar, 1, copies, (1,))

@@ -252,6 +252,9 @@ class ComputationGraphBuilder:
         rotary_dim: Optional[int] = None,
         output_gate: bool = False,
         qk_norm_zero_centered: bool = False,
+        q_latent_rank: Optional[int] = None,
+        q_latent_norm_eps: float = 1e-5,
+        rope_interleaved: bool = False,
     ) -> Tensor:
         """`causal`, `rope_theta` (rotary positions 0..s-1 on q and k) and
         `qk_norm_eps` (RMS norm of the projected q and k over all heads'
@@ -264,12 +267,17 @@ class ComputationGraphBuilder:
         columns one slice for all heads (`MultiHeadAttentionAttrs`).
         `rotary_dim` turns only a head's first columns, `output_gate` puts a
         sigmoid gate from the query projection on the context, and
-        `qk_norm_zero_centered` makes the QK-norm gains 1 + w."""
+        `qk_norm_zero_centered` makes the QK-norm gains 1 + w. On latent
+        attention `q_latent_rank` gives the query a normed low-rank row of
+        its own, and `rope_theta` turns the shared key slice and each query
+        head's last `shared_key_dim` columns, pairs (2j, 2j + 1) with
+        `rope_interleaved`."""
         fields = (
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, rope_theta, qk_norm_eps, num_kv_heads,
             kv_latent_rank, shared_key_dim, kv_latent_norm_eps,
             qk_norm_per_head, rotary_dim, output_gate, qk_norm_zero_centered,
+            q_latent_rank, q_latent_norm_eps, rope_interleaved,
         )
         if causal:
             from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
@@ -617,6 +625,23 @@ class ComputationGraphBuilder:
         if len(outs) > 1 and outs[1] not in self.aux_loss_tensors:
             self.aux_loss_tensors.append(outs[1])
         return outs
+
+    def label_cross_entropy(
+        self, logits: Tensor, labels: Tensor, weight: float = 1.0,
+        name: Optional[str] = None,
+    ) -> Tensor:
+        """A loss node (`LabelCrossEntropyAttrs`): `weight` times the mean
+        cross-entropy of `logits` [batch..., classes] against `labels`
+        [batch...], an integer tensor of the graph, over the positions whose
+        label is not negative. The scalar [1] is recorded in
+        `self.aux_loss_tensors`: training adds it to its loss."""
+        from flexflow_tpu.op_attrs.ops import LabelCrossEntropyAttrs
+
+        (out,) = self.add_layer(
+            LabelCrossEntropyAttrs(weight), [logits, labels], [], name
+        )
+        self.aux_loss_tensors.append(out)
+        return out
 
     def state_space(
         self,

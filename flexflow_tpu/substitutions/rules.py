@@ -759,7 +759,7 @@ def branch_reduce_sum_rule(degree: int) -> Substitution:
 def data_parallel_attention_rule(
     degree: int, bias: bool = False, qk_norm: bool = False,
     op_type: OperatorType = OperatorType.MULTIHEAD_ATTENTION,
-    latent: bool = False,
+    latent: bool = False, q_latent: bool = False,
 ) -> Substitution:
     """MHA(q,k,v,w[,bi,bo][,gq,gk]) -> Combine_0(MHA(Repartition_0(q,k,v),
     Replicate(w)[, Replicate(bi), Replicate(bo)][, Replicate(gq),
@@ -772,24 +772,32 @@ def data_parallel_attention_rule(
     of its own. `op_type=RING_ATTENTION` is the same rewrite for the
     program's causal attention, whose sequence dim stays whole here;
     `latent=True` matches latent attention (the latent norm's gain as one
-    more weight)."""
+    more weight), `q_latent=True` latent attention with a query rank (the
+    query norm's gain as one more after it); a rotary on the shared slice
+    adds no slot."""
     p = PCGPattern()
     q = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     k = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     v = p.add_input(TensorAttributePattern.dim_divisible_by(0, degree))
     weights = [
         p.add_input()
-        for _ in range(1 + 2 * bool(bias) + 2 * bool(qk_norm) + bool(latent))
+        for _ in range(
+            1 + 2 * bool(bias) + 2 * bool(qk_norm) + bool(latent)
+            + bool(q_latent)
+        )
     ]
-    ne = {}
+    ne, eq = {}, dict(bias=bias)
     if qk_norm:
         ne.update(qk_norm_eps=None)
     if latent:
         ne.update(kv_latent_rank=None)
+    if q_latent:
+        assert latent, "a query rank is latent attention's"
+        ne.update(q_latent_rank=None)
     pnode, (py,) = p.add_operator(
         _attr_pattern(
             op_type,
-            eq=dict(bias=bias),
+            eq=eq,
             ne=ne or None,
         ),
         [q, k, v, *weights],
@@ -811,10 +819,38 @@ def data_parallel_attention_rule(
         f"data_parallel_"
         f"{'ring_' if op_type == OperatorType.RING_ATTENTION else ''}"
         f"attention_{'b_' if bias else ''}{'qkn_' if qk_norm else ''}"
-        f"{'lat_' if latent else ''}{degree}",
+        f"{'lat_' if latent else ''}{'qlat_' if q_latent else ''}{degree}",
         p,
         og,
         ((q, oq), (k, ok), (v, ov), *zip(weights, o_weights)),
+        ((py, out),),
+    )
+
+
+def data_parallel_label_loss_rule(degree: int) -> Substitution:
+    """LabelLoss(logits, labels) -> Reduction(LabelLoss(Repartition_0(logits),
+    Repartition_0(labels))): sample parallelism for a loss node. Each batch
+    shard's scalar is a partial sum of the one mean over the labelled
+    positions of all shards (in the global view the op computes exactly
+    that), so the output carries sum_degree = degree and a Reduction
+    completes it."""
+    p = PCGPattern()
+    x = p.add_input(_shard_pattern(0, degree))
+    y = p.add_input(_shard_pattern(0, degree))
+    pnode, (py,) = p.add_operator(
+        OperatorAttributePattern.for_op_type(OperatorType.LABEL_LOSS), [x, y]
+    )
+    og = OutputGraphExpr()
+    ox, oy = og.add_input(), og.add_input()
+    _, (xp,) = og.add_operator(AttrConstant(RepartitionAttrs(0, degree)), [ox])
+    _, (yp,) = og.add_operator(AttrConstant(RepartitionAttrs(0, degree)), [oy])
+    _, (loss,) = og.add_operator(CopyAttrsFromMatched(pnode), [xp, yp])
+    _, (out,) = og.add_operator(AttrConstant(ReductionAttrs(degree)), [loss])
+    return Substitution(
+        f"data_parallel_label_loss_{degree}",
+        p,
+        og,
+        ((x, ox), (y, oy)),
         ((py, out),),
     )
 
@@ -1135,6 +1171,12 @@ def generate_parallelization_rules(
                     k, False, op_type=op_type, latent=True
                 )
             )
+            rules.append(
+                data_parallel_attention_rule(
+                    k, False, op_type=op_type, latent=True, q_latent=True
+                )
+            )
+        rules.append(data_parallel_label_loss_rule(k))
         rules.append(data_parallel_layer_norm_rule(k))
         rules.append(data_parallel_rms_norm_rule(k))
         rules.append(data_parallel_state_space_rule(k))

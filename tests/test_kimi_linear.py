@@ -430,9 +430,11 @@ def test_latent_attention_slots():
     assert attrs.weights_shape(x, x, x).dims == (1536 + 512 + 768 + 1024, 1)
     assert attrs.latent_gain_shape(x).dims == (12,)
     assert attrs.q_proj_size == 12 and attrs.own_key_dim == 8
+    # a rotary beside the latent rank is the shared slice's since PR 53
+    # (`tests/test_joyai_llm_flash.py`); QK-norm is still refused
     with pytest.raises(AssertionError):
         RingAttentionAttrs(32, 4, kdim=12, vdim=8, kv_latent_rank=12,
-                           shared_key_dim=4, rope_theta=1e4, causal=True)
+                           shared_key_dim=4, qk_norm_eps=1e-5, causal=True)
 
 
 def test_latent_attention_matches_plain_softmax_attention():

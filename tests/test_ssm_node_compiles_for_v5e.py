@@ -72,6 +72,20 @@ whose core must be the three `*_grouped` kernels (forward, delta, backward):
 the entries that keep k and v as whole rows under the default scope do not
 compile at this length and head size.
 
+And what the `joyai_llm_flash` cell added (PR 53), at its shape, one sequence
+of 8,192 positions: ONE latent-attention node with a query rank of 1,536 and a
+rotary on the 64 shared key columns, 32 heads of 192 | 128, forward and
+backward, whose core must be the wide-key forward that names its own limit
+(`flash_fwd_causal_wide_key`: the whole-row forward under the default scope
+does not compile at this length, "Scoped allocation with size 48.08M and limit
+48.00M exceeded") beside the wide-key entry's own backward and delta kernels;
+and the fused loss read TWICE through one head (the main loss and a masked
+second one), which must lower and keep no float32 `[8192, 16160]` logit
+tensor between ENTRY instructions. `python
+tests/test_ssm_node_compiles_for_v5e.py joyai_step <held experts>` compiles
+the cell's WHOLE step for the described chip and prints its bytes
+(15,103,823,872 at 16 held, 12,162,398,208 at 8, 60-100 s each, PR 53).
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -788,6 +802,157 @@ def check_qwen3next():
     return found
 
 
+JOYAI_INVARIANTS = [
+    "latent_node_with_rotary_compiles_on_the_long_row_forward",
+    "fused_loss_read_twice_keeps_no_float32_logits",
+]
+JOYAI_SHAPE = (1, 8192, 2048)
+JOYAI_VOCAB_ROWS = 16160
+
+
+def check_joyai():
+    """{invariant: "ok" or what was found} for the `joyai_llm_flash` cell's
+    new node and its second loss at the published shape."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.kernels import loss, ops
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops import (
+        LabelCrossEntropyAttrs,
+        RingAttentionAttrs,
+    )
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    found = {}
+    on_chip = _described_chip()
+    x = on_chip(JOYAI_SHAPE)
+    shape = TensorShape(JOYAI_SHAPE, DataType.FLOAT)
+    try:
+        attrs = RingAttentionAttrs(
+            2048, 32, kdim=192, vdim=128, causal=True, rope_theta=3.2e7,
+            rope_interleaved=True, kv_latent_rank=512, shared_key_dim=64,
+            kv_latent_norm_eps=1e-6, q_latent_rank=1536,
+            q_latent_norm_eps=1e-6,
+        )
+        flat = on_chip(attrs.weights_shape(shape, shape, shape).dims)
+        g_kv, g_q = on_chip((512,)), on_chip((1536,))
+
+        def scoped(x, flat, g_kv, g_q):
+            with jax.named_scope("ff.ring_attention.mla1"):
+                return ops._latent_mha_forward(
+                    attrs, x, flat, g_kv, True, q_gain=g_q
+                )
+
+        def node(x, flat, g_kv, g_q, cot):
+            y, vjp = jax.vjp(scoped, x, flat, g_kv, g_q)
+            return y, vjp(cot)
+
+        text = jax.jit(node).lower(x, flat, g_kv, g_q, x).compile().as_text()
+        names = sorted(set(re.findall(r"/(flash_\w+)/pallas_call", text)))
+        want = ["flash_bwd_causal_bshf", "flash_delta_bshf",
+                "flash_fwd_causal_wide_key"]
+        found[JOYAI_INVARIANTS[0]] = (
+            "ok" if names == want else f"kernels {names}, want {want}"
+        )
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        found[JOYAI_INVARIANTS[0]] = f"{type(e).__name__}: {e}"[:2000]
+    try:
+        rows, vocab = JOYAI_SHAPE[1], JOYAI_VOCAB_ROWS
+        head = on_chip((JOYAI_SHAPE[2], vocab))
+        labels = jax.ShapeDtypeStruct((1, rows), jnp.int32, sharding=x.sharding)
+        second = LabelCrossEntropyAttrs(weight=0.3)
+
+        def both(h, z, head, y, y2):
+            def total(h, z, head):
+                return loss._fused_scce(h @ head, y) + loss.label_cross_entropy(
+                    second, z @ head, y2
+                )[0]
+
+            return jax.value_and_grad(total, argnums=(0, 1, 2))(h, z, head)
+
+        text = jax.jit(both).lower(x, x, head, labels, labels).compile().as_text()
+        whole = [
+            f"{name}: {result[:60]}"
+            for name, result, opcode, _, _ in entry_instructions(text)
+            if opcode not in _NO_BUFFER and any(
+                dtype == "f32" and math.prod(dims) >= rows * vocab
+                for dtype, dims in shapes_of(result)
+            )
+        ]
+        found[JOYAI_INVARIANTS[1]] = "ok" if not whole else ", ".join(whole)
+    except Exception as e:  # noqa: BLE001
+        found[JOYAI_INVARIANTS[1]] = f"{type(e).__name__}: {e}"[:2000]
+    return found
+
+
+def joyai_step_bytes(held, root):
+    """The `joyai_llm_flash` cell's WHOLE step compiled for the described
+    chip with `held` experts a node: XLA's bytes as `run.py` adds them
+    (`step_hbm_gb`), the parameters, the Pallas kernels' names. The state
+    is never allocated (`jax.eval_shape` of the instance's `initialize`)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    sys.path.insert(0, os.path.join(root, "benchmark"))
+    import run as bench
+
+    from flexflow_tpu.analysis import lowering
+    from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+    from flexflow_tpu.local_execution import training_backing as tb
+
+    on_chip = _described_chip()
+    chip = on_chip((1,)).sharding
+    spec = bench.load_cell(
+        os.path.join(root, "BENCHMARK.json"), "joyaiflash48b_s8192_1chip"
+    )
+    config = dict(spec["config"], n_routed_experts=held)
+    job, training = spec["job"], spec["config"]["training"]
+    module = bench.load_module(spec["module_path"])
+    graph, logits = module.build(config, job["batch_per_chip"], job["seq"])
+    model = FFModel.from_computation_graph(
+        graph, logits,
+        FFConfig(batch_size=job["batch_per_chip"], seed=1, print_freq=0,
+                 max_devices=1),
+    )
+    initialize = tb.ModelTrainingInstance.initialize
+    tb.ModelTrainingInstance.initialize = (
+        lambda self, seed=0: jax.eval_shape(lambda: initialize(self, seed))
+    )
+    t0 = time.time()
+    model.compile(
+        AdamOptimizer(
+            alpha=training["alpha"], beta1=training["beta1"],
+            beta2=training["beta2"], epsilon=training["epsilon"],
+            weight_decay=training["weight_decay"],
+        ),
+        training["loss"], compute_dtype=jnp.dtype(training["compute_dtype"]),
+    )
+    example = lowering.step_example_args_cg(model.instance, model.loss_attrs)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip),
+        (model.params, model.opt_state, *example),
+    )
+    compiled = model.instance.compiled_step().lower(*args).compile()
+    mem = compiled.memory_analysis()
+    text = compiled.as_text()
+    return {
+        "held_experts": held,
+        "parameters": sum(
+            math.prod(v.shape) for v in jax.tree_util.tree_leaves(model.params)
+        ),
+        "step_hbm_gb": (
+            mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes
+        ) / 1e9,
+        "temp_bytes": mem.temp_size_in_bytes,
+        "kernels": sorted(set(re.findall(r"/(\w+)/pallas_call", text))),
+        "seconds": round(time.time() - t0, 1),
+    }
+
+
 def listing(name, least=4e6):
     """The node's ENTRY instructions that move `least` bytes or more."""
     if name == "kimi":
@@ -868,6 +1033,13 @@ def test_qwen3next_nodes_compiled_for_the_described_chip(compiled, invariant):
     assert compiled["qwen3next"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", JOYAI_INVARIANTS)
+def test_joyai_node_and_second_loss_compiled_for_the_described_chip(
+    compiled, invariant
+):
+    assert compiled["joyai"][invariant] == "ok"
+
+
 if __name__ == "__main__":
     argv = sys.argv[1:]
     root = os.getcwd()
@@ -876,11 +1048,14 @@ if __name__ == "__main__":
         root = os.path.abspath(argv[at + 1])
         del argv[at:at + 2]
     sys.path.insert(0, root)
-    if argv:
+    if argv and argv[0] == "joyai_step":
+        print(json.dumps(joyai_step_bytes(int(argv[1]), root)))
+    elif argv:
         print(listing(argv[0]))
     else:
         print(json.dumps(
             dict({name: check(name) for name in SHAPES}, kimi=check_kimi(),
                  lfm2=check_lfm2(), experts=check_experts(),
-                 held_sums=check_held_sums(), qwen3next=check_qwen3next())
+                 held_sums=check_held_sums(), qwen3next=check_qwen3next(),
+                 joyai=check_joyai())
         ))

@@ -501,7 +501,7 @@ def test_flash_kernels_lower_under_their_names(case, monkeypatch):
 def test_every_pallas_call_is_named():
     import inspect
 
-    for module, sites in ((fa, 20), (ring_flash, 3)):
+    for module, sites in ((fa, 21), (ring_flash, 3)):
         source = inspect.getsource(module)
         calls = source.count("pl.pallas_call(")
         assert calls == sites
