@@ -65,8 +65,27 @@ decides the chunks' operands and the chunk-to-chunk pass alike:
   matrix with g (the set of positions whose log-decays it sums), so it is
   <= 0 by construction, float32-exact in three bf16 passes, and its transpose
   is the way back to g. `kda_prep_inverse` is `unit_lower_inverse`'s levels with two
-  chunks side by side along the lanes; the inverse's two products with
-  K exp(G) and V, and its backward, stay XLA's batched float32 products.
+  chunks side by side along the lanes. *The triangular system*
+  (`corrected_products`, one `jax.custom_vjp` for both forms of the decay):
+  `kda_corrected_fwd` takes the inverse X, beta, K exp(G) and V of sixteen
+  chunk-heads a program and writes T (K exp(G)) and T V, T = X Diag(beta);
+  `kda_corrected_bwd` is the WRITTEN backward of the whole system from X
+  and the two cotangents dW, dU. With Y_w = X^T dW, Y_u = X^T dU and
+  M = Y_w (K exp(G))^T + Y_u V^T, a [Q, Q] matrix: d(K exp(G)) =
+  Diag(beta) Y_w, dV = Diag(beta) Y_u, dbeta = diag(M) for T's column
+  scaling, and dn = -M T^T under the diagonal for the inverse's input
+  n = Diag(beta) A. That is `unit_lower_inverse`'s dn = -X^T dX X^T with
+  X^T dX X^T = (X^T dT) T^T substituted (equally -(Y_w W^T + Y_u U^T), W
+  and U the forward's results, which the backward never forms): THREE
+  products a chunk-head where differentiating `_corrected` runs six, and
+  the cotangents of T and X exist nowhere. Every product keeps every term
+  `HIGHEST` keeps: a float32 operand goes in three bf16 parts, one that IS
+  bf16 (V, dW, dU in a bf16 step) in one, its other parts being exactly
+  zero (`_split_product`). Diag(beta) A and its cotangents stay XLA's, and
+  so does the whole of `_corrected` with `unit_lower_inverse` where the
+  kernels do not take the call: an odd number of chunk-heads (the
+  inverse's kernel takes them two by two) and everything on the "xla"
+  route. `observability/trace.triangular_products()` says which, by node.
   *The pass* (`kda_fwd_chunk`, `kda_states_chunk`, `kda_bwd_chunk`): one
   program is one (batch row, head, chunk); the chunk axis is sequential and
   the head's [dv, dk] float32 state (held transposed, so that the
@@ -110,10 +129,11 @@ strictly lower A and K exp(G); `gdn_prep_bwd` is its WRITTEN backward
 (`head_chunk_scores`, one `jax.custom_vjp`), which recomputes them from q, k
 and g, sums the value heads' cotangents of q and k in the program and takes
 the exponents' back to g. M, Q K^T, K K^T and the decays never reach HBM. The
-kernels share helpers with the per-channel form's (`_bf16_parts`, `_mm`,
-`kernel_inverse`) and no body: there the exponent is one a key channel and
-needs the levels, here one number a pair of positions and no level. The
-inverse and `_corrected`'s products are as there. Either way what comes back
+kernels share helpers with the per-channel form's (`_bf16_parts`, `_mm`) and
+no body: there the exponent is one a key channel and needs the levels, here
+one number a pair of positions and no level. The triangular system is the
+per-channel form's own, kernels and fallback (`_kernel_corrected`: both forms
+hand it A, K exp(G), V and beta by chunk and value head). Either way what comes back
 is what `chunk_operands` returns, exp(G_Q) written over the dk lanes, so
 `chunk_scan` and its kernels take it as they are. Which form a node took is
 `operand_form`'s answer (the attrs' `decay` and `scan_route`'s route, nothing
@@ -148,8 +168,13 @@ from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 # normalised (the public layer's l2norm; `assumed` in the benchmark's file)
 L2_EPS = 1e-6
 _HIGHEST = lax.Precision.HIGHEST
-# the one value of the recurrence that the node's checkpoint keeps
+# the one value of the recurrence that the node's checkpoint keeps, and the
+# policy that says so: ONE object for every node, because JAX caches what it
+# splits a jitted kernel call into under a checkpoint by the policy's
+# identity, and lowers each kernel once a step program only where the nodes
+# share it (a policy a node lowered `kda_prep_inverse` once a node)
 _KEPT = "kda_triangular_inverse"
+_KEEP_INVERSE = jax.checkpoint_policies.save_only_these_names(_KEPT)
 
 
 def _level_blocks(q: int, m: int):
@@ -221,15 +246,11 @@ def unit_lower_inverse(n):
     return x
 
 
-def _kept_inverse(inverse):
-    """The forward rule of `inverse`: the node's checkpoint keeps the inverse
-    (`_KEPT`) where it recomputes everything else, 16 KB a chunk for ten
-    products."""
-    def fwd(n):
-        x = checkpoint_name(inverse(n), _KEPT)
-        return x, x
-
-    return fwd
+def _unit_lower_inverse_fwd(n):
+    """The node's checkpoint keeps the inverse (`_KEPT`) where it recomputes
+    everything else, 16 KB a chunk for ten products."""
+    x = checkpoint_name(unit_lower_inverse(n), _KEPT)
+    return x, x
 
 
 def _unit_lower_inverse_bwd(x, dx):
@@ -240,16 +261,15 @@ def _unit_lower_inverse_bwd(x, dx):
     return (jnp.where(np.tri(x.shape[-1], k=-1, dtype=bool), -dn, 0.0),)
 
 
-unit_lower_inverse.defvjp(
-    _kept_inverse(unit_lower_inverse), _unit_lower_inverse_bwd
-)
+unit_lower_inverse.defvjp(_unit_lower_inverse_fwd, _unit_lower_inverse_bwd)
 
 
-def _corrected(a, kd, v5, beta5, dtype, inverse=unit_lower_inverse):
+def _corrected(a, kd, v5, beta5, dtype):
     """T (K exp(G)) and T V in `dtype`, T = (I + Diag(beta) A)^-1 Diag(beta):
-    a [.., Q, Q] and kd [.., Q, dk] float32, v5 [.., Q, dv], beta5 [.., Q];
-    `inverse` is `unit_lower_inverse` or the kernel that computes the same."""
-    t = inverse(beta5[..., :, None] * a) * beta5[..., None, :]
+    a [.., Q, Q] and kd [.., Q, dk] float32, v5 [.., Q, dv], beta5 [.., Q].
+    XLA's form, which JAX differentiates but for the inverse
+    (`corrected_products` is the kernels')."""
+    t = unit_lower_inverse(beta5[..., :, None] * a) * beta5[..., None, :]
     w = jnp.matmul(t, kd, precision=_HIGHEST).astype(dtype)
     uv = jnp.matmul(t, v5.astype(jnp.float32), precision=_HIGHEST).astype(dtype)
     return w, uv
@@ -287,8 +307,7 @@ def chunk_operands(q, k, v, g, beta, chunk: int):
     return qd, w, uv, ke, p.astype(dtype), jnp.exp(g_end)
 
 
-def head_decay_operands(q, k, v, g, beta, chunk: int,
-                        inverse=unit_lower_inverse):
+def head_decay_operands(q, k, v, g, beta, chunk: int):
     """`chunk_operands` for ONE log-decay a value head (module docstring,
     "One decay a head"): q, k [b, hk, s, dk] (normalised), v [b, hv, s, dv],
     g and beta [b, hv, s] float32, hv a multiple of hk; value head h reads
@@ -330,7 +349,7 @@ def head_decay_operands(q, k, v, g, beta, chunk: int,
     qd = by_value_head(qf * from_start).astype(dtype)
     kd = by_value_head(kf * from_start)
     ke = by_value_head(kf * to_end).astype(dtype)
-    w, uv = _corrected(a, kd, by_chunk(v), by_chunk(beta), dtype, inverse)
+    w, uv = _corrected(a, kd, by_chunk(v), by_chunk(beta), dtype)
     gamma = jnp.broadcast_to(
         jnp.exp(g_end)[..., None], (b, hv, c, 1, dk)
     )
@@ -1083,13 +1102,249 @@ def _pallas_inverse(n, interpret):
     return x.reshape(n.shape)
 
 
+# The two products around the inverse, W = T (K exp(G)) and U = T V with
+# T = X Diag(beta), and the WRITTEN backward of the whole triangular system
+# as kernels: `n` chunk-heads a program, unrolled, every operand of one
+# chunk-head in VMEM. With Y_w = X^T dW, Y_u = X^T dU and
+# M = Y_w Kd^T + Y_u V^T, a [Q, Q] matrix, the cotangents are
+#
+#     dKd = Diag(beta) Y_w     dV = Diag(beta) Y_u
+#     dbeta = diag(M)          (T's column scaling alone)
+#     dn = -M T^T              strictly under the diagonal
+#
+# the last `unit_lower_inverse`'s dn = -X^T dX X^T with dX = dT Diag(beta),
+# dT = dW Kd^T + dU V^T, so that X^T dX X^T = (X^T dT) T^T = M T^T; it is
+# -(Y_w W^T + Y_u U^T) with W = T Kd and U = T V, which the backward never
+# forms. Three products a chunk-head (Y, M, dn) where differentiating
+# `_corrected` runs six, and dT, dX and X^T dX exist nowhere. Every product
+# is float32 with every term `HIGHEST` keeps (`_split_product`).
+
+
+def _parts(x):
+    """The bf16 arrays that add up to x exactly: x itself where it is bf16,
+    three of anything wider (`_bf16_parts`)."""
+    if x.dtype == jnp.bfloat16:
+        return (x,)
+    return _bf16_parts(x.astype(jnp.float32))
+
+
+def _split_product(lhs, rhs, dims):
+    """The float32 product of two operands given as their bf16 parts
+    (`_parts`), `dims` one of `_NN`, `_NT`, `_TN`: with l_1 + l_2 + l_3 and
+    r_1 + r_2 + r_3 the parts, the six terms l_i r_j, i + j <= 4, as
+    `_pair_product` takes them; an operand that IS bf16 has one part and the
+    terms of its others, exactly zero, are left out. The terms are ONE
+    product, stacked along the contraction from the smallest up: a [Q, Q]
+    operand's six fill three whole passes of the matrix unit's 128-deep
+    contraction where one a term fills half of six, and the product's own
+    float32 accumulation adds them."""
+    terms = sorted(
+        ((i, j) for i in range(len(lhs)) for j in range(len(rhs))
+         if i + j < _PARTS),
+        key=lambda term: -sum(term),
+    )
+    lhs_axis, rhs_axis = {_NN: (1, 0), _NT: (1, 1), _TN: (0, 0)}[dims]
+    return _mm(
+        jnp.concatenate([lhs[i] for i, _ in terms], axis=lhs_axis),
+        jnp.concatenate([rhs[j] for _, j in terms], axis=rhs_axis), dims,
+    )
+
+
+def _kda_corrected_fwd_kernel(x_ref, beta_ref, kd_ref, v_ref, w_ref, uv_ref,
+                              *, heads: int):
+    """W = T Kd and U = T V, T = X Diag(beta), of each of the program's
+    chunk-heads: x [n Q, Q] and kd [n Q, dk] float32, v [n Q, dv], beta
+    [n, Q] (a chunk-head's steps along the lanes, as T's columns lie)."""
+    q = x_ref.shape[1]
+
+    def one(c, _):
+        rows = pl.ds(pl.multiple_of(c * q, q), q)
+        t = _parts(x_ref[rows, :] * beta_ref[pl.ds(c, 1), :])
+        w_ref[rows, :] = _split_product(
+            t, _parts(kd_ref[rows, :]), _NN
+        ).astype(w_ref.dtype)
+        uv_ref[rows, :] = _split_product(
+            t, _parts(v_ref[rows, :]), _NN
+        ).astype(uv_ref.dtype)
+
+    lax.fori_loop(0, heads, one, None, unroll=True)
+
+
+def _kda_corrected_bwd_kernel(
+    x_ref, beta_ref, kd_ref, v_ref, dw_ref, duv_ref,
+    dn_ref, dbeta_ref, dkd_ref, dv_ref, yw_ref, yu_ref, m_ref, *, heads: int,
+):
+    """The cotangents of n (of X = (I + n)^-1), beta (through T's columns),
+    Kd and V from those of W and U, of each of the program's chunk-heads
+    (the section's comment): one sweep over the chunk-heads a product, Y and M
+    between the sweeps in VMEM (`yw_ref`, `yu_ref` [n Q, .] and `m_ref`
+    [n Q, Q] float32), so that a sweep is `heads` short independent chains
+    and not one long one a chunk-head."""
+    q = x_ref.shape[1]
+    r = lax.broadcasted_iota(jnp.int32, (q, q), 0)
+    i = lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    diagonal, below = r == i, i < r
+
+    def rows_of(c):
+        return pl.ds(pl.multiple_of(c * q, q), q)
+
+    def from_the_left(c, _):  # Y = X^T dW | X^T dU
+        rows = rows_of(c)
+        beta = beta_ref[pl.ds(c, 1), :]  # [1, Q]: T's columns
+        by_row = jnp.sum(jnp.where(diagonal, beta, 0.0), axis=1, keepdims=True)
+        xs = _parts(x_ref[rows, :])
+        y_w = _split_product(xs, _parts(dw_ref[rows, :]), _TN)
+        y_u = _split_product(xs, _parts(duv_ref[rows, :]), _TN)
+        yw_ref[rows, :], yu_ref[rows, :] = y_w, y_u
+        dkd_ref[rows, :] = by_row * y_w
+        dv_ref[rows, :] = (by_row * y_u).astype(dv_ref.dtype)
+
+    def against_the_operands(c, _):  # M = Y_w Kd^T + Y_u V^T
+        rows = rows_of(c)
+        m = _split_product(
+            _parts(yw_ref[rows, :]), _parts(kd_ref[rows, :]), _NT
+        ) + _split_product(_parts(yu_ref[rows, :]), _parts(v_ref[rows, :]), _NT)
+        m_ref[rows, :] = m
+        dbeta_ref[pl.ds(c, 1), :] = jnp.sum(
+            jnp.where(diagonal, m, 0.0), axis=0, keepdims=True
+        )
+
+    def from_the_right(c, _):  # dn = -M T^T
+        rows = rows_of(c)
+        t = x_ref[rows, :] * beta_ref[pl.ds(c, 1), :]
+        dn = _split_product(_parts(m_ref[rows, :]), _parts(t), _NT)
+        dn_ref[rows, :] = jnp.where(below, -dn, 0.0)
+
+    for sweep in (from_the_left, against_the_operands, from_the_right):
+        lax.fori_loop(0, heads, sweep, None, unroll=True)
+
+
+# chunk-heads a program of the two kernels above (the largest that divides
+# their number, which is even: the inverse's kernel takes them two by two)
+_CORRECTED_HEADS = (16, 8, 4, 2)
+
+
+class _CorrectedBlocks:
+    """The BlockSpecs over the one grid axis, `n` chunk-heads a program: what
+    a chunk-head has, [count * Q, width], its rows; beta and its cotangent
+    [count / n, n, Q] a program's rows."""
+
+    def __init__(self, count: int, q: int, dk: int, dv: int, itemsize: int):
+        n = self.heads = next(n for n in _CORRECTED_HEADS if count % n == 0)
+        self.grid = (count // n,)
+        self.block_rows = n * q
+        self.steps = pl.BlockSpec((None, n, q), lambda i: (i, 0, 0))
+        # the backward's blocks (x and dn fill 128 lanes; kd, dkd float32; v,
+        # dw, duv, dv in the model's dtype), twice for the pipeline's two
+        # buffers, and as much again for Y, M and what the body holds
+        block = n * q * (2 * 4 * _LANES + 2 * 4 * dk + itemsize * (dk + 3 * dv))
+        self.between = [
+            pltpu.VMEM((n * q, width), jnp.float32) for width in (dk, dv, q)
+        ]
+        self.params = pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=max(4 * block, 16 * 1024 * 1024),
+        )
+
+    def rows(self, width: int):
+        return pl.BlockSpec((self.block_rows, width), lambda i: (i, 0))
+
+
+def _flat(t):
+    """[.., Q, w] -> [chunk-heads * Q, w]."""
+    return t.reshape(-1, t.shape[-1])
+
+
+@functools.partial(jax.jit, static_argnums=(4,))
+def _corrected_forward(x, beta, kd, v, interpret):
+    """(T Kd in v's dtype [.., Q, dk], T V [.., Q, dv]) of x [.., Q, Q] and kd
+    [.., Q, dk] float32, v [.., Q, dv] and beta [.., Q], as a kernel."""
+    q, dk, dv = x.shape[-1], kd.shape[-1], v.shape[-1]
+    count = beta.size // q
+    at = _CorrectedBlocks(count, q, dk, dv, v.dtype.itemsize)
+    w, uv = pl.pallas_call(
+        functools.partial(_kda_corrected_fwd_kernel, heads=at.heads),
+        grid=at.grid,
+        in_specs=[at.rows(q), at.steps, at.rows(dk), at.rows(dv)],
+        out_specs=[at.rows(dk), at.rows(dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct((count * q, dk), v.dtype),
+            jax.ShapeDtypeStruct((count * q, dv), v.dtype),
+        ],
+        compiler_params=at.params,
+        interpret=interpret,
+        name="kda_corrected_fwd",
+    )(_flat(x), beta.reshape(-1, at.heads, q), _flat(kd), _flat(v))
+    return w.reshape(kd.shape), uv.reshape(v.shape)
+
+
+@functools.partial(jax.jit, static_argnums=(6,))
+def _corrected_backward(x, beta, kd, v, dw, duv, interpret):
+    """The cotangents of (n, beta, kd, v), n the strictly lower matrix x is
+    the inverse of I + n of, from those of `_corrected_forward`'s results."""
+    f32 = jnp.float32
+    q, dk, dv = x.shape[-1], kd.shape[-1], v.shape[-1]
+    count = beta.size // q
+    at = _CorrectedBlocks(count, q, dk, dv, v.dtype.itemsize)
+    dn, dbeta, dkd, dvalue = pl.pallas_call(
+        functools.partial(_kda_corrected_bwd_kernel, heads=at.heads),
+        grid=at.grid,
+        in_specs=[at.rows(q), at.steps, at.rows(dk), at.rows(dv),
+                  at.rows(dk), at.rows(dv)],
+        out_specs=[at.rows(q), at.steps, at.rows(dk), at.rows(dv)],
+        out_shape=[
+            jax.ShapeDtypeStruct((count * q, q), f32),
+            jax.ShapeDtypeStruct((count // at.heads, at.heads, q), f32),
+            jax.ShapeDtypeStruct((count * q, dk), f32),
+            jax.ShapeDtypeStruct((count * q, dv), v.dtype),
+        ],
+        scratch_shapes=at.between,
+        compiler_params=at.params,
+        interpret=interpret,
+        name="kda_corrected_bwd",
+    )(_flat(x), beta.reshape(-1, at.heads, q), _flat(kd), _flat(v),
+      _flat(dw), _flat(duv))
+    return (
+        dn.reshape(x.shape), dbeta.reshape(beta.shape).astype(beta.dtype),
+        dkd.reshape(kd.shape), dvalue.reshape(v.shape),
+    )
+
+
 @jax.custom_vjp
-def kernel_inverse(n):
-    """`unit_lower_inverse` as a kernel, with its written backward."""
-    return _pallas_inverse(n, _interpret())
+def corrected_products(n, beta, kd, v):
+    """(T Kd, T V) in v's dtype, T = (I + n)^-1 Diag(beta), as kernels with
+    a WRITTEN backward (the section's comment): n [.., Q, Q] strictly lower
+    (Diag(beta) A) and kd [.., Q, dk] float32, v [.., Q, dv], beta [.., Q]
+    float32, over an even number of chunk-heads. What the backward keeps is
+    the inverse (`_KEPT`: under the node's checkpoint the inverse's kernel
+    runs once, this forward twice) beside beta, kd and v."""
+    return _corrected_products_fwd(n, beta, kd, v)[0]
 
 
-kernel_inverse.defvjp(_kept_inverse(kernel_inverse), _unit_lower_inverse_bwd)
+def _corrected_products_fwd(n, beta, kd, v):
+    x = checkpoint_name(_pallas_inverse(n, _interpret()), _KEPT)
+    return _corrected_forward(x, beta, kd, v, _interpret()), (x, beta, kd, v)
+
+
+def _corrected_products_bwd(kept, cotangents):
+    return _corrected_backward(*kept, *cotangents, _interpret())
+
+
+corrected_products.defvjp(_corrected_products_fwd, _corrected_products_bwd)
+
+
+def _kernel_corrected(a, kd, v5, beta5):
+    """`_corrected` on the "kda" route, `dtype` v5's: the kernels where they
+    take the number of chunk-heads (an even one), XLA's form with
+    `unit_lower_inverse` otherwise; which, told to the program's counter
+    (`observability/trace.triangular_products`)."""
+    from flexflow_tpu.observability import trace
+
+    if (beta5.size // beta5.shape[-1]) % 2:
+        trace.note_triangular_products("xla")
+        return _corrected(a, kd, v5, beta5, v5.dtype)
+    trace.note_triangular_products("kernels")
+    return corrected_products(beta5[..., :, None] * a, beta5, kd, v5)
 
 
 # what a padded position's decay pre-activation reads: its softplus, and so
@@ -1104,7 +1359,7 @@ def kernel_operands(qkv, f_up, dt_bias, a_log, v, beta, chunk: int):
     beta [b, h, s'] as `chunk_operands` takes them, s' = s padded to the
     chunk: the gates, the decays and the scores from one kernel, the
     triangular inverse from another, its two products with K exp(G) and V
-    XLA's batched float32 ones."""
+    from a third (`_kernel_corrected`)."""
     b, h, s, dv = v.shape
     c = s // chunk
     pad = s - f_up.shape[1]
@@ -1115,11 +1370,8 @@ def kernel_operands(qkv, f_up, dt_bias, a_log, v, beta, chunk: int):
             f_up, ((0, 0), (0, pad), (0, 0)), constant_values=_NO_DECAY
         )
     qd, ke, p, gamma, a, kd = chunk_scores(qkv, f_up, dt_bias, a_log, chunk)
-    w, uv = _corrected(
-        a, kd, v.reshape(b, h, c, chunk, dv), beta.reshape(b, h, c, chunk),
-        qkv.dtype,
-        # the inverse's kernel takes the chunks two by two
-        kernel_inverse if (b * h * c) % 2 == 0 else unit_lower_inverse,
+    w, uv = _kernel_corrected(
+        a, kd, v.reshape(b, h, c, chunk, dv), beta.reshape(b, h, c, chunk)
     )
     return qd, w, uv, ke, p, gamma
 
@@ -1399,16 +1651,13 @@ head_chunk_scores.defvjp(_head_chunk_scores_fwd, _head_chunk_scores_bwd)
 def head_kernel_operands(q, k, v, g, beta, chunk: int):
     """`head_decay_operands` on the "kda" route, on the same inputs: the
     decays and the scores from one kernel, the triangular inverse from
-    another, its two products with K exp(G) and V XLA's batched float32
-    ones."""
+    another, its two products with K exp(G) and V from a third
+    (`_kernel_corrected`)."""
     b, hv, s, dv = v.shape
     c = s // chunk
     qd, ke, p, gamma, a, kd = head_chunk_scores(q, k, g, chunk)
-    w, uv = _corrected(
-        a, kd, v.reshape(b, hv, c, chunk, dv), beta.reshape(b, hv, c, chunk),
-        q.dtype,
-        # the inverse's kernel takes the chunks two by two
-        kernel_inverse if (b * hv * c) % 2 == 0 else unit_lower_inverse,
+    w, uv = _kernel_corrected(
+        a, kd, v.reshape(b, hv, c, chunk, dv), beta.reshape(b, hv, c, chunk)
     )
     return qd, w, uv, ke, p, gamma
 
@@ -1439,7 +1688,9 @@ def operand_form(attrs: GatedDeltaAttrs, route: str) -> str:
     """Which form the chunks' operands of a node take on `route`
     (`scan_route`'s answer), told to the program's counter as well
     (`observability/trace.delta_rule_operands`): the attrs' own `decay` names
-    the set of kernels, and nothing else chooses."""
+    the set of kernels, and nothing else chooses. The "xla" route also
+    settles the products around the triangular inverse
+    (`observability/trace.triangular_products`)."""
     from flexflow_tpu.observability import trace
 
     if attrs.per_head_decay:
@@ -1447,6 +1698,10 @@ def operand_form(attrs: GatedDeltaAttrs, route: str) -> str:
     else:
         form = "channel_kernels" if route == "kda" else "xla"
     trace.note_delta_rule_operands(form)
+    if route != "kda":
+        # on the "kda" route the number of chunk-heads chooses
+        # (`_kernel_corrected`)
+        trace.note_triangular_products("xla")
     return form
 
 
@@ -1582,7 +1837,7 @@ def _gated_delta_head_decay(attrs: GatedDeltaAttrs, u, weights):
     form = operand_form(attrs, route)
     o = jax.checkpoint(
         functools.partial(_recurrence_head_decay, attrs, route, form),
-        policy=jax.checkpoint_policies.save_only_these_names(_KEPT),
+        policy=_KEEP_INVERSE,
     )(qkv, ba[..., hv:], dt_bias, a_log, ba[..., :hv])
     with jax.named_scope("norm"):
         y = jax.checkpoint(
@@ -1618,7 +1873,7 @@ def gated_delta_forward(
     operand_form(attrs, route)
     o = jax.checkpoint(
         functools.partial(_recurrence, attrs, route),
-        policy=jax.checkpoint_policies.save_only_these_names(_KEPT),
+        policy=_KEEP_INVERSE,
     )(qkv, f_up, dt_bias, a_log, proj[..., cw + 2 * rank:])
     with jax.named_scope("norm"):
         y = jax.checkpoint(

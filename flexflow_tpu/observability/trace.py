@@ -641,6 +641,7 @@ _lowering = threading.local()
 _ATTENTION_ROUTES: Dict[str, str] = {}
 _LATENT_ATTENTION_FORMS: Dict[str, dict] = {}
 _DELTA_RULE_OPERANDS: Dict[str, str] = {}
+_TRIANGULAR_PRODUCTS: Dict[str, str] = {}
 _GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
 _HELD_ROW_SUMS: Dict[str, Dict[str, dict]] = {}
 
@@ -713,6 +714,27 @@ def delta_rule_operands() -> Dict[str, str]:
     `kda_prep_fwd` / `kda_prep_bwd`) or `xla` (`chunk_operands`), so that a
     run that fell back to XLA's operands says so itself."""
     return dict(_DELTA_RULE_OPERANDS)
+
+
+def note_triangular_products(form: str) -> None:
+    """The form the products around the triangular inverse took in the
+    delta-rule node being lowered (`kernels/kda.py`: noted where the route
+    or the number of chunk-heads chooses); dropped where no node's scope is
+    open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _TRIANGULAR_PRODUCTS[scope] = form
+
+
+def triangular_products() -> Dict[str, str]:
+    """`{ff.kda.<name>: form}` of every gated delta-rule node this process
+    has lowered, as it was lowered last, beside `delta_rule_operands()`:
+    `kernels` (T (K exp(G)), T V and the triangular system's whole backward
+    from the Pallas kernels `kda_corrected_fwd` / `kda_corrected_bwd`) or
+    `xla` (`kernels/kda._corrected` with `unit_lower_inverse`, differentiated
+    by JAX: the "xla" route, and an odd number of chunk-heads on the "kda"
+    route), so that a run that fell back says so itself."""
+    return dict(_TRIANGULAR_PRODUCTS)
 
 
 def note_grouped_matmul_tiles(entries: Dict[str, dict]) -> None:

@@ -387,6 +387,117 @@ def test_the_whole_node_on_the_kernels_agrees_with_the_xla_route(monkeypatch):
     assert_trees_close(got, want, **F32_GRADS)
 
 
+# The products around the triangular inverse (`kda._kernel_corrected`, PR 54):
+# `lead` chunk-heads [b, h, c] of both cells' shape, chunks of 64 at heads of
+# 128 | 128. Eight are ONE program of `kda_corrected_fwd` / `kda_corrected_bwd`,
+# four one of four (`_CORRECTED_HEADS`); an odd number the kernels do not take.
+
+
+def triangular_case(lead, dtype, seed=17):
+    """((a, kd, v, beta), (dw, duv)): a strictly lower float32 [.., 64, 64]
+    at the size of unit keys' scores, kd float32 and v, dw, duv in `dtype`
+    [.., 64, 128], beta in (0, 1)."""
+    rs = np.random.RandomState(seed)
+    q, d = 64, 128
+    a = jnp.asarray(np.tril(rs.randn(*lead, q, q), -1) * 0.2, jnp.float32)
+    kd = rand(rs, *lead, q, d)
+    v, dw, duv = (rand(rs, *lead, q, d).astype(dtype) for _ in range(3))
+    beta = jnp.asarray(rs.rand(*lead, q), jnp.float32)
+    return (a, kd, v, beta), (dw, duv)
+
+
+def triangular_products(products, operands, cots):
+    """((w, uv), the cotangents of a, kd, v, beta) of `products`."""
+    f32 = jnp.float32
+
+    def loss(*operands):
+        out = products(*operands)
+        return sum(
+            jnp.sum(o.astype(f32) * cot.astype(f32)) for o, cot in zip(out, cots)
+        ), out
+
+    with jax.default_matmul_precision("highest"):
+        (_, out), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3), has_aux=True
+        )(*operands)
+    return out, grads
+
+
+def xla_corrected(a, kd, v, beta):
+    return kda._corrected(a, kd, v, beta, v.dtype)
+
+
+def assert_triangular_products_agree(got, want, dtype):
+    """w, uv to float32 rounding, or in bf16 to the last bit but for a value
+    in a thousand one ulp off (a float32 sum in another order rounds the
+    other way where it lies at a bf16 tie; where the sum's terms cancel, an
+    ulp of the float32 terms, 1e-5 at values of 10); every cotangent to 1e-5
+    of its largest value (measured 4e-7: the inverse's kernel and the
+    products add in another order than XLA's float32 ones)."""
+    for g, w in zip(got[0], want[0]):
+        assert g.dtype == w.dtype == dtype
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        if dtype == jnp.bfloat16:
+            assert np.all(np.abs(g - w) <= 2.0 ** -7 * np.abs(w) + 1e-5)
+            assert np.mean(g != w) < 1e-3
+        else:
+            np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-5)
+    for g, w in zip(got[1], want[1]):
+        assert g.dtype == w.dtype and bool(jnp.all(jnp.isfinite(g)))
+        tol = 2.0 ** -7 if w.dtype == jnp.bfloat16 else 1e-5  # v's: an ulp
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        np.testing.assert_allclose(g, w, rtol=tol, atol=tol * np.max(np.abs(w)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "lead", [(1, 2, 4), (1, 2, 2), (1, 1, 3)],
+    ids=["eight_a_program", "four_a_program", "odd_count_falls_back"],
+)
+def test_triangular_product_kernels_agree_with_xlas_products(
+    monkeypatch, lead, dtype
+):
+    """`kda._kernel_corrected` (interpret mode: the inverse's kernel, then
+    `kda_corrected_fwd` and the WRITTEN backward `kda_corrected_bwd`, three
+    products a chunk-head) against `_corrected` with `unit_lower_inverse` and
+    JAX's own gradient of it (six): T (K exp(G)), T V and the cotangents of
+    A, K exp(G), V and beta. A float32 step gives every operand three bf16
+    parts, a bf16 step leaves out the terms of v's, dw's and duv's second and
+    third, which are exactly zero. An odd number of chunk-heads keeps XLA's
+    form, bit for bit."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    operands, cots = triangular_case(lead, dtype)
+    got = triangular_products(kda._kernel_corrected, operands, cots)
+    want = triangular_products(xla_corrected, operands, cots)
+    if np.prod(lead) % 2:
+        assert_trees_close(got, want, rtol=0, atol=0)
+    else:
+        assert float(jnp.max(jnp.abs(got[1][0]))) > 1e-2  # dn is reached
+        assert not np.any(np.triu(np.asarray(got[1][0])))
+        assert_triangular_products_agree(got, want, dtype)
+
+
+def test_a_step_of_zero_and_a_padded_position_give_zero_rows(monkeypatch):
+    """A beta of exactly 0 and a padded position (k = 0, so A's row and column
+    and K exp(G)'s row are 0, beta 0 as `_recurrence` pads it) write nothing:
+    their rows of T (K exp(G)) and T V are zero, every gradient is finite and
+    agrees with XLA's form."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    (a, kd, v, beta), cots = triangular_case((1, 1, 2), jnp.float32, seed=18)
+    still, padded = 5, slice(40, 64)
+    beta = beta.at[..., still].set(0.0).at[..., padded].set(0.0)
+    a = a.at[..., padded, :].set(0.0).at[..., :, padded].set(0.0)
+    kd = kd.at[..., padded, :].set(0.0)
+    operands = (a, kd, v, beta)
+    got = triangular_products(kda._kernel_corrected, operands, cots)
+    for t in got[0]:
+        assert not np.any(np.asarray(t)[..., still, :])
+        assert not np.any(np.asarray(t)[..., padded, :])
+        assert float(jnp.min(jnp.max(jnp.abs(t[..., :still, :]), axis=-1))) > 0
+    want = triangular_products(xla_corrected, operands, cots)
+    assert_triangular_products_agree(got, want, jnp.float32)
+
+
 # -- latent attention ------------------------------------------------------------
 
 

@@ -14,6 +14,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from test_kimi_linear import (
+    assert_triangular_products_agree, triangular_case, triangular_products,
+    xla_corrected,
+)
 from test_nemotron_h import (
     BENCH, F32, F32_LOSS, assert_trees_close, bench, rand,
 )
@@ -258,6 +262,29 @@ def test_scalar_decay_kernels_agree_with_the_xla_operands(
     assert_trees_close(got, want, **tol)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize(
+    "lead", [(1, 4, 8), (1, 3, 2), (1, 3, 1)],
+    ids=["two_programs_of_sixteen", "three_programs_of_two", "odd_count_falls_back"],
+)
+def test_triangular_product_kernels_take_the_value_heads_chunks(
+    monkeypatch, lead, dtype
+):
+    """`kda._kernel_corrected` as `head_kernel_operands` calls it, by chunk
+    and VALUE head (`tests/test_kimi_linear.py` has the per-channel form's
+    counts and what is compared): thirty-two chunk-heads are two programs
+    of `kda_corrected_fwd` / `kda_corrected_bwd`, six are three of two, and
+    three keep `_corrected` with `unit_lower_inverse`, bit for bit."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    operands, cots = triangular_case(lead, dtype, seed=29)
+    got = triangular_products(kda._kernel_corrected, operands, cots)
+    want = triangular_products(xla_corrected, operands, cots)
+    if np.prod(lead) % 2:
+        assert_trees_close(got, want, rtol=0, atol=0)
+    else:
+        assert_triangular_products_agree(got, want, dtype)
+
+
 def kernel_sized_node(seq):
     """(attrs, u, weights, a cotangent): four value heads over two key heads
     of 128 | 128 in chunks of 64, hidden size 32."""
@@ -340,6 +367,58 @@ def test_the_operands_form_is_counted_by_node(monkeypatch):
     monkeypatch.setattr(trace._lowering, "scope", None)
     kda.operand_form(attrs, "kda")
     assert len(trace.delta_rule_operands()) == 4
+
+
+def test_the_triangular_products_form_is_counted_by_node(monkeypatch):
+    """`observability/trace.triangular_products()` names the form the
+    products around the triangular inverse took in each delta-rule node:
+    `kernels` on the "kda" route for both forms of the decay, `xla` under
+    `no_flash()`, on the plain CPU and where the route's number of
+    chunk-heads is odd."""
+    attrs, u, ws, _ = kernel_sized_node(64)
+    channel = GatedDeltaAttrs(2, 128, 128, 4, 8, 64, 1e-5)
+    rs = np.random.RandomState(31)
+    channel_ws = [
+        rand(rs, *s.dims, scale=0.3) for s in channel.weight_shapes(
+            TensorShape(u.shape, DataType.FLOAT)
+        )
+    ]
+    monkeypatch.setattr(trace, "_TRIANGULAR_PRODUCTS", {})
+
+    def lowered_as(scope, node=attrs, ws=ws, u=u):
+        monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+        jax.eval_shape(lambda u, ws: kda.gated_delta_forward(node, u, ws), u, ws)
+        return trace.triangular_products()[scope]
+
+    assert lowered_as("ff.kda.on_the_cpu") == "xla"
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    assert lowered_as("ff.kda.gdn0") == "kernels"
+    assert lowered_as("ff.kda.kda0", channel, channel_ws) == "kernels"
+    with flash.no_flash():
+        assert lowered_as("ff.kda.gdn1") == "xla"
+        assert lowered_as("ff.kda.kda1", channel, channel_ws) == "xla"
+    # one value head over three chunks: a count the kernels do not take
+    odd = GatedDeltaAttrs(
+        1, 128, 128, 4, chunk_size=64, norm_eps=1e-6, num_key_heads=1,
+        decay="head",
+    )
+    odd_u = rand(rs, 1, 192, 32)
+    odd_ws = [
+        rand(rs, *s.dims, scale=0.3) for s in odd.weight_shapes(
+            TensorShape(odd_u.shape, DataType.FLOAT)
+        )
+    ]
+    assert lowered_as("ff.kda.gdn2", odd, odd_ws, odd_u) == "xla"
+    assert trace.delta_rule_operands()["ff.kda.gdn2"] == "head_kernels"
+    assert trace.triangular_products() == {
+        "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "kernels",
+        "ff.kda.kda0": "kernels", "ff.kda.gdn1": "xla", "ff.kda.kda1": "xla",
+        "ff.kda.gdn2": "xla",
+    }
+    # a kernel called by itself, under no node's scope, is not counted
+    monkeypatch.setattr(trace._lowering, "scope", None)
+    kda._kernel_corrected(*triangular_case((1, 1, 2), jnp.float32)[0])
+    assert len(trace.triangular_products()) == 6
 
 
 # -- the gated grouped-query attention node --------------------------------------

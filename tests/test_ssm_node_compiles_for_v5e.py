@@ -21,8 +21,12 @@ published shape, 32 heads of 128 over 4,096 positions: ONE gated delta-rule
 node (`kernels/kda.gated_delta_forward`) forward and backward, whose
 chunk-to-chunk pass must come out as its three Pallas kernels and whose
 chunks' operands as theirs (PR 44: the scores forward, rematerialised and
-backward, the triangular inverse forward only, each under the node's `prep`
-part by the name the profile will carry), and which
+backward, the triangular inverse forward only; PR 54: the inverse's products
+with K exp(G) and V, `kda_corrected_fwd`, forward and rematerialised, and the
+triangular system's written backward `kda_corrected_bwd` once, both under the
+VMEM limit they state; each under the node's `prep` part by the name the
+profile will carry; the cell's whole step, compiled for the described chip,
+holds 11,843,911,680 bytes with them, 11,830,603,264 at PR 53), and which
 must hold no `[heads, positions, 128, 128]` state per position; and the causal
 flash kernels on a 256-wide padded key beside a 128-wide value
 (`flash_attention_bshf_wide_key`), forward and backward.
@@ -63,10 +67,13 @@ hold the scalar-decay operands' kernel `gdn_prep_fwd` twice (forward,
 rematerialised), its written backward `gdn_prep_bwd` once and
 `kda_prep_inverse` once, by the names the profile will carry, with no
 float32 [.., 64, 64] buffer of a mask or of Q K^T / K K^T left between
-ENTRY instructions (A, the inverse and what `_corrected` multiplies are),
-both kernels under the VMEM limit they state (the cell's whole step, compiled
-for the described chip, holds 13,635,138,048 bytes with them; 13,705,657,344
-at PR 51); and ONE output-gated grouped-query
+ENTRY instructions (A, the inverse and the triangular system's cotangent dn
+are), and since PR 54 `kda_corrected_fwd` twice and `kda_corrected_bwd` once
+where XLA's `dot_general`s a value head were (none is left under `prep`),
+all four kernels under the VMEM limit they state (the cell's whole step,
+compiled for the described chip, holds 13,646,528,000 bytes with them;
+13,635,138,048 at PR 52, 13,705,657,344 at PR 51); and ONE output-gated
+grouped-query
 node of 16 query heads over 2 key/value heads of 256, forward and backward,
 whose core must be the three `*_grouped` kernels (forward, delta, backward):
 the entries that keep k and v as whole rows under the default scope do not
@@ -236,10 +243,11 @@ def check(name):
 
 
 KIMI_INVARIANTS = [
-    "kda_node_compiles_with_its_seven_kernels",
+    "kda_node_compiles_with_its_kernels",
     "kda_operand_kernels_are_the_nodes_prep_part",
     "kda_holds_no_state_per_position",
     "kda_scores_read_the_models_layout",
+    "kda_triangular_product_kernels_compile_under_the_vmem_limit_they_state",
     "wide_key_flash_compiles_forward_and_backward",
 ]
 
@@ -297,6 +305,19 @@ def compiled_kda_node():
     return jax.jit(node).lower(u, weights, u).compile().as_text()
 
 
+def _corrected_kernels_limit(chunk_heads):
+    """"ok" where the limit `kda_corrected_fwd` / `kda_corrected_bwd` carry
+    over `chunk_heads` chunks of 64 at heads of 128 | 128 in a bf16 step is
+    within the chip's default scope (the node's compile is Mosaic's of both
+    under that limit), or the bytes they state."""
+    from flexflow_tpu.kernels import kda
+
+    limit = kda._CorrectedBlocks(
+        chunk_heads, 64, 128, 128, 2
+    ).params.vmem_limit_bytes
+    return "ok" if limit <= V5E_SCOPED_VMEM else f"{limit} bytes stated"
+
+
 def check_kimi():
     """{invariant: "ok" or what was found} for the `kimi_linear` cell's two
     new kernel paths at the published shape."""
@@ -312,7 +333,10 @@ def check_kimi():
         # a kernel's name as the profile has it: the chunk-to-chunk pass
         # forward, the states and the backward; the scores' kernel forward
         # and rematerialised, and its backward; the triangular inverse's
-        # kernel forward only, because the node's checkpoint keeps the inverse
+        # kernel forward only, because the node's checkpoint keeps the inverse;
+        # since PR 54 the products around it (`kda_corrected_fwd`) forward
+        # and rematerialised from the kept inverse, and the triangular
+        # system's written backward (`kda_corrected_bwd`) once
         # (the pass's rematerialised forward is dead code: its backward reads
         # the operands alone)
         calls = sorted(
@@ -327,11 +351,14 @@ def check_kimi():
              ("bwd", "kda", "kda2/scan", "kda_bwd_chunk"),
              ("fwd", "kda", "kda2/prep", "kda_prep_fwd"),
              ("fwd", "kda", "kda2/prep", "kda_prep_inverse"),
+             ("fwd", "kda", "kda2/prep", "kda_corrected_fwd"),
              ("bwd", "kda", "kda2/prep", "kda_prep_fwd"),
-             ("bwd", "kda", "kda2/prep", "kda_prep_bwd")]
+             ("bwd", "kda", "kda2/prep", "kda_corrected_fwd"),
+             ("bwd", "kda", "kda2/prep", "kda_prep_bwd"),
+             ("bwd", "kda", "kda2/prep", "kda_corrected_bwd")]
         )
         kernels = sorted(c[3] for c in calls)
-        found["kda_node_compiles_with_its_seven_kernels"] = (
+        found["kda_node_compiles_with_its_kernels"] = (
             "ok" if kernels == sorted(w[3] for w in want) else f"{kernels}"
         )
         # `kda_ms` and `kda_scan_roofline` read the kernels by these names: one
@@ -369,9 +396,10 @@ def check_kimi():
             "ok" if not by_head and not elsewhere
             else ", ".join(by_head + elsewhere)
         )
+        found[KIMI_INVARIANTS[4]] = _corrected_kernels_limit(heads * ROWS // 64)
     except Exception as e:  # noqa: BLE001 - the complaint is the result
         complaint = f"{type(e).__name__}: {e}"[:2000]
-        for invariant in KIMI_INVARIANTS[:4]:
+        for invariant in KIMI_INVARIANTS[:5]:
             found.setdefault(invariant, complaint)
     try:
         on_chip = _described_chip()
@@ -641,6 +669,7 @@ QWEN3NEXT_INVARIANTS = [
     "head_decay_operand_kernels_are_the_nodes_prep_part",
     "head_decay_prep_leaves_no_float32_mask_or_score_tile",
     "head_decay_operand_kernels_compile_under_the_vmem_limit_they_state",
+    "triangular_product_kernels_compile_under_the_vmem_limit_they_state",
 ]
 # the chip's default for a kernel's scoped VMEM
 V5E_SCOPED_VMEM = 16 * 1024 * 1024
@@ -700,9 +729,10 @@ def check_qwen3next():
         attrs, text = compiled_gdn_node()
         names = sorted(set(re.findall(r"/((?:kda|gdn)_\w+)/pallas_call", text)))
         want = ["gdn_prep_bwd", "gdn_prep_fwd", "kda_bwd_chunk",
-                "kda_fwd_chunk", "kda_prep_inverse", "kda_states_chunk"]
+                "kda_corrected_bwd", "kda_corrected_fwd", "kda_fwd_chunk",
+                "kda_prep_inverse", "kda_states_chunk"]
         found[QWEN3NEXT_INVARIANTS[0]] = (
-            "ok" if names == want and text.count("tpu_custom_call") >= 7
+            "ok" if names == want and text.count("tpu_custom_call") >= 10
             else f"kernels {names}, want {want}"
         )
         # as `check_kimi` reads them: `gdn_ms` and `gdn_scan_roofline` find
@@ -720,14 +750,18 @@ def check_qwen3next():
              ("bwd", "kda", "gdn0/scan", "kda_bwd_chunk"),
              ("fwd", "kda", "gdn0/prep", "gdn_prep_fwd"),
              ("fwd", "kda", "gdn0/prep", "kda_prep_inverse"),
+             ("fwd", "kda", "gdn0/prep", "kda_corrected_fwd"),
              ("bwd", "kda", "gdn0/prep", "gdn_prep_fwd"),
-             ("bwd", "kda", "gdn0/prep", "gdn_prep_bwd")]
+             ("bwd", "kda", "gdn0/prep", "kda_corrected_fwd"),
+             ("bwd", "kda", "gdn0/prep", "gdn_prep_bwd"),
+             ("bwd", "kda", "gdn0/prep", "kda_corrected_bwd")]
         )
         found[QWEN3NEXT_INVARIANTS[3]] = "ok" if calls == want else f"{calls}"
         # a float32 [.., 64, 64] buffer under `prep` is a kernel's (A, the
-        # inverse), the kept inverse's `reduce_precision`, ONE product
-        # Diag(beta) A, or a `dot_general` a VALUE head (the inverse's
-        # backward and `_corrected`'s cotangents); the masks exp(G_r - G_j)
+        # inverse, the triangular system's cotangent dn), the kept inverse's
+        # `reduce_precision` or ONE product Diag(beta) A; no `dot_general`
+        # a value head is left since PR 54 (the inverse's backward and
+        # `_corrected`'s cotangents were six), and the masks exp(G_r - G_j)
         # were a `sub` and Q K^T, K K^T products a KEY head ([16, ..] and
         # [16, 2, ..])
         tiles = [
@@ -738,7 +772,7 @@ def check_qwen3next():
             if dtype == "f32" and dims[-1] == 64
             and (dims[-2] == 64 or len(dims) == 2)
         ]
-        makers = {"pallas_call", "reduce_precision", "mul", "dot_general"}
+        makers = {"pallas_call", "reduce_precision", "mul"}
         strays = [
             f"{name} {made_by} f32{list(dims)}" for name, made_by, dims in tiles
             if made_by not in makers or attrs.key_heads in dims[:-2]
@@ -756,6 +790,9 @@ def check_qwen3next():
         ).params.vmem_limit_bytes
         found[QWEN3NEXT_INVARIANTS[5]] = (
             "ok" if limit <= V5E_SCOPED_VMEM else f"{limit} bytes stated"
+        )
+        found[QWEN3NEXT_INVARIANTS[6]] = _corrected_kernels_limit(
+            attrs.num_heads * QWEN3NEXT_SHAPE[1] // attrs.chunk_size
         )
         per_position = [
             f"{name}: {result[:60]}"
