@@ -18,9 +18,10 @@ preferred_element_type=f32 so bf16 inputs still accumulate in f32 on the MXU.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import functools
 import threading
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1160,7 +1161,8 @@ def _delta_kernel(do_ref, o_ref, delta_ref):
     delta_ref[:, 0, :] = res[0]
 
 
-def _delta_bshf(do, o, b, s, h, d, interpret=False):
+def _delta_bshf(do, o, b, s, h, d, interpret=False, block=None,
+                name="flash_delta_bshf"):
     """delta[b,h,1,s] = sum_d do*o per head, in the (1, block) lse tiling.
 
     A Pallas kernel instead of the XLA multiply+reduce: the XLA version
@@ -1170,21 +1172,30 @@ def _delta_bshf(do, o, b, s, h, d, interpret=False):
     bench); here the product lives only in VMEM tiles. The fold cap
     budgets this kernel's own residency: two [bb, s, d] input blocks,
     double-buffered by the pipeline (the 16 MB scoped-VMEM limit trips at
-    seq 2048 otherwise)."""
-    bb = _delta_fold_cap(b, s, d, do.dtype.itemsize)
+    seq 2048 otherwise). With `block`, a tile of that many positions of one
+    batch row a program: the whole-row blocks ask for 48 MB of VMEM at
+    8,192 positions of 256 columns (`CausalPlan.delta_block`)."""
+    if block is None:
+        bb = _delta_fold_cap(b, s, d, do.dtype.itemsize)
+        grid = (b // bb, h)
+        tile = pl.BlockSpec((bb, s, d), lambda bi, hi: (bi, 0, hi))
+        stat = pl.BlockSpec((bb, None, 1, s), lambda bi, hi: (bi, hi, 0, 0))
+    else:
+        grid = (b, h, s // block)
+        tile = pl.BlockSpec((1, block, d), lambda bi, hi, j: (bi, j, hi))
+        stat = pl.BlockSpec(
+            (1, None, 1, block), lambda bi, hi, j: (bi, hi, 0, j)
+        )
     return pl.pallas_call(
         _delta_kernel,
-        name="flash_delta_bshf",
+        name=name,
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")
+            dimension_semantics=("parallel",) * len(grid)
         ),
-        grid=(b // bb, h),
-        in_specs=[
-            pl.BlockSpec((bb, s, d), lambda bi, hi: (bi, 0, hi)),
-            pl.BlockSpec((bb, s, d), lambda bi, hi: (bi, 0, hi)),
-        ],
-        out_specs=pl.BlockSpec((bb, None, 1, s), lambda bi, hi: (bi, hi, 0, 0)),
+        grid=grid,
+        in_specs=[tile, tile],
+        out_specs=stat,
         out_shape=jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
     )(do, o)
 
@@ -1448,21 +1459,17 @@ def _bwd_blocks(
     block_q: int, block_k: int, s: int, explicit: bool, causal: bool = False
 ) -> Tuple[int, int]:
     """Backward-pass block sizes: explicit caller blocks verbatim, else
-    FLEXFLOW_TPU_FLASH_BWD_BLOCK_Q/K, else _CAUSAL_BLOCK squared for a causal
-    call (measured there) and (2048, 512) otherwise: the non-causal one-pass
-    backward at batch 4, seq 2048, 16 heads of 128 takes 2.20 ms a call at
-    (2048, 512) against 2.61 at (1024, 1024) (my chip run, PR 29; the MFU
-    pair this note used to quote is in no record). Its scores tile
-    (bq*bk*4B) stays within scoped VMEM for any s at this shape."""
-    import os
-
-    if explicit:
-        return _clamp_block(block_q, s), _clamp_block(block_k, s)
-    bq = int(os.environ.get("FLEXFLOW_TPU_FLASH_BWD_BLOCK_Q", "0"))
-    bk = int(os.environ.get("FLEXFLOW_TPU_FLASH_BWD_BLOCK_K", "0"))
-    bq = bq if bq > 0 else (_CAUSAL_BLOCK if causal else 2048)
-    bk = bk if bk > 0 else (_CAUSAL_BLOCK if causal else 512)
-    return _clamp_block(bq, s), _clamp_block(bk, s)
+    _CAUSAL_BLOCK squared for a causal call (measured there) and (2048, 512)
+    otherwise: the non-causal one-pass backward at batch 4, seq 2048, 16
+    heads of 128 takes 2.20 ms a call at (2048, 512) against 2.61 at
+    (1024, 1024) (my chip run, PR 29; the MFU pair this note used to quote
+    is in no record). Its scores tile (bq*bk*4B) stays within scoped VMEM
+    for any s at this shape."""
+    if not explicit:
+        block_q, block_k = (
+            (_CAUSAL_BLOCK, _CAUSAL_BLOCK) if causal else (2048, 512)
+        )
+    return _clamp_block(block_q, s), _clamp_block(block_k, s)
 
 
 def _default_blocks() -> Tuple[int, int]:
@@ -1483,30 +1490,19 @@ def _default_blocks() -> Tuple[int, int]:
     return out[0], out[1]
 
 
-def flash_attention_bshf(
-    q, k, v, num_heads: int, *, causal: bool = False,
-    block_q: int = None, block_k: int = None, interpret: bool = False,
-):
-    """Blockwise attention on [b, s, num_heads*d] seq-major tensors.
+def _bshf_blocks(s: int, d: int, causal: bool, block_q, block_k):
+    """(block_q, block_k, explicit) of a bshf call on `s` positions of
+    `d`-wide heads: the caller's blocks where it names them (`explicit`),
+    else the env's, else the measured ones of the d % 128 kernels."""
+    import os
 
-    Same kernels as flash_attention, blocked so plain-matmul QKV projections
-    feed the custom call without a layout copy. Returns [b, s, num_heads*d]."""
-    assert q.shape == k.shape == v.shape, (
-        f"flash_attention_bshf is self-attention-shaped: {q.shape} vs "
-        f"{k.shape} / {v.shape} (the K/V BlockSpecs use q's seq length)"
-    )
-    b, s, f = q.shape
-    assert f % num_heads == 0
     dq0, dk0 = _default_blocks()
     bq = _clamp_block(block_q if block_q is not None else dq0, s)
     bk = _clamp_block(block_k if block_k is not None else dk0, s)
-    d = f // num_heads
     explicit = block_q is not None or block_k is not None
-    import os as _os
-
     # explicit caller blocks and the env sweep knobs opt out of both rules
-    swept = explicit or "FLEXFLOW_TPU_FLASH_BLOCK_Q" in _os.environ or (
-        "FLEXFLOW_TPU_FLASH_BLOCK_K" in _os.environ)
+    swept = explicit or "FLEXFLOW_TPU_FLASH_BLOCK_Q" in os.environ or (
+        "FLEXFLOW_TPU_FLASH_BLOCK_K" in os.environ)
     if not swept and d % 128 == 0 and causal:
         bq = bk = _clamp_block(_CAUSAL_BLOCK, s)  # the causal tile schedule
     elif not swept and d % 128 == 0 and s <= 2048:
@@ -1519,16 +1515,53 @@ def flash_attention_bshf(
         f"seq {s} must divide into blocks ({bq}, {bk}); "
         "gate callers on flash_attention_supported"
     )
-    flash = _flash_bshf
+    return bq, bk, explicit
+
+
+def flash_attention_bshf(
+    q, k, v, num_heads: int, *, causal: bool = False,
+    block_q: int = None, block_k: int = None, interpret: bool = False,
+    num_kv_heads: int = None, scale: float = None,
+):
+    """Blockwise attention on [b, s, num_heads*d] seq-major tensors.
+
+    Same kernels as flash_attention, blocked so plain-matmul QKV projections
+    feed the custom call without a layout copy. Returns [b, s, num_heads*d].
+
+    Under a causal mask over more than one tile, heads of whole 128-lane
+    tiles run the causal tile schedule as `causal_plan` lays it out, and
+    there alone the key may be wider than the value (q, k
+    [b, s, num_heads * dk], v [b, s, num_heads * dv]), `scale` may differ
+    from dk ** -0.5 (a key padded with zero columns names its TRUE width's),
+    and k and v may hold `num_kv_heads` heads where the plan reads them in
+    place (`CausalPlan.group`: the caller repeats them where it is 1).
+    -> [b, s, num_heads * dv]."""
+    b, s, f = q.shape
+    kv = num_heads if num_kv_heads is None else num_kv_heads
+    assert f % num_heads == 0 and v.shape[-1] % kv == 0
+    d, dv = f // num_heads, v.shape[-1] // kv
+    bq, bk, explicit = _bshf_blocks(s, d, causal, block_q, block_k)
+    if causal and d % 128 == 0 and dv % 128 == 0 and s > min(bq, bk):
+        # more than one tile: skip the dead ones
+        plan = causal_plan(
+            b, s, num_heads, kv, d, dv, q.dtype.itemsize, block_q, block_k
+        )
+        assert num_heads == kv * plan.group, (num_heads, kv, plan.group)
+        assert k.shape == (b, s, kv * d) and v.shape == (b, s, kv * dv), (
+            q.shape, k.shape, v.shape
+        )
+        return _flash_causal(q, k, v, num_heads, plan, interpret, scale)
+    assert q.shape == k.shape == v.shape and kv == num_heads and scale is None, (
+        f"flash_attention_bshf is self-attention-shaped: {q.shape} vs "
+        f"{k.shape} / {v.shape} (the K/V BlockSpecs use q's seq length)"
+    )
     if d % 128 != 0:
         # head-pair mode (d=64): fused-backward only — callers gate on
         # bshf_pair_supported
         assert 2 * d == 128 and num_heads % 2 == 0 and s <= bq and s <= bk, (
             d, num_heads, s, bq, bk,
         )
-    elif causal and s > min(bq, bk):
-        flash = _flash_bshf_causal  # more than one tile: skip the dead ones
-    return flash(q, k, v, num_heads, causal, bq, bk, interpret, explicit)
+    return _flash_bshf(q, k, v, num_heads, causal, bq, bk, interpret, explicit)
 
 
 def bshf_pair_supported(num_heads: int, d: int, s: int) -> bool:
@@ -1712,6 +1745,83 @@ _CAUSAL_BLOCK = 512
 # [block_k, block_q] f32 tiles; v5e has 128 MiB of VMEM, 16 of it scoped to
 # a kernel by default.
 _CAUSAL_VMEM_LIMIT = 64 * 1024 * 1024
+# The kernels keep a (batch, head)'s keys and values resident as whole rows,
+# double-buffered: 2 * s * (dk + dv) * itemsize bytes in the forward. That is
+# 8 MB at 8,192 positions of 128 bf16 columns and 16 MB, the whole default
+# scope, at heads of 256 (PR 51); a padded key row [s, 256] beside a value row
+# [s, 128] at 8,192 positions is 12 MB, exactly this budget, and the forward
+# does not compile inside the default scope there (a described-chip compile of
+# the latent node at [1, 8192, 2048], 32 heads of 192 | 128: "Scoped allocation
+# with size 48.08M and limit 48.00M exceeded", PR 53). From the budget on the
+# forward takes one batch row a program and asks for the room the backward
+# always asked for (`_CAUSAL_VMEM_LIMIT`); `causal_plan` is where it is read.
+_SCOPED_ROWS_BUDGET = 12 * 1024 * 1024
+
+
+@dataclasses.dataclass(frozen=True)
+class CausalPlan:
+    """What the causal tile schedule's three `pallas_call`s (`_fwd_causal`,
+    `_bwd_causal`, `_delta_bshf`) are built from, beside the operands: a
+    value of static facts (`causal_plan`), not a setting."""
+
+    # s is whole `_CAUSAL_BLOCK`s and more than one tile: what a caller asks
+    # before it promises a key wider than its value, a scale or padded heads,
+    # which have no other body
+    supported: bool
+    block_q: int
+    block_k: int
+    bwd_block_q: int
+    bwd_block_k: int
+    fold: int  # batch rows a forward program takes (`_batch_block`, or 1)
+    vmem_limit: Optional[int]  # the forward's; the backward always names one
+    # query heads that read one key/value head WHERE IT LIES in
+    # [b, s, kv * d] rows (the backward writes a query head's own dk and dv
+    # and they are summed over the group after); 1: k and v hold a head a
+    # query head, repeated by the caller where the node has fewer
+    group: int
+    delta_block: Optional[int]  # positions a delta program takes; None: rows
+    fwd_name: str
+    bwd_name: str
+    delta_name: str
+
+
+def causal_plan(
+    b: int, s: int, num_heads: int, num_kv_heads: int, dk: int, dv: int,
+    itemsize: int, block_q: int = None, block_k: int = None,
+) -> CausalPlan:
+    """The plan of a causal call on `s` positions of `num_heads` heads with
+    `dk`-wide keys and `dv`-wide values (multiples of 128 lanes), the node's
+    k and v holding `num_kv_heads` heads. Rows that fit the default scope
+    fold batch rows under it; longer ones take one row a program under
+    `_CAUSAL_VMEM_LIMIT`, and with dk == dv they are read in place for their
+    group, with a delta kernel by tiles, under the grouped kernels' names
+    (so do heads of 256 with a key/value head a query head: a group of 1);
+    a wide key keeps the folded form's backward and whole-row delta. Each is
+    what its shapes ran with before there was a plan (PR 29, 43, 51, 53)."""
+    assert dk % 128 == 0 and dv % 128 == 0, (dk, dv)
+    assert num_heads % num_kv_heads == 0, (num_heads, num_kv_heads)
+    bq, bk, explicit = _bshf_blocks(s, dk, True, block_q, block_k)
+    bwd_bq, bwd_bk = _bwd_blocks(bq, bk, s, explicit, causal=True)
+    tiled = s > min(bq, bk)
+    long_rows = tiled and 2 * s * (dk + dv) * itemsize >= _SCOPED_ROWS_BUDGET
+    in_place = long_rows and dk == dv
+    return CausalPlan(
+        supported=tiled and s % _CAUSAL_BLOCK == 0,
+        block_q=bq, block_k=bk, bwd_block_q=bwd_bq, bwd_block_k=bwd_bk,
+        fold=1 if long_rows else _batch_block(b, bq, bk, s, dk, itemsize),
+        vmem_limit=_CAUSAL_VMEM_LIMIT if long_rows else None,
+        group=num_heads // num_kv_heads if in_place else 1,
+        delta_block=bwd_bq if in_place else None,
+        fwd_name=(
+            "flash_fwd_causal_grouped" if in_place
+            else "flash_fwd_causal_wide_key" if long_rows
+            else "flash_fwd_causal_bshf"
+        ),
+        bwd_name=(
+            "flash_bwd_causal_grouped" if in_place else "flash_bwd_causal_bshf"
+        ),
+        delta_name="flash_delta_grouped" if in_place else "flash_delta_bshf",
+    )
 
 
 def _causal_k_range(qi, block_q, block_k):
@@ -1778,8 +1888,8 @@ def _fwd_causal_kernel(
     cdiv(block_q, block_k)) blocks on it the masked one, and dead blocks
     are outside both loops (_causal_k_range). The row sums are carried as
     128 lane partials and folded once a q block. The keys may be wider than
-    the values (`flash_attention_bshf_wide_key`): the accumulator is as wide
-    as a value."""
+    the values (a padded latent key): the accumulator is as wide as a
+    value."""
     qi = pl.program_id(2)
     bb, block_q, _ = q_ref.shape
     d = v_ref.shape[-1]
@@ -1822,31 +1932,38 @@ def _fwd_causal_kernel(
     lse_ref[:, 0, :] = m + jnp.log2(l)  # base-2 lse
 
 
-def _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret=False,
-                     scale=None):
+def _kv_head(group: int):
+    """The key/value head query head `hi` reads, as a block index."""
+    return (lambda hi: hi) if group == 1 else (lambda hi: hi // group)
+
+
+def _fwd_causal(q, k, v, h, plan: CausalPlan, interpret, scale):
     """_fwd_bshf's grid and blocks (k and v resident as whole rows, batch
-    rows folded), on the kernel that skips. q and k [b, s, h * d], v
-    [b, s, h * dv]; `scale` where it is not d ** -0.5 (a key padded with
-    zero columns)."""
+    rows folded as the plan says), on the kernel that skips. q
+    [b, s, h * d], k [b, s, kv * d], v [b, s, kv * dv]; `scale` where it is
+    not d ** -0.5 (a key padded with zero columns)."""
     b, s, f = q.shape
-    d, dv = f // h, v.shape[-1] // h
-    bb = _batch_block(b, block_q, block_k, s, d, q.dtype.itemsize)
+    d, dv = f // h, v.shape[-1] // (h // plan.group)
+    bb, block_q, kv_head = plan.fold, plan.block_q, _kv_head(plan.group)
 
     def tile(width):
         return pl.BlockSpec((bb, block_q, width), lambda bi, hi, i: (bi, i, hi))
 
     def row(width):
-        return pl.BlockSpec((bb, s, width), lambda bi, hi, i: (bi, 0, hi))
+        return pl.BlockSpec(
+            (bb, s, width), lambda bi, hi, i: (bi, 0, kv_head(hi))
+        )
 
     return pl.pallas_call(
         functools.partial(
-            _fwd_causal_kernel, block_k=block_k,
+            _fwd_causal_kernel, block_k=plan.block_k,
             scale=1.0 / (d**0.5) if scale is None else scale,
         ),
-        name="flash_fwd_causal_bshf",
+        name=plan.fwd_name,
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=plan.vmem_limit,
         ),
         grid=(b // bb, h, s // block_q),
         in_specs=[tile(d), row(d), row(dv)],
@@ -1930,7 +2047,7 @@ def _bwd_causal_kernel(
 
     start, full = _causal_q_range(ki, block_q, block_k)
     zero = jnp.zeros((block_k, d), jnp.float32)
-    # a value may be narrower than its key (flash_attention_bshf_wide_key)
+    # a value may be narrower than its key (a padded wide key)
     dv0 = zero if v_ref.shape == zero.shape else jnp.zeros(v_ref.shape, jnp.float32)
     carry = jax.lax.fori_loop(
         start, full, functools.partial(body, masked=True), (zero, dv0)
@@ -1944,69 +2061,77 @@ def _bwd_causal_kernel(
         dq_ref[:] = dq_acc[:].astype(dq_ref.dtype)
 
 
-def _bwd_bshf_causal(q, k, v, o, lse, do, h, block_q, block_k,
-                     interpret=False, scale=None):
+def _bwd_causal(q, k, v, o, lse, do, h, plan: CausalPlan, interpret, scale):
     b, s, f = q.shape
-    d, dv = f // h, v.shape[-1] // h
-    delta4 = _delta_bshf(do, o, b, s, h, dv, interpret)
+    kv = h // plan.group
+    d, vd = f // h, v.shape[-1] // kv
+    block_q, block_k = plan.bwd_block_q, plan.bwd_block_k
+    delta4 = _delta_bshf(
+        do, o, b, s, h, vd, interpret, plan.delta_block, plan.delta_name
+    )
+    # k and v are read by the head the group shares; dk and dv are written
+    # a query head
+    own, shared = _kv_head(1), _kv_head(plan.group)
 
     def row(width):
         return pl.BlockSpec((None, s, width), lambda bi, hi, j: (bi, 0, hi))
 
-    def col(width):
+    def col(width, head):
         return pl.BlockSpec(
-            (None, block_k, width), lambda bi, hi, j: (bi, j, hi)
+            (None, block_k, width), lambda bi, hi, j: (bi, j, head(hi))
         )
 
     stat = pl.BlockSpec((None, None, 1, s), lambda bi, hi, j: (bi, hi, 0, 0))
-    return pl.pallas_call(
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_causal_kernel, block_q=block_q,
             scale=1.0 / (d**0.5) if scale is None else scale,
         ),
-        name="flash_bwd_causal_bshf",
+        name=plan.bwd_name,
         interpret=interpret,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
             vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
         ),
         grid=(b, h, s // block_k),
-        in_specs=[row(d), col(d), col(dv), row(dv), stat, stat],
-        out_specs=[row(d), col(d), col(dv)],
+        in_specs=[
+            row(d), col(d, shared), col(vd, shared), row(vd), stat, stat,
+        ],
+        out_specs=[row(d), col(d, own), col(vd, own)],
         out_shape=[
             jax.ShapeDtypeStruct((b, s, f), q.dtype),
             jax.ShapeDtypeStruct((b, s, f), k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
+            jax.ShapeDtypeStruct((b, s, h * vd), v.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
     )(q, k, v, do, lse, delta4)
+    if plan.group == 1:
+        return dq, dk, dv
+
+    def over_group(t):
+        t = t.reshape(b, s, kv, plan.group, -1).astype(jnp.float32)
+        return jnp.sum(t, axis=3).reshape(b, s, -1).astype(k.dtype)
+
+    return dq, over_group(dk), over_group(dv)
 
 
-# _flash_bshf's signature, so that flash_attention_bshf picks one of the two
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def _flash_bshf_causal(q, k, v, h, causal, block_q, block_k, interpret,
-                       explicit=False):
-    assert causal
-    o, _ = _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_causal(q, k, v, h, plan, interpret, scale):
+    o, _ = _fwd_causal(q, k, v, h, plan, interpret, scale)
     return o
 
 
-def _flash_bshf_causal_fwd(q, k, v, h, causal, block_q, block_k, interpret,
-                           explicit=False):
-    o, lse = _fwd_bshf_causal(q, k, v, h, block_q, block_k, interpret)
+def _flash_causal_fwd(q, k, v, h, plan, interpret, scale):
+    o, lse = _fwd_causal(q, k, v, h, plan, interpret, scale)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bshf_causal_bwd(h, causal, block_q, block_k, interpret, explicit,
-                           res, do):
+def _flash_causal_bwd(h, plan, interpret, scale, res, do):
     q, k, v, o, lse = res
-    bwd_bq, bwd_bk = _bwd_blocks(block_q, block_k, q.shape[1], explicit, True)
-    return _bwd_bshf_causal(
-        q, k, v, o, lse, do, h, bwd_bq, bwd_bk, interpret
-    )
+    return _bwd_causal(q, k, v, o, lse, do, h, plan, interpret, scale)
 
 
-_flash_bshf_causal.defvjp(_flash_bshf_causal_fwd, _flash_bshf_causal_bwd)
+_flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -2063,7 +2188,7 @@ def min_seq_for(family=None) -> int:
     what was measured for it (_MIN_SEQ), else MIN_SEQ_UNMEASURED. A key wider
     than its value (kd != vd: latent attention's 192 | 128) reads the "lane"
     family's length and, besides, needs more than one causal tile
-    (`wide_key_supported`): below that it takes XLA's dense attention."""
+    (`CausalPlan.supported`): below that it takes XLA's dense attention."""
     import os
 
     env = os.environ.get("FLEXFLOW_TPU_FLASH_MIN_SEQ")
@@ -2193,272 +2318,3 @@ def _pair_fwd_kernel(s: int, block_q: int, block_k: int):
 def wide_key_padded(kd: int) -> int:
     """The least multiple of 128 lanes that holds a `kd`-wide key."""
     return -(-kd // 128) * 128
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bshf_wide_key(q, k, v, h, block, interpret, scale):
-    o, _ = _fwd_bshf_causal(q, k, v, h, block, block, interpret, scale)
-    return o
-
-
-def _flash_bshf_wide_key_fwd(q, k, v, h, block, interpret, scale):
-    o, lse = _fwd_bshf_causal(q, k, v, h, block, block, interpret, scale)
-    return o, (q, k, v, o, lse)
-
-
-def _flash_bshf_wide_key_bwd(h, block, interpret, scale, res, do):
-    q, k, v, o, lse = res
-    return _bwd_bshf_causal(
-        q, k, v, o, lse, do, h, block, block, interpret, scale
-    )
-
-
-_flash_bshf_wide_key.defvjp(_flash_bshf_wide_key_fwd, _flash_bshf_wide_key_bwd)
-
-
-def wide_key_supported(s: int) -> bool:
-    """More than one causal tile: the tile schedule is the only body these
-    shapes have."""
-    return s % _CAUSAL_BLOCK == 0 and s > _CAUSAL_BLOCK
-
-
-def flash_attention_bshf_wide_key(
-    q, k, v, num_heads: int, *, scale: float, interpret: bool = False,
-):
-    """Causal attention on q, k [b, s, num_heads * dk] and v
-    [b, s, num_heads * dv] with dk and dv multiples of 128 and dk != dv
-    allowed; softmax(q k^T * scale) v -> [b, s, num_heads * dv]. The causal
-    tile schedule at its measured block (`_CAUSAL_BLOCK`); rows too long for
-    the default scope take the forward that names its own limit
-    (`wide_key_rows_exceed_scope`)."""
-    b, s, f = q.shape
-    assert k.shape == q.shape and v.shape[:2] == q.shape[:2], (
-        q.shape, k.shape, v.shape
-    )
-    assert f % (128 * num_heads) == 0 and v.shape[-1] % (128 * num_heads) == 0
-    assert wide_key_supported(s), s
-    long_rows = wide_key_rows_exceed_scope(
-        s, f // num_heads, v.shape[-1] // num_heads, q.dtype.itemsize
-    )
-    entry = _flash_bshf_wide_key_long if long_rows else _flash_bshf_wide_key
-    return entry(q, k, v, num_heads, _CAUSAL_BLOCK, interpret, float(scale))
-
-
-# ---------------------------------------------------------------------------
-# grouped key/value heads read in place, and whole rows too long for the
-# scoped default
-# ---------------------------------------------------------------------------
-#
-# The causal kernels above keep a (batch, head)'s keys and values resident as
-# whole [s, d] rows. Double-buffered that is 4 * s * d * itemsize bytes in the
-# forward: 8 MB at 8,192 positions of 128 bf16 columns, and 16 MB, the whole
-# default scope, at heads of 256. `flash_attention_bshf_grouped` is the same
-# two kernel BODIES on blocks of its own: the forward asks for the room the
-# backward always asked for (`_CAUSAL_VMEM_LIMIT`), and query head h reads
-# key/value head h // group WHERE IT LIES in the published [b, s, kv * d]
-# rows, so the group's repeated copies of k and v are never made. The
-# backward writes a query head's own dk and dv ([b, s, h * d]) and the caller
-# sums them over the group. At the end of the file for the reason the causal
-# schedule is.
-
-# what the forward's resident rows may take of the default 16 MB scope before
-# the grouped entry, which names its own limit, takes the shape over
-_SCOPED_ROWS_BUDGET = 12 * 1024 * 1024
-
-
-def causal_rows_exceed_scope(s: int, d: int, itemsize: int) -> bool:
-    """Whether a (batch, head)'s k and v rows [s, d], double-buffered, leave
-    the causal forward no room inside the default scoped VMEM."""
-    return 4 * s * d * itemsize > _SCOPED_ROWS_BUDGET
-
-
-def _fwd_bshf_grouped(q, k, v, h, kv, block, interpret, scale):
-    b, s, f = q.shape
-    d, group = f // h, h // kv
-
-    def tile(width):
-        return pl.BlockSpec((1, block, width), lambda bi, hi, i: (bi, i, hi))
-
-    row = pl.BlockSpec((1, s, d), lambda bi, hi, i: (bi, 0, hi // group))
-    return pl.pallas_call(
-        functools.partial(_fwd_causal_kernel, block_k=block, scale=scale),
-        name="flash_fwd_causal_grouped",
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-            vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
-        ),
-        grid=(b, h, s // block),
-        in_specs=[tile(d), row, row],
-        out_specs=[
-            tile(d),
-            pl.BlockSpec((1, None, 1, block), lambda bi, hi, i: (bi, hi, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, f), q.dtype),
-            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
-        ],
-    )(q, k, v)
-
-
-def _delta_bshf_tiled(do, o, h, block, interpret):
-    """`_delta_bshf` a tile of `block` positions a program: the whole-row
-    blocks of that entry ask for 48 MB of VMEM at 8,192 positions of 256
-    columns."""
-    b, s, f = do.shape
-    d = f // h
-    tile = pl.BlockSpec((1, block, d), lambda bi, hi, j: (bi, j, hi))
-    return pl.pallas_call(
-        _delta_kernel,
-        name="flash_delta_grouped",
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel")
-        ),
-        grid=(b, h, s // block),
-        in_specs=[tile, tile],
-        out_specs=pl.BlockSpec(
-            (1, None, 1, block), lambda bi, hi, j: (bi, hi, 0, j)
-        ),
-        out_shape=jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
-    )(do, o)
-
-
-def _bwd_bshf_grouped(q, k, v, o, lse, do, h, kv, block, interpret, scale):
-    b, s, f = q.shape
-    d, group = f // h, h // kv
-    delta4 = _delta_bshf_tiled(do, o, h, block, interpret)
-    row = pl.BlockSpec((None, s, d), lambda bi, hi, j: (bi, 0, hi))
-    own = pl.BlockSpec((None, block, d), lambda bi, hi, j: (bi, j, hi))
-    shared = pl.BlockSpec(
-        (None, block, d), lambda bi, hi, j: (bi, j, hi // group)
-    )
-    stat = pl.BlockSpec((None, None, 1, s), lambda bi, hi, j: (bi, hi, 0, 0))
-    like_q = jax.ShapeDtypeStruct((b, s, f), q.dtype)
-    dq, dk, dv = pl.pallas_call(
-        functools.partial(_bwd_causal_kernel, block_q=block, scale=scale),
-        name="flash_bwd_causal_grouped",
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
-        ),
-        grid=(b, h, s // block),
-        in_specs=[row, shared, shared, row, stat, stat],
-        out_specs=[row, own, own],
-        out_shape=[like_q, like_q, like_q],
-        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)],
-    )(q, k, v, do, lse, delta4)
-
-    def over_group(t):
-        t = t.reshape(b, s, kv, group, d).astype(jnp.float32)
-        return jnp.sum(t, axis=3).reshape(b, s, kv * d).astype(k.dtype)
-
-    return dq, over_group(dk), over_group(dv)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_bshf_grouped(q, k, v, h, kv, block, interpret, scale):
-    return _fwd_bshf_grouped(q, k, v, h, kv, block, interpret, scale)[0]
-
-
-def _flash_bshf_grouped_fwd(q, k, v, h, kv, block, interpret, scale):
-    o, lse = _fwd_bshf_grouped(q, k, v, h, kv, block, interpret, scale)
-    return o, (q, k, v, o, lse)
-
-
-def _flash_bshf_grouped_bwd(h, kv, block, interpret, scale, res, do):
-    q, k, v, o, lse = res
-    return _bwd_bshf_grouped(
-        q, k, v, o, lse, do, h, kv, block, interpret, scale
-    )
-
-
-_flash_bshf_grouped.defvjp(_flash_bshf_grouped_fwd, _flash_bshf_grouped_bwd)
-
-
-def flash_attention_bshf_grouped(
-    q, k, v, num_heads: int, num_kv_heads: int, *, interpret: bool = False,
-):
-    """Causal attention on q [b, s, num_heads * d] and k, v
-    [b, s, num_kv_heads * d], d a multiple of 128, over more than one causal
-    tile; query head h reads key/value head h // (num_heads / num_kv_heads).
-    -> [b, s, num_heads * d]."""
-    b, s, f = q.shape
-    d = f // num_heads
-    assert d % 128 == 0 and num_heads % num_kv_heads == 0, (d, num_heads)
-    assert k.shape == v.shape == (b, s, num_kv_heads * d), (q.shape, k.shape)
-    assert wide_key_supported(s), s
-    return _flash_bshf_grouped(
-        q, k, v, num_heads, num_kv_heads, _CAUSAL_BLOCK, interpret,
-        d ** -0.5,
-    )
-
-
-# ---------------------------------------------------------------------------
-# a wide key whose whole rows are too long for the scoped default
-# ---------------------------------------------------------------------------
-#
-# At 8,192 positions a head's padded key row [s, 256] and value row [s, 128]
-# in bf16 are 6 MB, 12 MB double-buffered: the whole of `_SCOPED_ROWS_BUDGET`,
-# and the causal forward does not compile inside the default scope there (a
-# described-chip compile of the latent node at [1, 8192, 2048], 32 heads of
-# 192 | 128: "Scoped allocation with size 48.08M and limit 48.00M exceeded",
-# PR 53). The same kernel body, one batch row a program, under the limit the
-# backward always named; the backward and its delta kernel are the wide-key
-# entry's own (they compile there as they are). Picked from the shapes by
-# `flash_attention_bshf_wide_key`; every shape that ran before keeps its
-# forward.
-
-
-def wide_key_rows_exceed_scope(s: int, dk: int, dv: int, itemsize: int) -> bool:
-    """Whether a (batch, head)'s key row [s, dk] and value row [s, dv],
-    double-buffered, leave the causal forward no room inside the default
-    scoped VMEM."""
-    return 2 * s * (dk + dv) * itemsize >= _SCOPED_ROWS_BUDGET
-
-
-def _fwd_bshf_wide_key_long(q, k, v, h, block, interpret, scale):
-    b, s, f = q.shape
-    d, dv = f // h, v.shape[-1] // h
-
-    def tile(width):
-        return pl.BlockSpec((1, block, width), lambda bi, hi, i: (bi, i, hi))
-
-    def row(width):
-        return pl.BlockSpec((1, s, width), lambda bi, hi, i: (bi, 0, hi))
-
-    return pl.pallas_call(
-        functools.partial(_fwd_causal_kernel, block_k=block, scale=scale),
-        name="flash_fwd_causal_wide_key",
-        interpret=interpret,
-        compiler_params=None if interpret else pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel"),
-            vmem_limit_bytes=_CAUSAL_VMEM_LIMIT,
-        ),
-        grid=(b, h, s // block),
-        in_specs=[tile(d), row(d), row(dv)],
-        out_specs=[
-            tile(dv),
-            pl.BlockSpec((1, None, 1, block), lambda bi, hi, i: (bi, hi, 0, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, s, h * dv), q.dtype),
-            jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32),
-        ],
-    )(q, k, v)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_bshf_wide_key_long(q, k, v, h, block, interpret, scale):
-    return _fwd_bshf_wide_key_long(q, k, v, h, block, interpret, scale)[0]
-
-
-def _flash_bshf_wide_key_long_fwd(q, k, v, h, block, interpret, scale):
-    o, lse = _fwd_bshf_wide_key_long(q, k, v, h, block, interpret, scale)
-    return o, (q, k, v, o, lse)
-
-
-_flash_bshf_wide_key_long.defvjp(
-    _flash_bshf_wide_key_long_fwd, _flash_bshf_wide_key_bwd
-)

@@ -288,8 +288,9 @@ def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains,
     is the equal-head one: same kernels, same route, and the repeat's
     transpose sums dK and dV over the group. A kernel that indexes the
     key/value block by `h // group` instead saves the two repeated copies:
-    `flash_attention_bshf_grouped` is one, and its caller passes `repeat`
-    false (ROADMAP, Reach (3))."""
+    the causal tile schedule is one where its plan says so
+    (`CausalPlan.group`), and its caller passes `repeat` false (ROADMAP,
+    Reach (3))."""
     if attrs.qk_norm_per_head:
         # a head's own features normed by themselves, one gain [d] for all
         # of q's heads and one for the key heads
@@ -321,51 +322,59 @@ def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains,
     return qp, kp, vp
 
 
+def _causal_plan_of(attrs: MultiHeadAttentionAttrs, s: int, itemsize: int = 2):
+    """`flash_attention.causal_plan` of the node's fused-row core as
+    `_mha_forward` / `_latent_mha_forward` would call it on `s` positions: a
+    latent key padded to whole tiles, heads of 64 padded to 128 lanes. None
+    where the causal tile schedule has no body for it (no mask, other
+    widths). `supported` and the blocks are the same at every itemsize: the
+    route, which has shapes and no dtype, asks at bf16's."""
+    from flexflow_tpu.kernels.flash_attention import causal_plan, wide_key_padded
+
+    H, kv = attrs.num_heads, attrs.kv_heads
+    kd, vd = attrs.q_proj_size, attrs.v_proj_size
+    if attrs.latent:
+        kd, kv = wide_key_padded(kd), H
+    elif kd == vd == 64:
+        kd = vd = 128
+    if not getattr(attrs, "causal", False) or kd % 128 or vd % 128:
+        return None
+    return causal_plan(1, s, H, kv, kd, vd, itemsize)
+
+
 def mha_pads_heads(attrs: MultiHeadAttentionAttrs, s: int) -> bool:
     """Whether the fused-row core runs these heads of 64 as 128-lane heads,
     each padded with 64 zero columns: a causal context of more than one tile,
     which the head-pair kernels refuse (their backward is the single-tile
     one). The d % 128 causal tile schedule takes them as it takes a padded
-    latent key (`flash_attention_bshf_wide_key`, the true head size's
-    scale): on a 128 x 128 matrix unit a 64-wide contraction or product
-    fills half the array either way, so the zero columns cost bytes and no
-    passes. A causal tile schedule for the pair kernels would save the
-    padded copies (ROADMAP, Reach)."""
-    from flexflow_tpu.kernels.flash_attention import (
-        bshf_pair_supported,
-        wide_key_supported,
-    )
+    latent key (the true head size's scale): on a 128 x 128 matrix unit a
+    64-wide contraction or product fills half the array either way, so the
+    zero columns cost bytes and no passes. A causal tile schedule for the
+    pair kernels would save the padded copies (ROADMAP, Reach)."""
+    from flexflow_tpu.kernels.flash_attention import bshf_pair_supported
 
     kd = attrs.q_proj_size
+    if not kd == attrs.v_proj_size == 64:
+        return False
+    plan = _causal_plan_of(attrs, s)
     return (
-        kd == attrs.v_proj_size == 64
-        and getattr(attrs, "causal", False)
+        plan is not None and plan.supported
         and not bshf_pair_supported(attrs.num_heads, kd, s)
-        and wide_key_supported(s)
     )
 
 
-def _padded_heads_core(attrs: MultiHeadAttentionAttrs, qp, kp, vp):
-    """`mha_pads_heads`' core on the fused [b, s, h * 64] rows: pad, the
-    wide-key entry at 128 | 128, and the context's own 64 columns back."""
-    from flexflow_tpu.kernels.flash_attention import (
-        flash_attention_bshf_wide_key,
-        per_batch_shard,
-    )
+def _padded_heads(x, kd: int):
+    """[b, s, heads * kd] -> [b, s, heads * 128], zero columns after each
+    head's own."""
+    b, s, f = x.shape
+    x = jnp.pad(x.reshape(b, s, f // kd, kd), ((0, 0),) * 3 + ((0, 128 - kd),))
+    return x.reshape(b, s, -1)
 
-    H, kd = attrs.num_heads, attrs.q_proj_size
-    b, s, _ = qp.shape
 
-    def padded(x):
-        x = jnp.pad(x.reshape(b, s, H, kd), ((0, 0),) * 3 + ((0, 128 - kd),))
-        return x.reshape(b, s, H * 128)
-
-    with jax.named_scope("core"):
-        ctx = per_batch_shard(
-            flash_attention_bshf_wide_key, padded(qp), padded(kp), padded(vp),
-            num_heads=H, scale=kd ** -0.5,
-        )
-        return ctx.reshape(b, s, H, 128)[..., :kd].reshape(b, s, H * kd)
+def _own_columns(ctx, kd: int):
+    """`_padded_heads`' inverse on the context: each head's own `kd`."""
+    b, s, f = ctx.shape
+    return ctx.reshape(b, s, f // 128, 128)[..., :kd].reshape(b, s, -1)
 
 
 def mha_core_route(
@@ -382,8 +391,8 @@ def mha_core_route(
     - "fused_row": three plain matmuls into [b, s, h*d] rows and
       `flash_attention_bshf` (d % 128 == 0, or d=64 with distinct operands
       or QK-norm / RoPE between projection and core), or, for d=64 under a
-      causal mask over more than one tile, the d % 128 causal kernels on
-      heads padded to 128 lanes (`mha_pads_heads`);
+      causal mask over more than one tile, the same entry's d % 128 causal
+      tile schedule on heads padded to 128 lanes (`mha_pads_heads`);
     - "rows": the per-head [b, h, s, d] projections and `flash_attention`
       (other head sizes; every head-sharded plan, through
       `sharded_flash_attention`);
@@ -392,9 +401,10 @@ def mha_core_route(
 
     A key wider than its value (kd != vd) has one kernel form: latent
     attention (`attrs.latent`) under a causal mask at more than one causal
-    tile takes "fused_row" on `flash_attention_bshf_wide_key`, its key
-    padded with zero columns to whole 128-lane tiles (192 -> 256); every
-    other kd != vd shape takes "rows" or "dense", as it always did.
+    tile (`CausalPlan.supported`) takes "fused_row" on `flash_attention_bshf`,
+    its key padded with zero columns to whole 128-lane tiles (192 -> 256)
+    and its scale the true width's; every other kd != vd shape takes "rows"
+    or "dense", as it always did.
 
     Under a declared mesh the gates read the block each device sees and the
     fused-row kernels are mapped over the batch shards (`per_batch_shard`);
@@ -416,15 +426,12 @@ def mha_core_route(
     mesh_ctx = current_flash_mesh()
     heads_whole = mesh_ctx is None or mesh_ctx[2] is None
     if attrs.latent:
-        from flexflow_tpu.kernels.flash_attention import (
-            wide_key_padded,
-            wide_key_supported,
-        )
+        from flexflow_tpu.kernels.flash_attention import wide_key_padded
 
         padded = (b, H, s, wide_key_padded(kd))
+        plan = _causal_plan_of(attrs, s)
         kernel = (
-            heads_whole and getattr(attrs, "causal", False)
-            and vd % 128 == 0 and wide_key_supported(s)
+            heads_whole and plan is not None and plan.supported
             and flash_core_supported(padded, padded, padded, "lane")
         )
         return "fused_row" if kernel else "dense"
@@ -547,21 +554,12 @@ def deinterleaved_columns(w, num_heads: int, start: int, width: int):
 def _note_latent_form(attrs: MultiHeadAttentionAttrs, route, s, itemsize):
     """Tell the program's counter which form the latent node being lowered
     took (`observability/trace.latent_attention_forms`)."""
-    from flexflow_tpu.kernels.flash_attention import (
-        wide_key_padded,
-        wide_key_rows_exceed_scope,
-    )
     from flexflow_tpu.observability import trace
 
     rope = attrs.rope_theta is not None
     core = "dense"
     if route == "fused_row":
-        long_rows = wide_key_rows_exceed_scope(
-            s, wide_key_padded(attrs.q_proj_size), attrs.v_proj_size, itemsize
-        )
-        core = (
-            "flash_fwd_causal_wide_key" if long_rows else "flash_fwd_causal_bshf"
-        )
+        core = _causal_plan_of(attrs, s, itemsize).fwd_name
     trace.note_latent_attention_form({
         "query_rank": attrs.q_latent_rank,
         "rotated_columns": attrs.shared_key_dim if rope else 0,
@@ -578,7 +576,7 @@ def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal,
     """Latent self-attention on x [b, s, e]: keys and values from one normed
     low-rank row (scope `latent`), head h's key its own columns beside the
     slice all heads share, then the attention core (scope `core`): the
-    wide-key flash kernels where `mha_core_route` says so, on a key padded
+    causal tile kernels where `mha_core_route` says so, on a key padded
     with zero columns, else XLA's dense attention. With `q_latent_rank` the
     query comes from a normed low-rank row of its own (scope `latent` too),
     and with `rope_theta` the shared slice, once a position before the heads
@@ -586,7 +584,7 @@ def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal,
     `rows`): halves of the slice against each other, on weights whose slice
     columns `rope_interleaved` puts evens first."""
     from flexflow_tpu.kernels.flash_attention import (
-        flash_attention_bshf_wide_key,
+        flash_attention_bshf,
         per_batch_shard,
         wide_key_padded,
     )
@@ -663,8 +661,8 @@ def _latent_mha_forward(attrs: MultiHeadAttentionAttrs, x, weight, gain, causal,
             q = project_q(wq_last.reshape(-1, H * (kd + pad)))
         with jax.named_scope("core"):
             ctx = per_batch_shard(
-                flash_attention_bshf_wide_key, q, k, v.reshape(b, s, H * vd),
-                num_heads=H, scale=kd ** -0.5,
+                flash_attention_bshf, q, k, v.reshape(b, s, H * vd),
+                num_heads=H, causal=causal, scale=kd ** -0.5,
             )
         return ctx @ wo
     q = project_q(wq[-1]).reshape(b, s, H, kd)
@@ -714,29 +712,6 @@ def _rows_scope(attrs: MultiHeadAttentionAttrs):
     )
 
 
-def mha_reads_kv_in_place(
-    attrs: MultiHeadAttentionAttrs, s: int, itemsize: int
-) -> bool:
-    """Whether the "fused_row" core is `flash_attention_bshf_grouped`: a
-    causal context whose whole key and value rows, double-buffered, leave
-    the causal forward no room in the default scoped VMEM (heads of 256 at
-    8,192 positions), so the entry that names its own limit takes it, and
-    reads each key/value head where it lies for the query heads that share
-    it. Every shape that ran before keeps the entry it had."""
-    from flexflow_tpu.kernels.flash_attention import (
-        causal_rows_exceed_scope,
-        wide_key_supported,
-    )
-
-    kd = attrs.q_proj_size
-    return (
-        getattr(attrs, "causal", False)
-        and kd == attrs.v_proj_size and kd % 128 == 0
-        and wide_key_supported(s)
-        and causal_rows_exceed_scope(s, kd, itemsize)
-    )
-
-
 def _mha_forward(
     attrs: MultiHeadAttentionAttrs, q, k, v, weight, input_bias=None,
     causal=False, qk_gains=None,
@@ -745,7 +720,6 @@ def _mha_forward(
         current_flash_mesh,
         flash_attention,
         flash_attention_bshf,
-        flash_attention_bshf_grouped,
         flash_attention_bshf_qkv,
         per_batch_shard,
         sharded_flash_attention,
@@ -776,24 +750,28 @@ def _mha_forward(
             attrs, q, k, v, weight, input_bias
         )
         qp, gate = _split_output_gate(attrs, qp)
-        in_place = mha_reads_kv_in_place(attrs, q.shape[1], q.dtype.itemsize)
+        s = q.shape[1]
+        pads = mha_pads_heads(attrs, s)
+        plan = _causal_plan_of(attrs, s, q.dtype.itemsize)
+        # query heads that read one key/value head where it lies
+        group = 1 if plan is None else plan.group
         if post:
             with _rows_scope(attrs):
                 qp, kp, vp = mha_between(
-                    attrs, qp, kp, vp, qk_gains, repeat=not in_place
+                    attrs, qp, kp, vp, qk_gains, repeat=group == 1
                 )
-        if mha_pads_heads(attrs, q.shape[1]):
-            return _padded_heads_core(attrs, qp, kp, vp) @ wo2
-        if in_place:
-            with jax.named_scope("core"):
-                ctx = per_batch_shard(
-                    flash_attention_bshf_grouped, qp, kp, vp,
-                    num_heads=H, num_kv_heads=attrs.kv_heads,
-                )
-        else:
+        # padded heads and rows read in place came with a scope of their own
+        with jax.named_scope("core") if pads or group > 1 else (
+            contextlib.nullcontext()
+        ):
+            if pads:
+                qp, kp, vp = (_padded_heads(x, kd) for x in (qp, kp, vp))
             ctx = per_batch_shard(
-                flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal
+                flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal,
+                num_kv_heads=H // group, scale=kd ** -0.5 if pads else None,
             )
+            if pads:
+                ctx = _own_columns(ctx, kd)
         return _gated_context(ctx, gate) @ wo2
 
     if post:

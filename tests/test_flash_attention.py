@@ -896,3 +896,107 @@ def test_flash_bshf_dispatch_is_pinned(case):
         assert not {"flash_bwd_dq_bshf", "flash_bwd_dkv_bshf"} & {
             name for name, _, _ in found
         }
+
+
+_BSHF = ("flash_fwd_causal_bshf", "flash_bwd_causal_bshf", "flash_delta_bshf")
+_GROUPED = (
+    "flash_fwd_causal_grouped", "flash_bwd_causal_grouped",
+    "flash_delta_grouped",
+)
+_LIMIT = 64 * 1024 * 1024
+# id: (where the shape is read, then what the plan must say: supported, the
+# forward's batch fold and vmem limit, the group read in place, the delta
+# kernel's block, the three kernel names). The shape is a cell's
+# (configuration, job) under benchmark/ or, for the boundary shapes the old
+# predicates' tests had, (b, s, heads, key/value heads, dk, dv). The answers
+# were written down from the PARENT of PR 55 (commit 5ee988b) before there
+# was a plan: a script over its five predicates (`wide_key_supported`,
+# `wide_key_rows_exceed_scope`, `causal_rows_exceed_scope`,
+# `mha_reads_kv_in_place`'s conjunction, `_batch_block`) said which of its four
+# `custom_vjp`s a shape took, and that door's wrappers what they were built
+# with.
+CAUSAL_PLAN_CASES = {
+    "cgpt13b": (("cerebras-gpt-1.3b", "pretrain_s2048_b4_1chip"),
+                True, 2, None, 1, None, _BSHF),
+    "olmoe": (("olmoe-1b-7b", "pretrain_s4096_b4_1chip"),
+              True, 2, None, 1, None, _BSHF),
+    # 32 over 2 and 4 over 1 heads of 128: repeated by the caller (group 1)
+    "twotower": (("nemotron-twotower-30b-a3b", "pretrain_s4096_b1_1chip"),
+                 True, 1, None, 1, None, _BSHF),
+    "super": (("nemotron-3-super-120b-a12b", "pretrain_s4096_b1_1chip"),
+              True, 1, None, 1, None, _BSHF),
+    # 32 heads of 192 -> 256 | 128
+    "kimi": (("kimi-linear-48b-a3b", "pretrain_s4096_b1_1chip"),
+             True, 1, None, 1, None, _BSHF),
+    # 32 heads of 64, padded to 128 | 128: 8 MB of rows
+    "lfm2": (("lfm2-24b-a2b", "pretrain_s8192_b2_1chip"),
+             True, 1, None, 1, None, _BSHF),
+    # 16 over 2 heads of 256: 16 MB of rows, read in place
+    "qwen3next": (("qwen3-next-80b-a3b", "pretrain_s8192_b1_1chip"),
+                  True, 1, _LIMIT, 8, 512, _GROUPED),
+    # 12 MB of rows, the budget itself: one row under the limit, and the
+    # folded form's backward with its whole-row delta
+    "joyai": (("joyai-llm-flash", "pretrain_s8192_b1_1chip"),
+              True, 1, _LIMIT, 1, None,
+              ("flash_fwd_causal_wide_key",) + _BSHF[1:]),
+    "qwen3next_at_4096": ((1, 4096, 16, 2, 256, 256),
+                          True, 1, None, 1, None, _BSHF),
+    "heads_of_128_over_8_at_8192": ((1, 8192, 32, 8, 128, 128),
+                                    True, 1, None, 1, None, _BSHF),
+    # as many key/value heads as query heads: the grouped form, a group of 1
+    "heads_of_256_ungrouped": ((1, 8192, 16, 16, 256, 256),
+                               True, 1, _LIMIT, 1, 512, _GROUPED),
+    # one causal tile: no such body, whatever the rest says
+    "kimi_one_tile": ((1, 512, 32, 32, 256, 128),
+                      False, 1, None, 1, None, _BSHF),
+}
+
+
+def _cell_attention_shape(config_name, job_name):
+    """(b, s, heads, key/value heads, dk, dv) of a cell's causal attention
+    as `kernels/ops` asks the plan for it: a latent key padded to whole
+    tiles, heads of 64 padded to 128 lanes."""
+    import json
+    import os
+
+    from flexflow_tpu.kernels.flash_attention import wide_key_padded
+
+    root = os.path.join(os.path.dirname(__file__), os.pardir, "benchmark")
+    with open(os.path.join(root, "configs", config_name + ".json")) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "jobs", job_name + ".json")) as f:
+        job = json.load(f)
+    heads = config.get("num_attention_heads", config.get("n_head"))
+    kv = config.get("num_key_value_heads", heads)
+    if "qk_nope_head_dim" in config:
+        dk = wide_key_padded(
+            config["qk_nope_head_dim"] + config["qk_rope_head_dim"]
+        )
+        dv = config["v_head_dim"]
+    else:
+        hidden = config.get("hidden_size", config.get("n_embd"))
+        dk = dv = max(config.get("head_dim") or hidden // heads, 128)
+    return job["batch_per_chip"], job["seq"], heads, kv, dk, dv
+
+
+@pytest.mark.parametrize("case", sorted(CAUSAL_PLAN_CASES))
+def test_causal_plan_is_pinned(case):
+    """`causal_plan` at the eight cells' attention shapes (bf16) and at the
+    boundary shapes: what the parent's four doors were built with."""
+    from flexflow_tpu.kernels.flash_attention import causal_plan
+
+    shape, supported, fold, limit, group, delta_block, names = (
+        CAUSAL_PLAN_CASES[case]
+    )
+    if isinstance(shape[0], str):
+        shape = _cell_attention_shape(*shape)
+    plan = causal_plan(*shape, 2)
+    assert plan.supported == supported
+    if not supported:
+        return
+    assert (plan.fold, plan.vmem_limit, plan.group, plan.delta_block) == (
+        fold, limit, group, delta_block
+    )
+    assert (plan.fwd_name, plan.bwd_name, plan.delta_name) == names
+    blocks = (plan.block_q, plan.block_k, plan.bwd_block_q, plan.bwd_block_k)
+    assert blocks == (512,) * 4

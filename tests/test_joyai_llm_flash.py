@@ -387,17 +387,22 @@ def test_wide_key_route_at_8192_takes_the_forward_with_its_own_limit(monkeypatch
     monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
     assert mha_core_route(attrs, shape, shape, shape, True) == "fused_row"
     # [8192, 256] and [8192, 128] in bf16, double-buffered, are the budget
-    assert flash.wide_key_rows_exceed_scope(8192, 256, 128, 2)
+    def forward(s, dk, dv):
+        plan = flash.causal_plan(1, s, 32, 32, dk, dv, 2)
+        return plan.fwd_name, plan.vmem_limit
+
+    limit = flash._CAUSAL_VMEM_LIMIT
+    assert forward(8192, 256, 128) == ("flash_fwd_causal_wide_key", limit)
     # every shape that ran before keeps its forward: Kimi's 4,096 positions,
     # LFM2's padded heads of 64 at 8,192
-    assert not flash.wide_key_rows_exceed_scope(4096, 256, 128, 2)
-    assert not flash.wide_key_rows_exceed_scope(8192, 128, 128, 2)
+    assert forward(4096, 256, 128) == ("flash_fwd_causal_bshf", None)
+    assert forward(8192, 128, 128) == ("flash_fwd_causal_bshf", None)
 
 
 def test_long_row_forward_matches_the_wide_key_entry(monkeypatch):
     """The forward that names its own limit is the same body: at two causal
-    tiles in interpret mode its output and gradients are the wide-key
-    entry's, to the bit."""
+    tiles in interpret mode its output and gradients are the folded
+    forward's, to the bit."""
     rs = np.random.RandomState(11)
     b, s, h, kd, vd = 1, 1024, 2, 256, 128
     q, k = rand(rs, b, s, h * kd, scale=0.5), rand(rs, b, s, h * kd, scale=0.5)
@@ -405,13 +410,16 @@ def test_long_row_forward_matches_the_wide_key_entry(monkeypatch):
 
     def run():
         return jax.value_and_grad(
-            lambda *a: jnp.sum(flash.flash_attention_bshf_wide_key(
-                *a, h, scale=192 ** -0.5, interpret=True
+            lambda *a: jnp.sum(flash.flash_attention_bshf(
+                *a, h, causal=True, scale=192 ** -0.5, interpret=True
             ) * cot), argnums=(0, 1, 2),
         )(q, k, v)
 
     plain = run()
-    monkeypatch.setattr(flash, "wide_key_rows_exceed_scope", lambda *a: True)
+    monkeypatch.setattr(flash, "_SCOPED_ROWS_BUDGET", 0)
+    assert flash.causal_plan(b, s, h, h, kd, vd, 4).fwd_name == (
+        "flash_fwd_causal_wide_key"
+    )
     assert_trees_close(run(), plain, rtol=0, atol=0)
 
 

@@ -593,8 +593,8 @@ def test_wide_key_route_is_read_from_shapes_and_backend(monkeypatch):
 
 
 def test_wide_key_kernels_match_dense_attention(monkeypatch):
-    """`flash_attention_bshf_wide_key` in interpret mode on a 256-wide padded
-    key beside a 128-wide value, two causal tiles: forward and the three
+    """`flash_attention_bshf` in interpret mode on a 256-wide padded key
+    beside a 128-wide value, two causal tiles: forward and the three
     gradients against XLA's masked softmax at the scale of the TRUE width."""
     rs = np.random.RandomState(11)
     b, s, h, kd, vd = 1, 1024, 2, 256, 128
@@ -612,8 +612,8 @@ def test_wide_key_kernels_match_dense_attention(monkeypatch):
         )
 
     def kernel(q, k, v):
-        return flash.flash_attention_bshf_wide_key(
-            q, k, v, h, scale=scale, interpret=True
+        return flash.flash_attention_bshf(
+            q, k, v, h, causal=True, scale=scale, interpret=True
         )
 
     def run(fn):

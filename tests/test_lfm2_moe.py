@@ -258,9 +258,9 @@ def test_padded_heads_route_is_read_from_shapes_and_backend(monkeypatch):
 def test_padded_heads_on_the_causal_tile_kernels_match_the_reference(monkeypatch):
     """The node as the cell runs it, in interpret mode: 4 query heads over 2
     key/value heads of 64 on two causal tiles, each head padded to 128
-    lanes for `flash_attention_bshf_wide_key`, against the reference's
-    masked softmax; forward and every gradient. The kernels take exp2 of
-    scaled scores and fold row sums by lanes: 2e-4."""
+    lanes for `flash_attention_bshf`'s causal tile schedule, against the
+    reference's masked softmax; forward and every gradient. The kernels take
+    exp2 of scaled scores and fold row sums by lanes: 2e-4."""
     import functools
 
     from flexflow_tpu.kernels import flash_attention as flash
@@ -277,8 +277,8 @@ def test_padded_heads_on_the_causal_tile_kernels_match_the_reference(monkeypatch
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", "512")
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_K", "512")
     monkeypatch.setattr(
-        flash, "flash_attention_bshf_wide_key",
-        functools.partial(flash.flash_attention_bshf_wide_key, interpret=True),
+        flash, "flash_attention_bshf",
+        functools.partial(flash.flash_attention_bshf, interpret=True),
     )
     got = jax.value_and_grad(
         lambda u, ws: jnp.sum(program_attention(u, ws, sizes) * cot), (0, 1)

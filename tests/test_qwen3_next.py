@@ -555,10 +555,11 @@ def test_gate_and_rotary_width_each_by_itself(gate, rotary):
 
 
 def test_grouped_flash_entry_reads_a_key_head_in_place(monkeypatch):
-    """`flash_attention_bshf_grouped` in interpret mode, 4 query heads on 2
-    key/value heads of 128 over two causal tiles, against dense attention on
-    the repeated heads: forward and the three gradients, dk and dv summed
-    over the group. bf16 kernels against a float32 dense form: 2e-2 is a
+    """`flash_attention_bshf` where its plan reads k and v in place (the
+    rows' budget set to nothing: these rows are 1 MB), in interpret mode, 4
+    query heads on 2 key/value heads of 128 over two causal tiles, against
+    dense attention on the repeated heads: forward and the three gradients,
+    dk and dv summed over the group. bf16 kernels against a float32 dense form: 2e-2 is a
     few bf16 roundings (2^-9) of values of order one through a sum of 1,024
     terms."""
     from flexflow_tpu.kernels import flash_attention as fa
@@ -570,8 +571,14 @@ def test_grouped_flash_entry_reads_a_key_head_in_place(monkeypatch):
     v = rand(rs, b, s, kv * d, scale=0.5).astype(jnp.bfloat16)
     cot = rand(rs, b, s, h * d)
 
+    monkeypatch.setattr(fa, "_SCOPED_ROWS_BUDGET", 0)
+    plan = fa.causal_plan(b, s, h, kv, d, d, 2)
+    assert (plan.group, plan.fwd_name) == (2, "flash_fwd_causal_grouped")
+
     def kernels(q, k, v):
-        return fa.flash_attention_bshf_grouped(q, k, v, h, kv, interpret=True)
+        return fa.flash_attention_bshf(
+            q, k, v, h, causal=True, num_kv_heads=kv, interpret=True
+        )
 
     def dense(q, k, v):
         qh = q.astype(jnp.float32).reshape(b, s, h, d)
@@ -600,15 +607,16 @@ def test_grouped_flash_entry_reads_a_key_head_in_place(monkeypatch):
         np.testing.assert_allclose(got, want, rtol=5e-2, atol=1e-1)
     # which shapes take it: whole rows that leave the causal forward no room
     # in the default scope, read from the shapes alone
+    monkeypatch.undo()
     big = attention_attrs(dict(TOY, hidden_size=2048, head_dim=256,
                                num_attention_heads=16, num_key_value_heads=2))
-    from flexflow_tpu.kernels.ops import mha_reads_kv_in_place
+    from flexflow_tpu.kernels.ops import _causal_plan_of
 
-    assert mha_reads_kv_in_place(big, 8192, 2)
-    assert not mha_reads_kv_in_place(big, 4096, 2)  # 8 MB of rows: fits
+    assert _causal_plan_of(big, 8192, 2).group == 8
+    assert _causal_plan_of(big, 4096, 2).group == 1  # 8 MB of rows: fits
     lfm2 = RingAttentionAttrs(2048, 32, kdim=128, vdim=128, causal=True,
                               num_kv_heads=8)
-    assert not mha_reads_kv_in_place(lfm2, 8192, 2)
+    assert _causal_plan_of(lfm2, 8192, 2).group == 1
 
 
 # -- the zero-centred norm -------------------------------------------------------

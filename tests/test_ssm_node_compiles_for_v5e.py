@@ -29,7 +29,7 @@ profile will carry; the cell's whole step, compiled for the described chip,
 holds 11,843,911,680 bytes with them, 11,830,603,264 at PR 53), and which
 must hold no `[heads, positions, 128, 128]` state per position; and the causal
 flash kernels on a 256-wide padded key beside a 128-wide value
-(`flash_attention_bshf_wide_key`), forward and backward.
+(`flash_attention_bshf` with the true width's scale), forward and backward.
 
 And what the `lfm2_moe` cell added (PR 47), at its shape, two sequences of
 8,192 positions: ONE double-gated short-convolution node
@@ -38,7 +38,7 @@ no float32 tensor a position (its chain converts inside its fusions, as
 `conv_silu` does) and the projection's row at most twice (forward, and
 recomputed in the backward: kept nowhere); and the causal core of 32 heads of
 64 over 16 tiles, each head padded to 128 lanes
-(`kernels/ops._padded_heads_core`), forward and backward on the d % 128 causal
+(`kernels/ops._padded_heads`), forward and backward on the d % 128 causal
 tile kernels (the `[b, h, s, d]` rows kernels are refused at this length: 16 MB
 of scoped VMEM).
 
@@ -321,8 +321,6 @@ def _corrected_kernels_limit(chunk_heads):
 def check_kimi():
     """{invariant: "ok" or what was found} for the `kimi_linear` cell's two
     new kernel paths at the published shape."""
-    import jax
-
     from flexflow_tpu.kernels import flash_attention as fa
     from flexflow_tpu.observability.trace import parse_scope
 
@@ -401,29 +399,33 @@ def check_kimi():
         complaint = f"{type(e).__name__}: {e}"[:2000]
         for invariant in KIMI_INVARIANTS[:5]:
             found.setdefault(invariant, complaint)
-    try:
-        on_chip = _described_chip()
-        q = on_chip((1, ROWS, heads * 256))
-        v = on_chip((1, ROWS, heads * 128))
-
-        def core(q, k, v, cot):
-            o, vjp = jax.vjp(
-                lambda q, k, v: fa.flash_attention_bshf_wide_key(
-                    q, k, v, heads, scale=192 ** -0.5
-                ), q, k, v,
-            )
-            return o, vjp(cot)
-
-        text = jax.jit(core).lower(q, q, v, v).compile().as_text()
-        kernels = text.count("tpu_custom_call")
-        found["wide_key_flash_compiles_forward_and_backward"] = (
-            "ok" if kernels == 3 else f"{kernels} kernels, want 3"
-        )
-    except Exception as e:  # noqa: BLE001
-        found["wide_key_flash_compiles_forward_and_backward"] = (
-            f"{type(e).__name__}: {e}"[:2000]
-        )
+    on_chip = _described_chip()
+    q = on_chip((1, ROWS, heads * 256))
+    v = on_chip((1, ROWS, heads * 128))
+    found["wide_key_flash_compiles_forward_and_backward"] = _causal_core_kernels(
+        lambda q, k, v: fa.flash_attention_bshf(
+            q, k, v, heads, causal=True, scale=192 ** -0.5
+        ), q, q, v,
+    )
     return found
+
+
+def _causal_core_kernels(core, q, k, v):
+    """"ok" where `core(q, k, v)`, forward and backward, compiles for the
+    described chip into the causal tile schedule's three kernels (forward,
+    delta, backward); else what was found."""
+    import jax
+
+    def both(q, k, v, cot):
+        o, vjp = jax.vjp(core, q, k, v)
+        return o, vjp(cot)
+
+    try:
+        text = jax.jit(both).lower(q, k, v, v).compile().as_text()
+        kernels = text.count("tpu_custom_call")
+        return "ok" if kernels == 3 else f"{kernels} kernels, want 3"
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        return f"{type(e).__name__}: {e}"[:2000]
 
 
 LFM2_INVARIANTS = [
@@ -438,9 +440,9 @@ def check_lfm2():
     its attention core."""
     import jax
 
-    from flexflow_tpu.kernels.ops import _padded_heads_core
+    from flexflow_tpu.kernels.flash_attention import flash_attention_bshf
+    from flexflow_tpu.kernels.ops import _own_columns, _padded_heads
     from flexflow_tpu.kernels.short_conv import gated_short_conv
-    from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
 
     found = {}
     on_chip = _described_chip()
@@ -475,22 +477,14 @@ def check_lfm2():
         )
     except Exception as e:  # noqa: BLE001 - the complaint is the result
         found[LFM2_INVARIANTS[0]] = f"{type(e).__name__}: {e}"[:2000]
-    try:
-        attrs = RingAttentionAttrs(hidden, 32, kdim=64, vdim=64, causal=True)
 
-        def core(q, k, v, cot):
-            o, vjp = jax.vjp(
-                lambda q, k, v: _padded_heads_core(attrs, q, k, v), q, k, v
-            )
-            return o, vjp(cot)
+    def core(q, k, v):
+        q, k, v = (_padded_heads(t, 64) for t in (q, k, v))
+        return _own_columns(flash_attention_bshf(
+            q, k, v, 32, causal=True, scale=64 ** -0.5
+        ), 64)
 
-        text = jax.jit(core).lower(x, x, x, x).compile().as_text()
-        kernels = text.count("tpu_custom_call")
-        found[LFM2_INVARIANTS[1]] = (
-            "ok" if kernels == 3 else f"{kernels} kernels, want 3"
-        )
-    except Exception as e:  # noqa: BLE001
-        found[LFM2_INVARIANTS[1]] = f"{type(e).__name__}: {e}"[:2000]
+    found[LFM2_INVARIANTS[1]] = _causal_core_kernels(core, x, x, x)
     return found
 
 

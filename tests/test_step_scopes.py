@@ -501,8 +501,10 @@ def test_flash_kernels_lower_under_their_names(case, monkeypatch):
 def test_every_pallas_call_is_named():
     import inspect
 
-    for module, sites in ((fa, 21), (ring_flash, 3)):
+    # a literal, a wrapper's `name` argument, or one of `CausalPlan`'s three
+    for module, sites in ((fa, 17), (ring_flash, 3)):
         source = inspect.getsource(module)
         calls = source.count("pl.pallas_call(")
         assert calls == sites
-        assert len(re.findall(r"\bname=(\"[a-z_]+\"|name),", source)) == calls
+        named = r"\bname=(\"[a-z_]+\"|name|plan\.[a-z]+_name),"
+        assert len(re.findall(named, source)) == calls
