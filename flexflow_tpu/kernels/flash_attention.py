@@ -20,6 +20,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import math
 import threading
 from typing import Optional, Tuple
 
@@ -1864,35 +1865,62 @@ def _causal_mask(scores, q0, k0, q_axis):
     return jnp.where(keep, scores, NEG_INF)
 
 
-def _lane_sums(p):
-    """[..., n] -> [..., 128] f32: the 128-lane slices of the minor dim
-    added elementwise on the VPU, the cross-lane fold left to the caller.
-    (A rowsum on the MXU, p @ ones, costs the MXU as much as p @ v does at
-    d = 128: 1.35 against 1.27 ms a forward call at seq 2048, my chip runs,
-    PR 29.)"""
-    n = p.shape[-1]
-    p = p.astype(jnp.float32)
-    if n % 128:
-        return p.sum(axis=-1, keepdims=True)
-    out = p[..., 0:128]
-    for j in range(1, n // 128):
-        out = out + p[..., j * 128:(j + 1) * 128]
-    return out
+# The causal forward holds a tile's scores TRANSPOSED, [block_k, block_q], as
+# _fwd_kernel_pair and the causal backward hold theirs: the maximum and the
+# sums run down the sublanes, so m, alpha, l and lse are [1, block_q] rows
+# along the lanes, where lse_ref stores them, and go back over a tile as
+# sublane broadcasts; p is the stationary operand of v^T @ p and the
+# accumulator is [dv, block_q], turned once a q block. Forward alone at the
+# eight cells' call shapes, bf16, device time from a trace (6 calls a form in
+# one process; my chip runs, PR 56), ms a call and (us a live tile):
+#   b x s, heads, dk | dv, rows a program   [queries, keys]   transposed
+#   1 x 8192, 32, 256 | 128, 1 (64 MB)      7.042 (1.618)     6.167 (1.417)
+#   1 x 4096, 32, 256 | 128, 1              2.088 (1.812)     1.701 (1.477)
+#   4 x 2048, 16, 128 | 128, 2              1.122 (1.754)     0.814 (1.272)
+#   4 x 4096, 16, 128 | 128, 2              3.293 (1.429)     2.700 (1.172)
+#   2 x 8192, 32, 128 | 128, 1             10.968 (1.260)    10.750 (1.235)
+#   1 x 8192, 16 over 2, 256 | 256, 1       4.531 (2.082)     4.136 (1.901)
+#   1 x 4096, 32, 128 | 128, 1              1.670 (1.450)     1.489 (1.293)
+#   1 x 4096, 4, 128 | 128, 1               0.212 (1.473)     0.189 (1.315)
+# "[queries, keys]" was this body until PR 56: a cross-lane maximum a tile,
+# m_new and alpha turned from lanes to sublanes to be broadcast, the sums
+# carried as 128 lane partials. What it lost was mostly AROUND the k loop,
+# once a q block (the fold of the partials, the accumulator's quotient by a
+# lane-major l, the carry's spills): 1.8 us a q block against 0.56, while a
+# tile inside the loop costs about what it did (fitted from the pairs of
+# lengths above: 1.40 -> 1.35 us at 256 | 128, 1.05 -> 1.17 at 128 | 128 and
+# one row a program, 1.02 -> 1.05 at two): the maximum down the sublanes
+# needs the WHOLE tile before the first exponential (0.22-0.26 us a tile,
+# timed by leaving it out), where the old body's rows were independent
+# chains woven through both matmuls. So the exponentials, the sums and
+# v^T @ p are taken _FWD_KEY_CHUNK keys at a time once the tile's maximum is
+# known: a chunk's matmul runs beside the next chunk's exponentials. One
+# chunk of 512: 6.530 / 0.832 / 11.336 ms a call on the first, third and
+# fifth shape above (slower than the old body on the fifth); chunks of 128,
+# in another call: 6.49 / 0.823 / 11.38. Not kept: two k tiles an iteration
+# with both score products first (6.875 / 0.876 / 11.195: slower on the chip
+# though 7-16% SHORTER by the compiler's bundle count); the q block in two
+# chunks of queries beside the key chunks (6.054 / 0.813 / 10.83: within 2%
+# either way, for a carry a chunk); a tree for the maximum and the sums
+# (within 2% either way).
+_FWD_KEY_CHUNK = 256
 
 
 def _fwd_causal_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, scale,
 ):
-    """_fwd_kernel_b's online softmax on the causal tile schedule: k blocks
+    """The online softmax of a q block on the causal tile schedule: k blocks
     wholly under the diagonal run the unmasked body, the (at most
     cdiv(block_q, block_k)) blocks on it the masked one, and dead blocks
-    are outside both loops (_causal_k_range). The row sums are carried as
-    128 lane partials and folded once a q block. The keys may be wider than
+    are outside both loops (_causal_k_range). Scores [bb, block_k, block_q]
+    float32 (see above), f32 maximum, sums and accumulator, _exp2_probs, MXU
+    operands in the inputs' dtype, base-2 lse. The keys may be wider than
     the values (a padded latent key): the accumulator is as wide as a
     value."""
     qi = pl.program_id(2)
     bb, block_q, _ = q_ref.shape
     d = v_ref.shape[-1]
+    chunk = math.gcd(_FWD_KEY_CHUNK, block_k)
     scale2 = scale * LOG2E
     # scale folded into the [bb, block_q, d] operand (see _fwd_kernel)
     q = q_ref[:] * jnp.asarray(scale2, q_ref.dtype)
@@ -1900,36 +1928,38 @@ def _fwd_causal_kernel(
     def body(j, carry, masked=False):
         acc, m, l = carry
         kb = k_ref[:, pl.ds(j * block_k, block_k), :]
-        vb = v_ref[:, pl.ds(j * block_k, block_k), :]
         scores = jax.lax.dot_general(
-            q, kb, (((2,), (2,)), ((0,), (0,))),
+            kb, q, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )
         if masked:
-            scores = _causal_mask(scores, qi * block_q, j * block_k, -2)
-        m_new = jnp.maximum(m, _row_max(scores))
-        p = _exp2_probs(scores - m_new[..., None], q_ref.dtype)
+            scores = _causal_mask(scores, qi * block_q, j * block_k, -1)
+        m_new = jnp.maximum(m, scores.max(axis=1, keepdims=True))
         alpha = jnp.exp2(m - m_new)
-        l = l * alpha[..., None] + _lane_sums(p)
-        acc = acc * alpha[..., None] + jax.lax.dot_general(
-            p.astype(vb.dtype), vb, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )
+        l = l * alpha
+        acc = acc * alpha
+        for k0 in range(0, block_k, chunk):
+            vb = v_ref[:, pl.ds(j * block_k + k0, chunk), :]
+            p = _exp2_probs(scores[:, k0:k0 + chunk, :] - m_new, q_ref.dtype)
+            l = l + p.astype(jnp.float32).sum(axis=1, keepdims=True)
+            acc = acc + jax.lax.dot_general(
+                vb, p.astype(vb.dtype), (((1,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            )  # [bb, d, block_q]: (p^T v)^T
         return acc, m_new, l
 
     carry = (
-        jnp.zeros((bb, block_q, d), jnp.float32),
-        jnp.full((bb, block_q), NEG_INF, jnp.float32),
-        jnp.zeros((bb, block_q, 1 if block_k % 128 else 128), jnp.float32),
+        jnp.zeros((bb, d, block_q), jnp.float32),
+        jnp.full((bb, 1, block_q), NEG_INF, jnp.float32),
+        jnp.zeros((bb, 1, block_q), jnp.float32),
     )
     full, live = _causal_k_range(qi, block_q, block_k)
     carry = jax.lax.fori_loop(0, full, body, carry)
     acc, m, l = jax.lax.fori_loop(
         full, live, functools.partial(body, masked=True), carry
     )
-    l = l.sum(axis=-1)
-    o_ref[:] = (acc / l[..., None]).astype(o_ref.dtype)
-    lse_ref[:, 0, :] = m + jnp.log2(l)  # base-2 lse
+    o_ref[:] = jnp.swapaxes(acc / l, 1, 2).astype(o_ref.dtype)
+    lse_ref[:] = m + jnp.log2(l)  # base-2 lse
 
 
 def _kv_head(group: int):

@@ -751,60 +751,151 @@ def test_causal_tile_schedule_counts_what_the_mask_keeps(
     assert by_q == by_k and len(by_q) == want[0]
 
 
-# id: (batch, seq, block_q, block_k): explicit blocks, or None for the rule
-# the dispatch reads from (causal, s, d). Two heads of 128 throughout.
+# id: (batch, seq, block_q, block_k, form): explicit blocks, or None for the
+# rule the dispatch reads from (causal, s, d); `form` names what differs from
+# two float32 heads of 128 | 128 with a key/value head each at the default
+# scale: heads, kv (key/value heads), dk, dv, scale, dtype, long_rows (the
+# scope's budget set to 0: one row a program under the 64 MB limit).
 CAUSAL_SCHEDULE_CASES = {
     # s = 4 x block: the first k block is full (unmasked) for every q block
     # but the first, and the last q block walks three full tiles
-    "square_blocks": (1, 512, 128, 128),
-    "block_q_twice_block_k": (3, 512, 256, 128),
-    "block_k_twice_block_q": (1, 512, 128, 256),
+    "square_blocks": (1, 512, 128, 128, {}),
+    "block_q_twice_block_k": (3, 512, 256, 128, {}),
+    "block_k_twice_block_q": (1, 512, 128, 256, {}),
     # every live tile straddles the diagonal: the masked loop alone
-    "only_diagonal_tiles": (1, 256, 256, 128),
+    "only_diagonal_tiles": (1, 256, 256, 128, {}),
     # the default blocks above the single-tile limit: 512 x 512, 3 of 4 live
-    "default_blocks": (1, 1024, None, None),
+    "default_blocks": (1, 1024, None, None, {}),
+    # the forms the eight cells run (`causal_plan`): a padded latent key
+    # beside its value at the true width's scale (Kimi), ...
+    "wide_key_with_scale": (
+        1, 1024, None, None, {"dk": 256, "scale": 192 ** -0.5}),
+    # ... the same as one long row a program (JoyAI), ...
+    "wide_key_long_rows": (
+        1, 1024, None, None,
+        {"dk": 256, "scale": 192 ** -0.5, "long_rows": True}),
+    # ... batch rows folded into a program (cgpt, OLMoE: 2 of 4), ...
+    "folded_batch_rows": (4, 1024, None, None, {}),
+    # ... 4 heads of 256 over 2 read in place (Qwen3-Next), ...
+    "grouped_in_place": (
+        1, 1024, None, None,
+        {"heads": 4, "kv": 2, "dk": 256, "dv": 256, "long_rows": True}),
+    # ... six tiles a q block: five unmasked ones before the diagonal's
+    "many_tiles": (1, 768, 128, 128, {}),
+    # ... a k tile that is no multiple of _FWD_KEY_CHUNK: three chunks of 128
+    "key_block_of_384": (1, 768, 128, 384, {}),
+    # ... and bf16 operands (every cell), against the float32 reference
+    "bf16_inputs": (2, 1024, None, None, {"dtype": jnp.bfloat16}),
 }
 
 
-@pytest.mark.parametrize("case", sorted(CAUSAL_SCHEDULE_CASES))
-def test_flash_bshf_causal_schedule_matches_dense(case):
-    """Forward and the three gradients of the causal d=128 bshf entry
-    against dense attention, over every branch of the tile schedule (dead
-    tiles skipped, diagonal tiles masked, full tiles unmasked, one visit a
-    tile in the backward)."""
-    from flexflow_tpu.kernels.flash_attention import flash_attention_bshf
+def _causal_case(case, monkeypatch):
+    """A CAUSAL_SCHEDULE_CASES entry as a namespace: `flash`, the entry on
+    fused rows in interpret mode, and `dense`, the float32 reference, both
+    on `qkv`, float32 [b, heads, s, d] operands already rounded to the
+    case's `dtype`; `scores`, the reference's masked scores; `to_bshf`;
+    `plan`, the call's CausalPlan; `scale`, the stated scale or None."""
+    import types
 
-    b, s, block_q, block_k = CAUSAL_SCHEDULE_CASES[case]
-    h, d = 2, 128
-    rs = np.random.RandomState(17)
-    q4, k4, v4 = (
-        jnp.asarray(rs.randn(b, h, s, d), jnp.float32) for _ in range(3)
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    b, s, block_q, block_k, form = CAUSAL_SCHEDULE_CASES[case]
+    h, kv = form.get("heads", 2), form.get("kv", form.get("heads", 2))
+    dk, dv = form.get("dk", 128), form.get("dv", 128)
+    scale = form.get("scale", dk ** -0.5)
+    dtype = form.get("dtype", jnp.float32)
+    if form.get("long_rows"):
+        monkeypatch.setattr(fa, "_SCOPED_ROWS_BUDGET", 0)
+    plan = fa.causal_plan(
+        b, s, h, kv, dk, dv, jnp.dtype(dtype).itemsize, block_q, block_k
     )
-    to_bshf = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, h * d)
+    assert plan.group == (h // kv if form.get("long_rows") else 1)
+    rs = np.random.RandomState(17)
+    q, k, v = (
+        jnp.asarray(rs.randn(b, n, s, d), dtype).astype(jnp.float32)
+        for n, d in ((h, dk), (kv, dk), (kv, dv))
+    )
+    to_bshf = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, -1)
     blocks = (
         {} if block_q is None else {"block_q": block_q, "block_k": block_k}
     )
 
     def flash(q, k, v):
-        return flash_attention_bshf(
-            to_bshf(q), to_bshf(k), to_bshf(v), h, causal=True,
-            interpret=True, **blocks,
-        )
+        return fa.flash_attention_bshf(
+            *(to_bshf(x).astype(dtype) for x in (q, k, v)), h, causal=True,
+            interpret=True, num_kv_heads=kv, scale=form.get("scale"),
+            **blocks,
+        ).astype(jnp.float32)
 
+    def scores(q, k):
+        sc = jnp.einsum(
+            "bhsd,bhtd->bhst", q, jnp.repeat(k, h // kv, axis=1)
+        ) * scale
+        mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        return jnp.where(mask, sc, -1e30)
+
+    def dense(q, k, v):
+        return to_bshf(jnp.einsum(
+            "bhst,bhtv->bhsv", jax.nn.softmax(scores(q, k), -1),
+            jnp.repeat(v, h // kv, axis=1),
+        ))
+
+    return types.SimpleNamespace(
+        flash=flash, dense=dense, scores=scores, qkv=(q, k, v), dtype=dtype,
+        to_bshf=to_bshf, plan=plan, scale=form.get("scale"),
+    )
+
+
+@pytest.mark.parametrize("case", sorted(CAUSAL_SCHEDULE_CASES))
+def test_flash_bshf_causal_schedule_matches_dense(case, monkeypatch):
+    """Forward and the three gradients of the causal d % 128 bshf entry
+    against dense attention, over every branch of the tile schedule (dead
+    tiles skipped, diagonal tiles masked, full tiles unmasked, one visit a
+    tile in the backward) and every form of `causal_plan`."""
+    c = _causal_case(case, monkeypatch)
+    flash, dense, qkv = c.flash, c.dense, c.qkv
+    # bf16: the probabilities and the output are rounded to 8 bits
+    fwd_tol, bwd_tol = (
+        (1e-5, 2e-4) if c.dtype == jnp.float32 else (2e-2, 1e-1)
+    )
     np.testing.assert_allclose(
-        np.asarray(flash(q4, k4, v4)),
-        np.asarray(to_bshf(dense_attention(q4, k4, v4, True))),
-        atol=1e-5,
+        np.asarray(flash(*qkv)), np.asarray(dense(*qkv)), atol=fwd_tol,
     )
     gf = jax.grad(
         lambda q, k, v: jnp.sum(flash(q, k, v) ** 2), argnums=(0, 1, 2)
-    )(q4, k4, v4)
+    )(*qkv)
     gd = jax.grad(
-        lambda q, k, v: jnp.sum(dense_attention(q, k, v, True) ** 2),
-        argnums=(0, 1, 2),
-    )(q4, k4, v4)
+        lambda q, k, v: jnp.sum(dense(q, k, v) ** 2), argnums=(0, 1, 2)
+    )(*qkv)
     for a, b_ in zip(gf, gd):
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=bwd_tol)
+
+
+@pytest.mark.parametrize(
+    "case", ["default_blocks", "folded_batch_rows", "grouped_in_place",
+             "wide_key_long_rows", "block_q_twice_block_k"],
+)
+def test_causal_forward_lse_is_the_dense_log_sum_exp(case, monkeypatch):
+    """The forward's second output, row by row: log2 of the sum over a
+    query's keys of 2 ** (score * log2(e)), [b, heads, 1, s] float32. The
+    backward rebuilds every probability from it, and it leaves the kernel
+    as the `[1, block_q]` row the transposed softmax carries."""
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    c = _causal_case(case, monkeypatch)
+    q, k, v = c.qkv
+    (b, h, s, _), kv = q.shape, k.shape[1]
+    # the caller repeats k and v where the plan does not read them in place
+    rows = [
+        c.to_bshf(x if c.plan.group > 1 else jnp.repeat(x, h // kv, axis=1))
+        for x in (k, v)
+    ]
+    _, lse = fa._fwd_causal(c.to_bshf(q), *rows, h, c.plan, True, c.scale)
+    want = jax.scipy.special.logsumexp(c.scores(q, k), axis=-1)
+    assert lse.shape == (b, h, 1, s) and lse.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(lse[:, :, 0, :]), np.asarray(want) * fa.LOG2E, atol=1e-5,
+    )
 
 
 def _pallas_calls(jaxpr):
