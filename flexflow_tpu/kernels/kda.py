@@ -139,18 +139,40 @@ is what `chunk_operands` returns, exp(G_Q) written over the dk lanes, so
 `operand_form`'s answer (the attrs' `decay` and `scan_route`'s route, nothing
 else), counted by node in `observability/trace.delta_rule_operands()`.
 
+**The gated norm** (`_gated_head_norm`, the node's last part before W_out):
+the rms norm of each head's dv features of the recurrence's o under a gate,
+silu(z) with one decay a head and sigmoid(g_up + b_g) with one a key channel.
+On the "kda" route it is `head_norm_gate`, ONE `jax.custom_vjp` for both
+gates over two kernels: `head_norm_gate_fwd` reads a block of rows of four
+heads of o, HEADS FIRST as the recurrence leaves it, and of the gate's
+operand where the projection left it (z in place, a column block of the
+input projection's row), in the model's dtype, and writes y, one pass;
+`head_norm_gate_bwd`, the WRITTEN backward, reads the same blocks and dy,
+recomputes the roots and the gate in VMEM and writes do and the operand's
+cotangent, with a program's partial sums of the gain's (and the bias's)
+gradient as one float32 tile that XLA adds up after. What the backward keeps
+is the operands (o, z or g_up and b_g, the gain), alive anyway; no float32
+of the rows' width and no root crosses HBM, and no forward is run again. On
+the "xla" route it is the plain `_head_norm_silu` / `_head_norm_gate` under a
+checkpoint of its own, differentiated by JAX: what the kernels are tested
+against. `observability/trace.head_norms()` says which, by node.
+
 The node's parts go under scopes of their own inside the node's
 (`ff.kda.<name>/scan`, `/prep`, `/gates`, `/conv`, `/norm`;
 `observability/trace.NODE_PARTS`). On the "kda" route `gates` holds what the
 scores' kernels do not take: the two rank-128 gate matmuls (`f_up`, `g_up`),
 beta's sigmoid, v's heads-first copy (and dv's back) and the small
 reductions; on the "xla" route also the norms of q and k, the softplus and
-the heads-first copies of q, k and the pre-activation.
+the heads-first copies of q, k and the pre-activation. `norm` holds the two
+kernels above and nothing else on the "kda" route, and on the "xla" route o's
+copy to the model's layout with XLA's fusions of the plain form, forward,
+recomputed and backward; `conv` is `kernels/ssm.conv_silu` on both.
 """
 
 from __future__ import annotations
 
 import functools
+import math
 from typing import Sequence
 
 import jax
@@ -1706,6 +1728,308 @@ def operand_form(attrs: GatedDeltaAttrs, route: str) -> str:
 
 
 # ---------------------------------------------------------------------------
+# the gated norm, the Pallas form: one pass over the rows each way
+# ---------------------------------------------------------------------------
+#
+# y = o r gain gate, r = rsqrt(mean_head(o^2) + eps) a position and head of dv
+# lanes and gate = silu(z) or sigmoid(gate_up + gate_bias), elementwise but
+# for the head's sum. o is read where `chunk_scan` left it, HEADS FIRST
+# [b, h, s, dv], a head's rows of a block as one [rows, dv] slab, and the
+# gate's operand where the projection left it, [b, s, .] (z as the column
+# block of the input projection's row it is): no copy of either is made for
+# the kernels, and do goes back heads first. A program holds a block of rows
+# of `_NORM_HEADS` heads of o, of the operand's columns of those heads (and
+# of dy's) in the model's dtype and walks it `_NORM_ROWS` rows a step, a
+# head at a time, float32 in vector registers; r and the gate are recomputed
+# in the backward, so nothing but the operands is kept and no float32 of the
+# rows' width reaches HBM. With n = o r and dn = dy gate gain:
+#
+#     do = r (dn - n mean_head(dn n))      d(operand) = dy n gain gate'
+#     dgain = sum dy n gate                dbias = sum d(operand)
+#
+# the two sums over a program's rows as ONE float32 tile a program (eight
+# sublanes of partial sums: the rows are added eight apart), which XLA adds
+# up after.
+#
+# What was ranked on the chip, kernels alone at [8192, 4096] bf16 under the
+# silu (Qwen3-Next's node; my chip run, PR 58, o still in the model's
+# layout): with 64 or 16 rows a step the forward runs in 0.314 ms and the
+# backward in 0.523, 640 GB/s each way, the rate a plain pass streams at; 32
+# rows a step ran the backward in 0.675 (its bundles say 0.44: a stall they
+# do not count). Blocks of 1, 2, 4 and 8 MB an operand (128 to 1,024 rows):
+# 0.669, 0.675, 0.688, 0.717 backward and 0.317-0.323 forward at 32 rows a
+# step, so 2 MB. The head's sum through the MXU (the squares' three bf16
+# parts against a [128, 128] block of ones, `_split_product`) ties with the
+# XLU's from 256 rows a step up (0.3146 / 0.5227) and is slower below (1.23
+# ms backward at 32): the XLU's it is, the pass being the HBM's either way.
+# With o read heads first, as now (same run, second call): 0.315 / 0.518 at
+# 64 rows a step, 0.317 / 0.515 at 16, 0.313 / 0.517 at 128, 0.317 / 0.675
+# at 32 again; Kimi's node ([4096, 4096] under the sigmoid with its bias)
+# 0.159 / 0.265. In the cells' traced steps a node's pair reads 0.28 + 0.52
+# ms (Qwen3-Next) and 0.11 + 0.27 (Kimi), and the heads-first read took the
+# [rows, width] copies of o and do off the step (`PERF.md` section 6, PR 58).
+# All of that with every head of a row in one program; `_NORM_HEADS` below.
+
+# rows a step of a program's loop (64: eight float32 registers a value)
+_NORM_ROWS = 64
+# heads a program (fewer where the node has fewer), the grid's third axis.
+# The body is written out a head, so its trace and lowering grow with them:
+# all 32 heads a program lowered in 1.0 s here and about 3 s on the chip
+# tool's host, `setup_s` +3.0 s warm in both cells; 4 heads in an eighth of
+# that. Kernels alone (my chip run, PR 58, forward / backward ms at Qwen3-
+# Next's shape, blocks of 2 MB an operand): 32 heads 0.315 / 0.518, 16
+# 0.320 / 0.522, 8 0.318 / 0.528, 4 0.314 / 0.529, 2 0.345 / 0.599; at 4
+# heads blocks of 1 and 4 MB 0.323 / 0.535 and 0.313 / 0.532.
+_NORM_HEADS = 4
+# rows a program (the largest that divides the rows and fits `_NORM_BLOCK`)
+_NORM_BLOCKS = (2048, 1024, 512, 256, 128, 64)
+_NORM_BLOCK = 2 * 1024 * 1024  # bytes of one operand's block
+
+
+def _gate_and_slope(x, silu: bool):
+    """(gate, its slope) at the float32 pre-activation x."""
+    s = jax.nn.sigmoid(x)
+    if silu:
+        return x * s, s * (1.0 + x * (1.0 - s))
+    return s, s * (1.0 - s)
+
+
+def _eight_apart(t):
+    """[rows, w] float32 -> [8, w]: the rows added eight apart (whole
+    registers added, no sublane leaves its place)."""
+    return functools.reduce(
+        jnp.add, [t[i:i + 8, :] for i in range(0, t.shape[0], 8)]
+    )
+
+
+def _head_mean(t):
+    """[rows, dv] -> [rows, 1]: a head's mean over its lanes."""
+    return jnp.mean(t, axis=-1, keepdims=True)
+
+
+def _norm_rows(i):
+    return pl.ds(pl.multiple_of(i * _NORM_ROWS, _NORM_ROWS), _NORM_ROWS)
+
+
+def _head_lanes(o_ref):
+    """[(head, its lanes of a [rows, heads * dv] block)] of o [h, rows, dv]."""
+    heads, _, dv = o_ref.shape
+    return [(h, slice(h * dv, (h + 1) * dv)) for h in range(heads)]
+
+
+def _head_norm_gate_fwd_kernel(*refs, eps: float):
+    """refs: o [heads, rows, dv], the gate's operand [rows, heads * dv],
+    (bias [1, heads * dv] float32 where the gate is the sigmoid), gain
+    [1, dv] float32; y [rows, heads * dv]."""
+    o_ref, x_ref, *bias_ref, gain_ref, y_ref = refs
+    f32 = jnp.float32
+    gain = gain_ref[:]
+
+    def step(i, _):
+        rows = _norm_rows(i)
+        for h, lanes in _head_lanes(o_ref):
+            of = o_ref[h, rows, :].astype(f32)
+            x = x_ref[rows, lanes].astype(f32)
+            if bias_ref:
+                x = x + bias_ref[0][:, lanes]
+            root = lax.rsqrt(_head_mean(of * of) + eps)
+            gate, _ = _gate_and_slope(x, not bias_ref)
+            y_ref[rows, lanes] = (of * root * gain * gate).astype(y_ref.dtype)
+
+    lax.fori_loop(0, o_ref.shape[1] // _NORM_ROWS, step, None)
+
+
+def _head_norm_gate_bwd_kernel(*refs, eps: float, biased: bool):
+    """refs: o, the gate's operand, (bias where `biased`), gain as the
+    forward's, dy [rows, heads * dv]; do [heads, rows, dv], the operand's
+    cotangent [rows, heads * dv], the gain's partial sums [8, dv] float32
+    (and the bias's [8, heads * dv])."""
+    o_ref, x_ref, *bias_ref, gain_ref, dy_ref = refs[:4 + biased]
+    do_ref, dx_ref, dgain_ref, *dbias_ref = refs[4 + biased:]
+    f32 = jnp.float32
+    gain = gain_ref[:]
+    if dbias_ref:
+        dbias_ref[0][:] = jnp.zeros(dbias_ref[0].shape, f32)
+
+    def step(i, dgain):
+        rows = _norm_rows(i)
+        for h, lanes in _head_lanes(o_ref):
+            of = o_ref[h, rows, :].astype(f32)
+            x = x_ref[rows, lanes].astype(f32)
+            if bias_ref:
+                x = x + bias_ref[0][:, lanes]
+            dy = dy_ref[rows, lanes].astype(f32)
+            root = lax.rsqrt(_head_mean(of * of) + eps)
+            gate, slope = _gate_and_slope(x, not bias_ref)
+            n = of * root
+            dyn = dy * n
+            dgain = dgain + _eight_apart(dyn * gate)
+            dx = dyn * gain * slope
+            dx_ref[rows, lanes] = dx.astype(dx_ref.dtype)
+            if dbias_ref:
+                dbias_ref[0][:, lanes] += _eight_apart(dx)
+            dn = dy * gate * gain
+            along = _head_mean(dn * n)
+            do_ref[h, rows, :] = (root * (dn - n * along)).astype(do_ref.dtype)
+        return dgain
+
+    dgain_ref[:] = lax.fori_loop(
+        0, o_ref.shape[1] // _NORM_ROWS, step, jnp.zeros((8, o_ref.shape[2]), f32)
+    )
+
+
+class _NormBlocks:
+    """The BlockSpecs over the grid (batch row, block of rows, group of
+    `_NORM_HEADS` heads) of o [b, heads, s, dv] and of [b, s, .] operands in
+    a dtype of `itemsize` bytes (`s` a multiple of `_NORM_ROWS`)."""
+
+    def __init__(self, b: int, heads: int, s: int, dv: int, itemsize: int):
+        group = math.gcd(heads, _NORM_HEADS)
+        groups, width = heads // group, group * dv
+        block = next(
+            n for n in _NORM_BLOCKS
+            if s % n == 0
+            and (n * width * itemsize <= _NORM_BLOCK or n == _NORM_ROWS)
+        )
+        self.grid = (b, s // block, groups)
+        self.heads_first = pl.BlockSpec(
+            (None, group, block, dv), lambda bi, ri, hi: (bi, hi, ri, 0)
+        )
+        self.bias = pl.BlockSpec((1, width), lambda bi, ri, hi: (0, hi))
+        self.gain = pl.BlockSpec((1, dv), lambda bi, ri, hi: (0, 0))
+        # a program's partial sums: the gain's [b, blocks, groups, 8, dv],
+        # the bias's [b, blocks, 8, heads * dv]
+        self.gain_sums = pl.BlockSpec(
+            (None, None, None, 8, dv), lambda bi, ri, hi: (bi, ri, hi, 0, 0)
+        )
+        self.bias_sums = pl.BlockSpec(
+            (None, None, 8, width), lambda bi, ri, hi: (bi, ri, 0, hi)
+        )
+
+        def rows(column_block=0):
+            # the group's columns of a [b, s, .] operand's `column_block`-th
+            # heads * dv columns
+            return pl.BlockSpec(
+                (None, block, width),
+                lambda bi, ri, hi: (bi, ri, column_block * groups + hi),
+            )
+
+        self.rows = rows
+        # the backward's five blocks of rows, twice for the pipeline's two
+        # buffers, and room for the rows of partial sums and the body
+        self.params = pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel"),
+            vmem_limit_bytes=max(
+                12 * block * width * itemsize, 16 * 1024 * 1024
+            ),
+        )
+
+
+def _norm_operands(o, x, bias, gain, first: int):
+    """(the operands as the kernels take them, x's column block, the
+    positions they were padded with): o [b, h, s, dv] and x [b, s, .] with
+    s padded with zeros to whole steps (a zero row adds nothing to a sum),
+    bias and gain as float32 rows. The gate's operand is x's columns from
+    `first` on: read in place where they are a whole column block, copied
+    out otherwise."""
+    f32 = jnp.float32
+    b, heads, s, dv = o.shape
+    width = heads * dv
+    if first % width:
+        x, first = x[..., first:first + width], 0
+    pad = -s % _NORM_ROWS
+    if pad:
+        o = jnp.pad(o, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
+    rows = [] if bias is None else [bias.astype(f32)[None, :]]
+    return [o, x, *rows, gain.astype(f32)[None, :]], first // width, pad
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _norm_forward(o, x, bias, gain, eps, first, interpret):
+    """`head_norm_gate`'s y as a kernel."""
+    b, heads, s, dv = o.shape
+    operands, column_block, pad = _norm_operands(o, x, bias, gain, first)
+    at = _NormBlocks(b, heads, s + pad, dv, o.dtype.itemsize)
+    y = pl.pallas_call(
+        functools.partial(_head_norm_gate_fwd_kernel, eps=eps),
+        grid=at.grid,
+        in_specs=[at.heads_first, at.rows(column_block)]
+        + [at.bias] * (bias is not None) + [at.gain],
+        out_specs=at.rows(),
+        out_shape=jax.ShapeDtypeStruct((b, s + pad, heads * dv), o.dtype),
+        compiler_params=at.params,
+        interpret=interpret,
+        name="head_norm_gate_fwd",
+    )(*operands)
+    return y[:, :s] if pad else y
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6, 7))
+def _norm_backward(o, x, bias, gain, dy, eps, first, interpret):
+    """The cotangents of (o, x, bias, gain) from y's."""
+    f32 = jnp.float32
+    b, heads, s, dv = o.shape
+    width = heads * dv
+    operands, column_block, pad = _norm_operands(o, x, bias, gain, first)
+    at = _NormBlocks(b, heads, s + pad, dv, o.dtype.itemsize)
+    if pad:
+        dy = jnp.pad(dy, ((0, 0), (0, pad), (0, 0)))
+    biased = bias is not None
+    do, dx, dgain, *dbias = pl.pallas_call(
+        functools.partial(_head_norm_gate_bwd_kernel, eps=eps, biased=biased),
+        grid=at.grid,
+        in_specs=[at.heads_first, at.rows(column_block)]
+        + [at.bias] * biased + [at.gain, at.rows()],
+        out_specs=[at.heads_first, at.rows(), at.gain_sums]
+        + [at.bias_sums] * biased,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, heads, s + pad, dv), o.dtype),
+            jax.ShapeDtypeStruct((b, s + pad, width), x.dtype),
+            jax.ShapeDtypeStruct((*at.grid, 8, dv), f32),
+        ] + [jax.ShapeDtypeStruct((*at.grid[:2], 8, width), f32)] * biased,
+        compiler_params=at.params,
+        interpret=interpret,
+        name="head_norm_gate_bwd",
+    )(*operands, dy)
+    if pad:
+        do, dx = do[:, :, :s], dx[:, :s]
+    after = x.shape[-1] - first - width
+    if first or after:  # the columns of x that the gate does not read
+        dx = jnp.pad(dx, ((0, 0), (0, 0), (first, after)))
+    return (
+        do, dx,
+        jnp.sum(dbias[0], axis=(0, 1, 2)).astype(bias.dtype) if biased else None,
+        jnp.sum(dgain, axis=(0, 1, 2, 3)).astype(gain.dtype),
+    )
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def head_norm_gate(o, x, bias, gain, eps: float, first: int = 0):
+    """y [b, s, h * dv]: the rms norm of each head's dv features of o
+    [b, h, s, dv], HEADS FIRST as the recurrence leaves it (dv a multiple of
+    128 lanes; gain [dv], shared by the heads), under a gate of the h * dv
+    columns of x [b, s, .] from `first` on: their silu where `bias` is None,
+    else the sigmoid of them + bias [h * dv]; float32 inside, o's dtype out
+    (`_head_norm_silu`, `_head_norm_gate` on o in the model's layout), as
+    the kernels `head_norm_gate_fwd` and, its WRITTEN backward,
+    `head_norm_gate_bwd` (the section's comment). What the backward keeps is
+    the operands; x's other columns get a zero cotangent."""
+    return _norm_forward(o, x, bias, gain, eps, first, _interpret())
+
+
+def _head_norm_gate_vjp_fwd(o, x, bias, gain, eps, first):
+    return head_norm_gate(o, x, bias, gain, eps, first), (o, x, bias, gain)
+
+
+def _head_norm_gate_vjp_bwd(eps, first, kept, dy):
+    return _norm_backward(*kept, dy, eps, first, _interpret())
+
+
+head_norm_gate.defvjp(_head_norm_gate_vjp_fwd, _head_norm_gate_vjp_bwd)
+
+
+# ---------------------------------------------------------------------------
 # the node
 # ---------------------------------------------------------------------------
 
@@ -1726,7 +2050,8 @@ def _unit(t, scale: float):
 def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
                 b_logit):
     """qkv [b, s, 2*h*dk + h*dv] after the convolution, f_up [b, s, h*dk] the
-    decay's pre-activation, b_logit [b, s, h] -> o [b, s, h*dv]."""
+    decay's pre-activation, b_logit [b, s, h] -> o [b, h, s, dv], heads
+    first as `chunk_scan` leaves it."""
     f32 = jnp.float32
     b, s, _ = qkv.shape
     h, dk, dv, chunk = (
@@ -1764,15 +2089,14 @@ def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
             operands = chunk_operands(q, k, v, g, beta, chunk)
     with jax.named_scope("scan"):
         o = chunk_scan(route, *operands)
-    o = o.reshape(b, h, s + pad, dv)[:, :, :s]
-    return jnp.swapaxes(o, 1, 2).reshape(b, s, h * dv)
+    return o.reshape(b, h, s + pad, dv)[:, :, :s]
 
 
 def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, form: str, qkv,
                            a_pre, dt_bias, a_log, b_logit):
     """`_recurrence` for one log-decay a value head: qkv
     [b, s, 2*hk*dk + hv*dv] after the convolution, a_pre and b_logit
-    [b, s, hv] -> o [b, s, hv*dv]; `form` is `operand_form`'s answer."""
+    [b, s, hv] -> o [b, hv, s, dv]; `form` is `operand_form`'s answer."""
     f32 = jnp.float32
     b, s, _ = qkv.shape
     hv, hk, dk, dv, chunk = (
@@ -1804,8 +2128,7 @@ def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, form: str, qkv,
         )(q, k, v, g, beta, chunk)
     with jax.named_scope("scan"):
         o = chunk_scan(route, *operands)
-    o = o.reshape(b, hv, s + pad, dv)[:, :, :s]
-    return jnp.swapaxes(o, 1, 2).reshape(b, s, hv * dv)
+    return o.reshape(b, hv, s + pad, dv)[:, :, :s]
 
 
 def _head_norm(o, gain, heads: int, eps: float):
@@ -1840,9 +2163,7 @@ def _gated_delta_head_decay(attrs: GatedDeltaAttrs, u, weights):
         policy=_KEEP_INVERSE,
     )(qkv, ba[..., hv:], dt_bias, a_log, ba[..., :hv])
     with jax.named_scope("norm"):
-        y = jax.checkpoint(
-            functools.partial(_head_norm_silu, heads=hv, eps=attrs.norm_eps)
-        )(o, proj[..., cw:], gain)
+        y = _gated_head_norm(attrs, route, o, proj, None, gain, first=cw)
     return y @ w_out
 
 
@@ -1853,6 +2174,32 @@ def _head_norm_gate(o, gate_up, gate_bias, gain, heads: int, eps: float):
     normed = _head_norm(o, gain, heads, eps)
     gate = jax.nn.sigmoid(gate_up.astype(f32) + gate_bias.astype(f32))
     return (normed * gate).astype(o.dtype)
+
+
+def _gated_head_norm(attrs: GatedDeltaAttrs, route: str, o, x, bias, gain,
+                     first: int = 0):
+    """The node's last part before W_out, y [b, s, h * dv]: the heads' norm
+    of the recurrence's o [b, h, s, dv] under the gate of x's h * dv columns
+    from `first` on (their silu where `bias` is None: one decay a head; else
+    the sigmoid of them + bias), in the form the node's `route` says
+    (`scan_route`, nothing else chooses), told to the program's counter
+    (`observability/trace.head_norms`): the kernels (`head_norm_gate`) on
+    "kda", else the plain form on o in the model's layout under a
+    checkpoint of its own."""
+    from flexflow_tpu.observability import trace
+
+    heads, eps = attrs.num_heads, attrs.norm_eps
+    trace.note_head_norm("kernels" if route == "kda" else "xla")
+    if route == "kda":
+        return head_norm_gate(o, x, bias, gain, eps, first)
+    b, _, s, dv = o.shape
+    o = jnp.swapaxes(o, 1, 2).reshape(b, s, heads * dv)
+    x = x[..., first:first + heads * dv]
+    if bias is None:
+        plain = functools.partial(_head_norm_silu, heads=heads, eps=eps)
+        return jax.checkpoint(plain)(o, x, gain)
+    plain = functools.partial(_head_norm_gate, heads=heads, eps=eps)
+    return jax.checkpoint(plain)(o, x, bias, gain)
 
 
 def gated_delta_forward(
@@ -1876,9 +2223,5 @@ def gated_delta_forward(
         policy=_KEEP_INVERSE,
     )(qkv, f_up, dt_bias, a_log, proj[..., cw + 2 * rank:])
     with jax.named_scope("norm"):
-        y = jax.checkpoint(
-            functools.partial(
-                _head_norm_gate, heads=attrs.num_heads, eps=attrs.norm_eps
-            )
-        )(o, g_up, b_g, gain)
+        y = _gated_head_norm(attrs, route, o, g_up, b_g, gain)
     return y @ w_out

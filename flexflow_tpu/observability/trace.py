@@ -584,7 +584,11 @@ NODE_PARTS = {
     # there, and `gates` holds the two rank-128 gate matmuls, beta's sigmoid,
     # v's heads-first copy (dv's back) and the small reductions; on the "xla"
     # route `gates` also holds the two norms, the softplus and the
-    # heads-first copies of q, k and the pre-activation
+    # heads-first copies of q, k and the pre-activation. `norm` is the heads'
+    # norm under its gate: on the "kda" route the kernels
+    # `head_norm_gate_fwd` / `head_norm_gate_bwd` (PR 58; `head_norms()`
+    # says which form a node took), on the "xla" route XLA's fusions of the
+    # plain form under a checkpoint
     "kda": ("scan", "prep", "gates", "conv", "norm"),
     # latent attention (`kernels/ops._latent_mha_forward`): the low-rank
     # key/value projections with their norm, and the attention core
@@ -653,6 +657,7 @@ _ATTENTION_ROUTES: Dict[str, str] = {}
 _LATENT_ATTENTION_FORMS: Dict[str, dict] = {}
 _DELTA_RULE_OPERANDS: Dict[str, str] = {}
 _TRIANGULAR_PRODUCTS: Dict[str, str] = {}
+_HEAD_NORMS: Dict[str, str] = {}
 _GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
 _HELD_ROW_SUMS: Dict[str, Dict[str, dict]] = {}
 
@@ -771,6 +776,26 @@ def triangular_products() -> Dict[str, str]:
     by JAX: the "xla" route, and an odd number of chunk-heads on the "kda"
     route), so that a run that fell back says so itself."""
     return dict(_TRIANGULAR_PRODUCTS)
+
+
+def note_head_norm(form: str) -> None:
+    """The form the gated per-head norm took in the delta-rule node being
+    lowered (`kernels/kda._gated_head_norm`); dropped where no node's scope
+    is open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _HEAD_NORMS[scope] = form
+
+
+def head_norms() -> Dict[str, str]:
+    """`{ff.kda.<name>: form}` of every gated delta-rule node this process
+    has lowered, as it was lowered last, beside `triangular_products()`:
+    `kernels` (the heads' norm under its gate and the whole of its backward
+    from the Pallas kernels `head_norm_gate_fwd` / `head_norm_gate_bwd`) or
+    `xla` (`kernels/kda._head_norm_silu` / `_head_norm_gate`, differentiated
+    by JAX under a checkpoint: the "xla" route), so that a run that fell
+    back says so itself."""
+    return dict(_HEAD_NORMS)
 
 
 def note_grouped_matmul_tiles(entries: Dict[str, dict]) -> None:

@@ -498,6 +498,115 @@ def test_a_step_of_zero_and_a_padded_position_give_zero_rows(monkeypatch):
     assert_triangular_products_agree(got, want, jnp.float32)
 
 
+# The heads' norm under its gate (`kda.head_norm_gate`, PR 58): rows of `heads`
+# heads of 128 lanes, o HEADS FIRST as the recurrence leaves it. Two rows of a
+# hundred positions pad to 128 each, a program of two 64-row steps a batch row;
+# 192 positions of eight heads are three blocks of one step (`_NORM_BLOCKS`: 64
+# rows divide them, 128 do not) times two groups of `_NORM_HEADS` heads, so the
+# partial sums of the gain's and the bias's gradients meet over batch rows,
+# over blocks, over groups, over steps and over heads.
+
+HEAD_NORM_SHAPES = [((2, 100), 2), ((1, 192), 8)]
+HEAD_NORM_IDS = ["two_heads_positions_padded", "eight_heads_six_programs"]
+
+
+def head_norm_case(lead, heads, dtype, biased, seed=41):
+    """((o, x, bias or None, gain), dy): o, x, dy [*lead, heads * 128]."""
+    rs = np.random.RandomState(seed)
+    dv = 128
+    o, x, dy = (rand(rs, *lead, heads * dv).astype(dtype) for _ in range(3))
+    bias = rand(rs, heads * dv, scale=0.3).astype(dtype) if biased else None
+    gain = (1.0 + rand(rs, dv, scale=0.1)).astype(dtype)
+    return (o, x, bias, gain), dy
+
+
+def plain_head_norm(o, x, bias, gain, heads, eps=1e-5):
+    if bias is None:
+        return kda._head_norm_silu(o, x, gain, heads, eps)
+    return kda._head_norm_gate(o, x, bias, gain, heads, eps)
+
+
+def heads_first(o, heads):
+    """o [b, s, heads * dv] -> [b, heads, s, dv]."""
+    b, s, _ = o.shape
+    return jnp.swapaxes(o.reshape(b, s, heads, -1), 1, 2)
+
+
+def kernel_head_norm(o, x, bias, gain, heads, eps=1e-5, first=0):
+    """`kda.head_norm_gate` on o in the model's layout, so that its
+    cotangent comes back in that layout too."""
+    return kda.head_norm_gate(heads_first(o, heads), x, bias, gain, eps, first)
+
+
+def head_norm_with_gradients(norm, operands, dy, heads):
+    """(y, the cotangents of o, x, (bias,) gain) of `norm`."""
+    y, vjp = jax.vjp(lambda *t: norm(*t, heads), *operands)
+    return y, [g for g in vjp(dy) if g is not None]
+
+
+def assert_head_norms_agree(got, want, dtype):
+    """y and every cotangent in the operand's dtype: float32 to its
+    round-off (the kernels add a head's squares and the partial sums in
+    another order: measured 4e-7 of the largest value), bf16 to its last bit
+    (a float32 value at a bf16 tie rounds the other way)."""
+    for g, w in zip([got[0]] + got[1], [want[0]] + want[1]):
+        assert g.shape == w.shape and g.dtype == w.dtype == dtype
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.max(np.abs(w)) > 1e-2  # the operand is reached
+        if dtype == jnp.bfloat16:
+            assert np.all(np.abs(g - w) <= 2.0 ** -7 * np.abs(w) + 1e-6)
+            assert np.mean(g != w) < 1e-2
+        else:
+            np.testing.assert_allclose(
+                g, w, rtol=1e-5, atol=2e-6 * np.max(np.abs(w))
+            )
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("lead,heads", HEAD_NORM_SHAPES, ids=HEAD_NORM_IDS)
+def test_head_norm_kernels_agree_with_the_plain_sigmoid_gated_norm(
+    monkeypatch, lead, heads, dtype
+):
+    """`kda.head_norm_gate` with a bias (interpret mode: `head_norm_gate_fwd`
+    and the WRITTEN backward `head_norm_gate_bwd`) against
+    `_head_norm_gate` and JAX's own gradient of it: y and the cotangents of
+    o, gate_up, gate_bias and the gain."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    operands, dy = head_norm_case(lead, heads, dtype, biased=True)
+    got = head_norm_with_gradients(kernel_head_norm, operands, dy, heads)
+    want = head_norm_with_gradients(plain_head_norm, operands, dy, heads)
+    assert len(got[1]) == 4
+    assert_head_norms_agree(got, want, dtype)
+
+
+@pytest.mark.parametrize("off", ["value_dim_64", "no_flash"])
+def test_the_sigmoid_gated_norm_off_the_route_is_the_plain_form(monkeypatch, off):
+    """Heads of 64 value features and a trace under `no_flash()` leave the
+    node on the "xla" route: `_gated_head_norm` is `_head_norm_gate`, bit for
+    bit, and no kernel is called."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(kda, "head_norm_gate", None)
+    dv = 64 if off == "value_dim_64" else 128
+    attrs = GatedDeltaAttrs(2, 128, dv, 4, 8, 64, 1e-5)
+    rs = np.random.RandomState(43)
+    o, x = rand(rs, 1, 2, 32, dv), rand(rs, 1, 32, 2 * dv)
+    bias, gain = rand(rs, 2 * dv, scale=0.3), 1.0 + rand(rs, dv, scale=0.1)
+
+    def run():
+        route = kda.scan_route(128, dv, 64)
+        return route, kda._gated_head_norm(attrs, route, o, x, bias, gain)
+
+    if off == "no_flash":
+        with flash.no_flash():
+            route, got = run()
+    else:
+        route, got = run()
+    assert route == "xla"
+    in_rows = jnp.swapaxes(o, 1, 2).reshape(1, 32, 2 * dv)
+    want = kda._head_norm_gate(in_rows, x, bias, gain, 2, 1e-5)
+    assert_trees_close(got, want, rtol=0, atol=0)
+
+
 # -- latent attention ------------------------------------------------------------
 
 

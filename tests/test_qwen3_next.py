@@ -15,8 +15,10 @@ import numpy as np
 import pytest
 
 from test_kimi_linear import (
-    assert_triangular_products_agree, triangular_case, triangular_products,
-    xla_corrected,
+    HEAD_NORM_IDS, HEAD_NORM_SHAPES, assert_head_norms_agree,
+    assert_triangular_products_agree, head_norm_case,
+    head_norm_with_gradients, kernel_head_norm, plain_head_norm,
+    triangular_case, triangular_products, xla_corrected,
 )
 from test_nemotron_h import (
     BENCH, F32, F32_LOSS, assert_trees_close, bench, rand,
@@ -285,6 +287,82 @@ def test_triangular_product_kernels_take_the_value_heads_chunks(
         assert_triangular_products_agree(got, want, dtype)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("lead,heads", HEAD_NORM_SHAPES, ids=HEAD_NORM_IDS)
+def test_head_norm_kernels_agree_with_the_plain_silu_gated_norm(
+    monkeypatch, lead, heads, dtype
+):
+    """`kda.head_norm_gate` with no bias (interpret mode:
+    `head_norm_gate_fwd` and the WRITTEN backward `head_norm_gate_bwd`,
+    `tests/test_kimi_linear.py` has the shapes and what is compared) against
+    `_head_norm_silu` and JAX's own gradient of it: y and the cotangents of
+    o, z and the gain."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    operands, dy = head_norm_case(lead, heads, dtype, biased=False, seed=47)
+    got = head_norm_with_gradients(kernel_head_norm, operands, dy, heads)
+    want = head_norm_with_gradients(plain_head_norm, operands, dy, heads)
+    assert len(got[1]) == 3
+    assert_head_norms_agree(got, want, dtype)
+
+
+@pytest.mark.parametrize("off", ["value_dim_64", "no_flash"])
+def test_the_silu_gated_norm_off_the_route_is_the_plain_form(monkeypatch, off):
+    """Value heads of 64 and a trace under `no_flash()` leave the node on
+    the "xla" route: `_gated_head_norm` is `_head_norm_silu`, bit for bit,
+    and no kernel is called."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(kda, "head_norm_gate", None)
+    dv = 64 if off == "value_dim_64" else 128
+    attrs = GatedDeltaAttrs(
+        4, 128, dv, 4, chunk_size=64, norm_eps=1e-6, num_key_heads=2,
+        decay="head",
+    )
+    rs = np.random.RandomState(53)
+    o, z = rand(rs, 1, 4, 32, dv), rand(rs, 1, 32, 4 * dv)
+    gain = 1.0 + rand(rs, dv, scale=0.1)
+
+    def run():
+        route = kda.scan_route(128, dv, 64)
+        return route, kda._gated_head_norm(attrs, route, o, z, None, gain)
+
+    if off == "no_flash":
+        with flash.no_flash():
+            route, got = run()
+    else:
+        route, got = run()
+    assert route == "xla"
+    in_rows = jnp.swapaxes(o, 1, 2).reshape(1, 32, 4 * dv)
+    want = kda._head_norm_silu(in_rows, z, gain, 4, 1e-6)
+    assert_trees_close(got, want, rtol=0, atol=0)
+
+
+@pytest.mark.parametrize(
+    "before", [2, 0.5], ids=["read_in_place", "copied_out"]
+)
+def test_head_norm_kernels_read_z_out_of_a_wider_row(monkeypatch, before):
+    """z as the node has it, the LAST heads * dv columns of the input
+    projection's row: two whole column blocks before it and the kernels read
+    it where it lies (`_NormBlocks.rows(column_block)`), half a block and it
+    is copied out first; either way y and the cotangents of o, z and the gain
+    are those of the plain form on the slice, and the row's other columns get
+    a cotangent of exactly zero."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    heads, dtype = 8, jnp.float32  # two groups of heads a block of rows
+    (o, z, _, gain), dy = head_norm_case((1, 64), heads, dtype, biased=False)
+    first = int(before * z.shape[-1])
+    rs = np.random.RandomState(59)
+    row = jnp.concatenate([rand(rs, 1, 64, first), z], axis=-1)
+
+    def from_the_row(o, row, gain):
+        return kernel_head_norm(o, row, None, gain, heads, first=first)
+
+    y, vjp = jax.vjp(from_the_row, o, row, gain)
+    do, drow, dgain = vjp(dy)
+    assert not np.any(np.asarray(drow[..., :first]))
+    want = head_norm_with_gradients(plain_head_norm, (o, z, None, gain), dy, heads)
+    assert_head_norms_agree((y, [do, drow[..., first:], dgain]), want, dtype)
+
+
 def kernel_sized_node(seq):
     """(attrs, u, weights, a cotangent): four value heads over two key heads
     of 128 | 128 in chunks of 64, hidden size 32."""
@@ -419,6 +497,43 @@ def test_the_triangular_products_form_is_counted_by_node(monkeypatch):
     monkeypatch.setattr(trace._lowering, "scope", None)
     kda._kernel_corrected(*triangular_case((1, 1, 2), jnp.float32)[0])
     assert len(trace.triangular_products()) == 6
+
+
+def test_the_head_norm_form_is_counted_by_node(monkeypatch):
+    """`observability/trace.head_norms()` names the form the heads' norm
+    under its gate took in each delta-rule node: `kernels` on the "kda" route
+    for both gates, `xla` under `no_flash()` and on the plain CPU."""
+    attrs, u, ws, _ = kernel_sized_node(64)
+    channel = GatedDeltaAttrs(2, 128, 128, 4, 8, 64, 1e-5)
+    rs = np.random.RandomState(37)
+    channel_ws = [
+        rand(rs, *s.dims, scale=0.3) for s in channel.weight_shapes(
+            TensorShape(u.shape, DataType.FLOAT)
+        )
+    ]
+    monkeypatch.setattr(trace, "_HEAD_NORMS", {})
+
+    def lowered_as(scope, node=attrs, ws=ws):
+        monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+        jax.eval_shape(lambda u, ws: kda.gated_delta_forward(node, u, ws), u, ws)
+        return trace.head_norms()[scope]
+
+    assert lowered_as("ff.kda.on_the_cpu") == "xla"
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    assert lowered_as("ff.kda.gdn0") == "kernels"
+    assert lowered_as("ff.kda.kda0", channel, channel_ws) == "kernels"
+    with flash.no_flash():
+        assert lowered_as("ff.kda.gdn1") == "xla"
+        assert lowered_as("ff.kda.kda1", channel, channel_ws) == "xla"
+    assert trace.head_norms() == {
+        "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "kernels",
+        "ff.kda.kda0": "kernels", "ff.kda.gdn1": "xla", "ff.kda.kda1": "xla",
+    }
+    # a norm called by itself, under no node's scope, is not counted
+    monkeypatch.setattr(trace._lowering, "scope", None)
+    o = jnp.ones((1, 4, 8, 128), jnp.float32)
+    kda._gated_head_norm(attrs, "xla", o, o.reshape(1, 8, 512), None, ws[5])
+    assert len(trace.head_norms()) == 5
 
 
 # -- the gated grouped-query attention node --------------------------------------

@@ -103,6 +103,13 @@ reads its keys and values, on the unbanded ones. `python
 tests/test_ssm_node_compiles_for_v5e.py phi4flash_step` compiles that cell's
 WHOLE step (12,021,627,392 bytes, 46 s, PR 57).
 
+And what PR 58 gave both delta-rule nodes: the heads' norm under its gate
+as the kernels `head_norm_gate_fwd` (forward once, never rematerialised) and
+`head_norm_gate_bwd` under `<name>/norm`, with no float32 buffer of the
+rows' width between ENTRY instructions there (`_gated_norm_part`; the whole
+steps hold 13,185,440,768 and 11,808,708,096 bytes with them, 13,646,528,000
+and 11,843,911,680 before).
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -259,6 +266,7 @@ KIMI_INVARIANTS = [
     "kda_scores_read_the_models_layout",
     "kda_triangular_product_kernels_compile_under_the_vmem_limit_they_state",
     "wide_key_flash_compiles_forward_and_backward",
+    "kda_gated_norm_is_two_kernels_and_no_float32_of_the_rows_width",
 ]
 
 
@@ -328,6 +336,28 @@ def _corrected_kernels_limit(chunk_heads):
     return "ok" if limit <= V5E_SCOPED_VMEM else f"{limit} bytes stated"
 
 
+def _gated_norm_part(text, elements):
+    """"ok" where the delta-rule node's `norm` part (PR 58) is the kernels
+    `head_norm_gate_fwd` once and `head_norm_gate_bwd` once, nothing
+    rematerialised, and no float32 buffer of `elements` (rows x width) or
+    more lies under it between ENTRY instructions: the roots, the gate and
+    every product stay in VMEM."""
+    kernels = sorted(re.findall(
+        r'custom_call_target="tpu_custom_call".*?op_name="[^"]*/norm/[^"]*?'
+        r"(head_norm_gate_\w+)/pallas_call", text
+    ))
+    wide = [
+        f"{name} f32{list(dims)}"
+        for name, result, opcode, _, line in entry_instructions(text)
+        if opcode not in _NO_BUFFER and "/norm/" in line
+        for dtype, dims in shapes_of(result)
+        if dtype == "f32" and math.prod(dims) >= elements
+    ]
+    if kernels == ["head_norm_gate_bwd", "head_norm_gate_fwd"] and not wide:
+        return "ok"
+    return ", ".join(wide) or f"kernels {kernels}"
+
+
 def check_kimi():
     """{invariant: "ok" or what was found} for the `kimi_linear` cell's two
     new kernel paths at the published shape."""
@@ -363,7 +393,10 @@ def check_kimi():
              ("bwd", "kda", "kda2/prep", "kda_prep_fwd"),
              ("bwd", "kda", "kda2/prep", "kda_corrected_fwd"),
              ("bwd", "kda", "kda2/prep", "kda_prep_bwd"),
-             ("bwd", "kda", "kda2/prep", "kda_corrected_bwd")]
+             ("bwd", "kda", "kda2/prep", "kda_corrected_bwd"),
+             # since PR 58 the heads' norm under its gate, each way once
+             ("fwd", "kda", "kda2/norm", "head_norm_gate_fwd"),
+             ("bwd", "kda", "kda2/norm", "head_norm_gate_bwd")]
         )
         kernels = sorted(c[3] for c in calls)
         found["kda_node_compiles_with_its_kernels"] = (
@@ -405,9 +438,10 @@ def check_kimi():
             else ", ".join(by_head + elsewhere)
         )
         found[KIMI_INVARIANTS[4]] = _corrected_kernels_limit(heads * ROWS // 64)
+        found[KIMI_INVARIANTS[6]] = _gated_norm_part(text, ROWS * heads * 128)
     except Exception as e:  # noqa: BLE001 - the complaint is the result
         complaint = f"{type(e).__name__}: {e}"[:2000]
-        for invariant in KIMI_INVARIANTS[:5]:
+        for invariant in KIMI_INVARIANTS[:5] + KIMI_INVARIANTS[6:]:
             found.setdefault(invariant, complaint)
     on_chip = _described_chip()
     q = on_chip((1, ROWS, heads * 256))
@@ -674,6 +708,7 @@ QWEN3NEXT_INVARIANTS = [
     "head_decay_prep_leaves_no_float32_mask_or_score_tile",
     "head_decay_operand_kernels_compile_under_the_vmem_limit_they_state",
     "triangular_product_kernels_compile_under_the_vmem_limit_they_state",
+    "head_decay_gated_norm_is_two_kernels_and_no_float32_of_the_rows_width",
 ]
 # the chip's default for a kernel's scoped VMEM
 V5E_SCOPED_VMEM = 16 * 1024 * 1024
@@ -758,7 +793,10 @@ def check_qwen3next():
              ("bwd", "kda", "gdn0/prep", "gdn_prep_fwd"),
              ("bwd", "kda", "gdn0/prep", "kda_corrected_fwd"),
              ("bwd", "kda", "gdn0/prep", "gdn_prep_bwd"),
-             ("bwd", "kda", "gdn0/prep", "kda_corrected_bwd")]
+             ("bwd", "kda", "gdn0/prep", "kda_corrected_bwd"),
+             # since PR 58 the heads' norm under its gate, each way once
+             ("fwd", "kda", "gdn0/norm", "head_norm_gate_fwd"),
+             ("bwd", "kda", "gdn0/norm", "head_norm_gate_bwd")]
         )
         found[QWEN3NEXT_INVARIANTS[3]] = "ok" if calls == want else f"{calls}"
         # a float32 [.., 64, 64] buffer under `prep` is a kernel's (A, the
@@ -797,6 +835,9 @@ def check_qwen3next():
         )
         found[QWEN3NEXT_INVARIANTS[6]] = _corrected_kernels_limit(
             attrs.num_heads * QWEN3NEXT_SHAPE[1] // attrs.chunk_size
+        )
+        found[QWEN3NEXT_INVARIANTS[7]] = _gated_norm_part(
+            text, QWEN3NEXT_SHAPE[1] * attrs.num_heads * attrs.value_dim
         )
         per_position = [
             f"{name}: {result[:60]}"
