@@ -166,7 +166,13 @@ reductions; on the "xla" route also the norms of q and k, the softplus and
 the heads-first copies of q, k and the pre-activation. `norm` holds the two
 kernels above and nothing else on the "kda" route, and on the "xla" route o's
 copy to the model's layout with XLA's fusions of the plain form, forward,
-recomputed and backward; `conv` is `kernels/ssm.conv_silu` on both.
+recomputed and backward. `conv` is `kernels/ssm.conv_silu` on both, and it
+chooses its own form (`ssm.conv_route`, from the widths, the sequence and the
+trace; `observability/trace.conv_forms()`): since PR 59 the kernels
+`conv_silu_fwd` / `conv_silu_bwd`, which read the q | k | v columns in place
+out of the input projection's row (the node hands it the row, not a slice),
+wherever the "kda" route runs and the sequence divides into their blocks,
+else its plain form.
 """
 
 from __future__ import annotations
@@ -183,7 +189,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flexflow_tpu.kernels.ssm import conv_silu
+from flexflow_tpu.kernels.ssm import _eight_apart, _interpret, conv_silu
 from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 
 # added under the root of a head's sum of squares before q and k are
@@ -651,12 +657,6 @@ def _chunk_scan_bwd(route, operands, do):
 
 
 chunk_scan.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)
-
-
-def _interpret() -> bool:
-    from flexflow_tpu.kernels import flash_attention as flash
-
-    return flash.interpret_default()
 
 
 # ---------------------------------------------------------------------------
@@ -1794,14 +1794,6 @@ def _gate_and_slope(x, silu: bool):
     return s, s * (1.0 - s)
 
 
-def _eight_apart(t):
-    """[rows, w] float32 -> [8, w]: the rows added eight apart (whole
-    registers added, no sublane leaves its place)."""
-    return functools.reduce(
-        jnp.add, [t[i:i + 8, :] for i in range(0, t.shape[0], 8)]
-    )
-
-
 def _head_mean(t):
     """[rows, dv] -> [rows, 1]: a head's mean over its lanes."""
     return jnp.mean(t, axis=-1, keepdims=True)
@@ -2094,11 +2086,14 @@ def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
 
 def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, form: str, qkv,
                            a_pre, dt_bias, a_log, b_logit):
-    """`_recurrence` for one log-decay a value head: qkv
-    [b, s, 2*hk*dk + hv*dv] after the convolution, a_pre and b_logit
-    [b, s, hv] -> o [b, hv, s, dv]; `form` is `operand_form`'s answer."""
+    """`_recurrence` for one log-decay a value head: qkv, the convolution's
+    result in its three pieces (q and k [b, s, hk*dk], v [b, s, hv*dv]:
+    `conv_silu`'s `pieces`, so that their cotangents go back apart), a_pre
+    and b_logit [b, s, hv] -> o [b, hv, s, dv]; `form` is `operand_form`'s
+    answer."""
     f32 = jnp.float32
-    b, s, _ = qkv.shape
+    q, k, v = qkv
+    b, s, _ = q.shape
     hv, hk, dk, dv, chunk = (
         attrs.num_heads, attrs.key_heads, attrs.key_dim, attrs.value_dim,
         attrs.chunk_size,
@@ -2113,10 +2108,10 @@ def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, form: str, qkv,
         return jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else t
 
     with jax.named_scope("gates"):
-        v = heads_first(qkv[..., 2 * kw:], hv)
+        v = heads_first(v, hv)
         beta = jax.nn.sigmoid(heads_first(b_logit, hv).astype(f32))[..., 0]
-        q = _unit(heads_first(qkv[..., :kw], hk), dk ** -0.5)
-        k = _unit(heads_first(qkv[..., kw:2 * kw], hk), 1.0)
+        q = _unit(heads_first(q, hk), dk ** -0.5)
+        k = _unit(heads_first(k, hk), 1.0)
         g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
             heads_first(a_pre, hv)[..., 0].astype(f32)
             + dt_bias.astype(f32)[:, None]
@@ -2150,10 +2145,10 @@ def _head_norm_silu(o, z, gain, heads: int, eps: float):
 def _gated_delta_head_decay(attrs: GatedDeltaAttrs, u, weights):
     """`gated_delta_forward` for `decay` "head"."""
     w_in, w_ba, w_conv, dt_bias, a_log, gain, w_out = weights
-    cw, hv = attrs.conv_width, attrs.num_heads
+    cw, kw, hv = attrs.conv_width, attrs.key_width, attrs.num_heads
     proj = u @ w_in
     with jax.named_scope("conv"):
-        qkv = conv_silu(proj[..., :cw], w_conv, None)
+        qkv = conv_silu(proj, w_conv, None, pieces=(kw, kw, cw - 2 * kw))
     with jax.named_scope("gates"):
         ba = u @ w_ba
     route = scan_route(attrs.key_dim, attrs.value_dim, attrs.chunk_size)
@@ -2212,7 +2207,7 @@ def gated_delta_forward(
     cw, rank = attrs.conv_width, attrs.gate_rank
     proj = u @ w_in
     with jax.named_scope("conv"):
-        qkv = conv_silu(proj[..., :cw], w_conv, None)
+        qkv = conv_silu(proj, w_conv, None)
     with jax.named_scope("gates"):
         f_up = proj[..., cw:cw + rank] @ w_f
         g_up = proj[..., cw + rank:cw + 2 * rank] @ w_g

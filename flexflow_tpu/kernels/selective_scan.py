@@ -49,10 +49,12 @@ of two forms of it from what the trace can observe:
   and recomputes inside. Float32 throughout.
 
 Both take their operands in the step's dtype and compute in float32; the
-state is float32 in both. The projections, the convolution (`ssm.conv_silu`)
-and the gate stay XLA's around the scan (`selective_scan_forward`), each
-under a scope of its own inside the node's (`in_proj`, `conv`, `scan`,
-`gate`, `out_proj`; `observability/trace.NODE_PARTS`).
+state is float32 in both. The projections and the gate stay XLA's around
+the scan (`selective_scan_forward`); the convolution is `ssm.conv_silu` on
+the x half of W_in's row, read in place, in the form `ssm.conv_route` picks
+(since PR 59 its own two kernels where they apply). Each goes under a scope
+of its own inside the node's (`in_proj`, `conv`, `scan`, `gate`, `out_proj`;
+`observability/trace.NODE_PARTS`).
 """
 
 from __future__ import annotations
@@ -486,9 +488,9 @@ def selective_scan_forward(
     rank = attrs.rank_for(u.shape[-1])
     with jax.named_scope("in_proj"):
         xz = u @ w_in
-        x, z = xz[..., :width], xz[..., width:]
+        z = xz[..., width:]
     with jax.named_scope("conv"):
-        x = conv_silu(x, w_conv, b_conv)
+        x = conv_silu(xz, w_conv, b_conv)
     with jax.named_scope("in_proj"):
         low = x @ w_x
         r = low[..., :rank] @ w_dt

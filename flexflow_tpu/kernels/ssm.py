@@ -55,18 +55,40 @@ the order of the floating-point sums differs between them:
 In neither form does a state per position ever exist.
 
 The two elementwise stages around the scan, `conv_silu` and
-`gated_group_norm`, are plain JAX in one form for every route, each with a
-WRITTEN backward (`jax.custom_vjp`), because what JAX's own transpose keeps
-and writes is the cost (PR 41; a node at [4096, 6144] / [4096, 4096] moved
-3.1 GB through these passes where 0.8 would do). Kept for the backward are
-their operands in the step's dtype (x, the taps and the bias; y, z and the
-gain) and the norm's reciprocal roots, one [rows, 1] column a run: no
-float32 tensor of the row's width, not the pre-activation, not the gate. The
-backwards recompute those in float32 inside the fusion that needs them.
-Float32 accumulation throughout; a rounding to the step's dtype only where
-the forward rounds (after the convolution, after SiLU, after the norm) and,
-in the convolution's backward, once between SiLU's derivative and the
-mirrored convolution, where the transposed casts round too.
+`gated_group_norm`, each carry a WRITTEN backward (`jax.custom_vjp`),
+because what JAX's own transpose keeps and writes is the cost (PR 41; a node
+at [4096, 6144] / [4096, 4096] moved 3.1 GB through these passes where 0.8
+would do). Kept for the backward are their operands in the step's dtype (x,
+the taps and the bias; y, z and the gain) and the norm's reciprocal roots,
+one [rows, 1] column a run: no float32 tensor of the row's width, not the
+pre-activation, not the gate. The backwards recompute those in float32
+where they need them. Float32 accumulation throughout; a rounding to the
+step's dtype only where the forward rounds (after the convolution, after
+SiLU, after the norm) and, in the convolution's backward, once between
+SiLU's derivative and the mirrored convolution, where the transposed casts
+round too.
+
+`gated_group_norm` is plain JAX in one form for every route. `conv_silu`
+has two behind its one `custom_vjp`, and `conv_route` picks one from what the
+trace can observe, for every caller alike (this node, `kernels/kda.py`'s two
+delta-rule nodes, `kernels/selective_scan.py`; told by node in
+`observability/trace.conv_forms()`):
+
+- **The Pallas kernels** (`conv_silu_fwd`, `conv_silu_bwd`; "kernels", PR
+  59): on a TPU, where the trace admits a bare Pallas call, the
+  convolution's first column in the projection's row and its width are whole
+  128-lane tiles and the sequence divides into the kernels' blocks. One pass
+  over the rows each way: a program reads a block of positions by columns IN
+  PLACE out of the projection's row (every caller hands `conv_silu` the row
+  and the first column, not a slice: a kernel's operand must be a buffer,
+  and a slice made for it is a copy), reaches the three positions before it
+  through a halo of one sublane tile, and in the backward recomputes the
+  pre-activation, forms ds = round(dy silu'(a)) in VMEM and writes dx, with
+  the taps' and the bias's sums in one float32 block that stays in VMEM
+  along the rows. ds never reaches HBM.
+- **The plain form** ("xla"): everything else, and what the kernels are
+  tested against. The same arithmetic as XLA's fusions of `_conv_taps` over
+  a slice of the row: two passes forward and back.
 
 The node's parts go under scopes of their own inside the node's
 (`ff.ssm.<name>/scan`, `/conv`, `/norm`; `observability/trace.NODE_PARTS`),
@@ -74,9 +96,10 @@ the backward's as the transpose of each, so a trace reader can tell the
 scan, the convolution and the norm from the projections.
 
 Two `optimization_barrier`s say what XLA's fusion heuristics get wrong here
-(my chip runs, PR 41). The convolution's backward recomputes the
+(my chip runs, PR 41). The convolution's PLAIN backward recomputes the
 pre-activation from x behind one, or XLA shares the forward's and has the
-forward WRITE it for the backward. The norm's result passes one, or XLA
+forward WRITE it for the backward (the kernels need none: a custom call
+shares nothing). The norm's result passes one, or XLA
 recomputes the normalised rows (a sigmoid and the runs' selects an element)
 inside every tile of the two matmuls that read them, the output projection
 and its weight gradient: 1.9 ms a step of `twotower30b_s4096_1chip` in the
@@ -86,6 +109,7 @@ matmuls' rows for 0.5 saved in the norm's.
 from __future__ import annotations
 
 import functools
+import itertools
 from typing import Sequence
 
 import jax
@@ -134,13 +158,20 @@ def _silu_slope(a):
     return a * sig, sig * (1.0 + a * (1.0 - sig))
 
 
-@jax.custom_vjp
-def conv_silu(x, weight, bias):
-    """silu(causal_depthwise_conv(x)): x [b, s, f], weight [taps, f], bias
-    [f] or None (the gated delta-rule mixer's convolutions have none, and
-    none is made for them) -> [b, s, f] in x's dtype. The convolution accumulates in float32
-    and is rounded to x's dtype, SiLU is taken of that in float32 and
-    rounded again: one pass over x, one output.
+def conv_silu(x, weight, bias, first: int = 0, pieces=None):
+    """silu(causal_depthwise_conv(.)) of the `weight.shape[1]` columns of x
+    [b, s, .] from `first` on (every caller's x is a projection's row, and
+    the convolution reads a column slice of it): weight [taps, f], bias [f]
+    or None (the gated delta-rule mixer's convolutions have none, and none
+    is made for them) -> [b, s, f] in x's dtype. The convolution accumulates
+    in float32 and is rounded to x's dtype, SiLU is taken of that in float32
+    and rounded again: one pass over x, one output.
+
+    Two routes behind the one `custom_vjp` (`_conv_silu`), `conv_route`'s
+    choice made here once for the forward and the backward, told to
+    the program's counter (`observability/trace.conv_forms`): the Pallas
+    kernels `conv_silu_fwd` / `conv_silu_bwd` (the section below), which
+    read x's columns in place, and the plain form here, which slices them.
 
     The backward is written because JAX's own keeps the wrong things: the
     transpose of `w[k] * slice(pad(float32(x)))` keeps the padded float32
@@ -151,17 +182,61 @@ def conv_silu(x, weight, bias):
     beside SiLU's derivative, rounds `dy silu'(a)` to x's dtype ONCE (where
     the transposed casts rounded it too), and takes the input's gradient as
     the mirrored convolution of that and the weight's and the bias's as
-    reductions over the same operands."""
-    return _conv_silu_fwd(x, weight, bias)[0]
+    reductions over the same operands. x's other columns get a zero
+    cotangent.
+
+    `pieces`, the widths of the column pieces the caller cuts the result
+    into (the delta-rule node's q | k | v), makes the result the tuple of
+    those pieces, each an output of the `custom_vjp` of its own: their
+    cotangents then come back apart and the backward's kernel reads each
+    where its producer left it, where XLA otherwise writes their `[rows, f]`
+    sum-of-pads for the kernel's sake (a kernel's operand must be a
+    buffer). The kernels' column block divides every piece
+    (`_conv_plan`)."""
+    from flexflow_tpu.observability import trace
+
+    pieces = None if pieces is None else tuple(pieces)
+    width = weight.shape[1]
+    route = conv_route(first, width, x.shape[1], len(weight), pieces)
+    trace.note_conv_form(route)
+    if route == "xla":
+        # the plain form takes its own columns and keeps no more of the row
+        x, first = x[..., first:first + width], 0
+    return _conv_silu(x, weight, bias, first, route, pieces)
 
 
-def _conv_silu_fwd(x, weight, bias):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _conv_silu(x, weight, bias, first, route, pieces):
+    """`conv_silu` on `route`, which the forward and the backward share: a
+    backward traced after a `no_flash()` has closed still takes the route
+    its forward took. On "kernels" x is the projection's row and `first` the
+    convolution's first column in it; on "xla" x is the convolution's own
+    columns."""
+    return _conv_silu_fwd(x, weight, bias, first, route, pieces)[0]
+
+
+def _conv_silu_fwd(x, weight, bias, first, route, pieces):
+    kept = (x, weight, bias)
+    if route == "kernels":
+        ys = _conv_forward(
+            x, weight, bias, first, pieces or weight.shape[1:], _interpret()
+        )
+        return (ys[0] if pieces is None else ys), kept
     a = _conv_taps(x, weight, bias).astype(x.dtype)
-    return jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype), (x, weight, bias)
+    y = jax.nn.silu(a.astype(jnp.float32)).astype(x.dtype)
+    if pieces is None:
+        return y, kept
+    ends = list(itertools.accumulate(pieces))
+    return tuple(y[..., e - n:e] for n, e in zip(pieces, ends)), kept
 
 
-def _conv_silu_bwd(kept, dy):
+def _conv_silu_bwd(first, route, pieces, kept, dy):
     x, weight, bias = kept
+    if route == "kernels":
+        dys = (dy,) if pieces is None else tuple(dy)
+        return _conv_backward(x, weight, bias, dys, first, _interpret())
+    if pieces is not None:
+        dy = jnp.concatenate(dy, axis=-1)
     f32 = jnp.float32
     # the forward computes the same pre-activation: without the barrier XLA
     # shares it, which is the forward WRITING it for the backward to read
@@ -178,7 +253,432 @@ def _conv_silu_bwd(kept, dy):
     return dx, dw, None if bias is None else db.astype(bias.dtype)
 
 
-conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def _interpret() -> bool:
+    from flexflow_tpu.kernels import flash_attention as flash
+
+    return flash.interpret_default()
+
+
+# ---------------------------------------------------------------------------
+# the convolution, the Pallas form: one pass over the rows each way
+# ---------------------------------------------------------------------------
+#
+# A program is one block of `rows` positions by `cols` columns of one batch
+# row, read IN PLACE out of the projection's row (the BlockSpec starts at
+# column block `first / cols`). It walks the block `_CONV_ROWS` positions a
+# step (a block of fewer in one) and a 128-lane tile at a time, float32 in
+# vector registers. A tap's
+# shifted copy of the step's rows is a sublane roll of [8 rows before, the
+# step's rows]: the 8 rows before are the tile the loop read a step ago,
+# and for a block's first step the HALO: a second BlockSpec on the same
+# array for the one sublane tile that ends where the block starts, zeros in
+# the sequence's first block (the convolution is causal a sequence: nothing
+# crosses a batch row).
+#
+# The backward, `_conv_silu_bwd` above in one pass: with a the rounded
+# pre-activation and ds = round(dy silu'(a)),
+#
+#     dx_t = sum_k w[k] ds_{t + (taps - 1) - k}
+#     dw[k] = sum_t ds_t x_{t - (taps - 1) + k}        db = sum_t ds_t
+#
+# dx reads ds of the rows AFTER its own, so the steps go last to first and
+# carry the first 8 rows of the step after; a block's last step starts from
+# the ds of the 8 positions after the block, recomputed from halos of x and
+# dy there (zeros past the sequence's end). ds never reaches HBM. dy may
+# come as the column pieces the caller cut y into (`conv_silu`'s `pieces`):
+# every piece is an operand, a program reads the one its column block lies
+# in, and the others' BlockSpecs stand still meanwhile (an index that does
+# not change is not fetched again). y's pieces are outputs the same way,
+# and a window that stands still is written back once, by the program that
+# filled it: true of ONE core walking the column blocks in order, so with
+# pieces the column axis is "arbitrary" and no chip may split it over cores
+# (`_ConvBlocks`). The taps'
+# and the bias's sums go to ONE float32 [taps (+ 1), 8, cols] block that
+# stays in VMEM along the row axis (the grid's last, sequential), eight
+# sublanes of partial sums a column that XLA adds up after.
+
+_CONV_ROWS = 64  # positions a step at most (whole bf16 sublane tiles)
+_CONV_BLOCKS = (1024, 512, 256, 128, 64, 32)  # positions a program
+_CONV_COLUMNS = (512, 256, 128)  # columns a program
+_HALO = 8  # a float32 sublane tile: the rows a roll can reach back over
+
+
+def _conv_plan(first: int, width: int, seq: int, pieces=None):
+    """(positions, columns) a program, or None where the kernels do not
+    apply: the largest block of `_CONV_BLOCKS` that divides the sequence,
+    the widest of `_CONV_COLUMNS` that divides the convolution's first
+    column in its row, its width and every one of the column `pieces` the
+    caller takes the result in (a piece is whole column blocks)."""
+    rows = next((n for n in _CONV_BLOCKS if seq % n == 0), None)
+    whole = (first, width) + tuple(pieces or ())
+    cols = next(
+        (n for n in _CONV_COLUMNS if all(w % n == 0 for w in whole)), None
+    )
+    return None if rows is None or cols is None else (rows, cols)
+
+
+def conv_route(
+    first: int, width: int, seq: int, taps: int, pieces=None
+) -> str:
+    """Which form `conv_silu` takes, from what the trace can observe:
+
+    - "kernels": `conv_silu_fwd` / `conv_silu_bwd`, where the backend is a
+      TPU (or the CPU with interpret mode opted in, `interpret_default`),
+      the trace admits a bare Pallas call (not under `no_flash()`, no
+      declared `flash_mesh`), the convolution's first column, its width and
+      the `pieces` its result is taken in are whole 128-lane tiles, the
+      sequence divides into the plan's blocks (`_conv_plan`) and the taps
+      reach back over no more than one float32 sublane tile;
+    - "xla": everything else, the plain form."""
+    from flexflow_tpu.kernels import flash_attention as flash
+
+    if _conv_plan(first, width, seq, pieces) is None or taps - 1 > _HALO:
+        return "xla"
+    if flash.current_flash_mesh() is not None:
+        return "xla"
+    if getattr(flash._tls, "disabled", False):
+        return "xla"
+    return "kernels" if flash._backend_ok(flash.interpret_default()) else "xla"
+
+
+def _eight_apart(t):
+    """[rows, w] float32 -> [8, w]: the rows added eight apart (whole
+    registers added, no sublane leaves its place)."""
+    return functools.reduce(
+        jnp.add, [t[i:i + 8, :] for i in range(0, t.shape[0], 8)]
+    )
+
+
+def _reached(window, taps: int, mirrored: bool = False):
+    """The `taps` shifted copies of a step's rows, as `_shifted` orders
+    them, from `window` [8 + rows, 128] float32 (the 8 rows before, then the
+    step's): x_{t - (taps - 1) + k}; `mirrored`, from [rows + 8, 128] (the
+    step's, then the 8 after): x_{t + (taps - 1) - k}."""
+    n = window.shape[0]
+    kept = slice(0, n - _HALO) if mirrored else slice(_HALO, n)
+
+    def shifted(back):
+        if not back:
+            return window
+        return pltpu.roll(window, n - back if mirrored else back, 0)
+
+    return [shifted(taps - 1 - k)[kept] for k in range(taps)]
+
+
+def _taps_sum(w, bias, shifted):
+    """bias + sum_k w[k] shifted[k], in `_conv_taps`'s order."""
+    y = bias
+    for k, x_k in enumerate(shifted):
+        y = w[k:k + 1] * x_k if y is None else y + w[k:k + 1] * x_k
+    return y
+
+
+def _last_rows(t):
+    return t.astype(jnp.float32)[t.shape[0] - _HALO:]
+
+
+def _lane_tiles(cols: int):
+    return [slice(c, c + _LANES) for c in range(0, cols, _LANES)]
+
+
+def _halo_before(before_ref, lanes):
+    """The 8 rows before a block, float32: zeros in a sequence's first."""
+    rows = _last_rows(before_ref[:, lanes])
+    return jnp.where(pl.program_id(2) == 0, jnp.zeros_like(rows), rows)
+
+
+def _step_rows(x_ref) -> int:
+    return min(_CONV_ROWS, x_ref.shape[0])
+
+
+def _window_before(x_ref, halo, i, lanes):
+    """(where step i's rows start, [8 + rows a step, 128] float32: those
+    rows of the block behind the 8 rows before them, `halo` for the block's
+    first step)."""
+    tile = 32 // x_ref.dtype.itemsize  # rows of x's sublane tile
+    step = _step_rows(x_ref)
+    at = pl.multiple_of(i * step, step)
+    before = pl.multiple_of(jnp.maximum(at - tile, 0), tile)
+    prev = _last_rows(x_ref[pl.ds(before, tile), lanes])
+    return at, jnp.concatenate(
+        [
+            jnp.where(i == 0, halo, prev),
+            x_ref[pl.ds(at, step), lanes].astype(jnp.float32),
+        ],
+        axis=0,
+    )
+
+
+def _conv_silu_fwd_kernel(
+    x_ref, before_ref, w_ref, *refs, taps: int, starts: tuple
+):
+    """refs: x [rows, cols], the sublane tile of x before it, the taps
+    [taps, cols] float32, (the bias [1, cols] float32); y's pieces
+    [rows, cols] (`starts`: the column block each begins at), of which a
+    program writes the one its column block lies in."""
+    b_ref, y_refs = refs[:-len(starts)], refs[-len(starts):]
+    f32 = jnp.float32
+    rows = _step_rows(x_ref)
+    tiles = _lane_tiles(x_ref.shape[1])
+    halos = [_halo_before(before_ref, lanes) for lanes in tiles]
+
+    def walk(y_ref):
+        def step(i, _):
+            for lanes, halo in zip(tiles, halos):
+                at, window = _window_before(x_ref, halo, i, lanes)
+                a = _taps_sum(
+                    w_ref[:, lanes], b_ref[0][:, lanes] if b_ref else None,
+                    _reached(window, taps),
+                ).astype(x_ref.dtype)
+                y_ref[pl.ds(at, rows), lanes] = jax.nn.silu(
+                    a.astype(f32)
+                ).astype(y_ref.dtype)
+
+        lax.fori_loop(0, x_ref.shape[0] // rows, step, None)
+
+    if len(y_refs) == 1:
+        return walk(y_refs[0])
+    # one walk a piece, so that no step of the loop holds a branch; the
+    # pieces a program does not write keep what their blocks hold, and
+    # those blocks stand still (`piece` in `_ConvBlocks`)
+    column_block = pl.program_id(1)
+    ends = starts[1:] + (pl.num_programs(1),)
+    for y_ref, start, end in zip(y_refs, starts, ends):
+        pl.when((column_block >= start) & (column_block < end))(
+            functools.partial(walk, y_ref)
+        )
+
+
+def _conv_silu_bwd_kernel(
+    x_ref, before_ref, after_ref, *refs, taps: int, starts: tuple
+):
+    """refs: x [rows, cols] with the sublane tiles of x before and after it,
+    dy's pieces [rows, cols] (`starts`: the column block each begins at),
+    the tile after each, the taps, (the bias); dx [rows, cols], the taps'
+    (and the bias's) partial sums [taps (+ 1), 8, cols] float32, one block
+    for all of a column block's programs."""
+    n = len(starts)
+    dy_refs, dy_after_refs = refs[:n], refs[n:2 * n]
+    w_ref, *b_ref, dx_ref, dw_ref = refs[2 * n:]
+
+    column_block = pl.program_id(1)
+
+    def of_piece(piece_refs, rows, lanes):
+        # the rows of the piece this program's column block lies in
+        out = piece_refs[0][rows, lanes]
+        for ref, start in zip(piece_refs[1:], starts[1:]):
+            out = jnp.where(column_block >= start, ref[rows, lanes], out)
+        return out
+
+    f32 = jnp.float32
+    dtype = x_ref.dtype
+    rows, step_rows = x_ref.shape[0], _step_rows(x_ref)
+    tiles = _lane_tiles(x_ref.shape[1])
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(pl.program_id(2) == 0)
+    def _first_block():
+        dw_ref[:] = jnp.zeros(dw_ref.shape, f32)
+
+    def slopes(window, dy, lanes):
+        """(ds = round(dy silu'(a)) in float32, the taps' copies of x)."""
+        shifted = _reached(window, taps)
+        a = _taps_sum(
+            w_ref[:, lanes], b_ref[0][:, lanes] if b_ref else None, shifted
+        ).astype(dtype).astype(f32)
+        return (dy * _silu_slope(a)[1]).astype(dtype).astype(f32), shifted
+
+    def after(tile_after):  # the 8 rows after the block: zeros past the end
+        rows_after = tile_after.astype(f32)[:_HALO]
+        return jnp.where(last, jnp.zeros_like(rows_after), rows_after)
+
+    halos = [_halo_before(before_ref, lanes) for lanes in tiles]
+    tile = before_ref.shape[0]
+    ds_after = tuple(
+        slopes(
+            jnp.concatenate(
+                [
+                    _last_rows(x_ref[rows - tile:rows, lanes]),
+                    after(after_ref[:, lanes]),
+                ],
+                axis=0,
+            ),
+            after(of_piece(dy_after_refs, slice(None), lanes)), lanes,
+        )[0]
+        for lanes in tiles
+    )
+
+    def step(j, ds_next):
+        i = rows // step_rows - 1 - j
+        heads = []
+        for lanes, halo, ds_n in zip(tiles, halos, ds_next):
+            at, window = _window_before(x_ref, halo, i, lanes)
+            here = pl.ds(at, step_rows)
+            ds, shifted = slopes(
+                window, of_piece(dy_refs, here, lanes).astype(f32), lanes
+            )
+            dx_ref[here, lanes] = _taps_sum(
+                w_ref[:, lanes], None,
+                _reached(jnp.concatenate([ds, ds_n], axis=0), taps, True),
+            ).astype(dx_ref.dtype)
+            for k, x_k in enumerate(shifted):
+                dw_ref[k, :, lanes] += _eight_apart(ds * x_k)
+            if b_ref:
+                dw_ref[taps, :, lanes] += _eight_apart(ds)
+            heads.append(ds[:_HALO])
+        return tuple(heads)
+
+    lax.fori_loop(0, rows // step_rows, step, ds_after)
+
+
+class _ConvBlocks:
+    """The BlockSpecs over the grid (batch row, column block, block of
+    positions) of a convolution of x [b, s, .] from column `first` on, in a
+    dtype of `itemsize` bytes, its result and its cotangent in column pieces
+    of the widths `pieces` (one where the caller cut none)."""
+
+    def __init__(self, b, s, first, pieces, taps, itemsize):
+        width = sum(pieces)
+        rows, cols = _conv_plan(first, width, s, pieces)
+        tile = 32 // itemsize  # rows of the dtype's sublane tile
+        self.grid = (b, width // cols, s // rows)
+        tiles, last = rows // tile, s // tile - 1
+        # the column block each piece begins at, the blocks it holds
+        self.blocks = [n // cols for n in pieces]
+        self.starts = tuple(itertools.accumulate([0] + self.blocks[:-1]))
+
+        def block(first=0):
+            return pl.BlockSpec(
+                (None, rows, cols),
+                lambda bi, ci, ri: (bi, ri, first // cols + ci),
+            )
+
+        def before(first=0):
+            return pl.BlockSpec(
+                (None, tile, cols),
+                lambda bi, ci, ri: (
+                    bi, jnp.maximum(ri * tiles - 1, 0), first // cols + ci
+                ),
+            )
+
+        def after(first=0):
+            return pl.BlockSpec(
+                (None, tile, cols),
+                lambda bi, ci, ri: (
+                    bi, jnp.minimum((ri + 1) * tiles, last), first // cols + ci
+                ),
+            )
+
+        def piece(start, blocks, spec):
+            """`spec` (`block` or `after`) for a piece of y or dy that holds
+            the convolution's column blocks `start .. start + blocks`: its
+            own blocks while the grid is there; before that its first and
+            after that its last, standing still. For a piece of y that
+            relies on an output's window being written back when its index
+            moves or the grid ends, and so on ONE core walking the column
+            blocks in order: with more than one piece the column axis is
+            "arbitrary" (`params`), or a second core would end on windows of
+            pieces it never wrote and write them back."""
+            whole = spec()
+            if len(pieces) == 1:
+                return whole
+            shape, index = whole.block_shape, whole.index_map
+            rows_last = self.grid[2] - 1
+
+            def held(bi, ci, ri):
+                ri = jnp.where(
+                    ci < start, 0, jnp.where(ci >= start + blocks, rows_last, ri)
+                )
+                return index(bi, jnp.clip(ci - start, 0, blocks - 1), ri)
+
+            return pl.BlockSpec(shape, held)
+
+        self.block, self.before, self.after = block, before, after
+        self.pieces = lambda spec: [
+            piece(i, n, spec) for i, n in zip(self.starts, self.blocks)
+        ]
+        self.taps = pl.BlockSpec((taps, cols), lambda bi, ci, ri: (0, ci))
+        self.bias = pl.BlockSpec((1, cols), lambda bi, ci, ri: (0, ci))
+
+        def sums(n):
+            return pl.BlockSpec(
+                (None, n, 8, cols), lambda bi, ci, ri: (bi, 0, 0, ci)
+            )
+
+        self.sums = sums
+        self.params = pltpu.CompilerParams(
+            dimension_semantics=(
+                "parallel",
+                "parallel" if len(pieces) == 1 else "arbitrary",
+                "arbitrary",
+            )
+        )
+
+
+def _conv_rows(weight, bias):
+    """The taps and the bias as the kernels take them: float32 rows."""
+    f32 = jnp.float32
+    return [weight.astype(f32)] + (
+        [] if bias is None else [bias.astype(f32)[None, :]]
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _conv_forward(x, weight, bias, first, pieces, interpret):
+    """`conv_silu`'s y as a kernel, in the column pieces of widths `pieces`,
+    each an output of the kernel."""
+    b, s, _ = x.shape
+    taps = len(weight)
+    at = _ConvBlocks(b, s, first, pieces, taps, x.dtype.itemsize)
+    return tuple(pl.pallas_call(
+        functools.partial(_conv_silu_fwd_kernel, taps=taps, starts=at.starts),
+        grid=at.grid,
+        in_specs=[at.block(first), at.before(first), at.taps]
+        + [at.bias] * (bias is not None),
+        out_specs=at.pieces(at.block),
+        out_shape=[jax.ShapeDtypeStruct((b, s, n), x.dtype) for n in pieces],
+        compiler_params=at.params,
+        interpret=interpret,
+        name="conv_silu_fwd",
+    )(x, x, *_conv_rows(weight, bias)))
+
+
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _conv_backward(x, weight, bias, dys, first, interpret):
+    """The cotangents of (x, weight, bias) from y's, which comes as the
+    tuple of its column pieces (one where the caller cut none)."""
+    b, s, _ = x.shape
+    taps, width = weight.shape
+    biased = bias is not None
+    pieces = tuple(dy.shape[-1] for dy in dys)
+    at = _ConvBlocks(b, s, first, pieces, taps, x.dtype.itemsize)
+    dx, sums = pl.pallas_call(
+        functools.partial(_conv_silu_bwd_kernel, taps=taps, starts=at.starts),
+        grid=at.grid,
+        in_specs=[at.block(first), at.before(first), at.after(first)]
+        + at.pieces(at.block) + at.pieces(at.after)
+        + [at.taps] + [at.bias] * biased,
+        out_specs=[at.block(), at.sums(taps + biased)],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, width), x.dtype),
+            jax.ShapeDtypeStruct((b, taps + biased, 8, width), jnp.float32),
+        ],
+        compiler_params=at.params,
+        interpret=interpret,
+        name="conv_silu_bwd",
+    )(x, x, x, *dys, *dys, *_conv_rows(weight, bias))
+    sums = jnp.sum(sums, axis=(0, 2))
+    # x's other columns get a zero cotangent
+    after = x.shape[-1] - first - width
+    if first or after:
+        dx = jnp.pad(dx, ((0, 0), (0, 0), (first, after)))
+    return (
+        dx, sums[:taps].astype(weight.dtype),
+        sums[taps].astype(bias.dtype) if biased else None,
+    )
 
 
 def _run_of_column(ndim: int, inner: int, groups: int):
@@ -840,10 +1340,9 @@ def state_space_forward(
     inner = attrs.inner
     zxbcdt = u @ w_in
     z = zxbcdt[..., :inner]
-    xbc = zxbcdt[..., inner:inner + attrs.conv_width]
     dt = zxbcdt[..., inner + attrs.conv_width:]
     with jax.named_scope("conv"):
-        xbc = conv_silu(xbc, w_conv, b_conv)
+        xbc = conv_silu(zxbcdt, w_conv, b_conv, inner)
     x = xbc[..., :inner].reshape(b, s, heads, p)
     b_mat = xbc[..., inner:inner + g * n].reshape(b, s, g, n)
     c_mat = xbc[..., inner + g * n:].reshape(b, s, g, n)

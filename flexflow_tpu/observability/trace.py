@@ -658,6 +658,7 @@ _LATENT_ATTENTION_FORMS: Dict[str, dict] = {}
 _DELTA_RULE_OPERANDS: Dict[str, str] = {}
 _TRIANGULAR_PRODUCTS: Dict[str, str] = {}
 _HEAD_NORMS: Dict[str, str] = {}
+_CONV_FORMS: Dict[str, str] = {}
 _GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
 _HELD_ROW_SUMS: Dict[str, Dict[str, dict]] = {}
 
@@ -796,6 +797,26 @@ def head_norms() -> Dict[str, str]:
     by JAX under a checkpoint: the "xla" route), so that a run that fell
     back says so itself."""
     return dict(_HEAD_NORMS)
+
+
+def note_conv_form(form: str) -> None:
+    """The form the causal depthwise convolution with its SiLU took in the
+    node being lowered (`kernels/ssm.conv_silu`); dropped where no node's
+    scope is open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _CONV_FORMS[scope] = form
+
+
+def conv_forms() -> Dict[str, str]:
+    """`{ff.<kind>.<name>: form}` of every node with a `conv_silu` (the
+    state-space, selective-scan and gated delta-rule mixers) this process
+    has lowered, as it was lowered last, beside `head_norms()`: `kernels`
+    (the Pallas kernels `conv_silu_fwd` / `conv_silu_bwd`, the projection's
+    row read in place) or `xla` (the plain form with its written backward:
+    `kernels/ssm.conv_route` says when), so that a run that fell back says
+    so itself."""
+    return dict(_CONV_FORMS)
 
 
 def note_grouped_matmul_tiles(entries: Dict[str, dict]) -> None:

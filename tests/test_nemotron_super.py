@@ -477,12 +477,17 @@ def test_data_parallel_rule_matches_the_latent_form_only():
 # weights behind a barrier where they are renormalised) changed all three
 # programs and wrote its own; PR 41 (the state-space node's convolution with
 # SiLU and its gated norm, each with a written backward) changed the two
-# that hold such a node and left `olmoe` as it was. A PR that means to change
-# them does too.
+# that hold such a node and left `olmoe` as it was; PR 59 (`conv_silu` takes
+# the projection's row and the convolution's first column, and at these toy
+# widths slices its columns out itself before its `custom_vjp`) swapped two
+# neighbouring slices of that row in the same two, x B C's and dt's, and with
+# them the two pads that carry their cotangents back: the same operations on
+# the same operands, nothing more kept. A PR that means to change them does
+# too.
 PINNED_TOY_STEPS = {
     "olmoe": "ba9e83a700b28d5db0dcecc91e1189c8156340b2c7c11118e46c7dd09e8c7c49",
-    "twotower": "3c8acf92a286e3e8edc4883ab7c5117c9274bc82299ee8cff5be9af77206c32b",
-    "super": "3de9c06e6fd74b6f842342d3b90131aaa6fcc7ea048dfa55539e379f74f4b654",
+    "twotower": "4e05c8a14fe554ef83d2c703670ec5a3ed8e7b0b2a5152bb3af8a446cc45a1f9",
+    "super": "39af48dc57d2c45d8f83bad7b27661898bb8b84a34f6570185ef7f445520ae3f",
 }
 
 

@@ -110,6 +110,15 @@ rows' width between ENTRY instructions there (`_gated_norm_part`; the whole
 steps hold 13,185,440,768 and 11,808,708,096 bytes with them, 13,646,528,000
 and 11,843,911,680 before).
 
+And what PR 59 gave every node with a `conv_silu` (the two state-space
+nodes, both delta-rule nodes, the selective-scan node; `_conv_part`): the
+convolution as ONE `conv_silu_fwd` and ONE `conv_silu_bwd` custom call, the
+forward's only `[rows, .]` operand the projection's row itself (no copy, no
+slice and no float32 tensor of x is made for it: the `BlockSpec` starts at
+the convolution's first column), the backward's that row and the cotangent
+in the pieces its producers left, and the two calls' results no more than y, dx and the taps' partial sums
+(ds is no buffer).
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -144,6 +153,7 @@ INVARIANTS = [
     "no_float32_of_the_convolutions_width",
     "no_run_glued_back",
     "at_most_one_float32_rows_by_inner",
+    "convolution_is_two_kernels_on_the_projections_row",
 ]
 _BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
 _SHAPE = re.compile(r"\b(f32|bf16|s32|u32|pred|s8|u8)\[([0-9,]*)\]")
@@ -225,6 +235,53 @@ def _nbytes(result):
     return total
 
 
+def _conv_part(text, rows, width):
+    """"ok" where the node's convolution with SiLU (PR 59) is the kernels
+    `conv_silu_fwd` once and `conv_silu_bwd` once; the forward's operands of
+    `rows` positions are the projection's row alone, wider than the
+    convolution's `width` columns (a `[rows, width]` operand would be a
+    slice or a copy of x made for the kernel), in the step's dtype; the
+    backward's are that row and the cotangent, whole or in the column pieces
+    the caller cut y into (no `[rows, width]` sum of them); and the two
+    calls write y, dx and the taps' partial sums and nothing else of the
+    rows' size (ds, a float32 x)."""
+    entry = entry_instructions(text)
+    result_of = {name: result for name, result, *_ in entry}
+    calls = {}
+    for name, result, opcode, operands, line in entry:
+        kernel = re.search(r"/(conv_silu_\w+)/pallas_call", line)
+        if opcode == "custom-call" and kernel:
+            calls.setdefault(kernel.group(1), []).append((result, operands))
+    if sorted(calls) != ["conv_silu_bwd", "conv_silu_fwd"] or any(
+        len(found) != 1 for found in calls.values()
+    ):
+        return f"kernels { {k: len(v) for k, v in calls.items()} }"
+    complaints = []
+    for kernel, [(result, operands)] in calls.items():
+        big = {
+            o: shape for o in dict.fromkeys(operands)
+            for shape in shapes_of(result_of.get(o, ""))
+            if len(shape[1]) >= 2 and shape[1][-2] == rows
+        }
+        complaints += [
+            f"{kernel} reads {o} {dtype}{list(dims)}"
+            for o, (dtype, dims) in big.items() if dtype == "f32"
+        ]
+        # the row is wider than the convolution; the cotangent's pieces (one
+        # where the caller cut none) are as wide as it together
+        rows_read = [o for o, (_, dims) in big.items() if dims[-1] > width]
+        pieces = sum(dims[-1] for _, dims in big.values() if dims[-1] <= width)
+        if len(rows_read) != 1 or pieces != width * (kernel == "conv_silu_bwd"):
+            complaints.append(
+                f"{kernel} reads { {o: list(d) for o, (_, d) in big.items()} }"
+            )
+    written = sum(_nbytes(result) for [(result, _)] in calls.values())
+    # y and dx in bf16, eight float32 sublanes a tap (and the bias) a column
+    if written > 2 * rows * width * 2 + 8 * 8 * width * 4:
+        complaints.append(f"the two calls write {written} bytes")
+    return ", ".join(complaints) or "ok"
+
+
 def check(name):
     """{invariant: "ok" or what was found} for one cell's node."""
     try:
@@ -256,6 +313,7 @@ def check(name):
         "at_most_one_float32_rows_by_inner": (
             "ok" if len(inner_f32) <= 1 else ", ".join(inner_f32)
         ),
+        INVARIANTS[4]: _conv_part(text, ROWS, attrs.conv_width),
     }
 
 
@@ -267,6 +325,7 @@ KIMI_INVARIANTS = [
     "kda_triangular_product_kernels_compile_under_the_vmem_limit_they_state",
     "wide_key_flash_compiles_forward_and_backward",
     "kda_gated_norm_is_two_kernels_and_no_float32_of_the_rows_width",
+    "kda_convolution_is_two_kernels_on_the_projections_row",
 ]
 
 
@@ -396,7 +455,10 @@ def check_kimi():
              ("bwd", "kda", "kda2/prep", "kda_corrected_bwd"),
              # since PR 58 the heads' norm under its gate, each way once
              ("fwd", "kda", "kda2/norm", "head_norm_gate_fwd"),
-             ("bwd", "kda", "kda2/norm", "head_norm_gate_bwd")]
+             ("bwd", "kda", "kda2/norm", "head_norm_gate_bwd"),
+             # since PR 59 the convolution with SiLU, each way once
+             ("fwd", "kda", "kda2/conv", "conv_silu_fwd"),
+             ("bwd", "kda", "kda2/conv", "conv_silu_bwd")]
         )
         kernels = sorted(c[3] for c in calls)
         found["kda_node_compiles_with_its_kernels"] = (
@@ -439,6 +501,7 @@ def check_kimi():
         )
         found[KIMI_INVARIANTS[4]] = _corrected_kernels_limit(heads * ROWS // 64)
         found[KIMI_INVARIANTS[6]] = _gated_norm_part(text, ROWS * heads * 128)
+        found[KIMI_INVARIANTS[7]] = _conv_part(text, ROWS, 3 * heads * 128)
     except Exception as e:  # noqa: BLE001 - the complaint is the result
         complaint = f"{type(e).__name__}: {e}"[:2000]
         for invariant in KIMI_INVARIANTS[:5] + KIMI_INVARIANTS[6:]:
@@ -709,6 +772,7 @@ QWEN3NEXT_INVARIANTS = [
     "head_decay_operand_kernels_compile_under_the_vmem_limit_they_state",
     "triangular_product_kernels_compile_under_the_vmem_limit_they_state",
     "head_decay_gated_norm_is_two_kernels_and_no_float32_of_the_rows_width",
+    "head_decay_convolution_is_two_kernels_on_the_projections_row",
 ]
 # the chip's default for a kernel's scoped VMEM
 V5E_SCOPED_VMEM = 16 * 1024 * 1024
@@ -796,7 +860,10 @@ def check_qwen3next():
              ("bwd", "kda", "gdn0/prep", "kda_corrected_bwd"),
              # since PR 58 the heads' norm under its gate, each way once
              ("fwd", "kda", "gdn0/norm", "head_norm_gate_fwd"),
-             ("bwd", "kda", "gdn0/norm", "head_norm_gate_bwd")]
+             ("bwd", "kda", "gdn0/norm", "head_norm_gate_bwd"),
+             # since PR 59 the convolution with SiLU, each way once
+             ("fwd", "kda", "gdn0/conv", "conv_silu_fwd"),
+             ("bwd", "kda", "gdn0/conv", "conv_silu_bwd")]
         )
         found[QWEN3NEXT_INVARIANTS[3]] = "ok" if calls == want else f"{calls}"
         # a float32 [.., 64, 64] buffer under `prep` is a kernel's (A, the
@@ -838,6 +905,9 @@ def check_qwen3next():
         )
         found[QWEN3NEXT_INVARIANTS[7]] = _gated_norm_part(
             text, QWEN3NEXT_SHAPE[1] * attrs.num_heads * attrs.value_dim
+        )
+        found[QWEN3NEXT_INVARIANTS[8]] = _conv_part(
+            text, QWEN3NEXT_SHAPE[1], attrs.conv_width
         )
         per_position = [
             f"{name}: {result[:60]}"
@@ -972,6 +1042,7 @@ PHI4FLASH_INVARIANTS = [
     "scan_node_compiles_with_its_two_kernels_and_no_state_a_position",
     "window_node_compiles_on_the_banded_kernels",
     "full_and_cross_nodes_compile_on_the_causal_kernels",
+    "scan_nodes_convolution_is_two_kernels_on_the_projections_row",
 ]
 PHI4FLASH_SHAPE = (1, 4096, 2560)
 
@@ -1030,8 +1101,10 @@ def check_phi4flash():
             "ok" if sorted(set(names)) == want and not states
             else f"kernels {names}, want {want}; states {states}"
         )
+        found[PHI4FLASH_INVARIANTS[3]] = _conv_part(text, rows, 5120)
     except Exception as e:  # noqa: BLE001 - the complaint is the result
-        found[PHI4FLASH_INVARIANTS[0]] = f"{type(e).__name__}: {e}"[:2000]
+        for invariant in (PHI4FLASH_INVARIANTS[0], PHI4FLASH_INVARIANTS[3]):
+            found.setdefault(invariant, f"{type(e).__name__}: {e}"[:2000])
 
     def differential(kind):
         return RingAttentionAttrs(
