@@ -385,7 +385,10 @@ def sequence_parallel_plan(k: int, flavor: str = "ring") -> PlanFn:
         if t == OperatorType.MULTIHEAD_ATTENTION:
             if getattr(attrs, "bias", False):
                 return None
-            if attrs.qk_norm or attrs.rope_theta is not None:
+            if (
+                attrs.qk_norm or attrs.rope_theta is not None
+                or attrs.window is not None
+            ):
                 return None  # RingAttentionAttrs' shape rule (ROADMAP R7)
             if flavor == "a2a" and attrs.num_heads % k:
                 return None

@@ -54,7 +54,8 @@ The node's parts go under scopes of their own inside the node's
 from __future__ import annotations
 
 import functools
-from typing import List, Sequence
+import math
+from typing import List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -869,12 +870,18 @@ def _window_rows_bwd(k, kept, g):
 _window_rows.defvjp(_window_rows_fwd, _window_rows_bwd)
 
 
-def held_window_rows(decisions: int, held: int, experts: int) -> int:
+def held_window_rows(
+    decisions: int, held: int, experts: int, factor: Optional[float] = None
+) -> int:
     """Rows one pass of `_held_rows_forward` takes: a quarter more than a
-    uniform router sends the held experts, in whole 128-row tiles, and never
-    more than there are decisions."""
+    uniform router sends the held experts (`factor` times what it sends them
+    where the attrs give a `held_window_factor`), in whole 128-row tiles, and
+    never more than there are decisions."""
     expected = -(-decisions * held // experts)
-    return min(decisions, max(128, -(-(expected + expected // 4) // 128) * 128))
+    rows = expected + expected // 4 if factor is None else math.ceil(
+        expected * factor
+    )
+    return min(decisions, max(128, -(-rows // 128) * 128))
 
 
 def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool, forms):
@@ -888,7 +895,9 @@ def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool, forms):
     else None."""
     held, (n, decisions) = counts.shape[0], (x2.shape[0], order.shape[0])
     k = decisions // n
-    window = held_window_rows(decisions, held, attrs.num_experts)
+    window = held_window_rows(
+        decisions, held, attrs.num_experts, attrs.held_window_factor
+    )
 
     def grouped(rows, w):
         # `held` matrices for held + 1 sizes: the last is the window's rest
@@ -1008,7 +1017,9 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     first, held = share
     n, k = topv.shape
     decisions = n * k
-    window = held_window_rows(decisions, held, attrs.num_experts)
+    window = held_window_rows(
+        decisions, held, attrs.num_experts, attrs.held_window_factor
+    )
     local = flat_e - first
     key = jnp.where((local >= 0) & (local < held), local, held)
     order = jnp.argsort(key, stable=True)  # the share's decisions first

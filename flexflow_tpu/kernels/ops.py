@@ -223,15 +223,46 @@ def rms_norm(x, gain, eps, zero_centered: bool = False):
     return (x32 * scale * gain.astype(jnp.float32)).astype(x.dtype)
 
 
-def _rope_leading_columns(x, num_heads: int, theta: float, width: int):
+def rope_frequencies(width: int, theta: float, scaling=None):
+    """(inv_freq [width / 2] in float32, amplitude) of a rotary `width`
+    columns wide: THE place a rotary's frequencies come from (`rope_bshf`,
+    `_rope_leading_columns`, `rope_tables`). Without `scaling` pair j turns
+    at theta^(-2j / width) and the amplitude is None (no product is
+    emitted: the arithmetic of every node from before there was a scaling,
+    bit for bit). With a `YarnScaling` the pairs from its `high` on turn
+    `factor` times slower, those below its `low` as they did, a line
+    between, and cosine and sine are to be multiplied by its amplitude
+    (`op_attrs/ops/attention.YarnScaling` has the formula). The ramp is a
+    constant of the node; the frequencies are float32, outside any kernel."""
+    half = width // 2
+    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / width)
+    if scaling is None:
+        return inv_freq, None
+    import numpy as np
+
+    low, high = scaling.correction_range(theta, width)
+    if low == high:
+        high += 0.001  # the public code's guard against a ramp of no width
+    ramp = jnp.asarray(
+        np.clip((np.arange(half, dtype=np.float32) - low) / (high - low), 0, 1),
+        jnp.float32,
+    )
+    inv_freq = inv_freq * (1.0 - ramp) + (inv_freq / scaling.factor) * ramp
+    return inv_freq, scaling.amplitude
+
+
+def _rope_leading_columns(x, num_heads: int, theta: float, width: int,
+                          scaling=None):
     """`rope_bshf` on the first `width` columns of each head alone (pairs
     (j, j + width / 2), angle pos * theta^(-2j / width)); the head's other
     columns pass."""
     b, s, f = x.shape
     half = width // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / width)
+    inv_freq, amplitude = rope_frequencies(width, theta, scaling)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    if amplitude is not None:
+        cos, sin = cos * amplitude, sin * amplitude
     heads = x.reshape(b, s, num_heads, f // num_heads)
     lo = heads[..., :half].astype(jnp.float32)
     hi = heads[..., half:width].astype(jnp.float32)
@@ -243,7 +274,7 @@ def _rope_leading_columns(x, num_heads: int, theta: float, width: int):
     ).reshape(b, s, f)
 
 
-def rope_bshf(x, num_heads: int, theta: float, rotary_dim=None):
+def rope_bshf(x, num_heads: int, theta: float, rotary_dim=None, scaling=None):
     """Rotary position embedding on x [b, s, h*d], positions 0..s-1, each
     d-lane head block rotated by itself with the rotate-half pairing
     (i, i + d/2) and angle pos * theta^(-2j/d). Written on the fused row so
@@ -251,17 +282,27 @@ def rope_bshf(x, num_heads: int, theta: float, rotary_dim=None):
     x rolled down by d/2 lanes in the lower half and up in the upper half,
     and a roll of the whole row agrees with the roll of a block wherever
     that half reads from its own block. `rotary_dim` narrower than the head
-    turns the head's first columns only (`_rope_leading_columns`)."""
+    turns the head's first columns only (`_rope_leading_columns`). `scaling`
+    (`MultiHeadAttentionAttrs.rope_scaling`) changes the frequencies and
+    multiplies cosine and sine by its amplitude (`rope_frequencies`)."""
     b, s, f = x.shape
     d = f // num_heads
     if rotary_dim is not None and rotary_dim != d:
-        return _rope_leading_columns(x, num_heads, theta, rotary_dim)
+        return _rope_leading_columns(x, num_heads, theta, rotary_dim, scaling)
     half = d // 2
     assert d % 2 == 0, f"rotary embedding needs an even head size, got {d}"
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / d)
+    inv_freq, amplitude = rope_frequencies(d, theta, scaling)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    cos = jnp.tile(jnp.cos(angle), (1, 2 * num_heads))  # [s, f]
+    # the order in which these are emitted is part of every rotary cell's
+    # lowered program (`sha256_without_locations`): cosine and its tile,
+    # then sine
+    cos = jnp.cos(angle)
+    if amplitude is not None:
+        cos = cos * amplitude
+    cos = jnp.tile(cos, (1, 2 * num_heads))  # [s, f]
     sin = jnp.sin(angle)
+    if amplitude is not None:
+        sin = sin * amplitude
     sin = jnp.tile(jnp.concatenate([-sin, sin], axis=-1), (1, num_heads))
     lower = (jnp.arange(f) % d) < half
     x32 = x.astype(jnp.float32)
@@ -307,8 +348,14 @@ def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains,
         qp = rms_norm(qp, qk_gains[0], attrs.qk_norm_eps, zc)
         kp = rms_norm(kp, qk_gains[1], attrs.qk_norm_eps, zc)
     if attrs.rope_theta is not None:
-        qp = rope_bshf(qp, attrs.num_heads, attrs.rope_theta, attrs.rotary_dim)
-        kp = rope_bshf(kp, attrs.kv_heads, attrs.rope_theta, attrs.rotary_dim)
+        qp = rope_bshf(
+            qp, attrs.num_heads, attrs.rope_theta, attrs.rotary_dim,
+            attrs.rope_scaling,
+        )
+        kp = rope_bshf(
+            kp, attrs.kv_heads, attrs.rope_theta, attrs.rotary_dim,
+            attrs.rope_scaling,
+        )
     if repeat and attrs.num_kv_heads not in (None, attrs.num_heads):
         H, KV = attrs.num_heads, attrs.num_kv_heads
 
@@ -450,6 +497,21 @@ def mha_core_route(
             and flash_core_supported(padded, padded, padded, "lane")
         )
         return "fused_row" if kernel else "dense"
+    if _banded(attrs, s):
+        # a band lives in the causal tile schedule alone (heads of whole
+        # lane tiles, or of 64 padded to them, over more than one tile) or
+        # is a mask on XLA's attention: the head-pair kernels, the fused qkv
+        # row and the [b, h, s, d] kernels have none, and a windowed node is
+        # never sent to them
+        lanes = (b, H, s, max(kd, 128))
+        plan = _causal_plan_of(attrs, s)
+        kernel = (
+            heads_whole and s == t and kd == vd
+            and plan is not None and plan.supported
+            and (kd % 128 == 0 or mha_pads_heads(attrs, s))
+            and flash_core_supported(lanes, lanes, lanes, "lane")
+        )
+        return "fused_row" if kernel else "dense"
     # kd % 128: blocks carved from the fused h*d minor dim must be
     # lane-aligned (Pallas requires block minor dims divisible by 128 unless
     # equal to the array dim). d=64 (the reference heads=16 config) rides
@@ -477,17 +539,52 @@ def mha_core_route(
     return "dense"
 
 
+def _banded(attrs: MultiHeadAttentionAttrs, s: int) -> bool:
+    """Whether the node's window hides any key of `s` positions from a
+    query that the causal mask shows it: a window of `s` or more is none."""
+    return attrs.window is not None and attrs.window < s
+
+
 def _note_route(route: str, attrs: MultiHeadAttentionAttrs = None) -> None:
     """Tell the program's counter which core the attention node being
-    lowered took (`observability/trace.attention_routes`), and of a
-    differential node that it is one and its window."""
+    lowered took (`observability/trace.attention_routes`), of a
+    differential node that it is one, and of any node its window."""
     from flexflow_tpu.observability import trace
 
-    if attrs is not None and attrs.differential:
-        route += " differential"
+    if attrs is not None:
+        if attrs.differential:
+            route += " differential"
         if attrs.window is not None:
             route += f" window={attrs.window}"
     trace.note_attention_route(route)
+
+
+def _note_rotary(attrs: MultiHeadAttentionAttrs) -> None:
+    """Tell the program's counter the rotary of the plain attention node
+    being lowered, where it has one (`observability/trace.rotaries`)."""
+    from flexflow_tpu.observability import trace
+
+    if attrs.rope_theta is None:
+        return
+    width = attrs.rotary_dim or attrs.q_proj_size
+    trace.note_rotary(
+        f"default theta={attrs.rope_theta:g}" if attrs.rope_scaling is None
+        else attrs.rope_scaling.describe(attrs.rope_theta, width)
+    )
+
+
+def _note_window_tiles(plan, s: int) -> None:
+    """Tell the program's counter what the band of the plan being lowered
+    skips (`observability/trace.window_tiles`): the tiles its forward
+    visits against those the causal schedule would."""
+    from flexflow_tpu.kernels.flash_attention import causal_tile_schedule
+    from flexflow_tpu.observability import trace
+
+    if plan is not None and plan.window is not None:
+        trace.note_window_tiles(
+            causal_tile_schedule(s, plan.block_q, plan.block_k, plan.window)[0],
+            causal_tile_schedule(s, plan.block_q, plan.block_k)[0],
+        )
 
 
 def _differential_forward(attrs: MultiHeadAttentionAttrs, q, k, v, weights):
@@ -503,12 +600,10 @@ def _differential_forward(attrs: MultiHeadAttentionAttrs, q, k, v, weights):
     subtraction under lambda, the sub-norm a head, its scale) and
     `out_proj`."""
     from flexflow_tpu.kernels.flash_attention import (
-        causal_tile_schedule,
         flash_attention_bshf,
         per_batch_shard,
         wide_key_padded,
     )
-    from flexflow_tpu.observability import trace
 
     assert getattr(attrs, "causal", False), (
         "differential attention is lowered under a causal mask only"
@@ -551,14 +646,7 @@ def _differential_forward(attrs: MultiHeadAttentionAttrs, q, k, v, weights):
             (b, t, KV // 2, share, 2, 2 * vd),
         ).reshape(b, t, heads, 2, 2 * vd)
         if route == "fused_row":
-            plan = _causal_plan_of(attrs, s, q.dtype.itemsize)
-            if plan.window is not None:
-                trace.note_window_tiles(
-                    causal_tile_schedule(
-                        s, plan.block_q, plan.block_k, plan.window
-                    )[0],
-                    causal_tile_schedule(s, plan.block_q, plan.block_k)[0],
-                )
+            _note_window_tiles(_causal_plan_of(attrs, s, q.dtype.itemsize), s)
             pad = ((0, 0),) * 4 + ((0, wide_key_padded(kd) - kd),)
             ctx = per_batch_shard(
                 flash_attention_bshf,
@@ -643,8 +731,7 @@ def rope_tables(s: int, width: int, theta: float):
     pos * theta^(-2j / width), positions 0..s-1: tables of their own (behind
     a barrier), so that a pass over rows a head and position wide reads them
     and does not take a cosine an element."""
-    half = width // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / width)
+    inv_freq, _ = rope_frequencies(width, theta)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
     return lax.optimization_barrier((jnp.cos(angle), jnp.sin(angle)))
 
@@ -858,7 +945,18 @@ def _mha_forward(
     # projections; a node without them takes the paths it always took
     post = mha_row_projections(attrs)
     route = mha_core_route(attrs, q.shape, k.shape, v.shape, q is k and k is v)
-    _note_route(route)
+    _note_route(route, attrs)
+    _note_rotary(attrs)
+    banded = _banded(attrs, q.shape[1])
+    if banded and (not causal or route not in ("fused_row", "dense")):
+        # `mha_core_route` sends no windowed node here; a caller that forces
+        # one gets an error and not a silent full attention
+        raise ValueError(
+            f"a window of {attrs.window} keys is honoured under a causal mask "
+            'on the "fused_row" route (a band in the causal tile schedule) '
+            'and on the "dense" one (a mask); this node has '
+            f"causal={causal} and took {route!r}, which has no band"
+        )
     if route == "fused_row_qkv":
         # self-attention on the head-pair path: ONE fused projection matmul
         # into the interleaved [q_pair|k_pair|v_pair] layout; flash reads
@@ -882,6 +980,8 @@ def _mha_forward(
         plan = _causal_plan_of(attrs, s, q.dtype.itemsize)
         # query heads that read one key/value head where it lies
         group = 1 if plan is None else plan.group
+        if banded:
+            _note_window_tiles(plan, s)
         if post:
             with _rows_scope(attrs):
                 qp, kp, vp = mha_between(
@@ -896,6 +996,7 @@ def _mha_forward(
             ctx = per_batch_shard(
                 flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal,
                 num_kv_heads=H // group, scale=kd ** -0.5 if pads else None,
+                window=attrs.window,
             )
             if pads:
                 ctx = _own_columns(ctx, kd)
@@ -941,6 +1042,10 @@ def _mha_forward(
     if causal:
         s, t = scores.shape[-2], scores.shape[-1]
         mask = jnp.arange(s)[:, None] >= jnp.arange(t)[None, :]
+        if banded:  # the band as a mask
+            mask = mask & (
+                jnp.arange(s)[:, None] - jnp.arange(t)[None, :] < attrs.window
+            )
         scores = jnp.where(mask, scores, jnp.asarray(-1e30, scores.dtype))
     attn = jax.nn.softmax(scores, axis=-1)
     ctx = jnp.einsum("bhst,bhtv->bhsv", attn, vp)

@@ -713,10 +713,34 @@ def attention_routes() -> Dict[str, str]:
     lowered on one device or in the global view, as it was lowered last: a
     program counter a reader (the benchmark's `gqa64_flash_roofline`) prints
     beside what it measures, so that a change of route says so itself. A
-    differential node's route is followed by ` differential` and, where it
-    has one, ` window=<keys>` (`fused_row differential window=512`); what
-    its band skips on the kernels is `window_tiles()`."""
+    differential node's route is followed by ` differential` and any node's,
+    where it has one, by ` window=<keys>` (`fused_row differential
+    window=512`, `fused_row window=1024`); what its band skips on the
+    kernels is `window_tiles()`."""
     return dict(_ATTENTION_ROUTES)
+
+
+_ROTARIES: Dict[str, str] = {}
+
+
+def note_rotary(kind: str) -> None:
+    """The rotary of the plain attention node being lowered
+    (`kernels/ops._note_route`); dropped where no node's scope is open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _ROTARIES[scope] = kind
+
+
+def rotaries() -> Dict[str, str]:
+    """`{ff.<kind>.<name>: rotary}` of every plain attention node with a
+    rotary that this process has lowered, as it was lowered last:
+    `default theta=500000`, or with a `YarnScaling`
+    `yarn factor=16 low=18 high=35 amp=1.2773` (the first pair the ramp
+    touches, the first it leaves `factor` times slower, the amplitude on
+    cosine and sine). A program counter the benchmark's `mellum2_*_flash_
+    roofline` readers print beside what they measure, so that two layers of
+    one graph that turn differently say so themselves."""
+    return dict(_ROTARIES)
 
 
 def note_latent_attention_form(form: dict) -> None:

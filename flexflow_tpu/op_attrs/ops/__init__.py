@@ -35,7 +35,10 @@ from flexflow_tpu.op_attrs.ops.norm_ops import (
     SoftmaxAttrs,
     DropoutAttrs,
 )
-from flexflow_tpu.op_attrs.ops.attention import MultiHeadAttentionAttrs
+from flexflow_tpu.op_attrs.ops.attention import (
+    MultiHeadAttentionAttrs,
+    YarnScaling,
+)
 from flexflow_tpu.op_attrs.ops.ring_attention import RingAttentionAttrs
 from flexflow_tpu.op_attrs.ops.ulysses_attention import UlyssesAttentionAttrs
 from flexflow_tpu.op_attrs.ops.shape_ops import (

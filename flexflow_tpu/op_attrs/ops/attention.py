@@ -14,6 +14,7 @@ the MHA op and adds sequence parallelism as a separate RingAttention op
 
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass
 from typing import Optional
 
@@ -23,6 +24,60 @@ from flexflow_tpu.op_attrs.parallel_tensor_shape import (
     get_reduced_shape,
     lift_to_parallel_with_degrees,
 )
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN (arXiv:2309.00071) as `rope_type` `yarn` is computed in the
+    public `transformers` code, under its published keys. With f_j =
+    theta^(-2j / width) and c(r) = width * ln(original_max_position_embeddings
+    / (2 pi r)) / (2 ln theta), `low` = floor(c(beta_fast)) and `high` =
+    ceil(c(beta_slow)), clipped to [0, width - 1]: pair j turns at
+    f_j * (1 - ramp_j) + (f_j / factor) * ramp_j, ramp_j =
+    clip((j - low) / (high - low), 0, 1): the fast pairs below `low` as they
+    did, the slow ones from `high` on `factor` times slower, a line between.
+    Cosine and sine are both multiplied by `attention_factor` (None:
+    0.1 ln(factor) + 1), on q and on k, so the scores carry its square.
+    ONE frozen value on the node and not five fields: the five mean nothing
+    apart, rules match its absence (`rope_scaling=None`) in one place, and
+    another scaling is another value's class."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+
+    def __post_init__(self):
+        assert self.factor >= 1.0 and self.beta_fast >= self.beta_slow > 0, self
+        assert self.original_max_position_embeddings > 0, self
+
+    @property
+    def amplitude(self) -> float:
+        if self.attention_factor is not None:
+            return float(self.attention_factor)
+        return 0.1 * math.log(self.factor) + 1.0
+
+    def correction_range(self, theta: float, width: int):
+        """(low, high): the first pair the ramp touches and the first it
+        leaves `factor` times slower, for a rotary `width` columns wide."""
+
+        def c(rotations):
+            return width * math.log(
+                self.original_max_position_embeddings
+                / (rotations * 2.0 * math.pi)
+            ) / (2.0 * math.log(theta))
+
+        low = max(math.floor(c(self.beta_fast)), 0)
+        high = min(math.ceil(c(self.beta_slow)), width - 1)
+        return low, high
+
+    def describe(self, theta: float, width: int) -> str:
+        low, high = self.correction_range(theta, width)
+        return (
+            f"yarn factor={self.factor:g} low={low} high={high} "
+            f"amp={self.amplitude:.4f}"
+        )
 
 
 @dataclass(frozen=True)
@@ -131,7 +186,10 @@ class MultiHeadAttentionAttrs:
     # window: with a causal mask, query t sees keys t - window + 1 .. t and
     # no others (a sliding window). None: every key before it. A route that
     # cannot honour it raises (`kernels/ops`): there is no silent full
-    # attention. Differential nodes only, so far.
+    # attention. Differential nodes and plain causal ones, grouped or equal
+    # heads (the causal tile schedule's band on "fused_row", a mask on
+    # "dense"); latent nodes are refused here, a sequence shard by
+    # `RingAttentionAttrs`' shape rule.
     window: Optional[int] = None
     # kv_outputs: the node's projected keys and values, as projected (bias
     # included, before any padding or repeat), are its SECOND and THIRD
@@ -141,6 +199,12 @@ class MultiHeadAttentionAttrs:
     # [wq | wo, 1] and its input bias [h * kdim]. Both differential only.
     kv_outputs: bool = False
     external_kv: bool = False
+    # rope_scaling: with `rope_theta`, how the rotary's frequencies and
+    # amplitude depart from theta^(-2j / width) and 1 (`YarnScaling`). A
+    # field of the NODE, so that two layers of one graph turn differently.
+    # None: the rotary as it always was. Plain nodes only (a latent node's
+    # rotary on its shared slice takes none yet).
+    rope_scaling: Optional[YarnScaling] = None
 
     def __post_init__(self):
         assert self.rotary_dim is None or (
@@ -219,11 +283,20 @@ class MultiHeadAttentionAttrs:
                 "a node hands its own keys and values on, or reads another's"
             )
         else:
-            assert (
-                self.window is None and not self.kv_outputs
-                and not self.external_kv
-            ), "window, kv_outputs and external_kv are differential nodes'"
+            assert not self.kv_outputs and not self.external_kv, (
+                "kv_outputs and external_kv are differential nodes'"
+            )
         assert self.window is None or self.window >= 1, self.window
+        assert self.window is None or not self.latent, (
+            "a window on latent attention is not lowered yet: its wide-key "
+            "kernels and its dense form have no band"
+        )
+        assert self.rope_scaling is None or (
+            self.rope_theta is not None and not self.latent
+        ), (
+            "rope_scaling says how rope_theta's frequencies are scaled on a "
+            "plain node: it needs one, and latent attention takes none yet"
+        )
 
     @property
     def grouped_query(self) -> bool:

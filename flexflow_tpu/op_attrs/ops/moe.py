@@ -170,6 +170,12 @@ class ExpertsAttrs:
     them, and what the absent experts would add is left out (the share of a
     layer that expert parallelism gives one chip, without the exchange).
     The shared expert is whole in every share.
+    held_window_factor: None, or f >= 1: a held share takes its rows in
+    passes of f times what a uniform router would send it, the shard's
+    static row buffer (None: a quarter over, `kernels.moe.held_window_rows`).
+    Nothing is dropped either way: rows past the first pass take further
+    ones, each at a whole pass's cost, so f says how far a router may lean
+    towards the share before a step costs a pass more. It changes no value.
     latent_size: None, or L: the routed experts live in a latent space of
     that width. Two more weights, `w_down` [D, L] in front of the dispatch
     and `w_up` [L, out] after the combine (slot order: gate[, selection
@@ -214,6 +220,7 @@ class ExpertsAttrs:
     held_experts: Optional[Tuple[int, int]] = None
     latent_size: Optional[int] = None
     shared_gate: bool = False
+    held_window_factor: Optional[float] = None
 
     def __post_init__(self):
         assert not self.shared_gate or self.shared_hidden_size, (
@@ -237,6 +244,9 @@ class ExpertsAttrs:
             assert self.capacity_factor is None and not self.use_bias, (
                 "a held share of the experts is dropless and has no biases"
             )
+        assert self.held_window_factor is None or (
+            self.held_experts is not None and self.held_window_factor >= 1
+        ), "held_window_factor sizes a held share's passes: f >= 1 of one"
         assert self.latent_size is None or self.latent_size > 0, self.latent_size
 
     @property

@@ -73,6 +73,11 @@ class RingAttentionAttrs(MultiHeadAttentionAttrs):
         assert seq_degree == 1 or not (self.grouped_query or self.latent), (
             "grouped-query and latent attention cannot be sequence-parallel yet"
         )
+        assert seq_degree == 1 or self.window is None, (
+            "windowed attention cannot be sequence-parallel yet: a shard's "
+            f"first queries see the {self.window} - 1 keys before it, a halo "
+            "the ring and all-to-all schedules do not carry"
+        )
         unpar = self.output_shape(
             get_reduced_shape(q), get_reduced_shape(k), get_reduced_shape(v)
         )

@@ -53,6 +53,7 @@ from flexflow_tpu.op_attrs.ops import (
     TopKAttrs,
     TransposeAttrs,
     WeightAttrs,
+    YarnScaling,
 )
 from flexflow_tpu.op_attrs.ops.shape_ops import ReduceOpType
 from flexflow_tpu.pcg.computation_graph import (
@@ -270,6 +271,8 @@ class ComputationGraphBuilder:
         q_latent_rank: Optional[int] = None,
         q_latent_norm_eps: float = 1e-5,
         rope_interleaved: bool = False,
+        window: Optional[int] = None,
+        rope_scaling: Optional[YarnScaling] = None,
     ) -> Tensor:
         """`causal`, `rope_theta` (rotary positions 0..s-1 on q and k) and
         `qk_norm_eps` (RMS norm of the projected q and k over all heads'
@@ -286,7 +289,12 @@ class ComputationGraphBuilder:
         attention `q_latent_rank` gives the query a normed low-rank row of
         its own, and `rope_theta` turns the shared key slice and each query
         head's last `shared_key_dim` columns, pairs (2j, 2j + 1) with
-        `rope_interleaved`."""
+        `rope_interleaved`. On a plain causal node (grouped or equal heads)
+        `window` is the keys a query sees, itself included (a sliding
+        window: a band in the causal tile kernels, a mask on XLA's
+        attention, an error on a route with neither), and `rope_scaling` a
+        `YarnScaling` beside `rope_theta`: the node's own frequencies and
+        amplitude, so that two layers of one graph turn differently."""
         fields = (
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, rope_theta, qk_norm_eps, num_kv_heads,
@@ -297,9 +305,13 @@ class ComputationGraphBuilder:
         if causal:
             from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
 
-            attrs = RingAttentionAttrs(*fields, causal=True)
+            attrs = RingAttentionAttrs(
+                *fields, window=window, rope_scaling=rope_scaling, causal=True
+            )
         else:
-            attrs = MultiHeadAttentionAttrs(*fields)
+            attrs = MultiHeadAttentionAttrs(
+                *fields, window=window, rope_scaling=rope_scaling
+            )
         (out,) = self.add_layer(attrs, [query, key, value], [initializer], name)
         return out
 
@@ -641,6 +653,7 @@ class ComputationGraphBuilder:
         held_experts: Optional[Tuple[int, int]] = None,
         latent_size: Optional[int] = None,
         shared_gate: bool = False,
+        held_window_factor: Optional[float] = None,
     ) -> List[Tensor]:
         """Fused MoE FFN (`ExpertsAttrs`); returns [out] or, with an
         auxiliary loss coefficient, [out, aux_loss], the scalar recorded in
@@ -668,6 +681,7 @@ class ComputationGraphBuilder:
             held_experts,
             latent_size,
             shared_gate,
+            held_window_factor,
         )
         inits = [initializer] * attrs.num_weights
         if selection_bias:

@@ -227,8 +227,9 @@ def _seq_parallel_attention_rule(
         _attr_pattern(
             OperatorType.MULTIHEAD_ATTENTION,
             # RoPE under a sequence shard needs the shard's global
-            # positions (RingAttentionAttrs' shape rule, ROADMAP R7)
-            eq=dict(bias=False, rope_theta=None),
+            # positions, a window its halo of keys (RingAttentionAttrs'
+            # shape rule, ROADMAP R7); a rope_scaling comes with a rope_theta
+            eq=dict(bias=False, rope_theta=None, window=None),
             div=extra_div,
         ),
         [q, k, v, w],

@@ -345,8 +345,13 @@ def test_a_window_on_a_body_without_a_band_is_an_error():
         flash.flash_attention_bshf(
             q, q, q, 2, causal=True, window=100, block_q=512, block_k=512
         )
+    # a plain node takes a window since PR 60; what it does not take
     with pytest.raises(AssertionError, match="differential nodes'"):
-        MultiHeadAttentionAttrs(32, 4, window=8)
+        MultiHeadAttentionAttrs(32, 4, kv_outputs=True)
+    with pytest.raises(AssertionError, match="latent attention is not lowered"):
+        MultiHeadAttentionAttrs(
+            32, 4, 12, 8, kv_latent_rank=4, shared_key_dim=4, window=8
+        )
 
 
 # -- differential attention ----------------------------------------------------

@@ -103,6 +103,15 @@ reads its keys and values, on the unbanded ones. `python
 tests/test_ssm_node_compiles_for_v5e.py phi4flash_step` compiles that cell's
 WHOLE step (12,021,627,392 bytes, 46 s, PR 57).
 
+And what the `mellum2` cell added (PR 60), at its published sizes and 8,192
+positions (`check_mellum2`): ONE plain grouped-query window node (32 query
+over 4 key/value heads of 128, per-head QK-norm, the default rotary, a
+1,024-key window), whose core must be the banded causal kernels
+(`flash_*_causal_bshf_window`, the folded form: `mha_between` repeats the
+keys and values), and the full node with its YaRN rotary on the unbanded
+ones. `python tests/test_ssm_node_compiles_for_v5e.py mellum2_step` compiles
+that cell's WHOLE step.
+
 And what PR 58 gave both delta-rule nodes: the heads' norm under its gate
 as the kernels `head_norm_gate_fwd` (forward once, never rematerialised) and
 `head_norm_gate_bwd` under `<name>/norm`, with no float32 buffer of the
@@ -1161,6 +1170,70 @@ def check_phi4flash():
     return found
 
 
+MELLUM2_INVARIANTS = [
+    "window_node_compiles_on_the_banded_kernels",
+    "full_node_with_yarn_compiles_on_the_causal_kernels",
+]
+MELLUM2_SHAPE = (1, 8192, 2304)
+
+
+def check_mellum2():
+    """{invariant: "ok" or what was found} for what the `mellum2` cell added,
+    at the published sizes and 8,192 positions, forward and backward: ONE
+    plain grouped-query node (32 query over 4 key/value heads of 128, a
+    per-head QK-norm, the default rotary) under a 1,024-key window, whose
+    core must be the banded kernels by the names the profile will carry, and
+    the full node with its YaRN rotary, whose core must be the unbanded
+    ones."""
+    import jax
+
+    from flexflow_tpu.kernels import ops
+    from flexflow_tpu.op_attrs.core import get_weight_shapes
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops import RingAttentionAttrs, YarnScaling
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    on_chip = _described_chip()
+    x = on_chip(MELLUM2_SHAPE)
+    shape = TensorShape(MELLUM2_SHAPE, DataType.FLOAT)
+    banded = ["flash_bwd_causal_bshf_window", "flash_delta_bshf",
+              "flash_fwd_causal_bshf_window"]
+    causal = ["flash_bwd_causal_bshf", "flash_delta_bshf",
+              "flash_fwd_causal_bshf"]
+    found = {}
+    for invariant, want, extra in (
+        (MELLUM2_INVARIANTS[0], banded, dict(window=1024)),
+        (MELLUM2_INVARIANTS[1], causal, dict(
+            rope_scaling=YarnScaling(16.0, 8192, 32.0, 1.0, 1.2772588722239782)
+        )),
+    ):
+        try:
+            attrs = RingAttentionAttrs(
+                2304, 32, 128, 128, rope_theta=500000.0, qk_norm_eps=1e-6,
+                qk_norm_per_head=True, num_kv_heads=4, causal=True, **extra,
+            )
+            ws = [on_chip(w.dims) for w in get_weight_shapes(attrs, [shape] * 3)]
+
+            def node(x, *ws, attrs=attrs):
+                with jax.named_scope("ff.ring_attention.attn0"):
+                    return ops._mha_forward(
+                        attrs, x, x, x, ws[0], causal=True, qk_gains=ws[1:]
+                    )
+
+            def both(*operands, node=node):
+                out, vjp = jax.vjp(node, *operands)
+                return out, vjp(out)
+
+            text = jax.jit(both).lower(x, *ws).compile().as_text()
+            names = sorted(set(re.findall(r"/(flash_\w+)/pallas_call", text)))
+            found[invariant] = (
+                "ok" if names == want else f"kernels {names}, want {want}"
+            )
+        except Exception as e:  # noqa: BLE001 - the complaint is the result
+            found[invariant] = f"{type(e).__name__}: {e}"[:2000]
+    return found
+
+
 def joyai_step_bytes(held, root):
     """The `joyai_llm_flash` cell's WHOLE step compiled for the described
     chip with `held` experts a node (`cell_step_bytes`)."""
@@ -1330,6 +1403,11 @@ def test_phi4flash_nodes_compiled_for_the_described_chip(compiled, invariant):
     assert compiled["phi4flash"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", MELLUM2_INVARIANTS)
+def test_mellum2_nodes_compiled_for_the_described_chip(compiled, invariant):
+    assert compiled["mellum2"][invariant] == "ok"
+
+
 if __name__ == "__main__":
     argv = sys.argv[1:]
     root = os.getcwd()
@@ -1342,6 +1420,10 @@ if __name__ == "__main__":
         print(json.dumps(joyai_step_bytes(int(argv[1]), root)))
     elif argv and argv[0] == "phi4flash_step":
         print(json.dumps(cell_step_bytes("phi4miniflash_s4096_1chip", root)))
+    elif argv and argv[0] == "mellum2_step":
+        print(json.dumps(cell_step_bytes("mellum2_12b_s8192_1chip", root)))
+    elif argv and argv[0] == "mellum2":
+        print(json.dumps(check_mellum2()))
     elif argv:
         print(listing(argv[0]))
     else:
@@ -1349,5 +1431,6 @@ if __name__ == "__main__":
             dict({name: check(name) for name in SHAPES}, kimi=check_kimi(),
                  lfm2=check_lfm2(), experts=check_experts(),
                  held_sums=check_held_sums(), qwen3next=check_qwen3next(),
-                 joyai=check_joyai(), phi4flash=check_phi4flash())
+                 joyai=check_joyai(), phi4flash=check_phi4flash(),
+                 mellum2=check_mellum2())
         ))

@@ -198,6 +198,18 @@ def test_scope_name_survives_the_name_stack(layer_name, want):
          ("bwd", "ring_attention", "attn19/combine")),
         ("jit(_step)/transpose(jvp(ff.ring_attention.attn19))/out_proj"
          "/dot_general", ("bwd", "ring_attention", "attn19/out_proj")),
+        # a plain windowed node (PR 60) keeps the names a plain node had: its
+        # banded kernels, the rotary and the repeat lie under the node's scope
+        # and no part's (the folded form opens no `core`)
+        ("jit(_step)/jvp(ff.ring_attention.attn0)/flash_fwd_causal_bshf_window"
+         "/pallas_call", ("fwd", "ring_attention", "attn0")),
+        ("jit(_step)/transpose(jvp(ff.ring_attention.attn2))"
+         "/flash_bwd_causal_bshf_window/pallas_call",
+         ("bwd", "ring_attention", "attn2")),
+        ("jit(_step)/transpose(jvp(ff.ring_attention.attn3))/flash_delta_bshf"
+         "/pallas_call", ("bwd", "ring_attention", "attn3")),
+        ("jit(_step)/jvp(ff.ring_attention.attn3)/cos",
+         ("fwd", "ring_attention", "attn3")),
         # a scope that only begins like a part, and a part of another kind
         ("jit(_step)/jvp(ff.experts.moe1)/shared_expert/mul",
          ("fwd", "experts", "moe1")),
