@@ -34,7 +34,9 @@ instead: it touches only the rows routed to its experts, a window of them at
 a time, so its cost follows the rows that do work and not N*k. A window's
 rows go back to their tokens as a sum over each token's own rows
 (`held_rows_sum`, a Pallas kernel of row copies) where the rows are bf16 on
-the chip, as XLA's scatter-add elsewhere.
+the chip, as XLA's scatter-add elsewhere; there, too, what runs between the
+grouped matmuls and that sum stops at the share's last row and not at the
+window's (`_window_stages`).
 
 Where the experts live in a latent space (`ExpertsAttrs.latent_size`) the
 rows that are sorted, gathered, multiplied and combined are the tokens'
@@ -295,15 +297,27 @@ def _grouped_matmul(rows, w, group_sizes, pallas: bool):
     float32 accumulation; `pallas`: `_pallas_allowed`. `group_sizes` has G
     entries, or G + 1: the last is then the rows' rest (`_held_window`),
     which meets no matrix and comes back zero. The kernels do not visit it
-    (a group offset of zero into G + 1 sizes); XLA's `ragged_dot` is given a
-    matrix of zeros for it."""
+    (a group offset of zero into G + 1 sizes; megablox then fills it with
+    zeros, a select over all M rows); XLA's `ragged_dot` is given a matrix
+    of zeros for it.
+
+    G sizes that add up to LESS than M (`_held_window` where its row stages
+    stop at the share's last row, `_window_stages`): the kernels visit the
+    same row tiles and nothing is filled, so the rows past the groups' last
+    are NOT WRITTEN, here and in the backward's input gradient, and hold
+    whatever the buffer held (NaN in interpret mode). That is sound where
+    every reader of them stops at the same row: `gmm` and `tgmm` (bounded by
+    the sizes; in the one tile the last group shares with the rest `gmm`
+    stores a group's rows under a select and `tgmm` selects both operands'
+    rows by group before it contracts over them, so no product meets a row
+    past the group's last), and the kernels of `_live_rows_call`."""
     w = w.astype(rows.dtype)
     rest = group_sizes.shape[0] - w.shape[0]
     assert rest in (0, 1), (group_sizes.shape, w.shape)
     tiles = _gmm_tiles(rows.shape[0], *w.shape[1:], w.shape[0]) if pallas else None
     if tiles is not None:
         offset = jnp.zeros((), jnp.int32) if rest else None
-        return _gmm(rows, w, group_sizes, offset, tiles)
+        return _gmm(rows, w, group_sizes, offset, tiles, _interpret())
     if rest:
         w = jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
     return lax.ragged_dot(
@@ -338,15 +352,18 @@ def _note_tiles(matrices, m: int, pallas: bool) -> None:
         trace.note_grouped_matmul_tiles(entries)
 
 
-def _note_held_sums(pallas: bool, n: int, k: int, window: int, dtype, sites):
+def _note_held_sums(pallas: bool, attrs, held: int, n: int, window: int, dtype, sites, ws):
     """The forms (`_held_sum_form`) of a held share's two sums of a window's
     rows over their tokens, the forward's output and the backward's gradient
     of x2, in that order, told to the trace as well
-    (`observability/trace.held_row_sums`) for the reason `_note_tiles` has.
-    `dtype`: the rows'; `sites`: name -> (row width, the sum's dtype)."""
+    (`observability/trace.held_row_sums`) for the reason `_note_tiles` has,
+    with the rows each row stage of the window runs over (`_window_stages`).
+    `held`: the share's experts; `n`: the tokens; `dtype`: the rows';
+    `sites`: name -> (row width, the sum's dtype); `ws`: the share's
+    matrices."""
     from flexflow_tpu.observability import trace
 
-    entries = {}
+    k, entries = attrs.num_select, {}
     for name, (width, sum_dtype) in sites.items():
         form = _held_sum_form(pallas, n, k, width, dtype)
         entries[name] = {
@@ -355,8 +372,11 @@ def _note_held_sums(pallas: bool, n: int, k: int, window: int, dtype, sites):
             "sum_dtype": jnp.dtype(sum_dtype).name,
             "token_tile": _held_sum_tile(n, k, width) if form == "pallas" else None,
         }
-    trace.note_held_row_sums(entries)
-    return tuple(entry["form"] for entry in entries.values())
+    forms = tuple(entry["form"] for entry in entries.values())
+    trace.note_held_row_sums(
+        dict(entries, stages=_window_stages(pallas, forms, attrs, held, n * k, ws))
+    )
+    return forms
 
 
 # XLA's TPU gather and scatter move one element at a time: 8.8-10.2 ns each
@@ -681,17 +701,32 @@ def _token_order(decision, valid, decisions: int):
     )
 
 
-def _held_rows_lanes_kernel(rows_ref, out_ref):
-    """rows_ref [tm, width] bf16 -> out_ref [tm * sublanes, 128] uint32: row
-    r's p-th word sublane at `r * sublanes + p`, group p's bf16 bits in the
-    low halves and group p + pairs' in the high."""
-    tm, width = rows_ref.shape
+def _live_tiles(live, tm: int):
+    """What a kernel over a window's row tiles is given so that it stops at
+    the share's last row: (`live` as the int32 [1] scalar prefetch, the row
+    block index of grid step i). The tiles past the `live`-th row all name
+    the last live tile, so the pipeline fetches nothing new for them and
+    writes nothing back, and the kernel's body skips them (`pl.when`): their
+    rows of the result are NOT WRITTEN (`_grouped_matmul` says who may read
+    them)."""
+    live = jnp.asarray(live, jnp.int32).reshape(1)
+
+    def block(i, live_ref):
+        return jnp.minimum(i, jnp.maximum(live_ref[0] - 1, 0) // tm), 0
+
+    return live, block
+
+
+def _write_lanes(part, out_ref, tm: int, width: int):
+    """out_ref [tm * sublanes, 128] uint32 from `part(g)`, the rows' g-th
+    128-lane group as float32 [tm, 128]: row r's p-th word sublane at `r *
+    sublanes + p`, group p's bf16 bits in the low halves and group p +
+    pairs' in the high."""
     groups = width // 128
     pairs, sublanes = _word_groups(width)
 
     def bits(g):  # a bf16 is its float32's upper half
-        part = rows_ref[:, g * 128:(g + 1) * 128].astype(jnp.float32)
-        return pltpu.bitcast(part, jnp.uint32)
+        return pltpu.bitcast(part(g), jnp.uint32)
 
     for p in range(pairs):
         words = bits(p) >> 16
@@ -700,27 +735,76 @@ def _held_rows_lanes_kernel(rows_ref, out_ref):
         out_ref[pl.ds(p, tm, stride=sublanes), :] = words
 
 
+def _held_rows_lanes_kernel(rows_ref, out_ref):
+    """rows_ref [tm, width] bf16 -> out_ref (`_write_lanes`)."""
+    tm, width = rows_ref.shape
+    _write_lanes(
+        lambda g: rows_ref[:, g * 128:(g + 1) * 128].astype(jnp.float32),
+        out_ref, tm, width,
+    )
+
+
+def _live_rows_lanes_kernel(live_ref, rows_ref, *refs):
+    """`_held_rows_lanes_kernel` of the tiles up to the `live_ref[0]`-th row,
+    the others left as they are; `refs`: `out_ref`, after a second
+    `[tm, width]` operand where the rows are a sum of two (added in bf16, as
+    XLA adds two cotangents: the float32 sum rounded)."""
+    *add_ref, out_ref = refs
+    tm, width = rows_ref.shape
+
+    def part(g):
+        rows = rows_ref[:, g * 128:(g + 1) * 128].astype(jnp.float32)
+        if add_ref:
+            rows = rows + add_ref[0][:, g * 128:(g + 1) * 128].astype(jnp.float32)
+            rows = rows.astype(jnp.bfloat16).astype(jnp.float32)
+        return rows
+
+    @pl.when(pl.program_id(0) * tm < live_ref[0])
+    def _():
+        _write_lanes(part, out_ref, tm, width)
+
+
 @functools.partial(jax.jit, static_argnums=(1,))
-def held_rows_lanes(rows, interpret: bool = False):
+def held_rows_lanes(rows, interpret: bool = False, live=None, addend=None):
     """rows [window, width] bf16 as uint32 [window * sublanes, 128]
     (`_word_groups`): a row is `sublanes` sublanes of words that lie
     together, which one DMA can take. The sublanes past a row's last pair
-    are not written, and nothing reads them."""
+    are not written, and nothing reads them. With `live` (an int32 scalar)
+    neither are the tiles of rows past the `live`-th (`_live_tiles`), which
+    `held_rows_sum` never asks for, and the rows are `rows + addend` where
+    there is one."""
     window, width = rows.shape
     sublanes = _word_groups(width)[1]
     tm = 256 if window % 256 == 0 else 128
     assert window % tm == 0 and width % 128 == 0, rows.shape
     assert rows.dtype == jnp.bfloat16, rows.dtype
+    if live is None:
+        assert addend is None
+        return pl.pallas_call(
+            _held_rows_lanes_kernel,
+            out_shape=jax.ShapeDtypeStruct((window * sublanes, 128), jnp.uint32),
+            grid=(window // tm,),
+            in_specs=[pl.BlockSpec((tm, width), lambda i: (i, 0))],
+            out_specs=pl.BlockSpec((tm * sublanes, 128), lambda i: (i, 0)),
+            compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+            interpret=interpret,
+            name="held_rows_lanes",
+        )(rows)
+    live, block = _live_tiles(live, tm)
+    operands = (rows,) if addend is None else (rows, addend)
     return pl.pallas_call(
-        _held_rows_lanes_kernel,
+        _live_rows_lanes_kernel,
         out_shape=jax.ShapeDtypeStruct((window * sublanes, 128), jnp.uint32),
-        grid=(window // tm,),
-        in_specs=[pl.BlockSpec((tm, width), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((tm * sublanes, 128), lambda i: (i, 0)),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(window // tm,),
+            in_specs=[pl.BlockSpec((tm, width), block) for _ in operands],
+            out_specs=pl.BlockSpec((tm * sublanes, 128), block),
+        ),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="held_rows_lanes",
-    )(rows)
+    )(live, *operands)
 
 
 def _held_rows_sum_kernel(tokens_ref, rows_ref, starts_ref, *refs):
@@ -838,33 +922,176 @@ def _interpret() -> bool:
     return flash.interpret_default()
 
 
+# -- a window's row stages stop at the share's last row -----------------------
+#
+# A window is a static `[window, .]` buffer and a share fills a part of it:
+# 23% of the decisions reach Mellum2's 16 held experts where the pass holds
+# 56% (PERF.md section 6, PR 61). `gmm` and `tgmm` visit the share's row
+# tiles alone (`group_sizes`), `held_rows_sum` its rows alone; what ran
+# between them ran over all `window` rows: megablox's zero fill of the rows
+# no matrix met, the mask of the gathered rows, the activation and its
+# backward, the weighted cotangent, the lane rewrites. The share's rows are
+# a PREFIX of the window (`order` puts them first), so "the share's rows
+# alone" is one scalar, `live`, and each of those stages is a Pallas kernel
+# over row tiles that skips the tiles past it (`_live_tiles`) or is not run
+# at all (the fills and the mask), where `_window_stages` says the pass is
+# large enough for it. The buffers keep their shapes; the rows
+# past `live` are not written and not read (`_grouped_matmul`). What still
+# runs over the window is XLA's gathers of whole rows (`x2[token]`, and the
+# cotangent's `g_out[token]` in the backward: 14-17 ns a row, where one DMA
+# a row costs `held_rows_sum` 32-37) and its index arithmetic.
+
+# Bytes of VMEM the blocks of one `_live_rows_call` may take, both of the
+# pipeline's buffers counted; the limit handed to the compiler is twice that
+# and 4 MB, for the float32 temporaries of a body.
+_LIVE_BLOCK_BYTES = 12 * 2**20
+
+
+def _live_rows_call(body, name: str, live, operands, results, over=None):
+    """`body(*blocks)` -> a block a result, over the row tiles of `operands`
+    ([window, w] arrays) up to the `live`-th row: one Pallas kernel `name`
+    whose program takes `[tm, w]` of every operand and writes `[tm, w]` of
+    every result (`results`: (width, dtype) each), the tiles past `live`
+    skipped (`_live_tiles`). `over`: operand -> the result written over it
+    (an operand of the result's width and dtype that nothing reads after
+    this call: XLA then gives the result no buffer of its own, 170 MB of
+    Mellum2's compiled step, and copies the operand where something does);
+    such a result's rows past `live` hold what the operand held."""
+    window = operands[0].shape[0]
+    row_bytes = sum(
+        a.shape[1] * a.dtype.itemsize for a in operands
+    ) + sum(w * jnp.dtype(d).itemsize for w, d in results)
+    tm = next(
+        (t for t in (512, 256) if window % t == 0
+         and 2 * t * row_bytes <= _LIVE_BLOCK_BYTES), 128
+    )
+    live, block = _live_tiles(live, tm)
+
+    def kernel(live_ref, *refs):
+        ins, outs = refs[:len(operands)], refs[len(operands):]
+
+        @pl.when(pl.program_id(0) * tm < live_ref[0])
+        def _():
+            values = body(*(ref[...] for ref in ins))
+            for ref, value in zip(outs, values):
+                ref[...] = value.astype(ref.dtype)
+
+    return pl.pallas_call(
+        kernel,
+        out_shape=[jax.ShapeDtypeStruct((window, w), d) for w, d in results],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(window // tm,),
+            in_specs=[pl.BlockSpec((tm, a.shape[1]), block) for a in operands],
+            out_specs=[pl.BlockSpec((tm, w), block) for w, _ in results],
+        ),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=4 * tm * row_bytes + 4 * 2**20,
+        ),
+        # the live count is input 0
+        input_output_aliases={1 + i: o for i, o in (over or {}).items()},
+        interpret=_interpret(),
+        name=name,
+    )(live, *operands)
+
+
+def _expert_hidden(attrs, h1, h3, c1):
+    """What an expert's second matrix reads, from the first's product `h1`
+    (`c1`: its bias rows, or None) and, where the expert is gated, the
+    third's `h3` (else None): a row at a time, in the rows' dtype."""
+    h = h1 if c1 is None else h1 + c1
+    if attrs.activation is not None:
+        h = attrs.activation.apply(h)
+    return h if h3 is None else h * h3
+
+
+def _hidden_blocks(attrs, present, blocks):
+    """`_expert_hidden` of the operands that are there, in float32:
+    `present` says which of (h1, h3, c1) `blocks` holds, in that order. A
+    stage kernel computes its tile in float32 and rounds once, where it
+    stores, as XLA's fusion of the same chain does on the chip (a v5e's VPU
+    has no bf16 arithmetic, and Mosaic there takes neither a bf16 `logistic`
+    nor a bf16 comparison)."""
+    blocks = (block.astype(jnp.float32) for block in blocks)
+    return _expert_hidden(attrs, *(next(blocks) if p else None for p in present))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _live_hidden(attrs, live, h1, h3, c1):
+    """`_expert_hidden` of the rows up to the `live`-th, a kernel each way
+    (`experts_hidden_fwd`, `experts_hidden_bwd`: the same expressions on a
+    tile of rows and their `jax.vjp`, in float32, each result rounded once
+    to the rows' dtype where it is stored: `_hidden_blocks`)."""
+    present = tuple(a is not None for a in (h1, h3, c1))
+    given = [a for a in (h1, h3, c1) if a is not None]
+    return _live_rows_call(
+        lambda *blocks: (_hidden_blocks(attrs, present, blocks),),
+        "experts_hidden_fwd", live, given, [(h1.shape[1], h1.dtype)],
+    )[0]
+
+
+def _live_hidden_fwd(attrs, live, h1, h3, c1):
+    return _live_hidden(attrs, live, h1, h3, c1), (live, h1, h3, c1)
+
+
+def _live_hidden_bwd(attrs, kept, g):
+    live, *operands = kept
+    present = tuple(a is not None for a in operands)
+    given = [a for a in operands if a is not None]
+
+    def body(g, *blocks):
+        return jax.vjp(
+            lambda *blocks: _hidden_blocks(attrs, present, blocks), *blocks
+        )[1](g.astype(jnp.float32))
+
+    # each operand's gradient over the operand: its last reader is this call
+    grads = iter(_live_rows_call(
+        body, "experts_hidden_bwd", live, [g, *given],
+        [(a.shape[1], a.dtype) for a in given],
+        over={1 + j: j for j in range(len(given))},
+    ))
+    return (None,) + tuple(next(grads) if p else None for p in present)
+
+
+_live_hidden.defvjp(_live_hidden_fwd, _live_hidden_bwd)
+
+
 def _masked_rows(x2, token, valid):
     """A window's token rows, `x2[token]` with the masked rows zero."""
     return jnp.where(valid[:, None], x2[token], 0)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _window_rows(x2, token, valid, decisions_sorted, rows_sorted, k):
-    """`_masked_rows` whose transpose is no scatter-add: with the window's
-    rows in token order (`_token_order`) it is `held_rows_sum` of the rows'
-    cotangent, summed in float32 and rounded once to x2's dtype."""
-    del decisions_sorted, rows_sorted, k
-    return _masked_rows(x2, token, valid)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def _window_rows(x2, token, valid, live, decisions_sorted, rows_sorted, k, readers):
+    """A window's token rows whose transpose is no scatter-add: with the
+    window's rows in token order (`_token_order`) it is `held_rows_sum` of
+    the rows' cotangent, summed in float32 and rounded once to x2's dtype.
+    `valid` [window]: `_masked_rows`; None: `x2[token]` as gathered, for
+    readers that stop at the `live`-th row, and so does the transpose.
+    The rows come `readers` times over (one array), so that two readers'
+    cotangents arrive apart and are added where they are rewritten
+    (`held_rows_lanes`), not in a pass of their own."""
+    del live, decisions_sorted, rows_sorted, k
+    rows = x2[token] if valid is None else _masked_rows(x2, token, valid)
+    return (rows,) * readers
 
 
-def _window_rows_fwd(x2, token, valid, decisions_sorted, rows_sorted, k):
-    return _masked_rows(x2, token, valid), (
-        decisions_sorted, rows_sorted, x2.shape[0]
+def _window_rows_fwd(x2, token, valid, live, decisions_sorted, rows_sorted, k, readers):
+    rows = _window_rows(
+        x2, token, valid, live, decisions_sorted, rows_sorted, k, readers
     )
+    return rows, (live, decisions_sorted, rows_sorted, x2.shape[0])
 
 
-def _window_rows_bwd(k, kept, g):
-    decisions_sorted, rows_sorted, n = kept
+def _window_rows_bwd(k, readers, kept, g):
+    live, decisions_sorted, rows_sorted, n = kept
+    assert readers in (1, 2), readers
     g_x2 = held_rows_sum(
-        held_rows_lanes(g, _interpret()), decisions_sorted, rows_sorted, None,
-        n, k, g.shape[1], g.dtype, _interpret(),
+        held_rows_lanes(g[0], _interpret(), live, *g[1:]), decisions_sorted,
+        rows_sorted, None, n, k, g[0].shape[1], g[0].dtype, _interpret(),
     )
-    return g_x2, None, None, None, None
+    return g_x2, None, None, None, None, None
 
 
 _window_rows.defvjp(_window_rows_fwd, _window_rows_bwd)
@@ -884,65 +1111,165 @@ def held_window_rows(
     return min(decisions, max(128, -(-rows // 128) * 128))
 
 
+def _window_stages(pallas: bool, forms, attrs, held: int, decisions: int, ws) -> dict:
+    """Which rows each row stage of a held window runs over, read from what
+    the window is given: "live", the share's rows alone, or "window", XLA's
+    forms over the whole pass. `rows_in`: the mask of the gathered rows
+    ("live": none is needed; the gather itself is XLA's over the window
+    either way); `zero_fill`: megablox's fill of the rows no matrix met
+    ("live": not run); `elementwise`: the activation, the gate's product and
+    the weighted cotangent; `lanes`: `held_rows_lanes`.
+
+    "live" where the forward's sum takes the kernel (`_held_sum_form`: on
+    the chip, bf16 rows of whole lane tiles), every matrix's grouped matmul
+    has its tiles (`_gmm_tiles`) AND the pass (`held_window_rows`, whatever
+    rule or attribute sized it) is at least half again the rows a uniform
+    router sends the share's `held` experts of `decisions`. That size is
+    where the stages were measured to pay (PERF.md section 6, PR 61, a
+    v5e): Mellum2's step +1.8 and +2.1% `tokens_per_s` at passes of 1.5
+    times the share and +5.9 to +6.2% at its own 2.25, and at a quarter
+    over, the rule of every other held graph, -0.75 to +0.56% over six
+    cells, under the 1% the benchmark resolves. What the condition guards
+    is the compiled step's BYTES: with the stages live at a quarter over,
+    `step_hbm_gb` read -3.7 to +2.6% with no pattern in the pass, the
+    widths or the tokens (the same LFM2 graph: +2.57% at two sequences a
+    batch, over the benchmark's bound of 1%, and -2.0% at one). The part
+    that moves is in none of XLA's buffers (they move by 13 MB): it is a
+    term of the executable's temporary bytes that no dump of the compiler
+    lists, 435 MB of LFM2's step before and 710 after, which follows
+    whether an XLA operation or another kernel reads a kernel's result.
+    Nothing a trace can read predicts it, so a pass whose gain the
+    benchmark cannot resolve keeps XLA's forms, the parent program to the
+    letter, and a graph whose passes grow past the size takes its compiles
+    first (1.25 to 2.25 times in Mellum2's step: -1.2 to +0.35%)."""
+    window = held_window_rows(
+        decisions, held, attrs.num_experts, attrs.held_window_factor
+    )
+    uniform = -(-decisions * held // attrs.num_experts)
+    live = pallas and forms[0] == "pallas" and 2 * window >= 3 * uniform and all(
+        _gmm_tiles(window, *w.shape[1:], w.shape[0]) for w in ws.values()
+        if w.ndim == 3
+    )
+    stages = dict.fromkeys(
+        ("rows_in", "zero_fill", "elementwise", "lanes"),
+        "live" if live else "window",
+    )
+    if forms[1] != "pallas":
+        stages["rows_in"] = "window"  # the scatter-add's mask stays
+    return stages
+
+
 def _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas: bool, forms):
-    """(tokens [window], the router's weights [window] float32, the experts'
-    outputs [window, out], the window's rows in token order) of the t-th
-    window of a share's rows (`_held_rows_forward`: `order` the decisions
-    with the share's first, `counts` [held] its decisions per expert, `forms`
-    the two sums' forms). A row's weight is zero where the share has no `t *
-    window + i`-th row at all, so a window past the share's last row gives
-    zeros. The last is `_token_order`'s pair where a sum takes the kernel,
-    else None."""
+    """The t-th window of a share's rows (`_held_rows_forward`: `order` the
+    decisions with the share's first, `counts` [held] its decisions per
+    expert, `forms` the two sums' forms), as a dict: `decision` and `token`
+    [window], the router's `weight` [window] float32, the experts' outputs
+    `y` [window, out], `valid` [window] (the share has a `t * window + i`-th
+    row), their count `live` where the row stages stop at it
+    (`_window_stages`, else None) and `by_token`, the window's rows in token
+    order (`_token_order`'s pair where a sum takes the kernel, else None). A
+    row's weight is zero where it is not valid, so a window past the share's
+    last row adds nothing; what `y` holds there is zeros on the "window"
+    stages and NOT WRITTEN on the "live" ones (`_grouped_matmul`)."""
     held, (n, decisions) = counts.shape[0], (x2.shape[0], order.shape[0])
     k = decisions // n
     window = held_window_rows(
         decisions, held, attrs.num_experts, attrs.held_window_factor
     )
-
-    def grouped(rows, w):
-        # `held` matrices for held + 1 sizes: the last is the window's rest
-        return _grouped_matmul(rows, w, sizes, pallas)
+    stages = _window_stages(pallas, forms, attrs, held, decisions, ws)
+    bounded = stages["elementwise"] == "live"
 
     total = jnp.sum(counts)
     ends = jnp.cumsum(counts)
     lo = t * window
     at = lo + jnp.arange(window, dtype=jnp.int32)
     valid = at < total
-    decision = order[jnp.minimum(at, decisions - 1)]
+    live = jnp.clip(total - lo, 0, window) if bounded else None
+    if bounded:
+        # `order[at]`, the last entry again past the end: a slice, where an
+        # indexed read of `window` scalars is 7 ns each (PERF.md section 5)
+        decision = lax.dynamic_slice(
+            jnp.concatenate([order, jnp.broadcast_to(order[-1:], (window,))]),
+            (lo,), (window,),
+        )
+    else:
+        decision = order[jnp.minimum(at, decisions - 1)]
     token = decision // k
     by_token = None
     if "pallas" in forms:
         by_token = _token_order(decision, valid, decisions)
+    readers = 2 if bounded and "w3" in ws else 1
     if forms[1] == "pallas":
-        rows = _window_rows(x2, token, valid, *by_token, k)
+        rows = _window_rows(
+            x2, token, None if bounded else valid, live, *by_token, k, readers
+        )
     else:
-        rows = _masked_rows(x2, token, valid)
+        rows = (_masked_rows(x2, token, valid),) * readers
     sizes = jnp.clip(
         jnp.minimum(ends, lo + window) - jnp.maximum(ends - counts, lo),
         0, window,
     )
-    sizes = jnp.concatenate(
+    # `held` matrices for held + 1 sizes: the last is the window's rest
+    rest = jnp.concatenate(
         [sizes, (window - jnp.sum(sizes))[None]]
     ).astype(jnp.int32)
 
+    def grouped(rows, w):
+        return _grouped_matmul(
+            rows, w, sizes.astype(jnp.int32) if bounded else rest, pallas
+        )
+
     def bias_rows(b):
         # each row's expert's bias, as a grouped matmul of a column of
-        # ones (see `experts_forward`)
-        return grouped(jnp.ones((window, 1), b.dtype), b[:, None, :])
+        # ones (see `experts_forward`), the rest's rows zero; the select
+        # changes no value and keeps the rows past the share's last out of
+        # the bias's gradient where the stages leave them unwritten
+        rows = _grouped_matmul(
+            jnp.ones((window, 1), b.dtype), b[:, None, :], rest, pallas
+        )
+        return jnp.where(valid[:, None], rows, 0) if bounded else rows
 
     with jax.named_scope("grouped_matmul"):
-        h = grouped(rows, ws["w1"])
-        if "b1" in ws:
-            h = h + bias_rows(ws["b1"])
-        if attrs.activation is not None:
-            h = attrs.activation.apply(h)
-        if "w3" in ws:
-            h = h * grouped(rows, ws["w3"])
+        h = grouped(rows[0], ws["w1"])
+        c1 = bias_rows(ws["b1"]) if "b1" in ws else None
+        if bounded:
+            h3 = grouped(rows[-1], ws["w3"]) if "w3" in ws else None
+            h = _live_hidden(attrs, live, h, h3, c1)
+        else:
+            h = _expert_hidden(attrs, h, None, c1)
+            if "w3" in ws:
+                h = h * grouped(rows[-1], ws["w3"])
         y = grouped(h, ws["w2"])
         if "b2" in ws:
             y = y + bias_rows(ws["b2"])
-    weight = jnp.where(valid, flat_w[decision], 0.0)
-    return token, weight, y, by_token
+    return {
+        "decision": decision, "token": token, "valid": valid, "y": y,
+        "weight": jnp.where(valid, flat_w[decision], 0.0),
+        "by_token": by_token, "live": live,
+    }
+
+
+def _live_cotangents(live, y, weight, g_rows):
+    """(the cotangent of `y` [window, out], the weights' [window] float32)
+    under `sum(weight[:, None] * float32(y) * g_rows)`, `g_rows` [window,
+    out] float32, for the rows up to the `live`-th: `weight * g_rows`
+    rounded to y's dtype and the row sums of `g_rows * float32(y)`, one
+    kernel (`experts_cotangent`) where XLA wrote the products out between
+    its passes. A [window] vector goes in and comes out a 128-lane tile
+    wide, every lane the same."""
+    out = y.shape[1]
+
+    def body(y, g_rows, weight):
+        g_weight = jnp.sum(g_rows * y.astype(jnp.float32), axis=1, keepdims=True)
+        return weight[:, :1] * g_rows, jnp.broadcast_to(g_weight, weight.shape)
+
+    g_y, g_weight = _live_rows_call(
+        body, "experts_cotangent", live,
+        [y, g_rows, jnp.broadcast_to(weight[:, None], (y.shape[0], 128))],
+        [(out, y.dtype), (128, jnp.float32)],
+        over={0: 0},  # y's cotangent over y
+    )
+    return g_y, g_weight[:, 0]
 
 
 # The two functions of the window index `t` that `_held_rows_forward` calls,
@@ -958,16 +1285,16 @@ def _held_window_add(out, t, order, counts, x2, flat_w, ws, attrs, pallas, forms
     each token's own rows summed (`held_rows_sum`) and the sums added, or
     XLA's scatter-add (`forms[0]`). The first window's `out` is a constant
     zero, which XLA folds either way."""
-    token, weight, y, by_token = _held_window(
-        t, order, counts, x2, flat_w, ws, attrs, pallas, forms
-    )
+    w = _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas, forms)
     if forms[0] == "pallas":
         return out + held_rows_sum(
-            held_rows_lanes(y, _interpret()), *by_token, weight, x2.shape[0],
-            order.shape[0] // x2.shape[0], out.shape[1], out.dtype,
-            _interpret(),
+            held_rows_lanes(w["y"], _interpret(), w["live"]), *w["by_token"],
+            w["weight"], x2.shape[0], order.shape[0] // x2.shape[0],
+            out.shape[1], out.dtype, _interpret(),
         )
-    return out.at[token].add(weight[:, None] * y.astype(jnp.float32))
+    return out.at[w["token"]].add(
+        w["weight"][:, None] * w["y"].astype(jnp.float32)
+    )
 
 
 @functools.partial(jax.jit, static_argnums=(7, 8, 9))
@@ -975,15 +1302,38 @@ def _held_window_grads(g_out, t, order, counts, x2, flat_w, ws, attrs, pallas, f
     """Window t's part of the gradients of (x2, flat_w, ws) under the
     cotangent `g_out` [N, out] float32 of the share's output: the window
     recomputed from its inputs and differentiated by itself (x2's through
-    `_window_rows`'s written transpose where `forms[1]` is the kernel)."""
+    `_window_rows`'s written transpose where `forms[1]` is the kernel).
+    Where the row stages stop at the share's last row the weighted sum's
+    own transpose is written out (`_live_cotangents`), the same products
+    and sums as autodiff's, for the rows that are there."""
+
+    def window(x2, flat_w, ws):
+        return _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas, forms)
 
     def window_dot(x2, flat_w, ws):
-        token, weight, y, _ = _held_window(
-            t, order, counts, x2, flat_w, ws, attrs, pallas, forms
+        w = window(x2, flat_w, ws)
+        return jnp.sum(
+            w["weight"][:, None] * w["y"].astype(jnp.float32) * g_out[w["token"]]
         )
-        return jnp.sum(weight[:, None] * y.astype(jnp.float32) * g_out[token])
 
-    return jax.grad(window_dot, argnums=(0, 1, 2))(x2, flat_w, ws)
+    def outputs(x2, ws):
+        w = window(x2, flat_w, ws)
+        return w.pop("y"), w
+
+    stages = _window_stages(
+        pallas, forms, attrs, counts.shape[0], order.shape[0], ws
+    )
+    if stages["elementwise"] != "live":
+        return jax.grad(window_dot, argnums=(0, 1, 2))(x2, flat_w, ws)
+    y, transpose, w = jax.vjp(outputs, x2, ws, has_aux=True)
+    g_y, g_weight = _live_cotangents(
+        w["live"], y, w["weight"], g_out[w["token"]]
+    )
+    g_x2, g_ws = transpose(g_y)
+    g_w = jnp.zeros_like(flat_w).at[w["decision"]].add(
+        jnp.where(w["valid"], g_weight, 0.0)
+    )
+    return g_x2, g_w, g_ws
 
 
 def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
@@ -1000,9 +1350,26 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
     the share's first, by local expert (one stable sort of N k small keys),
     and taken in windows of `held_window_rows` rows: gather the window's
     token rows, run the grouped matmuls over the share's groups (`gmm` /
-    `tgmm` visit those groups' row tiles and zero the rest), add the
-    weighted results to their tokens (`held_rows_sum`, or a scatter-add:
-    `_held_sum_form`). A uniform router fills less than
+    `tgmm` visit those groups' row tiles), add the weighted results to
+    their tokens (`held_rows_sum`, or a scatter-add: `_held_sum_form`).
+    A window is a static buffer and the share's rows are a prefix of it.
+    On the chip (`_window_stages`: "live") every stage between the gather
+    and the sum stops at the share's last row, as the kernels at either end
+    do: the rows past it are NOT WRITTEN in any intermediate (the gathered
+    rows' are some token's, the rest whatever the buffer held) and may be
+    read by nothing but a per-row operation that is itself not read there:
+    `gmm`, `tgmm` and `held_rows_sum` take the share's rows alone, the
+    stage kernels (`_live_rows_call`, `held_rows_lanes`) skip the tiles
+    past them and work a row at a time in the one tile the last row
+    shares, XLA's only touches are the bias rows' add (a row at a time)
+    and selects under `valid` (the bias's and the weights' gradients).
+    Nothing sums over rows but `tgmm`, which selects its operands' rows by
+    group first. Elsewhere ("window": the CPU mesh, a global-view SPMD
+    trace, float32 compute, widths the kernels do not take, a pass under
+    half again its uniform share) the rest of a
+    window is zeros: megablox fills what no matrix met, the gathered rows
+    are masked, and XLA's passes run over the whole window.
+    A uniform router fills less than
     one window, so the first window is straight-line code and its results
     ARE the accumulators: nothing is zero-filled and nothing added to the
     fill. A router that sends this share more takes the windows after the
@@ -1089,9 +1456,10 @@ def _held_rows_forward(attrs, share, x2, flat_e, topv, ws, pallas: bool):
         {name: w for name, w in ws.items() if w.ndim == 3}, window, pallas
     )
     forms = _note_held_sums(
-        pallas, n, k, window, x2.dtype,
+        pallas, attrs, held, n, window, x2.dtype,
         {"forward": (ws["w2"].shape[-1], jnp.float32),
          "backward": (x2.shape[-1], x2.dtype)},
+        ws,
     )
     ran = jnp.maximum(windows(counts), 1)  # the first runs whatever the counts
     return routed(order, counts, x2, flat_w, ws), counts, ran

@@ -867,8 +867,9 @@ def grouped_matmul_tiles() -> Dict[str, Dict[str, dict]]:
 def note_held_row_sums(entries: Dict[str, dict]) -> None:
     """How `kernels/moe.py` sums a held share's window rows over their
     tokens in the expert node being lowered: `{"forward" | "backward":
-    {"form", "window_rows", "width", "dtype", "sum_dtype", "token_tile"}}`;
-    dropped where no node's scope is open."""
+    {"form", "window_rows", "width", "dtype", "sum_dtype", "token_tile"},
+    "stages": {"rows_in", "zero_fill", "elementwise", "lanes"}}`; dropped
+    where no node's scope is open."""
     scope = getattr(_lowering, "scope", None)
     if scope is not None:
         _HELD_ROW_SUMS[scope] = {site: dict(e) for site, e in entries.items()}
@@ -882,7 +883,13 @@ def held_row_sums() -> Dict[str, Dict[str, dict]]:
     of the node's input), the `form` (`pallas`: the kernel `held_rows_sum`;
     `xla`: a scatter-add), the window's rows (`window_rows`), the row's
     `width` and `dtype`, the sum's (`sum_dtype`), and the tokens a program
-    of the kernel (`token_tile`, None on `xla`)."""
+    of the kernel (`token_tile`, None on `xla`). Beside them `stages`: for
+    each row stage of a window between the grouped matmuls and the sums
+    (`rows_in`, the mask of the gathered rows; `zero_fill`, of the rows no
+    matrix met; `elementwise`; `lanes`) `live` where it stops at the share's
+    last row and `window` where it runs over the whole pass
+    (`kernels/moe._window_stages`), so that a trace says whether a cell's
+    passes cost their rows or their size."""
     return {
         scope: {site: dict(e) for site, e in entries.items()}
         for scope, entries in _HELD_ROW_SUMS.items()

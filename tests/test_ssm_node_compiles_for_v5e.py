@@ -716,8 +716,14 @@ def check_experts():
 HELD_SUM_INVARIANTS = [
     f"{cell}_rows_reach_their_tokens_through_held_rows_sum_and_compile"
     for cell in ("lfm2", "kimi", "twotower", "super")
+] + [
+    # PR 61: the passes whose row stages stop at the share's last row
+    # (`kernels/moe._window_stages`), at both ends of their sizes
+    "mellum2_generous_pass_compiles_with_its_row_stages_live",
+    "a_pass_clamped_to_one_tile_compiles_with_its_row_stages_live",
 ]
-# input, then `ExpertsAttrs` of a node of each held cell (8 experts held)
+# input, then `ExpertsAttrs` of a node of each held cell (8 experts held
+# unless it says so)
 HELD_SUM_NODES = {
     "lfm2": (LFM2_SHAPE, dict(
         num_experts=64, num_select=4, hidden_size=1536, gated=True)),
@@ -730,29 +736,45 @@ HELD_SUM_NODES = {
     "super": ((1, ROWS, 4096), dict(
         num_experts=512, num_select=22, hidden_size=2688, gated=False,
         shared_hidden_size=5376, latent_size=1024)),
+    # 16 of 64 held in passes of 2.25 times the uniform share: 36,864 rows
+    "mellum2": ((1, 8192, 2304), dict(
+        num_experts=64, num_select=8, hidden_size=896, gated=True,
+        scoring="softmax", selection_bias=False, held_experts=(0, 16),
+        held_window_factor=2.25)),
+    # 2 of 64 held, 512 tokens: 64 rows if uniform, a pass of one 128-row tile
+    "clamped": ((1, 512, 2048), dict(
+        num_experts=64, num_select=4, hidden_size=1536, gated=True,
+        held_experts=(0, 2))),
 }
+LIVE_STAGE_KERNELS = ("experts_hidden_fwd", "experts_hidden_bwd", "experts_cotangent")
 
 
 def check_held_sums():
     """{invariant: "ok" or what was found} for one expert node of each of
-    the four held cells, forward and backward."""
+    the four held cells and of the two passes whose row stages are live
+    (their kernels in the lowered text, and in no other node's), forward and
+    backward."""
     from flexflow_tpu.op_attrs.activation import Activation
     from flexflow_tpu.op_attrs.ops import ExpertsAttrs
 
     found = {}
     for invariant, (shape, sizes) in zip(HELD_SUM_INVARIANTS, HELD_SUM_NODES.values()):
         try:
-            attrs = ExpertsAttrs(
-                activation=Activation.SILU if sizes["gated"] else Activation.RELU2,
-                capacity_factor=None, use_bias=False, renormalize=True,
-                scoring="sigmoid", selection_bias=True, held_experts=(0, 8),
+            attrs = ExpertsAttrs(**dict(
+                dict(
+                    activation=Activation.SILU if sizes["gated"] else Activation.RELU2,
+                    capacity_factor=None, use_bias=False, renormalize=True,
+                    scoring="sigmoid", selection_bias=True, held_experts=(0, 8),
+                ),
                 **sizes,
-            )
+            ))
             node, args = _experts_node(attrs, shape)
             lowered = node.lower(*args)
             text = lowered.as_text()
             sums = text.count('kernel_name = "held_rows_sum"')
             lanes = text.count('kernel_name = "held_rows_lanes"')
+            stages = [text.count(f'kernel_name = "{k}"') for k in LIVE_STAGE_KERNELS]
+            live = "row_stages_live" in invariant
             # a scatter of rows closes `}) : (tensor<tokens x width>, ...)`;
             # megablox's own scatters are of integers, and the one of bf16
             # rows is a later window's gradient of x2, in the loop's body
@@ -763,9 +785,10 @@ def check_held_sums():
             # JAX lowers a window function once for the straight-line site
             # and once for the loop's: two sites, each kernel two or four times
             found[invariant] = (
-                "ok" if sums == lanes >= 2 and not scatters else
+                "ok" if sums == lanes >= 2 and not scatters
+                and all(stages) == live == any(stages) else
                 f"{sums} held_rows_sum, {lanes} held_rows_lanes, "
-                f"{scatters} scatters of float32 rows"
+                f"{scatters} scatters of float32 rows, stage kernels {stages}"
             )
         except Exception as e:  # noqa: BLE001 - the complaint is the result
             found[invariant] = f"{type(e).__name__}: {e}"[:2000]
