@@ -119,16 +119,18 @@ def build_step_instance(
 
 
 def _example_label(logit_dims, loss_attrs, label_dtype):
-    """Zero-filled label derived from the logit shape — sparse CE labels
-    drop the class dim and default to int32, dense losses mirror the
-    logits (shared by the PCG and CG example-argument builders)."""
+    """Zero-filled label derived from the logit shape — class indices
+    (sparse CE; a step whose loss is its loss nodes alone, which `fit` hands
+    the same integer labels) drop the class dim and default to int32, dense
+    losses mirror the logits (shared by the PCG and CG example-argument
+    builders)."""
     import jax.numpy as jnp
 
-    from flexflow_tpu.op_attrs.ops.loss_functions import (
-        SparseCategoricalCrossEntropyLossAttrs,
-    )
+    from flexflow_tpu.op_attrs.ops.loss_functions import LossFunction
 
-    sparse = isinstance(loss_attrs, SparseCategoricalCrossEntropyLossAttrs)
+    sparse = loss_attrs.loss_type in (
+        LossFunction.SPARSE_CATEGORICAL_CROSSENTROPY, LossFunction.LOSS_NODES
+    )
     label_dims = logit_dims[:-1] if sparse else logit_dims
     if label_dtype is None:
         label_dtype = jnp.int32 if sparse else jnp.float32

@@ -168,7 +168,10 @@ def analyze_memory(
     with `serving` set, of one forward-only serving dispatch (no backward
     ticks, no gradient/optimizer terms, attention ops resident with their
     per-device KV-cache share)."""
-    from flexflow_tpu.compiler.machine_mapping.problem_tree import _from_weight
+    from flexflow_tpu.compiler.machine_mapping.problem_tree import (
+        _from_weight,
+        weight_source,
+    )
     from flexflow_tpu.op_attrs.core import is_parallel_op
     from flexflow_tpu.op_attrs.ops import (
         InputAttrs,
@@ -217,6 +220,11 @@ def analyze_memory(
             events[d].append((start, nbytes, category))
             events[d].append((end + 1, -nbytes, category))
 
+    # a weight with several readers (a tied head, a looped model's layer
+    # applied again) is ONE buffer with one gradient and one set of
+    # optimizer slots: charged with its first reader on a device, in the
+    # form that reader takes it; its readers' activations are each their own
+    charged: Dict[int, set] = {d: set() for d in devices}
     tick_labels: Dict[int, str] = {}
     for n in order:
         attrs = pcg.op_attrs(n)
@@ -263,19 +271,26 @@ def analyze_memory(
             )
 
             _, weight_vals = split_slot_values(attrs, list(ins))
-            w_shapes = [
-                pcg.tensor_shape(v) for v in weight_vals
-                if _from_weight(pcg, v)
+            sourced = [
+                (weight_source(pcg, v), pcg.tensor_shape(v))
+                for v in weight_vals
             ]
-            w_bytes = sum(get_piece_shape(s).size_bytes for s in w_shapes)
-            if w_bytes:
-                charge_resident(devs, "params", w_bytes)
+            for d in devs:
+                w_shapes = [
+                    shape for source, shape in sourced
+                    if source is not None and source not in charged[d]
+                ]
+                charged[d].update(source for source, _ in sourced)
+                w_bytes = sum(get_piece_shape(s).size_bytes for s in w_shapes)
+                if not w_bytes:
+                    continue
+                charge_resident([d], "params", w_bytes)
                 if serving is None:
-                    charge_resident(devs, "grads", w_bytes)
+                    charge_resident([d], "grads", w_bytes)
                     # a slot lives at its weight's update shard (the
                     # executor cuts it over every axis the weight is
                     # replicated on: update_shard_ways)
-                    charge_resident(devs, "opt_state", slots * sum(
+                    charge_resident([d], "opt_state", slots * sum(
                         -(-get_piece_shape(s).size_bytes
                           // update_shard_ways(s, machine_devices))
                         for s in w_shapes

@@ -24,8 +24,11 @@ PCG005 escaped-sum-degree   a tensor with sum_degree > 1 reaches a graph
 PCG006 dead-output          pure data-movement node (Repartition/Replicate/
                             Noop) with no consumers, or an unused
                             Input/Weight layer (warning)
-PCG007 not-series-parallel  the PCG is not SP-decomposable, so the
-                            machine-mapping DP cannot price it
+PCG007 not-series-parallel  the PCG is no series-parallel graph even with
+                            its sources collapsed: the machine-mapping DP
+                            prices it on a levelled tree (stages in series)
+                            and cannot map its branches side by side
+                            (warning)
 PCG008 overlap-annotation   a fused-overlap annotation (--overlap lowering
                             plan) names an edge whose adjacent op does not
                             actually consume/produce the moved tensor:
@@ -72,7 +75,7 @@ PCG_RULE_CATALOG: Dict[str, str] = {
     "PCG004": "dtype-mismatch: recorded dtype != propagated dtype",
     "PCG005": "escaped-sum-degree: undischarged partial sums reach a graph sink",
     "PCG006": "dead-output: data-movement node or weight/input with no consumers",
-    "PCG007": "not-series-parallel: PCG is not SP-decomposable",
+    "PCG007": "not-series-parallel: PCG is priced on a levelled tree",
     "PCG008": "overlap-annotation: fused-overlap edge's adjacent op does not consume/produce the moved tensor",
     # pipeline-stage rules (ISSUE 13 — pcg/pipeline.analyze_pipeline is
     # the shared structural analysis; the 1F1B executor and both
@@ -556,13 +559,8 @@ def verify_machine_mapping(
 
     if _tree_and_paths is not None:
         tree, path_of = _tree_and_paths
-        if tree is None:  # caller already found the PCG non-SP: no MV003
-            return diags
     else:
-        try:
-            tree, path_of = get_machine_mapping_problem_tree(pcg)
-        except ValueError:
-            return diags  # PCG007 is reported by verify_pcg
+        tree, path_of = get_machine_mapping_problem_tree(pcg)
     parallel_prefixes: List[tuple] = []
 
     def collect_splits(t, prefix):
@@ -607,7 +605,8 @@ def verify_pcg(
     check_sp: bool = True,
     overlap_plan: Optional[dict] = None,
 ) -> List[Diagnostic]:
-    """The full verifier: structural rules, SP-decomposability, (when a
+    """The full verifier: structural rules, the PCG007 note on a graph that
+    is no series-parallel one, (when a
     machine spec + mapping are given) machine-view legality, and (when an
     overlap lowering plan is given) the PCG008 fused-edge adjacency
     check."""
@@ -619,31 +618,29 @@ def verify_pcg(
     tree_and_paths = None
     if check_sp or (machine_spec is not None and mapping is not None):
         from flexflow_tpu.compiler.machine_mapping.problem_tree import (
-            get_machine_mapping_problem_tree,
+            machine_mapping_problem_tree,
         )
 
-        try:
-            tree_and_paths = get_machine_mapping_problem_tree(pcg)
-        except ValueError as e:
-            if check_sp:
-                diags.append(
-                    error(
-                        "PCG007",
-                        f"not series-parallel decomposable: {e}",
-                        hint="the machine-mapping DP requires an SP graph; "
-                        "check for cross-branch edges the normalization "
-                        "passes should have removed",
-                    )
+        tree, path_of, levelled = machine_mapping_problem_tree(pcg)
+        tree_and_paths = (tree, path_of)
+        if check_sp and levelled:
+            diags.append(
+                warning(
+                    "PCG007",
+                    "no series-parallel graph, sources collapsed or not: "
+                    "the machine-mapping DP prices it on a levelled tree",
+                    hint="tensors with several readers that share readers "
+                    "only in part; the plan maps no two of its branches "
+                    "side by side",
                 )
+            )
     if machine_spec is not None and mapping is not None:
-        # (None, None) tells the MV pass the PCG is known non-SP: per-node
-        # view checks still run, only the split-level MV003 is skipped
         diags.extend(
             verify_machine_mapping(
                 pcg,
                 machine_spec,
                 mapping,
-                _tree_and_paths=tree_and_paths or (None, None),
+                _tree_and_paths=tree_and_paths,
             )
         )
     return diags

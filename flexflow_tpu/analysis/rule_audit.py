@@ -365,6 +365,10 @@ def _default_attrs(op_type: OperatorType, eq: Dict, div: Dict, size: int):
         from flexflow_tpu.op_attrs.ops import LabelCrossEntropyAttrs
 
         return LabelCrossEntropyAttrs()
+    if op_type == OperatorType.MEAN_LOSS:
+        from flexflow_tpu.op_attrs.ops import MeanLossAttrs
+
+        return MeanLossAttrs()
     if op_type == OperatorType.REPARTITION:
         return RepartitionAttrs(
             eq.get("repartition_dim", 0), eq.get("repartition_degree", 2)
@@ -410,6 +414,7 @@ def _data_shape_table(op_type: OperatorType, size: int, arity: int):
         OperatorType.SHORT_CONV: ((S, S, S),),
         OperatorType.SELECTIVE_SCAN: ((S, S, S),),
         OperatorType.LABEL_LOSS: ((S, S, S), (S, S)),
+        OperatorType.MEAN_LOSS: ((S, S),),
         OperatorType.REPARTITION: ((S, S, S),),
         OperatorType.COMBINE: ((S, S, S),),
         OperatorType.REPLICATE: ((S, S, S),),

@@ -245,23 +245,30 @@ def operator_task_space(pcg: ParallelComputationGraph, node: Node) -> OperatorTa
 # ---------------------------------------------------------------------------
 
 
-def _from_weight(pcg: ParallelComputationGraph, v) -> bool:
-    """Does `v` trace back to a Weight layer through single-input
-    parallel-op wrappers only (i.e. is it a resident, possibly resharded,
-    parameter rather than a per-step activation)?"""
+def weight_source(pcg: ParallelComputationGraph, v) -> Optional[Node]:
+    """The Weight layer `v` traces back to through single-input parallel-op
+    wrappers only, or None: a resident, possibly resharded, parameter and
+    not a per-step activation. Two readers of one weight (a tied head, a
+    looped model's layer applied again) reach the SAME node here, each
+    through its own wrappers."""
     from flexflow_tpu.op_attrs.core import is_parallel_op
     from flexflow_tpu.op_attrs.ops import WeightAttrs
 
     while True:
         attrs = pcg.op_attrs(v.node)
         if isinstance(attrs, WeightAttrs):
-            return True
+            return v.node
         if not is_parallel_op(attrs):
-            return False
+            return None
         ins = pcg.inputs_of(v.node)
         if len(ins) != 1:
-            return False
+            return None
         v = ins[0]
+
+
+def _from_weight(pcg: ParallelComputationGraph, v) -> bool:
+    """Does `v` trace back to a Weight layer (`weight_source`)?"""
+    return weight_source(pcg, v) is not None
 
 
 def _leaf_key(
@@ -427,16 +434,57 @@ def _source_collapsed_decomposition(pcg):
     return expand(sp)
 
 
+def _levelled_decomposition(pcg):
+    """A series of parallel stages for ANY acyclic PCG: stage k holds the
+    nodes whose longest path from a source has k edges (no edge joins two
+    nodes of one stage; every edge runs from an earlier stage to a later
+    one, which a series of the stages preserves). The last resort where the
+    data flow itself is no series-parallel graph, sources collapsed or not:
+    tensors with several readers that share readers only in part (a looped
+    model's exit probabilities, each read by its own loss node AND by the
+    one entropy term; two loss nodes over one stream). It costs the tree
+    the branches' independence (stages are in series where two branches
+    could have been mapped side by side) and nothing else: as with the fake
+    source edges, only the TREE is shaped here, and the movements across
+    each series split still come from the real graph's edges."""
+    from flexflow_tpu.utils.graph.series_parallel import (
+        ParallelSplit,
+        SeriesSplit,
+    )
+
+    g = pcg.digraph()
+    depth: Dict[Node, int] = {}
+    for n in get_topological_ordering(g):
+        depth[n] = 1 + max((depth[p] for p in g.predecessors(n)), default=-1)
+    stages: Dict[int, List[Node]] = {}
+    for n, d in depth.items():
+        stages.setdefault(d, []).append(n)
+    children = tuple(
+        nodes[0] if len(nodes) == 1 else ParallelSplit(frozenset(nodes))
+        for _, nodes in sorted(stages.items())
+    )
+    return children[0] if len(children) == 1 else SeriesSplit(children)
+
+
 def get_machine_mapping_problem_tree(
     pcg: ParallelComputationGraph,
 ) -> Tuple[MachineMappingProblemTree, Dict[Node, BinaryTreePath]]:
+    """(tree, pcg node -> path) of `machine_mapping_problem_tree`."""
+    tree, path_of, _ = machine_mapping_problem_tree(pcg)
+    return tree, path_of
+
+
+def machine_mapping_problem_tree(
+    pcg: ParallelComputationGraph,
+) -> Tuple[MachineMappingProblemTree, Dict[Node, BinaryTreePath], bool]:
     """SP-decompose the (transitively reduced) PCG and build the problem
     tree, embedding the abstracted cross-split tensor movements in each
-    series split. Returns (tree, pcg node -> path).
+    series split. Returns (tree, pcg node -> path, levelled: the PCG is no
+    series-parallel graph and the tree is `_levelled_decomposition`'s).
 
-    Raises ValueError if the PCG is not series-parallel (the Unity search
-    applies only to SP-decomposable graphs; reference
-    get_pcg_series_parallel_decomposition).
+    A PCG that is no series-parallel graph even with its sources collapsed
+    (reference get_pcg_series_parallel_decomposition refuses those) gets the
+    tree of `_levelled_decomposition`.
     """
     from flexflow_tpu.pcg.pipeline import pipeline_contexts
 
@@ -457,8 +505,10 @@ def get_machine_mapping_problem_tree(
         # wrapper chains below sources (strategy-template rewrites) defeat
         # the plain augmentation; collapse them first
         sp = _source_collapsed_decomposition(pcg)
-    if sp is None:
-        raise ValueError("PCG is not series-parallel decomposable")
+    levelled = sp is None
+    if levelled:
+        # the data flow itself is no series-parallel graph
+        sp = _levelled_decomposition(pcg)
     btree = sp_decomposition_to_binary(sp)
 
     # Pass 1: absolute path of every PCG node + split kind at every internal
@@ -561,4 +611,4 @@ def get_machine_mapping_problem_tree(
         return intern(MMProblemTreeSeriesSplit(movement_at(prefix), left, right))
 
     tree = build(btree, ())
-    return tree, path_of
+    return tree, path_of, levelled

@@ -153,8 +153,8 @@ def _mcmc_optimize(
     start = evaluate_pcg(pcg, context, machine_spec, mm_cache)
     if start is None:
         raise ValueError(
-            "initial PCG is not SP-decomposable or has no feasible machine "
-            "mapping on the given machine spec"
+            "initial PCG has no feasible machine mapping on the given "
+            "machine spec"
         )
     serial_runtime = start.runtime
     degree_cap = machine_spec.num_devices

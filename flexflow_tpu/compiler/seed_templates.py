@@ -233,6 +233,7 @@ _DP_TYPES = frozenset(
         # a loss node's scalar is a partial sum a batch shard, completed by
         # a Reduction (data_parallel_label_loss_rule)
         OperatorType.LABEL_LOSS,
+        OperatorType.MEAN_LOSS,
     }
 )
 
@@ -259,7 +260,8 @@ def data_parallel_plan(k: int) -> PlanFn:
         return WrapSpec(
             [RepartitionAttrs(0, k)] * len(data_vals),
             [ReplicateAttrs(k)] * len(weight_vals),
-            [ReductionAttrs(k) if t == OperatorType.LABEL_LOSS
+            [ReductionAttrs(k)
+             if t in (OperatorType.LABEL_LOSS, OperatorType.MEAN_LOSS)
              else CombineAttrs(0, k)],
         )
 

@@ -357,8 +357,8 @@ def evaluate_pcg(
     machine_spec: MachineSpecification,
     cache: MachineMappingCache,
 ) -> Optional[GraphOptimizeResult]:
-    """Cost a PCG via its optimal machine mapping. Returns None if the PCG is
-    not SP-decomposable or no feasible mapping exists.
+    """Cost a PCG via its optimal machine mapping. Returns None if no
+    feasible mapping exists.
 
     `cache` is required: the shared MachineMappingCache is what makes
     pricing cheap ACROSS candidates (successive substitutions leave most
@@ -367,11 +367,8 @@ def evaluate_pcg(
     disables that reuse — callers pricing a one-off PCG should still create
     the cache explicitly so the cost is visible at the call site."""
     assert cache is not None, "evaluate_pcg requires a (shared) cache"
-    try:
-        with search_phase("tree_build"):
-            tree, path_of = get_machine_mapping_problem_tree(pcg)
-    except ValueError:
-        return None
+    with search_phase("tree_build"):
+        tree, path_of = get_machine_mapping_problem_tree(pcg)
     with search_phase("dp"):
         result = get_optimal_machine_mapping(cache, context, tree, machine_spec)
     if result is None:
@@ -859,8 +856,8 @@ def _graph_optimize(
         memory_caused = False
         if getattr(context, "memory_budget_bytes", 0.0):
             # attribute the rejection before falling through: a PCG that
-            # is also infeasible WITHOUT the budget (non-SP, no mapping on
-            # the grid) must keep the accurate structural error, not a
+            # is also infeasible WITHOUT the budget (no mapping on the
+            # grid) must keep the accurate structural error, not a
             # misleading memory diagnosis. Fresh cache on purpose — a
             # MachineMappingCache is only valid for one context.
             import dataclasses as _dc
@@ -872,8 +869,8 @@ def _graph_optimize(
             )
         if not memory_caused:
             raise ValueError(
-                "initial PCG is not SP-decomposable or has no feasible "
-                "machine mapping on the given machine spec"
+                "initial PCG has no feasible machine mapping on the given "
+                "machine spec"
             )
         # under a memory budget the SERIAL plan is often exactly what
         # cannot fit (that is the point of searching) — fall through to

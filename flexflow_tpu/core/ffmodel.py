@@ -638,7 +638,10 @@ class FFModel:
         logit = self._unwrap(logit_tensor or self._last_tensor)
         self._label_dtype = (
             jnp.int32
-            if loss_type == LossFunction.SPARSE_CATEGORICAL_CROSSENTROPY
+            if loss_type in (
+                LossFunction.SPARSE_CATEGORICAL_CROSSENTROPY,
+                LossFunction.LOSS_NODES,
+            )
             else jnp.float32
         )
 
@@ -1060,8 +1063,15 @@ class FFModel:
                 shape = pcg.tensor_shape(val)
                 if (
                     shape.sizes() == want_sizes
-                    and all(d == 1 for d in shape.shard_degrees())
                     and shape.sum_degree == 1
+                    and (
+                        all(d == 1 for d in shape.shard_degrees())
+                        # a step whose loss is its loss nodes alone reads
+                        # the returned logits nowhere: a plan that leaves
+                        # them in shards (a data-parallel one has no
+                        # Combine after the head) is taken as it is
+                        or self.loss_attrs.loss_type == LossFunction.LOSS_NODES
+                    )
                 ):
                     return val
                 return None
@@ -3159,15 +3169,20 @@ def _find_aux_outputs(graph) -> List[DataflowOutput]:
     """Aux-loss outputs, found structurally (so they survive substitutions
     that rebuild node identity): any secondary output of an Experts op with
     an auxiliary coefficient (lambda_bal, lambda_z) is that scalar, and so
-    is the output of a loss node (`LabelCrossEntropyAttrs`)."""
-    from flexflow_tpu.op_attrs.ops import ExpertsAttrs, LabelCrossEntropyAttrs
+    is the output of a loss node (`LabelCrossEntropyAttrs`,
+    `MeanLossAttrs`)."""
+    from flexflow_tpu.op_attrs.ops import (
+        ExpertsAttrs,
+        LabelCrossEntropyAttrs,
+        MeanLossAttrs,
+    )
 
     aux = []
     for n in graph.topological_ordering():
         attrs = graph.op_attrs(n)
         if isinstance(attrs, ExpertsAttrs) and attrs.has_aux:
             aux.extend(graph.outputs_of(n)[1:])
-        elif isinstance(attrs, LabelCrossEntropyAttrs):
+        elif isinstance(attrs, (LabelCrossEntropyAttrs, MeanLossAttrs)):
             aux.extend(graph.outputs_of(n))
     return aux
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from flexflow_tpu.op_attrs.ops.loss_functions import (
     LabelCrossEntropyAttrs,
     LossAttrs,
+    MeanLossAttrs,
     LossFunction,
     NonconfigurableLossAttrs,
     SparseCategoricalCrossEntropyLossAttrs,
@@ -120,18 +121,74 @@ def _masked_scce_bwd(res, g):
 _fused_masked_scce.defvjp(_masked_scce_fwd, _masked_scce_bwd)
 
 
+@jax.custom_vjp
+def _fused_weighted_scce(
+    logit: jnp.ndarray, label: jnp.ndarray, weight: jnp.ndarray
+) -> jnp.ndarray:
+    """`_fused_masked_scce` with a weight a position, `sum_i w_i CE_i / n`
+    over the n positions with a label: the same two passes over the logits
+    in their own dtype, and a gradient to the weights (each position's own
+    cross-entropy over n, kept from the forward as one float a position)."""
+    return _weighted_scce_fwd(logit, label, weight)[0]
+
+
+def _weighted_scce_fwd(logit, label, weight):
+    lse = _row_lse(logit)
+    label = label.astype(jnp.int32)
+    valid = label >= 0
+    count = jnp.maximum(jnp.sum(valid), 1).astype(jnp.float32)
+    rows = jnp.where(valid, lse - _picked(logit, jnp.maximum(label, 0)), 0.0)
+    loss = jnp.sum(rows * weight.astype(jnp.float32)) / count
+    return loss, (logit, label, lse, count, rows, weight)
+
+
+def _weighted_scce_bwd(res, g):
+    logit, label, lse, count, rows, weight = res
+    diff = _probs_minus_onehot(logit, label, lse)
+    scale = jnp.where(
+        label >= 0, g * weight.astype(jnp.float32) / count, 0.0
+    ).astype(diff.dtype)
+    dweight = (g * rows / count).astype(weight.dtype)  # rows are 0 off-label
+    return (diff * scale[..., None]).astype(logit.dtype), None, dweight
+
+
+_fused_weighted_scce.defvjp(_weighted_scce_fwd, _weighted_scce_bwd)
+
+
 def label_cross_entropy(
-    attrs: LabelCrossEntropyAttrs, logit: jnp.ndarray, label: jnp.ndarray
+    attrs: LabelCrossEntropyAttrs, logit: jnp.ndarray, label: jnp.ndarray,
+    weight: jnp.ndarray = None,
 ) -> jnp.ndarray:
     """The node's scalar [1]: `attrs.weight` times the mean cross-entropy
-    over the positions with a label, through the fused form. The unweighted
-    mean goes to the step's loss-term counter
-    (`observability/trace.loss_terms`)."""
+    over the positions with a label (each under its own `weight`, where the
+    node has position weights), through the fused form. The unweighted mean
+    goes to the step's loss-term counter (`observability/trace.loss_terms`),
+    with the weights' mean over the same positions as the term's `mass`."""
     from flexflow_tpu.observability import trace
 
-    mean = _fused_masked_scce(logit, label)
-    trace.record_loss_term(attrs.weight, mean)
+    if weight is None:
+        mean = _fused_masked_scce(logit, label)
+        trace.record_loss_term(attrs.weight, mean)
+    else:
+        mean = _fused_weighted_scce(logit, label, weight)
+        valid = label >= 0
+        mass = jnp.sum(
+            jnp.where(valid, weight.astype(jnp.float32), 0.0)
+        ) / jnp.maximum(jnp.sum(valid), 1)
+        trace.record_loss_term(
+            attrs.weight, mean, mass=jax.lax.stop_gradient(mass)
+        )
     return (mean * attrs.weight).reshape(1)  # float32, whatever the logits are
+
+
+def mean_loss(attrs: MeanLossAttrs, value: jnp.ndarray) -> jnp.ndarray:
+    """The node's scalar [1]: `attrs.weight` times the float32 mean of
+    `value`; the unweighted mean goes to the loss-term counter."""
+    from flexflow_tpu.observability import trace
+
+    mean = jnp.mean(value.astype(jnp.float32))
+    trace.record_loss_term(attrs.weight, mean)
+    return (mean * attrs.weight).reshape(1)
 
 
 def loss_forward(attrs: LossAttrs, logit: jnp.ndarray, label: jnp.ndarray) -> jnp.ndarray:
@@ -154,6 +211,10 @@ def loss_forward(attrs: LossAttrs, logit: jnp.ndarray, label: jnp.ndarray) -> jn
         return jnp.mean(jnp.abs(logit - label))
     if fn == LossFunction.IDENTITY:
         return jnp.mean(logit)
+    if fn == LossFunction.LOSS_NODES:
+        # the caller adds the graph's loss nodes to this; the logits are
+        # some node's input already and no term of their own
+        return jnp.zeros((), jnp.float32)
     raise ValueError(f"unknown loss {fn}")
 
 

@@ -1231,12 +1231,20 @@ def forward(
             out = out + weights[2]
         return [out]
 
-    from flexflow_tpu.op_attrs.ops.loss_functions import LabelCrossEntropyAttrs
+    from flexflow_tpu.op_attrs.ops.loss_functions import (
+        LabelCrossEntropyAttrs,
+        MeanLossAttrs,
+    )
 
     if isinstance(attrs, LabelCrossEntropyAttrs):
         from flexflow_tpu.kernels.loss import label_cross_entropy
 
-        return [label_cross_entropy(attrs, inputs[0], inputs[1])]
+        return [label_cross_entropy(attrs, *inputs)]
+
+    if isinstance(attrs, MeanLossAttrs):
+        from flexflow_tpu.kernels.loss import mean_loss
+
+        return [mean_loss(attrs, inputs[0])]
 
     if isinstance(attrs, ConcatAttrs):
         return [jnp.concatenate(inputs, axis=attrs.axis)]
@@ -1602,12 +1610,17 @@ def op_forward_flops(
             + w * (2 * attrs.conv_kernel + 9 * n + 2)
         )
 
-    from flexflow_tpu.op_attrs.ops.loss_functions import LabelCrossEntropyAttrs
+    from flexflow_tpu.op_attrs.ops.loss_functions import (
+        LabelCrossEntropyAttrs,
+        MeanLossAttrs,
+    )
 
     if isinstance(attrs, LabelCrossEntropyAttrs):
         # a maximum, an exponential, two sums and a pick an element of the
         # logits, whose one scalar says nothing of the passes
         return 5 * nelem(input_shapes[0])
+    if isinstance(attrs, MeanLossAttrs):
+        return nelem(input_shapes[0])
 
     total = sum(nelem(s) for s in output_shapes)
     return total

@@ -44,6 +44,7 @@ from flexflow_tpu.op_attrs.ops import InputAttrs, WeightAttrs
 from flexflow_tpu.op_attrs.ops.loss_functions import (
     LabelCrossEntropyAttrs,
     LossAttrs,
+    LossFunction,
 )
 from flexflow_tpu.pcg.computation_graph import ComputationGraph
 from flexflow_tpu.pcg.initializer import InitializerAttrs, initialize
@@ -139,32 +140,95 @@ def forward_interpreter(
     (the LM-head dX matmul fused with the final layer-norm grads ran at
     145 TF/s vs 178 standalone; profiled ~1.5 ms/step on the headline
     bench).
+
+    The nodes of one of the graph's `recompute_groups` are evaluated
+    together under ONE `jax.checkpoint`, when the first of them is reached:
+    what they compute inside is computed again in the backward pass.
     """
     env: Dict[DataflowOutput, jnp.ndarray] = {}
-    for n in cg.topological_ordering():
+
+    def run(n, slot_vals, rng):
+        """Node `n` on its slot values: its results, in output order."""
+        attrs = cg.op_attrs(n)
+        data_vals, weight_vals = split_slot_values(attrs, slot_vals)
+        # everything this node lowers to carries its name in the device
+        # trace (observability/trace.py)
+        with trace.node_scope(cg, n):
+            if n in barrier_nodes:
+                data_vals = [optimization_barrier(x) for x in data_vals]
+            op_rng = jax.random.fold_in(rng, n.idx) if rng is not None else None
+            return kernel_forward(
+                attrs, data_vals, weight_vals, train=train, rng=op_rng
+            )
+
+    def run_group(members):
+        """The group's nodes under one checkpoint. Everything a member reads
+        from outside is an argument; every member's outputs come back, and
+        so do the loss terms its loss nodes recorded (a value recorded
+        inside would be a tracer of the checkpointed function)."""
+        inside = {o for m in members for o in cg.outputs_of(m)}
+        outer = [
+            v for m in members for v in cg.inputs_of(m) if v not in inside
+        ]
+        missing = [v for v in outer if v not in env]
+        assert not missing, (
+            f"a recompute group reads {missing} before they are computed"
+        )
+        recorded = []  # (scope, weight, has a mass): static facts
+
+        def body(vals, rng):
+            from flexflow_tpu.observability import routing
+
+            vals = iter(vals)
+            local: Dict[DataflowOutput, jnp.ndarray] = {}
+            with trace.collecting_loss_terms() as terms, \
+                    routing.collecting() as held_rows:
+                for m in members:
+                    results = run(m, [
+                        local[v] if v in inside else next(vals)
+                        for v in cg.inputs_of(m)
+                    ], rng)
+                    local.update(zip(cg.outputs_of(m), results))
+            assert not held_rows, (
+                "a held expert share inside a recompute group: its routing "
+                "counters would leave the group as tracers"
+            )
+            recorded[:] = [(name, w, mass is not None) for name, w, _, mass in terms]
+            values = [(v, mass) for _, _, v, mass in terms]
+            return [local[o] for m in members for o in cg.outputs_of(m)], values
+
+        outs, values = jax.checkpoint(body)([env[v] for v in outer], rng)
+        env.update(zip((o for m in members for o in cg.outputs_of(m)), outs))
+        for (name, weight, _), (value, mass) in zip(recorded, values):
+            trace.record_loss_term(weight, value, scope=name, mass=mass)
+
+    order = cg.topological_ordering()
+    group_of: Dict[Node, List[Node]] = {}
+    if cg.recompute_groups:
+        position = {n: i for i, n in enumerate(order)}
+        for members in cg.recompute_groups:
+            members = sorted(members, key=position.__getitem__)
+            group_of.update((n, members) for n in members)
+    # the sources first: a group reads the weights of ALL its members when
+    # its first is reached
+    ops = []
+    for n in order:
         la = cg.layer_attrs(n)
-        attrs = la.attrs
-        outs = cg.outputs_of(n)
-        if isinstance(attrs, InputAttrs):
+        if isinstance(la.attrs, InputAttrs):
             key = la.name if la.name is not None and la.name in inputs else param_key(n)
             assert key in inputs, f"missing input binding for {la.name or key}"
-            env[outs[0]] = inputs[key]
-        elif isinstance(attrs, WeightAttrs):
-            env[outs[0]] = params[param_key(n)]
+            env[cg.outputs_of(n)[0]] = inputs[key]
+        elif isinstance(la.attrs, WeightAttrs):
+            env[cg.outputs_of(n)[0]] = params[param_key(n)]
         else:
-            slot_vals = [env[v] for v in cg.inputs_of(n)]
-            data_vals, weight_vals = split_slot_values(attrs, slot_vals)
-            # everything this node lowers to carries its name in the device
-            # trace (observability/trace.py)
-            with trace.node_scope(cg, n):
-                if n in barrier_nodes:
-                    data_vals = [optimization_barrier(x) for x in data_vals]
-                op_rng = (
-                    jax.random.fold_in(rng, n.idx) if rng is not None else None
-                )
-                results = kernel_forward(
-                    attrs, data_vals, weight_vals, train=train, rng=op_rng
-                )
+            ops.append(n)
+    for n in ops:
+        outs = cg.outputs_of(n)
+        if n in group_of:
+            if outs[0] not in env:
+                run_group(group_of[n])
+        else:
+            results = run(n, [env[v] for v in cg.inputs_of(n)], rng)
             for o, r in zip(outs, results):
                 env[o] = r
     return env
@@ -225,8 +289,14 @@ class ModelTrainingInstance:
             cg.inputs_of(n)[0].node for n in cg.topological_ordering()
             if isinstance(cg.op_attrs(n), LabelCrossEntropyAttrs)
         )
-        # [(scope, weight)] of the step's loss terms, as the last trace of
-        # the step recorded them; empty in a graph with one loss
+        if loss_attrs.loss_type == LossFunction.LOSS_NODES:
+            assert self.aux_loss_tensors, (
+                "loss_type loss_nodes on a graph without a loss node: the "
+                "step would have no loss"
+            )
+        # [(scope, weight, has a mass)] of the step's loss terms, as the
+        # last trace of the step recorded them; empty in a graph with one
+        # loss
         self.loss_term_names = []
         self._jit_step = None
         self._jit_fwd = None
@@ -276,18 +346,18 @@ class ModelTrainingInstance:
         logit = env[self.logit_tensor]
         with trace.step_scope("loss"):
             loss = loss_forward(self.loss_attrs, logit, label)
-            if terms:  # a graph with a loss node: the main loss is the first
-                terms.insert(0, ("ff.loss", 1.0, loss))
+            # a graph with a loss node: the main loss is the first term,
+            # where the step has one
+            if terms and self.loss_attrs.loss_type != LossFunction.LOSS_NODES:
+                terms.insert(0, ("ff.loss", 1.0, loss, None))
             for t in self.aux_loss_tensors:
                 loss = loss + jnp.sum(env[t].astype(loss.dtype))
         counters = {}
         if held_rows:
             counters[routing.ROUTING_KEY] = jnp.stack(held_rows)
         if terms:
-            self.loss_term_names = [(name, w) for name, w, _ in terms]
-            counters[trace.LOSS_TERMS_KEY] = jnp.stack(
-                [v.astype(jnp.float32) for _, _, v in terms]
-                + [jnp.ones((), jnp.float32)]
+            self.loss_term_names, counters[trace.LOSS_TERMS_KEY] = (
+                trace.loss_terms_vector(terms)
             )
         return loss, (logit, counters)
 

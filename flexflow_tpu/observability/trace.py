@@ -555,11 +555,13 @@ _KIND_NAMES = {
     "linear": "dense", "multihead_attention": "mha", "state_space": "ssm",
     "gated_delta": "kda", "short_conv": "shortconv", "selective_scan": "s6",
 }
-_NOT_IN_NAME = re.compile(r"[^A-Za-z0-9_.\-]")
+# `#` carries the application of a `SharedBlock`'s layer (`attn3#2`); not
+# `@`: XLA cuts an op_name at one, on the chip's compiler too
+_NOT_IN_NAME = re.compile(r"[^A-Za-z0-9_.\-#]")
 # the first `ff.` token of a name stack: a kind holds no dot, so the first
 # two dots split the scope; a name ends at the first `/` or `)`
 _SCOPE = re.compile(
-    r"(?<![A-Za-z0-9_.\-])ff\.([a-z0-9_]+)(?:\.([A-Za-z0-9_.\-]+))?"
+    r"(?<![A-Za-z0-9_.\-])ff\.([a-z0-9_]+)(?:\.([A-Za-z0-9_.\-#]+))?"
 )
 # a node may put a part of itself under a further scope inside its own
 # (`NODE_PARTS`: the state-space node its scan, its convolution with SiLU and
@@ -926,36 +928,62 @@ def collecting_loss_terms():
         _lowering.loss_terms = previous
 
 
-def record_loss_term(weight: float, value, scope: Optional[str] = None) -> None:
+def record_loss_term(
+    weight: float, value, scope: Optional[str] = None, mass=None
+) -> None:
     """One term of the step's loss: its weight in the sum and its UNWEIGHTED
     value (a float32 scalar tracer), under `scope` or the scope of the node
-    being lowered. Dropped where nobody collects."""
+    being lowered; `mass` the mean weight of a term that weighs its
+    positions (a looped model's exit: the share of the exit distribution
+    that left there). Dropped where nobody collects."""
     sink = getattr(_lowering, "loss_terms", None)
     if sink is not None:
         name = scope or getattr(_lowering, "scope", None) or f"term{len(sink)}"
-        sink.append((name, float(weight), value))
+        sink.append((name, float(weight), value, mass))
+
+
+def loss_terms_vector(terms):
+    """([(scope, weight, has a mass)], the float32 vector a step returns for
+    them: each term's value, the masses of the terms that have one, a 1)."""
+    import jax.numpy as jnp
+
+    names = [(name, w, mass is not None) for name, w, _, mass in terms]
+    values = [v for _, _, v, _ in terms] + [
+        m for _, _, _, m in terms if m is not None
+    ]
+    return names, jnp.stack(
+        [v.astype(jnp.float32) for v in values] + [jnp.ones((), jnp.float32)]
+    )
 
 
 def publish_loss_terms(terms, sums) -> None:
-    """`terms` [(scope, weight)] as they were recorded and `sums` [terms + 1]
-    summed over the steps of one `fit` call: each term's values, then the
-    steps."""
+    """`terms` [(scope, weight, has a mass)] as they were recorded and `sums`
+    as `loss_terms_vector` lays a step's out, summed over the steps of one
+    `fit` call."""
     global _published_loss_terms
     import numpy as np
 
     sums = np.asarray(sums, dtype=np.float64)
+    steps = sums[-1]
     _published_loss_terms = {
-        name: {"weight": weight, "mean": float(total / sums[-1])}
-        for (name, weight), total in zip(terms, sums[:-1])
+        name: {"weight": weight, "mean": float(total / steps)}
+        for (name, weight, _), total in zip(terms, sums)
     }
+    with_mass = [name for name, _, has in terms if has]
+    for name, total in zip(with_mass, sums[len(terms):-1]):
+        _published_loss_terms[name]["mass"] = float(total / steps)
 
 
 def loss_terms() -> Optional[Dict[str, Dict[str, float]]]:
     """`{scope: {"weight", "mean"}}` of the last `fit` call of a graph with a
     loss node: each term of the training loss by the scope it is computed
-    under (`ff.loss` the main one, `ff.label_loss.<name>` a node's), its
-    weight in the sum and its unweighted mean over the call's steps; None
-    before any, and in a process whose graphs have one loss."""
+    under (`ff.loss` the main one, `ff.label_loss.<name>` and
+    `ff.mean_loss.<name>` a node's), its weight in the sum and its
+    unweighted mean over the call's steps, and `mass`, the mean weight a
+    position, of a node with position weights (a looped model's exit
+    `<name>#<pass>`: the four masses are the exit distribution's mean and
+    sum to one); None before any, and in a process whose graphs have one
+    loss."""
     return _published_loss_terms and {
         name: dict(term) for name, term in _published_loss_terms.items()
     }

@@ -77,7 +77,10 @@ from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 from flexflow_tpu.op_attrs.ops.short_conv import ShortConvAttrs
 from flexflow_tpu.op_attrs.ops.selective_scan import SelectiveScanAttrs
-from flexflow_tpu.op_attrs.ops.loss_functions import LabelCrossEntropyAttrs
+from flexflow_tpu.op_attrs.ops.loss_functions import (
+    LabelCrossEntropyAttrs,
+    MeanLossAttrs,
+)
 
 
 # mixers and the experts op: their attrs list their own weight slots
@@ -127,6 +130,7 @@ class OperatorType(enum.Enum):
     SHORT_CONV = "short_conv"  # double-gated short-convolution mixer
     SELECTIVE_SCAN = "selective_scan"  # Mamba-1 mixer (a recurrence a channel)
     LABEL_LOSS = "label_loss"  # cross-entropy against a label tensor of the graph
+    MEAN_LOSS = "mean_loss"  # the mean of a float tensor of the graph, as a loss term
     REPARTITION = "repartition"
     COMBINE = "combine"
     REPLICATE = "replicate"
@@ -154,7 +158,7 @@ OpAttrs = Union[
     ReverseAttrs, GatherAttrs, TopKAttrs, ReduceAttrs,
     GroupByAttrs, AggregateAttrs, ExpertsAttrs, StateSpaceAttrs,
     GatedDeltaAttrs, ShortConvAttrs, SelectiveScanAttrs,
-    LabelCrossEntropyAttrs,
+    LabelCrossEntropyAttrs, MeanLossAttrs,
     RepartitionAttrs, CombineAttrs, ReplicateAttrs, ReductionAttrs,
     StagePartitionAttrs, StageMergeAttrs,
 ]
@@ -198,6 +202,7 @@ _OP_TYPE_BY_ATTRS = {
     ShortConvAttrs: OperatorType.SHORT_CONV,
     SelectiveScanAttrs: OperatorType.SELECTIVE_SCAN,
     LabelCrossEntropyAttrs: OperatorType.LABEL_LOSS,
+    MeanLossAttrs: OperatorType.MEAN_LOSS,
     RepartitionAttrs: OperatorType.REPARTITION,
     CombineAttrs: OperatorType.COMBINE,
     ReplicateAttrs: OperatorType.REPLICATE,
@@ -274,10 +279,9 @@ def get_incoming_tensor_roles(attrs: OpAttrs) -> List[IncomingTensorRole]:
 def num_data_inputs(attrs: OpAttrs) -> int:
     if isinstance(attrs, (InputAttrs, WeightAttrs)):
         return 0
-    if isinstance(attrs, (
-        ElementBinaryAttrs, BatchMatmulAttrs, GatherAttrs,
-        LabelCrossEntropyAttrs,
-    )):
+    if isinstance(attrs, LabelCrossEntropyAttrs):
+        return 2 + attrs.position_weights
+    if isinstance(attrs, (ElementBinaryAttrs, BatchMatmulAttrs, GatherAttrs)):
         return 2
     if isinstance(attrs, GroupByAttrs):
         return 2

@@ -66,6 +66,7 @@ from flexflow_tpu.op_attrs.ops.loss_functions import (
     NonconfigurableLossAttrs,
     LossAttrs,
     LabelCrossEntropyAttrs,
+    MeanLossAttrs,
 )
 from flexflow_tpu.op_attrs.ops.moe import (
     GroupByAttrs,

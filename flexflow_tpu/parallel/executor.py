@@ -250,7 +250,8 @@ def _interpret(
                     attrs, data_vals, weight_vals, in_tensors, shardings, mesh
                 )
                 if sharded is not None:
-                    env[outs[0]] = sharded
+                    for o, r in zip(outs, sharded):
+                        env[o] = r
                     continue
                 sharded = _try_sharded_experts(
                     attrs, slot_vals, in_tensors, shardings, mesh
@@ -666,8 +667,9 @@ def _try_sharded_flash_mha(attrs, data_vals, weight_vals, in_tensors,
     projections, biases and the output matmul are traced in the global view
     either way (XLA partitions a plain matmul natively and the weight
     gradients stay ordinary HLO for the all-reduce combiner); only the
-    attention core is shard_mapped. Returns the [b, s, e] output, or None
-    for a node that is not this lowering's."""
+    attention core is shard_mapped. Returns the node's outputs (the [b, s, e]
+    result; a differential node with `kv_outputs` its keys and values
+    beside it), or None for a node that is not this lowering's."""
     axes = _attention_shard_axes(attrs, in_tensors, shardings, mesh)
     if axes is None:
         return None
@@ -677,8 +679,7 @@ def _try_sharded_flash_mha(attrs, data_vals, weight_vals, in_tensors,
     )
 
     with flash_mesh(mesh, *axes, interpret_default()):
-        (out,) = kernel_forward(attrs, data_vals, weight_vals)
-    return out
+        return kernel_forward(attrs, data_vals, weight_vals)
 
 
 def attention_routes(pcg, shardings, mesh) -> Dict[str, str]:
@@ -922,15 +923,16 @@ class DistributedTrainingInstance:
         class dim (they are rank-1 lower than the logits)."""
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from flexflow_tpu.op_attrs.ops.loss_functions import (
-            SparseCategoricalCrossEntropyLossAttrs,
-        )
+        from flexflow_tpu.op_attrs.ops.loss_functions import LossFunction
 
         s = self.shardings.get(self.loss_logit_tensor)
         if s is None:
             return None
         spec = list(s.spec)
-        if isinstance(self.loss_attrs, SparseCategoricalCrossEntropyLossAttrs):
+        if self.loss_attrs.loss_type in (
+            LossFunction.SPARSE_CATEGORICAL_CROSSENTROPY,
+            LossFunction.LOSS_NODES,  # class indices too, for the loss nodes
+        ):
             spec = spec[:-1]
         return NamedSharding(self.machine_mesh.mesh, P(*spec))
 

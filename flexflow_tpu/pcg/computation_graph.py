@@ -35,6 +35,17 @@ class TensorAttrs:
 class ComputationGraph(DataflowGraph):
     """DataflowGraph[LayerAttrs, TensorAttrs] with CG-specific queries."""
 
+    def __init__(self) -> None:
+        super().__init__()
+        # groups of op nodes whose forward is NOT kept for the backward pass
+        # but computed again there (`ComputationGraphBuilder.recompute`): a
+        # plan choice the model's author states. The graph interpreter
+        # honours it (`local_execution/training_backing.forward_interpreter`:
+        # the one-chip and data-parallel backends); a PCG holds no such
+        # groups, so the lift refuses a graph that has any
+        # (`parallel_computation_graph.pcg_from_computation_graph`)
+        self.recompute_groups: tuple = ()
+
     def layer_attrs(self, n: Node) -> LayerAttrs:
         return self.node_label(n)
 

@@ -71,6 +71,76 @@ from flexflow_tpu.utils.graph import DataflowOutput
 Tensor = DataflowOutput
 
 
+class SharedBlock:
+    """Layers applied again on the weights their first application made.
+
+        block = b.shared_block()
+        for t in range(4):
+            with block:
+                h = stack(h)        # the same builder calls every time
+
+    The first `with` records, in order, every layer built inside (its attrs,
+    its name and its weights' shapes) and every weight created; each later
+    one hands those weights back to the same calls through `reuse_weights`
+    and refuses a call that differs from the recorded one. The graph stays a
+    DAG: an application's nodes are new nodes, named `<name>#<application>`
+    (`attn3#2`: layer `attn3`, second pass), reading the weight nodes
+    `<name>.weight<i>` of the first. A scope and not a method that takes the
+    stack as a function: what a pass hands on (the stream, its logits, its
+    gate) stays ordinary Python between the `with`s, and `reuse_weights`,
+    which it is built on, is a scope too."""
+
+    def __init__(self, builder: "ComputationGraphBuilder") -> None:
+        self.builder = builder
+        self.applications = 0
+        self.weights: List[Tensor] = []
+        self._layers: List[tuple] = []
+        self._next = 0
+
+    def check(self, attrs, name, weight_shapes) -> None:
+        """One layer of the application being built: recorded on the first,
+        held to the record on every later one."""
+        call = (attrs, name, tuple(ws.dims for ws in weight_shapes))
+        if self.applications == 1:
+            self._layers.append(call)
+            return
+        want = self._layers[self._next] if self._next < len(self._layers) else None
+        if call != want:
+            raise ValueError(
+                f"application {self.applications} of a shared block builds "
+                f"{call} as its layer {self._next}, the first built {want}: "
+                "a block is applied again only as it was built, on inputs "
+                "of the same shape"
+            )
+        self._next += 1
+
+    def __enter__(self) -> "SharedBlock":
+        b = self.builder
+        assert b._block is None, "shared blocks do not nest"
+        self.applications += 1
+        self._next = 0
+        self._mark = len(b.weight_log)
+        self._outer_queue = b._reuse_queue
+        b._block = self
+        if self.applications > 1:
+            b._reuse_queue = list(self.weights)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        b = self.builder
+        left, b._reuse_queue = b._reuse_queue, self._outer_queue
+        b._block = None
+        if self.applications == 1:
+            self.weights = b.weight_log[self._mark:]
+        elif exc[0] is None and (left or self._next != len(self._layers)):
+            raise ValueError(
+                f"application {self.applications} of a shared block built "
+                f"{self._next} layers and left {len(left)} weight(s) "
+                f"unbound, the first built {len(self._layers)}"
+            )
+        return False
+
+
 class ComputationGraphBuilder:
     def __init__(self) -> None:
         self.graph = ComputationGraph()
@@ -83,6 +153,10 @@ class ComputationGraphBuilder:
         # (keras weight sharing re-binds them via reuse_weights)
         self.weight_log: List[Tensor] = []
         self._reuse_queue: Optional[List[Tensor]] = None
+        # the `SharedBlock` whose application is being built, if any
+        self._block: Optional["SharedBlock"] = None
+        # the op nodes of the `recompute` scope being built, if any
+        self._recompute: Optional[List] = None
 
     # -- low-level --------------------------------------------------------
 
@@ -92,12 +166,14 @@ class ComputationGraphBuilder:
         API's shared-layer contract (a layer applied at several call sites
         owns ONE set of parameters; gradients accumulate through the fanned
         -out weight node). Reference:
-        python/flexflow/keras/models/base_model.py functional reuse."""
+        python/flexflow/keras/models/base_model.py functional reuse.
+        Inside another such scope (a `shared_block`'s later application) the
+        inner list is bound first and the outer one goes on after it."""
         import contextlib
 
         @contextlib.contextmanager
         def scope():
-            assert self._reuse_queue is None, "reuse_weights scopes nest"
+            outer = self._reuse_queue
             self._reuse_queue = list(weights)
             try:
                 yield
@@ -105,9 +181,42 @@ class ComputationGraphBuilder:
                     f"{len(self._reuse_queue)} shared weight(s) left unbound"
                 )
             finally:
-                self._reuse_queue = None
+                self._reuse_queue = outer
 
         return scope()
+
+    def recompute(self):
+        """Context manager: the op nodes built inside are ONE group whose
+        forward results are not kept for the backward pass but computed
+        again there from the group's inputs (activation checkpointing: a
+        layer application of a looped model at 8,192 positions keeps a dozen
+        [tokens, hidden] tensors, four passes keep four dozen a layer). What
+        crosses the group's border is kept; weights are inputs of the
+        group. The one-chip and data-parallel backends honour it
+        (`forward_interpreter`); the lift to a PCG refuses a graph with a
+        group, so a searched plan never drops one silently."""
+        import contextlib
+
+        @contextlib.contextmanager
+        def scope():
+            assert self._recompute is None, "recompute scopes do not nest"
+            self._recompute = []
+            try:
+                yield
+            finally:
+                nodes, self._recompute = self._recompute, None
+            if nodes:
+                self.graph.recompute_groups = (
+                    *self.graph.recompute_groups, tuple(nodes)
+                )
+
+        return scope()
+
+    def shared_block(self) -> "SharedBlock":
+        """A stack of layers that is applied more than once on ONE set of
+        weights (a looped model's pass): every `with block:` is one
+        application. See `SharedBlock`."""
+        return SharedBlock(self)
 
     def add_layer(
         self,
@@ -122,6 +231,9 @@ class ComputationGraphBuilder:
         input_shapes = [self.graph.tensor_shape(t) for t in inputs]
         weight_shapes = get_weight_shapes(attrs, input_shapes)
         op_defaults = get_default_weight_initializers(attrs, len(weight_shapes))
+        block = self._block
+        if block is not None:
+            block.check(attrs, name, weight_shapes)
         weight_tensors: List[Tensor] = []
         for i, ws in enumerate(weight_shapes):
             if self._reuse_queue is not None:
@@ -141,6 +253,8 @@ class ComputationGraphBuilder:
                 else op_defaults[i]
                 or (GlorotUniformAttrs() if len(ws.dims) > 1 else ZeroInitializerAttrs())
             )
+            # a weight is named after its layer, not after the application
+            # that happened to create it
             wname = f"{name}.weight{i}" if name else None
             _, (w,) = self.graph.add_node(
                 LayerAttrs(WeightAttrs(ws), wname),
@@ -150,11 +264,15 @@ class ComputationGraphBuilder:
             weight_tensors.append(w)
             self.weight_log.append(w)
         out_shapes = get_output_shapes(attrs, input_shapes)
-        _, outs = self.graph.add_node(
+        if block is not None and name is not None:
+            name = f"{name}#{block.applications}"
+        node, outs = self.graph.add_node(
             LayerAttrs(attrs, name),
             list(inputs) + weight_tensors,
             [TensorAttrs(s) for s in out_shapes],
         )
+        if self._recompute is not None:
+            self._recompute.append(node)
         return outs
 
     # -- inputs / weights -------------------------------------------------
@@ -694,17 +812,36 @@ class ComputationGraphBuilder:
     def label_cross_entropy(
         self, logits: Tensor, labels: Tensor, weight: float = 1.0,
         name: Optional[str] = None,
+        position_weights: Optional[Tensor] = None,
     ) -> Tensor:
         """A loss node (`LabelCrossEntropyAttrs`): `weight` times the mean
         cross-entropy of `logits` [batch..., classes] against `labels`
         [batch...], an integer tensor of the graph, over the positions whose
-        label is not negative. The scalar [1] is recorded in
+        label is not negative; with `position_weights`, a float tensor of
+        the graph [batch...], each position's cross-entropy under its own
+        weight, which takes a gradient. The scalar [1] is recorded in
         `self.aux_loss_tensors`: training adds it to its loss."""
         from flexflow_tpu.op_attrs.ops import LabelCrossEntropyAttrs
 
+        inputs = [logits, labels]
+        if position_weights is not None:
+            inputs.append(position_weights)
         (out,) = self.add_layer(
-            LabelCrossEntropyAttrs(weight), [logits, labels], [], name
+            LabelCrossEntropyAttrs(weight, position_weights is not None),
+            inputs, [], name,
         )
+        self.aux_loss_tensors.append(out)
+        return out
+
+    def mean_loss(
+        self, value: Tensor, weight: float = 1.0, name: Optional[str] = None
+    ) -> Tensor:
+        """A loss node without labels (`MeanLossAttrs`): `weight` times the
+        mean of the float tensor `value`, a term of the training loss under
+        the node's name."""
+        from flexflow_tpu.op_attrs.ops import MeanLossAttrs
+
+        (out,) = self.add_layer(MeanLossAttrs(weight), [value], [], name)
         self.aux_loss_tensors.append(out)
         return out
 

@@ -433,7 +433,25 @@ def pcg_from_computation_graph(cg: ComputationGraph) -> ParallelComputationGraph
 
     Reference: the CG->PCG conversion at the start of compile
     (SURVEY.md §3.1); parallelism is then introduced by substitutions.
+
+    A graph with `recompute_groups` is refused: a PCG holds none, and what
+    is made from one (a searched plan and its executor, the memory model)
+    would keep, and price, every activation the groups drop.
     """
+    if cg.recompute_groups:
+        first = ", ".join(
+            cg.layer_attrs(n).name or f"n{n.idx}"
+            for n in cg.recompute_groups[0][:4]
+        )
+        raise ValueError(
+            f"the graph states {len(cg.recompute_groups)} recompute group(s) "
+            f"(the first: {first}, ...); a parallel computation graph holds "
+            "none, so a searched plan, its executor and the memory model "
+            "would keep every activation the groups drop. Compile on one "
+            "device or with only_data_parallel (the graph interpreter "
+            "honours the groups), or build the graph without `recompute` "
+            "scopes."
+        )
     pcg = ParallelComputationGraph()
     value_map: Dict[DataflowOutput, DataflowOutput] = {}
     for n in cg.topological_ordering():

@@ -839,11 +839,13 @@ def test_bf16_compute_is_inside_its_tolerance_and_outside_float32s():
 
 
 def test_two_devices_train_the_step_on_the_data_parallel_backend():
-    """The whole step on two devices. A weight read by two nodes (the shared
-    embedding and head) makes the PCG no series-parallel graph, which the
-    search's machine mapping needs, so this graph takes the data-parallel
-    backend (GSPMD over the batch; ROADMAP, Reach): the loss is the
-    one-device loss, both terms are counted, and a step reduces it."""
+    """The whole step on two devices, on the data-parallel backend (GSPMD
+    over the batch; what `compile` picks without a search budget): the loss
+    is the one-device loss, both terms are counted, and a step reduces it.
+    Its data flow is no series-parallel graph (two loss terms over one
+    stream and one set of labels), which the search's machine mapping
+    refused before PR 62 ("seed ... is unmappable"); it prices a levelled
+    tree now, and the forced data-parallel seed gives the same loss."""
     from flexflow_tpu.parallel.data_parallel import DataParallelTrainingInstance
 
     seq = 24
@@ -851,9 +853,8 @@ def test_two_devices_train_the_step_on_the_data_parallel_backend():
     one = compiled_model(seq, max_devices=1)
     two = compiled_model(seq, max_devices=2)
     assert isinstance(two.instance, DataParallelTrainingInstance)
-    with pytest.raises(ValueError, match="unmappable"):
-        compiled_model(seq, max_devices=2, search_budget=2,
-                       force_strategy_seed="dp2xtp1xsp1")
+    searched = compiled_model(seq, max_devices=2, search_budget=2,
+                              force_strategy_seed="dp2xtp1xsp1")
     named = bench.named_parameters(two.instance, two.params)
     from test_olmoe import weight_keys
 
@@ -861,6 +862,12 @@ def test_two_devices_train_the_step_on_the_data_parallel_backend():
     one.params = {keys[name]: jnp.asarray(np.asarray(w)) for name, w in named.items()}
     first = system_loss(two, inputs, labels)
     assert abs(first - system_loss(one, inputs, labels)) <= F32_LOSS
+    keys = weight_keys(searched.instance)
+    searched.params = {
+        keys[name]: jax.device_put(np.asarray(w), searched.params[keys[name]].sharding)
+        for name, w in named.items()
+    }
+    assert abs(first - system_loss(searched, inputs, labels)) <= F32_LOSS
     two.fit(inputs, labels, epochs=1, shuffle=False, verbose=False)
     assert system_loss(two, inputs, labels) < first - 0.01
     assert list(trace.loss_terms()) == ["ff.loss", "ff.label_loss.mtp_loss"]

@@ -477,21 +477,22 @@ class TestDPMemoryPruner:
         # bare inf that would poison provenance JSON
         assert result.serial_runtime is None
 
-    def test_structural_infeasibility_not_blamed_on_budget(self):
-        """A non-SP graph under a GENEROUS budget keeps the accurate
-        structural error instead of a misleading memory diagnosis."""
+    def test_a_graph_that_is_not_series_parallel_is_priced_under_a_budget(self):
+        """A graph with an interior N-shape was refused before PR 62 (and the
+        refusal had to be kept apart from a memory diagnosis); it is priced
+        on a levelled tree now, under a generous budget like any other."""
         from flexflow_tpu.compiler import OptimizerConfig, graph_optimize
         from flexflow_tpu.substitutions import generate_parallelization_rules
         from test_static_analysis import bad_pcg007_non_sp
 
-        with pytest.raises(ValueError, match="not SP-decomposable"):
-            graph_optimize(
-                bad_pcg007_non_sp(),
-                _context(budget=float(2**40)),
-                SPEC8,
-                generate_parallelization_rules([2]),
-                OptimizerConfig(alpha=1.3, budget=2),
-            )
+        result = graph_optimize(
+            bad_pcg007_non_sp(),
+            _context(budget=float(2**40)),
+            SPEC8,
+            generate_parallelization_rules([2]),
+            OptimizerConfig(alpha=1.3, budget=2),
+        )
+        assert result.runtime > 0 and result.machine_mapping
 
 
 # ---------------------------------------------------------------------------

@@ -797,10 +797,11 @@ def test_parameter_count_is_the_configurations():
 
 
 def test_two_devices_train_the_step_on_the_data_parallel_backend():
-    """Tensors read by many later nodes and a weight read by two make the PCG
-    no series-parallel graph, which the search's machine mapping needs, so
-    this graph takes the data-parallel backend (GSPMD over the batch): the
-    loss is the one-device loss and a step reduces it."""
+    """The step on the data-parallel backend (GSPMD over the batch; what
+    `compile` picks without a search budget): the loss is the one-device
+    loss and a step reduces it. (Tensors read by many later nodes make the
+    PCG no series-parallel graph; the search prices a levelled tree for it
+    since PR 62, `tests/test_ouro.py`.)"""
     from flexflow_tpu.parallel.data_parallel import DataParallelTrainingInstance
 
     from test_olmoe import weight_keys

@@ -1445,6 +1445,14 @@ if __name__ == "__main__":
         print(json.dumps(cell_step_bytes("phi4miniflash_s4096_1chip", root)))
     elif argv and argv[0] == "mellum2_step":
         print(json.dumps(cell_step_bytes("mellum2_12b_s8192_1chip", root)))
+    elif argv and argv[0] == "ouro_step":
+        # `ouro_step <layers>`: the looped cell's whole step at that depth
+        layers = int(argv[1]) if len(argv) > 1 else None
+        cut = {} if layers is None else {
+            "num_hidden_layers": layers,
+            "layer_types": ["full_attention"] * layers,
+        }
+        print(json.dumps(cell_step_bytes("ouro26b_s8192_1chip", root, **cut)))
     elif argv and argv[0] == "mellum2":
         print(json.dumps(check_mellum2()))
     elif argv:

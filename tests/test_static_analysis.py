@@ -246,7 +246,11 @@ class TestVerifierNegativePaths:
         assert "PCG006" in rule_ids(verify_pcg(bad_pcg006_dangling_repartition()))
 
     def test_pcg007_not_series_parallel(self):
-        assert "PCG007" in rule_ids(verify_pcg(bad_pcg007_non_sp()))
+        """A warning since PR 62: such a graph is priced on a levelled tree
+        (`problem_tree._levelled_decomposition`) and no longer refused."""
+        diags = verify_pcg(bad_pcg007_non_sp())
+        assert "PCG007" in {d.rule_id for d in diags}
+        assert not errors_of(diags)
 
     def test_mv001_view_arity(self):
         g = _branch_pcg()
@@ -1137,8 +1141,10 @@ def _write_strategy(tmp_path, name, pcg, mapping):
 
 @pytest.mark.filterwarnings("ignore")
 def test_ffcheck_cli_seeded_violations(tmp_path):
-    """One subprocess run over nine violating documents: exit 1 and one
-    structured JSON diagnostic per seeded rule id."""
+    """One subprocess run over nine seeded documents: exit 1 and one
+    structured JSON diagnostic per seeded rule id; PCG007 (a graph that is
+    no series-parallel one: priced on a levelled tree since PR 62) is
+    reported as a warning, and alone does not fail the gate."""
     g = _branch_pcg()
     arity = _branch_mapping(g)
     (addn,) = [n for n in g.nodes if g.layer_attrs(n).name == "add"]
@@ -1156,7 +1162,6 @@ def test_ffcheck_cli_seeded_violations(tmp_path):
         "PCG006": _write_graph(
             tmp_path, "pcg006.json", bad_pcg006_dangling_repartition()
         ),
-        "PCG007": _write_graph(tmp_path, "pcg007.json", bad_pcg007_non_sp()),
         "MV001": _write_strategy(tmp_path, "mv001.json", g, arity),
         "MV002": _write_strategy(
             tmp_path, "mv002.json", g, _branch_mapping(g, a_stride=4)
@@ -1166,6 +1171,9 @@ def test_ffcheck_cli_seeded_violations(tmp_path):
         ),
     }
     assert len(files) >= 8
+    warned = {
+        "PCG007": _write_graph(tmp_path, "pcg007.json", bad_pcg007_non_sp()),
+    }
     proc = subprocess.run(
         [
             sys.executable,
@@ -1174,6 +1182,7 @@ def test_ffcheck_cli_seeded_violations(tmp_path):
             "--nodes", "1",
             "--devices-per-node", "4",
             *files.values(),
+            *warned.values(),
         ],
         capture_output=True,
         text=True,
@@ -1185,12 +1194,17 @@ def test_ffcheck_cli_seeded_violations(tmp_path):
     for d in diags:
         assert {"rule_id", "severity", "message"} <= set(d)
         if d.get("path"):
-            by_path.setdefault(os.path.basename(d["path"]), set()).add(
+            by_path.setdefault(os.path.basename(d["path"]), {})[
                 d["rule_id"]
-            )
-    for rule, path in files.items():
-        got = by_path.get(os.path.basename(path), set())
+            ] = d["severity"]
+    for rule, path in {**files, **warned}.items():
+        got = by_path.get(os.path.basename(path), {})
         assert rule in got, f"{rule} missing for {path}: {got}"
+    for rule, path in warned.items():
+        assert by_path[os.path.basename(path)] == {rule: "warning"}
+        assert TestFfcheckGate._main(
+            ["--json", "--nodes", "1", "--devices-per-node", "4", path]
+        ) == 0
     # and EACH violation alone exits non-zero (in-process for speed; the
     # subprocess above already pinned the real CLI exit code)
     for rule, path in files.items():

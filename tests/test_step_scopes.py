@@ -74,6 +74,8 @@ def _one_node_graph(attrs, name):
     [
         ("enc/block 0 (attn)", "enc_block_0__attn_"),
         ("a.b-c_d", "a.b-c_d"),
+        # a `SharedBlock`'s layer carries its application (PR 62)
+        ("attn3#2", "attn3#2"),
         ("jvp(x)/transpose(y)", "jvp_x__transpose_y_"),
         (None, "n7"),
         ("", "n7"),
@@ -210,6 +212,20 @@ def test_scope_name_survives_the_name_stack(layer_name, want):
          "/pallas_call", ("bwd", "ring_attention", "attn3")),
         ("jit(_step)/jvp(ff.ring_attention.attn3)/cos",
          ("fwd", "ring_attention", "attn3")),
+        # a looped model's layer names its pass, `<layer>#<pass>` (PR 62); a
+        # `recompute` group's second forward is backward time; the sum of a
+        # shared weight's gradients is JAX's own `add_any`, under the scope
+        # of the reader whose backward reaches it
+        ("jit(_step)/jvp(ff.ring_attention.attn3#2)/flash_fwd_causal_bshf"
+         "/pallas_call", ("fwd", "ring_attention", "attn3#2")),
+        ("jit(_step)/transpose(jvp(jvp()))/checkpoint/rematted_computation"
+         "/ff.dense.ffn0_w2#1/dot_general", ("bwd", "dense", "ffn0_w2#1")),
+        ("jit(_step)/transpose(jvp(ff.label_loss.exit#4))/mul",
+         ("bwd", "label_loss", "exit#4")),
+        ("jit(_step)/jvp(ff.mean_loss.entropy)/reduce_sum",
+         ("fwd", "mean_loss", "entropy")),
+        ("jit(_step)/transpose(jvp(ff.dense.ffn0_w2#1))/add_any",
+         ("bwd", "dense", "ffn0_w2#1")),
         # a scope that only begins like a part, and a part of another kind
         ("jit(_step)/jvp(ff.experts.moe1)/shared_expert/mul",
          ("fwd", "experts", "moe1")),
