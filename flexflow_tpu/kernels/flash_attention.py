@@ -1534,8 +1534,10 @@ def flash_attention_bshf(
     there alone the key may be wider than the value (q, k
     [b, s, num_heads * dk], v [b, s, num_heads * dv]), `scale` may differ
     from dk ** -0.5 (a key padded with zero columns names its TRUE width's),
-    and k and v may hold `num_kv_heads` heads where the plan reads them in
-    place (`CausalPlan.group`: the caller repeats them where it is 1).
+    and k and v hold the node's own `num_kv_heads` heads, read in place by
+    the query heads that share them (`CausalPlan.group`: where the key is as
+    wide as the value; a caller whose pairing is no `h // group` writes them
+    out a query head and names no `num_kv_heads`).
     `window` (keys a query sees, itself included) is honoured there alone,
     as a band in the tile schedule; anywhere else it is an error, not a
     silent full attention. -> [b, s, num_heads * dv]."""
@@ -1791,8 +1793,9 @@ class CausalPlan:
     vmem_limit: Optional[int]  # the forward's; the backward always names one
     # query heads that read one key/value head WHERE IT LIES in
     # [b, s, kv * d] rows (the backward writes a query head's own dk and dv
-    # and they are summed over the group after); 1: k and v hold a head a
-    # query head, repeated by the caller where the node has fewer
+    # and they are summed over the group after): heads over key/value heads
+    # wherever the key is as wide as the value; 1: k and v hold a head a
+    # query head
     group: int
     delta_block: Optional[int]  # positions a delta program takes; None: rows
     fwd_name: str
@@ -1809,13 +1812,17 @@ def causal_plan(
 ) -> CausalPlan:
     """The plan of a causal call on `s` positions of `num_heads` heads with
     `dk`-wide keys and `dv`-wide values (multiples of 128 lanes), the node's
-    k and v holding `num_kv_heads` heads. Rows that fit the default scope
-    fold batch rows under it; longer ones take one row a program under
-    `_CAUSAL_VMEM_LIMIT`, and with dk == dv they are read in place for their
-    group, with a delta kernel by tiles, under the grouped kernels' names
-    (so do heads of 256 with a key/value head a query head: a group of 1);
-    a wide key keeps the folded form's backward and whole-row delta. Each is
-    what its shapes ran with before there was a plan (PR 29, 43, 51, 53).
+    k and v holding `num_kv_heads` heads. Two independent facts. The ROWS:
+    those that fit the default scope fold batch rows under it, with a
+    whole-row delta; longer ones take one row a program under
+    `_CAUSAL_VMEM_LIMIT`, and with dk == dv a delta kernel by tiles under
+    the grouped kernels' names (heads of 256 with a key/value head a query
+    head too: a group of 1); a wide key keeps the folded form's backward and
+    whole-row delta. Each is what its shapes ran with before there was a
+    plan (PR 29, 43, 51, 53). The GROUP: a key as wide as its value is read
+    in place by the `num_heads // num_kv_heads` query heads that share it,
+    at every row length, folded rows included; a wide key (a latent node,
+    which has a key/value head a query head anyway) keeps a group of 1.
     `window` (keys a query sees, itself included) makes the schedule a band:
     the same blocks, fold and delta, the forward and backward under
     `<name>_window`; a window of `s` or more is no window."""
@@ -1828,24 +1835,24 @@ def causal_plan(
     bwd_bq, bwd_bk = _bwd_blocks(bq, bk, s, explicit, causal=True)
     tiled = s > min(bq, bk)
     long_rows = tiled and 2 * s * (dk + dv) * itemsize >= _SCOPED_ROWS_BUDGET
-    in_place = long_rows and dk == dv
+    grouped = long_rows and dk == dv  # the long rows' delta and names
     banded = "" if window is None else "_window"
     return CausalPlan(
         supported=tiled and s % _CAUSAL_BLOCK == 0,
         block_q=bq, block_k=bk, bwd_block_q=bwd_bq, bwd_block_k=bwd_bk,
         fold=1 if long_rows else _batch_block(b, bq, bk, s, dk, itemsize),
         vmem_limit=_CAUSAL_VMEM_LIMIT if long_rows else None,
-        group=num_heads // num_kv_heads if in_place else 1,
-        delta_block=bwd_bq if in_place else None,
+        group=num_heads // num_kv_heads if tiled and dk == dv else 1,
+        delta_block=bwd_bq if grouped else None,
         fwd_name=(
-            "flash_fwd_causal_grouped" if in_place
+            "flash_fwd_causal_grouped" if grouped
             else "flash_fwd_causal_wide_key" if long_rows
             else "flash_fwd_causal_bshf"
         ) + banded,
         bwd_name=(
-            "flash_bwd_causal_grouped" if in_place else "flash_bwd_causal_bshf"
+            "flash_bwd_causal_grouped" if grouped else "flash_bwd_causal_bshf"
         ) + banded,
-        delta_name="flash_delta_grouped" if in_place else "flash_delta_bshf",
+        delta_name="flash_delta_grouped" if grouped else "flash_delta_bshf",
         window=window,
     )
 

@@ -155,7 +155,8 @@ def mha_project_qkv_bshf(
 ):
     """q/k/v projections -> seq-major fused-head tensors [b, s, h*d] plus wo
     pre-arranged as [h*v, e]. Grouped-query heads: k and v come out as their
-    `kv_heads` published heads, [b, s, kv*d] (`mha_between` repeats them).
+    `kv_heads` published heads, [b, s, kv*d] (`mha_between` repeats them for
+    a core that does not read them in place).
 
     With heads fused into the minor dim every projection is a PLAIN MATMUL
     ([b,s,e] @ [e, h*d]), whose natural output layout matches
@@ -324,14 +325,14 @@ def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains,
     """What the attrs ask for between projection and attention core, on the
     fused [b, s, h*d] projections: QK-norm over the whole row (or, with
     `qk_norm_per_head`, over each head block by itself), then RoPE on
-    each head block, then each grouped-query key/value head repeated for
-    the query heads that read it (head h reads h // group), so that the core
-    is the equal-head one: same kernels, same route, and the repeat's
-    transpose sums dK and dV over the group. A kernel that indexes the
-    key/value block by `h // group` instead saves the two repeated copies:
-    the causal tile schedule is one where its plan says so
-    (`CausalPlan.group`), and its caller passes `repeat` false (ROADMAP,
-    Reach (3))."""
+    each head block, then, with `repeat`, each grouped-query key/value head
+    written out for the query heads that read it (head h reads h // group),
+    so that the core is the equal-head one and the repeat's transpose sums
+    dK and dV over the group: what the head-pair, `[b, h, s, d]` and dense
+    cores take. The causal tile schedule indexes the key/value block by
+    `h // group` instead (`CausalPlan.group`, wherever the key is as wide as
+    the value) and saves the two repeated copies: its caller passes `repeat`
+    false and hands the kernels k and v as they lie."""
     if attrs.qk_norm_per_head:
         # a head's own features normed by themselves, one gain [d] for all
         # of q's heads and one for the key heads
@@ -374,9 +375,14 @@ def _causal_plan_of(attrs: MultiHeadAttentionAttrs, s: int, itemsize: int = 2):
     `_mha_forward` / `_latent_mha_forward` would call it on `s` positions: a
     latent key padded to whole tiles, heads of 64 padded to 128 lanes. None
     where the causal tile schedule has no body for it (no mask, other
-    widths). `supported` and the blocks are the same at every itemsize: the
-    route, which has shapes and no dtype, asks at bf16's."""
-    from flexflow_tpu.kernels.flash_attention import causal_plan, wide_key_padded
+    widths) or is not asked (heads of 64 in one tile: the head-pair
+    kernels'). `supported` and the blocks are the same at every itemsize:
+    the route, which has shapes and no dtype, asks at bf16's."""
+    from flexflow_tpu.kernels.flash_attention import (
+        bshf_pair_supported,
+        causal_plan,
+        wide_key_padded,
+    )
 
     H, kv = attrs.num_heads, attrs.kv_heads
     kd, vd = attrs.q_proj_size, attrs.v_proj_size
@@ -388,6 +394,8 @@ def _causal_plan_of(attrs: MultiHeadAttentionAttrs, s: int, itemsize: int = 2):
         # share them (the pairing is no `h // group`)
         kd, vd, kv = wide_key_padded(kd), 2 * vd, H
     elif kd == vd == 64:
+        if bshf_pair_supported(H, kd, s):
+            return None
         kd = vd = 128
     if not getattr(attrs, "causal", False) or kd % 128 or vd % 128:
         return None
@@ -403,16 +411,10 @@ def mha_pads_heads(attrs: MultiHeadAttentionAttrs, s: int) -> bool:
     64-wide contraction or product fills half the array either way, so the
     zero columns cost bytes and no passes. A causal tile schedule for the
     pair kernels would save the padded copies (ROADMAP, Reach)."""
-    from flexflow_tpu.kernels.flash_attention import bshf_pair_supported
-
-    kd = attrs.q_proj_size
-    if not kd == attrs.v_proj_size == 64:
+    if not attrs.q_proj_size == attrs.v_proj_size == 64:
         return False
     plan = _causal_plan_of(attrs, s)
-    return (
-        plan is not None and plan.supported
-        and not bshf_pair_supported(attrs.num_heads, kd, s)
-    )
+    return plan is not None and plan.supported
 
 
 def _padded_heads(x, kd: int):
@@ -545,10 +547,13 @@ def _banded(attrs: MultiHeadAttentionAttrs, s: int) -> bool:
     return attrs.window is not None and attrs.window < s
 
 
-def _note_route(route: str, attrs: MultiHeadAttentionAttrs = None) -> None:
+def _note_route(
+    route: str, attrs: MultiHeadAttentionAttrs = None, group: int = 1
+) -> None:
     """Tell the program's counter which core the attention node being
     lowered took (`observability/trace.attention_routes`), of a
-    differential node that it is one, and of any node its window."""
+    differential node that it is one, of any node its window, and the
+    `group` of query heads that read a key/value head where it lies."""
     from flexflow_tpu.observability import trace
 
     if attrs is not None:
@@ -556,6 +561,8 @@ def _note_route(route: str, attrs: MultiHeadAttentionAttrs = None) -> None:
             route += " differential"
         if attrs.window is not None:
             route += f" window={attrs.window}"
+    if group > 1:
+        route += f" group={group}"
     trace.note_attention_route(route)
 
 
@@ -945,9 +952,16 @@ def _mha_forward(
     # projections; a node without them takes the paths it always took
     post = mha_row_projections(attrs)
     route = mha_core_route(attrs, q.shape, k.shape, v.shape, q is k and k is v)
-    _note_route(route, attrs)
+    s = q.shape[1]
+    plan = (
+        _causal_plan_of(attrs, s, q.dtype.itemsize) if route == "fused_row"
+        else None
+    )
+    # query heads that read one key/value head where it lies
+    group = 1 if plan is None else plan.group
+    _note_route(route, attrs, group)
     _note_rotary(attrs)
-    banded = _banded(attrs, q.shape[1])
+    banded = _banded(attrs, s)
     if banded and (not causal or route not in ("fused_row", "dense")):
         # `mha_core_route` sends no windowed node here; a caller that forces
         # one gets an error and not a silent full attention
@@ -975,11 +989,7 @@ def _mha_forward(
             attrs, q, k, v, weight, input_bias
         )
         qp, gate = _split_output_gate(attrs, qp)
-        s = q.shape[1]
         pads = mha_pads_heads(attrs, s)
-        plan = _causal_plan_of(attrs, s, q.dtype.itemsize)
-        # query heads that read one key/value head where it lies
-        group = 1 if plan is None else plan.group
         if banded:
             _note_window_tiles(plan, s)
         if post:

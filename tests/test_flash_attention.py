@@ -1,6 +1,8 @@
 """Pallas flash attention numerics vs dense reference (interpret mode on the
 CPU mesh; the compiled path runs on the real chip via bench/verify)."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -755,7 +757,10 @@ def test_causal_tile_schedule_counts_what_the_mask_keeps(
 # rule the dispatch reads from (causal, s, d); `form` names what differs from
 # two float32 heads of 128 | 128 with a key/value head each at the default
 # scale: heads, kv (key/value heads), dk, dv, scale, dtype, long_rows (the
-# scope's budget set to 0: one row a program under the 64 MB limit).
+# scope's budget set to 0: one row a program under the 64 MB limit), window
+# (keys a query sees), fold (batch rows a forward program must take), node
+# (the call goes through `kernels/ops._mha_forward` on heads of 64, which pads
+# them to 128 lanes at the key/value count).
 CAUSAL_SCHEDULE_CASES = {
     # s = 4 x block: the first k block is full (unmasked) for every q block
     # but the first, and the last q block walks three full tiles
@@ -780,6 +785,19 @@ CAUSAL_SCHEDULE_CASES = {
     "grouped_in_place": (
         1, 1024, None, None,
         {"heads": 4, "kv": 2, "dk": 256, "dv": 256, "long_rows": True}),
+    # ... 4 heads of 128 over 2 read in place in rows that fit the default
+    # scope, under the folded names (TwoTower, Super) ...
+    "grouped_folded_rows": (1, 1024, None, None, {"heads": 4, "kv": 2}),
+    # ... with two batch rows a forward program (no cell: `fold` 2, group 2)
+    "grouped_folded_batch": (
+        4, 1024, None, None, {"heads": 4, "kv": 2, "fold": 2}),
+    # ... under a window shorter than the rows (Mellum2's three) ...
+    "grouped_folded_band": (
+        1, 1024, None, None, {"heads": 4, "kv": 2, "window": 300}),
+    # ... and heads of 64 padded to 128 lanes at the key/value count (LFM2)
+    "grouped_padded_heads_of_64": (
+        1, 1024, 512, 512,
+        {"heads": 4, "kv": 2, "dk": 64, "dv": 64, "node": True}),
     # ... six tiles a q block: five unmasked ones before the diagonal's
     "many_tiles": (1, 768, 128, 128, {}),
     # ... a k tile that is no multiple of _FWD_KEY_CHUNK: three chunks of 128
@@ -787,6 +805,36 @@ CAUSAL_SCHEDULE_CASES = {
     # ... and bf16 operands (every cell), against the float32 reference
     "bf16_inputs": (2, 1024, None, None, {"dtype": jnp.bfloat16}),
 }
+
+
+def _interpret_node_kernels(monkeypatch):
+    """Steer `kernels/ops`' gates and its fused-row entry to the Pallas
+    interpreter: a node on the CPU then takes the kernels a chip would."""
+    from flexflow_tpu.kernels import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
+    monkeypatch.setattr(
+        fa, "flash_attention_bshf",
+        functools.partial(fa.flash_attention_bshf, interpret=True),
+    )
+
+
+def _attention_node_core(rows, h, kv, dk, dv, window):
+    """`kernels/ops._mha_forward` as nothing but its core: a causal
+    grouped-query node whose four projections are identities (exact in
+    float32), on fused rows q [b, s, h * dk], k [b, s, kv * dk] and v
+    [b, s, kv * dv] -> [b, s, h * dv]."""
+    from flexflow_tpu.kernels.ops import _mha_forward
+    from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
+
+    attrs = RingAttentionAttrs(
+        h * dv, h, dk, dv, causal=True, num_kv_heads=kv, window=window
+    )
+    weight = jnp.concatenate([
+        jnp.eye(n, dtype=rows[0].dtype).reshape(-1)
+        for n in (h * dk, kv * dk, kv * dv, h * dv)
+    ])
+    return _mha_forward(attrs, *rows, weight, causal=True)
 
 
 def _causal_case(case, monkeypatch):
@@ -804,12 +852,29 @@ def _causal_case(case, monkeypatch):
     dk, dv = form.get("dk", 128), form.get("dv", 128)
     scale = form.get("scale", dk ** -0.5)
     dtype = form.get("dtype", jnp.float32)
+    window, node = form.get("window"), form.get("node", False)
     if form.get("long_rows"):
         monkeypatch.setattr(fa, "_SCOPED_ROWS_BUDGET", 0)
+    if node:
+        # a node names no blocks: the sweep's knobs make 1,024 positions two
+        # tiles (more than the head-pair kernels' one)
+        monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", str(block_q))
+        monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_K", str(block_k))
+        _interpret_node_kernels(monkeypatch)
+        block_q = block_k = None
     plan = fa.causal_plan(
-        b, s, h, kv, dk, dv, jnp.dtype(dtype).itemsize, block_q, block_k
+        b, s, h, kv, max(dk, 128), max(dv, 128), jnp.dtype(dtype).itemsize,
+        block_q, block_k, window,
     )
-    assert plan.group == (h // kv if form.get("long_rows") else 1)
+    # a key as wide as its value is read in place by its group, whatever the
+    # rows' length; a wide key has a key/value head a query head
+    assert plan.group == (h // kv if dk == dv else 1)
+    assert plan.fold == form.get("fold", plan.fold)
+    assert plan.fwd_name.startswith(
+        "flash_fwd_causal_grouped" if form.get("long_rows") and dk == dv
+        else "flash_fwd_causal_wide_key" if form.get("long_rows")
+        else "flash_fwd_causal_bshf"
+    )
     rs = np.random.RandomState(17)
     q, k, v = (
         jnp.asarray(rs.randn(b, n, s, d), dtype).astype(jnp.float32)
@@ -821,17 +886,21 @@ def _causal_case(case, monkeypatch):
     )
 
     def flash(q, k, v):
+        rows = [to_bshf(x).astype(dtype) for x in (q, k, v)]
+        if node:
+            return _attention_node_core(rows, h, kv, dk, dv, window)
         return fa.flash_attention_bshf(
-            *(to_bshf(x).astype(dtype) for x in (q, k, v)), h, causal=True,
+            *rows, h, causal=True,
             interpret=True, num_kv_heads=kv, scale=form.get("scale"),
-            **blocks,
+            window=window, **blocks,
         ).astype(jnp.float32)
 
     def scores(q, k):
         sc = jnp.einsum(
             "bhsd,bhtd->bhst", q, jnp.repeat(k, h // kv, axis=1)
         ) * scale
-        mask = jnp.arange(s)[:, None] >= jnp.arange(s)[None, :]
+        ahead = jnp.arange(s)[:, None] - jnp.arange(s)[None, :]
+        mask = ahead >= 0 if window is None else (ahead >= 0) & (ahead < window)
         return jnp.where(mask, sc, -1e30)
 
     def dense(q, k, v):
@@ -852,8 +921,20 @@ def test_flash_bshf_causal_schedule_matches_dense(case, monkeypatch):
     against dense attention, over every branch of the tile schedule (dead
     tiles skipped, diagonal tiles masked, full tiles unmasked, one visit a
     tile in the backward) and every form of `causal_plan`."""
+    from test_step_scopes import pallas_eqns
+
     c = _causal_case(case, monkeypatch)
     flash, dense, qkv = c.flash, c.dense, c.qkv
+    # k and v reach every kernel with the heads they came with (a padded
+    # head of 64 is 128 lanes wide): nobody wrote them out a query head
+    (b, _, s, _), (kv, dk), dv = qkv[0].shape, qkv[1].shape[1::2], qkv[2].shape[3]
+    grad = jax.grad(lambda *xs: jnp.sum(flash(*xs)), argnums=(0, 1, 2))
+    grouped = c.plan.group > 1
+    for eqn in pallas_eqns(jax.make_jaxpr(grad)(*qkv).jaxpr) if grouped else ():
+        if "delta" not in eqn.params["name"]:
+            assert [v.aval.shape for v in eqn.invars[1:3]] == [
+                (b, s, kv * max(dk, 128)), (b, s, kv * max(dv, 128))
+            ], eqn.params["name"]
     # bf16: the probabilities and the output are rounded to 8 bits
     fwd_tol, bwd_tol = (
         (1e-5, 2e-4) if c.dtype == jnp.float32 else (2e-2, 1e-1)
@@ -871,9 +952,48 @@ def test_flash_bshf_causal_schedule_matches_dense(case, monkeypatch):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=bwd_tol)
 
 
+def test_grouped_heads_of_64_in_one_pair_tile_are_repeated_for_the_pair_kernels(
+    monkeypatch,
+):
+    """1,024 positions of heads of 64 are ONE tile of the head-pair kernels
+    and would be two of the causal schedule's on padded heads: the node
+    takes the pair kernels, which read a head a query head, so its 2
+    key/value heads are written out for the 4 query heads and no plan's
+    group is read. Forward and gradients against dense attention."""
+    from test_step_scopes import pallas_eqns
+
+    _interpret_node_kernels(monkeypatch)
+    b, s, h, kv, d = 1, 1024, 4, 2, 64
+    rs = np.random.RandomState(5)
+    q, k, v = (
+        jnp.asarray(rs.randn(b, n, s, d), jnp.float32) for n in (h, kv, kv)
+    )
+    to_bshf = lambda x: jnp.transpose(x, (0, 2, 1, 3)).reshape(b, s, -1)
+
+    def node(q, k, v):
+        return _attention_node_core(
+            [to_bshf(x) for x in (q, k, v)], h, kv, d, d, None
+        )
+
+    def dense(q, k, v):
+        k, v = (jnp.repeat(x, h // kv, axis=1) for x in (k, v))
+        return to_bshf(dense_attention(q, k, v, True))
+
+    names = {
+        eqn.params["name"] for eqn in pallas_eqns(jax.make_jaxpr(node)(q, k, v).jaxpr)
+    }
+    assert names == {"flash_fwd_pair"}, names
+    np.testing.assert_allclose(node(q, k, v), dense(q, k, v), atol=1e-5)
+    gf = jax.grad(lambda *x: jnp.sum(node(*x) ** 2), argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda *x: jnp.sum(dense(*x) ** 2), argnums=(0, 1, 2))(q, k, v)
+    for a, b_ in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), atol=2e-4)
+
+
 @pytest.mark.parametrize(
     "case", ["default_blocks", "folded_batch_rows", "grouped_in_place",
-             "wide_key_long_rows", "block_q_twice_block_k"],
+             "wide_key_long_rows", "block_q_twice_block_k",
+             "grouped_folded_batch", "grouped_folded_band"],
 )
 def test_causal_forward_lse_is_the_dense_log_sum_exp(case, monkeypatch):
     """The forward's second output, row by row: log2 of the sum over a
@@ -885,12 +1005,10 @@ def test_causal_forward_lse_is_the_dense_log_sum_exp(case, monkeypatch):
     c = _causal_case(case, monkeypatch)
     q, k, v = c.qkv
     (b, h, s, _), kv = q.shape, k.shape[1]
-    # the caller repeats k and v where the plan does not read them in place
-    rows = [
-        c.to_bshf(x if c.plan.group > 1 else jnp.repeat(x, h // kv, axis=1))
-        for x in (k, v)
-    ]
-    _, lse = fa._fwd_causal(c.to_bshf(q), *rows, h, c.plan, True, c.scale)
+    assert c.plan.group == h // kv  # k and v as they lie, a wide key's too
+    _, lse = fa._fwd_causal(
+        *(c.to_bshf(x) for x in (q, k, v)), h, c.plan, True, c.scale
+    )
     want = jax.scipy.special.logsumexp(c.scores(q, k), axis=-1)
     assert lse.shape == (b, h, 1, s) and lse.dtype == jnp.float32
     np.testing.assert_allclose(
@@ -999,29 +1117,32 @@ _LIMIT = 64 * 1024 * 1024
 # forward's batch fold and vmem limit, the group read in place, the delta
 # kernel's block, the three kernel names). The shape is a cell's
 # (configuration, job) under benchmark/ or, for the boundary shapes the old
-# predicates' tests had, (b, s, heads, key/value heads, dk, dv). The answers
-# were written down from the PARENT of PR 55 (commit 5ee988b) before there
-# was a plan: a script over its five predicates (`wide_key_supported`,
+# predicates' tests had, (b, s, heads, key/value heads, dk, dv[, window]). The
+# answers were written down from the PARENT of PR 55 (commit 5ee988b) before
+# there was a plan: a script over its five predicates (`wide_key_supported`,
 # `wide_key_rows_exceed_scope`, `causal_rows_exceed_scope`,
 # `mha_reads_kv_in_place`'s conjunction, `_batch_block`) said which of its four
 # `custom_vjp`s a shape took, and that door's wrappers what they were built
-# with.
+# with. Since PR 63 the group is the node's (heads over key/value heads where
+# the key is as wide as the value) at every row length: every other field of
+# the rows that fit the scope is still the folded door's.
 CAUSAL_PLAN_CASES = {
     "cgpt13b": (("cerebras-gpt-1.3b", "pretrain_s2048_b4_1chip"),
                 True, 2, None, 1, None, _BSHF),
     "olmoe": (("olmoe-1b-7b", "pretrain_s4096_b4_1chip"),
               True, 2, None, 1, None, _BSHF),
-    # 32 over 2 and 4 over 1 heads of 128: repeated by the caller (group 1)
+    # 32 over 2 and 4 over 1 heads of 128: read in place under the folded
+    # names (PR 63; the caller wrote them out a query head before)
     "twotower": (("nemotron-twotower-30b-a3b", "pretrain_s4096_b1_1chip"),
-                 True, 1, None, 1, None, _BSHF),
+                 True, 1, None, 16, None, _BSHF),
     "super": (("nemotron-3-super-120b-a12b", "pretrain_s4096_b1_1chip"),
-              True, 1, None, 1, None, _BSHF),
+              True, 1, None, 4, None, _BSHF),
     # 32 heads of 192 -> 256 | 128
     "kimi": (("kimi-linear-48b-a3b", "pretrain_s4096_b1_1chip"),
              True, 1, None, 1, None, _BSHF),
-    # 32 heads of 64, padded to 128 | 128: 8 MB of rows
+    # 32 over 8 heads of 64, padded to 128 | 128: 8 MB of rows
     "lfm2": (("lfm2-24b-a2b", "pretrain_s8192_b2_1chip"),
-             True, 1, None, 1, None, _BSHF),
+             True, 1, None, 4, None, _BSHF),
     # 16 over 2 heads of 256: 16 MB of rows, read in place
     "qwen3next": (("qwen3-next-80b-a3b", "pretrain_s8192_b1_1chip"),
                   True, 1, _LIMIT, 8, 512, _GROUPED),
@@ -1030,10 +1151,19 @@ CAUSAL_PLAN_CASES = {
     "joyai": (("joyai-llm-flash", "pretrain_s8192_b1_1chip"),
               True, 1, _LIMIT, 1, None,
               ("flash_fwd_causal_wide_key",) + _BSHF[1:]),
+    # rows that fit the scope keep the folded names, no limit and the
+    # whole-row delta, and read their group in place all the same
     "qwen3next_at_4096": ((1, 4096, 16, 2, 256, 256),
-                          True, 1, None, 1, None, _BSHF),
+                          True, 1, None, 8, None, _BSHF),
     "heads_of_128_over_8_at_8192": ((1, 8192, 32, 8, 128, 128),
-                                    True, 1, None, 1, None, _BSHF),
+                                    True, 1, None, 4, None, _BSHF),
+    # 32 over 4 heads of 128 at 8,192 positions, three nodes of four banded
+    "mellum2_full": ((1, 8192, 32, 4, 128, 128),
+                     True, 1, None, 8, None, _BSHF),
+    "mellum2_window": ((1, 8192, 32, 4, 128, 128, 1024),
+                       True, 1, None, 8, None,
+                       ("flash_fwd_causal_bshf_window",
+                        "flash_bwd_causal_bshf_window", "flash_delta_bshf")),
     # as many key/value heads as query heads: the grouped form, a group of 1
     "heads_of_256_ungrouped": ((1, 8192, 16, 16, 256, 256),
                                True, 1, _LIMIT, 1, 512, _GROUPED),
@@ -1081,7 +1211,7 @@ def test_causal_plan_is_pinned(case):
     )
     if isinstance(shape[0], str):
         shape = _cell_attention_shape(*shape)
-    plan = causal_plan(*shape, 2)
+    plan = causal_plan(*shape[:6], 2, None, None, *shape[6:])
     assert plan.supported == supported
     if not supported:
         return

@@ -249,6 +249,13 @@ def test_padded_heads_route_is_read_from_shapes_and_backend(monkeypatch):
     short = (2, 512, 2048)
     assert not mha_pads_heads(attrs, 512)
     assert mha_core_route(attrs, short, short, short, True) == "fused_row"
+    # ... up to 1,024 positions, which would be TWO causal tiles of 512 on
+    # padded heads: no plan is asked there, so nobody reads its group and the
+    # pair kernels get their keys and values repeated a query head
+    from flexflow_tpu.kernels.ops import _causal_plan_of
+
+    assert _causal_plan_of(attrs, 1024) is None
+    assert _causal_plan_of(attrs, 8192).group == 4
     # and without the mask nothing is padded: the per-head kernels' route
     open_ = MultiHeadAttentionAttrs(embed_dim=2048, num_heads=32)
     assert not mha_pads_heads(open_, 8192)

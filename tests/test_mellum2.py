@@ -223,10 +223,11 @@ def test_band_on_grouped_heads_matches_the_dense_mask(
     """A window shorter than, equal to and longer than a tile of 128 and one
     of the whole length, groups of 1 and 8, forward and every gradient in
     interpret mode against XLA's attention under the dense band mask, for
-    both forms the plan can pick: folded (the caller repeats the keys and
-    values, as `mha_between` does) and read in place for the group
-    (`CausalPlan.group`, which long rows take). Float32 sums in another
-    order (measured 5e-6)."""
+    both forms of rows the plan can pick, each reading the keys and values
+    in place for the group (`CausalPlan.group`): folded (rows that fit the
+    default scope, the whole-row delta: this cell's) and long rows (the
+    delta by tiles, the grouped names). Float32 sums in another order
+    (measured 5e-6)."""
     rs = np.random.RandomState(7)
     kv, s = 1, 512
     heads = kv * group
@@ -235,19 +236,18 @@ def test_band_on_grouped_heads_matches_the_dense_mask(
     if form == "in_place":  # every row is a long row
         monkeypatch.setattr(flash, "_SCOPED_ROWS_BUDGET", 0)
     plan = flash.causal_plan(1, s, heads, kv, 128, 128, 4, 128, 128, window)
-    assert plan.group == (group if form == "in_place" else 1)
+    assert plan.group == group
     assert plan.fwd_name.endswith("_window") == (window < s)
+    assert plan.fwd_name.startswith(
+        "flash_fwd_causal_grouped" if form == "in_place"
+        else "flash_fwd_causal_bshf"
+    )
+    assert (plan.delta_block is None) == (form == "folded")
 
     def kernel(q, k, v):
-        if plan.group == 1:  # the folded form takes a head a query head
-            k, v = (
-                jnp.repeat(t.reshape(1, s, kv, 128), group, axis=2).reshape(
-                    1, s, heads * 128
-                ) for t in (k, v)
-            )
         return jnp.sum(cot * flash.flash_attention_bshf(
             q, k, v, heads, causal=True, block_q=128, block_k=128,
-            interpret=True, window=window, num_kv_heads=heads // plan.group,
+            interpret=True, window=window, num_kv_heads=kv,
         ))
 
     def dense(q, k, v):
@@ -342,7 +342,8 @@ def test_a_window_as_long_as_the_sequence_is_no_window():
 def test_windowed_node_on_the_banded_kernels_matches_the_reference(monkeypatch):
     """The node as the cell runs it, in interpret mode: 8 query heads over 1
     key/value head of 128 (a group of 8) on two causal tiles under a 300-key
-    window, the per-head norm and the rotary before the folded repeat, against
+    window, the per-head norm and the rotary on the one key head as it lies
+    (the kernels read it in place for the group), against
     the reference's masked softmax; forward and every gradient. The kernels
     take exp2 of scaled scores and fold row sums by lanes: 2e-4. The route
     says the window, the counter the tiles the band visits, and the rotary
@@ -380,10 +381,80 @@ def test_windowed_node_on_the_banded_kernels_matches_the_reference(monkeypatch):
         trace._lowering.scope = None
     assert_trees_close(got, want, rtol=2e-4, atol=2e-4)
     assert trace.attention_routes()["ff.ring_attention.attn0"] == (
-        "fused_row window=300"
+        "fused_row window=300 group=8"
     )
     assert trace.window_tiles()["ff.ring_attention.attn0"] == (3, 3)
     assert trace.rotaries()["ff.ring_attention.attn0"] == "default theta=500000"
+
+
+def test_window_node_hands_the_kernels_its_keys_and_values_as_they_lie(
+    monkeypatch,
+):
+    """The window node at the published head shape and window (8 query heads
+    over 1 key/value head of 128, a group of 8 as the cell's 32 over 4; 1,024
+    keys over 2,048 positions), forward and backward, in interpret mode: the
+    causal kernels' k and v operands are the node's own `[b, s, kv * d]`
+    rows, nothing in the program writes them out a query head, the route
+    says the group, and the loss and every gradient are the repeated form's
+    (the plan's group forced to 1: `mha_between` writes the rows out eight
+    times and its transpose sums dk and dv; here the float32 sum over the
+    group follows the kernel) to float32 sums in another order."""
+    from test_step_scopes import pallas_eqns
+
+    from flexflow_tpu.observability import trace
+
+    sizes = dict(
+        TOY, hidden_size=64, head_dim=128, num_attention_heads=8,
+        num_key_value_heads=1, sliding_window=PUBLISHED["sliding_window"],
+    )
+    b, s, group, d = 1, 2048, 8, 128
+    u, ws = attention_case(seq=s, sizes=sizes, batch=b)
+    u, cot = u * 0.5, rand(np.random.RandomState(4), b, s, 64)
+    kind = "sliding_attention"
+    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    monkeypatch.setattr(
+        flash, "flash_attention_bshf",
+        functools.partial(flash.flash_attention_bshf, interpret=True),
+    )
+
+    def step(u, ws):
+        return jax.value_and_grad(
+            lambda u, ws: jnp.sum(program_attention(kind, u, ws, sizes) * cot),
+            (0, 1),
+        )(u, ws)
+
+    trace._lowering.scope = "ff.ring_attention.attn0"
+    try:
+        jaxpr = jax.make_jaxpr(step)(u, ws).jaxpr
+        got = step(u, ws)
+    finally:
+        trace._lowering.scope = None
+    assert trace.attention_routes()["ff.ring_attention.attn0"] == (
+        "fused_row window=1024 group=8"
+    )
+    kernels = {
+        eqn.params["name"]: [v.aval.shape for v in eqn.invars[1:3]]
+        for eqn in pallas_eqns(jaxpr) if "delta" not in eqn.params["name"]
+    }
+    assert kernels == {
+        name: [(b, s, d)] * 2 for name in (
+            "flash_fwd_causal_bshf_window", "flash_bwd_causal_bshf_window"
+        )
+    }
+
+    # the repeat's broadcast to [b, s, kv, group, d] is nowhere (the
+    # backward's sum over the group reshapes dk and dv to that shape)
+    repeat = f"f32[{b},{s},1,{group},{d}] = broadcast_in_dim"
+    assert repeat not in str(jaxpr)
+    plan_of = ops._causal_plan_of
+    monkeypatch.setattr(
+        ops, "_causal_plan_of",
+        lambda *a, **k: dataclasses.replace(plan_of(*a, **k), group=1),
+    )
+    # (a new function: JAX keeps a function's trace by its identity)
+    repeated = jax.make_jaxpr(lambda u, ws: step(u, ws))(u, ws).jaxpr
+    assert repeat in str(repeated)
+    assert_trees_close(got, step(u, ws), rtol=2e-5, atol=2e-5)
 
 
 def test_a_windowed_node_never_takes_a_route_without_a_band(monkeypatch):
@@ -397,7 +468,7 @@ def test_a_windowed_node_never_takes_a_route_without_a_band(monkeypatch):
     assert ops.mha_core_route(attrs, shape, shape, shape, True) == "fused_row"
     plan = ops._causal_plan_of(attrs, 8192)
     assert (plan.fwd_name, plan.group, plan.window) == (
-        "flash_fwd_causal_bshf_window", 1, 1024
+        "flash_fwd_causal_bshf_window", 8, 1024
     )
     assert flash.causal_tile_schedule(8192, plan.block_q, plan.block_k, 1024)[0] == 45
     assert flash.causal_tile_schedule(8192, plan.block_q, plan.block_k)[0] == 136

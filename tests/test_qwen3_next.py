@@ -720,18 +720,24 @@ def test_grouped_flash_entry_reads_a_key_head_in_place(monkeypatch):
     # gradients of sums over up to 1,024 positions and 2 heads of a group
     for got, want in zip(grads(kernels), grads(dense)):
         np.testing.assert_allclose(got, want, rtol=5e-2, atol=1e-1)
-    # which shapes take it: whole rows that leave the causal forward no room
-    # in the default scope, read from the shapes alone
+    # which shapes take the grouped NAMES: whole rows that leave the causal
+    # forward no room in the default scope, read from the shapes alone; the
+    # group is read in place at every row length (PR 63)
     monkeypatch.undo()
     big = attention_attrs(dict(TOY, hidden_size=2048, head_dim=256,
                                num_attention_heads=16, num_key_value_heads=2))
     from flexflow_tpu.kernels.ops import _causal_plan_of
 
-    assert _causal_plan_of(big, 8192, 2).group == 8
-    assert _causal_plan_of(big, 4096, 2).group == 1  # 8 MB of rows: fits
+    def form(attrs, s):
+        plan = _causal_plan_of(attrs, s, 2)
+        return plan.group, plan.fwd_name
+
+    assert form(big, 8192) == (8, "flash_fwd_causal_grouped")
+    # 8 MB of rows: fits
+    assert form(big, 4096) == (8, "flash_fwd_causal_bshf")
     lfm2 = RingAttentionAttrs(2048, 32, kdim=128, vdim=128, causal=True,
                               num_kv_heads=8)
-    assert _causal_plan_of(lfm2, 8192, 2).group == 1
+    assert form(lfm2, 8192) == (4, "flash_fwd_causal_bshf")
 
 
 # -- the zero-centred norm -------------------------------------------------------

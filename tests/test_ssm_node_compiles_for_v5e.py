@@ -107,9 +107,9 @@ And what the `mellum2` cell added (PR 60), at its published sizes and 8,192
 positions (`check_mellum2`): ONE plain grouped-query window node (32 query
 over 4 key/value heads of 128, per-head QK-norm, the default rotary, a
 1,024-key window), whose core must be the banded causal kernels
-(`flash_*_causal_bshf_window`, the folded form: `mha_between` repeats the
-keys and values), and the full node with its YaRN rotary on the unbanded
-ones. `python tests/test_ssm_node_compiles_for_v5e.py mellum2_step` compiles
+(`flash_*_causal_bshf_window`, the folded form, its keys and values read in
+place for the group of 8 since PR 63), and the full node with its YaRN
+rotary on the unbanded ones. `python tests/test_ssm_node_compiles_for_v5e.py mellum2_step` compiles
 that cell's WHOLE step.
 
 And what PR 58 gave both delta-rule nodes: the heads' norm under its gate
@@ -526,10 +526,11 @@ def check_kimi():
     return found
 
 
-def _causal_core_kernels(core, q, k, v):
-    """"ok" where `core(q, k, v)`, forward and backward, compiles for the
-    described chip into the causal tile schedule's three kernels (forward,
-    delta, backward); else what was found."""
+def _causal_core_kernels(core, q, k, v, cot=None):
+    """"ok" where `core(q, k, v)`, forward and backward (the cotangent
+    shaped as `cot`, else as v), compiles for the described chip into the
+    causal tile schedule's three kernels (forward, delta, backward); else
+    what was found."""
     import jax
 
     def both(q, k, v, cot):
@@ -537,7 +538,9 @@ def _causal_core_kernels(core, q, k, v):
         return o, vjp(cot)
 
     try:
-        text = jax.jit(both).lower(q, k, v, v).compile().as_text()
+        text = jax.jit(both).lower(
+            q, k, v, v if cot is None else cot
+        ).compile().as_text()
         kernels = text.count("tpu_custom_call")
         return "ok" if kernels == 3 else f"{kernels} kernels, want 3"
     except Exception as e:  # noqa: BLE001 - the complaint is the result
@@ -595,12 +598,15 @@ def check_lfm2():
         found[LFM2_INVARIANTS[0]] = f"{type(e).__name__}: {e}"[:2000]
 
     def core(q, k, v):
+        # as `_mha_forward` calls it: the 8 key/value heads padded where
+        # they lie and read in place by their 4 query heads
         q, k, v = (_padded_heads(t, 64) for t in (q, k, v))
         return _own_columns(flash_attention_bshf(
-            q, k, v, 32, causal=True, scale=64 ** -0.5
+            q, k, v, 32, causal=True, scale=64 ** -0.5, num_kv_heads=8
         ), 64)
 
-    found[LFM2_INVARIANTS[1]] = _causal_core_kernels(core, x, x, x)
+    kv = on_chip(LFM2_SHAPE[:2] + (8 * 64,))
+    found[LFM2_INVARIANTS[1]] = _causal_core_kernels(core, x, kv, kv, x)
     return found
 
 
@@ -1196,6 +1202,7 @@ def check_phi4flash():
 MELLUM2_INVARIANTS = [
     "window_node_compiles_on_the_banded_kernels",
     "full_node_with_yarn_compiles_on_the_causal_kernels",
+    "a_group_read_in_place_compiles_with_batch_rows_folded",
 ]
 MELLUM2_SHAPE = (1, 8192, 2304)
 
@@ -1207,10 +1214,14 @@ def check_mellum2():
     per-head QK-norm, the default rotary) under a 1,024-key window, whose
     core must be the banded kernels by the names the profile will carry, and
     the full node with its YaRN rotary, whose core must be the unbanded
-    ones."""
+    ones; and the group read in place where batch rows fold."""
     import jax
 
     from flexflow_tpu.kernels import ops
+    from flexflow_tpu.kernels.flash_attention import (
+        causal_plan,
+        flash_attention_bshf,
+    )
     from flexflow_tpu.op_attrs.core import get_weight_shapes
     from flexflow_tpu.op_attrs.datatype import DataType
     from flexflow_tpu.op_attrs.ops import RingAttentionAttrs, YarnScaling
@@ -1254,6 +1265,21 @@ def check_mellum2():
             )
         except Exception as e:  # noqa: BLE001 - the complaint is the result
             found[invariant] = f"{type(e).__name__}: {e}"[:2000]
+
+    # no cell has a grouped node with more than one sequence a chip: two
+    # sequences of 4,096 positions fold into one forward program, whose
+    # `[2, 4096, 128]` key and value blocks are the group's shared head
+    def core(q, k, v):
+        return flash_attention_bshf(
+            q, k, v, 32, causal=True, num_kv_heads=4, window=1024
+        )
+
+    q, kv = on_chip((2, 4096, 32 * 128)), on_chip((2, 4096, 4 * 128))
+    plan = causal_plan(2, 4096, 32, 4, 128, 128, 2, window=1024)
+    found[MELLUM2_INVARIANTS[2]] = (
+        _causal_core_kernels(core, q, kv, kv, q)
+        if (plan.fold, plan.group) == (2, 8) else f"the plan says {plan}"
+    )
     return found
 
 
