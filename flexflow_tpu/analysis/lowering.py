@@ -204,6 +204,7 @@ def lower_step_program(
     label_dtype=None,
 ) -> LoweredStepProgram:
     """Lower + compile the instance's donated step ONCE (never execute)."""
+    from flexflow_tpu.observability import step_account
     from flexflow_tpu.observability.trace import record_span
 
     with record_span("compile/lower_step", compiled=True):
@@ -215,6 +216,8 @@ def lower_step_program(
                 params, opt_state, batch, label, rng
             )
             compiled = lowered.compile()
+    # the step `step_account.last()` accounts, if anyone asks
+    step_account.note_step(instance, compiled)
     return LoweredStepProgram(
         instance=instance, compiled=compiled, lowered=lowered
     )
@@ -270,6 +273,7 @@ def lower_step_trace(
     `jax.stages.Lowered`."""
     import contextlib
 
+    from flexflow_tpu.observability import step_account
     from flexflow_tpu.observability.trace import record_span
 
     if params is None:
@@ -284,9 +288,13 @@ def lower_step_trace(
         )
         mesh = getattr(instance, "machine_mesh", None)
         with mesh.mesh if mesh is not None else contextlib.nullcontext():
-            return instance.compiled_step().lower(
+            lowered = instance.compiled_step().lower(
                 params, opt_state, batch, label, rng
             )
+    # a reference and nothing else: JAX's lowering cache holds the module
+    # anyway, and `step_account.last()` compiles it only if someone asks
+    step_account.note_step(instance, lowered)
+    return lowered
 
 
 def lower_plan(

@@ -1325,6 +1325,27 @@ class FFModel:
             label_dtype=self._label_dtype,
         )
 
+    def step_account(self) -> Dict[str, object]:
+        """Where the bytes of the step `fit` runs are, by node
+        (`observability/step_account.account`): XLA's totals with its own
+        peak, who holds the peak, what the forward pass leaves for the
+        backward pass, what lies in `S(1)`. Made from the step
+        `analysis/lowering.py` lowered last for this model's instance,
+        lowered here (no second trace: the arguments are `fit`'s) where none
+        was; `observability.step_account.report()` is the text of it."""
+        from flexflow_tpu.observability import step_account
+
+        if step_account.noted_instance() is not self.instance:
+            from flexflow_tpu.analysis.lowering import lower_step_trace
+
+            # every backend's step, and no compile until `last()` asks
+            lower_step_trace(
+                self.instance, self.loss_attrs,
+                label_dtype=self._label_dtype, params=self.params,
+                opt_state=self.opt_state,
+            )
+        return step_account.last()
+
     def _xla_memory_cross_check(self, lowered) -> Dict[str, object]:
         """Read XLA's `memory_analysis()` off the shared compiled step —
         the compiler's own per-device accounting of the exact program the

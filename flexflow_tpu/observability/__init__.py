@@ -10,6 +10,11 @@ trace through the scopes `trace` puts on every operation.
                    JAX's trace, lowering and compile seconds by
                    function, the step's trace by node kind, the seconds
                    before the program (`setup_report()`).
+- `step_account` -- the compiled step's BYTES by the same scopes: XLA's
+                   totals and its own peak, who holds the peak, what the
+                   forward pass leaves for the backward pass, what lies
+                   in `S(1)` (`FFModel.step_account()`,
+                   `step_account.report()`); nothing runs until asked.
 - `search_phases` -- compile-time twin of `trace`: per-phase wall-clock
                    attribution of the Unity search (tree_build / dp /
                    leaf_cost / match), reported as `phase_ms` in search
@@ -41,6 +46,7 @@ from flexflow_tpu.observability.trace import (
     setup_report,
     span_totals,
 )
+from flexflow_tpu.observability import step_account
 from flexflow_tpu.observability.search_phases import (
     collect_search_phases,
     search_phase,
@@ -82,6 +88,7 @@ __all__ = [
     "set_recorder",
     "setup_report",
     "span_totals",
+    "step_account",
     "collect_search_phases",
     "search_phase",
     "EVENT_SCHEMA_VERSION",

@@ -189,6 +189,10 @@ HOST_SPANS = (
     "step",
     "dispatch",  # the enqueue of the step program, inside `step`
     "fit/end",  # the last wait, the metric conversion, the routing counters
+    # the account of the compiled step's bytes, made when someone asks
+    # (`observability/step_account.last()`): the executable found again, its
+    # text, the parse
+    "step_account",
 )
 # events with no duration, counted by `count`
 STEP_TRACE = "step_trace"

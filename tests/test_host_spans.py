@@ -55,6 +55,8 @@ def _compile_and_fit(**config):
     try:
         m = _model(**config)
         m.fit(*_data(), epochs=1, shuffle=True, verbose=False)
+        # the one span neither emits: the account of the step, on demand
+        m.step_account()
     finally:
         trace.set_recorder(prev)
     parents = {}
@@ -88,6 +90,7 @@ PARENT = {
     "step": "fit",
     "dispatch": "step",
     "fit/end": "fit",
+    "step_account": None,
 }
 SEARCH_ONLY = {"compile/search", "compile/verify", "compile/lower_step"}
 

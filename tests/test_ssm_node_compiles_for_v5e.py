@@ -164,10 +164,23 @@ INVARIANTS = [
     "at_most_one_float32_rows_by_inner",
     "convolution_is_two_kernels_on_the_projections_row",
 ]
-_BYTES = {"f32": 4, "bf16": 2, "s32": 4, "u32": 4, "pred": 1, "s8": 1, "u8": 1}
-_SHAPE = re.compile(r"\b(f32|bf16|s32|u32|pred|s8|u8)\[([0-9,]*)\]")
-_INSTRUCTION = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([\w\-]+)\((.*)")
-_NO_BUFFER = ("parameter", "get-tuple-element", "tuple", "bitcast", "constant")
+
+
+def _bind_parser():
+    """The ENTRY parser has one home, the program's own
+    `observability/step_account.py` (PR 64: `entry_instructions`, `shapes_of`,
+    `_nbytes`, `_NO_BUFFER`, a listing's row, and `account`, which books the
+    same instructions by scope); bound once the checkout whose program is
+    compiled is on the path (`--root` of a script run)."""
+    from flexflow_tpu.observability import step_account
+
+    for name in ("entry_instructions", "shapes_of", "_nbytes", "_NO_BUFFER",
+                 "listing_row", "account"):
+        globals()[name] = getattr(step_account, name)
+
+
+if __name__ != "__main__":
+    _bind_parser()
 
 
 def compiled_node(name):
@@ -209,39 +222,6 @@ def compiled_node(name):
         return y, vjp(cot)
 
     return attrs, jax.jit(node).lower(u, weights, u).compile().as_text()
-
-
-def entry_instructions(text):
-    """[(name, result, opcode, operand names, line)] of the ENTRY
-    computation, in schedule order."""
-    entry = text[text.index("ENTRY"):]
-    entry = entry[: entry.index("\n}")]
-    rows = []
-    for line in entry.splitlines()[1:]:
-        m = _INSTRUCTION.match(line)
-        if m:
-            name, result, opcode, rest = m.groups()
-            operands = re.findall(r"%([\w.\-]+)", rest.split("), ")[0])
-            rows.append((name, result, opcode, operands, line))
-    return rows
-
-
-def shapes_of(result):
-    """[(dtype, dims)] of an instruction's result, a tuple's members each."""
-    return [
-        (m.group(1), tuple(int(d) for d in m.group(2).split(",") if d))
-        for m in _SHAPE.finditer(result)
-    ]
-
-
-def _nbytes(result):
-    total = 0
-    for dtype, dims in shapes_of(result):
-        n = _BYTES[dtype]
-        for d in dims:
-            n *= d
-        total += n
-    return total
 
 
 def _conv_part(text, rows, width):
@@ -1203,8 +1183,75 @@ MELLUM2_INVARIANTS = [
     "window_node_compiles_on_the_banded_kernels",
     "full_node_with_yarn_compiles_on_the_causal_kernels",
     "a_group_read_in_place_compiles_with_batch_rows_folded",
+    "window_nodes_account_books_s1_and_copies_to_its_scope",
 ]
 MELLUM2_SHAPE = (1, 8192, 2304)
+ACCOUNT_CELL = "bertlarge_s128_1chip"
+ACCOUNT_INVARIANTS = ["walk_over_xla_of_a_whole_one_chip_cell"]
+
+
+def _window_node_account(compiled):
+    """"ok" where the account of the window node (PR 64:
+    `observability/step_account.account`) books what XLA laid in `S(1)` and
+    its copies (the rotary's relayouts, PERF.md PR 63: `copy` under the
+    attention scopes) to `ff.ring_attention.attn0`, forward and backward,
+    and its walk lands on XLA's own peak."""
+    if compiled is None:
+        return "the window node did not compile"
+    try:
+        found = account(compiled)
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        return f"{type(e).__name__}: {e}"[:2000]
+    node = [
+        r for r in found["rows"]
+        if r["kind"] == "ring_attention" and r["name"].split("/")[0] == "attn0"
+    ]
+    complaints = []
+    for phase in ("fwd", "bwd"):
+        rows = [r for r in node if r["phase"] == phase]
+        copies = sum(
+            r["families"].get("copy", {"written_bytes": 0})["written_bytes"]
+            for r in rows
+        )
+        if not sum(r["s1_bytes"] for r in rows):
+            complaints.append(f"no S(1) byte under {phase} ring_attention attn0")
+        # q and k rows relaid for the rotary: more than one [8192, 4096] bf16
+        if copies < 8192 * 4096 * 2:
+            complaints.append(f"{phase} copies under the node: {copies} bytes")
+    unattributed = sum(
+        r["written_bytes"] for r in found["rows"] if r["phase"] == "unattributed"
+    )
+    made = sum(r["written_bytes"] for r in found["rows"])
+    if unattributed > 0.1 * made:
+        complaints.append(f"{unattributed} of {made} bytes made carry no scope")
+    ratio = found["walk"]["walk_over_xla"]
+    if not 0.95 <= ratio <= 1.05:
+        complaints.append(f"walk_over_xla {ratio:.4f}")
+    return ", ".join(complaints) or "ok"
+
+
+def check_account(root):
+    """{invariant: "ok" or what was found}: the account of ONE whole one-chip
+    cell's step (`ACCOUNT_CELL`, the cheapest to compile, 24 layers) against
+    XLA's own totals of the same executable."""
+    try:
+        found = cell_step_bytes(ACCOUNT_CELL, root)
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        return {ACCOUNT_INVARIANTS[0]: f"{type(e).__name__}: {e}"[:2000]}
+    memory, walk = found["account"]["memory"], found["account"]["walk"]
+    complaints = []
+    if memory["total"] != round(found["step_hbm_gb"] * 1e9):
+        complaints.append(f"total {memory['total']}, step_hbm_gb {found['step_hbm_gb']}")
+    if not memory["xla_peak"] or memory["xla_peak"] > memory["total"]:
+        complaints.append(f"xla_peak {memory['xla_peak']} of total {memory['total']}")
+    if not 0.90 <= walk["walk_over_xla"] <= 1.10:
+        complaints.append(f"walk_over_xla {walk['walk_over_xla']:.4f}")
+    held = {r["phase"]: r["bytes"] for r in walk["held_at_peak"][:1]}
+    if held != {"arguments": memory["arguments"]}:
+        complaints.append(f"the largest holder at the peak is {held}")
+    if not sum(r["bytes"] for r in walk["kept_for_backward"]):
+        complaints.append("nothing is kept for the backward pass")
+    return {ACCOUNT_INVARIANTS[0]: ", ".join(complaints) or "ok"}
 
 
 def check_mellum2():
@@ -1234,7 +1281,7 @@ def check_mellum2():
               "flash_fwd_causal_bshf_window"]
     causal = ["flash_bwd_causal_bshf", "flash_delta_bshf",
               "flash_fwd_causal_bshf"]
-    found = {}
+    found, window_node = {}, None
     for invariant, want, extra in (
         (MELLUM2_INVARIANTS[0], banded, dict(window=1024)),
         (MELLUM2_INVARIANTS[1], causal, dict(
@@ -1258,13 +1305,16 @@ def check_mellum2():
                 out, vjp = jax.vjp(node, *operands)
                 return out, vjp(out)
 
-            text = jax.jit(both).lower(x, *ws).compile().as_text()
+            compiled = jax.jit(both).lower(x, *ws).compile()
+            window_node = window_node or compiled
+            text = compiled.as_text()
             names = sorted(set(re.findall(r"/(flash_\w+)/pallas_call", text)))
             found[invariant] = (
                 "ok" if names == want else f"kernels {names}, want {want}"
             )
         except Exception as e:  # noqa: BLE001 - the complaint is the result
             found[invariant] = f"{type(e).__name__}: {e}"[:2000]
+    found[MELLUM2_INVARIANTS[3]] = _window_node_account(window_node)
 
     # no cell has a grouped node with more than one sequence a chip: two
     # sequences of 4,096 positions fold into one forward program, whose
@@ -1357,6 +1407,8 @@ def cell_step_bytes(cell, root, **overrides):
         "temp_bytes": mem.temp_size_in_bytes,
         "kernels": sorted(set(re.findall(r"/(\w+)/pallas_call", text))),
         "seconds": round(time.time() - t0, 1),
+        # the same program by scope: rows, the walk's peak and who holds it
+        "account": account(compiled),
     }
 
 
@@ -1378,12 +1430,8 @@ def listing(name, least=4e6):
         written = _nbytes(result)
         moved += read + written
         if read + written >= least:
-            kind = re.search(r"kind=(k\w+)", line)
-            scope = re.search(r'op_name="([^"]*)"', line)
             lines.append(
-                f"{row_name:42s} {opcode:12s} {kind.group(1) if kind else '':8s}"
-                f" reads {read / 1e6:7.1f} MB writes {written / 1e6:7.1f} MB  "
-                f"{result[:64]:64s} {scope.group(1)[-56:] if scope else ''}"
+                listing_row(row_name, result, opcode, line, read, written)
             )
     lines.append(
         f"operands and results of every ENTRY instruction: {moved / 1e6:.1f} MB "
@@ -1401,7 +1449,7 @@ def compiled():
         ALLOW_MULTIPLE_LIBTPU_LOAD="1",
     )
     child = subprocess.run(
-        [sys.executable, os.path.abspath(__file__)], env=env, timeout=900,
+        [sys.executable, os.path.abspath(__file__)], env=env, timeout=1200,
         capture_output=True, text=True,
         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     )
@@ -1457,6 +1505,13 @@ def test_mellum2_nodes_compiled_for_the_described_chip(compiled, invariant):
     assert compiled["mellum2"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", ACCOUNT_INVARIANTS)
+def test_account_of_a_whole_cell_compiled_for_the_described_chip(
+    compiled, invariant
+):
+    assert compiled["account"][invariant] == "ok"
+
+
 if __name__ == "__main__":
     argv = sys.argv[1:]
     root = os.getcwd()
@@ -1465,6 +1520,7 @@ if __name__ == "__main__":
         root = os.path.abspath(argv[at + 1])
         del argv[at:at + 2]
     sys.path.insert(0, root)
+    _bind_parser()
     if argv and argv[0] == "joyai_step":
         print(json.dumps(joyai_step_bytes(int(argv[1]), root)))
     elif argv and argv[0] == "phi4flash_step":
@@ -1489,5 +1545,5 @@ if __name__ == "__main__":
                  lfm2=check_lfm2(), experts=check_experts(),
                  held_sums=check_held_sums(), qwen3next=check_qwen3next(),
                  joyai=check_joyai(), phi4flash=check_phi4flash(),
-                 mellum2=check_mellum2())
+                 mellum2=check_mellum2(), account=check_account(root))
         ))
