@@ -320,8 +320,111 @@ def mha_row_projections(attrs: MultiHeadAttentionAttrs) -> bool:
     return attrs.qk_norm or attrs.rope_theta is not None or attrs.grouped_query
 
 
+def rope_lane_tables(s: int, d: int, theta: float, scaling=None):
+    """(cos, signed sin), float32 `[s, max(d, 128)]`: the rotary of
+    `rope_bshf` over whole heads of `d` columns as whole lane tiles a
+    POSITION, which every head reads (`kernels/norm_rotary`): a head's lanes
+    hold cos | cos and -sin | sin of its pairs' angles, twice where two
+    heads of 64 fill a tile. The frequencies, YaRN's ramp and amplitude are
+    `rope_frequencies`', as `rope_bshf` takes them."""
+    inv_freq, amplitude = rope_frequencies(d, theta, scaling)
+    angle = jnp.arange(s, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if amplitude is not None:
+        cos, sin = cos * amplitude, sin * amplitude
+    times = 2 * max(128 // d, 1)
+    return (
+        jnp.tile(cos, (1, times)),
+        jnp.tile(jnp.concatenate([-sin, sin], axis=-1), (1, times // 2)),
+    )
+
+
+def _pass_form(attrs: MultiHeadAttentionAttrs):
+    """`norm_rotary.PassForm` of what the attrs ask of q's and k's rows."""
+    from flexflow_tpu.kernels.norm_rotary import PassForm
+
+    span = (
+        "head" if attrs.qk_norm_per_head else "row" if attrs.qk_norm else None
+    )
+    return PassForm(attrs.q_proj_size, span, attrs.rope_theta is not None)
+
+
+def between_form(attrs: MultiHeadAttentionAttrs, route: str, s: int,
+                 t: int = None):
+    """(said, form): which form the norm and the rotary of `mha_between`
+    take in a node whose core took `route`, on queries of `s` and keys of
+    `t` positions (`s` by default), from what the trace can observe. `said`
+    is what the program's counter is told: "pallas"
+    (`norm_rotary.norm_rotary`, one Pallas pass each way; `form` is then the
+    pass's `PassForm`, which `mha_between` takes) or "xla (<why>)", the plain
+    form (`rms_norm`, then `rope_bshf`; `form` None), where
+
+    - `route`: the core is not the "fused_row" one (the `[b, h, s, d]` and
+      dense cores turn the rows to heads first anyway), or a `flash_mesh` is
+      declared (a bare Pallas call has no partitioning rule);
+    - `rotary_dim`: the rotary turns a head's leading columns only;
+    - `output_gate`: q is columns cut out of the `[q | gate]` row, and a
+      kernel's operand must be a buffer (XLA then writes the cut out: 0.82
+      ms of `copy` a step for the 0.37 the pass saved in
+      `qwen3next80b_s8192_1chip`, chip runs of PR 65);
+    - `head <d>` / `row <lanes>`: `norm_rotary.pass_plan` admits the shapes
+      its body is tested on: heads of 64, 128 or 256 columns, and under a
+      whole-row norm a row of a power of two of lanes, 4,096 at most.
+
+    A node with neither norm nor rotary has nothing between: (None, None)."""
+    from flexflow_tpu.kernels.flash_attention import current_flash_mesh
+    from flexflow_tpu.kernels.norm_rotary import HEAD_SIZES, pass_plan
+
+    form = _pass_form(attrs)
+    if form.span is None and not form.rotary:
+        return None, None
+    if route != "fused_row" or current_flash_mesh() is not None:
+        return "xla (route)", None
+    if attrs.rotary_dim not in (None, form.d):
+        return "xla (rotary_dim)", None
+    if attrs.output_gate:
+        return "xla (output_gate)", None
+    if form.d not in HEAD_SIZES:
+        return f"xla (head {form.d})", None
+    for rows, heads in ((s, attrs.num_heads), (t or s, attrs.kv_heads)):
+        if pass_plan(rows, heads * form.d, form) is None:
+            return f"xla (row {heads * form.d})", None
+    return "pallas", form
+
+
+def _between_pallas(attrs: MultiHeadAttentionAttrs, form, qp, kp, qk_gains):
+    """q and k through `norm_rotary.norm_rotary` in `form`: the gains as
+    float32 rows a slab wide (1 + w where `qk_norm_zero_centered`; a head's
+    gain twice where two heads of 64 fill a lane tile), the tables a
+    position."""
+    from flexflow_tpu.kernels.norm_rotary import norm_rotary
+
+    def tables(positions):
+        if not form.rotary:
+            return None, None
+        return rope_lane_tables(
+            positions, form.d, attrs.rope_theta, attrs.rope_scaling
+        )
+
+    def passed(x, gain, cos, sin):
+        if form.span:
+            gain = gain.astype(jnp.float32)
+            if attrs.qk_norm_zero_centered:
+                gain = 1.0 + gain
+            if form.span == "head" and form.d < 128:
+                gain = jnp.tile(gain, 128 // form.d)
+            gain = gain[None, :]
+        return norm_rotary(x, gain, cos, sin, form, attrs.qk_norm_eps)
+
+    gains = qk_gains if form.span else (None, None)
+    q_tables = tables(qp.shape[1])
+    # one pair of tables where the keys are as long as the queries
+    k_tables = q_tables if kp.shape[1] == qp.shape[1] else tables(kp.shape[1])
+    return passed(qp, gains[0], *q_tables), passed(kp, gains[1], *k_tables)
+
+
 def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains,
-                repeat: bool = True):
+                repeat: bool = True, form=None):
     """What the attrs ask for between projection and attention core, on the
     fused [b, s, h*d] projections: QK-norm over the whole row (or, with
     `qk_norm_per_head`, over each head block by itself), then RoPE on
@@ -332,8 +435,13 @@ def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains,
     cores take. The causal tile schedule indexes the key/value block by
     `h // group` instead (`CausalPlan.group`, wherever the key is as wide as
     the value) and saves the two repeated copies: its caller passes `repeat`
-    false and hands the kernels k and v as they lie."""
-    if attrs.qk_norm_per_head:
+    false and hands the kernels k and v as they lie. With a `form` (the
+    `PassForm` `between_form` gave) norm and rotary are ONE Pallas pass each
+    way; the plain form below stays what every other node runs, and the
+    reference the kernels' tests compare against."""
+    if form is not None:
+        qp, kp = _between_pallas(attrs, form, qp, kp, qk_gains)
+    elif attrs.qk_norm_per_head:
         # a head's own features normed by themselves, one gain [d] for all
         # of q's heads and one for the key heads
 
@@ -348,7 +456,7 @@ def mha_between(attrs: MultiHeadAttentionAttrs, qp, kp, vp, qk_gains,
         zc = attrs.qk_norm_zero_centered
         qp = rms_norm(qp, qk_gains[0], attrs.qk_norm_eps, zc)
         kp = rms_norm(kp, qk_gains[1], attrs.qk_norm_eps, zc)
-    if attrs.rope_theta is not None:
+    if attrs.rope_theta is not None and form is None:
         qp = rope_bshf(
             qp, attrs.num_heads, attrs.rope_theta, attrs.rotary_dim,
             attrs.rope_scaling,
@@ -578,6 +686,16 @@ def _note_rotary(attrs: MultiHeadAttentionAttrs) -> None:
         f"default theta={attrs.rope_theta:g}" if attrs.rope_scaling is None
         else attrs.rope_scaling.describe(attrs.rope_theta, width)
     )
+
+
+def _note_between(said) -> None:
+    """Tell the program's counter what `between_form` said of the plain
+    attention node being lowered (`observability/trace.between_passes`),
+    where it has a norm or a rotary."""
+    from flexflow_tpu.observability import trace
+
+    if said is not None:
+        trace.note_between_pass(said)
 
 
 def _note_window_tiles(plan, s: int) -> None:
@@ -961,6 +1079,11 @@ def _mha_forward(
     group = 1 if plan is None else plan.group
     _note_route(route, attrs, group)
     _note_rotary(attrs)
+    # the form of the norm and the rotary: asked once, noted and taken
+    said, between = (
+        between_form(attrs, route, s, k.shape[1]) if post else (None, None)
+    )
+    _note_between(said)
     banded = _banded(attrs, s)
     if banded and (not causal or route not in ("fused_row", "dense")):
         # `mha_core_route` sends no windowed node here; a caller that forces
@@ -995,7 +1118,8 @@ def _mha_forward(
         if post:
             with _rows_scope(attrs):
                 qp, kp, vp = mha_between(
-                    attrs, qp, kp, vp, qk_gains, repeat=group == 1
+                    attrs, qp, kp, vp, qk_gains, repeat=group == 1,
+                    form=between,
                 )
         # padded heads and rows read in place came with a scope of their own
         with jax.named_scope("core") if pads or group > 1 else (

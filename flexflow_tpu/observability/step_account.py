@@ -20,7 +20,10 @@ order (`is_scheduled=true`) and where every result is a buffer. It returns
   memory space 1 (`s1_bytes`);
 - `walk`: a liveness walk over the schedule, an ESTIMATE of XLA's assignment
   that says how good it is (`walk_over_xla`): the peak, who holds it, and
-  what the forward pass leaves for the backward pass;
+  what the forward pass leaves for the backward pass. A result, and a donated
+  argument, lies in an allocation that outlives the run; a temporary that
+  lives wholly while such an allocation holds nothing adds no byte
+  (`lodged_bytes`, `_Walk._lodge`);
 - `not_walked`: what the walk did not enter, by name.
 
 Bytes are a buffer's own: its dimensions rounded up to the tiles of its
@@ -42,6 +45,7 @@ text of it, as `setup_report()` is of set-up.
 
 from __future__ import annotations
 
+import bisect
 import re
 from typing import Dict, List, Optional, Tuple
 
@@ -335,6 +339,7 @@ class _Walk:
         self.born: List[int] = []
         self.last: List[int] = []  # the last reader's index
         self.free: set = set()  # outputs that come back in a donated argument
+        self.lodged: set = set()  # temporaries laid where a result will lie
         self.made: List[List[int]] = [[] for _ in self.rows]
         self.read: List[List[int]] = [[] for _ in self.rows]
         names = [_OP_NAME.search(r[4]) for r in self.rows]
@@ -342,7 +347,16 @@ class _Walk:
             parse_scope(m.group(1)) if m else ("unattributed", "", "")
             for m in names
         ]
-        self._named = [m is not None for m in names]
+        # an argument's own name on another instruction is XLA's relayout of
+        # the argument: no name of its own
+        arguments = {
+            m.group(1) for m, r in zip(names, self.rows)
+            if m and r[2] == "parameter"
+        }
+        self._named = [
+            m is not None and (r[2] == "parameter" or m.group(1) not in arguments)
+            for m, r in zip(names, self.rows)
+        ]
         # rows whose scope is what they move's -> the buffers they hand on
         self.unnamed: Dict[int, List[int]] = {}
         self.called: List[Tuple[int, str, str]] = []  # row, attribute, name
@@ -415,29 +429,107 @@ class _Walk:
                 if seen and not self._named[i]:
                     # XLA's own (an asynchronous copy into or out of S(1), a
                     # slice of one): booked to the scope of what it moves
-                    self.scope[i] = self.scope[self.owner[next(iter(seen))]]
+                    scopes = [self.scope[self.owner[b]] for b in seen]
+                    self.scope[i] = next(
+                        (s for s in scopes if s[0] != "unattributed"), scopes[0]
+                    )
                     self.unnamed[i] = _ids(tree)
             value[name] = tree
             if line.lstrip().startswith("ROOT "):
                 root = tree
         if root is None:
             raise ValueError("the ENTRY computation has no ROOT instruction")
+        header = re.search(r"^HloModule .*$", text, re.M)
+        over = {}  # a result's buffer -> the donated argument's it is written over
+        for out, parameter, inside in _aliases(
+            header.group(0) if header else "", "input_output_alias"
+        ):
+            ours = _ids(_at_path(parameters[parameter], inside))
+            theirs = _ids(_at_path(root, out))
+            # the output is written where the donated argument lay: one buffer
+            self.free |= set(theirs) - set(ours)
+            if len(theirs) == len(ours):  # (not pieces laid end to end)
+                over.update(zip(theirs, ours))
+        self._scope_what_moves_nothing()
+        self._lodge(dict.fromkeys(_ids(root)), over)
         end = len(self.rows)
         for b in _ids(root):
             # what the program returns lies in an allocation of its own, which
-            # the caller holds from the start (XLA shares no output's
-            # allocation with a temporary)
+            # the caller holds from the start (the only temporaries XLA lays
+            # there are `lodged`)
             self.born[b], self.last[b] = -1, end
         for b, owner in enumerate(self.owner):
             if self.rows[owner][2] == "parameter":
                 self.last[b] = end
-        header = re.search(r"^HloModule .*$", text, re.M)
-        for out, parameter, inside in _aliases(
-            header.group(0) if header else "", "input_output_alias"
-        ):
-            ours = set(_ids(_at_path(parameters[parameter], inside)))
-            # the output is written where the donated argument lay: one buffer
-            self.free |= set(_ids(_at_path(root, out))) - ours
+
+    def _scope_what_moves_nothing(self):
+        """An instruction XLA added without a name that moves nothing a scope
+        made (an argument prefetched into `S(1)` or relaid, the zeros a
+        gradient is put together in) is booked to the scope of the first
+        reader of what it makes: the node it was made for. Last to first, so
+        that a chain of them (a copy's start, its done) ends at a name."""
+        readers: Dict[int, List[int]] = {}
+        for i, read in enumerate(self.read):
+            for b in read:
+                readers.setdefault(b, []).append(i)
+        for i in reversed(range(len(self.rows))):
+            if self._named[i] or self.scope[i][0] != "unattributed":
+                continue
+            # what it makes, or hands on of what it was handed (a copy's done)
+            later = [
+                next((j for j in readers.get(b, ()) if j > i), None)
+                for b in self.made[i] + self.unnamed.get(i, [])
+            ]
+            later = [j for j in later if j is not None]
+            if later and self.rows[i][2] != "parameter":
+                self.scope[i] = self.scope[min(later)]
+
+    def _lodge(self, results, over):
+        """Mark the temporaries XLA can lay in an allocation that outlives the
+        run, from the lifetimes as walked. Such an allocation is a result's
+        (held by the caller from the start, empty until the result is made)
+        or a donated argument's (empty from its last reader until the result
+        in its place is made); XLA's buffer assignment gives it, besides, to
+        any buffer that fits and lives wholly while it is empty, one at a time
+        (each lies at offset 0), the largest buffers choosing first: the q
+        projection of an attention node ALONE lies where its weights' gradient
+        is put together, 67 MB the heap never holds. Here the smallest
+        allocation that fits takes it."""
+        gaps = []  # (bytes, first instruction empty, last instruction empty)
+        for b in results:
+            if self.space[b] or self.rows[self.owner[b]][2] == "parameter":
+                continue
+            if b not in over:
+                if b not in self.free:
+                    gaps.append((self.size[b], 0, self.born[b] - 1))
+            elif over[b] != b and not self.space[over[b]]:
+                gaps.append(
+                    (self.size[over[b]], self.last[over[b]] + 1, self.born[b] - 1)
+                )
+        gaps = sorted(g for g in gaps if g[1] <= g[2])
+        if not gaps:
+            return
+        sizes = [g[0] for g in gaps]
+        held: List[List[Tuple[int, int]]] = [[] for _ in gaps]
+        temporaries = sorted(
+            (
+                b for b in range(len(self.size))
+                if b not in results and 0 < self.size[b] <= sizes[-1]
+                and not self.space[b]
+                and self.rows[self.owner[b]][2] != "parameter"
+            ),
+            key=lambda b: (-self.size[b], self.born[b]),
+        )
+        for b in temporaries:
+            born, last = self.born[b], self.last[b]
+            for k in range(bisect.bisect_left(sizes, self.size[b]), len(gaps)):
+                _, first, until = gaps[k]
+                if first <= born and last <= until and all(
+                    last < s or e < born for s, e in held[k]
+                ):
+                    held[k].append((born, last))
+                    self.lodged.add(b)
+                    break
 
     def _made(self, i, result, opcode, held, tail, line):
         """The value of an instruction that makes buffers: a fresh one at every
@@ -489,8 +581,11 @@ class _Walk:
 
     def counted(self, b):
         """Bytes buffer `b` adds to the device's memory while it lives: none
-        where it is a donated argument's own, none outside memory space 0."""
-        return 0 if b in self.free or self.space[b] else self.size[b]
+        where it is a donated argument's own or lies where a result will
+        (`_lodge`), none outside memory space 0."""
+        if b in self.free or b in self.lodged or self.space[b]:
+            return 0
+        return self.size[b]
 
     def live_bytes(self):
         """Bytes of memory space 0 live at each instruction of the schedule."""
@@ -632,6 +727,7 @@ def account_of_text(text: str, memory: Optional[dict] = None) -> dict:
             "instructions": len(walk.rows),
             "buffers": len(walk.size),
             "peak_bytes": peak,
+            "lodged_bytes": sum(walk.size[b] for b in walk.lodged),
             "peak_at": {
                 "index": peak_at, "instruction": at[0], "opcode": at[2],
                 "scope": walk.scope[peak_at],
@@ -728,7 +824,8 @@ def report(top: int = 12, of: Optional[dict] = None) -> str:
         ),
         f"walk: peak {_mb(walk['peak_bytes'])} MB at instruction "
         f"{at['index']} of {walk['instructions']} ({at['instruction']}, "
-        f"{'/'.join(at['scope'])}), walk_over_xla "
+        f"{'/'.join(at['scope'])}), {_mb(walk['lodged_bytes'])} MB of "
+        "temporaries laid where a result will lie, walk_over_xla "
         + ("none" if ratio is None else f"{ratio:.4f}"),
         f"held at the peak (the {top} largest of {len(walk['held_at_peak'])}), MB:",
     ]

@@ -481,6 +481,12 @@ def setup_report(top: int = 12) -> str:
         f"once {counted_once:.3f} s: {naive - counted_once:.3f} s of nested jits "
         "counted again in their callers"
     )
+    forms = sorted(_BETWEEN_PASSES.values())
+    if forms:
+        lines.append(
+            "norm and rotary of the plain attention nodes (between_passes()): "
+            + ", ".join(f"{forms.count(f)} {f}" for f in dict.fromkeys(forms))
+        )
     return "\n".join(lines)
 
 
@@ -747,6 +753,30 @@ def rotaries() -> Dict[str, str]:
     roofline` readers print beside what they measure, so that two layers of
     one graph that turn differently say so themselves."""
     return dict(_ROTARIES)
+
+
+_BETWEEN_PASSES: Dict[str, str] = {}
+
+
+def note_between_pass(form: str) -> None:
+    """The form the norm and the rotary took in the plain attention node
+    being lowered (`kernels/ops.between_form`); dropped where no node's
+    scope is open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _BETWEEN_PASSES[scope] = form
+
+
+def between_passes() -> Dict[str, str]:
+    """`{ff.<kind>.<name>: form}` of every plain attention node with a
+    QK-norm or a rotary that this process has lowered, as it was lowered
+    last, beside `rotaries()`: `pallas` (norm and rotary of q and of k as ONE
+    Pallas pass each way, `norm_rotary_fwd` / `norm_rotary_bwd`) or
+    `xla (<why>)` (`rms_norm`, then `rope_bshf`, differentiated by JAX;
+    `kernels/ops.between_form` lists the reasons: `route`, `rotary_dim`,
+    `output_gate`, `head <d>`, `row <lanes>`), so that a run that fell back
+    says so itself. `setup_report()` prints it."""
+    return dict(_BETWEEN_PASSES)
 
 
 def note_latent_attention_form(form: dict) -> None:

@@ -349,3 +349,83 @@ def test_spans_are_events_of_the_profilers_host_plane(tmp_path):
         assert step.start_ns <= dispatch.start_ns
         assert (dispatch.start_ns + dispatch.duration_ns
                 <= step.start_ns + step.duration_ns)
+
+
+BETWEEN = {
+    # scope: (the node form of `test_mha_between_kernel.FORMS`, more attrs,
+    # the backend forced, the counter's word)
+    "ff.ring_attention.mellum2_window": (
+        "mellum2_default", dict(window=300), True, "pallas"),
+    "ff.ring_attention.mellum2_full": ("mellum2_yarn", {}, True, "pallas"),
+    "ff.ring_attention.ouro": ("ouro_rotary_alone", {}, True, "pallas"),
+    "ff.ring_attention.olmoe": ("olmoe_row_norm", {}, True, "pallas"),
+    "ff.ring_attention.lfm2": ("lfm2_heads_of_64", {}, True, "pallas"),
+    # Qwen3-Next's: 64 columns of a head turned
+    "ff.ring_attention.qwen3next": (
+        "heads_of_256_zero_centred", dict(rotary_dim=64), True,
+        "xla (rotary_dim)"),
+    "ff.ring_attention.gated": (
+        "mellum2_default", dict(output_gate=True), True, "xla (output_gate)"),
+    # no kernel core on the plain CPU: the dense route
+    "ff.ring_attention.on_the_cpu": ("mellum2_default", {}, False, "xla (route)"),
+}
+
+
+@pytest.mark.parametrize("scope", list(BETWEEN))
+def test_the_form_of_norm_and_rotary_is_counted_by_node(monkeypatch, scope):
+    """`observability/trace.between_passes()` names the form the norm and
+    the rotary took in each plain attention node as it was lowered:
+    `pallas` for the Mellum2, Ouro, OLMoE and LFM2 node forms on the
+    "fused_row" route, `xla` with the rule's reason elsewhere, nothing for a
+    node that has neither, nothing where no node's scope is open; and
+    `rotaries()` keeps its words."""
+    import functools
+
+    import jax.numpy as jnp
+    from test_mha_between_kernel import attrs_of, node_step
+
+    from flexflow_tpu.kernels import flash_attention as flash
+    from flexflow_tpu.kernels import ops
+
+    form, more, forced, want = BETWEEN[scope]
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", "512")
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_K", "512")
+    if forced:
+        monkeypatch.setattr(
+            flash, "_backend_ok", lambda allow_interpret=False: True
+        )
+        monkeypatch.setattr(
+            flash, "flash_attention_bshf",
+            functools.partial(flash.flash_attention_bshf, interpret=True),
+        )
+    monkeypatch.setattr(trace, "_BETWEEN_PASSES", {})
+    monkeypatch.setattr(trace, "_ROTARIES", {})
+    attrs = attrs_of(form, **more)
+    step, (x, flat, gains) = node_step(attrs)
+
+    def forward(x, flat, gains):
+        return ops._mha_forward(attrs, x, x, x, flat, causal=True, qk_gains=gains)
+
+    monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+    jax.eval_shape(forward, x, flat, gains)
+    assert trace.between_passes() == {scope: want}
+    assert trace.rotaries()[scope].startswith(
+        "yarn factor=16" if form == "mellum2_yarn" else "default theta="
+    )
+    # a node with neither norm nor rotary is not counted, nor is a pass
+    # called by itself, under no node's scope
+    from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
+
+    bare = RingAttentionAttrs(256, 4, 128, 128, causal=True, num_kv_heads=2)
+    monkeypatch.setattr(trace._lowering, "scope", "ff.ring_attention.bare")
+    _, (x, flat, _) = node_step(bare)
+    jax.eval_shape(
+        lambda x, flat: ops._mha_forward(bare, x, x, x, flat, causal=True),
+        x, flat,
+    )
+    monkeypatch.setattr(trace._lowering, "scope", None)
+    jax.eval_shape(forward, x, node_step(attrs)[1][1], gains)
+    assert trace.between_passes() == {scope: want}
+    report = trace.setup_report()
+    assert f"(between_passes()): 1 {want}" in report

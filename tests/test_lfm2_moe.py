@@ -278,6 +278,9 @@ def test_padded_heads_on_the_causal_tile_kernels_match_the_reference(monkeypatch
     want = jax.value_and_grad(
         lambda u, ws: jnp.sum(reference_attention(u, ws, sizes) * cot), (0, 1)
     )(u, ws)
+    # the per-head norm and the rotary before the core are the cell's too:
+    # two heads of 64 a lane tile through `kernels/norm_rotary`, interpreted
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
     # the head-pair kernels' one tile is 1,024 positions by default: at 512
     # this sequence is more than one, as the cell's 8,192 are at 1,024

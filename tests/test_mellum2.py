@@ -360,6 +360,9 @@ def test_windowed_node_on_the_banded_kernels_matches_the_reference(monkeypatch):
     want = jax.value_and_grad(
         lambda u, ws: jnp.sum(reference_attention(kind, u, ws, sizes) * cot), (0, 1)
     )(u, ws)
+    # the per-head norm and the rotary before the core are the cell's too:
+    # ONE Pallas pass each way (`kernels/norm_rotary`), interpreted
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", "512")
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_K", "512")
@@ -411,6 +414,9 @@ def test_window_node_hands_the_kernels_its_keys_and_values_as_they_lie(
     u, ws = attention_case(seq=s, sizes=sizes, batch=b)
     u, cot = u * 0.5, rand(np.random.RandomState(4), b, s, 64)
     kind = "sliding_attention"
+    # (the norm and the rotary before the core: `kernels/norm_rotary`,
+    # interpreted like the core)
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
     monkeypatch.setattr(
         flash, "flash_attention_bshf",
@@ -434,7 +440,9 @@ def test_window_node_hands_the_kernels_its_keys_and_values_as_they_lie(
     )
     kernels = {
         eqn.params["name"]: [v.aval.shape for v in eqn.invars[1:3]]
-        for eqn in pallas_eqns(jaxpr) if "delta" not in eqn.params["name"]
+        for eqn in pallas_eqns(jaxpr)
+        if "delta" not in eqn.params["name"]
+        and not eqn.params["name"].startswith("norm_rotary")
     }
     assert kernels == {
         name: [(b, s, d)] * 2 for name in (

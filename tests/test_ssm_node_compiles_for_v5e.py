@@ -1184,18 +1184,39 @@ MELLUM2_INVARIANTS = [
     "full_node_with_yarn_compiles_on_the_causal_kernels",
     "a_group_read_in_place_compiles_with_batch_rows_folded",
     "window_nodes_account_books_s1_and_copies_to_its_scope",
+    "window_node_holds_no_float32_query_row",
 ]
 MELLUM2_SHAPE = (1, 8192, 2304)
+# PR 66: the plain attention nodes whose norm and rotary are ONE Pallas pass
+# each way (`kernels/norm_rotary`), whole, forward and backward, at their
+# cells' shapes: (batch, positions, attrs). Mellum2's two nodes are compiled
+# by `check_mellum2`, Qwen3-Next's (the plain form: 64 of a head's 256
+# columns turned) by `check_qwen3next`
+BETWEEN_NODES = {
+    "ouro_rotary_alone_on_16_heads_of_128": (1, 8192, dict(
+        embed_dim=2048, num_heads=16, kdim=128, vdim=128, rope_theta=1e6)),
+    "olmoe_row_norm_of_16_heads_of_128": (4, 4096, dict(
+        embed_dim=2048, num_heads=16, kdim=128, vdim=128, rope_theta=1e4,
+        qk_norm_eps=1e-5)),
+    "lfm2_per_head_norm_of_32_over_8_heads_of_64": (2, 8192, dict(
+        embed_dim=2048, num_heads=32, kdim=64, vdim=64, rope_theta=1e6,
+        qk_norm_eps=1e-5, qk_norm_per_head=True, num_kv_heads=8)),
+}
+BETWEEN_FALLBACK = "qwen3next_node_keeps_the_plain_form_and_says_why"
 ACCOUNT_CELL = "bertlarge_s128_1chip"
 ACCOUNT_INVARIANTS = ["walk_over_xla_of_a_whole_one_chip_cell"]
 
 
 def _window_node_account(compiled):
     """"ok" where the account of the window node (PR 64:
-    `observability/step_account.account`) books what XLA laid in `S(1)` and
-    its copies (the rotary's relayouts, PERF.md PR 63: `copy` under the
-    attention scopes) to `ff.ring_attention.attn0`, forward and backward,
-    and its walk lands on XLA's own peak."""
+    `observability/step_account.account`) books what XLA laid in `S(1)` to
+    `ff.ring_attention.attn0`, forward and backward, its walk lands on
+    XLA's own peak, and (PR 66: the norm and the rotary are the kernels
+    `norm_rotary_*`) the copies under the node outside its core are less
+    than one `[8192, 4096]` bf16 row each way (the plain form relaid q and k
+    in float32 for the rotary, PERF.md PR 63: `copy` under the attention
+    scopes; the core keeps the group sum's relayouts of dk and dv, ROADMAP
+    S3 (vii))."""
     if compiled is None:
         return "the window node did not compile"
     try:
@@ -1204,19 +1225,18 @@ def _window_node_account(compiled):
         return f"{type(e).__name__}: {e}"[:2000]
     node = [
         r for r in found["rows"]
-        if r["kind"] == "ring_attention" and r["name"].split("/")[0] == "attn0"
+        if r["kind"] == "ring_attention" and r["name"].startswith("attn0")
     ]
     complaints = []
     for phase in ("fwd", "bwd"):
         rows = [r for r in node if r["phase"] == phase]
         copies = sum(
             r["families"].get("copy", {"written_bytes": 0})["written_bytes"]
-            for r in rows
+            for r in rows if r["name"] == "attn0"
         )
         if not sum(r["s1_bytes"] for r in rows):
             complaints.append(f"no S(1) byte under {phase} ring_attention attn0")
-        # q and k rows relaid for the rotary: more than one [8192, 4096] bf16
-        if copies < 8192 * 4096 * 2:
+        if copies >= 8192 * 4096 * 2:
             complaints.append(f"{phase} copies under the node: {copies} bytes")
     unattributed = sum(
         r["written_bytes"] for r in found["rows"] if r["phase"] == "unattributed"
@@ -1228,6 +1248,87 @@ def _window_node_account(compiled):
     if not 0.95 <= ratio <= 1.05:
         complaints.append(f"walk_over_xla {ratio:.4f}")
     return ", ".join(complaints) or "ok"
+
+
+def _float32_rows_under(text, scope, rows):
+    """"ok" where no ENTRY instruction under `scope` makes a float32 buffer
+    as wide as one of `rows` (`[.., positions, heads * d]`: q's or k's; the
+    plain form's norm and rotary left nine such results under Mellum2's
+    window node, 134 MB each)."""
+    wide = [
+        name for name, result, opcode, _, line in entry_instructions(text)
+        if opcode not in _NO_BUFFER and scope in line and any(
+            dtype == "f32" and dims[-2:] in rows
+            for dtype, dims in shapes_of(result)
+        )
+    ]
+    return f"float32 rows under {scope}: {wide[:4]}" if wide else "ok"
+
+
+def check_between():
+    """{node: "ok" or what was found}: each plain attention node of
+    `BETWEEN_NODES` whole, forward and backward, compiles for the described
+    chip with the pass's two kernels twice (q and k) by the names the
+    profile will carry, the counter says `pallas`, and no float32 buffer as
+    wide as q's or k's row lies under the node's scope; and Qwen3-Next's
+    node keeps the plain form and says why."""
+    import jax
+
+    from flexflow_tpu.kernels import ops
+    from flexflow_tpu.observability import trace
+    from flexflow_tpu.op_attrs.core import get_weight_shapes
+    from flexflow_tpu.op_attrs.datatype import DataType
+    from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
+    from flexflow_tpu.op_attrs.tensor_shape import TensorShape
+
+    on_chip = _described_chip()
+    scope = "ff.ring_attention.attn0"
+    found = {}
+    for name, (b, s, fields) in BETWEEN_NODES.items():
+        try:
+            attrs = RingAttentionAttrs(causal=True, **fields)
+            dims = (b, s, attrs.embed_dim)
+            shape = TensorShape(dims, DataType.FLOAT)
+            x = on_chip(dims)
+            ws = [on_chip(w.dims) for w in get_weight_shapes(attrs, [shape] * 3)]
+
+            def node(x, *ws, attrs=attrs):
+                with jax.named_scope(scope):
+                    return ops._mha_forward(
+                        attrs, x, x, x, ws[0], causal=True,
+                        qk_gains=ws[1:] or None,
+                    )
+
+            def both(*operands, node=node):
+                out, vjp = jax.vjp(node, *operands)
+                return out, vjp(out)
+
+            trace._lowering.scope = scope
+            try:
+                text = jax.jit(both).lower(x, *ws).compile().as_text()
+                said = trace.between_passes().get(scope)
+            finally:
+                trace._lowering.scope = None
+            names = sorted(re.findall(r"/(norm_rotary_\w+)/pallas_call", text))
+            # (the same kernel's call sites may share one custom call's name)
+            want = {"norm_rotary_bwd", "norm_rotary_fwd"}
+            d = attrs.q_proj_size
+            rows = {(s, attrs.num_heads * d), (s, attrs.kv_heads * d)}
+            found[name] = (
+                f"the counter says {said}" if said != "pallas"
+                else f"kernels {names}" if set(names) != want
+                else _float32_rows_under(text, scope, rows)
+            )
+        except Exception as e:  # noqa: BLE001 - the complaint is the result
+            found[name] = f"{type(e).__name__}: {e}"[:2000]
+    gated = RingAttentionAttrs(
+        embed_dim=2048, num_heads=16, kdim=256, vdim=256, causal=True,
+        rope_theta=1e7, rotary_dim=64, qk_norm_eps=1e-6, qk_norm_per_head=True,
+        qk_norm_zero_centered=True, num_kv_heads=2, output_gate=True,
+    )
+    said, _ = ops.between_form(gated, "fused_row", 8192)
+    found[BETWEEN_FALLBACK] = "ok" if said == "xla (rotary_dim)" else f"{said}"
+    return found
 
 
 def check_account(root):
@@ -1315,6 +1416,13 @@ def check_mellum2():
         except Exception as e:  # noqa: BLE001 - the complaint is the result
             found[invariant] = f"{type(e).__name__}: {e}"[:2000]
     found[MELLUM2_INVARIANTS[3]] = _window_node_account(window_node)
+    found[MELLUM2_INVARIANTS[4]] = (
+        "the window node did not compile" if window_node is None
+        else _float32_rows_under(
+            window_node.as_text(), "ff.ring_attention.attn0",
+            {(8192, 32 * 128), (8192, 4 * 128)},
+        )
+    )
 
     # no cell has a grouped node with more than one sequence a chip: two
     # sequences of 4,096 positions fold into one forward program, whose
@@ -1505,6 +1613,11 @@ def test_mellum2_nodes_compiled_for_the_described_chip(compiled, invariant):
     assert compiled["mellum2"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("node", list(BETWEEN_NODES) + [BETWEEN_FALLBACK])
+def test_norm_and_rotary_pass_compiled_for_the_described_chip(compiled, node):
+    assert compiled["between"][node] == "ok"
+
+
 @pytest.mark.parametrize("invariant", ACCOUNT_INVARIANTS)
 def test_account_of_a_whole_cell_compiled_for_the_described_chip(
     compiled, invariant
@@ -1537,6 +1650,8 @@ if __name__ == "__main__":
         print(json.dumps(cell_step_bytes("ouro26b_s8192_1chip", root, **cut)))
     elif argv and argv[0] == "mellum2":
         print(json.dumps(check_mellum2()))
+    elif argv and argv[0] == "between":
+        print(json.dumps(check_between()))
     elif argv:
         print(listing(argv[0]))
     else:
@@ -1545,5 +1660,6 @@ if __name__ == "__main__":
                  lfm2=check_lfm2(), experts=check_experts(),
                  held_sums=check_held_sums(), qwen3next=check_qwen3next(),
                  joyai=check_joyai(), phi4flash=check_phi4flash(),
-                 mellum2=check_mellum2(), account=check_account(root))
+                 mellum2=check_mellum2(), between=check_between(),
+                 account=check_account(root))
         ))

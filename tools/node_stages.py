@@ -36,6 +36,14 @@ def load(path):
     return step_anatomy.load_scoped(trace_reduce.find_xplane(path))
 
 
+def last_primitive(op_name):
+    """The last primitive of a framework op name; a Pallas kernel's with the
+    kernel's own name before it (`norm_rotary_fwd/pallas_call`), so that a
+    node's kernels are rows apart and no sum called `pallas_call`."""
+    parts = op_name.rstrip(":").split("/") if op_name else [""]
+    return "/".join(parts[-2:]) if parts[-1] == "pallas_call" else parts[-1]
+
+
 def stages(events, kind="ring_attention"):
     """{"steps", "busy_ms", "rows": [[node, phase, last primitive, family,
     ms a step], ...] largest first, "copies": [[kind, phase, ms], ...]}: the
@@ -46,8 +54,7 @@ def stages(events, kind="ring_attention"):
 
     def scope(op_name):
         phase, node_kind, name = parse_scope(op_name)
-        last = op_name.rstrip(":").split("/")[-1] if op_name else ""
-        return phase, node_kind, name + "|" + last
+        return phase, node_kind, name + "|" + last_primitive(op_name)
 
     steps = step_anatomy.traced_steps(events)
     table = step_anatomy.anatomy(events, scope)
