@@ -246,7 +246,7 @@ class FFModel:
         kdim=0, vdim=0, dropout=0.0, bias=False,
         add_bias_kv=False, add_zero_attn=False, initializer=None, name=None,
         causal=False, rope_theta=None, qk_norm_eps=None, window=None,
-        rope_scaling=None,
+        rope_scaling=None, softmax_scale=None,
     ) -> Tensor:
         return self._wrap(self._builder.multihead_attention(
             self._unwrap(query), self._unwrap(key), self._unwrap(value),
@@ -254,7 +254,7 @@ class FFModel:
             bias=bias, add_bias_kv=add_bias_kv, add_zero_attn=add_zero_attn,
             initializer=initializer, name=name, causal=causal,
             rope_theta=rope_theta, qk_norm_eps=qk_norm_eps, window=window,
-            rope_scaling=rope_scaling,
+            rope_scaling=rope_scaling, softmax_scale=softmax_scale,
         ))
 
     def conv2d(

@@ -259,10 +259,10 @@ def _fwd_kernel(
     lse_ref[0, :] = m + jnp.log2(l)  # base-2 lse
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret=False):
+def _fwd(q, k, v, causal, block_q, block_k, interpret=False, scale=None):
     bh, s, d = q.shape
     nq = s // block_q
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     bb = _batch_block(bh, block_q, block_k, s, d, q.dtype.itemsize)
     if bb > 1:
         # batch-fold BB (batch*head) rows per program: at d=64 (the
@@ -458,12 +458,12 @@ def _delta_rows(do, o, interpret=False):
     )(do, o)
 
 
-def _bwd_rows_fused(q, k, v, o, lse, do, causal, interpret=False):
+def _bwd_rows_fused(q, k, v, o, lse, do, causal, interpret=False, scale=None):
     """Batch-folded fused backward for the [bh, s, d] layout (s == block):
     the d=64 reference config otherwise pays one kernel launch per
     (batch, head) row."""
     bh, s, d = q.shape
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     lse3 = lse.reshape(bh, 1, s)
     delta3 = _delta_rows(do, o, interpret)
     bb = _batch_block(bh, s, s, s, d, q.dtype.itemsize, fused_bwd=True)
@@ -497,13 +497,13 @@ def _bwd_rows_fused(q, k, v, o, lse, do, causal, interpret=False):
     return dq, dk, dv
 
 
-def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret=False):
+def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret=False, scale=None):
     bh, s, d = q.shape
     if s <= block_q and s <= block_k:
-        return _bwd_rows_fused(q, k, v, o, lse, do, causal, interpret)
+        return _bwd_rows_fused(q, k, v, o, lse, do, causal, interpret, scale)
     nq = s // block_q
     nk = s // block_k
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     lse3 = lse.reshape(bh, 1, s)
     delta3 = delta.reshape(bh, 1, s)
@@ -559,20 +559,20 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret=False):
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, interpret):
-    o, _ = _fwd(q, k, v, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, block_q, block_k, interpret, scale=None):
+    o, _ = _fwd(q, k, v, causal, block_q, block_k, interpret, scale)
     return o
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, scale=None):
+    o, lse = _fwd(q, k, v, causal, block_q, block_k, interpret, scale)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, do):
+def _flash_bwd(causal, block_q, block_k, interpret, scale, res, do):
     q, k, v, o, lse = res
-    return _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret)
+    return _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret, scale)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -589,9 +589,10 @@ def _clamp_block(block: int, s: int) -> int:
 
 def flash_attention(
     q, k, v, *, causal: bool = False, block_q: int = None, block_k: int = None,
-    interpret: bool = False,
+    interpret: bool = False, scale: float = None,
 ):
-    """Blockwise attention on [b, h, s, d] per-head tensors.
+    """Blockwise attention on [b, h, s, d] per-head tensors (`scale`: the
+    scores' multiplier where it is not d ** -0.5).
 
     Requires s divisible by the block sizes; callers gate on
     flash_attention_supported(). Default blocks are 1024 (clamped to s,
@@ -611,7 +612,7 @@ def flash_attention(
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * h, s, d)
     vf = v.reshape(b * h, s, d)
-    o = _flash(qf, kf, vf, causal, bq, bk, interpret)
+    o = _flash(qf, kf, vf, causal, bq, bk, interpret, scale)
     return o.reshape(b, h, s, d)
 
 
@@ -758,7 +759,7 @@ def _fwd_kernel_b(
 
 def _fwd_pair_call(
     name, operands, b, s, f, h, causal, block_q, block_k, interpret, dtype,
-    qkv_index_maps,
+    qkv_index_maps, scale=None,
 ):
     """Shared pallas_call of the head-pair forwards: `operands` are the q/k/v
     arrays (three distinct, or the same fused-QKV array thrice),
@@ -767,7 +768,7 @@ def _fwd_pair_call(
     d = f // h
     assert 2 * d == 128 and h % 2 == 0, (d, h)
     nq = s // block_q
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     bb = _batch_block(b, block_q, block_k, s, 128, dtype.itemsize)
     kernel = functools.partial(
         _pair_fwd_kernel(s, block_q, block_k), causal=causal, scale=scale,
@@ -801,7 +802,8 @@ def _fwd_pair_call(
     return o, lse
 
 
-def _fwd_bshf_pair(q, k, v, h, causal, block_q, block_k, interpret=False):
+def _fwd_bshf_pair(q, k, v, h, causal, block_q, block_k, interpret=False,
+                   scale=None):
     """d=64 entry: blocks hold a PAIR of heads (128 lanes) — see
     _fwd_kernel_pair."""
     b, s, f = q.shape
@@ -812,14 +814,15 @@ def _fwd_bshf_pair(q, k, v, h, causal, block_q, block_k, interpret=False):
             lambda bi, hp, i: (bi, i, hp),
             lambda bi, hp, i: (bi, 0, hp),
             lambda bi, hp, i: (bi, 0, hp),
-        ),
+        ), scale,
     )
 
 
-def _bwd_bshf_pair_fused(q, k, v, o, lse, do, h, causal, interpret=False):
+def _bwd_bshf_pair_fused(q, k, v, o, lse, do, h, causal, interpret=False,
+                         scale=None):
     b, s, f = q.shape
     d = f // h
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     bb = _batch_block(
         b, s, s, s, 128, q.dtype.itemsize, fused_bwd=True, bwd_blocks=8,
     )
@@ -855,7 +858,8 @@ def _bwd_bshf_pair_fused(q, k, v, o, lse, do, h, causal, interpret=False):
     return dq, dk, dv
 
 
-def _fwd_bshf_pair_qkv(qkv, h, causal, block_q, block_k, interpret=False):
+def _fwd_bshf_pair_qkv(qkv, h, causal, block_q, block_k, interpret=False,
+                       scale=None):
     """Fused-QKV head-pair forward: qkv is ONE interleaved [b, s, 3f]
     array, laid out per pair-group hp as 384 lanes of
     [q_pair(128) | k_pair(128) | v_pair(128)]. The kernel is the ordinary
@@ -870,15 +874,16 @@ def _fwd_bshf_pair_qkv(qkv, h, causal, block_q, block_k, interpret=False):
             lambda bi, hp, i: (bi, i, 3 * hp),
             lambda bi, hp, i: (bi, 0, 3 * hp + 1),
             lambda bi, hp, i: (bi, 0, 3 * hp + 2),
-        ),
+        ), scale,
     )
 
 
-def _bwd_bshf_pair_fused_qkv(qkv, o, lse, do, h, causal, interpret=False):
+def _bwd_bshf_pair_fused_qkv(qkv, o, lse, do, h, causal, interpret=False,
+                             scale=None):
     b, s, f3 = qkv.shape
     f = f3 // 3
     d = f // h
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     bb = _batch_block(
         b, s, s, s, 128, qkv.dtype.itemsize, fused_bwd=True, bwd_blocks=8,
     )
@@ -905,25 +910,25 @@ def _bwd_bshf_pair_fused_qkv(qkv, o, lse, do, h, causal, interpret=False):
     )(qkv, qkv, qkv, o, do, lse)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5))
-def _flash_bshf_qkv(qkv, h, causal, block_q, block_k, interpret):
-    o, _ = _fwd_bshf_pair_qkv(qkv, h, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4, 5, 6))
+def _flash_bshf_qkv(qkv, h, causal, block_q, block_k, interpret, scale=None):
+    o, _ = _fwd_bshf_pair_qkv(qkv, h, causal, block_q, block_k, interpret, scale)
     return o
 
 
-def _flash_bshf_qkv_fwd(qkv, h, causal, block_q, block_k, interpret):
-    o, lse = _fwd_bshf_pair_qkv(qkv, h, causal, block_q, block_k, interpret)
+def _flash_bshf_qkv_fwd(qkv, h, causal, block_q, block_k, interpret, scale=None):
+    o, lse = _fwd_bshf_pair_qkv(qkv, h, causal, block_q, block_k, interpret, scale)
     return o, (qkv, o, lse)
 
 
-def _flash_bshf_qkv_bwd(h, causal, block_q, block_k, interpret, res, do):
+def _flash_bshf_qkv_bwd(h, causal, block_q, block_k, interpret, scale, res, do):
     qkv, o, lse = res
     s = qkv.shape[1]
     # pair mode ships the fused single-tile backward only; the entry gate
     # restricts shapes to s <= block
     assert s <= block_q and s <= block_k, (s, block_q, block_k)
     return (
-        _bwd_bshf_pair_fused_qkv(qkv, o, lse, do, h, causal, interpret),
+        _bwd_bshf_pair_fused_qkv(qkv, o, lse, do, h, causal, interpret, scale),
     )
 
 
@@ -932,6 +937,7 @@ _flash_bshf_qkv.defvjp(_flash_bshf_qkv_fwd, _flash_bshf_qkv_bwd)
 
 def flash_attention_bshf_qkv(
     qkv, num_heads: int, *, causal: bool = False, interpret: bool = False,
+    scale: float = None,
 ):
     """Head-pair (d=64) flash attention on ONE interleaved [b, s, 3*f]
     projection array (per pair-group: [q_pair | k_pair | v_pair], 384
@@ -947,16 +953,19 @@ def flash_attention_bshf_qkv(
     assert 2 * d == 128 and num_heads % 2 == 0 and s <= bq and s <= bk, (
         d, num_heads, s, bq, bk,
     )
-    return _flash_bshf_qkv(qkv, num_heads, causal, bq, bk, interpret)
+    return _flash_bshf_qkv(qkv, num_heads, causal, bq, bk, interpret, scale)
 
 
-def _fwd_bshf(q, k, v, h, causal, block_q, block_k, interpret=False):
+def _fwd_bshf(q, k, v, h, causal, block_q, block_k, interpret=False,
+              scale=None):
     b, s, f = q.shape
     d = f // h
     if d % 128 != 0:
-        return _fwd_bshf_pair(q, k, v, h, causal, block_q, block_k, interpret)
+        return _fwd_bshf_pair(
+            q, k, v, h, causal, block_q, block_k, interpret, scale
+        )
     nq = s // block_q
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     bb = _batch_block(b, block_q, block_k, s, d, q.dtype.itemsize)
     kernel = functools.partial(
         _fwd_kernel_b, causal=causal, block_k=block_k, scale=scale,
@@ -1201,11 +1210,12 @@ def _delta_bshf(do, o, b, s, h, d, interpret=False, block=None,
     )(do, o)
 
 
-def _bwd_bshf_fused(q, k, v, o, lse, do, h, causal, interpret=False):
+def _bwd_bshf_fused(q, k, v, o, lse, do, h, causal, interpret=False,
+                    scale=None):
     """Fused single-block backward for the bshf layout (s == block)."""
     b, s, f = q.shape
     d = f // h
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     delta4 = _delta_bshf(do, o, b, s, h, d, interpret)
     bb = _batch_block(b, s, s, s, d, q.dtype.itemsize, fused_bwd=True)
     dq, dk, dv = pl.pallas_call(
@@ -1298,13 +1308,13 @@ def _bwd_onepass_kernel(
 
 
 def _bwd_bshf_onepass(q, k, v, o, lse, do, h, causal, block_q, block_k,
-                      interpret=False):
+                      interpret=False, scale=None):
     assert not causal
     b, s, f = q.shape
     d = f // h
     nq = s // block_q
     nk = s // block_k
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     delta4 = _delta_bshf(do, o, b, s, h, d, interpret)
     dq, dkp, dvp = pl.pallas_call(
         functools.partial(_bwd_onepass_kernel, scale=scale, nk=nk),
@@ -1348,12 +1358,13 @@ def _bwd_bshf_onepass(q, k, v, o, lse, do, h, causal, block_q, block_k,
     return dq, dk, dv
 
 
-def _bwd_bshf(q, k, v, o, lse, do, h, causal, block_q, block_k, interpret=False):
+def _bwd_bshf(q, k, v, o, lse, do, h, causal, block_q, block_k,
+              interpret=False, scale=None):
     b, s, f = q.shape
     d = f // h
     nq = s // block_q
     nk = s // block_k
-    scale = 1.0 / (d**0.5)
+    scale = 1.0 / (d**0.5) if scale is None else scale
     delta4 = _delta_bshf(do, o, b, s, h, d, interpret)
 
     dq = pl.pallas_call(
@@ -1410,20 +1421,20 @@ def _bwd_bshf(q, k, v, o, lse, do, h, causal, block_q, block_k, interpret=False)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_bshf(q, k, v, h, causal, block_q, block_k, interpret,
-                explicit=False):
-    o, _ = _fwd_bshf(q, k, v, h, causal, block_q, block_k, interpret)
+                explicit=False, scale=None):
+    o, _ = _fwd_bshf(q, k, v, h, causal, block_q, block_k, interpret, scale)
     return o
 
 
 def _flash_bshf_fwd(q, k, v, h, causal, block_q, block_k, interpret,
-                    explicit=False):
-    o, lse = _fwd_bshf(q, k, v, h, causal, block_q, block_k, interpret)
+                    explicit=False, scale=None):
+    o, lse = _fwd_bshf(q, k, v, h, causal, block_q, block_k, interpret, scale)
     return o, (q, k, v, o, lse)
 
 
-def _flash_bshf_bwd(h, causal, block_q, block_k, interpret, explicit,
+def _flash_bshf_bwd(h, causal, block_q, block_k, interpret, explicit, scale,
                     res, do):
     q, k, v, o, lse = res
     s = q.shape[1]
@@ -1432,11 +1443,13 @@ def _flash_bshf_bwd(h, causal, block_q, block_k, interpret, explicit,
         # pair mode only ships the fused single-tile backward; the entry
         # gate restricts pair shapes to s <= block
         assert s <= block_q and s <= block_k, (s, block_q, block_k)
-        return _bwd_bshf_pair_fused(q, k, v, o, lse, do, h, causal, interpret)
+        return _bwd_bshf_pair_fused(
+            q, k, v, o, lse, do, h, causal, interpret, scale
+        )
     if s <= block_q and s <= block_k:
         # whole sequence in one tile: one fused kernel instead of two
         # (single scores/exp computation, q/k/v/do read once)
-        return _bwd_bshf_fused(q, k, v, o, lse, do, h, causal, interpret)
+        return _bwd_bshf_fused(q, k, v, o, lse, do, h, causal, interpret, scale)
     # backward tiles get their own block budget (unless the caller passed
     # explicit blocks): the dq/dkv kernels hold more live tiles than the
     # forward, so the forward-optimal blocks (e.g. K = full seq at 2048,
@@ -1448,9 +1461,11 @@ def _flash_bshf_bwd(h, causal, block_q, block_k, interpret, explicit,
         # pays); its dk/dv partials cost nq extra gradient-sized HBM
         # buffers, so large nq keeps the constant-memory kernel pair
         return _bwd_bshf_onepass(
-            q, k, v, o, lse, do, h, causal, bwd_bq, bwd_bk, interpret
+            q, k, v, o, lse, do, h, causal, bwd_bq, bwd_bk, interpret, scale
         )
-    return _bwd_bshf(q, k, v, o, lse, do, h, causal, bwd_bq, bwd_bk, interpret)
+    return _bwd_bshf(
+        q, k, v, o, lse, do, h, causal, bwd_bq, bwd_bk, interpret, scale
+    )
 
 
 _flash_bshf.defvjp(_flash_bshf_fwd, _flash_bshf_bwd)
@@ -1532,15 +1547,16 @@ def flash_attention_bshf(
     Under a causal mask over more than one tile, heads of whole 128-lane
     tiles run the causal tile schedule as `causal_plan` lays it out, and
     there alone the key may be wider than the value (q, k
-    [b, s, num_heads * dk], v [b, s, num_heads * dv]), `scale` may differ
-    from dk ** -0.5 (a key padded with zero columns names its TRUE width's),
-    and k and v hold the node's own `num_kv_heads` heads, read in place by
+    [b, s, num_heads * dk], v [b, s, num_heads * dv]) and k and v hold the node's own `num_kv_heads` heads, read in place by
     the query heads that share them (`CausalPlan.group`: where the key is as
     wide as the value; a caller whose pairing is no `h // group` writes them
     out a query head and names no `num_kv_heads`).
     `window` (keys a query sees, itself included) is honoured there alone,
     as a band in the tile schedule; anywhere else it is an error, not a
-    silent full attention. -> [b, s, num_heads * dv]."""
+    silent full attention. `scale` is the scores' multiplier where it is
+    not dk ** -0.5 (a key padded with zero columns names its TRUE width's; a
+    node states its own, `MultiHeadAttentionAttrs.softmax_scale`): every
+    body takes it. -> [b, s, num_heads * dv]."""
     b, s, f = q.shape
     kv = num_heads if num_kv_heads is None else num_kv_heads
     assert f % num_heads == 0 and v.shape[-1] % kv == 0
@@ -1564,7 +1580,7 @@ def flash_attention_bshf(
             f"of whole 128-lane tiles); these operands (causal={causal}, "
             f"d={d} | {dv}) take another body, which has no band"
         )
-    assert q.shape == k.shape == v.shape and kv == num_heads and scale is None, (
+    assert q.shape == k.shape == v.shape and kv == num_heads, (
         f"flash_attention_bshf is self-attention-shaped: {q.shape} vs "
         f"{k.shape} / {v.shape} (the K/V BlockSpecs use q's seq length)"
     )
@@ -1574,7 +1590,9 @@ def flash_attention_bshf(
         assert 2 * d == 128 and num_heads % 2 == 0 and s <= bq and s <= bk, (
             d, num_heads, s, bq, bk,
         )
-    return _flash_bshf(q, k, v, num_heads, causal, bq, bk, interpret, explicit)
+    return _flash_bshf(
+        q, k, v, num_heads, causal, bq, bk, interpret, explicit, scale
+    )
 
 
 def bshf_pair_supported(num_heads: int, d: int, s: int) -> bool:
@@ -1714,7 +1732,7 @@ def per_batch_shard(entry, *rows, **kwargs):
 
 def sharded_flash_attention(
     q, k, v, mesh, batch_axes, head_axes, *,
-    causal: bool = False, interpret: bool = False,
+    causal: bool = False, interpret: bool = False, scale: float = None,
 ):
     """Flash attention composed with SPMD sharding: each device runs the
     Pallas kernel on its local [b/dp, h/tp, s, d] block. Attention is
@@ -1726,7 +1744,9 @@ def sharded_flash_attention(
     from flexflow_tpu.utils.shard_map_compat import shard_map_compat
 
     spec = P(batch_axes, head_axes, None, None)
-    f = functools.partial(flash_attention, causal=causal, interpret=interpret)
+    f = functools.partial(
+        flash_attention, causal=causal, interpret=interpret, scale=scale
+    )
     # replication (vma) checking can't see through a pallas_call's out_shape;
     # the body is elementwise-parallel over b/h so the specs are exact
     wrapped = shard_map_compat(

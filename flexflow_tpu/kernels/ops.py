@@ -660,8 +660,9 @@ def _note_route(
 ) -> None:
     """Tell the program's counter which core the attention node being
     lowered took (`observability/trace.attention_routes`), of a
-    differential node that it is one, of any node its window, and the
-    `group` of query heads that read a key/value head where it lies."""
+    differential node that it is one, of any node its window, the `group`
+    of query heads that read a key/value head where it lies, and the scale
+    of the scores where the node states one."""
     from flexflow_tpu.observability import trace
 
     if attrs is not None:
@@ -671,7 +672,22 @@ def _note_route(
             route += f" window={attrs.window}"
     if group > 1:
         route += f" group={group}"
+    if attrs is not None and attrs.softmax_scale is not None:
+        route += f" scale={attrs.softmax_scale:g}"
     trace.note_attention_route(route)
+
+
+def _note_scan_blocks(attrs, x) -> None:
+    """Tell the program's counter how many column blocks the scan of the
+    state-space node being lowered goes as, 0 on the "xla" route
+    (`observability/trace.scan_column_blocks`)."""
+    from flexflow_tpu.kernels.ssm import scan_column_blocks
+    from flexflow_tpu.observability import trace
+
+    trace.note_scan_column_blocks(scan_column_blocks(
+        x.shape[0], attrs.num_heads, attrs.head_dim, attrs.num_groups,
+        attrs.state_size, attrs.chunk_size,
+    ))
 
 
 def _note_rotary(attrs: MultiHeadAttentionAttrs) -> None:
@@ -1104,7 +1120,8 @@ def _mha_forward(
         # by itself and the weight gradients stay ordinary HLO
         qkv, wo2 = mha_project_qkv_bshf_fused(attrs, q, weight, input_bias)
         ctx = per_batch_shard(
-            flash_attention_bshf_qkv, qkv, num_heads=H, causal=causal
+            flash_attention_bshf_qkv, qkv, num_heads=H, causal=causal,
+            scale=attrs.softmax_scale,
         )
         return ctx @ wo2
     if route == "fused_row":
@@ -1129,8 +1146,9 @@ def _mha_forward(
                 qp, kp, vp = (_padded_heads(x, kd) for x in (qp, kp, vp))
             ctx = per_batch_shard(
                 flash_attention_bshf, qp, kp, vp, num_heads=H, causal=causal,
-                num_kv_heads=H // group, scale=kd ** -0.5 if pads else None,
-                window=attrs.window,
+                num_kv_heads=H // group, window=attrs.window,
+                # padded heads name the TRUE width's scale, a node its own
+                scale=attrs.scale if pads else attrs.softmax_scale,
             )
             if pads:
                 ctx = _own_columns(ctx, kd)
@@ -1160,19 +1178,23 @@ def _mha_forward(
     if route == "rows":
         mesh_ctx = current_flash_mesh()
         if mesh_ctx is None:
-            ctx = flash_attention(qp, kp, vp, causal=causal)
+            ctx = flash_attention(
+                qp, kp, vp, causal=causal, scale=attrs.softmax_scale
+            )
         else:
             # SPMD trace: a bare pallas_call has no partitioning rule, so
             # flash must go through shard_map
             mesh, batch_axes, head_axes, interpret = mesh_ctx
             ctx = sharded_flash_attention(
                 qp, kp, vp, mesh, batch_axes, head_axes,
-                causal=causal, interpret=interpret,
+                causal=causal, interpret=interpret, scale=attrs.softmax_scale,
             )
         return jnp.einsum("bhsv,veh->bse", _gated_context(ctx, gate), wo)
-    scores = jnp.einsum("bhsk,bhtk->bhst", qp, kp) / jnp.sqrt(
-        jnp.asarray(kd, qp.dtype)
-    )
+    scores = jnp.einsum("bhsk,bhtk->bhst", qp, kp)
+    if attrs.softmax_scale is None:
+        scores = scores / jnp.sqrt(jnp.asarray(kd, qp.dtype))
+    else:
+        scores = scores * jnp.asarray(attrs.softmax_scale, qp.dtype)
     if causal:
         s, t = scores.shape[-2], scores.shape[-1]
         mask = jnp.arange(s)[:, None] >= jnp.arange(t)[None, :]
@@ -1439,6 +1461,7 @@ def forward(
     if isinstance(attrs, StateSpaceAttrs):
         from flexflow_tpu.kernels.ssm import state_space_forward
 
+        _note_scan_blocks(attrs, inputs[0])
         return [state_space_forward(attrs, inputs[0], weights)]
 
     from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs

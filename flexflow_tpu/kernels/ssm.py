@@ -32,7 +32,10 @@ the order of the floating-point sums differs between them:
 - **The Pallas kernels** (`ssd_fwd_chunk`, `ssd_states_chunk`,
   `ssd_bwd_chunk`; "ssd" / "ssd_sharded"): on a TPU, at chunk and state
   sizes that are multiples of 128 lanes and heads of 64 or 128 whose group
-  fills whole 128-lane tiles. One program is one (batch row, group, chunk);
+  fills whole 128-lane tiles. One program is one (batch row, group, chunk),
+  or where a group is wider than `_MAX_GROUP_COLUMNS` one of its column
+  blocks of whole heads, each reading the group's one B and C
+  (`_column_blocks`);
   C.B, the running-sum differences, the masked exponentials, the products
   and the state update of a chunk live in VMEM and only x, dt, B, C and y
   (and their gradients) cross HBM; the [r * P, N] states ride a VMEM scratch
@@ -858,14 +861,39 @@ _NT = (((1,), (1,)), ((), ()))  # [m, k] x [n, k]
 _TN = (((0,), (0,)), ((), ()))  # [k, m] x [k, n]
 
 # r * P columns a program holds at most: the [r * P, N] state and a few
-# [Q, r * P] float32 tiles have to fit the scoped VMEM. 1,024 is the widest
-# group of the published widths (16 heads of 64; the other has 8) and what
-# was compiled for the described v5e, forward and backward, at Q = N = 128:
-# the backward's five scratch tiles are 2 MB there and its double-buffered
-# blocks 2.5 MB more, of 16 MB. A wider group would go as column blocks that
-# read the same B and C; nothing published asks for one (PR 39).
+# [Q, r * P] float32 tiles have to fit the scoped VMEM. 1,024 is what was
+# compiled for the described v5e, forward and backward, at Q = N = 128 (the
+# backward's five scratch tiles are 2 MB there and its double-buffered
+# blocks 2.5 MB more, of 16 MB) and at Q = 256, N = 128 (twice the tiles,
+# four times the [Q, Q] masks: PR 68, compiled and run). A group of at most
+# this many columns is ONE program a chunk, as it always was (16 heads of 64
+# in one group, 8 heads of 64 in each of 8). A wider group (64 heads of 64 in
+# ONE group, 4,096 columns: PR 68) goes as COLUMN BLOCKS of whole heads
+# (`_column_blocks`): every block is a program of its own over the chunks,
+# reads the group's one B and C [Q, N], its own heads' dt and La, and carries
+# its own [columns, N] state from chunk to chunk. The recurrence couples no
+# two heads, so the forward needs nothing else; in the backward every block
+# holds a PART of dB and dC (a sum over the group's heads), written as
+# float32 partials and added up once after the kernel. Blocks of 1,024 and
+# not fewer columns: at 4,096 columns and Q = 256 the node's forward and
+# backward took 6.32 ms in blocks of 1,024 and 6.40 in blocks of 512 (the
+# scan alone 1.88 and 2.02: C.B and the reads of B and C once a block; my
+# chip run, PR 68).
 _MAX_GROUP_COLUMNS = 1024
 _LANES = 128
+
+
+def _column_blocks(columns: int) -> int:
+    """How many programs a group of `columns` = r * P columns (whole
+    128-lane tiles) goes as: 1 up to `_MAX_GROUP_COLUMNS` (the group whole,
+    today's program); beyond, the fewest equal blocks of whole 128-lane
+    tiles (so of whole heads of 64 or 128) of at most that many columns."""
+    if columns <= _MAX_GROUP_COLUMNS:
+        return 1
+    width = next(
+        w for w in range(_MAX_GROUP_COLUMNS, 0, -_LANES) if columns % w == 0
+    )
+    return columns // width
 
 
 def _mm(a, b, dims):
@@ -1102,38 +1130,49 @@ def _from_group(t):
 
 
 class _Blocks:
-    """The BlockSpecs of the kernels' operands over the grid (batch, group,
-    chunk); `reverse` visits the chunks last to first."""
+    """The BlockSpecs of the kernels' operands over the grid (batch, program,
+    chunk); `reverse` visits the chunks last to first. A program is a group,
+    or where a group is wider than `_MAX_GROUP_COLUMNS` one of its `blocks`
+    column blocks (`_column_blocks`): x, dt, La, D and the states are its
+    own columns' or heads', B and C its group's."""
 
     def __init__(self, x, b_mat, dt, groups: int, chunk: int, reverse: bool):
         b, s, hp = x.shape
-        self.grid = (b, groups, s // chunk)
-        self.r = dt.shape[2] // groups
-        self.rp, self.n = hp // groups, b_mat.shape[2] // groups
+        self.blocks = blocks = _column_blocks(hp // groups)
+        self.programs = programs = groups * blocks
+        self.grid = (b, programs, s // chunk)
+        self.r = dt.shape[2] // programs
+        self.rp, self.n = hp // programs, b_mat.shape[2] // groups
         self.p = self.rp // self.r
         last = s // chunk - 1
         at = (lambda ci: last - ci) if reverse else (lambda ci: ci)
         rp, n, r = self.rp, self.n, self.r
-        # [b, s, groups * width]: the chunk's rows, the group's columns
+        # [b, s, programs * width]: the chunk's rows, the program's columns
         self.x = pl.BlockSpec(
             (None, chunk, rp), lambda bi, gi, ci: (bi, at(ci), gi)
         )
-        self.bc = pl.BlockSpec(
+        # [b, s, groups * n] read, and [b, s, programs * n] written (dB and
+        # dC: a program's own part where a group goes as blocks)
+        self.bc_part = pl.BlockSpec(
             (None, chunk, n), lambda bi, gi, ci: (bi, at(ci), gi)
         )
-        # [b, groups, s, r] and [b, groups, r, s]
+        self.bc = self.bc_part if blocks == 1 else pl.BlockSpec(
+            (None, chunk, n), lambda bi, gi, ci: (bi, at(ci), gi // blocks)
+        )
+        # [b, programs, s, r] and [b, programs, r, s]
         self.col = pl.BlockSpec(
             (None, None, chunk, r), lambda bi, gi, ci: (bi, gi, at(ci), 0)
         )
         self.row = pl.BlockSpec(
             (None, None, r, chunk), lambda bi, gi, ci: (bi, gi, 0, at(ci))
         )
-        # [1, groups * rp], and [b, 1, groups * rp] resident over the chunks
+        # [1, programs * rp], and [b, 1, programs * rp] resident over the
+        # chunks
         self.skip = pl.BlockSpec((1, rp), lambda bi, gi, ci: (0, gi))
         self.sums = pl.BlockSpec(
             (None, 1, rp), lambda bi, gi, ci: (bi, 0, gi)
         )
-        # [b, groups, chunks, rp, n]
+        # [b, programs, chunks, rp, n]
         self.state = pl.BlockSpec(
             (None, None, None, rp, n),
             lambda bi, gi, ci: (bi, gi, at(ci), 0, 0),
@@ -1155,13 +1194,16 @@ _SEQUENTIAL_CHUNKS = pltpu.CompilerParams(
 def _ssd_forward(x, dt, la, b_mat, c_mat, d_row, groups, chunk, interpret,
                  states_only=False):
     """y [b, s, h * P] in x's dtype, or with `states_only` the float32 state
-    every chunk starts from, [b, groups, chunks, r * P, N]."""
+    every chunk starts from, [b, programs, chunks, r * P, N] (a program a
+    group, or a column block of one: `_Blocks`)."""
     f32 = jnp.float32
     (b, s, _), q = x.shape, chunk
     at = _Blocks(x, b_mat, dt, groups, chunk, reverse=False)
     scratch = [pltpu.VMEM((at.rp, at.n), f32), pltpu.VMEM((q, at.rp), x.dtype)]
     if states_only:
-        out_shape = jax.ShapeDtypeStruct((b, groups, s // q, at.rp, at.n), f32)
+        out_shape = jax.ShapeDtypeStruct(
+            (b, at.programs, s // q, at.rp, at.n), f32
+        )
         out_spec = at.state
     else:
         out_shape, out_spec = jax.ShapeDtypeStruct(x.shape, x.dtype), at.x
@@ -1178,7 +1220,7 @@ def _ssd_forward(x, dt, la, b_mat, c_mat, d_row, groups, chunk, interpret,
         compiler_params=_SEQUENTIAL_CHUNKS,
         interpret=interpret,
         name="ssd_states_chunk" if states_only else "ssd_fwd_chunk",
-    )(x, b_mat, c_mat, *_per_head_operands(dt, la, groups), d_row)
+    )(x, b_mat, c_mat, *_per_head_operands(dt, la, at.programs), d_row)
 
 
 @functools.partial(jax.jit, static_argnums=(8, 9, 10))
@@ -1188,18 +1230,25 @@ def _ssd_backward(x, dy, dt, la, b_mat, c_mat, d_row, h_in, groups, chunk,
     f32 = jnp.float32
     (b, s, hp), q = x.shape, chunk
     at = _Blocks(x, b_mat, dt, groups, chunk, reverse=True)
-    dtc, lac, lar = _per_head_operands(dt, la, groups)
+    dtc, lac, lar = _per_head_operands(dt, la, at.programs)
     small = jax.ShapeDtypeStruct(lac.shape, f32)
+    # dB and dC sum over a group's heads: where the group goes as column
+    # blocks every block writes its own part, in float32, and the parts are
+    # added once below (blocks * 2 * [s, N] float32 written and read again:
+    # 16 MB a node at 4 blocks of 4,096 positions, beside the 100 MB of x,
+    # dy and dx)
+    part = (b, s, at.programs * at.n)
     dx, db, dc, ddtc, dlac, dlar, dd = pl.pallas_call(
         functools.partial(_ssd_bwd_kernel, heads=at.r, p=at.p),
         grid=at.grid,
         in_specs=[at.x, at.x, at.bc, at.bc, at.col, at.col, at.row, at.skip,
                   at.state],
-        out_specs=[at.x, at.bc, at.bc, at.col, at.col, at.row, at.sums],
+        out_specs=[at.x, at.bc_part, at.bc_part, at.col, at.col, at.row,
+                   at.sums],
         out_shape=[
             jax.ShapeDtypeStruct(x.shape, x.dtype),
-            jax.ShapeDtypeStruct(b_mat.shape, b_mat.dtype),
-            jax.ShapeDtypeStruct(c_mat.shape, c_mat.dtype),
+            jax.ShapeDtypeStruct(part, b_mat.dtype if at.blocks == 1 else f32),
+            jax.ShapeDtypeStruct(part, c_mat.dtype if at.blocks == 1 else f32),
             small, small,
             jax.ShapeDtypeStruct(lar.shape, f32),
             jax.ShapeDtypeStruct((b, 1, hp), f32),
@@ -1216,6 +1265,12 @@ def _ssd_backward(x, dy, dt, la, b_mat, c_mat, d_row, h_in, groups, chunk,
         name="ssd_bwd_chunk",
     )(x, dy, b_mat, c_mat, dtc, lac, lar, d_row, h_in)
     dla = _from_group(dlac) + _from_group(jnp.swapaxes(dlar, 2, 3))
+    if at.blocks > 1:
+        db, dc = (
+            jnp.sum(t.reshape(b, s, groups, at.blocks, at.n), axis=3)
+            .reshape(b, s, groups * at.n).astype(like.dtype)
+            for t, like in ((db, b_mat), (dc, c_mat))
+        )
     return dx, _from_group(ddtc), dla, db, dc, jnp.sum(dd, axis=0)
 
 
@@ -1244,27 +1299,40 @@ def _ssd_scan_bwd(groups, chunk, interpret, operands, dy):
 _ssd_scan.defvjp(_ssd_scan_fwd, _ssd_scan_bwd)
 
 
+def scan_column_blocks(batch, heads, head_dim, groups, state, chunk) -> int:
+    """The column blocks a group's scan goes as on the Pallas kernels (1: the
+    group whole), 0 where `scan_route` names "xla": what the program's
+    counter keeps of a state-space node
+    (`observability/trace.scan_column_blocks`)."""
+    if scan_route(batch, heads, head_dim, groups, state, chunk) == "xla":
+        return 0
+    return _column_blocks(heads // groups * head_dim)
+
+
 def scan_route(batch, heads, head_dim, groups, state, chunk) -> str:
     """Which form `selective_scan` takes, from what the trace can observe:
 
     - "ssd": the Pallas kernels, where the backend is a TPU (or the CPU with
       interpret mode opted in, `interpret_default`), the tiles fill whole
       vregs (chunk and state multiples of 128 lanes, a group's r * P columns
-      whole 128-lane tiles of heads of 64 or 128) and fit VMEM
-      (`_MAX_GROUP_COLUMNS`), and the trace admits a bare Pallas call (not
-      under `no_flash()`);
+      whole 128-lane tiles of heads of 64 or 128) and fit VMEM (a group of
+      at most `_MAX_GROUP_COLUMNS` columns whole, a wider one as the column
+      blocks `_column_blocks` cuts it into), and the trace admits a bare
+      Pallas call (not under `no_flash()`);
     - "ssd_sharded": the same under a declared `flash_mesh` with whole heads
       whose batch axes divide the batch: the kernels mapped over the batch
-      shards, as the attention kernels are;
+      shards, as the attention kernels are (a group that goes as column
+      blocks has not been mapped over a mesh yet and takes "xla" there);
     - "xla": everything else, `_scan_core`."""
     from flexflow_tpu.kernels import flash_attention as flash
 
     columns = heads // groups * head_dim
     if (
         chunk % 128 or state % 128 or columns % 128
-        or head_dim not in (64, 128) or columns > _MAX_GROUP_COLUMNS
+        or head_dim not in (64, 128)
     ):
         return "xla"
+    blocks = _column_blocks(columns)
     ctx = flash.current_flash_mesh()
     if ctx is None:
         bare_call_ok = not getattr(flash._tls, "disabled", False)
@@ -1272,6 +1340,8 @@ def scan_route(batch, heads, head_dim, groups, state, chunk) -> str:
         return "ssd" if bare_call_ok and on_chip else "xla"
     mesh, batch_axes, head_axes, interpret = ctx
     if head_axes is not None or batch % flash._axes_size(mesh, batch_axes):
+        return "xla"
+    if blocks > 1:
         return "xla"
     return "ssd_sharded" if flash._backend_ok(interpret) else "xla"
 

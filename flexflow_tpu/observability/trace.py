@@ -727,9 +727,33 @@ def attention_routes() -> Dict[str, str]:
     beside what it measures, so that a change of route says so itself. A
     differential node's route is followed by ` differential` and any node's,
     where it has one, by ` window=<keys>` (`fused_row differential
-    window=512`, `fused_row window=1024`); what its band skips on the
-    kernels is `window_tiles()`."""
+    window=512`, `fused_row window=1024`), then ` group=<query heads a
+    key/value head>` where they are read in place and ` scale=<value>` where
+    the node states its scores' scale (`fused_row group=4 scale=0.015625`);
+    what its band skips on the kernels is `window_tiles()`."""
     return dict(_ATTENTION_ROUTES)
+
+
+_SCAN_COLUMN_BLOCKS: Dict[str, int] = {}
+
+
+def note_scan_column_blocks(blocks: int) -> None:
+    """The column blocks the state-space node being lowered runs a group's
+    scan as (`kernels/ssm.scan_column_blocks`); dropped where no node's
+    scope is open."""
+    scope = getattr(_lowering, "scope", None)
+    if scope is not None:
+        _SCAN_COLUMN_BLOCKS[scope] = blocks
+
+
+def scan_column_blocks() -> Dict[str, int]:
+    """`{ff.ssm.<name>: blocks}` of every state-space node this process has
+    lowered, as it was lowered last: the programs a group's scan goes as on
+    the Pallas kernels (1: the group whole; 4: a 4,096-column group in
+    blocks of 1,024), 0 where the node took `_scan_core`
+    ("xla"), so that a run that fell back says so itself (the benchmark's
+    `granite_scan_column_blocks` reads it)."""
+    return dict(_SCAN_COLUMN_BLOCKS)
 
 
 _ROTARIES: Dict[str, str] = {}

@@ -205,8 +205,31 @@ class MultiHeadAttentionAttrs:
     # None: the rotary as it always was. Plain nodes only (a latent node's
     # rotary on its shared slice takes none yet).
     rope_scaling: Optional[YarnScaling] = None
+    # softmax_scale: what the scores q k^T are multiplied by before the
+    # softmax. None is kdim ** -0.5, the op as it always was; a model under
+    # muP multipliers states its own (`attention_multiplier` 0.015625 = 1/64
+    # on heads of 64, not 1/8). A field of the node and no scaling of q in
+    # the graph: every core `kernels/ops.mha_core_route` can name for a
+    # plain node takes it where it took d ** -0.5 (the kernels fold it into
+    # an operand, the dense form multiplies by it). Plain nodes only, causal
+    # or not, grouped or equal heads: a latent or a differential node is
+    # refused here until one asks (their cores name their own scales), and a
+    # sequence shard by `RingAttentionAttrs`' shape rule.
+    softmax_scale: Optional[float] = None
 
     def __post_init__(self):
+        assert self.softmax_scale is None or self.softmax_scale > 0, (
+            f"softmax_scale {self.softmax_scale} multiplies the scores: a "
+            "positive number, or None for kdim ** -0.5"
+        )
+        assert self.softmax_scale is None or not (
+            self.latent or self.differential
+        ), (
+            "a stated softmax_scale is carried by plain attention nodes: the "
+            "latent core names the true key width's scale beside its padded "
+            "key and the differential core scales two maps a head, and "
+            "neither carries another through yet"
+        )
         assert self.rotary_dim is None or (
             self.rope_theta is not None and self.rotary_dim % 2 == 0
             and 0 < self.rotary_dim <= self.q_proj_size
@@ -297,6 +320,14 @@ class MultiHeadAttentionAttrs:
             "rope_scaling says how rope_theta's frequencies are scaled on a "
             "plain node: it needs one, and latent attention takes none yet"
         )
+
+    @property
+    def scale(self) -> float:
+        """What the scores are multiplied by: the stated `softmax_scale`,
+        or kdim ** -0.5."""
+        if self.softmax_scale is not None:
+            return float(self.softmax_scale)
+        return self.q_proj_size ** -0.5
 
     @property
     def grouped_query(self) -> bool:

@@ -78,6 +78,11 @@ class RingAttentionAttrs(MultiHeadAttentionAttrs):
             f"first queries see the {self.window} - 1 keys before it, a halo "
             "the ring and all-to-all schedules do not carry"
         )
+        assert seq_degree == 1 or self.softmax_scale is None, (
+            "attention with a stated softmax_scale cannot be sequence-"
+            "parallel yet: the ring and all-to-all schedules scale their "
+            "scores by d ** -0.5 themselves"
+        )
         unpar = self.output_shape(
             get_reduced_shape(q), get_reduced_shape(k), get_reduced_shape(v)
         )

@@ -16,7 +16,8 @@ inner = heads * head_dim. The recurrence is evaluated in chunks of
 `kernels/ssm.scan_route` picks from the shapes, the backend and the trace:
 Pallas kernels that keep a chunk in VMEM (a TPU, `chunk_size` and
 `state_size` multiples of 128, heads of 64 or 128 whose group fills whole
-128-lane tiles), or XLA matmuls with a scan over the chunks' states
+128-lane tiles; a group of more than 1,024 columns as column blocks of
+whole heads that read the group's one B and C), or XLA matmuls with a scan over the chunks' states
 (everything else). Both keep only the scan's inputs for the backward and
 recompute the chunk-boundary states there; the chunking and the choice of
 form change the order of the floating-point sums and nothing else.

@@ -391,6 +391,7 @@ class ComputationGraphBuilder:
         rope_interleaved: bool = False,
         window: Optional[int] = None,
         rope_scaling: Optional[YarnScaling] = None,
+        softmax_scale: Optional[float] = None,
     ) -> Tensor:
         """`causal`, `rope_theta` (rotary positions 0..s-1 on q and k) and
         `qk_norm_eps` (RMS norm of the projected q and k over all heads'
@@ -412,7 +413,9 @@ class ComputationGraphBuilder:
         window: a band in the causal tile kernels, a mask on XLA's
         attention, an error on a route with neither), and `rope_scaling` a
         `YarnScaling` beside `rope_theta`: the node's own frequencies and
-        amplitude, so that two layers of one graph turn differently."""
+        amplitude, so that two layers of one graph turn differently.
+        `softmax_scale` is what a plain node's scores are multiplied by where
+        that is not kdim ** -0.5 (a muP model's `attention_multiplier`)."""
         fields = (
             embed_dim, num_heads, kdim, vdim, dropout, bias, add_bias_kv,
             add_zero_attn, rope_theta, qk_norm_eps, num_kv_heads,
@@ -424,11 +427,13 @@ class ComputationGraphBuilder:
             from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
 
             attrs = RingAttentionAttrs(
-                *fields, window=window, rope_scaling=rope_scaling, causal=True
+                *fields, window=window, rope_scaling=rope_scaling,
+                softmax_scale=softmax_scale, causal=True,
             )
         else:
             attrs = MultiHeadAttentionAttrs(
-                *fields, window=window, rope_scaling=rope_scaling
+                *fields, window=window, rope_scaling=rope_scaling,
+                softmax_scale=softmax_scale,
             )
         (out,) = self.add_layer(attrs, [query, key, value], [initializer], name)
         return out

@@ -301,6 +301,10 @@ class ServingProgram:
             "the serving programs do not rotate by cache position or apply "
             "QK-norm yet (ROADMAP R5): training only"
         )
+        assert attrs.softmax_scale is None, (
+            "the serving programs scale their scores by sqrt(kdim) and carry "
+            "no stated softmax_scale yet: training only"
+        )
         q, k, v = data_vals
         input_bias = weight_vals[1] if attrs.bias else None
         qp, kp, vp, wo = mha_project_qkv(

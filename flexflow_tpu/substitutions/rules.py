@@ -228,8 +228,11 @@ def _seq_parallel_attention_rule(
             OperatorType.MULTIHEAD_ATTENTION,
             # RoPE under a sequence shard needs the shard's global
             # positions, a window its halo of keys (RingAttentionAttrs'
-            # shape rule, ROADMAP R7); a rope_scaling comes with a rope_theta
-            eq=dict(bias=False, rope_theta=None, window=None),
+            # shape rule, ROADMAP R7); a rope_scaling comes with a rope_theta;
+            # the schedules scale by d ** -0.5 themselves
+            eq=dict(
+                bias=False, rope_theta=None, window=None, softmax_scale=None
+            ),
             div=extra_div,
         ),
         [q, k, v, w],

@@ -252,10 +252,11 @@ def test_scan_kernels_map_over_the_batch_shards_of_a_declared_mesh(
 
 def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
     """The published widths take the kernels on a TPU, one row or many; toy
-    widths, a chunk of 8, a group wider than a program holds, a CPU without
-    the interpret opt-in and a trace under `no_flash()` take `_scan_core`;
-    a declared mesh maps the kernels over its batch shards if the heads are
-    whole and the batch divides."""
+    widths, a chunk of 8, a CPU without the interpret opt-in and a trace
+    under `no_flash()` take `_scan_core`; a group wider than a program holds
+    takes the kernels as column blocks (PR 68; `test_granite_hybrid.py`),
+    except under a declared mesh; a declared mesh maps the kernels over its
+    batch shards if the heads are whole and the batch divides."""
     from jax.sharding import Mesh
 
     from flexflow_tpu.kernels import flash_attention as fa
@@ -272,16 +273,18 @@ def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
     for other in (
         dict(heads=4, head_dim=8, groups=2, state=16, chunk=8),  # the toy
         dict(chunk=8), dict(chunk=192), dict(state=64), dict(head_dim=32),
-        dict(groups=2),  # 32 heads of 64 a group: 2048 columns a program
         dict(heads=8),  # one head a group: 64 columns
     ):
         assert scan_route(**dict(cell, **other)) == "xla", other
+    # 32 heads of 64 a group: 2,048 columns, two column blocks of 1,024
+    assert scan_route(**dict(cell, groups=2)) == "ssd"
     with fa.no_flash():
         assert scan_route(**cell) == "xla"
     mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
     with fa.flash_mesh(mesh, "data", None, False), fa.no_flash():
         assert scan_route(**dict(cell, batch=4)) == "ssd_sharded"
         assert scan_route(**dict(cell, batch=3)) == "xla"
+        assert scan_route(**dict(cell, batch=4, groups=2)) == "xla"
     with fa.flash_mesh(mesh, None, "data", False):
         assert scan_route(**dict(cell, batch=4)) == "xla"
 

@@ -345,7 +345,7 @@ def test_scan_route_gives_a_group_of_16_heads_the_kernels(monkeypatch):
     monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
     assert scan_route(1, 16, 64, 1, 128, 128) == "ssd"
     assert scan_route(1, 128, 64, 8, 128, 128) == "ssd"  # the uncut mixer
-    assert scan_route(1, 32, 64, 1, 128, 128) == "xla"  # 2,048 columns
+    assert scan_route(1, 32, 64, 1, 128, 128) == "ssd"  # two column blocks (PR 68)
 
 
 def test_a_group_of_16_heads_through_the_scan_kernels(monkeypatch):

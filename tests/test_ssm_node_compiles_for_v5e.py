@@ -128,6 +128,20 @@ the convolution's first column), the backward's that row and the cotangent
 in the pieces its producers left, and the two calls' results no more than y, dx and the taps' partial sums
 (ds is no buffer).
 
+And what the `granite-4.0-h-micro` cell added (PR 68), at its published
+sizes and 4,096 positions (`check_granite`): ONE state-space node of 64
+heads of 64 in ONE group (4,096 columns, more than a program holds) at
+chunks of 256, forward and backward, whose scan must come out as the same
+three Pallas kernels, each once, in COLUMN BLOCKS (`kernels/ssm._column_blocks`:
+4 programs of 1,024 columns a chunk, seen in the states `[1, 4, 16, 1024, 128]`
+and in dB's and dC's float32 partials `[1, 4096, 4 * 128]`, which one fusion
+each adds up), compiled inside the default 16 MB of scoped VMEM (the kernels
+state no limit of their own), with no `[chunks, heads, 256, 256]` decay mask
+between ENTRY instructions (the "xla" route's largest tensor) and the
+convolution over 4,352 channels as its two kernels. `python
+tests/test_ssm_node_compiles_for_v5e.py granite_step` compiles that cell's
+WHOLE step, the number its recomputation choice rests on.
+
 A compile that passes is not a chip run and says nothing of speed; the
 chip's numbers are in PERF.md. In the pattern of
 `test_pair_kernels_compile_for_v5e.py`: every compile in ONE child process
@@ -202,7 +216,7 @@ def compiled_node(name):
     fa._backend_ok = lambda allow_interpret=False: True
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
-    hidden, sizes = SHAPES[name]
+    hidden, sizes = dict(SHAPES, granite=GRANITE_SHAPE)[name]
     attrs = StateSpaceAttrs(*sizes, 1e-5)
 
     def on_chip(dims):
@@ -303,6 +317,68 @@ def check(name):
             "ok" if len(inner_f32) <= 1 else ", ".join(inner_f32)
         ),
         INVARIANTS[4]: _conv_part(text, ROWS, attrs.conv_width),
+    }
+
+
+# hidden size and `StateSpaceAttrs` of a `granite-4.0-h-micro` node: ONE
+# group of 64 heads of 64 (4,096 columns) at chunks of 256
+GRANITE_SHAPE = (2048, (64, 64, 128, 1, 4, 256))
+GRANITE_INVARIANTS = [
+    "granite_scan_is_the_three_kernels_once_each_under_the_default_vmem_scope",
+    "granite_group_goes_as_column_blocks",
+    "granite_holds_no_chunks_by_heads_decay_mask",
+    "granite_convolution_is_two_kernels_on_the_projections_row",
+]
+
+
+def check_granite():
+    """{invariant: "ok" or what was found} for the wide-group node."""
+    from flexflow_tpu.kernels import ssm
+
+    try:
+        attrs, text = compiled_node("granite")
+    except Exception as e:  # noqa: BLE001 - the complaint is the result
+        return dict.fromkeys(GRANITE_INVARIANTS, f"{type(e).__name__}: {e}"[:2000])
+    heads, p, n, groups, _, q = GRANITE_SHAPE[1]
+    blocks = ssm._column_blocks(heads * p // groups)
+    entry = [r for r in entry_instructions(text) if r[2] not in _NO_BUFFER]
+    calls = {}
+    for _name, result, opcode, _operands, line in entry:
+        kernel = re.search(r"/(ssd_\w+)/pallas_call", line)
+        if opcode == "custom-call" and kernel:
+            calls.setdefault(kernel.group(1), []).append((result, line))
+    counts = {k: len(v) for k, v in calls.items()}
+    limits = [
+        k for k, found in calls.items()
+        if any("vmem_limit_bytes" in line for _, line in found)
+    ]
+    want = {"ssd_fwd_chunk": 1, "ssd_states_chunk": 1, "ssd_bwd_chunk": 1}
+    shapes = {
+        k: [dims for result, _ in found for _, dims in shapes_of(result)]
+        for k, found in calls.items()
+    }
+    states = (1, groups * blocks, ROWS // q, heads * p // (groups * blocks), n)
+    partial = (1, ROWS, groups * blocks * n)
+    in_blocks = (
+        blocks > 1 and states in [tuple(d) for d in shapes.get("ssd_states_chunk", [])]
+        and [tuple(d) for d in shapes.get("ssd_bwd_chunk", [])].count(partial) == 2
+    )
+    masks = [
+        f"{name} {dtype}{list(dims)}"
+        for name, result, *_ in entry for dtype, dims in shapes_of(result)
+        if len(dims) >= 2 and tuple(dims[-2:]) == (q, q)
+        and math.prod(dims) >= (ROWS // q) * heads * q * q
+    ]
+    return {
+        GRANITE_INVARIANTS[0]: (
+            "ok" if counts == want and not limits
+            else f"kernels {counts}, limits stated by {limits}"
+        ),
+        GRANITE_INVARIANTS[1]: (
+            "ok" if in_blocks else f"{blocks} blocks, results {shapes}"
+        ),
+        GRANITE_INVARIANTS[2]: "ok" if not masks else ", ".join(masks),
+        GRANITE_INVARIANTS[3]: _conv_part(text, ROWS, attrs.conv_width),
     }
 
 
@@ -1613,6 +1689,11 @@ def test_mellum2_nodes_compiled_for_the_described_chip(compiled, invariant):
     assert compiled["mellum2"][invariant] == "ok"
 
 
+@pytest.mark.parametrize("invariant", GRANITE_INVARIANTS)
+def test_granite_node_compiled_for_the_described_chip(compiled, invariant):
+    assert compiled["granite"][invariant] == "ok"
+
+
 @pytest.mark.parametrize("node", list(BETWEEN_NODES) + [BETWEEN_FALLBACK])
 def test_norm_and_rotary_pass_compiled_for_the_described_chip(compiled, node):
     assert compiled["between"][node] == "ok"
@@ -1648,6 +1729,10 @@ if __name__ == "__main__":
             "layer_types": ["full_attention"] * layers,
         }
         print(json.dumps(cell_step_bytes("ouro26b_s8192_1chip", root, **cut)))
+    elif argv and argv[0] == "granite_step":
+        print(json.dumps(cell_step_bytes("granite4hmicro_s4096_1chip", root)))
+    elif argv and argv[0] == "granite":
+        print(json.dumps(check_granite()))
     elif argv and argv[0] == "mellum2":
         print(json.dumps(check_mellum2()))
     elif argv and argv[0] == "between":
@@ -1660,6 +1745,7 @@ if __name__ == "__main__":
                  lfm2=check_lfm2(), experts=check_experts(),
                  held_sums=check_held_sums(), qwen3next=check_qwen3next(),
                  joyai=check_joyai(), phi4flash=check_phi4flash(),
-                 mellum2=check_mellum2(), between=check_between(),
+                 mellum2=check_mellum2(), granite=check_granite(),
+                 between=check_between(),
                  account=check_account(root))
         ))
