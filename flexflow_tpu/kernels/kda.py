@@ -121,23 +121,43 @@ and meet the value heads' M through a group axis, so a key head is indexed
 K exp(G), K exp(G_Q - G), the inverse and the states are a value head's.
 `head_decay_operands` is that form in XLA (JAX differentiates it but for the
 triangular inverse): the "xla" route's, and what the kernels are tested
-against. On the "kda" route the operands are `head_kernel_operands`:
-`gdn_prep_fwd` computes the two products once a chunk of a KEY head and, for
-each value head that reads it, the mask and the three decay vectors in VMEM,
+against; it takes q, k and v HEADS FIRST, q and k normalised (`_unit`), which
+`_recurrence_head_decay` does under `gates` on that route alone. On the
+"kda" route the operands are `head_kernel_operands`, and every kernel that
+reads q, k or v reads it IN THE MODEL'S LAYOUT, where the convolution's
+kernel left its three pieces ([b, s, hk * dk] twice and [b, s, hv * dv]): a
+head of 128 channels is ONE 128-lane column block of the program's rows
+(`_HeadPrepBlocks.key_head`, `_CorrectedBlocks.value`), so no heads-first
+copy of q, k or v and no float32 pass of a norm exists, forward, recomputed
+or backward. `gdn_prep_fwd` takes q and k RAW, puts them over their own
+2-norm in VMEM (`_unit_root`: float32, one rounding to the input's dtype,
+q times dk ** -0.5), computes the two products once a chunk of a KEY head
+and, for each value head that reads it, the mask and the three decay vectors,
 eight chunks a program, and writes Q exp(G), K exp(G_Q - G), P, exp(G_Q), the
-strictly lower A and K exp(G); `gdn_prep_bwd` is its WRITTEN backward
-(`head_chunk_scores`, one `jax.custom_vjp`), which recomputes them from q, k
-and g, sums the value heads' cotangents of q and k in the program and takes
-the exponents' back to g. M, Q K^T, K K^T and the decays never reach HBM. The
-kernels share helpers with the per-channel form's (`_bf16_parts`, `_mm`) and
-no body: there the exponent is one a key channel and needs the levels, here
-one number a pair of positions and no level. The triangular system is the
-per-channel form's own, kernels and fallback (`_kernel_corrected`: both forms
-hand it A, K exp(G), V and beta by chunk and value head). Either way what comes back
-is what `chunk_operands` returns, exp(G_Q) written over the dk lanes, so
-`chunk_scan` and its kernels take it as they are. Which form a node took is
-`operand_form`'s answer (the attrs' `decay` and `scan_route`'s route, nothing
-else), counted by node in `observability/trace.delta_rule_operands()`.
+strictly lower A and K exp(G) heads first and by chunk, as `chunk_scan`
+reads them; `gdn_prep_bwd` is its WRITTEN backward (`head_chunk_scores`, one
+`jax.custom_vjp`), which recomputes the norms, the products and the decays
+from the raw q, k and g, sums the value heads' cotangents of the normalised q
+and k in the program, takes them through the norm in float32
+(`_unit_cotangent`) and writes dq and dk as column blocks of [b, s, hk * dk],
+and takes the exponents' back to g. `kda_corrected_fwd` reads v and
+`kda_corrected_bwd` writes dv the same way, a program's chunk-heads being
+chunks of one head. The three cotangents are what `conv_silu_bwd` reads, with
+nothing between. M, Q K^T, K K^T, the normalised q and k and the decays never
+reach HBM. A sequence that is not whole chunks is padded in the model's
+layout first (a copy of each piece; a padded position has q = k = v = 0).
+The kernels share helpers with the per-channel form's (`_bf16_parts`, `_mm`,
+`_unit_root`, `_unit_cotangent`) and no body: there the exponent is one a key
+channel and needs the levels, here one number a pair of positions and no
+level. The triangular system is the per-channel form's own, kernels and
+fallback (`_kernel_corrected`: both forms hand it A, K exp(G) and beta by
+chunk and value head, the per-channel form v likewise, this one v in the
+model's layout; XLA's fallback turns it heads first itself). Either way what
+comes back is what `chunk_operands` returns, exp(G_Q) written over the dk
+lanes, so `chunk_scan` and its kernels take it as they are. Which form a node
+took is `operand_form`'s answer (the attrs' `decay`, `scan_route`'s route and
+whether the sequence is whole chunks, nothing else), counted by node in
+`observability/trace.delta_rule_operands()`.
 
 **The gated norm** (`_gated_head_norm`, the node's last part before W_out):
 the rms norm of each head's dv features of the recurrence's o under a gate,
@@ -160,19 +180,22 @@ against. `observability/trace.head_norms()` says which, by node.
 The node's parts go under scopes of their own inside the node's
 (`ff.kda.<name>/scan`, `/prep`, `/gates`, `/conv`, `/norm`;
 `observability/trace.NODE_PARTS`). On the "kda" route `gates` holds what the
-scores' kernels do not take: the two rank-128 gate matmuls (`f_up`, `g_up`),
-beta's sigmoid, v's heads-first copy (and dv's back) and the small
-reductions; on the "xla" route also the norms of q and k, the softplus and
-the heads-first copies of q, k and the pre-activation. `norm` holds the two
-kernels above and nothing else on the "kda" route, and on the "xla" route o's
-copy to the model's layout with XLA's fusions of the plain form, forward,
-recomputed and backward. `conv` is `kernels/ssm.conv_silu` on both, and it
-chooses its own form (`ssm.conv_route`, from the widths, the sequence and the
-trace; `observability/trace.conv_forms()`): since PR 59 the kernels
-`conv_silu_fwd` / `conv_silu_bwd`, which read the q | k | v columns in place
-out of the input projection's row (the node hands it the row, not a slice),
-wherever the "kda" route runs and the sequence divides into their blocks,
-else its plain form.
+scores' kernels do not take: with a decay a key channel the two rank-128 gate
+matmuls (`f_up`, `g_up`), beta's sigmoid, v's heads-first copy (and dv's
+back) and the small reductions; with one decay a head the `W_ba` matmul and
+its backward, beta's sigmoid and g's softplus on [b, hv, s], and no copy of
+q, k or v. On the "xla" route it also holds the norms of q and k, the
+softplus and the heads-first copies of q, k, v and the pre-activation.
+`norm` holds the two kernels above and nothing else on the "kda" route, and
+on the "xla" route o's copy to the model's layout with XLA's fusions of the
+plain form, forward, recomputed and backward. `conv` is
+`kernels/ssm.conv_silu` on both, and it chooses its own form
+(`ssm.conv_route`, from the widths, the sequence and the trace;
+`observability/trace.conv_forms()`): since PR 59 the kernels `conv_silu_fwd`
+/ `conv_silu_bwd`, which read the q | k | v columns in place out of the input
+projection's row (the node hands it the row, not a slice), wherever the
+"kda" route runs and the sequence divides into their blocks, else its plain
+form.
 """
 
 from __future__ import annotations
@@ -1241,21 +1264,36 @@ def _kda_corrected_bwd_kernel(
         lax.fori_loop(0, heads, sweep, None, unroll=True)
 
 
-# chunk-heads a program of the two kernels above (the largest that divides
-# their number, which is even: the inverse's kernel takes them two by two)
-_CORRECTED_HEADS = (16, 8, 4, 2)
+# chunk-heads a program of the two kernels above: the largest that divides
+# their number, which is even (the inverse's kernel takes them two by two),
+# or, where v is read in the model's layout, the chunks of one head
+_CORRECTED_HEADS = (16, 8, 4, 2, 1)
 
 
 class _CorrectedBlocks:
     """The BlockSpecs over the one grid axis, `n` chunk-heads a program: what
     a chunk-head has, [count * Q, width], its rows; beta and its cotangent
-    [count / n, n, Q] a program's rows."""
+    [count / n, n, Q] a program's rows. With `in_place` = (heads, chunks a
+    head) v and its cotangent lie as the MODEL has them, [b, s, heads * dv]
+    (`value`): a program's chunk-heads are then chunks of ONE head, n the
+    largest that divides a head's chunks, and a head of dv = 128 value
+    channels one 128-lane column block of their rows."""
 
-    def __init__(self, count: int, q: int, dk: int, dv: int, itemsize: int):
-        n = self.heads = next(n for n in _CORRECTED_HEADS if count % n == 0)
+    def __init__(self, count: int, q: int, dk: int, dv: int, itemsize: int,
+                 in_place=None):
+        self.in_place = in_place is not None
+        heads, chunks = in_place or (1, count)
+        n = self.heads = next(n for n in _CORRECTED_HEADS if chunks % n == 0)
+        self.dv = dv
         self.grid = (count // n,)
         self.block_rows = n * q
         self.steps = pl.BlockSpec((None, n, q), lambda i: (i, 0, 0))
+        programs = chunks // n  # of one head
+        self.value = pl.BlockSpec(
+            (None, n * q, dv),
+            lambda i: (i // (heads * programs), i % programs,
+                       i // programs % heads),
+        ) if in_place else self.rows(dv)
         # the backward's blocks (x and dn fill 128 lanes; kd, dkd float32; v,
         # dw, duv, dv in the model's dtype), twice for the pipeline's two
         # buffers, and as much again for Y, M and what the body holds
@@ -1271,23 +1309,41 @@ class _CorrectedBlocks:
     def rows(self, width: int):
         return pl.BlockSpec((self.block_rows, width), lambda i: (i, 0))
 
+    def taken(self, v):
+        """v as the kernels take it: where it lies, or its rows flattened."""
+        return v if self.in_place else _flat(v)
+
 
 def _flat(t):
     """[.., Q, w] -> [chunk-heads * Q, w]."""
     return t.reshape(-1, t.shape[-1])
 
 
+def _corrected_blocks(beta, kd, v):
+    """`_CorrectedBlocks` of beta [b, h, c, Q], kd [b, h, c, Q, dk] and v, by
+    chunk and head [b, h, c, Q, dv] or in the model's layout [b, s, h * dv],
+    which the kernels then read in place."""
+    heads, chunks, q = beta.shape[-3:]
+    in_place = None if v.ndim == kd.ndim else (heads, chunks)
+    dv = v.shape[-1] // heads if in_place else v.shape[-1]
+    return _CorrectedBlocks(
+        beta.size // q, q, kd.shape[-1], dv, v.dtype.itemsize, in_place
+    )
+
+
 @functools.partial(jax.jit, static_argnums=(4,))
 def _corrected_forward(x, beta, kd, v, interpret):
     """(T Kd in v's dtype [.., Q, dk], T V [.., Q, dv]) of x [.., Q, Q] and kd
-    [.., Q, dk] float32, v [.., Q, dv] and beta [.., Q], as a kernel."""
-    q, dk, dv = x.shape[-1], kd.shape[-1], v.shape[-1]
+    [.., Q, dk] float32, beta [.., Q] and v ([.., Q, dv], or [b, s, h * dv]
+    where the model has it: `_corrected_blocks`), as a kernel."""
+    q, dk = x.shape[-1], kd.shape[-1]
     count = beta.size // q
-    at = _CorrectedBlocks(count, q, dk, dv, v.dtype.itemsize)
+    at = _corrected_blocks(beta, kd, v)
+    dv = at.dv
     w, uv = pl.pallas_call(
         functools.partial(_kda_corrected_fwd_kernel, heads=at.heads),
         grid=at.grid,
-        in_specs=[at.rows(q), at.steps, at.rows(dk), at.rows(dv)],
+        in_specs=[at.rows(q), at.steps, at.rows(dk), at.value],
         out_specs=[at.rows(dk), at.rows(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((count * q, dk), v.dtype),
@@ -1296,35 +1352,39 @@ def _corrected_forward(x, beta, kd, v, interpret):
         compiler_params=at.params,
         interpret=interpret,
         name="kda_corrected_fwd",
-    )(_flat(x), beta.reshape(-1, at.heads, q), _flat(kd), _flat(v))
-    return w.reshape(kd.shape), uv.reshape(v.shape)
+    )(_flat(x), beta.reshape(-1, at.heads, q), _flat(kd), at.taken(v))
+    return w.reshape(kd.shape), uv.reshape(*kd.shape[:-1], dv)
 
 
 @functools.partial(jax.jit, static_argnums=(6,))
 def _corrected_backward(x, beta, kd, v, dw, duv, interpret):
     """The cotangents of (n, beta, kd, v), n the strictly lower matrix x is
-    the inverse of I + n of, from those of `_corrected_forward`'s results."""
+    the inverse of I + n of, from those of `_corrected_forward`'s results;
+    v's is written in v's layout."""
     f32 = jnp.float32
-    q, dk, dv = x.shape[-1], kd.shape[-1], v.shape[-1]
+    q, dk = x.shape[-1], kd.shape[-1]
     count = beta.size // q
-    at = _CorrectedBlocks(count, q, dk, dv, v.dtype.itemsize)
+    at = _corrected_blocks(beta, kd, v)
+    dv = at.dv
     dn, dbeta, dkd, dvalue = pl.pallas_call(
         functools.partial(_kda_corrected_bwd_kernel, heads=at.heads),
         grid=at.grid,
-        in_specs=[at.rows(q), at.steps, at.rows(dk), at.rows(dv),
+        in_specs=[at.rows(q), at.steps, at.rows(dk), at.value,
                   at.rows(dk), at.rows(dv)],
-        out_specs=[at.rows(q), at.steps, at.rows(dk), at.rows(dv)],
+        out_specs=[at.rows(q), at.steps, at.rows(dk), at.value],
         out_shape=[
             jax.ShapeDtypeStruct((count * q, q), f32),
             jax.ShapeDtypeStruct((count // at.heads, at.heads, q), f32),
             jax.ShapeDtypeStruct((count * q, dk), f32),
-            jax.ShapeDtypeStruct((count * q, dv), v.dtype),
+            jax.ShapeDtypeStruct(
+                v.shape if at.in_place else (count * q, dv), v.dtype
+            ),
         ],
         scratch_shapes=at.between,
         compiler_params=at.params,
         interpret=interpret,
         name="kda_corrected_bwd",
-    )(_flat(x), beta.reshape(-1, at.heads, q), _flat(kd), _flat(v),
+    )(_flat(x), beta.reshape(-1, at.heads, q), _flat(kd), at.taken(v),
       _flat(dw), _flat(duv))
     return (
         dn.reshape(x.shape), dbeta.reshape(beta.shape).astype(beta.dtype),
@@ -1355,18 +1415,23 @@ def _corrected_products_bwd(kept, cotangents):
 corrected_products.defvjp(_corrected_products_fwd, _corrected_products_bwd)
 
 
-def _kernel_corrected(a, kd, v5, beta5):
-    """`_corrected` on the "kda" route, `dtype` v5's: the kernels where they
+def _kernel_corrected(a, kd, v, beta5):
+    """`_corrected` on the "kda" route, `dtype` v's: the kernels where they
     take the number of chunk-heads (an even one), XLA's form with
     `unit_lower_inverse` otherwise; which, told to the program's counter
-    (`observability/trace.triangular_products`)."""
+    (`observability/trace.triangular_products`). v is [b, h, c, Q, dv], or
+    [b, s, h * dv] as the model has it, which the kernels read in place and
+    only XLA's form turns heads first."""
     from flexflow_tpu.observability import trace
 
     if (beta5.size // beta5.shape[-1]) % 2:
         trace.note_triangular_products("xla")
-        return _corrected(a, kd, v5, beta5, v5.dtype)
+        if v.ndim != kd.ndim:
+            b, h, c, q = beta5.shape
+            v = jnp.transpose(v.reshape(b, c, q, h, -1), (0, 3, 1, 2, 4))
+        return _corrected(a, kd, v, beta5, v.dtype)
     trace.note_triangular_products("kernels")
-    return corrected_products(beta5[..., :, None] * a, beta5, kd, v5)
+    return corrected_products(beta5[..., :, None] * a, beta5, kd, v)
 
 
 # what a padded position's decay pre-activation reads: its softplus, and so
@@ -1403,8 +1468,11 @@ def kernel_operands(qkv, f_up, dt_bias, a_log, v, beta, chunk: int):
 # ---------------------------------------------------------------------------
 #
 # One program is `n` chunks of one (batch row, KEY head) with ALL the value
-# heads that read it: q and k are loaded once and Q K^T, K K^T taken once a
-# chunk, then met by each value head's mask M_rj = exp(G_r - G_j). No level is
+# heads that read it: q and k are loaded once, RAW and where the model has
+# them (a key head's 128-lane column block of [b, s, hk * dk]), normalised in
+# VMEM, and Q K^T, K K^T taken once a chunk, then met by each value head's
+# mask M_rj = exp(G_r - G_j). The chunks are a `fori_loop` that is unrolled:
+# the body is traced once, the value heads written out inside it. No level is
 # needed and no exponent table: with the chunk's log-decays g a ROW [1, Q]
 # (positions along the lanes, as [b, hv, s] lies), L_ri = g_i for i <= r and
 # 0 elsewhere is a broadcast and a select, and
@@ -1416,7 +1484,7 @@ def kernel_operands(qkv, f_up, dt_bias, a_log, v, beta, chunk: int):
 # each a SUM of log-decays over a set of positions and so <= 0. The product
 # takes L in three bf16 parts (`_bf16_parts`: float32-exact), and its
 # transpose takes the exponents' cotangent back to g the same way. M, Q K^T,
-# K K^T and the decay vectors never leave VMEM.
+# K K^T, the normalised q and k and the decay vectors never leave VMEM.
 
 
 def _head_decays(g_row, tri, after):
@@ -1455,26 +1523,30 @@ def _chunk_masks(q: int):
 
 def _gdn_prep_fwd_kernel(
     q_ref, k_ref, g_ref, qd_ref, ke_ref, p_ref, gam_ref, a_ref, kd_ref,
-    *, chunks: int, q: int,
+    *, chunks: int, q: int, scale: float,
 ):
     """Q exp(G), K exp(G_Q - G), P, exp(G_Q), the strictly lower A and
     K exp(G) (float32, for the triangular system) of each of the program's
-    chunks and each value head of its key head, from the NORMALISED q, k
-    [n Q, dk] and the heads' log-decays g [group, n, Q]."""
+    chunks and each value head of its key head, from the RAW q, k [n Q, dk]
+    of the key head, read where the convolution left them and put over
+    their own 2-norm here (`_unit_root`: float32, ROUNDED to the input's
+    dtype; q times `scale`), and the heads' log-decays g [group, n, Q]."""
     f32 = jnp.float32
     dtype = q_ref.dtype
     group = g_ref.shape[0]
     tri, strict, after, _ = _chunk_masks(q)
-    for c in range(chunks):
-        rows = pl.ds(c * q, q)
-        qn, kn = q_ref[rows, :], k_ref[rows, :]
+
+    def one_chunk(c, _):
+        rows = pl.ds(pl.multiple_of(c * q, q), q)
+        qn, _ = _unit_root(q_ref[rows, :], scale)
+        kn, _ = _unit_root(k_ref[rows, :], 1.0)
         qf, kf = qn.astype(f32), kn.astype(f32)
         # one product a KEY head meets its value heads' decays
         scores = _mm(jnp.concatenate([qn, kn], axis=0), kn, _NT)
         qk, kk = scores[:q], scores[q:]
         for h in range(group):
             between, from_start, to_end = _head_decays(
-                g_ref[h, c:c + 1, :], tri, after
+                g_ref[h, pl.ds(c, 1), :], tri, after
             )
             m = jnp.exp(between)
             from_start = jnp.exp(from_start)
@@ -1487,14 +1559,18 @@ def _gdn_prep_fwd_kernel(
                 from_start[q - 1:q, :], (1, kf.shape[1])
             )
 
+    lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
+
 
 def _gdn_prep_bwd_kernel(
     q_ref, k_ref, g_ref, dqd_ref, dke_ref, dp_ref, dgam_ref, da_ref, dkd_ref,
-    dq_ref, dk_ref, dg_ref, *, chunks: int, q: int,
+    dq_ref, dk_ref, dg_ref, *, chunks: int, q: int, scale: float,
 ):
-    """The cotangents of q, k (summed over the key head's value heads,
-    float32) and g from those of `_gdn_prep_fwd_kernel`'s six results, the
-    products and every decay recomputed. With dE the cotangent of the pairs'
+    """The cotangents of the RAW q, k (those of the normalised ones summed
+    over the key head's value heads in float32, then through the norm,
+    `_unit_cotangent`, and written where q and k were read) and of g from
+    those of `_gdn_prep_fwd_kernel`'s six results, the norms, the products
+    and every decay recomputed. With dE the cotangent of the pairs'
     exponents G_r - G_j, x_r that of G_r and y_r that of G_Q - G_r, g's is
     the sum down the rows of (dE [i > j]^T + x_r) where i <= r and of y_r
     elsewhere: the forward's sums transposed."""
@@ -1503,9 +1579,11 @@ def _gdn_prep_bwd_kernel(
     group = g_ref.shape[0]
     tri, strict, after, after_t = _chunk_masks(q)
     last = lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
-    for c in range(chunks):
-        rows = pl.ds(c * q, q)
-        qn, kn = q_ref[rows, :], k_ref[rows, :]
+
+    def one_chunk(c, _):
+        rows = pl.ds(pl.multiple_of(c * q, q), q)
+        qn, q_root = _unit_root(q_ref[rows, :], scale)
+        kn, k_root = _unit_root(k_ref[rows, :], 1.0)
         qf, kf = qn.astype(f32), kn.astype(f32)
         both = jnp.concatenate([qn, kn], axis=0)
         scores = _mm(both, kn, _NT)
@@ -1516,7 +1594,7 @@ def _gdn_prep_bwd_kernel(
         dkk = jnp.zeros((q, q), f32)
         for h in range(group):
             between, from_start, to_end = _head_decays(
-                g_ref[h, c:c + 1, :], tri, after
+                g_ref[h, pl.ds(c, 1), :], tri, after
             )
             m = jnp.where(tri, jnp.exp(between), 0.0)
             from_start, to_end = jnp.exp(from_start), jnp.exp(to_end)
@@ -1536,20 +1614,29 @@ def _gdn_prep_bwd_kernel(
                 _mm(part, after_t, _NN)
                 for part in _bf16_parts((dp * qk + da * kk) * m)
             )
-            dg_ref[h, c:c + 1, :] = jnp.sum(
+            dg_ref[h, pl.ds(c, 1), :] = jnp.sum(
                 jnp.where(tri, d_between + x, y), axis=0, keepdims=True
             )
         dqk, dkk = dqk.astype(dtype), dkk.astype(dtype)
-        dq_ref[rows, :] = (dq + _mm(dqk, kn, _NN)).astype(dq_ref.dtype)
-        dk_ref[rows, :] = (
-            dk + _mm(dkk, kn, _NN)
-            + _mm(jnp.concatenate([dqk, dkk], axis=0), both, _TN)
+        dq_ref[rows, :] = _unit_cotangent(
+            q_ref[rows, :], q_root, dq + _mm(dqk, kn, _NN), scale
+        ).astype(dq_ref.dtype)
+        dk = dk + _mm(dkk, kn, _NN) + _mm(
+            jnp.concatenate([dqk, dkk], axis=0), both, _TN
+        )
+        dk_ref[rows, :] = _unit_cotangent(
+            k_ref[rows, :], k_root, dk, 1.0
         ).astype(dk_ref.dtype)
+
+    lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
 
 
 class _HeadPrepBlocks:
     """The BlockSpecs over the grid (batch, KEY head, group of n chunks):
-    q, k [b, hk, s, dk] a key head's rows; what a value head has,
+    q, k and their cotangents lie as the model has them, [b, s, hk * dk], a
+    key head of dk = 128 key channels ONE 128-lane column block of the
+    program's rows (`key_head`: what `conv_silu` wrote and what its backward
+    reads, no heads-first copy between); what a value head has,
     [b, hv, s, .], the rows of the key head's `group` value heads (head
     hi * group onward: a key head is indexed, never repeated); g
     [b, hv, c / n, n, Q] and exp(G_Q) [b, hv, c, 1, dk] likewise."""
@@ -1559,13 +1646,15 @@ class _HeadPrepBlocks:
         n = self.chunks = next(n for n in _PREP_CHUNKS if c % n == 0)
         self.grid = (b, hk, c // n)
 
-        def rows(width, heads):
+        def rows(width):
             return pl.BlockSpec(
-                (None, heads, n * q, width), lambda bi, hi, gi: (bi, hi, gi, 0)
+                (None, group, n * q, width), lambda bi, hi, gi: (bi, hi, gi, 0)
             )
 
-        self.key_head = rows(dk, None)
-        self.key, self.scores = rows(dk, group), rows(q, group)
+        self.key_head = pl.BlockSpec(
+            (None, n * q, dk), lambda bi, hi, gi: (bi, gi, hi)
+        )
+        self.key, self.scores = rows(dk), rows(q)
         self.decay = pl.BlockSpec(
             (None, group, None, n, q), lambda bi, hi, gi: (bi, hi, gi, 0, 0)
         )
@@ -1584,15 +1673,16 @@ class _HeadPrepBlocks:
         )
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4))
-def _head_prep_forward(q, k, g, chunk, interpret):
-    """The kernel on q, k [b, hk, s, dk] (normalised) and g [b, hv, s]
-    float32; by chunk and VALUE head out ([b, hv, c, Q, .]): qd, ke, p, gamma
-    as `head_decay_operands` has them, then A [., Q, Q] and K exp(G)
-    [., Q, dk], float32."""
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _head_prep_forward(q, k, g, chunk, dk, interpret):
+    """The kernel on the RAW q, k [b, s, hk * dk] in the model's layout (the
+    convolution's pieces as they lie) and g [b, hv, s] float32; by chunk and
+    VALUE head out ([b, hv, c, Q, .]): qd, ke, p, gamma as
+    `head_decay_operands` has them of the normalised q and k, then A
+    [., Q, Q] and K exp(G) [., Q, dk], float32."""
     f32 = jnp.float32
-    b, hk, s, dk = q.shape
-    hv = g.shape[1]
+    b, s, width = q.shape
+    hk, hv = width // dk, g.shape[1]
     c = s // chunk
     at = _HeadPrepBlocks(b, hk, hv // hk, s, dk, chunk)
 
@@ -1600,7 +1690,9 @@ def _head_prep_forward(q, k, g, chunk, interpret):
         return jax.ShapeDtypeStruct((b, hv, s, width), dtype)
 
     qd, ke, p, gamma, a, kd = pl.pallas_call(
-        functools.partial(_gdn_prep_fwd_kernel, chunks=at.chunks, q=chunk),
+        functools.partial(
+            _gdn_prep_fwd_kernel, chunks=at.chunks, q=chunk, scale=dk ** -0.5
+        ),
         grid=at.grid,
         in_specs=[at.key_head, at.key_head, at.decay],
         out_specs=[at.key, at.key, at.scores, at.gamma, at.scores, at.key],
@@ -1620,16 +1712,19 @@ def _head_prep_forward(q, k, g, chunk, interpret):
     return by_chunk(qd), by_chunk(ke), by_chunk(p), gamma, by_chunk(a), by_chunk(kd)
 
 
-@functools.partial(jax.jit, static_argnums=(4, 5))
-def _head_prep_backward(q, k, g, cotangents, chunk, interpret):
-    """The cotangents of `_head_prep_forward`'s three inputs."""
-    b, hk, s, dk = q.shape
-    hv = g.shape[1]
+@functools.partial(jax.jit, static_argnums=(4, 5, 6))
+def _head_prep_backward(q, k, g, cotangents, chunk, dk, interpret):
+    """The cotangents of `_head_prep_forward`'s three inputs, q's and k's in
+    the model's layout as q and k were read."""
+    b, s, width = q.shape
+    hk, hv = width // dk, g.shape[1]
     at = _HeadPrepBlocks(b, hk, hv // hk, s, dk, chunk)
     dqd, dke, dp, dgam, da, dkd = cotangents
     by_program = (b, hv, s // chunk // at.chunks, at.chunks, chunk)
     dq, dkey, dg = pl.pallas_call(
-        functools.partial(_gdn_prep_bwd_kernel, chunks=at.chunks, q=chunk),
+        functools.partial(
+            _gdn_prep_bwd_kernel, chunks=at.chunks, q=chunk, scale=dk ** -0.5
+        ),
         grid=at.grid,
         in_specs=[
             at.key_head, at.key_head, at.decay,
@@ -1649,38 +1744,39 @@ def _head_prep_backward(q, k, g, cotangents, chunk, interpret):
     return dq, dkey, dg.reshape(g.shape)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def head_chunk_scores(q, k, g, chunk: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def head_chunk_scores(q, k, g, chunk: int, dk: int):
     """`chunk_scores` for ONE log-decay a value head: what
     `head_decay_operands` takes from q, k and the log-decays alone, as Pallas
     kernels with a WRITTEN backward (`_head_prep_forward`'s inputs and
-    results). What the backward keeps is those inputs: it recomputes the
-    products and every decay."""
-    return _head_prep_forward(q, k, g, chunk, _interpret())
+    results): the kernels read q and k RAW, heads of `dk` columns in the
+    model's layout, and normalise them in VMEM. What the backward keeps is
+    those inputs (what the node's checkpoint holds anyway): it recomputes
+    the norms, the products and every decay."""
+    return _head_prep_forward(q, k, g, chunk, dk, _interpret())
 
 
-def _head_chunk_scores_fwd(q, k, g, chunk):
-    return head_chunk_scores(q, k, g, chunk), (q, k, g)
+def _head_chunk_scores_fwd(q, k, g, chunk, dk):
+    return head_chunk_scores(q, k, g, chunk, dk), (q, k, g)
 
 
-def _head_chunk_scores_bwd(chunk, kept, cotangents):
-    return _head_prep_backward(*kept, cotangents, chunk, _interpret())
+def _head_chunk_scores_bwd(chunk, dk, kept, cotangents):
+    return _head_prep_backward(*kept, cotangents, chunk, dk, _interpret())
 
 
 head_chunk_scores.defvjp(_head_chunk_scores_fwd, _head_chunk_scores_bwd)
 
 
-def head_kernel_operands(q, k, v, g, beta, chunk: int):
-    """`head_decay_operands` on the "kda" route, on the same inputs: the
-    decays and the scores from one kernel, the triangular inverse from
-    another, its two products with K exp(G) and V from a third
-    (`_kernel_corrected`)."""
-    b, hv, s, dv = v.shape
-    c = s // chunk
-    qd, ke, p, gamma, a, kd = head_chunk_scores(q, k, g, chunk)
-    w, uv = _kernel_corrected(
-        a, kd, v.reshape(b, hv, c, chunk, dv), beta.reshape(b, hv, c, chunk)
-    )
+def head_kernel_operands(q, k, v, g, beta, chunk: int, dk: int):
+    """`head_decay_operands` on the "kda" route, from the node's RAW q, k
+    [b, s, hk * dk] and v [b, s, hv * dv] in the MODEL's layout (the
+    convolution's pieces as they lie, s whole chunks) beside g and beta
+    [b, hv, s]: the norms, the decays and the scores from one kernel, the
+    triangular inverse from another, its two products with K exp(G) and V
+    from a third (`_kernel_corrected`), which reads v where it lies too."""
+    b, hv, s = g.shape
+    qd, ke, p, gamma, a, kd = head_chunk_scores(q, k, g, chunk, dk)
+    w, uv = _kernel_corrected(a, kd, v, beta.reshape(b, hv, s // chunk, chunk))
     return qd, w, uv, ke, p, gamma
 
 
@@ -1706,17 +1802,23 @@ def scan_route(key_dim: int, value_dim: int, chunk: int) -> str:
     return "kda" if flash._backend_ok(flash.interpret_default()) else "xla"
 
 
-def operand_form(attrs: GatedDeltaAttrs, route: str) -> str:
+def operand_form(attrs: GatedDeltaAttrs, route: str, seq: int) -> str:
     """Which form the chunks' operands of a node take on `route`
-    (`scan_route`'s answer), told to the program's counter as well
-    (`observability/trace.delta_rule_operands`): the attrs' own `decay` names
-    the set of kernels, and nothing else chooses. The "xla" route also
-    settles the products around the triangular inverse
+    (`scan_route`'s answer) over `seq` positions, told to the program's
+    counter as well (`observability/trace.delta_rule_operands`): the attrs'
+    own `decay` names the set of kernels, and with one decay a head the
+    sequence says whether they read q, k and v where the convolution left
+    them (`head_kernels_in_place`: whole chunks) or padded copies of them
+    (`head_kernels`); nothing else chooses. The "xla" route also settles the
+    products around the triangular inverse
     (`observability/trace.triangular_products`)."""
     from flexflow_tpu.observability import trace
 
-    if attrs.per_head_decay:
-        form = "head_kernels" if route == "kda" else "head_xla"
+    if attrs.per_head_decay and route == "kda":
+        padded = seq % attrs.chunk_size
+        form = "head_kernels" if padded else "head_kernels_in_place"
+    elif attrs.per_head_decay:
+        form = "head_xla"
     else:
         form = "channel_kernels" if route == "kda" else "xla"
     trace.note_delta_rule_operands(form)
@@ -2084,13 +2186,17 @@ def _recurrence(attrs: GatedDeltaAttrs, route: str, qkv, f_up, dt_bias, a_log,
     return o.reshape(b, h, s + pad, dv)[:, :, :s]
 
 
-def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, form: str, qkv,
-                           a_pre, dt_bias, a_log, b_logit):
+def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, qkv, a_pre,
+                           dt_bias, a_log, b_logit):
     """`_recurrence` for one log-decay a value head: qkv, the convolution's
     result in its three pieces (q and k [b, s, hk*dk], v [b, s, hv*dv]:
     `conv_silu`'s `pieces`, so that their cotangents go back apart), a_pre
-    and b_logit [b, s, hv] -> o [b, hv, s, dv]; `form` is `operand_form`'s
-    answer."""
+    and b_logit [b, s, hv] -> o [b, hv, s, dv]. On the "kda" route the
+    kernels of `head_kernel_operands` read the three pieces where they lie
+    (`operand_form`: `head_kernels_in_place`; padded to the chunk first
+    where the sequence is not whole chunks, `head_kernels`) and hand their
+    cotangents back the same way; on the "xla" route q, k and v are turned
+    heads first and q and k normalised here, under `gates`."""
     f32 = jnp.float32
     q, k, v = qkv
     b, s, _ = q.shape
@@ -2098,29 +2204,31 @@ def _recurrence_head_decay(attrs: GatedDeltaAttrs, route: str, form: str, qkv,
         attrs.num_heads, attrs.key_heads, attrs.key_dim, attrs.value_dim,
         attrs.chunk_size,
     )
-    kw = attrs.key_width
     pad = -s % chunk
 
     def heads_first(t, heads):
         # [b, s, heads * width] -> [b, heads, s + pad, width]; a padded
-        # position has k = 0 and beta = 0: it writes nothing
+        # position has k = 0 and v = 0: it writes nothing
         t = jnp.swapaxes(t.reshape(b, s, heads, -1), 1, 2)
         return jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0))) if pad else t
 
     with jax.named_scope("gates"):
-        v = heads_first(v, hv)
         beta = jax.nn.sigmoid(heads_first(b_logit, hv).astype(f32))[..., 0]
-        q = _unit(heads_first(q, hk), dk ** -0.5)
-        k = _unit(heads_first(k, hk), 1.0)
         g = -jnp.exp(a_log.astype(f32))[:, None] * jax.nn.softplus(
             heads_first(a_pre, hv)[..., 0].astype(f32)
             + dt_bias.astype(f32)[:, None]
         )
+        if route != "kda":
+            v = heads_first(v, hv)
+            q = _unit(heads_first(q, hk), dk ** -0.5)
+            k = _unit(heads_first(k, hk), 1.0)
+        elif pad:
+            q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0))) for t in qkv)
     with jax.named_scope("prep"):
-        operands = (
-            head_kernel_operands if form == "head_kernels"
-            else head_decay_operands
-        )(q, k, v, g, beta, chunk)
+        if route == "kda":
+            operands = head_kernel_operands(q, k, v, g, beta, chunk, dk)
+        else:
+            operands = head_decay_operands(q, k, v, g, beta, chunk)
     with jax.named_scope("scan"):
         o = chunk_scan(route, *operands)
     return o.reshape(b, hv, s + pad, dv)[:, :, :s]
@@ -2152,9 +2260,9 @@ def _gated_delta_head_decay(attrs: GatedDeltaAttrs, u, weights):
     with jax.named_scope("gates"):
         ba = u @ w_ba
     route = scan_route(attrs.key_dim, attrs.value_dim, attrs.chunk_size)
-    form = operand_form(attrs, route)
+    operand_form(attrs, route, u.shape[1])
     o = jax.checkpoint(
-        functools.partial(_recurrence_head_decay, attrs, route, form),
+        functools.partial(_recurrence_head_decay, attrs, route),
         policy=_KEEP_INVERSE,
     )(qkv, ba[..., hv:], dt_bias, a_log, ba[..., :hv])
     with jax.named_scope("norm"):
@@ -2212,7 +2320,7 @@ def gated_delta_forward(
         f_up = proj[..., cw:cw + rank] @ w_f
         g_up = proj[..., cw + rank:cw + 2 * rank] @ w_g
     route = scan_route(attrs.key_dim, attrs.value_dim, attrs.chunk_size)
-    operand_form(attrs, route)
+    operand_form(attrs, route, u.shape[1])
     o = jax.checkpoint(
         functools.partial(_recurrence, attrs, route),
         policy=_KEEP_INVERSE,

@@ -587,9 +587,12 @@ NODE_PARTS = {
     # the gated delta-rule node (`kernels/kda.py`): the chunk-to-chunk pass,
     # the chunks' operands (decayed scores, the triangular inverse), the
     # gates, the convolution, the gated norm (with one decay a head, `prep`
-    # is `head_kernel_operands` on the "kda" route, kernels that take q, k
-    # NORMALISED and g, and `head_decay_operands` on the "xla" route; `gates`
-    # is what the "xla" route's is on both). With a decay a key channel, on
+    # is `head_kernel_operands` on the "kda" route, kernels that read q, k
+    # and v where the convolution left them and normalise q and k in VMEM
+    # (PR 69), so `gates` holds the `W_ba` matmul, beta and g there and no
+    # copy of q, k or v; on the "xla" route `prep` is `head_decay_operands`
+    # and `gates` also the heads-first copies and the norms). With a decay a
+    # key channel, on
     # the "kda" route the scores'
     # kernels read q, k and the decay's pre-activation in the model's layout
     # and normalise and take the softplus in VMEM (PR 45), so that is `prep`
@@ -834,9 +837,12 @@ def note_delta_rule_operands(form: str) -> None:
 
 def delta_rule_operands() -> Dict[str, str]:
     """`{ff.kda.<name>: form}` of every gated delta-rule node this process
-    has lowered, as it was lowered last: `head_kernels` (one decay a head,
-    the Pallas kernels `gdn_prep_fwd` / `gdn_prep_bwd`), `head_xla` (the same
-    form, `head_decay_operands`), `channel_kernels` (a decay a key channel,
+    has lowered, as it was lowered last: `head_kernels_in_place` (one decay
+    a head, the Pallas kernels `gdn_prep_fwd` / `gdn_prep_bwd` and
+    `kda_corrected_*` reading q, k and v where the convolution left them, a
+    sequence of whole chunks), `head_kernels` (the same kernels on copies
+    padded to the chunk), `head_xla` (the same form, `head_decay_operands`
+    on heads-first copies), `channel_kernels` (a decay a key channel,
     `kda_prep_fwd` / `kda_prep_bwd`) or `xla` (`chunk_operands`), so that a
     run that fell back to XLA's operands says so itself."""
     return dict(_DELTA_RULE_OPERANDS)

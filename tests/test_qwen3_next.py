@@ -6,6 +6,7 @@ Every tolerance states its reason."""
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -194,44 +195,69 @@ def test_scalar_decay_operands_are_the_per_channel_ones_on_a_broadcast_decay():
         np.testing.assert_allclose(got, want, **F32)
 
 
-# One key head of 128 | 128 in chunks of 64 with `group` value heads reading
-# it. 256 positions are four chunks, all in ONE program of the kernels; 192
-# are three, one a program (`_PREP_CHUNKS`), and with one value head an odd
-# number of chunk-heads, which XLA's `unit_lower_inverse` inverts where the
-# inverse's kernel takes them two by two; 100 positions pad to 128 as the
-# node pads them (q = k = 0, beta = 0, a decay all the same). A log-decay of
-# -27 to -30 a position puts exp(G_r - G_j) under float32's least (e^-103.3)
-# four positions apart and exp(-G) of the textbook form over its greatest
-# inside three.
+def heads_first(t, heads):
+    """[b, s, heads * d] as the model has it -> [b, heads, s, d]."""
+    b, s, _ = t.shape
+    return jnp.swapaxes(t.reshape(b, s, heads, -1), 1, 2)
+
+
+def plain_head_operands(q, k, v, g, beta, chunk, key_dim):
+    """The "xla" route on the kernels' inputs: q, k, v turned heads first, q
+    and k over their 2-norms (`kda._unit`), then `head_decay_operands`."""
+    hk, hv = q.shape[-1] // key_dim, g.shape[1]
+    return kda.head_decay_operands(
+        kda._unit(heads_first(q, hk), key_dim ** -0.5),
+        kda._unit(heads_first(k, hk), 1.0), heads_first(v, hv), g, beta, chunk,
+    )
+
+
+# `key_heads` key heads of 128 | 128 in chunks of 64, each read by `group`
+# value heads; q, k and v RAW and in the model's layout, [b, s, heads * 128],
+# as the convolution leaves them: a key head is one 128-lane column block of
+# q and k, a value head one of v (`_HeadPrepBlocks.key_head`,
+# `_CorrectedBlocks.value`). 256 positions are four chunks, all in ONE
+# program of the kernels; 192 are three, one a program (`_PREP_CHUNKS`),
+# with one value head an odd number of chunk-heads, which XLA's
+# `unit_lower_inverse` inverts where the inverse's kernel takes them two by
+# two (v turned heads first for it), and with two value heads an even number
+# that `kda_corrected_*` take one chunk-head a program, a head's chunks being
+# odd; 100 positions pad to 128 as the node pads them (raw q = k = v = 0,
+# beta = 0, a decay all the same). A log-decay of -27 to -30 a position puts
+# exp(G_r - G_j) under float32's least (e^-103.3) four positions apart and
+# exp(-G) of the textbook form over its greatest inside three.
 @pytest.mark.parametrize(
-    "group,seq,decay",
-    [(1, 128, 1.0), (2, 256, 1.0), (4, 128, 1.0), (2, 100, 1.0),
-     (1, 192, 1.0), (2, 128, 30.0)],
+    "key_heads,group,seq,decay",
+    [(1, 1, 128, 1.0), (1, 2, 256, 1.0), (1, 4, 128, 1.0), (1, 2, 100, 1.0),
+     (1, 1, 192, 1.0), (1, 2, 128, 30.0), (3, 2, 128, 1.0), (1, 2, 192, 1.0),
+     (2, 1, 256, 1.0)],
     ids=["one_value_head", "two_value_heads", "four_value_heads",
-         "padded_to_the_chunk", "odd_count_of_chunks", "decay_underflows"],
+         "padded_to_the_chunk", "odd_count_of_chunks", "decay_underflows",
+         "three_key_heads_of_two_value_heads", "odd_chunks_a_head",
+         "two_key_heads_of_one_value_head"],
 )
 def test_scalar_decay_kernels_agree_with_the_xla_operands(
-    monkeypatch, group, seq, decay
+    monkeypatch, key_heads, group, seq, decay
 ):
     """`head_kernel_operands` (the Pallas kernels in interpret mode:
-    `gdn_prep_fwd` and its WRITTEN backward `gdn_prep_bwd`, the triangular
-    inverse) against `head_decay_operands`, differentiated by JAX, at a
-    lane-sized head in float32: the six operands and the cotangents of q, k,
-    v, g and beta. The kernels take every exponent as one sum of log-decays
-    where XLA subtracts two running sums (of up to -1,900 in the last case:
-    1e-4 of an exponent), and sum the value heads' cotangents in another
-    order: measured 1.5e-5 at values of 5, 9e-5 at values of 8 in the last
-    case."""
+    `gdn_prep_fwd` and its WRITTEN backward `gdn_prep_bwd`, which normalise q
+    and k in VMEM, the triangular inverse, `kda_corrected_*` reading v and
+    writing its cotangent in place) against `plain_head_operands`,
+    differentiated by JAX, at lane-sized heads in float32: the six operands
+    and the cotangents of the raw q, k, v in the model's layout and of g and
+    beta. The kernels take every exponent as one sum of log-decays where XLA
+    subtracts two running sums (of up to -1,900 in the last case: 1e-4 of an
+    exponent), and sum the value heads' cotangents in another order: measured
+    1.5e-5 at values of 5, 9e-5 at values of 8 in the underflowing case."""
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     rs = np.random.RandomState(13)
-    b, hk, d, chunk = 1, 1, 128, 64
+    b, hk, d, chunk = 1, key_heads, 128, 64
     hv = hk * group
     padded = seq + -seq % chunk
     c = padded // chunk
     real = (jnp.arange(padded) < seq)[:, None]
-    q = kda._unit(rand(rs, b, hk, padded, d) * real, d ** -0.5)
-    k = kda._unit(rand(rs, b, hk, padded, d) * real, 1.0)
-    v = rand(rs, b, hv, padded, d) * real
+    q = rand(rs, b, padded, hk * d) * real
+    k = rand(rs, b, padded, hk * d) * real
+    v = rand(rs, b, padded, hv * d) * real
     g = -decay * jnp.asarray(0.9 + 0.1 * rs.rand(b, hv, padded), jnp.float32)
     beta = jnp.asarray(rs.rand(b, hv, padded), jnp.float32) * real[:, 0]
     cots = [
@@ -240,7 +266,7 @@ def test_scalar_decay_kernels_agree_with_the_xla_operands(
 
     def run(operands_of):
         def loss(*inputs):
-            operands = operands_of(*inputs, chunk)
+            operands = operands_of(*inputs, chunk, d)
             return sum(
                 jnp.sum(o * cot) for o, cot in zip(operands, cots)
             ), operands
@@ -249,12 +275,16 @@ def test_scalar_decay_kernels_agree_with_the_xla_operands(
             (_, operands), grads = jax.value_and_grad(
                 loss, argnums=(0, 1, 2, 3, 4), has_aux=True
             )(q, k, v, g, beta)
-        return operands, grads
+        # the node's pad drops the padded positions' cotangents of q, k, v
+        # (a zero row's inverse root is 1e3: cotangents of thousands there)
+        return operands, [t[:, :seq] for t in grads[:3]] + list(grads[3:])
 
-    got, want = run(kda.head_kernel_operands), run(kda.head_decay_operands)
+    got, want = run(kda.head_kernel_operands), run(plain_head_operands)
     assert all(
         bool(jnp.all(jnp.isfinite(t))) for t in jax.tree_util.tree_leaves(got)
     )
+    for grad in want[1][:3]:  # the raw q, k and v are reached
+        assert float(jnp.max(jnp.abs(grad))) > 1e-3
     if decay > 20.0:
         p, gamma = got[0][4], got[0][5]
         far = np.tri(chunk, k=-4, dtype=bool)
@@ -416,35 +446,115 @@ def test_the_whole_head_decay_node_on_the_kernels_agrees_with_the_xla_route(
 def test_the_operands_form_is_counted_by_node(monkeypatch):
     """`observability/trace.delta_rule_operands()` names the form each
     delta-rule node was lowered with: the scalar form's kernels where
-    `scan_route` says "kda", `head_decay_operands` under `no_flash()` and on
-    the plain CPU, and the per-channel form's two names likewise."""
+    `scan_route` says "kda", reading q, k and v in place over whole chunks
+    (`gdn0`-`gdn2`, as the cell's three nodes) and padded copies of them over
+    100 positions, `head_decay_operands` under `no_flash()` and on the plain
+    CPU, and the per-channel form's two names likewise."""
     attrs, u, ws, _ = kernel_sized_node(64)
     channel = GatedDeltaAttrs(2, 128, 128, 4, 8, 64, 1e-5)
     monkeypatch.setattr(trace, "_DELTA_RULE_OPERANDS", {})
 
-    def lowered_as(scope, node=attrs):
+    def lowered_as(scope, node=attrs, u=u):
         monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
         if node is attrs:
             jax.eval_shape(lambda u, ws: kda.gated_delta_forward(attrs, u, ws), u, ws)
         else:
-            kda.operand_form(node, kda.scan_route(128, 128, 64))
+            kda.operand_form(node, kda.scan_route(128, 128, 64), 64)
         return trace.delta_rule_operands()[scope]
 
     assert lowered_as("ff.kda.on_the_cpu") == "head_xla"
     assert lowered_as("ff.kda.on_the_cpu", channel) == "xla"
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
-    assert lowered_as("ff.kda.gdn0") == "head_kernels"
+    for name in ("gdn0", "gdn1", "gdn2"):
+        assert lowered_as(f"ff.kda.{name}") == "head_kernels_in_place"
+    assert lowered_as("ff.kda.padded", u=u[:, :36]) == "head_kernels"
     assert lowered_as("ff.kda.kda0", channel) == "channel_kernels"
     with flash.no_flash():
         assert lowered_as("ff.kda.gdn1") == "head_xla"
     assert trace.delta_rule_operands() == {
-        "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "head_kernels",
+        "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "head_kernels_in_place",
+        "ff.kda.gdn2": "head_kernels_in_place", "ff.kda.padded": "head_kernels",
         "ff.kda.kda0": "channel_kernels", "ff.kda.gdn1": "head_xla",
     }
     # a kernel called by itself, under no node's scope, is not counted
     monkeypatch.setattr(trace._lowering, "scope", None)
-    kda.operand_form(attrs, "kda")
-    assert len(trace.delta_rule_operands()) == 4
+    kda.operand_form(attrs, "kda", 64)
+    assert len(trace.delta_rule_operands()) == 6
+
+
+def _main_calls(text):
+    """{value: defining line} and [(callee, [operands])] of the lowered
+    module's `@main`."""
+    main = text[text.index("func.func public @main"):]
+    main = main[:main.index("\n  }\n")]
+    defined, calls = {}, []
+    for line in main.splitlines():
+        m = re.match(r"\s*(%\w+)(?::\d+)? = (.*)", line)
+        if not m:
+            continue
+        defined[m.group(1)] = m.group(2)
+        call = re.match(r"call @(\w+?)(?:_\d+)?\((.*?)\) :", m.group(2))
+        if call:
+            calls.append((call.group(1), m.group(1), call.group(2).split(", ")))
+    return defined, calls
+
+
+def test_the_lowered_node_hands_the_pieces_from_kernel_to_kernel(monkeypatch):
+    """The node lowered for the TPU, forward and backward, at whole chunks:
+    q and k reach `gdn_prep_fwd` (forward and recomputed) and `gdn_prep_bwd`,
+    and v `kda_corrected_fwd` / `kda_corrected_bwd`, as the very results of
+    `conv_silu_fwd` (through the checkpoint's `optimization_barrier` and
+    nothing else), and `conv_silu_bwd` takes dq, dk and dv as the very
+    results of `gdn_prep_bwd` and `kda_corrected_bwd`: no `transpose`,
+    `reshape`, `convert` or `pad` of q, k, v or their cotangents lies
+    between the convolution's and the recurrence's kernels (a Pallas operand
+    must be a buffer: XLA writes out whatever lies between)."""
+    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    attrs, u, ws, cot = kernel_sized_node(128)
+
+    def node(u, ws, cot):
+        y, vjp = jax.vjp(
+            lambda u, ws: kda.gated_delta_forward(attrs, u, ws), u, ws
+        )
+        return y, vjp(cot)
+
+    text = jax.jit(node).trace(u, ws, cot).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    defined, calls = _main_calls(text)
+
+    def source(value):
+        """(the call that made `value`, which of its results), through the
+        checkpoint's barriers."""
+        name, _, index = value.partition("#")
+        made = defined[name]
+        if made.startswith("stablehlo.optimization_barrier"):
+            operands = made[len("stablehlo.optimization_barrier "):]
+            return source(operands.split(" : ")[0].split(", ")[int(index or 0)])
+        callee = re.match(r"call @(\w+?)(?:_\d+)?\(", made)
+        return (callee.group(1) if callee else made.split(" ")[0]), int(index or 0)
+
+    took = {"_head_prep_forward": [], "_corrected_forward": []}
+    for callee, result, operands in calls:
+        if callee in ("_head_prep_forward", "_head_prep_backward"):
+            assert [source(o) for o in operands[:2]] == [
+                ("_conv_forward", 0), ("_conv_forward", 1)
+            ], (callee, operands)
+        if callee in ("_corrected_forward", "_corrected_backward"):
+            assert source(operands[3]) == ("_conv_forward", 2), (callee, operands)
+        if callee in took:
+            took[callee].append(result)
+        if callee == "_conv_backward":
+            assert [source(o) for o in operands[2:5]] == [
+                ("_head_prep_backward", 0), ("_head_prep_backward", 1),
+                ("_corrected_backward", 3),
+            ], operands
+    # the forward and the checkpoint's recomputation of it, one backward each
+    assert [len(v) for v in took.values()] == [2, 2]
+    names = [callee for callee, _, _ in calls]
+    for once in ("_conv_forward", "_conv_backward", "_head_prep_backward",
+                 "_corrected_backward"):
+        assert names.count(once) == 1, names
 
 
 def test_the_triangular_products_form_is_counted_by_node(monkeypatch):
@@ -487,7 +597,9 @@ def test_the_triangular_products_form_is_counted_by_node(monkeypatch):
         )
     ]
     assert lowered_as("ff.kda.gdn2", odd, odd_ws, odd_u) == "xla"
-    assert trace.delta_rule_operands()["ff.kda.gdn2"] == "head_kernels"
+    # q and k still go to `gdn_prep_*` where they lie; v is turned heads
+    # first for XLA's form alone
+    assert trace.delta_rule_operands()["ff.kda.gdn2"] == "head_kernels_in_place"
     assert trace.triangular_products() == {
         "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "kernels",
         "ff.kda.kda0": "kernels", "ff.kda.gdn1": "xla", "ff.kda.kda1": "xla",

@@ -151,6 +151,11 @@ pinned to the CPU, skipped only where the TPU's library is not installed.
     python tests/test_ssm_node_compiles_for_v5e.py twotower   # (or super, kimi, qwen3next) the node's ENTRY
         instructions of 4 MB or more, in schedule order, with operand and result
         bytes (7 s; `--root <checkout>` lists another checkout's node)
+    python tests/test_ssm_node_compiles_for_v5e.py qwen3next_account [--root <checkout>]
+        # that node's compiled program by part and phase: MB made, read, copied
+        # (PR 69: parent 5,350 made / 1,363 copied, change 4,063 / 280)
+    python tests/test_ssm_node_compiles_for_v5e.py qwen3next_step [--root <checkout>]
+        # the cell's WHOLE step: `step_hbm_gb` and its account (65 s)
 """
 
 import functools
@@ -447,15 +452,16 @@ def compiled_kda_node():
     return jax.jit(node).lower(u, weights, u).compile().as_text()
 
 
-def _corrected_kernels_limit(chunk_heads):
+def _corrected_kernels_limit(chunk_heads, in_place=None):
     """"ok" where the limit `kda_corrected_fwd` / `kda_corrected_bwd` carry
     over `chunk_heads` chunks of 64 at heads of 128 | 128 in a bf16 step is
     within the chip's default scope (the node's compile is Mosaic's of both
-    under that limit), or the bytes they state."""
+    under that limit), or the bytes they state; `in_place` = (heads, chunks
+    a head) where they read v in the model's layout."""
     from flexflow_tpu.kernels import kda
 
     limit = kda._CorrectedBlocks(
-        chunk_heads, 64, 128, 128, 2
+        chunk_heads, 64, 128, 128, 2, in_place
     ).params.vmem_limit_bytes
     return "ok" if limit <= V5E_SCOPED_VMEM else f"{limit} bytes stated"
 
@@ -867,6 +873,7 @@ QWEN3NEXT_INVARIANTS = [
     "triangular_product_kernels_compile_under_the_vmem_limit_they_state",
     "head_decay_gated_norm_is_two_kernels_and_no_float32_of_the_rows_width",
     "head_decay_convolution_is_two_kernels_on_the_projections_row",
+    "head_decay_recurrence_reads_the_convolutions_pieces_where_they_lie",
 ]
 # the chip's default for a kernel's scoped VMEM
 V5E_SCOPED_VMEM = 16 * 1024 * 1024
@@ -994,8 +1001,9 @@ def check_qwen3next():
         found[QWEN3NEXT_INVARIANTS[5]] = (
             "ok" if limit <= V5E_SCOPED_VMEM else f"{limit} bytes stated"
         )
+        chunks = QWEN3NEXT_SHAPE[1] // attrs.chunk_size
         found[QWEN3NEXT_INVARIANTS[6]] = _corrected_kernels_limit(
-            attrs.num_heads * QWEN3NEXT_SHAPE[1] // attrs.chunk_size
+            attrs.num_heads * chunks, in_place=(attrs.num_heads, chunks)
         )
         found[QWEN3NEXT_INVARIANTS[7]] = _gated_norm_part(
             text, QWEN3NEXT_SHAPE[1] * attrs.num_heads * attrs.value_dim
@@ -1003,6 +1011,22 @@ def check_qwen3next():
         found[QWEN3NEXT_INVARIANTS[8]] = _conv_part(
             text, QWEN3NEXT_SHAPE[1], attrs.conv_width
         )
+        # since PR 69 `gdn_prep_*` and `kda_corrected_*` read q, k and v as
+        # column blocks of the convolution's pieces and write dq, dk, dv the
+        # same way: what `gates` still makes at the size of q ([8192, 2048]
+        # bf16) is the `W_ba` matmul's cotangent of u; no heads-first copy,
+        # no float32 pass of `_unit`
+        glue = [
+            f"{name} {line.split('op_name="')[1].split('"')[0].split('/')[-1]}"
+            f" {result[:50]}"
+            for name, result, opcode, _, line in entry_instructions(text)
+            if opcode not in _NO_BUFFER and "/gates/" in line
+            and _nbytes(result) >= 2 * QWEN3NEXT_SHAPE[1] * attrs.key_width
+            and not line.split('op_name="')[1].startswith(
+                "jit(node)/transpose(jvp(ff.kda.gdn0))/gates/dot_general"
+            )
+        ]
+        found[QWEN3NEXT_INVARIANTS[9]] = "ok" if not glue else ", ".join(glue)
         per_position = [
             f"{name}: {result[:60]}"
             for name, result, opcode, _, _ in entry_instructions(text)
@@ -1596,6 +1620,35 @@ def cell_step_bytes(cell, root, **overrides):
     }
 
 
+def gdn_node_account():
+    """The Qwen3-Next delta-rule node's compiled program booked by part and
+    phase (`observability/step_account.account_of_text`), MB made and read
+    and the `copy` family's share of what is made: `qwen3next_account
+    [--root <the parent's checkout>]` prints one side of the comparison."""
+    from flexflow_tpu.observability.step_account import account_of_text
+
+    rows = {}
+    for row in account_of_text(compiled_gdn_node()[1])["rows"]:
+        part = row["name"].partition("/")[2] or "(projections)"
+        key = f"{part} {row['phase']}" if row["kind"] == "kda" else row["phase"]
+        to = rows.setdefault(key, {"instructions": 0, "made_mb": 0.0,
+                                   "read_mb": 0.0, "copies_made_mb": 0.0})
+        to["instructions"] += row["instructions"]
+        to["made_mb"] += row["written_bytes"] / 1e6
+        to["read_mb"] += row["read_bytes"] / 1e6
+        to["copies_made_mb"] += sum(
+            f["written_bytes"] for name, f in row["families"].items()
+            if name.startswith("copy")
+        ) / 1e6
+    total = {k: round(sum(r[k] for r in rows.values()), 1)
+             for k in ("instructions", "made_mb", "read_mb", "copies_made_mb")}
+    return {
+        "rows": {k: {n: round(v, 1) for n, v in r.items()}
+                 for k, r in sorted(rows.items())},
+        "total": total,
+    }
+
+
 def listing(name, least=4e6):
     """The node's ENTRY instructions that move `least` bytes or more."""
     if name == "kimi":
@@ -1731,6 +1784,10 @@ if __name__ == "__main__":
         print(json.dumps(cell_step_bytes("ouro26b_s8192_1chip", root, **cut)))
     elif argv and argv[0] == "granite_step":
         print(json.dumps(cell_step_bytes("granite4hmicro_s4096_1chip", root)))
+    elif argv and argv[0] == "qwen3next_account":
+        print(json.dumps(gdn_node_account()))
+    elif argv and argv[0] == "qwen3next_step":
+        print(json.dumps(cell_step_bytes("qwen3next80b_s8192_1chip", root)))
     elif argv and argv[0] == "granite":
         print(json.dumps(check_granite()))
     elif argv and argv[0] == "mellum2":
