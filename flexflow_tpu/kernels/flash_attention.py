@@ -17,11 +17,9 @@ preferred_element_type=f32 so bf16 inputs still accumulate in f32 on the MXU.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import functools
 import math
-import threading
 from typing import Optional, Tuple
 
 import jax
@@ -29,78 +27,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from flexflow_tpu.kernels import context
+
 NEG_INF = -1e30
-
-_tls = threading.local()
-
-
-@contextlib.contextmanager
-def flash_mesh(mesh, batch_axes, head_axes, interpret: bool = False):
-    """Declare the SPMD context for attention kernels traced within: the
-    mesh plus the PartitionSpec entries of the attention node's batch and
-    head dims. _mha_forward consults this to map its kernels over the shards
-    (shard_map) instead of emitting a bare (unpartitionable) pallas_call:
-    the one-chip fused-row dispatch per batch shard when heads are whole
-    (`head_axes is None`), sharded_flash_attention on [b, h, s, d] when they
-    are split."""
-    prev = getattr(_tls, "mesh_ctx", None)
-    _tls.mesh_ctx = (mesh, batch_axes, head_axes, interpret)
-    try:
-        yield
-    finally:
-        _tls.mesh_ctx = prev
-
-
-def current_flash_mesh():
-    return getattr(_tls, "mesh_ctx", None)
-
-
-def interpret_default() -> bool:
-    """Pallas interpret mode: only for CPU-mesh tests, opted in via env."""
-    import os
-
-    return (
-        jax.default_backend() == "cpu"
-        and os.environ.get("FLEXFLOW_TPU_FLASH_INTERPRET", "0") == "1"
-    )
-
-
-@contextlib.contextmanager
-def no_flash():
-    """Refuse a bare pallas_call within this trace (used by the distributed
-    executor: a pallas_call has no SPMD partitioning rule). What admits a
-    kernel to a sharded global-view program is a declared `flash_mesh`,
-    under which the kernel is mapped over the shards; a node lowered with
-    none declared keeps XLA's dense attention."""
-    prev = getattr(_tls, "disabled", False)
-    _tls.disabled = True
-    try:
-        yield
-    finally:
-        _tls.disabled = prev
-
 
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
-def _f32_probs() -> bool:
-    """FLEXFLOW_TPU_FLASH_F32_PROBS=1 keeps softmax probabilities (and the
-    fused-SCCE gradient, see kernels/loss.py) in f32 for accuracy-sensitive
-    runs, trading back the ~0.4% relative error the default bf16
-    probabilities inject into bf16 training. Read at trace time."""
-    import os
-
-    return os.environ.get("FLEXFLOW_TPU_FLASH_F32_PROBS", "0") == "1"
-
-
 def _exp2_probs(z, in_dtype):
     """exp2 of normalized (<= 0) f32 scores. bf16 kernel inputs compute
     bf16 probabilities — they feed a bf16 matmul anyway and the exp is the
-    kernel's VPU bottleneck; ~0.4% relative error on values in (0, 1] —
-    unless _f32_probs() opts the run out. Accumulators stay f32 either way."""
-    if in_dtype == jnp.bfloat16 and not _f32_probs():
+    kernel's VPU bottleneck; ~0.4% relative error on values in (0, 1].
+    Accumulators stay f32 either way."""
+    if in_dtype == jnp.bfloat16:
         return jnp.exp2(z.astype(jnp.bfloat16))
     return jnp.exp2(z)
 
@@ -1079,7 +1020,7 @@ def _bwd_pair_core(
         vb = v_ref[:, :, sl]
         do = do_ref[:, :, sl]
         lse = lse_ref[:, h2, 0, :]
-        if _f32_probs() or do_ref.dtype == jnp.float32:
+        if do_ref.dtype == jnp.float32:
             prod = do.astype(jnp.float32) * o_ref[:, :, sl].astype(jnp.float32)
         else:
             prod = do * o_ref[:, :, sl]
@@ -1154,12 +1095,11 @@ def _bwd_fused_kernel_pair_qkv(
 
 def _delta_kernel(do_ref, o_ref, delta_ref):
     # do/o: [bb, s, d] per-head slices; delta: [bb, 1, s]. Product in the
-    # storage dtype, accumulation in f32 (same policy as _exp2_probs;
-    # FLEXFLOW_TPU_FLASH_F32_PROBS=1 restores the f32 product). The
+    # storage dtype, accumulation in f32 (same policy as _exp2_probs). The
     # rowsum runs as an MXU contraction against a ones vector — cross-LANE
     # reductions on the VPU dominated this kernel.
     d = do_ref.shape[-1]
-    if _f32_probs() or do_ref.dtype == jnp.float32:
+    if do_ref.dtype == jnp.float32:
         prod = do_ref[:].astype(jnp.float32) * o_ref[:].astype(jnp.float32)
     else:
         prod = do_ref[:] * o_ref[:]
@@ -1622,13 +1562,6 @@ def _flash_shape_ok(shape: Tuple[int, ...], min_seq: int) -> bool:
     return b >= 1 and h >= 1 and s % 128 == 0 and s >= min_seq and d % 8 == 0
 
 
-def _backend_ok(allow_interpret: bool = False) -> bool:
-    # a backend that fails to initialise raises here: selecting dense
-    # attention instead would hide the device from the run
-    backend = jax.default_backend()
-    return backend == "tpu" or (allow_interpret and backend == "cpu")
-
-
 def flash_attention_supported(
     q_shape: Tuple[int, ...], k_shape, v_shape, min_seq: int = None
 ) -> bool:
@@ -1637,9 +1570,7 @@ def flash_attention_supported(
     XLA's dense attention, which keeps the [b, h, s, s] probabilities in
     HBM for the backward (`min_seq_for`; _min_seq_default for a caller that
     names no route)."""
-    if getattr(_tls, "disabled", False):
-        return False
-    if not _backend_ok():
+    if context.bare_calls_refused() or not context.on_tpu():
         return False
     if len(q_shape) != 4:
         return False
@@ -1681,7 +1612,7 @@ def sharded_flash_supported(
     over `batch_axes` and heads over `head_axes`? Gates on the LOCAL block
     shape each device will see (SURVEY.md §7 hard-part 4: pallas_call has no
     SPMD partitioning rule, so the kernel must be mapped per-shard)."""
-    if not _backend_ok(allow_interpret=interpret):
+    if not context.on_tpu(allow_interpret=interpret):
         return False
     if len(q_shape) != 4:
         return False
@@ -1700,7 +1631,7 @@ def flash_core_supported(q_shape, k_shape, v_shape, family=None) -> bool:
     would be emitted into: flash_attention_supported on the [b, h, s, d]
     shapes, or under a `flash_mesh` on the block each device sees."""
     least = min_seq_for(family)
-    ctx = current_flash_mesh()
+    ctx = context.declared_mesh()
     if ctx is None:
         return flash_attention_supported(q_shape, k_shape, v_shape, least)
     mesh, batch_axes, head_axes, interpret = ctx
@@ -1716,7 +1647,7 @@ def per_batch_shard(entry, *rows, **kwargs):
     the mesh's interpret flag, so that each device runs the one-chip kernel
     on its own sequences. Attention is independent over the batch: the body
     needs no collective."""
-    ctx = current_flash_mesh()
+    ctx = context.declared_mesh()
     if ctx is None:
         return entry(*rows, **kwargs)
     from jax.sharding import PartitionSpec as P

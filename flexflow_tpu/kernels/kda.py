@@ -42,7 +42,7 @@ inverse of the diagonal blocks so far and L the level's off-diagonal part,
 X <- X - X L X is exact block elimination, two [Q, Q] products a level.
 
 Two forms of the recurrence exist, and `scan_route` picks one from what the
-trace can observe (shapes, backend, `no_flash`, `flash_mesh`); the ONE route
+trace can observe (shapes and the facts of `kernels/context.py`); the ONE route
 decides the chunks' operands and the chunk-to-chunk pass alike:
 
 - **The Pallas kernels** ("kda"): on a TPU at heads whose key and value sizes
@@ -85,7 +85,7 @@ decides the chunks' operands and the chunk-to-chunk pass alike:
   so does the whole of `_corrected` with `unit_lower_inverse` where the
   kernels do not take the call: an odd number of chunk-heads (the
   inverse's kernel takes them two by two) and everything on the "xla"
-  route. `observability/trace.triangular_products()` says which, by node.
+  route. `trace.kernel_choices("triangular_products")` says which, by node.
   *The pass* (`kda_fwd_chunk`, `kda_states_chunk`, `kda_bwd_chunk`): one
   program is one (batch row, head, chunk); the chunk axis is sequential and
   the head's [dv, dk] float32 state (held transposed, so that the
@@ -157,7 +157,7 @@ comes back is what `chunk_operands` returns, exp(G_Q) written over the dk
 lanes, so `chunk_scan` and its kernels take it as they are. Which form a node
 took is `operand_form`'s answer (the attrs' `decay`, `scan_route`'s route and
 whether the sequence is whole chunks, nothing else), counted by node in
-`observability/trace.delta_rule_operands()`.
+`trace.kernel_choices("delta_rule_operands")`.
 
 **The gated norm** (`_gated_head_norm`, the node's last part before W_out):
 the rms norm of each head's dv features of the recurrence's o under a gate,
@@ -175,7 +175,7 @@ is the operands (o, z or g_up and b_g, the gain), alive anyway; no float32
 of the rows' width and no root crosses HBM, and no forward is run again. On
 the "xla" route it is the plain `_head_norm_silu` / `_head_norm_gate` under a
 checkpoint of its own, differentiated by JAX: what the kernels are tested
-against. `observability/trace.head_norms()` says which, by node.
+against. `trace.kernel_choices("head_norms")` says which, by node.
 
 The node's parts go under scopes of their own inside the node's
 (`ff.kda.<name>/scan`, `/prep`, `/gates`, `/conv`, `/norm`;
@@ -191,7 +191,7 @@ on the "xla" route o's copy to the model's layout with XLA's fusions of the
 plain form, forward, recomputed and backward. `conv` is
 `kernels/ssm.conv_silu` on both, and it chooses its own form
 (`ssm.conv_route`, from the widths, the sequence and the trace;
-`observability/trace.conv_forms()`): since PR 59 the kernels `conv_silu_fwd`
+`trace.kernel_choices("conv_forms")`): since PR 59 the kernels `conv_silu_fwd`
 / `conv_silu_bwd`, which read the q | k | v columns in place out of the input
 projection's row (the node hands it the row, not a slice), wherever the
 "kda" route runs and the sequence divides into their blocks, else its plain
@@ -212,7 +212,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flexflow_tpu.kernels.ssm import _eight_apart, _interpret, conv_silu
+from flexflow_tpu.kernels import context
+from flexflow_tpu.kernels.ssm import _eight_apart, conv_silu
 from flexflow_tpu.op_attrs.ops.kda import GatedDeltaAttrs
 
 # added under the root of a head's sum of squares before q and k are
@@ -664,7 +665,7 @@ def chunk_scan(route, qd, w, uv, ke, p, gamma):
     "xla", `scan_route`). What the backward keeps is these operands."""
     if route == "xla":
         return _xla_forward(qd, w, uv, ke, p, gamma, False)
-    return _pallas_forward(qd, w, uv, ke, p, gamma, _interpret())
+    return _pallas_forward(qd, w, uv, ke, p, gamma, context.interpret_default())
 
 
 def _chunk_scan_fwd(route, *operands):
@@ -675,8 +676,8 @@ def _chunk_scan_bwd(route, operands, do):
     if route == "xla":
         states = _xla_forward(*operands, True)
         return _xla_backward(*operands, states, do)
-    states = _pallas_forward(*operands, _interpret(), True)
-    return _pallas_backward(*operands, states, do, _interpret())
+    states = _pallas_forward(*operands, context.interpret_default(), True)
+    return _pallas_backward(*operands, states, do, context.interpret_default())
 
 
 chunk_scan.defvjp(_chunk_scan_fwd, _chunk_scan_bwd)
@@ -1060,7 +1061,7 @@ def chunk_scores(qkv, f_up, dt_bias, a_log, chunk: int):
     results): the kernels read the node's RAW inputs in the model's layout
     and normalise q and k and take the softplus in VMEM. What the backward
     keeps is those inputs: it recomputes the gates and every decay."""
-    return _prep_forward(qkv, f_up, dt_bias, a_log, chunk, _interpret())
+    return _prep_forward(qkv, f_up, dt_bias, a_log, chunk, context.interpret_default())
 
 
 def _chunk_scores_fwd(qkv, f_up, dt_bias, a_log, chunk):
@@ -1070,7 +1071,7 @@ def _chunk_scores_fwd(qkv, f_up, dt_bias, a_log, chunk):
 
 
 def _chunk_scores_bwd(chunk, kept, cotangents):
-    return _prep_backward(*kept, cotangents, chunk, _interpret())
+    return _prep_backward(*kept, cotangents, chunk, context.interpret_default())
 
 
 chunk_scores.defvjp(_chunk_scores_fwd, _chunk_scores_bwd)
@@ -1404,12 +1405,13 @@ def corrected_products(n, beta, kd, v):
 
 
 def _corrected_products_fwd(n, beta, kd, v):
-    x = checkpoint_name(_pallas_inverse(n, _interpret()), _KEPT)
-    return _corrected_forward(x, beta, kd, v, _interpret()), (x, beta, kd, v)
+    x = checkpoint_name(_pallas_inverse(n, context.interpret_default()), _KEPT)
+    kept = (x, beta, kd, v)
+    return _corrected_forward(*kept, context.interpret_default()), kept
 
 
 def _corrected_products_bwd(kept, cotangents):
-    return _corrected_backward(*kept, *cotangents, _interpret())
+    return _corrected_backward(*kept, *cotangents, context.interpret_default())
 
 
 corrected_products.defvjp(_corrected_products_fwd, _corrected_products_bwd)
@@ -1418,19 +1420,20 @@ corrected_products.defvjp(_corrected_products_fwd, _corrected_products_bwd)
 def _kernel_corrected(a, kd, v, beta5):
     """`_corrected` on the "kda" route, `dtype` v's: the kernels where they
     take the number of chunk-heads (an even one), XLA's form with
-    `unit_lower_inverse` otherwise; which, told to the program's counter
-    (`observability/trace.triangular_products`). v is [b, h, c, Q, dv], or
+    `unit_lower_inverse` otherwise; which, noted as the node's
+    `triangular_products` (`kernels/context.note`): `kernels` (T (K exp(G)),
+    T V and the triangular system's whole backward from `kda_corrected_fwd` /
+    `kda_corrected_bwd`) or `xla` (`_corrected`, differentiated by JAX: the
+    "xla" route, and an odd number of chunk-heads here). v is [b, h, c, Q, dv], or
     [b, s, h * dv] as the model has it, which the kernels read in place and
     only XLA's form turns heads first."""
-    from flexflow_tpu.observability import trace
-
     if (beta5.size // beta5.shape[-1]) % 2:
-        trace.note_triangular_products("xla")
+        context.note("triangular_products", "xla")
         if v.ndim != kd.ndim:
             b, h, c, q = beta5.shape
             v = jnp.transpose(v.reshape(b, c, q, h, -1), (0, 3, 1, 2, 4))
         return _corrected(a, kd, v, beta5, v.dtype)
-    trace.note_triangular_products("kernels")
+    context.note("triangular_products", "kernels")
     return corrected_products(beta5[..., :, None] * a, beta5, kd, v)
 
 
@@ -1753,7 +1756,7 @@ def head_chunk_scores(q, k, g, chunk: int, dk: int):
     model's layout, and normalise them in VMEM. What the backward keeps is
     those inputs (what the node's checkpoint holds anyway): it recomputes
     the norms, the products and every decay."""
-    return _head_prep_forward(q, k, g, chunk, dk, _interpret())
+    return _head_prep_forward(q, k, g, chunk, dk, context.interpret_default())
 
 
 def _head_chunk_scores_fwd(q, k, g, chunk, dk):
@@ -1761,7 +1764,9 @@ def _head_chunk_scores_fwd(q, k, g, chunk, dk):
 
 
 def _head_chunk_scores_bwd(chunk, dk, kept, cotangents):
-    return _head_prep_backward(*kept, cotangents, chunk, dk, _interpret())
+    return _head_prep_backward(
+        *kept, cotangents, chunk, dk, context.interpret_default()
+    )
 
 
 head_chunk_scores.defvjp(_head_chunk_scores_fwd, _head_chunk_scores_bwd)
@@ -1785,35 +1790,32 @@ def scan_route(key_dim: int, value_dim: int, chunk: int) -> str:
     `chunk_scan` alike, from what the trace can observe:
 
     - "kda": the Pallas kernels, where the backend is a TPU (or the CPU with
-      interpret mode opted in, `interpret_default`), a head's key and value
-      sizes are multiples of 128 lanes (the published 128 / 128) and its
-      chunk of whole sublane tiles, and the trace admits a bare Pallas call
-      (not under `no_flash()`, no declared `flash_mesh`: a sharded form as
+      interpret mode opted in, `context.interpret_default`), a head's key
+      and value sizes are multiples of 128 lanes (the published 128 / 128)
+      and its chunk of whole sublane tiles, and the trace admits a bare
+      Pallas call (`context.admits_bare_pallas_call`: a sharded form as
       `kernels/ssm` has is not written yet, ROADMAP Reach (5));
     - "xla": everything else, the scan over the chunks."""
-    from flexflow_tpu.kernels import flash_attention as flash
-
     if key_dim % _LANES or value_dim % _LANES or chunk % 16:
         return "xla"
-    if flash.current_flash_mesh() is not None:
-        return "xla"
-    if getattr(flash._tls, "disabled", False):
-        return "xla"
-    return "kda" if flash._backend_ok(flash.interpret_default()) else "xla"
+    admitted = context.admits_bare_pallas_call(context.interpret_default())
+    return "kda" if admitted else "xla"
 
 
 def operand_form(attrs: GatedDeltaAttrs, route: str, seq: int) -> str:
     """Which form the chunks' operands of a node take on `route`
-    (`scan_route`'s answer) over `seq` positions, told to the program's
-    counter as well (`observability/trace.delta_rule_operands`): the attrs'
-    own `decay` names the set of kernels, and with one decay a head the
-    sequence says whether they read q, k and v where the convolution left
-    them (`head_kernels_in_place`: whole chunks) or padded copies of them
-    (`head_kernels`); nothing else chooses. The "xla" route also settles the
-    products around the triangular inverse
-    (`observability/trace.triangular_products`)."""
-    from flexflow_tpu.observability import trace
-
+    (`scan_route`'s answer) over `seq` positions, noted as the node's
+    `delta_rule_operands` as well (`kernels/context.note`): the attrs' own
+    `decay` names the set of kernels, and with one decay a head the sequence
+    says whether they read q, k and v where the convolution left them
+    (`head_kernels_in_place`: `gdn_prep_fwd` / `gdn_prep_bwd` and
+    `kda_corrected_*` on whole chunks) or padded copies of them
+    (`head_kernels`), `head_xla` being the same form from
+    `head_decay_operands` on heads-first copies; with a decay a key channel
+    `channel_kernels` (`kda_prep_fwd` / `kda_prep_bwd`) or `xla`
+    (`chunk_operands`); nothing else chooses. The "xla" route also settles
+    the products around the triangular inverse (`triangular_products`,
+    `_kernel_corrected`)."""
     if attrs.per_head_decay and route == "kda":
         padded = seq % attrs.chunk_size
         form = "head_kernels" if padded else "head_kernels_in_place"
@@ -1821,11 +1823,11 @@ def operand_form(attrs: GatedDeltaAttrs, route: str, seq: int) -> str:
         form = "head_xla"
     else:
         form = "channel_kernels" if route == "kda" else "xla"
-    trace.note_delta_rule_operands(form)
+    context.note("delta_rule_operands", form)
     if route != "kda":
         # on the "kda" route the number of chunk-heads chooses
         # (`_kernel_corrected`)
-        trace.note_triangular_products("xla")
+        context.note("triangular_products", "xla")
     return form
 
 
@@ -2109,7 +2111,7 @@ def head_norm_gate(o, x, bias, gain, eps: float, first: int = 0):
     the kernels `head_norm_gate_fwd` and, its WRITTEN backward,
     `head_norm_gate_bwd` (the section's comment). What the backward keeps is
     the operands; x's other columns get a zero cotangent."""
-    return _norm_forward(o, x, bias, gain, eps, first, _interpret())
+    return _norm_forward(o, x, bias, gain, eps, first, context.interpret_default())
 
 
 def _head_norm_gate_vjp_fwd(o, x, bias, gain, eps, first):
@@ -2117,7 +2119,7 @@ def _head_norm_gate_vjp_fwd(o, x, bias, gain, eps, first):
 
 
 def _head_norm_gate_vjp_bwd(eps, first, kept, dy):
-    return _norm_backward(*kept, dy, eps, first, _interpret())
+    return _norm_backward(*kept, dy, eps, first, context.interpret_default())
 
 
 head_norm_gate.defvjp(_head_norm_gate_vjp_fwd, _head_norm_gate_vjp_bwd)
@@ -2285,14 +2287,13 @@ def _gated_head_norm(attrs: GatedDeltaAttrs, route: str, o, x, bias, gain,
     of the recurrence's o [b, h, s, dv] under the gate of x's h * dv columns
     from `first` on (their silu where `bias` is None: one decay a head; else
     the sigmoid of them + bias), in the form the node's `route` says
-    (`scan_route`, nothing else chooses), told to the program's counter
-    (`observability/trace.head_norms`): the kernels (`head_norm_gate`) on
-    "kda", else the plain form on o in the model's layout under a
-    checkpoint of its own."""
-    from flexflow_tpu.observability import trace
-
+    (`scan_route`, nothing else chooses), noted as the node's `head_norms`
+    (`kernels/context.note`): `kernels` (`head_norm_gate`: the norm under its
+    gate and the whole of its backward from `head_norm_gate_fwd` /
+    `head_norm_gate_bwd`) on "kda", else `xla`, the plain form on o in the
+    model's layout, differentiated by JAX under a checkpoint of its own."""
     heads, eps = attrs.num_heads, attrs.norm_eps
-    trace.note_head_norm("kernels" if route == "kda" else "xla")
+    context.note("head_norms", "kernels" if route == "kda" else "xla")
     if route == "kda":
         return head_norm_gate(o, x, bias, gain, eps, first)
     b, _, s, dv = o.shape

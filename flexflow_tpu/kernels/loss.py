@@ -60,14 +60,8 @@ def _probs_minus_onehot(logit, label, lse):
     head, ~12 ms/step of pure HBM traffic) that the weight-grad matmuls
     then re-read. The normalized scores are exact in f32 up to the cast;
     p in bf16 has ~0.4% relative error on a value in (0, 1], far below
-    gradient noise. FLEXFLOW_TPU_FLASH_F32_PROBS=1 (the same knob as the
-    flash kernels') restores the f32 computation for accuracy-sensitive
-    runs, paying the HBM traffic back. A negative label matches no class."""
-    from flexflow_tpu.kernels.flash_attention import _f32_probs
-
-    z = logit.astype(jnp.float32) - lse[..., None]
-    if not _f32_probs():
-        z = z.astype(logit.dtype)
+    gradient noise. A negative label matches no class."""
+    z = (logit.astype(jnp.float32) - lse[..., None]).astype(logit.dtype)
     p = jnp.exp(z)
     onehot = (
         jax.lax.broadcasted_iota(jnp.int32, logit.shape, logit.ndim - 1)

@@ -66,6 +66,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.op_attrs.ops.moe import (
     AggregateAttrs,
     ExpertsAttrs,
@@ -242,16 +243,8 @@ def _pallas_allowed(per_shard: bool) -> bool:
     """On the chip, and in a per-device program: the body of a shard_map, or
     a trace that is no global-view SPMD program (the data-parallel backend's
     jit, the searched executor's), where a Pallas call has no partitioning
-    rule. The signals the flash attention path reads."""
-    from flexflow_tpu.kernels import flash_attention as flash
-
-    return flash._backend_ok() and (
-        per_shard
-        or (
-            flash.current_flash_mesh() is None
-            and not getattr(flash._tls, "disabled", False)
-        )
-    )
+    rule (`kernels/context.admits_bare_pallas_call`)."""
+    return context.on_tpu() if per_shard else context.admits_bare_pallas_call()
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -317,7 +310,7 @@ def _grouped_matmul(rows, w, group_sizes, pallas: bool):
     tiles = _gmm_tiles(rows.shape[0], *w.shape[1:], w.shape[0]) if pallas else None
     if tiles is not None:
         offset = jnp.zeros((), jnp.int32) if rest else None
-        return _gmm(rows, w, group_sizes, offset, tiles, _interpret())
+        return _gmm(rows, w, group_sizes, offset, tiles, context.interpret_default())
     if rest:
         w = jnp.concatenate([w, jnp.zeros((1,) + w.shape[1:], w.dtype)])
     return lax.ragged_dot(
@@ -326,13 +319,18 @@ def _grouped_matmul(rows, w, group_sizes, pallas: bool):
 
 
 def _note_tiles(matrices, m: int, pallas: bool) -> None:
-    """Tell the trace which tiles the grouped matmuls of the expert node
-    being lowered take (`observability/trace.grouped_matmul_tiles`): asked
+    """Note which tiles the grouped matmuls of the expert node being
+    lowered take, its `grouped_matmul_tiles` (`kernels/context.note`): asked
     of `_gmm_tiles` as `_grouped_matmul` asks, here because the window
     functions are jitted and traced once for every node of one shape.
-    `matrices`: name -> [G, K, N] (`w1`, `w3`, `w2`), `m`: rows a call."""
-    from flexflow_tpu.observability import trace
-
+    `matrices`: name -> [G, K, N] (`w1`, `w3`, `w2`), `m`: rows a call.
+    The value is `{"<matrix>/<call>": entry}`: for each of the node's
+    matrices and each of a grouped matmul's three calls (`forward`,
+    `input_gradient`, `weight_gradient`) the call's `shape` (rows,
+    contraction, columns, in the kernel's own names), the `tile` it was
+    given and `padded_over_true`, the contraction and column sides in whole
+    tiles over their true size (the row side is the data's). A node on XLA's
+    `ragged_dot` notes nothing."""
     entries = {}
     for name, w in matrices.items():
         groups, k, n = w.shape
@@ -349,20 +347,28 @@ def _note_tiles(matrices, m: int, pallas: bool) -> None:
                 ),
             }
     if entries:
-        trace.note_grouped_matmul_tiles(entries)
+        context.note("grouped_matmul_tiles", entries)
 
 
 def _note_held_sums(pallas: bool, attrs, held: int, n: int, window: int, dtype, sites, ws):
     """The forms (`_held_sum_form`) of a held share's two sums of a window's
     rows over their tokens, the forward's output and the backward's gradient
-    of x2, in that order, told to the trace as well
-    (`observability/trace.held_row_sums`) for the reason `_note_tiles` has,
-    with the rows each row stage of the window runs over (`_window_stages`).
+    of x2, in that order, noted as the node's `held_row_sums` as well
+    (`kernels/context.note`) for the reason `_note_tiles` has, with the rows
+    each row stage of the window runs over (`_window_stages`).
     `held`: the share's experts; `n`: the tokens; `dtype`: the rows';
     `sites`: name -> (row width, the sum's dtype); `ws`: the share's
-    matrices."""
-    from flexflow_tpu.observability import trace
-
+    matrices. The value is `{"forward": entry, "backward": entry, "stages":
+    {...}}`: for the forward's sum of a window's output rows over their
+    tokens and for the backward's sum of the rows' cotangent, the `form`
+    (`pallas`: the kernel `held_rows_sum`; `xla`: a scatter-add), the
+    window's rows (`window_rows`), the row's `width` and `dtype`, the sum's
+    (`sum_dtype`) and the tokens a program of the kernel (`token_tile`, None
+    on `xla`); under `stages`, for each row stage of a window between the
+    grouped matmuls and the sums (`rows_in`, `zero_fill`, `elementwise`,
+    `lanes`) `live` where it stops at the share's last row and `window`
+    where it runs over the whole pass, so that a trace says whether a cell's
+    passes cost their rows or their size."""
     k, entries = attrs.num_select, {}
     for name, (width, sum_dtype) in sites.items():
         form = _held_sum_form(pallas, n, k, width, dtype)
@@ -373,7 +379,8 @@ def _note_held_sums(pallas: bool, attrs, held: int, n: int, window: int, dtype, 
             "token_tile": _held_sum_tile(n, k, width) if form == "pallas" else None,
         }
     forms = tuple(entry["form"] for entry in entries.values())
-    trace.note_held_row_sums(
+    context.note(
+        "held_row_sums",
         dict(entries, stages=_window_stages(pallas, forms, attrs, held, n * k, ws))
     )
     return forms
@@ -915,13 +922,6 @@ def held_rows_sum(src, decisions_sorted, rows_sorted, weight, n: int, k: int,
     )(*scalars, src)
 
 
-def _interpret() -> bool:
-    """Pallas interpret mode for `held_rows_sum`: CPU tests that opt in."""
-    from flexflow_tpu.kernels import flash_attention as flash
-
-    return flash.interpret_default()
-
-
 # -- a window's row stages stop at the share's last row -----------------------
 #
 # A window is a static `[window, .]` buffer and a share fills a part of it:
@@ -991,7 +991,7 @@ def _live_rows_call(body, name: str, live, operands, results, over=None):
         ),
         # the live count is input 0
         input_output_aliases={1 + i: o for i, o in (over or {}).items()},
-        interpret=_interpret(),
+        interpret=context.interpret_default(),
         name=name,
     )(live, *operands)
 
@@ -1087,9 +1087,10 @@ def _window_rows_fwd(x2, token, valid, live, decisions_sorted, rows_sorted, k, r
 def _window_rows_bwd(k, readers, kept, g):
     live, decisions_sorted, rows_sorted, n = kept
     assert readers in (1, 2), readers
+    interpret = context.interpret_default()
     g_x2 = held_rows_sum(
-        held_rows_lanes(g[0], _interpret(), live, *g[1:]), decisions_sorted,
-        rows_sorted, None, n, k, g[0].shape[1], g[0].dtype, _interpret(),
+        held_rows_lanes(g[0], interpret, live, *g[1:]), decisions_sorted,
+        rows_sorted, None, n, k, g[0].shape[1], g[0].dtype, interpret,
     )
     return g_x2, None, None, None, None, None
 
@@ -1287,10 +1288,11 @@ def _held_window_add(out, t, order, counts, x2, flat_w, ws, attrs, pallas, forms
     zero, which XLA folds either way."""
     w = _held_window(t, order, counts, x2, flat_w, ws, attrs, pallas, forms)
     if forms[0] == "pallas":
+        interpret = context.interpret_default()
         return out + held_rows_sum(
-            held_rows_lanes(w["y"], _interpret(), w["live"]), *w["by_token"],
+            held_rows_lanes(w["y"], interpret, w["live"]), *w["by_token"],
             w["weight"], x2.shape[0], order.shape[0] // x2.shape[0],
-            out.shape[1], out.dtype, _interpret(),
+            out.shape[1], out.dtype, interpret,
         )
     return out.at[w["token"]].add(
         w["weight"][:, None] * w["y"].astype(jnp.float32)

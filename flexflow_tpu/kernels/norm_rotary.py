@@ -55,7 +55,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from flexflow_tpu.kernels.ssm import _interpret
+from flexflow_tpu.kernels.context import interpret_default
 
 _LANES = 128
 # elements of a step's slab (rows a step = this over the slab's lanes, 16 at
@@ -341,7 +341,7 @@ def norm_rotary(x, gain, cos, sin, form: PassForm, eps: float):
     `kernels/ops.rms_norm` then `rope_bshf`, as the kernels
     `norm_rotary_fwd` and, its WRITTEN backward, `norm_rotary_bwd`.
     `pass_plan` must admit the form."""
-    return _forward(x, gain, cos, sin, form, eps, _interpret())
+    return _forward(x, gain, cos, sin, form, eps, interpret_default())
 
 
 def _norm_rotary_vjp_fwd(x, gain, cos, sin, form, eps):
@@ -351,7 +351,7 @@ def _norm_rotary_vjp_fwd(x, gain, cos, sin, form, eps):
 
 
 def _norm_rotary_vjp_bwd(form, eps, kept, dy):
-    dx, dgain = _backward(*kept, dy, form, eps, _interpret())
+    dx, dgain = _backward(*kept, dy, form, eps, interpret_default())
     # the tables are constants of the node: nothing reads their cotangent
     return dx, dgain, None, None
 

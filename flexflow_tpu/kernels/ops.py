@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.op_attrs.core import OpAttrs
 from flexflow_tpu.op_attrs.ops import (
     BatchMatmulAttrs,
@@ -372,13 +373,12 @@ def between_form(attrs: MultiHeadAttentionAttrs, route: str, s: int,
       whole-row norm a row of a power of two of lanes, 4,096 at most.
 
     A node with neither norm nor rotary has nothing between: (None, None)."""
-    from flexflow_tpu.kernels.flash_attention import current_flash_mesh
     from flexflow_tpu.kernels.norm_rotary import HEAD_SIZES, pass_plan
 
     form = _pass_form(attrs)
     if form.span is None and not form.rotary:
         return None, None
-    if route != "fused_row" or current_flash_mesh() is not None:
+    if route != "fused_row" or context.declared_mesh() is not None:
         return "xla (route)", None
     if attrs.rotary_dim not in (None, form.d):
         return "xla (rotary_dim)", None
@@ -544,7 +544,8 @@ def mha_core_route(
 ) -> str:
     """The attention core `_mha_forward` lowers these [b, s, e] operands to
     in the current trace, from static facts alone (the shapes, the declared
-    `flash_mesh`, the `no_flash` guard, the backend). THE layout rule, for
+    `flash_mesh`, the `no_flash` guard, the backend: `kernels/context.py`).
+    THE layout rule, for
     one chip and for a mesh alike:
 
     - "fused_row_qkv": one projection matmul into the head-pair interleaved
@@ -577,7 +578,6 @@ def mha_core_route(
         return "dense"
     from flexflow_tpu.kernels.flash_attention import (
         bshf_pair_supported,
-        current_flash_mesh,
         flash_core_supported,
     )
 
@@ -585,7 +585,7 @@ def mha_core_route(
     b, s, t = q_shape[0], q_shape[1], k_shape[1]
     proj_q = (b, H, s, kd)
     proj_kv = (b, H, t, kd)
-    mesh_ctx = current_flash_mesh()
+    mesh_ctx = context.declared_mesh()
     heads_whole = mesh_ctx is None or mesh_ctx[2] is None
     if attrs.differential:
         # two maps a differential head on the causal tile schedule, or XLA's
@@ -658,13 +658,15 @@ def _banded(attrs: MultiHeadAttentionAttrs, s: int) -> bool:
 def _note_route(
     route: str, attrs: MultiHeadAttentionAttrs = None, group: int = 1
 ) -> None:
-    """Tell the program's counter which core the attention node being
-    lowered took (`observability/trace.attention_routes`), of a
-    differential node that it is one, of any node its window, the `group`
-    of query heads that read a key/value head where it lies, and the scale
-    of the scores where the node states one."""
-    from flexflow_tpu.observability import trace
-
+    """Note the core the attention node being lowered took, its
+    `attention_routes` (`kernels/context.note`; the pinned view
+    `observability/trace.attention_routes`): `mha_core_route`'s name,
+    followed by ` differential` of a differential node and of any node,
+    where it has one, by ` window=<keys>` (`fused_row differential
+    window=512`, `fused_row window=1024`), then ` group=<query heads a
+    key/value head>` where they are read in place and ` scale=<value>` where
+    the node states its scores' scale (`fused_row group=4 scale=0.015625`);
+    what its band skips on the kernels is `window_tiles`."""
     if attrs is not None:
         if attrs.differential:
             route += " differential"
@@ -674,58 +676,68 @@ def _note_route(
         route += f" group={group}"
     if attrs is not None and attrs.softmax_scale is not None:
         route += f" scale={attrs.softmax_scale:g}"
-    trace.note_attention_route(route)
+    context.note("attention_routes", route)
 
 
 def _note_scan_blocks(attrs, x) -> None:
-    """Tell the program's counter how many column blocks the scan of the
-    state-space node being lowered goes as, 0 on the "xla" route
-    (`observability/trace.scan_column_blocks`)."""
+    """Note the programs a group's scan goes as in the state-space node
+    being lowered, its `scan_column_blocks` (`kernels/context.note`; the
+    pinned view `observability/trace.scan_column_blocks`): 1 the group
+    whole, 4 a 4,096-column group in blocks of 1,024 on the Pallas kernels,
+    0 where the node took `_scan_core` ("xla"), so that a run that fell back
+    says so itself."""
     from flexflow_tpu.kernels.ssm import scan_column_blocks
-    from flexflow_tpu.observability import trace
 
-    trace.note_scan_column_blocks(scan_column_blocks(
+    context.note("scan_column_blocks", scan_column_blocks(
         x.shape[0], attrs.num_heads, attrs.head_dim, attrs.num_groups,
         attrs.state_size, attrs.chunk_size,
     ))
 
 
 def _note_rotary(attrs: MultiHeadAttentionAttrs) -> None:
-    """Tell the program's counter the rotary of the plain attention node
-    being lowered, where it has one (`observability/trace.rotaries`)."""
-    from flexflow_tpu.observability import trace
-
+    """Note the rotary of the plain attention node being lowered, where it
+    has one, its `rotaries` (`kernels/context.note`; the pinned view
+    `observability/trace.rotaries`): `default theta=500000`, or with a
+    `YarnScaling` `yarn factor=16 low=18 high=35 amp=1.2773` (the first pair
+    the ramp touches, the first it leaves `factor` times slower, the
+    amplitude on cosine and sine), so that two layers of one graph that turn
+    differently say so themselves."""
     if attrs.rope_theta is None:
         return
     width = attrs.rotary_dim or attrs.q_proj_size
-    trace.note_rotary(
+    context.note(
+        "rotaries",
         f"default theta={attrs.rope_theta:g}" if attrs.rope_scaling is None
         else attrs.rope_scaling.describe(attrs.rope_theta, width)
     )
 
 
 def _note_between(said) -> None:
-    """Tell the program's counter what `between_form` said of the plain
-    attention node being lowered (`observability/trace.between_passes`),
-    where it has a norm or a rotary."""
-    from flexflow_tpu.observability import trace
-
+    """Note what `between_form` said of the plain attention node being
+    lowered, where it has a norm or a rotary, its `between_passes`
+    (`kernels/context.note`): `pallas` (norm and rotary of q and of k as ONE
+    Pallas pass each way, `norm_rotary_fwd` / `norm_rotary_bwd`) or
+    `xla (<why>)` (`rms_norm`, then `rope_bshf`, differentiated by JAX;
+    `between_form` lists the reasons), so that a run that fell back says so
+    itself."""
     if said is not None:
-        trace.note_between_pass(said)
+        context.note("between_passes", said)
 
 
 def _note_window_tiles(plan, s: int) -> None:
-    """Tell the program's counter what the band of the plan being lowered
-    skips (`observability/trace.window_tiles`): the tiles its forward
-    visits against those the causal schedule would."""
+    """Note what the band of the plan being lowered skips, the node's
+    `window_tiles` (`kernels/context.note`; the pinned view
+    `observability/trace.window_tiles`): the (q block, k block) tiles its
+    forward visits and those the causal schedule would, `(visited, causal)`.
+    A node whose band is a mask on XLA's dense attention skips nothing and
+    notes nothing."""
     from flexflow_tpu.kernels.flash_attention import causal_tile_schedule
-    from flexflow_tpu.observability import trace
 
     if plan is not None and plan.window is not None:
-        trace.note_window_tiles(
+        context.note("window_tiles", (
             causal_tile_schedule(s, plan.block_q, plan.block_k, plan.window)[0],
             causal_tile_schedule(s, plan.block_q, plan.block_k)[0],
-        )
+        ))
 
 
 def _differential_forward(attrs: MultiHeadAttentionAttrs, q, k, v, weights):
@@ -907,15 +919,19 @@ def deinterleaved_columns(w, num_heads: int, start: int, width: int):
 
 
 def _note_latent_form(attrs: MultiHeadAttentionAttrs, route, s, itemsize):
-    """Tell the program's counter which form the latent node being lowered
-    took (`observability/trace.latent_attention_forms`)."""
-    from flexflow_tpu.observability import trace
-
+    """Note the form the latent-attention node being lowered took, its
+    `latent_attention_forms` (`kernels/context.note`; the pinned view
+    `observability/trace.latent_attention_forms`): `query_rank` (None: one
+    full-rank query projection), `rotated_columns` (of the shared key slice
+    and of each query head; 0: no position encoding), `pairing`
+    (`interleaved`: columns (2j, 2j + 1); `halves`: (j, j + width / 2); None)
+    and `core` (the forward kernel of the wide-key entry, or `dense`), so
+    that a run says itself which node it measured."""
     rope = attrs.rope_theta is not None
     core = "dense"
     if route == "fused_row":
         core = _causal_plan_of(attrs, s, itemsize).fwd_name
-    trace.note_latent_attention_form({
+    context.note("latent_attention_forms", {
         "query_rank": attrs.q_latent_rank,
         "rotated_columns": attrs.shared_key_dim if rope else 0,
         "pairing": (
@@ -1072,7 +1088,6 @@ def _mha_forward(
     causal=False, qk_gains=None,
 ):
     from flexflow_tpu.kernels.flash_attention import (
-        current_flash_mesh,
         flash_attention,
         flash_attention_bshf,
         flash_attention_bshf_qkv,
@@ -1176,7 +1191,7 @@ def _mha_forward(
         qp, kp, vp, wo = mha_project_qkv(attrs, q, k, v, weight, input_bias)
         gate = None
     if route == "rows":
-        mesh_ctx = current_flash_mesh()
+        mesh_ctx = context.declared_mesh()
         if mesh_ctx is None:
             ctx = flash_attention(
                 qp, kp, vp, causal=causal, scale=attrs.softmax_scale

@@ -79,20 +79,7 @@ def barrier_grads(grads):
     """Keep XLA from fusing the optimizer's elementwise math into the
     weight-gradient matmuls: fused, the headline bench's wgrad dots run at
     56-67% of peak; separated they run pure and the update becomes a cheap
-    HBM pass. Opt out with FLEXFLOW_TPU_OPT_BARRIER=0."""
-    import os
-
-    mode = os.environ.get("FLEXFLOW_TPU_OPT_BARRIER", "1")
-    if mode == "0":
-        return grads
-    if mode == "2d":
-        # barrier only matmul-produced (>=2D) gradients: 1D bias/norm
-        # grads fuse harmlessly into their updates, and leaving them free
-        # lets XLA overlap those small updates with the backward
-        return jax.tree_util.tree_map(
-            lambda g: jax.lax.optimization_barrier(g) if g.ndim >= 2 else g,
-            grads,
-        )
+    HBM pass."""
     return jax.lax.optimization_barrier(grads)
 
 

@@ -29,14 +29,13 @@ import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels.flash_attention import (
     LOG2E,
     NEG_INF,
-    _backend_ok,
     _clamp_block,
     _default_blocks,
     _exp2_probs,
-    interpret_default,
 )
 
 # Like the dense flash kernels, scores are scaled into the base-2 domain
@@ -477,8 +476,8 @@ def ring_flash_supported(
     the same [t, d] layout), tile-aligned block lengths, and a Pallas
     backend (TPU, or CPU interpret mode for the virtual-mesh tests)."""
     if interpret is None:
-        interpret = interpret_default()
-    if not _backend_ok(allow_interpret=interpret):
+        interpret = context.interpret_default()
+    if not context.on_tpu(allow_interpret=interpret):
         return False
     if len(qp_shape) != 4 or len(kp_shape) != 4 or len(vp_shape) != 4:
         return False
@@ -510,7 +509,7 @@ def ring_flash_attention_block(
     behavior: qp/kp/vp are the local per-head blocks [b, h, s_blk, d];
     returns the local context block [b, h, s_blk, d]."""
     if interpret is None:
-        interpret = interpret_default()
+        interpret = context.interpret_default()
     s_blk, t_blk = qp.shape[2], kp.shape[2]
     dq0, dk0 = _default_blocks()
     bq = _clamp_block(block_q if block_q is not None else dq0, s_blk)

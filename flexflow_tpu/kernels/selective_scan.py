@@ -43,7 +43,7 @@ of two forms of it from what the trace can observe:
   accumulates in its [N, channels] output block over the chunks; dD and
   db_dt are XLA's reductions over the kernel's operands and results.
 - **`lax.scan`** ("scan"): the CPU, widths that fill no tile, a trace that
-  admits no bare Pallas call (`no_flash`, a declared `flash_mesh`). One
+  admits no bare Pallas call (`kernels/context.admits_bare_pallas_call`). One
   `lax.scan` over the chunks of a `jax.checkpoint`ed `lax.scan` over a
   chunk's positions: JAX's own transpose, which keeps the chunk-edge states
   and recomputes inside. Float32 throughout.
@@ -68,6 +68,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.op_attrs.ops.selective_scan import SelectiveScanAttrs
 
 # positions a program takes (and a kept state apart), channels a register
@@ -87,13 +88,10 @@ def _softplus(z):
 
 def scan_route(channels: int, state: int) -> str:
     """"pallas" or "scan" (module docstring), from static facts alone."""
-    from flexflow_tpu.kernels import flash_attention as flash
-
-    if getattr(flash._tls, "disabled", False) or flash.current_flash_mesh():
-        return "scan"
     if channels % _LANES or state % 8:
         return "scan"
-    return "pallas" if flash._backend_ok(flash.interpret_default()) else "scan"
+    admitted = context.admits_bare_pallas_call(context.interpret_default())
+    return "pallas" if admitted else "scan"
 
 
 # ---------------------------------------------------------------------------
@@ -459,17 +457,17 @@ def selective_scan(x, r, dt_bias, a_log, b_mat, c_mat, d_skip,
     the step before its bias and softplus), dt_bias and d_skip [channels],
     a_log [channels, N], b_mat and c_mat [b, s, N] -> m [b, s, channels] in
     x's dtype, by the form `scan_route` names (or `route`, and the kernels
-    in `interpret` mode, for a test)."""
-    from flexflow_tpu.kernels import flash_attention as flash
-
+    in `interpret` mode, for a test), noted as the node's
+    `selective_scan_routes` (`kernels/context.note`)."""
     route = route or scan_route(x.shape[-1], a_log.shape[-1])
+    context.note("selective_scan_routes", route)
     with jax.named_scope("scan"):
         if route == "scan":
             return _scan_reference(
                 x, r, dt_bias, a_log, b_mat, c_mat, d_skip, chunk
             )
         if interpret is None:
-            interpret = flash.interpret_default()
+            interpret = context.interpret_default()
         return _pallas_scan(
             chunk, interpret, x, r, dt_bias, a_log, b_mat, c_mat, d_skip
         )

@@ -26,7 +26,7 @@ operands in the input's dtype (bf16 in a bf16 step) and accumulate in
 float32.
 
 Two forms of these chunks exist, and `scan_route` picks one from what the
-trace can observe (shapes, backend, `no_flash`, `flash_mesh`); nothing but
+trace can observe (shapes and the facts of `kernels/context.py`); nothing but
 the order of the floating-point sums differs between them:
 
 - **The Pallas kernels** (`ssd_fwd_chunk`, `ssd_states_chunk`,
@@ -75,7 +75,7 @@ round too.
 has two behind its one `custom_vjp`, and `conv_route` picks one from what the
 trace can observe, for every caller alike (this node, `kernels/kda.py`'s two
 delta-rule nodes, `kernels/selective_scan.py`; told by node in
-`observability/trace.conv_forms()`):
+`trace.kernel_choices("conv_forms")`):
 
 - **The Pallas kernels** (`conv_silu_fwd`, `conv_silu_bwd`; "kernels", PR
   59): on a TPU, where the trace admits a bare Pallas call, the
@@ -121,6 +121,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
 
 
@@ -171,10 +172,13 @@ def conv_silu(x, weight, bias, first: int = 0, pieces=None):
     and rounded again: one pass over x, one output.
 
     Two routes behind the one `custom_vjp` (`_conv_silu`), `conv_route`'s
-    choice made here once for the forward and the backward, told to
-    the program's counter (`observability/trace.conv_forms`): the Pallas
-    kernels `conv_silu_fwd` / `conv_silu_bwd` (the section below), which
-    read x's columns in place, and the plain form here, which slices them.
+    choice made here once for the forward and the backward and noted as the
+    node's `conv_forms` (`kernels/context.note`; every node with a
+    `conv_silu`: the state-space, selective-scan and gated delta-rule
+    mixers): `kernels`, the Pallas kernels `conv_silu_fwd` / `conv_silu_bwd`
+    (the section below), which read x's columns in place, or `xla`, the
+    plain form here with its written backward, which slices them, so that a
+    run that fell back says so itself.
 
     The backward is written because JAX's own keeps the wrong things: the
     transpose of `w[k] * slice(pad(float32(x)))` keeps the padded float32
@@ -196,12 +200,10 @@ def conv_silu(x, weight, bias, first: int = 0, pieces=None):
     sum-of-pads for the kernel's sake (a kernel's operand must be a
     buffer). The kernels' column block divides every piece
     (`_conv_plan`)."""
-    from flexflow_tpu.observability import trace
-
     pieces = None if pieces is None else tuple(pieces)
     width = weight.shape[1]
     route = conv_route(first, width, x.shape[1], len(weight), pieces)
-    trace.note_conv_form(route)
+    context.note("conv_forms", route)
     if route == "xla":
         # the plain form takes its own columns and keeps no more of the row
         x, first = x[..., first:first + width], 0
@@ -222,7 +224,8 @@ def _conv_silu_fwd(x, weight, bias, first, route, pieces):
     kept = (x, weight, bias)
     if route == "kernels":
         ys = _conv_forward(
-            x, weight, bias, first, pieces or weight.shape[1:], _interpret()
+            x, weight, bias, first, pieces or weight.shape[1:],
+            context.interpret_default(),
         )
         return (ys[0] if pieces is None else ys), kept
     a = _conv_taps(x, weight, bias).astype(x.dtype)
@@ -237,7 +240,9 @@ def _conv_silu_bwd(first, route, pieces, kept, dy):
     x, weight, bias = kept
     if route == "kernels":
         dys = (dy,) if pieces is None else tuple(dy)
-        return _conv_backward(x, weight, bias, dys, first, _interpret())
+        return _conv_backward(
+            x, weight, bias, dys, first, context.interpret_default()
+        )
     if pieces is not None:
         dy = jnp.concatenate(dy, axis=-1)
     f32 = jnp.float32
@@ -257,12 +262,6 @@ def _conv_silu_bwd(first, route, pieces, kept, dy):
 
 
 _conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
-
-
-def _interpret() -> bool:
-    from flexflow_tpu.kernels import flash_attention as flash
-
-    return flash.interpret_default()
 
 
 # ---------------------------------------------------------------------------
@@ -329,22 +328,17 @@ def conv_route(
     """Which form `conv_silu` takes, from what the trace can observe:
 
     - "kernels": `conv_silu_fwd` / `conv_silu_bwd`, where the backend is a
-      TPU (or the CPU with interpret mode opted in, `interpret_default`),
-      the trace admits a bare Pallas call (not under `no_flash()`, no
-      declared `flash_mesh`), the convolution's first column, its width and
+      TPU (or the CPU with interpret mode opted in) and the trace admits a
+      bare Pallas call (`kernels/context.admits_bare_pallas_call`), the
+      convolution's first column, its width and
       the `pieces` its result is taken in are whole 128-lane tiles, the
       sequence divides into the plan's blocks (`_conv_plan`) and the taps
       reach back over no more than one float32 sublane tile;
     - "xla": everything else, the plain form."""
-    from flexflow_tpu.kernels import flash_attention as flash
-
     if _conv_plan(first, width, seq, pieces) is None or taps - 1 > _HALO:
         return "xla"
-    if flash.current_flash_mesh() is not None:
-        return "xla"
-    if getattr(flash._tls, "disabled", False):
-        return "xla"
-    return "kernels" if flash._backend_ok(flash.interpret_default()) else "xla"
+    admitted = context.admits_bare_pallas_call(context.interpret_default())
+    return "kernels" if admitted else "xla"
 
 
 def _eight_apart(t):
@@ -1302,8 +1296,7 @@ _ssd_scan.defvjp(_ssd_scan_fwd, _ssd_scan_bwd)
 def scan_column_blocks(batch, heads, head_dim, groups, state, chunk) -> int:
     """The column blocks a group's scan goes as on the Pallas kernels (1: the
     group whole), 0 where `scan_route` names "xla": what the program's
-    counter keeps of a state-space node
-    (`observability/trace.scan_column_blocks`)."""
+    counter keeps of a state-space node (`kernels/ops._note_scan_blocks`)."""
     if scan_route(batch, heads, head_dim, groups, state, chunk) == "xla":
         return 0
     return _column_blocks(heads // groups * head_dim)
@@ -1313,15 +1306,15 @@ def scan_route(batch, heads, head_dim, groups, state, chunk) -> str:
     """Which form `selective_scan` takes, from what the trace can observe:
 
     - "ssd": the Pallas kernels, where the backend is a TPU (or the CPU with
-      interpret mode opted in, `interpret_default`), the tiles fill whole
+      interpret mode opted in, `context.interpret_default`), the tiles fill whole
       vregs (chunk and state multiples of 128 lanes, a group's r * P columns
       whole 128-lane tiles of heads of 64 or 128) and fit VMEM (a group of
       at most `_MAX_GROUP_COLUMNS` columns whole, a wider one as the column
       blocks `_column_blocks` cuts it into), and the trace admits a bare
-      Pallas call (not under `no_flash()`);
-    - "ssd_sharded": the same under a declared `flash_mesh` with whole heads
-      whose batch axes divide the batch: the kernels mapped over the batch
-      shards, as the attention kernels are (a group that goes as column
+      Pallas call (`context.admits_bare_pallas_call`);
+    - "ssd_sharded": the same under a declared `context.flash_mesh` with
+      whole heads whose batch axes divide the batch: the kernels mapped over
+      the batch shards, as the attention kernels are (a group that goes as column
       blocks has not been mapped over a mesh yet and takes "xla" there);
     - "xla": everything else, `_scan_core`."""
     from flexflow_tpu.kernels import flash_attention as flash
@@ -1333,29 +1326,26 @@ def scan_route(batch, heads, head_dim, groups, state, chunk) -> str:
     ):
         return "xla"
     blocks = _column_blocks(columns)
-    ctx = flash.current_flash_mesh()
+    ctx = context.declared_mesh()
     if ctx is None:
-        bare_call_ok = not getattr(flash._tls, "disabled", False)
-        on_chip = flash._backend_ok(flash.interpret_default())
-        return "ssd" if bare_call_ok and on_chip else "xla"
+        admitted = context.admits_bare_pallas_call(context.interpret_default())
+        return "ssd" if admitted else "xla"
     mesh, batch_axes, head_axes, interpret = ctx
     if head_axes is not None or batch % flash._axes_size(mesh, batch_axes):
         return "xla"
     if blocks > 1:
         return "xla"
-    return "ssd_sharded" if flash._backend_ok(interpret) else "xla"
+    return "ssd_sharded" if context.on_tpu(interpret) else "xla"
 
 
 def _ssd_scan_routed(route, groups, chunk, *operands):
-    from flexflow_tpu.kernels import flash_attention as flash
-
     if route == "ssd":
-        return _ssd_scan(groups, chunk, flash.interpret_default(), *operands)
+        return _ssd_scan(groups, chunk, context.interpret_default(), *operands)
     from jax.sharding import PartitionSpec as P
 
     from flexflow_tpu.utils.shard_map_compat import shard_map_compat
 
-    mesh, batch_axes, _, interpret = flash.current_flash_mesh()
+    mesh, batch_axes, _, interpret = context.declared_mesh()
     rows = P(batch_axes, None, None)
     return shard_map_compat(
         functools.partial(_ssd_scan, groups, chunk, interpret),

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.op_attrs.ops.ulysses_attention import UlyssesAttentionAttrs
 
 
@@ -24,7 +25,6 @@ def _attend_full_seq(qp, kp, vp, causal: bool, interpret: bool):
     """Attention on full-sequence per-head blocks [b, h, s, d]; flash when
     the local block qualifies, dense einsums otherwise."""
     from flexflow_tpu.kernels.flash_attention import (
-        _backend_ok,
         _flash_shape_ok,
         _min_seq_default,
         flash_attention,
@@ -33,7 +33,7 @@ def _attend_full_seq(qp, kp, vp, causal: bool, interpret: bool):
     b, h, s, d = qp.shape
     if (
         kp.shape == qp.shape == vp.shape
-        and _backend_ok(allow_interpret=interpret)
+        and context.on_tpu(allow_interpret=interpret)
         and _flash_shape_ok(qp.shape, _min_seq_default())
     ):
         return flash_attention(qp, kp, vp, causal=causal, interpret=interpret)
@@ -111,10 +111,9 @@ def ulysses_mha_forward(
 ):
     """Global-view entry for the all-to-all schedule (contract identical to
     ring_mha_forward; plumbing shared via seq_parallel_mha_forward)."""
-    from flexflow_tpu.kernels.flash_attention import interpret_default
     from flexflow_tpu.kernels.ring_attention import seq_parallel_mha_forward
 
-    interpret = interpret_default()
+    interpret = context.interpret_default()
 
     def factory(attrs_, axis_names, sp, head_axes, tp):
         assert (attrs_.num_heads // max(tp, 1)) % sp == 0, (

@@ -79,6 +79,7 @@ from typing import Dict, List, Optional, Tuple
 import jax
 
 import flexflow_tpu
+from flexflow_tpu.kernels import context
 from flexflow_tpu.op_attrs.core import PARALLEL_OP_TYPES, op_type_of
 
 
@@ -481,13 +482,34 @@ def setup_report(top: int = 12) -> str:
         f"once {counted_once:.3f} s: {naive - counted_once:.3f} s of nested jits "
         "counted again in their callers"
     )
-    forms = sorted(_BETWEEN_PASSES.values())
-    if forms:
-        lines.append(
-            "norm and rotary of the plain attention nodes (between_passes()): "
-            + ", ".join(f"{forms.count(f)} {f}" for f in dict.fromkeys(forms))
-        )
+    lines += _choice_lines()
     return "\n".join(lines)
+
+
+# the words a kind's line of `setup_report()` starts with where they are not
+# `kernel_choices("<kind>")`: readers of the report know this line by them
+_CHOICE_WORDS = {
+    "between_passes":
+        "norm and rotary of the plain attention nodes (between_passes())",
+}
+
+
+def _choice_lines() -> List[str]:
+    """A line for each kind of `kernel_choices()`: how many nodes chose each
+    value, so that a node that took XLA's form says so in the report."""
+    by_kind: Dict[str, list] = {}
+    for noted in kernel_choices().values():
+        for kind, value in noted.items():
+            by_kind.setdefault(kind, []).append(
+                value if isinstance(value, str) else repr(value)
+            )
+    return [
+        _CHOICE_WORDS.get(kind, f'kernel_choices("{kind}")') + ": "
+        + ", ".join(
+            f"{values.count(v)} {v}" for v in dict.fromkeys(sorted(values))
+        )
+        for kind, values in sorted(by_kind.items())
+    ]
 
 
 # -- host spans, the call -------------------------------------------------------
@@ -601,9 +623,9 @@ NODE_PARTS = {
     # route `gates` also holds the two norms, the softplus and the
     # heads-first copies of q, k and the pre-activation. `norm` is the heads'
     # norm under its gate: on the "kda" route the kernels
-    # `head_norm_gate_fwd` / `head_norm_gate_bwd` (PR 58; `head_norms()`
-    # says which form a node took), on the "xla" route XLA's fusions of the
-    # plain form under a checkpoint
+    # `head_norm_gate_fwd` / `head_norm_gate_bwd` (PR 58;
+    # `kernel_choices("head_norms")` says which form a node took), on the
+    # "xla" route XLA's fusions of the plain form under a checkpoint
     "kda": ("scan", "prep", "gates", "conv", "norm"),
     # latent attention (`kernels/ops._latent_mha_forward`): the low-rank
     # key/value projections with their norm, and the attention core
@@ -664,302 +686,73 @@ def scope_name(graph, n) -> str:
     return f"ff.{kind}.{_NOT_IN_NAME.sub('_', name)}"
 
 
-# the scope of the node being lowered on this thread, and the attention core
-# each attention node took when it was last lowered in this process; the
-# grouped matmuls' tiles each expert node took likewise
-_lowering = threading.local()
-_ATTENTION_ROUTES: Dict[str, str] = {}
-_LATENT_ATTENTION_FORMS: Dict[str, dict] = {}
-_DELTA_RULE_OPERANDS: Dict[str, str] = {}
-_TRIANGULAR_PRODUCTS: Dict[str, str] = {}
-_HEAD_NORMS: Dict[str, str] = {}
-_CONV_FORMS: Dict[str, str] = {}
-_GROUPED_MATMUL_TILES: Dict[str, Dict[str, dict]] = {}
-_HELD_ROW_SUMS: Dict[str, Dict[str, dict]] = {}
-
-
 @contextlib.contextmanager
 def node_scope(graph, n):
     """The `jax.named_scope` everything lowered for node `n` goes under;
-    inside a trace of the step, a row of `node_trace_seconds()`."""
+    inside a trace of the step, a row of `node_trace_seconds()`. The node is
+    `kernels/context`'s node being lowered within: what its kernels choose
+    is filed under it (`kernel_choices`)."""
     name = scope_name(graph, n)
-    previous = getattr(_lowering, "scope", None)
-    _lowering.scope = name
-    try:
-        with _traced_scope(name, name.split(".")[1]):
-            yield
-    finally:
-        _lowering.scope = previous
+    with context.lowering_node(name), _traced_scope(name, name.split(".")[1]):
+        yield
 
 
-def note_attention_route(route: str) -> None:
-    """The attention core (`kernels/ops.mha_core_route`'s names) the node
-    being lowered took; dropped where no node's scope is open (a kernel
-    called by itself)."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _ATTENTION_ROUTES[scope] = route
+# -- what the kernels chose -------------------------------------------------
+#
+# `kernels/context.note` keeps, by node and kind, what each route rule chose
+# when the node was last lowered in this process; the kind's vocabulary is
+# written where it is noted. Here: the view of the whole table, and the five
+# kinds the benchmark's readers bind by name.
 
 
-_WINDOW_TILES: Dict[str, tuple] = {}
-
-
-def note_window_tiles(visited: int, causal: int) -> None:
-    """Of the windowed attention node being lowered: the (q block, k block)
-    tiles its forward visits and those the causal schedule would
-    (`flash_attention.causal_tile_schedule` with and without the band);
-    dropped where no node's scope is open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _WINDOW_TILES[scope] = (visited, causal)
-
-
-def window_tiles() -> Dict[str, tuple]:
-    """`{ff.<kind>.<name>: (visited, causal)}` of every windowed attention
-    node this process has lowered onto the causal tile kernels, as it was
-    lowered last: a program counter (the benchmark's
-    `window_live_tiles_pct` reads it). A node whose band is a mask on XLA's
-    dense attention skips nothing and is not in it."""
-    return dict(_WINDOW_TILES)
+def kernel_choices(kind: Optional[str] = None) -> dict:
+    """`{ff.<kind>.<name>: value}` of one kind of choice, or `{ff.<kind>.
+    <name>: {kind: value}}` of all (`kernels/context.choices`): a program
+    counter, so that a node that took XLA's form says so itself.
+    `setup_report()` prints it by kind."""
+    return context.choices(kind)
 
 
 def attention_routes() -> Dict[str, str]:
-    """`{ff.<kind>.<name>: route}` of every attention node this process has
-    lowered on one device or in the global view, as it was lowered last: a
-    program counter a reader (the benchmark's `gqa64_flash_roofline`) prints
-    beside what it measures, so that a change of route says so itself. A
-    differential node's route is followed by ` differential` and any node's,
-    where it has one, by ` window=<keys>` (`fused_row differential
-    window=512`, `fused_row window=1024`), then ` group=<query heads a
-    key/value head>` where they are read in place and ` scale=<value>` where
-    the node states its scores' scale (`fused_row group=4 scale=0.015625`);
-    what its band skips on the kernels is `window_tiles()`."""
-    return dict(_ATTENTION_ROUTES)
+    """The core every attention node took (`kernels/ops._note_route`): the
+    benchmark's `gqa64_flash_roofline` prints it beside what it measures."""
+    return context.choices("attention_routes")
 
 
-_SCAN_COLUMN_BLOCKS: Dict[str, int] = {}
-
-
-def note_scan_column_blocks(blocks: int) -> None:
-    """The column blocks the state-space node being lowered runs a group's
-    scan as (`kernels/ssm.scan_column_blocks`); dropped where no node's
-    scope is open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _SCAN_COLUMN_BLOCKS[scope] = blocks
-
-
-def scan_column_blocks() -> Dict[str, int]:
-    """`{ff.ssm.<name>: blocks}` of every state-space node this process has
-    lowered, as it was lowered last: the programs a group's scan goes as on
-    the Pallas kernels (1: the group whole; 4: a 4,096-column group in
-    blocks of 1,024), 0 where the node took `_scan_core`
-    ("xla"), so that a run that fell back says so itself (the benchmark's
-    `granite_scan_column_blocks` reads it)."""
-    return dict(_SCAN_COLUMN_BLOCKS)
-
-
-_ROTARIES: Dict[str, str] = {}
-
-
-def note_rotary(kind: str) -> None:
-    """The rotary of the plain attention node being lowered
-    (`kernels/ops._note_route`); dropped where no node's scope is open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _ROTARIES[scope] = kind
+def window_tiles() -> Dict[str, tuple]:
+    """`(visited, causal)` tiles of every windowed attention node on the
+    causal tile kernels (`kernels/ops._note_window_tiles`): the benchmark's
+    `window_live_tiles_pct` reads it."""
+    return context.choices("window_tiles")
 
 
 def rotaries() -> Dict[str, str]:
-    """`{ff.<kind>.<name>: rotary}` of every plain attention node with a
-    rotary that this process has lowered, as it was lowered last:
-    `default theta=500000`, or with a `YarnScaling`
-    `yarn factor=16 low=18 high=35 amp=1.2773` (the first pair the ramp
-    touches, the first it leaves `factor` times slower, the amplitude on
-    cosine and sine). A program counter the benchmark's `mellum2_*_flash_
-    roofline` readers print beside what they measure, so that two layers of
-    one graph that turn differently say so themselves."""
-    return dict(_ROTARIES)
-
-
-_BETWEEN_PASSES: Dict[str, str] = {}
-
-
-def note_between_pass(form: str) -> None:
-    """The form the norm and the rotary took in the plain attention node
-    being lowered (`kernels/ops.between_form`); dropped where no node's
-    scope is open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _BETWEEN_PASSES[scope] = form
-
-
-def between_passes() -> Dict[str, str]:
-    """`{ff.<kind>.<name>: form}` of every plain attention node with a
-    QK-norm or a rotary that this process has lowered, as it was lowered
-    last, beside `rotaries()`: `pallas` (norm and rotary of q and of k as ONE
-    Pallas pass each way, `norm_rotary_fwd` / `norm_rotary_bwd`) or
-    `xla (<why>)` (`rms_norm`, then `rope_bshf`, differentiated by JAX;
-    `kernels/ops.between_form` lists the reasons: `route`, `rotary_dim`,
-    `output_gate`, `head <d>`, `row <lanes>`), so that a run that fell back
-    says so itself. `setup_report()` prints it."""
-    return dict(_BETWEEN_PASSES)
-
-
-def note_latent_attention_form(form: dict) -> None:
-    """The form the latent-attention node being lowered took
-    (`kernels/ops._latent_mha_forward`); dropped where no node's scope is
-    open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _LATENT_ATTENTION_FORMS[scope] = dict(form)
+    """The rotary of every plain attention node that has one
+    (`kernels/ops._note_rotary`): the benchmark's `mellum2_*` readers print
+    it beside what they measure."""
+    return context.choices("rotaries")
 
 
 def latent_attention_forms() -> Dict[str, dict]:
-    """`{ff.<kind>.<name>: form}` of every latent-attention node this
-    process has lowered, as it was lowered last: `query_rank` (None: one
-    full-rank query projection), `rotated_columns` (of the shared key slice
-    and of each query head; 0: no position encoding), `pairing`
-    (`interleaved`: columns (2j, 2j + 1); `halves`: (j, j + width / 2); None)
-    and `core` (the forward kernel of the wide-key entry, or `dense`), so
-    that a run says itself which node it measured."""
-    return {scope: dict(f) for scope, f in _LATENT_ATTENTION_FORMS.items()}
+    """The form every latent-attention node took
+    (`kernels/ops._note_latent_form`): the benchmark's `mla_rope_ms` prints
+    it."""
+    return context.choices("latent_attention_forms")
 
 
-def note_delta_rule_operands(form: str) -> None:
-    """The form of the chunks' operands (`kernels/kda.operand_form`'s names)
-    the delta-rule node being lowered took; dropped where no node's scope is
-    open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _DELTA_RULE_OPERANDS[scope] = form
+# Not the table: `benchmark/tests/test_granite_readers.py` (the benchmark's
+# own test of its reader, which no PR but a `benchmark` one edits) puts a dict
+# under this name to feed `scan_column_blocks()`. None everywhere else.
+_SCAN_COLUMN_BLOCKS: Optional[Dict[str, int]] = None
 
 
-def delta_rule_operands() -> Dict[str, str]:
-    """`{ff.kda.<name>: form}` of every gated delta-rule node this process
-    has lowered, as it was lowered last: `head_kernels_in_place` (one decay
-    a head, the Pallas kernels `gdn_prep_fwd` / `gdn_prep_bwd` and
-    `kda_corrected_*` reading q, k and v where the convolution left them, a
-    sequence of whole chunks), `head_kernels` (the same kernels on copies
-    padded to the chunk), `head_xla` (the same form, `head_decay_operands`
-    on heads-first copies), `channel_kernels` (a decay a key channel,
-    `kda_prep_fwd` / `kda_prep_bwd`) or `xla` (`chunk_operands`), so that a
-    run that fell back to XLA's operands says so itself."""
-    return dict(_DELTA_RULE_OPERANDS)
-
-
-def note_triangular_products(form: str) -> None:
-    """The form the products around the triangular inverse took in the
-    delta-rule node being lowered (`kernels/kda.py`: noted where the route
-    or the number of chunk-heads chooses); dropped where no node's scope is
-    open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _TRIANGULAR_PRODUCTS[scope] = form
-
-
-def triangular_products() -> Dict[str, str]:
-    """`{ff.kda.<name>: form}` of every gated delta-rule node this process
-    has lowered, as it was lowered last, beside `delta_rule_operands()`:
-    `kernels` (T (K exp(G)), T V and the triangular system's whole backward
-    from the Pallas kernels `kda_corrected_fwd` / `kda_corrected_bwd`) or
-    `xla` (`kernels/kda._corrected` with `unit_lower_inverse`, differentiated
-    by JAX: the "xla" route, and an odd number of chunk-heads on the "kda"
-    route), so that a run that fell back says so itself."""
-    return dict(_TRIANGULAR_PRODUCTS)
-
-
-def note_head_norm(form: str) -> None:
-    """The form the gated per-head norm took in the delta-rule node being
-    lowered (`kernels/kda._gated_head_norm`); dropped where no node's scope
-    is open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _HEAD_NORMS[scope] = form
-
-
-def head_norms() -> Dict[str, str]:
-    """`{ff.kda.<name>: form}` of every gated delta-rule node this process
-    has lowered, as it was lowered last, beside `triangular_products()`:
-    `kernels` (the heads' norm under its gate and the whole of its backward
-    from the Pallas kernels `head_norm_gate_fwd` / `head_norm_gate_bwd`) or
-    `xla` (`kernels/kda._head_norm_silu` / `_head_norm_gate`, differentiated
-    by JAX under a checkpoint: the "xla" route), so that a run that fell
-    back says so itself."""
-    return dict(_HEAD_NORMS)
-
-
-def note_conv_form(form: str) -> None:
-    """The form the causal depthwise convolution with its SiLU took in the
-    node being lowered (`kernels/ssm.conv_silu`); dropped where no node's
-    scope is open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _CONV_FORMS[scope] = form
-
-
-def conv_forms() -> Dict[str, str]:
-    """`{ff.<kind>.<name>: form}` of every node with a `conv_silu` (the
-    state-space, selective-scan and gated delta-rule mixers) this process
-    has lowered, as it was lowered last, beside `head_norms()`: `kernels`
-    (the Pallas kernels `conv_silu_fwd` / `conv_silu_bwd`, the projection's
-    row read in place) or `xla` (the plain form with its written backward:
-    `kernels/ssm.conv_route` says when), so that a run that fell back says
-    so itself."""
-    return dict(_CONV_FORMS)
-
-
-def note_grouped_matmul_tiles(entries: Dict[str, dict]) -> None:
-    """What `kernels/moe.py` gave the Pallas grouped matmuls of the expert
-    node being lowered: `{"<matrix>/<call>": {"shape", "tile",
-    "padded_over_true"}}`; dropped where no node's scope is open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _GROUPED_MATMUL_TILES[scope] = dict(entries)
-
-
-def grouped_matmul_tiles() -> Dict[str, Dict[str, dict]]:
-    """`{ff.experts.<name>: {"<matrix>/<call>": entry}}` of every expert node
-    this process has lowered onto the `gmm` / `tgmm` kernels, as it was
-    lowered last: for each of the node's matrices (`w1`, `w3`, `w2`) and each
-    of a grouped matmul's three calls (`forward`, `input_gradient`,
-    `weight_gradient`) the call's `shape` (rows, contraction, columns, in the
-    kernel's own names), the `tile` it was given and `padded_over_true`, the
-    contraction and column sides in whole tiles over their true size (the
-    row side is the data's). A node on XLA's `ragged_dot` has no entry."""
-    return {scope: dict(entries) for scope, entries in _GROUPED_MATMUL_TILES.items()}
-
-
-def note_held_row_sums(entries: Dict[str, dict]) -> None:
-    """How `kernels/moe.py` sums a held share's window rows over their
-    tokens in the expert node being lowered: `{"forward" | "backward":
-    {"form", "window_rows", "width", "dtype", "sum_dtype", "token_tile"},
-    "stages": {"rows_in", "zero_fill", "elementwise", "lanes"}}`; dropped
-    where no node's scope is open."""
-    scope = getattr(_lowering, "scope", None)
-    if scope is not None:
-        _HELD_ROW_SUMS[scope] = {site: dict(e) for site, e in entries.items()}
-
-
-def held_row_sums() -> Dict[str, Dict[str, dict]]:
-    """`{ff.experts.<name>: {"forward": entry, "backward": entry}}` of every
-    expert node with a held share this process has lowered, as it was
-    lowered last: for the forward's sum of a window's output rows over their
-    tokens and for the backward's sum of the rows' cotangent (the gradient
-    of the node's input), the `form` (`pallas`: the kernel `held_rows_sum`;
-    `xla`: a scatter-add), the window's rows (`window_rows`), the row's
-    `width` and `dtype`, the sum's (`sum_dtype`), and the tokens a program
-    of the kernel (`token_tile`, None on `xla`). Beside them `stages`: for
-    each row stage of a window between the grouped matmuls and the sums
-    (`rows_in`, the mask of the gathered rows; `zero_fill`, of the rows no
-    matrix met; `elementwise`; `lanes`) `live` where it stops at the share's
-    last row and `window` where it runs over the whole pass
-    (`kernels/moe._window_stages`), so that a trace says whether a cell's
-    passes cost their rows or their size."""
-    return {
-        scope: {site: dict(e) for site, e in entries.items()}
-        for scope, entries in _HELD_ROW_SUMS.items()
-    }
+def scan_column_blocks() -> Dict[str, int]:
+    """The column blocks every state-space node's scan goes as, 0 on XLA's
+    form (`kernels/ops._note_scan_blocks`): the benchmark's
+    `granite_scan_column_blocks` reads it."""
+    if _SCAN_COLUMN_BLOCKS is not None:
+        return dict(_SCAN_COLUMN_BLOCKS)
+    return context.choices("scan_column_blocks")
 
 
 # -- the step's loss terms --------------------------------------------------
@@ -976,6 +769,8 @@ def held_row_sums() -> Dict[str, Dict[str, dict]]:
 # such a node records nothing and its step is the one it always was.
 
 LOSS_TERMS_KEY = "loss_terms"
+# the list `collecting_loss_terms` has open on this thread
+_collecting = threading.local()
 _published_loss_terms: Optional[Dict[str, Dict[str, float]]] = None
 
 
@@ -983,13 +778,13 @@ _published_loss_terms: Optional[Dict[str, Dict[str, float]]] = None
 def collecting_loss_terms():
     """While the body traces, `record_loss_term` appends (scope, weight,
     value) to the list this yields."""
-    previous = getattr(_lowering, "loss_terms", None)
+    previous = getattr(_collecting, "loss_terms", None)
     sink: list = []
-    _lowering.loss_terms = sink
+    _collecting.loss_terms = sink
     try:
         yield sink
     finally:
-        _lowering.loss_terms = previous
+        _collecting.loss_terms = previous
 
 
 def record_loss_term(
@@ -1000,9 +795,9 @@ def record_loss_term(
     being lowered; `mass` the mean weight of a term that weighs its
     positions (a looped model's exit: the share of the exit distribution
     that left there). Dropped where nobody collects."""
-    sink = getattr(_lowering, "loss_terms", None)
+    sink = getattr(_collecting, "loss_terms", None)
     if sink is not None:
-        name = scope or getattr(_lowering, "scope", None) or f"term{len(sink)}"
+        name = scope or context.lowering_scope() or f"term{len(sink)}"
         sink.append((name, float(weight), value, mass))
 
 

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.local_execution.training_backing import ModelTrainingInstance
 from flexflow_tpu.op_attrs.ops.loss_functions import LossAttrs
 from flexflow_tpu.pcg.computation_graph import ComputationGraph
@@ -83,16 +84,13 @@ class DataParallelTrainingInstance(ModelTrainingInstance):
 
     def compiled_step(self):
         if self._jit_step is None:
-            from flexflow_tpu.kernels.flash_attention import (
-                flash_mesh,
-                interpret_default,
-            )
-
             def step_with_mesh_ctx(*args):
                 # batch dim rides the "data" axis; heads unsharded in pure DP.
                 # The context routes attention through shard_map'd flash
                 # (a bare pallas_call cannot be SPMD-partitioned).
-                with flash_mesh(self.mesh, "data", None, interpret_default()):
+                with context.flash_mesh(
+                    self.mesh, "data", None, context.interpret_default()
+                ):
                     return self._step(*args)
 
             rep, bat = self.replicated, self.batch_sharded

@@ -29,6 +29,7 @@ import numpy as np
 from flexflow_tpu.kernels import (
     apply_optimizer,
     compute_metrics,
+    context,
     forward as kernel_forward,
     loss_forward,
     make_optimizer_state,
@@ -139,7 +140,6 @@ def pcg_forward_interpreter(
     forward_interpreter)."""
     import contextlib
 
-    from flexflow_tpu.kernels.flash_attention import no_flash
     from flexflow_tpu.kernels.ring_attention import ring_mha_forward
 
     def constrain(v, o):
@@ -152,7 +152,7 @@ def pcg_forward_interpreter(
     # and has its kernels mapped over the shards; any other stays pure XLA
     # (sharded via constraints)
     multi_device = mesh is not None and mesh.size > 1
-    guard = no_flash() if multi_device else contextlib.nullcontext()
+    guard = context.no_flash() if multi_device else contextlib.nullcontext()
     with guard:
         return _interpret(
             pcg, params, inputs, shardings, constrain, train, rng, mesh,
@@ -673,12 +673,7 @@ def _try_sharded_flash_mha(attrs, data_vals, weight_vals, in_tensors,
     axes = _attention_shard_axes(attrs, in_tensors, shardings, mesh)
     if axes is None:
         return None
-    from flexflow_tpu.kernels.flash_attention import (
-        flash_mesh,
-        interpret_default,
-    )
-
-    with flash_mesh(mesh, *axes, interpret_default()):
+    with context.flash_mesh(mesh, *axes, context.interpret_default()):
         return kernel_forward(attrs, data_vals, weight_vals)
 
 
@@ -693,10 +688,6 @@ def attention_routes(pcg, shardings, mesh) -> Dict[str, str]:
     - "rows_sharded": the [b, h, s, d] kernels per batch and head shard;
     - "dense": XLA's attention, in the global view;
     - on a single device, `mha_core_route`'s own names."""
-    from flexflow_tpu.kernels.flash_attention import (
-        flash_mesh,
-        interpret_default,
-    )
     from flexflow_tpu.kernels.ops import mha_core_route
     from flexflow_tpu.op_attrs.ops import MultiHeadAttentionAttrs
 
@@ -719,7 +710,7 @@ def attention_routes(pcg, shardings, mesh) -> Dict[str, str]:
             route = "dense"
             axes = _attention_shard_axes(attrs, in_tensors, shardings, mesh)
             if axes is not None:
-                with flash_mesh(mesh, *axes, interpret_default()):
+                with context.flash_mesh(mesh, *axes, context.interpret_default()):
                     route = mha_core_route(*core_args)
             if route != "dense":
                 route = route.replace("_qkv", "") + "_sharded"

@@ -54,3 +54,15 @@ def top_k_jvp_refused(monkeypatch):
         raise AssertionError("top_k was differentiated")
 
     monkeypatch.setitem(ad.primitive_jvps, jax.lax.top_k_p, refuse)
+
+
+@pytest.fixture
+def entered():
+    """`entered(cm)` enters a context manager until the test ends (the
+    last entered leaves first): how a test opens a node's scope
+    (`kernels/context.lowering_node`) or lowers as if for a TPU
+    (`context.described_tpu`) part of the way through its body."""
+    import contextlib
+
+    with contextlib.ExitStack() as stack:
+        yield stack.enter_context

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels.flash_attention import flash_attention
 
 
@@ -807,16 +808,21 @@ CAUSAL_SCHEDULE_CASES = {
 }
 
 
-def _interpret_node_kernels(monkeypatch):
-    """Steer `kernels/ops`' gates and its fused-row entry to the Pallas
-    interpreter: a node on the CPU then takes the kernels a chip would."""
+@pytest.fixture
+def interpret_node_kernels(monkeypatch, entered):
+    """Called, steers `kernels/ops`' gates and its fused-row entry to the
+    Pallas interpreter until the test ends: a node on the CPU then takes the
+    kernels a chip would."""
     from flexflow_tpu.kernels import flash_attention as fa
 
-    monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
-    monkeypatch.setattr(
-        fa, "flash_attention_bshf",
-        functools.partial(fa.flash_attention_bshf, interpret=True),
-    )
+    def steer():
+        entered(context.described_tpu())
+        monkeypatch.setattr(
+            fa, "flash_attention_bshf",
+            functools.partial(fa.flash_attention_bshf, interpret=True),
+        )
+
+    return steer
 
 
 def _attention_node_core(rows, h, kv, dk, dv, window):
@@ -837,7 +843,7 @@ def _attention_node_core(rows, h, kv, dk, dv, window):
     return _mha_forward(attrs, *rows, weight, causal=True)
 
 
-def _causal_case(case, monkeypatch):
+def _causal_case(case, monkeypatch, interpret_node_kernels):
     """A CAUSAL_SCHEDULE_CASES entry as a namespace: `flash`, the entry on
     fused rows in interpret mode, and `dense`, the float32 reference, both
     on `qkv`, float32 [b, heads, s, d] operands already rounded to the
@@ -860,7 +866,7 @@ def _causal_case(case, monkeypatch):
         # tiles (more than the head-pair kernels' one)
         monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", str(block_q))
         monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_K", str(block_k))
-        _interpret_node_kernels(monkeypatch)
+        interpret_node_kernels()
         block_q = block_k = None
     plan = fa.causal_plan(
         b, s, h, kv, max(dk, 128), max(dv, 128), jnp.dtype(dtype).itemsize,
@@ -916,14 +922,16 @@ def _causal_case(case, monkeypatch):
 
 
 @pytest.mark.parametrize("case", sorted(CAUSAL_SCHEDULE_CASES))
-def test_flash_bshf_causal_schedule_matches_dense(case, monkeypatch):
+def test_flash_bshf_causal_schedule_matches_dense(
+    case, monkeypatch, interpret_node_kernels
+):
     """Forward and the three gradients of the causal d % 128 bshf entry
     against dense attention, over every branch of the tile schedule (dead
     tiles skipped, diagonal tiles masked, full tiles unmasked, one visit a
     tile in the backward) and every form of `causal_plan`."""
     from test_step_scopes import pallas_eqns
 
-    c = _causal_case(case, monkeypatch)
+    c = _causal_case(case, monkeypatch, interpret_node_kernels)
     flash, dense, qkv = c.flash, c.dense, c.qkv
     # k and v reach every kernel with the heads they came with (a padded
     # head of 64 is 128 lanes wide): nobody wrote them out a query head
@@ -953,7 +961,7 @@ def test_flash_bshf_causal_schedule_matches_dense(case, monkeypatch):
 
 
 def test_grouped_heads_of_64_in_one_pair_tile_are_repeated_for_the_pair_kernels(
-    monkeypatch,
+    interpret_node_kernels,
 ):
     """1,024 positions of heads of 64 are ONE tile of the head-pair kernels
     and would be two of the causal schedule's on padded heads: the node
@@ -962,7 +970,7 @@ def test_grouped_heads_of_64_in_one_pair_tile_are_repeated_for_the_pair_kernels(
     group is read. Forward and gradients against dense attention."""
     from test_step_scopes import pallas_eqns
 
-    _interpret_node_kernels(monkeypatch)
+    interpret_node_kernels()
     b, s, h, kv, d = 1, 1024, 4, 2, 64
     rs = np.random.RandomState(5)
     q, k, v = (
@@ -995,14 +1003,16 @@ def test_grouped_heads_of_64_in_one_pair_tile_are_repeated_for_the_pair_kernels(
              "wide_key_long_rows", "block_q_twice_block_k",
              "grouped_folded_batch", "grouped_folded_band"],
 )
-def test_causal_forward_lse_is_the_dense_log_sum_exp(case, monkeypatch):
+def test_causal_forward_lse_is_the_dense_log_sum_exp(
+    case, monkeypatch, interpret_node_kernels
+):
     """The forward's second output, row by row: log2 of the sum over a
     query's keys of 2 ** (score * log2(e)), [b, heads, 1, s] float32. The
     backward rebuilds every probability from it, and it leaves the kernel
     as the `[1, block_q]` row the transposed softmax carries."""
     from flexflow_tpu.kernels import flash_attention as fa
 
-    c = _causal_case(case, monkeypatch)
+    c = _causal_case(case, monkeypatch, interpret_node_kernels)
     q, k, v = c.qkv
     (b, h, s, _), kv = q.shape, k.shape[1]
     assert c.plan.group == h // kv  # k and v as they lie, a wide key's too

@@ -9,23 +9,19 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import flash_attention as fa
 from flexflow_tpu.kernels.ops import mha_core_route
 from flexflow_tpu.op_attrs.ops import MultiHeadAttentionAttrs
 
 
 @pytest.fixture
-def tpu_shaped_gate(monkeypatch):
+def tpu_shaped_gate(monkeypatch, entered):
     """The gates as a TPU sees them: they ask `jax.default_backend()`, which
     is the CPU here, and no override of the least length is set."""
     monkeypatch.delenv("FLEXFLOW_TPU_FLASH_MIN_SEQ", raising=False)
     monkeypatch.delenv("FLEXFLOW_TPU_FLASH", raising=False)
-    from flexflow_tpu.kernels import ring_flash
-
-    for module in (fa, ring_flash):  # ring_flash binds the name at import
-        monkeypatch.setattr(
-            module, "_backend_ok", lambda allow_interpret=False: True
-        )
+    entered(context.described_tpu())
 
 
 def heads_of(qkv, h):

@@ -22,6 +22,7 @@ import pytest
 from test_nemotron_h import BENCH, F32_LOSS, bench, rand
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import flash_attention as fa
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels import ssm
@@ -403,7 +404,7 @@ def node_value_and_gradients(attrs, x, weight):
         return [y, *grads]
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    with mesh, fa.flash_mesh(mesh, "data", None, True):
+    with mesh, context.flash_mesh(mesh, "data", None, True):
         return jax.jit(run)(x, weight)
 
 
@@ -411,7 +412,7 @@ def route_of(attrs, shape):
     from jax.sharding import Mesh
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
-    with fa.flash_mesh(mesh, "data", None, True):
+    with context.flash_mesh(mesh, "data", None, True):
         return mha_core_route(attrs, shape, shape, shape, True)
 
 

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import moe
 from flexflow_tpu.op_attrs.activation import Activation
 from flexflow_tpu.op_attrs.ops import ExpertsAttrs
@@ -581,26 +582,27 @@ def _cell_node(cell):
 
 
 @pytest.mark.parametrize("cell", list(CELLS))
-def test_counter_says_pallas_for_both_sums_at_the_cells_shapes(monkeypatch, cell):
-    """`trace.held_row_sums()` after an expert node is traced with the gate
-    forced (nothing runs): `pallas` for the forward's sum and the
+def test_counter_says_pallas_for_both_sums_at_the_cells_shapes(
+    monkeypatch, cell, entered
+):
+    """`trace.kernel_choices("held_row_sums")` after an expert node is traced
+    with the gate forced (nothing runs): `pallas` for the forward's sum and the
     backward's, the window's rows, the row's width and dtype and the token
     tile (of the first window, which every step runs); the `held_rows_sum`
     kernel in the traced program. On the CPU
     mesh, as it is, both say `xla` and the program has none."""
-    from flexflow_tpu.kernels import flash_attention as flash
     from flexflow_tpu.observability import trace
 
     k, held, experts, width = CELLS[cell]
     tokens, tile = CELL_TOKENS[cell]
     window = moe.held_window_rows(tokens * k, held, experts)
     grad, x, weights = _cell_node(cell)
-    monkeypatch.setattr(trace, "_HELD_ROW_SUMS", {})
-    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.on_xla", raising=False)
+    monkeypatch.setattr(context, "_CHOICES", {})
+    entered(context.lowering_node("ff.experts.on_xla"))
     text = str(jax.make_jaxpr(grad)(x, weights))
     assert "held_rows_sum" not in text
     assert len(re.findall(r"(f32|bf16)\[\d+,\d+\] = scatter-add", text)) == 2
-    noted = trace.held_row_sums()
+    noted = trace.kernel_choices("held_row_sums")
     assert list(noted) == ["ff.experts.on_xla"]
     assert noted["ff.experts.on_xla"].pop("stages") == dict.fromkeys(STAGES, "window")
     assert noted == {
@@ -611,11 +613,11 @@ def test_counter_says_pallas_for_both_sums_at_the_cells_shapes(monkeypatch, cell
         }
     }
 
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
-    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.e1")
+    entered(context.described_tpu())
+    entered(context.lowering_node("ff.experts.e1"))
     grad, x, weights = _cell_node(cell)  # a new function: traced anew
     text = str(jax.make_jaxpr(grad)(x, weights))
-    noted = trace.held_row_sums()["ff.experts.e1"]
+    noted = trace.kernel_choices("held_row_sums")["ff.experts.e1"]
     # a quarter over the uniform share: the parent's forms over the window
     assert noted.pop("stages") == dict.fromkeys(STAGES, "window")
     assert noted == {
@@ -631,8 +633,7 @@ def test_counter_says_pallas_for_both_sums_at_the_cells_shapes(monkeypatch, cell
     assert len(re.findall(r"bf16\[\d+,\d+\] = scatter-add", text)) == 1
 
 
-def test_counter_says_xla_for_a_width_of_no_whole_lane_tiles(monkeypatch):
-    from flexflow_tpu.kernels import flash_attention as flash
+def test_counter_says_xla_for_a_width_of_no_whole_lane_tiles(monkeypatch, entered):
     from flexflow_tpu.observability import trace
 
     attrs = ExpertsAttrs(
@@ -645,13 +646,13 @@ def test_counter_says_xla_for_a_width_of_no_whole_lane_tiles(monkeypatch):
         jnp.zeros((4, 192, 256), jnp.bfloat16),
         jnp.zeros((4, 256, 192), jnp.bfloat16),
     ]
-    monkeypatch.setattr(trace, "_HELD_ROW_SUMS", {})
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
-    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.odd", raising=False)
+    monkeypatch.setattr(context, "_CHOICES", {})
+    entered(context.described_tpu())
+    entered(context.lowering_node("ff.experts.odd"))
     text = str(jax.make_jaxpr(
         lambda x, weights: moe.experts_forward(attrs, x, weights)[0]
     )(x, weights))
-    noted = trace.held_row_sums()["ff.experts.odd"]
+    noted = trace.kernel_choices("held_row_sums")["ff.experts.odd"]
     assert [noted[site]["form"] for site in ("forward", "backward")] == ["xla", "xla"]
     assert noted["stages"] == dict.fromkeys(STAGES, "window")
     assert "held_rows_sum" not in text
@@ -699,13 +700,12 @@ def test_the_row_stages_are_read_from_the_gate_the_forms_the_pass_and_the_matric
     assert moe._window_stages(pallas, forms, attrs, 16, 65536, ws) == stages
 
 
-def test_counter_says_live_for_the_stages_of_a_generous_pass(monkeypatch):
+def test_counter_says_live_for_the_stages_of_a_generous_pass(monkeypatch, entered):
     """Mellum2's expert node (16 of 64 held, 8 a token, passes of 2.25 times
     the uniform share: 36,864 rows for 16,384) traced with the gate forced:
-    `trace.held_row_sums()` says `live` for every row stage and the traced
-    program holds their kernels, and no select of megablox's over a
-    window's rows; on the CPU mesh it says `window`."""
-    from flexflow_tpu.kernels import flash_attention as flash
+    `trace.kernel_choices("held_row_sums")` says `live` for every row stage
+    and the traced program holds their kernels, and no select of megablox's
+    over a window's rows; on the CPU mesh it says `window`."""
     from flexflow_tpu.observability import trace
 
     attrs = ExpertsAttrs(
@@ -725,18 +725,18 @@ def test_counter_says_live_for_the_stages_of_a_generous_pass(monkeypatch):
 
         return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, weights))
 
-    monkeypatch.setattr(trace, "_HELD_ROW_SUMS", {})
-    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.on_xla", raising=False)
+    monkeypatch.setattr(context, "_CHOICES", {})
+    entered(context.lowering_node("ff.experts.on_xla"))
     assert "name=experts_" not in grad()
-    assert trace.held_row_sums()["ff.experts.on_xla"]["stages"] == dict.fromkeys(
+    assert trace.kernel_choices("held_row_sums")["ff.experts.on_xla"]["stages"] == dict.fromkeys(
         STAGES, "window"
     )
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
-    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.moe0")
+    entered(context.described_tpu())
+    entered(context.lowering_node("ff.experts.moe0"))
     for traced_once in (moe._held_window_add, moe._held_window_grads):
         traced_once.clear_cache()  # the gate is read while tracing
     text = grad()
-    noted = trace.held_row_sums()["ff.experts.moe0"]
+    noted = trace.kernel_choices("held_row_sums")["ff.experts.moe0"]
     assert noted["stages"] == dict.fromkeys(STAGES, "live")
     assert noted["forward"]["window_rows"] == 36864
     for kernel in ("experts_hidden_fwd", "experts_hidden_bwd", "experts_cotangent"):

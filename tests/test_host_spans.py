@@ -15,6 +15,7 @@ import pytest
 
 from flexflow_tpu.analysis.lowering import lower_step_trace
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import context
 from flexflow_tpu.observability import trace
 
 STEPS, BATCH = 4, 16
@@ -372,9 +373,11 @@ BETWEEN = {
 
 
 @pytest.mark.parametrize("scope", list(BETWEEN))
-def test_the_form_of_norm_and_rotary_is_counted_by_node(monkeypatch, scope):
-    """`observability/trace.between_passes()` names the form the norm and
-    the rotary took in each plain attention node as it was lowered:
+def test_the_form_of_norm_and_rotary_is_counted_by_node(
+    monkeypatch, scope, entered
+):
+    """`observability/trace.kernel_choices("between_passes")` names the form
+    the norm and the rotary took in each plain attention node as it was lowered:
     `pallas` for the Mellum2, Ouro, OLMoE and LFM2 node forms on the
     "fused_row" route, `xla` with the rule's reason elsewhere, nothing for a
     node that has neither, nothing where no node's scope is open; and
@@ -392,24 +395,21 @@ def test_the_form_of_norm_and_rotary_is_counted_by_node(monkeypatch, scope):
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", "512")
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_K", "512")
     if forced:
-        monkeypatch.setattr(
-            flash, "_backend_ok", lambda allow_interpret=False: True
-        )
+        entered(context.described_tpu())
         monkeypatch.setattr(
             flash, "flash_attention_bshf",
             functools.partial(flash.flash_attention_bshf, interpret=True),
         )
-    monkeypatch.setattr(trace, "_BETWEEN_PASSES", {})
-    monkeypatch.setattr(trace, "_ROTARIES", {})
+    monkeypatch.setattr(context, "_CHOICES", {})
     attrs = attrs_of(form, **more)
     step, (x, flat, gains) = node_step(attrs)
 
     def forward(x, flat, gains):
         return ops._mha_forward(attrs, x, x, x, flat, causal=True, qk_gains=gains)
 
-    monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+    entered(context.lowering_node(scope))
     jax.eval_shape(forward, x, flat, gains)
-    assert trace.between_passes() == {scope: want}
+    assert trace.kernel_choices("between_passes") == {scope: want}
     assert trace.rotaries()[scope].startswith(
         "yarn factor=16" if form == "mellum2_yarn" else "default theta="
     )
@@ -418,14 +418,14 @@ def test_the_form_of_norm_and_rotary_is_counted_by_node(monkeypatch, scope):
     from flexflow_tpu.op_attrs.ops import RingAttentionAttrs
 
     bare = RingAttentionAttrs(256, 4, 128, 128, causal=True, num_kv_heads=2)
-    monkeypatch.setattr(trace._lowering, "scope", "ff.ring_attention.bare")
+    entered(context.lowering_node("ff.ring_attention.bare"))
     _, (x, flat, _) = node_step(bare)
     jax.eval_shape(
         lambda x, flat: ops._mha_forward(bare, x, x, x, flat, causal=True),
         x, flat,
     )
-    monkeypatch.setattr(trace._lowering, "scope", None)
+    entered(context.lowering_node(None))
     jax.eval_shape(forward, x, node_step(attrs)[1][1], gains)
-    assert trace.between_passes() == {scope: want}
+    assert trace.kernel_choices("between_passes") == {scope: want}
     report = trace.setup_report()
     assert f"(between_passes()): 1 {want}" in report

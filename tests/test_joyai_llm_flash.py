@@ -20,6 +20,7 @@ from test_nemotron_h import (
 )
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import flash_attention as flash
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels.loss import label_cross_entropy
@@ -380,11 +381,11 @@ def test_rotary_by_hand_and_the_deinterleaving_of_a_head_block():
     )
 
 
-def test_wide_key_route_at_8192_takes_the_forward_with_its_own_limit(monkeypatch):
+def test_wide_key_route_at_8192_takes_the_forward_with_its_own_limit(monkeypatch, entered):
     attrs = latent_attrs(bench.load_json(CONFIG + ".json"))
     shape = (1, 8192, 2048)
     assert mha_core_route(attrs, shape, shape, shape, True) == "dense"  # the CPU
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     assert mha_core_route(attrs, shape, shape, shape, True) == "fused_row"
     # [8192, 256] and [8192, 128] in bf16, double-buffered, are the budget
     def forward(s, dk, dv):

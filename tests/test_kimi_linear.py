@@ -20,6 +20,7 @@ from test_nemotron_h import (
 )
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import flash_attention as flash
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels import kda
@@ -161,17 +162,17 @@ def test_a_decay_that_overflows_exp_of_minus_g_stays_finite_and_equal():
     )
 
 
-def test_scan_route_is_read_from_shapes_and_backend(monkeypatch):
+def test_scan_route_is_read_from_shapes_and_backend(monkeypatch, entered):
     # the CPU, whatever the shape: the scan over the chunks
     assert kda.scan_route(128, 128, 64) == "xla"
     assert kda.scan_route(8, 8, 8) == "xla"
     # a TPU at the published shape (32 heads of 128, chunks of 64): the
     # Pallas kernels; toy heads stay with XLA there too
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     assert kda.scan_route(128, 128, 64) == "kda"
     assert kda.scan_route(8, 8, 8) == "xla"
     assert kda.scan_route(128, 64, 64) == "xla"
-    with flash.no_flash():
+    with context.no_flash():
         assert kda.scan_route(128, 128, 64) == "xla"
     # ONE route decides the chunks' operands and the chunk-to-chunk pass
     # alike: "kda" takes both from the kernels, "xla" the operands from
@@ -202,7 +203,7 @@ def test_scan_route_is_read_from_shapes_and_backend(monkeypatch):
     ws = [rand(rs, *s.dims, scale=0.3) for s in shapes]
     u = rand(rs, 1, 64, 16)
     kda.gated_delta_forward(attrs, u, ws)
-    with flash.no_flash():
+    with context.no_flash():
         kda.gated_delta_forward(attrs, u, ws)
     assert took == ["kernel_operands", "kda", "chunk_operands", "xla"]
 
@@ -379,7 +380,7 @@ def test_the_whole_node_on_the_kernels_agrees_with_the_xla_route(monkeypatch):
         return y, grads
 
     got = run()
-    with flash.no_flash():
+    with context.no_flash():
         want = run()
     assert routes == ["kda", "xla"]
     for grad in jax.tree_util.tree_leaves(want[1]):
@@ -597,7 +598,7 @@ def test_the_sigmoid_gated_norm_off_the_route_is_the_plain_form(monkeypatch, off
         return route, kda._gated_head_norm(attrs, route, o, x, bias, gain)
 
     if off == "no_flash":
-        with flash.no_flash():
+        with context.no_flash():
             route, got = run()
     else:
         route, got = run()
@@ -685,11 +686,11 @@ def test_latent_attention_gradients_reach_the_shared_key_slice():
     )
 
 
-def test_wide_key_route_is_read_from_shapes_and_backend(monkeypatch):
+def test_wide_key_route_is_read_from_shapes_and_backend(monkeypatch, entered):
     attrs = latent_attrs(bench.load_json(CONFIG + ".json"))
     shape = (1, 4096, 2304)
     assert mha_core_route(attrs, shape, shape, shape, True) == "dense"  # the CPU
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     # the 192-wide key on the causal tile kernels, padded to 256
     assert mha_core_route(attrs, shape, shape, shape, True) == "fused_row"
     assert flash.wide_key_padded(192) == 256

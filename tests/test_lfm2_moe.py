@@ -19,6 +19,7 @@ from test_nemotron_h import (
 )
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels.moe import experts_forward
 from flexflow_tpu.kernels.short_conv import gated_short_conv
@@ -234,14 +235,14 @@ def test_grouped_heads_with_per_head_norm_and_rotary_match_the_reference():
     )
 
 
-def test_padded_heads_route_is_read_from_shapes_and_backend(monkeypatch):
+def test_padded_heads_route_is_read_from_shapes_and_backend(monkeypatch, entered):
     from flexflow_tpu.kernels import flash_attention as flash
     from flexflow_tpu.kernels.ops import mha_core_route, mha_pads_heads
 
     attrs = attention_attrs(bench.load_json(CONFIG + ".json"))
     shape = (2, 8192, 2048)
     assert mha_core_route(attrs, shape, shape, shape, True) == "dense"  # the CPU
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     # heads of 64 over 16 causal tiles: the d % 128 tile schedule, padded
     assert mha_pads_heads(attrs, 8192)
     assert mha_core_route(attrs, shape, shape, shape, True) == "fused_row"
@@ -262,7 +263,7 @@ def test_padded_heads_route_is_read_from_shapes_and_backend(monkeypatch):
     assert mha_core_route(open_, shape, shape, shape, True) == "rows"
 
 
-def test_padded_heads_on_the_causal_tile_kernels_match_the_reference(monkeypatch):
+def test_padded_heads_on_the_causal_tile_kernels_match_the_reference(monkeypatch, entered):
     """The node as the cell runs it, in interpret mode: 4 query heads over 2
     key/value heads of 64 on two causal tiles, each head padded to 128
     lanes for `flash_attention_bshf`'s causal tile schedule, against the
@@ -281,7 +282,7 @@ def test_padded_heads_on_the_causal_tile_kernels_match_the_reference(monkeypatch
     # the per-head norm and the rotary before the core are the cell's too:
     # two heads of 64 a lane tile through `kernels/norm_rotary`, interpreted
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     # the head-pair kernels' one tile is 1,024 positions by default: at 512
     # this sequence is more than one, as the cell's 8,192 are at 1,024
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", "512")

@@ -20,6 +20,7 @@ from test_nemotron_h import (
 )
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import flash_attention as flash
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels import ops
@@ -339,7 +340,7 @@ def test_a_window_as_long_as_the_sequence_is_no_window():
     )
 
 
-def test_windowed_node_on_the_banded_kernels_matches_the_reference(monkeypatch):
+def test_windowed_node_on_the_banded_kernels_matches_the_reference(monkeypatch, entered):
     """The node as the cell runs it, in interpret mode: 8 query heads over 1
     key/value head of 128 (a group of 8) on two causal tiles under a 300-key
     window, the per-head norm and the rotary on the one key head as it lies
@@ -363,7 +364,7 @@ def test_windowed_node_on_the_banded_kernels_matches_the_reference(monkeypatch):
     # the per-head norm and the rotary before the core are the cell's too:
     # ONE Pallas pass each way (`kernels/norm_rotary`), interpreted
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", "512")
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_K", "512")
     monkeypatch.setattr(
@@ -374,14 +375,11 @@ def test_windowed_node_on_the_banded_kernels_matches_the_reference(monkeypatch):
     shape = (1, 1024, 64)
     assert ops.mha_core_route(attrs, shape, shape, shape, True) == "fused_row"
 
-    trace._lowering.scope = "ff.ring_attention.attn0"
-    try:
+    with context.lowering_node("ff.ring_attention.attn0"):
         got = jax.value_and_grad(
             lambda u, ws: jnp.sum(program_attention(kind, u, ws, sizes) * cot),
             (0, 1),
         )(u, ws)
-    finally:
-        trace._lowering.scope = None
     assert_trees_close(got, want, rtol=2e-4, atol=2e-4)
     assert trace.attention_routes()["ff.ring_attention.attn0"] == (
         "fused_row window=300 group=8"
@@ -391,7 +389,7 @@ def test_windowed_node_on_the_banded_kernels_matches_the_reference(monkeypatch):
 
 
 def test_window_node_hands_the_kernels_its_keys_and_values_as_they_lie(
-    monkeypatch,
+    monkeypatch, entered,
 ):
     """The window node at the published head shape and window (8 query heads
     over 1 key/value head of 128, a group of 8 as the cell's 32 over 4; 1,024
@@ -417,7 +415,7 @@ def test_window_node_hands_the_kernels_its_keys_and_values_as_they_lie(
     # (the norm and the rotary before the core: `kernels/norm_rotary`,
     # interpreted like the core)
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     monkeypatch.setattr(
         flash, "flash_attention_bshf",
         functools.partial(flash.flash_attention_bshf, interpret=True),
@@ -429,12 +427,9 @@ def test_window_node_hands_the_kernels_its_keys_and_values_as_they_lie(
             (0, 1),
         )(u, ws)
 
-    trace._lowering.scope = "ff.ring_attention.attn0"
-    try:
+    with context.lowering_node("ff.ring_attention.attn0"):
         jaxpr = jax.make_jaxpr(step)(u, ws).jaxpr
         got = step(u, ws)
-    finally:
-        trace._lowering.scope = None
     assert trace.attention_routes()["ff.ring_attention.attn0"] == (
         "fused_row window=1024 group=8"
     )
@@ -465,12 +460,12 @@ def test_window_node_hands_the_kernels_its_keys_and_values_as_they_lie(
     assert_trees_close(got, step(u, ws), rtol=2e-5, atol=2e-5)
 
 
-def test_a_windowed_node_never_takes_a_route_without_a_band(monkeypatch):
+def test_a_windowed_node_never_takes_a_route_without_a_band(monkeypatch, entered):
     """At the published shape the windowed node takes the causal tile
     kernels; where an unwindowed node of the same shapes would take the
     head-pair, fused-qkv or per-head kernels (none has a band) the windowed
     one takes the mask on XLA's attention; a forced route raises."""
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     shape = (1, 8192, 2304)
     attrs = attention_attrs("sliding_attention", PUBLISHED)
     assert ops.mha_core_route(attrs, shape, shape, shape, True) == "fused_row"

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import flash_attention as flash
 from flexflow_tpu.kernels import norm_rotary as nr
 from flexflow_tpu.kernels import ops
@@ -297,7 +298,7 @@ def test_rule(case):
     want = (want, ops._pass_form(attrs) if want == "pallas" else None)
     if meshed:
         mesh = jax.sharding.Mesh(np.array(jax.devices()[:2]), ("data",))
-        with flash.flash_mesh(mesh, ("data",), None):
+        with context.flash_mesh(mesh, ("data",), None):
             assert ops.between_form(attrs, route, rows) == want
     else:
         assert ops.between_form(attrs, route, rows) == want
@@ -414,12 +415,12 @@ def node_step(attrs, rows=1024, seed=3):
 
 
 @pytest.fixture
-def fused_row_on_the_cpu(monkeypatch, interpreted):
+def fused_row_on_the_cpu(monkeypatch, interpreted, entered):
     """The "fused_row" route on the CPU: the gates told a TPU is there, the
     causal core interpreted over tiles of 512."""
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_Q", "512")
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_BLOCK_K", "512")
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     monkeypatch.setattr(
         flash, "flash_attention_bshf",
         functools.partial(flash.flash_attention_bshf, interpret=True),
@@ -448,13 +449,8 @@ def body_traces(monkeypatch):
 
 
 def lowered_under(scope, step, case):
-    from flexflow_tpu.observability import trace
-
-    trace._lowering.scope = scope
-    try:
+    with context.lowering_node(scope):
         return jax.make_jaxpr(step)(*case).jaxpr
-    finally:
-        trace._lowering.scope = None
 
 
 def test_q_and_k_of_one_shape_are_one_trace(fused_row_on_the_cpu, body_traces):
@@ -476,7 +472,7 @@ def test_q_and_k_of_one_shape_are_one_trace(fused_row_on_the_cpu, body_traces):
         "norm_rotary_bwd", "norm_rotary_bwd", "norm_rotary_fwd",
         "norm_rotary_fwd",
     ]
-    assert trace.between_passes()["ff.ring_attention.attn0"] == "pallas"
+    assert trace.kernel_choices("between_passes")["ff.ring_attention.attn0"] == "pallas"
     assert "norm and rotary of the plain attention nodes" in trace.setup_report()
 
 
@@ -560,10 +556,10 @@ def test_a_node_with_nothing_between_emits_what_it_emitted_before(
 
     attrs = RingAttentionAttrs(256, 4, 128, 128, causal=True, num_kv_heads=2)
     assert ops.between_form(attrs, "fused_row", 1024) == (None, None)
-    monkeypatch.setattr(trace, "_BETWEEN_PASSES", {})
+    monkeypatch.setattr(context, "_CHOICES", {})
     step, case = node_step(attrs)
     jaxpr = lowered_under("ff.ring_attention.plain", step, case)
-    assert trace.between_passes() == {}
+    assert trace.kernel_choices("between_passes") == {}
     assert sorted(eqn.params["name"] for eqn in pallas_eqns(jaxpr)) == [
         "flash_bwd_causal_bshf", "flash_delta_bshf", "flash_fwd_causal_bshf",
     ]

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import moe as moe_kernels
 from flexflow_tpu.kernels.moe import (
     aggregate_forward,
@@ -642,15 +643,14 @@ def test_grouped_matmul_vjp_with_a_tile_a_call(k, n, tiles, rest):
 
 @pytest.mark.parametrize("held", [(4, 4), None], ids=["held_share", "all_rows"])
 def test_grouped_matmul_tiles_counter_names_what_a_lowered_node_took(
-    monkeypatch, held
+    monkeypatch, held, entered
 ):
-    """`trace.grouped_matmul_tiles()` after an expert node is traced for the
-    kernels (the backend gate forced; nothing runs): under the node's scope,
+    """`trace.kernel_choices("grouped_matmul_tiles")` after an expert node is
+    traced for the kernels (the backend gate forced; nothing runs): under the node's scope,
     for each of `w1`, `w3`, `w2` and each of the three calls, the call's own
     shape (the input gradient contracts over the forward's columns), the
     tile `_gmm_tiles` gives it and 1.0 padded over true. A node on
     `ragged_dot` notes nothing."""
-    from flexflow_tpu.kernels import flash_attention as flash
     from flexflow_tpu.observability import trace
     from flexflow_tpu.op_attrs.activation import Activation
 
@@ -672,15 +672,15 @@ def test_grouped_matmul_tiles_counter_names_what_a_lowered_node_took(
     def loss(x, weights):
         return jnp.sum(experts_forward(attrs, x, weights)[0].astype(jnp.float32))
 
-    monkeypatch.setattr(trace, "_GROUPED_MATMUL_TILES", {})
-    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.on_xla", raising=False)
+    monkeypatch.setattr(context, "_CHOICES", {})
+    entered(context.lowering_node("ff.experts.on_xla"))
     jax.make_jaxpr(loss)(x, weights)
-    assert trace.grouped_matmul_tiles() == {}
+    assert trace.kernel_choices("grouped_matmul_tiles") == {}
 
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
-    monkeypatch.setattr(trace._lowering, "scope", "ff.experts.e1")
+    entered(context.described_tpu())
+    entered(context.lowering_node("ff.experts.e1"))
     text = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1)))(x, weights))
-    noted = trace.grouped_matmul_tiles()
+    noted = trace.kernel_choices("grouped_matmul_tiles")
     assert list(noted) == ["ff.experts.e1"]
     entries = noted["ff.experts.e1"]
     assert sorted(entries) == sorted(

@@ -24,6 +24,7 @@ if BENCH not in sys.path:
 import run as bench  # noqa: E402  (benchmark/run.py: the harness's loaders)
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel  # noqa: E402
+from flexflow_tpu.kernels import context  # noqa: E402
 from flexflow_tpu.kernels import forward as kernel_forward  # noqa: E402
 from flexflow_tpu.kernels.moe import experts_forward, route  # noqa: E402
 from flexflow_tpu.op_attrs.activation import Activation  # noqa: E402
@@ -96,7 +97,7 @@ SIZES = {"toy": TOY, "kernels": KERNEL_TOY}
 @pytest.fixture
 def interpreted_kernels(monkeypatch):
     """The opt-in under which the CPU backend runs the Pallas kernels in
-    interpret mode (`flash_attention.interpret_default`)."""
+    interpret mode (`context.interpret_default`)."""
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
 
 
@@ -239,7 +240,7 @@ def test_scan_kernels_map_over_the_batch_shards_of_a_declared_mesh(
     one-device kernels'."""
     from jax.sharding import Mesh
 
-    from flexflow_tpu.kernels.flash_attention import flash_mesh
+    from flexflow_tpu.kernels.context import flash_mesh
 
     operands = scan_operands(128, jnp.float32)
     cot = rand(np.random.RandomState(4), *operands[0].shape)
@@ -250,7 +251,7 @@ def test_scan_kernels_map_over_the_batch_shards_of_a_declared_mesh(
     assert_trees_close(got, want, rtol=1e-5, atol=1e-5)
 
 
-def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
+def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch, entered):
     """The published widths take the kernels on a TPU, one row or many; toy
     widths, a chunk of 8, a CPU without the interpret opt-in and a trace
     under `no_flash()` take `_scan_core`; a group wider than a program holds
@@ -259,7 +260,6 @@ def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
     batch shards if the heads are whole and the batch divides."""
     from jax.sharding import Mesh
 
-    from flexflow_tpu.kernels import flash_attention as fa
     from flexflow_tpu.kernels.ssm import scan_route
 
     cell = dict(batch=1, heads=64, head_dim=64, groups=8, state=128, chunk=128)
@@ -267,7 +267,7 @@ def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     assert scan_route(**cell) == "ssd"
     monkeypatch.delenv("FLEXFLOW_TPU_FLASH_INTERPRET")
-    monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     assert scan_route(**cell) == "ssd"
     assert scan_route(**dict(cell, batch=8)) == "ssd"
     for other in (
@@ -278,14 +278,14 @@ def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
         assert scan_route(**dict(cell, **other)) == "xla", other
     # 32 heads of 64 a group: 2,048 columns, two column blocks of 1,024
     assert scan_route(**dict(cell, groups=2)) == "ssd"
-    with fa.no_flash():
+    with context.no_flash():
         assert scan_route(**cell) == "xla"
     mesh = Mesh(np.array(jax.devices()[:2]), ("data",))
-    with fa.flash_mesh(mesh, "data", None, False), fa.no_flash():
+    with context.flash_mesh(mesh, "data", None, False), context.no_flash():
         assert scan_route(**dict(cell, batch=4)) == "ssd_sharded"
         assert scan_route(**dict(cell, batch=3)) == "xla"
         assert scan_route(**dict(cell, batch=4, groups=2)) == "xla"
-    with fa.flash_mesh(mesh, None, "data", False):
+    with context.flash_mesh(mesh, None, "data", False):
         assert scan_route(**dict(cell, batch=4)) == "xla"
 
 
@@ -364,7 +364,7 @@ def test_step_moves_no_routing_value_an_element_at_a_time(top_k_jvp_refused):
     }
 
 
-def test_lowered_step_holds_the_scan_kernels_and_no_mask_tensor(monkeypatch):
+def test_lowered_step_holds_the_scan_kernels_and_no_mask_tensor(monkeypatch, entered):
     """Each of the tower's two state-space layers calls the forward kernel,
     the state pass and the backward kernel once (the jitted callers
     `_ssd_forward` and `_ssd_backward`; their bodies, `ssd_fwd_chunk`,
@@ -373,11 +373,10 @@ def test_lowered_step_holds_the_scan_kernels_and_no_mask_tensor(monkeypatch):
     their product) is left in the program, which the XLA form has."""
     import re
 
-    from flexflow_tpu.kernels import flash_attention as fa
 
     mask = re.compile(r"tensor<(?:\d+x)+128x128xf32>")
     assert mask.findall(lowered_tower_step(False))  # the XLA form
-    monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     text = lowered_tower_step(True)
     assert sorted(
         name for name in re.findall(r'kernel_name = "(\w+)"', text)

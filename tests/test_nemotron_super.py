@@ -23,6 +23,7 @@ from test_nemotron_h import (
 from test_olmoe import weight_keys
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels.moe import experts_forward, held_window_rows
 from flexflow_tpu.op_attrs.activation import Activation
@@ -337,12 +338,11 @@ def g16_operands(seq, dtype, seed=3):
     )
 
 
-def test_scan_route_gives_a_group_of_16_heads_the_kernels(monkeypatch):
-    from flexflow_tpu.kernels import flash_attention as fa
+def test_scan_route_gives_a_group_of_16_heads_the_kernels(monkeypatch, entered):
     from flexflow_tpu.kernels.ssm import scan_route
 
     assert scan_route(1, 16, 64, 1, 128, 128) == "xla"  # the CPU, no opt-in
-    monkeypatch.setattr(fa, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     assert scan_route(1, 16, 64, 1, 128, 128) == "ssd"
     assert scan_route(1, 128, 64, 8, 128, 128) == "ssd"  # the uncut mixer
     assert scan_route(1, 32, 64, 1, 128, 128) == "ssd"  # two column blocks (PR 68)

@@ -19,6 +19,7 @@ from test_nemotron_h import (
 )
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import flash_attention as flash
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels import selective_scan as s6
@@ -216,7 +217,7 @@ def test_scan_route_is_read_from_shapes_backend_and_trace(monkeypatch):
     assert s6.scan_route(256, 16) == "pallas"
     assert s6.scan_route(64, 16) == "scan"  # channels fill no lane tile
     assert s6.scan_route(256, 4) == "scan"  # a state of no whole sublane tile
-    with flash.no_flash():
+    with context.no_flash():
         assert s6.scan_route(256, 16) == "scan"
 
 
@@ -450,7 +451,7 @@ def test_a_dropped_second_map_is_outside_the_tolerance():
     assert float(jnp.max(jnp.abs(got - dropped))) > 100 * F32["atol"]
 
 
-def test_the_kernel_core_matches_the_dense_core_with_the_band(monkeypatch):
+def test_the_kernel_core_matches_the_dense_core_with_the_band(monkeypatch, entered):
     """At kernel widths (4 query heads of 64 over a 128-wide value, 1,024
     positions, a 300-key window) the node on the causal tile kernels in
     interpret mode against the same node on XLA's dense attention with the
@@ -467,18 +468,15 @@ def test_the_kernel_core_matches_the_dense_core_with_the_band(monkeypatch):
     dense = kernel_forward(attrs, [u, u, u], ws)[0]
     import functools
 
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     monkeypatch.setattr(
         flash, "flash_attention_bshf",
         functools.partial(flash.flash_attention_bshf, interpret=True),
     )
     assert mha_core_route(attrs, u.shape, u.shape, u.shape, False) == "fused_row"
 
-    trace._lowering.scope = "ff.ring_attention.attn1"
-    try:
+    with context.lowering_node("ff.ring_attention.attn1"):
         kernels = kernel_forward(attrs, [u, u, u], ws)[0]
-    finally:
-        trace._lowering.scope = None
     # float32 on both sides; the online softmax sums in another order
     np.testing.assert_allclose(kernels, dense, rtol=2e-5, atol=2e-5)
     assert trace.attention_routes()["ff.ring_attention.attn1"] == (

@@ -26,7 +26,7 @@ from test_nemotron_h import (
 )
 
 from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
-from flexflow_tpu.kernels import flash_attention as flash
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import forward as kernel_forward
 from flexflow_tpu.kernels import kda
 from flexflow_tpu.kernels.moe import experts_forward
@@ -356,7 +356,7 @@ def test_the_silu_gated_norm_off_the_route_is_the_plain_form(monkeypatch, off):
         return route, kda._gated_head_norm(attrs, route, o, z, None, gain)
 
     if off == "no_flash":
-        with flash.no_flash():
+        with context.no_flash():
             route, got = run()
     else:
         route, got = run()
@@ -435,7 +435,7 @@ def test_the_whole_head_decay_node_on_the_kernels_agrees_with_the_xla_route(
         return y, grads
 
     got = run()
-    with flash.no_flash():
+    with context.no_flash():
         want = run()
     assert routes == ["kda", "xla"]
     for grad in jax.tree_util.tree_leaves(want[1]):
@@ -443,8 +443,8 @@ def test_the_whole_head_decay_node_on_the_kernels_agrees_with_the_xla_route(
     assert_trees_close(got, want, **F32_GRADS)
 
 
-def test_the_operands_form_is_counted_by_node(monkeypatch):
-    """`observability/trace.delta_rule_operands()` names the form each
+def test_the_operands_form_is_counted_by_node(monkeypatch, entered):
+    """`observability/trace.kernel_choices("delta_rule_operands")` names the form each
     delta-rule node was lowered with: the scalar form's kernels where
     `scan_route` says "kda", reading q, k and v in place over whole chunks
     (`gdn0`-`gdn2`, as the cell's three nodes) and padded copies of them over
@@ -452,15 +452,15 @@ def test_the_operands_form_is_counted_by_node(monkeypatch):
     CPU, and the per-channel form's two names likewise."""
     attrs, u, ws, _ = kernel_sized_node(64)
     channel = GatedDeltaAttrs(2, 128, 128, 4, 8, 64, 1e-5)
-    monkeypatch.setattr(trace, "_DELTA_RULE_OPERANDS", {})
+    monkeypatch.setattr(context, "_CHOICES", {})
 
     def lowered_as(scope, node=attrs, u=u):
-        monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+        entered(context.lowering_node(scope))
         if node is attrs:
             jax.eval_shape(lambda u, ws: kda.gated_delta_forward(attrs, u, ws), u, ws)
         else:
             kda.operand_form(node, kda.scan_route(128, 128, 64), 64)
-        return trace.delta_rule_operands()[scope]
+        return trace.kernel_choices("delta_rule_operands")[scope]
 
     assert lowered_as("ff.kda.on_the_cpu") == "head_xla"
     assert lowered_as("ff.kda.on_the_cpu", channel) == "xla"
@@ -469,17 +469,17 @@ def test_the_operands_form_is_counted_by_node(monkeypatch):
         assert lowered_as(f"ff.kda.{name}") == "head_kernels_in_place"
     assert lowered_as("ff.kda.padded", u=u[:, :36]) == "head_kernels"
     assert lowered_as("ff.kda.kda0", channel) == "channel_kernels"
-    with flash.no_flash():
+    with context.no_flash():
         assert lowered_as("ff.kda.gdn1") == "head_xla"
-    assert trace.delta_rule_operands() == {
+    assert trace.kernel_choices("delta_rule_operands") == {
         "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "head_kernels_in_place",
         "ff.kda.gdn2": "head_kernels_in_place", "ff.kda.padded": "head_kernels",
         "ff.kda.kda0": "channel_kernels", "ff.kda.gdn1": "head_xla",
     }
     # a kernel called by itself, under no node's scope, is not counted
-    monkeypatch.setattr(trace._lowering, "scope", None)
+    entered(context.lowering_node(None))
     kda.operand_form(attrs, "kda", 64)
-    assert len(trace.delta_rule_operands()) == 6
+    assert len(trace.kernel_choices("delta_rule_operands")) == 6
 
 
 def _main_calls(text):
@@ -499,7 +499,7 @@ def _main_calls(text):
     return defined, calls
 
 
-def test_the_lowered_node_hands_the_pieces_from_kernel_to_kernel(monkeypatch):
+def test_the_lowered_node_hands_the_pieces_from_kernel_to_kernel(monkeypatch, entered):
     """The node lowered for the TPU, forward and backward, at whole chunks:
     q and k reach `gdn_prep_fwd` (forward and recomputed) and `gdn_prep_bwd`,
     and v `kda_corrected_fwd` / `kda_corrected_bwd`, as the very results of
@@ -509,7 +509,7 @@ def test_the_lowered_node_hands_the_pieces_from_kernel_to_kernel(monkeypatch):
     `reshape`, `convert` or `pad` of q, k, v or their cotangents lies
     between the convolution's and the recurrence's kernels (a Pallas operand
     must be a buffer: XLA writes out whatever lies between)."""
-    monkeypatch.setattr(flash, "_backend_ok", lambda allow_interpret=False: True)
+    entered(context.described_tpu())
     attrs, u, ws, cot = kernel_sized_node(128)
 
     def node(u, ws, cot):
@@ -557,8 +557,8 @@ def test_the_lowered_node_hands_the_pieces_from_kernel_to_kernel(monkeypatch):
         assert names.count(once) == 1, names
 
 
-def test_the_triangular_products_form_is_counted_by_node(monkeypatch):
-    """`observability/trace.triangular_products()` names the form the
+def test_the_triangular_products_form_is_counted_by_node(monkeypatch, entered):
+    """`observability/trace.kernel_choices("triangular_products")` names the form the
     products around the triangular inverse took in each delta-rule node:
     `kernels` on the "kda" route for both forms of the decay, `xla` under
     `no_flash()`, on the plain CPU and where the route's number of
@@ -571,18 +571,18 @@ def test_the_triangular_products_form_is_counted_by_node(monkeypatch):
             TensorShape(u.shape, DataType.FLOAT)
         )
     ]
-    monkeypatch.setattr(trace, "_TRIANGULAR_PRODUCTS", {})
+    monkeypatch.setattr(context, "_CHOICES", {})
 
     def lowered_as(scope, node=attrs, ws=ws, u=u):
-        monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+        entered(context.lowering_node(scope))
         jax.eval_shape(lambda u, ws: kda.gated_delta_forward(node, u, ws), u, ws)
-        return trace.triangular_products()[scope]
+        return trace.kernel_choices("triangular_products")[scope]
 
     assert lowered_as("ff.kda.on_the_cpu") == "xla"
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     assert lowered_as("ff.kda.gdn0") == "kernels"
     assert lowered_as("ff.kda.kda0", channel, channel_ws) == "kernels"
-    with flash.no_flash():
+    with context.no_flash():
         assert lowered_as("ff.kda.gdn1") == "xla"
         assert lowered_as("ff.kda.kda1", channel, channel_ws) == "xla"
     # one value head over three chunks: a count the kernels do not take
@@ -599,20 +599,20 @@ def test_the_triangular_products_form_is_counted_by_node(monkeypatch):
     assert lowered_as("ff.kda.gdn2", odd, odd_ws, odd_u) == "xla"
     # q and k still go to `gdn_prep_*` where they lie; v is turned heads
     # first for XLA's form alone
-    assert trace.delta_rule_operands()["ff.kda.gdn2"] == "head_kernels_in_place"
-    assert trace.triangular_products() == {
+    assert trace.kernel_choices("delta_rule_operands")["ff.kda.gdn2"] == "head_kernels_in_place"
+    assert trace.kernel_choices("triangular_products") == {
         "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "kernels",
         "ff.kda.kda0": "kernels", "ff.kda.gdn1": "xla", "ff.kda.kda1": "xla",
         "ff.kda.gdn2": "xla",
     }
     # a kernel called by itself, under no node's scope, is not counted
-    monkeypatch.setattr(trace._lowering, "scope", None)
+    entered(context.lowering_node(None))
     kda._kernel_corrected(*triangular_case((1, 1, 2), jnp.float32)[0])
-    assert len(trace.triangular_products()) == 6
+    assert len(trace.kernel_choices("triangular_products")) == 6
 
 
-def test_the_head_norm_form_is_counted_by_node(monkeypatch):
-    """`observability/trace.head_norms()` names the form the heads' norm
+def test_the_head_norm_form_is_counted_by_node(monkeypatch, entered):
+    """`observability/trace.kernel_choices("head_norms")` names the form the heads' norm
     under its gate took in each delta-rule node: `kernels` on the "kda" route
     for both gates, `xla` under `no_flash()` and on the plain CPU."""
     attrs, u, ws, _ = kernel_sized_node(64)
@@ -623,29 +623,29 @@ def test_the_head_norm_form_is_counted_by_node(monkeypatch):
             TensorShape(u.shape, DataType.FLOAT)
         )
     ]
-    monkeypatch.setattr(trace, "_HEAD_NORMS", {})
+    monkeypatch.setattr(context, "_CHOICES", {})
 
     def lowered_as(scope, node=attrs, ws=ws):
-        monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+        entered(context.lowering_node(scope))
         jax.eval_shape(lambda u, ws: kda.gated_delta_forward(node, u, ws), u, ws)
-        return trace.head_norms()[scope]
+        return trace.kernel_choices("head_norms")[scope]
 
     assert lowered_as("ff.kda.on_the_cpu") == "xla"
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     assert lowered_as("ff.kda.gdn0") == "kernels"
     assert lowered_as("ff.kda.kda0", channel, channel_ws) == "kernels"
-    with flash.no_flash():
+    with context.no_flash():
         assert lowered_as("ff.kda.gdn1") == "xla"
         assert lowered_as("ff.kda.kda1", channel, channel_ws) == "xla"
-    assert trace.head_norms() == {
+    assert trace.kernel_choices("head_norms") == {
         "ff.kda.on_the_cpu": "xla", "ff.kda.gdn0": "kernels",
         "ff.kda.kda0": "kernels", "ff.kda.gdn1": "xla", "ff.kda.kda1": "xla",
     }
     # a norm called by itself, under no node's scope, is not counted
-    monkeypatch.setattr(trace._lowering, "scope", None)
+    entered(context.lowering_node(None))
     o = jnp.ones((1, 4, 8, 128), jnp.float32)
     kda._gated_head_norm(attrs, "xla", o, o.reshape(1, 8, 512), None, ws[5])
-    assert len(trace.head_norms()) == 5
+    assert len(trace.kernel_choices("head_norms")) == 5
 
 
 # -- the gated grouped-query attention node --------------------------------------

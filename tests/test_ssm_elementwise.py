@@ -18,7 +18,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from flexflow_tpu.kernels import flash_attention as flash
+from flexflow_tpu.kernels import context
 from flexflow_tpu.kernels import ssm
 from flexflow_tpu.kernels.ssm import conv_silu, gated_group_norm
 from flexflow_tpu.observability import trace
@@ -129,7 +129,7 @@ def conv_pair(widths, seq, dtype, monkeypatch):
         )
 
     def plain(*operands):
-        with flash.no_flash():
+        with context.no_flash():
             return conv(*operands)
 
     return (
@@ -283,9 +283,9 @@ def test_conv_route_takes_the_kernels_only_where_they_apply(monkeypatch):
     assert ssm.conv_route(64, 768, 64, TAPS) == "xla"
     assert ssm.conv_route(0, 768, 40, TAPS) == "xla"
     assert ssm.conv_route(0, 768, 64, 10) == "xla"
-    with flash.no_flash():
+    with context.no_flash():
         assert ssm.conv_route(0, 768, 64, TAPS) == "xla"
-    with flash.flash_mesh(None, ("data",), None):
+    with context.flash_mesh(None, ("data",), None):
         assert ssm.conv_route(0, 768, 64, TAPS) == "xla"
     assert ssm.conv_route(0, 768, 64, TAPS) == "kernels"
     # the widest column block that divides both the first column and the
@@ -298,29 +298,29 @@ def test_conv_route_takes_the_kernels_only_where_they_apply(monkeypatch):
     assert ssm.conv_route(0, 768, 64, TAPS, (512, 192, 64)) == "xla"
 
 
-def test_the_convolutions_form_is_counted_by_node(monkeypatch):
-    """`observability/trace.conv_forms()` names the form `conv_silu` took in
-    each node that has one: `kernels` where `conv_route` says so, `xla` on
+def test_the_convolutions_form_is_counted_by_node(monkeypatch, entered):
+    """`observability/trace.kernel_choices("conv_forms")` names the form
+    `conv_silu` took in each node that has one: `kernels` where `conv_route` says so, `xla` on
     the plain CPU and under `no_flash()`; a call under no node's scope is
     not counted."""
     (x, w, b), _ = conv_case(256, 64, jnp.bfloat16)
-    monkeypatch.setattr(trace, "_CONV_FORMS", {})
+    monkeypatch.setattr(context, "_CHOICES", {})
 
     def lowered_as(scope, bias=b[:128]):
-        monkeypatch.setattr(trace._lowering, "scope", scope, raising=False)
+        entered(context.lowering_node(scope))
         jax.eval_shape(lambda x, w: conv_silu(x, w, bias, 128), x, w[:, :128])
-        return trace.conv_forms()[scope]
+        return trace.kernel_choices("conv_forms")[scope]
 
     assert lowered_as("ff.ssm.on_the_cpu") == "xla"
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     assert lowered_as("ff.ssm.m0") == "kernels"
     assert lowered_as("ff.kda.gdn0", None) == "kernels"
-    with flash.no_flash():
+    with context.no_flash():
         assert lowered_as("ff.selective_scan.s0") == "xla"
-    assert trace.conv_forms() == {
+    assert trace.kernel_choices("conv_forms") == {
         "ff.ssm.on_the_cpu": "xla", "ff.ssm.m0": "kernels",
         "ff.kda.gdn0": "kernels", "ff.selective_scan.s0": "xla",
     }
-    monkeypatch.setattr(trace._lowering, "scope", None)
+    entered(context.lowering_node(None))
     conv_silu(x, w, b)
-    assert len(trace.conv_forms()) == 4
+    assert len(trace.kernel_choices("conv_forms")) == 4

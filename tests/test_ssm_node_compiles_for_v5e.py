@@ -210,15 +210,12 @@ def compiled_node(name):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from flexflow_tpu.kernels import flash_attention as fa
     from flexflow_tpu.kernels import ssm
     from flexflow_tpu.op_attrs.datatype import DataType
     from flexflow_tpu.op_attrs.ops.ssm import StateSpaceAttrs
     from flexflow_tpu.op_attrs.tensor_shape import TensorShape
 
     jax.config.update("jax_enable_compilation_cache", False)
-    # `scan_route` asks the backend; nothing runs here, so say a TPU is there
-    fa._backend_ok = lambda allow_interpret=False: True
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
     hidden, sizes = dict(SHAPES, granite=GRANITE_SHAPE)[name]
@@ -401,17 +398,15 @@ KIMI_INVARIANTS = [
 
 @functools.lru_cache(maxsize=None)
 def _described_chip():
-    """(a bf16 `ShapeDtypeStruct` on the described v5e for `dims`): `scan_route`
-    asks the backend and nothing runs here, so say a TPU is there."""
+    """(a bf16 `ShapeDtypeStruct` on the described v5e for `dims`). The gates
+    ask the backend and nothing runs here: the process says a TPU is there
+    (`context.described_tpu`, `__main__`)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from flexflow_tpu.kernels import flash_attention as fa
-
     jax.config.update("jax_enable_compilation_cache", False)
-    fa._backend_ok = lambda allow_interpret=False: True
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     chip = SingleDeviceSharding(topo.devices[0])
     return lambda dims: jax.ShapeDtypeStruct(
@@ -1374,7 +1369,7 @@ def check_between():
     node keeps the plain form and says why."""
     import jax
 
-    from flexflow_tpu.kernels import ops
+    from flexflow_tpu.kernels import context, ops
     from flexflow_tpu.observability import trace
     from flexflow_tpu.op_attrs.core import get_weight_shapes
     from flexflow_tpu.op_attrs.datatype import DataType
@@ -1403,12 +1398,9 @@ def check_between():
                 out, vjp = jax.vjp(node, *operands)
                 return out, vjp(out)
 
-            trace._lowering.scope = scope
-            try:
+            with context.lowering_node(scope):
                 text = jax.jit(both).lower(x, *ws).compile().as_text()
-                said = trace.between_passes().get(scope)
-            finally:
-                trace._lowering.scope = None
+            said = trace.kernel_choices("between_passes").get(scope)
             names = sorted(re.findall(r"/(norm_rotary_\w+)/pallas_call", text))
             # (the same kernel's call sites may share one custom call's name)
             want = {"norm_rotary_bwd", "norm_rotary_fwd"}
@@ -1768,41 +1760,45 @@ if __name__ == "__main__":
         del argv[at:at + 2]
     sys.path.insert(0, root)
     _bind_parser()
-    if argv and argv[0] == "joyai_step":
-        print(json.dumps(joyai_step_bytes(int(argv[1]), root)))
-    elif argv and argv[0] == "phi4flash_step":
-        print(json.dumps(cell_step_bytes("phi4miniflash_s4096_1chip", root)))
-    elif argv and argv[0] == "mellum2_step":
-        print(json.dumps(cell_step_bytes("mellum2_12b_s8192_1chip", root)))
-    elif argv and argv[0] == "ouro_step":
-        # `ouro_step <layers>`: the looped cell's whole step at that depth
-        layers = int(argv[1]) if len(argv) > 1 else None
-        cut = {} if layers is None else {
-            "num_hidden_layers": layers,
-            "layer_types": ["full_attention"] * layers,
-        }
-        print(json.dumps(cell_step_bytes("ouro26b_s8192_1chip", root, **cut)))
-    elif argv and argv[0] == "granite_step":
-        print(json.dumps(cell_step_bytes("granite4hmicro_s4096_1chip", root)))
-    elif argv and argv[0] == "qwen3next_account":
-        print(json.dumps(gdn_node_account()))
-    elif argv and argv[0] == "qwen3next_step":
-        print(json.dumps(cell_step_bytes("qwen3next80b_s8192_1chip", root)))
-    elif argv and argv[0] == "granite":
-        print(json.dumps(check_granite()))
-    elif argv and argv[0] == "mellum2":
-        print(json.dumps(check_mellum2()))
-    elif argv and argv[0] == "between":
-        print(json.dumps(check_between()))
-    elif argv:
-        print(listing(argv[0]))
-    else:
-        print(json.dumps(
-            dict({name: check(name) for name in SHAPES}, kimi=check_kimi(),
-                 lfm2=check_lfm2(), experts=check_experts(),
-                 held_sums=check_held_sums(), qwen3next=check_qwen3next(),
-                 joyai=check_joyai(), phi4flash=check_phi4flash(),
-                 mellum2=check_mellum2(), granite=check_granite(),
-                 between=check_between(),
-                 account=check_account(root))
-        ))
+    from flexflow_tpu.kernels import context
+
+    # nothing runs in this process: every gate is asked as on the chip
+    with context.described_tpu():
+        if argv and argv[0] == "joyai_step":
+            print(json.dumps(joyai_step_bytes(int(argv[1]), root)))
+        elif argv and argv[0] == "phi4flash_step":
+            print(json.dumps(cell_step_bytes("phi4miniflash_s4096_1chip", root)))
+        elif argv and argv[0] == "mellum2_step":
+            print(json.dumps(cell_step_bytes("mellum2_12b_s8192_1chip", root)))
+        elif argv and argv[0] == "ouro_step":
+            # `ouro_step <layers>`: the looped cell's whole step at that depth
+            layers = int(argv[1]) if len(argv) > 1 else None
+            cut = {} if layers is None else {
+                "num_hidden_layers": layers,
+                "layer_types": ["full_attention"] * layers,
+            }
+            print(json.dumps(cell_step_bytes("ouro26b_s8192_1chip", root, **cut)))
+        elif argv and argv[0] == "granite_step":
+            print(json.dumps(cell_step_bytes("granite4hmicro_s4096_1chip", root)))
+        elif argv and argv[0] == "qwen3next_account":
+            print(json.dumps(gdn_node_account()))
+        elif argv and argv[0] == "qwen3next_step":
+            print(json.dumps(cell_step_bytes("qwen3next80b_s8192_1chip", root)))
+        elif argv and argv[0] == "granite":
+            print(json.dumps(check_granite()))
+        elif argv and argv[0] == "mellum2":
+            print(json.dumps(check_mellum2()))
+        elif argv and argv[0] == "between":
+            print(json.dumps(check_between()))
+        elif argv:
+            print(listing(argv[0]))
+        else:
+            print(json.dumps(
+                dict({name: check(name) for name in SHAPES}, kimi=check_kimi(),
+                     lfm2=check_lfm2(), experts=check_experts(),
+                     held_sums=check_held_sums(), qwen3next=check_qwen3next(),
+                     joyai=check_joyai(), phi4flash=check_phi4flash(),
+                     mellum2=check_mellum2(), granite=check_granite(),
+                     between=check_between(),
+                     account=check_account(root))
+            ))

@@ -61,7 +61,7 @@ def run_case(name, batch, seq, heads, same_qkv, mesh=None):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from flexflow_tpu.kernels.flash_attention import flash_mesh
+    from flexflow_tpu.kernels.context import flash_mesh
     from flexflow_tpu.kernels.ops import forward
     from flexflow_tpu.op_attrs.ops import MultiHeadAttentionAttrs
     from flexflow_tpu.op_attrs.tensor_shape import TensorShape

@@ -79,42 +79,40 @@ def main():
     jax.config.update("jax_enable_compilation_cache", False)
     from flexflow_tpu.analysis import lowering
     from flexflow_tpu.core import AdamOptimizer, FFConfig, FFModel
-    from flexflow_tpu.kernels import flash_attention, ring_flash
+    from flexflow_tpu.kernels import context
 
     # the gates ask `jax.default_backend()`; steer them here, not by an option
-    flash_attention._backend_ok = lambda allow_interpret=False: True
-    ring_flash._backend_ok = flash_attention._backend_ok
-
-    module = bench.load_module(spec["module_path"])
-    training = config["training"]
-    batch = job["batch_per_chip"] * job["chips"]
-    graph, logits = module.build(config, batch, job["seq"])
-    model = FFModel.from_computation_graph(
-        graph, logits,
-        FFConfig(batch_size=batch, seed=1, print_freq=0,
-                 max_devices=job["chips"], **job.get("ffconfig", {})),
-    )
-    model.compile(
-        AdamOptimizer(
-            alpha=training["alpha"], beta1=training["beta1"],
-            beta2=training["beta2"], epsilon=training["epsilon"],
-            weight_decay=training["weight_decay"],
-        ),
-        training["loss"], compute_dtype=jnp.dtype(training["compute_dtype"]),
-    )
-    instance = model.instance
-    example = (
-        lowering.step_example_args if hasattr(instance, "pcg")
-        else lowering.step_example_args_cg
-    )(instance, model.loss_attrs)
-    mesh = (
-        instance.machine_mesh.mesh if hasattr(instance, "machine_mesh")
-        else contextlib.nullcontext()
-    )
-    with mesh:
-        text = instance.compiled_step().trace(
-            model.params, model.opt_state, *example
-        ).lower(lowering_platforms=("tpu",)).as_text()
+    with context.described_tpu():
+        module = bench.load_module(spec["module_path"])
+        training = config["training"]
+        batch = job["batch_per_chip"] * job["chips"]
+        graph, logits = module.build(config, batch, job["seq"])
+        model = FFModel.from_computation_graph(
+            graph, logits,
+            FFConfig(batch_size=batch, seed=1, print_freq=0,
+                     max_devices=job["chips"], **job.get("ffconfig", {})),
+        )
+        model.compile(
+            AdamOptimizer(
+                alpha=training["alpha"], beta1=training["beta1"],
+                beta2=training["beta2"], epsilon=training["epsilon"],
+                weight_decay=training["weight_decay"],
+            ),
+            training["loss"], compute_dtype=jnp.dtype(training["compute_dtype"]),
+        )
+        instance = model.instance
+        example = (
+            lowering.step_example_args if hasattr(instance, "pcg")
+            else lowering.step_example_args_cg
+        )(instance, model.loss_attrs)
+        mesh = (
+            instance.machine_mesh.mesh if hasattr(instance, "machine_mesh")
+            else contextlib.nullcontext()
+        )
+        with mesh:
+            text = instance.compiled_step().trace(
+                model.params, model.opt_state, *example
+            ).lower(lowering_platforms=("tpu",)).as_text()
     if args.out:
         with open(args.out, "w") as f:
             f.write(text)
