@@ -64,28 +64,34 @@ decides the chunks' operands and the chunk-to-chunk pass alike:
   `dt_bias`'s and `a_log`'s. Every exponent is the product of a constant 0/1
   matrix with g (the set of positions whose log-decays it sums), so it is
   <= 0 by construction, float32-exact in three bf16 passes, and its transpose
-  is the way back to g. `kda_prep_inverse` is `unit_lower_inverse`'s levels with two
-  chunks side by side along the lanes. *The triangular system*
-  (`corrected_products`, one `jax.custom_vjp` for both forms of the decay):
-  `kda_corrected_fwd` takes the inverse X, beta, K exp(G) and V of sixteen
-  chunk-heads a program and writes T (K exp(G)) and T V, T = X Diag(beta);
-  `kda_corrected_bwd` is the WRITTEN backward of the whole system from X
-  and the two cotangents dW, dU. With Y_w = X^T dW, Y_u = X^T dU and
-  M = Y_w (K exp(G))^T + Y_u V^T, a [Q, Q] matrix: d(K exp(G)) =
-  Diag(beta) Y_w, dV = Diag(beta) Y_u, dbeta = diag(M) for T's column
-  scaling, and dn = -M T^T under the diagonal for the inverse's input
-  n = Diag(beta) A. That is `unit_lower_inverse`'s dn = -X^T dX X^T with
-  X^T dX X^T = (X^T dT) T^T substituted (equally -(Y_w W^T + Y_u U^T), W
-  and U the forward's results, which the backward never forms): THREE
-  products a chunk-head where differentiating `_corrected` runs six, and
-  the cotangents of T and X exist nowhere. Every product keeps every term
-  `HIGHEST` keeps: a float32 operand goes in three bf16 parts, one that IS
-  bf16 (V, dW, dU in a bf16 step) in one, its other parts being exactly
-  zero (`_split_product`). Diag(beta) A and its cotangents stay XLA's, and
-  so does the whole of `_corrected` with `unit_lower_inverse` where the
-  kernels do not take the call: an odd number of chunk-heads (the
-  inverse's kernel takes them two by two) and everything on the "xla"
-  route. `trace.kernel_choices("triangular_products")` says which, by node.
+  is the way back to g. *The triangular system* (`corrected_products`, one
+  `jax.custom_vjp` for both forms of the decay): its float32 [Q, Q] arrays
+  cross HBM TWO chunk-heads to a row, [.., c / 2, Q, 2Q] (whole 128-lane
+  rows at Q = 64: `_side_by_side`), from the kernel that makes each to the
+  kernel that reads it: A from the operands' kernel, the inverse X from
+  `kda_prep_inverse` (`unit_lower_inverse`'s levels on the pair as it lies,
+  n = Diag(beta) A formed in VMEM), dA from `kda_corrected_bwd` back into
+  the operands' backward. `kda_corrected_fwd` takes X, beta, K exp(G) and V
+  of sixteen chunk-heads a program and writes T (K exp(G)) and T V,
+  T = X Diag(beta); `kda_corrected_bwd` is the WRITTEN backward of the
+  whole system from X, A and the two cotangents dW, dU. With Y_w = X^T dW,
+  Y_u = X^T dU and M = Y_w (K exp(G))^T + Y_u V^T, a [Q, Q] matrix:
+  d(K exp(G)) = Diag(beta) Y_w, dV = Diag(beta) Y_u, dn = -M T^T under the
+  diagonal for the inverse's input n, dA = Diag(beta) dn, and dbeta =
+  diag(M) (T's column scaling) + sum_j dn_rj A_rj (n's rows). dn is
+  `unit_lower_inverse`'s dn = -X^T dX X^T with X^T dX X^T = (X^T dT) T^T
+  substituted (equally -(Y_w W^T + Y_u U^T), W and U the forward's results,
+  which the backward never forms): THREE products a chunk-head where
+  differentiating `_corrected` runs six, and n, dn and the cotangents of T
+  and X exist nowhere in HBM. Every product keeps every term `HIGHEST`
+  keeps: a float32 operand goes in three bf16 parts, one that IS bf16 (V,
+  dW, dU in a bf16 step) in one, its other parts being exactly zero
+  (`_split_product`). The whole of `_corrected` with `unit_lower_inverse`
+  stays XLA's where the kernels do not take the call: an odd number of
+  chunks a head (the system's kernels take a head's chunks two by two; A
+  is then a chunk a row) and everything on the "xla" route.
+  `trace.kernel_choices("triangular_products")` says which, by node, and
+  `kernel_choices("triangular_layout")` `pairs` beside `kernels`.
   *The pass* (`kda_fwd_chunk`, `kda_states_chunk`, `kda_bwd_chunk`): one
   program is one (batch row, head, chunk); the chunk axis is sequential and
   the head's [dv, dk] float32 state (held transposed, so that the
@@ -133,10 +139,11 @@ or backward. `gdn_prep_fwd` takes q and k RAW, puts them over their own
 2-norm in VMEM (`_unit_root`: float32, one rounding to the input's dtype,
 q times dk ** -0.5), computes the two products once a chunk of a KEY head
 and, for each value head that reads it, the mask and the three decay vectors,
-eight chunks a program, and writes Q exp(G), K exp(G_Q - G), P, exp(G_Q), the
-strictly lower A and K exp(G) heads first and by chunk, as `chunk_scan`
-reads them; `gdn_prep_bwd` is its WRITTEN backward (`head_chunk_scores`, one
-`jax.custom_vjp`), which recomputes the norms, the products and the decays
+eight chunks a program, and writes Q exp(G), K exp(G_Q - G), P, exp(G_Q) and
+K exp(G) heads first and by chunk, as `chunk_scan` reads them, and the
+strictly lower A two chunks to a row (`_side_by_side`); `gdn_prep_bwd` is
+its WRITTEN backward (`head_chunk_scores`, one `jax.custom_vjp`), which
+recomputes the norms, the products and the decays
 from the raw q, k and g, sums the value heads' cotangents of the normalised q
 and k in the program, takes them through the norm in float32
 (`_unit_cotangent`) and writes dq and dk as column blocks of [b, s, hk * dk],
@@ -750,6 +757,38 @@ def _stacked_parts(x):
     return jnp.concatenate(_bf16_parts(x), axis=0)
 
 
+def _side_by_side(chunks: int) -> int:
+    """How many of a program's chunks lay their float32 [Q, Q] of the
+    triangular system (A, its cotangent) side by side along the lanes: two
+    where the program's chunks go two by two, so that a row of the array in
+    HBM is 2 Q = 128 whole lanes ([.., c / 2, Q, 2 Q]: chunk 2 p in lanes
+    [0, Q), chunk 2 p + 1 in [Q, 2 Q), as `kda_prep_inverse` holds a pair);
+    one where a head's chunks are odd in number ([.., c, Q, Q], which only
+    XLA's `_corrected` reads: `_kernel_corrected`)."""
+    return 2 - chunks % 2
+
+
+def _beside(i, half: int, q: int):
+    """Where chunk `side * i + half`'s [Q, Q] lies in a program's block of
+    chunks side by side: row block i (a loop index), lanes `half` (static)."""
+    return pl.ds(pl.multiple_of(i * q, q), q), slice(half * q, (half + 1) * q)
+
+
+def _each_chunk(chunks: int, one_chunk, carry=None):
+    """`one_chunk(c, i, half, carry)` -> carry for each of a program's
+    `chunks`, unrolled, `_side_by_side(chunks)` to a row: c = side * i +
+    half, i the loop's index and `half` static (`_beside`). The body is
+    traced once a half."""
+    side = _side_by_side(chunks)
+
+    def one_row(i, carry):
+        for half in range(side):
+            carry = one_chunk(side * i + half, i, half, carry)
+        return carry
+
+    return lax.fori_loop(0, chunks // side, one_row, carry, unroll=True)
+
+
 def _chunk_decays(sums_ref, g, q: int):
     """piece -> exp of that piece's sums of the chunk's log-decays g [q, dk],
     float32 [q, dk]; every exponent is <= 0."""
@@ -777,16 +816,16 @@ def _kda_prep_fwd_kernel(
     qd_ref, ke_ref, p_ref, gam_ref, a_ref, kd_ref, *, chunks: int, scale: float,
 ):
     """Q exp(G), K exp(G_Q - G), P, exp(G_Q), the strictly lower key-against-
-    key scores A and K exp(G) (float32, for the triangular system) of each of
-    the program's chunks, from the RAW q, k and decay pre-activation of the
-    head (`_raw_gates`)."""
+    key scores A (`_side_by_side`) and K exp(G) (float32, for the triangular
+    system) of each of the program's chunks, from the RAW q, k and decay
+    pre-activation of the head (`_raw_gates`)."""
     f32 = jnp.float32
     dtype = q_ref.dtype
     q = owner_ref.shape[0]
     levels = sums_ref.shape[0] // q - _FIRST_LEVEL
     owner = owner_ref[:]
 
-    def one_chunk(c, _):
+    def one_chunk(c, i, half, _):
         rows = pl.ds(pl.multiple_of(c * q, q), q)
         qn, kn, g, _ = _raw_gates(
             q_ref, k_ref, f_ref, bias_ref, rate_ref, rows, scale
@@ -808,9 +847,9 @@ def _kda_prep_fwd_kernel(
             p = jnp.where(mine, _mm((qf * e).astype(dtype), kz, _NT), p)
             a = jnp.where(mine, _mm(kz, kz, _NT), a)
         p_ref[rows, :] = p.astype(dtype)
-        a_ref[rows, :] = a
+        a_ref[_beside(i, half, q)] = a
 
-    lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
+    _each_chunk(chunks, one_chunk)
 
 
 def _unit_cotangent(x, root, dy, scale: float):
@@ -845,7 +884,7 @@ def _kda_prep_bwd_kernel(
     levels = sums_ref.shape[0] // q - _FIRST_LEVEL
     owner = owner_ref[:]
 
-    def one_chunk(c, sums):
+    def one_chunk(c, i, half, sums):
         rows = pl.ds(pl.multiple_of(c * q, q), q)
         qn, kn, g, (q_root, k_root, log_slope) = _raw_gates(
             q_ref, k_ref, f_ref, bias_ref, rate_ref, rows, scale
@@ -865,7 +904,7 @@ def _kda_prep_bwd_kernel(
         dk = dkd * from_start + dke * to_end
         to_g(_FROM_START, (dqd * qf + dkd * kf) * from_start)
         to_g(_TO_END, dke * kf * to_end)
-        dp, da = dp_ref[rows, :].astype(f32), da_ref[rows, :]
+        dp, da = dp_ref[rows, :].astype(f32), da_ref[_beside(i, half, q)]
         own = jnp.where(owner == levels, dp, 0.0).astype(dtype)
         dq = dq + _mm(own, kn, _NN)
         dk = dk + _mm(own, qn, _NN)
@@ -905,9 +944,7 @@ def _kda_prep_bwd_kernel(
         )
 
     zero = jnp.zeros(bias_ref.shape, f32)
-    dbias_ref[:], dalog_ref[:] = lax.fori_loop(
-        0, chunks, one_chunk, (zero, zero), unroll=True
-    )
+    dbias_ref[:], dalog_ref[:] = _each_chunk(chunks, one_chunk, (zero, zero))
 
 
 _PARALLEL_CHUNKS = pltpu.CompilerParams(
@@ -930,17 +967,21 @@ class _PrepBlocks:
     key channels is ONE 128-lane column block (`head`; k's lie `heads`
     blocks after q's), so no heads-first copy of q, k or the decay's
     pre-activation exists; a row of the per-channel vectors [1, heads * dk]
-    is cut the same way. The results are [b, h, s, .]."""
+    is cut the same way. The results are [b, h, s, .], A and its cotangent
+    (`beside`) `side` chunks side by side, [b, h, s / side, side * Q]."""
 
     def __init__(self, b: int, h: int, s: int, dk: int, q: int):
         c = s // q
         n = self.chunks = next(n for n in _PREP_CHUNKS if c % n == 0)
+        side = self.side = _side_by_side(n)
         self.grid = (b, h, c // n)
 
-        def rows(width):
+        def rows(width, count=n * q):
             return pl.BlockSpec(
-                (None, None, n * q, width), lambda bi, hi, gi: (bi, hi, gi, 0)
+                (None, None, count, width), lambda bi, hi, gi: (bi, hi, gi, 0)
             )
+
+        self.beside = rows(side * q, n * q // side)
 
         def head(first):
             return pl.BlockSpec(
@@ -974,8 +1015,9 @@ def _prep_forward(qkv, f_up, dt_bias, a_log, chunk, interpret):
     """The kernel on the convolved q | k | v [b, s, 2 * h * dk + h * dv] (its
     q and k columns are read), the decay's pre-activation f_up [b, s, h * dk],
     dt_bias [h * dk] and a_log [h]; by chunk out ([b, h, c, Q, .]): qd, ke,
-    p, gamma as `chunk_operands` has them, then A [., Q, Q] and K exp(G)
-    [., Q, dk], float32."""
+    p, gamma as `chunk_operands` has them, then A (`_side_by_side`:
+    [b, h, c / 2, Q, 2 Q] where the chunks are even in number, [., Q, Q]
+    otherwise) and K exp(G) [., Q, dk], float32."""
     f32 = jnp.float32
     b, s, width = f_up.shape
     h = a_log.shape[0]
@@ -993,11 +1035,12 @@ def _prep_forward(qkv, f_up, dt_bias, a_log, chunk, interpret):
         ),
         grid=at.grid,
         in_specs=[_table(sums), _table(owner), *at.raw],
-        out_specs=[at.key, at.key, at.scores, at.gamma, at.scores, at.key],
+        out_specs=[at.key, at.key, at.scores, at.gamma, at.beside, at.key],
         out_shape=[
             rows(dk, qkv.dtype), rows(dk, qkv.dtype), rows(chunk, qkv.dtype),
             jax.ShapeDtypeStruct((b, h, c, 1, dk), f32),
-            rows(chunk, f32), rows(dk, f32),
+            jax.ShapeDtypeStruct((b, h, s // at.side, at.side * chunk), f32),
+            rows(dk, f32),
         ],
         compiler_params=_PARALLEL_CHUNKS,
         interpret=interpret,
@@ -1006,7 +1049,7 @@ def _prep_forward(qkv, f_up, dt_bias, a_log, chunk, interpret):
       *_raw_inputs(qkv, f_up, dt_bias, a_log))
 
     def by_chunk(t):
-        return t.reshape(b, h, c, chunk, t.shape[-1])
+        return t.reshape(b, h, -1, chunk, t.shape[-1])
 
     return by_chunk(qd), by_chunk(ke), by_chunk(p), gamma, by_chunk(a), by_chunk(kd)
 
@@ -1030,7 +1073,7 @@ def _prep_backward(qkv, f_up, dt_bias, a_log, cotangents, chunk, interpret):
         grid=at.grid,
         in_specs=[
             _table(sums), _table(sums_t), _table(owner), *at.raw,
-            at.key, at.key, at.scores, at.gamma, at.scores, at.key,
+            at.key, at.key, at.scores, at.gamma, at.beside, at.key,
         ],
         out_specs=[at.head, at.head, at.head, at.partial, at.partial],
         out_shape=[
@@ -1077,12 +1120,23 @@ def _chunk_scores_bwd(chunk, kept, cotangents):
 chunk_scores.defvjp(_chunk_scores_fwd, _chunk_scores_bwd)
 
 
+# The triangular system's float32 [Q, Q] arrays (A, Diag(beta) A, the inverse
+# X, the cotangents dn and dA) cross HBM TWO chunk-heads to a row,
+# [.., c / 2, Q, 2 Q]: chunk-head 2 p in lanes [0, Q), 2 p + 1 in [Q, 2 Q)
+# (`_side_by_side`), whole 128-lane rows at Q = 64 where a [rows, 64] float32
+# array is padded to twice its bytes and streams at a fifth of the HBM's rate.
+# The operands' kernels write A so and read dA so, `kda_prep_inverse` reads A
+# and writes X so, `kda_corrected_fwd` / `kda_corrected_bwd` read X and A and
+# write dA so; Diag(beta) A, dn, Diag(beta) dn and beta's share of dn exist in
+# VMEM alone, and no XLA pass stands between two of the kernels. beta
+# [.., c, Q] IS that layout when read two chunk-heads a row, [.., c / 2, 2 Q].
+#
 # The triangular inverse as a kernel (`unit_lower_inverse`'s levels, its
-# products float32 with every term `HIGHEST` keeps): TWO chunks side by side
-# along the lanes ([Q, 2Q]: a float32 [Q, Q] fills half of each vreg), the
-# right-hand operand of a product block-diagonal [2Q, 2Q], so that one MXU
-# pass multiplies both chunks at full depth and width and every vector
-# operation works on whole vregs.
+# products float32 with every term `HIGHEST` keeps) works on the pair as it
+# lies ([Q, 2Q]: a float32 [Q, Q] fills half of each vreg), the right-hand
+# operand of a product block-diagonal [2Q, 2Q], so that one MXU pass
+# multiplies both chunks at full depth and width and every vector operation
+# works on whole vregs.
 
 
 def _pair_product(lhs, rhs, first):
@@ -1100,70 +1154,88 @@ def _pair_product(lhs, rhs, first):
     return by_r1[:q] + ((by_r1[q:2 * q] + by_r2[:q]) + small)
 
 
-def _kda_inverse_kernel(owner_ref, eye_ref, n_ref, x_ref, *, pairs: int):
-    """(I + n)^-1 of each of the program's pairs of chunks, up the levels
-    from the narrowest; `owner_ref` and `eye_ref` [Q, 2Q] hold `_prep_tables`'
-    owner and the identity for both chunks of a pair."""
+def _down_the_rows(steps, diagonal):
+    """[Q, 1] of steps [1, Q]: a chunk-head's beta lies along the lanes (T's
+    columns) and scales ROWS here; one term and zeros a sum, so exact."""
+    return jnp.sum(jnp.where(diagonal, steps, 0.0), axis=1, keepdims=True)
+
+
+def _kda_inverse_kernel(owner_ref, eye_ref, a_ref, beta_ref, x_ref, *,
+                        pairs: int):
+    """(I + Diag(beta) A)^-1 of each of the program's pairs of chunk-heads,
+    up the levels from the narrowest: a and x [pairs Q, 2Q], beta
+    [pairs, 2Q] (the section's comment); `owner_ref` and `eye_ref` [Q, 2Q]
+    hold `_prep_tables`' owner and the identity for both chunks of a pair."""
     q = owner_ref.shape[0]
     narrowest = q.bit_length() - 2
     owner = owner_ref[:]
     first = lax.broadcasted_iota(jnp.int32, (q, 2 * q), 1) < q
+    diagonal = owner[:, :q] == narrowest + 1
 
     def one_pair(c, _):
-        one = pl.ds(pl.multiple_of(2 * c * q, 2 * q), q)
-        other = pl.ds(pl.multiple_of(2 * c * q, 2 * q) + q, q)
-        n = jnp.concatenate([n_ref[one, :], n_ref[other, :]], axis=1)
+        rows = pl.ds(pl.multiple_of(c * q, q), q)
+        beta = beta_ref[pl.ds(c, 1), :]
+        n = a_ref[rows, :] * jnp.where(
+            first, _down_the_rows(beta[:, :q], diagonal),
+            _down_the_rows(beta[:, q:], diagonal),
+        )
         # the first level's X is the identity, and I L I = L: no product
         x = eye_ref[:] - jnp.where(owner == narrowest, n, 0.0)
         for level in range(narrowest - 1, -1, -1):
             below = jnp.where(owner == level, n, 0.0)
             x = x - _pair_product(_pair_product(x, below, first), x, first)
-        x_ref[one, :] = x[:, :q]
-        x_ref[other, :] = x[:, q:]
+        x_ref[rows, :] = x
 
     lax.fori_loop(0, pairs, one_pair, None, unroll=True)
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _pallas_inverse(n, interpret):
-    """`unit_lower_inverse` of n [.., Q, Q] float32 over an even number of
-    chunks, four pairs a program where they divide."""
-    q = n.shape[-1]
-    chunks = n.size // (q * q)
-    pairs = next(p for p in (4, 2, 1) if chunks % (2 * p) == 0)
+@functools.partial(jax.jit, static_argnums=(2,))
+def _pallas_inverse(a, beta, interpret):
+    """`unit_lower_inverse` of Diag(beta) A, pairs in and pairs out: a
+    [.., c / 2, Q, 2Q] float32 and beta [.., c, Q], four pairs a program
+    where they divide."""
+    q = a.shape[-2]
+    count = a.size // (2 * q * q)
+    pairs = next(p for p in (4, 2, 1) if count % p == 0)
     owner = np.tile(_prep_tables(q)[2], (1, 2))
     eye = np.tile(np.eye(q, dtype=np.float32), (1, 2))
-    rows = pl.BlockSpec((2 * pairs * q, q), lambda i: (i, 0))
+    rows = pl.BlockSpec((pairs * q, 2 * q), lambda i: (i, 0))
 
     x = pl.pallas_call(
         functools.partial(_kda_inverse_kernel, pairs=pairs),
-        grid=(chunks // (2 * pairs),),
-        in_specs=[_table(owner), _table(eye), rows],
+        grid=(count // pairs,),
+        in_specs=[
+            _table(owner), _table(eye), rows,
+            pl.BlockSpec((None, pairs, 2 * q), lambda i: (i, 0, 0)),
+        ],
         out_specs=rows,
-        out_shape=jax.ShapeDtypeStruct((chunks * q, q), n.dtype),
+        out_shape=jax.ShapeDtypeStruct((count * q, 2 * q), a.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
         interpret=interpret,
         name="kda_prep_inverse",
-    )(jnp.asarray(owner), jnp.asarray(eye), n.reshape(chunks * q, q))
-    return x.reshape(n.shape)
+    )(jnp.asarray(owner), jnp.asarray(eye), a.reshape(count * q, 2 * q),
+      beta.reshape(-1, pairs, 2 * q))
+    return x.reshape(a.shape)
 
 
 # The two products around the inverse, W = T (K exp(G)) and U = T V with
 # T = X Diag(beta), and the WRITTEN backward of the whole triangular system
 # as kernels: `n` chunk-heads a program, unrolled, every operand of one
-# chunk-head in VMEM. With Y_w = X^T dW, Y_u = X^T dU and
-# M = Y_w Kd^T + Y_u V^T, a [Q, Q] matrix, the cotangents are
+# chunk-head in VMEM, X, A and dA a pair of chunk-heads a row block (a lane
+# half of a block in VMEM is a chunk-head's: `_beside`). With Y_w = X^T dW,
+# Y_u = X^T dU and M = Y_w Kd^T + Y_u V^T, a [Q, Q] matrix, the cotangents are
 #
 #     dKd = Diag(beta) Y_w     dV = Diag(beta) Y_u
-#     dbeta = diag(M)          (T's column scaling alone)
 #     dn = -M T^T              strictly under the diagonal
+#     dA = Diag(beta) dn       (n = Diag(beta) A)
+#     dbeta = diag(M) + sum_j dn_rj A_rj      (T's columns, then n's rows)
 #
-# the last `unit_lower_inverse`'s dn = -X^T dX X^T with dX = dT Diag(beta),
+# dn `unit_lower_inverse`'s dn = -X^T dX X^T with dX = dT Diag(beta),
 # dT = dW Kd^T + dU V^T, so that X^T dX X^T = (X^T dT) T^T = M T^T; it is
 # -(Y_w W^T + Y_u U^T) with W = T Kd and U = T V, which the backward never
 # forms. Three products a chunk-head (Y, M, dn) where differentiating
-# `_corrected` runs six, and dT, dX and X^T dX exist nowhere. Every product
-# is float32 with every term `HIGHEST` keeps (`_split_product`).
+# `_corrected` runs six, and dT, dX, X^T dX and dn reach HBM nowhere. Every
+# product is float32 with every term `HIGHEST` keeps (`_split_product`).
 
 
 def _parts(x):
@@ -1199,13 +1271,15 @@ def _split_product(lhs, rhs, dims):
 def _kda_corrected_fwd_kernel(x_ref, beta_ref, kd_ref, v_ref, w_ref, uv_ref,
                               *, heads: int):
     """W = T Kd and U = T V, T = X Diag(beta), of each of the program's
-    chunk-heads: x [n Q, Q] and kd [n Q, dk] float32, v [n Q, dv], beta
-    [n, Q] (a chunk-head's steps along the lanes, as T's columns lie)."""
-    q = x_ref.shape[1]
+    chunk-heads: x [n / 2 Q, 2Q] (pairs) and kd [n Q, dk] float32, v
+    [n Q, dv], beta [n / 2, 2Q] (a chunk-head's steps along the lanes, as
+    T's columns lie)."""
+    q = x_ref.shape[1] // 2
 
-    def one(c, _):
+    def one(c, i, half, _):
         rows = pl.ds(pl.multiple_of(c * q, q), q)
-        t = _parts(x_ref[rows, :] * beta_ref[pl.ds(c, 1), :])
+        pair, lanes = _beside(i, half, q)
+        t = _parts(x_ref[pair, lanes] * beta_ref[pl.ds(i, 1), lanes])
         w_ref[rows, :] = _split_product(
             t, _parts(kd_ref[rows, :]), _NN
         ).astype(w_ref.dtype)
@@ -1213,72 +1287,82 @@ def _kda_corrected_fwd_kernel(x_ref, beta_ref, kd_ref, v_ref, w_ref, uv_ref,
             t, _parts(v_ref[rows, :]), _NN
         ).astype(uv_ref.dtype)
 
-    lax.fori_loop(0, heads, one, None, unroll=True)
+    _each_chunk(heads, one)
 
 
 def _kda_corrected_bwd_kernel(
-    x_ref, beta_ref, kd_ref, v_ref, dw_ref, duv_ref,
-    dn_ref, dbeta_ref, dkd_ref, dv_ref, yw_ref, yu_ref, m_ref, *, heads: int,
+    x_ref, a_ref, beta_ref, kd_ref, v_ref, dw_ref, duv_ref,
+    da_ref, dbeta_ref, dkd_ref, dv_ref, yw_ref, yu_ref, m_ref, *, heads: int,
 ):
-    """The cotangents of n (of X = (I + n)^-1), beta (through T's columns),
-    Kd and V from those of W and U, of each of the program's chunk-heads
-    (the section's comment): one sweep over the chunk-heads a product, Y and M
-    between the sweeps in VMEM (`yw_ref`, `yu_ref` [n Q, .] and `m_ref`
-    [n Q, Q] float32), so that a sweep is `heads` short independent chains
-    and not one long one a chunk-head."""
-    q = x_ref.shape[1]
+    """The cotangents of A (through n = Diag(beta) A and X = (I + n)^-1),
+    beta (through T's columns and n's rows), Kd and V from those of W and U,
+    of each of the program's chunk-heads (the section's comment): one sweep
+    over the chunk-heads a product, Y and M between the sweeps in VMEM
+    (`yw_ref`, `yu_ref` [n Q, .] and `m_ref` [n Q, Q] float32), so that a
+    sweep is `heads` short independent chains and not one long one a
+    chunk-head."""
+    q = x_ref.shape[1] // 2
     r = lax.broadcasted_iota(jnp.int32, (q, q), 0)
-    i = lax.broadcasted_iota(jnp.int32, (q, q), 1)
-    diagonal, below = r == i, i < r
+    j = lax.broadcasted_iota(jnp.int32, (q, q), 1)
+    diagonal, below = r == j, j < r
 
     def rows_of(c):
         return pl.ds(pl.multiple_of(c * q, q), q)
 
-    def from_the_left(c, _):  # Y = X^T dW | X^T dU
+    def steps_of(i, half):  # [1, Q]: T's columns
+        return pl.ds(i, 1), _beside(i, half, q)[1]
+
+    def from_the_left(c, i, half, _):  # Y = X^T dW | X^T dU
         rows = rows_of(c)
-        beta = beta_ref[pl.ds(c, 1), :]  # [1, Q]: T's columns
-        by_row = jnp.sum(jnp.where(diagonal, beta, 0.0), axis=1, keepdims=True)
-        xs = _parts(x_ref[rows, :])
+        by_row = _down_the_rows(beta_ref[steps_of(i, half)], diagonal)
+        xs = _parts(x_ref[_beside(i, half, q)])
         y_w = _split_product(xs, _parts(dw_ref[rows, :]), _TN)
         y_u = _split_product(xs, _parts(duv_ref[rows, :]), _TN)
         yw_ref[rows, :], yu_ref[rows, :] = y_w, y_u
         dkd_ref[rows, :] = by_row * y_w
         dv_ref[rows, :] = (by_row * y_u).astype(dv_ref.dtype)
 
-    def against_the_operands(c, _):  # M = Y_w Kd^T + Y_u V^T
+    def against_the_operands(c, i, half, _):  # M = Y_w Kd^T + Y_u V^T
         rows = rows_of(c)
         m = _split_product(
             _parts(yw_ref[rows, :]), _parts(kd_ref[rows, :]), _NT
         ) + _split_product(_parts(yu_ref[rows, :]), _parts(v_ref[rows, :]), _NT)
         m_ref[rows, :] = m
-        dbeta_ref[pl.ds(c, 1), :] = jnp.sum(
+        dbeta_ref[steps_of(i, half)] = jnp.sum(
             jnp.where(diagonal, m, 0.0), axis=0, keepdims=True
         )
 
-    def from_the_right(c, _):  # dn = -M T^T
-        rows = rows_of(c)
-        t = x_ref[rows, :] * beta_ref[pl.ds(c, 1), :]
-        dn = _split_product(_parts(m_ref[rows, :]), _parts(t), _NT)
-        dn_ref[rows, :] = jnp.where(below, -dn, 0.0)
+    def from_the_right(c, i, half, _):  # dn = -M T^T, and what n hands on
+        pair = _beside(i, half, q)
+        beta = beta_ref[steps_of(i, half)]
+        dn = jnp.where(below, -_split_product(
+            _parts(m_ref[rows_of(c), :]), _parts(x_ref[pair] * beta), _NT
+        ), 0.0)
+        da_ref[pair] = _down_the_rows(beta, diagonal) * dn
+        by_row = jnp.sum(dn * a_ref[pair], axis=1, keepdims=True)
+        dbeta_ref[steps_of(i, half)] += jnp.sum(
+            jnp.where(diagonal, by_row, 0.0), axis=0, keepdims=True
+        )
 
     for sweep in (from_the_left, against_the_operands, from_the_right):
-        lax.fori_loop(0, heads, sweep, None, unroll=True)
+        _each_chunk(heads, sweep)
 
 
 # chunk-heads a program of the two kernels above: the largest that divides
-# their number, which is even (the inverse's kernel takes them two by two),
-# or, where v is read in the model's layout, the chunks of one head
-_CORRECTED_HEADS = (16, 8, 4, 2, 1)
+# their number, which is even (X, A and dA hold them two by two), or, where
+# v is read in the model's layout, the chunks of one head
+_CORRECTED_HEADS = (16, 8, 4, 2)
 
 
 class _CorrectedBlocks:
     """The BlockSpecs over the one grid axis, `n` chunk-heads a program: what
-    a chunk-head has, [count * Q, width], its rows; beta and its cotangent
-    [count / n, n, Q] a program's rows. With `in_place` = (heads, chunks a
-    head) v and its cotangent lie as the MODEL has them, [b, s, heads * dv]
-    (`value`): a program's chunk-heads are then chunks of ONE head, n the
-    largest that divides a head's chunks, and a head of dv = 128 value
-    channels one 128-lane column block of their rows."""
+    a chunk-head has, [count * Q, width], its rows; X, A and dA
+    [count / 2 * Q, 2Q] the rows of the program's pairs (`pairs`); beta and
+    its cotangent [count / n, n / 2, 2Q] a program's rows. With `in_place` =
+    (heads, chunks a head) v and its cotangent lie as the MODEL has them,
+    [b, s, heads * dv] (`value`): a program's chunk-heads are then chunks of
+    ONE head, n the largest that divides a head's chunks, and a head of
+    dv = 128 value channels one 128-lane column block of their rows."""
 
     def __init__(self, count: int, q: int, dk: int, dv: int, itemsize: int,
                  in_place=None):
@@ -1288,17 +1372,22 @@ class _CorrectedBlocks:
         self.dv = dv
         self.grid = (count // n,)
         self.block_rows = n * q
-        self.steps = pl.BlockSpec((None, n, q), lambda i: (i, 0, 0))
+        self.pairs = pl.BlockSpec((n // 2 * q, 2 * q), lambda i: (i, 0))
+        self.steps = pl.BlockSpec((None, n // 2, 2 * q), lambda i: (i, 0, 0))
         programs = chunks // n  # of one head
         self.value = pl.BlockSpec(
             (None, n * q, dv),
             lambda i: (i // (heads * programs), i % programs,
                        i // programs % heads),
         ) if in_place else self.rows(dv)
-        # the backward's blocks (x and dn fill 128 lanes; kd, dkd float32; v,
-        # dw, duv, dv in the model's dtype), twice for the pipeline's two
-        # buffers, and as much again for Y, M and what the body holds
-        block = n * q * (2 * 4 * _LANES + 2 * 4 * dk + itemsize * (dk + 3 * dv))
+        # the backward's blocks (x, a and da two chunk-heads to a row of at
+        # least 128 lanes; kd, dkd float32; v, dw, duv, dv in the model's
+        # dtype), twice for the pipeline's two buffers, and as much again
+        # for Y, M and what the body holds
+        block = n * q * (
+            3 * 4 * max(2 * q, _LANES) // 2 + 2 * 4 * dk
+            + itemsize * (dk + 3 * dv)
+        )
         self.between = [
             pltpu.VMEM((n * q, width), jnp.float32) for width in (dk, dv, q)
         ]
@@ -1314,9 +1403,13 @@ class _CorrectedBlocks:
         """v as the kernels take it: where it lies, or its rows flattened."""
         return v if self.in_place else _flat(v)
 
+    def steps_taken(self, beta):
+        """beta [.., c, Q] as the kernels take it, a program's pairs' rows."""
+        return beta.reshape(self.grid[0], self.heads // 2, -1)
+
 
 def _flat(t):
-    """[.., Q, w] -> [chunk-heads * Q, w]."""
+    """[.., Q, w] -> [chunk-heads * Q, w] (pairs: [pairs * Q, 2Q])."""
     return t.reshape(-1, t.shape[-1])
 
 
@@ -1334,17 +1427,18 @@ def _corrected_blocks(beta, kd, v):
 
 @functools.partial(jax.jit, static_argnums=(4,))
 def _corrected_forward(x, beta, kd, v, interpret):
-    """(T Kd in v's dtype [.., Q, dk], T V [.., Q, dv]) of x [.., Q, Q] and kd
-    [.., Q, dk] float32, beta [.., Q] and v ([.., Q, dv], or [b, s, h * dv]
-    where the model has it: `_corrected_blocks`), as a kernel."""
-    q, dk = x.shape[-1], kd.shape[-1]
+    """(T Kd in v's dtype [.., Q, dk], T V [.., Q, dv]) of x
+    [.., c / 2, Q, 2Q] (pairs) and kd [.., Q, dk] float32, beta [.., Q] and
+    v ([.., Q, dv], or [b, s, h * dv] where the model has it:
+    `_corrected_blocks`), as a kernel."""
+    q, dk = beta.shape[-1], kd.shape[-1]
     count = beta.size // q
     at = _corrected_blocks(beta, kd, v)
     dv = at.dv
     w, uv = pl.pallas_call(
         functools.partial(_kda_corrected_fwd_kernel, heads=at.heads),
         grid=at.grid,
-        in_specs=[at.rows(q), at.steps, at.rows(dk), at.value],
+        in_specs=[at.pairs, at.steps, at.rows(dk), at.value],
         out_specs=[at.rows(dk), at.rows(dv)],
         out_shape=[
             jax.ShapeDtypeStruct((count * q, dk), v.dtype),
@@ -1353,29 +1447,29 @@ def _corrected_forward(x, beta, kd, v, interpret):
         compiler_params=at.params,
         interpret=interpret,
         name="kda_corrected_fwd",
-    )(_flat(x), beta.reshape(-1, at.heads, q), _flat(kd), at.taken(v))
+    )(_flat(x), at.steps_taken(beta), _flat(kd), at.taken(v))
     return w.reshape(kd.shape), uv.reshape(*kd.shape[:-1], dv)
 
 
-@functools.partial(jax.jit, static_argnums=(6,))
-def _corrected_backward(x, beta, kd, v, dw, duv, interpret):
-    """The cotangents of (n, beta, kd, v), n the strictly lower matrix x is
-    the inverse of I + n of, from those of `_corrected_forward`'s results;
-    v's is written in v's layout."""
+@functools.partial(jax.jit, static_argnums=(7,))
+def _corrected_backward(x, a, beta, kd, v, dw, duv, interpret):
+    """The cotangents of (a, beta, kd, v), x the inverse of I + Diag(beta) a,
+    from those of `_corrected_forward`'s results; a's in pairs as a and x
+    are, v's in v's layout."""
     f32 = jnp.float32
-    q, dk = x.shape[-1], kd.shape[-1]
+    q, dk = beta.shape[-1], kd.shape[-1]
     count = beta.size // q
     at = _corrected_blocks(beta, kd, v)
     dv = at.dv
-    dn, dbeta, dkd, dvalue = pl.pallas_call(
+    da, dbeta, dkd, dvalue = pl.pallas_call(
         functools.partial(_kda_corrected_bwd_kernel, heads=at.heads),
         grid=at.grid,
-        in_specs=[at.rows(q), at.steps, at.rows(dk), at.value,
+        in_specs=[at.pairs, at.pairs, at.steps, at.rows(dk), at.value,
                   at.rows(dk), at.rows(dv)],
-        out_specs=[at.rows(q), at.steps, at.rows(dk), at.value],
+        out_specs=[at.pairs, at.steps, at.rows(dk), at.value],
         out_shape=[
-            jax.ShapeDtypeStruct((count * q, q), f32),
-            jax.ShapeDtypeStruct((count // at.heads, at.heads, q), f32),
+            jax.ShapeDtypeStruct((count // 2 * q, 2 * q), f32),
+            jax.ShapeDtypeStruct((at.grid[0], at.heads // 2, 2 * q), f32),
             jax.ShapeDtypeStruct((count * q, dk), f32),
             jax.ShapeDtypeStruct(
                 v.shape if at.in_place else (count * q, dv), v.dtype
@@ -1385,29 +1479,30 @@ def _corrected_backward(x, beta, kd, v, dw, duv, interpret):
         compiler_params=at.params,
         interpret=interpret,
         name="kda_corrected_bwd",
-    )(_flat(x), beta.reshape(-1, at.heads, q), _flat(kd), at.taken(v),
+    )(_flat(x), _flat(a), at.steps_taken(beta), _flat(kd), at.taken(v),
       _flat(dw), _flat(duv))
     return (
-        dn.reshape(x.shape), dbeta.reshape(beta.shape).astype(beta.dtype),
+        da.reshape(a.shape), dbeta.reshape(beta.shape).astype(beta.dtype),
         dkd.reshape(kd.shape), dvalue.reshape(v.shape),
     )
 
 
 @jax.custom_vjp
-def corrected_products(n, beta, kd, v):
-    """(T Kd, T V) in v's dtype, T = (I + n)^-1 Diag(beta), as kernels with
-    a WRITTEN backward (the section's comment): n [.., Q, Q] strictly lower
-    (Diag(beta) A) and kd [.., Q, dk] float32, v [.., Q, dv], beta [.., Q]
-    float32, over an even number of chunk-heads. What the backward keeps is
-    the inverse (`_KEPT`: under the node's checkpoint the inverse's kernel
-    runs once, this forward twice) beside beta, kd and v."""
-    return _corrected_products_fwd(n, beta, kd, v)[0]
+def corrected_products(a, beta, kd, v):
+    """(T Kd, T V) in v's dtype, T = (I + Diag(beta) A)^-1 Diag(beta), as
+    kernels with a WRITTEN backward (the section's comment): a
+    [.., c / 2, Q, 2Q] strictly lower in pairs (`_side_by_side`) and kd
+    [.., Q, dk] float32, v [.., Q, dv], beta [.., c, Q] float32. What the
+    backward keeps is the inverse, in pairs (`_KEPT`: under the node's
+    checkpoint the inverse's kernel runs once, this forward twice), beside a,
+    beta, kd and v."""
+    return _corrected_products_fwd(a, beta, kd, v)[0]
 
 
-def _corrected_products_fwd(n, beta, kd, v):
-    x = checkpoint_name(_pallas_inverse(n, context.interpret_default()), _KEPT)
-    kept = (x, beta, kd, v)
-    return _corrected_forward(*kept, context.interpret_default()), kept
+def _corrected_products_fwd(a, beta, kd, v):
+    interpret = context.interpret_default()
+    x = checkpoint_name(_pallas_inverse(a, beta, interpret), _KEPT)
+    return _corrected_forward(x, beta, kd, v, interpret), (x, a, beta, kd, v)
 
 
 def _corrected_products_bwd(kept, cotangents):
@@ -1418,23 +1513,27 @@ corrected_products.defvjp(_corrected_products_fwd, _corrected_products_bwd)
 
 
 def _kernel_corrected(a, kd, v, beta5):
-    """`_corrected` on the "kda" route, `dtype` v's: the kernels where they
-    take the number of chunk-heads (an even one), XLA's form with
-    `unit_lower_inverse` otherwise; which, noted as the node's
-    `triangular_products` (`kernels/context.note`): `kernels` (T (K exp(G)),
-    T V and the triangular system's whole backward from `kda_corrected_fwd` /
-    `kda_corrected_bwd`) or `xla` (`_corrected`, differentiated by JAX: the
-    "xla" route, and an odd number of chunk-heads here). v is [b, h, c, Q, dv], or
-    [b, s, h * dv] as the model has it, which the kernels read in place and
-    only XLA's form turns heads first."""
-    if (beta5.size // beta5.shape[-1]) % 2:
+    """`_corrected` on the "kda" route, `dtype` v's: the kernels where the
+    operands' kernel wrote A in pairs (`_side_by_side`: a head's chunks are
+    even in number), XLA's form with `unit_lower_inverse` where it wrote
+    A [.., Q, Q] a chunk; which, noted as the node's `triangular_products`
+    (`kernels/context.note`): `kernels` (the inverse, T (K exp(G)), T V and
+    the triangular system's whole backward from `kda_prep_inverse`,
+    `kda_corrected_fwd` / `kda_corrected_bwd`, with `triangular_layout`
+    `pairs` noted beside it: how A, X and dA cross HBM) or `xla`
+    (`_corrected`, differentiated by JAX: the "xla" route, and an odd number
+    of chunks a head here). v is [b, h, c, Q, dv], or [b, s, h * dv] as the
+    model has it, which the kernels read in place and only XLA's form turns
+    heads first."""
+    if a.shape[-1] == a.shape[-2]:
         context.note("triangular_products", "xla")
         if v.ndim != kd.ndim:
             b, h, c, q = beta5.shape
             v = jnp.transpose(v.reshape(b, c, q, h, -1), (0, 3, 1, 2, 4))
         return _corrected(a, kd, v, beta5, v.dtype)
     context.note("triangular_products", "kernels")
-    return corrected_products(beta5[..., :, None] * a, beta5, kd, v)
+    context.note("triangular_layout", "pairs")
+    return corrected_products(a, beta5, kd, v)
 
 
 # what a padded position's decay pre-activation reads: its softplus, and so
@@ -1528,9 +1627,10 @@ def _gdn_prep_fwd_kernel(
     q_ref, k_ref, g_ref, qd_ref, ke_ref, p_ref, gam_ref, a_ref, kd_ref,
     *, chunks: int, q: int, scale: float,
 ):
-    """Q exp(G), K exp(G_Q - G), P, exp(G_Q), the strictly lower A and
-    K exp(G) (float32, for the triangular system) of each of the program's
-    chunks and each value head of its key head, from the RAW q, k [n Q, dk]
+    """Q exp(G), K exp(G_Q - G), P, exp(G_Q), the strictly lower A
+    (`_side_by_side`) and K exp(G) (float32, for the triangular system) of
+    each of the program's chunks and each value head of its key head, from
+    the RAW q, k [n Q, dk]
     of the key head, read where the convolution left them and put over
     their own 2-norm here (`_unit_root`: float32, ROUNDED to the input's
     dtype; q times `scale`), and the heads' log-decays g [group, n, Q]."""
@@ -1539,7 +1639,7 @@ def _gdn_prep_fwd_kernel(
     group = g_ref.shape[0]
     tri, strict, after, _ = _chunk_masks(q)
 
-    def one_chunk(c, _):
+    def one_chunk(c, i, half, _):
         rows = pl.ds(pl.multiple_of(c * q, q), q)
         qn, _ = _unit_root(q_ref[rows, :], scale)
         kn, _ = _unit_root(k_ref[rows, :], 1.0)
@@ -1554,7 +1654,7 @@ def _gdn_prep_fwd_kernel(
             m = jnp.exp(between)
             from_start = jnp.exp(from_start)
             p_ref[h, rows, :] = jnp.where(tri, m * qk, 0.0).astype(dtype)
-            a_ref[h, rows, :] = jnp.where(strict, m * kk, 0.0)
+            a_ref[(h, *_beside(i, half, q))] = jnp.where(strict, m * kk, 0.0)
             qd_ref[h, rows, :] = (qf * from_start).astype(dtype)
             kd_ref[h, rows, :] = kf * from_start
             ke_ref[h, rows, :] = (kf * jnp.exp(to_end)).astype(dtype)
@@ -1562,7 +1662,7 @@ def _gdn_prep_fwd_kernel(
                 from_start[q - 1:q, :], (1, kf.shape[1])
             )
 
-    lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
+    _each_chunk(chunks, one_chunk)
 
 
 def _gdn_prep_bwd_kernel(
@@ -1583,7 +1683,7 @@ def _gdn_prep_bwd_kernel(
     tri, strict, after, after_t = _chunk_masks(q)
     last = lax.broadcasted_iota(jnp.int32, (q, 1), 0) == q - 1
 
-    def one_chunk(c, _):
+    def one_chunk(c, i, half, _):
         rows = pl.ds(pl.multiple_of(c * q, q), q)
         qn, q_root = _unit_root(q_ref[rows, :], scale)
         kn, k_root = _unit_root(k_ref[rows, :], 1.0)
@@ -1604,7 +1704,7 @@ def _gdn_prep_bwd_kernel(
             dqd, dkd = dqd_ref[h, rows, :].astype(f32), dkd_ref[h, rows, :]
             dke = dke_ref[h, rows, :].astype(f32)
             dp = dp_ref[h, rows, :].astype(f32)
-            da = jnp.where(strict, da_ref[h, rows, :], 0.0)
+            da = jnp.where(strict, da_ref[(h, *_beside(i, half, q))], 0.0)
             dq = dq + dqd * from_start
             dk = dk + dkd * from_start + dke * to_end
             dqk = dqk + m * dp
@@ -1631,7 +1731,7 @@ def _gdn_prep_bwd_kernel(
             k_ref[rows, :], k_root, dk, 1.0
         ).astype(dk_ref.dtype)
 
-    lax.fori_loop(0, chunks, one_chunk, None, unroll=True)
+    _each_chunk(chunks, one_chunk)
 
 
 class _HeadPrepBlocks:
@@ -1641,18 +1741,23 @@ class _HeadPrepBlocks:
     program's rows (`key_head`: what `conv_silu` wrote and what its backward
     reads, no heads-first copy between); what a value head has,
     [b, hv, s, .], the rows of the key head's `group` value heads (head
-    hi * group onward: a key head is indexed, never repeated); g
-    [b, hv, c / n, n, Q] and exp(G_Q) [b, hv, c, 1, dk] likewise."""
+    hi * group onward: a key head is indexed, never repeated), A and its
+    cotangent (`beside`) `side` chunks side by side,
+    [b, hv, s / side, side * Q]; g [b, hv, c / n, n, Q] and exp(G_Q)
+    [b, hv, c, 1, dk] likewise."""
 
     def __init__(self, b: int, hk: int, group: int, s: int, dk: int, q: int):
         c = s // q
         n = self.chunks = next(n for n in _PREP_CHUNKS if c % n == 0)
+        side = self.side = _side_by_side(n)
         self.grid = (b, hk, c // n)
 
-        def rows(width):
+        def rows(width, count=n * q):
             return pl.BlockSpec(
-                (None, group, n * q, width), lambda bi, hi, gi: (bi, hi, gi, 0)
+                (None, group, count, width), lambda bi, hi, gi: (bi, hi, gi, 0)
             )
+
+        self.beside = rows(side * q, n * q // side)
 
         self.key_head = pl.BlockSpec(
             (None, n * q, dk), lambda bi, hi, gi: (bi, gi, hi)
@@ -1665,7 +1770,8 @@ class _HeadPrepBlocks:
             (None, group, n, 1, dk), lambda bi, hi, gi: (bi, hi, gi, 0, 0)
         )
         # a program's blocks in a bf16 step (q, k and the six results or
-        # their cotangents; a [., Q] tile fills 128 lanes), twice for the
+        # their cotangents; a [., Q] tile fills 128 lanes, side by side or
+        # not), twice for the
         # pipeline's two buffers, and as much again for what the body holds
         block = n * q * (
             2 * 2 * dk + group * (2 * 2 * dk + 4 * dk + 6 * _LANES)
@@ -1682,7 +1788,8 @@ def _head_prep_forward(q, k, g, chunk, dk, interpret):
     convolution's pieces as they lie) and g [b, hv, s] float32; by chunk and
     VALUE head out ([b, hv, c, Q, .]): qd, ke, p, gamma as
     `head_decay_operands` has them of the normalised q and k, then A
-    [., Q, Q] and K exp(G) [., Q, dk], float32."""
+    (`_side_by_side`: [b, hv, c / 2, Q, 2 Q] where the chunks are even in
+    number) and K exp(G) [., Q, dk], float32."""
     f32 = jnp.float32
     b, s, width = q.shape
     hk, hv = width // dk, g.shape[1]
@@ -1698,11 +1805,12 @@ def _head_prep_forward(q, k, g, chunk, dk, interpret):
         ),
         grid=at.grid,
         in_specs=[at.key_head, at.key_head, at.decay],
-        out_specs=[at.key, at.key, at.scores, at.gamma, at.scores, at.key],
+        out_specs=[at.key, at.key, at.scores, at.gamma, at.beside, at.key],
         out_shape=[
             rows(dk, q.dtype), rows(dk, q.dtype), rows(chunk, q.dtype),
             jax.ShapeDtypeStruct((b, hv, c, 1, dk), f32),
-            rows(chunk, f32), rows(dk, f32),
+            jax.ShapeDtypeStruct((b, hv, s // at.side, at.side * chunk), f32),
+            rows(dk, f32),
         ],
         compiler_params=at.params,
         interpret=interpret,
@@ -1710,7 +1818,7 @@ def _head_prep_forward(q, k, g, chunk, dk, interpret):
     )(q, k, g.reshape(b, hv, c // at.chunks, at.chunks, chunk))
 
     def by_chunk(t):
-        return t.reshape(b, hv, c, chunk, t.shape[-1])
+        return t.reshape(b, hv, -1, chunk, t.shape[-1])
 
     return by_chunk(qd), by_chunk(ke), by_chunk(p), gamma, by_chunk(a), by_chunk(kd)
 
@@ -1731,7 +1839,7 @@ def _head_prep_backward(q, k, g, cotangents, chunk, dk, interpret):
         grid=at.grid,
         in_specs=[
             at.key_head, at.key_head, at.decay,
-            at.key, at.key, at.scores, at.gamma, at.scores, at.key,
+            at.key, at.key, at.scores, at.gamma, at.beside, at.key,
         ],
         out_specs=[at.key_head, at.key_head, at.decay],
         out_shape=[
@@ -1825,7 +1933,7 @@ def operand_form(attrs: GatedDeltaAttrs, route: str, seq: int) -> str:
         form = "channel_kernels" if route == "kda" else "xla"
     context.note("delta_rule_operands", form)
     if route != "kda":
-        # on the "kda" route the number of chunk-heads chooses
+        # on the "kda" route the number of a head's chunks chooses
         # (`_kernel_corrected`)
         context.note("triangular_products", "xla")
     return form

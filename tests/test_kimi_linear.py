@@ -391,7 +391,11 @@ def test_the_whole_node_on_the_kernels_agrees_with_the_xla_route(monkeypatch):
 # The products around the triangular inverse (`kda._kernel_corrected`, PR 54):
 # `lead` chunk-heads [b, h, c] of both cells' shape, chunks of 64 at heads of
 # 128 | 128. Eight are ONE program of `kda_corrected_fwd` / `kda_corrected_bwd`,
-# four one of four (`_CORRECTED_HEADS`); an odd number the kernels do not take.
+# four one of four, two one of two (`_CORRECTED_HEADS`) and one pair of the
+# inverse's kernel; six are three pairs, one a program of the inverse's, a
+# count that is no multiple of four; an odd number of chunks a head the
+# kernels do not take. Since PR 71 A, the inverse and dA cross HBM two
+# chunk-heads to a row (`in_pairs`, `kda._side_by_side`).
 
 
 def triangular_case(lead, dtype, seed=17):
@@ -428,6 +432,22 @@ def xla_corrected(a, kd, v, beta):
     return kda._corrected(a, kd, v, beta, v.dtype)
 
 
+def in_pairs(a):
+    """[.., c, Q, Q] -> [.., c / 2, Q, 2Q]: chunk 2 p in lanes [0, Q), chunk
+    2 p + 1 beside it, as the operands' kernels write A where a head's
+    chunks are even in number (`kda._side_by_side`)."""
+    return jnp.concatenate([a[..., 0::2, :, :], a[..., 1::2, :, :]], axis=-1)
+
+
+def kernel_corrected(a, kd, v, beta):
+    """`kda._kernel_corrected` as the operands' kernels hand it A: in pairs,
+    or a chunk a row where a head's chunks are odd in number; a's cotangent
+    comes back [.., c, Q, Q] through `in_pairs`' own transpose."""
+    return kda._kernel_corrected(
+        a if a.shape[-3] % 2 else in_pairs(a), kd, v, beta
+    )
+
+
 def assert_triangular_products_agree(got, want, dtype):
     """w, uv to float32 rounding, or in bf16 to the last bit but for a value
     in a thousand one ulp off (a float32 sum in another order rounds the
@@ -452,8 +472,10 @@ def assert_triangular_products_agree(got, want, dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize(
-    "lead", [(1, 2, 4), (1, 2, 2), (1, 1, 3)],
-    ids=["eight_a_program", "four_a_program", "odd_count_falls_back"],
+    "lead", [(1, 2, 4), (1, 2, 2), (1, 1, 3), (1, 1, 2), (1, 3, 2), (2, 1, 3)],
+    ids=["eight_a_program", "four_a_program", "odd_count_falls_back",
+         "two_a_program", "three_pairs_one_a_program",
+         "odd_chunks_a_head_fall_back"],
 )
 def test_triangular_product_kernels_agree_with_xlas_products(
     monkeypatch, lead, dtype
@@ -464,18 +486,38 @@ def test_triangular_product_kernels_agree_with_xlas_products(
     JAX's own gradient of it (six): T (K exp(G)), T V and the cotangents of
     A, K exp(G), V and beta. A float32 step gives every operand three bf16
     parts, a bf16 step leaves out the terms of v's, dw's and duv's second and
-    third, which are exactly zero. An odd number of chunk-heads keeps XLA's
-    form, bit for bit."""
+    third, which are exactly zero. An odd number of chunks a head (and so
+    every odd number of chunk-heads) keeps XLA's form, bit for bit."""
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     operands, cots = triangular_case(lead, dtype)
-    got = triangular_products(kda._kernel_corrected, operands, cots)
+    got = triangular_products(kernel_corrected, operands, cots)
     want = triangular_products(xla_corrected, operands, cots)
-    if np.prod(lead) % 2:
+    if lead[-1] % 2:
         assert_trees_close(got, want, rtol=0, atol=0)
     else:
         assert float(jnp.max(jnp.abs(got[1][0]))) > 1e-2  # dn is reached
         assert not np.any(np.triu(np.asarray(got[1][0])))
         assert_triangular_products_agree(got, want, dtype)
+
+
+@pytest.mark.parametrize(
+    "lead", [(1, 2, 4), (1, 3, 2), (1, 1, 2)],
+    ids=["four_pairs_a_program", "three_pairs_one_a_program", "one_pair"],
+)
+def test_the_inverses_kernel_agrees_with_unit_lower_inverse(monkeypatch, lead):
+    """`kda._pallas_inverse` (interpret mode: A and beta in, Diag(beta) A
+    formed in VMEM, the pair written as ONE [Q, 2Q] row block) against
+    `unit_lower_inverse` of XLA's Diag(beta) A, laid in pairs: the levels
+    are the same and the products add in another order (measured 2e-7 at
+    entries of 1)."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    a, _, _, beta = triangular_case(lead, jnp.float32, seed=19)[0]
+    got = kda._pallas_inverse(in_pairs(a), beta, True)
+    with jax.default_matmul_precision("highest"):
+        want = in_pairs(kda.unit_lower_inverse(beta[..., :, None] * a))
+    assert got.shape == (*lead[:2], lead[2] // 2, 64, 128)
+    assert float(jnp.max(jnp.abs(want))) >= 1.0  # the diagonal is there
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
 def test_a_step_of_zero_and_a_padded_position_give_zero_rows(monkeypatch):
@@ -490,7 +532,7 @@ def test_a_step_of_zero_and_a_padded_position_give_zero_rows(monkeypatch):
     a = a.at[..., padded, :].set(0.0).at[..., :, padded].set(0.0)
     kd = kd.at[..., padded, :].set(0.0)
     operands = (a, kd, v, beta)
-    got = triangular_products(kda._kernel_corrected, operands, cots)
+    got = triangular_products(kernel_corrected, operands, cots)
     for t in got[0]:
         assert not np.any(np.asarray(t)[..., still, :])
         assert not np.any(np.asarray(t)[..., padded, :])
@@ -857,6 +899,63 @@ def system_loss(model, inputs, labels):
     read = bench.make_loss_reader(model.instance)
     batch, label = bench.place_batch(model.instance, inputs, labels)
     return read(model.params, batch, label)
+
+
+def rehearsal_choices(monkeypatch, ref, name, adam, **lane_sized):
+    """`trace.kernel_choices()` after the loss of the rehearsal cell
+    `benchmark/configs/rehearsal-<name>.json` is traced (nothing runs) on the
+    kernels' route: one row of 128 positions, its delta-rule heads
+    `lane_sized` to the published 128 | 128 in chunks of 64, which is what
+    the route asks of a node (`kda.scan_route`)."""
+    from flexflow_tpu.observability import trace
+
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    monkeypatch.setattr(context, "_CHOICES", {})
+    sizes = dict(
+        bench.load_json(os.path.join(BENCH, "configs", f"rehearsal-{name}.json")),
+        **lane_sized,
+    )
+    builder, logits = ref.build(sizes, 1, 128)
+    model = FFModel.from_computation_graph(
+        builder, logits, FFConfig(batch_size=1, seed=7, print_freq=0)
+    )
+    model.compile(
+        AdamOptimizer(
+            alpha=adam["alpha"], beta1=adam["beta1"], beta2=adam["beta2"],
+            epsilon=adam["epsilon"], weight_decay=adam["weight_decay"],
+        ),
+        adam["loss"],
+    )
+    inputs, labels = ref.make_data(np.random.RandomState(0), sizes, 1, 128)
+    batch, label = bench.place_batch(model.instance, inputs, labels)
+    jax.eval_shape(
+        lambda p: model.instance.loss_fn(p, batch, label)[0], model.params
+    )
+    return trace.kernel_choices()
+
+
+def test_the_rehearsal_graphs_nodes_say_how_the_triangular_system_crosses_hbm(
+    monkeypatch,
+):
+    """`trace.kernel_choices("triangular_layout")` names `pairs` for the four
+    delta-rule nodes of the rehearsal graph at lane-sized heads, beside
+    `triangular_products` = `kernels` (PR 71: A, the inverse and dA two
+    chunk-heads to a 128-lane row); a node on XLA's form notes no layout
+    (`tests/test_qwen3_next.py` has the scalar-decay graph's three)."""
+    la = dict(
+        bench.load_json(os.path.join(BENCH, "configs", "rehearsal-kimi.json"))[
+            "linear_attn_config"
+        ],
+        head_dim=128,
+    )
+    noted = rehearsal_choices(
+        monkeypatch, ref, "kimi", ADAM, linear_attn_config=la, kda_chunk_size=64
+    )
+    nodes = [f"ff.kda.kda{layer}" for layer in la["kda_layers"]]
+    assert len(nodes) == 4
+    for node in nodes:
+        assert noted[node]["triangular_products"] == "kernels"
+        assert noted[node]["triangular_layout"] == "pairs"
 
 
 def test_layers_are_the_published_period():

@@ -17,7 +17,8 @@ import pytest
 
 from test_kimi_linear import (
     HEAD_NORM_IDS, HEAD_NORM_SHAPES, assert_head_norms_agree,
-    assert_triangular_products_agree, head_norm_case,
+    assert_triangular_products_agree, head_norm_case, kernel_corrected,
+    rehearsal_choices,
     head_norm_with_gradients, kernel_head_norm, plain_head_norm,
     triangular_case, triangular_products, xla_corrected,
 )
@@ -217,11 +218,12 @@ def plain_head_operands(q, k, v, g, beta, chunk, key_dim):
 # q and k, a value head one of v (`_HeadPrepBlocks.key_head`,
 # `_CorrectedBlocks.value`). 256 positions are four chunks, all in ONE
 # program of the kernels; 192 are three, one a program (`_PREP_CHUNKS`),
-# with one value head an odd number of chunk-heads, which XLA's
-# `unit_lower_inverse` inverts where the inverse's kernel takes them two by
-# two (v turned heads first for it), and with two value heads an even number
-# that `kda_corrected_*` take one chunk-head a program, a head's chunks being
-# odd; 100 positions pad to 128 as the node pads them (raw q = k = v = 0,
+# and an odd number of chunks a head, which XLA's `unit_lower_inverse`
+# inverts where the triangular system's kernels take a head's chunks two by
+# two (A a chunk a row from `gdn_prep_fwd`, v turned heads first for XLA's
+# form), with one value head and with two; 384 are six, three pairs a head
+# and two chunk-heads a program of `kda_corrected_*`; 512 eight, one
+# program a head; 100 positions pad to 128 as the node pads them (raw q = k = v = 0,
 # beta = 0, a decay all the same). A log-decay of -27 to -30 a position puts
 # exp(G_r - G_j) under float32's least (e^-103.3) four positions apart and
 # exp(-G) of the textbook form over its greatest inside three.
@@ -229,11 +231,12 @@ def plain_head_operands(q, k, v, g, beta, chunk, key_dim):
     "key_heads,group,seq,decay",
     [(1, 1, 128, 1.0), (1, 2, 256, 1.0), (1, 4, 128, 1.0), (1, 2, 100, 1.0),
      (1, 1, 192, 1.0), (1, 2, 128, 30.0), (3, 2, 128, 1.0), (1, 2, 192, 1.0),
-     (2, 1, 256, 1.0)],
+     (2, 1, 256, 1.0), (1, 1, 384, 1.0), (1, 2, 512, 1.0)],
     ids=["one_value_head", "two_value_heads", "four_value_heads",
          "padded_to_the_chunk", "odd_count_of_chunks", "decay_underflows",
          "three_key_heads_of_two_value_heads", "odd_chunks_a_head",
-         "two_key_heads_of_one_value_head"],
+         "two_key_heads_of_one_value_head", "three_pairs_a_head",
+         "eight_chunks_a_program"],
 )
 def test_scalar_decay_kernels_agree_with_the_xla_operands(
     monkeypatch, key_heads, group, seq, decay
@@ -296,8 +299,9 @@ def test_scalar_decay_kernels_agree_with_the_xla_operands(
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
 @pytest.mark.parametrize(
-    "lead", [(1, 4, 8), (1, 3, 2), (1, 3, 1)],
-    ids=["two_programs_of_sixteen", "three_programs_of_two", "odd_count_falls_back"],
+    "lead", [(1, 4, 8), (1, 3, 2), (1, 3, 1), (1, 1, 8), (2, 1, 4)],
+    ids=["two_programs_of_sixteen", "three_programs_of_two",
+         "odd_count_falls_back", "one_program_of_eight", "two_programs_of_four"],
 )
 def test_triangular_product_kernels_take_the_value_heads_chunks(
     monkeypatch, lead, dtype
@@ -305,16 +309,50 @@ def test_triangular_product_kernels_take_the_value_heads_chunks(
     """`kda._kernel_corrected` as `head_kernel_operands` calls it, by chunk
     and VALUE head (`tests/test_kimi_linear.py` has the per-channel form's
     counts and what is compared): thirty-two chunk-heads are two programs
-    of `kda_corrected_fwd` / `kda_corrected_bwd`, six are three of two, and
-    three keep `_corrected` with `unit_lower_inverse`, bit for bit."""
+    of `kda_corrected_fwd` / `kda_corrected_bwd`, six are three of two (three
+    pairs, one a program of the inverse's kernel), and three heads of one
+    chunk keep `_corrected` with `unit_lower_inverse`, bit for bit."""
     monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
     operands, cots = triangular_case(lead, dtype, seed=29)
-    got = triangular_products(kda._kernel_corrected, operands, cots)
+    got = triangular_products(kernel_corrected, operands, cots)
     want = triangular_products(xla_corrected, operands, cots)
-    if np.prod(lead) % 2:
+    if lead[-1] % 2:
         assert_trees_close(got, want, rtol=0, atol=0)
     else:
         assert_triangular_products_agree(got, want, dtype)
+
+
+@pytest.mark.parametrize(
+    "heads,chunks", [(2, 2), (1, 4), (2, 8), (1, 6), (1, 3)],
+    ids=["two_a_program", "four_a_program", "eight_a_program",
+         "three_pairs_a_head", "odd_chunks_a_head_take_xlas_form"],
+)
+def test_triangular_product_kernels_read_v_where_the_model_has_it(
+    monkeypatch, heads, chunks
+):
+    """`kda._kernel_corrected` with v [b, s, heads * dv] as the convolution
+    leaves it (Qwen3-Next's form: a program's chunk-heads are chunks of ONE
+    head, X, A and dA its pairs) against `_corrected` on v heads first: the
+    two products and the cotangents of A, K exp(G), v (in the model's
+    layout) and beta. An odd number of chunks a head is the ONE thing that
+    still takes XLA's form on the route (`triangular_products` "xla")."""
+    monkeypatch.setenv("FLEXFLOW_TPU_FLASH_INTERPRET", "1")
+    (a, kd, v, beta), cots = triangular_case((1, heads, chunks), jnp.float32, seed=43)
+
+    def as_the_model_has(t):  # [b, h, c, Q, d] -> [b, s, h * d]
+        b, h, c, q, d = t.shape
+        return jnp.transpose(t, (0, 2, 3, 1, 4)).reshape(b, c * q, h * d)
+
+    got = triangular_products(
+        kernel_corrected, (a, kd, as_the_model_has(v), beta), cots
+    )
+    out, (da, dkd, dv, dbeta) = triangular_products(
+        xla_corrected, (a, kd, v, beta), cots
+    )
+    assert got[1][2].shape == (1, chunks * 64, heads * 128)
+    assert_triangular_products_agree(
+        got, (out, (da, dkd, as_the_model_has(dv), dbeta)), jnp.float32
+    )
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
@@ -508,7 +546,12 @@ def test_the_lowered_node_hands_the_pieces_from_kernel_to_kernel(monkeypatch, en
     results of `gdn_prep_bwd` and `kda_corrected_bwd`: no `transpose`,
     `reshape`, `convert` or `pad` of q, k, v or their cotangents lies
     between the convolution's and the recurrence's kernels (a Pallas operand
-    must be a buffer: XLA writes out whatever lies between)."""
+    must be a buffer: XLA writes out whatever lies between). Since PR 71 the
+    triangular system's arrays go from kernel to kernel the same way, in
+    pairs: A is the very result of `gdn_prep_fwd` where `kda_prep_inverse`
+    and `kda_corrected_bwd` read it, and dA the very result of
+    `kda_corrected_bwd` where `gdn_prep_bwd` reads it (no Diag(beta) A, no
+    dn and no un-pairing in XLA)."""
     entered(context.described_tpu())
     attrs, u, ws, cot = kernel_sized_node(128)
 
@@ -541,7 +584,13 @@ def test_the_lowered_node_hands_the_pieces_from_kernel_to_kernel(monkeypatch, en
                 ("_conv_forward", 0), ("_conv_forward", 1)
             ], (callee, operands)
         if callee in ("_corrected_forward", "_corrected_backward"):
-            assert source(operands[3]) == ("_conv_forward", 2), (callee, operands)
+            v = operands[3 + (callee == "_corrected_backward")]
+            assert source(v) == ("_conv_forward", 2), (callee, operands)
+        if callee in ("_pallas_inverse", "_corrected_backward"):
+            a = operands[callee == "_corrected_backward"]
+            assert source(a) == ("_head_prep_forward", 4), (callee, operands)
+        if callee == "_head_prep_backward":
+            assert source(operands[7]) == ("_corrected_backward", 0), operands
         if callee in took:
             took[callee].append(result)
         if callee == "_conv_backward":
@@ -553,17 +602,18 @@ def test_the_lowered_node_hands_the_pieces_from_kernel_to_kernel(monkeypatch, en
     assert [len(v) for v in took.values()] == [2, 2]
     names = [callee for callee, _, _ in calls]
     for once in ("_conv_forward", "_conv_backward", "_head_prep_backward",
-                 "_corrected_backward"):
+                 "_corrected_backward", "_pallas_inverse"):
         assert names.count(once) == 1, names
 
 
 def test_the_triangular_products_form_is_counted_by_node(monkeypatch, entered):
     """`observability/trace.kernel_choices("triangular_products")` names the form the
     products around the triangular inverse took in each delta-rule node:
-    `kernels` on the "kda" route for both forms of the decay, `xla` under
-    `no_flash()`, on the plain CPU and where the route's number of
-    chunk-heads is odd."""
-    attrs, u, ws, _ = kernel_sized_node(64)
+    `kernels` on the "kda" route for both forms of the decay (two chunks a
+    head: since PR 71 the kernels take a head's chunks two by two, and
+    `triangular_layout` says `pairs` beside it), `xla` under `no_flash()`,
+    on the plain CPU and where a head's chunks are odd in number."""
+    attrs, u, ws, _ = kernel_sized_node(128)
     channel = GatedDeltaAttrs(2, 128, 128, 4, 8, 64, 1e-5)
     rs = np.random.RandomState(31)
     channel_ws = [
@@ -605,10 +655,32 @@ def test_the_triangular_products_form_is_counted_by_node(monkeypatch, entered):
         "ff.kda.kda0": "kernels", "ff.kda.gdn1": "xla", "ff.kda.kda1": "xla",
         "ff.kda.gdn2": "xla",
     }
+    # the layout is the kernels' alone: XLA's form notes none
+    assert trace.kernel_choices("triangular_layout") == {
+        "ff.kda.gdn0": "pairs", "ff.kda.kda0": "pairs",
+    }
     # a kernel called by itself, under no node's scope, is not counted
     entered(context.lowering_node(None))
-    kda._kernel_corrected(*triangular_case((1, 1, 2), jnp.float32)[0])
+    kernel_corrected(*triangular_case((1, 1, 2), jnp.float32)[0])
     assert len(trace.kernel_choices("triangular_products")) == 6
+    assert len(trace.kernel_choices("triangular_layout")) == 2
+
+
+def test_the_rehearsal_graphs_nodes_say_how_the_triangular_system_crosses_hbm(
+    monkeypatch,
+):
+    """`trace.kernel_choices("triangular_layout")` names `pairs` for the
+    three scalar-decay nodes of the rehearsal graph at lane-sized heads
+    (`tests/test_kimi_linear.py` has the helper and the per-channel graph's
+    four)."""
+    noted = rehearsal_choices(
+        monkeypatch, ref, "qwen3next", ADAM, linear_key_head_dim=128,
+        linear_value_head_dim=128, gdn_chunk_size=64,
+    )
+    for node in ("ff.kda.gdn0", "ff.kda.gdn1", "ff.kda.gdn2"):
+        assert noted[node]["triangular_products"] == "kernels"
+        assert noted[node]["triangular_layout"] == "pairs"
+        assert noted[node]["delta_rule_operands"] == "head_kernels_in_place"
 
 
 def test_the_head_norm_form_is_counted_by_node(monkeypatch, entered):

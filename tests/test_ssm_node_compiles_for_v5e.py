@@ -393,6 +393,7 @@ KIMI_INVARIANTS = [
     "wide_key_flash_compiles_forward_and_backward",
     "kda_gated_norm_is_two_kernels_and_no_float32_of_the_rows_width",
     "kda_convolution_is_two_kernels_on_the_projections_row",
+    "kda_triangular_system_crosses_hbm_in_pairs",
 ]
 
 
@@ -459,6 +460,38 @@ def _corrected_kernels_limit(chunk_heads, in_place=None):
         chunk_heads, 64, 128, 128, 2, in_place
     ).params.vmem_limit_bytes
     return "ok" if limit <= V5E_SCOPED_VMEM else f"{limit} bytes stated"
+
+
+_TRIANGULAR_KERNELS = ("kda_prep_inverse", "kda_corrected_fwd", "kda_corrected_bwd")
+
+
+def _triangular_system_in_pairs(text):
+    """"ok" where the triangular system's three kernels (PR 71) have no
+    float32 operand or result with 64-lane rows ([.., 64]: half a lane tile,
+    twice its bytes in HBM and a fifth of the stream's rate; A, the inverse,
+    dn and dA were that) and each takes or gives a float32 [rows, 128] pair
+    array: A, X and dA go from kernel to kernel two chunk-heads to a row,
+    read from the compiled custom calls' operand layouts and results."""
+    seen, half_rows = set(), []
+    for name, result, opcode, _, line in entry_instructions(text):
+        kernel = re.search(r"/(\w+)/pallas_call", line)
+        if opcode != "custom-call" or not kernel:
+            continue
+        kernel = kernel.group(1)
+        if kernel not in _TRIANGULAR_KERNELS:
+            continue
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=", line)
+        shapes = list(shapes_of(result)) + list(shapes_of(operands.group(1)))
+        half_rows += [
+            f"{name} f32{list(dims)}" for dtype, dims in shapes
+            if dtype == "f32" and dims[-1] == 64
+        ]
+        if any(dtype == "f32" and len(dims) == 2 and dims[1] == 128
+               and dims[0] % 64 == 0 for dtype, dims in shapes):
+            seen.add(kernel)
+    if not half_rows and seen == set(_TRIANGULAR_KERNELS):
+        return "ok"
+    return ", ".join(half_rows) or f"pairs on {sorted(seen)} alone"
 
 
 def _gated_norm_part(text, elements):
@@ -568,6 +601,7 @@ def check_kimi():
         found[KIMI_INVARIANTS[4]] = _corrected_kernels_limit(heads * ROWS // 64)
         found[KIMI_INVARIANTS[6]] = _gated_norm_part(text, ROWS * heads * 128)
         found[KIMI_INVARIANTS[7]] = _conv_part(text, ROWS, 3 * heads * 128)
+        found[KIMI_INVARIANTS[8]] = _triangular_system_in_pairs(text)
     except Exception as e:  # noqa: BLE001 - the complaint is the result
         complaint = f"{type(e).__name__}: {e}"[:2000]
         for invariant in KIMI_INVARIANTS[:5] + KIMI_INVARIANTS[6:]:
@@ -869,6 +903,7 @@ QWEN3NEXT_INVARIANTS = [
     "head_decay_gated_norm_is_two_kernels_and_no_float32_of_the_rows_width",
     "head_decay_convolution_is_two_kernels_on_the_projections_row",
     "head_decay_recurrence_reads_the_convolutions_pieces_where_they_lie",
+    "head_decay_triangular_system_crosses_hbm_in_pairs",
 ]
 # the chip's default for a kernel's scoped VMEM
 V5E_SCOPED_VMEM = 16 * 1024 * 1024
@@ -962,30 +997,33 @@ def check_qwen3next():
              ("bwd", "kda", "gdn0/conv", "conv_silu_bwd")]
         )
         found[QWEN3NEXT_INVARIANTS[3]] = "ok" if calls == want else f"{calls}"
-        # a float32 [.., 64, 64] buffer under `prep` is a kernel's (A, the
-        # inverse, the triangular system's cotangent dn), the kept inverse's
-        # `reduce_precision` or ONE product Diag(beta) A; no `dot_general`
-        # a value head is left since PR 54 (the inverse's backward and
-        # `_corrected`'s cotangents were six), and the masks exp(G_r - G_j)
-        # were a `sub` and Q K^T, K K^T products a KEY head ([16, ..] and
-        # [16, 2, ..])
-        tiles = [
+        # no float32 [.., 64, 64] or [rows, 64] buffer is left under `prep`
+        # at all since PR 71:
+        # A, the inverse and dA are kernels' results two chunk-heads to a
+        # 128-lane row, Diag(beta) A and dn exist in VMEM alone (a kernel's
+        # [.., 64, 64], the kept inverse's `reduce_precision` and ONE `mul`
+        # were allowed before); no `dot_general` a value head since PR 54
+        # (the inverse's backward and `_corrected`'s cotangents were six),
+        # and the masks exp(G_r - G_j) were a `sub` and Q K^T, K K^T
+        # products a KEY head ([16, ..] and [16, 2, ..]). What IS there, in
+        # pairs, must be a kernel's or the kept inverse's `reduce_precision`
+        under_prep = [
             (name, line.split('op_name="')[1].split('"')[0].split("/")[-1], dims)
             for name, result, opcode, _, line in entry_instructions(text)
             if opcode not in _NO_BUFFER and "/prep/" in line
             for dtype, dims in shapes_of(result)
-            if dtype == "f32" and dims[-1] == 64
-            and (dims[-2] == 64 or len(dims) == 2)
+            if dtype == "f32" and (
+                (dims[-1] == 64 and (dims[-2] == 64 or len(dims) == 2))
+                or dims[-2:] == (64, 128) or (len(dims) == 2 and dims[-1] == 128)
+            )
         ]
-        makers = {"pallas_call", "reduce_precision", "mul"}
         strays = [
-            f"{name} {made_by} f32{list(dims)}" for name, made_by, dims in tiles
-            if made_by not in makers or attrs.key_heads in dims[:-2]
+            f"{name} {made_by} f32{list(dims)}" for name, made_by, dims in under_prep
+            if dims[-1] == 64 or made_by not in ("pallas_call", "reduce_precision")
         ]
-        products = sum(made_by == "mul" for _, made_by, _ in tiles)
         found[QWEN3NEXT_INVARIANTS[4]] = (
-            "ok" if tiles and not strays and products <= 1
-            else ", ".join(strays) or f"{len(tiles)} tiles, {products} `mul`"
+            "ok" if under_prep and not strays
+            else ", ".join(strays) or "no pair array under prep"
         )
         # the compile above is Mosaic's of both kernels under the limit
         # their `CompilerParams` carry, which is the chip's default here
@@ -1022,6 +1060,7 @@ def check_qwen3next():
             )
         ]
         found[QWEN3NEXT_INVARIANTS[9]] = "ok" if not glue else ", ".join(glue)
+        found[QWEN3NEXT_INVARIANTS[10]] = _triangular_system_in_pairs(text)
         per_position = [
             f"{name}: {result[:60]}"
             for name, result, opcode, _, _ in entry_instructions(text)
